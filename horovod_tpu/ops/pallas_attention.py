@@ -88,8 +88,11 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
     def _finalize():
         l_safe = jnp.maximum(l_ref[:], 1e-30)
         o_ref[0] = (acc_ref[:] / l_safe).astype(o_ref.dtype)
-        # log-sum-exp residual for the backward pass: lse = m + log(l)
-        lse_ref[0] = (m_ref[:] + jnp.log(l_safe))[:, 0]
+        # log-sum-exp residual for the backward pass: lse = m + log(l),
+        # written lane-dense as a [1, bq] row of the [BH, 1, Sq] output
+        # (a (1, bq) block of a 2-D [BH, Sq] array is not tile-aligned
+        # and the TPU lowering refuses it)
+        lse_ref[0, 0] = (m_ref[:] + jnp.log(l_safe))[:, 0]
 
 
 def _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret):
@@ -116,11 +119,11 @@ def _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret):
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q), lambda b, i, j: (b, i)),
+            pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * H, Sq, D), q.dtype),
-            jax.ShapeDtypeStruct((B * H, Sq), jnp.float32),
+            jax.ShapeDtypeStruct((B * H, 1, Sq), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, D), jnp.float32),   # acc
@@ -128,8 +131,10 @@ def _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret):
             pltpu.VMEM((block_q, 1), jnp.float32),   # l (running sum)
         ],
         interpret=interpret,
+        name="hvd_flash_attention",
     )(qf, kf, vf)
-    return out.reshape(B, H, Sq, D).transpose(0, 2, 1, 3), lse
+    return (out.reshape(B, H, Sq, D).transpose(0, 2, 1, 3),
+            lse.reshape(B * H, Sq))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))

@@ -99,6 +99,7 @@ def block_quantize(blocks: jax.Array, interpret: bool = False):
             jax.ShapeDtypeStruct((n, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="hvd_block_quantize",
     )(blocks)
     if pad:
         vals, scales = vals[:n_blocks], scales[:n_blocks]
@@ -154,6 +155,7 @@ def block_quantize_ef(blocks: jax.Array, interpret: bool = False):
             jax.ShapeDtypeStruct((n, block), jnp.float32),
         ],
         interpret=interpret,
+        name="hvd_block_quantize_ef",
     )(blocks)
     if pad:
         vals, scales, res = vals[:n_blocks], scales[:n_blocks], \
@@ -185,6 +187,7 @@ def block_dequantize(vals: jax.Array, scales: jax.Array,
         out_specs=pl.BlockSpec((ROWS, block), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, block), jnp.float32),
         interpret=interpret,
+        name="hvd_block_dequantize",
     )(vals, scales)
     return out[:n_blocks] if pad else out
 
@@ -282,6 +285,7 @@ def fused_sgd_apply(vals: jax.Array, scales: jax.Array, mom, lr, momentum,
             out_specs=tile(block),
             out_shape=jax.ShapeDtypeStruct((n, block), jnp.float32),
             interpret=interpret,
+            name="hvd_fused_sgd_apply",
         )(h, vals, scales)
         new_mom = None
     else:
@@ -294,6 +298,7 @@ def fused_sgd_apply(vals: jax.Array, scales: jax.Array, mom, lr, momentum,
             out_shape=[jax.ShapeDtypeStruct((n, block), jnp.float32),
                        jax.ShapeDtypeStruct((n, block), jnp.float32)],
             interpret=interpret,
+            name="hvd_fused_sgd_apply",
         )(h, vals, scales, mom)
         new_mom = new_mom[:n_blocks] if pad else new_mom
     return (delta[:n_blocks] if pad else delta), new_mom
@@ -327,6 +332,7 @@ def fused_adam_apply(vals: jax.Array, scales: jax.Array, m: jax.Array,
         out_specs=[tile(block), tile(block), tile(block)],
         out_shape=[jax.ShapeDtypeStruct((n, block), jnp.float32)] * 3,
         interpret=interpret,
+        name="hvd_fused_adam_apply",
     )(h, vals, scales, m, v)
     if pad:
         delta, nm, nv = delta[:n_blocks], nm[:n_blocks], nv[:n_blocks]

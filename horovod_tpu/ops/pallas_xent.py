@@ -102,6 +102,7 @@ def _xent_fwd_impl(logits, labels, block_n: int, block_v: int,
             pltpu.VMEM((block_n, 1), jnp.float32),   # z (label logit)
         ],
         interpret=interpret,
+        name="hvd_fused_xent",
     )(labels[:, None], logits)
     return loss[:, 0], lse[:, 0]
 

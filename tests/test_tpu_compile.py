@@ -1,0 +1,145 @@
+"""The main path's Pallas kernels, compiled for a described TPU v5e at
+real widths — no chip needed: the TPU compiler is installed and compiles
+for a topology that is described, not attached. Interpret mode cannot see
+what this sees (the flash kernel's log-sum-exp block spec passed every
+interpret test and was refused by the TPU lowering).
+
+Nothing runs here, so these say nothing about results or times; the
+parity tests are the interpret-mode files and ``chip_smoke.py``.
+
+Also here: the one compile-cache rule (``utils/compile_cache``) and the
+contract that ``chip_smoke.py`` fails without a chip.
+"""
+
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from horovod_tpu.ops import pallas_attention as pa
+from horovod_tpu.ops import pallas_quantize as pq
+from horovod_tpu.ops import pallas_xent as px
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """Sharding on one device of a described (not attached) v5e 2x2."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e topology: {e!r}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip (the next one warns), so the
+    cache is off around these."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _sum32(*xs):
+    return sum(x.astype(jnp.float32).sum() for x in xs)
+
+
+# attention at the smoke's flagship shape: B8 S2048 H8 D128 bf16
+_QKV = [((8, 2048, 8, 128), jnp.bfloat16)] * 3
+# LM loss rows x a real tokenizer's vocab (not a BLOCK_V multiple: the
+# wrapper pads it)
+_XENT = [((16384, 32000), jnp.bfloat16), ((16384,), jnp.int32)]
+_BLOCKS = ((8192, 256), jnp.float32)
+_CODES = [((8192, 256), jnp.int8), ((8192, 1), jnp.float32)]
+
+CASES = {
+    "flash_fwd": (
+        lambda q, k, v: pa.flash_attention_tpu(q, k, v, True),
+        _QKV, "hvd_flash_attention"),
+    "flash_fwd_grad": (
+        jax.grad(lambda q, k, v: _sum32(
+            pa.flash_attention_tpu(q, k, v, True)), (0, 1, 2)),
+        _QKV, "hvd_flash_attention"),
+    # the ring-attention step: non-causal, lse differentiated too
+    "flash_lse_noncausal_grad": (
+        jax.grad(lambda q, k, v: _sum32(*pa.flash_attention_with_lse(
+            q, k, v, causal=False)), (0, 1, 2)),
+        _QKV, "hvd_flash_attention"),
+    "xent_fwd": (px.fused_softmax_xent, _XENT, "hvd_fused_xent"),
+    "xent_grad": (
+        jax.grad(lambda l, y: px.fused_softmax_xent(l, y).sum()),
+        _XENT, "hvd_fused_xent"),
+    "quantize": (pq.block_quantize, [_BLOCKS], "hvd_block_quantize"),
+    "quantize_ef": (pq.block_quantize_ef, [_BLOCKS],
+                    "hvd_block_quantize_ef"),
+    "dequantize": (pq.block_dequantize, _CODES, "hvd_block_dequantize"),
+    "fused_sgd_apply": (
+        lambda c, s, m: pq.fused_sgd_apply(c, s, m, 0.1, 0.9),
+        _CODES + [_BLOCKS], "hvd_fused_sgd_apply"),
+    "fused_adam_apply": (
+        lambda c, s, m, v: pq.fused_adam_apply(
+            c, s, m, v, 1e-3, 0.9, 0.999, 1e-8, 0.1, 0.001),
+        _CODES + [_BLOCKS, _BLOCKS], "hvd_fused_adam_apply"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_kernel_compiles_for_v5e(case, v5e, no_compile_cache, monkeypatch):
+    fn, shapes, kernel = CASES[case]
+    # the dispatchers ask the default backend, which is the CPU here
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    args = [jax.ShapeDtypeStruct(s, d, sharding=v5e) for s, d in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert any(kernel in line for line in calls), (kernel, calls)
+
+
+@pytest.fixture
+def cache_dir_updates(monkeypatch):
+    """Record, without applying, what compile_cache.enable() would set."""
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: updates.append((k, v)))
+    return updates
+
+
+def test_compile_cache_env_set_sets_no_dir_in_code(cache_dir_updates,
+                                                   monkeypatch):
+    from horovod_tpu.utils import compile_cache
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/x")
+    assert compile_cache.enable() is None
+    assert cache_dir_updates == []
+
+
+def test_compile_cache_env_unset_is_checkout_local(cache_dir_updates,
+                                                   monkeypatch):
+    from horovod_tpu.utils import compile_cache
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    want = os.path.join(_REPO, ".jax_cache")
+    assert compile_cache.enable() == want
+    assert cache_dir_updates == [("jax_compilation_cache_dir", want)]
+
+
+def test_chip_smoke_fails_without_a_chip():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    proc = subprocess.run(
+        [sys.executable, os.path.join(_REPO, "chip_smoke.py")],
+        capture_output=True, text=True, timeout=120, env=env, cwd=_REPO)
+    assert proc.returncode != 0, proc.stdout
+    assert '"ok"' not in proc.stdout, proc.stdout
+    assert "tpu" in proc.stderr.lower(), proc.stderr
