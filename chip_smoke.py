@@ -1,0 +1,759 @@
+"""The quickest proof that the trainer still starts on the chip.
+
+    python chip_smoke.py              # one TPU chip (what the driver runs)
+    python chip_smoke.py --chips 4    # one host's four chips, that path only
+    python chip_smoke.py --rehearse   # off-chip walk of every phase, tiny
+
+One process, the only one that touches JAX (a chip belongs to one process).
+Each phase prints its own JSON lines; the last stdout line is
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}
+
+and is printed only after every phase passed on a TPU. Without a TPU the
+script says why on stderr and exits 2 without training and without that
+line. ``--rehearse`` is the off-chip rehearsal: the same phases at tiny
+sizes with interpret-mode kernels; every line it prints says
+``"rehearsal": true``, it ends ``"ok": false`` and exits 3. A failed check
+raises :class:`SmokeFailure`; nothing on the path catches it.
+
+Phases (default, one chip):
+  device   jax.devices(); platform must be tpu.
+  train    BERT-Large at published widths (24 layers, hidden 1024, 16 heads,
+           FFN 4096, vocab 30522, bf16), batch 64 x seq 128, optax.adamw,
+           through hvd.init / hvd.build_mesh / init_bert /
+           make_bert_train_step: one compiling step + 30 steps on a fixed
+           batch; every loss finite, the last below the first, no compile
+           after the first step. (adamw at 1e-4 with no warmup overshoots
+           for its first ~5 steps on this model, on the CPU in float32 as
+           on the chip; after that the loss falls steadily, so the run is
+           long enough to see past the transient.)
+  kernels  every Pallas kernel of the main path, compiled (tpu_custom_call),
+           against its XLA reference at real widths; then two steps of the
+           flagship transformer at head_dim 128 with both kernels asserted
+           in the compiled program.
+
+``--chips 4`` runs only the four-chip phase and what it is compared with:
+BERT-Large dp=4 against one device, the two n=4 layouts of
+``__graft_entry__`` against one device / a 4-virtual-device CPU mesh, and
+ring attention (flash kernel per step) over sp=4 against plain attention.
+
+The printed seconds and bytes are smoke prints, not benchmark metrics.
+
+Tolerances (all stated here, none tuned per run):
+  flash attention   max|got-ref| / max|ref| <= 2e-2 (bf16 outputs and
+                    grads; the reference runs at "highest" precision)
+  fused xent        loss (f32) abs <= 2e-3 on values ~ log(vocab);
+                    dlogits (bf16) normalized <= 1e-2
+  int8 codec        scales rtol 1e-6; codes within +-1 (a division that
+                    lands on a rounding boundary), <= 0.1% of them off;
+                    residual equal to x - codes*scale of the kernel's own
+                    codes; dequantize / fused sgd / fused adam rtol 1e-4
+  losses across meshes  BERT-Large bf16, dp=4 vs one device: step 1 (same
+                    params, forward only) rel 2e-3; steps 2-3 rel 5e-2 (adam's
+                    first updates are sign-like, so bf16 noise decides the
+                    direction of near-zero gradient coordinates);
+                    flagship f32 layouts: abs 2e-2 on a loss ~ 5.5 (TPU f32
+                    matmuls run as bf16 passes at default precision).
+"""
+
+from __future__ import annotations
+
+import argparse
+import dataclasses
+import json
+import os
+import statistics
+import sys
+import time
+
+FLASH_TOL = 2e-2
+XENT_LOSS_ATOL = 2e-3
+XENT_GRAD_TOL = 1e-2
+CODEC_RTOL = 1e-4
+CODE_MISMATCH_MAX = 1e-3
+BERT_MESH_RTOL_FIRST = 2e-3
+BERT_MESH_RTOL_LATER = 5e-2
+TRAIN_STEPS = 30
+LAYOUT_ATOL = 2e-2
+
+
+class SmokeFailure(Exception):
+    """A phase's check did not hold."""
+
+
+def check(cond, msg: str) -> None:
+    if not cond:
+        raise SmokeFailure(msg)
+
+
+@dataclasses.dataclass(frozen=True)
+class Sizes:
+    """What a phase runs at: the real widths, or the rehearsal's."""
+    bert: dict            # BertConfig overrides ({} = bert_large())
+    bert_batch: int
+    bert_seq: int
+    attn: tuple           # flash check q/k/v [B, S, H, D]
+    xent: tuple           # fused xent check [rows, vocab]
+    blocks: tuple         # codec check [n_blocks, block]
+    gpt: dict             # flagship TransformerConfig fields
+    gpt_batch: int
+    ring: tuple           # four-chip ring attention [B, S, H, D]
+
+
+REAL = Sizes(
+    bert={}, bert_batch=64, bert_seq=128,
+    attn=(8, 2048, 8, 128), xent=(16384, 32000), blocks=(8192, 256),
+    # depth cut to 4 layers: this phase checks kernels in place, not a model
+    gpt=dict(vocab_size=32000, d_model=1024, n_heads=8, n_layers=4,
+             d_ff=4096, max_seq=2048),
+    gpt_batch=8, ring=(2, 2048, 4, 128))
+TINY = Sizes(
+    bert=dict(vocab_size=512, hidden_size=64, num_layers=2, num_heads=4,
+              intermediate_size=128, max_position=64),
+    bert_batch=8, bert_seq=16,
+    attn=(1, 256, 2, 128), xent=(256, 1000), blocks=(64, 128),
+    gpt=dict(vocab_size=1000, d_model=256, n_heads=2, n_layers=2,
+             d_ff=256, max_seq=256),
+    gpt_batch=2, ring=(1, 512, 2, 128))
+
+
+class Smoke:
+    """One run: the parsed options, the sizes, and the line printer."""
+
+    def __init__(self, args):
+        self.chips = args.chips
+        self.seed = args.seed
+        self.rehearsal = args.rehearse
+        self.sizes = TINY if args.rehearse else REAL
+
+    @property
+    def on_chip(self) -> bool:
+        return not self.rehearsal
+
+    def emit(self, phase: str, **fields) -> None:
+        doc = {"phase": phase, **fields}
+        if self.rehearsal:
+            doc["rehearsal"] = True
+        print(json.dumps(doc), flush=True)
+
+
+# ---------------------------------------------------------------------------
+# device
+# ---------------------------------------------------------------------------
+
+def phase_device(smoke: Smoke) -> dict:
+    import jax
+    devs = jax.devices()
+    d = devs[0]
+    device = {"platform": d.platform, "kind": d.device_kind,
+              "count": len(devs)}
+    if smoke.rehearsal:
+        check(d.platform != "tpu", "--rehearse is the off-chip rehearsal; "
+              "run without it on a machine that has the chip")
+    elif d.platform != "tpu":
+        print(f"chip_smoke: JAX found no TPU (devices: {devs}); this script "
+              "never trains on the CPU. Run it on a machine with the chip, "
+              "or rehearse off-chip with --rehearse.", file=sys.stderr)
+        sys.exit(2)
+    check(len(devs) == smoke.chips,
+          f"expected {smoke.chips} device(s), JAX reports {len(devs)}")
+    smoke.emit("device", **device,
+               compile_cache=jax.config.jax_compilation_cache_dir)
+    return device
+
+
+# ---------------------------------------------------------------------------
+# train: BERT-Large through the normal entry points
+# ---------------------------------------------------------------------------
+
+def _bert_setup(hvd, mesh, smoke: Smoke):
+    """The construction of bench.py's bert child and
+    examples/jax/bert_pretrain_synthetic.py --large, scan_steps=1."""
+    import numpy as np
+    import jax
+    import jax.numpy as jnp
+    import optax
+    from horovod_tpu.models import init_opt_state
+    from horovod_tpu.models.bert import (Bert, bert_large, init_bert,
+                                         make_bert_train_step)
+
+    z = smoke.sizes
+    cfg = dataclasses.replace(bert_large(), **z.bert)
+    model = Bert(cfg)
+    B, S = z.bert_batch, z.bert_seq
+    params = init_bert(model, jax.random.PRNGKey(smoke.seed), S, mesh)
+    tx = optax.adamw(1e-4)
+    opt_state = init_opt_state(tx, params, mesh)
+    step = make_bert_train_step(model, tx, mesh, scan_steps=1)
+
+    rng = np.random.RandomState(smoke.seed)
+    sh = hvd.batch_sharding(mesh)
+
+    def put(x, dtype):
+        return jax.device_put(jnp.asarray(x, dtype), sh)
+
+    batch = {
+        "input_ids": put(rng.randint(0, cfg.vocab_size, (B, S)), jnp.int32),
+        "token_type_ids": put(np.zeros((B, S)), jnp.int32),
+        "attention_mask": put(np.ones((B, S)), bool),
+        "mlm_labels": put(rng.randint(0, cfg.vocab_size, (B, S)), jnp.int32),
+        "mlm_mask": put(rng.rand(B, S) < 0.15, jnp.float32),
+        "nsp_labels": put(rng.randint(0, 2, (B,)), jnp.int32),
+    }
+    n_params = sum(x.size for x in jax.tree_util.tree_leaves(params))
+    return cfg, step, params, opt_state, batch, n_params
+
+
+def _compile_counters(hvd) -> tuple:
+    reg = hvd.metrics_snapshot()["registry"]
+
+    def value(name):
+        return int(reg[name]["value"]) if name in reg else 0
+    return (value("hvd_compile_total"),
+            value("hvd_compile_cache_miss_total"))
+
+
+def _persistent_cache_events() -> dict:
+    """Live counts of JAX's persistent-compilation-cache hits and misses
+    from here on (the backend-compile event fires for both)."""
+    import jax.monitoring
+    counts = {"hits": 0, "misses": 0}
+
+    def listener(event, **_):
+        if event == "/jax/compilation_cache/cache_hits":
+            counts["hits"] += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            counts["misses"] += 1
+    jax.monitoring.register_event_listener(listener)
+    return counts
+
+
+def _memory(device) -> dict:
+    """What the runtime reports for live arrays on the device (None off
+    the chip). A program's temporaries are not in it: see _step_bytes."""
+    stats = device.memory_stats() or {}
+    return {k: stats.get(k) for k in
+            ("bytes_in_use", "peak_bytes_in_use", "bytes_limit")}
+
+
+def _step_bytes(mem) -> dict:
+    """Per-device bytes of one compiled step, from memory_analysis():
+    arguments + outputs - aliased (donated) + temporaries."""
+    doc = {k: getattr(mem, f"{k}_size_in_bytes")
+           for k in ("argument", "output", "alias", "temp")}
+    doc["total"] = (doc["argument"] + doc["output"] - doc["alias"]
+                    + doc["temp"])
+    return doc
+
+
+def phase_train(smoke: Smoke, hvd) -> None:
+    import math
+    import jax
+    from horovod_tpu.profiling import compile_watch
+
+    mesh = hvd.build_mesh(dp=-1)
+    cfg, step, params, opt_state, batch, n_params = _bert_setup(
+        hvd, mesh, smoke)
+    z = smoke.sizes
+    # after the set-up: init_bert runs op by op, and each op compiles
+    check(compile_watch.ensure_installed(), "compile metrics are disabled")
+
+    cache = _persistent_cache_events()
+    backend_s0 = compile_watch.totals()["seconds_total"]
+    t0 = time.perf_counter()
+    params, opt_state, loss = step(params, opt_state, batch)
+    losses = [float(loss)]
+    first_step_s = time.perf_counter() - t0
+    # the backend-compile event also times a read from the persistent cache
+    compile_s = compile_watch.totals()["seconds_total"] - backend_s0
+    cache_hit = cache["hits"] > 0 and cache["misses"] == 0
+    compiles_after_first = _compile_counters(hvd)
+
+    step_s, dispatch_s, readback_s = [], [], []
+    for _ in range(TRAIN_STEPS):
+        t0 = time.perf_counter()
+        params, opt_state, loss = step(params, opt_state, batch)
+        t_dispatch = time.perf_counter()
+        jax.block_until_ready(loss)
+        t_ready = time.perf_counter()
+        losses.append(float(loss))
+        t_read = time.perf_counter()
+        dispatch_s.append(t_dispatch - t0)
+        step_s.append(t_ready - t0)
+        readback_s.append(t_read - t_ready)
+    compiles_at_end = _compile_counters(hvd)
+
+    check(all(math.isfinite(x) for x in losses), f"non-finite loss: {losses}")
+    check(losses[-1] < losses[0],
+          f"loss did not fall on a fixed batch: {losses}")
+    check(compiles_at_end == compiles_after_first,
+          "compiled again after the first step: (backend compiles, tracing "
+          f"misses) {compiles_after_first} -> {compiles_at_end}")
+    # if block_until_ready returned before the step ended, the readback
+    # that follows it would carry the step's time
+    med_step, med_read = (statistics.median(step_s),
+                          statistics.median(readback_s))
+    ready_waits = med_read < 0.05 * med_step
+    if smoke.on_chip:
+        check(ready_waits, "jax.block_until_ready(loss) returned before the "
+              f"step ended: float(loss) then took {med_read:.4f}s of a "
+              f"{med_step:.4f}s step")
+
+    # after the compile check: lowering again traces again. The step's
+    # own temporaries are in this analysis, not in memory_stats()
+    mem = step.lower(params, opt_state, batch).compile().memory_analysis()
+    d = jax.devices()[0]
+    smoke.emit(
+        "train", model="bert_large" if not z.bert else "bert_tiny",
+        layers=cfg.num_layers, hidden=cfg.hidden_size, heads=cfg.num_heads,
+        ffn=cfg.intermediate_size, vocab=cfg.vocab_size,
+        dtype=str(cfg.dtype.__name__), n_params=n_params,
+        batch=z.bert_batch, seq=z.bert_seq, optimizer="optax.adamw(1e-4)",
+        attention_path="xla (models/bert.py SelfAttention; no kernel)",
+        device_kind=d.device_kind,
+        first_step_seconds=round(first_step_s, 3),
+        compile_or_cache_read_seconds=round(compile_s, 3),
+        step_program="read from the persistent cache (warm)" if cache_hit
+        else "compiled (cold)",
+        losses=[round(x, 4) for x in losses],
+        compiles_after_first_step=0,
+        median_step_seconds=med_step,
+        median_dispatch_seconds=statistics.median(dispatch_s),
+        median_float_after_ready_seconds=med_read,
+        block_until_ready_waits_for_the_step=ready_waits,
+        compiled_step_bytes=_step_bytes(mem), hbm_live_arrays=_memory(d))
+
+
+# ---------------------------------------------------------------------------
+# kernels: compiled Pallas against the XLA reference of the same file
+# ---------------------------------------------------------------------------
+
+def _custom_calls(text: str) -> list:
+    return [line for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line]
+
+
+def _run_compiled(smoke: Smoke, fn, args, kernel: str):
+    """Compile ``fn`` for the attached device, require the named kernel as
+    a tpu_custom_call in the program (on the chip), and run that program."""
+    import jax
+    compiled = jax.jit(fn).lower(*args).compile()
+    if smoke.on_chip:
+        calls = _custom_calls(compiled.as_text())
+        check(any(kernel in c for c in calls),
+              f"{kernel}: no such tpu_custom_call in the compiled program "
+              f"({len(calls)} custom calls)")
+    return compiled(*args)
+
+
+def _rel_err(got, want) -> float:
+    """max|got - want| / max|want|, computed on the device."""
+    import jax.numpy as jnp
+    g, w = got.astype(jnp.float32), want.astype(jnp.float32)
+    check(bool(jnp.all(jnp.isfinite(g))), "non-finite kernel output")
+    return float(jnp.max(jnp.abs(g - w)) / jnp.maximum(
+        jnp.max(jnp.abs(w)), 1e-30))
+
+
+def _kernel_line(smoke: Smoke, kernel: str, what: str, err: float,
+                 tol: float, **more) -> None:
+    smoke.emit("kernels", kernel=kernel, what=what, err=err, tol=tol,
+               ran="tpu_custom_call" if smoke.on_chip else "interpret",
+               **more)
+    check(err <= tol, f"{kernel} {what}: err {err} > tol {tol}")
+
+
+def _check_flash(smoke: Smoke) -> None:
+    import jax
+    import jax.numpy as jnp
+    from horovod_tpu.ops.pallas_attention import flash_attention_tpu
+    from horovod_tpu.parallel.ring_attention import _plain_attention
+
+    interpret = smoke.rehearsal
+    shape = smoke.sizes.attn
+    keys = jax.random.split(jax.random.PRNGKey(smoke.seed), 4)
+    q, k, v = (jax.random.normal(kk, shape, jnp.bfloat16) for kk in keys[:3])
+    w = jax.random.normal(keys[3], shape, jnp.float32)  # cotangent
+
+    def kernel(q, k, v):
+        return flash_attention_tpu(q, k, v, True, interpret=interpret)
+
+    def reference(q, k, v):
+        with jax.default_matmul_precision("highest"):
+            return _plain_attention(q, k, v, True)
+
+    def loss_of(attn):
+        return lambda q, k, v: jnp.sum(attn(q, k, v).astype(jnp.float32) * w)
+
+    got = _run_compiled(smoke, kernel, (q, k, v), "hvd_flash_attention")
+    want = jax.jit(reference)(q, k, v)
+    _kernel_line(smoke, "flash_attention", "fwd", _rel_err(got, want),
+                 FLASH_TOL, shape=shape, dtype="bfloat16")
+    got = _run_compiled(smoke, jax.grad(loss_of(kernel), (0, 1, 2)),
+                        (q, k, v), "hvd_flash_attention")
+    want = jax.jit(jax.grad(loss_of(reference), (0, 1, 2)))(q, k, v)
+    for name, g, r in zip(("dq", "dk", "dv"), got, want):
+        _kernel_line(smoke, "flash_attention", f"grad {name}",
+                     _rel_err(g, r), FLASH_TOL)
+
+
+def _check_xent(smoke: Smoke) -> None:
+    import jax
+    import jax.numpy as jnp
+    from horovod_tpu.ops.pallas_xent import _xla_xent, fused_softmax_xent
+
+    interpret = smoke.rehearsal
+    n, vocab = smoke.sizes.xent
+    k1, k2 = jax.random.split(jax.random.PRNGKey(smoke.seed + 1))
+    logits = (4.0 * jax.random.normal(k1, (n, vocab), jnp.float32)
+              ).astype(jnp.bfloat16)
+    labels = jax.random.randint(k2, (n,), 0, vocab, jnp.int32)
+
+    def kernel(lg, y):   # the public wrapper: pads 32000 to a block multiple
+        return fused_softmax_xent(lg, y, interpret=interpret)
+
+    got = _run_compiled(smoke, kernel, (logits, labels), "hvd_fused_xent")
+    want = jax.jit(_xla_xent)(logits, labels)
+    err = float(jnp.max(jnp.abs(got - want)))
+    _kernel_line(smoke, "fused_xent", "fwd (abs)", err, XENT_LOSS_ATOL,
+                 shape=(n, vocab), dtype="bfloat16")
+    got = _run_compiled(
+        smoke, jax.grad(lambda lg, y: kernel(lg, y).sum()),
+        (logits, labels), "hvd_fused_xent")
+    want = jax.jit(jax.grad(lambda lg, y: _xla_xent(lg, y).sum()))(
+        logits, labels)
+    _kernel_line(smoke, "fused_xent", "grad dlogits", _rel_err(got, want),
+                 XENT_GRAD_TOL)
+
+
+def _check_codec(smoke: Smoke) -> None:
+    import jax
+    import jax.numpy as jnp
+    from horovod_tpu.ops import pallas_quantize as pq
+
+    interpret = smoke.rehearsal
+    shape = smoke.sizes.blocks
+    keys = jax.random.split(jax.random.PRNGKey(smoke.seed + 2), 3)
+    x = jax.random.normal(keys[0], shape, jnp.float32)
+    mom = jax.random.normal(keys[1], shape, jnp.float32)
+    nu = jnp.square(jax.random.normal(keys[2], shape, jnp.float32))
+
+    def codes_line(kernel, codes, scales, ref_codes, ref_scales, **more):
+        diff = jnp.abs(codes.astype(jnp.int32) - ref_codes.astype(jnp.int32))
+        check(int(jnp.max(diff)) <= 1,
+              f"{kernel}: a code is off by {int(jnp.max(diff))}")
+        off = float(jnp.mean((diff > 0).astype(jnp.float32)))
+        _kernel_line(smoke, kernel, "codes off by one (fraction)", off,
+                     CODE_MISMATCH_MAX, shape=shape, **more)
+        _kernel_line(smoke, kernel, "scales", _rel_err(scales, ref_scales),
+                     1e-6)
+
+    ref_codes, ref_scales = jax.jit(pq._xla_quantize)(x)
+    codes, scales = _run_compiled(
+        smoke, lambda b: pq.block_quantize(b, interpret=interpret), (x,),
+        "hvd_block_quantize")
+    codes_line("block_quantize", codes, scales, ref_codes, ref_scales)
+
+    codes_ef, scales_ef, res = _run_compiled(
+        smoke, lambda b: pq.block_quantize_ef(b, interpret=interpret), (x,),
+        "hvd_block_quantize_ef")
+    codes_line("block_quantize_ef", codes_ef, scales_ef, ref_codes,
+               ref_scales)
+    _kernel_line(smoke, "block_quantize_ef", "residual",
+                 _rel_err(res, x - pq._xla_dequantize(codes_ef, scales_ef)),
+                 CODEC_RTOL)
+
+    got = _run_compiled(
+        smoke, lambda c, s: pq.block_dequantize(c, s, interpret=interpret),
+        (codes, scales), "hvd_block_dequantize")
+    _kernel_line(smoke, "block_dequantize", "values",
+                 _rel_err(got, pq._xla_dequantize(codes, scales)), CODEC_RTOL)
+
+    h = jnp.asarray([0.1, 0.9], jnp.float32)
+    got = _run_compiled(
+        smoke, lambda c, s, m: pq.fused_sgd_apply(
+            c, s, m, h[0], h[1], interpret=interpret),
+        (codes, scales, mom), "hvd_fused_sgd_apply")
+    want = jax.jit(pq._xla_fused_sgd)(h, codes, scales, mom)
+    for name, g, r in zip(("delta", "momentum"), got, want):
+        _kernel_line(smoke, "fused_sgd_apply", name, _rel_err(g, r),
+                     CODEC_RTOL)
+
+    h = jnp.asarray([1e-3, 0.9, 0.999, 1e-8, 0.1, 0.001], jnp.float32)
+    got = _run_compiled(
+        smoke, lambda c, s, m, v: pq.fused_adam_apply(
+            c, s, m, v, *h, interpret=interpret),
+        (codes, scales, mom, nu), "hvd_fused_adam_apply")
+    want = jax.jit(pq._xla_fused_adam)(h, codes, scales, mom, nu)
+    for name, g, r in zip(("delta", "m", "v"), got, want):
+        _kernel_line(smoke, "fused_adam_apply", name, _rel_err(g, r),
+                     CODEC_RTOL)
+
+
+def _attention_path(shape) -> str:
+    """Which implementation ``attend`` picks for q/k/v of ``shape`` on the
+    default backend, read from the lowered program."""
+    import jax
+    import jax.numpy as jnp
+    from horovod_tpu.ops.pallas_attention import attend
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    text = jax.jit(attend).lower(x, x, x).as_text()
+    return "pallas hvd_flash_attention" if "tpu_custom_call" in text \
+        else "xla _plain_attention"
+
+
+def _check_flagship(smoke: Smoke, hvd) -> None:
+    """Two steps of the flagship transformer through make_train_step at
+    head_dim 128, with the attention and cross-entropy kernels asserted in
+    the compiled program."""
+    import math
+    import numpy as np
+    import jax
+    import optax
+    from horovod_tpu.models.transformer import (
+        TransformerConfig, init_opt_state, init_params, make_train_step,
+        shard_batch, shard_params)
+
+    z = smoke.sizes
+    cfg = TransformerConfig(**z.gpt)
+    mesh = hvd.build_mesh(dp=-1)
+    params = shard_params(
+        init_params(np.random.RandomState(smoke.seed), cfg, 1), cfg, mesh)
+    tx = optax.adamw(1e-4)
+    opt_state = init_opt_state(tx, params, mesh, cfg)
+    rng = np.random.RandomState(smoke.seed + 1)
+    B, S = z.gpt_batch, cfg.max_seq
+    tokens = rng.randint(0, cfg.vocab_size, (B, S)).astype(np.int32)
+    tokens, targets = shard_batch(tokens, np.roll(tokens, -1, 1), mesh)
+
+    step = make_train_step(cfg, mesh, tx)
+    t0 = time.perf_counter()
+    compiled = step.lower(params, opt_state, tokens, targets).compile()
+    compile_s = time.perf_counter() - t0
+    calls = _custom_calls(compiled.as_text())
+    in_program = {k: any(k in c for c in calls)
+                  for k in ("hvd_flash_attention", "hvd_fused_xent")}
+    if smoke.on_chip:
+        check(all(in_program.values()),
+              f"kernels missing from the flagship step: {in_program}")
+    losses = []
+    for _ in range(2):
+        params, opt_state, loss, _aux = compiled(params, opt_state, tokens,
+                                                 targets)
+        losses.append(float(loss))
+    check(all(math.isfinite(x) for x in losses), f"non-finite: {losses}")
+
+    heads16 = (B, S, 2 * cfg.n_heads, cfg.head_dim // 2)
+    paths = {"heads_%d_head_dim_%d" % (cfg.n_heads, cfg.head_dim):
+             _attention_path((B, S, cfg.n_heads, cfg.head_dim)),
+             # bench.py's gpt default: d_model 1024 / 16 heads
+             "heads_%d_head_dim_%d" % heads16[2:]: _attention_path(heads16)}
+    if smoke.on_chip:
+        check(paths["heads_8_head_dim_128"].startswith("pallas"), str(paths))
+        check(paths["heads_16_head_dim_64"].startswith("xla"), str(paths))
+    smoke.emit("kernels", model="flagship transformer", **z.gpt,
+               batch=B, compile_seconds=round(compile_s, 3),
+               kernels_in_compiled_step=in_program if smoke.on_chip
+               else "not checked (rehearsal: the CPU takes the XLA paths)",
+               losses=[round(x, 4) for x in losses],
+               attention_path=paths,
+               compiled_step_bytes=_step_bytes(compiled.memory_analysis()),
+               hbm_live_arrays=_memory(jax.devices()[0]))
+
+
+def phase_kernels(smoke: Smoke, hvd) -> None:
+    _check_flash(smoke)
+    _check_xent(smoke)
+    _check_codec(smoke)
+    _check_flagship(smoke, hvd)
+
+
+# ---------------------------------------------------------------------------
+# four chips: what exists only across chips, and what it is compared with
+# ---------------------------------------------------------------------------
+
+def _bert_three_steps(smoke: Smoke, hvd, mesh) -> tuple:
+    """(losses, compiled text, facts about placement) of 3 BERT steps."""
+    import jax
+    cfg, step, params, opt_state, batch, _ = _bert_setup(hvd, mesh, smoke)
+    compiled = step.lower(params, opt_state, batch).compile()
+    n_mesh = mesh.devices.size
+    leaves = jax.tree_util.tree_leaves(params)
+    facts = {
+        "batch_shard_devices": sorted(
+            s.device.id for s in batch["input_ids"].addressable_shards),
+        "params_replicated_on": min(
+            len(x.sharding.device_set) if x.sharding.is_fully_replicated
+            else 0 for x in leaves),
+        "mesh_devices": n_mesh,
+    }
+    losses = []
+    for _ in range(3):
+        params, opt_state, loss = compiled(params, opt_state, batch)
+        losses.append(float(loss))
+    facts["hbm_live_arrays_per_device"] = {
+        str(d.id): _memory(d) for d in mesh.devices.flat}
+    facts["compiled_step_bytes_per_device"] = _step_bytes(
+        compiled.memory_analysis())
+    return losses, compiled.as_text(), facts
+
+
+def _four_bert(smoke: Smoke, hvd) -> None:
+    import math
+    import jax
+    mesh4 = hvd.build_mesh(dp=-1)
+    losses4, text4, facts4 = _bert_three_steps(smoke, hvd, mesh4)
+    check(len(set(facts4["batch_shard_devices"])) == 4,
+          f"batch is not on 4 distinct devices: {facts4}")
+    check(facts4["params_replicated_on"] == 4,
+          f"params are not replicated on all 4 devices: {facts4}")
+    check("all-reduce" in text4, "no all-reduce in the dp=4 compiled step")
+    smoke.emit("four_chips", what="bert dp=4", losses=losses4,
+               all_reduce_in_step=True, **facts4)
+
+    mesh1 = hvd.build_mesh(dp=-1, devices=jax.devices()[:1])
+    losses1, _text, facts1 = _bert_three_steps(smoke, hvd, mesh1)
+    rel = [abs(a - b) / abs(b) for a, b in zip(losses4, losses1)]
+    smoke.emit("four_chips", what="bert one device (same seed and batch)",
+               losses=losses1, rel_diff_vs_dp4=rel,
+               tol_first_step=BERT_MESH_RTOL_FIRST,
+               tol_later_steps=BERT_MESH_RTOL_LATER, **facts1)
+    check(all(math.isfinite(x) for x in losses4 + losses1), "non-finite")
+    check(rel[0] <= BERT_MESH_RTOL_FIRST
+          and max(rel[1:]) <= BERT_MESH_RTOL_LATER,
+          f"dp=4 and one-device losses differ by {rel}: "
+          f"{losses4} vs {losses1}")
+
+
+def _four_layouts(smoke: Smoke) -> None:
+    """The two n=4 layouts of __graft_entry__._dryrun_child, on the real
+    devices, first-step loss against one device."""
+    import jax
+    import __graft_entry__ as graft
+
+    cfg = graft._flagship_cfg(tiny=True)
+    moe_cfg = dataclasses.replace(cfg, n_experts=4, n_microbatches=1)
+    B, S = 8, 32
+    one = dict(dp=1, devices=jax.devices()[:1])
+
+    dense4 = graft._run_layout(cfg, dict(dp=2, tp=2), 1, B, S)
+    dense1 = graft._run_layout(cfg, one, 1, B, S)
+    smoke.emit("four_chips", what="flagship dense dp=2 tp=2 vs one device",
+               loss_4=dense4, loss_1=dense1, diff=abs(dense4 - dense1),
+               tol=LAYOUT_ATOL)
+    check(abs(dense4 - dense1) <= LAYOUT_ATOL, "dense layout loss differs")
+
+    # Expert capacity is per token group, and ep x sp cuts the batch into
+    # four groups, so this layout drops different tokens than one device
+    # does: by design its loss is not the one-device loss. It is compared
+    # with the same layout on a 4-virtual-device CPU mesh; the one-device
+    # loss is printed beside it.
+    moe4 = graft._run_layout(moe_cfg, dict(ep=2, sp=2), 1, B, S)
+    moe_cpu = graft._run_layout(
+        moe_cfg, dict(ep=2, sp=2, devices=jax.devices("cpu")[:4]), 1, B, S)
+    moe1 = graft._run_layout(moe_cfg, one, 1, B, S)
+    smoke.emit("four_chips",
+               what="flagship MoE ep=2 sp=2 vs the same layout on a "
+                    "4-virtual-device CPU mesh (capacity per group makes "
+                    "one device differ by design)",
+               loss_4=moe4, loss_cpu_mesh=moe_cpu, loss_1=moe1,
+               diff=abs(moe4 - moe_cpu), diff_vs_one_device=abs(moe4 - moe1),
+               tol=LAYOUT_ATOL)
+    check(abs(moe4 - moe_cpu) <= LAYOUT_ATOL, "MoE layout loss differs")
+
+
+def _four_ring(smoke: Smoke, hvd) -> None:
+    """Ring attention over sp=4 with the flash kernel as each step's block
+    attention (its (o, lse) pair merged by logaddexp), against plain
+    attention on the whole sequence."""
+    import jax
+    import jax.numpy as jnp
+    from horovod_tpu.parallel.ring_attention import (_plain_attention,
+                                                     ring_attention)
+
+    mesh = hvd.build_mesh(dp=1, sp=4)
+    shape = smoke.sizes.ring
+    keys = jax.random.split(jax.random.PRNGKey(smoke.seed + 3), 4)
+    q, k, v = (jax.random.normal(kk, shape, jnp.bfloat16) for kk in keys[:3])
+    w = jax.random.normal(keys[3], shape, jnp.float32)
+
+    def ring(q, k, v):
+        return ring_attention(q, k, v, mesh, "sp", causal=True,
+                              use_flash=True, interpret=smoke.rehearsal)
+
+    def reference(q, k, v):
+        with jax.default_matmul_precision("highest"):
+            return _plain_attention(q, k, v, True)
+
+    def loss_of(attn):
+        return lambda q, k, v: jnp.sum(attn(q, k, v).astype(jnp.float32) * w)
+
+    compiled = jax.jit(jax.value_and_grad(loss_of(ring), (0, 1, 2))).lower(
+        q, k, v).compile()
+    text = compiled.as_text()
+    if smoke.on_chip:
+        check(any("hvd_flash_attention" in c for c in _custom_calls(text)),
+              "no flash kernel in the ring attention program")
+        check("collective-permute" in text, "no ring permute in the program")
+    _, got = compiled(q, k, v)
+    want = jax.jit(jax.grad(loss_of(reference), (0, 1, 2)))(q, k, v)
+    for name, g, r in zip(("dq", "dk", "dv"), got, want):
+        err = _rel_err(g, r)
+        smoke.emit("four_chips", what=f"ring attention sp=4 grad {name}",
+                   shape=shape, err=err, tol=FLASH_TOL)
+        check(err <= FLASH_TOL, f"ring attention {name}: {err}")
+
+
+def phase_four_chips(smoke: Smoke, hvd) -> None:
+    _four_bert(smoke, hvd)
+    _four_layouts(smoke)
+    _four_ring(smoke, hvd)
+
+
+# ---------------------------------------------------------------------------
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
+                    help="4: only the four-chip phase and its comparison")
+    ap.add_argument("--rehearse", action="store_true",
+                    help="off-chip walk of the phases at tiny sizes; "
+                         "ends ok: false, exit 3")
+    ap.add_argument("--seed", type=int, default=0)
+    smoke = Smoke(ap.parse_args(argv))
+
+    if smoke.chips == 4:
+        # the MoE layout's reference mesh (and the rehearsal) needs four
+        # host devices; must be set before JAX starts a backend
+        os.environ["XLA_FLAGS"] = (
+            os.environ.get("XLA_FLAGS", "")
+            + " --xla_force_host_platform_device_count=4").strip()
+
+    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+    import horovod_tpu as hvd
+    from horovod_tpu.utils import compile_cache
+
+    if smoke.on_chip:   # the rehearsal's CPU programs are not worth keeping
+        compile_cache.enable()
+    device = phase_device(smoke)
+    hvd.init()
+    try:
+        if smoke.chips == 4:
+            phase_four_chips(smoke, hvd)
+        else:
+            phase_train(smoke, hvd)
+            phase_kernels(smoke, hvd)
+    finally:
+        hvd.shutdown()
+    if smoke.rehearsal:
+        print(json.dumps({"ok": False, "rehearsal": True,
+                          "note": "every phase walked off-chip; nothing "
+                                  "here ran on a TPU", "device": device}),
+              flush=True)
+        return 3
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import optax
 
 import horovod_tpu as hvd
+from horovod_tpu.models import init_opt_state
 from horovod_tpu.models.bert import (Bert, BertConfig, bert_large, init_bert,
                                      make_bert_train_step)
 
@@ -51,7 +52,7 @@ def main():
     model = Bert(cfg)
     params = init_bert(model, jax.random.PRNGKey(0), args.seq_len, mesh)
     tx = optax.adamw(1e-4)
-    opt_state = jax.jit(tx.init)(params)
+    opt_state = init_opt_state(tx, params, mesh)
     step = make_bert_train_step(model, tx, mesh)
 
     rng = np.random.RandomState(0)

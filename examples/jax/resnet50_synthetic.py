@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import optax
 
 import horovod_tpu as hvd
+from horovod_tpu.models import init_opt_state
 from horovod_tpu.models.resnet import (ResNet50, batch_sharding,
                                        create_resnet_state,
                                        make_resnet_train_step)
@@ -38,7 +39,7 @@ def main():
     params, stats = create_resnet_state(model, jax.random.PRNGKey(0),
                                         mesh=mesh)
     tx = optax.sgd(0.1, momentum=0.9)
-    opt_state = jax.jit(tx.init)(params)
+    opt_state = init_opt_state(tx, params, mesh)
     step = make_resnet_train_step(model, tx, mesh)
 
     rng = np.random.RandomState(0)

@@ -173,8 +173,10 @@ def init_bert(model: Bert, rng_key, seq_len: int = 128, mesh: Mesh = None):
     """Initialize; apply flax logical partitioning onto the mesh's tp axis
     (replicated when tp is absent)."""
     dummy = jnp.zeros((1, seq_len), jnp.int32)
-    variables = model.init(rng_key, dummy, dummy,
-                           jnp.ones((1, seq_len), bool))
+    # one compiled program: op by op, 24 layers of initializers are a
+    # few hundred small compiles on the chip
+    variables = jax.jit(model.init)(rng_key, dummy, dummy,
+                                    jnp.ones((1, seq_len), bool))
     params = variables["params"]
     if mesh is not None:
         import flax

@@ -465,11 +465,22 @@ def make_forward(cfg: TransformerConfig, mesh: Mesh):
     return jax.jit(fwd)
 
 
-def init_opt_state(optimizer, params, mesh: Mesh, cfg: TransformerConfig):
-    """Initialize optimizer state under jit so every state leaf inherits the
-    corresponding parameter's sharding (adam moments mirror params; scalars
-    replicate)."""
-    return jax.jit(optimizer.init)(params)
+def init_opt_state(optimizer, params, mesh: Mesh, cfg=None):
+    """Optimizer state placed as the train step will return it: a leaf
+    that mirrors a parameter (adam moments) takes that parameter's
+    sharding, anything else (step counts) is replicated on the mesh.
+
+    Left to itself ``jax.jit(optimizer.init)`` puts its zeros on ONE
+    device whatever the params' shardings are; the step then compiles
+    twice — once for that placement, once for its own outputs' — and a
+    dp mesh starts with the whole state on device 0."""
+    import optax
+    replicated = NamedSharding(mesh, P())
+    shardings = optax.tree_utils.tree_map_params(
+        optimizer, lambda _, p: p.sharding,
+        jax.eval_shape(optimizer.init, params), params,
+        transform_non_params=lambda _: replicated)
+    return jax.jit(optimizer.init, out_shardings=shardings)(params)
 
 
 def shard_batch(tokens, targets, mesh: Mesh):
