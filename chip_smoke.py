@@ -48,10 +48,8 @@ Tolerances (all stated here, none tuned per run):
                     lands on a rounding boundary), <= 0.1% of them off;
                     residual equal to x - codes*scale of the kernel's own
                     codes; dequantize / fused sgd / fused adam rtol 1e-4
-  losses across meshes  BERT-Large bf16, dp=4 vs one device: step 1 (same
-                    params, forward only) rel 2e-3; steps 2-3 rel 5e-2 (adam's
-                    first updates are sign-like, so bf16 noise decides the
-                    direction of near-zero gradient coordinates);
+  losses across meshes  BERT-Large bf16, dp=4 vs one device: rel 1e-2 on each
+                    of 3 steps (the all-reduce changes the summation order);
                     flagship f32 layouts: abs 2e-2 on a loss ~ 5.5 (TPU f32
                     matmuls run as bf16 passes at default precision).
 """
@@ -71,8 +69,7 @@ XENT_LOSS_ATOL = 2e-3
 XENT_GRAD_TOL = 1e-2
 CODEC_RTOL = 1e-4
 CODE_MISMATCH_MAX = 1e-3
-BERT_MESH_RTOL_FIRST = 2e-3
-BERT_MESH_RTOL_LATER = 5e-2
+BERT_MESH_RTOL = 1e-2
 TRAIN_STEPS = 30
 LAYOUT_ATOL = 2e-2
 
@@ -615,12 +612,10 @@ def _four_bert(smoke: Smoke, hvd) -> None:
     losses1, _text, facts1 = _bert_three_steps(smoke, hvd, mesh1)
     rel = [abs(a - b) / abs(b) for a, b in zip(losses4, losses1)]
     smoke.emit("four_chips", what="bert one device (same seed and batch)",
-               losses=losses1, rel_diff_vs_dp4=rel,
-               tol_first_step=BERT_MESH_RTOL_FIRST,
-               tol_later_steps=BERT_MESH_RTOL_LATER, **facts1)
+               losses=losses1, rel_diff_vs_dp4=rel, tol=BERT_MESH_RTOL,
+               **facts1)
     check(all(math.isfinite(x) for x in losses4 + losses1), "non-finite")
-    check(rel[0] <= BERT_MESH_RTOL_FIRST
-          and max(rel[1:]) <= BERT_MESH_RTOL_LATER,
+    check(max(rel) <= BERT_MESH_RTOL,
           f"dp=4 and one-device losses differ by {rel}: "
           f"{losses4} vs {losses1}")
 
