@@ -1,7 +1,8 @@
 #!/usr/bin/env python
 """Bench artifact contract check: bench.py must print exactly one line of
-parseable JSON with the headline metric keys, succeeding (value numeric)
-on TPU and degrading to a diagnostic (value null, error set) elsewhere.
+parseable JSON with the headline metric keys, succeeding (value numeric,
+exit 0) when a measurement landed and otherwise failing with a diagnostic
+(value null, error set, non-zero exit).
 
 ``--scaling NEW [--baseline OLD] [--tolerance T]`` is the scaling-curve
 regression gate (ISSUE 6): NEW/OLD are MULTICHIP_* artifacts (or raw
@@ -199,9 +200,8 @@ def check_trajectory(series, tolerance: float = 0.5):
 
 
 def _load_bench_doc(path: str):
-    """The bench result doc from a raw doc JSON, a BENCH_r* artifact
-    (doc under ``parsed``), or a BENCH_MEASURED run entry (under
-    ``result``)."""
+    """The bench result doc from a raw doc JSON, or from an artifact
+    that wraps it (doc under ``parsed`` or ``result``)."""
     with open(path) as f:
         doc = json.load(f)
     if isinstance(doc, dict):
@@ -1064,6 +1064,11 @@ def main() -> int:
             return 1
     if doc["value"] is None and "error" not in doc:
         print(f"null value without diagnostic error: {doc}")
+        return 1
+    if (doc["value"] is None) != (out.returncode != 0):
+        print(f"bench.py exit code {out.returncode} disagrees with "
+              f"value={doc['value']!r}: a null exits non-zero, a "
+              "measurement exits 0")
         return 1
     # per-phase timing contract: a run that got as far as touching devices
     # must say WHERE the wall clock went — either completed phases

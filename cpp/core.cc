@@ -817,7 +817,7 @@ void Core::Loop() {
 
 namespace {
 // negotiation-phase names (reference: timeline.h NEGOTIATING state +
-// activity taxonomy common.h:73-105)
+// activity classes common.h:73-105)
 const char* NegotiatePhase(Request::Type t) {
   switch (t) {
     case Request::kAllreduce: return "NEGOTIATE_ALLREDUCE";
@@ -1715,7 +1715,7 @@ void Core::Execute(CoordDomain& d, const Response& r) {
   int32_t dtag = DomTag(id, kTagData);
   counters_.responses_executed++;
   // sub-activity markers nested under EXECUTE on the unit's tid
-  // (reference activity taxonomy: MEMCPY_IN_FUSION_BUFFER /
+  // (reference activity classes: MEMCPY_IN_FUSION_BUFFER /
   // MEMCPY_OUT_FUSION_BUFFER / <op> — common.h:73-105)
   bool tl = timeline_ && timeline_->enabled() && !r.names.empty();
   auto act_begin = [&](const char* a) {

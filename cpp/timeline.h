@@ -1,7 +1,7 @@
 // Chrome-tracing timeline for the native core (reference:
 // horovod/common/timeline.{h,cc} — writer thread + activity events;
 // coordinator-only file, operations.cc:459-475; dynamic start/stop via the
-// C API, operations.cc:1011-1041; activity taxonomy common.h:73-105).
+// C API, operations.cc:1011-1041; activity classes common.h:73-105).
 #pragma once
 
 #include <atomic>

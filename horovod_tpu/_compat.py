@@ -1,43 +1,12 @@
-"""Bridges over jax API drift, internal to horovod_tpu.
+"""The two jax names the manual-SPMD code uses, imported from one place.
 
-The codebase targets the newer-jax spellings — top-level ``jax.shard_map``
-(with its ``check_vma`` kwarg) and ``jax.lax.axis_size`` — while older
-environments ship ``jax.experimental.shard_map`` (kwarg ``check_rep``) and
-no ``axis_size``. Every in-repo call site imports the two names from here
-instead of reaching into ``jax`` directly, so the bridging never leaks into
-the third-party module (other libraries in the process must see the stock
-``jax`` surface, feature-detection and all).
+The installed jax (0.9) has ``jax.shard_map`` (with ``check_vma``) and
+``jax.lax.axis_size``; every in-repo call site imports them from here.
 """
 
 from __future__ import annotations
 
-import functools
-
 import jax
 
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:
-    try:
-        from jax.experimental.shard_map import shard_map as _legacy_shard_map
-
-        @functools.wraps(_legacy_shard_map)
-        def shard_map(*args, **kwargs):
-            if "check_vma" in kwargs:
-                kwargs["check_rep"] = kwargs.pop("check_vma")
-            return _legacy_shard_map(*args, **kwargs)
-    except ImportError:  # even older jax: informative error at call time
-        def shard_map(*args, **kwargs):
-            raise NotImplementedError(
-                "this jax provides neither jax.shard_map nor "
-                "jax.experimental.shard_map; horovod_tpu's manual-SPMD "
-                "paths need one of the two")
-
-if hasattr(jax.lax, "axis_size"):
-    axis_size = jax.lax.axis_size
-else:
-    def axis_size(axis_name):
-        """Size of a named mesh axis from inside shard_map (newer jax
-        reads it from static metadata; psum of ones is the classic
-        equivalent and folds to a constant under jit)."""
-        return jax.lax.psum(1, axis_name)
+shard_map = jax.shard_map
+axis_size = jax.lax.axis_size

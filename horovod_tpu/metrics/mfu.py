@@ -11,7 +11,8 @@ from __future__ import annotations
 
 from typing import Optional
 
-# Peak dense bf16 FLOPs per chip by device-kind substring (public specs).
+# Peak dense bf16 FLOPs per chip by device-kind substring (Google Cloud
+# TPU documentation, per-chip figures; v5e: "TPU v5e", 197 TFLOP/s).
 PEAK_BF16_FLOPS = (
     ("v5 lite", 197e12), ("v5litepod", 197e12), ("v5e", 197e12),
     ("v5p", 459e12), ("v6", 918e12), ("v4", 275e12), ("v3", 123e12),
@@ -19,21 +20,27 @@ PEAK_BF16_FLOPS = (
 )
 
 
-def peak_flops(device_kind: str) -> Optional[float]:
-    """Peak dense bf16 FLOPs/s for a device-kind string, or None when the
-    chip is unknown (CPU hosts, future TPUs not yet tabled)."""
+def peak_flops(device_kind: str) -> float:
+    """Peak dense bf16 FLOPs/s for a TPU device-kind string. A kind with
+    no row raises ``LookupError``: a device that is not in the table is
+    an error, not a default — an MFU against a guessed peak is worse
+    than none."""
     kind = device_kind.lower()
     for sub, peak in PEAK_BF16_FLOPS:
         if sub in kind:
             return peak
-    return None
+    raise LookupError(
+        f"no peak-FLOPs row for device kind {device_kind!r}; add it to "
+        "horovod_tpu.metrics.mfu.PEAK_BF16_FLOPS with its source")
 
 
 def device_peak_flops() -> Optional[float]:
-    """Peak FLOPs of the first local device (None off-TPU)."""
+    """Peak FLOPs of the first local device. None says "not a TPU" (a CPU
+    host has no MFU) and nothing else: a TPU the table does not know
+    raises (:func:`peak_flops`)."""
     import jax
-    devs = jax.devices()
-    return peak_flops(devs[0].device_kind) if devs else None
+    dev = jax.devices()[0]
+    return peak_flops(dev.device_kind) if dev.platform == "tpu" else None
 
 
 def hlo_flops_per_device(jitted, args, factor: int = 1) -> Optional[float]:

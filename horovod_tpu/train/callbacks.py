@@ -172,10 +172,8 @@ class StepTimer:
         if self.flops_per_step and dt > 0:
             if self._peak is _UNSET:
                 from horovod_tpu.metrics.mfu import device_peak_flops
-                try:
-                    self._peak = device_peak_flops()
-                except Exception:
-                    self._peak = None
+                # None off-TPU; an untabled TPU raises (no guessed MFU)
+                self._peak = device_peak_flops()
             if self._peak:
                 self.last_mfu = self.flops_per_step / dt / self._peak
                 if self.mfu_gauge is None:

@@ -11,8 +11,6 @@ os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=1")
 
 import jax  # noqa: E402
 
-jax.config.update("jax_platforms", "cpu")
-
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 import optax  # noqa: E402

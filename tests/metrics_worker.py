@@ -17,8 +17,6 @@ import urllib.request  # noqa: E402
 
 import jax  # noqa: E402
 
-jax.config.update("jax_platforms", "cpu")
-
 import jax.numpy as jnp  # noqa: E402
 
 import horovod_tpu as hvd  # noqa: E402

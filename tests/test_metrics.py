@@ -521,3 +521,33 @@ def test_healthz_liveness_served_end_to_end(monkeypatch):
     finally:
         exp.stop()
         wd.reset()
+
+
+@pytest.mark.parametrize("kind,peak", [
+    ("TPU v5 lite", 197e12), ("TPU v5e", 197e12), ("TPU v4", 275e12)])
+def test_peak_flops_known_tpu_kinds(kind, peak):
+    from horovod_tpu.metrics.mfu import peak_flops
+    assert peak_flops(kind) == peak
+
+
+def test_peak_flops_unknown_kind_raises():
+    """A device that is not in the table is an error, not a default."""
+    from horovod_tpu.metrics.mfu import peak_flops
+    with pytest.raises(LookupError, match="TPU v99"):
+        peak_flops("TPU v99")
+
+
+def test_device_peak_flops_is_none_only_off_tpu(monkeypatch):
+    import jax
+    from horovod_tpu.metrics import mfu
+
+    assert mfu.device_peak_flops() is None  # the CPU test mesh
+
+    class _Dev:
+        platform = "tpu"
+        device_kind = "TPU v99"
+    monkeypatch.setattr(jax, "devices", lambda *a: [_Dev()])
+    with pytest.raises(LookupError):
+        mfu.device_peak_flops()
+    _Dev.device_kind = "TPU v5 lite"
+    assert mfu.device_peak_flops() == 197e12
