@@ -148,13 +148,6 @@ def _log(msg: str) -> None:
     print(f"[bench] {msg}", file=sys.stderr, flush=True)
 
 
-def _peak_flops():
-    # single source of truth shared with the train-loop telemetry: None
-    # off-TPU (a CPU test child has no MFU); a TPU with no row raises
-    from horovod_tpu.metrics.mfu import device_peak_flops
-    return device_peak_flops()
-
-
 # -- per-phase timing (child side) -------------------------------------------
 # Cumulative phase -> seconds, persisted to HVD_BENCH_PHASE_FILE at every
 # boundary so a deadline-killed child still leaves a record of WHERE the
@@ -309,7 +302,10 @@ def _measure_and_report(step_fn, state, readback, analytic_flops_per_device,
 
     def emit(value, dt_window, n_iters, provisional, flops_per_device,
              flops_src, compile_s, series=None):
-        peak = _peak_flops()
+        # the table the train-loop telemetry uses: None off-TPU (a CPU
+        # test child has no MFU); a TPU kind with no row raises
+        from horovod_tpu.metrics.mfu import device_peak_flops
+        peak = device_peak_flops()
         mfu = (round(flops_per_device * n_iters / dt_window / peak, 4)
                if peak and flops_per_device else None)
         gp_snap, gp_att = _goodput_doc(mfu)

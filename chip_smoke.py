@@ -197,17 +197,7 @@ def _bert_setup(hvd, mesh, smoke: Smoke):
         "mlm_mask": put(rng.rand(B, S) < 0.15, jnp.float32),
         "nsp_labels": put(rng.randint(0, 2, (B,)), jnp.int32),
     }
-    n_params = sum(x.size for x in jax.tree_util.tree_leaves(params))
-    return cfg, step, params, opt_state, batch, n_params
-
-
-def _compile_counters(hvd) -> tuple:
-    reg = hvd.metrics_snapshot()["registry"]
-
-    def value(name):
-        return int(reg[name]["value"]) if name in reg else 0
-    return (value("hvd_compile_total"),
-            value("hvd_compile_cache_miss_total"))
+    return cfg, step, params, opt_state, batch
 
 
 def _persistent_cache_events() -> dict:
@@ -249,22 +239,25 @@ def phase_train(smoke: Smoke, hvd) -> None:
     from horovod_tpu.profiling import compile_watch
 
     mesh = hvd.build_mesh(dp=-1)
-    cfg, step, params, opt_state, batch, n_params = _bert_setup(
-        hvd, mesh, smoke)
+    cfg, step, params, opt_state, batch = _bert_setup(hvd, mesh, smoke)
+    n_params = sum(x.size for x in jax.tree_util.tree_leaves(params))
     z = smoke.sizes
-    # after the set-up: init_bert runs op by op, and each op compiles
+    # hvd.init() installed the watcher; without it (HVD_TPU_COMPILE_METRICS=0)
+    # the zero-compiles check below would pass on empty counters
     check(compile_watch.ensure_installed(), "compile metrics are disabled")
 
+    # the watcher's totals feed hvd_compile_total / _cache_miss_total:
+    # backend compiles (a read from the persistent cache counts and is
+    # timed as one), tracing-cache misses, compile seconds
     cache = _persistent_cache_events()
-    backend_s0 = compile_watch.totals()["seconds_total"]
+    before = compile_watch.totals()
     t0 = time.perf_counter()
     params, opt_state, loss = step(params, opt_state, batch)
     losses = [float(loss)]
     first_step_s = time.perf_counter() - t0
-    # the backend-compile event also times a read from the persistent cache
-    compile_s = compile_watch.totals()["seconds_total"] - backend_s0
+    after_first = compile_watch.totals()
+    compile_s = after_first["seconds_total"] - before["seconds_total"]
     cache_hit = cache["hits"] > 0 and cache["misses"] == 0
-    compiles_after_first = _compile_counters(hvd)
 
     step_s, dispatch_s, readback_s = [], [], []
     for _ in range(TRAIN_STEPS):
@@ -278,14 +271,13 @@ def phase_train(smoke: Smoke, hvd) -> None:
         dispatch_s.append(t_dispatch - t0)
         step_s.append(t_ready - t0)
         readback_s.append(t_read - t_ready)
-    compiles_at_end = _compile_counters(hvd)
+    at_end = compile_watch.totals()
 
     check(all(math.isfinite(x) for x in losses), f"non-finite loss: {losses}")
     check(losses[-1] < losses[0],
           f"loss did not fall on a fixed batch: {losses}")
-    check(compiles_at_end == compiles_after_first,
-          "compiled again after the first step: (backend compiles, tracing "
-          f"misses) {compiles_after_first} -> {compiles_at_end}")
+    check(at_end == after_first,
+          f"compiled again after the first step: {after_first} -> {at_end}")
     # if block_until_ready returned before the step ended, the readback
     # that follows it would carry the step's time
     med_step, med_read = (statistics.median(step_s),
@@ -325,9 +317,25 @@ def phase_train(smoke: Smoke, hvd) -> None:
 # kernels: compiled Pallas against the XLA reference of the same file
 # ---------------------------------------------------------------------------
 
-def _custom_calls(text: str) -> list:
-    return [line for line in text.splitlines()
-            if 'custom_call_target="tpu_custom_call"' in line]
+def _has_kernel(compiled, kernel: str) -> bool:
+    """Whether the compiled program holds a tpu_custom_call instruction
+    named after ``kernel`` (the pallas_call's ``name``)."""
+    return any(kernel in line for line in compiled.as_text().splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line)
+
+
+def _reference_attention(q, k, v):
+    """Plain causal attention at "highest" matmul precision."""
+    import jax
+    from horovod_tpu.parallel.ring_attention import _plain_attention
+    with jax.default_matmul_precision("highest"):
+        return _plain_attention(q, k, v, True)
+
+
+def _weighted_sum(attn, w):
+    """Scalar loss of an attention function with a fixed cotangent ``w``."""
+    import jax.numpy as jnp
+    return lambda q, k, v: jnp.sum(attn(q, k, v).astype(jnp.float32) * w)
 
 
 def _run_compiled(smoke: Smoke, fn, args, kernel: str):
@@ -336,10 +344,8 @@ def _run_compiled(smoke: Smoke, fn, args, kernel: str):
     import jax
     compiled = jax.jit(fn).lower(*args).compile()
     if smoke.on_chip:
-        calls = _custom_calls(compiled.as_text())
-        check(any(kernel in c for c in calls),
-              f"{kernel}: no such tpu_custom_call in the compiled program "
-              f"({len(calls)} custom calls)")
+        check(_has_kernel(compiled, kernel),
+              f"{kernel}: no such tpu_custom_call in the compiled program")
     return compiled(*args)
 
 
@@ -364,7 +370,6 @@ def _check_flash(smoke: Smoke) -> None:
     import jax
     import jax.numpy as jnp
     from horovod_tpu.ops.pallas_attention import flash_attention_tpu
-    from horovod_tpu.parallel.ring_attention import _plain_attention
 
     interpret = smoke.rehearsal
     shape = smoke.sizes.attn
@@ -375,20 +380,14 @@ def _check_flash(smoke: Smoke) -> None:
     def kernel(q, k, v):
         return flash_attention_tpu(q, k, v, True, interpret=interpret)
 
-    def reference(q, k, v):
-        with jax.default_matmul_precision("highest"):
-            return _plain_attention(q, k, v, True)
-
-    def loss_of(attn):
-        return lambda q, k, v: jnp.sum(attn(q, k, v).astype(jnp.float32) * w)
-
     got = _run_compiled(smoke, kernel, (q, k, v), "hvd_flash_attention")
-    want = jax.jit(reference)(q, k, v)
+    want = jax.jit(_reference_attention)(q, k, v)
     _kernel_line(smoke, "flash_attention", "fwd", _rel_err(got, want),
                  FLASH_TOL, shape=shape, dtype="bfloat16")
-    got = _run_compiled(smoke, jax.grad(loss_of(kernel), (0, 1, 2)),
+    got = _run_compiled(smoke, jax.grad(_weighted_sum(kernel, w), (0, 1, 2)),
                         (q, k, v), "hvd_flash_attention")
-    want = jax.jit(jax.grad(loss_of(reference), (0, 1, 2)))(q, k, v)
+    want = jax.jit(jax.grad(_weighted_sum(_reference_attention, w),
+                            (0, 1, 2)))(q, k, v)
     for name, g, r in zip(("dq", "dk", "dv"), got, want):
         _kernel_line(smoke, "flash_attention", f"grad {name}",
                      _rel_err(g, r), FLASH_TOL)
@@ -527,8 +526,7 @@ def _check_flagship(smoke: Smoke, hvd) -> None:
     t0 = time.perf_counter()
     compiled = step.lower(params, opt_state, tokens, targets).compile()
     compile_s = time.perf_counter() - t0
-    calls = _custom_calls(compiled.as_text())
-    in_program = {k: any(k in c for c in calls)
+    in_program = {k: _has_kernel(compiled, k)
                   for k in ("hvd_flash_attention", "hvd_fused_xent")}
     if smoke.on_chip:
         check(all(in_program.values()),
@@ -540,14 +538,15 @@ def _check_flagship(smoke: Smoke, hvd) -> None:
         losses.append(float(loss))
     check(all(math.isfinite(x) for x in losses), f"non-finite: {losses}")
 
-    heads16 = (B, S, 2 * cfg.n_heads, cfg.head_dim // 2)
-    paths = {"heads_%d_head_dim_%d" % (cfg.n_heads, cfg.head_dim):
-             _attention_path((B, S, cfg.n_heads, cfg.head_dim)),
-             # bench.py's gpt default: d_model 1024 / 16 heads
-             "heads_%d_head_dim_%d" % heads16[2:]: _attention_path(heads16)}
+    # the same d_model over twice the heads is bench.py's gpt default
+    # (1024 / 16 heads, head_dim 64)
+    shapes = ((B, S, cfg.n_heads, cfg.head_dim),
+              (B, S, 2 * cfg.n_heads, cfg.head_dim // 2))
+    paths = {f"{s[2]} heads x head_dim {s[3]}": _attention_path(s)
+             for s in shapes}
     if smoke.on_chip:
-        check(paths["heads_8_head_dim_128"].startswith("pallas"), str(paths))
-        check(paths["heads_16_head_dim_64"].startswith("xla"), str(paths))
+        check(list(paths.values()) == ["pallas hvd_flash_attention",
+                                       "xla _plain_attention"], str(paths))
     smoke.emit("kernels", model="flagship transformer", **z.gpt,
                batch=B, compile_seconds=round(compile_s, 3),
                kernels_in_compiled_step=in_program if smoke.on_chip
@@ -570,9 +569,9 @@ def phase_kernels(smoke: Smoke, hvd) -> None:
 # ---------------------------------------------------------------------------
 
 def _bert_three_steps(smoke: Smoke, hvd, mesh) -> tuple:
-    """(losses, compiled text, facts about placement) of 3 BERT steps."""
+    """(losses, compiled step, facts about placement) of 3 BERT steps."""
     import jax
-    cfg, step, params, opt_state, batch, _ = _bert_setup(hvd, mesh, smoke)
+    _cfg, step, params, opt_state, batch = _bert_setup(hvd, mesh, smoke)
     compiled = step.lower(params, opt_state, batch).compile()
     n_mesh = mesh.devices.size
     leaves = jax.tree_util.tree_leaves(params)
@@ -592,24 +591,26 @@ def _bert_three_steps(smoke: Smoke, hvd, mesh) -> tuple:
         str(d.id): _memory(d) for d in mesh.devices.flat}
     facts["compiled_step_bytes_per_device"] = _step_bytes(
         compiled.memory_analysis())
-    return losses, compiled.as_text(), facts
+    return losses, compiled, facts
 
 
 def _four_bert(smoke: Smoke, hvd) -> None:
     import math
     import jax
     mesh4 = hvd.build_mesh(dp=-1)
-    losses4, text4, facts4 = _bert_three_steps(smoke, hvd, mesh4)
+    losses4, step4, facts4 = _bert_three_steps(smoke, hvd, mesh4)
     check(len(set(facts4["batch_shard_devices"])) == 4,
           f"batch is not on 4 distinct devices: {facts4}")
     check(facts4["params_replicated_on"] == 4,
           f"params are not replicated on all 4 devices: {facts4}")
-    check("all-reduce" in text4, "no all-reduce in the dp=4 compiled step")
+    check("all-reduce" in step4.as_text(),
+          "no all-reduce in the dp=4 compiled step")
+    del step4
     smoke.emit("four_chips", what="bert dp=4", losses=losses4,
                all_reduce_in_step=True, **facts4)
 
     mesh1 = hvd.build_mesh(dp=-1, devices=jax.devices()[:1])
-    losses1, _text, facts1 = _bert_three_steps(smoke, hvd, mesh1)
+    losses1, _step1, facts1 = _bert_three_steps(smoke, hvd, mesh1)
     rel = [abs(a - b) / abs(b) for a, b in zip(losses4, losses1)]
     smoke.emit("four_chips", what="bert one device (same seed and batch)",
                losses=losses1, rel_diff_vs_dp4=rel, tol=BERT_MESH_RTOL,
@@ -663,8 +664,7 @@ def _four_ring(smoke: Smoke, hvd) -> None:
     attention on the whole sequence."""
     import jax
     import jax.numpy as jnp
-    from horovod_tpu.parallel.ring_attention import (_plain_attention,
-                                                     ring_attention)
+    from horovod_tpu.parallel.ring_attention import ring_attention
 
     mesh = hvd.build_mesh(dp=1, sp=4)
     shape = smoke.sizes.ring
@@ -676,22 +676,16 @@ def _four_ring(smoke: Smoke, hvd) -> None:
         return ring_attention(q, k, v, mesh, "sp", causal=True,
                               use_flash=True, interpret=smoke.rehearsal)
 
-    def reference(q, k, v):
-        with jax.default_matmul_precision("highest"):
-            return _plain_attention(q, k, v, True)
-
-    def loss_of(attn):
-        return lambda q, k, v: jnp.sum(attn(q, k, v).astype(jnp.float32) * w)
-
-    compiled = jax.jit(jax.value_and_grad(loss_of(ring), (0, 1, 2))).lower(
+    compiled = jax.jit(jax.grad(_weighted_sum(ring, w), (0, 1, 2))).lower(
         q, k, v).compile()
-    text = compiled.as_text()
     if smoke.on_chip:
-        check(any("hvd_flash_attention" in c for c in _custom_calls(text)),
+        check(_has_kernel(compiled, "hvd_flash_attention"),
               "no flash kernel in the ring attention program")
-        check("collective-permute" in text, "no ring permute in the program")
-    _, got = compiled(q, k, v)
-    want = jax.jit(jax.grad(loss_of(reference), (0, 1, 2)))(q, k, v)
+        check("collective-permute" in compiled.as_text(),
+              "no ring permute in the program")
+    got = compiled(q, k, v)
+    want = jax.jit(jax.grad(_weighted_sum(_reference_attention, w),
+                            (0, 1, 2)))(q, k, v)
     for name, g, r in zip(("dq", "dk", "dv"), got, want):
         err = _rel_err(g, r)
         smoke.emit("four_chips", what=f"ring attention sp=4 grad {name}",

@@ -20,12 +20,12 @@ compiler into a first-class metrics source:
   function named in the finding and the flight event (and, via the
   anomaly->profile hook, a device trace of the storm itself).
 
-Sources (jax 0.4.x):
+Sources:
 
 * ``jax.monitoring`` duration events
   (``/jax/core/compile/backend_compile_duration``) time the actual XLA
   backend compile;
-* the ``jax_log_compiles`` log line ("Compiling <name> with global
+* the ``jax_log_compiles`` log line ("Compiling jit(<name>) with global
   shapes...") names the function being compiled — jax's monitoring
   events carry no name, so the log record is the attribution channel.
   When this module enabled the flag itself it also stops those records
@@ -50,7 +50,10 @@ DEFAULT_RECOMPILE_STORM = 3
 
 # jax's lowering log line; the WARNING level is jax's own choice for
 # log_compiles output (jax._src.interpreters.pxla)
-_COMPILING_RE = re.compile(r"^Compiling ([^\s]+) with global shapes")
+# the installed jax writes "Compiling jit(train_step) with global
+# shapes..."; the function's own name is what the metrics carry
+_COMPILING_RE = re.compile(
+    r"^Compiling (?:jit\()?([^\s()]+)\)? with global shapes")
 _PXLA_LOGGER = "jax._src.interpreters.pxla"
 # also logs at WARNING under log_compiles ("Finished tracing...",
 # "Finished XLA compilation...") — silenced alongside when WE own the

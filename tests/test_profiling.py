@@ -626,9 +626,9 @@ def _telemetry_loop_with_work(steps):
     from horovod_tpu.train.callbacks import TelemetryCallback
     cb = TelemetryCallback(units_per_step=32, registry=Registry())
     x = jnp.ones((8, 16, 16))
-    devs = jax.devices()
-    y = jax.device_put(x, jax.sharding.PositionalSharding(
-        devs).reshape(8, 1, 1))
+    mesh = jax.sharding.Mesh(jax.devices(), ("d",))
+    y = jax.device_put(x, jax.sharding.NamedSharding(
+        mesh, jax.sharding.PartitionSpec("d")))
     step = jax.jit(lambda a: (a @ a).sum())
     for _ in range(steps):
         cb.on_step_begin()
