@@ -1,0 +1,148 @@
+"""The harness end to end at tiny sizes on the CPU: a cell, a
+configuration and a per-layer metric added by new files and new entries
+alone, and the reference check failing when the two sides compute
+different things."""
+
+import json
+import os
+import shutil
+import subprocess
+import sys
+
+CHIP = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ROOT = os.path.dirname(os.path.dirname(CHIP))
+
+
+def _read(*parts):
+    with open(os.path.join(*parts)) as f:
+        return json.load(f)
+
+
+def _write(doc, *parts):
+    with open(os.path.join(*parts), "w") as f:
+        json.dump(doc, f)
+
+
+def test_a_cell_a_config_and_a_metric_are_added_by_files_alone(tmp_path):
+    root = str(tmp_path)
+    chip = os.path.join(root, "benchmarks", "chip")
+    shutil.copytree(CHIP, chip, ignore=shutil.ignore_patterns(
+        "tests", "__pycache__", "tools"))
+
+    # a configuration: its file of sizes (here BERT-Large's, one layer
+    # less at the tiny size), served by the adapter that is there
+    config = _read(CHIP, "configs", "bert-large.json")
+    config["tiny"] = {**config["tiny"], "num_hidden_layers": 1}
+    _write(config, chip, "configs", "throwaway.json")
+    # a traffic mix: a data file of parameters
+    job = _read(CHIP, "workloads", "train.s128.b64.json")
+    job["tiny"] = {**job["tiny"], "seq_len": 8, "batch_per_chip": 2}
+    _write(job, chip, "workloads", "train.throwaway.json")
+    # a per-layer metric over a reader that is there
+    _write({"layer": "host loop", "unit": "ms", "better": "lower",
+            "source": "host_clock", "moves": "tokens_per_s_per_chip",
+            "read": {"span": "bench.wait", "reduce": "median_ms"}},
+           chip, "layer_metrics", "host.wait_ms.json")
+    bench = _read(ROOT, "BENCHMARK.json")
+    bench["configs"].append({
+        "name": "throwaway", "source": config["source"],
+        "file": "benchmarks/chip/configs/throwaway.json", "reduced": [],
+        "why": "test"})
+    bench["workloads"].append({
+        "name": "throwaway.s8", "config": "throwaway",
+        "traffic": "train.throwaway", "chips": 1, "why": "test"})
+    bench["per_layer"].append({
+        "name": "host.wait_ms", "unit": "ms", "better": "lower",
+        "source": "host_clock", "layer": "host loop",
+        "moves": "tokens_per_s_per_chip", "workloads": ["throwaway.s8"]})
+    _write(bench, root, "BENCHMARK.json")
+
+    env = {**os.environ, "PYTHONPATH": ROOT, "JAX_PLATFORMS": "cpu"}
+    for trace, expect in ((1, "rehearsal.host.wait_ms"),
+                          (0, "rehearsal.tokens_per_s_per_chip")):
+        run = subprocess.run(
+            [sys.executable, os.path.join(chip, "run.py"), "--workload",
+             "throwaway.s8", "--seed", "3", "--seconds", "1", "--trace",
+             str(trace), "--rehearse", "--out", os.path.join(root, "out")],
+            env=env, capture_output=True, text=True, timeout=300)
+        assert run.returncode == 0, run.stderr[-2000:]
+        lines = run.stdout.strip().splitlines()
+        result = json.loads(lines[-1])
+        assert set(result) == {"correct", "attempted", "failed", "metrics",
+                               "device"}
+        assert result["correct"] is True and result["failed"] == 0
+        assert result["attempted"] > 0
+        assert expect in result["metrics"]
+        # nothing of a rehearsal stands under a metric's own name
+        assert all(k.startswith("rehearsal.") for k in result["metrics"])
+        # every earlier line names the device it ran on
+        for line in lines[:-1]:
+            doc = json.loads(line)
+            assert {"platform", "kind", "count"} <= set(doc)
+
+    # without --rehearse there is no CPU fallback: non-zero, no result
+    run = subprocess.run(
+        [sys.executable, os.path.join(chip, "run.py"), "--workload",
+         "throwaway.s8", "--seed", "3", "--seconds", "1", "--trace", "0"],
+        env=env, capture_output=True, text=True, timeout=300)
+    assert run.returncode != 0
+    assert run.stdout.strip() == ""
+
+
+def _tiny_cell(config_name, traffic, adapter_name):
+    import importlib
+    import horovod_tpu as hvd
+    import jax
+    config = _read(CHIP, "configs", config_name + ".json")
+    job = _read(CHIP, "workloads", traffic + ".json")
+    config, job = {**config, **config["tiny"]}, {**job, **job["tiny"]}
+    adapter = importlib.import_module(f"adapters.{adapter_name}")
+    reference = importlib.import_module(f"reference.{adapter_name}")
+    mesh = hvd.build_mesh(devices=jax.devices()[:1], dp=-1)
+    return adapter, reference, adapter.Cell(config, job, mesh, 0), config, job
+
+
+def test_bert_reference_check_fails_when_a_term_is_dropped(monkeypatch):
+    import run as harness
+    adapter, reference, cell, config, job = _tiny_cell(
+        "bert-large", "train.s128.b64", "bert")
+    check = harness.reference_check(adapter, reference, cell, config, job, 0)
+    assert check["ok"], check
+    # the reference without the next-sentence loss: 0.69 of ~7
+    monkeypatch.setattr(reference, "nsp_loss", lambda *a: 0.0)
+    check = harness.reference_check(adapter, reference, cell, config, job, 0)
+    assert not check["ok"] and check["loss_rel"] > 0.05, check
+
+
+def test_flagship_reference_check_fails_without_rotary(monkeypatch):
+    import run as harness
+    adapter, reference, cell, config, job = _tiny_cell(
+        "gpt-1.3b-widths", "train.s2048.b2", "flagship")
+    check = harness.reference_check(adapter, reference, cell, config, job, 0)
+    assert check["ok"], check
+    # a reference that leaves the positions out: the loss barely moves at
+    # random weights, the query projection's gradient does
+    monkeypatch.setattr(reference, "_rope", lambda x: x)
+    check = harness.reference_check(adapter, reference, cell, config, job, 0)
+    assert not check["ok"], check
+    assert check["grad_rel_l2"]["first_query"] > 0.2, check
+
+
+def test_flagship_device_init_fills_init_params_tree():
+    """The adapter draws on the device what transformer.init_params draws
+    on the host: the same tree, shapes and scales."""
+    import jax
+    import numpy as np
+    from horovod_tpu.models.transformer import init_params
+    _a, _r, cell, _c, _j = _tiny_cell(
+        "gpt-1.3b-widths", "train.s2048.b2", "flagship")
+    host = init_params(np.random.RandomState(0), cell.cfg, 1)
+    ours = jax.device_get(cell.params)
+    assert jax.tree_util.tree_structure(host) == \
+        jax.tree_util.tree_structure(ours)
+    for (path, h), o in zip(jax.tree_util.tree_leaves_with_path(host),
+                            jax.tree_util.tree_leaves(ours)):
+        assert h.shape == o.shape and h.dtype == o.dtype, path
+        assert abs(float(o.mean()) - float(h.mean())) < 0.02, path
+        assert abs(float(o.std()) / max(float(h.std()), 1e-9) - 1) < 0.05 \
+            or float(h.std()) == 0.0, path
