@@ -16,6 +16,8 @@ from typing import Any, Iterator, Optional, Sequence
 
 import jax
 
+from horovod_tpu.profiling import annotate, scopes
+
 
 class BaseDataLoader:
     """Iteration contract (reference: ``BaseDataLoader:23-60``)."""
@@ -152,14 +154,16 @@ def _device_prefetch_gen(it, sharding, buffer_size: int):
         if pending_error is not None:
             return False
         try:
-            batch = next(it)
+            with annotate(scopes.INPUT_SOURCE):
+                batch = next(it)
         except StopIteration:
             return False
         except BaseException as e:
             # drain the already-transferred batches before surfacing it
             pending_error = e
             return False
-        q.append(place(batch))
+        with annotate(scopes.INPUT_PLACE):
+            q.append(place(batch))
         return True
 
     for _ in range(buffer_size):

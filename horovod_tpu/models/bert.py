@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from horovod_tpu.models.scan_util import multi_step
+from horovod_tpu.profiling import scopes
 import flax.linen as nn
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -59,11 +60,13 @@ class SelfAttention(nn.Module):
         q = dense("query")(x)
         k = dense("key")(x)
         v = dense("value")(x)
-        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(
-            jnp.asarray(head_dim, c.dtype))
-        s = jnp.where(mask[:, None, None, :], s, -1e9)
-        p = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(c.dtype)
-        o = jnp.einsum("bhqk,bkhd->bqhd", p, v)
+        with jax.named_scope(scopes.ATTENTION_CORE):
+            s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(
+                jnp.asarray(head_dim, c.dtype))
+            s = jnp.where(mask[:, None, None, :], s, -1e9)
+            p = jax.nn.softmax(s.astype(jnp.float32),
+                               axis=-1).astype(c.dtype)
+            o = jnp.einsum("bhqk,bkhd->bqhd", p, v)
         o = nn.DenseGeneral(c.hidden_size, axis=(-2, -1), dtype=c.dtype,
                             name="out",
                             kernel_init=nn.with_partitioning(
@@ -78,16 +81,18 @@ class BertLayer(nn.Module):
     @nn.compact
     def __call__(self, x, mask):
         c = self.cfg
-        a = SelfAttention(c, name="attention")(x, mask)
-        x = nn.LayerNorm(dtype=jnp.float32, name="ln_att")(x + a)
-        h = nn.Dense(c.intermediate_size, dtype=c.dtype, name="ffn_in",
-                     kernel_init=nn.with_partitioning(
-                         nn.initializers.normal(0.02), (None, "tp")))(x)
-        h = nn.gelu(h)
-        h = nn.Dense(c.hidden_size, dtype=c.dtype, name="ffn_out",
-                     kernel_init=nn.with_partitioning(
-                         nn.initializers.normal(0.02), ("tp", None)))(h)
-        x = nn.LayerNorm(dtype=jnp.float32, name="ln_ffn")(x + h)
+        with jax.named_scope(scopes.ATTENTION):
+            a = SelfAttention(c, name="attention")(x, mask)
+            x = nn.LayerNorm(dtype=jnp.float32, name="ln_att")(x + a)
+        with jax.named_scope(scopes.MLP):
+            h = nn.Dense(c.intermediate_size, dtype=c.dtype, name="ffn_in",
+                         kernel_init=nn.with_partitioning(
+                             nn.initializers.normal(0.02), (None, "tp")))(x)
+            h = nn.gelu(h)
+            h = nn.Dense(c.hidden_size, dtype=c.dtype, name="ffn_out",
+                         kernel_init=nn.with_partitioning(
+                             nn.initializers.normal(0.02), ("tp", None)))(h)
+            x = nn.LayerNorm(dtype=jnp.float32, name="ln_ffn")(x + h)
         return x
 
 
@@ -101,34 +106,40 @@ class Bert(nn.Module):
                        name="word_embeddings",
                        embedding_init=nn.with_partitioning(
                            nn.initializers.normal(0.02), ("tp", None)))
-        x = emb(input_ids)
-        pos = jnp.arange(input_ids.shape[1])[None]
-        x = x + nn.Embed(c.max_position, c.hidden_size, dtype=c.dtype,
-                         name="position_embeddings")(pos)
-        x = x + nn.Embed(c.type_vocab_size, c.hidden_size, dtype=c.dtype,
-                         name="token_type_embeddings")(token_type_ids)
-        x = nn.LayerNorm(dtype=jnp.float32, name="ln_emb")(x)
-        for i in range(c.num_layers):
-            x = BertLayer(c, name=f"layer_{i}")(x, attention_mask)
+        with jax.named_scope(scopes.EMBED):
+            x = emb(input_ids)
+            pos = jnp.arange(input_ids.shape[1])[None]
+            x = x + nn.Embed(c.max_position, c.hidden_size, dtype=c.dtype,
+                             name="position_embeddings")(pos)
+            x = x + nn.Embed(c.type_vocab_size, c.hidden_size,
+                             dtype=c.dtype,
+                             name="token_type_embeddings")(token_type_ids)
+            x = nn.LayerNorm(dtype=jnp.float32, name="ln_emb")(x)
+        with jax.named_scope(scopes.LAYERS):
+            for i in range(c.num_layers):
+                x = BertLayer(c, name=f"layer_{i}")(x, attention_mask)
         # MLM head (tied to word embeddings) + NSP head on [CLS]
-        h = nn.Dense(c.hidden_size, dtype=c.dtype, name="mlm_transform")(x)
-        h = nn.LayerNorm(dtype=jnp.float32, name="mlm_ln")(nn.gelu(h))
-        mlm_logits = emb.attend(h.astype(c.dtype)).astype(jnp.float32)
-        nsp_logits = nn.Dense(2, dtype=jnp.float32, name="nsp")(
-            x[:, 0].astype(jnp.float32))
+        with jax.named_scope(scopes.HEAD):
+            h = nn.Dense(c.hidden_size, dtype=c.dtype,
+                         name="mlm_transform")(x)
+            h = nn.LayerNorm(dtype=jnp.float32, name="mlm_ln")(nn.gelu(h))
+            mlm_logits = emb.attend(h.astype(c.dtype)).astype(jnp.float32)
+            nsp_logits = nn.Dense(2, dtype=jnp.float32, name="nsp")(
+                x[:, 0].astype(jnp.float32))
         return mlm_logits, nsp_logits
 
 
 def pretrain_loss(mlm_logits, nsp_logits, mlm_labels, mlm_mask, nsp_labels):
     """Masked-LM + next-sentence loss (standard BERT pretraining)."""
-    v = mlm_logits.shape[-1]
-    mlm = optax.softmax_cross_entropy(
-        mlm_logits, jax.nn.one_hot(mlm_labels, v))
-    denom = jnp.maximum(jnp.sum(mlm_mask), 1.0)
-    mlm = jnp.sum(mlm * mlm_mask) / denom
-    nsp = optax.softmax_cross_entropy(
-        nsp_logits, jax.nn.one_hot(nsp_labels, 2)).mean()
-    return mlm + nsp
+    with jax.named_scope(scopes.HEAD):
+        v = mlm_logits.shape[-1]
+        mlm = optax.softmax_cross_entropy(
+            mlm_logits, jax.nn.one_hot(mlm_labels, v))
+        denom = jnp.maximum(jnp.sum(mlm_mask), 1.0)
+        mlm = jnp.sum(mlm * mlm_mask) / denom
+        nsp = optax.softmax_cross_entropy(
+            nsp_logits, jax.nn.one_hot(nsp_labels, 2)).mean()
+        return mlm + nsp
 
 
 def make_bert_train_step(model: Bert, optimizer, mesh: Mesh,
@@ -156,8 +167,9 @@ def make_bert_train_step(model: Bert, optimizer, mesh: Mesh,
                                  batch["mlm_labels"], batch["mlm_mask"],
                                  batch["nsp_labels"])
         loss, grads = jax.value_and_grad(loss_fn)(params)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        with jax.named_scope(scopes.OPTIMIZER):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         return params, opt_state, loss
 
     chain = multi_step(one_step, n_carry=2, scan_steps=scan_steps)

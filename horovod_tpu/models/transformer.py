@@ -38,6 +38,7 @@ from horovod_tpu._compat import axis_size, shard_map
 from horovod_tpu.models.scan_util import multi_step
 from horovod_tpu.parallel.ring_attention import ring_attention_spmd
 from horovod_tpu.parallel.moe import moe_layer_spmd, top_k_gating
+from horovod_tpu.profiling import scopes
 
 
 @dataclasses.dataclass(frozen=True)
@@ -218,24 +219,29 @@ def _softmax_xent(logits_local, targets):
 def _attention_block(p, x, positions, cfg: TransformerConfig):
     """x: [B', S', M] local. Heads sharded over tp; sequence over sp."""
     B, S, M = x.shape
-    h = _rmsnorm(x, p["ln1"])
-    q = (h @ p["wq"].astype(h.dtype))
-    k = (h @ p["wk"].astype(h.dtype))
-    v = (h @ p["wv"].astype(h.dtype))
-    Hl = q.shape[-1] // cfg.head_dim
-    q = q.reshape(B, S, Hl, cfg.head_dim)
-    k = k.reshape(B, S, Hl, cfg.head_dim)
-    v = v.reshape(B, S, Hl, cfg.head_dim)
-    q, k = _rope(q, positions), _rope(k, positions)
-    if _axis_live("sp"):
-        o = ring_attention_spmd(q, k, v, "sp", causal=True)
-    else:
-        # pallas flash kernel on TPU when tiling permits, XLA otherwise
-        from horovod_tpu.ops.pallas_attention import attend
-        o = attend(q, k, v, causal=True)
-    o = o.reshape(B, S, Hl * cfg.head_dim) @ p["wo"].astype(x.dtype)
-    o = _psum_if(o, "tp")
-    return x + o
+    with jax.named_scope(scopes.ATTENTION):
+        h = _rmsnorm(x, p["ln1"])
+        q = (h @ p["wq"].astype(h.dtype))
+        k = (h @ p["wk"].astype(h.dtype))
+        v = (h @ p["wv"].astype(h.dtype))
+        Hl = q.shape[-1] // cfg.head_dim
+        q = q.reshape(B, S, Hl, cfg.head_dim)
+        k = k.reshape(B, S, Hl, cfg.head_dim)
+        v = v.reshape(B, S, Hl, cfg.head_dim)
+        q, k = _rope(q, positions), _rope(k, positions)
+        # the core is the call a kernel replaces: its custom_vjp backward
+        # (XLA einsums today) is traced under the same scope
+        with jax.named_scope(scopes.ATTENTION_CORE):
+            if _axis_live("sp"):
+                o = ring_attention_spmd(q, k, v, "sp", causal=True)
+            else:
+                # pallas flash kernel on TPU when tiling permits, XLA
+                # otherwise
+                from horovod_tpu.ops.pallas_attention import attend
+                o = attend(q, k, v, causal=True)
+        o = o.reshape(B, S, Hl * cfg.head_dim) @ p["wo"].astype(x.dtype)
+        o = _psum_if(o, "tp")
+        return x + o
 
 
 def _dense_ffn(p, x):
@@ -264,13 +270,14 @@ def _moe_ffn(p, x, cfg: TransformerConfig):
 
 def _block(p, x, positions, cfg: TransformerConfig):
     x = _attention_block(p, x, positions, cfg)
-    h = _rmsnorm(x, p["ln2"])
-    if cfg.n_experts > 0:
-        o, metrics = _moe_ffn(p, h, cfg)
-        aux = metrics.aux_loss
-    else:
-        o, aux = _dense_ffn(p, h), jnp.zeros((), jnp.float32)
-    return x + o.astype(x.dtype), aux
+    with jax.named_scope(scopes.MLP):
+        h = _rmsnorm(x, p["ln2"])
+        if cfg.n_experts > 0:
+            o, metrics = _moe_ffn(p, h, cfg)
+            aux = metrics.aux_loss
+        else:
+            o, aux = _dense_ffn(p, h), jnp.zeros((), jnp.float32)
+        return x + o.astype(x.dtype), aux
 
 
 def _stage_fn_factory(cfg: TransformerConfig, positions):
@@ -306,16 +313,36 @@ def _stage_fn_factory(cfg: TransformerConfig, positions):
 
 def forward_loss_spmd(params, tokens, targets, cfg: TransformerConfig):
     """Local shapes: tokens/targets [B', S']. Returns (loss, aux_loss)."""
-    B, S = tokens.shape
+    S = tokens.shape[1]
     sp_idx = lax.axis_index("sp") if _axis_live("sp") else 0
     positions = sp_idx * S + jnp.arange(S)
 
-    x = _embed_lookup(params["embed"].astype(cfg.dtype), tokens)  # [B,S,M]
+    # the table's cast is shared with the tied logits below: XLA keeps one
+    with jax.named_scope(scopes.EMBED):
+        x = _embed_lookup(params["embed"].astype(cfg.dtype),
+                          tokens)                               # [B,S,M]
 
-    lp = params["layers"]
-    n_stages = lp["ln1"].shape[0]
-    aux_total = jnp.zeros((), jnp.float32)
+    with jax.named_scope(scopes.LAYERS):
+        x, aux_total = _run_layers(params["layers"], x, positions, cfg)
 
+    with jax.named_scope(scopes.HEAD):
+        x = _rmsnorm(x, params["ln_f"])
+        logits_local = x @ params["embed"].astype(cfg.dtype).T  # [B,S,V/tp]
+        nll = _softmax_xent(logits_local, targets)              # [B,S]
+        loss = jnp.mean(nll)
+        # average over data-like axes so every shard reports the global
+        # loss (ep subdivides the batch — see data_sharding_spec)
+        for ax in ("dp", "ep", "sp"):
+            if _axis_live(ax):
+                loss = lax.pmean(loss, ax)
+                aux_total = lax.pmean(aux_total, ax)
+    return loss, aux_total
+
+
+def _run_layers(lp, x, positions, cfg: TransformerConfig):
+    """The stack of blocks: the GPipe schedule over live pp stages, else
+    one scan over all layers. Returns (activations, mean aux loss)."""
+    B = x.shape[0]
     if _axis_live("pp"):
         from horovod_tpu.parallel.pipeline import (pipeline_spmd,
                                                    psum_cotangent)
@@ -343,18 +370,7 @@ def forward_loss_spmd(params, tokens, targets, cfg: TransformerConfig):
             lambda a: a.reshape((-1,) + a.shape[2:]), lp)
         x, auxs = lax.scan(scan_body, x, flat)
         aux_total = jnp.sum(auxs) / max(cfg.n_layers, 1)
-
-    x = _rmsnorm(x, params["ln_f"])
-    logits_local = x @ params["embed"].astype(cfg.dtype).T    # [B,S,V/tp]
-    nll = _softmax_xent(logits_local, targets)                # [B,S]
-    loss = jnp.mean(nll)
-    # average over data-like axes so every shard reports the global loss
-    # (ep subdivides the batch — see data_sharding_spec)
-    for ax in ("dp", "ep", "sp"):
-        if _axis_live(ax):
-            loss = lax.pmean(loss, ax)
-            aux_total = lax.pmean(aux_total, ax)
-    return loss, aux_total
+    return x, aux_total
 
 
 # ---------------------------------------------------------------------------
@@ -389,7 +405,8 @@ def _grad_sync(grads, pspec):
             if ax not in used:
                 g = _psum_if(g, ax)
         return g
-    return jax.tree_util.tree_map(one, grads, pspec)
+    with jax.named_scope(scopes.GRAD_SYNC):
+        return jax.tree_util.tree_map(one, grads, pspec)
 
 
 def make_grad_fn(cfg: TransformerConfig, mesh: Mesh):
@@ -436,8 +453,9 @@ def make_train_step(cfg: TransformerConfig, mesh: Mesh, optimizer,
 
     def one_step(params, opt_state, tokens, targets):
         loss, aux, grads = grad_fn(params, tokens, targets)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        with jax.named_scope(scopes.OPTIMIZER):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         return params, opt_state, loss, aux
 
     chain = multi_step(one_step, n_carry=2, scan_steps=scan_steps)

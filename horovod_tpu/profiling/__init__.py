@@ -25,26 +25,29 @@ This package owns the two cross-cutting seams:
   into the finding itself, so the flight event, ``/metrics`` and the
   autopsy all point at the same evidence.
 
-Also re-exported here: the device-annotation helpers the old
-``horovod_tpu.utils.profiler`` stub used to hold (that module is now a
-shim over this package).
+Also here: the phase vocabulary (:mod:`horovod_tpu.profiling.scopes`:
+the ``jax.named_scope`` names of the train step's parts and the host
+spans of the input path) and the trace helpers ``start_trace`` /
+``stop_trace`` / ``trace`` / ``annotate``.
 """
 
 from __future__ import annotations
 
 import contextlib
-from typing import Any, Iterator, Optional
+from typing import Iterator, Optional
+
+import jax
 
 from horovod_tpu.profiling.manager import (ProfileManager, default_manager,
                                            profile_dir)
-from horovod_tpu.profiling import compile_watch, memory
+from horovod_tpu.profiling import compile_watch, memory, scopes
 
 __all__ = [
     "ProfileManager", "default_manager", "profile_dir",
-    "compile_watch", "memory",
+    "compile_watch", "memory", "scopes",
     "on_step_begin", "on_step_end", "on_anomaly",
     "recent_captures", "finalize_open_capture", "reset",
-    "start_trace", "stop_trace", "trace", "annotate", "annotate_fn",
+    "start_trace", "stop_trace", "trace", "annotate",
 ]
 
 
@@ -122,17 +125,15 @@ def reset() -> None:
     compile_watch.reset_counts()
 
 
-# -- device-annotation helpers (the old utils/profiler surface) --------------
+# -- trace helpers ------------------------------------------------------------
 def start_trace(log_dir: str) -> None:
     """Begin a device trace viewable in TensorBoard/XProf (the device
     -side counterpart of ``hvd.start_timeline``).  Prefer
     :class:`ProfileManager` for bounded, managed captures."""
-    import jax
     jax.profiler.start_trace(log_dir)
 
 
 def stop_trace() -> None:
-    import jax
     jax.profiler.stop_trace()
 
 
@@ -145,22 +146,9 @@ def trace(log_dir: str) -> Iterator[None]:
         stop_trace()
 
 
-@contextlib.contextmanager
-def annotate(name: str) -> Iterator[None]:
-    """Named range on the device timeline (NVTX-range analog)."""
-    import jax
-    with jax.profiler.TraceAnnotation(name):
-        yield
-
-
-def annotate_fn(name: Optional[str] = None):
-    """Decorator form: ``@annotate_fn("allreduce.grads")``."""
-    def deco(fn):
-        label = name or fn.__name__
-
-        def wrapped(*args: Any, **kwargs: Any):
-            import jax
-            with jax.profiler.TraceAnnotation(label):
-                return fn(*args, **kwargs)
-        return wrapped
-    return deco
+def annotate(name: str):
+    """Named range on the profiler's host plane, on the device planes'
+    clock (NVTX-range analog): a ``jax.profiler.TraceAnnotation``, which
+    costs about a microsecond and records nothing while no profiler
+    session is open. Names come from :mod:`.scopes`."""
+    return jax.profiler.TraceAnnotation(name)

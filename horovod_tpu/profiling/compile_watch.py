@@ -24,7 +24,19 @@ Sources:
 
 * ``jax.monitoring`` duration events
   (``/jax/core/compile/backend_compile_duration``) time the actual XLA
-  backend compile;
+  backend compile — *or the read of the persistent cache in its place*:
+  JAX times ``compile_or_get_cached`` as a whole, so on a cache hit the
+  event covers the retrieval (deserialising the executable and loading
+  it onto the devices) and ``compiles`` / ``seconds_total`` count it like
+  a compile. :func:`totals` therefore also splits a program's way to the
+  device by JAX's other compile events: ``trace_seconds``
+  (``jaxpr_trace_duration``: Python to jaxpr), ``lower_seconds``
+  (``jaxpr_to_mlir_module_duration``: jaxpr to StableHLO),
+  ``cache_read_seconds`` (``cache_retrieval_time_sec``, recorded on hits
+  only and a part of ``seconds_total``), ``persistent_cache_hits`` and
+  ``persistent_cache_misses`` (``/jax/compilation_cache/cache_hits`` and
+  ``cache_misses``; JAX counts a miss when it *writes* the entry, so a
+  compile too quick or too small to be kept is neither);
 * the ``jax_log_compiles`` log line ("Compiling jit(<name>) with global
   shapes...") names the function being compiled — jax's monitoring
   events carry no name, so the log record is the attribution channel.
@@ -69,17 +81,33 @@ _null_handler: Optional[logging.Handler] = None
 _we_enabled_flag = False
 _prev_propagate: Dict[str, bool] = {}
 _registry = None
-# jax.monitoring has no listener removal, so the duration listener is
+# jax.monitoring has no listener removal, so the listeners are
 # registered at most once per process and gated on ``_installed`` —
 # an uninstall/ensure_installed cycle must NOT add a second listener
 # (every compile would count twice)
 _listener_registered = False
 
+_BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# jax's other compile events -> the key of totals() each adds to
+_DURATION_TOTALS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_seconds",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_seconds",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_read_seconds",
+}
+_EVENT_TOTALS = {
+    "/jax/compilation_cache/cache_hits": "persistent_cache_hits",
+    "/jax/compilation_cache/cache_misses": "persistent_cache_misses",
+}
+
 # per-function compile counts + storm bookkeeping
 _compiles: Dict[str, int] = {}
 _flagged_at: Dict[str, int] = {}
 _label_set: set = set()
-_totals = {"compiles": 0, "cache_misses": 0, "seconds_total": 0.0}
+_ZERO_TOTALS = {"compiles": 0, "cache_misses": 0, "seconds_total": 0.0,
+                "trace_seconds": 0.0, "lower_seconds": 0.0,
+                "cache_read_seconds": 0.0, "persistent_cache_hits": 0,
+                "persistent_cache_misses": 0}
+_totals = dict(_ZERO_TOTALS)
 
 
 def _envi(name: str, default: int) -> int:
@@ -196,13 +224,23 @@ def ensure_installed(registry=None) -> bool:
         import jax.monitoring
 
         def _dur_listener(event: str, duration: float, **_kw) -> None:
-            if _installed and \
-                    event == "/jax/core/compile/backend_compile_duration":
+            if not _installed:
+                return
+            if event == _BACKEND_COMPILE_EVENT:
                 _on_backend_compile(duration)
+            elif event in _DURATION_TOTALS:
+                with _LOCK:
+                    _totals[_DURATION_TOTALS[event]] += float(duration)
+
+        def _event_listener(event: str, **_kw) -> None:
+            if _installed and event in _EVENT_TOTALS:
+                with _LOCK:
+                    _totals[_EVENT_TOTALS[event]] += 1
 
         if not _listener_registered:
             jax.monitoring.register_event_duration_secs_listener(
                 _dur_listener)
+            jax.monitoring.register_event_listener(_event_listener)
             _listener_registered = True
         lg = logging.getLogger(_PXLA_LOGGER)
         _handler = _CompileLogHandler(level=logging.DEBUG)
@@ -258,9 +296,14 @@ def uninstall() -> None:
 
 
 def totals() -> dict:
-    """Process-lifetime compile totals — what ``bench.py`` records as
-    ``compile_seconds`` (measured backend-compile time, not the wall
-    clock of a phase that also ran the first step)."""
+    """Process-lifetime compile totals. ``compiles`` / ``seconds_total``
+    are JAX's backend-compile events (``hvd_compile_total``; what
+    ``bench.py`` records as ``compile_seconds``: measured, not the wall
+    clock of a phase that also ran the first step) and on a persistent
+    -cache hit hold the read in the compile's place; ``cache_misses`` is
+    jit's *tracing*-cache misses. ``trace_seconds``, ``lower_seconds``,
+    ``cache_read_seconds``, ``persistent_cache_hits`` and
+    ``persistent_cache_misses`` are the module docstring's split."""
     with _LOCK:
         return dict(_totals)
 
@@ -283,5 +326,4 @@ def reset_counts() -> None:
         _compiles.clear()
         _flagged_at.clear()
         _label_set.clear()
-        _totals.update({"compiles": 0, "cache_misses": 0,
-                        "seconds_total": 0.0})
+        _totals.update(_ZERO_TOTALS)

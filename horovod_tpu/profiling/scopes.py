@@ -1,0 +1,56 @@
+"""The one vocabulary of phase names: what the train step's parts are
+called inside the compiled program and on the profiler's host plane.
+
+Device phases are put with ``jax.named_scope`` where the work happens
+(``models/bert.py``, ``models/transformer.py``). A scope is metadata:
+it adds a path component to the ``op_name`` of every HLO instruction
+traced under it and changes no jaxpr and no compiled code. (Nor the
+persistent cache's key, which leaves metadata out, except where the
+program holds a Pallas kernel, whose serialised body carries the name
+stack: an executable read from the cache has the names of the build
+that wrote it.) Differentiation writes the direction into the same
+path, around its outermost component: ``jvp(hvd.head)/..`` forward and
+``transpose(jvp(hvd.head))/..`` backward where the scope is outermost,
+``transpose(jvp(Bert))/layer_3/hvd.mlp/..`` under a flax module; the
+optimizer update carries plain ``hvd.optimizer``. Any ``jax.profiler``
+/ XProf trace groups by these names, and
+``benchmarks/chip/scope_reduce.py`` reads device time per phase and
+direction from them.
+
+Host spans go through :func:`horovod_tpu.profiling.annotate`.
+
+A model that needs another phase (``hvd.moe``, ...) adds it here, and
+nowhere else: ``tests/test_scopes.py`` holds the strings to this file.
+The Pallas kernels' names (``hvd_flash_attention``, ``hvd_fused_xent``)
+are instruction names, not scopes, and stay where the kernels are.
+"""
+
+from __future__ import annotations
+
+# -- device phases (jax.named_scope) -----------------------------------------
+EMBED = "hvd.embed"
+#: the stack of blocks and what runs it: under a scan (or a pipeline
+#: schedule) its slicing and stacking of per-layer weights, residuals and
+#: gradients, which belong to no one block's attention or MLP
+LAYERS = "hvd.layers"
+#: the attention block with its projections, residual and norm
+ATTENTION = "hvd.attention"
+#: nested in ATTENTION: scores, softmax, weighted sum — what a kernel replaces
+ATTENTION_CORE = "hvd.attention.core"
+MLP = "hvd.mlp"
+#: final norm or transform, logits, loss
+HEAD = "hvd.head"
+GRAD_SYNC = "hvd.grad_sync"
+OPTIMIZER = "hvd.optimizer"
+
+#: phases of the model proper: each appears forward and backward
+MODEL_PHASES = (EMBED, LAYERS, ATTENTION, ATTENTION_CORE, MLP, HEAD)
+DEVICE_PHASES = MODEL_PHASES + (GRAD_SYNC, OPTIMIZER)
+
+# -- host spans (profiling.annotate) ------------------------------------------
+#: the input iterator's ``next()``: the host makes the batch
+INPUT_SOURCE = "hvd.input.source"
+#: ``jax.device_put`` of the batch onto its sharding
+INPUT_PLACE = "hvd.input.place"
+
+HOST_SPANS = (INPUT_SOURCE, INPUT_PLACE)

@@ -1,0 +1,250 @@
+"""The phase vocabulary (horovod_tpu/profiling/scopes.py): the names are
+written in one place, the two train steps the chip benchmark runs carry
+every phase forward and backward in their compiled text, a scope is
+metadata only, the input path's host spans open once per batch in order,
+and the compile watcher splits a program's way to the device."""
+
+import contextlib
+import os
+import re
+
+import numpy as np
+import pytest
+import jax
+import jax.numpy as jnp
+import optax
+
+from horovod_tpu.parallel import build_mesh
+from horovod_tpu.profiling import compile_watch, scopes
+
+PACKAGE = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "horovod_tpu")
+
+
+# -- the vocabulary ----------------------------------------------------------
+
+def test_the_vocabulary_is_the_only_place_the_strings_are_written():
+    names = scopes.DEVICE_PHASES + scopes.HOST_SPANS
+    assert len(set(names)) == len(names) == 10
+    assert all(n.startswith("hvd.") for n in names)
+    home = os.path.join(PACKAGE, "profiling", "scopes.py")
+    elsewhere = []
+    for folder, _dirs, files in os.walk(PACKAGE):
+        for f in files:
+            path = os.path.join(folder, f)
+            if f.endswith(".py") and path != home:
+                with open(path) as fh:
+                    text = fh.read()
+                elsewhere += [(os.path.relpath(path, PACKAGE), n)
+                              for n in names if n in text]
+    assert not elsewhere, elsewhere
+
+
+# -- the device side: phases and directions in the compiled step -------------
+
+def _bert_step():
+    from horovod_tpu.models import init_opt_state
+    from horovod_tpu.models.bert import (Bert, BertConfig, init_bert,
+                                         make_bert_train_step)
+    mesh = build_mesh(devices=jax.devices()[:1], dp=-1)
+    model = Bert(BertConfig(vocab_size=64, hidden_size=32, num_layers=2,
+                            num_heads=4, intermediate_size=64,
+                            max_position=32, dtype=jnp.float32))
+    params = init_bert(model, jax.random.PRNGKey(0), seq_len=16, mesh=mesh)
+    tx = optax.adamw(1e-3)
+    ids = jnp.zeros((2, 16), jnp.int32)
+    batch = {"input_ids": ids, "token_type_ids": ids,
+             "attention_mask": jnp.ones((2, 16), bool), "mlm_labels": ids,
+             "mlm_mask": jnp.ones((2, 16), jnp.float32),
+             "nsp_labels": jnp.zeros((2,), jnp.int32)}
+    return (make_bert_train_step(model, tx, mesh),
+            (params, init_opt_state(tx, params, mesh), batch))
+
+
+def _flagship_step(dp: int = 1):
+    from horovod_tpu.models import (TransformerConfig, init_opt_state,
+                                    init_params, make_train_step,
+                                    shard_batch, shard_params)
+    cfg = TransformerConfig(vocab_size=64, d_model=32, n_heads=4,
+                            n_layers=2, d_ff=64, max_seq=32,
+                            dtype=jnp.float32, remat=False)
+    mesh = build_mesh(devices=jax.devices()[:dp], dp=-1)
+    params = shard_params(init_params(np.random.RandomState(0), cfg, 1),
+                          cfg, mesh)
+    tx = optax.adamw(1e-3)
+    tokens = np.zeros((2 * dp, 16), np.int32)
+    t, y = shard_batch(tokens, tokens, mesh)
+    return (make_train_step(cfg, mesh, tx),
+            (params, init_opt_state(tx, params, mesh, cfg), t, y))
+
+
+_TEXTS = {}
+
+
+def _compiled_text(model: str) -> str:
+    if model not in _TEXTS:
+        step, args = {"bert": _bert_step, "flagship": _flagship_step,
+                      "flagship.dp2": lambda: _flagship_step(2)}[model]()
+        _TEXTS[model] = step.lower(*args).compile().as_text()
+    return _TEXTS[model]
+
+
+def _directions(text: str, phase: str) -> set:
+    """The directions in which ``phase`` is a whole component of an
+    instruction's op_name path, ``jvp(..)`` / ``transpose(..)`` peeled
+    off: differentiation wraps the outermost component of the name stack
+    (``jvp(Bert)/layer_0/hvd.mlp/..`` under flax,
+    ``transpose(jvp(hvd.head))/..`` where the scope is outermost), so
+    the direction is the path's and the phase a component's."""
+    found = set()
+    for path in re.findall(r'op_name="([^"]*)"', text):
+        parts = [re.sub(r"^(?:\w+\()+|\)+$", "", c) for c in path.split("/")]
+        if phase in parts:
+            found.add("bwd" if "transpose(" in path else "fwd")
+    return found
+
+
+@pytest.mark.parametrize("phase", scopes.MODEL_PHASES)
+@pytest.mark.parametrize("model", ["bert", "flagship"])
+def test_the_step_carries_every_model_phase_in_both_directions(model, phase):
+    assert _directions(_compiled_text(model), phase) == {"fwd", "bwd"}
+
+
+@pytest.mark.parametrize("model", ["bert", "flagship"])
+def test_the_update_is_under_the_optimizer_scope(model):
+    text = _compiled_text(model)
+    assert _directions(text, scopes.OPTIMIZER) == {"fwd"}
+    # nothing of the model proper is traced under it
+    for path in re.findall(r'op_name="([^"]*)"', text):
+        if f"/{scopes.OPTIMIZER}/" in path:
+            assert not any(p in path for p in scopes.MODEL_PHASES), path
+
+
+def test_the_flagship_gradient_psum_is_under_grad_sync():
+    text = _compiled_text("flagship.dp2")
+    assert _directions(text, scopes.GRAD_SYNC) == {"fwd"}
+    synced = [line for line in text.splitlines()
+              if " all-reduce(" in line and scopes.GRAD_SYNC in line]
+    assert synced
+
+
+def test_a_phase_is_not_found_by_substring():
+    text = 'x = f32[] add(a, b), metadata={op_name="jit(f)/jvp(' \
+           + scopes.ATTENTION_CORE + ')/add"}'
+    assert _directions(text, scopes.ATTENTION_CORE) == {"fwd"}
+    assert _directions(text, scopes.ATTENTION) == set()
+
+
+def test_a_scope_is_metadata_only(monkeypatch):
+    """With every named_scope taken out the lowered program is the same
+    text: a scope changes no jaxpr, hence no compiled code."""
+    def lowered():
+        step, args = _flagship_step()
+        return step.lower(*args).as_text()
+    with_scopes = lowered()
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda _name: contextlib.nullcontext())
+    assert lowered() == with_scopes
+
+
+# -- the host side: the input path's spans -----------------------------------
+
+def _record_annotations(monkeypatch):
+    from horovod_tpu.data import data_loader
+    entered = []
+
+    @contextlib.contextmanager
+    def annotate(name):
+        entered.append(name)
+        yield
+    monkeypatch.setattr(data_loader, "annotate", annotate)
+    return entered
+
+
+def test_device_prefetch_enters_source_then_place_once_per_batch(
+        monkeypatch):
+    from horovod_tpu.data.data_loader import device_prefetch
+    entered = _record_annotations(monkeypatch)
+    out = list(device_prefetch(
+        ({"x": np.full((2,), i)} for i in range(5)), buffer_size=2))
+    assert [int(b["x"][0]) for b in out] == [0, 1, 2, 3, 4]
+    assert entered[:10] == [scopes.INPUT_SOURCE, scopes.INPUT_PLACE] * 5
+    # then only the next() calls that find the source exhausted
+    assert set(entered[10:]) == {scopes.INPUT_SOURCE}
+
+
+def test_device_prefetch_surfaces_a_source_error_at_its_position(
+        monkeypatch):
+    from horovod_tpu.data.data_loader import device_prefetch
+    entered = _record_annotations(monkeypatch)
+
+    def source():
+        yield np.zeros(2)
+        yield np.ones(2)
+        raise RuntimeError("decode failed")
+    it = device_prefetch(source(), buffer_size=2)
+    assert float(next(it)[0]) == 0.0
+    assert float(next(it)[0]) == 1.0
+    with pytest.raises(RuntimeError, match="decode failed"):
+        next(it)
+    assert entered.count(scopes.INPUT_PLACE) == 2
+
+
+def test_annotate_is_a_trace_annotation():
+    from horovod_tpu import profiling
+    span = profiling.annotate(scopes.INPUT_PLACE)
+    assert isinstance(span, jax.profiler.TraceAnnotation)
+    with span:      # no profiler session: records nothing, must not raise
+        pass
+    assert not hasattr(profiling, "annotate_fn")
+
+
+# -- the counters: a program's way to the device, split ----------------------
+
+NEW_TOTALS = ("trace_seconds", "lower_seconds", "cache_read_seconds",
+              "persistent_cache_hits", "persistent_cache_misses")
+
+
+def test_compile_totals_split_rises_on_the_first_call_only():
+    compile_watch.ensure_installed()
+    compile_watch.reset_counts()
+    zero = compile_watch.totals()
+    assert set(NEW_TOTALS) <= set(zero)
+    assert all(zero[k] == 0 for k in zero)
+
+    @jax.jit
+    def split_me(x):
+        return jnp.tanh(x) * 3
+
+    split_me(jnp.ones(7)).block_until_ready()
+    first = compile_watch.totals()
+    assert first["trace_seconds"] > 0 and first["lower_seconds"] > 0
+    # today's keys count as before: one backend compile, one tracing-cache
+    # miss, its seconds
+    assert first["compiles"] >= 1 and first["cache_misses"] >= 1
+    assert first["seconds_total"] > 0
+    split_me(jnp.ones(7)).block_until_ready()
+    assert compile_watch.totals() == first
+
+
+def test_compile_totals_listen_to_each_of_jaxs_compile_events():
+    import jax.monitoring
+    compile_watch.ensure_installed()
+    compile_watch.reset_counts()
+    for event, seconds in (
+            ("/jax/core/compile/jaxpr_trace_duration", 0.5),
+            ("/jax/core/compile/jaxpr_to_mlir_module_duration", 0.25),
+            ("/jax/core/compile/backend_compile_duration", 2.0),
+            ("/jax/compilation_cache/cache_retrieval_time_sec", 1.5),
+            ("/jax/compilation_cache/compile_time_saved_sec", 9.0)):
+        jax.monitoring.record_event_duration_secs(event, seconds)
+    jax.monitoring.record_event("/jax/compilation_cache/cache_hits")
+    jax.monitoring.record_event("/jax/compilation_cache/cache_misses")
+    jax.monitoring.record_event("/jax/compilation_cache/cache_misses")
+    assert compile_watch.totals() == {
+        "compiles": 1, "cache_misses": 0, "seconds_total": 2.0,
+        "trace_seconds": 0.5, "lower_seconds": 0.25,
+        "cache_read_seconds": 1.5, "persistent_cache_hits": 1,
+        "persistent_cache_misses": 2}
+    compile_watch.reset_counts()
+    assert not any(compile_watch.totals().values())
