@@ -86,9 +86,9 @@ def ring_attention_spmd(q: jax.Array, k: jax.Array, v: jax.Array,
     scale = scale if scale is not None else (1.0 / (D ** 0.5))
 
     if use_flash is None:
-        from horovod_tpu.ops.pallas_attention import BLOCK_K, BLOCK_Q
-        use_flash = (jax.default_backend() == "tpu" and D % 128 == 0
-                     and Sq % BLOCK_Q == 0 and k.shape[1] % BLOCK_K == 0)
+        from horovod_tpu.ops.pallas_attention import flash_eligible
+        use_flash = (jax.default_backend() == "tpu"
+                     and flash_eligible(Sq, k.shape[1], D))
     if use_flash:
         return _ring_flash(q, k, v, axis_name, causal, scale, n, my,
                            interpret)

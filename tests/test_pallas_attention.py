@@ -6,23 +6,113 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from horovod_tpu.ops.pallas_attention import attend, flash_attention_tpu
+from horovod_tpu.ops import pallas_attention as pa
+from horovod_tpu.ops.pallas_attention import (attend, flash_attention_tpu,
+                                              flash_blocks)
 from horovod_tpu.parallel.ring_attention import _plain_attention
 
 
-def _qkv(B=2, S=256, H=2, D=128, seed=0):
+def _qkv(B=2, S=256, H=2, D=128, seed=0, Sk=None, dtype=jnp.float32):
     rng = np.random.RandomState(seed)
-    mk = lambda: jnp.asarray(rng.randn(B, S, H, D), jnp.float32) * 0.3
-    return mk(), mk(), mk()
+    mk = lambda s: (jnp.asarray(rng.randn(B, s, H, D), jnp.float32)
+                    * 0.3).astype(dtype)
+    return mk(S), mk(Sk or S), mk(Sk or S)
+
+
+def _assert_forward(q, k, v, causal, rtol=1e-5, atol=1e-5, **blocks):
+    out = flash_attention_tpu(q, k, v, causal=causal, interpret=True,
+                              **blocks)
+    ref = _plain_attention(*(x.astype(jnp.float32) for x in (q, k, v)),
+                           causal=causal)
+    assert out.dtype == q.dtype
+    np.testing.assert_allclose(np.asarray(out.astype(jnp.float32)),
+                               np.asarray(ref), rtol=rtol, atol=atol)
+
+
+def _assert_grads(q, k, v, causal, cotangent, **blocks):
+    """The custom-VJP backward (blockwise recompute from lse) must agree
+    with autodiff through the XLA oracle — the kernel is used in training
+    forwards, so its gradient is load-bearing."""
+    def loss_flash(q, k, v):
+        return cotangent(flash_attention_tpu(q, k, v, causal=causal,
+                                             interpret=True, **blocks))
+
+    def loss_ref(q, k, v):
+        return cotangent(_plain_attention(q, k, v, causal=causal))
+
+    g_flash = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    for gf, gr, name in zip(g_flash, g_ref, "qkv"):
+        np.testing.assert_allclose(np.asarray(gf), np.asarray(gr),
+                                   rtol=2e-4, atol=2e-4, err_msg=name)
 
 
 @pytest.mark.parametrize("causal", [True, False])
 def test_flash_kernel_matches_oracle(causal):
-    q, k, v = _qkv()
-    out = flash_attention_tpu(q, k, v, causal=causal, interpret=True)
-    ref = _plain_attention(q, k, v, causal=causal)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=1e-5, atol=1e-5)
+    _assert_forward(*_qkv(), causal)
+
+
+# (Sq, Sk, H) -> the tile the rule picks at float32, head_dim 128: one tile,
+# several tiles of one size, a q and a k tile of different sizes, and the
+# narrow tile on one axis only
+_RULE_SHAPES = {
+    (256, 256, 2): (256, 256),
+    (1024, 1024, 1): (512, 1024),
+    (384, 384, 1): (128, 128),
+    (128, 256, 2): (128, 256),
+    (512, 1536, 1): (512, 512),
+}
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("shape", sorted(_RULE_SHAPES))
+def test_flash_kernel_matches_oracle_at_the_rules_tiles(shape, causal):
+    Sq, Sk, H = shape
+    assert flash_blocks(Sq, Sk, 128, jnp.float32) == _RULE_SHAPES[shape]
+    _assert_forward(*_qkv(B=1, S=Sq, Sk=Sk, H=H, seed=3), causal)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("blocks", [(128, 128), (256, 128), (128, 256),
+                                    (512, 256), (256, 512)])
+def test_flash_kernel_tile_overrides(blocks, causal):
+    """Every tile gives the oracle's result: q tiles wider and narrower
+    than k tiles put the diagonal through tiles in every way (crossed,
+    wholly below, wholly above and never fetched)."""
+    _assert_forward(*_qkv(B=1, S=512, H=1, seed=4), causal,
+                    block_q=blocks[0], block_k=blocks[1])
+
+
+def test_flash_kernel_tile_does_not_change_float32_bits_much():
+    """The tile changes the order of the online-softmax updates only."""
+    q, k, v = _qkv(B=1, S=512, H=1, seed=5)
+    a = flash_attention_tpu(q, k, v, True, interpret=True,
+                            block_q=128, block_k=128)
+    b = flash_attention_tpu(q, k, v, True, interpret=True)
+    np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                               rtol=2e-6, atol=2e-6)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("S", [256, 1024])
+def test_flash_kernel_bf16_inputs_match_float32_oracle(S, causal):
+    """bf16 q, k, v are multiplied as bf16 (float32 accumulation, float32
+    softmax statistics); against the float32 oracle on the same values
+    the result holds chip_smoke.py's tolerance."""
+    q, k, v = _qkv(B=1, S=S, H=1, seed=6, dtype=jnp.bfloat16)
+    _assert_forward(q, k, v, causal, rtol=2e-2, atol=2e-2)
+
+
+def test_flash_lse_is_float32_for_bf16_inputs():
+    q, k, v = _qkv(B=1, S=256, H=1, dtype=jnp.bfloat16)
+    o, lse = pa.flash_attention_with_lse(q, k, v, True, interpret=True)
+    assert o.dtype == jnp.bfloat16 and lse.dtype == jnp.float32
+    s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
+                   k.astype(jnp.float32)) / 128 ** 0.5
+    s = jnp.where(jnp.tril(jnp.ones((256, 256), bool)), s, -jnp.inf)
+    want = jax.nn.logsumexp(s, -1).reshape(1, 256)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(want),
+                               rtol=1e-4, atol=1e-4)
 
 
 def test_attend_fallback_on_cpu():
@@ -33,52 +123,102 @@ def test_attend_fallback_on_cpu():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-6)
 
 
+def _cos_cotangent(o):
+    return jnp.sum(o * jnp.cos(o))   # non-trivial cotangent
+
+
 @pytest.mark.parametrize("causal", [True, False])
 def test_flash_kernel_grads_match_oracle(causal):
-    """The custom-VJP backward (blockwise recompute from lse) must agree
-    with autodiff through the XLA oracle — the kernel is used in training
-    forwards, so its gradient is load-bearing."""
-    q, k, v = _qkv(B=1, S=256, H=2, D=128)
+    _assert_grads(*_qkv(B=1, S=256, H=2, D=128), causal, _cos_cotangent)
 
-    def loss_flash(q, k, v):
-        o = flash_attention_tpu(q, k, v, causal=causal, interpret=True)
-        return jnp.sum(o * jnp.cos(o))   # non-trivial cotangent
 
-    def loss_ref(q, k, v):
-        o = _plain_attention(q, k, v, causal=causal)
-        return jnp.sum(o * jnp.cos(o))
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("shape", [(1024, 1024, 1), (384, 384, 1),
+                                   (128, 256, 2)])
+def test_flash_kernel_grads_match_oracle_at_the_rules_tiles(shape, causal):
+    """The forward's tile (512 x 1024, 128 x 128, 128 x 256) and the
+    backward's block (always BWD_BLOCK_K) are two values."""
+    Sq, Sk, H = shape
+    _assert_grads(*_qkv(B=1, S=Sq, Sk=Sk, H=H, seed=7), causal,
+                  _cos_cotangent)
 
-    g_flash = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    for gf, gr, name in zip(g_flash, g_ref, "qkv"):
-        np.testing.assert_allclose(np.asarray(gf), np.asarray(gr),
-                                   rtol=2e-4, atol=2e-4, err_msg=name)
+
+def test_flash_backward_ignores_the_forward_tile():
+    """The backward's program is the same whatever tile the forward took
+    (its float32 temporaries grow with the block: PERF.md, PR 25)."""
+    q, k, v = _qkv(B=1, S=512, H=1, seed=8)
+    res = (q, k, v, q, jnp.zeros((1, 512), jnp.float32))
+    cts = (q, None)
+
+    def text(bq, bk):
+        return str(jax.make_jaxpr(
+            lambda: pa._flash_bwd(True, 0.1, bq, bk, True, res, cts))())
+    assert text(128, 128) == text(512, 512)
+    assert f"512,{pa.BWD_BLOCK_K}]" in text(512, 512)
 
 
 def test_flash_grads_rect():
     """Sq != Sk backward (cross-attention shape)."""
-    rng = np.random.RandomState(2)
-    q = jnp.asarray(rng.randn(1, 128, 2, 128), jnp.float32) * 0.3
-    k = jnp.asarray(rng.randn(1, 256, 2, 128), jnp.float32) * 0.3
-    v = jnp.asarray(rng.randn(1, 256, 2, 128), jnp.float32) * 0.3
-
-    f = lambda q, k, v: jnp.sum(flash_attention_tpu(
-        q, k, v, causal=False, interpret=True) ** 2)
-    r = lambda q, k, v: jnp.sum(_plain_attention(q, k, v, causal=False) ** 2)
-    gf = jax.grad(f, argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(r, argnums=(0, 1, 2))(q, k, v)
-    for a, b, name in zip(gf, gr, "qkv"):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=2e-4, atol=2e-4, err_msg=name)
+    _assert_grads(*_qkv(B=1, S=128, Sk=256, seed=2), False,
+                  lambda o: jnp.sum(o ** 2))
 
 
-def test_flash_kernel_rect(causal=True):
+def test_flash_kernel_rect():
     # Sq != Sk (cross-block boundary conditions)
-    rng = np.random.RandomState(1)
-    q = jnp.asarray(rng.randn(1, 128, 2, 128), jnp.float32) * 0.3
-    k = jnp.asarray(rng.randn(1, 256, 2, 128), jnp.float32) * 0.3
-    v = jnp.asarray(rng.randn(1, 256, 2, 128), jnp.float32) * 0.3
-    out = flash_attention_tpu(q, k, v, causal=False, interpret=True)
-    ref = _plain_attention(q, k, v, causal=False)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=1e-5, atol=1e-5)
+    _assert_forward(*_qkv(B=1, S=128, Sk=256, seed=1), False)
+
+
+# -- the tile rule ----------------------------------------------------------
+
+_LENGTHS = [128, 256, 384, 512, 640, 1024, 1536, 2048, 4096, 8192]
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("D", [128, 256, 512])
+def test_flash_blocks_divide_fit_and_never_go_under_128(D, dtype):
+    itemsize = jnp.dtype(dtype).itemsize
+    for Sq in _LENGTHS:
+        for Sk in _LENGTHS:
+            bq, bk = flash_blocks(Sq, Sk, D, dtype)
+            assert bq in pa.TILES and bk in pa.TILES
+            assert Sq % bq == 0 and Sk % bk == 0
+            assert min(bq, bk) >= pa.MIN_BLOCK
+            fits = pa.flash_vmem_bytes(bq, bk, D, itemsize) <= pa.VMEM_BUDGET
+            assert fits or (bq, bk) == (pa.MIN_BLOCK, pa.MIN_BLOCK)
+
+
+def test_flash_blocks_at_the_benchmarks_shape():
+    # gpt-1.3b-widths.s2048: B2 S2048 H16 D128 bf16 -> 32 x 2 x 2 steps a
+    # call where 128 x 128 took 32 x 16 x 16 (PERF.md, PR 25's sweep)
+    assert flash_blocks(2048, 2048, 128, jnp.bfloat16) == (1024, 1024)
+    assert pa.flash_grid(2, 16, 2048, 2048, 1024, 1024) == (32, 2, 2)
+
+
+def test_flash_blocks_short_and_rectangular_shapes_keep_their_tiles():
+    # a ring step with 256 local positions, the rectangular test, and a
+    # length only 128 divides
+    assert flash_blocks(256, 256, 128, jnp.bfloat16) == (256, 256)
+    assert flash_blocks(128, 256, 128, jnp.float32) == (128, 256)
+    assert flash_blocks(384, 640, 128, jnp.bfloat16) == (128, 128)
+
+
+def test_flash_blocks_shrink_the_q_tile_first_under_the_vmem_budget():
+    # float32 tiles of 1024 x 1024 do not fit; the k tile stays wide (the
+    # sweep: a wide k tile is worth more than a tall q tile)
+    assert pa.flash_vmem_bytes(1024, 1024, 128, 4) > pa.VMEM_BUDGET
+    assert flash_blocks(2048, 2048, 128, jnp.float32) == (512, 1024)
+    assert flash_blocks(4096, 4096, 512, jnp.float32) == (256, 1024)
+    assert pa.flash_vmem_bytes(256, 1024, 512, 4) <= pa.VMEM_BUDGET
+
+
+@pytest.mark.parametrize("Sq,Sk", [(100, 128), (128, 192), (64, 64)])
+def test_flash_blocks_refuses_what_128_does_not_divide(Sq, Sk):
+    assert not pa.flash_eligible(Sq, Sk, 128)
+    with pytest.raises(ValueError):
+        flash_blocks(Sq, Sk, 128, jnp.float32)
+
+
+def test_flash_eligible_is_the_contract_of_128():
+    assert pa.flash_eligible(128, 256, 128)
+    assert pa.flash_eligible(384, 384, 256)
+    assert not pa.flash_eligible(256, 256, 64)

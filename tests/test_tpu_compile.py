@@ -60,6 +60,9 @@ def _sum32(*xs):
 
 # attention at the smoke's flagship shape: B8 S2048 H8 D128 bf16
 _QKV = [((8, 2048, 8, 128), jnp.bfloat16)] * 3
+# the benchmark's cell gpt-1.3b-widths.s2048: B2 S2048 H16 D128 bf16. A tile
+# that does not fit VMEM or a block spec the lowering refuses fails here
+_QKV_CELL = [((2, 2048, 16, 128), jnp.bfloat16)] * 3
 # LM loss rows x a real tokenizer's vocab (not a BLOCK_V multiple: the
 # wrapper pads it)
 _XENT = [((16384, 32000), jnp.bfloat16), ((16384,), jnp.int32)]
@@ -74,6 +77,13 @@ CASES = {
         jax.grad(lambda q, k, v: _sum32(
             pa.flash_attention_tpu(q, k, v, True)), (0, 1, 2)),
         _QKV, "hvd_flash_attention"),
+    "flash_fwd_cell": (
+        lambda q, k, v: pa.flash_attention_tpu(q, k, v, True),
+        _QKV_CELL, "hvd_flash_attention"),
+    "flash_fwd_grad_cell": (
+        jax.grad(lambda q, k, v: _sum32(
+            pa.flash_attention_tpu(q, k, v, True)), (0, 1, 2)),
+        _QKV_CELL, "hvd_flash_attention"),
     # the ring-attention step: non-causal, lse differentiated too
     "flash_lse_noncausal_grad": (
         jax.grad(lambda q, k, v: _sum32(*pa.flash_attention_with_lse(
