@@ -65,6 +65,8 @@ import sys
 import time
 
 FLASH_TOL = 2e-2
+# bf16 rows and weights against a float32 "highest" reference, as FLASH_TOL
+GMM_TOL = 2e-2
 XENT_LOSS_ATOL = 2e-3
 XENT_GRAD_TOL = 1e-2
 CODEC_RTOL = 1e-4
@@ -72,6 +74,13 @@ CODE_MISMATCH_MAX = 1e-3
 BERT_MESH_RTOL = 1e-2
 TRAIN_STEPS = 30
 LAYOUT_ATOL = 2e-2
+#: a gradient leaf's relative L2 distance between two layouts, float32 at
+#: matmul precision "highest" (measured on four chips: 1.4e-6). At the
+#: chip's default precision a float32 matmul is one bfloat16 pass, the
+#: layouts round differently, a few of the 256 tokens' near-tied router
+#: choices flip, and the same leaves are 7-11 % apart (PERF.md section 6,
+#: PR 26): that would hide the fault this check is for.
+LAYOUT_GRAD_RTOL = 1e-3
 
 
 class SmokeFailure(Exception):
@@ -92,6 +101,7 @@ class Sizes:
     attn: tuple           # flash check q/k/v [B, S, H, D]
     xent: tuple           # fused xent check [rows, vocab]
     blocks: tuple         # codec check [n_blocks, block]
+    gmm: tuple            # grouped matmul check (rows, in, out, groups)
     gpt: dict             # flagship TransformerConfig fields
     gpt_batch: int
     ring: tuple           # four-chip ring attention [B, S, H, D]
@@ -100,6 +110,7 @@ class Sizes:
 REAL = Sizes(
     bert={}, bert_batch=64, bert_seq=128,
     attn=(8, 2048, 8, 128), xent=(16384, 32000), blocks=(8192, 256),
+    gmm=(16384, 2048, 1024, 64),
     # depth cut to 4 layers: this phase checks kernels in place, not a model
     gpt=dict(vocab_size=32000, d_model=1024, n_heads=8, n_layers=4,
              d_ff=4096, max_seq=2048),
@@ -109,6 +120,7 @@ TINY = Sizes(
               intermediate_size=128, max_position=64),
     bert_batch=8, bert_seq=16,
     attn=(1, 256, 2, 128), xent=(256, 1000), blocks=(64, 128),
+    gmm=(256, 128, 128, 4),
     gpt=dict(vocab_size=1000, d_model=256, n_heads=2, n_layers=2,
              d_ff=256, max_seq=256),
     gpt_batch=2, ring=(1, 512, 2, 128))
@@ -339,11 +351,12 @@ def _weighted_sum(attn, w):
 
 
 def _run_compiled(smoke: Smoke, fn, args, kernel: str):
-    """Compile ``fn`` for the attached device, require the named kernel as
-    a tpu_custom_call in the program (on the chip), and run that program."""
+    """Compile ``fn`` for the attached device, require the named kernel (if
+    one is named) as a tpu_custom_call in the program (on the chip), and run
+    that program."""
     import jax
     compiled = jax.jit(fn).lower(*args).compile()
-    if smoke.on_chip:
+    if smoke.on_chip and kernel:
         check(_has_kernel(compiled, kernel),
               f"{kernel}: no such tpu_custom_call in the compiled program")
     return compiled(*args)
@@ -360,9 +373,9 @@ def _rel_err(got, want) -> float:
 
 def _kernel_line(smoke: Smoke, kernel: str, what: str, err: float,
                  tol: float, **more) -> None:
-    smoke.emit("kernels", kernel=kernel, what=what, err=err, tol=tol,
-               ran="tpu_custom_call" if smoke.on_chip else "interpret",
-               **more)
+    more.setdefault("ran",
+                    "tpu_custom_call" if smoke.on_chip else "interpret")
+    smoke.emit("kernels", kernel=kernel, what=what, err=err, tol=tol, **more)
     check(err <= tol, f"{kernel} {what}: err {err} > tol {tol}")
 
 
@@ -420,6 +433,77 @@ def _check_xent(smoke: Smoke) -> None:
         logits, labels)
     _kernel_line(smoke, "fused_xent", "grad dlogits", _rel_err(got, want),
                  XENT_GRAD_TOL)
+
+
+def _check_gmm(smoke: Smoke) -> None:
+    """The expert layer's grouped matmul (parallel/moe.py), forward and both
+    gradients, on ragged groups with empty ones among them that end a
+    quarter before the rows do, as an ep shard's do: on the megablox
+    kernels named hvd_moe_gmm, and at a 64-wide expert on XLA's ragged_dot,
+    which is where a TPU falls back to. The rows beyond the groups must be
+    zero, forward and in d_rows (the kernel never writes them; what XLA's
+    ragged_dot gives there is printed). The reference is ragged_dot in
+    float32 on the groups' rows alone. Prints which path
+    ``grouped_matmul`` takes at each size and at the OLMoE cell's."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from horovod_tpu.parallel.moe import GMM_NAME, gmm_path, grouped_matmul
+
+    interpret = smoke.rehearsal
+    rows, d_in, d_out, groups = smoke.sizes.gmm
+    keys = jax.random.split(jax.random.PRNGKey(smoke.seed + 2), 3)
+    x = jax.random.normal(keys[0], (rows, d_in), jnp.bfloat16)
+    rng = np.random.default_rng(smoke.seed)
+    share = rng.dirichlet(np.full(groups, 0.5)) * (rng.random(groups) > 0.25)
+    inside = rows * 3 // 4
+    sizes = rng.multinomial(inside, share / share.sum()).astype(np.int32)
+    gs = jnp.asarray(sizes)
+
+    def ours(x, w):
+        return grouped_matmul(x, w, gs, interpret=interpret)
+
+    def reference(x, w):
+        with jax.default_matmul_precision("highest"):
+            out = jax.lax.ragged_dot(x[:inside].astype(jnp.float32), w, gs)
+        return jnp.pad(out, ((0, rows - inside), (0, 0)))
+
+    def beyond(a):
+        return float(jnp.max(jnp.abs(a[inside:].astype(jnp.float32))))
+    olmoe = gmm_path(65536, 2048, 1024)
+    for kernel, width in ((GMM_NAME, d_out), (None, 64)):
+        w = jax.random.normal(keys[1], (groups, d_in, width),
+                              jnp.float32) / np.sqrt(d_in)
+        ct = jax.random.normal(keys[2], (rows, width), jnp.float32)
+        path = gmm_path(rows, d_in, width)
+        if smoke.on_chip:
+            check(olmoe.startswith(f"pallas {GMM_NAME} ") and
+                  path.startswith(f"pallas {GMM_NAME} " if kernel
+                                  else "xla ragged_dot"), path + olmoe)
+        name = "moe_gmm" if kernel else "moe_gmm on xla ragged_dot"
+        ran = {} if kernel else {"ran": "xla"}
+
+        def loss(f):
+            return lambda x, w: jnp.sum(f(x, w).astype(jnp.float32) * ct)
+        got = _run_compiled(smoke, ours, (x, w), kernel)
+        raw = jax.jit(jax.lax.ragged_dot)(x, w.astype(x.dtype), gs)
+        _kernel_line(smoke, name, "fwd",
+                     _rel_err(got, jax.jit(reference)(x, w)), GMM_TOL,
+                     shape=(rows, d_in, width, groups), dtype="bfloat16",
+                     gmm_path=path, gmm_path_at_the_olmoe_cell=olmoe,
+                     rows_in_groups=inside, largest_group=int(sizes.max()),
+                     empty_groups=int((sizes == 0).sum()),
+                     beyond_the_groups=beyond(got),
+                     plain_ragged_dot_beyond_the_groups=beyond(raw), **ran)
+        check(beyond(got) == 0, f"{name}: rows beyond the groups not zero")
+        got = _run_compiled(smoke, jax.grad(loss(ours), (0, 1)), (x, w),
+                            kernel)
+        want = jax.jit(jax.grad(loss(reference), (0, 1)))(x, w)
+        for leaf, g, r in zip(("d_rows", "d_weights"), got, want):
+            _kernel_line(smoke, name, f"grad {leaf}", _rel_err(g, r),
+                         GMM_TOL, **ran)
+        check(beyond(got[0]) == 0,
+              f"{name}: d_rows beyond the groups not zero")
 
 
 def _check_codec(smoke: Smoke) -> None:
@@ -568,6 +652,7 @@ def _check_flagship(smoke: Smoke, hvd) -> None:
 def phase_kernels(smoke: Smoke, hvd) -> None:
     _check_flash(smoke)
     _check_xent(smoke)
+    _check_gmm(smoke)
     _check_codec(smoke)
     _check_flagship(smoke, hvd)
 
@@ -629,14 +714,41 @@ def _four_bert(smoke: Smoke, hvd) -> None:
           f"{losses4} vs {losses1}")
 
 
+def _loss_and_grads(cfg, mesh_kwargs, B, S):
+    """Loss and a few gradient leaves of the flagship on one layout, on
+    __graft_entry__._run_layout's weights and batch. The flagship's
+    gradient sync sums over the data shards where the loss averaged
+    (ROADMAP A16): divided out here, so that layouts compare."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from horovod_tpu.models import (init_params, make_grad_fn, shard_batch,
+                                    shard_params)
+    from horovod_tpu.parallel.mesh import build_mesh
+    mesh = build_mesh(**mesh_kwargs)
+    params = shard_params(
+        init_params(np.random.RandomState(0), cfg, 1), cfg, mesh)
+    tokens = np.random.RandomState(1).randint(0, cfg.vocab_size, (B, S))
+    tokens, targets = shard_batch(
+        jnp.asarray(tokens, jnp.int32),
+        jnp.asarray(np.roll(tokens, -1, 1), jnp.int32), mesh)
+    loss, aux, grads = jax.jit(make_grad_fn(cfg, mesh))(params, tokens,
+                                                        targets)
+    leaves = {k: np.asarray(grads["layers"][k], np.float64) / mesh.size
+              for k in ("router", "we1", "we2", "wq")}
+    return float(loss + aux["aux_loss"]), leaves
+
+
 def _four_layouts(smoke: Smoke) -> None:
     """The two n=4 layouts of __graft_entry__._dryrun_child, on the real
-    devices, first-step loss against one device."""
+    devices, first-step loss against one device; for the MoE, at widths
+    the hvd_moe_gmm kernels take, the gradients too."""
     import jax
+    import numpy as np
     import __graft_entry__ as graft
+    from horovod_tpu.parallel.moe import GMM_NAME, gmm_path
 
     cfg = graft._flagship_cfg(tiny=True)
-    moe_cfg = dataclasses.replace(cfg, n_experts=4, n_microbatches=1)
     B, S = 8, 32
     one = dict(dp=1, devices=jax.devices()[:1])
 
@@ -647,23 +759,33 @@ def _four_layouts(smoke: Smoke) -> None:
                tol=LAYOUT_ATOL)
     check(abs(dense4 - dense1) <= LAYOUT_ATOL, "dense layout loss differs")
 
-    # Expert capacity is per token group, and ep x sp cuts the batch into
-    # four groups, so this layout drops different tokens than one device
-    # does: by design its loss is not the one-device loss. It is compared
-    # with the same layout on a 4-virtual-device CPU mesh; the one-device
-    # loss is printed beside it.
-    moe4 = graft._run_layout(moe_cfg, dict(ep=2, sp=2), 1, B, S)
-    moe_cpu = graft._run_layout(
-        moe_cfg, dict(ep=2, sp=2, devices=jax.devices("cpu")[:4]), 1, B, S)
-    moe1 = graft._run_layout(moe_cfg, one, 1, B, S)
-    smoke.emit("four_chips",
-               what="flagship MoE ep=2 sp=2 vs the same layout on a "
-                    "4-virtual-device CPU mesh (capacity per group makes "
-                    "one device differ by design)",
-               loss_4=moe4, loss_cpu_mesh=moe_cpu, loss_1=moe1,
-               diff=abs(moe4 - moe_cpu), diff_vs_one_device=abs(moe4 - moe1),
-               tol=LAYOUT_ATOL)
-    check(abs(moe4 - moe_cpu) <= LAYOUT_ATOL, "MoE layout loss differs")
+    # the expert layer is dropless, so no capacity per token group stands
+    # between the layouts: ep x sp computes the one-device loss, and its
+    # gradients. 128-wide, so that on the chip the experts run on the
+    # kernels: an ep shard's groups end before its rows do, and what the
+    # kernel leaves unwritten there must reach no gradient.
+    moe_cfg = dataclasses.replace(cfg, n_experts=4, n_microbatches=1,
+                                  d_model=128, d_ff=128)
+    k = moe_cfg.moe_top_k
+    paths = {"ep=2 sp=2": gmm_path(B * S * k // 2, 128, 128),
+             "one device": gmm_path(B * S * k, 128, 128)}
+    if smoke.on_chip:
+        check(all(p.startswith(f"pallas {GMM_NAME} ")
+                  for p in paths.values()), str(paths))
+    with jax.default_matmul_precision("highest"):
+        moe4, grads4 = _loss_and_grads(moe_cfg, dict(ep=2, sp=2), B, S)
+        moe1, grads1 = _loss_and_grads(moe_cfg, one, B, S)
+    grad_err = {
+        name: float(np.linalg.norm(grads4[name] - g)
+                    / np.linalg.norm(g)) for name, g in grads1.items()}
+    smoke.emit("four_chips", what="flagship MoE ep=2 sp=2 vs one device",
+               loss_4=moe4, loss_1=moe1, diff=abs(moe4 - moe1),
+               tol=LAYOUT_ATOL, matmul_precision="highest",
+               gmm_path=paths, grad_rel_l2=grad_err,
+               grad_tol=LAYOUT_GRAD_RTOL)
+    check(abs(moe4 - moe1) <= LAYOUT_ATOL, "MoE layout loss differs")
+    check(all(e <= LAYOUT_GRAD_RTOL for e in grad_err.values()),
+          f"MoE layout gradients differ: {grad_err}")
 
 
 def _four_ring(smoke: Smoke, hvd) -> None:

@@ -54,7 +54,8 @@ def main():
     for i in range(10):
         params, opt_state, loss, aux = step(params, opt_state, tokens,
                                             targets)
-        print(f"step {i}: loss {float(loss):.4f} aux {float(aux):.4f}")
+        print(f"step {i}: loss {float(loss):.4f} "
+              f"aux {float(aux['aux_loss']):.4f}")
     hvd.shutdown()
 
 

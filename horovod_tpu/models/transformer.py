@@ -14,7 +14,8 @@ Layout conventions (local = per-device shapes):
   embedding       [V/tp, M]          (vocab-sharded, tied softmax)
   attention       heads sharded tp → q/k/v [B', S', H/tp, Dh], ring over sp
   mlp             w1 [M, F/tp], w2 [F/tp, M], psum(tp) after w2
-  MoE             experts sharded ep; tokens dispatched via all_to_all
+  MoE             experts sharded ep; dropless sorted dispatch, the ep
+                  group's tokens exchanged by all_gather / psum_scatter
   layers          stacked [pp, L/pp, ...]; GPipe schedule over pp
 Gradient sync: params are replicated over (dp, sp) → psum over those axes
 after ``jax.grad``; tp/ep/pp-sharded leaves keep local (sharded) grads.
@@ -37,7 +38,7 @@ from horovod_tpu._compat import axis_size, shard_map
 
 from horovod_tpu.models.scan_util import multi_step
 from horovod_tpu.parallel.ring_attention import ring_attention_spmd
-from horovod_tpu.parallel.moe import moe_layer_spmd, top_k_gating
+from horovod_tpu.parallel.moe import grouped_matmul, moe_layer_spmd
 from horovod_tpu.profiling import scopes
 
 
@@ -51,7 +52,19 @@ class TransformerConfig:
     max_seq: int = 2048
     n_experts: int = 0          # 0 → dense FFN; >0 → MoE every layer
     moe_top_k: int = 2
-    capacity_factor: float = 1.25
+    # -- what the architecture is (defaults: the GPT block this file began
+    # as; OLMoE sets every one of them) --------------------------------
+    moe_gated: bool = False     # experts down(silu(gate(x)) * up(x)), three
+    #                             matrices of width d_ff; else gelu, two
+    moe_renormalize: bool = False   # top-k weights divided by their sum
+    moe_balance_weight: float = 0.01    # load-balancing loss, over all k
+    moe_z_weight: float = 0.0   # router z-loss mean(logsumexp(logits)^2)
+    qk_norm: bool = False       # RMSNorm over the whole q and k projections,
+    #                             before the head split and rope
+    tie_embeddings: bool = True     # logits from the embedding table; else
+    #                                 an ``lm_head`` [M, V] of its own
+    norm_eps: float = 1e-6
+    rope_theta: float = 10000.0
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     n_microbatches: int = 1     # pipeline microbatches (per pp>1)
@@ -86,22 +99,34 @@ def init_params(rng: np.random.RandomState, cfg: TransformerConfig,
         "wo": w(n_stages, lps, H * Dh, M),
         "ln2": np.ones((n_stages, lps, M), np.float32),
     }
+    if cfg.qk_norm:
+        layer.update({
+            "q_norm": np.ones((n_stages, lps, H * Dh), np.float32),
+            "k_norm": np.ones((n_stages, lps, H * Dh), np.float32),
+        })
     if cfg.n_experts > 0:
+        # we1 is the gate of a gated expert, we3 its up projection, we2
+        # the way back down (the Mixtral numbering)
         layer.update({
             "router": w(n_stages, lps, M, cfg.n_experts, scale=0.02),
             "we1": w(n_stages, lps, cfg.n_experts, M, F),
             "we2": w(n_stages, lps, cfg.n_experts, F, M),
         })
+        if cfg.moe_gated:
+            layer["we3"] = w(n_stages, lps, cfg.n_experts, M, F)
     else:
         layer.update({
             "w1": w(n_stages, lps, M, F),
             "w2": w(n_stages, lps, F, M),
         })
-    return {
+    params = {
         "embed": (rng.randn(cfg.vocab_size, M) * 0.02).astype(np.float32),
         "ln_f": np.ones((M,), np.float32),
         "layers": layer,
     }
+    if not cfg.tie_embeddings:
+        params["lm_head"] = w(M, cfg.vocab_size)
+    return params
 
 
 def param_shardings(cfg: TransformerConfig, mesh: Mesh) -> Dict:
@@ -116,16 +141,23 @@ def param_shardings(cfg: TransformerConfig, mesh: Mesh) -> Dict:
         "wq": s(pp, None, None, tp), "wk": s(pp, None, None, tp),
         "wv": s(pp, None, None, tp), "wo": s(pp, None, tp, None),
     }
+    if cfg.qk_norm:
+        layers.update({"q_norm": s(pp, None, tp), "k_norm": s(pp, None, tp)})
     if cfg.n_experts > 0:
         layers.update({
             "router": s(pp),
             "we1": s(pp, None, ep, None, tp),
             "we2": s(pp, None, ep, tp, None),
         })
+        if cfg.moe_gated:
+            layers["we3"] = s(pp, None, ep, None, tp)
     else:
         layers.update({"w1": s(pp, None, None, tp),
                        "w2": s(pp, None, tp, None)})
-    return {"embed": s(tp), "ln_f": s(), "layers": layers}
+    shardings = {"embed": s(tp), "ln_f": s(), "layers": layers}
+    if not cfg.tie_embeddings:
+        shardings["lm_head"] = s(None, tp)
+    return shardings
 
 
 def shard_params(params: Dict, cfg: TransformerConfig, mesh: Mesh) -> Dict:
@@ -150,17 +182,28 @@ def _psum_if(x, name):
     return lax.psum(x, name) if _axis_live(name) else x
 
 
-def _rmsnorm(x, g):
+def _rmsnorm(x, g, eps=1e-6):
     var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
-    return (x.astype(jnp.float32) * jax.lax.rsqrt(var + 1e-6)
+    return (x.astype(jnp.float32) * jax.lax.rsqrt(var + eps)
             ).astype(x.dtype) * g.astype(x.dtype)
 
 
-def _rope(x, positions):
-    """Rotary embedding; x [B, S, H, D], positions [S] absolute."""
+def _qk_norm(x, g, eps):
+    """RMSNorm over the whole projection width, of which this tp shard
+    holds ``x``'s last dimension."""
+    xf = x.astype(jnp.float32)
+    total = _psum_if(jnp.sum(jnp.square(xf), axis=-1, keepdims=True), "tp")
+    width = x.shape[-1] * (axis_size("tp") if _axis_live("tp") else 1)
+    return (xf * jax.lax.rsqrt(total / width + eps)
+            ).astype(x.dtype) * g.astype(x.dtype)
+
+
+def _rope(x, positions, theta=10000.0):
+    """Rotary embedding, halves layout; x [B, S, H, D], positions [S]
+    absolute."""
     B, S, H, D = x.shape
     half = D // 2
-    freqs = 1.0 / (10000 ** (jnp.arange(half, dtype=jnp.float32) / half))
+    freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
     ang = positions[:, None].astype(jnp.float32) * freqs[None, :]  # [S, half]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     x1, x2 = x[..., :half], x[..., half:]
@@ -220,15 +263,19 @@ def _attention_block(p, x, positions, cfg: TransformerConfig):
     """x: [B', S', M] local. Heads sharded over tp; sequence over sp."""
     B, S, M = x.shape
     with jax.named_scope(scopes.ATTENTION):
-        h = _rmsnorm(x, p["ln1"])
+        h = _rmsnorm(x, p["ln1"], cfg.norm_eps)
         q = (h @ p["wq"].astype(h.dtype))
         k = (h @ p["wk"].astype(h.dtype))
         v = (h @ p["wv"].astype(h.dtype))
+        if cfg.qk_norm:
+            q = _qk_norm(q, p["q_norm"], cfg.norm_eps)
+            k = _qk_norm(k, p["k_norm"], cfg.norm_eps)
         Hl = q.shape[-1] // cfg.head_dim
         q = q.reshape(B, S, Hl, cfg.head_dim)
         k = k.reshape(B, S, Hl, cfg.head_dim)
         v = v.reshape(B, S, Hl, cfg.head_dim)
-        q, k = _rope(q, positions), _rope(k, positions)
+        q = _rope(q, positions, cfg.rope_theta)
+        k = _rope(k, positions, cfg.rope_theta)
         # the core is the call a kernel replaces: its custom_vjp backward
         # (XLA einsums today) is traced under the same scope
         with jax.named_scope(scopes.ATTENTION_CORE):
@@ -251,41 +298,65 @@ def _dense_ffn(p, x):
 
 
 def _moe_ffn(p, x, cfg: TransformerConfig):
-    """x: [B', S', M] local → tokens [G, M]; experts over ep, inner mats tp."""
+    """x: [B', S', M] local → tokens [G, M]; experts over ep, inner mats tp.
+    Returns the layer's output and its auxiliary terms (:func:`_no_aux`'s
+    keys and the layer's four metrics)."""
     B, S, M = x.shape
     toks = x.reshape(B * S, M)
 
-    def expert_fn(ep_params, t):
-        h = jax.nn.gelu(t @ ep_params["w1"].astype(t.dtype))
-        o = h @ ep_params["w2"].astype(t.dtype)
-        return _psum_if(o, "tp")
+    def expert_fn(ep, rows, group_sizes):
+        h = grouped_matmul(rows, ep["we1"], group_sizes)
+        if cfg.moe_gated:
+            h = jax.nn.silu(h) * grouped_matmul(rows, ep["we3"], group_sizes)
+        else:
+            h = jax.nn.gelu(h)
+        return grouped_matmul(h, ep["we2"], group_sizes)
 
-    y, metrics = moe_layer_spmd(
-        toks, p["router"].astype(jnp.float32),
-        expert_fn, {"w1": p["we1"], "w2": p["we2"]},
-        axis_name="ep" if _axis_live("ep") else None,
-        k=cfg.moe_top_k, capacity_factor=cfg.capacity_factor)
-    return y.reshape(B, S, M), metrics
+    with jax.named_scope(scopes.MOE):
+        y, m = moe_layer_spmd(
+            toks, p["router"], expert_fn,
+            {n: p[n] for n in ("we1", "we2", "we3") if n in p},
+            axis_name="ep" if _axis_live("ep") else None,
+            k=cfg.moe_top_k, renormalize=cfg.moe_renormalize,
+            stat_axes=[a for a in ("dp", "ep", "sp") if _axis_live(a)])
+        y = _psum_if(y, "tp")
+    aux = {"aux_loss": (cfg.moe_balance_weight * m.load_balance_loss
+                        + cfg.moe_z_weight * m.router_z_loss),
+           **m._asdict()}
+    return y.reshape(B, S, M), aux
+
+
+def _no_aux():
+    return {"aux_loss": jnp.zeros((), jnp.float32)}
+
+
+def _over_layers(auxs):
+    """One layer's auxiliary terms stacked ``[L]`` → the step's: the
+    losses averaged, the largest load, the dropped assignments summed
+    (the tokens' choices are :func:`router_choices`' to return)."""
+    how = {"max_expert_load": jnp.max, "dropped": jnp.sum}
+    return {k: how.get(k, jnp.mean)(v) for k, v in auxs.items()
+            if k != "experts"}
 
 
 def _block(p, x, positions, cfg: TransformerConfig):
     x = _attention_block(p, x, positions, cfg)
     with jax.named_scope(scopes.MLP):
-        h = _rmsnorm(x, p["ln2"])
+        h = _rmsnorm(x, p["ln2"], cfg.norm_eps)
         if cfg.n_experts > 0:
-            o, metrics = _moe_ffn(p, h, cfg)
-            aux = metrics.aux_loss
+            o, aux = _moe_ffn(p, h, cfg)
         else:
-            o, aux = _dense_ffn(p, h), jnp.zeros((), jnp.float32)
+            o, aux = _dense_ffn(p, h), _no_aux()
         return x + o.astype(x.dtype), aux
 
 
 def _stage_fn_factory(cfg: TransformerConfig, positions):
     """Returns stage_fn(stage_params, act) running L/pp blocks via scan.
 
-    The MoE aux loss rides as one extra feature column of the activation so
-    the pipeline carry stays a single array (pipeline_spmd requirement); it
-    accumulates across stages and is read back after the pipeline.
+    The weighted sum of the MoE's auxiliary losses rides as one extra
+    feature column of the activation so the pipeline carry stays a single
+    array (pipeline_spmd requirement); it accumulates across stages and is
+    read back after the pipeline.
     """
     def one_block(x, lp):
         def fn(xx):
@@ -301,7 +372,7 @@ def _stage_fn_factory(cfg: TransformerConfig, positions):
             y, aux = one_block(x, lp)
             return y, aux
         y, auxs = lax.scan(scan_body, act.astype(cfg.dtype), stage_params)
-        aux_out = aux_in + jnp.sum(auxs) / max(cfg.n_layers, 1)
+        aux_out = aux_in + jnp.sum(auxs["aux_loss"]) / max(cfg.n_layers, 1)
         return jnp.concatenate([y.astype(jnp.float32), aux_out], axis=-1)
 
     return stage_fn
@@ -312,12 +383,16 @@ def _stage_fn_factory(cfg: TransformerConfig, positions):
 # ---------------------------------------------------------------------------
 
 def forward_loss_spmd(params, tokens, targets, cfg: TransformerConfig):
-    """Local shapes: tokens/targets [B', S']. Returns (loss, aux_loss)."""
+    """Local shapes: tokens/targets [B', S']. Returns (loss, aux): ``aux``
+    a dict with ``aux_loss``, the weighted auxiliary losses that training
+    adds to the loss (0 for a dense model), and for an MoE model off the
+    pipeline path its parts and counters: ``load_balance_loss``,
+    ``router_z_loss``, ``max_expert_load``, ``dropped`` (always 0)."""
     S = tokens.shape[1]
     sp_idx = lax.axis_index("sp") if _axis_live("sp") else 0
     positions = sp_idx * S + jnp.arange(S)
 
-    # the table's cast is shared with the tied logits below: XLA keeps one
+    # the table's cast is shared with tied logits below: XLA keeps one
     with jax.named_scope(scopes.EMBED):
         x = _embed_lookup(params["embed"].astype(cfg.dtype),
                           tokens)                               # [B,S,M]
@@ -326,8 +401,12 @@ def forward_loss_spmd(params, tokens, targets, cfg: TransformerConfig):
         x, aux_total = _run_layers(params["layers"], x, positions, cfg)
 
     with jax.named_scope(scopes.HEAD):
-        x = _rmsnorm(x, params["ln_f"])
-        logits_local = x @ params["embed"].astype(cfg.dtype).T  # [B,S,V/tp]
+        x = _rmsnorm(x, params["ln_f"], cfg.norm_eps)
+        if cfg.tie_embeddings:
+            head = params["embed"].astype(cfg.dtype).T
+        else:
+            head = params["lm_head"].astype(cfg.dtype)
+        logits_local = x @ head                                 # [B,S,V/tp]
         nll = _softmax_xent(logits_local, targets)              # [B,S]
         loss = jnp.mean(nll)
         # average over data-like axes so every shard reports the global
@@ -335,13 +414,15 @@ def forward_loss_spmd(params, tokens, targets, cfg: TransformerConfig):
         for ax in ("dp", "ep", "sp"):
             if _axis_live(ax):
                 loss = lax.pmean(loss, ax)
-                aux_total = lax.pmean(aux_total, ax)
+                aux_total = jax.tree_util.tree_map(
+                    lambda a: lax.pmean(a, ax), aux_total)
     return loss, aux_total
 
 
 def _run_layers(lp, x, positions, cfg: TransformerConfig):
     """The stack of blocks: the GPipe schedule over live pp stages, else
-    one scan over all layers. Returns (activations, mean aux loss)."""
+    one scan over all layers. Returns (activations, the step's auxiliary
+    terms)."""
     B = x.shape[0]
     if _axis_live("pp"):
         from horovod_tpu.parallel.pipeline import (pipeline_spmd,
@@ -360,17 +441,34 @@ def _run_layers(lp, x, positions, cfg: TransformerConfig):
         ym = pipeline_spmd(stage_fn, lp, xm, "pp")
         ya = ym.reshape((B,) + ym.shape[2:])
         x = ya[..., :-1].astype(cfg.dtype)
-        aux_total = jnp.mean(ya[..., -1])
+        aux_total = {"aux_loss": jnp.mean(ya[..., -1])}
     else:
         # no pipeline: scan all layers of the single stage
-        def scan_body(carry, layer_p):
-            y, aux = _block(layer_p, carry, positions, cfg)
-            return y, aux
-        flat = jax.tree_util.tree_map(
-            lambda a: a.reshape((-1,) + a.shape[2:]), lp)
-        x, auxs = lax.scan(scan_body, x, flat)
-        aux_total = jnp.sum(auxs) / max(cfg.n_layers, 1)
+        x, auxs = _scan_layers(lp, x, positions, cfg)
+        aux_total = _over_layers(auxs)
     return x, aux_total
+
+
+def _scan_layers(lp, x, positions, cfg: TransformerConfig):
+    """One scan over the blocks of ``lp`` (``[stage, layer, ...]`` leaves).
+    Returns (activations, every layer's auxiliary terms stacked ``[L]``)."""
+    def scan_body(carry, layer_p):
+        y, aux = _block(layer_p, carry, positions, cfg)
+        return y, aux
+    flat = jax.tree_util.tree_map(
+        lambda a: a.reshape((-1,) + a.shape[2:]), lp)
+    return lax.scan(scan_body, x, flat)
+
+
+def router_choices(params, tokens, cfg: TransformerConfig):
+    """The experts the router of each layer chooses for each token,
+    ``[L, B * S, k]``: the model's own blocks, on one device (no mesh). For
+    diagnostics, such as telling what differing choices explain of an error
+    against a reference (benchmarks/chip/tools/olmoe_routing.py)."""
+    x = params["embed"].astype(cfg.dtype)[tokens]
+    _x, auxs = _scan_layers(params["layers"], x,
+                            jnp.arange(tokens.shape[1]), cfg)
+    return auxs["experts"]
 
 
 # ---------------------------------------------------------------------------
@@ -424,7 +522,7 @@ def make_grad_fn(cfg: TransformerConfig, mesh: Mesh):
     def grad_fn(params, tokens, targets):
         def loss_fn(p):
             loss, aux = forward_loss_spmd(p, tokens, targets, cfg)
-            return loss + 0.01 * aux, (loss, aux)
+            return loss + aux["aux_loss"], (loss, aux)
         grads, (loss, aux) = jax.grad(loss_fn, has_aux=True)(params)
         grads = _grad_sync(grads, pspec)
         return loss, aux, grads
@@ -478,7 +576,7 @@ def make_forward(cfg: TransformerConfig, mesh: Mesh):
                        out_specs=P(), check_vma=False)
     def fwd(params, tokens, targets):
         loss, aux = forward_loss_spmd(params, tokens, targets, cfg)
-        return loss + 0.01 * aux
+        return loss + aux["aux_loss"]
 
     return jax.jit(fwd)
 
@@ -535,9 +633,10 @@ def flatten_decode_params(params: Dict) -> Dict:
     ``[L, ...]`` — decode scans all layers on one device; the pipeline
     split is a training-time concern."""
     layers = params["layers"]
-    if "w1" not in layers:
+    if "w1" not in layers or "q_norm" in layers or "lm_head" in params:
         raise NotImplementedError(
-            "paged decode supports dense-FFN transformers (n_experts=0)")
+            "paged decode supports the dense GPT block: n_experts=0, no "
+            "qk_norm, tied embeddings")
     flat = {k: jnp.asarray(v).reshape((-1,) + tuple(np.shape(v)[2:]))
             for k, v in layers.items()}
     return {"embed": jnp.asarray(params["embed"]),
@@ -545,12 +644,12 @@ def flatten_decode_params(params: Dict) -> Dict:
             "layers": flat}
 
 
-def _rope_rows(x, pos):
+def _rope_rows(x, pos, theta=10000.0):
     """Rotary embedding for per-row positions: x [N, H, D], pos [N] —
     the decode-time counterpart of :func:`_rope` (one token per row,
     each at its own absolute position)."""
     half = x.shape[-1] // 2
-    freqs = 1.0 / (10000 ** (jnp.arange(half, dtype=jnp.float32) / half))
+    freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
     ang = pos[:, None].astype(jnp.float32) * freqs[None, :]   # [N, half]
     cos = jnp.cos(ang)[:, None, :].astype(x.dtype)
     sin = jnp.sin(ang)[:, None, :].astype(x.dtype)
@@ -570,11 +669,11 @@ def _paged_layer(lp, x, q_pos, kv_pages, dest_page, offs, gather_rows,
     kp, vp = kv_pages
     H, Dh = cfg.n_heads, cfg.head_dim
     N = x.shape[0]
-    h = _rmsnorm(x, lp["ln1"].astype(jnp.float32))
+    h = _rmsnorm(x, lp["ln1"].astype(jnp.float32), cfg.norm_eps)
     q = _rope_rows((h @ lp["wq"].astype(jnp.float32)).reshape(N, H, Dh),
-                   q_pos)
+                   q_pos, cfg.rope_theta)
     k = _rope_rows((h @ lp["wk"].astype(jnp.float32)).reshape(N, H, Dh),
-                   q_pos)
+                   q_pos, cfg.rope_theta)
     v = (h @ lp["wv"].astype(jnp.float32))
     kp = kp.at[dest_page, offs].set(k.reshape(N, H * Dh))
     vp = vp.at[dest_page, offs].set(v)
@@ -592,7 +691,7 @@ def _paged_layer(lp, x, q_pos, kv_pages, dest_page, offs, gather_rows,
     else:
         o = jnp.einsum("nht,nthd->nhd", probs, v_all)
     x = x + o.reshape(N, H * Dh) @ lp["wo"].astype(jnp.float32)
-    h2 = _rmsnorm(x, lp["ln2"].astype(jnp.float32))
+    h2 = _rmsnorm(x, lp["ln2"].astype(jnp.float32), cfg.norm_eps)
     f = jax.nn.gelu(h2 @ lp["w1"].astype(jnp.float32))
     return x + f @ lp["w2"].astype(jnp.float32), (kp, vp)
 
@@ -630,7 +729,7 @@ def decode_step_paged(params: Dict, k_pages, v_pages, page_table,
 
     x, (k_pages, v_pages) = lax.scan(
         body, x, (params["layers"], k_pages, v_pages))
-    x = _rmsnorm(x, params["ln_f"].astype(jnp.float32))
+    x = _rmsnorm(x, params["ln_f"].astype(jnp.float32), cfg.norm_eps)
     logits = x @ emb.T                                     # [S, V]
     return jnp.argmax(logits, axis=-1).astype(jnp.int32), k_pages, v_pages
 
@@ -667,7 +766,7 @@ def prefill_chunk_paged(params: Dict, k_pages, v_pages, page_row,
 
     x, (k_pages, v_pages) = lax.scan(
         body, x, (params["layers"], k_pages, v_pages))
-    x = _rmsnorm(x, params["ln_f"].astype(jnp.float32))
+    x = _rmsnorm(x, params["ln_f"].astype(jnp.float32), cfg.norm_eps)
     x_last = x[jnp.clip(valid - 1, 0, C - 1)]
     logits = x_last @ emb.T                                # [V]
     return jnp.argmax(logits).astype(jnp.int32), k_pages, v_pages
@@ -691,11 +790,11 @@ def reference_greedy_decode(params: Dict, cfg: TransformerConfig,
         pos = jnp.arange(Tn, dtype=jnp.int32)
         for li in range(L):
             lp = {k: v[li] for k, v in flat["layers"].items()}
-            h = _rmsnorm(x, lp["ln1"].astype(jnp.float32))
+            h = _rmsnorm(x, lp["ln1"].astype(jnp.float32), cfg.norm_eps)
             q = _rope_rows((h @ lp["wq"].astype(jnp.float32))
-                           .reshape(Tn, H, Dh), pos)
+                           .reshape(Tn, H, Dh), pos, cfg.rope_theta)
             k = _rope_rows((h @ lp["wk"].astype(jnp.float32))
-                           .reshape(Tn, H, Dh), pos)
+                           .reshape(Tn, H, Dh), pos, cfg.rope_theta)
             v = (h @ lp["wv"].astype(jnp.float32)).reshape(Tn, H, Dh)
             scores = jnp.einsum("qhd,khd->hqk", q, k) / np.sqrt(Dh)
             mask = pos[None, :] <= pos[:, None]
@@ -703,10 +802,10 @@ def reference_greedy_decode(params: Dict, cfg: TransformerConfig,
             probs = jax.nn.softmax(scores, axis=-1)
             o = jnp.einsum("hqk,khd->qhd", probs, v).reshape(Tn, H * Dh)
             x = x + o @ lp["wo"].astype(jnp.float32)
-            h2 = _rmsnorm(x, lp["ln2"].astype(jnp.float32))
+            h2 = _rmsnorm(x, lp["ln2"].astype(jnp.float32), cfg.norm_eps)
             f = jax.nn.gelu(h2 @ lp["w1"].astype(jnp.float32))
             x = x + f @ lp["w2"].astype(jnp.float32)
-        x = _rmsnorm(x, flat["ln_f"].astype(jnp.float32))
+        x = _rmsnorm(x, flat["ln_f"].astype(jnp.float32), cfg.norm_eps)
         nxt = int(jnp.argmax(x[-1] @ emb.T))
         out.append(nxt)
         toks.append(nxt)

@@ -2,23 +2,35 @@
 
 The reference's users build MoE from ``alltoall`` + process sets (SURVEY.md
 §2.6: EP "absent as a strategy; alltoall + process sets are the primitives").
-Here the full strategy ships: GShard/Switch-style capacity-based dense
-dispatch (MXU-friendly einsums, static shapes — no dynamic gather inside
-jit) with ``lax.all_to_all`` token exchange across expert shards.
+Here the strategy ships, **dropless**: every (token, choice) assignment is
+computed, whatever the imbalance, by sorting the assignments by expert and
+running one grouped matmul per expert matrix over the ragged groups
+(MegaBlocks, arXiv:2211.15841; how OLMoE is trained, arXiv:2409.02060).
+All shapes are static: ``G * k`` rows, ``E`` group sizes that sum to it.
 
-Dataflow per ep-shard (G local tokens, E global experts, C capacity):
-  gates = softmax(router(x))                      [G, E]
-  dispatch/combine one-hots via top-k + cumsum    [G, E, C]
-  xs = einsum(gm,gec->ecm)(x, dispatch)           [E, C, M]
-  xs = all_to_all(ep)                             [E/ep, ep*C, M]
-  ys = expert_ffn(xs)  (local experts only)
-  ys = all_to_all back; y = einsum(ecm,gec->gm)(ys, combine)
+Dataflow per shard (G local tokens, E experts, k choices a token):
+  probs    = softmax(x @ router_w)            float32            [G, E]
+  weights, experts = top_k(probs, k)                             [G, k]
+  order    = stable argsort of the G*k assignments by expert
+  rows     = x[order // k]                                       [G*k, M]
+  rows     = expert_fn(expert_params, rows, group_sizes)   grouped matmuls
+             (on a TPU the megablox Pallas kernels, named ``hvd_moe_gmm``)
+  y        = sum_k weights * rows[inverse(order)]                [G, M]
+Both row movements are gathers in both directions (the permutation one
+way, its inverse the other), so no scatter-add is traced.
+
+With ``ep`` > 1 the experts are sharded and the tokens of the ep group are
+exchanged the simplest static-shape way: every shard gathers the group's
+tokens and routing (``all_gather``), computes the rows of its own experts
+(sorted to the front; :func:`grouped_matmul` gives zeros for the rows behind
+them, which are the other shards' to compute) and the weighted partial sums
+return to their home shards by ``psum_scatter``.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Callable, NamedTuple, Optional, Tuple
+from typing import Callable, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -26,121 +38,304 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from horovod_tpu._compat import axis_size, shard_map
+from horovod_tpu.profiling import scopes
 
 
 class MoEMetrics(NamedTuple):
-    aux_loss: jax.Array       # load-balancing loss (Switch aux loss)
-    fraction_dropped: jax.Array
+    #: ``E * sum_e f_e * P_e``: f_e the share of tokens that chose expert e
+    #: among their k (sums to k), P_e the mean router probability
+    load_balance_loss: jax.Array
+    #: ``mean(logsumexp(logits) ** 2)``
+    router_z_loss: jax.Array
+    #: largest group over the mean group
+    max_expert_load: jax.Array
+    #: assignments no grouped matmul row was computed for: always 0
+    dropped: jax.Array
+    #: the k experts each of the shard's tokens chose, ``[G, k]`` (the others
+    #: are scalars of the global batch)
+    experts: jax.Array
 
 
-def top_k_gating(logits: jax.Array, k: int, capacity: int
-                 ) -> Tuple[jax.Array, jax.Array, MoEMetrics]:
-    """Compute dense dispatch/combine tensors.
+#: megablox tile (rows, contraction, columns) of the grouped matmul and of
+#: its two gradients; the sweep behind it is in PERF.md section 6 (PR 26)
+GMM_TILE = (512, 1024, 1024)
+#: the name of the three Pallas calls on the device: ``hvd_moe_gmm`` forward,
+#: ``transpose_jvp_hvd_moe_gmm`` the input's and the weights' gradients
+GMM_NAME = "hvd_moe_gmm"
 
-    logits: [G, E]. Returns dispatch [G, E, C] (0/1), combine [G, E, C]
-    (gate weights), metrics.
-    """
-    G, E = logits.shape
-    gates = jax.nn.softmax(logits, axis=-1)          # [G, E]
 
-    # Switch aux loss: E * sum_e (mean_g gates_e * mean_g route_e)
-    top1 = jnp.argmax(gates, axis=-1)
-    density = jnp.mean(jax.nn.one_hot(top1, E), axis=0)
-    density_proxy = jnp.mean(gates, axis=0)
-    aux = E * jnp.sum(density * density_proxy)
+def _gmm_tile(n_rows: int, k: int, f: int):
+    """The tile the three calls share, or None where the kernels do not
+    apply. The input gradient swaps the roles of the last two entries, and
+    the TPU lowering wants a block's last dimension a multiple of 128 that
+    divides the array's: so one value for both, dividing ``k`` and ``f``."""
+    tm = next((t for t in (GMM_TILE[0], 256, 128) if n_rows % t == 0), None)
+    tkn = next((t for t in (GMM_TILE[1], 512, 256, 128)
+                if k % t == 0 and f % t == 0), None)
+    return (tm, tkn, tkn) if tm and tkn else None
 
-    dispatch = jnp.zeros((G, E, capacity), jnp.float32)
-    combine = jnp.zeros((G, E, capacity), jnp.float32)
-    # Track per-expert fill across the k choices so slots are not reused.
-    fill = jnp.zeros((E,), jnp.int32)
-    masked_gates = gates
-    dropped = jnp.zeros((), jnp.float32)
-    for _ in range(k):
-        choice = jnp.argmax(masked_gates, axis=-1)               # [G]
-        onehot = jax.nn.one_hot(choice, E, dtype=jnp.int32)      # [G, E]
-        pos_in_expert = (jnp.cumsum(onehot, axis=0) - onehot) \
-            + fill[None, :]                                      # [G, E]
-        pos = jnp.sum(pos_in_expert * onehot, axis=-1)           # [G]
-        keep = pos < capacity
-        gate_val = jnp.take_along_axis(
-            gates, choice[:, None], axis=-1)[:, 0]               # [G]
-        disp = (jax.nn.one_hot(choice, E)[:, :, None]
-                * jax.nn.one_hot(jnp.clip(pos, 0, capacity - 1),
-                                 capacity)[:, None, :]
-                * keep[:, None, None])
-        dispatch = dispatch + disp
-        combine = combine + disp * gate_val[:, None, None]
-        dropped = dropped + jnp.sum(1.0 - keep) / (G * k)
-        fill = fill + jnp.sum(onehot * keep[:, None], axis=0)
-        masked_gates = masked_gates * (1.0 - jax.nn.one_hot(choice, E))
-    return dispatch, combine, MoEMetrics(aux, dropped)
+
+def gmm_path(n_rows: int, k: int, f: int) -> str:
+    """Which implementation :func:`grouped_matmul` takes on the default
+    backend for rows ``[n_rows, k]`` and weights ``[E, k, f]``, and why
+    (``chip_smoke.py`` prints it, as it does ``attend``'s choice)."""
+    tile = _gmm_tile(n_rows, k, f)
+    if tile is None:
+        return (f"xla ragged_dot (no 128-multiple tile divides rows "
+                f"{n_rows} and widths {k}, {f})")
+    if jax.default_backend() != "tpu":
+        return f"xla ragged_dot (backend {jax.default_backend()})"
+    return f"pallas {GMM_NAME} tile {tile[0]}x{tile[1]}x{tile[2]}"
+
+
+def _megablox():
+    """megablox's two kernels without their own ``jit``, whose name would
+    else stand in the instruction's name in place of :data:`GMM_NAME`."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
+    return [getattr(f, "__wrapped__", f) for f in (gmm, tgmm)]
+
+
+def _zero_beyond(rows, group_sizes):
+    """Zeros in the rows beyond the groups. Neither implementation gives
+    them by itself: megablox's ``gmm`` never writes those rows, and XLA's
+    ``ragged_dot`` fills them with zeros on the CPU and with products on a
+    TPU (``chip_smoke.py`` reads both; PERF.md section 6, PR 26)."""
+    inside = jnp.arange(rows.shape[0]) < jnp.sum(group_sizes)
+    return jnp.where(inside[:, None], rows, 0)
+
+
+def _ragged_dot(rows, weights, group_sizes):
+    return lax.ragged_dot(rows, weights.astype(rows.dtype), group_sizes,
+                          preferred_element_type=jnp.float32
+                          ).astype(rows.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _gmm(rows, weights, group_sizes, tile, interpret):
+    """``tile`` None: XLA's ``ragged_dot``; else the megablox kernels."""
+    if tile is None:
+        out = _ragged_dot(rows, weights, group_sizes)
+    else:
+        gmm, _tgmm = _megablox()
+        with jax.named_scope(GMM_NAME):
+            out = gmm(rows, weights.astype(rows.dtype), group_sizes,
+                      rows.dtype, tile, interpret=interpret)
+    return _zero_beyond(out, group_sizes)
+
+
+def _gmm_fwd(rows, weights, group_sizes, tile, interpret):
+    return (_gmm(rows, weights, group_sizes, tile, interpret),
+            (rows, weights, group_sizes))
+
+
+def _gmm_bwd(tile, interpret, res, g):
+    rows, weights, group_sizes = res
+    if tile is None:
+        # what XLA does with a row beyond the groups is its own: no such
+        # row's cotangent may reach a weight gradient
+        d_rows, d_weights = jax.vjp(
+            lambda r, w: _ragged_dot(r, w, group_sizes), rows, weights)[1](
+                _zero_beyond(g, group_sizes))
+    else:
+        gmm, tgmm = _megablox()     # both visit the groups' rows only
+        with jax.named_scope(GMM_NAME):
+            d_rows = gmm(g, weights.astype(rows.dtype), group_sizes,
+                         rows.dtype, tile, transpose_rhs=True,
+                         interpret=interpret)
+            d_weights = tgmm(rows.swapaxes(0, 1), g, group_sizes,
+                             rows.dtype, tile, interpret=interpret)
+    return (_zero_beyond(d_rows, group_sizes),
+            d_weights.astype(weights.dtype), None)
+
+
+_gmm.defvjp(_gmm_fwd, _gmm_bwd)
+
+
+def grouped_matmul(rows: jax.Array, weights: jax.Array,
+                   group_sizes: jax.Array, interpret: bool = False
+                   ) -> jax.Array:
+    """``rows[start_g:end_g] @ weights[g]`` for each group g of consecutive
+    rows. rows ``[N, K]``, weights ``[E, K, F]`` (cast to ``rows.dtype``),
+    group_sizes ``[E]`` int32; float32 accumulation, the result in
+    ``rows.dtype``. Rows beyond ``sum(group_sizes)`` belong to no group:
+    the result is zero there, so is their gradient, and they add nothing
+    to the weights' gradient.
+
+    On a TPU (and under ``interpret``) the megablox Pallas kernels of the
+    installed JAX at :data:`GMM_TILE`, kept over XLA's ``ragged_dot``
+    kernels by the sweep in PERF.md; elsewhere, or where no row tile divides
+    ``N`` or no 128-lane tile both widths, ``jax.lax.ragged_dot``, the same
+    function as plain XLA (the pattern of ``pallas_attention.attend``;
+    :func:`gmm_path` says which and why)."""
+    on_kernels = interpret or jax.default_backend() == "tpu"
+    tile = _gmm_tile(rows.shape[0], *weights.shape[1:]) if on_kernels else None
+    return _gmm(rows, weights, group_sizes.astype(jnp.int32), tile,
+                interpret)
+
+
+def route(logits: jax.Array, k: int, renormalize: bool
+          ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Float32 softmax over the experts, then top-k. Returns the router
+    probabilities ``[G, E]``, the k weights (divided by their sum when
+    ``renormalize``) and the k expert indices ``[G, k]``."""
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    weights, experts = lax.top_k(probs, k)
+    if renormalize:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return probs, weights, experts
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _dispatch(x, order, inverse, k):
+    """Row r of the result is the token of sorted assignment r."""
+    del inverse
+    return x[order // k]
+
+
+def _dispatch_fwd(x, order, inverse, k):
+    return x[order // k], (inverse, x.shape[0])
+
+
+def _dispatch_bwd(k, res, g):
+    inverse, n_tokens = res
+    return (g[inverse].reshape(n_tokens, k, -1).astype(jnp.float32).sum(axis=1)
+            .astype(g.dtype),
+            None, None)
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _permute(rows, index, back):
+    """``rows[index]`` where ``back`` is the inverse permutation of
+    ``index``: the cotangent is a gather too."""
+    del back
+    return rows[index]
+
+
+def _permute_fwd(rows, index, back):
+    return rows[index], back
+
+
+def _permute_bwd(back, g):
+    return g[back], None, None
+
+
+_permute.defvjp(_permute_fwd, _permute_bwd)
+
+
+def _weighted_sum(rows, weights):
+    """``sum_k weights[t, k] * rows[t, k]`` in float32: rows ``[T, k, M]``
+    in the compute dtype, weights ``[T, k]`` float32."""
+    return jnp.sum(rows.astype(jnp.float32) * weights[..., None], axis=1)
 
 
 def moe_layer_spmd(x: jax.Array, router_w: jax.Array,
-                   expert_fn: Callable[[jax.Array, jax.Array], jax.Array],
-                   expert_params, axis_name: str = "ep", k: int = 2,
-                   capacity_factor: float = 1.25
+                   expert_fn: Callable[..., jax.Array], expert_params,
+                   axis_name: Optional[str] = "ep", k: int = 2,
+                   renormalize: bool = False,
+                   stat_axes: Sequence[str] = ()
                    ) -> Tuple[jax.Array, MoEMetrics]:
     """SPMD MoE (inside shard_map). Local shapes:
 
     x: [G, M] local tokens; router_w: [M, E] (replicated); expert_params:
     pytree with leading dim E_local = E/ep (this shard's experts).
-    expert_fn(params_e, tokens [N, M]) -> [N, M], vmapped over local experts.
-    """
+    ``expert_fn(expert_params, rows [N, M], group_sizes [E_local]) ->
+    [N, M]``: rows sorted by local expert, group g the next
+    ``group_sizes[g]`` of them; the rows beyond the groups (with ``ep`` > 1,
+    the other shards' to compute) must come back zero, as
+    :func:`grouped_matmul` leaves them. ``stat_axes``: the mesh axes the
+    tokens are sharded over, so that the metrics are those of the global
+    batch and the same on every layout."""
     n = axis_size(axis_name) if axis_name else 1
     G, M = x.shape
     E = router_w.shape[1]
-    if E % max(n, 1) != 0:
+    if E % n != 0:
         raise ValueError(f"ep axis size ({n}) must divide n_experts ({E})")
-    capacity = max(1, int(capacity_factor * k * G / E))
+    e_local = E // n
 
-    logits = x @ router_w                                  # [G, E]
-    dispatch, combine, metrics = top_k_gating(logits, k, capacity)
+    with jax.named_scope(scopes.MOE_ROUTER):
+        logits = x.astype(jnp.float32) @ router_w.astype(jnp.float32)
+        probs, weights, experts = route(logits, k, renormalize)
 
-    xs = jnp.einsum("gm,gec->ecm", x.astype(jnp.float32),
-                    dispatch).astype(x.dtype)              # [E, C, M]
-    if n > 1:
-        # split expert dim across shards; gather the source dim into rows:
-        # [E, C, M] -> [E/ep, ep*C, M]
-        xs = lax.all_to_all(xs, axis_name, split_axis=0, concat_axis=1,
-                            tiled=True)
-    ys = jax.vmap(expert_fn)(expert_params, xs)            # [E/ep, n*C, M]
-    if n > 1:
-        ys = lax.all_to_all(ys, axis_name, split_axis=1, concat_axis=0,
-                            tiled=True)                    # [E, C, M]
-    y = jnp.einsum("ecm,gec->gm", ys.astype(jnp.float32),
-                   combine).astype(x.dtype)                # [G, M]
+    with jax.named_scope(scopes.MOE_DISPATCH):
+        if n > 1:
+            # the ep group's tokens, and which experts each chose
+            x_all = lax.all_gather(x, axis_name, axis=0, tiled=True)
+            experts_all = lax.all_gather(experts, axis_name, axis=0,
+                                         tiled=True)
+            first = lax.axis_index(axis_name) * e_local
+        else:
+            x_all, experts_all, first = x, experts, 0
+        # this shard's experts sort to the front, the others behind them
+        local = (experts_all.reshape(-1) - first) % E
+        order = jnp.argsort(local, stable=True)
+        inverse = jnp.argsort(order)
+        group_sizes = jnp.sum(
+            local[:, None] == jnp.arange(e_local, dtype=local.dtype),
+            axis=0, dtype=jnp.int32)
+        rows = _dispatch(x_all, order, inverse, k)
+
+    with jax.named_scope(scopes.MOE_EXPERTS):
+        rows = expert_fn(expert_params, rows, group_sizes)
+
+    with jax.named_scope(scopes.MOE_COMBINE):
+        rows = _permute(rows, inverse, order).reshape(n * G, k, M)
+        if n > 1:
+            weights_all = lax.all_gather(weights, axis_name, axis=0,
+                                         tiled=True)
+        else:
+            weights_all = weights
+        y = _weighted_sum(rows, weights_all)
+        if n > 1:
+            # each home shard sums what the expert shards computed for it
+            y = lax.psum_scatter(y, axis_name, scatter_dimension=0,
+                                 tiled=True)
+        y = y.astype(x.dtype)
+
+    with jax.named_scope(scopes.MOE_ROUTER):
+        def total(v):
+            return lax.psum(v, tuple(stat_axes)) if stat_axes else v
+        tokens = total(jnp.float32(G))
+        counts = total(jnp.sum(
+            experts.reshape(-1)[:, None] == jnp.arange(E), axis=0,
+            dtype=jnp.float32))
+        mean_probs = total(jnp.sum(probs, axis=0)) / tokens
+        z = jax.nn.logsumexp(logits, axis=-1)
+        computed = jnp.sum(group_sizes).astype(jnp.float32)
+        if n > 1:
+            # over ep each assignment of the group is computed once
+            computed = lax.psum(computed, axis_name) / n
+        metrics = MoEMetrics(
+            load_balance_loss=E * jnp.sum(counts / tokens * mean_probs),
+            router_z_loss=total(jnp.sum(jnp.square(z))) / tokens,
+            max_expert_load=jnp.max(counts) * E / (tokens * k),
+            dropped=total(G * k - computed), experts=experts)
     return y, metrics
 
 
 def moe_layer(x: jax.Array, router_w: jax.Array, expert_fn: Callable,
               expert_params, mesh: Mesh, axis_name: str = "ep",
-              k: int = 2, capacity_factor: float = 1.25,
+              k: int = 2, renormalize: bool = False,
               token_axes: Tuple[Optional[str], ...] = ("dp",)
               ) -> Tuple[jax.Array, MoEMetrics]:
     """Array-level MoE: x ``[T, M]`` tokens sharded over ``token_axes``;
-    expert_params leading dim E sharded over ``axis_name``."""
+    expert_params leading dim E sharded over ``axis_name``; ``expert_fn``
+    as :func:`moe_layer_spmd` takes it."""
     from horovod_tpu.parallel.mesh import mesh_axis_size
     n = mesh_axis_size(mesh, axis_name)
-    tok_ax = tuple(a for a in token_axes if mesh_axis_size(mesh, a) > 1) \
-        or None
-    tok_spec = P(tok_ax)
+    tok_ax = tuple(a for a in token_axes if mesh_axis_size(mesh, a) > 1)
+    tok_spec = P(tok_ax or None)
     ep_ax = axis_name if n > 1 else None
-    # metrics must be averaged over every axis the computation varies on —
-    # the token shards AND the ep shards — to honor the replicated out_spec
-    metric_axes = tuple(tok_ax or ()) + ((axis_name,) if n > 1 else ())
 
     @functools.partial(
         shard_map, mesh=mesh,
         in_specs=(tok_spec, P(), P(ep_ax)),
-        out_specs=(tok_spec, P()), check_vma=False)
+        out_specs=(tok_spec, MoEMetrics(P(), P(), P(), P(), tok_spec)),
+        check_vma=False)
     def run(xl, rw, ep_params):
-        y, met = moe_layer_spmd(xl, rw, expert_fn, ep_params,
-                                axis_name if n > 1 else None,
-                                k, capacity_factor)
-        if metric_axes:
-            met = MoEMetrics(lax.pmean(met.aux_loss, metric_axes),
-                             lax.pmean(met.fraction_dropped, metric_axes))
-        return y, met
+        return moe_layer_spmd(xl, rw, expert_fn, ep_params, ep_ax, k,
+                              renormalize, stat_axes=tok_ax)
 
     return run(x, router_w, expert_params)

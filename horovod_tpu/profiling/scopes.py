@@ -19,7 +19,7 @@ direction from them.
 
 Host spans go through :func:`horovod_tpu.profiling.annotate`.
 
-A model that needs another phase (``hvd.moe``, ...) adds it here, and
+A model that needs another phase adds it here, and
 nowhere else: ``tests/test_scopes.py`` holds the strings to this file.
 The Pallas kernels' names (``hvd_flash_attention``, ``hvd_fused_xent``)
 are instruction names, not scopes, and stay where the kernels are.
@@ -38,6 +38,16 @@ ATTENTION = "hvd.attention"
 #: nested in ATTENTION: scores, softmax, weighted sum — what a kernel replaces
 ATTENTION_CORE = "hvd.attention.core"
 MLP = "hvd.mlp"
+#: nested in MLP: the expert layer of an MoE block, and its four parts.
+#: Router: logits, softmax, top-k, the auxiliary losses and counters.
+#: Dispatch: the sort by expert and the row gather (with ``ep`` > 1 the
+#: exchange of tokens). Experts: the grouped matmuls and the activation
+#: between them. Combine: rows back to their tokens, the weighted sum.
+MOE = "hvd.moe"
+MOE_ROUTER = "hvd.moe.router"
+MOE_DISPATCH = "hvd.moe.dispatch"
+MOE_EXPERTS = "hvd.moe.experts"
+MOE_COMBINE = "hvd.moe.combine"
 #: final norm or transform, logits, loss
 HEAD = "hvd.head"
 GRAD_SYNC = "hvd.grad_sync"
@@ -45,7 +55,9 @@ OPTIMIZER = "hvd.optimizer"
 
 #: phases of the model proper: each appears forward and backward
 MODEL_PHASES = (EMBED, LAYERS, ATTENTION, ATTENTION_CORE, MLP, HEAD)
-DEVICE_PHASES = MODEL_PHASES + (GRAD_SYNC, OPTIMIZER)
+#: phases only an MoE model has, each forward and backward
+MOE_PHASES = (MOE, MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS, MOE_COMBINE)
+DEVICE_PHASES = MODEL_PHASES + MOE_PHASES + (GRAD_SYNC, OPTIMIZER)
 
 # -- host spans (profiling.annotate) ------------------------------------------
 #: the input iterator's ``next()``: the host makes the batch

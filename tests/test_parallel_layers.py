@@ -11,7 +11,7 @@ from horovod_tpu.parallel import build_mesh
 from horovod_tpu.parallel.ring_attention import (ring_attention,
                                                  _plain_attention)
 from horovod_tpu.parallel.ulysses import ulysses_attention
-from horovod_tpu.parallel.moe import moe_layer, top_k_gating
+from horovod_tpu.parallel.moe import grouped_matmul, moe_layer
 from horovod_tpu.parallel.pipeline import (pipeline_apply, stage_stacked)
 
 
@@ -91,20 +91,10 @@ def test_ulysses_matches_full(causal):
                                rtol=2e-5, atol=2e-5)
 
 
-def test_top_k_gating_shapes_and_capacity():
-    rng = np.random.RandomState(0)
-    logits = jnp.asarray(rng.randn(16, 4), jnp.float32)
-    dispatch, combine, metrics = top_k_gating(logits, k=2, capacity=8)
-    d = np.asarray(dispatch)
-    assert d.shape == (16, 4, 8)
-    # each token dispatched at most k times, each slot at most one token
-    assert d.sum() <= 16 * 2
-    assert np.all(d.sum(axis=0) <= 1.0 + 1e-6)
-    assert float(metrics.aux_loss) > 0
-
-
-def _ffn_expert(p, x):
-    return jnp.tanh(x @ p["w1"]) @ p["w2"]
+def _ffn_experts(p, rows, group_sizes):
+    """moe_layer's expert function: sorted rows, one group an expert."""
+    h = jnp.tanh(grouped_matmul(rows, p["w1"], group_sizes))
+    return grouped_matmul(h, p["w2"], group_sizes)
 
 
 def _expert_params(E, M, Hdim, seed=1):
@@ -113,33 +103,153 @@ def _expert_params(E, M, Hdim, seed=1):
             "w2": jnp.asarray(rng.randn(E, Hdim, M), jnp.float32) * 0.1}
 
 
-def test_moe_ep_matches_single_device():
-    E, M, Hd, T = 4, 8, 16, 64
-    rng = np.random.RandomState(2)
-    x = jnp.asarray(rng.randn(T, M), jnp.float32)
-    rw = jnp.asarray(rng.randn(M, E), jnp.float32) * 0.1
-    ep_params = _expert_params(E, M, Hd)
+def _moe_oracle(x, rw, p, k, renormalize=False):
+    """Every expert on every token, masked by the float32 top-k choice."""
+    probs = jax.nn.softmax(x @ rw, axis=-1)
+    top, idx = jax.lax.top_k(probs, k)
+    if renormalize:
+        top = top / top.sum(-1, keepdims=True)
+    combine = jnp.zeros_like(probs).at[
+        jnp.arange(x.shape[0])[:, None], idx].set(top)
+    hidden = jnp.tanh(jnp.einsum("tm,emh->eth", x, p["w1"]))
+    every = jnp.einsum("eth,ehm->tem", hidden, p["w2"])
+    return jnp.einsum("te,tem->tm", combine, every)
 
-    mesh1 = build_mesh(dp=8)   # no expert sharding
-    y1, m1 = moe_layer(x, rw, _ffn_expert, ep_params, mesh1, token_axes=())
-    mesh2 = build_mesh(dp=2, ep=4)  # 4-way expert parallel
-    y2, m2 = moe_layer(x, rw, _ffn_expert, ep_params, mesh2, token_axes=())
-    np.testing.assert_allclose(np.asarray(y1), np.asarray(y2),
+
+def _moe_inputs(seed, E=4, M=8, Hd=16, T=64):
+    rng = np.random.RandomState(seed)
+    return (jnp.asarray(rng.randn(T, M), jnp.float32),
+            jnp.asarray(rng.randn(M, E), jnp.float32) * 0.5,
+            _expert_params(E, M, Hd))
+
+
+@pytest.mark.parametrize("interpret", [False, True])
+@pytest.mark.parametrize("sizes", [[100, 0, 30, 0, 126], [0, 0, 256, 0, 0],
+                                   [50, 50, 50, 50, 56], [60, 0, 40, 0, 28],
+                                   [0, 0, 0, 0, 0]])
+def test_grouped_matmul_matches_a_per_expert_loop(sizes, interpret):
+    """Ragged groups with empty ones among them, forward and both gradients;
+    ``interpret`` runs the TPU path's Pallas kernels on the CPU, where a row
+    the kernel did not write reads NaN. Rows beyond the groups (the last two
+    cases: with ep > 1 they are another shard's) are zero, and so is their
+    gradient, on both paths."""
+    from horovod_tpu.parallel.moe import _gmm_tile
+    assert _gmm_tile(256, 128, 256) == (256, 128, 128)   # the kernels apply
+    assert _gmm_tile(256, 64, 256) is None               # 64 lanes: XLA
+    rng = np.random.RandomState(0)
+    x = jnp.asarray(rng.randn(256, 128), jnp.float32)
+    w = jnp.asarray(rng.randn(5, 128, 256), jnp.float32)
+    ct = jnp.asarray(rng.randn(256, 256), jnp.float32)
+    gs = jnp.asarray(sizes, jnp.int32)
+
+    def loop(x, w):
+        out, a = [], 0
+        for e, n in enumerate(sizes):
+            out.append(x[a:a + n] @ w[e])
+            a += n
+        out.append(jnp.zeros((x.shape[0] - a, w.shape[2]), x.dtype))
+        return jnp.concatenate(out)
+
+    def ours(x, w):
+        return grouped_matmul(x, w, gs, interpret=interpret)
+    np.testing.assert_allclose(ours(x, w), loop(x, w), rtol=1e-5, atol=1e-4)
+    got, want = jax.vjp(ours, x, w)[1](ct), jax.vjp(loop, x, w)[1](ct)
+    for g, r, name in zip(got, want, ("d_rows", "d_weights")):
+        np.testing.assert_allclose(g, r, rtol=1e-5, atol=1e-4, err_msg=name)
+
+
+@pytest.mark.parametrize("renormalize", [False, True])
+def test_moe_layer_matches_the_dense_oracle(renormalize):
+    x, rw, p = _moe_inputs(2)
+    mesh = build_mesh(dp=8)
+    y, m = jax.jit(lambda x, rw, p: moe_layer(
+        x, rw, _ffn_experts, p, mesh, k=2, renormalize=renormalize,
+        token_axes=()))(x, rw, p)
+    np.testing.assert_allclose(y, _moe_oracle(x, rw, p, 2, renormalize),
                                rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(float(m1.aux_loss), float(m2.aux_loss),
-                               rtol=1e-5)
+    assert float(m.dropped) == 0.0
+    assert float(m.load_balance_loss) > 0 and float(m.router_z_loss) > 0
 
 
-def test_moe_with_token_sharding():
-    E, M, Hd, T = 4, 8, 16, 64
-    rng = np.random.RandomState(3)
-    x = jnp.asarray(rng.randn(T, M), jnp.float32)
-    rw = jnp.asarray(rng.randn(M, E), jnp.float32) * 0.1
-    ep_params = _expert_params(E, M, Hd)
-    mesh = build_mesh(dp=2, ep=4)
-    y, m = moe_layer(x, rw, _ffn_expert, ep_params, mesh, token_axes=("dp",))
-    assert y.shape == (T, M)
-    assert np.all(np.isfinite(np.asarray(y)))
+def test_moe_layer_is_dropless_under_total_imbalance():
+    """Every token to experts 0 and 1: two groups of all the rows, the
+    others empty, nothing dropped, the oracle's result."""
+    x, _rw, p = _moe_inputs(5)
+    x = x.at[:, 0].set(1.0)
+    rw = jnp.zeros((8, 4), jnp.float32).at[0, 0].set(40.0).at[0, 1].set(20.0)
+    mesh = build_mesh(dp=8)
+    y, m = jax.jit(lambda x, rw, p: moe_layer(
+        x, rw, _ffn_experts, p, mesh, k=2, token_axes=()))(x, rw, p)
+    assert float(m.dropped) == 0.0
+    assert float(m.max_expert_load) == 2.0      # 64 rows where 32 is even
+    np.testing.assert_allclose(y, _moe_oracle(x, rw, p, 2), rtol=1e-5,
+                               atol=1e-5)
+
+
+@pytest.mark.parametrize("layout, token_axes", [
+    (dict(dp=2, ep=4), ()), (dict(ep=2), ()), (dict(dp=2, ep=2), ("dp",)),
+    (dict(dp=4, ep=2), ("dp",))])
+def test_moe_ep_matches_single_device(layout, token_axes):
+    """Expert parallelism is a layout: output, metrics and gradients are one
+    device's, tokens replicated over ep or sharded over dp beside it."""
+    x, rw, p = _moe_inputs(3)
+    n = int(np.prod(list(layout.values())))
+
+    def run(mesh, axes):
+        def f(x, rw, p):
+            y, m = moe_layer(x, rw, _ffn_experts, p, mesh, k=2,
+                             token_axes=axes)
+            return jnp.sum(y * y) + m.load_balance_loss + m.router_z_loss, \
+                (y, m)
+        (_, (y, m)), grads = jax.jit(jax.value_and_grad(
+            f, (0, 1, 2), has_aux=True))(x, rw, p)
+        return y, m, grads
+    y1, m1, g1 = run(build_mesh(dp=1, devices=jax.devices()[:1]), ())
+    y2, m2, g2 = run(build_mesh(**layout, devices=jax.devices()[:n]),
+                     token_axes)
+    np.testing.assert_allclose(y1, y2, rtol=1e-5, atol=1e-5)
+    for a, b, name in zip(m1, m2, m1._fields):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
+                                   err_msg=name)
+    assert float(m2.dropped) == 0.0
+    for a, b in zip(jax.tree_util.tree_leaves(g1),
+                    jax.tree_util.tree_leaves(g2)):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("layout, token_axes", [
+    (dict(ep=2), ()), (dict(dp=2, ep=2), ("dp",))])
+def test_moe_ep_on_the_kernels_matches_single_device(layout, token_axes):
+    """The same on the TPU path's Pallas kernels (interpret mode, 128-wide
+    experts): each ep shard's groups end before its rows do, and the kernel
+    leaves the rows behind them unwritten (NaN here, stale memory on a
+    chip). Output and the gradients of the tokens, the router and both
+    expert matrices are one device's on XLA's ragged_dot."""
+    x, rw, p = _moe_inputs(7, E=4, M=128, Hd=128, T=64)
+    n = int(np.prod(list(layout.values())))
+
+    def run(mesh, axes, interpret):
+        def experts(p, rows, group_sizes):
+            h = jnp.tanh(grouped_matmul(rows, p["w1"], group_sizes,
+                                        interpret=interpret))
+            return grouped_matmul(h, p["w2"], group_sizes,
+                                  interpret=interpret)
+
+        def f(x, rw, p):
+            y, m = moe_layer(x, rw, experts, p, mesh, k=2, token_axes=axes)
+            return jnp.sum(y * y) + m.load_balance_loss, y
+        (_, y), grads = jax.jit(jax.value_and_grad(
+            f, (0, 1, 2), has_aux=True))(x, rw, p)
+        return y, grads
+    y1, g1 = run(build_mesh(dp=1, devices=jax.devices()[:1]), (), False)
+    y2, g2 = run(build_mesh(**layout, devices=jax.devices()[:n]),
+                 token_axes, True)
+    np.testing.assert_allclose(y1, y2, rtol=1e-4, atol=1e-5)
+    for a, b, name in zip(jax.tree_util.tree_leaves(g1),
+                          jax.tree_util.tree_leaves(g2),
+                          ("x", "router", "w1", "w2")):
+        assert np.all(np.isfinite(b)), name
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4, err_msg=name)
 
 
 def _stage_fn(p, x):

@@ -25,7 +25,7 @@ PACKAGE = os.path.join(os.path.dirname(os.path.dirname(
 
 def test_the_vocabulary_is_the_only_place_the_strings_are_written():
     names = scopes.DEVICE_PHASES + scopes.HOST_SPANS
-    assert len(set(names)) == len(names) == 10
+    assert len(set(names)) == len(names) == 15
     assert all(n.startswith("hvd.") for n in names)
     home = os.path.join(PACKAGE, "profiling", "scopes.py")
     elsewhere = []
@@ -78,13 +78,35 @@ def _flagship_step(dp: int = 1):
             (params, init_opt_state(tx, params, mesh, cfg), t, y))
 
 
+def _moe_step():
+    """The flagship block as OLMoE sets it: gated experts, QK-norm, an
+    untied head, both auxiliary losses."""
+    from horovod_tpu.models import (TransformerConfig, init_opt_state,
+                                    init_params, make_train_step,
+                                    shard_batch, shard_params)
+    cfg = TransformerConfig(vocab_size=64, d_model=32, n_heads=4,
+                            n_layers=2, d_ff=16, max_seq=32, n_experts=4,
+                            moe_top_k=2, moe_gated=True, moe_z_weight=1e-3,
+                            qk_norm=True, tie_embeddings=False,
+                            dtype=jnp.float32)
+    mesh = build_mesh(devices=jax.devices()[:1], dp=-1)
+    params = shard_params(init_params(np.random.RandomState(0), cfg, 1),
+                          cfg, mesh)
+    tx = optax.adamw(1e-3)
+    tokens = np.zeros((2, 16), np.int32)
+    t, y = shard_batch(tokens, tokens, mesh)
+    return (make_train_step(cfg, mesh, tx),
+            (params, init_opt_state(tx, params, mesh, cfg), t, y))
+
+
 _TEXTS = {}
 
 
 def _compiled_text(model: str) -> str:
     if model not in _TEXTS:
         step, args = {"bert": _bert_step, "flagship": _flagship_step,
-                      "flagship.dp2": lambda: _flagship_step(2)}[model]()
+                      "flagship.dp2": lambda: _flagship_step(2),
+                      "moe": _moe_step}[model]()
         _TEXTS[model] = step.lower(*args).compile().as_text()
     return _TEXTS[model]
 
@@ -108,6 +130,28 @@ def _directions(text: str, phase: str) -> set:
 @pytest.mark.parametrize("model", ["bert", "flagship"])
 def test_the_step_carries_every_model_phase_in_both_directions(model, phase):
     assert _directions(_compiled_text(model), phase) == {"fwd", "bwd"}
+
+
+@pytest.mark.parametrize("phase", scopes.MODEL_PHASES + scopes.MOE_PHASES)
+def test_the_moe_step_carries_every_phase_in_both_directions(phase):
+    assert _directions(_compiled_text("moe"), phase) == {"fwd", "bwd"}
+
+
+def test_the_expert_layer_s_phases_nest_in_moe_inside_mlp():
+    """hvd.moe.* inside hvd.moe inside hvd.mlp: the five kinds and the
+    phase metrics of the dense steps read an MoE step unchanged."""
+    paths = re.findall(r'op_name="([^"]*)"', _compiled_text("moe"))
+    inner = [p for p in paths if any(
+        f"/{name}/" in p or p.endswith("/" + name)
+        for name in scopes.MOE_PHASES[1:])]
+    assert inner
+    for path in inner:
+        assert scopes.MOE in re.sub(r"[()]", "/", path).split("/"), path
+    for path in paths:
+        if scopes.MOE + "/" in path or scopes.MOE + ")" in path:
+            assert scopes.MLP in path, path
+    assert not any(name in _compiled_text("flagship")
+                   for name in scopes.MOE_PHASES)
 
 
 @pytest.mark.parametrize("model", ["bert", "flagship"])

@@ -22,6 +22,7 @@ import pytest
 from horovod_tpu.ops import pallas_attention as pa
 from horovod_tpu.ops import pallas_quantize as pq
 from horovod_tpu.ops import pallas_xent as px
+from horovod_tpu.parallel import moe
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -66,6 +67,12 @@ _QKV_CELL = [((2, 2048, 16, 128), jnp.bfloat16)] * 3
 # LM loss rows x a real tokenizer's vocab (not a BLOCK_V multiple: the
 # wrapper pads it)
 _XENT = [((16384, 32000), jnp.bfloat16), ((16384,), jnp.int32)]
+# the expert layer of the cell olmoe-1b-7b.s4096: 8192 tokens x top-8 rows,
+# 64 experts of 2048 <-> 1024, bf16 rows and float32 parameters
+_GMM_UP = [((65536, 2048), jnp.bfloat16), ((64, 2048, 1024), jnp.float32),
+           ((64,), jnp.int32)]
+_GMM_DOWN = [((65536, 1024), jnp.bfloat16), ((64, 1024, 2048), jnp.float32),
+             ((64,), jnp.int32)]
 _BLOCKS = ((8192, 256), jnp.float32)
 _CODES = [((8192, 256), jnp.int8), ((8192, 1), jnp.float32)]
 
@@ -93,6 +100,22 @@ CASES = {
     "xent_grad": (
         jax.grad(lambda l, y: px.fused_softmax_xent(l, y).sum()),
         _XENT, "hvd_fused_xent"),
+    "moe_gmm_up": (moe.grouped_matmul, _GMM_UP, moe.GMM_NAME),
+    "moe_gmm_down_grad": (
+        jax.grad(lambda x, w, g: _sum32(moe.grouped_matmul(x, w, g)),
+                 (0, 1)), _GMM_DOWN, "transpose_jvp_" + moe.GMM_NAME),
+    # an ep shard's share at the four-chip smoke's MoE: float32, 128 wide
+    "moe_gmm_smoke_grad": (
+        jax.grad(lambda x, w, g: _sum32(moe.grouped_matmul(x, w, g)),
+                 (0, 1)),
+        [((256, 128), jnp.float32), ((2, 128, 128), jnp.float32),
+         ((2,), jnp.int32)], "transpose_jvp_" + moe.GMM_NAME),
+    # widths the kernels' blocks do not fit (64 lanes): XLA's ragged dot
+    "moe_gmm_narrow_grad": (
+        jax.grad(lambda x, w, g: _sum32(moe.grouped_matmul(x, w, g)),
+                 (0, 1)),
+        [((256, 128), jnp.float32), ((4, 128, 64), jnp.float32),
+         ((4,), jnp.int32)], "ragged-dot"),
     "quantize": (pq.block_quantize, [_BLOCKS], "hvd_block_quantize"),
     "quantize_ef": (pq.block_quantize_ef, [_BLOCKS],
                     "hvd_block_quantize_ef"),

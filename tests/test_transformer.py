@@ -108,7 +108,7 @@ def test_train_step_pipeline_moe():
     p, opt_state, loss, aux = step(p, opt_state, t, y)
     jax.block_until_ready(loss)
     assert np.isfinite(float(loss))
-    assert np.isfinite(float(aux))
+    assert np.isfinite(float(aux["aux_loss"]))
 
 
 def test_train_step_pipeline_matches_pure_dp_trajectory():
