@@ -27,13 +27,19 @@ Phases (default, one chip):
            for its first ~5 steps on this model, on the CPU in float32 as
            on the chip; after that the loss falls steadily, so the run is
            long enough to see past the transient.)
+           The line's attention_path says how the attention core ran
+           (at 128 x 128 scores XLA's: the block kernels do not win there).
   kernels  every Pallas kernel of the main path, compiled (tpu_custom_call),
-           against its XLA reference at real widths; then two steps of the
-           flagship transformer at head_dim 128 with both kernels asserted
-           in the compiled program.
+           against its XLA reference at real widths (the block attention
+           kernels at the shapes of both BERT cells, with padded keys and
+           a row of nothing else); then two steps of the flagship
+           transformer at head_dim 128 with both kernels asserted in the
+           compiled program.
 
 ``--chips 4`` runs only the four-chip phase and what it is compared with:
-BERT-Large dp=4 against one device, the two n=4 layouts of
+BERT-Large dp=4 against one device at 2 x 512 tokens a chip (the block
+attention kernels on each chip's own rows: both in the compiled step, no
+all-gather), the two n=4 layouts of
 ``__graft_entry__`` against one device / a 4-virtual-device CPU mesh, and
 ring attention (flash kernel per step) over sp=4 against plain attention.
 
@@ -41,7 +47,8 @@ The printed seconds and bytes are smoke prints, not benchmark metrics.
 
 Tolerances (all stated here, none tuned per run):
   flash attention   max|got-ref| / max|ref| <= 2e-2 (bf16 outputs and
-                    grads; the reference runs at "highest" precision)
+                    grads; the reference runs at "highest" precision);
+                    the block attention kernels the same
   fused xent        loss (f32) abs <= 2e-3 on values ~ log(vocab);
                     dlogits (bf16) normalized <= 1e-2
   int8 codec        scales rtol 1e-6; codes within +-1 (a division that
@@ -72,6 +79,7 @@ XENT_GRAD_TOL = 1e-2
 CODEC_RTOL = 1e-4
 CODE_MISMATCH_MAX = 1e-3
 BERT_MESH_RTOL = 1e-2
+BLOCK_KERNELS = ("hvd_block_attention", "hvd_block_attention_bwd")
 TRAIN_STEPS = 30
 LAYOUT_ATOL = 2e-2
 #: a gradient leaf's relative L2 distance between two layouts, float32 at
@@ -98,7 +106,9 @@ class Sizes:
     bert: dict            # BertConfig overrides ({} = bert_large())
     bert_batch: int
     bert_seq: int
+    bert4: tuple          # four-chip BERT (global batch, seq)
     attn: tuple           # flash check q/k/v [B, S, H, D]
+    block: tuple          # block attention checks, each [B, S, H, D]
     xent: tuple           # fused xent check [rows, vocab]
     blocks: tuple         # codec check [n_blocks, block]
     gmm: tuple            # grouped matmul check (rows, in, out, groups)
@@ -109,7 +119,12 @@ class Sizes:
 
 REAL = Sizes(
     bert={}, bert_batch=64, bert_seq=128,
-    attn=(8, 2048, 8, 128), xent=(16384, 32000), blocks=(8192, 256),
+    # 512 positions: the shape at which attend picks the block kernels
+    bert4=(8, 512),
+    attn=(8, 2048, 8, 128),
+    # the attention core of bert-large.s128 and bert-large.s512
+    block=((64, 128, 16, 64), (8, 512, 16, 64)),
+    xent=(16384, 32000), blocks=(8192, 256),
     gmm=(16384, 2048, 1024, 64),
     # depth cut to 4 layers: this phase checks kernels in place, not a model
     gpt=dict(vocab_size=32000, d_model=1024, n_heads=8, n_layers=4,
@@ -118,8 +133,9 @@ REAL = Sizes(
 TINY = Sizes(
     bert=dict(vocab_size=512, hidden_size=64, num_layers=2, num_heads=4,
               intermediate_size=128, max_position=64),
-    bert_batch=8, bert_seq=16,
-    attn=(1, 256, 2, 128), xent=(256, 1000), blocks=(64, 128),
+    bert_batch=8, bert_seq=16, bert4=(8, 16),
+    attn=(1, 256, 2, 128), block=((2, 128, 2, 64),),
+    xent=(256, 1000), blocks=(64, 128),
     gmm=(256, 128, 128, 4),
     gpt=dict(vocab_size=1000, d_model=256, n_heads=2, n_layers=2,
              d_ff=256, max_seq=256),
@@ -175,9 +191,10 @@ def phase_device(smoke: Smoke) -> dict:
 # train: BERT-Large through the normal entry points
 # ---------------------------------------------------------------------------
 
-def _bert_setup(hvd, mesh, smoke: Smoke):
+def _bert_setup(hvd, mesh, smoke: Smoke, shape=None):
     """The construction of bench.py's bert child and
-    examples/jax/bert_pretrain_synthetic.py --large, scan_steps=1."""
+    examples/jax/bert_pretrain_synthetic.py --large, scan_steps=1, at
+    ``shape`` = (batch, seq) or the train phase's."""
     import numpy as np
     import jax
     import jax.numpy as jnp
@@ -189,7 +206,7 @@ def _bert_setup(hvd, mesh, smoke: Smoke):
     z = smoke.sizes
     cfg = dataclasses.replace(bert_large(), **z.bert)
     model = Bert(cfg)
-    B, S = z.bert_batch, z.bert_seq
+    B, S = shape or (z.bert_batch, z.bert_seq)
     params = init_bert(model, jax.random.PRNGKey(smoke.seed), S, mesh)
     tx = optax.adamw(1e-4)
     opt_state = init_opt_state(tx, params, mesh)
@@ -302,7 +319,12 @@ def phase_train(smoke: Smoke, hvd) -> None:
 
     # after the compile check: lowering again traces again. The step's
     # own temporaries are in this analysis, not in memory_stats()
-    mem = step.lower(params, opt_state, batch).compile().memory_analysis()
+    compiled = step.lower(params, opt_state, batch).compile()
+    mem = compiled.memory_analysis()
+    heads = cfg.num_heads
+    path = _attention_path((z.bert_batch, z.bert_seq, heads,
+                            cfg.hidden_size // heads), causal=False,
+                           masked=True)
     d = jax.devices()[0]
     smoke.emit(
         "train", model="bert_large" if not z.bert else "bert_tiny",
@@ -310,7 +332,7 @@ def phase_train(smoke: Smoke, hvd) -> None:
         ffn=cfg.intermediate_size, vocab=cfg.vocab_size,
         dtype=str(cfg.dtype.__name__), n_params=n_params,
         batch=z.bert_batch, seq=z.bert_seq, optimizer="optax.adamw(1e-4)",
-        attention_path="xla (models/bert.py SelfAttention; no kernel)",
+        attention_path=path,
         device_kind=d.device_kind,
         first_step_seconds=round(first_step_s, 3),
         compile_or_cache_read_seconds=round(compile_s, 3),
@@ -404,6 +426,51 @@ def _check_flash(smoke: Smoke) -> None:
     for name, g, r in zip(("dq", "dk", "dv"), got, want):
         _kernel_line(smoke, "flash_attention", f"grad {name}",
                      _rel_err(g, r), FLASH_TOL)
+
+
+def _check_block(smoke: Smoke) -> None:
+    """The block attention kernels (BERT's core: non-causal, a key mask,
+    head_dim 64) against the XLA core in float32 at "highest", forward and
+    gradients, at the shapes of both BERT cells. Row 0 has half its keys
+    padded, row 1 all of them: it attends evenly and moves no q or k."""
+    import jax
+    import jax.numpy as jnp
+    from horovod_tpu.ops.pallas_attention import (_key_masked_attention,
+                                                  block_attention)
+
+    for shape in smoke.sizes.block:
+        B, S, _H, _D = shape
+        keys = jax.random.split(jax.random.PRNGKey(smoke.seed + S), 4)
+        q, k, v = (jax.random.normal(kk, shape, jnp.bfloat16)
+                   for kk in keys[:3])
+        w = jax.random.normal(keys[3], shape, jnp.float32)
+        mask = jnp.ones((B, S), bool).at[0, S // 2:].set(False)
+        mask = mask.at[1, :].set(False)
+
+        def kernel(q, k, v):
+            return block_attention(q, k, v, mask, interpret=smoke.rehearsal)
+
+        def reference(q, k, v):
+            with jax.default_matmul_precision("highest"):
+                return _key_masked_attention(
+                    *(x.astype(jnp.float32) for x in (q, k, v)), mask)
+
+        got = _run_compiled(smoke, kernel, (q, k, v), BLOCK_KERNELS[0])
+        _kernel_line(smoke, "block_attention", "fwd",
+                     _rel_err(got, jax.jit(reference)(q, k, v)), FLASH_TOL,
+                     shape=shape, dtype="bfloat16",
+                     attention_path=_attention_path(shape, False, True))
+        got = _run_compiled(
+            smoke, jax.grad(_weighted_sum(kernel, w), (0, 1, 2)), (q, k, v),
+            BLOCK_KERNELS[1])
+        want = jax.jit(jax.grad(_weighted_sum(reference, w),
+                                (0, 1, 2)))(q, k, v)
+        for name, g, r in zip(("dq", "dk", "dv"), got, want):
+            _kernel_line(smoke, "block_attention", f"grad {name}",
+                         _rel_err(g, r), FLASH_TOL)
+        check(float(jnp.max(jnp.abs(got[0][1].astype(jnp.float32)))) == 0
+              and float(jnp.max(jnp.abs(got[1][1].astype(jnp.float32)))) == 0,
+              "block_attention: a row of padded keys moved q or k")
 
 
 def _check_xent(smoke: Smoke) -> None:
@@ -570,22 +637,33 @@ def _check_codec(smoke: Smoke) -> None:
                      CODEC_RTOL)
 
 
-def _attention_path(shape) -> str:
-    """Which implementation ``attend`` picks for q/k/v of ``shape`` on the
-    default backend, read from the lowered program; for the kernel, the
-    tile its rule picks and the grid steps of one call."""
+def _attention_path(shape, causal=True, masked=False) -> str:
+    """Which implementation ``attend`` picks for q/k/v of ``shape`` (with
+    a key mask if ``masked``) on the default backend, read from the
+    lowered program; for a kernel, what its rule picks for one call: the
+    flash kernel's tile, the block kernels' batch rows and heads a grid
+    step and their VMEM estimate, and the grid steps."""
     import math
     import jax
     import jax.numpy as jnp
-    from horovod_tpu.ops.pallas_attention import (attend, flash_blocks,
-                                                  flash_grid)
-    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
-    text = jax.jit(attend).lower(x, x, x).as_text()
-    if "tpu_custom_call" not in text:
-        return "xla _plain_attention"
+    from horovod_tpu.ops import pallas_attention as pa
     B, S, H, D = shape
-    bq, bk = flash_blocks(S, S, D, jnp.bfloat16)
-    steps = math.prod(flash_grid(B, H, S, S, bq, bk))
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    mask = jax.ShapeDtypeStruct((B, S), bool) if masked else None
+    text = jax.jit(lambda q, k, v, m: pa.attend(
+        q, k, v, causal=causal, key_mask=m)).lower(x, x, x, mask).as_text()
+    if "tpu_custom_call" not in text:
+        return ("xla _key_masked_attention" if masked
+                else "xla _plain_attention")
+    if pa.attention_path(S, S, H, D, causal, masked) == "block":
+        rows = pa.block_rows(B, S, S, jnp.bfloat16)
+        mib = pa.block_vmem_bytes(rows, S, S, 2) / 2 ** 20
+        return (f"pallas {' + '.join(BLOCK_KERNELS)}, {rows} rows x "
+                f"{pa.LANES // D} heads a step, "
+                f"{math.prod(pa.block_grid(B, H, D, rows))} steps, "
+                f"VMEM estimate {mib:.1f} MiB")
+    bq, bk = pa.flash_blocks(S, S, D, jnp.bfloat16)
+    steps = math.prod(pa.flash_grid(B, H, S, S, bq, bk))
     return f"pallas hvd_flash_attention {bq}x{bk}, {steps} steps"
 
 
@@ -651,6 +729,7 @@ def _check_flagship(smoke: Smoke, hvd) -> None:
 
 def phase_kernels(smoke: Smoke, hvd) -> None:
     _check_flash(smoke)
+    _check_block(smoke)
     _check_xent(smoke)
     _check_gmm(smoke)
     _check_codec(smoke)
@@ -664,7 +743,8 @@ def phase_kernels(smoke: Smoke, hvd) -> None:
 def _bert_three_steps(smoke: Smoke, hvd, mesh) -> tuple:
     """(losses, compiled step, facts about placement) of 3 BERT steps."""
     import jax
-    _cfg, step, params, opt_state, batch = _bert_setup(hvd, mesh, smoke)
+    _cfg, step, params, opt_state, batch = _bert_setup(hvd, mesh, smoke,
+                                                       smoke.sizes.bert4)
     compiled = step.lower(params, opt_state, batch).compile()
     n_mesh = mesh.devices.size
     leaves = jax.tree_util.tree_leaves(params)
@@ -675,6 +755,7 @@ def _bert_three_steps(smoke: Smoke, hvd, mesh) -> tuple:
             len(x.sharding.device_set) if x.sharding.is_fully_replicated
             else 0 for x in leaves),
         "mesh_devices": n_mesh,
+        "global_batch_and_seq": smoke.sizes.bert4,
     }
     losses = []
     for _ in range(3):
@@ -696,11 +777,21 @@ def _four_bert(smoke: Smoke, hvd) -> None:
           f"batch is not on 4 distinct devices: {facts4}")
     check(facts4["params_replicated_on"] == 4,
           f"params are not replicated on all 4 devices: {facts4}")
-    check("all-reduce" in step4.as_text(),
-          "no all-reduce in the dp=4 compiled step")
-    del step4
+    text = step4.as_text()
+    check("all-reduce" in text, "no all-reduce in the dp=4 compiled step")
+    kernels = {k: _has_kernel(step4, k) for k in BLOCK_KERNELS}
+    if smoke.on_chip:
+        # each chip's kernels on its own rows: were the call not split,
+        # q, k and v would be gathered onto every chip
+        check(all(kernels.values()) and "all-gather" not in text,
+              f"dp=4 step: kernels {kernels}, all-gather "
+              f"{'all-gather' in text}")
+    del step4, text
     smoke.emit("four_chips", what="bert dp=4", losses=losses4,
-               all_reduce_in_step=True, **facts4)
+               all_reduce_in_step=True,
+               attention_kernels_in_step=kernels if smoke.on_chip
+               else "not checked (rehearsal: the CPU takes the XLA path)",
+               **facts4)
 
     mesh1 = hvd.build_mesh(dp=-1, devices=jax.devices()[:1])
     losses1, _step1, facts1 = _bert_three_steps(smoke, hvd, mesh1)

@@ -13,12 +13,14 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
 
 from horovod_tpu.models.scan_util import multi_step
+from horovod_tpu.ops.pallas_attention import attend, attention_path
 from horovod_tpu.profiling import scopes
 import flax.linen as nn
 import optax
@@ -46,33 +48,71 @@ def bert_base(dtype=jnp.bfloat16) -> "BertConfig":
                       intermediate_size=3072, dtype=dtype)
 
 
+class HeadsDense(nn.Module):
+    """``nn.DenseGeneral`` into or out of ``[heads, head_dim]``, with its
+    parameters (names, shapes, initial values, ``tp`` partitioning), as
+    one 2-D matmul on ``[.., heads·head_dim]``. ``kernel`` is ``[hidden,
+    heads, head_dim]`` (``n_in`` 1) or ``[heads, head_dim, hidden]``
+    (``n_in`` 2). An activation shaped ``[B, S, heads, 64]`` makes XLA:TPU
+    put S on the lanes (64 would pad to 128), and every array that then
+    crosses into the attention kernel's ``[B, S, heads·64]`` is copied:
+    q, k, v, o and their cotangents, 8 copies of 16.8 MB a layer at
+    BERT-Large (PERF.md, PR 27). Born flat, none is."""
+    kernel_shape: tuple
+    kernel_names: tuple
+    n_in: int
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, x):
+        shape, n_in = self.kernel_shape, self.n_in
+        rows, cols = math.prod(shape[:n_in]), math.prod(shape[n_in:])
+
+        def flat_normal(key, shape, dtype=jnp.float32):
+            # DenseGeneral draws the matrix flat and folds it
+            return nn.initializers.normal(0.02)(
+                key, (rows, cols), dtype).reshape(shape)
+        kernel = self.param("kernel", nn.with_partitioning(
+            flat_normal, self.kernel_names), shape)
+        bias = self.param("bias", nn.initializers.zeros, shape[n_in:])
+        x, kernel, bias = nn.dtypes.promote_dtype(x, kernel, bias,
+                                                  dtype=self.dtype)
+        return x @ kernel.reshape(rows, cols) + bias.reshape(cols)
+
+
 class SelfAttention(nn.Module):
     cfg: BertConfig
 
     @nn.compact
     def __call__(self, x, mask):
         c = self.cfg
-        head_dim = c.hidden_size // c.num_heads
-        dense = lambda name: nn.DenseGeneral(
-            (c.num_heads, head_dim), dtype=c.dtype, name=name,
-            kernel_init=nn.with_partitioning(
-                nn.initializers.normal(0.02), (None, "tp", None)))
-        q = dense("query")(x)
-        k = dense("key")(x)
-        v = dense("value")(x)
+        heads = (c.num_heads, c.hidden_size // c.num_heads)
+        # the projections write what the core reads: flat rows for the
+        # block kernels; for XLA's einsums DenseGeneral's [B, S, H, D],
+        # whose layout is then XLA's to choose. Same parameters either way
+        flat = attention_path(x.shape[1], x.shape[1], *heads, False,
+                              True) == "block"
+        if flat:
+            q, k, v = (HeadsDense((c.hidden_size,) + heads,
+                                  (None, "tp", None), 1, c.dtype,
+                                  name=name)(x).reshape(x.shape[:2] + heads)
+                       for name in ("query", "key", "value"))
+        else:
+            q, k, v = (nn.DenseGeneral(
+                heads, dtype=c.dtype, name=name,
+                kernel_init=nn.with_partitioning(
+                    nn.initializers.normal(0.02), (None, "tp", None)))(x)
+                for name in ("query", "key", "value"))
         with jax.named_scope(scopes.ATTENTION_CORE):
-            s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(
-                jnp.asarray(head_dim, c.dtype))
-            s = jnp.where(mask[:, None, None, :], s, -1e9)
-            p = jax.nn.softmax(s.astype(jnp.float32),
-                               axis=-1).astype(c.dtype)
-            o = jnp.einsum("bhqk,bkhd->bqhd", p, v)
-        o = nn.DenseGeneral(c.hidden_size, axis=(-2, -1), dtype=c.dtype,
-                            name="out",
-                            kernel_init=nn.with_partitioning(
-                                nn.initializers.normal(0.02),
-                                ("tp", None, None)))(o)
-        return o
+            o = attend(q, k, v, causal=False, key_mask=mask)
+        if flat:
+            return HeadsDense(heads + (c.hidden_size,), ("tp", None, None),
+                              2, c.dtype, name="out")(o.reshape(x.shape))
+        return nn.DenseGeneral(c.hidden_size, axis=(-2, -1), dtype=c.dtype,
+                               name="out",
+                               kernel_init=nn.with_partitioning(
+                                   nn.initializers.normal(0.02),
+                                   ("tp", None, None)))(o)
 
 
 class BertLayer(nn.Module):
@@ -184,11 +224,14 @@ def make_bert_train_step(model: Bert, optimizer, mesh: Mesh,
 def init_bert(model: Bert, rng_key, seq_len: int = 128, mesh: Mesh = None):
     """Initialize; apply flax logical partitioning onto the mesh's tp axis
     (replicated when tp is absent)."""
-    dummy = jnp.zeros((1, seq_len), jnp.int32)
+    # no parameter's shape or value depends on the sequence length, and a
+    # few positions keep the attention kernels (their lowering and their
+    # compile) out of a program that only draws the parameters
+    dummy = jnp.zeros((1, min(seq_len, 8)), jnp.int32)
     # one compiled program: op by op, 24 layers of initializers are a
     # few hundred small compiles on the chip
     variables = jax.jit(model.init)(rng_key, dummy, dummy,
-                                    jnp.ones((1, seq_len), bool))
+                                    jnp.ones(dummy.shape, bool))
     params = variables["params"]
     if mesh is not None:
         import flax

@@ -44,18 +44,25 @@ forward's tile does not reach it.
 
 Falls back to the pure-XLA implementation on CPU or when shapes don't meet
 TPU tiling constraints (last dim 128-multiple, 128-divisible sequence).
+
+A second pair of kernels, further down, serves short non-causal sequences
+with a key mask (BERT): a head's whole score tile stays in VMEM, forward
+and backward, at head_dim 64 too. :func:`attend` picks between the two
+and XLA from the shape (:func:`attention_path`).
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+import math
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
 NEG_INF = -1e30
 
@@ -361,19 +368,423 @@ def flash_attention_tpu(q: jax.Array, k: jax.Array, v: jax.Array,
     return _call(q, k, v, causal, scale, block_q, block_k, interpret)[0]
 
 
+# ---------------------------------------------------------------------------
+# Block attention: a head's whole [Sq, Sk] score tile in VMEM
+# ---------------------------------------------------------------------------
+#
+# For short sequences (BERT: 128 to 512 positions) there is one k tile, so
+# no online softmax and no running max: a grid step reads ``rows`` batch
+# rows of one 128-lane column of q, k and v as the projections wrote them,
+# ``[B, S, H·D]``, and keeps every score in VMEM, forward and backward.
+# Nothing is transposed around the call. At head_dim 64 a 128-lane column
+# holds two heads: q (and do) are stacked, one copy a head with the other
+# head's lanes zeroed (:func:`_stack`), so one matmul against the unsplit
+# k gives both heads' scores as ``[2·Sq, Sk]``, the softmax runs once over
+# all of it, and every matmul is 128 lanes wide. The MXU passes are half
+# empty either way at head_dim 64.
+
+#: the lanes of a vreg: the width of the column of ``H·D`` a grid step takes
+LANES = 128
+#: the longest sequence the block kernels take: at 512 a column's two
+#: float32 score tiles are 2 MiB each and the backward holds several
+BLOCK_MAX_SEQ = 512
+BLOCK_HEAD_DIMS = (64, 128)
+#: the fewest score elements a head must have for :func:`attend` to pick
+#: the block kernels. At 128 x 128 they take what XLA's fused core takes:
+#: bert-large.s128 +0.84 % on one chip, -1.04 % beside dp=4's all-reduce
+#: (PERF.md, PR 27). At 512 x 512 they halve the core (+7.4 %); alone, a
+#: layer's forward + backward at 256 x 256 is 1067 µs against XLA's 1307
+BLOCK_MIN_SCORES = 256 * 256
+#: a masked key's score, as models/bert.py writes it
+MASKED_SCORE = -1e9
+FWD_NAME = "hvd_block_attention"
+BWD_NAME = "hvd_block_attention_bwd"
+
+
+def block_eligible(Sq: int, Sk: int, H: int, D: int) -> bool:
+    """Whether the block kernels take q ``[.., Sq, H, D]`` against k/v
+    ``[.., Sk, H, D]`` (non-causal): a head_dim that fills a 128-lane
+    column alone or in pairs, heads that fill whole columns, lengths that
+    are whole lane tiles and short enough for one score tile."""
+    return (D in BLOCK_HEAD_DIMS and H * D % LANES == 0
+            and Sq % MIN_BLOCK == 0 and Sk % MIN_BLOCK == 0
+            and max(Sq, Sk) <= BLOCK_MAX_SEQ)
+
+
+def block_vmem_bytes(rows: int, Sq: int, Sk: int, itemsize: int) -> int:
+    """Working set of one grid step of the backward kernel, the larger of
+    the two: q, o, do, dq and k, v, dk, dv columns of ``rows`` batch rows,
+    double-buffered by the pipeline, the float32 lse rows (a ``[heads,
+    Sq]`` tile is padded to 8 sublanes), and for each row the loop has in
+    flight two float32 score tiles of a column's two heads and one cast
+    for the matmuls, as ``flash_vmem_bytes`` counts them (the backward's
+    dp and ds take the room of s and p: B8 S512 with two rows in flight,
+    21 MiB if all four were counted apart, runs under the 16 MiB limit)."""
+    io = 2 * rows * 4 * (Sq + Sk) * LANES * itemsize
+    lse = 2 * rows * 8 * Sq * 4
+    in_flight = math.gcd(rows, _row_unroll(Sq, Sk))
+    tiles = in_flight * 2 * Sq * Sk * (4 + 4 + itemsize)
+    return io + lse + tiles
+
+
+def block_rows(B: int, Sq: int, Sk: int, dtype) -> int:
+    """Batch rows a grid step takes: the most that divide ``B`` and fit
+    ``VMEM_BUDGET`` by :func:`block_vmem_bytes`. A grid step costs 0.35 µs
+    whatever it does and one 128 x 128 head is 0.07 µs of work (PERF.md,
+    PR 25), so a step takes all it can hold."""
+    itemsize = jnp.dtype(dtype).itemsize
+    return max((r for r in range(1, B + 1) if B % r == 0 and
+                block_vmem_bytes(r, Sq, Sk, itemsize) <= VMEM_BUDGET),
+               default=1)
+
+
+def block_grid(B: int, H: int, D: int, rows: int) -> Tuple[int, int]:
+    """The block kernels' grid: (row groups, 128-lane columns of H·D)."""
+    return (B // rows, H * D // LANES)
+
+
+def _head_lanes(h: int, D: int):
+    """[1, LANES] bool: the lanes of head ``h`` of a column's heads."""
+    lane = lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+    return jnp.logical_and(lane >= h * D, lane < (h + 1) * D)
+
+
+def _stack(x, D: int):
+    """``[S, LANES]`` → ``[heads·S, LANES]``: one copy of ``x`` a head of
+    the column, each with the other heads' lanes zeroed. A matmul that
+    contracts the lanes then gives that head's product alone, and one that
+    contracts the rows sums the heads into their own lanes."""
+    if D == LANES:
+        return x
+    return jnp.concatenate(
+        [jnp.where(_head_lanes(h, D), x, jnp.zeros_like(x))
+         for h in range(LANES // D)], axis=0)
+
+
+def _unstack(y, D: int):
+    """``[heads·S, LANES]`` → ``[S, LANES]``: head ``h``'s lanes from the
+    ``h``-th copy."""
+    heads = LANES // D
+    S = y.shape[0] // heads
+    out = y[:S]
+    for h in range(1, heads):
+        out = jnp.where(_head_lanes(h, D), y[h * S:(h + 1) * S], out)
+    return out
+
+
+_NT = (((1,), (1,)), ((), ()))      # a · bᵀ
+_NN = (((1,), (0,)), ((), ()))      # a · b
+_TN = (((0,), (0,)), ((), ()))      # aᵀ · b
+
+
+def _dot(a, b, dims):
+    return lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+def _scaled(q, scale: float):
+    """(q with ``scale`` folded in, what is left to multiply the scores
+    by). A power of two (head_dim 64: 1/8) folds exactly in any float
+    dtype and saves a pass over the score tile; any other scale stays on
+    the float32 scores."""
+    if math.log2(scale).is_integer():
+        return q * jnp.asarray(scale, q.dtype), 1.0
+    return q, scale
+
+
+def _scores(q, k, fill, scale: float, masked: bool):
+    """float32 ``q·kᵀ · scale`` with the key mask applied: ``fill`` is
+    positive at a live key and holds the score to write at a masked one."""
+    s = _dot(q, k, _NT)
+    if scale != 1.0:
+        s = s * scale
+    return jnp.where(fill > 0, s, fill) if masked else s
+
+
+#: batch rows the kernels' row loop may have in flight (code size)
+MAX_ROW_UNROLL = 8
+
+
+def _row_unroll(Sq: int, Sk: int) -> int:
+    """Batch rows the kernels' row loop has in flight: a row of short
+    sequences is a short chain of small matmuls and reductions, each
+    waiting for the last, and more rows in flight fill the gaps (v5e,
+    B64 S128, forward + backward of a layer: 1069 µs at 1, 901 at 2, 826 at
+    4, 780 at 8; PERF.md, PR 27). As many as hold the score elements of
+    two rows of 512 x 512, a power of two so that it divides the rows
+    of a step."""
+    fit = max(1, 2 * BLOCK_MAX_SEQ ** 2 // (Sq * Sk))
+    return min(MAX_ROW_UNROLL, 1 << (fit.bit_length() - 1))
+
+
+def _for_rows(row, rows: int, unroll: int) -> None:
+    """``row(i)`` for the ``rows`` batch rows of a grid step, ``unroll``
+    (as far as it divides them) to an iteration of the loop: Mosaic
+    unrolls a loop wholly or not at all."""
+    unroll = math.gcd(rows, unroll)
+
+    def body(g, carry):
+        for j in range(unroll):
+            row(g * unroll + j)
+        return carry
+    lax.fori_loop(0, rows // unroll, body, 0)
+
+
+def _block_fwd_kernel(fill_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *,
+                      scale: float, D: int, masked: bool):
+    Sq, Sk = q_ref.shape[1], k_ref.shape[1]
+
+    def row(i):
+        v = v_ref[i]
+        q2, left = _scaled(_stack(q_ref[i], D), scale)
+        s = _scores(q2, k_ref[i], fill_ref[i], left, masked)
+        m = jnp.max(s, axis=-1, keepdims=True)      # [heads·Sq, 1]
+        p = jnp.exp(s - m)
+        l = jnp.sum(p, axis=-1, keepdims=True)
+        # copy h holds p_h·v: head h's lanes of it are head h's output
+        o = _dot(p.astype(v.dtype), v, _NN) / l
+        o_ref[i] = _unstack(o, D).astype(o_ref.dtype)
+        lse = m + jnp.log(l)
+        for h in range(LANES // D):
+            lse_ref[i, 0, h] = lse[h * Sq:(h + 1) * Sq, 0]
+
+    _for_rows(row, q_ref.shape[0], _row_unroll(Sq, Sk))
+
+
+def _block_bwd_kernel(fill_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
+                      dq_ref, dk_ref, dv_ref, *, scale: float, D: int,
+                      masked: bool):
+    Sq, Sk = q_ref.shape[1], k_ref.shape[1]
+
+    def row(i):
+        k, v, do = k_ref[i], v_ref[i], do_ref[i]
+        fill = fill_ref[i]
+        q2, do2 = _stack(q_ref[i], D), _stack(do, D)
+        lse = jnp.concatenate([lse_ref[i, 0, h][:, None]
+                               for h in range(LANES // D)], axis=0)
+        qs, left = _scaled(q2, scale)
+        p = jnp.exp(_scores(qs, k, fill, left, masked) - lse)
+        # d loss / d s through softmax is p ⊙ (dp − Δ), Δ = rowsum(do ⊙ o)
+        # per head, and s = scale · q·kᵀ: the scale goes on dq and dk, not
+        # on the score tile. A masked key's s is a constant, and there p
+        # is 0 unless every key of the row is masked (p uniform): then the
+        # whole row's ds is 0, which the same scalar says
+        delta = jnp.sum(_stack(do.astype(jnp.float32)
+                               * o_ref[i].astype(jnp.float32), D),
+                        axis=-1, keepdims=True)
+        ds = (p * (_dot(do2, v, _NT) - delta)).astype(q2.dtype)
+        live = jnp.max(fill, axis=-1, keepdims=True) > 0
+        ds_scale = jnp.where(live, scale, 0.0) if masked else scale
+        # contracting the stacked rows sums the heads into their lanes
+        dv_ref[i] = _dot(p.astype(do.dtype), do2, _TN).astype(dv_ref.dtype)
+        dk_ref[i] = (_dot(ds, q2, _TN) * ds_scale).astype(dk_ref.dtype)
+        dq_ref[i] = (_unstack(_dot(ds, k, _NN), D)
+                     * ds_scale).astype(dq_ref.dtype)
+
+    _for_rows(row, q_ref.shape[0], _row_unroll(Sq, Sk))
+
+
+class _Statics(NamedTuple):
+    """What a call is compiled for, beside its shapes."""
+    scale: float
+    D: int
+    masked: bool
+    rows: Optional[int]
+    interpret: bool
+
+
+# the operands of each kernel, by the block spec each takes: the mask's
+# fill, a q-shaped column, a k-shaped column, the lse rows
+_FWD_IO = (("fill", "q", "k", "k"), ("q", "lse"))
+_BWD_IO = (("fill", "q", "k", "k", "q", "q", "lse"), ("q", "k", "k"))
+
+
+def _block_call(kernel, name, io, q, k, statics: _Statics):
+    """One pallas_call over (row groups, 128-lane columns)."""
+    B, Sq, M = q.shape
+    Sk, heads = k.shape[1], LANES // statics.D
+    rows = statics.rows or block_rows(B, Sq, Sk, q.dtype)
+    if B % rows:
+        raise ValueError(f"rows={rows} does not divide the batch {B}")
+
+    def column(S):
+        return pl.BlockSpec((rows, S, LANES), lambda b, g: (b, 0, g))
+    spec = {"fill": pl.BlockSpec((rows, 1, Sk), lambda b, g: (b, 0, 0)),
+            "q": column(Sq), "k": column(Sk),
+            "lse": pl.BlockSpec((rows, 1, heads, Sq),
+                                lambda b, g: (b, g, 0, 0))}
+    shape = {"q": jax.ShapeDtypeStruct(q.shape, q.dtype),
+             "k": jax.ShapeDtypeStruct(k.shape, k.dtype),
+             "lse": jax.ShapeDtypeStruct((B, M // LANES, heads, Sq),
+                                         jnp.float32)}
+    ins, outs = io
+    return pl.pallas_call(
+        functools.partial(kernel, scale=statics.scale, D=statics.D,
+                          masked=statics.masked),
+        grid=(B // rows, M // LANES),
+        in_specs=[spec[x] for x in ins],
+        out_specs=[spec[x] for x in outs],
+        out_shape=[shape[x] for x in outs],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=statics.interpret, name=name)
+
+
+# jitted so that a model's layers share one trace and one lowering of each
+# kernel: 48 Mosaic lowerings add 7.6 s to the trace of BERT-Large's step
+@functools.partial(jax.jit, static_argnames="statics")
+def _block_fwd_local(fill, q, k, v, statics):
+    """(o ``[B, Sq, H·D]``, lse ``[B, H·D/128, 128/D, Sq]``) of the arrays
+    one device holds."""
+    return _block_call(_block_fwd_kernel, FWD_NAME, _FWD_IO, q, k,
+                       statics)(fill, q, k, v)
+
+
+@functools.partial(jax.jit, static_argnames="statics")
+def _block_bwd_local(fill, q, k, v, o, do, lse, statics):
+    """(dq, dk, dv) of the arrays one device holds."""
+    return _block_call(_block_bwd_kernel, BWD_NAME, _BWD_IO, q, k,
+                       statics)(fill, q, k, v, o, do, lse)
+
+
+def _over_mesh(local, io, q):
+    """``local`` on each device's own shard of the mesh that ``q`` is
+    placed on (its type says which, under any jit whose arguments are
+    committed to a mesh): the kernels are independent over batch rows and
+    over 128-lane columns of ``H·D``, and a bare pallas_call is a custom
+    call GSPMD cannot split (JAX refuses to lower one for several
+    devices). Batch rows go over ``dp`` and columns over ``tp`` where the
+    axis divides them, and what is not split is computed on every device.
+    On one device, or already inside a shard_map, the call is ``local``
+    itself."""
+    from horovod_tpu.parallel.mesh import DATA_AXIS, TENSOR_AXIS
+    mesh = jax.typeof(q).sharding.mesh
+    if mesh.empty or mesh.size == 1 or mesh.manual_axes:
+        return local
+
+    def axis(name, n):
+        return name if mesh.shape.get(name, 1) > 1 \
+            and n % mesh.shape[name] == 0 else None
+    batch = axis(DATA_AXIS, q.shape[0])
+    columns = axis(TENSOR_AXIS, q.shape[2] // LANES)
+    spec = {"fill": P(batch, None, None), "q": P(batch, None, columns),
+            "k": P(batch, None, columns),
+            "lse": P(batch, columns, None, None)}
+    ins, outs = io
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=tuple(spec[x] for x in ins),
+                         out_specs=tuple(spec[x] for x in outs),
+                         check_vma=False)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _block(statics, fill, q, k, v):
+    return _block_fwd(statics, fill, q, k, v)[0]
+
+
+def _block_fwd(statics, fill, q, k, v):
+    call = _over_mesh(functools.partial(_block_fwd_local, statics=statics),
+                      _FWD_IO, q)
+    o, lse = call(fill, q, k, v)
+    return o, (fill, q, k, v, o, lse)
+
+
+def _block_bwd(statics, res, do):
+    fill, q, k, v, o, lse = res
+    call = _over_mesh(functools.partial(_block_bwd_local, statics=statics),
+                      _BWD_IO, q)
+    return (None,) + tuple(call(fill, q, k, v, o, do, lse))
+
+
+_block.defvjp(_block_fwd, _block_bwd)
+
+
+def block_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                    key_mask: Optional[jax.Array] = None,
+                    scale: Optional[float] = None,
+                    rows: Optional[int] = None,
+                    interpret: bool = False) -> jax.Array:
+    """Non-causal attention of q ``[B, Sq, H, D]`` on k/v ``[B, Sk, H, D]``
+    with a ``[B, Sk]`` bool mask of live keys, in the block kernels
+    (:func:`block_eligible` shapes; use :func:`attend` for the dispatch).
+    Scores and softmax are float32, the probabilities are cast to ``v``'s
+    dtype for p·v, and the residuals of the backward are q, k, v, o and
+    the float32 log-sum-exp: no ``[B, H, Sq, Sk]`` array reaches HBM. A
+    masked key scores ``MASKED_SCORE`` as in ``models/bert.py``, so a row
+    whose keys are all masked attends to all of them evenly (its scores
+    are written as 0: the log-sum-exp of a row of -1e9 loses log(Sk) in
+    float32). ``rows`` overrides :func:`block_rows` (tests, sweeps). Under
+    a jit whose arrays are placed on a mesh each device runs the kernels
+    on its own batch rows and heads (:func:`_over_mesh`)."""
+    B, Sq, H, D = q.shape
+    Sk = k.shape[1]
+    if not block_eligible(Sq, Sk, H, D):
+        raise ValueError(f"block attention does not take Sq={Sq} Sk={Sk} "
+                         f"H={H} D={D}")
+    if key_mask is None:
+        fill = jnp.ones((B, 1, Sk), jnp.float32)
+    else:
+        some = jnp.any(key_mask, axis=-1, keepdims=True)
+        fill = jnp.where(key_mask, 1.0, jnp.where(some, MASKED_SCORE, 0.0))
+        fill = fill.astype(jnp.float32)[:, None, :]
+    statics = _Statics(float(scale) if scale is not None else D ** -0.5, D,
+                       key_mask is not None, rows, interpret)
+    o = _block(statics, fill, q.reshape(B, Sq, H * D),
+               k.reshape(B, Sk, H * D), v.reshape(B, Sk, H * D))
+    return o.reshape(B, Sq, H, D)
+
+
+def _key_masked_attention(q, k, v, key_mask, scale=None):
+    """The XLA core of ``models/bert.py``: scores in the inputs' dtype,
+    masked keys at ``MASKED_SCORE``, a float32 softmax, probabilities cast
+    back for p·v. What the block kernels are held against."""
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k)
+    s = (s / jnp.sqrt(jnp.asarray(q.shape[-1], q.dtype)) if scale is None
+         else s * scale)
+    s = jnp.where(key_mask[:, None, None, :], s, MASKED_SCORE)
+    p = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(q.dtype)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+
 def flash_eligible(Sq: int, Sk: int, D: int) -> bool:
     """The kernel's contract to callers: every length a multiple of
     ``MIN_BLOCK`` (the tile is then :func:`flash_blocks`' to choose)."""
     return D % MIN_BLOCK == 0 and Sq % MIN_BLOCK == 0 and Sk % MIN_BLOCK == 0
 
 
+def attention_path(Sq: int, Sk: int, H: int, D: int, causal: bool,
+                   masked: bool) -> str:
+    """Which implementation :func:`attend` takes, from the shape alone:
+    ``"flash"`` wherever the flash kernel's contract holds and no key
+    mask is given, ``"block"`` where a head's whole score tile fits VMEM
+    and is large enough to be worth a kernel (non-causal; with or without
+    a key mask), else ``"xla"``; off the TPU always ``"xla"``."""
+    if jax.default_backend() != "tpu":
+        return "xla"
+    if not masked and flash_eligible(Sq, Sk, D):
+        return "flash"
+    if (not causal and block_eligible(Sq, Sk, H, D)
+            and Sq * Sk >= BLOCK_MIN_SCORES):
+        return "block"
+    return "xla"
+
+
 def attend(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = True,
-           scale: Optional[float] = None) -> jax.Array:
-    """Attention with automatic kernel selection: the Pallas flash kernel on
-    TPU when shapes satisfy its tiling constraints, else the fused-XLA
-    fallback. Differentiable on both paths."""
-    if jax.default_backend() == "tpu" and flash_eligible(
-            q.shape[1], k.shape[1], q.shape[-1]):
+           scale: Optional[float] = None,
+           key_mask: Optional[jax.Array] = None) -> jax.Array:
+    """Attention with automatic kernel selection (:func:`attention_path`)
+    on a TPU, the fused-XLA fallback elsewhere. ``key_mask`` is a
+    ``[B, Sk]`` bool array of live keys (non-causal only). Differentiable
+    on every path."""
+    if causal and key_mask is not None:
+        raise ValueError("a key mask with causal attention is not "
+                         "implemented")
+    path = attention_path(q.shape[1], k.shape[1], q.shape[2], q.shape[3],
+                          causal, key_mask is not None)
+    if path == "flash":
         return flash_attention_tpu(q, k, v, causal, scale)
+    if path == "block":
+        return block_attention(q, k, v, key_mask, scale)
+    if key_mask is not None:
+        return _key_masked_attention(q, k, v, key_mask, scale)
     from horovod_tpu.parallel.ring_attention import _plain_attention
     return _plain_attention(q, k, v, causal, scale)

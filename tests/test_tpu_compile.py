@@ -28,16 +28,21 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(scope="module")
-def v5e():
-    """Sharding on one device of a described (not attached) v5e 2x2."""
+def topo():
+    """A described (not attached) v5e 2x2."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:  # no TPU compiler in this installation
         pytest.skip(f"cannot describe a v5e topology: {e!r}")
+
+
+@pytest.fixture(scope="module")
+def v5e(topo):
+    """Sharding on one device of it."""
+    from jax.sharding import SingleDeviceSharding
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -64,6 +69,10 @@ _QKV = [((8, 2048, 8, 128), jnp.bfloat16)] * 3
 # the benchmark's cell gpt-1.3b-widths.s2048: B2 S2048 H16 D128 bf16. A tile
 # that does not fit VMEM or a block spec the lowering refuses fails here
 _QKV_CELL = [((2, 2048, 16, 128), jnp.bfloat16)] * 3
+# the attention core of the cells bert-large.s128 and bert-large.s512: q, k,
+# v and the [B, S] key mask. Sixteen heads of 64 are eight 128-lane columns
+_BERT_S128 = [((64, 128, 16, 64), jnp.bfloat16)] * 3 + [((64, 128), jnp.bool_)]
+_BERT_S512 = [((8, 512, 16, 64), jnp.bfloat16)] * 3 + [((8, 512), jnp.bool_)]
 # LM loss rows x a real tokenizer's vocab (not a BLOCK_V multiple: the
 # wrapper pads it)
 _XENT = [((16384, 32000), jnp.bfloat16), ((16384,), jnp.int32)]
@@ -96,6 +105,17 @@ CASES = {
         jax.grad(lambda q, k, v: _sum32(*pa.flash_attention_with_lse(
             q, k, v, causal=False)), (0, 1, 2)),
         _QKV, "hvd_flash_attention"),
+    "block_fwd_s128": (pa.block_attention, _BERT_S128, pa.FWD_NAME),
+    "block_grad_s128": (_block_grad := jax.grad(
+        lambda q, k, v, m: _sum32(pa.block_attention(q, k, v, m)),
+        (0, 1, 2)), _BERT_S128, pa.BWD_NAME),
+    "block_fwd_s512": (pa.block_attention, _BERT_S512, pa.FWD_NAME),
+    "block_grad_s512": (_block_grad, _BERT_S512, pa.BWD_NAME),
+    # one head of 128 a column, no mask given
+    "block_grad_d128": (
+        jax.grad(lambda q, k, v: _sum32(pa.block_attention(q, k, v)),
+                 (0, 1, 2)),
+        [((8, 384, 8, 128), jnp.bfloat16)] * 3, pa.BWD_NAME),
     "xent_fwd": (px.fused_softmax_xent, _XENT, "hvd_fused_xent"),
     "xent_grad": (
         jax.grad(lambda l, y: px.fused_softmax_xent(l, y).sum()),
@@ -140,6 +160,36 @@ def test_kernel_compiles_for_v5e(case, v5e, no_compile_cache, monkeypatch):
     calls = [line for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     assert any(kernel in line for line in calls), (kernel, calls)
+
+
+def test_block_attention_stays_on_its_shard_of_a_mesh(topo,
+                                                      no_compile_cache):
+    """Under GSPMD, batch over dp and heads over tp: each device's kernels
+    take its own rows and columns (a bare pallas_call would have q, k and
+    v gathered onto every device)."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("dp", "tp"))
+    x = jax.ShapeDtypeStruct((128, 128, 16, 64), jnp.bfloat16,
+                             sharding=NamedSharding(
+                                 mesh, P("dp", None, "tp", None)))
+    m = jax.ShapeDtypeStruct((128, 128), jnp.bool_,
+                             sharding=NamedSharding(mesh, P("dp", None)))
+    grad = jax.grad(lambda q, k, v, m: _sum32(
+        pa.block_attention(q, k, v, m)), (0, 1, 2))
+    text = jax.jit(grad).lower(x, x, x, m).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 2 and all("bf16[64,128,512]" in c for c in calls)
+    assert "all-gather" not in text and "all-reduce" not in text
+    # the benchmark's reference check on four chips: replicated arrays
+    # that do not split; every device runs the whole call
+    rep = NamedSharding(Mesh(np.array(topo.devices).reshape(4, 1),
+                             ("dp", "tp")), P())
+    x = jax.ShapeDtypeStruct((2, 128, 16, 64), jnp.bfloat16, sharding=rep)
+    m = jax.ShapeDtypeStruct((2, 128), jnp.bool_, sharding=rep)
+    text = jax.jit(grad).lower(x, x, x, m).compile().as_text()
+    assert text.count("bf16[2,128,1024]") and pa.BWD_NAME in text
 
 
 @pytest.fixture
