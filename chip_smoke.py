@@ -192,7 +192,7 @@ def phase_device(smoke: Smoke) -> dict:
 # ---------------------------------------------------------------------------
 
 def _bert_setup(hvd, mesh, smoke: Smoke, shape=None):
-    """The construction of bench.py's bert child and
+    """The construction of benchmarks/chip/adapters/bert.py and
     examples/jax/bert_pretrain_synthetic.py --large, scan_steps=1, at
     ``shape`` = (batch, seq) or the train phase's."""
     import numpy as np
@@ -707,8 +707,8 @@ def _check_flagship(smoke: Smoke, hvd) -> None:
         losses.append(float(loss))
     check(all(math.isfinite(x) for x in losses), f"non-finite: {losses}")
 
-    # the same d_model over twice the heads is bench.py's gpt default
-    # (1024 / 16 heads, head_dim 64)
+    # the same d_model over twice the heads (1024 / 16 heads,
+    # head_dim 64): a causal shape the kernels leave to XLA
     shapes = ((B, S, cfg.n_heads, cfg.head_dim),
               (B, S, 2 * cfg.n_heads, cfg.head_dim // 2))
     paths = {f"{s[2]} heads x head_dim {s[3]}": _attention_path(s)

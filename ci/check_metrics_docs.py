@@ -32,8 +32,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 DOC = os.path.join(REPO, "docs", "OBSERVABILITY.md")
 
 # where registrations live (tests register throwaway names on purpose)
-SCAN_ROOTS = ("horovod_tpu", "benchmarks")
-SCAN_FILES = ("bench.py", "__graft_entry__.py")
+SCAN_ROOTS = ("horovod_tpu",)
+SCAN_FILES = ("__graft_entry__.py",)
 
 _REG_CALL = re.compile(
     r'(?:\.(?:counter|gauge|histogram)|\bg|\b_metric)\('

@@ -33,10 +33,7 @@ def run_tier(name: str, spec: dict) -> bool:
         if rc != 0:
             print(f"--- tier {name}: SETUP FAILED rc={rc}", flush=True)
             return False
-    if "command" in spec:
-        cmd = spec["command"].split()
-    else:
-        cmd = [sys.executable, "-m", "pytest", "-q", *spec["paths"]]
+    cmd = [sys.executable, "-m", "pytest", "-q", *spec["paths"]]
     t0 = time.time()
     try:
         rc = subprocess.run(cmd, cwd=REPO, timeout=timeout).returncode

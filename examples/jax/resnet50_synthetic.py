@@ -2,8 +2,10 @@
 ``examples/pytorch/pytorch_synthetic_benchmark.py`` /
 ``examples/tensorflow2/tensorflow2_synthetic_benchmark.py``).
 
-Prints img/sec like the reference's synthetic benchmarks; ``bench.py`` at
-the repo root is the driver-facing single-line variant of this script.
+Prints img/sec like the reference's synthetic benchmarks.  The CNNs
+(ResNet, VGG, Inception in ``horovod_tpu.models``) have no cell in
+``BENCHMARK.json``, so the repo records no number for them; this script
+is how to run ResNet-50.
 """
 
 import argparse

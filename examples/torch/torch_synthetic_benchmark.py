@@ -4,7 +4,7 @@ Reference analog: ``examples/pytorch/pytorch_synthetic_benchmark.py`` —
 the canonical "always prints img/sec" harness: warm-up batches, timed
 iterations, per-rank rate allreduced to a total. The reference benches
 torchvision models on GPU; here the adapter is host-side (the TPU compute
-path is JAX — see ``bench.py`` for the chip benchmarks), so the default
+path is JAX, and ``benchmarks/chip/`` is its benchmark), so the default
 model is a small conv net and the number this prints measures the
 adapter + TCP-core data plane, not an accelerator.
 

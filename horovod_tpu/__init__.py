@@ -106,7 +106,7 @@ from horovod_tpu.parallel import (  # noqa: F401
     batch_sharding,
     logical_sharding,
 )
-# Unified parallelism plan (docs/PERF.md "Pipeline parallelism"): the
+# Unified parallelism plan (cost model: parallel/pipeline.py): the
 # frozen dp x pp / schedule / microbatch / comms decision object, the
 # single compile seam behind the step factories, and the composed
 # DP x PP pipelined train step.
@@ -137,8 +137,8 @@ from horovod_tpu.train.optimizer import (  # noqa: F401
     broadcast_object,
     allgather_object,
 )
-# Backprop/collective overlap engine (docs/PERF.md "Overlap &
-# bucketing"): byte-budgeted gradient buckets, software-pipelined
+# Backprop/collective overlap engine (train/overlap.py):
+# byte-budgeted gradient buckets, software-pipelined
 # microbatch accumulation, fused dequantize+apply optimizers.
 from horovod_tpu.train.buckets import (  # noqa: F401
     BucketPlan,
@@ -149,7 +149,7 @@ from horovod_tpu.train.overlap import (  # noqa: F401
     make_overlap_train_step,
     pipelined_accumulate,
 )
-# Mesh-path communication autotuner (docs/PERF.md "Autotuning"):
+# Mesh-path communication autotuner (train/autotune.py):
 # topology-aware hierarchical collectives + online plan search with a
 # persistent, fingerprint-keyed tuning cache.
 from horovod_tpu.common.topology import (  # noqa: F401
@@ -168,7 +168,7 @@ from horovod_tpu.train.fused_apply import (  # noqa: F401
 )
 # Gradient compression subsystem (quantizers + error feedback +
 # quantized wire paths; reference analog: horovod/torch/compression.py,
-# grown per EQuARX — see docs/PERF.md "Gradient compression")
+# grown per EQuARX — see compression/__init__.py)
 from horovod_tpu.compression import (  # noqa: F401
     Compression,
     Compressor,

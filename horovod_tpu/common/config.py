@@ -66,13 +66,13 @@ class Config:
     fusion_threshold_bytes: int = 64 * 1024 * 1024
     cycle_time_ms: float = 1.0
     cache_capacity: int = 1024
-    # Gradient bucketing / overlap (docs/PERF.md "Overlap & bucketing"):
+    # Gradient bucketing / overlap (train/overlap.py):
     # bucket_bytes 0 = follow fusion_threshold_bytes; overlap_buckets
     # gates the eager per-bucket async issue path (off = one grouped
     # call for the whole tree, the pre-bucketing behavior).
     bucket_bytes: int = 0
     overlap_buckets: bool = True
-    # Small-bucket latency floor (docs/PERF.md "Autotuning"): gradient
+    # Small-bucket latency floor (train/autotune.py): gradient
     # buckets under this many bytes skip quantization and ring /
     # hierarchical chunking and take one dense psum (latency-optimized
     # small-tensor path, arxiv 1909.09756). 0 = off.

@@ -18,7 +18,7 @@ Transport integration: ``DistributedGradTransform(compression=...)``
 ``ErrorFeedback(...)``-wrapped codecs;
 ``ops.collectives.quantized_allreduce`` and
 ``ops.mesh_collectives.device_allreduce(compression=...)`` are the
-quantized wire paths (see docs/PERF.md "Gradient compression").
+quantized wire paths.
 """
 
 from horovod_tpu.compression.base import (  # noqa: F401

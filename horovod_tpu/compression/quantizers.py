@@ -251,8 +251,7 @@ class OneBitQuantizer(Quantizer):
 
 
 def resolve_compressor(name: str):
-    """Map a knob string (``--compression`` / HVD_BENCH_COMPRESSION) to a
-    compressor: int8 | fp8 | fp8_e4m3 | fp8_e5m2 | onebit | fp16 | bf16 |
+    """Map a codec name to a compressor: int8 | fp8 | fp8_e4m3 | fp8_e5m2 | onebit | fp16 | bf16 |
     none."""
     from horovod_tpu.compression.base import (BF16Compressor,
                                               FP16Compressor,

@@ -107,8 +107,8 @@ def device_prefetch(iterator, sharding=None, buffer_size: int = 2):
     consumer (double-buffered by default): ``jax.device_put`` is
     asynchronous, so the host→HBM transfer of the next batches overlaps
     the compute consuming the current one and H2D drops off the step's
-    critical path (docs/PERF.md headroom (c); the reference's analog is
-    the CUDA-stream prefetch users pair with its AsyncDataLoaderMixin).
+    critical path (the reference's analog is the CUDA-stream prefetch
+    users pair with its AsyncDataLoaderMixin).
 
     ``sharding`` places each leaf (e.g. ``hvd.batch_sharding(mesh)`` for
     dp-sharded batches); ``None`` uses the default device. When the

@@ -33,10 +33,7 @@ from horovod_tpu.diagnostics.spans import (  # noqa: F401
     next_span,
 )
 from horovod_tpu.diagnostics.clock import estimate_wall_offset  # noqa: F401
-from horovod_tpu.diagnostics.merge import (  # noqa: F401
-    merge_directory,
-    merge_shards,
-)
+from horovod_tpu.diagnostics.merge import merge_shards  # noqa: F401
 from horovod_tpu.diagnostics.watchdog import (  # noqa: F401
     Watchdog,
     ensure_watchdog,

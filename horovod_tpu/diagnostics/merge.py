@@ -188,9 +188,8 @@ def merge_shards(paths: Sequence[str],
 
 
 def find_shards(directory: str) -> List[str]:
-    """Shard files under ``directory`` (the per-rank naming both the
-    host timeline and bench use: ``*rank<r>*.json``), excluding
-    previously merged outputs."""
+    """Shard files under ``directory`` (the host timeline's per-rank
+    naming: ``*rank<r>*.json``), excluding previously merged outputs."""
     out = []
     for path in glob.glob(os.path.join(directory, "*.json")):
         base = os.path.basename(path)
@@ -199,16 +198,3 @@ def find_shards(directory: str) -> List[str]:
         if re.search(r"rank[._-]?\d+", base):
             out.append(path)
     return sorted(out)
-
-
-def merge_directory(directory: str,
-                    out_path: Optional[str] = None) -> Optional[str]:
-    """Merge every shard found in ``directory`` into
-    ``out_path`` (default ``<directory>/merged_trace.json``).  Returns
-    the output path, or None when no shards exist."""
-    paths = find_shards(directory)
-    if not paths:
-        return None
-    out_path = out_path or os.path.join(directory, "merged_trace.json")
-    merge_shards(paths, out_path)
-    return out_path

@@ -19,7 +19,7 @@ Seven layers (see ``docs/OBSERVABILITY.md``):
   the series: step-time drift, throughput regression, persistent
   straggler, exposed-comm growth.
 * :mod:`~horovod_tpu.metrics.mfu` — chip peak FLOPs + compiled-HLO FLOPs
-  counting shared by ``bench.py`` and the train-loop telemetry.
+  counting for the train-loop telemetry.
 """
 
 from horovod_tpu.metrics.registry import (  # noqa: F401

@@ -11,7 +11,7 @@ over the points the time-series layer records
 * ``throughput_regression`` — units/s falls below the rolling baseline;
 * ``exposed_comm_growth`` — the exposed-communication fraction of the
   step (``hvd_overlap_exposed_comm_seconds`` / step time) grows — the
-  overlap schedule is losing (docs/PERF.md "Overlap & bucketing");
+  overlap schedule (``train/overlap.py``) is losing;
 * ``persistent_straggler`` — the fleet view charges the SAME rank as
   slowest for N consecutive aggregation windows (fed by the fleet
   aggregator on rank 0, :mod:`horovod_tpu.metrics.fleet`);

@@ -1,10 +1,10 @@
 """MFU accounting helpers: chip peak FLOPs + compiled-HLO FLOPs counting.
 
-Shared between the benchmark harness (``bench.py``) and the train-loop
-telemetry (:class:`horovod_tpu.train.callbacks.TelemetryCallback`), so the
-two report the same MFU for the same program (MLPerf TPU-pod scaling work
-emphasizes step-time/MFU accounting as the scaling metric — PAPERS.md,
-arXiv:1909.09756).
+Used by the train-loop telemetry
+(:class:`horovod_tpu.train.callbacks.TelemetryCallback`; MLPerf TPU-pod
+scaling work emphasizes step-time/MFU accounting as the scaling metric —
+PAPERS.md, arXiv:1909.09756).  The benchmark's ``mfu_pct`` is computed
+from shapes in ``benchmarks/chip/``, not here.
 """
 
 from __future__ import annotations

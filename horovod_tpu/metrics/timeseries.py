@@ -12,12 +12,10 @@ survives the process and is queryable offline::
 
     python -m horovod_tpu.metrics history --dir $HVD_TPU_OBS_DIR
 
-Producers: ``StepTimer.end_step`` (every training loop with telemetry),
-``bench.py``'s measured window, and the fleet aggregator's per-push
-fleet summaries on rank 0.  Consumers: the anomaly engine
-(:mod:`horovod_tpu.metrics.anomaly`) detects drift over these points,
-the CLI renders them, and ``ci/check_bench.py`` gates on the bench's
-recorded trajectory instead of only its last point.
+Producers: ``StepTimer.end_step`` (every training loop with telemetry)
+and the fleet aggregator's per-push fleet summaries on rank 0.
+Consumers: the anomaly engine (:mod:`horovod_tpu.metrics.anomaly`)
+detects drift over these points, and the CLI renders them.
 
 Stdlib-only, like the rest of the metrics plane.
 """
@@ -240,7 +238,7 @@ def recorder() -> StepSeriesRecorder:
 def record_step(step: int, seconds: float, units: float = 0.0,
                 **extra: Any) -> None:
     """Module-level convenience for the instrumented call sites
-    (``StepTimer.end_step``, bench's measured window); never raises."""
+    (``StepTimer.end_step``); never raises."""
     try:
         recorder().record_step(step, seconds, units, **extra)
     except Exception:

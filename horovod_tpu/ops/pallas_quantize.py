@@ -193,7 +193,7 @@ def block_dequantize(vals: jax.Array, scales: jax.Array,
 
 
 # ---------------------------------------------------------------------------
-# Fused dequantize + optimizer apply (docs/PERF.md "Overlap & bucketing")
+# Fused dequantize + optimizer apply (the tail of train/overlap.py)
 #
 # After a quantized gradient exchange the tail used to be three separate
 # HBM sweeps: dequantize codes -> fp32 gradient, momentum update, delta.

@@ -100,7 +100,7 @@ def build_mesh(spec: Optional[MeshSpec] = None,
 def dp_pp_mesh(dp: int = -1, pp: int = 1,
                devices: Optional[Sequence[jax.Device]] = None) -> Mesh:
     """The documented two-axis dp x pp mesh for pipelined data-parallel
-    training (docs/PERF.md "Pipeline parallelism"): ``dp`` replicas each
+    training (``parallel/pipeline.py``): ``dp`` replicas each
     running a ``pp``-deep pipeline. ``dp=-1`` (default) absorbs the
     remaining devices, so ``dp_pp_mesh(pp=4)`` on 8 devices is the
     2x4 layout. ``pp`` is innermost (the canonical axis order), keeping

@@ -8,7 +8,7 @@ same pytree with a leading stage dim sharded over ``pp`` — so the schedule
 is a compiled ``lax.scan``, with no host round-trips between ticks (the
 whole pipeline is one XLA program; ICI transfers overlap with stage compute).
 
-Schedule cost model (docs/PERF.md "Pipeline parallelism"): because the
+Schedule cost model: because the
 program is SPMD, every device executes every tick's full body with
 invalid units masked — masked compute costs the same time as real
 compute. A combined forward+backward tick (the 1F1B family) therefore

@@ -148,7 +148,7 @@ class ParallelPlan:
 
     def bubble_fraction(self) -> float:
         """Analytic fill+drain bubble fraction of this plan's schedule
-        (0.0 when pp == 1; docs/PERF.md "Pipeline parallelism")."""
+        (0.0 when pp == 1; cost model in ``parallel/pipeline.py``)."""
         from horovod_tpu.parallel.pipeline import bubble_fraction
         return bubble_fraction(self.schedule, self.pp,
                                self.n_microbatches, self.virtual_stages)

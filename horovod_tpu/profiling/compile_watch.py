@@ -297,11 +297,10 @@ def uninstall() -> None:
 
 def totals() -> dict:
     """Process-lifetime compile totals. ``compiles`` / ``seconds_total``
-    are JAX's backend-compile events (``hvd_compile_total``; what
-    ``bench.py`` records as ``compile_seconds``: measured, not the wall
-    clock of a phase that also ran the first step) and on a persistent
-    -cache hit hold the read in the compile's place; ``cache_misses`` is
-    jit's *tracing*-cache misses. ``trace_seconds``, ``lower_seconds``,
+    are JAX's backend-compile events (``hvd_compile_total``: measured,
+    not the wall clock of a phase that also ran the first step) and on a
+    persistent-cache hit hold the read in the compile's place;
+    ``cache_misses`` is jit's *tracing*-cache misses. ``trace_seconds``, ``lower_seconds``,
     ``cache_read_seconds``, ``persistent_cache_hits`` and
     ``persistent_cache_misses`` are the module docstring's split."""
     with _LOCK:

@@ -15,9 +15,8 @@ Three pieces live here:
 
 * :func:`quantile` — THE one nearest-rank quantile implementation
   (fraction ``q`` in ``[0, 1]``).  The SLO plane's p99, the rollout
-  comparator's per-version p99 and the bench artifact's p99 gated by
-  ``ci/check_bench.py --serving`` all route through it, so "p99" means
-  the same thing everywhere.
+  comparator's per-version p99 and the windows' TTFT percentiles all
+  route through it, so "p99" means the same thing everywhere.
 * :class:`WindowBooks` + :class:`ExemplarRing` — per-window stage
   aggregation (sums, shares, dominant stage) and a bounded ring of
   tail exemplars: the worst requests per window with trace id + full
@@ -82,7 +81,8 @@ def close_books(e2e_s: float, stages: Dict[str, float]) -> Dict[str, float]:
 
 def residual_fraction(e2e_s: float, stages: Dict[str, float]) -> float:
     """Fraction of ``e2e_s`` the named stages do NOT cover (the
-    books-close number ``check_bench --serving`` gates < 10%)."""
+    books-close number; ``tests/test_serving_ledger.py`` holds the
+    windows' aggregate under 10%)."""
     if e2e_s <= 0:
         return 0.0
     attributed = sum(max(0.0, float(v)) for k, v in stages.items()

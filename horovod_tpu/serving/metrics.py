@@ -304,9 +304,8 @@ def inc_gen_finished(reason: str) -> None:
 
 
 #: THE one nearest-rank quantile — canonical implementation lives in
-#: :mod:`horovod_tpu.serving.ledger` (the SLO plane, the rollout
-#: comparator and ``ci/check_bench.py --serving`` all share it, so
-#: "p99" means the same thing everywhere)
+#: :mod:`horovod_tpu.serving.ledger` (the SLO plane and the rollout
+#: comparator share it, so "p99" means the same thing everywhere)
 percentile = ledger.quantile
 
 

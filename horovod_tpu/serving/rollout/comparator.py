@@ -31,8 +31,8 @@ import urllib.request
 from typing import Dict, List, Optional, Sequence, Tuple
 
 # one quantile implementation serves the whole SLO plane (the
-# LatencyWindow, this comparator, and ci/check_bench --serving): a
-# verdict replayed through any of them sees the same p99
+# LatencyWindow and this comparator): a verdict replayed through
+# either sees the same p99
 from horovod_tpu.serving.ledger import dominant_stage, quantile as percentile
 
 Endpoint = Tuple[str, int]

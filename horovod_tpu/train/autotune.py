@@ -531,9 +531,8 @@ class AutotuneController:
         if self._step_in_plan >= 1 + self._trial_steps:
             # plan's window complete. Score = MIN over the window:
             # contention only ever adds time, so the fastest observed
-            # step is the cleanest estimate of what the plan can do —
-            # the same best-of estimator bench.py and the overlap bench
-            # use (a mean/median would let one scheduler hiccup on a
+            # step is the cleanest estimate of what the plan can do
+            # (a mean/median would let one scheduler hiccup on a
             # loaded box evict the true winner)
             if self._samples:
                 score = min(self._samples)
@@ -741,9 +740,7 @@ def make_autotuned_train_step(loss_fn, optimizer, mesh,
 
     The explicit communication kwargs (``bucket_bytes`` / ``algorithm``
     / ``compression`` / ``small_floor``) become the BASELINE candidate —
-    the search can only confirm or beat the hand-set config, and the
-    tuned-vs-default CI gate (``ci/check_bench.py --tuned``) holds it to
-    that.
+    the search can only confirm or beat the hand-set config.
     """
     from horovod_tpu.common.topology import detect_topology
     from horovod_tpu.ops.reduce_op import Average

@@ -213,9 +213,9 @@ class TelemetryCallback:
     Call ``on_step_begin()`` / ``on_step_end()`` from any loop (same hook
     style as the other callbacks in this module). FLOPs for MFU are
     resolved lazily on the first completed step from ``lowerable`` — a
-    zero-arg callable returning ``(jitted, args)`` exactly like
-    ``bench.py``'s ``_Run.lowerable`` — via the compiled executable's
-    cost analysis (:func:`horovod_tpu.metrics.mfu.hlo_flops_per_device`);
+    zero-arg callable returning ``(jitted, args)`` — via the compiled
+    executable's cost analysis
+    (:func:`horovod_tpu.metrics.mfu.hlo_flops_per_device`);
     a failure there just leaves MFU unset, never breaks the loop.
 
     ``log_every_n_steps`` > 0 logs a one-line telemetry summary (step

@@ -1,6 +1,6 @@
 """Back-compat shim: the compression subsystem moved to
 :mod:`horovod_tpu.compression` (quantizers, error feedback, Pallas
-kernels, wire paths — see docs/PERF.md "Gradient compression").
+kernels, wire paths).
 
 This module keeps the original import surface
 (``horovod_tpu.train.compression.Compression`` et al., mirroring the

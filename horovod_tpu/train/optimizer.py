@@ -276,8 +276,8 @@ def DistributedGradTransform(op: ReduceOp = Average,
     eager wire then moves quantized payloads), or
     ``ErrorFeedback(codec)``: the transform state grows a per-leaf fp32
     residual and every step compresses ``grad + residual``, carrying
-    the quantization error to the next step (so lossy codecs converge —
-    docs/PERF.md "Gradient compression"). With EF the in-graph
+    the quantization error to the next step (so lossy codecs
+    converge). With EF the in-graph
     quantize∘dequantize runs in EVERY regime, including global-SPMD jit
     where the sync itself is an identity; a bare (non-EF) quantizer
     compresses the eager wire only — traced regimes leave gradients to
@@ -342,7 +342,7 @@ def DistributedOptimizer(optimizer: optax.GradientTransformation,
     compression seam — combining them raises.
 
     ``autotune=True`` warm-starts the communication knobs from the
-    persistent plan cache (docs/PERF.md "Autotuning"): at ``init`` the
+    persistent plan cache (``train/autotune.py``): at ``init`` the
     gradient tree's fingerprint is looked up in
     ``HVD_TPU_AUTOTUNE_CACHE_DIR`` and a hit applies the tuned
     ``bucket_bytes`` (and — when you passed no ``compression`` of your
@@ -461,7 +461,7 @@ def _warm_start_optimizer(optimizer, *, op, process_set, compression,
                     if codec is not None and \
                             compression is Compression.none:
                         # lossy codec on the wire needs the residual
-                        # carry to converge (docs/PERF.md)
+                        # carry to converge
                         comp = ErrorFeedback(codec)
                     from horovod_tpu.diagnostics.flight_recorder import \
                         record_event

@@ -280,12 +280,11 @@ def make_overlap_train_step(loss_fn: Callable, optimizer, mesh,
     ``step(params, opt_state, batch) -> (params, opt_state, loss)``
     with the batch's leading axis sharded over ``axis_name`` and
     divisible by ``n_micro`` per shard. Keyword knobs mirror
-    :func:`pipelined_accumulate` (see docs/PERF.md "Overlap &
-    bucketing").
+    :func:`pipelined_accumulate`.
 
     ``autotune`` hands the communication knobs (``bucket_bytes``,
     ``algorithm``, ``compression`` codec, ``small_floor``) to the online
-    plan search (docs/PERF.md "Autotuning"): pass ``True`` for the
+    plan search (``train/autotune.py``): pass ``True`` for the
     default search, or a :class:`horovod_tpu.train.autotune.AutotuneOptions`.
     The returned step then measures candidate plans during early steps,
     locks the winner, and persists it to the plan cache; explicit values

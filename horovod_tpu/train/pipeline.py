@@ -16,8 +16,7 @@ the flagship transformer's scanned blocks already use):
 Layer-major is what makes (pp, virtual_stages) SEARCHABLE axes: the
 same params restack into any ``pp x v`` split by reshaping the leading
 dim, so the autotuner can score ``dp8/pp1`` against ``dp2xpp4/1f1b/m8``
-against ``dp4xpp2/interleaved`` without touching the model
-(docs/PERF.md "Pipeline parallelism").
+against ``dp4xpp2/interleaved`` without touching the model.
 
 Inside the step, stage gradients leave the pipeline scan through
 :func:`~horovod_tpu.train.overlap.bucketed_grad_sync` over the dp axis
@@ -71,30 +70,6 @@ def _pipeline_metrics(plan) -> None:
                 1.0 if s == plan.schedule else 0.0)
     except Exception:   # metrics are telemetry, never a step failure
         log.debug("pipeline metrics unavailable", exc_info=True)
-
-
-def record_measured_bubble(measured: float) -> None:
-    """Land the MEASURED bubble fraction of the active pipeline step on
-    /metrics next to the analytic one (docs/OBSERVABILITY.md "Pipeline
-    metrics").  Derivation is the overlap_bench attribution pattern
-    (``benchmarks/overlap_bench.py``): time the same model + global
-    batch at ``pp=1`` — per-device compute is identical
-    (``n_layers·M·rows/pp`` either way) with zero pipeline
-    dependencies — and ``1 − t_compute / t_pipelined`` is the fraction
-    of the pipelined step the devices spent NOT computing.  The
-    analytic gauge says what the schedule should cost; this one says
-    what it did — drift between them is remat/comm overhead the tick
-    model cannot see (``ci/check_bench.py --pipeline`` prints both)."""
-    try:
-        from horovod_tpu.metrics.registry import default_registry
-        default_registry().gauge(
-            "hvd_pipeline_bubble_fraction_measured",
-            help="measured bubble fraction of the active pipeline "
-                 "step: 1 - compute-only (pp=1) step time / pipelined "
-                 "step time").set(
-            max(0.0, min(1.0, float(measured))))
-    except Exception:
-        log.debug("measured-bubble gauge unavailable", exc_info=True)
 
 
 def stage_layout_permutation(n_layers: int, pp: int,
@@ -220,7 +195,7 @@ def make_pipeline_train_step(layer_fn: Callable, loss_fn: Callable,
     signature, same microbatch-accumulation semantics, bucket overlap
     engine and all. ``autotune`` (or ``HVD_TPU_AUTOTUNE_MESH=1``) hands
     (pp, n_microbatches, schedule) AND the communication knobs to the
-    parallel-plan search (docs/PERF.md "Autotuning"); an explicit
+    parallel-plan search (``train/autotune.py``); an explicit
     ``plan=`` pins the layout with zero search.
 
     ``dp_sync="bucketed"`` (default) routes stage gradients through

@@ -1,7 +1,7 @@
 """One rule for where JAX's persistent compilation cache lives.
 
-``chip_smoke.py``, ``bench.py`` and ``tests/conftest.py`` all call
-:func:`enable`; nothing else in the repo names a cache directory.
+``chip_smoke.py``, ``benchmarks/chip/run.py`` and ``tests/conftest.py``
+all call :func:`enable`; nothing else in the repo names a cache directory.
 
 * ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself, so nothing is
   touched and no directory is set in code (a code-side directory would

@@ -130,7 +130,7 @@ _PATHS = {
     (4096, 4096, 16, 128, True, False): "flash",   # olmoe-1b-7b.s4096
     (128, 128, 16, 64, False, True): "xla",        # bert-large.s128: too few
     (128, 256, 16, 64, False, True): "xla",        # scores to win (PR 27)
-    (2048, 2048, 16, 64, True, False): "xla",      # bench.py's gpt default
+    (2048, 2048, 16, 64, True, False): "xla",      # causal at head_dim 64
     (512, 512, 16, 64, True, False): "xla",        # causal is not the block's
     (1024, 1024, 16, 64, False, True): "xla",      # one score tile too many
     (300, 300, 16, 64, False, True): "xla",

@@ -1,0 +1,181 @@
+"""The contract between the product and the one client that measures it.
+
+``benchmarks/chip/`` is how this repo is measured (``BENCHMARK.json``), and
+tier-1 does not run it: a rename of anything it calls, or of a name it looks
+for inside the program, would otherwise be found on the chip, as a cell that
+fails.  The cases are collected from the benchmark's own source, so the list
+cannot go stale:
+
+* every ``(module, name)`` that ``run.py`` and the adapters import from
+  ``horovod_tpu`` or reach through such an import (``hvd.init``,
+  ``compile_watch.totals``) must resolve, and accept the positional count
+  and the keywords of every call site (``scan_steps=1``, ``buffer_size=``);
+* every kernel name, phase and host span that a metric file
+  (``layer_metrics/``, ``phase_metrics/``) or ``scope_reduce.py`` looks for
+  must be a name the product gives: a kernel's ``name=`` / ``*_NAME`` in
+  ``horovod_tpu/ops`` or ``parallel/moe.py``, a constant of
+  ``profiling/scopes.py``.
+
+CPU only, nothing is traced or compiled.
+"""
+
+import ast
+import glob
+import importlib
+import inspect
+import json
+import os
+import re
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+CHIP = os.path.join(REPO, "benchmarks", "chip")
+CLIENT_FILES = [os.path.join(CHIP, "run.py")] + sorted(
+    glob.glob(os.path.join(CHIP, "adapters", "*.py")))
+KERNEL_FILES = [os.path.join(REPO, "horovod_tpu", *p) for p in (
+    ("ops", "pallas_attention.py"), ("ops", "pallas_xent.py"),
+    ("parallel", "moe.py"))]
+#: read by name in a run's ``breakdown`` (PERF.md §3) until a metric file
+#: names them
+BLOCK_KERNELS = ("hvd_block_attention", "hvd_block_attention_bwd")
+
+
+def _parse(path):
+    with open(path) as f:
+        return ast.parse(f.read(), path)
+
+
+def _dotted(node, aliases):
+    """``alias.attr`` -> ``(what alias stands for, attr)``; ``name`` imported
+    from the product -> its ``(module, name)``."""
+    if isinstance(node, ast.Attribute) and isinstance(node.value, ast.Name) \
+            and node.value.id in aliases:
+        return ".".join(aliases[node.value.id]), node.attr
+    if isinstance(node, ast.Name) and node.id in aliases \
+            and len(aliases[node.id]) == 2:
+        return aliases[node.id]
+    return None
+
+
+def _imports():
+    """{(module, name): [(file:line, n positional, keywords), ...]}: what
+    the client files take from ``horovod_tpu``, with every call's shape."""
+    uses = {}
+    for path in CLIENT_FILES:
+        tree, rel = _parse(path), os.path.relpath(path, REPO)
+        aliases = {}      # local name -> (module,) or (module, name)
+        for node in ast.walk(tree):
+            if isinstance(node, ast.Import):
+                for a in node.names:
+                    if a.name.split(".")[0] == "horovod_tpu":
+                        aliases[a.asname or a.name] = (a.name,)
+            elif isinstance(node, ast.ImportFrom) and node.module \
+                    and node.module.split(".")[0] == "horovod_tpu":
+                for a in node.names:
+                    aliases[a.asname or a.name] = (node.module, a.name)
+                    uses.setdefault((node.module, a.name), [])
+        for node in ast.walk(tree):
+            if isinstance(node, ast.Attribute) and _dotted(node, aliases):
+                uses.setdefault(_dotted(node, aliases), [])
+            elif isinstance(node, ast.Call) and _dotted(node.func, aliases):
+                uses.setdefault(_dotted(node.func, aliases), []).append((
+                    f"{rel}:{node.lineno}",
+                    sum(not isinstance(a, ast.Starred) for a in node.args),
+                    [k.arg for k in node.keywords if k.arg]))
+    return uses
+
+
+IMPORTS = _imports()
+
+
+def _resolve(module, name):
+    try:
+        return getattr(importlib.import_module(module), name)
+    except AttributeError:
+        return importlib.import_module(f"{module}.{name}")
+
+
+@pytest.mark.parametrize("module,name", sorted(IMPORTS),
+                         ids=lambda v: v.replace("horovod_tpu", "hvd"))
+def test_what_the_benchmark_imports_resolves(module, name):
+    obj = _resolve(module, name)
+    for where, n_positional, keywords in IMPORTS[(module, name)]:
+        try:
+            inspect.signature(obj).bind_partial(
+                *[None] * n_positional, **dict.fromkeys(keywords))
+        except TypeError as e:
+            pytest.fail(f"{where} calls {module}.{name} with "
+                        f"{n_positional} positional and {keywords}: {e}")
+
+
+def test_the_collection_sees_the_step_factories():
+    """The walk above is only worth its cases if it finds the calls: the
+    two it exists for, with the keyword that pins ``scan_steps``."""
+    bert = IMPORTS[("horovod_tpu.models.bert", "make_bert_train_step")]
+    assert bert and all("scan_steps" in kw for _w, _n, kw in bert), bert
+    assert IMPORTS[("horovod_tpu.models.transformer", "make_train_step")]
+    assert IMPORTS[("horovod_tpu", "init")]
+    prefetch = IMPORTS[("horovod_tpu.data.data_loader", "device_prefetch")]
+    assert any("buffer_size" in kw for _w, _n, kw in prefetch), prefetch
+
+
+def _names_looked_for():
+    """[(kind, name, where)]: kernel names, phases and host spans the
+    benchmark's metric files and ``scope_reduce.py`` look for."""
+    found = {}
+    for path in sorted(glob.glob(os.path.join(CHIP, "*_metrics", "*.json"))):
+        with open(path) as f:
+            read = json.load(f)["read"]
+        rel = os.path.relpath(path, CHIP)
+        ops = read.get("trace_ops")
+        if isinstance(ops, str) and re.fullmatch(r"hvd_\w+", ops):
+            found.setdefault(("kernel", ops), rel)
+        phase = (read.get("trace_scope") or {}).get("phase")
+        if phase:
+            found.setdefault(("phase", phase), rel)
+        if read.get("host_span"):
+            found.setdefault(("span", read["host_span"]), rel)
+    for node in ast.walk(_parse(os.path.join(CHIP, "scope_reduce.py"))):
+        if isinstance(node, ast.Constant) and isinstance(node.value, str) \
+                and re.fullmatch(r"hvd(\.[a-z_]+)+", node.value):
+            found.setdefault(("phase", node.value), "scope_reduce.py")
+    for name in BLOCK_KERNELS:
+        found.setdefault(("kernel", name), "PERF.md §3")
+    return [(kind, name, where) for (kind, name), where in sorted(
+        found.items())]
+
+
+def _kernel_names():
+    """Every ``name="hvd_..."`` keyword and ``*_NAME = "hvd_..."`` constant
+    in the files that hold the kernels."""
+    names = set()
+    for path in KERNEL_FILES:
+        for node in ast.walk(_parse(path)):
+            value = None
+            if isinstance(node, ast.keyword) and node.arg == "name":
+                value = node.value
+            elif isinstance(node, ast.Assign) and any(
+                    isinstance(t, ast.Name) and t.id.endswith("_NAME")
+                    for t in node.targets):
+                value = node.value
+            if isinstance(value, ast.Constant) \
+                    and isinstance(value.value, str):
+                names.add(value.value)
+    return names
+
+
+LOOKED_FOR = _names_looked_for()
+KERNEL_NAMES = _kernel_names()
+
+
+@pytest.mark.parametrize("kind,name,where", LOOKED_FOR,
+                         ids=[name for _kind, name, _where in LOOKED_FOR])
+def test_what_the_benchmark_looks_for_is_what_the_program_says(
+        kind, name, where):
+    from horovod_tpu.profiling import scopes
+    said = {"kernel": KERNEL_NAMES, "phase": scopes.DEVICE_PHASES,
+            "span": scopes.HOST_SPANS}[kind]
+    assert name in said, (
+        f"benchmarks/chip/{where} looks for the {kind} {name!r}; the "
+        f"product has {sorted(said)}")

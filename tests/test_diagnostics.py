@@ -16,7 +16,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from horovod_tpu.diagnostics.flight_recorder import FlightRecorder  # noqa: E402
 from horovod_tpu.diagnostics import spans  # noqa: E402
 from horovod_tpu.diagnostics.merge import (load_shard,  # noqa: E402
-                                           merge_directory, merge_shards)
+                                           merge_shards)
 from horovod_tpu.diagnostics.watchdog import Watchdog  # noqa: E402
 
 
@@ -187,15 +187,11 @@ def test_merge_directory_and_cli(tmp_path):
     ev = [{"ph": "B", "name": "A", "cat": "c", "tid": "x", "ts": 0.0}]
     _shard(tmp_path / "timeline.rank0.json", 0, 10.0, 0.0, list(ev))
     _shard(tmp_path / "timeline.rank1.json", 1, 10.0, 0.0, list(ev))
-    out = merge_directory(str(tmp_path))
-    assert out and out.endswith("merged_trace.json")
+    from horovod_tpu.diagnostics.__main__ import main
+    out = str(tmp_path / "cli_merged.json")
+    assert main(["merge", "--dir", str(tmp_path), "-o", out]) == 0
     doc = json.load(open(out))
     assert len({e["pid"] for e in doc["traceEvents"]}) >= 2
-    # the CLI drives the same path
-    from horovod_tpu.diagnostics.__main__ import main
-    out2 = str(tmp_path / "cli_merged.json")
-    assert main(["merge", "--dir", str(tmp_path), "-o", out2]) == 0
-    assert json.load(open(out2))["traceEvents"]
 
 
 def test_timeline_shard_roundtrip(tmp_path):

@@ -14,21 +14,18 @@ replica ``/generate`` path (roundtrip, duplicate replay, concurrent
 duplicates joining one in-flight decode, 400 on oversized prompts),
 router ``submit_generate`` exactly-once accounting with
 ``tokens_emitted`` on the audit line, lifecycle trace-span coverage,
-the per-phase metrics, and the ``check_bench --serving-gen`` gate.
+and the per-phase metrics.
 
 Everything here runs in-process on the 8-virtual-device CPU mesh; the
 demo model is a tiny fp32 dense transformer so parity is exact.
 """
 
 import json
-import os
 import threading
 import time
 
 import numpy as np
 import pytest
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(autouse=True)
@@ -505,83 +502,3 @@ def test_router_generate_exactly_once_audit_and_trace_spans(gen_replica):
     assert names.count("gen_decode_step") == 4  # token 1 is prefill's
     finish = [s for s in spans if s["name"] == "gen_finish"][0]
     assert finish["attrs"]["tokens_emitted"] == 5
-
-
-# -- the check_bench --serving-gen gate ---------------------------------------
-def _gen_doc(**over):
-    doc = {"bench": "serving_generate", "requests": 16, "failed": 0,
-           "n_slots": 4, "prefill_chunk": 8, "total_tokens": 150,
-           "duration_s": 0.1, "tokens_per_s": 1500.0,
-           "ttft_p50_s": 0.03, "ttft_p99_s": 0.06,
-           "itl_p50_s": 0.001, "itl_p99_s": 0.003,
-           "slot_occupancy_mean": 0.85, "decode_steps": 40,
-           "decode_compiles": 1, "speedup": 1.2,
-           "baseline_tokens_per_s": 1250.0}
-    doc.update(over)
-    return doc
-
-
-def _gate():
-    import sys as _sys
-    _sys.path.insert(0, REPO)
-    try:
-        import ci.check_bench as cb
-    finally:
-        _sys.path.remove(REPO)
-    return cb
-
-
-def test_check_bench_serving_gen_gate(tmp_path):
-    cb = _gate()
-    # extraction: raw JSON and a captured BENCH_SERVE_GEN line both
-    # load; a BENCH_SERVE (request-level) line does NOT
-    raw = tmp_path / "BENCH_SERVE_GEN.json"
-    raw.write_text(json.dumps(_gen_doc()))
-    assert cb._load_serving_gen_doc(str(raw))["speedup"] == 1.2
-    cap = tmp_path / "out.txt"
-    cap.write_text("noise\nBENCH_SERVE {\"bench\": \"serving\"}\n"
-                   "BENCH_SERVE_GEN " + json.dumps(_gen_doc()) + "\n")
-    assert cb._load_serving_gen_doc(str(cap))["requests"] == 16
-    other = tmp_path / "serve_only.txt"
-    other.write_text("BENCH_SERVE " + json.dumps({"p99_s": 1}) + "\n")
-    assert cb._load_serving_gen_doc(str(other)) is None
-    # clean + explicit baseline: OK
-    assert cb.serving_gen_main(["--serving-gen", str(raw),
-                                "--baseline", str(raw)]) == 0
-    # failed requests / compile churn / no speedup all refuse
-    assert cb.check_serving_gen(_gen_doc(failed=2), None, 0.5)
-    assert cb.check_serving_gen(_gen_doc(decode_compiles=2), None, 0.5)
-    assert cb.check_serving_gen(_gen_doc(decode_compiles=0), None, 0.5)
-    assert cb.check_serving_gen(_gen_doc(speedup=0.97), None, 0.5)
-    assert cb.check_serving_gen(_gen_doc(speedup=None), None, 0.5)
-    # tokens/s regression beyond tolerance fails, inside passes
-    base = _gen_doc(tokens_per_s=2000.0)
-    assert cb.check_serving_gen(_gen_doc(tokens_per_s=900.0), base, 0.5)
-    assert not cb.check_serving_gen(_gen_doc(tokens_per_s=1500.0),
-                                    base, 0.5)
-    # end to end: a dirty artifact fails through main
-    bad = tmp_path / "bad.json"
-    bad.write_text(json.dumps(_gen_doc(decode_compiles=3)))
-    assert cb.serving_gen_main(["--serving-gen", str(bad),
-                                "--baseline", str(raw)]) == 1
-
-
-def test_serving_gen_gate_skips_null_baselines_loudly(tmp_path,
-                                                      monkeypatch,
-                                                      capsys):
-    """The --goodput loud-skip contract: auto-discovery must SAY which
-    committed artifacts it skipped and why — a silent skip reads as
-    "compared against the last round" when it wasn't."""
-    cb = _gate()
-    (tmp_path / "BENCH_SERVE_GEN_r2.json").write_text(
-        json.dumps(_gen_doc(tokens_per_s=None)))   # failure artifact
-    (tmp_path / "BENCH_SERVE_GEN_r1.json").write_text(
-        json.dumps(_gen_doc(tokens_per_s=1400.0)))
-    new = tmp_path / "new.json"
-    new.write_text(json.dumps(_gen_doc()))
-    monkeypatch.setattr(cb, "REPO", str(tmp_path))
-    assert cb.serving_gen_main(["--serving-gen", str(new)]) == 0
-    out = capsys.readouterr().out
-    assert "skipping BENCH_SERVE_GEN_r2.json" in out
-    assert "null tokens/s" in out
-    assert "BENCH_SERVE_GEN_r1.json" in out        # the one it used

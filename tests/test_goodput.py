@@ -20,7 +20,6 @@ import pytest
 from horovod_tpu.metrics import goodput
 from horovod_tpu.metrics.goodput import CATEGORIES, GoodputLedger
 from horovod_tpu.metrics.registry import Registry, default_registry
-from horovod_tpu.profiling import attribution
 
 
 @pytest.fixture(autouse=True)
@@ -240,58 +239,6 @@ def test_autopsy_summary_embeds_flushed_ledger(monkeypatch, tmp_path):
     assert json.load(open(f"{bundle2}/{s2[0]}"))["goodput"] is None
 
 
-# -- roofline MFU attribution ------------------------------------------------
-
-def _snapshot_doc(wall=100.0, compute=80.0, exposed=10.0, compile_s=5.0,
-                  idle=5.0, steps=50):
-    secs = {c: 0.0 for c in CATEGORIES}
-    secs.update({"compute": compute, "exposed_comm": exposed,
-                 "compile": compile_s, "idle_other": idle})
-    return {"wall_s": wall, "seconds": secs, "steps": steps}
-
-
-def test_attribution_identity_decomposes_one_minus_mfu():
-    att = attribution.attribute(_snapshot_doc(), mfu=0.5)
-    assert att["mfu"] == 0.5 and att["one_minus_mfu"] == 0.5
-    assert sum(att["shares"].values()) == pytest.approx(1.0)
-    # the roofline identity: 1 − MFU = non-compute share + the kernel
-    # inefficiency hiding INSIDE the compute share
-    assert att["kernel_inefficiency"] == pytest.approx(0.8 - 0.5)
-    assert att["non_compute_share"] == pytest.approx(0.2)
-    assert att["one_minus_mfu"] == pytest.approx(
-        att["kernel_inefficiency"] + att["non_compute_share"])
-    assert att["dominating"] == "exposed_comm"
-
-
-def test_attribution_cpu_path_mfu_none():
-    """CPU/bench children have no roofline: shares still attribute, the
-    MFU-derived fields are None (never fabricated)."""
-    att = attribution.attribute(_snapshot_doc())
-    assert att["mfu"] is None and att["one_minus_mfu"] is None
-    assert att["kernel_inefficiency"] is None
-    assert att["shares"]["compute"] == pytest.approx(0.8)
-
-
-def test_attribution_derives_mfu_from_flops():
-    att = attribution.attribute(_snapshot_doc(), flops_per_step=1e9,
-                                peak_flops=1e9)
-    # 1e9 FLOPs x 50 steps / (100 s x 1e9 FLOP/s) = 0.5
-    assert att["mfu"] == pytest.approx(0.5)
-    # measured MFU above the attributed compute share clamps to 0
-    att2 = attribution.attribute(_snapshot_doc(), mfu=0.95)
-    assert att2["kernel_inefficiency"] == 0.0
-
-
-def test_attribution_absent_ledger_is_none():
-    assert attribution.attribute(None) is None
-    assert attribution.attribute({"wall_s": 0.0, "seconds": {}}) is None
-    assert attribution.from_ledger() is None  # plane never ran
-    assert "no ledger data" in attribution.render_lines(None)
-    text = attribution.render_lines(
-        attribution.attribute(_snapshot_doc(), mfu=0.5))
-    assert "mfu=0.500" in text and "kernel_inefficiency" in text
-
-
 # -- goodput_regression detector --------------------------------------------
 
 def _tuned_engine(monkeypatch, consecutive=2):
@@ -475,14 +422,40 @@ def _e2e_env(monkeypatch, tmp_path, profile_on):
     monkeypatch.setenv("HVD_TPU_PROFILE_DIR", str(tmp_path / "profiles"))
 
 
-def _e2e_loop(ckpt, stall_steps=()):
+class _TestClock:
+    """Stands in for the ``time`` module where the ledger, the StepTimer,
+    the re-mesh episode and the chaos stall read it: ``perf_counter``
+    moves only when one of them (or the loop) sleeps, so what a window
+    holds is arithmetic and not the scheduling of a shared CPU."""
+
+    def __init__(self):
+        self._now = 0.0
+
+    def perf_counter(self):
+        return self._now
+
+    def sleep(self, seconds):
+        self._now += seconds
+
+    def __getattr__(self, name):
+        return getattr(time, name)
+
+
+# what the test books for the loop's two pieces of real work
+_COMPILE_S = 0.05
+_CKPT_S = 0.01
+
+
+def _e2e_loop(monkeypatch, ckpt, stall_steps=()):
     """The acceptance loop: 6 ledger windows of 5 steps driven through
     the real StepTimer seam — window 1 pays a REAL jit compile, window
     4 a waited checkpoint save, window 5 a completed re-mesh episode,
     and ``stall_steps`` get an inter-step chaos stall (the input
     pipeline going away BETWEEN envelopes, not inside one — in-step
     time is the step's own claim).  The clean run differs only in the
-    stall."""
+    stall.  Time is a ``_TestClock``: a step is 20 ms of it, and the
+    compile and the save, which really happen and reach the ledger
+    through their real seams, cost ``_COMPILE_S`` and ``_CKPT_S``."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -490,10 +463,27 @@ def _e2e_loop(ckpt, stall_steps=()):
     from horovod_tpu import chaos
     from horovod_tpu.elastic import remesh
     from horovod_tpu.profiling import compile_watch
-    from horovod_tpu.train.callbacks import StepTimer
+    from horovod_tpu.train import callbacks
 
+    clock = _TestClock()
+    for mod in (goodput, callbacks, remesh, chaos):
+        monkeypatch.setattr(mod, "time", clock)
     compile_watch.ensure_installed()
-    timer = StepTimer(registry=Registry())
+    # the compile watcher's total, on the test's clock: _COMPILE_S once
+    # the watcher has seen the loop's compile
+    watched = goodput._compile_seconds_total
+    before = watched()
+    monkeypatch.setattr(goodput, "_compile_seconds_total",
+                        lambda: _COMPILE_S * (watched() > before))
+    # the store's own call after its waited save, on the test's clock
+    note_stall = goodput.note_checkpoint_stall
+
+    def booked_stall(_real_seconds):
+        clock.sleep(_CKPT_S)
+        note_stall(_CKPT_S)
+    monkeypatch.setattr(goodput, "note_checkpoint_stall", booked_stall)
+
+    timer = callbacks.StepTimer(registry=Registry())
     fn = jax.jit(lambda x: jnp.tanh(x) * 2.0 + x)
     x = np.arange(17.0, dtype=np.float32)  # odd shape: forces a compile
     # 33 steps: 6 full 5-step windows + 3 trailing healthy steps so a
@@ -508,12 +498,13 @@ def _e2e_loop(ckpt, stall_steps=()):
         if i == 22:
             remesh.begin("test", old_size=8, generation=0)
             with remesh.phase("rebuild"):
-                time.sleep(0.012)
+                clock.sleep(0.012)
             remesh.mark_recovered(new_size=8, generation=0)
         timer.start_step()
         if i == 0:
             fn(x).block_until_ready()  # the first step pays the compile
-        time.sleep(0.02)
+            clock.sleep(_COMPILE_S)
+        clock.sleep(0.02)
         timer.end_step(32)
     return timer
 
@@ -534,7 +525,8 @@ def test_goodput_e2e_regression_flagged_and_profiled(
     goodput.reset()
     chaos.install(rank=0)
     try:
-        _e2e_loop(ShardedCheckpointer(str(tmp_path / "ckpt"), rank=0,
+        _e2e_loop(monkeypatch,
+                  ShardedCheckpointer(str(tmp_path / "ckpt"), rank=0,
                                       world_size=1),
                   stall_steps=(25, 26, 27))
     finally:
@@ -568,13 +560,6 @@ def test_goodput_e2e_regression_flagged_and_profiled(
     assert trig["kind"] == "goodput_regression"
     assert trig["category"] == "input_wait"
 
-    # the MFU decomposition over the same account (CPU: mfu is None,
-    # the shares still name the dominating loss)
-    att = attribution.from_ledger()
-    assert att is not None and att["mfu"] is None
-    assert att["shares"]["compute"] == pytest.approx(
-        snap["fractions"]["compute"], abs=0.01)
-
 
 def test_goodput_e2e_clean_run_reports_nothing(monkeypatch, tmp_path):
     import horovod_tpu.profiling as profiling
@@ -585,7 +570,8 @@ def test_goodput_e2e_clean_run_reports_nothing(monkeypatch, tmp_path):
     anomaly.reset()
     profiling.reset()
     goodput.reset()
-    _e2e_loop(ShardedCheckpointer(str(tmp_path / "ckpt"), rank=0,
+    _e2e_loop(monkeypatch,
+              ShardedCheckpointer(str(tmp_path / "ckpt"), rank=0,
                                   world_size=1),
               stall_steps=())
     snap = goodput.snapshot(flush_open=True)

@@ -1,6 +1,7 @@
 """Driver-contract tests: dryrun_multichip must compile+run at every device
 count the driver may choose, and entry() must produce a jittable forward."""
 
+import json
 import os
 import subprocess
 import sys
@@ -38,12 +39,11 @@ def test_driver_call_path(capsys, monkeypatch):
         sys.path.remove(REPO)
     out = capsys.readouterr().out
     assert "[dryrun] OK" in out
-    sys.path.insert(0, REPO)
-    try:
-        from ci.check_bench import extract_scaling_curve
-    finally:
-        sys.path.remove(REPO)
-    curve = extract_scaling_curve(out)
+    # the last "[scaling] " line that is JSON (progress lines are not)
+    curve = None
+    for line in out.splitlines():
+        if line.startswith("[scaling] {"):
+            curve = json.loads(line[len("[scaling] "):])
     assert curve and curve["scaling_curve"][0]["world"] == 2
     assert curve["scaling_curve"][0]["samples_per_sec"] > 0
     assert curve["scaling_curve"][0]["samples_per_sec_int8"] > 0

@@ -66,8 +66,8 @@ def test_hier_quantized_inter_hop_within_codec_bound(mesh, topo, op):
     out = _run_hier(mesh, x, topo, op, codec=BlockInt8Quantizer())
     ref = _flat_ref(x, op)
     # one quantization step on the already-reduced inter-host payload:
-    # |err| <= absmax/254 per block (docs/PERF.md "Gradient
-    # compression") — absmax bounded by the reduced tensor's max
+    # |err| <= absmax/254 per block (the int8 codec's bound) —
+    # absmax bounded by the reduced tensor's max
     bound = np.abs(ref).max() / 254 + 1e-6
     assert np.abs(out - ref).max() <= bound
 

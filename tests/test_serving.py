@@ -6,8 +6,8 @@ per-request timeouts), /readyz-vs-/healthz split, in-process replica
 (roundtrip, idempotency, chaos seam, hot weight swap, drain), router
 (retry to a survivor, hedging a slow replica, admission shed,
 exactly-once accounting), the SLO window -> slo_breach ->
-autopilot-scale_out chain, `metrics top`/`history --serving`
-rendering, and the `check_bench --serving` gate.
+autopilot-scale_out chain, and `metrics top`/`history --serving`
+rendering.
 
 Slow (serving/chaos CI tiers; tier-1 budget rule — all multiprocess
 tests are slow-marked): the chaos acceptance pair — (a) SIGKILL one
@@ -27,8 +27,6 @@ import time
 
 import numpy as np
 import pytest
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(autouse=True)
@@ -613,70 +611,6 @@ def test_history_serving_table(tmp_path, monkeypatch, capsys):
     assert rc == 1  # nothing but serving windows in the store
 
 
-# -- bench gate ---------------------------------------------------------------
-def _serving_doc(**over):
-    doc = {"bench": "serving", "replicas": 2, "clients": 4,
-           "duration_s": 5.0, "requests": 1000, "qps": 200.0,
-           "p50_s": 0.002, "p99_s": 0.01, "shed_fraction": 0.0,
-           "failed": 0, "unanswered": 0, "answered_twice": 0,
-           # the request ledger's closed books (ISSUE 19): the gate
-           # refuses an artifact without them
-           "stage_seconds": {"forward": 1.6, "queue": 0.3,
-                             "dispatch": 0.05, "unattributed": 0.05},
-           "stage_unattributed_frac": 0.025,
-           "dominant_stage": "forward"}
-    doc.update(over)
-    return doc
-
-
-def test_check_bench_serving_gate(tmp_path):
-    import sys as _sys
-    _sys.path.insert(0, REPO)
-    try:
-        from ci.check_bench import (_load_serving_doc, check_serving,
-                                    serving_main)
-    finally:
-        _sys.path.remove(REPO)
-    # extraction: raw JSON and captured BENCH_SERVE line both load
-    raw = tmp_path / "BENCH_SERVE.json"
-    raw.write_text(json.dumps(_serving_doc()))
-    assert _load_serving_doc(str(raw))["qps"] == 200.0
-    cap = tmp_path / "out.txt"
-    cap.write_text("noise\nBENCH_SERVE " + json.dumps(_serving_doc())
-                   + "\n")
-    assert _load_serving_doc(str(cap))["qps"] == 200.0
-    # clean + no baseline: OK
-    assert serving_main(["--serving", str(raw)]) == 0
-    # a "clean" number that shed requests is refused
-    assert check_serving(_serving_doc(shed_fraction=0.1), None, 0.5)
-    # failed / zero-drop-audit violations are refused
-    assert check_serving(_serving_doc(failed=3), None, 0.5)
-    assert check_serving(_serving_doc(answered_twice=1), None, 0.5)
-    # p99 regression beyond tolerance fails, inside tolerance passes
-    base = _serving_doc(p99_s=0.005)
-    assert check_serving(_serving_doc(p99_s=0.02), base, 0.5)
-    assert not check_serving(_serving_doc(p99_s=0.007), base, 0.5)
-    # the ledger's books-close gate (ISSUE 19): missing breakdown and
-    # open books both fail; a closed artifact passes (above)
-    assert check_serving(_serving_doc(stage_seconds=None), None, 0.5)
-    assert check_serving(
-        _serving_doc(stage_unattributed_frac=0.25), None, 0.5)
-    assert check_serving(
-        _serving_doc(stage_unattributed_frac=None), None, 0.5)
-    # percentile replay: a sample that agrees passes, one that says the
-    # artifact's p99 math diverged fails
-    good = _serving_doc(latency_sample=[0.002] * 50 + [0.01] * 5)
-    assert not check_serving(good, None, 0.5)
-    bad = _serving_doc(latency_sample=[0.002] * 55, p99_s=0.2)
-    assert any("replay" in p for p in check_serving(bad, None, 0.5))
-    # end to end with a baseline file
-    shed = tmp_path / "shed.json"
-    shed.write_text(json.dumps(_serving_doc(shed_fraction=0.2)))
-    assert serving_main(["--serving", str(shed)]) == 1
-    assert serving_main(["--serving", str(raw), "--baseline",
-                         str(raw)]) == 0
-
-
 def test_chaos_plan_validates_serving_seam():
     from horovod_tpu.chaos import FaultPlanError, parse_plan
     plan = parse_plan(json.dumps({"faults": [
@@ -692,30 +626,6 @@ def test_chaos_plan_validates_serving_seam():
     with pytest.raises(FaultPlanError, match="not valid for seam"):
         parse_plan(json.dumps({"faults": [
             {"seam": "step", "kind": "shed"}]}))
-
-
-@pytest.mark.slow  # spins real traffic for ~3s; serving/chaos tiers
-def test_serving_bench_end_to_end_through_gate(tmp_path):
-    """benchmarks/serving_bench.py (in-process mode) emits a clean
-    BENCH_SERVE artifact that passes the check_bench --serving gate."""
-    import sys as _sys
-    bench_dir = os.path.join(REPO, "benchmarks")
-    _sys.path.insert(0, bench_dir)
-    _sys.path.insert(0, REPO)
-    try:
-        from serving_bench import run_bench
-        from ci.check_bench import check_serving
-    finally:
-        _sys.path.remove(bench_dir)
-        _sys.path.remove(REPO)
-    doc = run_bench(replicas=2, clients=3, duration_s=2.0,
-                    in_process=True, warmup_s=0.5)
-    assert doc["requests"] > 0 and doc["qps"] > 0
-    assert doc["p50_s"] <= doc["p99_s"]
-    problems = check_serving(doc, None, 0.5)
-    assert not problems, problems
-    # and vs itself as baseline (regression band trivially holds)
-    assert not check_serving(doc, doc, 0.5)
 
 
 # -- slow: the chaos acceptance pair ------------------------------------------

@@ -2,7 +2,7 @@
 request ledger").
 
 Fast battery: the shared nearest-rank quantile (one implementation for
-the SLO plane, the rollout comparator and ``check_bench --serving`` —
+the SLO plane, the rollout comparator and the ledger's windows —
 p50/p99 semantics pinned here), close_books/residual/dominant-stage
 units, the bounded tail-exemplar ring + ``/debug/exemplars`` +
 autopsy dump, WindowBooks window accounting, burn-rate SLO hysteresis
@@ -26,8 +26,6 @@ import time
 import numpy as np
 import pytest
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
 
 @pytest.fixture(autouse=True)
 def _clean():
@@ -44,8 +42,8 @@ def _clean():
 def test_quantile_nearest_rank_semantics_pinned():
     """THE shared quantile: nearest-rank over a sorted sequence,
     fraction in [0, 1].  p50 of 1..100 is 51 (index round(.5*99)=50),
-    p99 is 99 (index 98) — pinned so the SLO plane, the comparator and
-    the bench gate can never drift apart on what "p99" means."""
+    p99 is 99 (index 98) — pinned so the SLO plane and the comparator
+    can never drift apart on what "p99" means."""
     from horovod_tpu.serving.ledger import quantile
     assert quantile([], 0.99) == 0.0
     assert quantile([5.0], 0.5) == 5.0
@@ -60,15 +58,13 @@ def test_quantile_nearest_rank_semantics_pinned():
 
 def test_quantile_is_shared_across_all_three_call_sites():
     """serving.metrics.percentile and the rollout comparator's
-    percentile must BE ledger.quantile (not copies), and check_bench's
-    replay gate must import the same function."""
+    percentile must BE ledger.quantile (not copies): with the ledger's
+    own windows, the three places a p99 is computed."""
     from horovod_tpu.serving import ledger
     from horovod_tpu.serving import metrics as smetrics
     from horovod_tpu.serving.rollout import comparator
     assert smetrics.percentile is ledger.quantile
     assert comparator.percentile is ledger.quantile
-    src = open(os.path.join(REPO, "ci", "check_bench.py")).read()
-    assert "from horovod_tpu.serving.ledger import quantile" in src
 
 
 # -- close_books units --------------------------------------------------------

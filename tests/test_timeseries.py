@@ -1,8 +1,8 @@
 """Unit tests for the step time-series history layer
 (docs/OBSERVABILITY.md "Step time-series history"): ring bounds, JSONL
-persistence + rotation + torn-tail tolerance, the sampling stride, the
-``python -m horovod_tpu.metrics`` CLI (history table + one-shot top
-frame), and the bench trajectory gate in ``ci/check_bench.py``."""
+persistence + rotation + torn-tail tolerance, the sampling stride,
+and the ``python -m horovod_tpu.metrics`` CLI (history table +
+one-shot top frame)."""
 
 import json
 import os
@@ -190,44 +190,3 @@ def test_cli_top_render_is_pure():
     assert "step_time_drift×2" in frame
     assert "rank    2" in frame  # per-rank bar chart row
 
-
-# -- bench trajectory gate --------------------------------------------------
-
-def _check_bench():
-    sys.path.insert(0, os.path.join(REPO, "ci"))
-    try:
-        import check_bench
-        return check_bench
-    finally:
-        sys.path.pop(0)
-
-
-def test_trajectory_gate_flags_drift_not_noise():
-    cb = _check_bench()
-    flat = [0.1] * 12
-    noisy = [0.1, 0.12, 0.09, 0.11, 0.1, 0.13, 0.1, 0.09, 0.12, 0.11]
-    drifting = [0.1] * 4 + [0.12] * 4 + [0.2] * 4  # tail 2x the head
-    assert cb.check_trajectory(flat) is None
-    assert cb.check_trajectory(noisy) is None
-    assert cb.check_trajectory(drifting) is not None
-    assert cb.check_trajectory([0.1] * 3) is None  # too short to judge
-    assert cb.check_trajectory("not-a-list") is not None
-    assert cb.check_trajectory([0.1, None, 0.1]) is not None
-
-
-def test_trajectory_cli_gate(tmp_path):
-    good = tmp_path / "good.json"
-    bad = tmp_path / "bad.json"
-    good.write_text(json.dumps(
-        {"value": 1.0, "step_time_series": [0.1] * 12}))
-    bad.write_text(json.dumps(
-        {"value": 1.0, "step_time_series": [0.1] * 6 + [0.3] * 6}))
-    base = [sys.executable, os.path.join(REPO, "ci", "check_bench.py"),
-            "--trajectory"]
-    ok = subprocess.run(base + [str(good)], capture_output=True,
-                        text=True, timeout=60)
-    assert ok.returncode == 0, ok.stdout
-    fail = subprocess.run(base + [str(bad)], capture_output=True,
-                          text=True, timeout=60)
-    assert fail.returncode == 1
-    assert "drift" in fail.stdout
