@@ -34,7 +34,8 @@ Phases (default, one chip):
            kernels at the shapes of both BERT cells, with padded keys and
            a row of nothing else); then two steps of the flagship
            transformer at head_dim 128 with both kernels asserted in the
-           compiled program.
+           compiled program. xent_path says how the LM loss ran (the
+           kernel's rows and chunk and its grid steps, or why XLA).
 
 ``--chips 4`` runs only the four-chip phase and what it is compared with:
 BERT-Large dp=4 against one device at 2 x 512 tokens a chip (the block
@@ -50,7 +51,8 @@ Tolerances (all stated here, none tuned per run):
                     grads; the reference runs at "highest" precision);
                     the block attention kernels the same
   fused xent        loss (f32) abs <= 2e-3 on values ~ log(vocab);
-                    dlogits (bf16) normalized <= 1e-2
+                    dlogits (bf16), and dx / dw through the head form,
+                    normalized <= 1e-2
   int8 codec        scales rtol 1e-6; codes within +-1 (a division that
                     lands on a rounding boundary), <= 0.1% of them off;
                     residual equal to x - codes*scale of the kernel's own
@@ -473,26 +475,43 @@ def _check_block(smoke: Smoke) -> None:
               "block_attention: a row of padded keys moved q or k")
 
 
+def _xent_path(n: int, vocab: int, interpret: bool = False) -> str:
+    """Which implementation the LM loss takes for ``[n, vocab]`` bf16
+    logits on the default backend and, for the kernel, its tile and grid
+    steps (``pallas_xent.xent_path``)."""
+    import jax.numpy as jnp
+    from horovod_tpu.ops.pallas_xent import xent_path
+    path, detail = xent_path(n, vocab, jnp.bfloat16, interpret)
+    return (f"pallas hvd_fused_xent {detail}" if path == "kernel"
+            else f"xla _xla_xent ({detail})")
+
+
 def _check_xent(smoke: Smoke) -> None:
+    """The loss kernel against ``_xla_xent``: the loss and its gradient
+    through the logits form, and through the head form the flagship runs
+    (``x @ w`` inside; dx and dw under a per-row cotangent with zeros)."""
     import jax
     import jax.numpy as jnp
-    from horovod_tpu.ops.pallas_xent import _xla_xent, fused_softmax_xent
+    from horovod_tpu.ops.pallas_xent import (
+        _xla_xent, fused_softmax_xent, head_softmax_xent)
 
     interpret = smoke.rehearsal
     n, vocab = smoke.sizes.xent
-    k1, k2 = jax.random.split(jax.random.PRNGKey(smoke.seed + 1))
+    k1, k2, k3, k4, k5 = jax.random.split(jax.random.PRNGKey(smoke.seed + 1),
+                                          5)
     logits = (4.0 * jax.random.normal(k1, (n, vocab), jnp.float32)
               ).astype(jnp.bfloat16)
     labels = jax.random.randint(k2, (n,), 0, vocab, jnp.int32)
 
-    def kernel(lg, y):   # the public wrapper: pads 32000 to a block multiple
+    def kernel(lg, y):   # whole rows a block: 32000 is not padded
         return fused_softmax_xent(lg, y, interpret=interpret)
 
     got = _run_compiled(smoke, kernel, (logits, labels), "hvd_fused_xent")
     want = jax.jit(_xla_xent)(logits, labels)
     err = float(jnp.max(jnp.abs(got - want)))
     _kernel_line(smoke, "fused_xent", "fwd (abs)", err, XENT_LOSS_ATOL,
-                 shape=(n, vocab), dtype="bfloat16")
+                 shape=(n, vocab), dtype="bfloat16",
+                 xent_path=_xent_path(n, vocab, interpret))
     got = _run_compiled(
         smoke, jax.grad(lambda lg, y: kernel(lg, y).sum()),
         (logits, labels), "hvd_fused_xent")
@@ -500,6 +519,23 @@ def _check_xent(smoke: Smoke) -> None:
         logits, labels)
     _kernel_line(smoke, "fused_xent", "grad dlogits", _rel_err(got, want),
                  XENT_GRAD_TOL)
+
+    width = 256
+    x = jax.random.normal(k3, (n, width), jnp.bfloat16)
+    w = (0.1 * jax.random.normal(k4, (width, vocab), jnp.float32)
+         ).astype(jnp.bfloat16)
+    g = jax.random.uniform(k5, (n,), jnp.float32).at[::7].set(0.0)
+
+    def head(loss):
+        return jax.grad(lambda x, w, y: jnp.sum(loss(x, w, y) * g), (0, 1))
+
+    got = _run_compiled(
+        smoke, head(lambda x, w, y: head_softmax_xent(
+            x, w, y, interpret=interpret)), (x, w, labels), "hvd_fused_xent")
+    want = jax.jit(head(lambda x, w, y: _xla_xent(x @ w, y)))(x, w, labels)
+    for name, got_, want_ in zip(("dx", "dw"), got, want):
+        _kernel_line(smoke, "fused_xent", f"head grad {name}",
+                     _rel_err(got_, want_), XENT_GRAD_TOL)
 
 
 def _check_gmm(smoke: Smoke) -> None:
@@ -723,6 +759,7 @@ def _check_flagship(smoke: Smoke, hvd) -> None:
                else "not checked (rehearsal: the CPU takes the XLA paths)",
                losses=[round(x, 4) for x in losses],
                attention_path=paths,
+               xent_path=_xent_path(B * S, cfg.vocab_size),
                compiled_step_bytes=_step_bytes(compiled.memory_analysis()),
                hbm_live_arrays=_memory(jax.devices()[0]))
 

@@ -248,15 +248,17 @@ def _sharded_softmax_xent(logits_local, targets):
     return jnp.log(se) + m - corr     # [B, S]
 
 
-def _softmax_xent(logits_local, targets):
-    """Dispatch: tp-sharded vocab takes the psum algebra above; a full
-    local vocab takes the fused Pallas kernel (one HBM pass over the
-    logits; auto-falls back off-TPU / untiled — same self-gating pattern
-    as ``pallas_attention.attend``)."""
+def _head_xent(x, head, targets):
+    """Per-token loss of the head ``x @ head``. A tp-sharded vocabulary
+    takes the psum algebra above; a full local one takes the fused Pallas
+    kernel, which leaves the logits' gradient in the logits' buffer for
+    the two backward matmuls (one HBM pass over ``[N, V]``; falls back
+    off-TPU / untiled by ``pallas_xent.xent_path``, the self-gating
+    pattern of ``pallas_attention.attend``)."""
     if _axis_live("tp"):
-        return _sharded_softmax_xent(logits_local, targets)
-    from horovod_tpu.ops.pallas_xent import fused_softmax_xent
-    return fused_softmax_xent(logits_local, targets)
+        return _sharded_softmax_xent(x @ head, targets)       # [B,S,V/tp]
+    from horovod_tpu.ops.pallas_xent import head_softmax_xent
+    return head_softmax_xent(x, head, targets)
 
 
 def _attention_block(p, x, positions, cfg: TransformerConfig):
@@ -406,8 +408,7 @@ def forward_loss_spmd(params, tokens, targets, cfg: TransformerConfig):
             head = params["embed"].astype(cfg.dtype).T
         else:
             head = params["lm_head"].astype(cfg.dtype)
-        logits_local = x @ head                                 # [B,S,V/tp]
-        nll = _softmax_xent(logits_local, targets)              # [B,S]
+        nll = _head_xent(x, head, targets)                      # [B,S]
         loss = jnp.mean(nll)
         # average over data-like axes so every shard reports the global
         # loss (ep subdivides the batch — see data_sharding_spec)

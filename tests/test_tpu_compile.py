@@ -73,8 +73,8 @@ _QKV_CELL = [((2, 2048, 16, 128), jnp.bfloat16)] * 3
 # v and the [B, S] key mask. Sixteen heads of 64 are eight 128-lane columns
 _BERT_S128 = [((64, 128, 16, 64), jnp.bfloat16)] * 3 + [((64, 128), jnp.bool_)]
 _BERT_S512 = [((8, 512, 16, 64), jnp.bfloat16)] * 3 + [((8, 512), jnp.bool_)]
-# LM loss rows x a real tokenizer's vocab (not a BLOCK_V multiple: the
-# wrapper pads it)
+# LM loss rows x a real tokenizer's vocab (whole rows a block: a vocabulary
+# of any size, nothing padded)
 _XENT = [((16384, 32000), jnp.bfloat16), ((16384,), jnp.int32)]
 # the expert layer of the cell olmoe-1b-7b.s4096: 8192 tokens x top-8 rows,
 # 64 experts of 2048 <-> 1024, bf16 rows and float32 parameters
@@ -190,6 +190,69 @@ def test_block_attention_stays_on_its_shard_of_a_mesh(topo,
     m = jax.ShapeDtypeStruct((2, 128), jnp.bool_, sharding=rep)
     text = jax.jit(grad).lower(x, x, x, m).compile().as_text()
     assert text.count("bf16[2,128,1024]") and pa.BWD_NAME in text
+
+
+# the LM head of the two flagship cells: activations, the float32 table as
+# the parameters hold it (GPT's tied embedding [V, M], transposed; OLMoE's
+# lm_head [M, V]) and the labels
+HEADS = {
+    "gpt-1.3b-widths.s2048": (4096, 2048, 50257, True),
+    "olmoe-1b-7b.s4096": (8192, 2048, 50304, False),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(HEADS))
+def test_head_touches_the_logits_once(cell, v5e, no_compile_cache,
+                                      monkeypatch):
+    """The head as ``forward_loss_spmd`` writes it (the table cast to
+    bf16, logits matmul, loss, both gradients) compiled for the v5e at a
+    cell's shape: between the logits matmul and the two backward matmuls
+    stands the kernel alone. No pad, no elementwise sweep over an
+    ``[N, V]`` array (the parent had ``pad`` and a ``kLoop``
+    ``multiply_convert_fusion``, 2 to 4 ms a step), and the temporaries
+    are one ``[N, V]`` bf16 array and ``[N, M]`` ones."""
+    n, m, v, tied = HEADS[cell]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def loss(x, table, labels):
+        head = table.astype(jnp.bfloat16)
+        return px.head_softmax_xent(x, head.T if tied else head,
+                                    labels).mean()
+
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+            for shape, dtype in (((n, m), jnp.bfloat16),
+                                 ((v, m) if tied else (m, v), jnp.float32),
+                                 ((n,), jnp.int32))]
+    compiled = jax.jit(jax.grad(loss, (0, 1))).lower(*args).compile()
+    entry = compiled.as_text().split("ENTRY ", 1)[1].splitlines()[1:]
+    # name -> (result type + opcode, operands + attributes) of the entry's
+    # instructions; a view of an array (an element of the kernel's result
+    # tuple, a bitcast) is the array it views
+    parts = {}
+    for line in entry:
+        name, eq, rest = line.strip().removeprefix("ROOT ").partition(" = ")
+        if eq:
+            result, _, operands = rest.partition("(%")
+            parts[name] = result, "%" + operands
+    views = ("get-tuple-element", "bitcast")
+    wide = {name for name, (result, _) in parts.items()
+            if f"[{n},{v}]" in result}
+    touch = {name for name, (result, operands) in parts.items()
+             if (name in wide or any(w + "," in operands or w + ")" in operands
+                                     for w in wide))
+             and not result.endswith(views)}
+    kernels = {name for name in touch if "hvd_fused_xent" in name}
+    assert len(kernels) == 1 and "custom-call" in parts[min(kernels)][0]
+    # what else writes or reads an [N, V] array: the logits matmul and the
+    # two backward matmuls (XLA:TPU's convolution fusions are kOutput)
+    matmuls = touch - kernels
+    assert len(matmuls) == 3, sorted(touch)
+    for name in matmuls:
+        result, operands = parts[name]
+        assert result.endswith(" fusion") and "kind=kOutput" in operands, \
+            (name, result)
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        <= n * v * 2 + 4 * n * m * 4
 
 
 @pytest.fixture
