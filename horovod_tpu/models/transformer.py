@@ -17,6 +17,9 @@ Layout conventions (local = per-device shapes):
   MoE             experts sharded ep; dropless sorted dispatch, the ep
                   group's tokens exchanged by all_gather / psum_scatter
   layers          stacked [pp, L/pp, ...]; GPipe schedule over pp
+  loop            n_loops > 1: a scan over loop steps around the scan over
+                  layers, the same weights each step, ln_f after each; every
+                  step's state goes to the head and the exit gate (no pp)
 Gradient sync: params are replicated over (dp, sp) → psum over those axes
 after ``jax.grad``; tp/ep/pp-sharded leaves keep local (sharded) grads.
 """
@@ -63,12 +66,25 @@ class TransformerConfig:
     #                             before the head split and rope
     tie_embeddings: bool = True     # logits from the embedding table; else
     #                                 an ``lm_head`` [M, V] of its own
+    post_norm: bool = False     # an RMSNorm after each sublayer too, before
+    #                             the residual add ("sandwich": ln1_post,
+    #                             ln2_post), as Ouro has them
+    ffn_gated: bool = False     # dense FFN down(silu(gate(x)) * up(x)), three
+    #                             matrices (w1, w3, w2); else gelu, two
+    n_loops: int = 1            # the whole stack applied this many times with
+    #                             the same weights (a looped language model,
+    #                             arXiv:2510.25741): ln_f closes every loop
+    #                             step, each step's state feeds the next step
+    #                             and the head, an exit gate mixes the losses
     norm_eps: float = 1e-6
     rope_theta: float = 10000.0
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     n_microbatches: int = 1     # pipeline microbatches (per pp>1)
-    remat: bool = True          # jax.checkpoint each block (HBM for FLOPs)
+    remat: Optional[bool] = None    # jax.checkpoint each block (HBM for
+    #                             FLOPs). None: where the architecture needs
+    #                             it: the pipeline's stages and a looped
+    #                             stack yes, the single scan over layers no
 
     @property
     def head_dim(self) -> int:
@@ -104,6 +120,11 @@ def init_params(rng: np.random.RandomState, cfg: TransformerConfig,
             "q_norm": np.ones((n_stages, lps, H * Dh), np.float32),
             "k_norm": np.ones((n_stages, lps, H * Dh), np.float32),
         })
+    if cfg.post_norm:
+        layer.update({
+            "ln1_post": np.ones((n_stages, lps, M), np.float32),
+            "ln2_post": np.ones((n_stages, lps, M), np.float32),
+        })
     if cfg.n_experts > 0:
         # we1 is the gate of a gated expert, we3 its up projection, we2
         # the way back down (the Mixtral numbering)
@@ -115,10 +136,14 @@ def init_params(rng: np.random.RandomState, cfg: TransformerConfig,
         if cfg.moe_gated:
             layer["we3"] = w(n_stages, lps, cfg.n_experts, M, F)
     else:
+        # w1 is the gate of a gated FFN and w3 its up projection, as the
+        # experts number theirs
         layer.update({
             "w1": w(n_stages, lps, M, F),
             "w2": w(n_stages, lps, F, M),
         })
+        if cfg.ffn_gated:
+            layer["w3"] = w(n_stages, lps, M, F)
     params = {
         "embed": (rng.randn(cfg.vocab_size, M) * 0.02).astype(np.float32),
         "ln_f": np.ones((M,), np.float32),
@@ -126,6 +151,10 @@ def init_params(rng: np.random.RandomState, cfg: TransformerConfig,
     }
     if not cfg.tie_embeddings:
         params["lm_head"] = w(M, cfg.vocab_size)
+    if cfg.n_loops > 1:
+        # Linear(M -> 1), read on every loop step's state
+        params["exit_gate"] = w(M, 1)
+        params["exit_gate_bias"] = np.zeros((1,), np.float32)
     return params
 
 
@@ -143,6 +172,8 @@ def param_shardings(cfg: TransformerConfig, mesh: Mesh) -> Dict:
     }
     if cfg.qk_norm:
         layers.update({"q_norm": s(pp, None, tp), "k_norm": s(pp, None, tp)})
+    if cfg.post_norm:
+        layers.update({"ln1_post": s(pp), "ln2_post": s(pp)})
     if cfg.n_experts > 0:
         layers.update({
             "router": s(pp),
@@ -154,9 +185,13 @@ def param_shardings(cfg: TransformerConfig, mesh: Mesh) -> Dict:
     else:
         layers.update({"w1": s(pp, None, None, tp),
                        "w2": s(pp, None, tp, None)})
+        if cfg.ffn_gated:
+            layers["w3"] = s(pp, None, None, tp)
     shardings = {"embed": s(tp), "ln_f": s(), "layers": layers}
     if not cfg.tie_embeddings:
         shardings["lm_head"] = s(None, tp)
+    if cfg.n_loops > 1:
+        shardings.update({"exit_gate": s(), "exit_gate_bias": s()})
     return shardings
 
 
@@ -290,11 +325,17 @@ def _attention_block(p, x, positions, cfg: TransformerConfig):
                 o = attend(q, k, v, causal=True)
         o = o.reshape(B, S, Hl * cfg.head_dim) @ p["wo"].astype(x.dtype)
         o = _psum_if(o, "tp")
+        if cfg.post_norm:
+            o = _rmsnorm(o, p["ln1_post"], cfg.norm_eps)
         return x + o
 
 
-def _dense_ffn(p, x):
-    h = jax.nn.gelu(x @ p["w1"].astype(x.dtype))
+def _dense_ffn(p, x, cfg: TransformerConfig):
+    h = x @ p["w1"].astype(x.dtype)
+    if cfg.ffn_gated:
+        h = jax.nn.silu(h) * (x @ p["w3"].astype(x.dtype))
+    else:
+        h = jax.nn.gelu(h)
     o = h @ p["w2"].astype(x.dtype)
     return _psum_if(o, "tp")
 
@@ -348,8 +389,17 @@ def _block(p, x, positions, cfg: TransformerConfig):
         if cfg.n_experts > 0:
             o, aux = _moe_ffn(p, h, cfg)
         else:
-            o, aux = _dense_ffn(p, h), _no_aux()
-        return x + o.astype(x.dtype), aux
+            o, aux = _dense_ffn(p, h, cfg), _no_aux()
+        o = o.astype(x.dtype)
+        if cfg.post_norm:
+            o = _rmsnorm(o, p["ln2_post"], cfg.norm_eps)
+        return x + o, aux
+
+
+def _remat(cfg: TransformerConfig, needed: bool) -> bool:
+    """Whether a path checkpoints its blocks: what the config says, or
+    where it says nothing, whether the path needs it to fit."""
+    return needed if cfg.remat is None else cfg.remat
 
 
 def _stage_fn_factory(cfg: TransformerConfig, positions):
@@ -363,7 +413,7 @@ def _stage_fn_factory(cfg: TransformerConfig, positions):
     def one_block(x, lp):
         def fn(xx):
             return _block(lp, xx, positions, cfg)
-        if cfg.remat:
+        if _remat(cfg, True):
             fn = jax.checkpoint(fn)
         return fn(x)
 
@@ -389,7 +439,10 @@ def forward_loss_spmd(params, tokens, targets, cfg: TransformerConfig):
     a dict with ``aux_loss``, the weighted auxiliary losses that training
     adds to the loss (0 for a dense model), and for an MoE model off the
     pipeline path its parts and counters: ``load_balance_loss``,
-    ``router_z_loss``, ``max_expert_load``, ``dropped`` (always 0)."""
+    ``router_z_loss``, ``max_expert_load``, ``dropped`` (always 0). A
+    looped model's loss is the exit-weighted objective of
+    :func:`_looped_loss`, and ``aux`` gains ``step_losses`` ``[T]``,
+    ``exit_share`` ``[T]`` and ``gate_entropy``."""
     S = tokens.shape[1]
     sp_idx = lax.axis_index("sp") if _axis_live("sp") else 0
     positions = sp_idx * S + jnp.arange(S)
@@ -400,16 +453,27 @@ def forward_loss_spmd(params, tokens, targets, cfg: TransformerConfig):
                           tokens)                               # [B,S,M]
 
     with jax.named_scope(scopes.LAYERS):
-        x, aux_total = _run_layers(params["layers"], x, positions, cfg)
+        if cfg.n_loops > 1:
+            x, aux_total = _loop_layers(params["layers"], params["ln_f"], x,
+                                        positions, cfg)      # [T,B,S,M]
+        else:
+            x, aux_total = _run_layers(params["layers"], x, positions, cfg)
 
     with jax.named_scope(scopes.HEAD):
-        x = _rmsnorm(x, params["ln_f"], cfg.norm_eps)
         if cfg.tie_embeddings:
             head = params["embed"].astype(cfg.dtype).T
         else:
             head = params["lm_head"].astype(cfg.dtype)
-        nll = _head_xent(x, head, targets)                      # [B,S]
-        loss = jnp.mean(nll)
+        if cfg.n_loops > 1:
+            # every loop step's state is already through ln_f
+            nll = jnp.stack([_head_xent(x[t], head, targets)
+                             for t in range(cfg.n_loops)])      # [T,B,S]
+            loss, exits = _looped_loss(_exit_gate(params, x), nll)
+            aux_total = {**aux_total, **exits}
+        else:
+            x = _rmsnorm(x, params["ln_f"], cfg.norm_eps)
+            nll = _head_xent(x, head, targets)                  # [B,S]
+            loss = jnp.mean(nll)
         # average over data-like axes so every shard reports the global
         # loss (ep subdivides the batch — see data_sharding_spec)
         for ax in ("dp", "ep", "sp"):
@@ -418,6 +482,65 @@ def forward_loss_spmd(params, tokens, targets, cfg: TransformerConfig):
                 aux_total = jax.tree_util.tree_map(
                     lambda a: lax.pmean(a, ax), aux_total)
     return loss, aux_total
+
+
+def _exit_gate(params, states):
+    """The exit gate's logits ``z_t = h_t w + b`` ``[T, B, S]`` of the loop
+    steps' normed states ``[T, B, S, M]``, in float32: a multiply and a
+    sum, not a matmul the MXU would take in bfloat16."""
+    with jax.named_scope(scopes.LOOP_GATE):
+        return (jnp.sum(states.astype(jnp.float32)
+                        * params["exit_gate"][:, 0].astype(jnp.float32), -1)
+                + params["exit_gate_bias"].astype(jnp.float32))
+
+
+#: beta of the looped objective ``mean(sum_t p_t xent_t - beta H(p))``
+EXIT_ENTROPY_WEIGHT = 0.1
+
+
+def _looped_loss(z, nll):
+    """The looped model's training objective (arXiv:2510.25741, stage I)
+    from the gate's logits and the loop steps' per-token losses, both
+    ``[T, B, S]``: ``lambda_t = sigmoid(z_t)``, the exit distribution
+    ``p_t = lambda_t prod_{j<t}(1 - lambda_j)`` with the last step taking
+    what is left (``lambda_T`` is not read), and
+    ``mean(sum_t p_t nll_t - beta H(p))``, in log space and the dtype of
+    ``z`` (float32). Returns (loss, what the step reports of it)."""
+    with jax.named_scope(scopes.LOOP_GATE):
+        # log p_t = log lambda_t + sum_{j<t} log(1 - lambda_j)
+        stay = jnp.cumsum(jax.nn.log_sigmoid(-z[:-1]), axis=0)
+        stay = jnp.concatenate([jnp.zeros_like(z[:1]), stay])
+        log_p = stay.at[:-1].add(jax.nn.log_sigmoid(z[:-1]))
+        p = jnp.exp(log_p)
+        entropy = -jnp.sum(p * log_p, axis=0)                   # [B,S]
+        loss = jnp.mean(jnp.sum(p * nll, axis=0)
+                        - EXIT_ENTROPY_WEIGHT * entropy)
+        return loss, {"step_losses": jnp.mean(nll, axis=(1, 2)),
+                      "exit_share": jnp.mean(p, axis=(1, 2)),
+                      "gate_entropy": jnp.mean(entropy)}
+
+
+def _loop_layers(lp, ln_f, x, positions, cfg: TransformerConfig):
+    """``n_loops`` passes through the same stack of blocks, ``ln_f`` after
+    each: a scan over loop steps around the scan over layers, the stacked
+    parameters closed over, so a weight's gradient is the sum over its
+    uses. Returns (every step's normed state ``[T, B, S, M]``, the
+    auxiliary terms over all passes)."""
+    if _axis_live("pp"):
+        raise NotImplementedError(
+            "a looped stack (n_loops > 1) on a live pp axis: the pipeline "
+            "schedule would have to send the last stage's output, through "
+            "ln_f, back to the first stage for every loop step and hand "
+            "every step's state to the head; pipeline_spmd runs the stages "
+            "once")
+
+    def loop_step(h, _):
+        y, auxs = _scan_layers(lp, h, positions, cfg)
+        y = _rmsnorm(y, ln_f, cfg.norm_eps)
+        return y, (y, _over_layers(auxs))
+    with jax.named_scope(scopes.LOOP):
+        _, (states, auxs) = lax.scan(loop_step, x, None, length=cfg.n_loops)
+    return states, _over_layers(auxs)
 
 
 def _run_layers(lp, x, positions, cfg: TransformerConfig):
@@ -452,9 +575,17 @@ def _run_layers(lp, x, positions, cfg: TransformerConfig):
 
 def _scan_layers(lp, x, positions, cfg: TransformerConfig):
     """One scan over the blocks of ``lp`` (``[stage, layer, ...]`` leaves).
-    Returns (activations, every layer's auxiliary terms stacked ``[L]``)."""
+    Returns (activations, every layer's auxiliary terms stacked ``[L]``).
+    A looped stack checkpoints each block (its passes' activations would
+    not fit beside the weights), the single pass does not, unless
+    ``cfg.remat`` says otherwise."""
+    def block(layer_p, x):
+        return _block(layer_p, x, positions, cfg)
+    if _remat(cfg, cfg.n_loops > 1):
+        block = jax.checkpoint(block)
+
     def scan_body(carry, layer_p):
-        y, aux = _block(layer_p, carry, positions, cfg)
+        y, aux = block(layer_p, carry)
         return y, aux
     flat = jax.tree_util.tree_map(
         lambda a: a.reshape((-1,) + a.shape[2:]), lp)
@@ -634,10 +765,13 @@ def flatten_decode_params(params: Dict) -> Dict:
     ``[L, ...]`` — decode scans all layers on one device; the pipeline
     split is a training-time concern."""
     layers = params["layers"]
-    if "w1" not in layers or "q_norm" in layers or "lm_head" in params:
+    if "w1" not in layers or "q_norm" in layers or "lm_head" in params \
+            or "w3" in layers or "ln1_post" in layers \
+            or "exit_gate" in params:
         raise NotImplementedError(
             "paged decode supports the dense GPT block: n_experts=0, no "
-            "qk_norm, tied embeddings")
+            "qk_norm, tied embeddings, no post_norm, no ffn_gated, no "
+            "looped stack (n_loops > 1)")
     flat = {k: jnp.asarray(v).reshape((-1,) + tuple(np.shape(v)[2:]))
             for k, v in layers.items()}
     return {"embed": jnp.asarray(params["embed"]),

@@ -48,6 +48,12 @@ MOE_ROUTER = "hvd.moe.router"
 MOE_DISPATCH = "hvd.moe.dispatch"
 MOE_EXPERTS = "hvd.moe.experts"
 MOE_COMBINE = "hvd.moe.combine"
+#: nested in LAYERS: a looped model's passes through its stack, with the
+#: final norm that closes each loop step
+LOOP = "hvd.loop"
+#: nested in HEAD: a looped model's exit gate, exit distribution and the
+#: mixing of the loop steps' losses
+LOOP_GATE = "hvd.loop.gate"
 #: final norm or transform, logits, loss
 HEAD = "hvd.head"
 GRAD_SYNC = "hvd.grad_sync"
@@ -57,7 +63,10 @@ OPTIMIZER = "hvd.optimizer"
 MODEL_PHASES = (EMBED, LAYERS, ATTENTION, ATTENTION_CORE, MLP, HEAD)
 #: phases only an MoE model has, each forward and backward
 MOE_PHASES = (MOE, MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS, MOE_COMBINE)
-DEVICE_PHASES = MODEL_PHASES + MOE_PHASES + (GRAD_SYNC, OPTIMIZER)
+#: phases only a looped model has, each forward and backward
+LOOP_PHASES = (LOOP, LOOP_GATE)
+DEVICE_PHASES = (MODEL_PHASES + MOE_PHASES + LOOP_PHASES
+                 + (GRAD_SYNC, OPTIMIZER))
 
 # -- host spans (profiling.annotate) ------------------------------------------
 #: the input iterator's ``next()``: the host makes the batch

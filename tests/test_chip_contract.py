@@ -16,7 +16,11 @@ cannot go stale:
   ``horovod_tpu/ops`` or ``parallel/moe.py``, a constant of
   ``profiling/scopes.py``.
 
-CPU only, nothing is traced or compiled.
+* what an adapter reads by a string the walk cannot see: the ``ouro``
+  adapter's leaves of ``init_params``' tree and the keys of the looped
+  step's fourth output (traced at the ``tiny`` sizes, not compiled).
+
+CPU only, nothing is compiled.
 """
 
 import ast
@@ -179,3 +183,40 @@ def test_what_the_benchmark_looks_for_is_what_the_program_says(
     assert name in said, (
         f"benchmarks/chip/{where} looks for the {kind} {name!r}; the "
         f"product has {sorted(said)}")
+
+
+def test_the_looped_step_gives_what_the_ouro_adapter_reads():
+    """``adapters/ouro.py`` names leaves of the parameter tree
+    (``_leaf_paths``, ``_init_function``) and reads ``exit_share`` from the
+    step's fourth output; the configuration's fields reach
+    ``TransformerConfig`` as ``n_loops``, ``post_norm``, ``ffn_gated``."""
+    import sys
+    import jax
+    import numpy as np
+    if CHIP not in sys.path:
+        sys.path.insert(0, CHIP)
+    from adapters import ouro
+    from trees import get_leaves
+    from horovod_tpu.models import transformer as t
+    from horovod_tpu.parallel import build_mesh
+    with open(os.path.join(CHIP, "configs", "ouro-2.6b.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(CHIP, "workloads", "train.s4096.b1.json")) as f:
+        job = json.load(f)
+    config, job = {**config, **config["tiny"]}, {**job, **job["tiny"]}
+    cfg = ouro._model_config(config, job)
+    assert (cfg.n_loops, cfg.post_norm, cfg.ffn_gated) == (
+        config["total_ut_steps"], True, True)
+    params = t.init_params(np.random.RandomState(0), cfg, 1)
+    assert {"exit_gate", "exit_gate_bias", "lm_head"} <= set(params)
+    assert {"ln1_post", "ln2_post", "w3"} <= set(params["layers"])
+    assert set(get_leaves(params, ouro._leaf_paths(cfg.n_layers))) == {
+        "lm_head", "exit_gate", "first_query", "last_ffn_down",
+        "last_post_norm"}
+    mesh = build_mesh(devices=jax.devices()[:1], dp=-1)
+    batch = ouro.host_batch(config, job, 0, 0, 1)
+    _loss, aux, _grads = jax.eval_shape(
+        t.make_grad_fn(cfg, mesh), params, batch["tokens"], batch["targets"])
+    assert set(aux) == {"aux_loss", "step_losses", "exit_share",
+                        "gate_entropy"}
+    assert aux["exit_share"].shape == (cfg.n_loops,)

@@ -1,0 +1,417 @@
+"""Ouro on the normal path (ISSUE 30): the flagship block with sandwich
+norms and a SiLU-gated dense FFN, the stack of layers looped with the same
+weights, ``ln_f`` after every loop step, the head and the exit gate on
+every step's state and the exit-weighted loss, in float32 at the benchmark
+configuration's ``tiny`` sizes (2 layers, 2 loop steps; one case at 3 loop
+steps), against the plain reference ``benchmarks/chip/reference/ouro.py``
+on seeded weights.
+
+TOL: both sides are float32 here and differ in the order of their sums (a
+scan against Python loops, a fused cross-entropy against logsumexp, the
+exit distribution in log space against plain products), measured at 1e-6 of
+a leaf's norm. 1e-4 leaves that room and is far under what each fault of
+``test_a_wrong_term_fails`` does (tried, each fails): the exit gate or the
+entropy in bfloat16, the un-normed state fed to the next loop step, a loop
+step dropped, ``p_T`` taken from ``lambda_T``, the post-norms left out.
+"""
+
+import dataclasses
+import json
+import os
+import sys
+
+import numpy as np
+import pytest
+import jax
+import jax.numpy as jnp
+
+from horovod_tpu.models import transformer as t
+from horovod_tpu.models import shard_batch, shard_params
+from horovod_tpu.parallel import build_mesh
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+_CHIP = os.path.join(_REPO, "benchmarks", "chip")
+if _CHIP not in sys.path:
+    sys.path.insert(0, _CHIP)
+
+from adapters import ouro as adapter          # noqa: E402
+from reference import ouro as reference       # noqa: E402
+from trees import get_leaves                  # noqa: E402
+
+TOL = 1e-4
+
+
+def _tiny():
+    with open(os.path.join(_CHIP, "configs", "ouro-2.6b.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(_CHIP, "workloads", "train.s4096.b1.json")) as f:
+        job = json.load(f)
+    return {**config, **config["tiny"]}, {**job, **job["tiny"]}
+
+
+CONFIG, JOB = _tiny()
+SIZES = adapter.shapes(CONFIG, JOB)
+CFG = adapter._model_config(CONFIG, JOB)
+LEAVES = {
+    "embed": (("embed",), None),
+    "ln_f": (("ln_f",), None),
+    "lm_head": (("lm_head",), None),
+    "exit_gate": (("exit_gate",), None),
+    "exit_gate_bias": (("exit_gate_bias",), None),
+    **{name: (("layers", name), (0, layer))
+       for layer, names in enumerate((
+           ("ln1", "wq", "wk", "wo", "w1", "ln2_post"),
+           ("ln1_post", "wv", "ln2", "w3", "w2")))
+       for name in names},
+}
+
+
+def _params(cfg=CFG, seed=0, n_stages=1):
+    params = jax.tree_util.tree_map(
+        jnp.asarray, t.init_params(np.random.RandomState(seed), cfg,
+                                   n_stages))
+    if "exit_gate_bias" in params:    # a bias of 0 hides a wrong gradient
+        params["exit_gate_bias"] = jnp.full((1,), 0.3, jnp.float32)
+    return params
+
+
+def _batch(n_seqs=2, seed=0):
+    return jax.tree_util.tree_map(
+        jnp.asarray, adapter.host_batch(CONFIG, JOB, seed, 0, n_seqs))
+
+
+def _rel(got, want):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    return np.linalg.norm(got - want) / np.linalg.norm(want)
+
+
+def _program(cfg, params, batch, mesh_axes=None):
+    """(loss, aux, gradients) by the program's make_grad_fn on a mesh (one
+    device by default)."""
+    axes = mesh_axes or {"dp": 1}
+    n = int(np.prod(list(axes.values())))
+    mesh = build_mesh(devices=jax.devices()[:n], **axes)
+    p = shard_params(params, cfg, mesh)
+    tok, tgt = shard_batch(batch["tokens"], batch["targets"], mesh)
+    return jax.jit(t.make_grad_fn(cfg, mesh))(p, tok, tgt)
+
+
+def _reference(params, batch, sizes=SIZES, leaves=LEAVES):
+    """The plain objective, no checkpoint anywhere, and its gradients."""
+    def objective(p):
+        total, *reported = reference.objective(p, batch, sizes)
+        return total, reported
+    with jax.default_matmul_precision("highest"):
+        (total, (steps, share, entropy)), grads = jax.value_and_grad(
+            objective, has_aux=True)(params)
+    return {"loss": total, "step_losses": steps, "exit_share": share,
+            "gate_entropy": entropy,
+            **{f"grad:{k}": v for k, v in get_leaves(grads, leaves).items()}}
+
+
+@pytest.fixture(scope="module")
+def both_sides():
+    params, batch = _params(), _batch()
+    loss, aux, grads = _program(CFG, params, batch)
+    got = {"loss": loss, **{k: aux[k] for k in (
+        "step_losses", "exit_share", "gate_entropy")},
+        **{f"grad:{k}": v for k, v in get_leaves(grads, LEAVES).items()}}
+    return got, _reference(params, batch), aux
+
+
+@pytest.mark.parametrize("what", [
+    "loss", "step_losses", "exit_share", "gate_entropy",
+    *(f"grad:{k}" for k in LEAVES)])
+def test_program_matches_the_reference(both_sides, what):
+    got, want, _aux = both_sides
+    assert _rel(got[what], want[what]) < TOL, what
+
+
+def test_the_step_reports_its_exit_distribution(both_sides):
+    _got, _want, aux = both_sides
+    assert set(aux) == {"aux_loss", "step_losses", "exit_share",
+                        "gate_entropy"}
+    assert aux["step_losses"].shape == aux["exit_share"].shape == (
+        CFG.n_loops,)
+    np.testing.assert_allclose(float(aux["exit_share"].sum()), 1.0,
+                               atol=1e-6)
+    assert float(aux["aux_loss"]) == 0.0
+    assert 0.0 < float(aux["gate_entropy"]) <= np.log(CFG.n_loops) + 1e-6
+
+
+def test_three_loop_steps_match_the_reference():
+    """The middle steps' ``p_t = lambda_t prod_{j<t}(1 - lambda_j)``, which
+    two loop steps do not have."""
+    cfg = dataclasses.replace(CFG, n_loops=3)
+    sizes = {**SIZES, "loops": 3}
+    params, batch = _params(cfg, seed=1), _batch(seed=1)
+    loss, aux, grads = _program(cfg, params, batch)
+    want = _reference(params, batch, sizes)
+    assert _rel(loss, want["loss"]) < TOL
+    assert _rel(aux["exit_share"], want["exit_share"]) < TOL
+    np.testing.assert_allclose(float(aux["exit_share"].sum()), 1.0,
+                               atol=1e-6)
+    for name in ("exit_gate", "exit_gate_bias", "lm_head", "wq", "w2"):
+        assert _rel(get_leaves(grads, LEAVES)[name],
+                    want[f"grad:{name}"]) < TOL, name
+
+
+# -- what TOL must not let through --------------------------------------------
+
+def _gate_in_bf16(monkeypatch):
+    """The gate as the MXU would take it: bfloat16 operands and result."""
+    def exit_gate(params, states):
+        z = states.astype(jnp.bfloat16) @ params["exit_gate"][:, 0].astype(
+            jnp.bfloat16) + params["exit_gate_bias"].astype(jnp.bfloat16)
+        return z.astype(jnp.float32)
+    monkeypatch.setattr(t, "_exit_gate", exit_gate)
+
+
+def _entropy_in_bf16(monkeypatch):
+    """The exit distribution, its logarithm and the entropy in bfloat16
+    (``_looped_loss`` computes in the dtype of ``z``)."""
+    monkeypatch.setattr(
+        t, "_looped_loss", lambda z, nll, real=t._looped_loss: real(
+            z.astype(jnp.bfloat16), nll))
+
+
+def _unnormed_state_fed_forward(monkeypatch):
+    """``ln_f`` on the head's input only: the next loop step gets the
+    stack's raw output."""
+    def loop_layers(lp, ln_f, x, positions, cfg):
+        states, auxs = [], None
+        for _ in range(cfg.n_loops):
+            x, auxs = t._scan_layers(lp, x, positions, cfg)
+            states.append(t._rmsnorm(x, ln_f, cfg.norm_eps))
+        return jnp.stack(states), t._over_layers(auxs)
+    monkeypatch.setattr(t, "_loop_layers", loop_layers)
+
+
+def _a_loop_step_dropped(monkeypatch):
+    """The last step's state is the one before it: its pass never ran."""
+    real = t._loop_layers
+
+    def loop_layers(lp, ln_f, x, positions, cfg):
+        states, auxs = real(lp, ln_f, x, positions, cfg)
+        return states.at[-1].set(states[-2]), auxs
+    monkeypatch.setattr(t, "_loop_layers", loop_layers)
+
+
+def _last_share_from_its_own_gate(monkeypatch):
+    """``p_T = lambda_T prod_{j<T}(1 - lambda_j)``: no longer sums to 1."""
+    def looped_loss(z, nll):
+        stay = jnp.concatenate([jnp.zeros_like(z[:1]), jnp.cumsum(
+            jax.nn.log_sigmoid(-z[:-1]), axis=0)])
+        log_p = stay + jax.nn.log_sigmoid(z)
+        p = jnp.exp(log_p)
+        entropy = -jnp.sum(p * log_p, axis=0)
+        loss = jnp.mean(jnp.sum(p * nll, axis=0)
+                        - t.EXIT_ENTROPY_WEIGHT * entropy)
+        return loss, {"step_losses": jnp.mean(nll, axis=(1, 2)),
+                      "exit_share": jnp.mean(p, axis=(1, 2)),
+                      "gate_entropy": jnp.mean(entropy)}
+    monkeypatch.setattr(t, "_looped_loss", looped_loss)
+
+
+def _post_norms_left_out(monkeypatch):
+    monkeypatch.setattr(
+        t, "_block", lambda p, x, positions, cfg, block=t._block: block(
+            p, x, positions, dataclasses.replace(cfg, post_norm=False)))
+
+
+@pytest.mark.parametrize("fault", [
+    _gate_in_bf16, _entropy_in_bf16, _unnormed_state_fed_forward,
+    _a_loop_step_dropped, _last_share_from_its_own_gate,
+    _post_norms_left_out], ids=lambda f: f.__name__.strip("_"))
+def test_a_wrong_term_fails(monkeypatch, both_sides, fault):
+    """Each moves the loss or the gate's gradient far beyond TOL."""
+    _got, want, _aux = both_sides
+    fault(monkeypatch)
+    loss, _aux, grads = _program(CFG, _params(), _batch())
+    errors = [_rel(loss, want["loss"]),
+              _rel(grads["exit_gate"], want["grad:exit_gate"]),
+              _rel(grads["layers"]["wq"][0, 0], want["grad:wq"])]
+    assert max(errors) > 20 * TOL, (fault.__name__, errors)
+
+
+# -- defaults reproduce the parent's model; remat means something -------------
+
+DENSE = t.TransformerConfig(vocab_size=512, d_model=128, n_heads=4,
+                            n_layers=2, d_ff=256, max_seq=64,
+                            dtype=jnp.float32)
+
+
+def _parents_loss(params, tokens, targets, cfg):
+    """The dense GPT block and its loss as the parent commit's
+    ``forward_loss_spmd`` computed them, written out from the same
+    building blocks: pre-norms only, ``gelu(x w1) w2``, one scan, one
+    head."""
+    positions = jnp.arange(tokens.shape[1])
+
+    def block(x, p):
+        x = t._attention_block(p, x, positions, cfg)
+        h = t._rmsnorm(x, p["ln2"], cfg.norm_eps)
+        o = jax.nn.gelu(h @ p["w1"].astype(h.dtype)) @ p["w2"].astype(
+            h.dtype)
+        return x + o.astype(x.dtype), None
+    x = params["embed"].astype(cfg.dtype)[tokens]
+    flat = jax.tree_util.tree_map(
+        lambda a: a.reshape((-1,) + a.shape[2:]), params["layers"])
+    x, _ = jax.lax.scan(block, x, flat)
+    x = t._rmsnorm(x, params["ln_f"], cfg.norm_eps)
+    return jnp.mean(t._head_xent(
+        x, params["embed"].astype(cfg.dtype).T, targets))
+
+
+def test_the_new_fields_at_their_defaults_are_the_parent_s_model():
+    """``n_loops=1, post_norm=False, ffn_gated=False``: the parent's tree,
+    loss and gradients, bit for bit."""
+    explicit = dataclasses.replace(DENSE, n_loops=1, post_norm=False,
+                                   ffn_gated=False)
+    assert explicit == DENSE and DENSE.remat is None
+    params, batch = _params(DENSE), _batch()
+    assert set(params) == {"embed", "ln_f", "layers"}
+    assert set(params["layers"]) == {"ln1", "ln2", "wq", "wk", "wv", "wo",
+                                     "w1", "w2"}
+    loss, aux, grads = _program(DENSE, params, batch)
+    assert set(aux) == {"aux_loss"}
+    want_loss, want = jax.jit(jax.value_and_grad(_parents_loss),
+                              static_argnums=3)(
+        params, batch["tokens"], batch["targets"], DENSE)
+    assert float(loss) == float(want_loss)
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(grads),
+                            jax.tree_util.tree_leaves(want)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w),
+                                      err_msg=str(path))
+
+
+@pytest.mark.parametrize("path, cfg", [("scan", DENSE), ("looped", CFG)])
+def test_remat_changes_what_is_stored_not_what_is_computed(path, cfg):
+    params, batch = _params(cfg), _batch()
+    loss0, _aux, grads0 = _program(cfg, params, batch)
+    for remat in (True, False):
+        loss, _aux, grads = _program(
+            dataclasses.replace(cfg, remat=remat), params, batch)
+        np.testing.assert_allclose(float(loss), float(loss0), rtol=1e-6)
+        for g, g0 in zip(jax.tree_util.tree_leaves(grads),
+                         jax.tree_util.tree_leaves(grads0)):
+            assert _rel(g, g0) < 1e-5, (path, remat)
+
+
+def test_remat_none_checkpoints_the_looped_stack_only():
+    """What ``None`` resolves to, read from the traced step: a looped
+    stack's blocks run under ``checkpoint``, the single scan's do not (the
+    GPT and OLMoE cells' programs stay as they were), and an explicit
+    value is honoured on both."""
+    def checkpointed(cfg):
+        mesh = build_mesh(devices=jax.devices()[:1], dp=1)
+        params, batch = _params(cfg), _batch()
+        text = str(jax.make_jaxpr(t.make_grad_fn(cfg, mesh))(
+            params, batch["tokens"], batch["targets"]))
+        return "checkpoint" in text or "remat" in text
+    assert checkpointed(CFG) and not checkpointed(DENSE)
+    assert not checkpointed(dataclasses.replace(CFG, remat=False))
+    assert checkpointed(dataclasses.replace(DENSE, remat=True))
+
+
+# -- layouts --------------------------------------------------------------------
+
+@pytest.mark.parametrize("axes", [{"dp": 2}, {"sp": 2}, {"dp": 2, "sp": 2}])
+def test_data_layouts_give_one_device_s_result(axes):
+    """Nothing in the loop is per-device: the same loss, the same exit
+    distribution and (after the data shards' sum, which the flagship's
+    gradient sync leaves undivided) the same gradients."""
+    params, batch = _params(), _batch(n_seqs=4)
+    loss1, aux1, grads1 = _program(CFG, params, batch)
+    loss, aux, grads = _program(CFG, params, batch, axes)
+    np.testing.assert_allclose(float(loss), float(loss1), rtol=1e-5)
+    for k in aux1:
+        np.testing.assert_allclose(np.asarray(aux[k]), np.asarray(aux1[k]),
+                                   rtol=1e-5, atol=1e-6, err_msg=k)
+    shards = int(np.prod(list(axes.values())))
+    for (path, g), g1 in zip(jax.tree_util.tree_leaves_with_path(grads),
+                             jax.tree_util.tree_leaves(grads1)):
+        assert _rel(np.asarray(g) / shards, g1) < TOL, path
+
+
+def test_tensor_parallel_gives_one_device_s_result():
+    """tp shards the heads, the FFN's width (``w3`` as ``w1``) and the
+    head's vocabulary (the psum algebra in place of the kernel); the
+    post-norms, the gate and the loop see whole activations. Loss, exit
+    distribution and the sharded leaves' gradients (which the flagship
+    leaves scaled by the shards, ROADMAP A16, as a replicated leaf's miss
+    the tp sum)."""
+    params, batch = _params(), _batch(n_seqs=4)
+    loss1, aux1, grads1 = _program(CFG, params, batch)
+    loss, aux, grads = _program(CFG, params, batch, {"tp": 2})
+    np.testing.assert_allclose(float(loss), float(loss1), rtol=1e-5)
+    for k in aux1:
+        np.testing.assert_allclose(np.asarray(aux[k]), np.asarray(aux1[k]),
+                                   rtol=1e-5, atol=1e-6, err_msg=k)
+    for name in ("wq", "wo", "w1", "w3", "w2"):
+        assert _rel(np.asarray(grads["layers"][name]) / 2,
+                    grads1["layers"][name]) < TOL, name
+    assert _rel(np.asarray(grads["lm_head"]) / 2, grads1["lm_head"]) < TOL
+
+
+def test_a_live_pipeline_axis_is_refused_by_name():
+    cfg = dataclasses.replace(CFG, n_microbatches=2)
+    params, batch = _params(cfg, n_stages=2), _batch(n_seqs=4)
+    with pytest.raises(NotImplementedError, match="looped stack.*pp axis"):
+        _program(cfg, params, batch, {"pp": 2})
+    # the block's new parts alone ride the pipeline as any block does
+    cfg = dataclasses.replace(cfg, n_loops=1)
+    params = _params(cfg, n_stages=2)
+    loss, _aux, _grads = _program(cfg, params, batch, {"pp": 2})
+    assert np.isfinite(float(loss))
+
+
+# -- the tree, the adapter, the serving paths -----------------------------------
+
+def test_init_params_and_shardings_hold_the_new_leaves():
+    mesh = build_mesh(devices=jax.devices()[:4], dp=2, tp=2)
+    params = t.init_params(np.random.RandomState(0), CFG, 1)
+    sh = t.param_shardings(CFG, mesh)
+    assert jax.tree_util.tree_structure(params) == \
+        jax.tree_util.tree_structure(sh)
+    assert set(params["layers"]) == {"ln1", "ln1_post", "ln2", "ln2_post",
+                                     "wq", "wk", "wv", "wo", "w1", "w3",
+                                     "w2"}
+    assert params["exit_gate"].shape == (CFG.d_model, 1)
+    assert params["exit_gate_bias"].shape == (1,)
+    assert params["layers"]["w3"].shape == (1, CFG.n_layers, CFG.d_model,
+                                            CFG.d_ff)
+    assert sh["layers"]["w3"].spec == sh["layers"]["w1"].spec
+
+
+def test_the_adapter_draws_init_params_tree_on_the_device():
+    host = t.init_params(np.random.RandomState(0), CFG, 1)
+    ours = jax.device_get(jax.jit(adapter._init_function(CFG))(
+        jax.random.PRNGKey(0)))
+    assert jax.tree_util.tree_structure(host) == \
+        jax.tree_util.tree_structure(ours)
+    for (path, h), o in zip(jax.tree_util.tree_leaves_with_path(host),
+                            jax.tree_util.tree_leaves(ours)):
+        assert h.shape == o.shape and h.dtype == o.dtype, path
+        if float(h.std()) > 0:
+            assert abs(float(o.std()) / float(h.std()) - 1) < 0.15, path
+
+
+@pytest.mark.parametrize("field", ["post_norm", "ffn_gated", "n_loops"])
+def test_the_decode_paths_refuse_the_new_trees_by_name(field):
+    cfg = dataclasses.replace(DENSE, **{field: 2 if field == "n_loops"
+                                        else True})
+    params = t.init_params(np.random.RandomState(0), cfg, 1)
+    with pytest.raises(NotImplementedError, match=field):
+        t.flatten_decode_params(params)
+
+
+def test_the_reference_imports_nothing_of_the_program():
+    with open(os.path.join(_CHIP, "reference", "ouro.py")) as f:
+        text = f.read()
+    assert "horovod_tpu" not in text.split('"""', 2)[2]
+    assert '"highest"' in text
+    # the objective tier-1 differentiates is Python loops alone: the scan
+    # and the checkpoints are loss_and_grads' (the chip's check)
+    plain = text.split("def stored_less", 1)[0].split('"""', 2)[2]
+    assert "scan" not in plain and "checkpoint" not in plain

@@ -25,7 +25,7 @@ PACKAGE = os.path.join(os.path.dirname(os.path.dirname(
 
 def test_the_vocabulary_is_the_only_place_the_strings_are_written():
     names = scopes.DEVICE_PHASES + scopes.HOST_SPANS
-    assert len(set(names)) == len(names) == 15
+    assert len(set(names)) == len(names) == 17
     assert all(n.startswith("hvd.") for n in names)
     home = os.path.join(PACKAGE, "profiling", "scopes.py")
     elsewhere = []
@@ -99,6 +99,26 @@ def _moe_step():
             (params, init_opt_state(tx, params, mesh, cfg), t, y))
 
 
+def _looped_step():
+    """The flagship block as Ouro sets it: sandwich norms, a gated FFN, the
+    stack looped twice, a head and the exit gate on every loop step."""
+    from horovod_tpu.models import (TransformerConfig, init_opt_state,
+                                    init_params, make_train_step,
+                                    shard_batch, shard_params)
+    cfg = TransformerConfig(vocab_size=64, d_model=32, n_heads=4,
+                            n_layers=2, d_ff=64, max_seq=32, n_loops=2,
+                            post_norm=True, ffn_gated=True,
+                            tie_embeddings=False, dtype=jnp.float32)
+    mesh = build_mesh(devices=jax.devices()[:1], dp=-1)
+    params = shard_params(init_params(np.random.RandomState(0), cfg, 1),
+                          cfg, mesh)
+    tx = optax.adamw(1e-3)
+    tokens = np.zeros((2, 16), np.int32)
+    t, y = shard_batch(tokens, tokens, mesh)
+    return (make_train_step(cfg, mesh, tx),
+            (params, init_opt_state(tx, params, mesh, cfg), t, y))
+
+
 _TEXTS = {}
 
 
@@ -106,7 +126,7 @@ def _compiled_text(model: str) -> str:
     if model not in _TEXTS:
         step, args = {"bert": _bert_step, "flagship": _flagship_step,
                       "flagship.dp2": lambda: _flagship_step(2),
-                      "moe": _moe_step}[model]()
+                      "moe": _moe_step, "looped": _looped_step}[model]()
         _TEXTS[model] = step.lower(*args).compile().as_text()
     return _TEXTS[model]
 
@@ -135,6 +155,27 @@ def test_the_step_carries_every_model_phase_in_both_directions(model, phase):
 @pytest.mark.parametrize("phase", scopes.MODEL_PHASES + scopes.MOE_PHASES)
 def test_the_moe_step_carries_every_phase_in_both_directions(phase):
     assert _directions(_compiled_text("moe"), phase) == {"fwd", "bwd"}
+
+
+@pytest.mark.parametrize("phase", scopes.MODEL_PHASES + scopes.LOOP_PHASES)
+def test_the_looped_step_carries_every_phase_in_both_directions(phase):
+    assert _directions(_compiled_text("looped"), phase) == {"fwd", "bwd"}
+
+
+def test_the_loop_nests_in_layers_and_its_gate_in_head():
+    """hvd.loop inside hvd.layers and hvd.loop.gate inside hvd.head: the
+    five kinds and the phase metrics of the other steps read a looped step
+    unchanged; a step that does not loop has neither."""
+    paths = re.findall(r'op_name="([^"]*)"', _compiled_text("looped"))
+    for inner, outer in ((scopes.LOOP, scopes.LAYERS),
+                         (scopes.LOOP_GATE, scopes.HEAD)):
+        found = [p for p in paths
+                 if inner in re.sub(r"[()]", "/", p).split("/")]
+        assert found, inner
+        for path in found:
+            assert outer in re.sub(r"[()]", "/", path).split("/"), path
+    assert not any(name in _compiled_text("flagship")
+                   for name in scopes.LOOP_PHASES)
 
 
 def test_the_expert_layer_s_phases_nest_in_moe_inside_mlp():

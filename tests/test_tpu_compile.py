@@ -255,6 +255,84 @@ def test_head_touches_the_logits_once(cell, v5e, no_compile_cache,
         <= n * v * 2 + 4 * n * m * 4
 
 
+# -- whole train steps of the benchmark's flagship cells ---------------------
+
+_CHIP = os.path.join(_REPO, "benchmarks", "chip")
+
+
+def _cell_step(cell, topo):
+    """(jitted step, its abstract arguments, the adapter's shapes) of a
+    cell of BENCHMARK.json at its real sizes, on one described chip: what
+    ``benchmarks/chip/rehearse.py compile`` builds."""
+    import importlib
+    import sys
+    for path in (_REPO, _CHIP):
+        if path not in sys.path:
+            sys.path.insert(0, path)
+    import horovod_tpu as hvd
+    import run as harness
+    _bench, entry, config, job = harness.load_cell(cell, tiny=False)
+    mesh = hvd.build_mesh(devices=topo.devices[:entry["chips"]],
+                          **job["mesh"])
+    adapter = importlib.import_module(f"adapters.{config['adapter']}")
+    step, args = adapter.abstract_step(config, job, mesh,
+                                       harness.make_optimizer(job))
+    return step, args, adapter.shapes(config, job), harness.step_bytes
+
+
+def test_looped_step_compiles_for_v5e_with_both_kernels(
+        topo, no_compile_cache, monkeypatch):
+    """The cell ouro-2.6b.s4096's step, 6 layers looped 4 times at 4096
+    tokens: both kernels engage, the calls a step are what the adapter's
+    ``shapes()`` tells the roofline functions (the forward flash kernel
+    once a layer pass in the forward scan and once more in the backward
+    scan, which recomputes the checkpointed pass; the head's kernel once a
+    loop step), the loop's scopes are in the program, and the step fits
+    with the room the issue asks for."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    step, args, shapes, step_bytes = _cell_step("ouro-2.6b.s4096", topo)
+    b, s, h, d = (shapes[k] for k in ("batch", "seq", "heads", "head_dim"))
+    assert pa.attention_path(s, s, h, d, True, False) == "flash"
+    assert px.xent_path(b * s, shapes["vocab"], jnp.bfloat16)[0] == "kernel"
+    compiled = step.lower(*args).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    passes = shapes["layers"] * shapes["loops"]
+    # a call site in a scan's body runs once a layer pass
+    assert sum("hvd_flash_attention" in c for c in calls) * passes \
+        == shapes["attention_forward_calls"]
+    assert sum("hvd_fused_xent" in c for c in calls) == shapes["head_calls"]
+    from horovod_tpu.profiling import scopes
+    names = "\n".join(line for line in text.splitlines()
+                      if "op_name=" in line)
+    assert scopes.LOOP + "/" in names and scopes.LOOP_GATE + "/" in names
+    assert step_bytes(compiled.memory_analysis())["total"] < 15.0e9
+
+
+@pytest.mark.parametrize("cell", ["gpt-1.3b-widths.s2048",
+                                  "olmoe-1b-7b.s4096"])
+def test_the_block_s_new_fields_leave_the_flagship_cells_alone(
+        cell, topo, no_compile_cache, monkeypatch):
+    """``n_loops``, ``post_norm``, ``ffn_gated`` at their defaults and
+    ``remat=None`` on the single scan: the step the cell lowers is, to the
+    letter, the one with every new field spelled out and no checkpoint, so
+    it compiles to the same program and the same bytes."""
+    import dataclasses
+    from horovod_tpu.models import transformer
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    step, args, _shapes, _bytes = _cell_step(cell, topo)
+    lowered = step.lower(*args).as_text()
+    real = transformer.TransformerConfig
+    monkeypatch.setattr(
+        transformer, "TransformerConfig",
+        lambda **kw: dataclasses.replace(
+            real(**kw), n_loops=1, post_norm=False, ffn_gated=False,
+            remat=False))
+    spelled_out, args, _shapes, _bytes = _cell_step(cell, topo)
+    assert spelled_out.lower(*args).as_text() == lowered
+
+
 @pytest.fixture
 def cache_dir_updates(monkeypatch):
     """Record, without applying, what compile_cache.enable() would set."""
