@@ -32,9 +32,10 @@ Phases (default, one chip):
   kernels  every Pallas kernel of the main path, compiled (tpu_custom_call),
            against its XLA reference at real widths (the block attention
            kernels at the shapes of both BERT cells, with padded keys and
-           a row of nothing else); then two steps of the flagship
-           transformer at head_dim 128 with both kernels asserted in the
-           compiled program. xent_path says how the LM loss ran (the
+           a row of nothing else; the flash kernel's gradients through
+           hvd_flash_bwd); then two steps of the flagship transformer at
+           head_dim 128 with the three kernels asserted in the compiled
+           program. xent_path says how the LM loss ran (the
            kernel's rows and chunk and its grid steps, or why XLA).
 
 ``--chips 4`` runs only the four-chip phase and what it is compared with:
@@ -420,9 +421,10 @@ def _check_flash(smoke: Smoke) -> None:
     got = _run_compiled(smoke, kernel, (q, k, v), "hvd_flash_attention")
     want = jax.jit(_reference_attention)(q, k, v)
     _kernel_line(smoke, "flash_attention", "fwd", _rel_err(got, want),
-                 FLASH_TOL, shape=shape, dtype="bfloat16")
+                 FLASH_TOL, shape=shape, dtype="bfloat16",
+                 attention_path=_attention_path(shape))
     got = _run_compiled(smoke, jax.grad(_weighted_sum(kernel, w), (0, 1, 2)),
-                        (q, k, v), "hvd_flash_attention")
+                        (q, k, v), "hvd_flash_bwd")
     want = jax.jit(jax.grad(_weighted_sum(_reference_attention, w),
                             (0, 1, 2)))(q, k, v)
     for name, g, r in zip(("dq", "dk", "dv"), got, want):
@@ -677,8 +679,10 @@ def _attention_path(shape, causal=True, masked=False) -> str:
     """Which implementation ``attend`` picks for q/k/v of ``shape`` (with
     a key mask if ``masked``) on the default backend, read from the
     lowered program; for a kernel, what its rule picks for one call: the
-    flash kernel's tile, the block kernels' batch rows and heads a grid
-    step and their VMEM estimate, and the grid steps."""
+    flash kernel's tile and grid steps, forward and backward, and whether
+    the backward keeps a head's dq in VMEM or goes over the q rows in
+    ranges; the block kernels' batch rows and heads a grid step and their
+    VMEM estimate."""
     import math
     import jax
     import jax.numpy as jnp
@@ -700,7 +704,15 @@ def _attention_path(shape, causal=True, masked=False) -> str:
                 f"VMEM estimate {mib:.1f} MiB")
     bq, bk = pa.flash_blocks(S, S, D, jnp.bfloat16)
     steps = math.prod(pa.flash_grid(B, H, S, S, bq, bk))
-    return f"pallas hvd_flash_attention {bq}x{bk}, {steps} steps"
+    bwd = pa.flash_bwd_blocks(S, S, D, jnp.bfloat16)
+    ranges = S // bwd.rows
+    form = ("dq resident" if ranges == 1
+            else f"dq in {ranges} q ranges of {bwd.rows} rows")
+    return (f"pallas hvd_flash_attention {bq}x{bk}, {steps} steps; "
+            f"hvd_flash_bwd {bwd.block_q}x{bwd.block_k}, {form}, "
+            f"{math.prod(pa.flash_bwd_grid(B, H, S, S, bwd))} steps, VMEM "
+            f"estimate {pa.flash_bwd_vmem_bytes(*bwd, D, 2) / 2 ** 20:.1f} "
+            "MiB")
 
 
 def _check_flagship(smoke: Smoke, hvd) -> None:
@@ -732,7 +744,8 @@ def _check_flagship(smoke: Smoke, hvd) -> None:
     compiled = step.lower(params, opt_state, tokens, targets).compile()
     compile_s = time.perf_counter() - t0
     in_program = {k: _has_kernel(compiled, k)
-                  for k in ("hvd_flash_attention", "hvd_fused_xent")}
+                  for k in ("hvd_flash_attention", "hvd_flash_bwd",
+                            "hvd_fused_xent")}
     if smoke.on_chip:
         check(all(in_program.values()),
               f"kernels missing from the flagship step: {in_program}")
@@ -937,8 +950,10 @@ def _four_ring(smoke: Smoke, hvd) -> None:
     compiled = jax.jit(jax.grad(_weighted_sum(ring, w), (0, 1, 2))).lower(
         q, k, v).compile()
     if smoke.on_chip:
-        check(_has_kernel(compiled, "hvd_flash_attention"),
-              "no flash kernel in the ring attention program")
+        check(_has_kernel(compiled, "hvd_flash_attention")
+              and _has_kernel(compiled, "hvd_flash_bwd"),
+              "the flash kernels are not both in the ring attention "
+              "program")
         check("collective-permute" in compiled.as_text(),
               "no ring permute in the program")
     got = compiled(q, k, v)

@@ -33,14 +33,30 @@ Pallas issues no DMA for a block index that repeats. Only the tiles the
 diagonal crosses build the iota mask; those below it skip it.
 
 The kernel is DIFFERENTIABLE: a ``jax.custom_vjp`` pairs the forward
-kernel (which also emits the per-row log-sum-exp residual) with a
-blockwise backward pass that recomputes attention probabilities one
-K-block at a time from (q, k, v, o, lse) — the standard flash-attention
-backward (Dao et al.), memory-bounded at O(S·BWD_BLOCK_K) instead of
-O(S²), so training through the kernel never materializes the score
-matrix. The backward is XLA code with a block of its own
-(``BWD_BLOCK_K``): its float32 temporaries grow with the block, so the
-forward's tile does not reach it.
+kernel (which also emits the per-row log-sum-exp residual) with a second
+kernel, ``hvd_flash_bwd``, that recomputes the attention probabilities
+tile by tile in VMEM from (q, k, v, o, lse) — the standard flash-attention
+backward (Dao et al.) — so training through the kernel never writes a
+score-shaped array to HBM:
+
+  grid = (batch·heads, q ranges, Sk/block_k, rows/block_q) — q tile innermost
+  per (k tile): for each q tile: pT = exp(k @ qᵀ·scale − lse);
+      dv += pT @ do; dsT = pT ⊙ (v @ doᵀ − adj); dk += dsT @ q; dq += dsTᵀ @ k
+
+One kernel, not the usual dk/dv + dq pair: a head's whole float32 dq
+(``Sq·D·4`` bytes, 2 MiB at 4096 positions) stays in a VMEM scratch across
+its k tiles, so every tile is computed once: five matmuls and one pass of
+the vector work where two kernels do seven and two. Where that does not
+fit (tens of thousands of positions on one device) the q rows go in ranges
+(:func:`flash_bwd_blocks`). Its tile is a rule of its own
+(``flash_bwd_blocks``): 1024 × 1024 at the benchmark's shapes, where the
+MXU is at 86 % of its peak on the tiles it runs whole (v5e; PERF.md, PR
+31); a square tile on the diagonal runs in ``DIAG_ROWS``-row pieces that
+leave out the q rows masked for the whole piece. Same precision as the forward:
+operands multiply as they come and accumulate in float32, ``pT`` and
+``dsT`` are float32 in VMEM and cast for their matmuls. q, k, v and do are
+read as ``[B, S, H·D]``, a head whole 128-lane columns, and dq, dk, dv
+written so: no transpose around the call.
 
 Falls back to the pure-XLA implementation on CPU or when shapes don't meet
 TPU tiling constraints (last dim 128-multiple, 128-divisible sequence).
@@ -69,16 +85,21 @@ NEG_INF = -1e30
 #: what callers are held to: Sq, Sk and head_dim are multiples of this
 #: (the TPU's lane count; the smallest tile)
 MIN_BLOCK = 128
-#: K-block of the XLA backward (``_flash_bwd``): sets its float32
-#: ``[B·H, Sq − r0, bk]`` temporaries and the count of unrolled einsums
-BWD_BLOCK_K = 128
-
 TILES = (1024, 512, 256, MIN_BLOCK)
 #: bytes the forward's working set may take by ``flash_vmem_bytes``: the
 #: v5e's default scoped-VMEM limit (16 MiB of 128), so no limit is asked
 #: for. The estimate counts ``s`` and ``p`` apart where the compiler shares
 #: their room: tiles it puts at 22 MiB still compile under that limit
 VMEM_BUDGET = 16 * 1024 * 1024
+
+_NT = (((1,), (1,)), ((), ()))      # a · bᵀ
+_NN = (((1,), (0,)), ((), ()))      # a · b
+_TN = (((0,), (0,)), ((), ()))      # aᵀ · b
+
+
+def _dot(a, b, dims):
+    return lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
 
 
 def flash_vmem_bytes(block_q: int, block_k: int, D: int, itemsize: int) -> int:
@@ -246,87 +267,287 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
     return (o, lse), (q, k, v, o, lse)
 
 
-def _flash_bwd(causal, scale, block_q, block_k, interpret, res, cts):
-    """Blockwise flash backward (Dao et al.): recompute p = exp(s - lse)
-    one K-block at a time; dv = pᵀdo, ds = p⊙(do·vᵀ − Δ + dlse), dq +=
-    ds·k, dk = dsᵀq. Peak extra memory O(Sq·BWD_BLOCK_K) per (batch·head).
-    The lse cotangent enters through ∂lse/∂s_j = p_j (lse is the row
-    log-partition), which is what makes the (o, lse) pair usable as a
-    mergeable partial result (ring attention). ``block_q`` / ``block_k``
-    are the forward's tile and set nothing here."""
-    do, dlse = cts
-    q, k, v, o, lse = res
+# ---------------------------------------------------------------------------
+# The backward: one kernel, k tile outer, q tile inner, dq resident
+# ---------------------------------------------------------------------------
+#
+# The scores are computed transposed, ``sT = k @ qT`` as ``[bk, bq]``: a q
+# row's log-sum-exp and its ``adj`` then lie along the lanes, as the ``[1,
+# bq]`` rows the forward wrote, and of the five matmuls only dq's needs a
+# transposed operand (q-major scores need it for dv and for dk).
+
+#: k rows of a piece of a square tile on the diagonal
+#: (:func:`_flash_bwd_kernel`). v5e, a 1024 x 1024 tile, ms a call at the
+#: looped cell's shape: whole 1.311, pieces of 512 1.229, 256 1.232, 128
+#: 1.195 (PERF.md, PR 31)
+DIAG_ROWS = 128
+#: bytes the backward's working set may take by ``flash_bwd_vmem_bytes``,
+#: asked for as the call's scoped-VMEM limit (the v5e has 128 MiB)
+BWD_VMEM_BUDGET = 32 * 1024 * 1024
+
+
+class BwdBlocks(NamedTuple):
+    """The backward's tile and the q rows whose dq a head keeps in VMEM."""
+    block_q: int
+    block_k: int
+    rows: int
+
+
+def flash_bwd_vmem_bytes(block_q: int, block_k: int, rows: int, D: int,
+                         itemsize: int) -> int:
+    """Working set of one grid step of the backward: q, do, k, v tiles and
+    the dk, dv and ``[rows, D]`` dq output blocks double-buffered by the
+    pipeline, the lse and adj rows (a ``[1, bq]`` float32 block takes 8
+    sublanes), the float32 accumulators of dk, dv and dq, and the score
+    tile five times: ``sT`` / ``pT``, ``dpT`` and ``dsT`` in float32, dsT
+    transposed for dq's matmul, and the two casts for the matmuls."""
+    io = 2 * (2 * block_q + 2 * block_k) * D * itemsize
+    stats = 2 * 2 * 8 * block_q * 4
+    out = 2 * (2 * block_k + rows) * D * itemsize
+    scratch = (2 * block_k + rows) * D * 4
+    tiles = block_q * block_k * (3 * 4 + 3 * itemsize)
+    return io + stats + out + scratch + tiles
+
+
+def flash_bwd_blocks(Sq: int, Sk: int, D: int, dtype) -> BwdBlocks:
+    """The backward kernel's ``(block_q, block_k, rows)`` for q/do ``[..,
+    Sq, D]`` against k/v ``[.., Sk, D]``. ``rows == Sq`` is the resident
+    form: a head's whole float32 dq stays in VMEM across its k tiles and
+    every tile is computed once (five matmuls, one pass of the vector
+    work). Where that does not fit ``BWD_VMEM_BUDGET`` (a sequence of tens
+    of thousands of positions on one device) the q rows are split into
+    ranges of ``rows``, each of which walks all k tiles and writes its own
+    partial dk and dv, summed outside the kernel."""
+    if Sq % MIN_BLOCK or Sk % MIN_BLOCK:
+        raise ValueError(f"Sq={Sq} and Sk={Sk} must be multiples of "
+                         f"{MIN_BLOCK}")
+    itemsize = jnp.dtype(dtype).itemsize
+
+    def over(bq, bk, rows):
+        return flash_bwd_vmem_bytes(bq, bk, rows, D, itemsize) \
+            > BWD_VMEM_BUDGET
+    # the fewest ranges whose accumulators leave room for the smallest tile
+    units = Sq // MIN_BLOCK
+    rows = Sq // next(n for n in range(1, units + 1) if units % n == 0
+                      and not over(MIN_BLOCK, MIN_BLOCK, Sq // n))
+    bq = next(t for t in TILES if rows % t == 0)
+    bk = next(t for t in TILES if Sk % t == 0)
+    while over(bq, bk, rows) and max(bq, bk) > MIN_BLOCK:
+        if bq >= bk:
+            bq //= 2
+        else:
+            bk //= 2
+    return BwdBlocks(bq, bk, rows)
+
+
+def flash_bwd_grid(B: int, H: int, Sq: int, Sk: int,
+                   blocks: BwdBlocks) -> Tuple[int, int, int, int]:
+    """The backward kernel's grid: (heads, q ranges, k tiles, q tiles of a
+    range), the last innermost."""
+    return (B * H, Sq // blocks.rows, Sk // blocks.block_k,
+            blocks.rows // blocks.block_q)
+
+
+def _first_live_q_tile(kj, block_q: int, block_k: int):
+    """Causal: the first q tile with a row at or below k tile ``kj``'s
+    first column; the q tiles before it are wholly above the diagonal."""
+    return (kj * block_k) // block_q
+
+
+def _last_live_k_tile(qi, block_q: int, block_k: int):
+    """Causal: the last k tile with a column at or before q tile ``qi``'s
+    last row."""
+    return (qi * block_q + block_q - 1) // block_k
+
+
+def _flash_bwd_kernel(q_ref, do_ref, k_ref, v_ref, lse_ref, adj_ref,
+                      dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
+                      scale: float, causal: bool, block_q: int,
+                      block_k: int):
+    """One (k tile, q tile) step; grid (BH, q ranges, nk, nq) with q
+    innermost: dk and dv of the k tile accumulate over the q tiles, dq of
+    the range's rows over the k tiles."""
+    kj, i = pl.program_id(2), pl.program_id(3)
+    nk, nq = pl.num_programs(2), pl.num_programs(3)
+    qi = pl.program_id(1) * nq + i            # the q tile in the sequence
+
+    @pl.when(i == 0)
+    def _init():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    def part(k0: int, n_k: int, q0: int, masked: bool):
+        """The tile's k rows ``[k0, k0 + n_k)`` against its q rows from
+        ``q0`` on (static): dk and dv of those k rows, dq of those q
+        rows."""
+        ks, qs = pl.ds(k0, n_k), pl.ds(q0, block_q - q0)
+        q, do, k, v = q_ref[0, qs], do_ref[0, qs], k_ref[0, ks], v_ref[0, ks]
+        st = _dot(k, q, _NT) * scale                    # [n_k, bq - q0]
+        if masked:
+            kpos = kj * block_k + k0 + lax.broadcasted_iota(
+                jnp.int32, st.shape, 0)
+            qpos = qi * block_q + q0 + lax.broadcasted_iota(
+                jnp.int32, st.shape, 1)
+            st = jnp.where(qpos >= kpos, st, NEG_INF)
+        pt = jnp.exp(st - lse_ref[0, :, qs])            # lse: [1, bq - q0]
+        dv_acc[ks] += _dot(pt.astype(do.dtype), do, _NN)
+        # d loss / d s = p * (dp - adj); s = scale * q kT, and the scale
+        # goes on dq and dk as they are written, not on the score tile
+        dst = (pt * (_dot(v, do, _NT) - adj_ref[0, :, qs])).astype(q.dtype)
+        dk_acc[ks] += _dot(dst, q, _NN)
+        dq = _dot(dst, k, _TN)                          # [bq - q0, D]
+        rows = pl.ds(pl.multiple_of(i * block_q + q0, MIN_BLOCK),
+                     block_q - q0)
+        if k0 == 0:     # the rows' first k rows, if this is the first tile
+            @pl.when(kj == 0)       # every q tile meets the first k tile
+            def _first():
+                dq_acc[rows] = dq
+
+            @pl.when(kj > 0)
+            def _later():
+                dq_acc[rows] += dq
+        else:
+            dq_acc[rows] += dq
+
+    def whole(masked: bool):
+        part(0, block_k, 0, masked)
+
+    def diagonal():
+        """A square tile on the diagonal, ``DIAG_ROWS`` k rows at a time:
+        the q rows before a piece's first column are masked for all of it
+        and are left out, so a 1024 x 1024 tile runs 36 of its 64 blocks
+        of 128 x 128 in eight pieces."""
+        piece = min(DIAG_ROWS, block_k)
+        for k0 in range(0, block_k, piece):
+            part(k0, piece, k0, True)
+
+    if causal:
+        first_row, first_col = qi * block_q, kj * block_k
+        last_row, last_col = first_row + block_q - 1, first_col + block_k - 1
+        # the diagonal crosses the tile: some of it is masked, not all.
+        # Square tiles meet the diagonal corner to corner (qi == kj)
+        crossed = jnp.logical_and(first_col <= last_row,
+                                  last_col > first_row)
+        pl.when(crossed)(diagonal if block_q == block_k
+                         else functools.partial(whole, True))
+        # wholly at or below the diagonal: no mask to build. Tiles
+        # strictly above it run nothing (and fetch nothing: q_tile)
+        pl.when(last_col <= first_row)(functools.partial(whole, False))
+        last_kj = jnp.minimum(_last_live_k_tile(qi, block_q, block_k),
+                              nk - 1)
+    else:
+        whole(False)
+        last_kj = nk - 1
+
+    rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
+
+    @pl.when(kj == last_kj)
+    def _write_dq():
+        dq_ref[0, rows] = (dq_acc[rows] * scale).astype(dq_ref.dtype)
+
+    @pl.when(i == nq - 1)
+    def _write_dkv():
+        dk_ref[0, 0] = (dk_acc[:] * scale).astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
+
+
+# jitted so that the call sites of a program (a ring step's switch
+# branches, layers outside a scan) share one trace and one Mosaic lowering
+@functools.partial(jax.jit, static_argnames=("H", "causal", "scale",
+                                             "blocks", "interpret"))
+def _flash_bwd_local(q, k, v, do, lse, adj, *, H, causal, scale, blocks,
+                     interpret):
+    """(dq ``[B, Sq, H*D]``, dk and dv ``[ranges, B, Sk, H*D]``) of q, do
+    ``[B, Sq, H*D]`` and k, v ``[B, Sk, H*D]`` as the projections wrote
+    them: a head is whole 128-lane columns (one at head_dim 128), so a
+    ``(1, tile, D)`` block addresses it with no transpose around the call.
+    lse and adj are ``[B*H, 1, Sq]`` float32, a q row along the lanes."""
+    B, Sq, M = q.shape
+    Sk, D = k.shape[1], M // H
+    bq, bk, rows = blocks
+    grid = flash_bwd_grid(B, H, Sq, Sk, blocks)
+    nq = grid[3]
+
+    if causal:
+        # above the diagonal the block index repeats the k tile's first
+        # live q tile, so the pipeline issues no DMA for a skipped step
+        def q_tile(r, j, i):
+            return jnp.clip(_first_live_q_tile(j, bq, bk), r * nq + i,
+                            r * nq + nq - 1)
+    else:
+        def q_tile(r, j, i):
+            return r * nq + i
+    q_spec = pl.BlockSpec((1, bq, D),
+                          lambda b, r, j, i: (b // H, q_tile(r, j, i), b % H))
+    k_spec = pl.BlockSpec((1, bk, D), lambda b, r, j, i: (b // H, j, b % H))
+    row_spec = pl.BlockSpec((1, 1, bq),
+                            lambda b, r, j, i: (b, 0, q_tile(r, j, i)))
+    part_spec = pl.BlockSpec((1, 1, bk, D),
+                             lambda b, r, j, i: (r, b // H, j, b % H))
+    part = jax.ShapeDtypeStruct((grid[1],) + k.shape, k.dtype)
+    return pl.pallas_call(
+        functools.partial(_flash_bwd_kernel, scale=scale, causal=causal,
+                          block_q=bq, block_k=bk),
+        grid=grid,
+        in_specs=[q_spec, q_spec, k_spec, k_spec, row_spec, row_spec],
+        out_specs=[
+            pl.BlockSpec((1, rows, D), lambda b, r, j, i: (b // H, r, b % H)),
+            part_spec, part_spec],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype), part, part],
+        scratch_shapes=[
+            pltpu.VMEM((rows, D), jnp.float32),      # dq of the range
+            pltpu.VMEM((bk, D), jnp.float32),        # dk of the k tile
+            pltpu.VMEM((bk, D), jnp.float32),        # dv
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary",
+                                 "arbitrary"),
+            vmem_limit_bytes=BWD_VMEM_BUDGET),
+        interpret=interpret,
+        name="hvd_flash_bwd",
+    )(q, do, k, v, lse, adj)
+
+
+def flash_backward(q, k, v, o, lse, do, dlse, causal: bool, scale: float,
+                   blocks: Optional[BwdBlocks] = None,
+                   interpret: bool = False):
+    """(dq, dk, dv) of flash attention from its residuals (q, k, v, o
+    ``[B, S, H, D]``, lse ``[B*H, Sq]``) and the cotangents of ``o`` and
+    ``lse``: p = exp(s - lse) is recomputed tile by tile in VMEM (Dao et
+    al.), dv = pT do, ds = p * (do vT - adj), dq = ds k, dk = dsT q, all
+    times ``scale``. ``adj = sum_d do * o - dlse`` is computed here, once,
+    in float32: the lse cotangent enters through d lse / d s_j = p_j (lse
+    is the row log-partition), which is what makes the (o, lse) pair
+    usable as a mergeable partial result (ring attention). Operands
+    multiply in the dtype they come in and accumulate in float32; p and ds
+    are float32 in VMEM and cast for their matmuls. ``blocks`` overrides
+    :func:`flash_bwd_blocks` (tests, sweeps)."""
     B, Sq, H, D = q.shape
     Sk = k.shape[1]
-    bk = BWD_BLOCK_K
-    nk = Sk // bk
+    blocks = blocks or flash_bwd_blocks(Sq, Sk, D, q.dtype)
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    adj = delta.transpose(0, 2, 1).reshape(B * H, Sq) \
+        - dlse.astype(jnp.float32)
+    dq, dk, dv = _flash_bwd_local(
+        q.reshape(B, Sq, H * D), k.reshape(B, Sk, H * D),
+        v.reshape(B, Sk, H * D), do.reshape(B, Sq, H * D),
+        lse.reshape(B * H, 1, Sq), adj.reshape(B * H, 1, Sq), H=H,
+        causal=causal, scale=scale, blocks=blocks, interpret=interpret)
 
-    qf = q.transpose(0, 2, 1, 3).reshape(B * H, Sq, D).astype(jnp.float32)
-    kf = k.transpose(0, 2, 1, 3).reshape(B * H, Sk, D).astype(jnp.float32)
-    vf = v.transpose(0, 2, 1, 3).reshape(B * H, Sk, D).astype(jnp.float32)
-    of = o.transpose(0, 2, 1, 3).reshape(B * H, Sq, D).astype(jnp.float32)
-    dof = do.transpose(0, 2, 1, 3).reshape(B * H, Sq, D).astype(jnp.float32)
+    def total(parts):
+        if parts.shape[0] > 1:
+            parts = parts.astype(jnp.float32).sum(0).astype(parts.dtype)
+        return parts.reshape(B, Sk, H, D)
+    return dq.reshape(B, Sq, H, D), total(dk), total(dv)
 
-    if dlse is None:
-        dlse = jnp.zeros_like(lse)
-    # ds = p ⊙ (dp − Δ + dlse): fold the lse cotangent into the row term
-    adj = jnp.sum(dof * of, axis=-1) - dlse.astype(jnp.float32)  # [BH, Sq]
 
-    dq = jnp.zeros_like(qf)
-    dk = jnp.zeros_like(kf)
-    dv = jnp.zeros_like(vf)
-
-    if causal and nk <= 64:
-        # Statically-unrolled loop with per-block row restriction: K-block
-        # j only reaches q rows >= j*bk (the rest are masked in the
-        # forward), so slicing the q side halves the backward FLOPs —
-        # mirroring the forward kernel's diagonal block-skip. Unrolling is
-        # bounded (<= 64 blocks) to keep compile time sane; longer
-        # sequences take the dynamic full-row loop below.
-        for j in range(nk):
-            r0 = j * bk                                     # first live row
-            qs, dos = qf[:, r0:], dof[:, r0:]
-            kb, vb = kf[:, r0:r0 + bk], vf[:, r0:r0 + bk]
-            s = jnp.einsum("bqd,bkd->bqk", qs, kb) * scale  # [BH,Sq-r0,bk]
-            qpos = r0 + jnp.arange(Sq - r0)
-            kpos = r0 + jnp.arange(bk)
-            s = jnp.where(qpos[:, None] >= kpos[None, :], s, NEG_INF)
-            p = jnp.exp(s - lse[:, r0:, None])
-            dvb = jnp.einsum("bqk,bqd->bkd", p, dos)
-            dp = jnp.einsum("bqd,bkd->bqk", dos, vb)
-            ds = p * (dp - adj[:, r0:, None]) * scale
-            dq = dq.at[:, r0:].add(jnp.einsum("bqk,bkd->bqd", ds, kb))
-            dk = dk.at[:, r0:r0 + bk].set(
-                jnp.einsum("bqk,bqd->bkd", ds, qs))
-            dv = dv.at[:, r0:r0 + bk].set(dvb)
-    else:
-        qpos = jnp.arange(Sq)
-
-        def block(j, carry):
-            dq, dk, dv = carry
-            kb = lax.dynamic_slice_in_dim(kf, j * bk, bk, axis=1)
-            vb = lax.dynamic_slice_in_dim(vf, j * bk, bk, axis=1)
-            s = jnp.einsum("bqd,bkd->bqk", qf, kb) * scale  # [BH,Sq,bk]
-            if causal:
-                kpos = j * bk + jnp.arange(bk)
-                s = jnp.where(qpos[:, None] >= kpos[None, :], s, NEG_INF)
-            p = jnp.exp(s - lse[..., None])                 # [BH,Sq,bk]
-            dvb = jnp.einsum("bqk,bqd->bkd", p, dof)
-            dp = jnp.einsum("bqd,bkd->bqk", dof, vb)
-            ds = p * (dp - adj[..., None]) * scale
-            dq = dq + jnp.einsum("bqk,bkd->bqd", ds, kb)
-            dkb = jnp.einsum("bqk,bqd->bkd", ds, qf)
-            dk = lax.dynamic_update_slice_in_dim(dk, dkb, j * bk, axis=1)
-            dv = lax.dynamic_update_slice_in_dim(dv, dvb, j * bk, axis=1)
-            return dq, dk, dv
-
-        dq, dk, dv = lax.fori_loop(0, nk, block, (dq, dk, dv))
-
-    def unfold(x, S):
-        return x.reshape(B, H, S, D).transpose(0, 2, 1, 3)
-
-    return (unfold(dq, Sq).astype(q.dtype), unfold(dk, Sk).astype(k.dtype),
-            unfold(dv, Sk).astype(v.dtype))
+def _flash_bwd(causal, scale, block_q, block_k, interpret, res, cts):
+    """``block_q`` / ``block_k`` are the forward's tile and set nothing
+    here: the backward's come from :func:`flash_bwd_blocks`."""
+    q, k, v, o, lse = res
+    do, dlse = cts
+    return flash_backward(q, k, v, o, lse, do, dlse, causal, scale,
+                          interpret=interpret)
 
 
 _flash_lse.defvjp(_flash_fwd, _flash_bwd)
@@ -470,15 +691,6 @@ def _unstack(y, D: int):
     for h in range(1, heads):
         out = jnp.where(_head_lanes(h, D), y[h * S:(h + 1) * S], out)
     return out
-
-
-_NT = (((1,), (1,)), ((), ()))      # a · bᵀ
-_NN = (((1,), (0,)), ((), ()))      # a · b
-_TN = (((0,), (0,)), ((), ()))      # aᵀ · b
-
-
-def _dot(a, b, dims):
-    return lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
 
 
 def _scaled(q, scale: float):

@@ -21,8 +21,12 @@ Host spans go through :func:`horovod_tpu.profiling.annotate`.
 
 A model that needs another phase adds it here, and
 nowhere else: ``tests/test_scopes.py`` holds the strings to this file.
-The Pallas kernels' names (``hvd_flash_attention``, ``hvd_fused_xent``)
-are instruction names, not scopes, and stay where the kernels are.
+The Pallas kernels' names (``hvd_flash_attention``, ``hvd_flash_bwd``,
+``hvd_fused_xent``) are instruction names, not scopes, and stay where the
+kernels are. A metric finds a kernel by searching its pattern in the
+instruction's name, so no kernel's name holds another's: attention's
+backward is not ``hvd_flash_attention_bwd``, which every metric of the
+forward kernel would sum in.
 """
 
 from __future__ import annotations

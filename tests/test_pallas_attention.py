@@ -137,24 +137,96 @@ def test_flash_kernel_grads_match_oracle(causal):
                                    (128, 256, 2)])
 def test_flash_kernel_grads_match_oracle_at_the_rules_tiles(shape, causal):
     """The forward's tile (512 x 1024, 128 x 128, 128 x 256) and the
-    backward's block (always BWD_BLOCK_K) are two values."""
+    backward's (``flash_bwd_blocks``: 1024 x 1024 in four pieces on the
+    diagonal, 128 x 128, 128 x 256) are two rules."""
     Sq, Sk, H = shape
     _assert_grads(*_qkv(B=1, S=Sq, Sk=Sk, H=H, seed=7), causal,
                   _cos_cotangent)
 
 
-def test_flash_backward_ignores_the_forward_tile():
-    """The backward's program is the same whatever tile the forward took
-    (its float32 temporaries grow with the block: PERF.md, PR 25)."""
-    q, k, v = _qkv(B=1, S=512, H=1, seed=8)
-    res = (q, k, v, q, jnp.zeros((1, 512), jnp.float32))
-    cts = (q, None)
+def _backward(q, k, v, causal, cotangent, blocks=None, lse_weight=None):
+    """(dq, dk, dv) from ``flash_backward`` on the forward kernel's own
+    residuals, and the oracle's by autodiff; with ``lse_weight`` the
+    loss also reads the log-sum-exp (ring attention's merge does)."""
+    scale = q.shape[-1] ** -0.5
+    o, lse = pa.flash_attention_with_lse(q, k, v, causal, interpret=True)
 
-    def text(bq, bk):
-        return str(jax.make_jaxpr(
-            lambda: pa._flash_bwd(True, 0.1, bq, bk, True, res, cts))())
-    assert text(128, 128) == text(512, 512)
-    assert f"512,{pa.BWD_BLOCK_K}]" in text(512, 512)
+    def loss(o, lse):
+        extra = 0.0 if lse_weight is None else jnp.sum(lse * lse_weight)
+        return cotangent(o) + extra
+    do, dlse = jax.grad(loss, (0, 1))(o.astype(jnp.float32), lse)
+    got = pa.flash_backward(q, k, v, o, lse, do.astype(q.dtype), dlse,
+                            causal, scale, blocks=blocks, interpret=True)
+
+    def oracle(q, k, v):
+        B, Sq, H, _D = q.shape
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
+        if causal:
+            s = jnp.where(jnp.tril(jnp.ones(s.shape[-2:], bool)), s,
+                          -jnp.inf)
+        o = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), v)
+        return loss(o, jax.nn.logsumexp(s, -1).reshape(B * H, Sq))
+    want = jax.grad(oracle, (0, 1, 2))(
+        *(x.astype(jnp.float32) for x in (q, k, v)))
+    return got, want
+
+
+def _assert_backward(got, want, tol=2e-4):
+    for g, w, name in zip(got, want, ("dq", "dk", "dv")):
+        assert g.dtype == got[0].dtype
+        np.testing.assert_allclose(np.asarray(g.astype(jnp.float32)),
+                                   np.asarray(w), rtol=tol, atol=tol,
+                                   err_msg=name)
+
+
+# (Sq, Sk, block_q, block_k, rows): q tiles wider and narrower than k
+# tiles, square tiles of several diagonal pieces, dq resident and in q
+# ranges of two tiles and of one, Sq != Sk both ways
+_BWD_TILES = [(512, 512, 128, 128, 512), (512, 512, 256, 128, 512),
+              (512, 512, 128, 256, 512), (512, 512, 512, 512, 512),
+              (1024, 1024, 512, 512, 1024), (512, 512, 128, 256, 256),
+              (512, 512, 128, 128, 128), (768, 512, 256, 256, 768),
+              (256, 512, 128, 128, 256)]
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("tile", _BWD_TILES)
+def test_flash_backward_tile_overrides(tile, causal):
+    """Every tile and every q range gives the oracle's gradients: the
+    diagonal crosses tiles in every way, tiles above it are skipped, a
+    square tile on it runs in pieces, partial dk / dv of ranges add up."""
+    Sq, Sk, bq, bk, rows = tile
+    got, want = _backward(*_qkv(B=1, S=Sq, Sk=Sk, H=2, seed=9), causal,
+                          _cos_cotangent, pa.BwdBlocks(bq, bk, rows))
+    _assert_backward(got, want)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_flash_backward_takes_the_lse_cotangent(causal):
+    q, k, v = _qkv(B=1, S=256, H=2, seed=10)
+    weight = jnp.asarray(np.random.RandomState(11).randn(2, 256),
+                         jnp.float32)
+    got, want = _backward(q, k, v, causal, _cos_cotangent,
+                          lse_weight=weight)
+    _assert_backward(got, want)
+    # and it matters: without it dq differs
+    plain, _ = _backward(q, k, v, causal, _cos_cotangent)
+    assert float(jnp.max(jnp.abs(plain[0] - got[0]))) > 1e-3
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("S", [256, 1024])
+def test_flash_backward_bf16_inputs_match_float32_oracle(S, causal):
+    """bf16 operands multiply as bf16 with float32 accumulation, p and ds
+    are cast for their matmuls: against the float32 oracle on the same
+    values the gradients hold chip_smoke.py's tolerance."""
+    q, k, v = _qkv(B=1, S=S, H=1, seed=12, dtype=jnp.bfloat16)
+    got, want = _backward(q, k, v, causal, lambda o: jnp.sum(o ** 2))
+    assert got[0].dtype == jnp.bfloat16
+    for g, w, name in zip(got, want, ("dq", "dk", "dv")):
+        err = jnp.max(jnp.abs(g.astype(jnp.float32) - w)) / jnp.max(
+            jnp.abs(w))
+        assert float(err) <= 2e-2, (name, float(err))
 
 
 def test_flash_grads_rect():
@@ -222,3 +294,101 @@ def test_flash_eligible_is_the_contract_of_128():
     assert pa.flash_eligible(128, 256, 128)
     assert pa.flash_eligible(384, 384, 256)
     assert not pa.flash_eligible(256, 256, 64)
+
+
+# -- the backward's tile rule -------------------------------------------------
+
+# what the three causal cells and a ring step of chip_smoke.py call it
+# with (head_dim 128, bf16): (Sq, Sk) -> (block_q, block_k, rows)
+_BWD_RULE = {
+    (2048, 2048): (1024, 1024, 2048),     # gpt-1.3b-widths.s2048
+    (4096, 4096): (1024, 1024, 4096),     # olmoe-1b-7b.s4096, ouro-2.6b.s4096
+    (512, 512): (512, 512, 512),          # ring attention, sp=4 of 2048
+    (128, 256): (128, 256, 128),
+    (384, 640): (128, 128, 384),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(_BWD_RULE))
+def test_flash_bwd_blocks_at_the_shapes_that_run(shape):
+    blocks = pa.flash_bwd_blocks(*shape, 128, jnp.bfloat16)
+    assert blocks == _BWD_RULE[shape]
+    assert blocks.rows == shape[0]        # dq resident: one range
+    assert pa.flash_bwd_vmem_bytes(*blocks, 128, 2) <= pa.BWD_VMEM_BUDGET
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("D", [128, 256, 512])
+def test_flash_bwd_blocks_divide_and_fit(D, dtype):
+    itemsize = jnp.dtype(dtype).itemsize
+    for Sq in _LENGTHS + [16384, 65536]:
+        for Sk in _LENGTHS:
+            bq, bk, rows = pa.flash_bwd_blocks(Sq, Sk, D, dtype)
+            assert bq in pa.TILES and bk in pa.TILES
+            assert Sq % rows == 0 and rows % bq == 0 and Sk % bk == 0
+            assert pa.flash_bwd_vmem_bytes(bq, bk, rows, D, itemsize) \
+                <= pa.BWD_VMEM_BUDGET
+
+
+def test_flash_bwd_blocks_keep_dq_resident_while_it_fits():
+    """A head's float32 dq and its output block are 8 bytes a row and
+    lane at bf16: resident to 16 384 rows at head_dim 128 beside the
+    smallest tiles; beyond that the q rows go in ranges."""
+    for S in (4096, 8192, 16384):
+        assert pa.flash_bwd_blocks(S, S, 128, jnp.bfloat16).rows == S
+    long = pa.flash_bwd_blocks(65536, 65536, 128, jnp.bfloat16)
+    assert long.rows < 65536 and 65536 % long.rows == 0
+    assert pa.flash_bwd_grid(1, 2, 65536, 65536, long)[1] \
+        == 65536 // long.rows
+    # a length whose only divisors are 1 and itself goes tile by tile
+    prime = pa.flash_bwd_blocks(128 * 251, 128 * 251, 128, jnp.bfloat16)
+    assert prime == (128, 128, 128)
+    # float32 and a wider head hold fewer rows
+    assert pa.flash_bwd_blocks(16384, 16384, 256, jnp.float32).rows < 16384
+    assert pa.flash_bwd_grid(2, 16, 2048, 2048, pa.BwdBlocks(
+        1024, 1024, 2048)) == (32, 1, 2, 2)
+
+
+@pytest.mark.parametrize("tile", [(2048, 2048, 1024, 1024, 3, 4),
+                                  (2048, 2048, 512, 512, 10, 16),
+                                  (4096, 4096, 1024, 1024, 10, 16),
+                                  (512, 1024, 256, 128, 6, 16),
+                                  (1024, 512, 128, 256, 14, 16)])
+def test_flash_bwd_causal_tiles_above_the_diagonal_are_not_visited(tile):
+    """The q tile a grid step fetches is clamped to the k tile's first
+    live one, and a q tile's dq is written at its last live k tile: by
+    that arithmetic the live tiles are those the mask leaves anything
+    of (S 2048: 3 of 4 at 1024 x 1024, 10 of 16 at 512 x 512)."""
+    Sq, Sk, bq, bk, live, steps = tile
+    nq, nk = Sq // bq, Sk // bk
+    assert nq * nk == steps
+    seen = 0
+    for kj in range(nk):
+        first = min(pa._first_live_q_tile(kj, bq, bk), nq)
+        for qi in range(nq):
+            any_live = qi * bq + bq - 1 >= kj * bk
+            assert any_live == (qi >= first)
+            if any_live:
+                assert kj <= min(pa._last_live_k_tile(qi, bq, bk), nk - 1)
+            seen += any_live
+    assert seen == live
+    for qi in range(nq):     # the write comes at a live tile, the last
+        last = min(pa._last_live_k_tile(qi, bq, bk), nk - 1)
+        assert qi >= pa._first_live_q_tile(last, bq, bk)
+        assert last == nk - 1 or qi < pa._first_live_q_tile(last + 1, bq,
+                                                            bk)
+
+
+@pytest.mark.parametrize("Sq,Sk", [(100, 128), (128, 192)])
+def test_flash_bwd_blocks_refuses_what_128_does_not_divide(Sq, Sk):
+    with pytest.raises(ValueError):
+        pa.flash_bwd_blocks(Sq, Sk, 128, jnp.float32)
+
+
+def test_flash_grads_rect_causal():
+    """Sq != Sk under the causal mask, both ways (top-left alignment: a
+    k tile beyond the last q row gets zeros)."""
+    _assert_grads(*_qkv(B=1, S=256, Sk=128, seed=13), True,
+                  lambda o: jnp.sum(o ** 2))
+    _assert_grads(*_qkv(B=1, S=128, Sk=384, seed=14), True,
+                  lambda o: jnp.sum(o ** 2))

@@ -69,6 +69,10 @@ _QKV = [((8, 2048, 8, 128), jnp.bfloat16)] * 3
 # the benchmark's cell gpt-1.3b-widths.s2048: B2 S2048 H16 D128 bf16. A tile
 # that does not fit VMEM or a block spec the lowering refuses fails here
 _QKV_CELL = [((2, 2048, 16, 128), jnp.bfloat16)] * 3
+# the 4096-token cells (ouro-2.6b.s4096; olmoe-1b-7b.s4096 at batch 2): the
+# backward holds a head's float32 dq of 4096 rows in VMEM
+_QKV_S4096 = [((1, 4096, 16, 128), jnp.bfloat16)] * 3
+_FLASH = ("hvd_flash_attention", "hvd_flash_bwd")
 # the attention core of the cells bert-large.s128 and bert-large.s512: q, k,
 # v and the [B, S] key mask. Sixteen heads of 64 are eight 128-lane columns
 _BERT_S128 = [((64, 128, 16, 64), jnp.bfloat16)] * 3 + [((64, 128), jnp.bool_)]
@@ -89,22 +93,18 @@ CASES = {
     "flash_fwd": (
         lambda q, k, v: pa.flash_attention_tpu(q, k, v, True),
         _QKV, "hvd_flash_attention"),
-    "flash_fwd_grad": (
-        jax.grad(lambda q, k, v: _sum32(
-            pa.flash_attention_tpu(q, k, v, True)), (0, 1, 2)),
-        _QKV, "hvd_flash_attention"),
+    "flash_fwd_grad": (_flash_grad := jax.grad(lambda q, k, v: _sum32(
+        pa.flash_attention_tpu(q, k, v, True)), (0, 1, 2)), _QKV, _FLASH),
     "flash_fwd_cell": (
         lambda q, k, v: pa.flash_attention_tpu(q, k, v, True),
         _QKV_CELL, "hvd_flash_attention"),
-    "flash_fwd_grad_cell": (
-        jax.grad(lambda q, k, v: _sum32(
-            pa.flash_attention_tpu(q, k, v, True)), (0, 1, 2)),
-        _QKV_CELL, "hvd_flash_attention"),
+    "flash_fwd_grad_cell": (_flash_grad, _QKV_CELL, _FLASH),
+    "flash_fwd_grad_s4096": (_flash_grad, _QKV_S4096, _FLASH),
     # the ring-attention step: non-causal, lse differentiated too
     "flash_lse_noncausal_grad": (
         jax.grad(lambda q, k, v: _sum32(*pa.flash_attention_with_lse(
             q, k, v, causal=False)), (0, 1, 2)),
-        _QKV, "hvd_flash_attention"),
+        _QKV, _FLASH),
     "block_fwd_s128": (pa.block_attention, _BERT_S128, pa.FWD_NAME),
     "block_grad_s128": (_block_grad := jax.grad(
         lambda q, k, v, m: _sum32(pa.block_attention(q, k, v, m)),
@@ -152,14 +152,45 @@ CASES = {
 
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_kernel_compiles_for_v5e(case, v5e, no_compile_cache, monkeypatch):
-    fn, shapes, kernel = CASES[case]
+    fn, shapes, kernels = CASES[case]
     # the dispatchers ask the default backend, which is the CPU here
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     args = [jax.ShapeDtypeStruct(s, d, sharding=v5e) for s, d in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
     calls = [line for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
-    assert any(kernel in line for line in calls), (kernel, calls)
+    for kernel in ([kernels] if isinstance(kernels, str) else kernels):
+        assert any(kernel in line for line in calls), (kernel, calls)
+
+
+def test_flash_gradient_leaves_no_score_array_and_no_float32_operand(
+        v5e, no_compile_cache, monkeypatch):
+    """The gradient at the GPT cell's shape, compiled for the v5e: the
+    scores live in the backward kernel's VMEM. The XLA backward this
+    replaced held ``f32[32, 2048 - r0, 128]`` score blocks, one set a
+    128-column k block, and the ``p`` / ``ds`` operands of its matmuls in
+    float32; nothing of that shape is left, nothing float32 is as large
+    as q, and beside the two kernels the program only moves q, k, v, o
+    and do and sums do * o."""
+    import re
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    (shape, dtype), = set(_QKV_CELL)
+    B, S, H, D = shape
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)] * 3
+    text = jax.jit(_flash_grad).lower(*args).compile().as_text()
+    # what the program materialises: the results of the entry's
+    # instructions (a fusion's body holds values, not arrays)
+    entry = text.split("ENTRY ", 1)[1]
+    big = B * S * H * D
+    for m in re.finditer(r"\bf32\[([0-9,]+)\]", entry):
+        dims = [int(d) for d in m.group(1).split(",")]
+        elements = 1
+        for d in dims:
+            elements *= d
+        assert elements < big, m.group(0)
+        assert not (len(dims) == 3 and dims[0] == B * H and dims[2] == 128
+                    and dims[1] > 1), m.group(0)
+    assert "convolution" not in text and " dot(" not in text
 
 
 def test_block_attention_stays_on_its_shard_of_a_mesh(topo,
