@@ -33,7 +33,9 @@ Phases (default, one chip):
            against its XLA reference at real widths (the block attention
            kernels at the shapes of both BERT cells, with padded keys and
            a row of nothing else; the flash kernel's gradients through
-           hvd_flash_bwd); then two steps of the flagship transformer at
+           hvd_flash_bwd, and both again with a window and seven query
+           heads a key/value head, attention_path saying what the band
+           leaves of a head's tiles and the group); then two steps of the flagship transformer at
            head_dim 128 with the three kernels asserted in the compiled
            program. xent_path says how the LM loss ran (the
            kernel's rows and chunk and its grid steps, or why XLA).
@@ -111,6 +113,8 @@ class Sizes:
     bert_seq: int
     bert4: tuple          # four-chip BERT (global batch, seq)
     attn: tuple           # flash check q/k/v [B, S, H, D]
+    banded: tuple         # flash check with a band and grouped heads:
+    #                       (B, S, H, k/v heads, D, window)
     block: tuple          # block attention checks, each [B, S, H, D]
     xent: tuple           # fused xent check [rows, vocab]
     blocks: tuple         # codec check [n_blocks, block]
@@ -125,6 +129,10 @@ REAL = Sizes(
     # 512 positions: the shape at which attend picks the block kernels
     bert4=(8, 512),
     attn=(8, 2048, 8, 128),
+    # a window of two 1024-tiles under four, seven query heads a k/v head
+    # as in smallthinker-21b-a3b.s8192 (half its length and heads: the
+    # float32 reference's scores are 0.9 GB)
+    banded=(1, 4096, 14, 2, 128, 2048),
     # the attention core of bert-large.s128 and bert-large.s512
     block=((64, 128, 16, 64), (8, 512, 16, 64)),
     xent=(16384, 32000), blocks=(8192, 256),
@@ -137,7 +145,8 @@ TINY = Sizes(
     bert=dict(vocab_size=512, hidden_size=64, num_layers=2, num_heads=4,
               intermediate_size=128, max_position=64),
     bert_batch=8, bert_seq=16, bert4=(8, 16),
-    attn=(1, 256, 2, 128), block=((2, 128, 2, 64),),
+    attn=(1, 256, 2, 128), banded=(1, 512, 4, 2, 128, 256),
+    block=((2, 128, 2, 64),),
     xent=(256, 1000), blocks=(64, 128),
     gmm=(256, 128, 128, 4),
     gpt=dict(vocab_size=1000, d_model=256, n_heads=2, n_layers=2,
@@ -432,6 +441,45 @@ def _check_flash(smoke: Smoke) -> None:
                      _rel_err(g, r), FLASH_TOL)
 
 
+def _check_banded(smoke: Smoke) -> None:
+    """The flash kernels with a window and grouped heads against the XLA
+    form of the same function at "highest": the band's edge in both index
+    maps and masks, a k/v head read by its group, dk and dv summed over
+    it."""
+    import jax
+    import jax.numpy as jnp
+    from horovod_tpu.ops.pallas_attention import (_banded_attention,
+                                                  flash_attention_tpu)
+
+    B, S, H, Hkv, D, window = smoke.sizes.banded
+    keys = jax.random.split(jax.random.PRNGKey(smoke.seed + 7), 4)
+    q = jax.random.normal(keys[0], (B, S, H, D), jnp.bfloat16)
+    k, v = (jax.random.normal(kk, (B, S, Hkv, D), jnp.bfloat16)
+            for kk in keys[1:3])
+    w = jax.random.normal(keys[3], (B, S, H, D), jnp.float32)
+
+    def kernel(q, k, v):
+        return flash_attention_tpu(q, k, v, True, interpret=smoke.rehearsal,
+                                   window=window)
+
+    def reference(q, k, v):
+        with jax.default_matmul_precision("highest"):
+            return _banded_attention(q, k, v, window)
+
+    got = _run_compiled(smoke, kernel, (q, k, v), "hvd_flash_attention")
+    _kernel_line(smoke, "flash_attention", "banded fwd",
+                 _rel_err(got, jax.jit(reference)(q, k, v)), FLASH_TOL,
+                 shape=(B, S, H, D), dtype="bfloat16",
+                 attention_path=_attention_path((B, S, H, D), kv_heads=Hkv,
+                                                window=window))
+    got = _run_compiled(smoke, jax.grad(_weighted_sum(kernel, w), (0, 1, 2)),
+                        (q, k, v), "hvd_flash_bwd")
+    want = jax.jit(jax.grad(_weighted_sum(reference, w), (0, 1, 2)))(q, k, v)
+    for name, g, r in zip(("dq", "dk", "dv"), got, want):
+        _kernel_line(smoke, "flash_attention", f"banded grad {name}",
+                     _rel_err(g, r), FLASH_TOL)
+
+
 def _check_block(smoke: Smoke) -> None:
     """The block attention kernels (BERT's core: non-causal, a key mask,
     head_dim 64) against the XLA core in float32 at "highest", forward and
@@ -675,24 +723,31 @@ def _check_codec(smoke: Smoke) -> None:
                      CODEC_RTOL)
 
 
-def _attention_path(shape, causal=True, masked=False) -> str:
-    """Which implementation ``attend`` picks for q/k/v of ``shape`` (with
-    a key mask if ``masked``) on the default backend, read from the
-    lowered program; for a kernel, what its rule picks for one call: the
-    flash kernel's tile and grid steps, forward and backward, and whether
-    the backward keeps a head's dq in VMEM or goes over the q rows in
-    ranges; the block kernels' batch rows and heads a grid step and their
-    VMEM estimate."""
+def _attention_path(shape, causal=True, masked=False, kv_heads=None,
+                    window=None) -> str:
+    """Which implementation ``attend`` picks for q of ``shape`` (k/v with
+    ``kv_heads`` heads if given, a key mask if ``masked``, a causal
+    ``window``) on the default backend, read from the lowered program; for
+    a kernel, what its rule picks for one call: the flash kernel's tile
+    and grid steps, forward and backward, and whether the backward keeps a
+    head's dq in VMEM or goes over the q rows in ranges, the tiles a band
+    leaves of a head's causal tiles and the query heads a k/v head serves;
+    the block kernels' batch rows and heads a grid step and their VMEM
+    estimate."""
     import math
     import jax
     import jax.numpy as jnp
     from horovod_tpu.ops import pallas_attention as pa
     B, S, H, D = shape
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((B, S, kv_heads or H, D), jnp.bfloat16)
     mask = jax.ShapeDtypeStruct((B, S), bool) if masked else None
     text = jax.jit(lambda q, k, v, m: pa.attend(
-        q, k, v, causal=causal, key_mask=m)).lower(x, x, x, mask).as_text()
+        q, k, v, causal=causal, key_mask=m, window=window)).lower(
+            x, kv, kv, mask).as_text()
     if "tpu_custom_call" not in text:
+        if window is not None or kv_heads not in (None, H):
+            return "xla _banded_attention"
         return ("xla _key_masked_attention" if masked
                 else "xla _plain_attention")
     if pa.attention_path(S, S, H, D, causal, masked) == "block":
@@ -708,11 +763,20 @@ def _attention_path(shape, causal=True, masked=False) -> str:
     ranges = S // bwd.rows
     form = ("dq resident" if ranges == 1
             else f"dq in {ranges} q ranges of {bwd.rows} rows")
+    band = ""
+    if window is not None:
+        def tiles(bq, bk):
+            every, live, edge = pa.band_tile_counts(S, bq, bk, window)
+            return f"{live} of {every} causal tiles a head, {edge} on the edge"
+        band += (f"; window {window}: forward {tiles(bq, bk)}, backward "
+                 f"{tiles(bwd.block_q, bwd.block_k)}")
+    if kv_heads not in (None, H):
+        band += f"; kv heads {kv_heads}, group {H // kv_heads}"
     return (f"pallas hvd_flash_attention {bq}x{bk}, {steps} steps; "
             f"hvd_flash_bwd {bwd.block_q}x{bwd.block_k}, {form}, "
             f"{math.prod(pa.flash_bwd_grid(B, H, S, S, bwd))} steps, VMEM "
             f"estimate {pa.flash_bwd_vmem_bytes(*bwd, D, 2) / 2 ** 20:.1f} "
-            "MiB")
+            f"MiB{band}")
 
 
 def _check_flagship(smoke: Smoke, hvd) -> None:
@@ -779,6 +843,7 @@ def _check_flagship(smoke: Smoke, hvd) -> None:
 
 def phase_kernels(smoke: Smoke, hvd) -> None:
     _check_flash(smoke)
+    _check_banded(smoke)
     _check_block(smoke)
     _check_xent(smoke)
     _check_gmm(smoke)

@@ -20,12 +20,18 @@ Layout conventions (local = per-device shapes):
   loop            n_loops > 1: a scan over loop steps around the scan over
                   layers, the same weights each step, ln_f after each; every
                   step's state goes to the head and the exit gate (no pp)
+  layer kinds     ``layer_pattern``: one period of (window, rope) kinds; the
+                  scan goes over periods, a period's layers unrolled inside
+  expert share    ``expert_share=(i, of)``: this device holds that share of
+                  every layer's experts with no ep axis live (one chip of an
+                  expert-parallel group, run alone)
 Gradient sync: params are replicated over (dp, sp) → psum over those axes
 after ``jax.grad``; tp/ep/pp-sharded leaves keep local (sharded) grads.
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 from typing import Any, Dict, Optional, Tuple
@@ -76,6 +82,32 @@ class TransformerConfig:
     #                             arXiv:2510.25741): ln_f closes every loop
     #                             step, each step's state feeds the next step
     #                             and the head, an exit gate mixes the losses
+    # -- attention's shape and the kinds of layer (defaults: multi-head
+    # attention at d_model / n_heads, every layer causal with rope) -------
+    head_width: Optional[int] = None    # a head's width where it is not
+    #                             d_model // n_heads (q and o are then
+    #                             ``n_heads * head_width`` wide, not d_model)
+    n_kv_heads: Optional[int] = None    # k/v heads (grouped-query attention:
+    #                             q head h reads k/v head h // (n_heads //
+    #                             n_kv_heads)). None: n_heads
+    layer_pattern: Tuple[Tuple[Optional[int], bool], ...] = ((None, True),)
+    #                             one period of the stack's layer kinds, each
+    #                             (window or None, rope or not): layer l is
+    #                             of kind l % len. A window W keeps of a query
+    #                             at t the keys t - W < j <= t; a layer
+    #                             without rope has no positions at all (NoPE)
+    moe_router_input: str = "tokens"    # what the router's logits are
+    #                             computed from: the normed tokens the experts
+    #                             get ("tokens"), or the block's input, before
+    #                             its first norm and attention ("block_input")
+    moe_activation: str = "silu"    # a gated expert's gate activation:
+    #                             "silu", or "relu" (ReGLU)
+    expert_share: Tuple[int, int] = (0, 1)  # (index, of): this device holds
+    #                             experts [index * E / of, (index + 1) * E /
+    #                             of) of every layer, as the leading dimension
+    #                             of we1 / we3 / we2, with no ep axis live;
+    #                             the router keeps its n_experts columns and
+    #                             the layer's output is those experts' part
     norm_eps: float = 1e-6
     rope_theta: float = 10000.0
     dtype: Any = jnp.bfloat16
@@ -88,7 +120,33 @@ class TransformerConfig:
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+        return self.head_width or self.d_model // self.n_heads
+
+    @property
+    def kv_heads(self) -> int:
+        return self.n_kv_heads or self.n_heads
+
+    @property
+    def held_experts(self) -> int:
+        """Experts whose weights one unsharded copy of a layer holds."""
+        return self.n_experts // self.expert_share[1]
+
+    def __post_init__(self):
+        index, of = self.expert_share
+        if self.n_experts % of or not 0 <= index < of:
+            raise ValueError(f"expert_share={self.expert_share} does not "
+                             f"divide n_experts={self.n_experts}")
+        if self.n_heads % self.kv_heads:
+            raise ValueError(f"n_kv_heads={self.n_kv_heads} does not divide "
+                             f"n_heads={self.n_heads}")
+        if not self.layer_pattern or self.n_layers % len(self.layer_pattern):
+            raise ValueError(
+                f"layer_pattern of {len(self.layer_pattern)} kinds does not "
+                f"divide n_layers={self.n_layers}")
+        if self.moe_router_input not in ("tokens", "block_input"):
+            raise ValueError(f"moe_router_input={self.moe_router_input!r}")
+        if self.moe_activation not in ("silu", "relu"):
+            raise ValueError(f"moe_activation={self.moe_activation!r}")
 
 
 # ---------------------------------------------------------------------------
@@ -102,6 +160,7 @@ def init_params(rng: np.random.RandomState, cfg: TransformerConfig,
     assert L % n_stages == 0, (L, n_stages)
     lps = L // n_stages
     M, H, Dh, F = cfg.d_model, cfg.n_heads, cfg.head_dim, cfg.d_ff
+    Hkv = cfg.kv_heads
 
     def w(*shape, scale=None):
         scale = scale if scale is not None else (1.0 / np.sqrt(shape[-2]))
@@ -110,15 +169,15 @@ def init_params(rng: np.random.RandomState, cfg: TransformerConfig,
     layer: Dict[str, np.ndarray] = {
         "ln1": np.ones((n_stages, lps, M), np.float32),
         "wq": w(n_stages, lps, M, H * Dh),
-        "wk": w(n_stages, lps, M, H * Dh),
-        "wv": w(n_stages, lps, M, H * Dh),
+        "wk": w(n_stages, lps, M, Hkv * Dh),
+        "wv": w(n_stages, lps, M, Hkv * Dh),
         "wo": w(n_stages, lps, H * Dh, M),
         "ln2": np.ones((n_stages, lps, M), np.float32),
     }
     if cfg.qk_norm:
         layer.update({
             "q_norm": np.ones((n_stages, lps, H * Dh), np.float32),
-            "k_norm": np.ones((n_stages, lps, H * Dh), np.float32),
+            "k_norm": np.ones((n_stages, lps, Hkv * Dh), np.float32),
         })
     if cfg.post_norm:
         layer.update({
@@ -127,14 +186,16 @@ def init_params(rng: np.random.RandomState, cfg: TransformerConfig,
         })
     if cfg.n_experts > 0:
         # we1 is the gate of a gated expert, we3 its up projection, we2
-        # the way back down (the Mixtral numbering)
+        # the way back down (the Mixtral numbering); the experts held
+        # here lead, the router scores them all
+        held = cfg.held_experts
         layer.update({
             "router": w(n_stages, lps, M, cfg.n_experts, scale=0.02),
-            "we1": w(n_stages, lps, cfg.n_experts, M, F),
-            "we2": w(n_stages, lps, cfg.n_experts, F, M),
+            "we1": w(n_stages, lps, held, M, F),
+            "we2": w(n_stages, lps, held, F, M),
         })
         if cfg.moe_gated:
-            layer["we3"] = w(n_stages, lps, cfg.n_experts, M, F)
+            layer["we3"] = w(n_stages, lps, held, M, F)
     else:
         # w1 is the gate of a gated FFN and w3 its up projection, as the
         # experts number theirs
@@ -165,6 +226,20 @@ def param_shardings(cfg: TransformerConfig, mesh: Mesh) -> Dict:
     tp = "tp" if mesh.shape.get("tp", 1) > 1 else None
     pp = "pp" if mesh.shape.get("pp", 1) > 1 else None
     ep = "ep" if mesh.shape.get("ep", 1) > 1 else None
+    if tp and cfg.kv_heads % mesh.shape["tp"]:
+        raise ValueError(
+            f"tp={mesh.shape['tp']} does not divide n_kv_heads="
+            f"{cfg.kv_heads}: a tp shard holds whole k/v heads")
+    if ep and cfg.expert_share != (0, 1):
+        raise ValueError(
+            f"expert_share={cfg.expert_share} on a mesh with a live ep "
+            "axis: a device holds its experts by its place on the axis or "
+            "by being told, not both")
+    if pp and (cfg.n_layers // mesh.shape["pp"]) % len(cfg.layer_pattern):
+        raise ValueError(
+            f"layer_pattern of {len(cfg.layer_pattern)} kinds on pp="
+            f"{mesh.shape['pp']}: a stage of "
+            f"{cfg.n_layers // mesh.shape['pp']} layers is not whole periods")
     layers = {
         "ln1": s(pp), "ln2": s(pp),
         "wq": s(pp, None, None, tp), "wk": s(pp, None, None, tp),
@@ -296,9 +371,22 @@ def _head_xent(x, head, targets):
     return head_softmax_xent(x, head, targets)
 
 
-def _attention_block(p, x, positions, cfg: TransformerConfig):
-    """x: [B', S', M] local. Heads sharded over tp; sequence over sp."""
+#: a layer of the default pattern: the whole causal triangle, with rope
+_PLAIN_LAYER = (None, True)
+
+
+def _attention_block(p, x, positions, cfg: TransformerConfig,
+                     kind=_PLAIN_LAYER):
+    """x: [B', S', M] local. Heads sharded over tp; sequence over sp.
+    ``kind``: the layer's (window or None, rope or not)."""
     B, S, M = x.shape
+    window, rope = kind
+    grouped = cfg.kv_heads != cfg.n_heads
+    if _axis_live("sp") and (window is not None or grouped):
+        raise NotImplementedError(
+            "a window (layer_pattern) or grouped heads (n_kv_heads) on a "
+            "live sp axis: ring_attention_spmd passes whole k/v blocks of "
+            "n_heads heads round the ring and masks by the diagonal only")
     with jax.named_scope(scopes.ATTENTION):
         h = _rmsnorm(x, p["ln1"], cfg.norm_eps)
         q = (h @ p["wq"].astype(h.dtype))
@@ -309,20 +397,27 @@ def _attention_block(p, x, positions, cfg: TransformerConfig):
             k = _qk_norm(k, p["k_norm"], cfg.norm_eps)
         Hl = q.shape[-1] // cfg.head_dim
         q = q.reshape(B, S, Hl, cfg.head_dim)
-        k = k.reshape(B, S, Hl, cfg.head_dim)
-        v = v.reshape(B, S, Hl, cfg.head_dim)
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
+        k = k.reshape(B, S, k.shape[-1] // cfg.head_dim, cfg.head_dim)
+        v = v.reshape(B, S, v.shape[-1] // cfg.head_dim, cfg.head_dim)
+        if rope:
+            q = _rope(q, positions, cfg.rope_theta)
+            k = _rope(k, positions, cfg.rope_theta)
         # the core is the call a kernel replaces: its custom_vjp backward
-        # (XLA einsums today) is traced under the same scope
+        # is traced under the same scope
         with jax.named_scope(scopes.ATTENTION_CORE):
             if _axis_live("sp"):
                 o = ring_attention_spmd(q, k, v, "sp", causal=True)
             else:
                 # pallas flash kernel on TPU when tiling permits, XLA
-                # otherwise
+                # otherwise; a stack of several kinds says which kind a
+                # call is of
                 from horovod_tpu.ops.pallas_attention import attend
-                o = attend(q, k, v, causal=True)
+                with (contextlib.nullcontext()
+                      if cfg.layer_pattern == (_PLAIN_LAYER,)
+                      else jax.named_scope(scopes.ATTENTION_CORE_FULL
+                                           if window is None else
+                                           scopes.ATTENTION_CORE_WINDOW)):
+                    o = attend(q, k, v, causal=True, window=window)
         o = o.reshape(B, S, Hl * cfg.head_dim) @ p["wo"].astype(x.dtype)
         o = _psum_if(o, "tp")
         if cfg.post_norm:
@@ -340,17 +435,37 @@ def _dense_ffn(p, x, cfg: TransformerConfig):
     return _psum_if(o, "tp")
 
 
-def _moe_ffn(p, x, cfg: TransformerConfig):
+def _router_logits(p, x):
+    """The router's float32 logits ``[B' * S', E]`` of ``x`` ``[B', S',
+    M]``, for a router that reads something other than the experts'
+    tokens (``moe_router_input``). The residual stream is not normed, so
+    the logits are as large as it is and a top-k weight moves with their
+    absolute error: the product is float32 in fact ("highest": a TPU
+    multiplies float32 operands as bfloat16 otherwise), on a matmul of
+    ``E`` columns."""
+    with jax.named_scope(scopes.MOE), jax.named_scope(scopes.MOE_ROUTER):
+        return jnp.matmul(x.reshape(-1, x.shape[-1]).astype(jnp.float32),
+                          p["router"].astype(jnp.float32),
+                          precision=lax.Precision.HIGHEST)
+
+
+def _moe_ffn(p, x, cfg: TransformerConfig, logits=None):
     """x: [B', S', M] local → tokens [G, M]; experts over ep, inner mats tp.
-    Returns the layer's output and its auxiliary terms (:func:`_no_aux`'s
-    keys and the layer's four metrics)."""
+    ``logits``: the router's, where it does not read ``x``. Returns the
+    layer's output and its auxiliary terms (:func:`_no_aux`'s keys and the
+    layer's metrics)."""
     B, S, M = x.shape
     toks = x.reshape(B * S, M)
+    if not cfg.moe_gated and cfg.moe_activation != "silu":
+        raise NotImplementedError(
+            f"moe_activation={cfg.moe_activation!r} without moe_gated: it "
+            "names a gated expert's gate activation")
+    gate = jax.nn.relu if cfg.moe_activation == "relu" else jax.nn.silu
 
     def expert_fn(ep, rows, group_sizes):
         h = grouped_matmul(rows, ep["we1"], group_sizes)
         if cfg.moe_gated:
-            h = jax.nn.silu(h) * grouped_matmul(rows, ep["we3"], group_sizes)
+            h = gate(h) * grouped_matmul(rows, ep["we3"], group_sizes)
         else:
             h = jax.nn.gelu(h)
         return grouped_matmul(h, ep["we2"], group_sizes)
@@ -361,11 +476,15 @@ def _moe_ffn(p, x, cfg: TransformerConfig):
             {n: p[n] for n in ("we1", "we2", "we3") if n in p},
             axis_name="ep" if _axis_live("ep") else None,
             k=cfg.moe_top_k, renormalize=cfg.moe_renormalize,
-            stat_axes=[a for a in ("dp", "ep", "sp") if _axis_live(a)])
+            stat_axes=[a for a in ("dp", "ep", "sp") if _axis_live(a)],
+            logits=logits, share=cfg.expert_share)
         y = _psum_if(y, "tp")
+    metrics = m._asdict()
+    if cfg.expert_share == (0, 1):
+        del metrics["held_rows"]    # every assignment: nothing to report
     aux = {"aux_loss": (cfg.moe_balance_weight * m.load_balance_loss
                         + cfg.moe_z_weight * m.router_z_loss),
-           **m._asdict()}
+           **metrics}
     return y.reshape(B, S, M), aux
 
 
@@ -377,17 +496,23 @@ def _over_layers(auxs):
     """One layer's auxiliary terms stacked ``[L]`` → the step's: the
     losses averaged, the largest load, the dropped assignments summed
     (the tokens' choices are :func:`router_choices`' to return)."""
-    how = {"max_expert_load": jnp.max, "dropped": jnp.sum}
+    how = {"max_expert_load": jnp.max, "dropped": jnp.sum,
+           "held_rows": jnp.sum}
     return {k: how.get(k, jnp.mean)(v) for k, v in auxs.items()
             if k != "experts"}
 
 
-def _block(p, x, positions, cfg: TransformerConfig):
-    x = _attention_block(p, x, positions, cfg)
+def _block(p, x, positions, cfg: TransformerConfig, kind=_PLAIN_LAYER):
+    logits = None
+    if cfg.n_experts > 0 and cfg.moe_router_input == "block_input":
+        # before attention, from the residual as it comes in: nothing of
+        # this block stands between the choice and its experts' weights
+        logits = _router_logits(p, x)
+    x = _attention_block(p, x, positions, cfg, kind)
     with jax.named_scope(scopes.MLP):
         h = _rmsnorm(x, p["ln2"], cfg.norm_eps)
         if cfg.n_experts > 0:
-            o, aux = _moe_ffn(p, h, cfg)
+            o, aux = _moe_ffn(p, h, cfg, logits)
         else:
             o, aux = _dense_ffn(p, h, cfg), _no_aux()
         o = o.astype(x.dtype)
@@ -410,20 +535,22 @@ def _stage_fn_factory(cfg: TransformerConfig, positions):
     array (pipeline_spmd requirement); it accumulates across stages and is
     read back after the pipeline.
     """
-    def one_block(x, lp):
-        def fn(xx):
-            return _block(lp, xx, positions, cfg)
-        if _remat(cfg, True):
-            fn = jax.checkpoint(fn)
-        return fn(x)
+    def block_of(kind):
+        def one_block(lp, x):
+            def fn(xx):
+                return _block(lp, xx, positions, cfg, kind)
+            if _remat(cfg, True):
+                fn = jax.checkpoint(fn)
+            return fn(x)
+        return one_block
 
     def stage_fn(stage_params, act_with_aux):
         act = act_with_aux[..., :-1]
         aux_in = act_with_aux[..., -1:]
-        def scan_body(x, lp):
-            y, aux = one_block(x, lp)
-            return y, aux
-        y, auxs = lax.scan(scan_body, act.astype(cfg.dtype), stage_params)
+        # a stage is whole periods (param_shardings), so its first layer
+        # is of the pattern's first kind
+        y, auxs = _scan_periods(block_of, act.astype(cfg.dtype),
+                                stage_params, cfg.layer_pattern)
         aux_out = aux_in + jnp.sum(auxs["aux_loss"]) / max(cfg.n_layers, 1)
         return jnp.concatenate([y.astype(jnp.float32), aux_out], axis=-1)
 
@@ -579,17 +706,43 @@ def _scan_layers(lp, x, positions, cfg: TransformerConfig):
     A looped stack checkpoints each block (its passes' activations would
     not fit beside the weights), the single pass does not, unless
     ``cfg.remat`` says otherwise."""
-    def block(layer_p, x):
-        return _block(layer_p, x, positions, cfg)
-    if _remat(cfg, cfg.n_loops > 1):
-        block = jax.checkpoint(block)
-
-    def scan_body(carry, layer_p):
-        y, aux = block(layer_p, carry)
-        return y, aux
+    def block_of(kind):
+        def block(layer_p, x):
+            return _block(layer_p, x, positions, cfg, kind)
+        if _remat(cfg, cfg.n_loops > 1):
+            block = jax.checkpoint(block)
+        return block
     flat = jax.tree_util.tree_map(
         lambda a: a.reshape((-1,) + a.shape[2:]), lp)
-    return lax.scan(scan_body, x, flat)
+    return _scan_periods(block_of, x, flat, cfg.layer_pattern)
+
+
+def _scan_periods(block_of, x, layers, pattern):
+    """``x`` through ``block_of(kind)(layer_p, x)`` for every layer of
+    ``layers`` (leaves ``[L, ...]``), layer ``l`` of kind ``pattern[l %
+    len(pattern)]``: a scan over periods with a period's layers unrolled
+    inside, each with its static kind; a period of one is a scan over
+    layers. Returns (activations, every layer's auxiliary terms ``[L]``)."""
+    blocks = {kind: block_of(kind) for kind in pattern}
+    if len(pattern) == 1:
+        def scan_body(carry, layer_p):
+            y, aux = blocks[pattern[0]](layer_p, carry)
+            return y, aux
+        return lax.scan(scan_body, x, layers)
+    n = len(pattern)
+
+    def period_body(carry, period_p):
+        auxs = []
+        for i, kind in enumerate(pattern):
+            carry, aux = blocks[kind](
+                jax.tree_util.tree_map(lambda a: a[i], period_p), carry)
+            auxs.append(aux)
+        return carry, jax.tree_util.tree_map(lambda *a: jnp.stack(a), *auxs)
+    periods = jax.tree_util.tree_map(
+        lambda a: a.reshape((a.shape[0] // n, n) + a.shape[1:]), layers)
+    y, auxs = lax.scan(period_body, x, periods)
+    return y, jax.tree_util.tree_map(
+        lambda a: a.reshape((-1,) + a.shape[2:]), auxs)
 
 
 def router_choices(params, tokens, cfg: TransformerConfig):
@@ -754,9 +907,26 @@ def shard_batch(tokens, targets, mesh: Mesh):
 #       page holds its token positions [j*page_tokens, (j+1)*page_tokens);
 #       gathered back, position p of a slot lands at flat index p.
 
+def _dense_decode_only(cfg: TransformerConfig) -> None:
+    """The decode paths below compute multi-head attention over the whole
+    causal history with rope on every layer: refuse, by name, what they
+    would silently compute otherwise."""
+    off = [name for name, plain in (
+        ("layer_pattern", cfg.layer_pattern == (_PLAIN_LAYER,)),
+        ("n_kv_heads", cfg.kv_heads == cfg.n_heads),
+        ("moe_router_input", cfg.moe_router_input == "tokens"),
+        ("expert_share", cfg.expert_share == (0, 1))) if not plain]
+    if off:
+        raise NotImplementedError(
+            f"paged decode does not implement {', '.join(off)}: its cache "
+            "holds n_heads k/v heads of every position, and its layers "
+            "attend to all of them with rope")
+
+
 def kv_cache_spec(cfg: TransformerConfig) -> Tuple[int, int, Any]:
     """(n_layers, per-token K width, cache dtype) — the model
     fingerprint the page planner sizes pages from."""
+    _dense_decode_only(cfg)
     return cfg.n_layers, cfg.n_heads * cfg.head_dim, jnp.float32
 
 
@@ -844,6 +1014,7 @@ def decode_step_paged(params: Dict, k_pages, v_pages, page_table,
     slots compute masked garbage into the scratch page — their lanes
     exist only to keep the shape constant.  Returns
     ``(next_token [S] int32, k_pages, v_pages)``."""
+    _dense_decode_only(cfg)
     S = last_token.shape[0]
     pt = k_pages.shape[2]
     scratch = k_pages.shape[1] - 1
@@ -877,6 +1048,7 @@ def prefill_chunk_paged(params: Dict, k_pages, v_pages, page_row,
     returns the greedy next token after the last VALID position — the
     first generated token once the final chunk lands.  Returns
     ``(next_token scalar int32, k_pages, v_pages)``."""
+    _dense_decode_only(cfg)
     C = tokens.shape[0]
     pt = k_pages.shape[2]
     scratch = k_pages.shape[1] - 1
@@ -913,6 +1085,7 @@ def reference_greedy_decode(params: Dict, cfg: TransformerConfig,
     for every emitted token (no cache, no paging, no batching).  Slow
     on purpose — this is the ground truth the paged continuous engine
     must match token-for-token (tests/test_generate.py)."""
+    _dense_decode_only(cfg)
     flat = flatten_decode_params(params)
     H, Dh, L = cfg.n_heads, cfg.head_dim, cfg.n_layers
     toks = [int(t) for t in np.asarray(prompt).reshape(-1)]

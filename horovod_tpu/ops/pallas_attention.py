@@ -32,6 +32,18 @@ nothing: the K/V index maps clamp to the q tile's last live tile, and
 Pallas issues no DMA for a block index that repeats. Only the tiles the
 diagonal crosses build the iota mask; those below it skip it.
 
+**Window.** ``window=W`` (causal only) keeps of a query at ``t`` the keys
+``t - W < j <= t``: a band under the diagonal. A tile wholly below the
+band is skipped as one above the diagonal is (the index maps clamp to the
+first live tile too), and a tile the band's lower edge crosses is masked as
+a diagonal tile is. At 8192 x 8192 with 1024 x 1024 tiles and a window of
+4096 a head runs 30 of its 36 causal tiles, four of them edge tiles.
+
+**Grouped heads.** k and v may have fewer heads than q (``H % Hkv == 0``):
+q head ``h`` reads k/v head ``h // (H // Hkv)`` through the block index,
+and the backward writes a q head's part of dk and dv, summed over the
+group outside the kernel.
+
 The kernel is DIFFERENTIABLE: a ``jax.custom_vjp`` pairs the forward
 kernel (which also emits the per-row log-sum-exp residual) with a second
 kernel, ``hvd_flash_bwd``, that recomputes the attention probabilities
@@ -102,6 +114,19 @@ def _dot(a, b, dims):
 
 
 
+def _group(H: int, Hkv: int) -> int:
+    """q heads a k/v head serves."""
+    if H % Hkv:
+        raise ValueError(f"{Hkv} k/v heads do not divide {H} q heads")
+    return H // Hkv
+
+
+def _check_window(window, causal) -> None:
+    if window is not None and (not causal or window < 1):
+        raise ValueError(f"window={window}: a window is a band under the "
+                         "diagonal, at least one key wide (causal only)")
+
+
 def flash_vmem_bytes(block_q: int, block_k: int, D: int, itemsize: int) -> int:
     """Working set of one grid step: q, k, v and o tiles double-buffered
     by the pipeline, the float32 ``s`` and ``p`` tiles and ``p`` cast for
@@ -142,9 +167,56 @@ def flash_grid(B: int, H: int, Sq: int, Sk: int, block_q: int,
     return (B * H, Sq // block_q, Sk // block_k)
 
 
+def _band_tiles(first_row, first_col, block_q: int, block_k: int,
+                window: int):
+    """(crossed, whole) of the tile at ``(first_row, first_col)`` under a
+    causal window: whether the diagonal or the band's lower edge crosses
+    it (some of it is masked, not all), and whether all of it is live.
+    Neither: the tile is wholly above the diagonal or below the band."""
+    last_row, last_col = first_row + block_q - 1, first_col + block_k - 1
+    diagonal = jnp.logical_and(first_col <= last_row, last_col > first_row)
+    edge = jnp.logical_and(first_col <= last_row - window,
+                           last_col > first_row - window)
+    crossed = jnp.logical_or(diagonal, edge)
+    live = jnp.logical_and(first_col <= last_row,
+                           last_col > first_row - window)
+    return crossed, jnp.logical_and(live, jnp.logical_not(crossed))
+
+
+def band_tile_counts(S: int, block_q: int, block_k: int,
+                     window: Optional[int]) -> Tuple[int, int, int]:
+    """Of a head's ``S x S`` scores in ``block_q x block_k`` tiles: (the
+    tiles the causal triangle touches, those of them a window of
+    ``window`` keeps, those of them the band's lower edge crosses). What a
+    call runs, for ``chip_smoke.py``'s ``attention_path`` and the tests."""
+    causal = live = edge = 0
+    for r0 in range(0, S, block_q):
+        for c0 in range(0, S, block_k):
+            r1, c1 = r0 + block_q - 1, c0 + block_k - 1
+            if c0 > r1:
+                continue
+            causal += 1
+            if window is None or c1 > r0 - window:
+                live += 1
+                edge += window is not None and c0 <= r1 - window
+    return causal, live, edge
+
+
+def _first_band_k_tile(qi, block_q: int, block_k: int, window: int):
+    """Window: the first k tile with a column inside q tile ``qi``'s
+    band; the k tiles before it are wholly below the band."""
+    return jnp.maximum(qi * block_q - window + 1, 0) // block_k
+
+
+def _last_band_q_tile(kj, block_q: int, block_k: int, window: int):
+    """Window: the last q tile with a row whose band reaches k tile
+    ``kj``'s last column (not yet held to the sequence's end)."""
+    return (kj * block_k + block_k - 1 + window - 1) // block_q
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
                   l_ref, *, scale: float, causal: bool, block_q: int,
-                  block_k: int):
+                  block_k: int, window: Optional[int] = None):
     """One (q-tile, k-tile) step; grid (BH, nq, nk) with k innermost."""
     kv_idx = pl.program_id(2)
     q_idx = pl.program_id(1)
@@ -166,7 +238,10 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
                 jnp.int32, s.shape, 0)
             kpos = kv_idx * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, 1)
-            s = jnp.where(qpos >= kpos, s, NEG_INF)
+            live = qpos >= kpos
+            if window is not None:
+                live = jnp.logical_and(live, kpos > qpos - window)
+            s = jnp.where(live, s, NEG_INF)
         m_prev = m_ref[:]
         l_prev = l_ref[:]
         m_cur = jnp.max(s, axis=-1)[:, None]       # [bq, 1]
@@ -187,10 +262,19 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
         # the diagonal crosses the tile: some of it is masked, not all
         crossed = jnp.logical_and(first_col <= first_row + block_q - 1,
                                   last_col > first_row)
-        pl.when(crossed)(functools.partial(body, True))
-        # wholly at or below the diagonal: no mask to build. Tiles
-        # strictly above it run nothing (and fetch nothing: kv_index)
-        pl.when(last_col <= first_row)(functools.partial(body, False))
+        if window is None:
+            pl.when(crossed)(functools.partial(body, True))
+            # wholly at or below the diagonal: no mask to build. Tiles
+            # strictly above it run nothing (and fetch nothing: kv_index)
+            pl.when(last_col <= first_row)(functools.partial(body, False))
+        else:
+            crossed, whole = _band_tiles(first_row, first_col, block_q,
+                                         block_k, window)
+            pl.when(crossed)(functools.partial(body, True))
+            # a row the band's edge masks for all of its first tile holds
+            # garbage under m = NEG_INF until its next tile's alpha = 0
+            # wipes it: every row's diagonal tile comes later
+            pl.when(whole)(functools.partial(body, False))
     else:
         body(False)
 
@@ -205,28 +289,47 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
         lse_ref[0, 0] = (m_ref[:] + jnp.log(l_safe))[:, 0]
 
 
-def _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret):
-    """Run the kernel; q/k/v [B, S, H, D] → (o [B, S, H, D], lse [BH, Sq])."""
+def _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret,
+                    window=None):
+    """Run the kernel; q [B, S, H, D], k/v [B, S, Hkv, D] → (o [B, S, H,
+    D], lse [BH, Sq])."""
     B, Sq, H, D = q.shape
-    Sk = k.shape[1]
+    Sk, Hkv = k.shape[1], k.shape[2]
+    group = _group(H, Hkv)
+    _check_window(window, causal)
     # layout: fold batch & heads; tiles over sequence
     qf = q.transpose(0, 2, 1, 3).reshape(B * H, Sq, D)
-    kf = k.transpose(0, 2, 1, 3).reshape(B * H, Sk, D)
-    vf = v.transpose(0, 2, 1, 3).reshape(B * H, Sk, D)
+    kf = k.transpose(0, 2, 1, 3).reshape(B * Hkv, Sk, D)
+    vf = v.transpose(0, 2, 1, 3).reshape(B * Hkv, Sk, D)
 
-    if causal:
+    if group == 1:
+        def kv_head(b):
+            return b
+    else:
+        def kv_head(b):      # q head h of a batch row reads kv head h // group
+            return (b // H) * Hkv + (b % H) // group
+
+    if window is not None:
+        # below the band as above the diagonal: the index stays on a live
+        # tile, from both sides
+        def kv_index(b, i, j):
+            return (kv_head(b), jnp.clip(
+                j, _first_band_k_tile(i, block_q, block_k, window),
+                _last_live_k_tile(i, block_q, block_k)), 0)
+    elif causal:
         # above the diagonal the block index repeats the q tile's last
         # live tile, so the pipeline issues no DMA for a skipped step
         def kv_index(b, i, j):
-            return (b, jnp.minimum(j, (i * block_q + block_q - 1) // block_k),
+            return (kv_head(b),
+                    jnp.minimum(j, (i * block_q + block_q - 1) // block_k),
                     0)
     else:
         def kv_index(b, i, j):
-            return (b, j, 0)
+            return (kv_head(b), j, 0)
 
     out, lse = pl.pallas_call(
         functools.partial(_flash_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k),
+                          block_q=block_q, block_k=block_k, window=window),
         grid=flash_grid(B, H, Sq, Sk, block_q, block_k),
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
@@ -255,15 +358,15 @@ def _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret):
             lse.reshape(B * H, Sq))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_lse(q, k, v, causal, scale, block_q, block_k, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_lse(q, k, v, causal, scale, block_q, block_k, interpret, window):
     return _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k,
-                           interpret)
+                           interpret, window)
 
 
-def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
+def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret, window):
     o, lse = _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k,
-                             interpret)
+                             interpret, window)
     return (o, lse), (q, k, v, o, lse)
 
 
@@ -363,7 +466,7 @@ def _last_live_k_tile(qi, block_q: int, block_k: int):
 def _flash_bwd_kernel(q_ref, do_ref, k_ref, v_ref, lse_ref, adj_ref,
                       dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
                       scale: float, causal: bool, block_q: int,
-                      block_k: int):
+                      block_k: int, window: Optional[int] = None):
     """One (k tile, q tile) step; grid (BH, q ranges, nk, nq) with q
     innermost: dk and dv of the k tile accumulate over the q tiles, dq of
     the range's rows over the k tiles."""
@@ -376,19 +479,22 @@ def _flash_bwd_kernel(q_ref, do_ref, k_ref, v_ref, lse_ref, adj_ref,
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    def part(k0: int, n_k: int, q0: int, masked: bool):
-        """The tile's k rows ``[k0, k0 + n_k)`` against its q rows from
-        ``q0`` on (static): dk and dv of those k rows, dq of those q
-        rows."""
-        ks, qs = pl.ds(k0, n_k), pl.ds(q0, block_q - q0)
+    def part(k0: int, n_k: int, q0: int, masked: bool,
+             q1: int = block_q):
+        """The tile's k rows ``[k0, k0 + n_k)`` against its q rows ``[q0,
+        q1)`` (static): dk and dv of those k rows, dq of those q rows."""
+        ks, qs = pl.ds(k0, n_k), pl.ds(q0, q1 - q0)
         q, do, k, v = q_ref[0, qs], do_ref[0, qs], k_ref[0, ks], v_ref[0, ks]
-        st = _dot(k, q, _NT) * scale                    # [n_k, bq - q0]
+        st = _dot(k, q, _NT) * scale                    # [n_k, q1 - q0]
         if masked:
             kpos = kj * block_k + k0 + lax.broadcasted_iota(
                 jnp.int32, st.shape, 0)
             qpos = qi * block_q + q0 + lax.broadcasted_iota(
                 jnp.int32, st.shape, 1)
-            st = jnp.where(qpos >= kpos, st, NEG_INF)
+            live = qpos >= kpos
+            if window is not None:
+                live = jnp.logical_and(live, kpos > qpos - window)
+            st = jnp.where(live, st, NEG_INF)
         pt = jnp.exp(st - lse_ref[0, :, qs])            # lse: [1, bq - q0]
         dv_acc[ks] += _dot(pt.astype(do.dtype), do, _NN)
         # d loss / d s = p * (dp - adj); s = scale * q kT, and the scale
@@ -397,8 +503,10 @@ def _flash_bwd_kernel(q_ref, do_ref, k_ref, v_ref, lse_ref, adj_ref,
         dk_acc[ks] += _dot(dst, q, _NN)
         dq = _dot(dst, k, _TN)                          # [bq - q0, D]
         rows = pl.ds(pl.multiple_of(i * block_q + q0, MIN_BLOCK),
-                     block_q - q0)
-        if k0 == 0:     # the rows' first k rows, if this is the first tile
+                     q1 - q0)
+        if window is not None:  # zeroed at the rows' first live tile
+            dq_acc[rows] += dq
+        elif k0 == 0:   # the rows' first k rows, if this is the first tile
             @pl.when(kj == 0)       # every q tile meets the first k tile
             def _first():
                 dq_acc[rows] = dq
@@ -421,7 +529,36 @@ def _flash_bwd_kernel(q_ref, do_ref, k_ref, v_ref, lse_ref, adj_ref,
         for k0 in range(0, block_k, piece):
             part(k0, piece, k0, True)
 
-    if causal:
+    def edge():
+        """A square tile whose corners lie on the band's lower edge (the
+        window a multiple of the tile): k row ``c`` is live for the q rows
+        ``r < c``, so a piece leaves out the q rows from its last k row
+        on."""
+        piece = min(DIAG_ROWS, block_k)
+        for k0 in range(0, block_k, piece):
+            part(k0, piece, 0, True, k0 + piece)
+
+    if window is not None:
+        first_row, first_col = qi * block_q, kj * block_k
+        rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
+
+        @pl.when(kj == _first_band_k_tile(qi, block_q, block_k, window))
+        def _zero_dq():
+            dq_acc[rows] = jnp.zeros((block_q, dq_acc.shape[1]),
+                                     jnp.float32)
+        crossed, clean = _band_tiles(first_row, first_col, block_q, block_k,
+                                     window)
+        if block_q == block_k and window % block_k == 0:
+            # the diagonal and the edge cross different tiles, each corner
+            # to corner
+            pl.when(qi == kj)(diagonal)
+            pl.when(first_col == first_row - window)(edge)
+        else:
+            pl.when(crossed)(functools.partial(whole, True))
+        pl.when(clean)(functools.partial(whole, False))
+        last_kj = jnp.minimum(_last_live_k_tile(qi, block_q, block_k),
+                              nk - 1)
+    elif causal:
         first_row, first_col = qi * block_q, kj * block_k
         last_row, last_col = first_row + block_q - 1, first_col + block_k - 1
         # the diagonal crosses the tile: some of it is masked, not all.
@@ -454,21 +591,33 @@ def _flash_bwd_kernel(q_ref, do_ref, k_ref, v_ref, lse_ref, adj_ref,
 # jitted so that the call sites of a program (a ring step's switch
 # branches, layers outside a scan) share one trace and one Mosaic lowering
 @functools.partial(jax.jit, static_argnames=("H", "causal", "scale",
-                                             "blocks", "interpret"))
+                                             "blocks", "interpret",
+                                             "window"))
 def _flash_bwd_local(q, k, v, do, lse, adj, *, H, causal, scale, blocks,
-                     interpret):
+                     interpret, window=None):
     """(dq ``[B, Sq, H*D]``, dk and dv ``[ranges, B, Sk, H*D]``) of q, do
-    ``[B, Sq, H*D]`` and k, v ``[B, Sk, H*D]`` as the projections wrote
+    ``[B, Sq, H*D]`` and k, v ``[B, Sk, Hkv*D]`` as the projections wrote
     them: a head is whole 128-lane columns (one at head_dim 128), so a
     ``(1, tile, D)`` block addresses it with no transpose around the call.
-    lse and adj are ``[B*H, 1, Sq]`` float32, a q row along the lanes."""
+    lse and adj are ``[B*H, 1, Sq]`` float32, a q row along the lanes. dk
+    and dv come a q head: with grouped heads a k/v head's are the sum over
+    its group, the caller's to take."""
     B, Sq, M = q.shape
     Sk, D = k.shape[1], M // H
+    group = _group(H, k.shape[2] // D)
     bq, bk, rows = blocks
     grid = flash_bwd_grid(B, H, Sq, Sk, blocks)
     nq = grid[3]
 
-    if causal:
+    if window is not None:
+        # beyond the band as above the diagonal: the index stays on a
+        # live q tile, from both sides
+        def q_tile(r, j, i):
+            first = _first_live_q_tile(j, bq, bk)
+            last = jnp.maximum(_last_band_q_tile(j, bq, bk, window), first)
+            return jnp.clip(jnp.clip(r * nq + i, first, last), r * nq,
+                            r * nq + nq - 1)
+    elif causal:
         # above the diagonal the block index repeats the k tile's first
         # live q tile, so the pipeline issues no DMA for a skipped step
         def q_tile(r, j, i):
@@ -479,15 +628,20 @@ def _flash_bwd_local(q, k, v, do, lse, adj, *, H, causal, scale, blocks,
             return r * nq + i
     q_spec = pl.BlockSpec((1, bq, D),
                           lambda b, r, j, i: (b // H, q_tile(r, j, i), b % H))
-    k_spec = pl.BlockSpec((1, bk, D), lambda b, r, j, i: (b // H, j, b % H))
+    if group == 1:
+        k_spec = pl.BlockSpec((1, bk, D),
+                              lambda b, r, j, i: (b // H, j, b % H))
+    else:
+        k_spec = pl.BlockSpec(
+            (1, bk, D), lambda b, r, j, i: (b // H, j, b % H // group))
     row_spec = pl.BlockSpec((1, 1, bq),
                             lambda b, r, j, i: (b, 0, q_tile(r, j, i)))
     part_spec = pl.BlockSpec((1, 1, bk, D),
                              lambda b, r, j, i: (r, b // H, j, b % H))
-    part = jax.ShapeDtypeStruct((grid[1],) + k.shape, k.dtype)
+    part = jax.ShapeDtypeStruct((grid[1], B, Sk, M), k.dtype)
     return pl.pallas_call(
         functools.partial(_flash_bwd_kernel, scale=scale, causal=causal,
-                          block_q=bq, block_k=bk),
+                          block_q=bq, block_k=bk, window=window),
         grid=grid,
         in_specs=[q_spec, q_spec, k_spec, k_spec, row_spec, row_spec],
         out_specs=[
@@ -510,7 +664,7 @@ def _flash_bwd_local(q, k, v, do, lse, adj, *, H, causal, scale, blocks,
 
 def flash_backward(q, k, v, o, lse, do, dlse, causal: bool, scale: float,
                    blocks: Optional[BwdBlocks] = None,
-                   interpret: bool = False):
+                   interpret: bool = False, window: Optional[int] = None):
     """(dq, dk, dv) of flash attention from its residuals (q, k, v, o
     ``[B, S, H, D]``, lse ``[B*H, Sq]``) and the cotangents of ``o`` and
     ``lse``: p = exp(s - lse) is recomputed tile by tile in VMEM (Dao et
@@ -521,45 +675,54 @@ def flash_backward(q, k, v, o, lse, do, dlse, causal: bool, scale: float,
     usable as a mergeable partial result (ring attention). Operands
     multiply in the dtype they come in and accumulate in float32; p and ds
     are float32 in VMEM and cast for their matmuls. ``blocks`` overrides
-    :func:`flash_bwd_blocks` (tests, sweeps)."""
+    :func:`flash_bwd_blocks` (tests, sweeps). k and v may have fewer heads
+    than q (grouped heads): the kernel writes each q head's part of dk and
+    dv and the parts of a group are summed here, in float32. ``window``:
+    the causal band of the forward."""
     B, Sq, H, D = q.shape
-    Sk = k.shape[1]
+    Sk, Hkv = k.shape[1], k.shape[2]
+    _check_window(window, causal)
     blocks = blocks or flash_bwd_blocks(Sq, Sk, D, q.dtype)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     adj = delta.transpose(0, 2, 1).reshape(B * H, Sq) \
         - dlse.astype(jnp.float32)
     dq, dk, dv = _flash_bwd_local(
-        q.reshape(B, Sq, H * D), k.reshape(B, Sk, H * D),
-        v.reshape(B, Sk, H * D), do.reshape(B, Sq, H * D),
+        q.reshape(B, Sq, H * D), k.reshape(B, Sk, Hkv * D),
+        v.reshape(B, Sk, Hkv * D), do.reshape(B, Sq, H * D),
         lse.reshape(B * H, 1, Sq), adj.reshape(B * H, 1, Sq), H=H,
-        causal=causal, scale=scale, blocks=blocks, interpret=interpret)
+        causal=causal, scale=scale, blocks=blocks, interpret=interpret,
+        window=window)
 
     def total(parts):
+        if Hkv != H:    # a k/v head's gradient: its group's, every range's
+            parts = parts.reshape(-1, B, Sk, Hkv, H // Hkv, D)
+            return parts.astype(jnp.float32).sum((0, 4)).astype(parts.dtype)
         if parts.shape[0] > 1:
             parts = parts.astype(jnp.float32).sum(0).astype(parts.dtype)
         return parts.reshape(B, Sk, H, D)
     return dq.reshape(B, Sq, H, D), total(dk), total(dv)
 
 
-def _flash_bwd(causal, scale, block_q, block_k, interpret, res, cts):
+def _flash_bwd(causal, scale, block_q, block_k, interpret, window, res, cts):
     """``block_q`` / ``block_k`` are the forward's tile and set nothing
     here: the backward's come from :func:`flash_bwd_blocks`."""
     q, k, v, o, lse = res
     do, dlse = cts
     return flash_backward(q, k, v, o, lse, do, dlse, causal, scale,
-                          interpret=interpret)
+                          interpret=interpret, window=window)
 
 
 _flash_lse.defvjp(_flash_fwd, _flash_bwd)
 
 
-def _call(q, k, v, causal, scale, block_q, block_k, interpret):
+def _call(q, k, v, causal, scale, block_q, block_k, interpret, window=None):
     D = q.shape[-1]
     scale = float(scale) if scale is not None else float(1.0 / (D ** 0.5))
     if block_q is None or block_k is None:
         bq, bk = flash_blocks(q.shape[1], k.shape[1], D, q.dtype)
         block_q, block_k = block_q or bq, block_k or bk
-    return _flash_lse(q, k, v, causal, scale, block_q, block_k, interpret)
+    return _flash_lse(q, k, v, causal, scale, block_q, block_k, interpret,
+                      window)
 
 
 def flash_attention_with_lse(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -567,14 +730,16 @@ def flash_attention_with_lse(q: jax.Array, k: jax.Array, v: jax.Array,
                              scale: Optional[float] = None,
                              block_q: Optional[int] = None,
                              block_k: Optional[int] = None,
-                             interpret: bool = False):
+                             interpret: bool = False,
+                             window: Optional[int] = None):
     """Flash attention returning ``(o, lse)``: the normalized output plus
     the per-row log-partition (``lse`` shaped ``[B*H, Sq]``). The pair is
     a mergeable partial softmax — two results over disjoint key sets
     combine exactly via logaddexp (ring attention's per-step merge).
     Differentiable in both outputs. ``block_q`` / ``block_k`` override the
-    forward tile of :func:`flash_blocks` (tests)."""
-    return _call(q, k, v, causal, scale, block_q, block_k, interpret)
+    forward tile of :func:`flash_blocks` (tests). ``window`` and grouped
+    heads as :func:`flash_attention_tpu` takes them."""
+    return _call(q, k, v, causal, scale, block_q, block_k, interpret, window)
 
 
 def flash_attention_tpu(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -582,11 +747,16 @@ def flash_attention_tpu(q: jax.Array, k: jax.Array, v: jax.Array,
                         scale: Optional[float] = None,
                         block_q: Optional[int] = None,
                         block_k: Optional[int] = None,
-                        interpret: bool = False) -> jax.Array:
-    """q/k/v: [B, S, H, D] → [B, S, H, D]. Requires S % 128 == 0 and
-    D % 128 == 0 (use :func:`attend` for the auto-fallback wrapper).
-    Differentiable (custom VJP with blockwise recompute backward)."""
-    return _call(q, k, v, causal, scale, block_q, block_k, interpret)[0]
+                        interpret: bool = False,
+                        window: Optional[int] = None) -> jax.Array:
+    """q [B, S, H, D], k/v [B, S, Hkv, D] (``H % Hkv == 0``; q head ``h``
+    attends to k/v head ``h // (H // Hkv)``) → [B, S, H, D]. Requires
+    S % 128 == 0 and D % 128 == 0 (use :func:`attend` for the auto-fallback
+    wrapper). ``window=W`` (causal only): a query at ``t`` sees the keys
+    ``t - W < j <= t``. Differentiable (custom VJP with blockwise recompute
+    backward)."""
+    return _call(q, k, v, causal, scale, block_q, block_k, interpret,
+                 window)[0]
 
 
 # ---------------------------------------------------------------------------
@@ -980,20 +1150,51 @@ def attention_path(Sq: int, Sk: int, H: int, D: int, causal: bool,
     return "xla"
 
 
+def _banded_attention(q, k, v, window, scale=None):
+    """The XLA form of causal attention with a window and / or grouped
+    heads, what the flash kernels are held against: q ``[B, S, H, D]``
+    against k/v ``[B, S, Hkv, D]``, float32 scores and softmax, a query at
+    ``t`` on the keys ``t - window < j <= t`` (all ``j <= t`` without a
+    window)."""
+    B, Sq, H, D = q.shape
+    Sk, Hkv = k.shape[1], k.shape[2]
+    scale = scale if scale is not None else (1.0 / (D ** 0.5))
+    qg = q.astype(jnp.float32).reshape(B, Sq, Hkv, _group(H, Hkv), D)
+    s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k.astype(jnp.float32)) * scale
+    t, j = jnp.arange(Sq)[:, None], jnp.arange(Sk)[None, :]
+    live = j <= t
+    if window is not None:
+        live = jnp.logical_and(live, j > t - window)
+    p = jax.nn.softmax(jnp.where(live, s, NEG_INF), axis=-1)
+    out = jnp.einsum("bhgqk,bkhd->bqhgd", p, v.astype(jnp.float32))
+    return out.reshape(B, Sq, H, D).astype(q.dtype)
+
+
 def attend(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = True,
            scale: Optional[float] = None,
-           key_mask: Optional[jax.Array] = None) -> jax.Array:
+           key_mask: Optional[jax.Array] = None,
+           window: Optional[int] = None) -> jax.Array:
     """Attention with automatic kernel selection (:func:`attention_path`)
     on a TPU, the fused-XLA fallback elsewhere. ``key_mask`` is a
-    ``[B, Sk]`` bool array of live keys (non-causal only). Differentiable
-    on every path."""
+    ``[B, Sk]`` bool array of live keys (non-causal only). ``window=W``
+    (causal only): a query at ``t`` sees the keys ``t - W < j <= t``. k and
+    v may have fewer heads than q (grouped heads, causal only): q head
+    ``h`` attends to k/v head ``h // (H // Hkv)``. Differentiable on every
+    path."""
     if causal and key_mask is not None:
         raise ValueError("a key mask with causal attention is not "
+                         "implemented")
+    _check_window(window, causal)
+    grouped = k.shape[2] != q.shape[2]
+    if grouped and not causal:
+        raise ValueError("grouped heads without causal attention are not "
                          "implemented")
     path = attention_path(q.shape[1], k.shape[1], q.shape[2], q.shape[3],
                           causal, key_mask is not None)
     if path == "flash":
-        return flash_attention_tpu(q, k, v, causal, scale)
+        return flash_attention_tpu(q, k, v, causal, scale, window=window)
+    if window is not None or grouped:
+        return _banded_attention(q, k, v, window, scale)
     if path == "block":
         return block_attention(q, k, v, key_mask, scale)
     if key_mask is not None:

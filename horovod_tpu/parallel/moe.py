@@ -25,6 +25,13 @@ tokens and routing (``all_gather``), computes the rows of its own experts
 (sorted to the front; :func:`grouped_matmul` gives zeros for the rows behind
 them, which are the other shards' to compute) and the weighted partial sums
 return to their home shards by ``psum_scatter``.
+
+A device can also be told which experts it holds with no ``ep`` axis live
+(``share=(i, of)``: experts ``[i * E / of, (i + 1) * E / of)``, one chip of
+an expert-parallel group run alone): it routes over all ``E``, sorts its own
+experts' assignments to the front as an ``ep`` shard does, and its weighted
+sum is its part of the layer's output. That is the ``ep`` path without its
+``all_gather`` / ``psum_scatter``; nothing stands in for the absent chips.
 """
 
 from __future__ import annotations
@@ -49,8 +56,13 @@ class MoEMetrics(NamedTuple):
     router_z_loss: jax.Array
     #: largest group over the mean group
     max_expert_load: jax.Array
-    #: assignments no grouped matmul row was computed for: always 0
+    #: assignments to experts held here (every assignment, unless the
+    #: device holds a ``share`` of the experts) that no grouped matmul row
+    #: was computed for: always 0
     dropped: jax.Array
+    #: assignments of the global batch to the experts held here: ``G * k``
+    #: of it unless the device holds a ``share`` of the experts
+    held_rows: jax.Array
     #: the k experts each of the shard's tokens chose, ``[G, k]`` (the others
     #: are scalars of the global batch)
     experts: jax.Array
@@ -185,6 +197,12 @@ def route(logits: jax.Array, k: int, renormalize: bool
     return probs, weights, experts
 
 
+def _all_but_gathers(prim, *_, **__) -> bool:
+    """Checkpoint policy of a held share's experts: every value is kept
+    for the backward but what a gather moved (:func:`_dispatch`'s rows)."""
+    return prim is not lax.gather_p
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _dispatch(x, order, inverse, k):
     """Row r of the result is the token of sorted assignment r."""
@@ -235,7 +253,9 @@ def moe_layer_spmd(x: jax.Array, router_w: jax.Array,
                    expert_fn: Callable[..., jax.Array], expert_params,
                    axis_name: Optional[str] = "ep", k: int = 2,
                    renormalize: bool = False,
-                   stat_axes: Sequence[str] = ()
+                   stat_axes: Sequence[str] = (),
+                   logits: Optional[jax.Array] = None,
+                   share: Tuple[int, int] = (0, 1)
                    ) -> Tuple[jax.Array, MoEMetrics]:
     """SPMD MoE (inside shard_map). Local shapes:
 
@@ -247,16 +267,35 @@ def moe_layer_spmd(x: jax.Array, router_w: jax.Array,
     the other shards' to compute) must come back zero, as
     :func:`grouped_matmul` leaves them. ``stat_axes``: the mesh axes the
     tokens are sharded over, so that the metrics are those of the global
-    batch and the same on every layout."""
+    batch and the same on every layout. ``logits``: the router's float32
+    logits ``[G, E]`` where the caller computed them from something other
+    than ``x`` (a router that reads the block's input); else ``x @
+    router_w`` here. ``share=(index, of)`` with no live ``axis_name``: this
+    device holds the experts ``[index * E / of, (index + 1) * E / of)`` as
+    ``expert_params``' leading dimension, and the result is their part of
+    the layer's output."""
     n = axis_size(axis_name) if axis_name else 1
     G, M = x.shape
     E = router_w.shape[1]
-    if E % n != 0:
-        raise ValueError(f"ep axis size ({n}) must divide n_experts ({E})")
-    e_local = E // n
+    index, of = share
+    if n > 1 and (index, of) != (0, 1):
+        raise ValueError(
+            f"share={share} with a live {axis_name!r} axis: a device "
+            "holds a share of the experts either by its place on the axis "
+            "or by being told, not both")
+    if E % (n * of) != 0 or not 0 <= index < of:
+        raise ValueError(f"ep axis size ({n}) must divide n_experts ({E})"
+                         if of == 1 else
+                         f"share={share} does not divide n_experts ({E})")
+    e_local = E // (n * of)
+    held = {a.shape[0] for a in jax.tree_util.tree_leaves(expert_params)}
+    if held != {e_local}:
+        raise ValueError(f"expert_params hold {sorted(held)} experts, the "
+                         f"layout {e_local} of {E}")
 
     with jax.named_scope(scopes.MOE_ROUTER):
-        logits = x.astype(jnp.float32) @ router_w.astype(jnp.float32)
+        if logits is None:
+            logits = x.astype(jnp.float32) @ router_w.astype(jnp.float32)
         probs, weights, experts = route(logits, k, renormalize)
 
     with jax.named_scope(scopes.MOE_DISPATCH):
@@ -267,7 +306,7 @@ def moe_layer_spmd(x: jax.Array, router_w: jax.Array,
                                          tiled=True)
             first = lax.axis_index(axis_name) * e_local
         else:
-            x_all, experts_all, first = x, experts, 0
+            x_all, experts_all, first = x, experts, index * e_local
         # this shard's experts sort to the front, the others behind them
         local = (experts_all.reshape(-1) - first) % E
         order = jnp.argsort(local, stable=True)
@@ -275,10 +314,19 @@ def moe_layer_spmd(x: jax.Array, router_w: jax.Array,
         group_sizes = jnp.sum(
             local[:, None] == jnp.arange(e_local, dtype=local.dtype),
             axis=0, dtype=jnp.int32)
-        rows = _dispatch(x_all, order, inverse, k)
 
-    with jax.named_scope(scopes.MOE_EXPERTS):
-        rows = expert_fn(expert_params, rows, group_sizes)
+    def gathered(x_all, expert_params):
+        with jax.named_scope(scopes.MOE_DISPATCH):
+            rows = _dispatch(x_all, order, inverse, k)
+        with jax.named_scope(scopes.MOE_EXPERTS):
+            return expert_fn(expert_params, rows, group_sizes)
+    if of > 1:
+        # of a share's G * k gathered rows all but 1 / of lie behind the
+        # groups: the backward gathers them again (a gather, no FLOPs)
+        # rather than keep them (at 8192 tokens, top-6 and 2560 columns
+        # 252 MB a layer); everything the experts compute is kept
+        gathered = jax.checkpoint(gathered, policy=_all_but_gathers)
+    rows = gathered(x_all, expert_params)
 
     with jax.named_scope(scopes.MOE_COMBINE):
         rows = _permute(rows, inverse, order).reshape(n * G, k, M)
@@ -307,11 +355,18 @@ def moe_layer_spmd(x: jax.Array, router_w: jax.Array,
         if n > 1:
             # over ep each assignment of the group is computed once
             computed = lax.psum(computed, axis_name) / n
+        if of == 1:
+            held_rows = G * k
+        else:   # counted from the choices, not from the sort's groups
+            held_rows = jnp.sum(
+                (experts >= first) & (experts < first + e_local),
+                dtype=jnp.float32)
         metrics = MoEMetrics(
             load_balance_loss=E * jnp.sum(counts / tokens * mean_probs),
             router_z_loss=total(jnp.sum(jnp.square(z))) / tokens,
             max_expert_load=jnp.max(counts) * E / (tokens * k),
-            dropped=total(G * k - computed), experts=experts)
+            dropped=total(held_rows - computed),
+            held_rows=total(jnp.float32(held_rows)), experts=experts)
     return y, metrics
 
 
@@ -332,7 +387,7 @@ def moe_layer(x: jax.Array, router_w: jax.Array, expert_fn: Callable,
     @functools.partial(
         shard_map, mesh=mesh,
         in_specs=(tok_spec, P(), P(ep_ax)),
-        out_specs=(tok_spec, MoEMetrics(P(), P(), P(), P(), tok_spec)),
+        out_specs=(tok_spec, MoEMetrics(P(), P(), P(), P(), P(), tok_spec)),
         check_vma=False)
     def run(xl, rw, ep_params):
         return moe_layer_spmd(xl, rw, expert_fn, ep_params, ep_ax, k,

@@ -41,6 +41,11 @@ LAYERS = "hvd.layers"
 ATTENTION = "hvd.attention"
 #: nested in ATTENTION: scores, softmax, weighted sum — what a kernel replaces
 ATTENTION_CORE = "hvd.attention.core"
+#: nested in ATTENTION_CORE, in a stack with several kinds of layer
+#: (``TransformerConfig.layer_pattern``): the core of a layer with a
+#: window, and of one that sees the whole causal history
+ATTENTION_CORE_WINDOW = "hvd.attention.core.window"
+ATTENTION_CORE_FULL = "hvd.attention.core.full"
 MLP = "hvd.mlp"
 #: nested in MLP: the expert layer of an MoE block, and its four parts.
 #: Router: logits, softmax, top-k, the auxiliary losses and counters.
@@ -69,7 +74,10 @@ MODEL_PHASES = (EMBED, LAYERS, ATTENTION, ATTENTION_CORE, MLP, HEAD)
 MOE_PHASES = (MOE, MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS, MOE_COMBINE)
 #: phases only a looped model has, each forward and backward
 LOOP_PHASES = (LOOP, LOOP_GATE)
-DEVICE_PHASES = (MODEL_PHASES + MOE_PHASES + LOOP_PHASES
+#: phases only a stack with several kinds of layer has, each forward and
+#: backward
+MIXED_PHASES = (ATTENTION_CORE_WINDOW, ATTENTION_CORE_FULL)
+DEVICE_PHASES = (MODEL_PHASES + MOE_PHASES + LOOP_PHASES + MIXED_PHASES
                  + (GRAD_SYNC, OPTIMIZER))
 
 # -- host spans (profiling.annotate) ------------------------------------------

@@ -220,3 +220,70 @@ def test_the_looped_step_gives_what_the_ouro_adapter_reads():
     assert set(aux) == {"aux_loss", "step_losses", "exit_share",
                         "gate_entropy"}
     assert aux["exit_share"].shape == (cfg.n_loops,)
+
+
+def test_the_mixed_step_gives_what_the_smallthinker_adapter_reads():
+    """``adapters/smallthinker.py`` names leaves of the parameter tree
+    (``_leaf_paths``, ``_init_function``), reads ``held_rows`` and
+    ``dropped`` from the step's fourth output and ``router_choices``; the
+    configuration's fields reach ``TransformerConfig`` by keyword as
+    ``head_width``, ``n_kv_heads``, ``layer_pattern``, ``moe_router_input``,
+    ``moe_activation``, ``expert_share``; the two phase files look for the
+    scopes of the layer kinds."""
+    import sys
+    import jax
+    import numpy as np
+    if CHIP not in sys.path:
+        sys.path.insert(0, CHIP)
+    from adapters import smallthinker
+    from trees import get_leaves
+    from horovod_tpu.models import transformer as t
+    from horovod_tpu.parallel import build_mesh
+    from horovod_tpu.profiling import scopes
+    for name in ("shapes", "tokens_per_step", "flops_per_token",
+                 "host_batch", "abstract_step", "Cell"):
+        assert callable(getattr(smallthinker, name)), name
+    assert {"program_choices", "program_loss_and_grads", "compiled_step",
+            "step"} <= set(dir(smallthinker.Cell))
+    with open(os.path.join(CHIP, "configs", "smallthinker-21b-a3b.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(CHIP, "workloads", "train.s8192.b1.json")) as f:
+        job = json.load(f)
+    full = smallthinker._model_config(config, job)
+    assert (full.head_width, full.n_kv_heads, full.n_heads, full.d_model,
+            full.n_experts, full.held_experts, full.moe_top_k) == (
+                128, 4, 28, 2560, 64, 16, 6)
+    assert full.layer_pattern == ((None, False),) + ((4096, True),) * 3
+    assert (full.moe_router_input, full.moe_activation, full.expert_share) \
+        == ("block_input", "relu", (0, 4))
+    config, job = {**config, **config["tiny"]}, {**job, **job["tiny"]}
+    cfg = smallthinker._model_config(config, job)
+    params = t.init_params(np.random.RandomState(0), cfg, 1)
+    layers = params["layers"]
+    assert layers["wq"].shape[-1] == cfg.n_heads * cfg.head_dim
+    assert layers["wk"].shape[-1] == cfg.kv_heads * cfg.head_dim
+    assert layers["we1"].shape[2] == cfg.held_experts
+    assert layers["router"].shape[-1] == cfg.n_experts
+    ours = jax.eval_shape(
+        smallthinker._init_function(cfg, config["assumed"]["embedding_std"]),
+        jax.random.PRNGKey(0))
+    assert jax.tree_util.tree_map(lambda a: a.shape, ours) == \
+        jax.tree_util.tree_map(lambda a: a.shape, params)
+    sizes = smallthinker.shapes(config, job)
+    assert set(get_leaves(params, smallthinker._leaf_paths(
+        sizes["layer_windows"]))) == {
+            "lm_head", "first_query", "window_key", "last_router",
+            "last_experts_down"}
+    mesh = build_mesh(devices=jax.devices()[:1], dp=-1)
+    batch = smallthinker.host_batch(config, job, 0, 0, 1)
+    _loss, aux, _grads = jax.eval_shape(
+        t.make_grad_fn(cfg, mesh), params, batch["tokens"], batch["targets"])
+    assert set(aux) == {"aux_loss", "load_balance_loss", "router_z_loss",
+                        "max_expert_load", "dropped", "held_rows"}
+    choices = jax.eval_shape(
+        lambda p, tok: t.router_choices(p, tok, cfg), params,
+        batch["tokens"])
+    assert choices.shape == (cfg.n_layers, batch["tokens"].size,
+                             cfg.moe_top_k)
+    assert (scopes.ATTENTION_CORE_WINDOW, scopes.ATTENTION_CORE_FULL) == (
+        "hvd.attention.core.window", "hvd.attention.core.full")

@@ -214,9 +214,10 @@ def _last_share_from_its_own_gate(monkeypatch):
 
 
 def _post_norms_left_out(monkeypatch):
-    monkeypatch.setattr(
-        t, "_block", lambda p, x, positions, cfg, block=t._block: block(
-            p, x, positions, dataclasses.replace(cfg, post_norm=False)))
+    def without(p, x, positions, cfg, *kind, block=t._block):
+        return block(p, x, positions,
+                     dataclasses.replace(cfg, post_norm=False), *kind)
+    monkeypatch.setattr(t, "_block", without)
 
 
 @pytest.mark.parametrize("fault", [

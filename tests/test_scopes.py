@@ -25,7 +25,7 @@ PACKAGE = os.path.join(os.path.dirname(os.path.dirname(
 
 def test_the_vocabulary_is_the_only_place_the_strings_are_written():
     names = scopes.DEVICE_PHASES + scopes.HOST_SPANS
-    assert len(set(names)) == len(names) == 17
+    assert len(set(names)) == len(names) == 19
     assert all(n.startswith("hvd.") for n in names)
     home = os.path.join(PACKAGE, "profiling", "scopes.py")
     elsewhere = []
@@ -119,6 +119,31 @@ def _looped_step():
             (params, init_opt_state(tx, params, mesh, cfg), t, y))
 
 
+def _mixed_step():
+    """The flagship block with two kinds of layer (a full layer without
+    positions, a window layer with rope), grouped heads, the router on the
+    block's input, ReLU-gated experts of which a share is held."""
+    from horovod_tpu.models import (TransformerConfig, init_opt_state,
+                                    init_params, make_train_step,
+                                    shard_batch, shard_params)
+    cfg = TransformerConfig(vocab_size=64, d_model=32, n_heads=4,
+                            n_layers=2, d_ff=16, max_seq=32, n_experts=4,
+                            moe_top_k=2, moe_gated=True,
+                            moe_renormalize=True, tie_embeddings=False,
+                            dtype=jnp.float32, head_width=16, n_kv_heads=2,
+                            layer_pattern=((None, False), (8, True)),
+                            moe_router_input="block_input",
+                            moe_activation="relu", expert_share=(0, 2))
+    mesh = build_mesh(devices=jax.devices()[:1], dp=-1)
+    params = shard_params(init_params(np.random.RandomState(0), cfg, 1),
+                          cfg, mesh)
+    tx = optax.adamw(1e-3)
+    tokens = np.zeros((2, 16), np.int32)
+    t, y = shard_batch(tokens, tokens, mesh)
+    return (make_train_step(cfg, mesh, tx),
+            (params, init_opt_state(tx, params, mesh, cfg), t, y))
+
+
 _TEXTS = {}
 
 
@@ -126,7 +151,8 @@ def _compiled_text(model: str) -> str:
     if model not in _TEXTS:
         step, args = {"bert": _bert_step, "flagship": _flagship_step,
                       "flagship.dp2": lambda: _flagship_step(2),
-                      "moe": _moe_step, "looped": _looped_step}[model]()
+                      "moe": _moe_step, "looped": _looped_step,
+                      "mixed": _mixed_step}[model]()
         _TEXTS[model] = step.lower(*args).compile().as_text()
     return _TEXTS[model]
 
@@ -160,6 +186,34 @@ def test_the_moe_step_carries_every_phase_in_both_directions(phase):
 @pytest.mark.parametrize("phase", scopes.MODEL_PHASES + scopes.LOOP_PHASES)
 def test_the_looped_step_carries_every_phase_in_both_directions(phase):
     assert _directions(_compiled_text("looped"), phase) == {"fwd", "bwd"}
+
+
+@pytest.mark.parametrize("phase", scopes.MODEL_PHASES + scopes.MOE_PHASES
+                         + scopes.MIXED_PHASES)
+def test_the_mixed_step_carries_every_phase_in_both_directions(phase):
+    assert _directions(_compiled_text("mixed"), phase) == {"fwd", "bwd"}
+
+
+def test_the_layer_kinds_nest_in_the_attention_core():
+    """hvd.attention.core.window and hvd.attention.core.full inside
+    hvd.attention.core, so ``step.attention_core_ms`` covers both; a stack
+    of one kind of layer has neither; and the router of a block that
+    reads its input is scoped where its logits are computed, before
+    attention, outside hvd.mlp."""
+    paths = re.findall(r'op_name="([^"]*)"', _compiled_text("mixed"))
+    for inner in scopes.MIXED_PHASES:
+        found = [p for p in paths
+                 if inner in re.sub(r"[()]", "/", p).split("/")]
+        assert found, inner
+        for path in found:
+            assert scopes.ATTENTION_CORE in re.sub(
+                r"[()]", "/", path).split("/"), path
+    assert not any(name in _compiled_text(model)
+                   for name in scopes.MIXED_PHASES
+                   for model in ("flagship", "moe", "looped"))
+    router = [p for p in paths if scopes.MOE_ROUTER in p and "dot_general"
+              in p]
+    assert router and any(scopes.MLP not in p for p in router), router
 
 
 def test_the_loop_nests_in_layers_and_its_gate_in_head():

@@ -72,6 +72,10 @@ _QKV_CELL = [((2, 2048, 16, 128), jnp.bfloat16)] * 3
 # the 4096-token cells (ouro-2.6b.s4096; olmoe-1b-7b.s4096 at batch 2): the
 # backward holds a head's float32 dq of 4096 rows in VMEM
 _QKV_S4096 = [((1, 4096, 16, 128), jnp.bfloat16)] * 3
+# the cell smallthinker-21b-a3b.s8192: 28 query heads on 4 key/value
+# heads, 8192 positions (a head's float32 dq of 8192 rows stays in VMEM)
+_QKV_GROUPED = [((1, 8192, 28, 128), jnp.bfloat16)] \
+    + [((1, 8192, 4, 128), jnp.bfloat16)] * 2
 _FLASH = ("hvd_flash_attention", "hvd_flash_bwd")
 # the attention core of the cells bert-large.s128 and bert-large.s512: q, k,
 # v and the [B, S] key mask. Sixteen heads of 64 are eight 128-lane columns
@@ -86,6 +90,10 @@ _GMM_UP = [((65536, 2048), jnp.bfloat16), ((64, 2048, 1024), jnp.float32),
            ((64,), jnp.int32)]
 _GMM_DOWN = [((65536, 1024), jnp.bfloat16), ((64, 1024, 2048), jnp.float32),
              ((64,), jnp.int32)]
+# a held share's expert layer in smallthinker-21b-a3b.s8192: 8192 tokens x
+# top-6 gathered rows, 16 held experts of 2560 <-> 768 (tile 512 x 256 x 256)
+_GMM_SHARE = [((49152, 2560), jnp.bfloat16), ((16, 2560, 768), jnp.float32),
+              ((16,), jnp.int32)]
 _BLOCKS = ((8192, 256), jnp.float32)
 _CODES = [((8192, 256), jnp.int8), ((8192, 1), jnp.float32)]
 
@@ -100,6 +108,17 @@ CASES = {
         _QKV_CELL, "hvd_flash_attention"),
     "flash_fwd_grad_cell": (_flash_grad, _QKV_CELL, _FLASH),
     "flash_fwd_grad_s4096": (_flash_grad, _QKV_S4096, _FLASH),
+    # a window layer and a full layer of the grouped cell: the band's
+    # clamps in both index maps, the k/v block index by group, the
+    # backward's pieces on the diagonal and on the band's edge
+    "flash_fwd_grad_window_grouped": (
+        jax.grad(lambda q, k, v: _sum32(pa.flash_attention_tpu(
+            q, k, v, True, window=4096)), (0, 1, 2)), _QKV_GROUPED, _FLASH),
+    "flash_fwd_grad_full_grouped": (_flash_grad, _QKV_GROUPED, _FLASH),
+    # a window that is no multiple of the tile: whole masked tiles
+    "flash_fwd_grad_window_unaligned": (
+        jax.grad(lambda q, k, v: _sum32(pa.flash_attention_tpu(
+            q, k, v, True, window=1536)), (0, 1, 2)), _QKV_S4096, _FLASH),
     # the ring-attention step: non-causal, lse differentiated too
     "flash_lse_noncausal_grad": (
         jax.grad(lambda q, k, v: _sum32(*pa.flash_attention_with_lse(
@@ -124,6 +143,9 @@ CASES = {
     "moe_gmm_down_grad": (
         jax.grad(lambda x, w, g: _sum32(moe.grouped_matmul(x, w, g)),
                  (0, 1)), _GMM_DOWN, "transpose_jvp_" + moe.GMM_NAME),
+    "moe_gmm_share_grad": (
+        jax.grad(lambda x, w, g: _sum32(moe.grouped_matmul(x, w, g)),
+                 (0, 1)), _GMM_SHARE, "transpose_jvp_" + moe.GMM_NAME),
     # an ep shard's share at the four-chip smoke's MoE: float32, 128 wide
     "moe_gmm_smoke_grad": (
         jax.grad(lambda x, w, g: _sum32(moe.grouped_matmul(x, w, g)),
@@ -339,6 +361,37 @@ def test_looped_step_compiles_for_v5e_with_both_kernels(
                       if "op_name=" in line)
     assert scopes.LOOP + "/" in names and scopes.LOOP_GATE + "/" in names
     assert step_bytes(compiled.memory_analysis())["total"] < 15.0e9
+
+
+def test_mixed_step_compiles_for_v5e_on_the_kernels(
+        topo, no_compile_cache, monkeypatch):
+    """The cell smallthinker-21b-a3b.s8192's step, one period of a full and
+    three window layers at 8192 tokens with 28 / 4 grouped heads and 16 of
+    64 experts held: every layer's attention is the two flash kernels (a
+    call site a layer of the unrolled period, forward and backward; no
+    score-shaped array in the program), the experts are ``hvd_moe_gmm``,
+    the head ``hvd_fused_xent``; both layer kinds' scopes are in the
+    program; the step fits with the room ISSUE 32 asks for."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    step, args, shapes, step_bytes = _cell_step(
+        "smallthinker-21b-a3b.s8192", topo)
+    compiled = step.lower(*args).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    layers = shapes["layers"]
+    assert sum("hvd_flash_attention" in c for c in calls) == layers
+    assert sum("hvd_flash_bwd" in c for c in calls) == layers
+    assert sum(moe.GMM_NAME in c for c in calls) == 9 * layers
+    assert sum("hvd_fused_xent" in c for c in calls) == 1
+    s = shapes["seq"]
+    assert f",{s},{s}]" not in text, "a score-shaped array"
+    from horovod_tpu.profiling import scopes
+    names = "\n".join(line for line in text.splitlines()
+                      if "op_name=" in line)
+    for name in scopes.MIXED_PHASES:
+        assert name + "/" in names, name
+    assert 4.0e9 < step_bytes(compiled.memory_analysis())["total"] < 15.0e9
 
 
 @pytest.mark.parametrize("cell", ["gpt-1.3b-widths.s2048",
