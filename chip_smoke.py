@@ -597,7 +597,8 @@ def _check_gmm(smoke: Smoke) -> None:
     zero, forward and in d_rows (the kernel never writes them; what XLA's
     ragged_dot gives there is printed). The reference is ragged_dot in
     float32 on the groups' rows alone. Prints which path
-    ``grouped_matmul`` takes at each size and at the OLMoE cell's."""
+    ``grouped_matmul`` takes, and the tile of each of its three calls, at
+    each size, at the OLMoE cell's and at the share cell's."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -624,6 +625,7 @@ def _check_gmm(smoke: Smoke) -> None:
     def beyond(a):
         return float(jnp.max(jnp.abs(a[inside:].astype(jnp.float32))))
     olmoe = gmm_path(65536, 2048, 1024)
+    share = gmm_path(49152, 2560, 768)
     for kernel, width in ((GMM_NAME, d_out), (None, 64)):
         w = jax.random.normal(keys[1], (groups, d_in, width),
                               jnp.float32) / np.sqrt(d_in)
@@ -631,8 +633,10 @@ def _check_gmm(smoke: Smoke) -> None:
         path = gmm_path(rows, d_in, width)
         if smoke.on_chip:
             check(olmoe.startswith(f"pallas {GMM_NAME} ") and
+                  share.startswith(f"pallas {GMM_NAME} ") and
                   path.startswith(f"pallas {GMM_NAME} " if kernel
-                                  else "xla ragged_dot"), path + olmoe)
+                                  else "xla ragged_dot"),
+                  path + olmoe + share)
         name = "moe_gmm" if kernel else "moe_gmm on xla ragged_dot"
         ran = {} if kernel else {"ran": "xla"}
 
@@ -644,6 +648,7 @@ def _check_gmm(smoke: Smoke) -> None:
                      _rel_err(got, jax.jit(reference)(x, w)), GMM_TOL,
                      shape=(rows, d_in, width, groups), dtype="bfloat16",
                      gmm_path=path, gmm_path_at_the_olmoe_cell=olmoe,
+                     gmm_path_at_the_share_cell=share,
                      rows_in_groups=inside, largest_group=int(sizes.max()),
                      empty_groups=int((sizes == 0).sum()),
                      beyond_the_groups=beyond(got),
@@ -950,6 +955,7 @@ def _four_layouts(smoke: Smoke) -> None:
     devices, first-step loss against one device; for the MoE, at widths
     the hvd_moe_gmm kernels take, the gradients too."""
     import jax
+    import jax.numpy as jnp
     import numpy as np
     import __graft_entry__ as graft
     from horovod_tpu.parallel.moe import GMM_NAME, gmm_path
@@ -973,8 +979,8 @@ def _four_layouts(smoke: Smoke) -> None:
     moe_cfg = dataclasses.replace(cfg, n_experts=4, n_microbatches=1,
                                   d_model=128, d_ff=128)
     k = moe_cfg.moe_top_k
-    paths = {"ep=2 sp=2": gmm_path(B * S * k // 2, 128, 128),
-             "one device": gmm_path(B * S * k, 128, 128)}
+    paths = {"ep=2 sp=2": gmm_path(B * S * k // 2, 128, 128, jnp.float32),
+             "one device": gmm_path(B * S * k, 128, 128, jnp.float32)}
     if smoke.on_chip:
         check(all(p.startswith(f"pallas {GMM_NAME} ")
                   for p in paths.values()), str(paths))

@@ -68,36 +68,121 @@ class MoEMetrics(NamedTuple):
     experts: jax.Array
 
 
-#: megablox tile (rows, contraction, columns) of the grouped matmul and of
-#: its two gradients; the sweep behind it is in PERF.md section 6 (PR 26)
-GMM_TILE = (512, 1024, 1024)
 #: the name of the three Pallas calls on the device: ``hvd_moe_gmm`` forward,
 #: ``transpose_jvp_hvd_moe_gmm`` the input's and the weights' gradients
 GMM_NAME = "hvd_moe_gmm"
+#: what one call's blocks may take of the 16 MiB of scoped VMEM (megablox's
+#: ``pallas_call`` asks for no limit of its own): two buffers of each input
+#: and output block and the float32 accumulator. The rest is Mosaic's, for
+#: the operands it copies and masks inside the kernel; the compiles that
+#: drew the line are in PERF.md section 6 (PR 33)
+GMM_VMEM_BUDGET = 14 * 2 ** 20
+#: row tiles in the order a call prefers them. A group that does not start
+#: on a row tile visits one tile more, so with its weights resident a small
+#: tile costs no bandwidth and wastes fewer rows: 256 before 128, which
+#: fills the MXU's passes worse (the sweep in PERF.md section 6, PR 33)
+GMM_ROW_TILES = (256, 128)
+#: where the contraction is split the weight blocks are fetched again every
+#: row tile, and the largest row tile pays for them best
+GMM_ROW_TILES_SPLIT = (512, 256, 128)
 
 
-def _gmm_tile(n_rows: int, k: int, f: int):
-    """The tile the three calls share, or None where the kernels do not
-    apply. The input gradient swaps the roles of the last two entries, and
-    the TPU lowering wants a block's last dimension a multiple of 128 that
-    divides the array's: so one value for both, dividing ``k`` and ``f``."""
-    tm = next((t for t in (GMM_TILE[0], 256, 128) if n_rows % t == 0), None)
-    tkn = next((t for t in (GMM_TILE[1], 512, 256, 128)
-                if k % t == 0 and f % t == 0), None)
-    return (tm, tkn, tkn) if tm and tkn else None
+class GmmTiles(NamedTuple):
+    """megablox's ``(tm, tk, tn)`` for each of the three calls: rows, the
+    call's own contraction, the call's own output columns."""
+    #: ``gmm``: rows ``[N, K] @ [E, K, F]``, contraction K, columns F
+    forward: Tuple[int, int, int]
+    #: ``gmm(transpose_rhs=True)``: cotangent ``[N, F]`` against the same
+    #: weights, contraction F, columns K: the other way round
+    input_grad: Tuple[int, int, int]
+    #: ``tgmm``: a group's rows streamed through in ``tm`` steps into its
+    #: ``[tk, tn]`` block of the ``[E, K, F]`` result
+    weight_grad: Tuple[int, int, int]
 
 
-def gmm_path(n_rows: int, k: int, f: int) -> str:
+def _lane_tiles(width: int):
+    """The 128-multiples that divide ``width``, widest first (the TPU
+    lowering wants a block's last dimension a multiple of 128 lanes)."""
+    return [t for t in range(width - width % 128, 0, -128) if width % t == 0]
+
+
+def _fits(lhs, rhs, out, itemsize: int) -> bool:
+    """Whether a call's blocks ``(rows, columns)`` stay under
+    :data:`GMM_VMEM_BUDGET`: two buffers of each and a float32 accumulator
+    of the output block's shape."""
+    elements = sum(r * c for r, c in (lhs, rhs, out))
+    return (2 * itemsize * elements + 4 * out[0] * out[1]
+            <= GMM_VMEM_BUDGET)
+
+
+def _gmm_call_tile(n_rows: int, k: int, n: int, itemsize: int):
+    """Tile of one ``gmm`` call with contraction ``k`` and ``n`` output
+    columns. The weight block's index is (group, k tile, n tile): with the
+    whole contraction in one block, consecutive row tiles of a group ask for
+    the same block and it stays in VMEM, read once a group instead of once
+    a row tile. So: the widest contraction first, then the widest columns
+    (the rows are read once a column tile), then the row tile, all under
+    :data:`GMM_VMEM_BUDGET`."""
+    for tk in _lane_tiles(k):
+        row_tiles = GMM_ROW_TILES if tk == k else GMM_ROW_TILES_SPLIT
+        for tn in _lane_tiles(n):
+            for tm in row_tiles:
+                if n_rows % tm == 0 and _fits((tm, tk), (tk, tn), (tm, tn),
+                                              itemsize):
+                    return tm, tk, tn
+    return None
+
+
+def _tgmm_call_tile(n_rows: int, k: int, n: int, itemsize: int):
+    """Tile of the ``tgmm`` call: the largest ``[tk, tn]`` output block
+    under the budget (a group's rows are read ``k / tk`` and ``n / tn``
+    times, ``tk * tn / (tk + tn)`` FLOPs a byte), with the first row tile
+    of :data:`GMM_ROW_TILES` that fits beside it."""
+    best = None
+    for tk in _lane_tiles(k):
+        for tn in _lane_tiles(n):
+            for tm in GMM_ROW_TILES:
+                if n_rows % tm == 0 and _fits((tm, tk), (tm, tn), (tk, tn),
+                                              itemsize):
+                    rank = (tk * tn, tk * tn / (tk + tn))
+                    if best is None or rank > best[0]:
+                        best = rank, (tm, tk, tn)
+                    break
+    return best and best[1]
+
+
+def _gmm_tile(n_rows: int, k: int, f: int, itemsize: int):
+    """:class:`GmmTiles` for rows ``[n_rows, k]`` of ``itemsize`` bytes an
+    element against weights ``[E, k, f]``, each call's tile from that call's
+    own shape; None where the kernels do not apply (rows or a width that no
+    128-multiple divides: XLA's ``ragged_dot``)."""
+    tiles = (_gmm_call_tile(n_rows, k, f, itemsize),
+             _gmm_call_tile(n_rows, f, k, itemsize),
+             _tgmm_call_tile(n_rows, k, f, itemsize))
+    return GmmTiles(*tiles) if all(tiles) else None
+
+
+def gmm_path(n_rows: int, k: int, f: int, dtype=jnp.bfloat16) -> str:
     """Which implementation :func:`grouped_matmul` takes on the default
-    backend for rows ``[n_rows, k]`` and weights ``[E, k, f]``, and why
-    (``chip_smoke.py`` prints it, as it does ``attend``'s choice)."""
-    tile = _gmm_tile(n_rows, k, f)
-    if tile is None:
+    backend for rows ``[n_rows, k]`` of ``dtype`` and weights ``[E, k, f]``,
+    the tile of each of the three calls and why (``chip_smoke.py`` prints
+    it, as it does ``attend``'s choice)."""
+    tiles = _gmm_tile(n_rows, k, f, jnp.dtype(dtype).itemsize)
+    if tiles is None:
         return (f"xla ragged_dot (no 128-multiple tile divides rows "
                 f"{n_rows} and widths {k}, {f})")
     if jax.default_backend() != "tpu":
         return f"xla ragged_dot (backend {jax.default_backend()})"
-    return f"pallas {GMM_NAME} tile {tile[0]}x{tile[1]}x{tile[2]}"
+
+    def say(tile, contraction):
+        resident = (", a group's weights resident"
+                    if tile[1] == contraction else "")
+        return "x".join(map(str, tile)) + resident
+    return (f"pallas {GMM_NAME} forward {say(tiles.forward, k)}; "
+            f"input gradient {say(tiles.input_grad, f)}; "
+            f"weight gradient {'x'.join(map(str, tiles.weight_grad))} "
+            f"(whole contraction first, else the widest blocks under "
+            f"{GMM_VMEM_BUDGET >> 20} MiB of VMEM)")
 
 
 def _megablox():
@@ -123,26 +208,27 @@ def _ragged_dot(rows, weights, group_sizes):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _gmm(rows, weights, group_sizes, tile, interpret):
-    """``tile`` None: XLA's ``ragged_dot``; else the megablox kernels."""
-    if tile is None:
+def _gmm(rows, weights, group_sizes, tiles, interpret):
+    """``tiles`` None: XLA's ``ragged_dot``; else the megablox kernels at
+    :class:`GmmTiles`."""
+    if tiles is None:
         out = _ragged_dot(rows, weights, group_sizes)
     else:
         gmm, _tgmm = _megablox()
         with jax.named_scope(GMM_NAME):
             out = gmm(rows, weights.astype(rows.dtype), group_sizes,
-                      rows.dtype, tile, interpret=interpret)
+                      rows.dtype, tiles.forward, interpret=interpret)
     return _zero_beyond(out, group_sizes)
 
 
-def _gmm_fwd(rows, weights, group_sizes, tile, interpret):
-    return (_gmm(rows, weights, group_sizes, tile, interpret),
+def _gmm_fwd(rows, weights, group_sizes, tiles, interpret):
+    return (_gmm(rows, weights, group_sizes, tiles, interpret),
             (rows, weights, group_sizes))
 
 
-def _gmm_bwd(tile, interpret, res, g):
+def _gmm_bwd(tiles, interpret, res, g):
     rows, weights, group_sizes = res
-    if tile is None:
+    if tiles is None:
         # what XLA does with a row beyond the groups is its own: no such
         # row's cotangent may reach a weight gradient
         d_rows, d_weights = jax.vjp(
@@ -152,10 +238,11 @@ def _gmm_bwd(tile, interpret, res, g):
         gmm, tgmm = _megablox()     # both visit the groups' rows only
         with jax.named_scope(GMM_NAME):
             d_rows = gmm(g, weights.astype(rows.dtype), group_sizes,
-                         rows.dtype, tile, transpose_rhs=True,
+                         rows.dtype, tiles.input_grad, transpose_rhs=True,
                          interpret=interpret)
             d_weights = tgmm(rows.swapaxes(0, 1), g, group_sizes,
-                             rows.dtype, tile, interpret=interpret)
+                             rows.dtype, tiles.weight_grad,
+                             interpret=interpret)
     return (_zero_beyond(d_rows, group_sizes),
             d_weights.astype(weights.dtype), None)
 
@@ -174,14 +261,16 @@ def grouped_matmul(rows: jax.Array, weights: jax.Array,
     to the weights' gradient.
 
     On a TPU (and under ``interpret``) the megablox Pallas kernels of the
-    installed JAX at :data:`GMM_TILE`, kept over XLA's ``ragged_dot``
-    kernels by the sweep in PERF.md; elsewhere, or where no row tile divides
-    ``N`` or no 128-lane tile both widths, ``jax.lax.ragged_dot``, the same
-    function as plain XLA (the pattern of ``pallas_attention.attend``;
-    :func:`gmm_path` says which and why)."""
+    installed JAX, kept over XLA's ``ragged_dot`` kernels by the sweeps in
+    PERF.md, each of the three calls at a tile from its own shape and
+    ``rows.dtype`` (:func:`_gmm_tile`); elsewhere, or where no row tile
+    divides ``N`` or a width is no multiple of 128 lanes,
+    ``jax.lax.ragged_dot``, the same function as plain XLA (the pattern of
+    ``pallas_attention.attend``; :func:`gmm_path` says which and why)."""
     on_kernels = interpret or jax.default_backend() == "tpu"
-    tile = _gmm_tile(rows.shape[0], *weights.shape[1:]) if on_kernels else None
-    return _gmm(rows, weights, group_sizes.astype(jnp.int32), tile,
+    tiles = _gmm_tile(rows.shape[0], *weights.shape[1:],
+                      rows.dtype.itemsize) if on_kernels else None
+    return _gmm(rows, weights, group_sizes.astype(jnp.int32), tiles,
                 interpret)
 
 

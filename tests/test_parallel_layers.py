@@ -2,6 +2,8 @@
 against single-device oracles (the TPU analog of the reference's
 test/parallel numeric-equality suite)."""
 
+import functools
+
 import numpy as np
 import pytest
 import jax
@@ -123,6 +125,16 @@ def _moe_inputs(seed, E=4, M=8, Hd=16, T=64):
             _expert_params(E, M, Hd))
 
 
+def _per_expert_loop(sizes, x, w):
+    """``x[group g] @ w[g]`` group by group, zeros beyond the groups."""
+    out, a = [], 0
+    for e, n in enumerate(sizes):
+        out.append(x[a:a + n] @ w[e])
+        a += n
+    out.append(jnp.zeros((x.shape[0] - a, w.shape[2]), x.dtype))
+    return jnp.concatenate(out)
+
+
 @pytest.mark.parametrize("interpret", [False, True])
 @pytest.mark.parametrize("sizes", [[100, 0, 30, 0, 126], [0, 0, 256, 0, 0],
                                    [50, 50, 50, 50, 56], [60, 0, 40, 0, 28],
@@ -134,26 +146,92 @@ def test_grouped_matmul_matches_a_per_expert_loop(sizes, interpret):
     cases: with ep > 1 they are another shard's) are zero, and so is their
     gradient, on both paths."""
     from horovod_tpu.parallel.moe import _gmm_tile
-    assert _gmm_tile(256, 128, 256) == (256, 128, 128)   # the kernels apply
-    assert _gmm_tile(256, 64, 256) is None               # 64 lanes: XLA
+    assert _gmm_tile(256, 128, 256, 4) is not None       # the kernels apply
+    assert _gmm_tile(256, 64, 256, 4) is None            # 64 lanes: XLA
     rng = np.random.RandomState(0)
     x = jnp.asarray(rng.randn(256, 128), jnp.float32)
     w = jnp.asarray(rng.randn(5, 128, 256), jnp.float32)
     ct = jnp.asarray(rng.randn(256, 256), jnp.float32)
     gs = jnp.asarray(sizes, jnp.int32)
 
-    def loop(x, w):
-        out, a = [], 0
-        for e, n in enumerate(sizes):
-            out.append(x[a:a + n] @ w[e])
-            a += n
-        out.append(jnp.zeros((x.shape[0] - a, w.shape[2]), x.dtype))
-        return jnp.concatenate(out)
+    loop = functools.partial(_per_expert_loop, sizes)
 
     def ours(x, w):
         return grouped_matmul(x, w, gs, interpret=interpret)
     np.testing.assert_allclose(ours(x, w), loop(x, w), rtol=1e-5, atol=1e-4)
     got, want = jax.vjp(ours, x, w)[1](ct), jax.vjp(loop, x, w)[1](ct)
+    for g, r, name in zip(got, want, ("d_rows", "d_weights")):
+        np.testing.assert_allclose(g, r, rtol=1e-5, atol=1e-4, err_msg=name)
+
+
+# rows, k, f, itemsize: the share cell's up and down calls
+# (smallthinker-21b-a3b.s8192), OLMoE's, the four-chip smoke's float32 case,
+# widths whose only common 128-multiple divisor is 128, a width of three
+# 128-lane columns, 64-lane widths, rows that no row tile divides
+GMM_SHAPES = [(49152, 2560, 768, 2), (49152, 768, 2560, 2),
+              (65536, 2048, 1024, 2), (65536, 1024, 2048, 2),
+              (256, 128, 128, 4), (1024, 384, 640, 2), (1024, 384, 640, 4),
+              (128, 20480, 384, 2), (256, 64, 256, 2), (256, 256, 64, 4),
+              (200, 128, 128, 2), (4160, 128, 128, 2)]
+
+
+@pytest.mark.parametrize("n_rows,k,f,itemsize", GMM_SHAPES)
+def test_gmm_tiles_come_from_each_calls_own_shape(n_rows, k, f, itemsize):
+    """Each of the three megablox calls gets a tile from its own (rows,
+    contraction, columns): 128-multiples that divide them, blocks under the
+    stated VMEM budget at the operands' itemsize, the input gradient's
+    contraction being ``f`` and its columns ``k``; XLA exactly where the
+    one shared tile gave up (rows or a width that 128 does not divide)."""
+    from horovod_tpu.parallel import moe
+    tiles = moe._gmm_tile(n_rows, k, f, itemsize)
+    assert (tiles is None) == bool(n_rows % 128 or k % 128 or f % 128)
+    if tiles is None:
+        return
+    calls = {"forward": (tiles.forward, k, f),
+             "input_grad": (tiles.input_grad, f, k),
+             "weight_grad": (tiles.weight_grad, k, f)}
+    for name, ((tm, tk, tn), contraction, columns) in calls.items():
+        assert tm % 128 == 0 and n_rows % tm == 0, (name, tm)
+        assert tk % 128 == 0 and contraction % tk == 0, (name, tk)
+        assert tn % 128 == 0 and columns % tn == 0, (name, tn)
+        out = (tk, tn) if name == "weight_grad" else (tm, tn)
+        blocks = [(tm, tk), (tk, tn), (tm, tn)]      # two inputs, one output
+        assert (2 * itemsize * sum(a * b for a, b in blocks)
+                + 4 * out[0] * out[1]) <= moe.GMM_VMEM_BUDGET, name
+    if k * f * 2 * itemsize <= moe.GMM_VMEM_BUDGET // 2:
+        # a group's whole weight matrix fits beside the rows: one block,
+        # resident across the group's row tiles, both ways round
+        assert tiles.forward[1:] == (k, f), tiles
+        assert tiles.input_grad[1:] == (f, k), tiles
+
+
+@pytest.mark.parametrize("tiles", [None, ((128, 128, 384), (128, 384, 128),
+                                          (128, 256, 128))])
+def test_grouped_matmul_at_unequal_k_and_n_tiles(tiles):
+    """Widths 256 x 384 in interpret mode: the rule's own tiles (k and n
+    tiles differ, the input gradient's the other way round), and tiles of
+    several k and n steps handed to ``_gmm``; ragged groups with empty
+    ones, rows beyond the groups; forward and both gradients against the
+    per-expert loop."""
+    from horovod_tpu.parallel import moe
+    sizes, n_rows, k, f = [130, 0, 77, 200, 0, 41], 512, 256, 384
+    rule = moe._gmm_tile(n_rows, k, f, 4)
+    assert rule.forward[1:] == (k, f) and rule.input_grad[1:] == (f, k)
+    tiles = rule if tiles is None else moe.GmmTiles(*tiles)
+    rng = np.random.RandomState(3)
+    x = jnp.asarray(rng.randn(n_rows, k), jnp.float32)
+    w = jnp.asarray(rng.randn(len(sizes), k, f), jnp.float32)
+    ct = jnp.asarray(rng.randn(n_rows, f), jnp.float32)
+    gs = jnp.asarray(sizes, jnp.int32)
+
+    loop = functools.partial(_per_expert_loop, sizes)
+
+    def ours(x, w):
+        return moe._gmm(x, w, gs, tiles, True)
+    with jax.default_matmul_precision("highest"):
+        np.testing.assert_allclose(ours(x, w), loop(x, w),
+                                   rtol=1e-5, atol=1e-4)
+        got, want = jax.vjp(ours, x, w)[1](ct), jax.vjp(loop, x, w)[1](ct)
     for g, r, name in zip(got, want, ("d_rows", "d_weights")):
         np.testing.assert_allclose(g, r, rtol=1e-5, atol=1e-4, err_msg=name)
 

@@ -91,9 +91,13 @@ _GMM_UP = [((65536, 2048), jnp.bfloat16), ((64, 2048, 1024), jnp.float32),
 _GMM_DOWN = [((65536, 1024), jnp.bfloat16), ((64, 1024, 2048), jnp.float32),
              ((64,), jnp.int32)]
 # a held share's expert layer in smallthinker-21b-a3b.s8192: 8192 tokens x
-# top-6 gathered rows, 16 held experts of 2560 <-> 768 (tile 512 x 256 x 256)
+# top-6 gathered rows, 16 held experts of 2560 <-> 768. The widths share no
+# tile but 256: each call's own tile holds an expert's whole matrix, and
+# what Mosaic allocates beside the blocks is this compile's to say
 _GMM_SHARE = [((49152, 2560), jnp.bfloat16), ((16, 2560, 768), jnp.float32),
               ((16,), jnp.int32)]
+_GMM_SHARE_DOWN = [((49152, 768), jnp.bfloat16),
+                   ((16, 768, 2560), jnp.float32), ((16,), jnp.int32)]
 _BLOCKS = ((8192, 256), jnp.float32)
 _CODES = [((8192, 256), jnp.int8), ((8192, 1), jnp.float32)]
 
@@ -146,6 +150,9 @@ CASES = {
     "moe_gmm_share_grad": (
         jax.grad(lambda x, w, g: _sum32(moe.grouped_matmul(x, w, g)),
                  (0, 1)), _GMM_SHARE, "transpose_jvp_" + moe.GMM_NAME),
+    "moe_gmm_share_down_grad": (
+        jax.grad(lambda x, w, g: _sum32(moe.grouped_matmul(x, w, g)),
+                 (0, 1)), _GMM_SHARE_DOWN, "transpose_jvp_" + moe.GMM_NAME),
     # an ep shard's share at the four-chip smoke's MoE: float32, 128 wide
     "moe_gmm_smoke_grad": (
         jax.grad(lambda x, w, g: _sum32(moe.grouped_matmul(x, w, g)),
