@@ -253,8 +253,12 @@ def init(ranks: Optional[Sequence[int]] = None,
         # observability"): compile-time metrics + the recompile_storm
         # detector; idempotent, gated on HVD_TPU_COMPILE_METRICS
         try:
-            from horovod_tpu.profiling import compile_watch
+            from horovod_tpu.profiling import compile_watch, host_log
             compile_watch.ensure_installed()
+            # garbage collections as ``scopes.HOST_GC`` spans of the host log
+            # ("Host pauses"); one ``gc.callbacks`` entry, removed by
+            # shutdown()
+            host_log.install_gc_callback()
         except Exception:
             pass
         # autopilot policy engine (docs/OBSERVABILITY.md "Autopilot"):
@@ -290,6 +294,11 @@ def shutdown(force: bool = False) -> None:
             # init() resumes it for the new world
             from horovod_tpu.diagnostics import watchdog as _wd
             _wd.suspend()
+        except Exception:
+            pass
+        try:
+            from horovod_tpu.profiling import host_log
+            host_log.uninstall_gc_callback()
         except Exception:
             pass
         try:

@@ -5,7 +5,7 @@ a dedicated writer thread, producing chrome://tracing JSON; activity names in
 ``horovod/common/common.h:73-105``; dynamic start/stop via the C API
 (``operations.cc:1011-1041``). TPU equivalent: the same host-side negotiation
 timeline, while device-side profiling is delegated to ``jax.profiler``
-(see :func:`horovod_tpu.profiling.trace`).
+(``jax.profiler.trace``; managed captures: :mod:`horovod_tpu.profiling`).
 """
 
 from __future__ import annotations

@@ -27,27 +27,25 @@ This package owns the two cross-cutting seams:
 
 Also here: the phase vocabulary (:mod:`horovod_tpu.profiling.scopes`:
 the ``jax.named_scope`` names of the train step's parts and the host
-spans of the input path) and the trace helpers ``start_trace`` /
-``stop_trace`` / ``trace`` / ``annotate``.
+spans), the host log (:mod:`horovod_tpu.profiling.host_log`: one bounded
+ring of the host's spans, garbage collections and compiles on
+``time.perf_counter()``) and ``annotate``, the one door for host spans.
 """
 
 from __future__ import annotations
 
-import contextlib
-from typing import Iterator, Optional
-
-import jax
+from typing import Optional
 
 from horovod_tpu.profiling.manager import (ProfileManager, default_manager,
                                            profile_dir)
-from horovod_tpu.profiling import compile_watch, memory, scopes
+from horovod_tpu.profiling import compile_watch, host_log, memory, scopes
 
 __all__ = [
     "ProfileManager", "default_manager", "profile_dir",
-    "compile_watch", "memory", "scopes",
+    "compile_watch", "host_log", "memory", "scopes",
     "on_step_begin", "on_step_end", "on_anomaly",
     "recent_captures", "finalize_open_capture", "reset",
-    "start_trace", "stop_trace", "trace", "annotate",
+    "annotate",
 ]
 
 
@@ -125,30 +123,11 @@ def reset() -> None:
     compile_watch.reset_counts()
 
 
-# -- trace helpers ------------------------------------------------------------
-def start_trace(log_dir: str) -> None:
-    """Begin a device trace viewable in TensorBoard/XProf (the device
-    -side counterpart of ``hvd.start_timeline``).  Prefer
-    :class:`ProfileManager` for bounded, managed captures."""
-    jax.profiler.start_trace(log_dir)
-
-
-def stop_trace() -> None:
-    jax.profiler.stop_trace()
-
-
-@contextlib.contextmanager
-def trace(log_dir: str) -> Iterator[None]:
-    start_trace(log_dir)
-    try:
-        yield
-    finally:
-        stop_trace()
-
-
-def annotate(name: str):
-    """Named range on the profiler's host plane, on the device planes'
-    clock (NVTX-range analog): a ``jax.profiler.TraceAnnotation``, which
-    costs about a microsecond and records nothing while no profiler
-    session is open. Names come from :mod:`.scopes`."""
-    return jax.profiler.TraceAnnotation(name)
+# -- host spans ---------------------------------------------------------------
+def annotate(name: str) -> host_log.Span:
+    """Named range on the host: a ``jax.profiler.TraceAnnotation`` (on the
+    profiler's host plane and the device planes' clock while a session is
+    open; NVTX-range analog) that also leaves ``(name, start, duration)``
+    on ``time.perf_counter()`` in the host log, session or none. Under a
+    microsecond. Names come from :mod:`.scopes`."""
+    return host_log.Span(name)

@@ -36,7 +36,9 @@ Sources:
   only and a part of ``seconds_total``), ``persistent_cache_hits`` and
   ``persistent_cache_misses`` (``/jax/compilation_cache/cache_hits`` and
   ``cache_misses``; JAX counts a miss when it *writes* the entry, so a
-  compile too quick or too small to be kept is neither);
+  compile too quick or too small to be kept is neither). Each timed
+  event is also a ``scopes.HOST_COMPILE`` record of the host log
+  (:mod:`.host_log`): *when* it happened, not only for how long;
 * the ``jax_log_compiles`` log line ("Compiling jit(<name>) with global
   shapes...") names the function being compiled — jax's monitoring
   events carry no name, so the log record is the attribution channel.
@@ -54,7 +56,10 @@ from __future__ import annotations
 import logging
 import re
 import threading
+import time
 from typing import Dict, Optional
+
+from horovod_tpu.profiling import host_log, scopes
 
 MAX_FUNCTION_LABELS = 32
 DEFAULT_RECOMPILE_WARMUP = 2
@@ -88,11 +93,20 @@ _registry = None
 _listener_registered = False
 
 _BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-# jax's other compile events -> the key of totals() each adds to
-_DURATION_TOTALS = {
-    "/jax/core/compile/jaxpr_trace_duration": "trace_seconds",
-    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_seconds",
-    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_read_seconds",
+# jax's timed compile events -> (the word for its kind in the host log, the
+# key of totals() its seconds add to). Each leaves a ``scopes.HOST_COMPILE``
+# record in the host log when it ends: its interval, its kind and the
+# function's name (JAX 0.9 passes ``fun_name`` to the duration listener for
+# the three it times in ``dispatch.log_elapsed_time``; a cache read carries
+# none). A persistent-cache *write* has no event of its own: it lies inside
+# the backend compile (``compile_or_get_cached``) and cannot be told from it
+_DURATION_EVENTS = {
+    _BACKEND_COMPILE_EVENT: ("backend_compile", "seconds_total"),
+    "/jax/core/compile/jaxpr_trace_duration": ("trace", "trace_seconds"),
+    "/jax/core/compile/jaxpr_to_mlir_module_duration":
+        ("lower", "lower_seconds"),
+    "/jax/compilation_cache/cache_retrieval_time_sec":
+        ("cache_read", "cache_read_seconds"),
 }
 _EVENT_TOTALS = {
     "/jax/compilation_cache/cache_hits": "persistent_cache_hits",
@@ -223,14 +237,19 @@ def ensure_installed(registry=None) -> bool:
         import jax
         import jax.monitoring
 
-        def _dur_listener(event: str, duration: float, **_kw) -> None:
-            if not _installed:
+        def _dur_listener(event: str, duration: float, **kw) -> None:
+            if not _installed or event not in _DURATION_EVENTS:
                 return
+            kind, key = _DURATION_EVENTS[event]
+            host_log.record(
+                scopes.HOST_COMPILE, time.perf_counter() - duration,
+                float(duration),
+                {"event": kind, "function": kw.get("fun_name")})
             if event == _BACKEND_COMPILE_EVENT:
                 _on_backend_compile(duration)
-            elif event in _DURATION_TOTALS:
+            else:
                 with _LOCK:
-                    _totals[_DURATION_TOTALS[event]] += float(duration)
+                    _totals[key] += float(duration)
 
         def _event_listener(event: str, **_kw) -> None:
             if _installed and event in _EVENT_TOTALS:

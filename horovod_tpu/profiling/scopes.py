@@ -17,7 +17,9 @@ optimizer update carries plain ``hvd.optimizer``. Any ``jax.profiler``
 ``benchmarks/chip/scope_reduce.py`` reads device time per phase and
 direction from them.
 
-Host spans go through :func:`horovod_tpu.profiling.annotate`.
+Host spans go through :func:`horovod_tpu.profiling.annotate`, which also
+keeps them in the host log (``profiling/host_log.py``) outside a profiler
+session.
 
 A model that needs another phase adds it here, and
 nowhere else: ``tests/test_scopes.py`` holds the strings to this file.
@@ -86,4 +88,12 @@ INPUT_SOURCE = "hvd.input.source"
 #: ``jax.device_put`` of the batch onto its sharding
 INPUT_PLACE = "hvd.input.place"
 
-HOST_SPANS = (INPUT_SOURCE, INPUT_PLACE)
+#: one garbage collection of the interpreter (``profiling/host_log.py``'s
+#: ``gc.callbacks`` entry): the host runs no Python while it lasts
+HOST_GC = "hvd.host.gc"
+#: one trace, lowering, backend compile or persistent-cache read that JAX
+#: timed (``profiling/compile_watch.py``'s listener); a record of the host
+#: log only, written when it ends
+HOST_COMPILE = "hvd.host.compile"
+
+HOST_SPANS = (INPUT_SOURCE, INPUT_PLACE, HOST_GC, HOST_COMPILE)

@@ -11,10 +11,17 @@ cannot go stale:
   ``compile_watch.totals``) must resolve, and accept the positional count
   and the keywords of every call site (``scan_steps=1``, ``buffer_size=``);
 * every kernel name, phase and host span that a metric file
-  (``layer_metrics/``, ``phase_metrics/``) or ``scope_reduce.py`` looks for
-  must be a name the product gives: a kernel's ``name=`` / ``*_NAME`` in
+  (``layer_metrics/``), a reader (``readers/``) or ``scope_reduce.py`` looks
+  for must be a name the product gives: a kernel's ``name=`` / ``*_NAME`` in
   ``horovod_tpu/ops`` or ``parallel/moe.py``, a constant of
-  ``profiling/scopes.py``.
+  ``profiling/scopes.py``;
+* the door between ``BENCHMARK.json``'s ``per_layer`` entries, the files of
+  ``layer_metrics/`` and ``run.read_layer_metric``: every entry has its
+  file, file and entry agree, every ``read`` dispatches (nothing recorded,
+  nothing read: None, never a raise), a ``reader`` names a file under
+  ``readers/`` whose imports from the product resolve like ``run.py``'s.
+  The cases are the benchmark's own (``benchmarks/chip/tests/
+  test_layer_metrics.py``), imported here so that tier-1 runs them.
 
 * what an adapter reads by a string the walk cannot see: the ``ouro``
   adapter's leaves of ``init_params``' tree and the keys of the looped
@@ -36,7 +43,8 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CHIP = os.path.join(REPO, "benchmarks", "chip")
 CLIENT_FILES = [os.path.join(CHIP, "run.py")] + sorted(
-    glob.glob(os.path.join(CHIP, "adapters", "*.py")))
+    glob.glob(os.path.join(CHIP, "adapters", "*.py"))
+    + glob.glob(os.path.join(CHIP, "readers", "*.py")))
 KERNEL_FILES = [os.path.join(REPO, "horovod_tpu", *p) for p in (
     ("ops", "pallas_attention.py"), ("ops", "pallas_xent.py"),
     ("parallel", "moe.py"))]
@@ -128,7 +136,8 @@ def _names_looked_for():
     """[(kind, name, where)]: kernel names, phases and host spans the
     benchmark's metric files and ``scope_reduce.py`` look for."""
     found = {}
-    for path in sorted(glob.glob(os.path.join(CHIP, "*_metrics", "*.json"))):
+    for path in sorted(glob.glob(os.path.join(CHIP, "layer_metrics",
+                                              "*.json"))):
         with open(path) as f:
             read = json.load(f)["read"]
         rel = os.path.relpath(path, CHIP)
@@ -144,6 +153,12 @@ def _names_looked_for():
         if isinstance(node, ast.Constant) and isinstance(node.value, str) \
                 and re.fullmatch(r"hvd(\.[a-z_]+)+", node.value):
             found.setdefault(("phase", node.value), "scope_reduce.py")
+    for path in sorted(glob.glob(os.path.join(CHIP, "readers", "*.py"))):
+        for node in ast.walk(_parse(path)):
+            if isinstance(node, ast.Constant) and isinstance(node.value, str) \
+                    and re.fullmatch(r"hvd(\.[a-z_]+)+", node.value):
+                found.setdefault(("span", node.value),
+                                 os.path.relpath(path, CHIP))
     for name in BLOCK_KERNELS:
         found.setdefault(("kernel", name), "PERF.md §3")
     return [(kind, name, where) for (kind, name), where in sorted(
@@ -183,6 +198,44 @@ def test_what_the_benchmark_looks_for_is_what_the_program_says(
     assert name in said, (
         f"benchmarks/chip/{where} looks for the {kind} {name!r}; the "
         f"product has {sorted(said)}")
+
+
+# -- the door: per_layer entries, their files, the harness's dispatch ---------
+# The benchmark's own cases (benchmarks/chip/tests/test_layer_metrics.py, which
+# tier-1 does not collect), taken by import and not restated: one case a
+# metric for "file and entry agree" and one for "its read dispatches".
+
+def _benchmarks_own(module):
+    import importlib.util
+    import sys
+    for path in (REPO, CHIP):            # as benchmarks/chip/tests/conftest.py
+        if path not in sys.path:
+            sys.path.insert(0, path)
+    spec = importlib.util.spec_from_file_location(
+        "chip_" + module, os.path.join(CHIP, "tests", module + ".py"))
+    loaded = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(loaded)
+    return loaded
+
+
+_DOOR = _benchmarks_own("test_layer_metrics")
+test_every_entry_has_its_file_and_every_file_its_entry = \
+    _DOOR.test_every_entry_has_its_file_and_every_file_its_entry
+test_metric_file_and_entry_agree = _DOOR.test_file_and_entry_agree
+test_every_read_is_a_kind_the_harness_dispatches = \
+    _DOOR.test_every_read_is_a_kind_the_harness_dispatches
+test_an_unknown_kind_without_a_reader_raises = \
+    _DOOR.test_an_unknown_kind_without_a_reader_raises
+
+
+@pytest.mark.parametrize("path", sorted(
+    glob.glob(os.path.join(CHIP, "readers", "*.py"))), ids=os.path.basename)
+def test_a_reader_is_a_file_with_a_read_that_some_metric_names(path):
+    assert any(isinstance(n, ast.FunctionDef) and n.name == "read"
+               for n in _parse(path).body), path
+    name = os.path.basename(path)[:-len(".py")]
+    assert any(_DOOR._spec(m)["read"].get("reader") == name
+               for m in _DOOR.FILES), f"no metric reads readers/{name}.py"
 
 
 def test_the_looped_step_gives_what_the_ouro_adapter_reads():

@@ -25,7 +25,9 @@ PACKAGE = os.path.join(os.path.dirname(os.path.dirname(
 
 def test_the_vocabulary_is_the_only_place_the_strings_are_written():
     names = scopes.DEVICE_PHASES + scopes.HOST_SPANS
-    assert len(set(names)) == len(names) == 19
+    assert len(set(names)) == len(names) == 21
+    assert scopes.HOST_SPANS == ("hvd.input.source", "hvd.input.place",
+                                 "hvd.host.gc", "hvd.host.compile")
     assert all(n.startswith("hvd.") for n in names)
     home = os.path.join(PACKAGE, "profiling", "scopes.py")
     elsewhere = []
@@ -333,8 +335,9 @@ def test_annotate_is_a_trace_annotation():
     from horovod_tpu import profiling
     span = profiling.annotate(scopes.INPUT_PLACE)
     assert isinstance(span, jax.profiler.TraceAnnotation)
-    with span:      # no profiler session: records nothing, must not raise
+    with span:      # no profiler session: the host log alone keeps it
         pass
+    assert profiling.host_log.records()[-1][0] == scopes.INPUT_PLACE
     assert not hasattr(profiling, "annotate_fn")
 
 
