@@ -15,9 +15,13 @@ Dataflow per shard (G local tokens, E experts, k choices a token):
   rows     = x[order // k]                                       [G*k, M]
   rows     = expert_fn(expert_params, rows, group_sizes)   grouped matmuls
              (on a TPU the megablox Pallas kernels, named ``hvd_moe_gmm``)
-  y        = sum_k weights * rows[inverse(order)]                [G, M]
+  y        = sum_j weights[:, j] * rows[inverse(order)[j::k]]    [G, M]
 Both row movements are gathers in both directions (the permutation one
-way, its inverse the other), so no scatter-add is traced.
+way, its inverse the other), so no scatter-add is traced; every row-sized
+array is 2-D in sorted order (``[G * k, M]``, the rows no expert here
+computes a contiguous tail) or k token-major slabs end to end
+(``[k, G, M]``), never ``[G, k, M]``; both backward passes are written by
+hand (:func:`_dispatch`, :func:`_combine`).
 
 With ``ep`` > 1 the experts are sharded and the tokens of the ep group are
 exchanged the simplest static-shape way: every shard gathers the group's
@@ -300,42 +304,86 @@ def _dispatch(x, order, inverse, k):
 
 
 def _dispatch_fwd(x, order, inverse, k):
-    return x[order // k], (inverse, x.shape[0])
+    return x[order // k], inverse
 
 
-def _dispatch_bwd(k, res, g):
-    inverse, n_tokens = res
-    return (g[inverse].reshape(n_tokens, k, -1).astype(jnp.float32).sum(axis=1)
-            .astype(g.dtype),
-            None, None)
+def _by_choice(rows, inverse, k):
+    """The sorted rows ``[T * k, M]`` back at their tokens, ``[k, T, M]``:
+    slab j holds every token's j-th row. With k the leading axis the split
+    of ``[k * T, M]`` is free and a sum over it is one fused pass; beside
+    ``M`` a k under the sublane tile (6 of 8 float32 rows) would make
+    ``[T, k, M]`` a padded copy (PERF.md section 6, PR 36)."""
+    return rows[inverse.reshape(-1, k).T]
+
+
+def _dispatch_bwd(k, inverse, g):
+    total = jnp.sum(_by_choice(g, inverse, k).astype(jnp.float32), axis=0)
+    return total.astype(g.dtype), None, None
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
-@jax.custom_vjp
-def _permute(rows, index, back):
-    """``rows[index]`` where ``back`` is the inverse permutation of
-    ``index``: the cotangent is a gather too."""
-    del back
-    return rows[index]
+def _permuted(scalars, to):
+    """``scalars[i]`` at place ``to[i]``, which is ``scalars[back]`` for
+    ``to``'s inverse permutation ``back``, as a sort by ``to``: a gather of
+    single float32 elements is the slow way on a TPU (49 152 of them 0.42
+    ms, the sort 0.06: PERF.md section 6, PR 36)."""
+    return lax.sort((to, scalars), num_keys=1)[1]
 
 
-def _permute_fwd(rows, index, back):
-    return rows[index], back
+def _products(rows, by):
+    """The combine's arithmetic, forward and backward: float32 products of
+    rows ``[R, M]`` in the compute dtype with float32 weights ``[R, 1]``
+    (summed over a token's k rows: the output; alone: a row's cotangent) or
+    with other rows ``[R, M]`` (summed over M: a weight's cotangent)."""
+    return rows.astype(jnp.float32) * by.astype(jnp.float32)
 
 
-def _permute_bwd(back, g):
-    return g[back], None, None
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _combine(rows, weights, order, inverse, axis_name, dtype):
+    """``y[t] = sum_j weights[t, j] * rows[inverse[t * k + j]]`` in
+    ``dtype``: the :func:`_products` of the sorted rows :func:`_by_choice`
+    with their weights, summed over the choices in float32; with a live
+    ``axis_name`` each home shard then sums, in float32, what the expert
+    shards computed for it. Autodiff's cotangent of this is a float32
+    broadcast to every row; the one written here goes to sorted order in
+    the compute dtype and through one pass. The cast to ``dtype`` is taken
+    inside so that the cotangent arrives in ``dtype`` and is gathered (and
+    with ``axis_name`` all-gathered) at that width."""
+    del order
+    k = weights.shape[1]
+    # the barrier keeps the float32 cast behind the split of the major
+    # dimension: XLA:TPU else hoists it and leaves it outside the sum's
+    # fusion, a float32 [k * T, M] array (tests/test_tpu_compile.py)
+    by_choice = lax.optimization_barrier(_by_choice(rows, inverse, k))
+    y = jnp.sum(_products(by_choice, weights.T[:, :, None]), axis=0)
+    if axis_name:
+        y = lax.psum_scatter(y, axis_name, scatter_dimension=0, tiled=True)
+    return y.astype(dtype)
 
 
-_permute.defvjp(_permute_fwd, _permute_bwd)
+def _combine_fwd(rows, weights, order, inverse, axis_name, dtype):
+    return (_combine(rows, weights, order, inverse, axis_name, dtype),
+            (rows, weights, order, inverse))
 
 
-def _weighted_sum(rows, weights):
-    """``sum_k weights[t, k] * rows[t, k]`` in float32: rows ``[T, k, M]``
-    in the compute dtype, weights ``[T, k]`` float32."""
-    return jnp.sum(rows.astype(jnp.float32) * weights[..., None], axis=1)
+def _combine_bwd(axis_name, dtype, res, g):
+    rows, weights, order, inverse = res
+    k = weights.shape[1]
+    if axis_name:
+        g = lax.all_gather(g, axis_name, axis=0, tiled=True)
+    # sorted row r is of token order[r] // k, under weight order[r]
+    g_sorted = g[order // k]
+    w_sorted = _permuted(weights.reshape(-1), inverse)
+    d_rows = _products(g_sorted, w_sorted[:, None])
+    row_dots = jnp.sum(_products(g_sorted, rows), axis=1)
+    d_weights = _permuted(row_dots, order).reshape(weights.shape)
+    return (d_rows.astype(rows.dtype), d_weights.astype(weights.dtype),
+            None, None)
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 def moe_layer_spmd(x: jax.Array, router_w: jax.Array,
@@ -418,18 +466,14 @@ def moe_layer_spmd(x: jax.Array, router_w: jax.Array,
     rows = gathered(x_all, expert_params)
 
     with jax.named_scope(scopes.MOE_COMBINE):
-        rows = _permute(rows, inverse, order).reshape(n * G, k, M)
         if n > 1:
             weights_all = lax.all_gather(weights, axis_name, axis=0,
                                          tiled=True)
         else:
             weights_all = weights
-        y = _weighted_sum(rows, weights_all)
-        if n > 1:
-            # each home shard sums what the expert shards computed for it
-            y = lax.psum_scatter(y, axis_name, scatter_dimension=0,
-                                 tiled=True)
-        y = y.astype(x.dtype)
+        # with ep > 1 each home shard sums what the expert shards computed
+        y = _combine(rows, weights_all, order, inverse,
+                     axis_name if n > 1 else None, x.dtype)
 
     with jax.named_scope(scopes.MOE_ROUTER):
         def total(v):

@@ -188,10 +188,8 @@ def _bf16_weights(logits, k, renormalize, route=moe.route):
     return probs, weights.astype(jnp.bfloat16).astype(jnp.float32), experts
 
 
-def _bf16_sum(rows, weights):
-    return jnp.sum(rows.astype(jnp.bfloat16)
-                   * weights[..., None].astype(jnp.bfloat16), axis=1
-                   ).astype(jnp.float32)
+def _bf16_products(rows, by):
+    return rows.astype(jnp.bfloat16) * by.astype(jnp.bfloat16)
 
 
 def _drop_one(rows, weights, group_sizes, interpret=False,
@@ -202,7 +200,7 @@ def _drop_one(rows, weights, group_sizes, interpret=False,
 @pytest.mark.parametrize("what, where, wrong", [
     ("router softmax in bfloat16", (moe, "route"), _bf16_softmax),
     ("top-k weights in bfloat16", (moe, "route"), _bf16_weights),
-    ("combine in bfloat16", (moe, "_weighted_sum"), _bf16_sum),
+    ("combine in bfloat16", (moe, "_products"), _bf16_products),
     ("a dropped assignment", (t, "grouped_matmul"), _drop_one),
 ])
 def test_a_wrong_term_fails(monkeypatch, what, where, wrong):

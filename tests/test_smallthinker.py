@@ -347,10 +347,8 @@ def _bf16_softmax(logits, k, renormalize):
     return probs, weights / jnp.sum(weights, -1, keepdims=True), experts
 
 
-def _bf16_sum(rows, weights):
-    return jnp.sum(rows.astype(jnp.bfloat16)
-                   * weights[..., None].astype(jnp.bfloat16), axis=1
-                   ).astype(jnp.float32)
+def _bf16_products(rows, by):
+    return rows.astype(jnp.bfloat16) * by.astype(jnp.bfloat16)
 
 
 @pytest.mark.parametrize("what, change", [
@@ -361,7 +359,7 @@ def _bf16_sum(rows, weights):
         (None, True), (32, True), (32, True), (32, True))}}),
     ("top-k weights not renormalised", {"cfg": {"moe_renormalize": False}}),
     ("router softmax in bfloat16", {"patch": (moe, "route", _bf16_softmax)}),
-    ("combine in bfloat16", {"patch": (moe, "_weighted_sum", _bf16_sum)}),
+    ("combine in bfloat16", {"patch": (moe, "_products", _bf16_products)}),
 ])
 def test_a_wrong_term_fails(monkeypatch, what, change):
     """What TOL must not let through: each moves the last router's

@@ -12,6 +12,7 @@ contract that ``chip_smoke.py`` fails without a chip.
 """
 
 import os
+import re
 import subprocess
 import sys
 
@@ -370,6 +371,22 @@ def test_looped_step_compiles_for_v5e_with_both_kernels(
     assert step_bytes(compiled.memory_analysis())["total"] < 15.0e9
 
 
+def _arrays_in_memory(text):
+    """The lines of a compiled program's text outside its fused
+    computations: each is an instruction whose result is an array in
+    memory. Inside a fusion's body the same shapes are values the fusion
+    holds a tile of at a time."""
+    fused = set(re.findall(r"fusion\(.*calls=%([\w.\-]+)", text))
+    lines, inside = [], False
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\{$", line)
+        if head:
+            inside = head.group(1) in fused
+        elif not inside:
+            lines.append(line)
+    return "\n".join(lines)
+
+
 def test_mixed_step_compiles_for_v5e_on_the_kernels(
         topo, no_compile_cache, monkeypatch):
     """The cell smallthinker-21b-a3b.s8192's step, one period of a full and
@@ -378,7 +395,10 @@ def test_mixed_step_compiles_for_v5e_on_the_kernels(
     call site a layer of the unrolled period, forward and backward; no
     score-shaped array in the program), the experts are ``hvd_moe_gmm``,
     the head ``hvd_fused_xent``; both layer kinds' scopes are in the
-    program; the step fits with the room ISSUE 32 asks for."""
+    program; the step fits with the room ISSUE 32 asks for. The expert
+    layer's rows have no top-6 axis (``[8192, 6, 2560]`` is a copy padded
+    to the tile's 8 or 16 sublanes) and none is an array in float32
+    (ISSUE 36)."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     step, args, shapes, step_bytes = _cell_step(
         "smallthinker-21b-a3b.s8192", topo)
@@ -393,6 +413,10 @@ def test_mixed_step_compiles_for_v5e_on_the_kernels(
     assert sum("hvd_fused_xent" in c for c in calls) == 1
     s = shapes["seq"]
     assert f",{s},{s}]" not in text, "a score-shaped array"
+    k, m = shapes["experts_per_token"], shapes["d_model"]
+    assert f"[{s},{k},{m}]" not in text, "the rows with a top-k axis"
+    assert f"f32[{s * k},{m}]" not in _arrays_in_memory(text), \
+        "the rows in float32"
     from horovod_tpu.profiling import scopes
     names = "\n".join(line for line in text.splitlines()
                       if "op_name=" in line)
