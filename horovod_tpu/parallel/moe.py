@@ -23,11 +23,22 @@ computes a contiguous tail) or k token-major slabs end to end
 (``[k, G, M]``), never ``[G, k, M]``; both backward passes are written by
 hand (:func:`_dispatch`, :func:`_combine`).
 
+Of the ``G * k`` sorted rows only the first ``held = sum(group_sizes)`` are
+an expert's here (all of them, unless the experts are sharded or the device
+holds a ``share``), and nothing reads a sorted row behind them: the grouped
+matmuls visit their groups' tiles only and leave the rest unwritten; the
+combine's backward pass runs over the row chunks that hold a held row
+(:func:`_over_held_chunks`, a loop with a device trip count; one pass and
+no loop where every row is held); and what the token-major gathers
+(:func:`_by_choice`) fetch from there is replaced by zeros. Those gathers
+read the held rows out of a prefix of the sorted rows that XLA:TPU gathers
+from at its fast rate, where the held rows lie within it.
+
 With ``ep`` > 1 the experts are sharded and the tokens of the ep group are
 exchanged the simplest static-shape way: every shard gathers the group's
 tokens and routing (``all_gather``), computes the rows of its own experts
-(sorted to the front; :func:`grouped_matmul` gives zeros for the rows behind
-them, which are the other shards' to compute) and the weighted partial sums
+(sorted to the front; the rows behind them are the other shards' to compute,
+and count as zeros in this shard's sums) and the weighted partial sums
 return to their home shards by ``psum_scatter``.
 
 A device can also be told which experts it holds with no ``ep`` axis live
@@ -197,10 +208,9 @@ def _megablox():
 
 
 def _zero_beyond(rows, group_sizes):
-    """Zeros in the rows beyond the groups. Neither implementation gives
-    them by itself: megablox's ``gmm`` never writes those rows, and XLA's
-    ``ragged_dot`` fills them with zeros on the CPU and with products on a
-    TPU (``chip_smoke.py`` reads both; PERF.md section 6, PR 26)."""
+    """Zeros in the rows beyond the groups, which XLA's ``ragged_dot`` fills
+    with zeros on the CPU and with products on a TPU (``chip_smoke.py``
+    reads both; PERF.md section 6, PR 26)."""
     inside = jnp.arange(rows.shape[0]) < jnp.sum(group_sizes)
     return jnp.where(inside[:, None], rows, 0)
 
@@ -213,16 +223,17 @@ def _ragged_dot(rows, weights, group_sizes):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def _gmm(rows, weights, group_sizes, tiles, interpret):
-    """``tiles`` None: XLA's ``ragged_dot``; else the megablox kernels at
-    :class:`GmmTiles`."""
+    """``tiles`` None: XLA's ``ragged_dot``, zeros beyond the groups; else
+    the megablox kernels at :class:`GmmTiles`, which visit the groups' tiles
+    only: a row beyond the groups is never written, forward or backward,
+    and never read into a weight's gradient."""
     if tiles is None:
-        out = _ragged_dot(rows, weights, group_sizes)
-    else:
-        gmm, _tgmm = _megablox()
-        with jax.named_scope(GMM_NAME):
-            out = gmm(rows, weights.astype(rows.dtype), group_sizes,
-                      rows.dtype, tiles.forward, interpret=interpret)
-    return _zero_beyond(out, group_sizes)
+        return _zero_beyond(_ragged_dot(rows, weights, group_sizes),
+                            group_sizes)
+    gmm, _tgmm = _megablox()
+    with jax.named_scope(GMM_NAME):
+        return gmm(rows, weights.astype(rows.dtype), group_sizes,
+                   rows.dtype, tiles.forward, interpret=interpret)
 
 
 def _gmm_fwd(rows, weights, group_sizes, tiles, interpret):
@@ -238,8 +249,9 @@ def _gmm_bwd(tiles, interpret, res, g):
         d_rows, d_weights = jax.vjp(
             lambda r, w: _ragged_dot(r, w, group_sizes), rows, weights)[1](
                 _zero_beyond(g, group_sizes))
+        d_rows = _zero_beyond(d_rows, group_sizes)
     else:
-        gmm, tgmm = _megablox()     # both visit the groups' rows only
+        gmm, tgmm = _megablox()
         with jax.named_scope(GMM_NAME):
             d_rows = gmm(g, weights.astype(rows.dtype), group_sizes,
                          rows.dtype, tiles.input_grad, transpose_rhs=True,
@@ -247,8 +259,7 @@ def _gmm_bwd(tiles, interpret, res, g):
             d_weights = tgmm(rows.swapaxes(0, 1), g, group_sizes,
                              rows.dtype, tiles.weight_grad,
                              interpret=interpret)
-    return (_zero_beyond(d_rows, group_sizes),
-            d_weights.astype(weights.dtype), None)
+    return d_rows, d_weights.astype(weights.dtype), None
 
 
 _gmm.defvjp(_gmm_fwd, _gmm_bwd)
@@ -260,9 +271,12 @@ def grouped_matmul(rows: jax.Array, weights: jax.Array,
     """``rows[start_g:end_g] @ weights[g]`` for each group g of consecutive
     rows. rows ``[N, K]``, weights ``[E, K, F]`` (cast to ``rows.dtype``),
     group_sizes ``[E]`` int32; float32 accumulation, the result in
-    ``rows.dtype``. Rows beyond ``sum(group_sizes)`` belong to no group:
-    the result is zero there, so is their gradient, and they add nothing
-    to the weights' gradient.
+    ``rows.dtype``. Rows beyond ``sum(group_sizes)`` belong to no group
+    and add nothing to the weights' gradient. What the result and the rows'
+    gradient hold there is for no one to read: zeros from ``ragged_dot``,
+    whatever the buffer held from the kernels, which never write them (a
+    select over every row to zero a tail the expert layer does not read
+    was 7.5 ms a step of the share cell: PERF.md section 6, PR 37).
 
     On a TPU (and under ``interpret``) the megablox Pallas kernels of the
     installed JAX, kept over XLA's ``ragged_dot`` kernels by the sweeps in
@@ -290,35 +304,100 @@ def route(logits: jax.Array, k: int, renormalize: bool
     return probs, weights, experts
 
 
+#: the most rows one pass of the sorted-order loop takes. The last chunk of
+#: the held rows is half dead on average, so a chunk is a small part of a
+#: share's rows; a loop step costs microseconds, so it is no smaller (1024
+#: and 4096 read within 0.2 % of it: PERF.md section 6, PR 37)
+ROW_CHUNK = 2048
+#: the most bytes of a row gather's source that XLA:TPU is known to copy
+#: into on-chip memory and gather from there: 49 152 rows out of a source
+#: of 94 MB take 0.50 ms on a v5e, out of one of 126 MB 2.5 (PERF.md
+#: section 6, PR 37)
+GATHER_SOURCE_BYTES = 90 * 2 ** 20
+
+
+def _row_chunk(n_rows: int) -> int:
+    """Rows a pass of the sorted-order loop: the largest divisor of
+    ``n_rows`` up to :data:`ROW_CHUNK`, so that every chunk is whole; rows
+    with no divisor within an eighth of that go in one piece."""
+    chunk = next(c for c in range(min(ROW_CHUNK, n_rows), 0, -1)
+                 if n_rows % c == 0)
+    return chunk if chunk * 8 >= min(ROW_CHUNK, n_rows) else n_rows
+
+
+def _over_held_chunks(held, n_rows: int, body, init):
+    """``body(start, chunk, carry) -> carry`` over the row chunks ``[start,
+    start + chunk)`` of the ``n_rows`` sorted rows that hold a row below
+    ``held``: a device scalar, the loop's trip count ``ceil(held / chunk)``,
+    or None where every row is held, which is one pass over them all and
+    no loop. The last chunk's rows from ``held`` on are nobody's: a body
+    may leave anything there that nothing reads."""
+    if held is None:
+        return body(0, n_rows, init)
+    chunk = _row_chunk(n_rows)
+    return lax.fori_loop(0, (held + chunk - 1) // chunk,
+                         lambda i, carry: body(i * chunk, chunk, carry), init)
+
+
 def _all_but_gathers(prim, *_, **__) -> bool:
     """Checkpoint policy of a held share's experts: every value is kept
     for the backward but what a gather moved (:func:`_dispatch`'s rows)."""
     return prim is not lax.gather_p
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _dispatch(x, order, inverse, k):
-    """Row r of the result is the token of sorted assignment r."""
-    del inverse
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _dispatch(x, order, inverse, held, k):
+    """Row r of the result is the token of sorted assignment r: all
+    ``T * k`` of them, one gather out of the tokens, which costs less than
+    a loop over the held rows and the array it would start from (PERF.md
+    section 6, PR 37)."""
+    del inverse, held
     return x[order // k]
 
 
-def _dispatch_fwd(x, order, inverse, k):
-    return x[order // k], inverse
+def _dispatch_fwd(x, order, inverse, held, k):
+    return x[order // k], (inverse, held)
 
 
-def _by_choice(rows, inverse, k):
+def _by_choice(rows, inverse, held, k, pin=False):
     """The sorted rows ``[T * k, M]`` back at their tokens, ``[k, T, M]``:
-    slab j holds every token's j-th row. With k the leading axis the split
-    of ``[k * T, M]`` is free and a sum over it is one fused pass; beside
-    ``M`` a k under the sublane tile (6 of 8 float32 rows) would make
-    ``[T, k, M]`` a padded copy (PERF.md section 6, PR 36)."""
-    return rows[inverse.reshape(-1, k).T]
+    slab j holds every token's j-th row, zeros where that row is none of
+    the ``held`` (None: every row is), so that what the sorted rows hold
+    behind those reaches no result. With k the leading axis the split of
+    ``[k * T, M]`` is free and a sum over it is one fused pass; beside ``M``
+    a k under the sublane tile (6 of 8 float32 rows) would make ``[T, k,
+    M]`` a padded copy (PERF.md section 6, PR 36).
+
+    What the gather costs is set by its source: one of up to
+    :data:`GATHER_SOURCE_BYTES` XLA:TPU brings on the chip first and
+    gathers from at five times the rate. The held rows are the first of
+    the sorted ones, so where they lie within the largest such prefix the
+    gather is out of that prefix alone. On a TPU the gather is a pass of
+    its own and takes nothing in: the select that makes the zeros comes
+    after it (and after the barrier, where the caller asks to ``pin`` the
+    gathered slabs), for the pass that reads the slabs to take into its
+    own fusion."""
+    at = inverse.reshape(-1, k).T
+    prefix = GATHER_SOURCE_BYTES // (rows.shape[1] * rows.dtype.itemsize)
+    prefix -= prefix % 8        # whole sublane tiles
+    if held is None or not 0 < prefix < rows.shape[0]:
+        slabs = rows[at]
+    else:
+        slabs = lax.cond(held <= prefix,
+                         lambda: rows[:prefix][jnp.minimum(at, prefix - 1)],
+                         lambda: rows[at])
+    if pin:
+        slabs = lax.optimization_barrier(slabs)
+    if held is None:
+        return slabs
+    return jnp.where((at < held)[:, :, None], slabs, 0)
 
 
-def _dispatch_bwd(k, inverse, g):
-    total = jnp.sum(_by_choice(g, inverse, k).astype(jnp.float32), axis=0)
-    return total.astype(g.dtype), None, None
+def _dispatch_bwd(k, res, g):
+    inverse, held = res
+    total = jnp.sum(_by_choice(g, inverse, held, k).astype(jnp.float32),
+                    axis=0)
+    return total.astype(g.dtype), None, None, None
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
@@ -340,47 +419,65 @@ def _products(rows, by):
     return rows.astype(jnp.float32) * by.astype(jnp.float32)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def _combine(rows, weights, order, inverse, axis_name, dtype):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _combine(rows, weights, order, inverse, held, axis_name, dtype):
     """``y[t] = sum_j weights[t, j] * rows[inverse[t * k + j]]`` in
-    ``dtype``: the :func:`_products` of the sorted rows :func:`_by_choice`
-    with their weights, summed over the choices in float32; with a live
-    ``axis_name`` each home shard then sums, in float32, what the expert
-    shards computed for it. Autodiff's cotangent of this is a float32
-    broadcast to every row; the one written here goes to sorted order in
-    the compute dtype and through one pass. The cast to ``dtype`` is taken
-    inside so that the cotangent arrives in ``dtype`` and is gathered (and
-    with ``axis_name`` all-gathered) at that width."""
+    ``dtype`` over the choices whose row is one of the ``held`` (None: over
+    every choice): the :func:`_products` of the sorted rows
+    :func:`_by_choice` with their weights, summed over the choices in
+    float32; with a live ``axis_name`` each home shard then sums, in
+    float32, what the expert shards computed for it. Autodiff's cotangent
+    of this is a float32 broadcast to every row; the one written here goes
+    to sorted order in the compute dtype, through one pass, over the held
+    rows' chunks only. The cast to ``dtype`` is taken inside so that the
+    cotangent arrives in ``dtype`` and is gathered (and with ``axis_name``
+    all-gathered) at that width."""
     del order
     k = weights.shape[1]
     # the barrier keeps the float32 cast behind the split of the major
     # dimension: XLA:TPU else hoists it and leaves it outside the sum's
     # fusion, a float32 [k * T, M] array (tests/test_tpu_compile.py)
-    by_choice = lax.optimization_barrier(_by_choice(rows, inverse, k))
+    by_choice = _by_choice(rows, inverse, held, k, pin=True)
     y = jnp.sum(_products(by_choice, weights.T[:, :, None]), axis=0)
     if axis_name:
         y = lax.psum_scatter(y, axis_name, scatter_dimension=0, tiled=True)
     return y.astype(dtype)
 
 
-def _combine_fwd(rows, weights, order, inverse, axis_name, dtype):
-    return (_combine(rows, weights, order, inverse, axis_name, dtype),
-            (rows, weights, order, inverse))
+def _combine_fwd(rows, weights, order, inverse, held, axis_name, dtype):
+    return (_combine(rows, weights, order, inverse, held, axis_name, dtype),
+            (rows, weights, order, inverse, held))
 
 
 def _combine_bwd(axis_name, dtype, res, g):
-    rows, weights, order, inverse = res
+    rows, weights, order, inverse, held = res
     k = weights.shape[1]
     if axis_name:
         g = lax.all_gather(g, axis_name, axis=0, tiled=True)
     # sorted row r is of token order[r] // k, under weight order[r]
-    g_sorted = g[order // k]
+    source = order // k
     w_sorted = _permuted(weights.reshape(-1), inverse)
-    d_rows = _products(g_sorted, w_sorted[:, None])
-    row_dots = jnp.sum(_products(g_sorted, rows), axis=1)
+    n_rows, width = rows.shape
+
+    def cotangents(start, chunk, carry):
+        # the rows' cotangent is written over the rows, a chunk at a time:
+        # nothing reads them after their dots with it
+        d_rows, row_dots = carry
+        g_sorted = g[lax.dynamic_slice(source, (start,), (chunk,))]
+        d = _products(g_sorted, lax.dynamic_slice(
+            w_sorted, (start,), (chunk,))[:, None]).astype(rows.dtype)
+        dots = jnp.sum(_products(g_sorted, lax.dynamic_slice(
+            d_rows, (start, 0), (chunk, width))), axis=1)
+        return (lax.dynamic_update_slice(d_rows, d, (start, 0)),
+                lax.dynamic_update_slice(
+                    row_dots, dots.astype(row_dots.dtype), (start,)))
+    d_rows, row_dots = _over_held_chunks(
+        held, n_rows, cotangents, (rows, jnp.zeros(n_rows, jnp.float32)))
+    if held is not None:
+        # a choice whose row is not held gets no gradient from here
+        row_dots = jnp.where(jnp.arange(n_rows) < held, row_dots, 0)
     d_weights = _permuted(row_dots, order).reshape(weights.shape)
-    return (d_rows.astype(rows.dtype), d_weights.astype(weights.dtype),
-            None, None)
+    return d_rows, d_weights.astype(weights.dtype), None, None, None
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
@@ -401,8 +498,8 @@ def moe_layer_spmd(x: jax.Array, router_w: jax.Array,
     ``expert_fn(expert_params, rows [N, M], group_sizes [E_local]) ->
     [N, M]``: rows sorted by local expert, group g the next
     ``group_sizes[g]`` of them; the rows beyond the groups (with ``ep`` > 1,
-    the other shards' to compute) must come back zero, as
-    :func:`grouped_matmul` leaves them. ``stat_axes``: the mesh axes the
+    the other shards' to compute) are not for this device, and what comes
+    back in their place is not read. ``stat_axes``: the mesh axes the
     tokens are sharded over, so that the metrics are those of the global
     batch and the same on every layout. ``logits``: the router's float32
     logits ``[G, E]`` where the caller computed them from something other
@@ -451,10 +548,13 @@ def moe_layer_spmd(x: jax.Array, router_w: jax.Array,
         group_sizes = jnp.sum(
             local[:, None] == jnp.arange(e_local, dtype=local.dtype),
             axis=0, dtype=jnp.int32)
+        # the sorted rows that are an expert's here are the first ``held``;
+        # None where that is every row, which needs no device scalar
+        held = jnp.sum(group_sizes) if n * of > 1 else None
 
     def gathered(x_all, expert_params):
         with jax.named_scope(scopes.MOE_DISPATCH):
-            rows = _dispatch(x_all, order, inverse, k)
+            rows = _dispatch(x_all, order, inverse, held, k)
         with jax.named_scope(scopes.MOE_EXPERTS):
             return expert_fn(expert_params, rows, group_sizes)
     if of > 1:
@@ -472,7 +572,7 @@ def moe_layer_spmd(x: jax.Array, router_w: jax.Array,
         else:
             weights_all = weights
         # with ep > 1 each home shard sums what the expert shards computed
-        y = _combine(rows, weights_all, order, inverse,
+        y = _combine(rows, weights_all, order, inverse, held,
                      axis_name if n > 1 else None, x.dtype)
 
     with jax.named_scope(scopes.MOE_ROUTER):

@@ -11,6 +11,7 @@ Also here: the one compile-cache rule (``utils/compile_cache``) and the
 contract that ``chip_smoke.py`` fails without a chip.
 """
 
+import contextlib
 import os
 import re
 import subprocess
@@ -47,8 +48,8 @@ def v5e(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.fixture
-def no_compile_cache():
+@contextlib.contextmanager
+def _compile_cache_off():
     """A compile for a described chip is written to the persistent cache
     but cannot be read back without the chip (the next one warns), so the
     cache is off around these."""
@@ -56,9 +57,17 @@ def no_compile_cache():
     prev = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", prev)
-    cc.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+        cc.reset_cache()
+
+
+@pytest.fixture
+def no_compile_cache():
+    with _compile_cache_off():
+        yield
 
 
 def _sum32(*xs):
@@ -387,8 +396,21 @@ def _arrays_in_memory(text):
     return "\n".join(lines)
 
 
-def test_mixed_step_compiles_for_v5e_on_the_kernels(
-        topo, no_compile_cache, monkeypatch):
+@pytest.fixture(scope="module")
+def mixed_step(topo):
+    """The cell smallthinker-21b-a3b.s8192's step compiled once for a
+    described v5e (``jax.default_backend`` answering "tpu", the compile
+    cache off), for the cases that read the compiled program: (compiled,
+    the adapter's shapes, step_bytes)."""
+    with _compile_cache_off(), pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        step, args, shapes, step_bytes = _cell_step(
+            "smallthinker-21b-a3b.s8192", topo)
+        compiled = step.lower(*args).compile()
+    return compiled, shapes, step_bytes
+
+
+def test_mixed_step_compiles_for_v5e_on_the_kernels(mixed_step):
     """The cell smallthinker-21b-a3b.s8192's step, one period of a full and
     three window layers at 8192 tokens with 28 / 4 grouped heads and 16 of
     64 experts held: every layer's attention is the two flash kernels (a
@@ -399,10 +421,7 @@ def test_mixed_step_compiles_for_v5e_on_the_kernels(
     layer's rows have no top-6 axis (``[8192, 6, 2560]`` is a copy padded
     to the tile's 8 or 16 sublanes) and none is an array in float32
     (ISSUE 36)."""
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    step, args, shapes, step_bytes = _cell_step(
-        "smallthinker-21b-a3b.s8192", topo)
-    compiled = step.lower(*args).compile()
+    compiled, shapes, step_bytes = mixed_step
     text = compiled.as_text()
     calls = [line for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
@@ -423,6 +442,99 @@ def test_mixed_step_compiles_for_v5e_on_the_kernels(
     for name in scopes.MIXED_PHASES:
         assert name + "/" in names, name
     assert 4.0e9 < step_bytes(compiled.memory_analysis())["total"] < 15.0e9
+
+
+def _computations(text):
+    """{name: its instruction lines} of a compiled program's text."""
+    found, lines = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\{$", line)
+        if head:
+            lines = found.setdefault(head.group(1), [])
+        elif lines is not None and " = " in line:
+            lines.append(line)
+    return found
+
+
+def test_mixed_step_moves_only_the_rows_it_holds(mixed_step):
+    """ISSUE 37, on the same compiled step: of the expert layer's
+    ``T * k`` = 49 152 sorted rows a quarter are an expert's here. What a
+    row gather costs on the chip is set by its source (PERF.md section 6,
+    PR 37), so: a gather out of the whole ``bf16[49152,2560]`` rows stands
+    only in a conditional, beside a branch that gathers out of a prefix of
+    them which XLA has copied on the chip (``S(1)``), two such
+    conditionals a layer (the combine's forward, the dispatch's backward;
+    the parent has those eight gathers unconditional); the other gathers
+    read the ``[8192, 2560]`` tokens. No ``select`` writes a whole rows
+    array (the parent zeroes the kernels' outputs behind the groups under
+    a ``pred[49152]`` mask, five selects a layer), and the combine's
+    backward pass is a loop over the held rows' chunks that writes into the
+    rows in place, one a layer."""
+    compiled, shapes, _step_bytes = mixed_step
+    text = compiled.as_text()
+    layers, width = shapes["layers"], shapes["d_model"]
+    tokens = shapes["seq"]
+    rows = tokens * shapes["experts_per_token"]
+    whole = re.compile(r"bf16\[(%d|%d,%d),%d\]" % (
+        rows, shapes["experts_per_token"], tokens, width))
+    computations = _computations(text)
+    fused = set(re.findall(r"fusion\(.*calls=%([\w.\-]+)", text))
+    loop_bodies = set(re.findall(r"body=%([\w.\-]+)", text))
+    branches = [re.findall(r"%([\w.\-]+)", found) for found in re.findall(
+        r"conditional\(.*branch_computations=\{([^}]*)\}", text)]
+
+    def fusions(name):
+        """(result type, first operand's name, body text) of every fusion
+        that stands in computation ``name``."""
+        for line in computations[name]:
+            found = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (\S+) fusion\((%[\w.\-]+)"
+                             r".*calls=%([\w.\-]+)", line)
+            if found and found.group(3) in computations:
+                yield (found.group(1), found.group(2),
+                       "\n".join(computations[found.group(3)]))
+
+    def writes_whole_rows(name, op):
+        return any(whole.match(result) and f" {op}(" in body
+                   for result, _operand, body in fusions(name))
+
+    def gather_sources(name):
+        """(source rows, whether the source lies on the chip) of every row
+        gather (a fusion with a gather in its body) in computation
+        ``name``."""
+        found = []
+        for _result, operand, body in fusions(name):
+            source = re.search(r"= bf16\[(\d+),%d\]\S* parameter\(0\)" % width,
+                               body)
+            if " gather(" in body and source:
+                made = re.compile(r"\s*(?:ROOT )?%s = (\S+) "
+                                  % re.escape(operand))
+                types = [m.group(1) for m in map(made.match,
+                                                 computations[name]) if m]
+                found.append((int(source.group(1)),
+                              bool(types) and "S(1)" in types[0]))
+        return found
+
+    for name in computations:
+        if name in fused or any(name in pair for pair in branches):
+            continue
+        assert rows not in [s for s, _ in gather_sources(name)], \
+            (name, "a gather out of all the rows")
+        assert not writes_whole_rows(name, "select"), name
+    by_prefix = 0
+    for pair in branches:
+        sources = sorted(s for name in pair for s in gather_sources(name))
+        if sources and sources[-1][0] == rows:
+            prefix, on_chip = sources[0]
+            assert prefix * width * 2 <= moe.GATHER_SOURCE_BYTES \
+                < rows * width * 2
+            assert on_chip, "the prefix is gathered from where it lies"
+            by_prefix += 1
+    assert by_prefix == 2 * layers
+    in_place = [name for name in loop_bodies
+                if writes_whole_rows(name, "dynamic-update-slice")]
+    assert len(in_place) == layers, in_place
+    for name in in_place:
+        assert [s for s, _ in gather_sources(name)] == [tokens], name
 
 
 @pytest.mark.parametrize("cell", ["gpt-1.3b-widths.s2048",
