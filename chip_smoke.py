@@ -39,6 +39,8 @@ Phases (default, one chip):
            head_dim 128 with the three kernels asserted in the compiled
            program. xent_path says how the LM loss ran (the
            kernel's rows and chunk and its grid steps, or why XLA).
+           The untied embedding's lookup and hand-written gradient at two
+           cells' tables, Zipf ids, against float64 sums.
 
 ``--chips 4`` runs only the four-chip phase and what it is compared with:
 BERT-Large dp=4 against one device at 2 x 512 tokens a chip (the block
@@ -56,6 +58,9 @@ Tolerances (all stated here, none tuned per run):
   fused xent        loss (f32) abs <= 2e-3 on values ~ log(vocab);
                     dlogits (bf16), and dx / dw through the head form,
                     normalized <= 1e-2
+  embedding         the untied table's gradient (float32 sums of bf16 rows)
+                    against float64 sums on the host, normalized <= 1e-5;
+                    the rows taken bit for bit the cast table's
   int8 codec        scales rtol 1e-6; codes within +-1 (a division that
                     lands on a rounding boundary), <= 0.1% of them off;
                     residual equal to x - codes*scale of the kernel's own
@@ -84,6 +89,9 @@ XENT_GRAD_TOL = 1e-2
 CODEC_RTOL = 1e-4
 CODE_MISMATCH_MAX = 1e-3
 BERT_MESH_RTOL = 1e-2
+#: float32 sums of bf16 rows against float64 sums, max|got - ref| / max|ref|
+#: (the bf16 sums of the tied form read 4e-3 to 1e-2 on the same ids)
+EMBED_GRAD_TOL = 1e-5
 BLOCK_KERNELS = ("hvd_block_attention", "hvd_block_attention_bwd")
 TRAIN_STEPS = 30
 LAYOUT_ATOL = 2e-2
@@ -119,6 +127,7 @@ class Sizes:
     xent: tuple           # fused xent check [rows, vocab]
     blocks: tuple         # codec check [n_blocks, block]
     gmm: tuple            # grouped matmul check (rows, in, out, groups)
+    embed: tuple          # embedding checks, each (vocab, width, tokens)
     gpt: dict             # flagship TransformerConfig fields
     gpt_batch: int
     ring: tuple           # four-chip ring attention [B, S, H, D]
@@ -137,6 +146,8 @@ REAL = Sizes(
     block=((64, 128, 16, 64), (8, 512, 16, 64)),
     xent=(16384, 32000), blocks=(8192, 256),
     gmm=(16384, 2048, 1024, 64),
+    # the tables of smallthinker-21b-a3b.s8192 and of olmoe-1b-7b.s4096
+    embed=((37984, 2560, 8192), (50304, 2048, 8192)),
     # depth cut to 4 layers: this phase checks kernels in place, not a model
     gpt=dict(vocab_size=32000, d_model=1024, n_heads=8, n_layers=4,
              d_ff=4096, max_seq=2048),
@@ -148,7 +159,7 @@ TINY = Sizes(
     attn=(1, 256, 2, 128), banded=(1, 512, 4, 2, 128, 256),
     block=((2, 128, 2, 64),),
     xent=(256, 1000), blocks=(64, 128),
-    gmm=(256, 128, 128, 4),
+    gmm=(256, 128, 128, 4), embed=((64, 2560, 48),),
     gpt=dict(vocab_size=1000, d_model=256, n_heads=2, n_layers=2,
              d_ff=256, max_seq=256),
     gpt_batch=2, ring=(1, 512, 2, 128))
@@ -671,6 +682,74 @@ def _check_gmm(smoke: Smoke) -> None:
               f"{name}: d_rows beyond the groups not zero")
 
 
+def _check_embed(smoke: Smoke) -> None:
+    """The lookup of a model whose head has a table of its own, and its
+    hand-written gradient (models/transformer.py:_table_rows), at the
+    cells' tables with token ids as text has them (Zipf: the commonest id
+    some 700 times in 8192, a tenth of the ids more than once): the rows
+    bit for bit the cast table's, the table's gradient against the float64
+    sum of the bf16 cotangent rows, made on the host. No leaf of a cell's
+    ``correct`` is the table's, and two layouts of one step share this
+    backward, so this is where a wrong sum would show. Prints the
+    milliseconds of lookup + gradient (host clock over calls back to back:
+    a smoke print) with the rows added in pieces of ``SUM_COLUMNS`` and
+    whole, the two readings the constant rests on."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from horovod_tpu.models import transformer
+    from horovod_tpu.models.transformer import TransformerConfig
+
+    def timed(columns, table, ids, cot, cfg, calls=10):
+        was, transformer.SUM_COLUMNS = transformer.SUM_COLUMNS, columns
+        try:
+            def both(table, ids, cot):
+                rows, back = jax.vjp(
+                    lambda e: transformer._embed_lookup(e, ids, cfg), table)
+                return rows, back(cot)[0]
+            f = jax.jit(both)
+            out = jax.block_until_ready(f(table, ids, cot))
+        finally:
+            transformer.SUM_COLUMNS = was
+        t0 = time.perf_counter()
+        for _ in range(calls):
+            last = f(table, ids, cot)
+        jax.block_until_ready(last)
+        return out, (time.perf_counter() - t0) / calls * 1e3
+
+    rng = np.random.default_rng(smoke.seed + 5)
+    for vocab, width, tokens in smoke.sizes.embed:
+        cfg = TransformerConfig(
+            vocab_size=vocab, d_model=width, n_heads=width // 128,
+            n_layers=1, d_ff=width, max_seq=tokens, dtype=jnp.bfloat16,
+            tie_embeddings=False)
+        share = 1.0 / np.arange(1, vocab + 1)
+        ids = rng.permutation(vocab)[rng.choice(
+            vocab, tokens, p=share / share.sum())].astype(np.int32)
+        keys = jax.random.split(jax.random.PRNGKey(smoke.seed + 5))
+        table = jax.random.normal(keys[0], (vocab, width), jnp.float32)
+        cot = jax.random.normal(keys[1], (1, tokens, width), jnp.bfloat16)
+        (rows, grad), ms = timed(transformer.SUM_COLUMNS, table,
+                                 jnp.asarray(ids)[None], cot, cfg)
+        _whole, ms_whole = timed(width, table, jnp.asarray(ids)[None], cot,
+                                 cfg)
+        want = np.zeros((vocab, width), np.float64)
+        np.add.at(want, ids, np.asarray(cot[0], np.float64))
+        err = float(np.abs(np.asarray(grad, np.float64) - want).max()
+                    / np.abs(want).max())
+        same = bool(jnp.array_equal(rows, table.astype(jnp.bfloat16)[ids][None]))
+        _kernel_line(smoke, "embed lookup", "grad table", err,
+                     EMBED_GRAD_TOL, shape=(vocab, width, tokens),
+                     ids="zipf", commonest_id=int(np.bincount(ids).max()),
+                     distinct_ids=int(np.unique(ids).size),
+                     rows_are_the_cast_table_s=same, ran="xla",
+                     sum_columns=transformer.SUM_COLUMNS,
+                     lookup_and_grad_host_ms=round(ms, 3),
+                     lookup_and_grad_host_ms_rows_added_whole=round(
+                         ms_whole, 3))
+        check(same, "embed lookup: rows differ from table.astype(bf16)[ids]")
+
+
 def _check_codec(smoke: Smoke) -> None:
     import jax
     import jax.numpy as jnp
@@ -859,6 +938,7 @@ def phase_kernels(smoke: Smoke, hvd) -> None:
     _check_block(smoke)
     _check_xent(smoke)
     _check_gmm(smoke)
+    _check_embed(smoke)
     _check_codec(smoke)
     _check_flagship(smoke, hvd)
 

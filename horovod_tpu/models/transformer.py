@@ -322,17 +322,78 @@ def _rope(x, positions, theta=10000.0):
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
 
 
-def _embed_lookup(emb_local, tokens):
-    """Vocab-sharded embedding lookup: mask + psum over tp."""
+#: the most columns a row of the embedding gradient's float32 sums has when
+#: it is added: on a v5e 8192 rows of 2560 add in 3.5 ms whole and in 0.68 as
+#: two pieces of 1280, rows of 2048 in 0.84 and 0.53 (PERF.md §6, PR 38;
+#: ``chip_smoke.py``'s ``embed lookup`` lines time both forms again)
+SUM_COLUMNS = 1280
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _table_rows(table, ids, dtype):
+    """``table[ids]`` in ``dtype``: the rows taken are cast, not the table
+    (the same bits), and the table's gradient is by hand. Autodiff's is a
+    scatter-add of the rows into the table, which a v5e runs at 1.9 us a
+    row for a table of 2560 columns, bf16 or float32 (PERF.md §6, PR 38)."""
+    return table[ids].astype(dtype)
+
+
+def _table_rows_fwd(table, ids, dtype):
+    # (the table for its shape and dtype: a parameter, live anyway)
+    return _table_rows(table, ids, dtype), (table, ids)
+
+
+def _table_rows_bwd(dtype, res, cot):
+    """The table's gradient with no scatter into the table: the sorted
+    ids' equal runs are summed in float32 into ``[n + 1, M]`` (a target
+    that small stays in the chip's near memory; the columns are added in
+    pieces of at most ``SUM_COLUMNS``, each a sum of its own over the same
+    sorted runs), and every row of the table then *gathers* its run's sum,
+    or the zeros of slot ``n``."""
+    table, ids = res
+    vocab, width = table.shape
+    ids = ids.reshape(-1)
+    n = ids.shape[0]
+    order = jnp.argsort(ids)
+    ids = ids[order]
+    run = jnp.cumsum(jnp.concatenate(
+        [jnp.zeros((1,), jnp.int32),
+         (ids[1:] != ids[:-1]).astype(jnp.int32)]))           # [n], sorted
+    pieces = next(r for r in range(1, width + 1)
+                  if width % r == 0 and width // r <= SUM_COLUMNS)
+    rows = cot.reshape(n, width)[order]
+    sums = jnp.concatenate(
+        [jax.ops.segment_sum(piece.astype(jnp.float32), run,
+                             num_segments=n + 1, indices_are_sorted=True)
+         for piece in jnp.split(rows, pieces, axis=1)], axis=1)
+    slot = jnp.full((vocab,), n, jnp.int32).at[ids].set(
+        run, indices_are_sorted=True)
+    return sums[slot].astype(table.dtype), None
+
+
+_table_rows.defvjp(_table_rows_fwd, _table_rows_bwd)
+
+
+def _embed_lookup(emb_local, tokens, cfg: TransformerConfig):
+    """Vocab-sharded embedding lookup in ``cfg.dtype``: mask + psum over tp.
+
+    With a head of its own the float32 table's rows are taken and cast
+    (:func:`_table_rows`), so the table's gradient adds repeated tokens'
+    rows in float32. A tied head reads the whole table in ``cfg.dtype``
+    anyway: the lookup shares that cast, and its gradient joins the head's
+    in the cast's transpose."""
+    def take(ids):
+        if cfg.tie_embeddings:
+            return emb_local.astype(cfg.dtype)[ids]
+        return _table_rows(emb_local, ids, cfg.dtype)
     Vl, M = emb_local.shape
     if _axis_live("tp"):
         off = lax.axis_index("tp") * Vl
         idx = tokens - off
         ok = (idx >= 0) & (idx < Vl)
-        x = jnp.where(ok[..., None],
-                      emb_local[jnp.clip(idx, 0, Vl - 1)], 0)
+        x = jnp.where(ok[..., None], take(jnp.clip(idx, 0, Vl - 1)), 0)
         return lax.psum(x, "tp")
-    return emb_local[tokens]
+    return take(tokens)
 
 
 def _sharded_softmax_xent(logits_local, targets):
@@ -574,10 +635,8 @@ def forward_loss_spmd(params, tokens, targets, cfg: TransformerConfig):
     sp_idx = lax.axis_index("sp") if _axis_live("sp") else 0
     positions = sp_idx * S + jnp.arange(S)
 
-    # the table's cast is shared with tied logits below: XLA keeps one
     with jax.named_scope(scopes.EMBED):
-        x = _embed_lookup(params["embed"].astype(cfg.dtype),
-                          tokens)                               # [B,S,M]
+        x = _embed_lookup(params["embed"], tokens, cfg)         # [B,S,M]
 
     with jax.named_scope(scopes.LAYERS):
         if cfg.n_loops > 1:
@@ -750,7 +809,7 @@ def router_choices(params, tokens, cfg: TransformerConfig):
     ``[L, B * S, k]``: the model's own blocks, on one device (no mesh). For
     diagnostics, such as telling what differing choices explain of an error
     against a reference (benchmarks/chip/tools/olmoe_routing.py)."""
-    x = params["embed"].astype(cfg.dtype)[tokens]
+    x = _embed_lookup(params["embed"], tokens, cfg)
     _x, auxs = _scan_layers(params["layers"], x,
                             jnp.arange(tokens.shape[1]), cfg)
     return auxs["experts"]

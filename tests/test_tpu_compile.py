@@ -560,6 +560,56 @@ def test_the_block_s_new_fields_leave_the_flagship_cells_alone(
     assert spelled_out.lower(*args).as_text() == lowered
 
 
+# -- the embedding's gradient (ISSUE 38) --------------------------------------
+
+def _assert_no_scatter_into_the_table(text, vocab, width):
+    scattered = re.search(
+        rf"^.* = \w+\[{vocab},{width}\]\S* scatter\(.*$", text, re.M)
+    assert not scattered, \
+        "the table's gradient is scattered:\n" + scattered.group(0)
+    assert f"bf16[{vocab},{width}]" not in text, \
+        "a bf16 copy of the table: the lookup casts it whole"
+
+
+def test_untied_embedding_gradient_scatters_nothing_into_the_table(
+        v5e, no_compile_cache):
+    """The lookup and its gradient at the share cell's table (37 984 rows
+    of 2560, 8192 tokens), a head of its own: no ``scatter`` has the table
+    for its result (on a v5e that scatter of 8192 rows is 15 ms at this
+    width, bf16 or float32; the float32 sums of the sorted ids' runs,
+    gathered, are under 3: PERF.md §6, PR 38) and no bf16 copy of the
+    table exists. The sums are added in float32. Cast the table before the lookup again, or drop the
+    hand-written gradient, and this fails."""
+    from horovod_tpu.models.transformer import (TransformerConfig,
+                                                _embed_lookup)
+    from horovod_tpu.profiling import scopes
+    vocab, width, tokens = 37984, 2560, 8192
+    cfg = TransformerConfig(vocab_size=vocab, d_model=width, n_heads=20,
+                            n_layers=1, d_ff=width, max_seq=tokens,
+                            dtype=jnp.bfloat16, tie_embeddings=False)
+
+    def gradient(table, ids, cotangent):
+        with jax.named_scope(scopes.EMBED):
+            rows, back = jax.vjp(lambda e: _embed_lookup(e, ids, cfg), table)
+        return rows, back(cotangent)[0]
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+    text = jax.jit(gradient).lower(
+        spec((vocab, width), jnp.float32), spec((1, tokens), jnp.int32),
+        spec((1, tokens, width), jnp.bfloat16)).compile().as_text()
+    _assert_no_scatter_into_the_table(text, vocab, width)
+    sums = re.findall(r"= (\w+)\[\d+,\d+\]\S* scatter\(", text)
+    assert sums and set(sums) <= {"f32", "s32"}, sums
+
+
+def test_mixed_step_scatters_nothing_into_the_embedding_table(mixed_step):
+    """The same, in the share cell's real step."""
+    compiled, shapes, _step_bytes = mixed_step
+    _assert_no_scatter_into_the_table(compiled.as_text(), shapes["vocab"],
+                                      shapes["d_model"])
+
+
 @pytest.fixture
 def cache_dir_updates(monkeypatch):
     """Record, without applying, what compile_cache.enable() would set."""
