@@ -41,6 +41,9 @@ Phases (default, one chip):
            kernel's rows and chunk and its grid steps, or why XLA).
            The untied embedding's lookup and hand-written gradient at two
            cells' tables, Zipf ids, against float64 sums.
+           The Mamba-2 scan's chunked form at the hybrid cell's heads
+           against the recurrence one position at a time; ssm_path says
+           the chunk count and what the backward pass keeps.
 
 ``--chips 4`` runs only the four-chip phase and what it is compared with:
 BERT-Large dp=4 against one device at 2 x 512 tokens a chip (the block
@@ -58,6 +61,9 @@ Tolerances (all stated here, none tuned per run):
   fused xent        loss (f32) abs <= 2e-3 on values ~ log(vocab);
                     dlogits (bf16), and dx / dw through the head form,
                     normalized <= 1e-2
+  mamba-2 scan      bf16 operands, float32 decays and state, against the
+                    float32 recurrence: max|got-ref| / max|ref| <= 2e-2,
+                    forward and every operand's gradient
   embedding         the untied table's gradient (float32 sums of bf16 rows)
                     against float64 sums on the host, normalized <= 1e-5;
                     the rows taken bit for bit the cast table's
@@ -84,6 +90,8 @@ import time
 FLASH_TOL = 2e-2
 # bf16 rows and weights against a float32 "highest" reference, as FLASH_TOL
 GMM_TOL = 2e-2
+# the chunked scan's bf16 matmul operands against a float32 recurrence
+SSM_TOL = 2e-2
 XENT_LOSS_ATOL = 2e-3
 XENT_GRAD_TOL = 1e-2
 CODEC_RTOL = 1e-4
@@ -128,6 +136,8 @@ class Sizes:
     blocks: tuple         # codec check [n_blocks, block]
     gmm: tuple            # grouped matmul check (rows, in, out, groups)
     embed: tuple          # embedding checks, each (vocab, width, tokens)
+    ssm: tuple            # Mamba-2 scan check (S, heads, head width, groups,
+    #                       state, chunk)
     gpt: dict             # flagship TransformerConfig fields
     gpt_batch: int
     ring: tuple           # four-chip ring attention [B, S, H, D]
@@ -148,6 +158,8 @@ REAL = Sizes(
     gmm=(16384, 2048, 1024, 64),
     # the tables of smallthinker-21b-a3b.s8192 and of olmoe-1b-7b.s4096
     embed=((37984, 2560, 8192), (50304, 2048, 8192)),
+    # nemotron-3-nano-30b-a3b.s8192's heads, an eighth of its length
+    ssm=(1024, 64, 64, 8, 128, 128),
     # depth cut to 4 layers: this phase checks kernels in place, not a model
     gpt=dict(vocab_size=32000, d_model=1024, n_heads=8, n_layers=4,
              d_ff=4096, max_seq=2048),
@@ -160,6 +172,7 @@ TINY = Sizes(
     block=((2, 128, 2, 64),),
     xent=(256, 1000), blocks=(64, 128),
     gmm=(256, 128, 128, 4), embed=((64, 2560, 48),),
+    ssm=(64, 4, 8, 2, 16, 16),
     gpt=dict(vocab_size=1000, d_model=256, n_heads=2, n_layers=2,
              d_ff=256, max_seq=256),
     gpt_batch=2, ring=(1, 512, 2, 128))
@@ -611,7 +624,8 @@ def _check_gmm(smoke: Smoke) -> None:
     with nothing, and kept out of the weights' gradient. The reference is
     ragged_dot in float32 on the groups' rows alone. Prints which path
     ``grouped_matmul`` takes, and the tile of each of its three calls, at
-    each size, at the OLMoE cell's and at the share cell's."""
+    each size, at the OLMoE cell's, at the share cell's and at the hybrid
+    cell's (an expert width no 128-multiple divides)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -639,6 +653,7 @@ def _check_gmm(smoke: Smoke) -> None:
         return float(jnp.max(jnp.abs(a[inside:].astype(jnp.float32))))
     olmoe = gmm_path(65536, 2048, 1024)
     share = gmm_path(49152, 2560, 768)
+    hybrid = gmm_path(49152, 2688, 1856)
     for kernel, width in ((GMM_NAME, d_out), (None, 64)):
         w = jax.random.normal(keys[1], (groups, d_in, width),
                               jnp.float32) / np.sqrt(d_in)
@@ -647,9 +662,10 @@ def _check_gmm(smoke: Smoke) -> None:
         if smoke.on_chip:
             check(olmoe.startswith(f"pallas {GMM_NAME} ") and
                   share.startswith(f"pallas {GMM_NAME} ") and
+                  hybrid.startswith(f"pallas {GMM_NAME} ") and
                   path.startswith(f"pallas {GMM_NAME} " if kernel
                                   else "xla ragged_dot"),
-                  path + olmoe + share)
+                  path + olmoe + share + hybrid)
         name = "moe_gmm" if kernel else "moe_gmm on xla ragged_dot"
         ran = {} if kernel else {"ran": "xla"}
 
@@ -665,6 +681,7 @@ def _check_gmm(smoke: Smoke) -> None:
                      shape=(rows, d_in, width, groups), dtype="bfloat16",
                      gmm_path=path, gmm_path_at_the_olmoe_cell=olmoe,
                      gmm_path_at_the_share_cell=share,
+                     gmm_path_at_the_hybrid_cell=hybrid,
                      rows_in_groups=inside, largest_group=int(sizes.max()),
                      empty_groups=int((sizes == 0).sum()),
                      beyond_the_groups=None if kernel else beyond(got),
@@ -680,6 +697,57 @@ def _check_gmm(smoke: Smoke) -> None:
                          GMM_TOL, **ran)
         check(kernel or beyond(got[0]) == 0,
               f"{name}: d_rows beyond the groups not zero")
+
+
+def _check_ssm(smoke: Smoke) -> None:
+    """The Mamba-2 scan in its chunked form (models/transformer.py:
+    ssm_chunked), bfloat16 operands with float32 time steps, decays and
+    carried state, against the recurrence one position at a time in
+    float32, forward and every operand's gradient, at the hybrid cell's
+    heads. Prints ``ssm_path`` at the cell's length: the chunk count and
+    what the backward pass keeps of a block."""
+    import jax
+    import jax.numpy as jnp
+    from horovod_tpu.models.transformer import (TransformerConfig,
+                                                ssm_chunked, ssm_path)
+    S, H, P, G, N, chunk = smoke.sizes.ssm
+    keys = jax.random.split(jax.random.PRNGKey(smoke.seed + 5), 6)
+    x = jax.random.normal(keys[0], (1, S, H, P), jnp.bfloat16)
+    b, c = (jax.random.normal(k, (1, S, G, N), jnp.bfloat16) / N ** 0.25
+            for k in keys[1:3])
+    dt = jax.nn.softplus(jax.random.normal(keys[3], (1, S, H)) - 3.0)
+    a = -jnp.exp(jax.random.uniform(keys[4], (H,), minval=0.0, maxval=2.7))
+    ct = jax.random.normal(keys[5], (1, S, H, P), jnp.float32)
+
+    def stepwise(x, dt, a, b, c):
+        x, b, c = (v.astype(jnp.float32) for v in (x, b, c))
+        b, c = (jnp.repeat(v, H // G, axis=2) for v in (b, c))
+
+        def step(h, at):
+            x_t, dt_t, b_t, c_t = at
+            h = (jnp.exp(dt_t * a)[..., None, None] * h
+                 + (dt_t[..., None] * x_t)[..., None] * b_t[:, :, None, :])
+            return h, jnp.sum(h * c_t[:, :, None, :], axis=-1)
+        _, y = jax.lax.scan(step, jnp.zeros((1, H, P, N)), tuple(
+            jnp.moveaxis(v, 1, 0) for v in (x, dt, b, c)))
+        return jnp.moveaxis(y, 0, 1)
+
+    def loss(f):
+        return lambda *ops: jnp.sum(f(*ops) * ct)
+    ops = (x, dt, a, b, c)
+    cfg = TransformerConfig(ssm_heads=H, ssm_head_dim=P, ssm_state=N,
+                            ssm_groups=G, ssm_chunk=chunk)
+    got = jax.jit(lambda *o: ssm_chunked(*o, chunk))(*ops)
+    _kernel_line(smoke, "mamba-2 scan", "fwd",
+                 _rel_err(got, jax.jit(stepwise)(*ops)), SSM_TOL,
+                 shape=(S, H, P, G, N), ran="xla",
+                 ssm_path_at_the_hybrid_cell=ssm_path(cfg, 8 * S))
+    got = jax.jit(jax.grad(loss(lambda *o: ssm_chunked(*o, chunk)),
+                           (0, 1, 2, 3, 4)))(*ops)
+    want = jax.jit(jax.grad(loss(stepwise), (0, 1, 2, 3, 4)))(*ops)
+    for leaf, g, r in zip(("d_x", "d_dt", "d_a", "d_b", "d_c"), got, want):
+        _kernel_line(smoke, "mamba-2 scan", f"grad {leaf}", _rel_err(g, r),
+                     SSM_TOL, ran="xla")
 
 
 def _check_embed(smoke: Smoke) -> None:
@@ -938,6 +1006,7 @@ def phase_kernels(smoke: Smoke, hvd) -> None:
     _check_block(smoke)
     _check_xent(smoke)
     _check_gmm(smoke)
+    _check_ssm(smoke)
     _check_embed(smoke)
     _check_codec(smoke)
     _check_flagship(smoke, hvd)

@@ -22,6 +22,13 @@ Layout conventions (local = per-device shapes):
                   step's state goes to the head and the exit gate (no pp)
   layer kinds     ``layer_pattern``: one period of (window, rope) kinds; the
                   scan goes over periods, a period's layers unrolled inside
+  one sublayer    a kind that starts with a word, ("mamba",), ("experts",),
+                  ("attention", window, rope): ``x + mixer(norm(x))`` and no
+                  more (Nemotron-H); the tree's ``layers`` then holds one
+                  stack a word, ``[pp, blocks of that word / pp, ...]``
+  mamba           a Mamba-2 mixer: projection, causal depthwise convolution,
+                  the chunked scan with its carried state in float32, the
+                  gated grouped norm; no sp, pp or tp
   expert share    ``expert_share=(i, of)``: this device holds that share of
                   every layer's experts with no ep axis live (one chip of an
                   expert-parallel group, run alone)
@@ -90,18 +97,48 @@ class TransformerConfig:
     n_kv_heads: Optional[int] = None    # k/v heads (grouped-query attention:
     #                             q head h reads k/v head h // (n_heads //
     #                             n_kv_heads)). None: n_heads
-    layer_pattern: Tuple[Tuple[Optional[int], bool], ...] = ((None, True),)
+    layer_pattern: Tuple[Tuple, ...] = ((None, True),)
     #                             one period of the stack's layer kinds, each
     #                             (window or None, rope or not): layer l is
     #                             of kind l % len. A window W keeps of a query
     #                             at t the keys t - W < j <= t; a layer
-    #                             without rope has no positions at all (NoPE)
+    #                             without rope has no positions at all (NoPE).
+    #                             A kind that starts with a word is a block of
+    #                             ONE sublayer, ``x + mixer(norm(x))``:
+    #                             ("mamba",) a Mamba-2 mixer (``ssm_*``),
+    #                             ("experts",) the expert layer, ("attention",
+    #                             window, rope) attention; a pattern is of
+    #                             such kinds throughout or of none
     moe_router_input: str = "tokens"    # what the router's logits are
     #                             computed from: the normed tokens the experts
     #                             get ("tokens"), or the block's input, before
     #                             its first norm and attention ("block_input")
     moe_activation: str = "silu"    # a gated expert's gate activation:
-    #                             "silu", or "relu" (ReGLU)
+    #                             "silu", or "relu" (ReGLU); "relu2": an
+    #                             ungated expert down(relu(up(x)) ** 2), which
+    #                             is gelu otherwise
+    moe_router_scores: str = "softmax"  # the experts' scores: the softmax
+    #                             over them, or each one's own "sigmoid", the
+    #                             top-k then taken of score + ``router_bias``
+    #                             (a leaf no gradient reaches) and the weights
+    #                             the chosen scores (parallel/moe.py:route)
+    moe_routed_scale: float = 1.0   # the top-k weights times this, after
+    #                             their renormalisation
+    moe_shared_width: int = 0   # > 0: an expert of this width every token
+    #                             runs beside its chosen ones (``ws1``,
+    #                             ``ws2``; ``moe_activation``), whole on every
+    #                             device that holds a share of the others
+    # -- a Mamba-2 mixer (arXiv:2405.21060), where the pattern has one ------
+    ssm_heads: int = 0          # heads of ``ssm_head_dim`` channels: the
+    #                             inner width is their product, not a
+    #                             multiple of d_model
+    ssm_head_dim: int = 64
+    ssm_state: int = 128        # a head's state is [ssm_head_dim, ssm_state]
+    ssm_groups: int = 1         # B and C are shared by the heads of a group:
+    #                             head h reads group h // (heads / groups)
+    ssm_conv: int = 4           # taps of the causal depthwise convolution
+    ssm_chunk: int = 128        # positions a chunk of the scan; the sequence
+    #                             is whole chunks
     expert_share: Tuple[int, int] = (0, 1)  # (index, of): this device holds
     #                             experts [index * E / of, (index + 1) * E /
     #                             of) of every layer, as the leading dimension
@@ -115,8 +152,9 @@ class TransformerConfig:
     n_microbatches: int = 1     # pipeline microbatches (per pp>1)
     remat: Optional[bool] = None    # jax.checkpoint each block (HBM for
     #                             FLOPs). None: where the architecture needs
-    #                             it: the pipeline's stages and a looped
-    #                             stack yes, the single scan over layers no
+    #                             it: the pipeline's stages, a looped stack
+    #                             and a Mamba block yes, the single scan over
+    #                             layers of any other kind no
 
     @property
     def head_dim(self) -> int:
@@ -130,6 +168,20 @@ class TransformerConfig:
     def held_experts(self) -> int:
         """Experts whose weights one unsharded copy of a layer holds."""
         return self.n_experts // self.expert_share[1]
+
+    @property
+    def one_sublayer(self) -> bool:
+        """Whether the pattern's kinds are blocks of one sublayer."""
+        return _one_sublayer(self.layer_pattern)
+
+    @property
+    def ssm_inner(self) -> int:
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_width(self) -> int:
+        """Channels the convolution runs over: x, B and C."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
 
     def __post_init__(self):
         index, of = self.expert_share
@@ -145,66 +197,161 @@ class TransformerConfig:
                 f"divide n_layers={self.n_layers}")
         if self.moe_router_input not in ("tokens", "block_input"):
             raise ValueError(f"moe_router_input={self.moe_router_input!r}")
-        if self.moe_activation not in ("silu", "relu"):
+        if self.moe_activation not in ("silu", "relu", "relu2"):
             raise ValueError(f"moe_activation={self.moe_activation!r}")
+        if self.moe_router_scores not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"moe_router_scores={self.moe_router_scores!r}")
+        words = {kind[0] for kind in self.layer_pattern
+                 if isinstance(kind[0], str)}
+        if words and (not words <= set(_SUBLAYERS) or any(
+                len(kind) != _SUBLAYERS.get(kind[0])
+                for kind in self.layer_pattern)):
+            raise ValueError(
+                f"layer_pattern={self.layer_pattern}: a block of one "
+                f"sublayer is one of {sorted(_SUBLAYERS)}, and a pattern is "
+                "of such blocks throughout or of none")
+        if "experts" in words and not self.n_experts:
+            raise ValueError("layer_pattern has (\"experts\",) blocks and "
+                             "n_experts=0")
+        if "mamba" in words and (
+                self.ssm_heads < 1 or self.ssm_heads % self.ssm_groups):
+            raise ValueError(
+                f"layer_pattern has (\"mamba\",) blocks: ssm_groups="
+                f"{self.ssm_groups} does not divide ssm_heads="
+                f"{self.ssm_heads}")
+
+
+#: the blocks of one sublayer, and the length of each one's kind
+_SUBLAYERS = {"mamba": 1, "experts": 1, "attention": 3}
+
+
+def _one_sublayer(pattern) -> bool:
+    """Whether a pattern's kinds start with a word (a valid pattern's do
+    throughout or not at all)."""
+    return isinstance(pattern[0][0], str)
 
 
 # ---------------------------------------------------------------------------
 # Parameter init (host-side, then device_put with shardings)
 # ---------------------------------------------------------------------------
 
-def init_params(rng: np.random.RandomState, cfg: TransformerConfig,
-                n_stages: int = 1) -> Dict:
-    """Initialize parameters in the stacked-stage layout ``[pp, L/pp, ...]``."""
-    L = cfg.n_layers
-    assert L % n_stages == 0, (L, n_stages)
-    lps = L // n_stages
-    M, H, Dh, F = cfg.d_model, cfg.n_heads, cfg.head_dim, cfg.d_ff
-    Hkv = cfg.kv_heads
+def _sublayer_counts(cfg: TransformerConfig) -> Dict[str, int]:
+    """Blocks of each word in one period of a pattern of one-sublayer
+    blocks, in the order the words first appear."""
+    words = [kind[0] for kind in cfg.layer_pattern]
+    return {word: words.count(word) for word in dict.fromkeys(words)}
 
-    def w(*shape, scale=None):
-        scale = scale if scale is not None else (1.0 / np.sqrt(shape[-2]))
-        return (rng.randn(*shape) * scale).astype(np.float32)
 
-    layer: Dict[str, np.ndarray] = {
-        "ln1": np.ones((n_stages, lps, M), np.float32),
-        "wq": w(n_stages, lps, M, H * Dh),
-        "wk": w(n_stages, lps, M, Hkv * Dh),
-        "wv": w(n_stages, lps, M, Hkv * Dh),
-        "wo": w(n_stages, lps, H * Dh, M),
-        "ln2": np.ones((n_stages, lps, M), np.float32),
+def _attention_leaves(cfg: TransformerConfig, w, ones, lead) -> Dict:
+    M, H, Dh, Hkv = cfg.d_model, cfg.n_heads, cfg.head_dim, cfg.kv_heads
+    leaves = {
+        "ln1": ones(*lead, M),
+        "wq": w(*lead, M, H * Dh),
+        "wk": w(*lead, M, Hkv * Dh),
+        "wv": w(*lead, M, Hkv * Dh),
+        "wo": w(*lead, H * Dh, M),
     }
     if cfg.qk_norm:
-        layer.update({
-            "q_norm": np.ones((n_stages, lps, H * Dh), np.float32),
-            "k_norm": np.ones((n_stages, lps, Hkv * Dh), np.float32),
-        })
+        leaves.update({"q_norm": ones(*lead, H * Dh),
+                       "k_norm": ones(*lead, Hkv * Dh)})
     if cfg.post_norm:
-        layer.update({
-            "ln1_post": np.ones((n_stages, lps, M), np.float32),
-            "ln2_post": np.ones((n_stages, lps, M), np.float32),
-        })
+        leaves["ln1_post"] = ones(*lead, M)
+    return leaves
+
+
+def _ffn_leaves(cfg: TransformerConfig, w, ones, lead) -> Dict:
+    M, F = cfg.d_model, cfg.d_ff
+    leaves = {"ln2": ones(*lead, M)}
+    if cfg.post_norm:
+        leaves["ln2_post"] = ones(*lead, M)
     if cfg.n_experts > 0:
         # we1 is the gate of a gated expert, we3 its up projection, we2
         # the way back down (the Mixtral numbering); the experts held
         # here lead, the router scores them all
         held = cfg.held_experts
-        layer.update({
-            "router": w(n_stages, lps, M, cfg.n_experts, scale=0.02),
-            "we1": w(n_stages, lps, held, M, F),
-            "we2": w(n_stages, lps, held, F, M),
+        leaves.update({
+            "router": w(*lead, M, cfg.n_experts, scale=0.02),
+            "we1": w(*lead, held, M, F),
+            "we2": w(*lead, held, F, M),
         })
         if cfg.moe_gated:
-            layer["we3"] = w(n_stages, lps, held, M, F)
+            leaves["we3"] = w(*lead, held, M, F)
+        if cfg.moe_router_scores == "sigmoid":
+            # the correction of the choice: a buffer, held at zero (what
+            # moves it in training is no gradient and not implemented)
+            leaves["router_bias"] = np.zeros((*lead, cfg.n_experts),
+                                             np.float32)
+        if cfg.moe_shared_width:
+            leaves.update({"ws1": w(*lead, M, cfg.moe_shared_width),
+                           "ws2": w(*lead, cfg.moe_shared_width, M)})
     else:
         # w1 is the gate of a gated FFN and w3 its up projection, as the
         # experts number theirs
-        layer.update({
-            "w1": w(n_stages, lps, M, F),
-            "w2": w(n_stages, lps, F, M),
-        })
+        leaves.update({"w1": w(*lead, M, F), "w2": w(*lead, F, M)})
         if cfg.ffn_gated:
-            layer["w3"] = w(n_stages, lps, M, F)
+            leaves["w3"] = w(*lead, M, F)
+    return leaves
+
+
+#: the range a Mamba-2 head's time step ``softplus(dt_bias)`` is drawn
+#: from, log-uniformly, its floor, and the range of ``-A`` (the reference
+#: implementation's defaults, which Nemotron-H's config repeats)
+SSM_DT_RANGE, SSM_DT_FLOOR, SSM_A_RANGE = (1e-3, 1e-1), 1e-4, (1.0, 16.0)
+
+
+def _mamba_leaves(cfg: TransformerConfig, rng, w, ones, lead) -> Dict:
+    """``ssm_in`` maps to ``[z | x B C | dt]``; the convolution's taps are
+    ``[tap, channel]``, tap ``ssm_conv - 1`` on the current position."""
+    M, H, inner, K = cfg.d_model, cfg.ssm_heads, cfg.ssm_inner, cfg.ssm_conv
+    dt = np.exp(rng.uniform(*np.log(SSM_DT_RANGE), size=(*lead, H)))
+    dt = np.maximum(dt, SSM_DT_FLOOR)
+
+    def taps(*shape):
+        return (rng.uniform(-1, 1, shape) / np.sqrt(K)).astype(np.float32)
+    return {
+        "ln1": ones(*lead, M),
+        "ssm_in": w(*lead, M, inner + cfg.ssm_conv_width + H),
+        "ssm_conv_w": taps(*lead, K, cfg.ssm_conv_width),
+        "ssm_conv_b": taps(*lead, cfg.ssm_conv_width),
+        # softplus(dt_bias) = dt
+        "ssm_dt_bias": np.log(np.expm1(dt)).astype(np.float32),
+        "ssm_a_log": np.log(rng.uniform(*SSM_A_RANGE, size=(*lead, H))
+                            ).astype(np.float32),
+        "ssm_d": ones(*lead, H),
+        "ssm_norm": ones(*lead, inner),
+        "ssm_out": w(*lead, inner, M),
+    }
+
+
+def init_params(rng: np.random.RandomState, cfg: TransformerConfig,
+                n_stages: int = 1) -> Dict:
+    """Initialize parameters in the stacked-stage layout ``[pp, L/pp, ...]``;
+    a pattern of one-sublayer blocks has one such stack a word under
+    ``layers`` (``layers["mamba"]["ssm_in"]`` ``[pp, blocks / pp, ...]``)."""
+    L = cfg.n_layers
+    assert L % n_stages == 0, (L, n_stages)
+    lps = L // n_stages
+    M = cfg.d_model
+
+    def w(*shape, scale=None):
+        scale = scale if scale is not None else (1.0 / np.sqrt(shape[-2]))
+        return (rng.randn(*shape) * scale).astype(np.float32)
+
+    def ones(*shape):
+        return np.ones(shape, np.float32)
+
+    if cfg.one_sublayer:
+        layer = {}
+        for word, count in _sublayer_counts(cfg).items():
+            lead = (n_stages, lps // len(cfg.layer_pattern) * count)
+            layer[word] = (
+                _mamba_leaves(cfg, rng, w, ones, lead) if word == "mamba"
+                else _ffn_leaves(cfg, w, ones, lead) if word == "experts"
+                else _attention_leaves(cfg, w, ones, lead))
+    else:
+        layer = {**_attention_leaves(cfg, w, ones, (n_stages, lps)),
+                 **_ffn_leaves(cfg, w, ones, (n_stages, lps))}
     params = {
         "embed": (rng.randn(cfg.vocab_size, M) * 0.02).astype(np.float32),
         "ln_f": np.ones((M,), np.float32),
@@ -240,28 +387,53 @@ def param_shardings(cfg: TransformerConfig, mesh: Mesh) -> Dict:
             f"layer_pattern of {len(cfg.layer_pattern)} kinds on pp="
             f"{mesh.shape['pp']}: a stage of "
             f"{cfg.n_layers // mesh.shape['pp']} layers is not whole periods")
-    layers = {
-        "ln1": s(pp), "ln2": s(pp),
+    live = [a for a in ("sp", "pp", "tp") if mesh.shape.get(a, 1) > 1]
+    if live and ("mamba",) in cfg.layer_pattern:
+        raise NotImplementedError(
+            f"a (\"mamba\",) block of layer_pattern on a live "
+            f"{' / '.join(live)} axis: the convolution and the scan's "
+            "carried state run over the whole sequence on one device (no "
+            "hand-over between sp shards), its heads and groups are not "
+            "split over tp, and no pipeline schedule has run it")
+    attention = {
+        "ln1": s(pp),
         "wq": s(pp, None, None, tp), "wk": s(pp, None, None, tp),
         "wv": s(pp, None, None, tp), "wo": s(pp, None, tp, None),
     }
+    ffn = {"ln2": s(pp)}
     if cfg.qk_norm:
-        layers.update({"q_norm": s(pp, None, tp), "k_norm": s(pp, None, tp)})
+        attention.update({"q_norm": s(pp, None, tp),
+                          "k_norm": s(pp, None, tp)})
     if cfg.post_norm:
-        layers.update({"ln1_post": s(pp), "ln2_post": s(pp)})
+        attention["ln1_post"] = ffn["ln2_post"] = s(pp)
     if cfg.n_experts > 0:
-        layers.update({
+        ffn.update({
             "router": s(pp),
             "we1": s(pp, None, ep, None, tp),
             "we2": s(pp, None, ep, tp, None),
         })
         if cfg.moe_gated:
-            layers["we3"] = s(pp, None, ep, None, tp)
+            ffn["we3"] = s(pp, None, ep, None, tp)
+        if cfg.moe_router_scores == "sigmoid":
+            ffn["router_bias"] = s(pp)
+        if cfg.moe_shared_width:
+            ffn.update({"ws1": s(pp, None, None, tp),
+                        "ws2": s(pp, None, tp, None)})
     else:
-        layers.update({"w1": s(pp, None, None, tp),
-                       "w2": s(pp, None, tp, None)})
+        ffn.update({"w1": s(pp, None, None, tp),
+                    "w2": s(pp, None, tp, None)})
         if cfg.ffn_gated:
-            layers["w3"] = s(pp, None, None, tp)
+            ffn["w3"] = s(pp, None, None, tp)
+    if cfg.one_sublayer:
+        # (a Mamba block's leaves are whole on every device: no pp, tp)
+        mamba = {name: s() for name in (
+            "ln1", "ssm_in", "ssm_conv_w", "ssm_conv_b", "ssm_dt_bias",
+            "ssm_a_log", "ssm_d", "ssm_norm", "ssm_out")}
+        layers = {word: {"mamba": mamba, "experts": ffn,
+                         "attention": attention}[word]
+                  for word in _sublayer_counts(cfg)}
+    else:
+        layers = {**attention, **ffn}
     shardings = {"embed": s(tp), "ln_f": s(), "layers": layers}
     if not cfg.tie_embeddings:
         shardings["lm_head"] = s(None, tp)
@@ -499,11 +671,13 @@ def _dense_ffn(p, x, cfg: TransformerConfig):
 def _router_logits(p, x):
     """The router's float32 logits ``[B' * S', E]`` of ``x`` ``[B', S',
     M]``, for a router that reads something other than the experts'
-    tokens (``moe_router_input``). The residual stream is not normed, so
-    the logits are as large as it is and a top-k weight moves with their
-    absolute error: the product is float32 in fact ("highest": a TPU
-    multiplies float32 operands as bfloat16 otherwise), on a matmul of
-    ``E`` columns."""
+    tokens (``moe_router_input``), and for one with sigmoid scores. The
+    residual stream is not normed, so the logits are as large as it is and
+    a top-k weight moves with their absolute error; and of 128 sigmoid
+    scores the 6th and 7th lie some 0.08 logits apart, where a bfloat16
+    pass over the router's weights moves a logit by 0.002: the product is
+    float32 in fact ("highest": a TPU multiplies float32 operands as
+    bfloat16 otherwise), on a matmul of ``E`` columns."""
     with jax.named_scope(scopes.MOE), jax.named_scope(scopes.MOE_ROUTER):
         return jnp.matmul(x.reshape(-1, x.shape[-1]).astype(jnp.float32),
                           p["router"].astype(jnp.float32),
@@ -517,20 +691,28 @@ def _moe_ffn(p, x, cfg: TransformerConfig, logits=None):
     layer's metrics)."""
     B, S, M = x.shape
     toks = x.reshape(B * S, M)
-    if not cfg.moe_gated and cfg.moe_activation != "silu":
+    if cfg.moe_activation == ("relu2" if cfg.moe_gated else "relu"):
         raise NotImplementedError(
-            f"moe_activation={cfg.moe_activation!r} without moe_gated: it "
-            "names a gated expert's gate activation")
+            f"moe_activation={cfg.moe_activation!r} with moe_gated="
+            f"{cfg.moe_gated}: \"silu\" and \"relu\" name a gated expert's "
+            "gate activation, \"relu2\" an ungated expert's")
     gate = jax.nn.relu if cfg.moe_activation == "relu" else jax.nn.silu
+
+    def ungated(h):
+        if cfg.moe_activation == "relu2":
+            return jnp.square(jax.nn.relu(h))
+        return jax.nn.gelu(h)
 
     def expert_fn(ep, rows, group_sizes):
         h = grouped_matmul(rows, ep["we1"], group_sizes)
         if cfg.moe_gated:
             h = gate(h) * grouped_matmul(rows, ep["we3"], group_sizes)
         else:
-            h = jax.nn.gelu(h)
+            h = ungated(h)
         return grouped_matmul(h, ep["we2"], group_sizes)
 
+    if logits is None and cfg.moe_router_scores == "sigmoid":
+        logits = _router_logits(p, x)
     with jax.named_scope(scopes.MOE):
         y, m = moe_layer_spmd(
             toks, p["router"], expert_fn,
@@ -538,7 +720,11 @@ def _moe_ffn(p, x, cfg: TransformerConfig, logits=None):
             axis_name="ep" if _axis_live("ep") else None,
             k=cfg.moe_top_k, renormalize=cfg.moe_renormalize,
             stat_axes=[a for a in ("dp", "ep", "sp") if _axis_live(a)],
-            logits=logits, share=cfg.expert_share)
+            logits=logits, share=cfg.expert_share,
+            scores=cfg.moe_router_scores, bias=p.get("router_bias"),
+            scale=cfg.moe_routed_scale)
+        if cfg.moe_shared_width:
+            y = y + _shared_expert(p, toks, ungated)
         y = _psum_if(y, "tp")
     metrics = m._asdict()
     if cfg.expert_share == (0, 1):
@@ -547,6 +733,180 @@ def _moe_ffn(p, x, cfg: TransformerConfig, logits=None):
                         + cfg.moe_z_weight * m.router_z_loss),
            **metrics}
     return y.reshape(B, S, M), aux
+
+
+def _causal_conv(x, taps, bias):
+    """Depthwise causal convolution over the sequence: ``y[t] = bias +
+    sum_j taps[j] * x[t - (K - 1) + j]`` with zeros before the start. x
+    ``[B, S, C]``, taps ``[K, C]``; K shifted multiply-adds in float32."""
+    K, S = taps.shape[0], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
+    y = bias.astype(jnp.float32)
+    for j in range(K):
+        y = y + (taps[j].astype(jnp.float32)
+                 * padded[:, j:j + S].astype(jnp.float32))
+    return y
+
+
+def _ssm_decay(log_decay):
+    """``exp`` of a sum of ``dt_t a`` (never positive), in float32 as it
+    comes: the one place the scan's decays are made (a test swaps it for
+    the nearest precision below)."""
+    return jnp.exp(log_decay)
+
+
+def _carried_states(whole, states):
+    """The state each chunk starts from, ``[B, n, ...]``: ``H <- whole_c H
+    + states_c`` over the ``n`` chunks from ``H = 0``, in float32. whole
+    ``[B, n, G, R]`` a chunk's whole decay ``exp(s_Q)``, states ``[B, n, G,
+    R, P, N]`` what a chunk's own positions leave behind."""
+    def carry(h, chunk):
+        decay, state = chunk
+        return decay[..., None, None] * h + state, h
+    _, before = lax.scan(
+        carry, jnp.zeros_like(states[:, 0]),
+        (jnp.moveaxis(whole, 1, 0), jnp.moveaxis(states, 1, 0)))
+    return jnp.moveaxis(before, 0, 1)
+
+
+def _gated_norm(y, z, weight, groups: int, eps: float):
+    """``rmsnorm(y * silu(z)) * weight`` in float32, the gate before the
+    norm and the norm over each of ``groups`` groups of channels. y, z
+    ``[B, S, C]``."""
+    B, S, C = y.shape
+    y = (y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+         ).reshape(B, S, groups, C // groups)
+    y = y * jax.lax.rsqrt(jnp.mean(jnp.square(y), axis=-1, keepdims=True)
+                          + eps)
+    return y.reshape(B, S, C) * weight.astype(jnp.float32)
+
+
+def _within_chunks(x, b, c, s, dt):
+    """``y_i = sum_{j <= i} exp(s_i - s_j) (c_i . b_j) dt_j x_j`` inside
+    every chunk: the scores, decays and their product are ``[B, n, G, R, Q,
+    Q]`` (at 8192 positions and 64 heads of chunk 128, 268 MB in float32).
+    x ``[B, n, Q, G, R, P]``, b and c ``[B, n, Q, G, N]``, s and dt ``[B,
+    n, G, R, Q]`` float32; returns float32 ``[B, n, Q, G, R, P]``."""
+    chunk = x.shape[2]
+    scores = jnp.einsum("bnigs,bnjgs->bngij", c, b,
+                        preferred_element_type=jnp.float32)
+    causal = jnp.tril(jnp.ones((chunk, chunk), bool))
+    decay = _ssm_decay(jnp.where(
+        causal, s[..., :, None] - s[..., None, :], -jnp.inf))
+    weights = (scores[:, :, :, None] * decay * dt[..., None, :]
+               ).astype(x.dtype)
+    return jnp.einsum("bngrij,bnjgrp->bnigrp", weights, x,
+                      preferred_element_type=jnp.float32)
+
+
+def ssm_chunked(x, dt, a, b, c, chunk: int):
+    """The selective state-space recurrence of Mamba-2 in its chunked
+    (dual) form (arXiv:2405.21060, section 6). Per head, with ``a_t = dt_t
+    a`` (``a`` < 0) and the state ``H`` ``[P, N]``:
+
+        H_t = exp(a_t) H_{t-1} + dt_t x_t (x) b_t        y_t = H_t c_t
+
+    Inside a chunk of ``chunk`` positions, ``s_i = sum_{t <= i} a_t``:
+
+        y_i = sum_{j <= i} exp(s_i - s_j) (c_i . b_j) dt_j x_j
+              + exp(s_i) c_i . H_prev
+        H_next = exp(s_Q) H_prev + sum_j exp(s_Q - s_j) dt_j x_j (x) b_j
+
+    so a chunk is three batches of matmuls (scores ``c b^T``, scores times
+    x, x^T times b) and the sequence a loop over chunks that carries
+    ``H``. The time steps, the sums ``s``, every decay and the carried
+    state are float32; the matmuls take operands of ``x.dtype`` and
+    accumulate in float32, the decays and ``dt`` multiplied into the
+    scores before they are cast.
+
+    x ``[B, S, H, P]``; dt ``[B, S, H]`` float32, after its softplus; a
+    ``[H]`` float32; b, c ``[B, S, G, N]``, head h reading group ``h // (H
+    / G)``. Returns y ``[B, S, H, P]`` float32 (without the skip ``D x``).
+    """
+    B, S, H, P = x.shape
+    G, N = b.shape[2:]
+    if S % chunk:
+        raise ValueError(f"ssm_chunk={chunk} does not divide the sequence "
+                         f"of {S} positions")
+    n, R = S // chunk, H // G
+    x = x.reshape(B, n, chunk, G, R, P)
+    b, c = (v.reshape(B, n, chunk, G, N) for v in (b, c))
+    # [B, n, G, R, Q]: a head's positions last
+    dt = dt.reshape(B, n, chunk, G, R).transpose(0, 1, 3, 4, 2)
+    s = jnp.cumsum(dt * a.reshape(G, R, 1), axis=-1)
+
+    y = _within_chunks(x, b, c, s, dt)
+
+    # -- a chunk's own state, and the state each chunk starts from ---------
+    to_end = (_ssm_decay(s[..., -1:] - s) * dt).transpose(0, 1, 4, 2, 3)
+    states = jnp.einsum("bnjgrp,bnjgs->bngrps",
+                        (x.astype(jnp.float32) * to_end[..., None]
+                         ).astype(x.dtype), b,
+                        preferred_element_type=jnp.float32)
+    since_start = _ssm_decay(s)                 # exp(s_i); the last: exp(s_Q)
+    before = _carried_states(since_start[..., -1], states)
+    y = y + (jnp.einsum("bnigs,bngrps->bnigrp", c, before.astype(x.dtype),
+                        preferred_element_type=jnp.float32)
+             * since_start.transpose(0, 1, 4, 2, 3)[..., None])
+    return y.reshape(B, S, H, P)
+
+
+def ssm_path(cfg: TransformerConfig, seq_len: int) -> str:
+    """How a Mamba block's scan runs at ``seq_len`` positions and what the
+    backward pass keeps of the block (``chip_smoke.py`` prints it, as it
+    does ``attend``'s choice)."""
+    kept = ("each Mamba block checkpointed: its input kept, the block run "
+            "again in the backward pass" if _remat(cfg, True) else
+            "everything kept for the backward pass")
+    return (f"jax.numpy chunked scan, {seq_len // cfg.ssm_chunk} chunks of "
+            f"{cfg.ssm_chunk}, float32 sums, decays and carried state "
+            f"[{cfg.ssm_heads}, {cfg.ssm_head_dim}, {cfg.ssm_state}]; {kept}")
+
+
+def _mamba_block(p, x, cfg: TransformerConfig):
+    """``x + mamba2(norm(x))``, x ``[B', S', M]`` with the whole sequence
+    here (no sp). The mixer: ``[z | x B C | dt] = h W_in``; x, B and C
+    through the causal convolution and silu; ``dt = softplus(dt +
+    dt_bias)``, ``a = -exp(a_log)`` a head; the scan (:func:`ssm_chunked`)
+    plus the skip ``d x``; ``rmsnorm(y * silu(z))`` over each of the
+    ``ssm_groups`` groups of channels; ``W_out``."""
+    B, S, M = x.shape
+    H, P, G, N = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state
+    inner, wide = cfg.ssm_inner, cfg.ssm_conv_width
+    with jax.named_scope(scopes.SSM):
+        h = _rmsnorm(x, p["ln1"], cfg.norm_eps)
+        with jax.named_scope(scopes.SSM_PROJ):
+            zxbcdt = h @ p["ssm_in"].astype(h.dtype)
+        z, xbc, dt = (zxbcdt[..., :inner], zxbcdt[..., inner:inner + wide],
+                      zxbcdt[..., inner + wide:])
+        with jax.named_scope(scopes.SSM_CONV):
+            xbc = jax.nn.silu(_causal_conv(
+                xbc, p["ssm_conv_w"], p["ssm_conv_b"]).astype(h.dtype))
+        xs = xbc[..., :inner].reshape(B, S, H, P)
+        b = xbc[..., inner:inner + G * N].reshape(B, S, G, N)
+        c = xbc[..., inner + G * N:].reshape(B, S, G, N)
+        with jax.named_scope(scopes.SSM_SCAN):
+            dt = jax.nn.softplus(dt.astype(jnp.float32)
+                                 + p["ssm_dt_bias"].astype(jnp.float32))
+            a = -jnp.exp(p["ssm_a_log"].astype(jnp.float32))
+            y = ssm_chunked(xs, dt, a, b, c, cfg.ssm_chunk)
+            y = y + (p["ssm_d"].astype(jnp.float32)[:, None]
+                     * xs.astype(jnp.float32))
+        with jax.named_scope(scopes.SSM_NORM):
+            y = _gated_norm(y.reshape(B, S, inner), z, p["ssm_norm"], G,
+                            cfg.norm_eps).astype(h.dtype)
+        with jax.named_scope(scopes.SSM_PROJ):
+            o = y @ p["ssm_out"].astype(h.dtype)
+        return x + o
+
+
+def _shared_expert(p, toks, activation):
+    """The expert every token runs, ``down(activation(up(toks)))``, two
+    dense matmuls over all the tokens (inner width over tp, the caller's
+    psum); the same on every device that holds a share of the others."""
+    with jax.named_scope(scopes.MOE_SHARED):
+        h = activation(toks @ p["ws1"].astype(toks.dtype))
+        return h @ p["ws2"].astype(toks.dtype)
 
 
 def _no_aux():
@@ -564,12 +924,26 @@ def _over_layers(auxs):
 
 
 def _block(p, x, positions, cfg: TransformerConfig, kind=_PLAIN_LAYER):
+    """One block of ``kind`` on ``x``; returns (the new residual, the
+    block's auxiliary terms, None where it has none to stack)."""
+    if kind[0] == "mamba":
+        return _mamba_block(p, x, cfg), None
+    if kind[0] == "attention":
+        return _attention_block(p, x, positions, cfg, kind[1:]), None
+    if kind[0] == "experts":
+        return _ffn_block(p, x, cfg)
     logits = None
     if cfg.n_experts > 0 and cfg.moe_router_input == "block_input":
         # before attention, from the residual as it comes in: nothing of
         # this block stands between the choice and its experts' weights
         logits = _router_logits(p, x)
     x = _attention_block(p, x, positions, cfg, kind)
+    return _ffn_block(p, x, cfg, logits)
+
+
+def _ffn_block(p, x, cfg: TransformerConfig, logits=None):
+    """``x + ffn(norm(x))``, the FFN dense or the experts; ``logits``: a
+    router's that read something else than the normed tokens."""
     with jax.named_scope(scopes.MLP):
         h = _rmsnorm(x, p["ln2"], cfg.norm_eps)
         if cfg.n_experts > 0:
@@ -763,12 +1137,14 @@ def _scan_layers(lp, x, positions, cfg: TransformerConfig):
     """One scan over the blocks of ``lp`` (``[stage, layer, ...]`` leaves).
     Returns (activations, every layer's auxiliary terms stacked ``[L]``).
     A looped stack checkpoints each block (its passes' activations would
-    not fit beside the weights), the single pass does not, unless
-    ``cfg.remat`` says otherwise."""
+    not fit beside the weights), the single pass only its Mamba blocks (a
+    block's float32 chunk states, decays and gate keep 1.2 GB at 8192
+    positions: PERF.md section 6, PR 39), unless ``cfg.remat`` says
+    otherwise."""
     def block_of(kind):
         def block(layer_p, x):
             return _block(layer_p, x, positions, cfg, kind)
-        if _remat(cfg, cfg.n_loops > 1):
+        if _remat(cfg, cfg.n_loops > 1 or kind[0] == "mamba"):
             block = jax.checkpoint(block)
         return block
     flat = jax.tree_util.tree_map(
@@ -781,24 +1157,46 @@ def _scan_periods(block_of, x, layers, pattern):
     ``layers`` (leaves ``[L, ...]``), layer ``l`` of kind ``pattern[l %
     len(pattern)]``: a scan over periods with a period's layers unrolled
     inside, each with its static kind; a period of one is a scan over
-    layers. Returns (activations, every layer's auxiliary terms ``[L]``)."""
+    layers. A pattern of one-sublayer blocks has a stack a word
+    (``layers[word]``, leaves ``[blocks of that word, ...]``), and a
+    period's i-th block of a word takes the i-th of the period's layers
+    in that stack. Returns (activations, the auxiliary terms of every
+    layer that has any, stacked)."""
     blocks = {kind: block_of(kind) for kind in pattern}
-    if len(pattern) == 1:
+    by_word = _one_sublayer(pattern)
+    if len(pattern) == 1 and not by_word:
         def scan_body(carry, layer_p):
             y, aux = blocks[pattern[0]](layer_p, carry)
             return y, aux
         return lax.scan(scan_body, x, layers)
     n = len(pattern)
+    if by_word:
+        words = [kind[0] for kind in pattern]
+        # (the stack, the place in a period's part of it) of each block
+        places = [(w, words[:i].count(w)) for i, w in enumerate(words)]
+        periods = {
+            w: jax.tree_util.tree_map(
+                lambda a, per=words.count(w): a.reshape(
+                    (a.shape[0] // per, per) + a.shape[1:]), layers[w])
+            for w in set(words)}
+
+        def layer_of(period_p, i):
+            word, place = places[i]
+            return jax.tree_util.tree_map(lambda a: a[place], period_p[word])
+    else:
+        periods = jax.tree_util.tree_map(
+            lambda a: a.reshape((a.shape[0] // n, n) + a.shape[1:]), layers)
+
+        def layer_of(period_p, i):
+            return jax.tree_util.tree_map(lambda a: a[i], period_p)
 
     def period_body(carry, period_p):
         auxs = []
         for i, kind in enumerate(pattern):
-            carry, aux = blocks[kind](
-                jax.tree_util.tree_map(lambda a: a[i], period_p), carry)
+            carry, aux = blocks[kind](layer_of(period_p, i), carry)
             auxs.append(aux)
+        auxs = [aux for aux in auxs if aux is not None] or [_no_aux()]
         return carry, jax.tree_util.tree_map(lambda *a: jnp.stack(a), *auxs)
-    periods = jax.tree_util.tree_map(
-        lambda a: a.reshape((a.shape[0] // n, n) + a.shape[1:]), layers)
     y, auxs = lax.scan(period_body, x, periods)
     return y, jax.tree_util.tree_map(
         lambda a: a.reshape((-1,) + a.shape[2:]), auxs)
@@ -974,7 +1372,11 @@ def _dense_decode_only(cfg: TransformerConfig) -> None:
         ("layer_pattern", cfg.layer_pattern == (_PLAIN_LAYER,)),
         ("n_kv_heads", cfg.kv_heads == cfg.n_heads),
         ("moe_router_input", cfg.moe_router_input == "tokens"),
-        ("expert_share", cfg.expert_share == (0, 1))) if not plain]
+        ("expert_share", cfg.expert_share == (0, 1)),
+        ("moe_router_scores", cfg.moe_router_scores == "softmax"),
+        ("moe_shared_width", cfg.moe_shared_width == 0),
+        ("ssm_heads (a Mamba block's recurrent state is no page of keys)",
+         cfg.ssm_heads == 0)) if not plain]
     if off:
         raise NotImplementedError(
             f"paged decode does not implement {', '.join(off)}: its cache "

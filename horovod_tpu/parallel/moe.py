@@ -117,8 +117,12 @@ class GmmTiles(NamedTuple):
 
 def _lane_tiles(width: int):
     """The 128-multiples that divide ``width``, widest first (the TPU
-    lowering wants a block's last dimension a multiple of 128 lanes)."""
-    return [t for t in range(width - width % 128, 0, -128) if width % t == 0]
+    lowering wants a block's last dimension a multiple of 128 lanes, or the
+    array's whole dimension); where none does and the width is at least a
+    lane tile, the whole width as one block (1856 = 2^6 * 29)."""
+    tiles = [t for t in range(width - width % 128, 0, -128)
+             if width % t == 0]
+    return tiles or ([width] if width >= 128 else [])
 
 
 def _fits(lhs, rhs, out, itemsize: int) -> bool:
@@ -292,15 +296,32 @@ def grouped_matmul(rows: jax.Array, weights: jax.Array,
                 interpret)
 
 
-def route(logits: jax.Array, k: int, renormalize: bool
-          ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Float32 softmax over the experts, then top-k. Returns the router
-    probabilities ``[G, E]``, the k weights (divided by their sum when
-    ``renormalize``) and the k expert indices ``[G, k]``."""
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    weights, experts = lax.top_k(probs, k)
+def route(logits: jax.Array, k: int, renormalize: bool,
+          scores: str = "softmax", bias: Optional[jax.Array] = None,
+          scale: float = 1.0) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Float32 scores over the experts, then top-k. Returns the scores
+    ``[G, E]``, the k weights (divided by their sum when ``renormalize``)
+    and the k expert indices ``[G, k]``. ``scores`` "softmax": the softmax
+    over the experts. "sigmoid" (DeepSeek-V3's router, arXiv:2412.19437,
+    which Nemotron-H's experts take): each expert's own ``sigmoid(logit)``;
+    the choice is the top-k of ``score + bias`` (``bias`` ``[E]``, the
+    correction that balances the load: it moves the choice and never a
+    weight, so no gradient reaches it), the weights are the chosen scores,
+    then ``scale`` times them."""
+    if scores == "softmax":
+        probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+        weights, experts = lax.top_k(probs, k)
+    elif scores == "sigmoid":
+        probs = jax.nn.sigmoid(logits.astype(jnp.float32))
+        _, experts = lax.top_k(
+            probs if bias is None else probs + bias.astype(jnp.float32), k)
+        weights = jnp.take_along_axis(probs, experts, axis=-1)
+    else:
+        raise ValueError(f"router scores {scores!r}")
     if renormalize:
         weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    if scale != 1.0:
+        weights = weights * scale
     return probs, weights, experts
 
 
@@ -489,7 +510,9 @@ def moe_layer_spmd(x: jax.Array, router_w: jax.Array,
                    renormalize: bool = False,
                    stat_axes: Sequence[str] = (),
                    logits: Optional[jax.Array] = None,
-                   share: Tuple[int, int] = (0, 1)
+                   share: Tuple[int, int] = (0, 1),
+                   scores: str = "softmax",
+                   bias: Optional[jax.Array] = None, scale: float = 1.0
                    ) -> Tuple[jax.Array, MoEMetrics]:
     """SPMD MoE (inside shard_map). Local shapes:
 
@@ -507,7 +530,7 @@ def moe_layer_spmd(x: jax.Array, router_w: jax.Array,
     router_w`` here. ``share=(index, of)`` with no live ``axis_name``: this
     device holds the experts ``[index * E / of, (index + 1) * E / of)`` as
     ``expert_params``' leading dimension, and the result is their part of
-    the layer's output."""
+    the layer's output. ``scores``, ``bias``, ``scale``: :func:`route`'s."""
     n = axis_size(axis_name) if axis_name else 1
     G, M = x.shape
     E = router_w.shape[1]
@@ -530,7 +553,12 @@ def moe_layer_spmd(x: jax.Array, router_w: jax.Array,
     with jax.named_scope(scopes.MOE_ROUTER):
         if logits is None:
             logits = x.astype(jnp.float32) @ router_w.astype(jnp.float32)
-        probs, weights, experts = route(logits, k, renormalize)
+        # (the plain router by its three arguments, as every caller of
+        # ``route`` and the tests that swap it know it)
+        plain = scores == "softmax" and bias is None and scale == 1.0
+        other = {} if plain else {"scores": scores, "bias": bias,
+                                  "scale": scale}
+        probs, weights, experts = route(logits, k, renormalize, **other)
 
     with jax.named_scope(scopes.MOE_DISPATCH):
         if n > 1:

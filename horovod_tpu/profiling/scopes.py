@@ -59,6 +59,19 @@ MOE_ROUTER = "hvd.moe.router"
 MOE_DISPATCH = "hvd.moe.dispatch"
 MOE_EXPERTS = "hvd.moe.experts"
 MOE_COMBINE = "hvd.moe.combine"
+#: nested in MOE: the expert every token runs (``moe_shared_width``), whole
+#: on every chip of an expert-parallel group
+MOE_SHARED = "hvd.moe.shared"
+#: a state-space (Mamba-2) block with its norm and residual, and its four
+#: parts. Proj: both projections, in and out. Conv: the causal depthwise
+#: convolution and its activation. Scan: the time steps and decays, the
+#: chunks' products, the carried state, the skip ``D x``. Norm: the gate
+#: and the grouped RMSNorm
+SSM = "hvd.ssm"
+SSM_PROJ = "hvd.ssm.proj"
+SSM_CONV = "hvd.ssm.conv"
+SSM_SCAN = "hvd.ssm.scan"
+SSM_NORM = "hvd.ssm.norm"
 #: nested in LAYERS: a looped model's passes through its stack, with the
 #: final norm that closes each loop step
 LOOP = "hvd.loop"
@@ -74,13 +87,16 @@ OPTIMIZER = "hvd.optimizer"
 MODEL_PHASES = (EMBED, LAYERS, ATTENTION, ATTENTION_CORE, MLP, HEAD)
 #: phases only an MoE model has, each forward and backward
 MOE_PHASES = (MOE, MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS, MOE_COMBINE)
+#: phases only a stack of one-sublayer blocks with state-space blocks and a
+#: shared expert has (Nemotron-H), each forward and backward
+HYBRID_PHASES = (MOE_SHARED, SSM, SSM_PROJ, SSM_CONV, SSM_SCAN, SSM_NORM)
 #: phases only a looped model has, each forward and backward
 LOOP_PHASES = (LOOP, LOOP_GATE)
 #: phases only a stack with several kinds of layer has, each forward and
 #: backward
 MIXED_PHASES = (ATTENTION_CORE_WINDOW, ATTENTION_CORE_FULL)
 DEVICE_PHASES = (MODEL_PHASES + MOE_PHASES + LOOP_PHASES + MIXED_PHASES
-                 + (GRAD_SYNC, OPTIMIZER))
+                 + HYBRID_PHASES + (GRAD_SYNC, OPTIMIZER))
 
 # -- host spans (profiling.annotate) ------------------------------------------
 #: the input iterator's ``next()``: the host makes the batch

@@ -340,3 +340,91 @@ def test_the_mixed_step_gives_what_the_smallthinker_adapter_reads():
                              cfg.moe_top_k)
     assert (scopes.ATTENTION_CORE_WINDOW, scopes.ATTENTION_CORE_FULL) == (
         "hvd.attention.core.window", "hvd.attention.core.full")
+
+
+def test_the_hybrid_step_gives_what_the_nemotron_h_adapter_reads():
+    """``adapters/nemotron_h.py`` names leaves of the by-word parameter tree
+    (``_leaf_paths``, ``_init_function``), reads ``held_rows`` and
+    ``dropped`` from the step's fourth output and ``router_choices``; the
+    configuration's fields reach ``TransformerConfig`` by keyword as
+    ``layer_pattern`` words, ``ssm_*``, ``moe_router_scores``,
+    ``moe_routed_scale``, ``moe_shared_width``, ``moe_activation``; the
+    phase files look for the mixer's and the shared expert's scopes, the
+    roofline functions for the adapter's ``shapes()`` keys."""
+    import sys
+    import jax
+    import numpy as np
+    if CHIP not in sys.path:
+        sys.path.insert(0, CHIP)
+    from adapters import nemotron_h
+    from trees import get_leaves
+    from horovod_tpu.models import transformer as t
+    from horovod_tpu.parallel import build_mesh
+    from horovod_tpu.profiling import scopes
+    for name in ("shapes", "tokens_per_step", "flops_per_token",
+                 "host_batch", "abstract_step", "Cell"):
+        assert callable(getattr(nemotron_h, name)), name
+    assert {"program_choices", "program_loss_and_grads", "compiled_step",
+            "step"} <= set(dir(nemotron_h.Cell))
+    with open(os.path.join(CHIP, "configs",
+                           "nemotron-3-nano-30b-a3b.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(CHIP, "workloads",
+                           "train.s8192.b1.hybrid.json")) as f:
+        job = json.load(f)
+    full = nemotron_h._model_config(config, job)
+    assert full.layer_pattern == (("mamba",), ("experts",)) * 3 + (
+        ("mamba",), ("attention", None, False), ("experts",))
+    assert (full.ssm_heads, full.ssm_head_dim, full.ssm_state,
+            full.ssm_groups, full.ssm_conv, full.ssm_chunk) == (
+                64, 64, 128, 8, 4, 128)
+    assert (full.moe_router_scores, full.moe_routed_scale,
+            full.moe_shared_width, full.moe_activation, full.moe_gated,
+            full.expert_share, full.held_experts) == (
+                "sigmoid", 2.5, 3712, "relu2", False, (0, 16), 8)
+    for function, module in (
+            ("hybrid_moe_gmm", "roofline_hybrid_moe_gmm"),
+            ("hybrid_flash_attention", "roofline_hybrid_flash_attention"),
+            ("hybrid_flash_attention_backward",
+             "roofline_hybrid_flash_attention_backward")):
+        need = getattr(importlib.import_module(module), function)(
+            nemotron_h.shapes(config, job))
+        assert need["flops"] > 0 and need["bytes"] > 0, function
+    config, job = {**config, **config["tiny"]}, {**job, **job["tiny"]}
+    cfg = nemotron_h._model_config(config, job)
+    params = t.init_params(np.random.RandomState(0), cfg, 1)
+    assert set(params["layers"]) == {"mamba", "experts", "attention"}
+    assert set(params["layers"]["mamba"]) == {
+        "ln1", "ssm_in", "ssm_conv_w", "ssm_conv_b", "ssm_dt_bias",
+        "ssm_a_log", "ssm_d", "ssm_norm", "ssm_out"}
+    assert set(params["layers"]["experts"]) == {
+        "ln2", "router", "router_bias", "we1", "we2", "ws1", "ws2"}
+    assert set(params["layers"]["attention"]) == {"ln1", "wq", "wk", "wv",
+                                                  "wo"}
+    assert params["layers"]["mamba"]["ssm_in"].shape == (
+        1, 8, cfg.d_model,
+        2 * cfg.ssm_inner + 2 * cfg.ssm_groups * cfg.ssm_state
+        + cfg.ssm_heads)
+    ours = jax.eval_shape(nemotron_h._init_function(cfg, config),
+                          jax.random.PRNGKey(0))
+    assert jax.tree_util.tree_map(lambda a: a.shape, ours) == \
+        jax.tree_util.tree_map(lambda a: a.shape, params)
+    assert set(get_leaves(params, nemotron_h._leaf_paths(
+        config["hybrid_override_pattern"]))) == {
+            "lm_head", "first_ssm_in", "last_ssm_a_log", "attention_key",
+            "last_router", "last_experts_down", "last_shared_down"}
+    mesh = build_mesh(devices=jax.devices()[:1], dp=-1)
+    batch = nemotron_h.host_batch(config, job, 0, 0, 1)
+    _loss, aux, _grads = jax.eval_shape(
+        t.make_grad_fn(cfg, mesh), params, batch["tokens"], batch["targets"])
+    assert set(aux) == {"aux_loss", "load_balance_loss", "router_z_loss",
+                        "max_expert_load", "dropped", "held_rows"}
+    choices = jax.eval_shape(
+        lambda p, tok: t.router_choices(p, tok, cfg), params,
+        batch["tokens"])
+    assert choices.shape == (
+        config["hybrid_override_pattern"].count("E"), batch["tokens"].size,
+        cfg.moe_top_k)
+    assert scopes.HYBRID_PHASES == (
+        "hvd.moe.shared", "hvd.ssm", "hvd.ssm.proj", "hvd.ssm.conv",
+        "hvd.ssm.scan", "hvd.ssm.norm")

@@ -25,7 +25,7 @@ PACKAGE = os.path.join(os.path.dirname(os.path.dirname(
 
 def test_the_vocabulary_is_the_only_place_the_strings_are_written():
     names = scopes.DEVICE_PHASES + scopes.HOST_SPANS
-    assert len(set(names)) == len(names) == 21
+    assert len(set(names)) == len(names) == 27
     assert scopes.HOST_SPANS == ("hvd.input.source", "hvd.input.place",
                                  "hvd.host.gc", "hvd.host.compile")
     assert all(n.startswith("hvd.") for n in names)
@@ -146,6 +146,36 @@ def _mixed_step():
             (params, init_opt_state(tx, params, mesh, cfg), t, y))
 
 
+def _hybrid_step():
+    """A stack of one-sublayer blocks: a Mamba-2 mixer, sigmoid-routed
+    ungated experts with a shared expert of which a share is held,
+    attention without positions."""
+    from horovod_tpu.models import (TransformerConfig, init_opt_state,
+                                    init_params, make_train_step,
+                                    shard_batch, shard_params)
+    cfg = TransformerConfig(vocab_size=64, d_model=32, n_heads=4,
+                            n_layers=3, d_ff=16, max_seq=32, n_experts=4,
+                            moe_top_k=2, moe_renormalize=True,
+                            moe_activation="relu2", moe_balance_weight=0.0,
+                            moe_router_scores="sigmoid",
+                            moe_routed_scale=2.5, moe_shared_width=32,
+                            tie_embeddings=False, dtype=jnp.float32,
+                            head_width=16, n_kv_heads=2, layer_pattern=(
+                                ("mamba",), ("experts",),
+                                ("attention", None, False)),
+                            expert_share=(0, 2), ssm_heads=4,
+                            ssm_head_dim=8, ssm_state=8, ssm_groups=2,
+                            ssm_chunk=8)
+    mesh = build_mesh(devices=jax.devices()[:1], dp=-1)
+    params = shard_params(init_params(np.random.RandomState(0), cfg, 1),
+                          cfg, mesh)
+    tx = optax.adamw(1e-3)
+    tokens = np.zeros((2, 16), np.int32)
+    t, y = shard_batch(tokens, tokens, mesh)
+    return (make_train_step(cfg, mesh, tx),
+            (params, init_opt_state(tx, params, mesh, cfg), t, y))
+
+
 _TEXTS = {}
 
 
@@ -154,7 +184,8 @@ def _compiled_text(model: str) -> str:
         step, args = {"bert": _bert_step, "flagship": _flagship_step,
                       "flagship.dp2": lambda: _flagship_step(2),
                       "moe": _moe_step, "looped": _looped_step,
-                      "mixed": _mixed_step}[model]()
+                      "mixed": _mixed_step,
+                      "hybrid": _hybrid_step}[model]()
         _TEXTS[model] = step.lower(*args).compile().as_text()
     return _TEXTS[model]
 
@@ -194,6 +225,29 @@ def test_the_looped_step_carries_every_phase_in_both_directions(phase):
                          + scopes.MIXED_PHASES)
 def test_the_mixed_step_carries_every_phase_in_both_directions(phase):
     assert _directions(_compiled_text("mixed"), phase) == {"fwd", "bwd"}
+
+
+@pytest.mark.parametrize("phase", scopes.MODEL_PHASES + scopes.MOE_PHASES
+                         + scopes.HYBRID_PHASES
+                         + (scopes.ATTENTION_CORE_FULL,))
+def test_the_hybrid_step_carries_every_phase_in_both_directions(phase):
+    assert _directions(_compiled_text("hybrid"), phase) == {"fwd", "bwd"}
+
+
+def test_the_mixer_s_parts_nest_in_ssm_and_the_shared_expert_in_moe():
+    paths = re.findall(r'op_name="([^"]*)"', _compiled_text("hybrid"))
+
+    def parts(path):
+        return [re.sub(r"^(?:\w+\()+|\)+$", "", c) for c in path.split("/")]
+    for inner in scopes.HYBRID_PHASES[2:]:
+        inside = [parts(p) for p in paths if inner in parts(p)]
+        assert inside and all(scopes.SSM in p for p in inside), inner
+    shared = [parts(p) for p in paths if scopes.MOE_SHARED in parts(p)]
+    assert shared and all(scopes.MOE in p and scopes.MLP in p
+                          for p in shared)
+    # a Mamba block is no attention and no MLP
+    assert not any(scopes.ATTENTION in p or scopes.MLP in p
+                   for p in map(parts, paths) if scopes.SSM in p)
 
 
 def test_the_layer_kinds_nest_in_the_attention_core():

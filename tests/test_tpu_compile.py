@@ -106,6 +106,10 @@ _GMM_DOWN = [((65536, 1024), jnp.bfloat16), ((64, 1024, 2048), jnp.float32),
 # what Mosaic allocates beside the blocks is this compile's to say
 _GMM_SHARE = [((49152, 2560), jnp.bfloat16), ((16, 2560, 768), jnp.float32),
               ((16,), jnp.int32)]
+_GMM_HYBRID = [((49152, 2688), jnp.bfloat16), ((8, 2688, 1856), jnp.float32),
+               ((8,), jnp.int32)]
+_GMM_HYBRID_DOWN = [((49152, 1856), jnp.bfloat16),
+                    ((8, 1856, 2688), jnp.float32), ((8,), jnp.int32)]
 _GMM_SHARE_DOWN = [((49152, 768), jnp.bfloat16),
                    ((16, 768, 2560), jnp.float32), ((16,), jnp.int32)]
 _BLOCKS = ((8192, 256), jnp.float32)
@@ -163,6 +167,17 @@ CASES = {
     "moe_gmm_share_down_grad": (
         jax.grad(lambda x, w, g: _sum32(moe.grouped_matmul(x, w, g)),
                  (0, 1)), _GMM_SHARE_DOWN, "transpose_jvp_" + moe.GMM_NAME),
+    # an expert width no 128-multiple divides (1856 = 2^6 * 29): a block
+    # spans it whole, as the contraction and as the output's columns
+    "moe_gmm_hybrid_grad": (
+        jax.grad(lambda x, w, g: _sum32(moe.grouped_matmul(x, w, g)),
+                 (0, 1)), _GMM_HYBRID, "transpose_jvp_" + moe.GMM_NAME),
+    "moe_gmm_hybrid_down": (moe.grouped_matmul, _GMM_HYBRID_DOWN,
+                            moe.GMM_NAME),
+    "moe_gmm_hybrid_down_grad": (
+        jax.grad(lambda x, w, g: _sum32(moe.grouped_matmul(x, w, g)),
+                 (0, 1)), _GMM_HYBRID_DOWN,
+        "transpose_jvp_" + moe.GMM_NAME),
     # an ep shard's share at the four-chip smoke's MoE: float32, 128 wide
     "moe_gmm_smoke_grad": (
         jax.grad(lambda x, w, g: _sum32(moe.grouped_matmul(x, w, g)),
