@@ -41,9 +41,11 @@ Phases (default, one chip):
            kernel's rows and chunk and its grid steps, or why XLA).
            The untied embedding's lookup and hand-written gradient at two
            cells' tables, Zipf ids, against float64 sums.
-           The Mamba-2 scan's chunked form at the hybrid cell's heads
-           against the recurrence one position at a time; ssm_path says
-           the chunk count and what the backward pass keeps.
+           The Mamba-2 scan on its kernels (hvd_ssm_scan, hvd_ssm_scan_bwd)
+           at the hybrid cell's heads against the recurrence one position
+           at a time and against the jax.numpy form; ssm_path says the
+           kernels' tiles, the chunk count and what the backward pass
+           keeps.
 
 ``--chips 4`` runs only the four-chip phase and what it is compared with:
 BERT-Large dp=4 against one device at 2 x 512 tokens a chip (the block
@@ -700,16 +702,20 @@ def _check_gmm(smoke: Smoke) -> None:
 
 
 def _check_ssm(smoke: Smoke) -> None:
-    """The Mamba-2 scan in its chunked form (models/transformer.py:
-    ssm_chunked), bfloat16 operands with float32 time steps, decays and
-    carried state, against the recurrence one position at a time in
+    """The Mamba-2 scan on its kernels (ops/pallas_ssm.py: hvd_ssm_scan,
+    hvd_ssm_scan_bwd, through models/transformer.py:ssm_chunked as a Mamba
+    block calls it), bfloat16 operands with float32 time steps, sums, decays
+    and carried state, against the recurrence one position at a time in
     float32, forward and every operand's gradient, at the hybrid cell's
-    heads. Prints ``ssm_path`` at the cell's length: the chunk count and
-    what the backward pass keeps of a block."""
+    heads; and against the ``jax.numpy`` form on the same operands. Prints
+    ``ssm_path`` at the cell's length: the kernels' tiles, the chunk count
+    and what the backward pass keeps of a block."""
     import jax
     import jax.numpy as jnp
+    from horovod_tpu.models import transformer
     from horovod_tpu.models.transformer import (TransformerConfig,
                                                 ssm_chunked, ssm_path)
+    from horovod_tpu.ops import pallas_ssm
     S, H, P, G, N, chunk = smoke.sizes.ssm
     keys = jax.random.split(jax.random.PRNGKey(smoke.seed + 5), 6)
     x = jax.random.normal(keys[0], (1, S, H, P), jnp.bfloat16)
@@ -732,22 +738,34 @@ def _check_ssm(smoke: Smoke) -> None:
             jnp.moveaxis(v, 1, 0) for v in (x, dt, b, c)))
         return jnp.moveaxis(y, 0, 1)
 
+    def kernels(*ops):
+        return ssm_chunked(*ops, chunk, interpret=smoke.rehearsal)
+
+    def numpy_form(x, dt, a, b, c):
+        return transformer._ssm_chunked_numpy(
+            x, dt, transformer._chunk_sums(dt * a, chunk), b, c, chunk)
+
     def loss(f):
         return lambda *ops: jnp.sum(f(*ops) * ct)
     ops = (x, dt, a, b, c)
     cfg = TransformerConfig(ssm_heads=H, ssm_head_dim=P, ssm_state=N,
                             ssm_groups=G, ssm_chunk=chunk)
-    got = jax.jit(lambda *o: ssm_chunked(*o, chunk))(*ops)
+    got = _run_compiled(smoke, kernels, ops, pallas_ssm.FWD_NAME)
     _kernel_line(smoke, "mamba-2 scan", "fwd",
                  _rel_err(got, jax.jit(stepwise)(*ops)), SSM_TOL,
-                 shape=(S, H, P, G, N), ran="xla",
+                 shape=(S, H, P, G, N),
+                 against_the_numpy_form=_rel_err(
+                     got, jax.jit(numpy_form)(*ops)),
                  ssm_path_at_the_hybrid_cell=ssm_path(cfg, 8 * S))
-    got = jax.jit(jax.grad(loss(lambda *o: ssm_chunked(*o, chunk)),
-                           (0, 1, 2, 3, 4)))(*ops)
-    want = jax.jit(jax.grad(loss(stepwise), (0, 1, 2, 3, 4)))(*ops)
-    for leaf, g, r in zip(("d_x", "d_dt", "d_a", "d_b", "d_c"), got, want):
+    leaves = (0, 1, 2, 3, 4)
+    got = _run_compiled(smoke, jax.grad(loss(kernels), leaves), ops,
+                        pallas_ssm.BWD_NAME)
+    want = jax.jit(jax.grad(loss(stepwise), leaves))(*ops)
+    other = jax.jit(jax.grad(loss(numpy_form), leaves))(*ops)
+    for leaf, g, r, n in zip(("d_x", "d_dt", "d_a", "d_b", "d_c"), got, want,
+                             other):
         _kernel_line(smoke, "mamba-2 scan", f"grad {leaf}", _rel_err(g, r),
-                     SSM_TOL, ran="xla")
+                     SSM_TOL, against_the_numpy_form=_rel_err(g, n))
 
 
 def _check_embed(smoke: Smoke) -> None:

@@ -53,6 +53,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from horovod_tpu._compat import axis_size, shard_map
 
 from horovod_tpu.models.scan_util import multi_step
+from horovod_tpu.ops import pallas_ssm
 from horovod_tpu.parallel.ring_attention import ring_attention_spmd
 from horovod_tpu.parallel.moe import grouped_matmul, moe_layer_spmd
 from horovod_tpu.profiling import scopes
@@ -799,7 +800,15 @@ def _within_chunks(x, b, c, s, dt):
                       preferred_element_type=jnp.float32)
 
 
-def ssm_chunked(x, dt, a, b, c, chunk: int):
+def _chunk_sums(steps, chunk: int):
+    """``s_i = sum_{t <= i} steps_t`` inside every chunk of ``chunk``
+    positions, ``[B, S, H]`` float32."""
+    B, S, H = steps.shape
+    return jnp.cumsum(steps.reshape(B, S // chunk, chunk, H), axis=2
+                      ).reshape(B, S, H)
+
+
+def ssm_chunked(x, dt, a, b, c, chunk: int, interpret: bool = False):
     """The selective state-space recurrence of Mamba-2 in its chunked
     (dual) form (arXiv:2405.21060, section 6). Per head, with ``a_t = dt_t
     a`` (``a`` < 0) and the state ``H`` ``[P, N]``:
@@ -822,18 +831,39 @@ def ssm_chunked(x, dt, a, b, c, chunk: int):
     x ``[B, S, H, P]``; dt ``[B, S, H]`` float32, after its softplus; a
     ``[H]`` float32; b, c ``[B, S, G, N]``, head h reading group ``h // (H
     / G)``. Returns y ``[B, S, H, P]`` float32 (without the skip ``D x``).
+
+    On a TPU (and under ``interpret``) the chunks run in the Pallas kernels
+    of ``ops/pallas_ssm.py`` wherever the shapes fit their tiles
+    (:func:`pallas_ssm.ssm_eligible`): the same algorithm at the same
+    precision, the sums ``s`` made here, nothing of a chunk's inside in
+    HBM. Elsewhere, and as what the kernels are held against, the
+    ``jax.numpy`` form below (:func:`_ssm_chunked_numpy`).
     """
     B, S, H, P = x.shape
     G, N = b.shape[2:]
     if S % chunk:
         raise ValueError(f"ssm_chunk={chunk} does not divide the sequence "
                          f"of {S} positions")
+    s = _chunk_sums(dt * a, chunk)
+    if interpret or (jax.default_backend() == "tpu"
+                     and pallas_ssm.ssm_eligible(S, H, P, G, N, chunk)):
+        return pallas_ssm.ssm_scan(x, dt, s, b, c, chunk, interpret)
+    return _ssm_chunked_numpy(x, dt, s, b, c, chunk)
+
+
+def _ssm_chunked_numpy(x, dt, s, b, c, chunk: int):
+    """:func:`ssm_chunked` from the sums ``s`` ``[B, S, H]`` on, in
+    ``jax.numpy`` and differentiated by JAX: the scores, decays and their
+    product inside a chunk, the chunks' own states and the states they
+    start from are arrays of their own."""
+    B, S, H, P = x.shape
+    G, N = b.shape[2:]
     n, R = S // chunk, H // G
     x = x.reshape(B, n, chunk, G, R, P)
     b, c = (v.reshape(B, n, chunk, G, N) for v in (b, c))
     # [B, n, G, R, Q]: a head's positions last
-    dt = dt.reshape(B, n, chunk, G, R).transpose(0, 1, 3, 4, 2)
-    s = jnp.cumsum(dt * a.reshape(G, R, 1), axis=-1)
+    dt, s = (v.reshape(B, n, chunk, G, R).transpose(0, 1, 3, 4, 2)
+             for v in (dt, s))
 
     y = _within_chunks(x, b, c, s, dt)
 
@@ -858,7 +888,10 @@ def ssm_path(cfg: TransformerConfig, seq_len: int) -> str:
     kept = ("each Mamba block checkpointed: its input kept, the block run "
             "again in the backward pass" if _remat(cfg, True) else
             "everything kept for the backward pass")
-    return (f"jax.numpy chunked scan, {seq_len // cfg.ssm_chunk} chunks of "
+    how = pallas_ssm.ssm_scan_path(
+        seq_len, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups,
+        cfg.ssm_state, cfg.ssm_chunk)
+    return (f"{how}; chunked scan, {seq_len // cfg.ssm_chunk} chunks of "
             f"{cfg.ssm_chunk}, float32 sums, decays and carried state "
             f"[{cfg.ssm_heads}, {cfg.ssm_head_dim}, {cfg.ssm_state}]; {kept}")
 

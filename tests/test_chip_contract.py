@@ -47,7 +47,7 @@ CLIENT_FILES = [os.path.join(CHIP, "run.py")] + sorted(
     + glob.glob(os.path.join(CHIP, "readers", "*.py")))
 KERNEL_FILES = [os.path.join(REPO, "horovod_tpu", *p) for p in (
     ("ops", "pallas_attention.py"), ("ops", "pallas_xent.py"),
-    ("parallel", "moe.py"))]
+    ("ops", "pallas_ssm.py"), ("parallel", "moe.py"))]
 #: read by name in a run's ``breakdown`` (PERF.md §3) until a metric file
 #: names them
 BLOCK_KERNELS = ("hvd_block_attention", "hvd_block_attention_bwd")
@@ -386,7 +386,8 @@ def test_the_hybrid_step_gives_what_the_nemotron_h_adapter_reads():
             ("hybrid_moe_gmm", "roofline_hybrid_moe_gmm"),
             ("hybrid_flash_attention", "roofline_hybrid_flash_attention"),
             ("hybrid_flash_attention_backward",
-             "roofline_hybrid_flash_attention_backward")):
+             "roofline_hybrid_flash_attention_backward"),
+            ("hybrid_ssm_scan", "roofline_hybrid_ssm_scan")):
         need = getattr(importlib.import_module(module), function)(
             nemotron_h.shapes(config, job))
         assert need["flops"] > 0 and need["bytes"] > 0, function

@@ -189,6 +189,26 @@ def test_the_kernels_least_work_by_hand():
         2.5 * need["flops"]
 
 
+def test_the_scan_kernels_least_work_by_hand():
+    """Four Mamba blocks, two forward calls and one backward call each, at
+    8192 positions of 64 heads of 64, 8 groups, state 128, chunk 128."""
+    import roofline_hybrid_ssm_scan as scan
+    config, job = _cell(tiny=False)
+    need = scan.hybrid_ssm_scan(adapter.shapes(config, job))
+    scores, weighted = 2 * 8 * 128 * 64.5, 2 * 4096 * 64.5
+    state = 2 * 4096 * 128
+    forward = scores + weighted + 2 * state
+    assert need["flops"] == 4 * 8192 * (
+        2 * forward + 2 * forward + scores + state)
+    x, bc, sums, y = 4096 * 2, 2 * 1024 * 2, 2 * 64 * 4, 4096 * 4
+    assert need["bytes"] == 4 * 8192 * (
+        2 * (x + bc + sums + y) + (x + bc + sums + y) + (x + bc + sums)
+        + 2 * 4096 * 128 * 4 / 128)
+    # 0.24 GB a forward call, the scan's matmuls 1/20 of a block's
+    assert 0.23e9 < 8192 * (x + bc + sums + y) < 0.25e9
+    assert need["bytes"] / 819e9 > need["flops"] / 197e12
+
+
 # -- the program against the reference ---------------------------------------
 
 @pytest.fixture(scope="module")
@@ -394,6 +414,23 @@ def test_the_sound_small_stack_matches_the_reference(small_reference):
     assert _small_error(SMALL, small_reference) < TOL
 
 
+@pytest.fixture
+def on_the_kernels(monkeypatch):
+    """The Mamba blocks' scan on ``ops/pallas_ssm.py``'s kernels, in
+    interpret mode (what a TPU runs at the cell's shapes)."""
+    import functools
+    monkeypatch.setattr(t, "ssm_chunked", functools.partial(
+        t.ssm_chunked, interpret=True))
+
+
+def test_the_sound_small_stack_matches_the_reference_on_the_kernels(
+        small_reference, on_the_kernels):
+    calls = str(jax.make_jaxpr(lambda p, b: t.forward_loss_spmd(
+        p, b["tokens"], b["targets"], SMALL)[0])(*small_reference[:2]))
+    assert "pallas_call" in calls
+    assert _small_error(SMALL, small_reference) < TOL
+
+
 @pytest.mark.parametrize("what, change", [
     ("a chunk boundary that drops the carried state",
      {"patch": (t, "_carried_states", _no_carried_state)}),
@@ -419,6 +456,27 @@ def test_a_wrong_term_fails(monkeypatch, small_reference, what, change):
     cfg = dataclasses.replace(SMALL, **change.get("cfg", {}))
     err = _small_error(cfg, small_reference)
     assert err > 5 * TOL, (what, err)
+
+
+def _kernel_state_not_carried(state, whole, own):
+    return own
+
+
+@pytest.mark.parametrize("what, patch", [
+    ("a chunk boundary that drops the carried state",
+     ("_carry", _kernel_state_not_carried)),
+    ("the decays made in bfloat16", ("_decay", _bf16_decay)),
+])
+def test_a_wrong_scan_fails_on_the_kernels(monkeypatch, small_reference,
+                                           on_the_kernels, what, patch):
+    """The same stack with the scan on the kernels: a dropped state moves
+    the loss far beyond TOL, bfloat16 decays a block's gradients by four
+    times TOL and more (``test_the_scan_s_decays_in_bfloat16_fail`` says
+    why a whole block reads less than the scan alone)."""
+    from horovod_tpu.ops import pallas_ssm
+    monkeypatch.setattr(pallas_ssm, *patch)
+    err = _small_error(SMALL, small_reference)
+    assert err > 4 * TOL, (what, err)
 
 
 # -- the share cut: one expert layer ---------------------------------------------

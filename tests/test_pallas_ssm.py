@@ -1,0 +1,257 @@
+"""The Mamba-2 scan's Pallas kernels (``ops/pallas_ssm.py``: ``hvd_ssm_scan``,
+``hvd_ssm_scan_bwd``) in interpret mode on the CPU, in float32, against the
+``jax.numpy`` form of ``models/transformer.py:ssm_chunked`` and against the
+recurrence one position at a time (the benchmark's plain reference), at
+shapes that cross three chunk boundaries and have two groups; one of them
+has two lane tiles of two heads a group.
+
+TOL is ``tests/test_nemotron_h.py``'s: both sides are float32 and differ in
+the order of their sums (1e-8 to 3e-5 here); what TOL must not let through
+(a decay in bfloat16, a state that is not carried, a missing causal mask)
+reads 4.5 times it (the bfloat16 decays) and more.
+"""
+
+import os
+import sys
+
+import numpy as np
+import pytest
+import jax
+import jax.numpy as jnp
+
+from horovod_tpu.models import transformer as t
+from horovod_tpu.ops import pallas_ssm as ps
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+_CHIP = os.path.join(_REPO, "benchmarks", "chip")
+if _CHIP not in sys.path:
+    sys.path.insert(0, _CHIP)
+
+from reference import nemotron_h as reference         # noqa: E402
+
+TOL = 1e-4
+#: (B, S, H, P, G, N, chunk): four chunks, two groups; heads that share a
+#: lane tile (2 or 4 of them), a head that is a tile, two tiles a group
+SHAPES = {
+    "two heads a tile": (2, 64, 4, 8, 2, 16, 16),
+    "four heads a tile": (1, 64, 8, 8, 2, 16, 16),
+    "two tiles of two heads": (1, 64, 8, 64, 2, 16, 16),
+    "a head a tile": (1, 64, 4, 128, 2, 8, 16),
+}
+NAMES = ("x", "dt", "a", "b", "c")
+
+
+def _operands(shape, seed=0, dtype=jnp.float32):
+    B, S, H, P, G, N, _chunk = shape
+    rng = np.random.RandomState(seed)
+    x = jnp.asarray(rng.randn(B, S, H, P), dtype)
+    dt = jax.nn.softplus(jnp.asarray(rng.randn(B, S, H) - 1, jnp.float32))
+    a = -jnp.exp(jnp.asarray(rng.uniform(0, 2.5, H), jnp.float32))
+    b, c = (jnp.asarray(rng.randn(B, S, G, N), dtype) for _ in range(2))
+    return x, dt, a, b, c
+
+
+def _weight(shape, seed=1):
+    B, S, H, P = shape[:4]
+    return jnp.asarray(np.random.RandomState(seed).randn(B, S, H, P),
+                       jnp.float32)
+
+
+def _kernels(x, dt, a, b, c, chunk):
+    return t.ssm_chunked(x, dt, a, b, c, chunk, interpret=True)
+
+
+def _stepwise(x, dt, a, b, c):
+    heads, groups = x.shape[2], b.shape[2]
+    return reference.recurrence(
+        x, dt, a, *(jnp.repeat(v, heads // groups, axis=2) for v in (b, c)))
+
+
+def _rel(got, want):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    return np.linalg.norm(got - want) / (np.linalg.norm(want) or 1.0)
+
+
+def _sums(dt, a, chunk):
+    return t._chunk_sums(dt * a, chunk)
+
+
+@pytest.mark.parametrize("shape", SHAPES.values(), ids=SHAPES.keys())
+def test_the_forward_kernel_is_the_numpy_form_and_the_recurrence(shape):
+    ops, chunk = _operands(shape), shape[-1]
+    tiles = ps.ssm_tiles(*shape[2:5])
+    assert tiles.tiles * tiles.heads_per_tile == shape[2] // shape[4]
+    got = _kernels(*ops, chunk)
+    assert got.dtype == jnp.float32 and got.shape == ops[0].shape
+    assert _rel(got, t.ssm_chunked(*ops, chunk)) < TOL
+    assert _rel(got, _stepwise(*ops)) < TOL
+
+
+@pytest.mark.parametrize("shape", SHAPES.values(), ids=SHAPES.keys())
+def test_every_cotangent_is_autodiff_s_of_the_numpy_form(shape):
+    """x, dt (through the sums and directly), a, b and c."""
+    ops, chunk, weight = _operands(shape), shape[-1], _weight(shape)
+    got = jax.grad(lambda *v: jnp.sum(_kernels(*v, chunk) * weight),
+                   (0, 1, 2, 3, 4))(*ops)
+    want = jax.grad(lambda *v: jnp.sum(t.ssm_chunked(*v, chunk) * weight),
+                    (0, 1, 2, 3, 4))(*ops)
+    stepwise = jax.grad(lambda *v: jnp.sum(_stepwise(*v) * weight),
+                        (0, 1, 2, 3, 4))(*ops)
+    for name, g, w, r in zip(NAMES, got, want, stepwise):
+        assert g.dtype == w.dtype and g.shape == w.shape, name
+        assert _rel(g, w) < TOL, name
+        assert _rel(g, r) < TOL, name
+
+
+def test_dt_s_and_the_sums_cotangents_apart():
+    """The kernels take dt and the sums as two operands and return a
+    cotangent for each: each against autodiff of the ``jax.numpy`` form
+    from the same two operands."""
+    shape = SHAPES["two heads a tile"]
+    (x, dt, a, b, c), chunk, weight = _operands(shape), 16, _weight(shape)
+    s = _sums(dt, a, chunk)
+    got = jax.grad(lambda dt, s: jnp.sum(
+        ps.ssm_scan(x, dt, s, b, c, chunk, True) * weight), (0, 1))(dt, s)
+    want = jax.grad(lambda dt, s: jnp.sum(
+        t._ssm_chunked_numpy(x, dt, s, b, c, chunk) * weight), (0, 1))(dt, s)
+    for name, g, w in zip(("dt", "s"), got, want):
+        assert _rel(g, w) < TOL, name
+    # neither is the other's, nor small beside it
+    assert _rel(got[0], got[1]) > 0.5
+
+
+def test_one_chunk_is_the_whole_sequence_and_the_state_crosses_chunks():
+    shape = SHAPES["two heads a tile"]
+    ops = _operands(shape, seed=2)
+    whole = _kernels(*ops, 64)
+    assert _rel(_kernels(*ops, 16), whole) < TOL
+    x, dt, a, b, c = ops
+    alone = _kernels(x[:, 16:32], dt[:, 16:32], a, b[:, 16:32], c[:, 16:32],
+                     16)
+    assert _rel(alone, whole[:, 16:32]) > 1e-2
+    assert _rel(_kernels(x[:, :16], dt[:, :16], a, b[:, :16], c[:, :16], 16),
+                whole[:, :16]) < TOL
+    weight = _weight(shape)
+    one, four = (jax.grad(lambda *v: jnp.sum(_kernels(*v, chunk) * weight),
+                          (0, 1, 2, 3, 4))(*ops) for chunk in (64, 16))
+    for name, g, w in zip(NAMES, four, one):
+        assert _rel(g, w) < TOL, name
+
+
+# -- what TOL must not let through -------------------------------------------
+
+def _bf16_decay(log_decay):
+    return jnp.exp(log_decay.astype(jnp.bfloat16)).astype(jnp.float32)
+
+
+def _not_carried(state, whole, own):
+    return jnp.zeros_like(own)
+
+
+def _no_mask(Q, transposed=False):
+    return jnp.ones((Q, Q), bool)
+
+
+def _decays_capped(log_decay):
+    """Without the mask a decay's exponent is positive above the diagonal;
+    capped so that the wrong sum stays finite."""
+    return jnp.exp(jnp.minimum(log_decay, 3.0))
+
+
+@pytest.mark.parametrize("what, patches", [
+    ("the decays made in bfloat16", {"_decay": _bf16_decay}),
+    ("a state that is not carried", {"_carry": _not_carried}),
+    ("no causal mask inside a chunk", {"_causal": _no_mask,
+                                       "_decay": _decays_capped}),
+])
+def test_a_wrong_piece_fails_on_the_kernels(monkeypatch, what, patches):
+    """The forward and, beside it, the gradients (the backward kernel makes
+    its decays, mask and carried cotangent from the same three pieces)."""
+    shape = SHAPES["two heads a tile"]
+    ops, weight = _operands(shape), _weight(shape)
+
+    def errors():
+        forward = _rel(_kernels(*ops, 16), _stepwise(*ops))
+        got = jax.grad(lambda *v: jnp.sum(_kernels(*v, 16) * weight),
+                       (0, 1, 2, 3, 4))(*ops)
+        want = jax.grad(lambda *v: jnp.sum(_stepwise(*v) * weight),
+                        (0, 1, 2, 3, 4))(*ops)
+        return forward, max(_rel(g, w) for g, w in zip(got, want))
+    assert max(errors()) < TOL
+    for name, wrong in patches.items():
+        monkeypatch.setattr(ps, name, wrong)
+    forward, backward = errors()
+    assert forward > 4 * TOL, (what, forward)
+    assert backward > 4 * TOL, (what, backward)
+
+
+def test_bfloat16_operands_keep_float32_sums_decays_and_state():
+    """The cell's dtypes: y float32, every cotangent in its operand's
+    dtype, and both within bfloat16's rounding of the ``jax.numpy`` form at
+    the same dtypes (which rounds the same operands in another order)."""
+    shape = SHAPES["two tiles of two heads"]
+    ops, weight = _operands(shape, dtype=jnp.bfloat16), _weight(shape)
+    got = _kernels(*ops, 16)
+    assert got.dtype == jnp.float32
+    assert _rel(got, t.ssm_chunked(*ops, 16)) < 1e-2
+    grads = jax.grad(lambda *v: jnp.sum(_kernels(*v, 16) * weight),
+                     (0, 1, 2, 3, 4))(*ops)
+    want = jax.grad(lambda *v: jnp.sum(t.ssm_chunked(*v, 16) * weight),
+                    (0, 1, 2, 3, 4))(*ops)
+    for name, g, w, op in zip(NAMES, grads, want, ops):
+        assert g.dtype == op.dtype, name
+        assert _rel(g, w) < 2e-2, name
+
+
+# -- which form runs -----------------------------------------------------------
+
+CELL = (8192, 64, 64, 8, 128, 128)           # S, H, P, G, N, chunk
+
+
+def _calls_a_kernel(shape):
+    B, S, H, P, G, N, chunk = shape
+    x = jax.ShapeDtypeStruct((B, S, H, P), jnp.bfloat16)
+    dt = jax.ShapeDtypeStruct((B, S, H), jnp.float32)
+    a = jax.ShapeDtypeStruct((H,), jnp.float32)
+    b = jax.ShapeDtypeStruct((B, S, G, N), jnp.bfloat16)
+    return "pallas_call" in str(jax.make_jaxpr(
+        lambda *v: t.ssm_chunked(*v, chunk))(x, dt, a, b, b))
+
+
+def test_the_kernels_run_on_a_tpu_where_the_tiles_fit(monkeypatch):
+    cfg = t.TransformerConfig(layer_pattern=(("mamba",),), ssm_heads=64,
+                              ssm_head_dim=64, ssm_state=128, ssm_groups=8,
+                              ssm_chunk=128)
+    assert ps.ssm_eligible(*CELL)
+    assert ps.ssm_tiles(64, 64, 8) == ps.SsmTiles(2, 64, 4)
+    assert "jax.numpy (backend cpu)" in t.ssm_path(cfg, 8192)
+    assert not _calls_a_kernel((1, 256, 64, 64, 8, 128, 128))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    said = t.ssm_path(cfg, 8192)
+    assert ps.FWD_NAME in said and ps.BWD_NAME in said
+    assert "64 chunks" in said and "4 lane tiles of 2 heads" in said
+    assert "128x512" in said and "checkpointed" in said
+    assert _calls_a_kernel((1, 256, 64, 64, 8, 128, 128))
+
+
+@pytest.mark.parametrize("what, change", [
+    ("a chunk of 64", {"ssm_chunk": 64}),
+    ("a state of 64", {"ssm_state": 64}),
+    ("a tile of one head of 64", {"ssm_heads": 8}),
+    ("heads of 96", {"ssm_head_dim": 96}),
+])
+def test_a_shape_the_tiles_do_not_fit_takes_the_numpy_form(monkeypatch, what,
+                                                           change):
+    import dataclasses
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(t.TransformerConfig(
+        layer_pattern=(("mamba",),), ssm_heads=64, ssm_head_dim=64,
+        ssm_state=128, ssm_groups=8, ssm_chunk=128), **change)
+    shape = (1, 256, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups,
+             cfg.ssm_state, cfg.ssm_chunk)
+    assert not ps.ssm_eligible(256, *shape[2:]), what
+    said = t.ssm_path(cfg, 256)
+    assert said.startswith("jax.numpy (") and ps.FWD_NAME not in said, what
+    assert not _calls_a_kernel(shape), what
+    with pytest.raises(ValueError, match="ssm_chunk=48"):
+        _calls_a_kernel((1, 256, 64, 64, 8, 128, 48))

@@ -23,7 +23,9 @@ import pytest
 
 from horovod_tpu.ops import pallas_attention as pa
 from horovod_tpu.ops import pallas_quantize as pq
+from horovod_tpu.ops import pallas_ssm
 from horovod_tpu.ops import pallas_xent as px
+from horovod_tpu.models import transformer
 from horovod_tpu.parallel import moe
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -112,6 +114,10 @@ _GMM_HYBRID_DOWN = [((49152, 1856), jnp.bfloat16),
                     ((8, 1856, 2688), jnp.float32), ((8,), jnp.int32)]
 _GMM_SHARE_DOWN = [((49152, 768), jnp.bfloat16),
                    ((16, 768, 2560), jnp.float32), ((16,), jnp.int32)]
+# the Mamba-2 scan of the cell nemotron-3-nano-30b-a3b.s8192: x, dt, a, b,
+# c at 8192 positions, 64 heads of 64 in 8 groups, state 128, chunk 128
+_SSM_CELL = [((1, 8192, 64, 64), jnp.bfloat16), ((1, 8192, 64), jnp.float32),
+             ((64,), jnp.float32)] + [((1, 8192, 8, 128), jnp.bfloat16)] * 2
 _BLOCKS = ((8192, 256), jnp.float32)
 _CODES = [((8192, 256), jnp.int8), ((8192, 1), jnp.float32)]
 
@@ -190,6 +196,15 @@ CASES = {
                  (0, 1)),
         [((256, 128), jnp.float32), ((4, 128, 64), jnp.float32),
          ((4,), jnp.int32)], "ragged-dot"),
+    # a block spec the lowering refuses (dt and the sums a head's column
+    # and a head's row) or a working set past VMEM fails here
+    "ssm_scan_cell": (
+        lambda x, dt, a, b, c: transformer.ssm_chunked(x, dt, a, b, c, 128),
+        _SSM_CELL, pallas_ssm.FWD_NAME),
+    "ssm_scan_grad_cell": (
+        jax.grad(lambda x, dt, a, b, c: _sum32(transformer.ssm_chunked(
+            x, dt, a, b, c, 128)), (0, 1, 2, 3, 4)),
+        _SSM_CELL, (pallas_ssm.FWD_NAME, pallas_ssm.BWD_NAME)),
     "quantize": (pq.block_quantize, [_BLOCKS], "hvd_block_quantize"),
     "quantize_ef": (pq.block_quantize_ef, [_BLOCKS],
                     "hvd_block_quantize_ef"),
@@ -573,6 +588,63 @@ def test_the_block_s_new_fields_leave_the_flagship_cells_alone(
             remat=False))
     spelled_out, args, _shapes, _bytes = _cell_step(cell, topo)
     assert spelled_out.lower(*args).as_text() == lowered
+
+
+# -- the Mamba-2 scan on its kernels (ISSUE 40) -------------------------------
+
+def test_mamba_block_keeps_a_chunk_s_inside_on_the_chip(v5e, no_compile_cache,
+                                                        monkeypatch):
+    """A checkpointed Mamba block of the cell nemotron-3-nano-30b-a3b.s8192,
+    forward and backward: the forward kernel twice (the block runs again in
+    the backward pass) and the backward kernel once, under ``hvd.ssm.scan``;
+    of what the ``jax.numpy`` form keeps in memory only the states the
+    chunks start from are left, an output of the forward kernel (under
+    differentiation it writes them both times; the first copy is read by
+    nothing) that the backward kernel reads with no copy between: no ``[..,
+    128, 128]`` float32 array (scores, decays, weights) and no other array
+    of 64 chunks' states."""
+    import numpy as np
+    from horovod_tpu.profiling import scopes
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    S, H, P, G, N, Q, M = 8192, 64, 64, 8, 128, 128, 2688
+    cfg = transformer.TransformerConfig(
+        d_model=M, n_heads=32, n_layers=1, layer_pattern=(("mamba",),),
+        ssm_heads=H, ssm_head_dim=P, ssm_state=N, ssm_groups=G, ssm_chunk=Q,
+        dtype=jnp.bfloat16)
+    assert pallas_ssm.FWD_NAME in transformer.ssm_path(cfg, S)
+    leaves = jax.eval_shape(lambda: jax.tree_util.tree_map(
+        lambda v: jnp.asarray(v[0, 0]), transformer.init_params(
+            np.random.RandomState(0), cfg, 1)["layers"]["mamba"]))
+    params = jax.tree_util.tree_map(
+        lambda v: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=v5e),
+        leaves)
+    h = jax.ShapeDtypeStruct((1, S, M), jnp.bfloat16, sharding=v5e)
+
+    def loss(p, h):
+        block = jax.checkpoint(
+            lambda p, h: transformer._mamba_block(p, h, cfg))
+        return _sum32(jnp.square(block(p, h)))
+    text = jax.jit(jax.grad(loss, (0, 1))).lower(params, h).compile(
+        ).as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    backward = [c for c in calls if pallas_ssm.BWD_NAME + "/" in c]
+    forward = [c for c in calls if pallas_ssm.FWD_NAME + "/" in c]
+    assert (len(forward), len(backward)) == (2, 1), calls
+    assert all(scopes.SSM_SCAN + "/" in c for c in forward + backward)
+    states = f"f32[1,{S // Q},{G},{N},{H // G * P}]"
+    assert all(states in c.split(" custom-call(")[0] for c in forward)
+    assert states in backward[0].split(" custom-call(")[1]
+    for line in _arrays_in_memory(text).splitlines():
+        result = line.split(" = ")[1].split("(")[0] if " = " in line else ""
+        if "custom-call" in line or "get-tuple-element" in line:
+            continue
+        assert states not in result, line
+        for dims in re.findall(r"f32\[([\d,]+)\]", result):
+            dims = [int(d) for d in dims.split(",")]
+            assert dims[-2:] != [Q, Q], line
+            assert not (np.prod(dims) >= S // Q * H * P * N
+                        and N in dims[-2:] and S not in dims), line
 
 
 # -- the embedding's gradient (ISSUE 38) --------------------------------------
