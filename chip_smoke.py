@@ -136,7 +136,8 @@ class Sizes:
     block: tuple          # block attention checks, each [B, S, H, D]
     xent: tuple           # fused xent check [rows, vocab]
     blocks: tuple         # codec check [n_blocks, block]
-    gmm: tuple            # grouped matmul check (rows, in, out, groups)
+    gmm: tuple            # grouped matmul checks, each (rows, in, out,
+    #                       groups, rows in the groups)
     embed: tuple          # embedding checks, each (vocab, width, tokens)
     ssm: tuple            # Mamba-2 scan check (S, heads, head width, groups,
     #                       state, chunk)
@@ -157,7 +158,9 @@ REAL = Sizes(
     # the attention core of bert-large.s128 and bert-large.s512
     block=((64, 128, 16, 64), (8, 512, 16, 64)),
     xent=(16384, 32000), blocks=(8192, 256),
-    gmm=(16384, 2048, 1024, 64),
+    # OLMoE's widths; the hybrid cell's way up, whose 1856 columns are no
+    # multiple of 128 lanes: the weights read as the chip stores them
+    gmm=((16384, 2048, 1024, 64, 12288), (49152, 2688, 1856, 8, 12288)),
     # the tables of smallthinker-21b-a3b.s8192 and of olmoe-1b-7b.s4096
     embed=((37984, 2560, 8192), (50304, 2048, 8192)),
     # nemotron-3-nano-30b-a3b.s8192's heads, an eighth of its length
@@ -173,7 +176,8 @@ TINY = Sizes(
     attn=(1, 256, 2, 128), banded=(1, 512, 4, 2, 128, 256),
     block=((2, 128, 2, 64),),
     xent=(256, 1000), blocks=(64, 128),
-    gmm=(256, 128, 128, 4), embed=((64, 2560, 48),),
+    gmm=((256, 128, 128, 4, 192), (256, 128, 192, 4, 192)),
+    embed=((64, 2560, 48),),
     ssm=(64, 4, 8, 2, 16, 16),
     gpt=dict(vocab_size=1000, d_model=256, n_heads=2, n_layers=2,
              d_ff=256, max_seq=256),
@@ -616,58 +620,64 @@ def _check_xent(smoke: Smoke) -> None:
 
 def _check_gmm(smoke: Smoke) -> None:
     """The expert layer's grouped matmul (parallel/moe.py), forward and both
-    gradients, on ragged groups with empty ones among them that end a
-    quarter before the rows do, as an ep shard's do: on the megablox
-    kernels named hvd_moe_gmm, and at a 64-wide expert on XLA's ragged_dot,
-    which is where a TPU falls back to. On ragged_dot's path the rows
-    beyond the groups must be zero, forward and in d_rows (what XLA's
-    ragged_dot alone gives there is printed); the kernels never write them
-    and the expert layer reads none (ISSUE 37), so there they are compared
-    with nothing, and kept out of the weights' gradient. The reference is
-    ragged_dot in float32 on the groups' rows alone. Prints which path
-    ``grouped_matmul`` takes, and the tile of each of its three calls, at
-    each size, at the OLMoE cell's, at the share cell's and at the hybrid
-    cell's (an expert width no 128-multiple divides)."""
+    gradients, on ragged groups with empty ones among them that end before
+    the rows do, as an ep shard's and a held share's do: on the megablox
+    kernels named hvd_moe_gmm at each of ``sizes.gmm`` (aligned widths, read
+    row-major; the hybrid cell's way up, whose weights the calls read and
+    whose gradient they write the other way round, as the chip stores them:
+    ISSUE 41), and at a 64-wide expert on XLA's ragged_dot, which is where a
+    TPU falls back to. On ragged_dot's path the rows beyond the groups must
+    be zero, forward and in d_rows (what XLA's ragged_dot alone gives there
+    is printed); the kernels never write them and the expert layer reads
+    none (ISSUE 37), so there they are compared with nothing, and kept out
+    of the weights' gradient. The reference is ragged_dot in float32 on the
+    groups' rows alone. Prints which path ``grouped_matmul`` takes, which
+    way round it reads the weights and the tile of each of its three calls,
+    at each size, at the OLMoE cell's, at the share cell's and at the hybrid
+    cell's."""
     import jax
     import jax.numpy as jnp
     import numpy as np
     from horovod_tpu.parallel.moe import GMM_NAME, gmm_path, grouped_matmul
 
     interpret = smoke.rehearsal
-    rows, d_in, d_out, groups = smoke.sizes.gmm
-    keys = jax.random.split(jax.random.PRNGKey(smoke.seed + 2), 3)
-    x = jax.random.normal(keys[0], (rows, d_in), jnp.bfloat16)
-    rng = np.random.default_rng(smoke.seed)
-    share = rng.dirichlet(np.full(groups, 0.5)) * (rng.random(groups) > 0.25)
-    inside = rows * 3 // 4
-    sizes = rng.multinomial(inside, share / share.sum()).astype(np.int32)
-    gs = jnp.asarray(sizes)
-
-    def ours(x, w):
-        return grouped_matmul(x, w, gs, interpret=interpret)
-
-    def reference(x, w):
-        with jax.default_matmul_precision("highest"):
-            out = jax.lax.ragged_dot(x[:inside].astype(jnp.float32), w, gs)
-        return jnp.pad(out, ((0, rows - inside), (0, 0)))
-
-    def beyond(a):
-        return float(jnp.max(jnp.abs(a[inside:].astype(jnp.float32))))
     olmoe = gmm_path(65536, 2048, 1024)
     share = gmm_path(49152, 2560, 768)
     hybrid = gmm_path(49152, 2688, 1856)
-    for kernel, width in ((GMM_NAME, d_out), (None, 64)):
+    if smoke.on_chip:
+        check(olmoe.startswith(f"pallas {GMM_NAME} ") and "row-major" in olmoe
+              and share.startswith(f"pallas {GMM_NAME} ")
+              and hybrid.startswith(f"pallas {GMM_NAME} ")
+              and "[E, 1856, 2688]" in hybrid, olmoe + share + hybrid)
+    narrow = smoke.sizes.gmm[0][:2] + (64,) + smoke.sizes.gmm[0][3:]
+    cases = [(GMM_NAME, size) for size in smoke.sizes.gmm] + [(None, narrow)]
+    for case, (kernel, (rows, d_in, width, groups, inside)) in enumerate(cases):
+        keys = jax.random.split(jax.random.PRNGKey(smoke.seed + 2), 3)
+        x = jax.random.normal(keys[0], (rows, d_in), jnp.bfloat16)
+        rng = np.random.default_rng(smoke.seed + case)
+        part = rng.dirichlet(np.full(groups, 0.5)) \
+            * (rng.random(groups) > 0.25)
+        sizes = rng.multinomial(inside, part / part.sum()).astype(np.int32)
+        gs = jnp.asarray(sizes)
+
+        def ours(x, w):
+            return grouped_matmul(x, w, gs, interpret=interpret)
+
+        def reference(x, w):
+            with jax.default_matmul_precision("highest"):
+                out = jax.lax.ragged_dot(x[:inside].astype(jnp.float32), w,
+                                         gs)
+            return jnp.pad(out, ((0, rows - inside), (0, 0)))
+
+        def beyond(a):
+            return float(jnp.max(jnp.abs(a[inside:].astype(jnp.float32))))
         w = jax.random.normal(keys[1], (groups, d_in, width),
                               jnp.float32) / np.sqrt(d_in)
         ct = jax.random.normal(keys[2], (rows, width), jnp.float32)
         path = gmm_path(rows, d_in, width)
         if smoke.on_chip:
-            check(olmoe.startswith(f"pallas {GMM_NAME} ") and
-                  share.startswith(f"pallas {GMM_NAME} ") and
-                  hybrid.startswith(f"pallas {GMM_NAME} ") and
-                  path.startswith(f"pallas {GMM_NAME} " if kernel
-                                  else "xla ragged_dot"),
-                  path + olmoe + share + hybrid)
+            check(path.startswith(f"pallas {GMM_NAME} " if kernel
+                                  else "xla ragged_dot"), path)
         name = "moe_gmm" if kernel else "moe_gmm on xla ragged_dot"
         ran = {} if kernel else {"ran": "xla"}
 

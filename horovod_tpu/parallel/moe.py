@@ -104,15 +104,20 @@ GMM_ROW_TILES_SPLIT = (512, 256, 128)
 
 class GmmTiles(NamedTuple):
     """megablox's ``(tm, tk, tn)`` for each of the three calls: rows, the
-    call's own contraction, the call's own output columns."""
-    #: ``gmm``: rows ``[N, K] @ [E, K, F]``, contraction K, columns F
+    call's own contraction, the call's own output columns; and which way
+    round the calls read the weights."""
+    #: ``gmm``: rows ``[N, K]`` against the weights, contraction K, columns F
     forward: Tuple[int, int, int]
-    #: ``gmm(transpose_rhs=True)``: cotangent ``[N, F]`` against the same
-    #: weights, contraction F, columns K: the other way round
+    #: ``gmm``: cotangent ``[N, F]`` against the same weights, contraction
+    #: F, columns K: the other way round (of the two, the one that meets the
+    #: weights with its contraction last is the ``transpose_rhs`` call)
     input_grad: Tuple[int, int, int]
     #: ``tgmm``: a group's rows streamed through in ``tm`` steps into its
-    #: ``[tk, tn]`` block of the ``[E, K, F]`` result
+    #: ``[tk, tn]`` block of the result, ``[E, K, F]`` or ``[E, F, K]``
     weight_grad: Tuple[int, int, int]
+    #: the calls take and return the weights as ``[E, F, K]``
+    #: (:func:`_stored_transposed`)
+    transposed: bool = False
 
 
 def _lane_tiles(width: int):
@@ -170,22 +175,38 @@ def _tgmm_call_tile(n_rows: int, k: int, n: int, itemsize: int):
     return best and best[1]
 
 
+def _stored_transposed(k: int, f: int) -> bool:
+    """Whether XLA:TPU keeps weights ``[E, k, f]`` with ``k`` on the lanes:
+    its layout for an array whose last dimension is no multiple of 128 while
+    the one before it is (``f32[8,2688,1856]{1,2,0}``; 1920 columns get the
+    plain ``{2,1,0}``). ``jnp.swapaxes(weights, -1, -2)`` is then the array
+    as it lies in memory, row-major, which is how a Pallas call wants its
+    operand; handed ``[E, k, f]`` itself, the step copies the weights and
+    both moments to row-major and back for the update (six copies of 638 MB
+    in the hybrid cell, 12.15 ms: PERF.md section 6, PR 41)."""
+    return f % 128 != 0 and k % 128 == 0
+
+
 def _gmm_tile(n_rows: int, k: int, f: int, itemsize: int):
     """:class:`GmmTiles` for rows ``[n_rows, k]`` of ``itemsize`` bytes an
     element against weights ``[E, k, f]``, each call's tile from that call's
-    own shape; None where the kernels do not apply (rows or a width that no
+    own shape (a ``tgmm`` that writes ``[E, f, k]`` has ``f`` for its
+    ``tk``); None where the kernels do not apply (rows or a width that no
     128-multiple divides: XLA's ``ragged_dot``)."""
+    transposed = _stored_transposed(k, f)
+    written = (f, k) if transposed else (k, f)
     tiles = (_gmm_call_tile(n_rows, k, f, itemsize),
              _gmm_call_tile(n_rows, f, k, itemsize),
-             _tgmm_call_tile(n_rows, k, f, itemsize))
-    return GmmTiles(*tiles) if all(tiles) else None
+             _tgmm_call_tile(n_rows, *written, itemsize))
+    return GmmTiles(*tiles, transposed) if all(tiles) else None
 
 
 def gmm_path(n_rows: int, k: int, f: int, dtype=jnp.bfloat16) -> str:
     """Which implementation :func:`grouped_matmul` takes on the default
     backend for rows ``[n_rows, k]`` of ``dtype`` and weights ``[E, k, f]``,
-    the tile of each of the three calls and why (``chip_smoke.py`` prints
-    it, as it does ``attend``'s choice)."""
+    which way round the calls read the weights, the tile of each of the
+    three calls and why (``chip_smoke.py`` prints it, as it does
+    ``attend``'s choice)."""
     tiles = _gmm_tile(n_rows, k, f, jnp.dtype(dtype).itemsize)
     if tiles is None:
         return (f"xla ragged_dot (no 128-multiple tile divides rows "
@@ -197,7 +218,15 @@ def gmm_path(n_rows: int, k: int, f: int, dtype=jnp.bfloat16) -> str:
         resident = (", a group's weights resident"
                     if tile[1] == contraction else "")
         return "x".join(map(str, tile)) + resident
-    return (f"pallas {GMM_NAME} forward {say(tiles.forward, k)}; "
+    if tiles.transposed:
+        read = (f"weights read as stored, [E, {f}, {k}] ({f} columns are no "
+                f"multiple of 128 lanes and {k} rows are, so the chip keeps "
+                f"the rows minor: transpose_rhs forward, the weight "
+                f"gradient written [E, {f}, {k}])")
+    else:
+        read = (f"weights read as stored, [E, {k}, {f}] row-major "
+                f"(transpose_rhs in the input gradient)")
+    return (f"pallas {GMM_NAME} {read}: forward {say(tiles.forward, k)}; "
             f"input gradient {say(tiles.input_grad, f)}; "
             f"weight gradient {'x'.join(map(str, tiles.weight_grad))} "
             f"(whole contraction first, else the widest blocks under "
@@ -225,6 +254,15 @@ def _ragged_dot(rows, weights, group_sizes):
                           ).astype(rows.dtype)
 
 
+def _as_read(weights, tiles: GmmTiles, dtype):
+    """The weights as the three calls read them: in the rows' ``dtype``,
+    ``[E, F, K]`` where they are stored that way (a bitcast there, not a
+    copy)."""
+    if tiles.transposed:
+        weights = jnp.swapaxes(weights, -1, -2)
+    return weights.astype(dtype)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def _gmm(rows, weights, group_sizes, tiles, interpret):
     """``tiles`` None: XLA's ``ragged_dot``, zeros beyond the groups; else
@@ -236,8 +274,9 @@ def _gmm(rows, weights, group_sizes, tiles, interpret):
                             group_sizes)
     gmm, _tgmm = _megablox()
     with jax.named_scope(GMM_NAME):
-        return gmm(rows, weights.astype(rows.dtype), group_sizes,
-                   rows.dtype, tiles.forward, interpret=interpret)
+        return gmm(rows, _as_read(weights, tiles, rows.dtype), group_sizes,
+                   rows.dtype, tiles.forward, transpose_rhs=tiles.transposed,
+                   interpret=interpret)
 
 
 def _gmm_fwd(rows, weights, group_sizes, tiles, interpret):
@@ -257,12 +296,19 @@ def _gmm_bwd(tiles, interpret, res, g):
     else:
         gmm, tgmm = _megablox()
         with jax.named_scope(GMM_NAME):
-            d_rows = gmm(g, weights.astype(rows.dtype), group_sizes,
-                         rows.dtype, tiles.input_grad, transpose_rhs=True,
+            d_rows = gmm(g, _as_read(weights, tiles, rows.dtype), group_sizes,
+                         rows.dtype, tiles.input_grad,
+                         transpose_rhs=not tiles.transposed,
                          interpret=interpret)
-            d_weights = tgmm(rows.swapaxes(0, 1), g, group_sizes,
+            # [E, K, F] is rows^T g; read as stored it is [E, F, K], g^T rows
+            # swapped back: the array the update reads beside the weights
+            # and their moments, in their layout
+            lhs, rhs = (g, rows) if tiles.transposed else (rows, g)
+            d_weights = tgmm(lhs.swapaxes(0, 1), rhs, group_sizes,
                              rows.dtype, tiles.weight_grad,
                              interpret=interpret)
+            if tiles.transposed:
+                d_weights = jnp.swapaxes(d_weights, -1, -2)
     return d_rows, d_weights.astype(weights.dtype), None
 
 
@@ -285,7 +331,10 @@ def grouped_matmul(rows: jax.Array, weights: jax.Array,
     On a TPU (and under ``interpret``) the megablox Pallas kernels of the
     installed JAX, kept over XLA's ``ragged_dot`` kernels by the sweeps in
     PERF.md, each of the three calls at a tile from its own shape and
-    ``rows.dtype`` (:func:`_gmm_tile`); elsewhere, or where no row tile
+    ``rows.dtype`` (:func:`_gmm_tile`), reading the weights, and writing
+    their gradient, the way round the chip stores them
+    (:func:`_stored_transposed`: by the two widths modulo 128, so that no
+    call makes the step copy a parameter); elsewhere, or where no row tile
     divides ``N`` or a width is no multiple of 128 lanes,
     ``jax.lax.ragged_dot``, the same function as plain XLA (the pattern of
     ``pallas_attention.attend``; :func:`gmm_path` says which and why)."""

@@ -526,12 +526,26 @@ def test_the_sixteen_shares_add_up_to_the_uncut_layer():
 def test_gmm_takes_a_width_no_lane_tile_divides_as_one_block(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     path = moe.gmm_path(49152, 2688, 1856)
-    assert path.startswith("pallas hvd_moe_gmm forward 256x896x1856; input "
-                           "gradient 256x1856x896, a group's weights "
-                           "resident; weight gradient 256x384x1856"), path
-    assert "pallas hvd_moe_gmm" in moe.gmm_path(49152, 1856, 2688)
+    assert path.startswith(
+        "pallas hvd_moe_gmm weights read as stored, [E, 1856, 2688] (1856 "
+        "columns are no multiple of 128 lanes and 2688 rows are, so the "
+        "chip keeps the rows minor: transpose_rhs forward, the weight "
+        "gradient written [E, 1856, 2688]): forward 256x896x1856; input "
+        "gradient 256x1856x896, a group's weights resident; weight gradient "
+        "256x1856x384"), path
+    down = moe.gmm_path(49152, 1856, 2688)
+    assert down.startswith(
+        "pallas hvd_moe_gmm weights read as stored, [E, 1856, 2688] "
+        "row-major (transpose_rhs in the input gradient): forward "
+        "256x1856x896, a group's weights resident; input gradient "
+        "256x896x1856; weight gradient 256x1856x384"), down
     assert moe._lane_tiles(1856) == [1856] and moe._lane_tiles(64) == []
     assert moe._lane_tiles(2688) == [2688, 896, 384, 128]
+    # the way up's calls are the way down's, each other's: one stored shape
+    assert moe._gmm_tile(49152, 2688, 1856, 2) == moe.GmmTiles(
+        (256, 896, 1856), (256, 1856, 896), (256, 1856, 384), True)
+    assert moe._gmm_tile(49152, 1856, 2688, 2) == moe.GmmTiles(
+        (256, 1856, 896), (256, 896, 1856), (256, 1856, 384), False)
     # the OLMoE and SmallThinker shapes keep the tiles PR 33 gave them
     assert moe._gmm_tile(65536, 2048, 1024, 2) == moe.GmmTiles(
         (256, 2048, 1024), (256, 1024, 2048), (256, 1024, 1024))
@@ -541,24 +555,31 @@ def test_gmm_takes_a_width_no_lane_tile_divides_as_one_block(monkeypatch):
         (256, 768, 2560), (256, 2560, 768), (256, 768, 1280))
 
 
-def test_the_kernels_at_such_a_width_are_the_ragged_dot():
+@pytest.mark.parametrize("k, f, transposed", [
+    (128, 192, True), (256, 192, True), (128, 256, False),
+    (192, 128, False), (192, 192, False)])
+def test_the_kernels_at_such_a_width_are_the_ragged_dot(k, f, transposed):
     """Interpret mode: 192 columns (1.5 lane tiles) as one block, forward
-    and both gradients, groups that start inside a row tile."""
+    and both gradients, groups that start inside a row tile and end before
+    the rows do. Where the columns are no multiple of 128 lanes and the
+    rows are, the calls read the weights ``[E, f, k]`` and return their
+    gradient swapped back (ISSUE 41); elsewhere in the order they had."""
     rng = np.random.RandomState(0)
-    rows = jnp.asarray(rng.randn(256, 128), jnp.float32)
-    w = jnp.asarray(rng.randn(3, 128, 192) / 8, jnp.float32)
+    rows = jnp.asarray(rng.randn(256, k), jnp.float32)
+    w = jnp.asarray(rng.randn(3, k, f) / 8, jnp.float32)
     sizes = jnp.asarray([100, 0, 92], jnp.int32)
-    assert moe._gmm_tile(256, 128, 192, 4) is not None
-    weight = jnp.asarray(rng.randn(256, 192), jnp.float32)
+    assert moe._gmm_tile(256, k, f, 4).transposed == transposed
+    weight = jnp.asarray(rng.randn(256, f), jnp.float32)
     inside = (jnp.arange(256) < 192)[:, None]
 
     def run(interpret):
-        def f(r, w):
+        def loss(r, w):
             y = moe.grouped_matmul(r, w, sizes, interpret=interpret)
             return jnp.sum(jnp.where(inside, y, 0) * weight)
-        return jax.value_and_grad(f, (0, 1))(rows, w)
+        return jax.value_and_grad(loss, (0, 1))(rows, w)
     with jax.default_matmul_precision("highest"):
         (got, (d_rows, d_w)), (want, (r_rows, r_w)) = run(True), run(False)
+    assert d_w.shape == w.shape and d_w.dtype == w.dtype
     assert _rel(got, want) < 1e-5
     assert _rel(jnp.where(inside, d_rows, 0), r_rows) < 1e-5
     assert _rel(d_w, r_w) < 1e-5

@@ -234,15 +234,21 @@ def test_expert_parallel_layouts_give_one_device_s_result(axes):
                     grads1["layers"][name]) < TOL, name
 
 
+@pytest.mark.parametrize("d_ff", [128, 192])
 @pytest.mark.parametrize("axes", [{"ep": 2}, {"dp": 2, "ep": 2}])
 def test_expert_parallel_on_the_kernels_gives_one_device_s_result(
-        monkeypatch, axes):
+        monkeypatch, axes, d_ff):
     """The same with the block's three grouped matmuls on the TPU path's
     Pallas kernels (interpret mode; experts 128 wide so the kernels apply):
     a shard's groups end before its rows do, and what the kernel leaves
     unwritten there (NaN here, stale memory on a chip) must reach neither
-    the loss nor a gradient."""
-    cfg = dataclasses.replace(CFG, d_ff=128)
+    the loss nor a gradient. Experts 192 wide, 1.5 lane tiles: the two ways
+    up ``[E, 128, 192]`` are read and differentiated the other way round, as
+    a chip stores them (``moe._stored_transposed``), beside the way down
+    ``[E, 192, 128]`` in today's order."""
+    assert moe._gmm_tile(256, 128, d_ff, 4).transposed == (d_ff == 192)
+    assert not moe._gmm_tile(256, d_ff, 128, 4).transposed
+    cfg = dataclasses.replace(CFG, d_ff=d_ff)
     params, batch = _params(cfg), _batch(n_seqs=4)
     loss1, _aux1, grads1 = _program(cfg, params, batch)
     monkeypatch.setattr(t, "grouped_matmul", functools.partial(

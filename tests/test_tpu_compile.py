@@ -232,6 +232,51 @@ def test_kernel_compiles_for_v5e(case, v5e, no_compile_cache, monkeypatch):
         assert any(kernel in line for line in calls), (kernel, calls)
 
 
+def test_unaligned_expert_weights_are_read_and_updated_where_they_lie(
+        v5e, no_compile_cache, monkeypatch):
+    """The routed way up of the cell nemotron-3-nano-30b-a3b.s8192 (ISSUE
+    41): 1856 columns are no multiple of 128 lanes and 2688 rows are, so the
+    chip keeps ``f32[8,2688,1856]`` with the rows minor, ``{1,2,0}`` (if that
+    assertion fails a new compiler changed the rule, not the program: look
+    at ``moe._stored_transposed`` again). The three kernels read the
+    weights, and write their gradient, that way round, ``[8, 1856, 2688]``
+    row-major, so that forward, both gradients and an update of the donated
+    weights and a moment move neither: no ``copy`` and no ``transpose`` of
+    the weights' shape (handed ``[E, K, F]`` itself the calls cost a copy in
+    and a copy out of each). The aligned shapes (``_GMM_*`` above) keep
+    today's order."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    for shapes in (_GMM_HYBRID, _GMM_UP, _GMM_DOWN, _GMM_SHARE,
+                   _GMM_SHARE_DOWN, _GMM_HYBRID_DOWN):
+        (rows, _), (w, _), _ = shapes
+        assert moe._gmm_tile(rows[0], *w[1:], 2).transposed \
+            == (shapes is _GMM_HYBRID)
+    _, k, f = _GMM_HYBRID[1][0]
+
+    def update(rows, w, sizes, m):
+        def loss(w, rows):
+            y = moe.grouped_matmul(rows, w, sizes)
+            return _sum32(y), y
+        (_, y), (d_w, d_rows) = jax.value_and_grad(loss, (0, 1),
+                                                   has_aux=True)(w, rows)
+        m = 0.9 * m + 0.1 * d_w
+        return w - 1e-3 * m, m, d_rows, y
+    args = [jax.ShapeDtypeStruct(s, d, sharding=v5e)
+            for s, d in _GMM_HYBRID + [_GMM_HYBRID[1]]]
+    text = jax.jit(update, donate_argnums=(1, 3)).lower(*args).compile(
+        ).as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert sum(moe.GMM_NAME in c for c in calls) == 3, calls
+    entry = text[text.index("\nENTRY "):]
+    stored = re.findall(r" = f32\[8,%d,%d\]\{([\d,]+)\S* parameter\("
+                        % (k, f), entry)
+    assert stored == ["1,2,0"] * 2, stored
+    moved = re.findall(r"^.* = \w+\[8,(?:%d,%d|%d,%d)\]\S* (?:copy|transpose)"
+                       r"\(.*$" % (k, f, f, k), text, re.M)
+    assert not moved, moved
+
+
 def test_flash_gradient_leaves_no_score_array_and_no_float32_operand(
         v5e, no_compile_cache, monkeypatch):
     """The gradient at the GPT cell's shape, compiled for the v5e: the
