@@ -713,7 +713,7 @@ def _check_gmm(smoke: Smoke) -> None:
 
 def _check_ssm(smoke: Smoke) -> None:
     """The Mamba-2 scan on its kernels (ops/pallas_ssm.py: hvd_ssm_scan,
-    hvd_ssm_scan_bwd, through models/transformer.py:ssm_chunked as a Mamba
+    hvd_ssm_scan_bwd, through models/mamba.py:ssm_chunked as a Mamba
     block calls it), bfloat16 operands with float32 time steps, sums, decays
     and carried state, against the recurrence one position at a time in
     float32, forward and every operand's gradient, at the hybrid cell's
@@ -722,9 +722,9 @@ def _check_ssm(smoke: Smoke) -> None:
     and what the backward pass keeps of a block."""
     import jax
     import jax.numpy as jnp
-    from horovod_tpu.models import transformer
-    from horovod_tpu.models.transformer import (TransformerConfig,
-                                                ssm_chunked, ssm_path)
+    from horovod_tpu.models import mamba
+    from horovod_tpu.models.mamba import ssm_chunked, ssm_path
+    from horovod_tpu.models.transformer import TransformerConfig
     from horovod_tpu.ops import pallas_ssm
     S, H, P, G, N, chunk = smoke.sizes.ssm
     keys = jax.random.split(jax.random.PRNGKey(smoke.seed + 5), 6)
@@ -752,8 +752,8 @@ def _check_ssm(smoke: Smoke) -> None:
         return ssm_chunked(*ops, chunk, interpret=smoke.rehearsal)
 
     def numpy_form(x, dt, a, b, c):
-        return transformer._ssm_chunked_numpy(
-            x, dt, transformer._chunk_sums(dt * a, chunk), b, c, chunk)
+        return mamba._ssm_chunked_numpy(
+            x, dt, mamba._chunk_sums(dt * a, chunk), b, c, chunk)
 
     def loss(f):
         return lambda *ops: jnp.sum(f(*ops) * ct)
@@ -1113,7 +1113,7 @@ def _loss_and_grads(cfg, mesh_kwargs, B, S):
     """Loss and a few gradient leaves of the flagship on one layout, on
     __graft_entry__._run_layout's weights and batch. The flagship's
     gradient sync sums over the data shards where the loss averaged
-    (ROADMAP A16): divided out here, so that layouts compare."""
+    (ROADMAP A17): divided out here, so that layouts compare."""
     import jax
     import jax.numpy as jnp
     import numpy as np

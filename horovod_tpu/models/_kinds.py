@@ -1,0 +1,69 @@
+"""What a parameter leaf and a kind of block are: the declarations that
+``models/transformer.py`` builds ``init_params``' tree, ``param_shardings``'
+specs and the stack's scan from. It imports neither that file nor the mixers
+(``models/mamba.py``) that declare theirs with it.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Callable, Iterable, NamedTuple, Optional, Tuple
+
+import numpy as np
+
+import jax
+import jax.numpy as jnp
+
+
+class Leaf(NamedTuple):
+    """One parameter. A block's leaves are a function of the config that
+    yields them in the order ``init_params`` draws them; the ``if`` around a
+    ``yield`` is the one place that says when a config has the leaf."""
+    name: str
+    shape: Tuple[int, ...]      # without the stack's leading [stage, block]
+    draw: Callable              # (rng, the whole shape) -> float32 array
+    spec: Tuple[Optional[str], ...] = ()    # "tp", "ep" or None for each of
+    #                             ``shape``'s dimensions; (): not split
+
+
+class BlockKind(NamedTuple):
+    """A block of ONE sublayer, ``x + mixer(norm(x))``: a row of
+    ``transformer._BLOCK_KINDS``."""
+    length: int                 # of its kind tuple in ``layer_pattern``
+    leaves: Callable[[Any], Iterable[Leaf]]     # of the config
+    apply: Callable             # (p, x, positions, cfg, kind) -> (x, the
+    #                             block's auxiliary terms or None)
+    validate: Callable[[Any], None] = lambda cfg: None  # ValueError for a
+    #                             config without the fields the block reads
+    checkpointed: bool = False  # by the single pass, if ``cfg.remat`` is None
+    refuses: Tuple[str, ...] = ()   # mesh axes it does not run on live (with
+    #                             "pp": its stack is whole on every device)
+    refusal: str = ""           # and why: ``param_shardings``' error
+
+
+def ones(rng, shape):
+    return np.ones(shape, np.float32)
+
+
+def zeros(rng, shape):
+    return np.zeros(shape, np.float32)
+
+
+def normal(scale=None):
+    """A normal draw at ``scale``, or at ``1 / sqrt(rows)`` of the matrix
+    the last two dimensions are."""
+    def draw(rng, shape):
+        std = scale if scale is not None else 1.0 / np.sqrt(shape[-2])
+        return (rng.randn(*shape) * std).astype(np.float32)
+    return draw
+
+
+def remat(cfg, needed: bool) -> bool:
+    """Whether a path checkpoints its blocks: what the config says, or
+    where it says nothing, whether the path needs it to fit."""
+    return needed if cfg.remat is None else cfg.remat
+
+
+def rmsnorm(x, g, eps=1e-6):
+    var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
+    return (x.astype(jnp.float32) * jax.lax.rsqrt(var + eps)
+            ).astype(x.dtype) * g.astype(x.dtype)

@@ -9,6 +9,13 @@ counterpart of that contract at modern scale: the training step compiles to
 a single XLA program whose collectives (psum over tp, ppermute rings over
 sp and pp, all_to_all over ep, psum over dp for gradients) all ride ICI.
 
+This file is the training model; the Mamba-2 mixer is ``models/mamba.py``, the
+serving plane's paged decode model and its oracle ``models/decode.py``. A leaf
+is declared once, in its block's ``*_leaves`` function (``models/_kinds.py``:
+name, shape, draw, partition spec, under the one ``if`` that says when the
+config has it), and ``init_params`` and ``param_shardings`` are both built
+from that; a kind of block once, as a row of ``_BLOCK_KINDS``.
+
 Layout conventions (local = per-device shapes):
   tokens          [B/dp, S/sp]
   embedding       [V/tp, M]          (vocab-sharded, tied softmax)
@@ -22,13 +29,11 @@ Layout conventions (local = per-device shapes):
                   step's state goes to the head and the exit gate (no pp)
   layer kinds     ``layer_pattern``: one period of (window, rope) kinds; the
                   scan goes over periods, a period's layers unrolled inside
-  one sublayer    a kind that starts with a word, ("mamba",), ("experts",),
-                  ("attention", window, rope): ``x + mixer(norm(x))`` and no
-                  more (Nemotron-H); the tree's ``layers`` then holds one
-                  stack a word, ``[pp, blocks of that word / pp, ...]``
-  mamba           a Mamba-2 mixer: projection, causal depthwise convolution,
-                  the chunked scan with its carried state in float32, the
-                  gated grouped norm; no sp, pp or tp
+  one sublayer    a kind that starts with a word of ``_BLOCK_KINDS``,
+                  ("mamba",), ("experts",), ("attention", window, rope):
+                  ``x + mixer(norm(x))`` and no more (Nemotron-H); the tree's
+                  ``layers`` then holds one stack a word, ``[pp, blocks of
+                  that word / pp, ...]`` (a Mamba block: ``models/mamba.py``)
   expert share    ``expert_share=(i, of)``: this device holds that share of
                   every layer's experts with no ep axis live (one chip of an
                   expert-parallel group, run alone)
@@ -52,8 +57,10 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from horovod_tpu._compat import axis_size, shard_map
 
+from horovod_tpu.models import mamba
+from horovod_tpu.models._kinds import (BlockKind, Leaf, normal, ones, remat,
+                                       rmsnorm, zeros)
 from horovod_tpu.models.scan_util import multi_step
-from horovod_tpu.ops import pallas_ssm
 from horovod_tpu.parallel.ring_attention import ring_attention_spmd
 from horovod_tpu.parallel.moe import grouped_matmul, moe_layer_spmd
 from horovod_tpu.profiling import scopes
@@ -173,7 +180,7 @@ class TransformerConfig:
     @property
     def one_sublayer(self) -> bool:
         """Whether the pattern's kinds are blocks of one sublayer."""
-        return _one_sublayer(self.layer_pattern)
+        return isinstance(self.layer_pattern[0][0], str)
 
     @property
     def ssm_inner(self) -> int:
@@ -203,250 +210,18 @@ class TransformerConfig:
         if self.moe_router_scores not in ("softmax", "sigmoid"):
             raise ValueError(
                 f"moe_router_scores={self.moe_router_scores!r}")
-        words = {kind[0] for kind in self.layer_pattern
-                 if isinstance(kind[0], str)}
-        if words and (not words <= set(_SUBLAYERS) or any(
-                len(kind) != _SUBLAYERS.get(kind[0])
-                for kind in self.layer_pattern)):
+        words = dict.fromkeys(kind[0] for kind in self.layer_pattern
+                              if isinstance(kind[0], str))
+        if words and not all(
+                kind[0] in _BLOCK_KINDS
+                and len(kind) == _BLOCK_KINDS[kind[0]].length
+                for kind in self.layer_pattern):
             raise ValueError(
                 f"layer_pattern={self.layer_pattern}: a block of one "
-                f"sublayer is one of {sorted(_SUBLAYERS)}, and a pattern is "
-                "of such blocks throughout or of none")
-        if "experts" in words and not self.n_experts:
-            raise ValueError("layer_pattern has (\"experts\",) blocks and "
-                             "n_experts=0")
-        if "mamba" in words and (
-                self.ssm_heads < 1 or self.ssm_heads % self.ssm_groups):
-            raise ValueError(
-                f"layer_pattern has (\"mamba\",) blocks: ssm_groups="
-                f"{self.ssm_groups} does not divide ssm_heads="
-                f"{self.ssm_heads}")
-
-
-#: the blocks of one sublayer, and the length of each one's kind
-_SUBLAYERS = {"mamba": 1, "experts": 1, "attention": 3}
-
-
-def _one_sublayer(pattern) -> bool:
-    """Whether a pattern's kinds start with a word (a valid pattern's do
-    throughout or not at all)."""
-    return isinstance(pattern[0][0], str)
-
-
-# ---------------------------------------------------------------------------
-# Parameter init (host-side, then device_put with shardings)
-# ---------------------------------------------------------------------------
-
-def _sublayer_counts(cfg: TransformerConfig) -> Dict[str, int]:
-    """Blocks of each word in one period of a pattern of one-sublayer
-    blocks, in the order the words first appear."""
-    words = [kind[0] for kind in cfg.layer_pattern]
-    return {word: words.count(word) for word in dict.fromkeys(words)}
-
-
-def _attention_leaves(cfg: TransformerConfig, w, ones, lead) -> Dict:
-    M, H, Dh, Hkv = cfg.d_model, cfg.n_heads, cfg.head_dim, cfg.kv_heads
-    leaves = {
-        "ln1": ones(*lead, M),
-        "wq": w(*lead, M, H * Dh),
-        "wk": w(*lead, M, Hkv * Dh),
-        "wv": w(*lead, M, Hkv * Dh),
-        "wo": w(*lead, H * Dh, M),
-    }
-    if cfg.qk_norm:
-        leaves.update({"q_norm": ones(*lead, H * Dh),
-                       "k_norm": ones(*lead, Hkv * Dh)})
-    if cfg.post_norm:
-        leaves["ln1_post"] = ones(*lead, M)
-    return leaves
-
-
-def _ffn_leaves(cfg: TransformerConfig, w, ones, lead) -> Dict:
-    M, F = cfg.d_model, cfg.d_ff
-    leaves = {"ln2": ones(*lead, M)}
-    if cfg.post_norm:
-        leaves["ln2_post"] = ones(*lead, M)
-    if cfg.n_experts > 0:
-        # we1 is the gate of a gated expert, we3 its up projection, we2
-        # the way back down (the Mixtral numbering); the experts held
-        # here lead, the router scores them all
-        held = cfg.held_experts
-        leaves.update({
-            "router": w(*lead, M, cfg.n_experts, scale=0.02),
-            "we1": w(*lead, held, M, F),
-            "we2": w(*lead, held, F, M),
-        })
-        if cfg.moe_gated:
-            leaves["we3"] = w(*lead, held, M, F)
-        if cfg.moe_router_scores == "sigmoid":
-            # the correction of the choice: a buffer, held at zero (what
-            # moves it in training is no gradient and not implemented)
-            leaves["router_bias"] = np.zeros((*lead, cfg.n_experts),
-                                             np.float32)
-        if cfg.moe_shared_width:
-            leaves.update({"ws1": w(*lead, M, cfg.moe_shared_width),
-                           "ws2": w(*lead, cfg.moe_shared_width, M)})
-    else:
-        # w1 is the gate of a gated FFN and w3 its up projection, as the
-        # experts number theirs
-        leaves.update({"w1": w(*lead, M, F), "w2": w(*lead, F, M)})
-        if cfg.ffn_gated:
-            leaves["w3"] = w(*lead, M, F)
-    return leaves
-
-
-#: the range a Mamba-2 head's time step ``softplus(dt_bias)`` is drawn
-#: from, log-uniformly, its floor, and the range of ``-A`` (the reference
-#: implementation's defaults, which Nemotron-H's config repeats)
-SSM_DT_RANGE, SSM_DT_FLOOR, SSM_A_RANGE = (1e-3, 1e-1), 1e-4, (1.0, 16.0)
-
-
-def _mamba_leaves(cfg: TransformerConfig, rng, w, ones, lead) -> Dict:
-    """``ssm_in`` maps to ``[z | x B C | dt]``; the convolution's taps are
-    ``[tap, channel]``, tap ``ssm_conv - 1`` on the current position."""
-    M, H, inner, K = cfg.d_model, cfg.ssm_heads, cfg.ssm_inner, cfg.ssm_conv
-    dt = np.exp(rng.uniform(*np.log(SSM_DT_RANGE), size=(*lead, H)))
-    dt = np.maximum(dt, SSM_DT_FLOOR)
-
-    def taps(*shape):
-        return (rng.uniform(-1, 1, shape) / np.sqrt(K)).astype(np.float32)
-    return {
-        "ln1": ones(*lead, M),
-        "ssm_in": w(*lead, M, inner + cfg.ssm_conv_width + H),
-        "ssm_conv_w": taps(*lead, K, cfg.ssm_conv_width),
-        "ssm_conv_b": taps(*lead, cfg.ssm_conv_width),
-        # softplus(dt_bias) = dt
-        "ssm_dt_bias": np.log(np.expm1(dt)).astype(np.float32),
-        "ssm_a_log": np.log(rng.uniform(*SSM_A_RANGE, size=(*lead, H))
-                            ).astype(np.float32),
-        "ssm_d": ones(*lead, H),
-        "ssm_norm": ones(*lead, inner),
-        "ssm_out": w(*lead, inner, M),
-    }
-
-
-def init_params(rng: np.random.RandomState, cfg: TransformerConfig,
-                n_stages: int = 1) -> Dict:
-    """Initialize parameters in the stacked-stage layout ``[pp, L/pp, ...]``;
-    a pattern of one-sublayer blocks has one such stack a word under
-    ``layers`` (``layers["mamba"]["ssm_in"]`` ``[pp, blocks / pp, ...]``)."""
-    L = cfg.n_layers
-    assert L % n_stages == 0, (L, n_stages)
-    lps = L // n_stages
-    M = cfg.d_model
-
-    def w(*shape, scale=None):
-        scale = scale if scale is not None else (1.0 / np.sqrt(shape[-2]))
-        return (rng.randn(*shape) * scale).astype(np.float32)
-
-    def ones(*shape):
-        return np.ones(shape, np.float32)
-
-    if cfg.one_sublayer:
-        layer = {}
-        for word, count in _sublayer_counts(cfg).items():
-            lead = (n_stages, lps // len(cfg.layer_pattern) * count)
-            layer[word] = (
-                _mamba_leaves(cfg, rng, w, ones, lead) if word == "mamba"
-                else _ffn_leaves(cfg, w, ones, lead) if word == "experts"
-                else _attention_leaves(cfg, w, ones, lead))
-    else:
-        layer = {**_attention_leaves(cfg, w, ones, (n_stages, lps)),
-                 **_ffn_leaves(cfg, w, ones, (n_stages, lps))}
-    params = {
-        "embed": (rng.randn(cfg.vocab_size, M) * 0.02).astype(np.float32),
-        "ln_f": np.ones((M,), np.float32),
-        "layers": layer,
-    }
-    if not cfg.tie_embeddings:
-        params["lm_head"] = w(M, cfg.vocab_size)
-    if cfg.n_loops > 1:
-        # Linear(M -> 1), read on every loop step's state
-        params["exit_gate"] = w(M, 1)
-        params["exit_gate_bias"] = np.zeros((1,), np.float32)
-    return params
-
-
-def param_shardings(cfg: TransformerConfig, mesh: Mesh) -> Dict:
-    """NamedSharding tree matching :func:`init_params` layout."""
-    def s(*spec):
-        return NamedSharding(mesh, P(*spec))
-    tp = "tp" if mesh.shape.get("tp", 1) > 1 else None
-    pp = "pp" if mesh.shape.get("pp", 1) > 1 else None
-    ep = "ep" if mesh.shape.get("ep", 1) > 1 else None
-    if tp and cfg.kv_heads % mesh.shape["tp"]:
-        raise ValueError(
-            f"tp={mesh.shape['tp']} does not divide n_kv_heads="
-            f"{cfg.kv_heads}: a tp shard holds whole k/v heads")
-    if ep and cfg.expert_share != (0, 1):
-        raise ValueError(
-            f"expert_share={cfg.expert_share} on a mesh with a live ep "
-            "axis: a device holds its experts by its place on the axis or "
-            "by being told, not both")
-    if pp and (cfg.n_layers // mesh.shape["pp"]) % len(cfg.layer_pattern):
-        raise ValueError(
-            f"layer_pattern of {len(cfg.layer_pattern)} kinds on pp="
-            f"{mesh.shape['pp']}: a stage of "
-            f"{cfg.n_layers // mesh.shape['pp']} layers is not whole periods")
-    live = [a for a in ("sp", "pp", "tp") if mesh.shape.get(a, 1) > 1]
-    if live and ("mamba",) in cfg.layer_pattern:
-        raise NotImplementedError(
-            f"a (\"mamba\",) block of layer_pattern on a live "
-            f"{' / '.join(live)} axis: the convolution and the scan's "
-            "carried state run over the whole sequence on one device (no "
-            "hand-over between sp shards), its heads and groups are not "
-            "split over tp, and no pipeline schedule has run it")
-    attention = {
-        "ln1": s(pp),
-        "wq": s(pp, None, None, tp), "wk": s(pp, None, None, tp),
-        "wv": s(pp, None, None, tp), "wo": s(pp, None, tp, None),
-    }
-    ffn = {"ln2": s(pp)}
-    if cfg.qk_norm:
-        attention.update({"q_norm": s(pp, None, tp),
-                          "k_norm": s(pp, None, tp)})
-    if cfg.post_norm:
-        attention["ln1_post"] = ffn["ln2_post"] = s(pp)
-    if cfg.n_experts > 0:
-        ffn.update({
-            "router": s(pp),
-            "we1": s(pp, None, ep, None, tp),
-            "we2": s(pp, None, ep, tp, None),
-        })
-        if cfg.moe_gated:
-            ffn["we3"] = s(pp, None, ep, None, tp)
-        if cfg.moe_router_scores == "sigmoid":
-            ffn["router_bias"] = s(pp)
-        if cfg.moe_shared_width:
-            ffn.update({"ws1": s(pp, None, None, tp),
-                        "ws2": s(pp, None, tp, None)})
-    else:
-        ffn.update({"w1": s(pp, None, None, tp),
-                    "w2": s(pp, None, tp, None)})
-        if cfg.ffn_gated:
-            ffn["w3"] = s(pp, None, None, tp)
-    if cfg.one_sublayer:
-        # (a Mamba block's leaves are whole on every device: no pp, tp)
-        mamba = {name: s() for name in (
-            "ln1", "ssm_in", "ssm_conv_w", "ssm_conv_b", "ssm_dt_bias",
-            "ssm_a_log", "ssm_d", "ssm_norm", "ssm_out")}
-        layers = {word: {"mamba": mamba, "experts": ffn,
-                         "attention": attention}[word]
-                  for word in _sublayer_counts(cfg)}
-    else:
-        layers = {**attention, **ffn}
-    shardings = {"embed": s(tp), "ln_f": s(), "layers": layers}
-    if not cfg.tie_embeddings:
-        shardings["lm_head"] = s(None, tp)
-    if cfg.n_loops > 1:
-        shardings.update({"exit_gate": s(), "exit_gate_bias": s()})
-    return shardings
-
-
-def shard_params(params: Dict, cfg: TransformerConfig, mesh: Mesh) -> Dict:
-    sh = param_shardings(cfg, mesh)
-    return jax.tree_util.tree_map(
-        lambda x, s: jax.device_put(jnp.asarray(x), s), params, sh)
+                f"sublayer is one of {sorted(_BLOCK_KINDS)}, and a pattern "
+                "is of such blocks throughout or of none")
+        for word in words:
+            _BLOCK_KINDS[word].validate(self)
 
 
 # ---------------------------------------------------------------------------
@@ -463,12 +238,6 @@ def _axis_live(name: str) -> bool:
 
 def _psum_if(x, name):
     return lax.psum(x, name) if _axis_live(name) else x
-
-
-def _rmsnorm(x, g, eps=1e-6):
-    var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
-    return (x.astype(jnp.float32) * jax.lax.rsqrt(var + eps)
-            ).astype(x.dtype) * g.astype(x.dtype)
 
 
 def _qk_norm(x, g, eps):
@@ -605,6 +374,21 @@ def _head_xent(x, head, targets):
     return head_softmax_xent(x, head, targets)
 
 
+def _attention_leaves(cfg: TransformerConfig):
+    M = cfg.d_model
+    q, kv = cfg.n_heads * cfg.head_dim, cfg.kv_heads * cfg.head_dim
+    yield Leaf("ln1", (M,), ones)
+    yield Leaf("wq", (M, q), normal(), (None, "tp"))
+    yield Leaf("wk", (M, kv), normal(), (None, "tp"))
+    yield Leaf("wv", (M, kv), normal(), (None, "tp"))
+    yield Leaf("wo", (q, M), normal(), ("tp", None))
+    if cfg.qk_norm:
+        yield Leaf("q_norm", (q,), ones, ("tp",))
+        yield Leaf("k_norm", (kv,), ones, ("tp",))
+    if cfg.post_norm:
+        yield Leaf("ln1_post", (M,), ones)
+
+
 #: a layer of the default pattern: the whole causal triangle, with rope
 _PLAIN_LAYER = (None, True)
 
@@ -622,7 +406,7 @@ def _attention_block(p, x, positions, cfg: TransformerConfig,
             "live sp axis: ring_attention_spmd passes whole k/v blocks of "
             "n_heads heads round the ring and masks by the diagonal only")
     with jax.named_scope(scopes.ATTENTION):
-        h = _rmsnorm(x, p["ln1"], cfg.norm_eps)
+        h = rmsnorm(x, p["ln1"], cfg.norm_eps)
         q = (h @ p["wq"].astype(h.dtype))
         k = (h @ p["wk"].astype(h.dtype))
         v = (h @ p["wv"].astype(h.dtype))
@@ -655,8 +439,39 @@ def _attention_block(p, x, positions, cfg: TransformerConfig,
         o = o.reshape(B, S, Hl * cfg.head_dim) @ p["wo"].astype(x.dtype)
         o = _psum_if(o, "tp")
         if cfg.post_norm:
-            o = _rmsnorm(o, p["ln1_post"], cfg.norm_eps)
+            o = rmsnorm(o, p["ln1_post"], cfg.norm_eps)
         return x + o
+
+
+def _ffn_leaves(cfg: TransformerConfig):
+    M, F, E, held = cfg.d_model, cfg.d_ff, cfg.n_experts, cfg.held_experts
+    shared = cfg.moe_shared_width
+    yield Leaf("ln2", (M,), ones)
+    if cfg.post_norm:
+        yield Leaf("ln2_post", (M,), ones)
+    if E > 0:
+        # we1 is the gate of a gated expert, we3 its up projection, we2
+        # the way back down (the Mixtral numbering); the experts held
+        # here lead, the router scores them all
+        yield Leaf("router", (M, E), normal(0.02))
+        yield Leaf("we1", (held, M, F), normal(), ("ep", None, "tp"))
+        yield Leaf("we2", (held, F, M), normal(), ("ep", "tp", None))
+        if cfg.moe_gated:
+            yield Leaf("we3", (held, M, F), normal(), ("ep", None, "tp"))
+        if cfg.moe_router_scores == "sigmoid":
+            # the correction of the choice: a buffer, held at zero (what
+            # moves it in training is no gradient and not implemented)
+            yield Leaf("router_bias", (E,), zeros)
+        if shared:
+            yield Leaf("ws1", (M, shared), normal(), (None, "tp"))
+            yield Leaf("ws2", (shared, M), normal(), ("tp", None))
+    else:
+        # w1 is the gate of a gated FFN and w3 its up projection, as the
+        # experts number theirs
+        yield Leaf("w1", (M, F), normal(), (None, "tp"))
+        yield Leaf("w2", (F, M), normal(), ("tp", None))
+        if cfg.ffn_gated:
+            yield Leaf("w3", (M, F), normal(), (None, "tp"))
 
 
 def _dense_ffn(p, x, cfg: TransformerConfig):
@@ -717,7 +532,8 @@ def _moe_ffn(p, x, cfg: TransformerConfig, logits=None):
     with jax.named_scope(scopes.MOE):
         y, m = moe_layer_spmd(
             toks, p["router"], expert_fn,
-            {n: p[n] for n in ("we1", "we2", "we3") if n in p},
+            {leaf.name: p[leaf.name] for leaf in _ffn_leaves(cfg)
+             if "ep" in leaf.spec},
             axis_name="ep" if _axis_live("ep") else None,
             k=cfg.moe_top_k, renormalize=cfg.moe_renormalize,
             stat_axes=[a for a in ("dp", "ep", "sp") if _axis_live(a)],
@@ -734,203 +550,6 @@ def _moe_ffn(p, x, cfg: TransformerConfig, logits=None):
                         + cfg.moe_z_weight * m.router_z_loss),
            **metrics}
     return y.reshape(B, S, M), aux
-
-
-def _causal_conv(x, taps, bias):
-    """Depthwise causal convolution over the sequence: ``y[t] = bias +
-    sum_j taps[j] * x[t - (K - 1) + j]`` with zeros before the start. x
-    ``[B, S, C]``, taps ``[K, C]``; K shifted multiply-adds in float32."""
-    K, S = taps.shape[0], x.shape[1]
-    padded = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
-    y = bias.astype(jnp.float32)
-    for j in range(K):
-        y = y + (taps[j].astype(jnp.float32)
-                 * padded[:, j:j + S].astype(jnp.float32))
-    return y
-
-
-def _ssm_decay(log_decay):
-    """``exp`` of a sum of ``dt_t a`` (never positive), in float32 as it
-    comes: the one place the scan's decays are made (a test swaps it for
-    the nearest precision below)."""
-    return jnp.exp(log_decay)
-
-
-def _carried_states(whole, states):
-    """The state each chunk starts from, ``[B, n, ...]``: ``H <- whole_c H
-    + states_c`` over the ``n`` chunks from ``H = 0``, in float32. whole
-    ``[B, n, G, R]`` a chunk's whole decay ``exp(s_Q)``, states ``[B, n, G,
-    R, P, N]`` what a chunk's own positions leave behind."""
-    def carry(h, chunk):
-        decay, state = chunk
-        return decay[..., None, None] * h + state, h
-    _, before = lax.scan(
-        carry, jnp.zeros_like(states[:, 0]),
-        (jnp.moveaxis(whole, 1, 0), jnp.moveaxis(states, 1, 0)))
-    return jnp.moveaxis(before, 0, 1)
-
-
-def _gated_norm(y, z, weight, groups: int, eps: float):
-    """``rmsnorm(y * silu(z)) * weight`` in float32, the gate before the
-    norm and the norm over each of ``groups`` groups of channels. y, z
-    ``[B, S, C]``."""
-    B, S, C = y.shape
-    y = (y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
-         ).reshape(B, S, groups, C // groups)
-    y = y * jax.lax.rsqrt(jnp.mean(jnp.square(y), axis=-1, keepdims=True)
-                          + eps)
-    return y.reshape(B, S, C) * weight.astype(jnp.float32)
-
-
-def _within_chunks(x, b, c, s, dt):
-    """``y_i = sum_{j <= i} exp(s_i - s_j) (c_i . b_j) dt_j x_j`` inside
-    every chunk: the scores, decays and their product are ``[B, n, G, R, Q,
-    Q]`` (at 8192 positions and 64 heads of chunk 128, 268 MB in float32).
-    x ``[B, n, Q, G, R, P]``, b and c ``[B, n, Q, G, N]``, s and dt ``[B,
-    n, G, R, Q]`` float32; returns float32 ``[B, n, Q, G, R, P]``."""
-    chunk = x.shape[2]
-    scores = jnp.einsum("bnigs,bnjgs->bngij", c, b,
-                        preferred_element_type=jnp.float32)
-    causal = jnp.tril(jnp.ones((chunk, chunk), bool))
-    decay = _ssm_decay(jnp.where(
-        causal, s[..., :, None] - s[..., None, :], -jnp.inf))
-    weights = (scores[:, :, :, None] * decay * dt[..., None, :]
-               ).astype(x.dtype)
-    return jnp.einsum("bngrij,bnjgrp->bnigrp", weights, x,
-                      preferred_element_type=jnp.float32)
-
-
-def _chunk_sums(steps, chunk: int):
-    """``s_i = sum_{t <= i} steps_t`` inside every chunk of ``chunk``
-    positions, ``[B, S, H]`` float32."""
-    B, S, H = steps.shape
-    return jnp.cumsum(steps.reshape(B, S // chunk, chunk, H), axis=2
-                      ).reshape(B, S, H)
-
-
-def ssm_chunked(x, dt, a, b, c, chunk: int, interpret: bool = False):
-    """The selective state-space recurrence of Mamba-2 in its chunked
-    (dual) form (arXiv:2405.21060, section 6). Per head, with ``a_t = dt_t
-    a`` (``a`` < 0) and the state ``H`` ``[P, N]``:
-
-        H_t = exp(a_t) H_{t-1} + dt_t x_t (x) b_t        y_t = H_t c_t
-
-    Inside a chunk of ``chunk`` positions, ``s_i = sum_{t <= i} a_t``:
-
-        y_i = sum_{j <= i} exp(s_i - s_j) (c_i . b_j) dt_j x_j
-              + exp(s_i) c_i . H_prev
-        H_next = exp(s_Q) H_prev + sum_j exp(s_Q - s_j) dt_j x_j (x) b_j
-
-    so a chunk is three batches of matmuls (scores ``c b^T``, scores times
-    x, x^T times b) and the sequence a loop over chunks that carries
-    ``H``. The time steps, the sums ``s``, every decay and the carried
-    state are float32; the matmuls take operands of ``x.dtype`` and
-    accumulate in float32, the decays and ``dt`` multiplied into the
-    scores before they are cast.
-
-    x ``[B, S, H, P]``; dt ``[B, S, H]`` float32, after its softplus; a
-    ``[H]`` float32; b, c ``[B, S, G, N]``, head h reading group ``h // (H
-    / G)``. Returns y ``[B, S, H, P]`` float32 (without the skip ``D x``).
-
-    On a TPU (and under ``interpret``) the chunks run in the Pallas kernels
-    of ``ops/pallas_ssm.py`` wherever the shapes fit their tiles
-    (:func:`pallas_ssm.ssm_eligible`): the same algorithm at the same
-    precision, the sums ``s`` made here, nothing of a chunk's inside in
-    HBM. Elsewhere, and as what the kernels are held against, the
-    ``jax.numpy`` form below (:func:`_ssm_chunked_numpy`).
-    """
-    B, S, H, P = x.shape
-    G, N = b.shape[2:]
-    if S % chunk:
-        raise ValueError(f"ssm_chunk={chunk} does not divide the sequence "
-                         f"of {S} positions")
-    s = _chunk_sums(dt * a, chunk)
-    if interpret or (jax.default_backend() == "tpu"
-                     and pallas_ssm.ssm_eligible(S, H, P, G, N, chunk)):
-        return pallas_ssm.ssm_scan(x, dt, s, b, c, chunk, interpret)
-    return _ssm_chunked_numpy(x, dt, s, b, c, chunk)
-
-
-def _ssm_chunked_numpy(x, dt, s, b, c, chunk: int):
-    """:func:`ssm_chunked` from the sums ``s`` ``[B, S, H]`` on, in
-    ``jax.numpy`` and differentiated by JAX: the scores, decays and their
-    product inside a chunk, the chunks' own states and the states they
-    start from are arrays of their own."""
-    B, S, H, P = x.shape
-    G, N = b.shape[2:]
-    n, R = S // chunk, H // G
-    x = x.reshape(B, n, chunk, G, R, P)
-    b, c = (v.reshape(B, n, chunk, G, N) for v in (b, c))
-    # [B, n, G, R, Q]: a head's positions last
-    dt, s = (v.reshape(B, n, chunk, G, R).transpose(0, 1, 3, 4, 2)
-             for v in (dt, s))
-
-    y = _within_chunks(x, b, c, s, dt)
-
-    # -- a chunk's own state, and the state each chunk starts from ---------
-    to_end = (_ssm_decay(s[..., -1:] - s) * dt).transpose(0, 1, 4, 2, 3)
-    states = jnp.einsum("bnjgrp,bnjgs->bngrps",
-                        (x.astype(jnp.float32) * to_end[..., None]
-                         ).astype(x.dtype), b,
-                        preferred_element_type=jnp.float32)
-    since_start = _ssm_decay(s)                 # exp(s_i); the last: exp(s_Q)
-    before = _carried_states(since_start[..., -1], states)
-    y = y + (jnp.einsum("bnigs,bngrps->bnigrp", c, before.astype(x.dtype),
-                        preferred_element_type=jnp.float32)
-             * since_start.transpose(0, 1, 4, 2, 3)[..., None])
-    return y.reshape(B, S, H, P)
-
-
-def ssm_path(cfg: TransformerConfig, seq_len: int) -> str:
-    """How a Mamba block's scan runs at ``seq_len`` positions and what the
-    backward pass keeps of the block (``chip_smoke.py`` prints it, as it
-    does ``attend``'s choice)."""
-    kept = ("each Mamba block checkpointed: its input kept, the block run "
-            "again in the backward pass" if _remat(cfg, True) else
-            "everything kept for the backward pass")
-    how = pallas_ssm.ssm_scan_path(
-        seq_len, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups,
-        cfg.ssm_state, cfg.ssm_chunk)
-    return (f"{how}; chunked scan, {seq_len // cfg.ssm_chunk} chunks of "
-            f"{cfg.ssm_chunk}, float32 sums, decays and carried state "
-            f"[{cfg.ssm_heads}, {cfg.ssm_head_dim}, {cfg.ssm_state}]; {kept}")
-
-
-def _mamba_block(p, x, cfg: TransformerConfig):
-    """``x + mamba2(norm(x))``, x ``[B', S', M]`` with the whole sequence
-    here (no sp). The mixer: ``[z | x B C | dt] = h W_in``; x, B and C
-    through the causal convolution and silu; ``dt = softplus(dt +
-    dt_bias)``, ``a = -exp(a_log)`` a head; the scan (:func:`ssm_chunked`)
-    plus the skip ``d x``; ``rmsnorm(y * silu(z))`` over each of the
-    ``ssm_groups`` groups of channels; ``W_out``."""
-    B, S, M = x.shape
-    H, P, G, N = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state
-    inner, wide = cfg.ssm_inner, cfg.ssm_conv_width
-    with jax.named_scope(scopes.SSM):
-        h = _rmsnorm(x, p["ln1"], cfg.norm_eps)
-        with jax.named_scope(scopes.SSM_PROJ):
-            zxbcdt = h @ p["ssm_in"].astype(h.dtype)
-        z, xbc, dt = (zxbcdt[..., :inner], zxbcdt[..., inner:inner + wide],
-                      zxbcdt[..., inner + wide:])
-        with jax.named_scope(scopes.SSM_CONV):
-            xbc = jax.nn.silu(_causal_conv(
-                xbc, p["ssm_conv_w"], p["ssm_conv_b"]).astype(h.dtype))
-        xs = xbc[..., :inner].reshape(B, S, H, P)
-        b = xbc[..., inner:inner + G * N].reshape(B, S, G, N)
-        c = xbc[..., inner + G * N:].reshape(B, S, G, N)
-        with jax.named_scope(scopes.SSM_SCAN):
-            dt = jax.nn.softplus(dt.astype(jnp.float32)
-                                 + p["ssm_dt_bias"].astype(jnp.float32))
-            a = -jnp.exp(p["ssm_a_log"].astype(jnp.float32))
-            y = ssm_chunked(xs, dt, a, b, c, cfg.ssm_chunk)
-            y = y + (p["ssm_d"].astype(jnp.float32)[:, None]
-                     * xs.astype(jnp.float32))
-        with jax.named_scope(scopes.SSM_NORM):
-            y = _gated_norm(y.reshape(B, S, inner), z, p["ssm_norm"], G,
-                            cfg.norm_eps).astype(h.dtype)
-        with jax.named_scope(scopes.SSM_PROJ):
-            o = y @ p["ssm_out"].astype(h.dtype)
-        return x + o
 
 
 def _shared_expert(p, toks, activation):
@@ -956,15 +575,24 @@ def _over_layers(auxs):
             if k != "experts"}
 
 
-def _block(p, x, positions, cfg: TransformerConfig, kind=_PLAIN_LAYER):
-    """One block of ``kind`` on ``x``; returns (the new residual, the
-    block's auxiliary terms, None where it has none to stack)."""
-    if kind[0] == "mamba":
-        return _mamba_block(p, x, cfg), None
-    if kind[0] == "attention":
-        return _attention_block(p, x, positions, cfg, kind[1:]), None
-    if kind[0] == "experts":
-        return _ffn_block(p, x, cfg)
+def _ffn_block(p, x, cfg: TransformerConfig, logits=None):
+    """``x + ffn(norm(x))``, the FFN dense or the experts; ``logits``: a
+    router's that read something else than the normed tokens."""
+    with jax.named_scope(scopes.MLP):
+        h = rmsnorm(x, p["ln2"], cfg.norm_eps)
+        if cfg.n_experts > 0:
+            o, aux = _moe_ffn(p, h, cfg, logits)
+        else:
+            o, aux = _dense_ffn(p, h, cfg), _no_aux()
+        o = o.astype(x.dtype)
+        if cfg.post_norm:
+            o = rmsnorm(o, p["ln2_post"], cfg.norm_eps)
+        return x + o, aux
+
+
+def _attention_then_ffn(p, x, positions, cfg: TransformerConfig, kind):
+    """The block of two sublayers, ``kind`` its attention's (window, rope):
+    the attention row's function, then the FFN row's."""
     logits = None
     if cfg.n_experts > 0 and cfg.moe_router_input == "block_input":
         # before attention, from the residual as it comes in: nothing of
@@ -974,55 +602,140 @@ def _block(p, x, positions, cfg: TransformerConfig, kind=_PLAIN_LAYER):
     return _ffn_block(p, x, cfg, logits)
 
 
-def _ffn_block(p, x, cfg: TransformerConfig, logits=None):
-    """``x + ffn(norm(x))``, the FFN dense or the experts; ``logits``: a
-    router's that read something else than the normed tokens."""
-    with jax.named_scope(scopes.MLP):
-        h = _rmsnorm(x, p["ln2"], cfg.norm_eps)
-        if cfg.n_experts > 0:
-            o, aux = _moe_ffn(p, h, cfg, logits)
-        else:
-            o, aux = _dense_ffn(p, h, cfg), _no_aux()
-        o = o.astype(x.dtype)
-        if cfg.post_norm:
-            o = _rmsnorm(o, p["ln2_post"], cfg.norm_eps)
-        return x + o, aux
+def _needs_experts(cfg: TransformerConfig) -> None:
+    if not cfg.n_experts:
+        raise ValueError("layer_pattern has (\"experts\",) blocks and "
+                         "n_experts=0")
 
 
-def _remat(cfg: TransformerConfig, needed: bool) -> bool:
-    """Whether a path checkpoints its blocks: what the config says, or
-    where it says nothing, whether the path needs it to fit."""
-    return needed if cfg.remat is None else cfg.remat
+#: the kinds of block of ONE sublayer, by the word a kind of
+#: ``layer_pattern`` starts with: the one place that says which exist. A new
+#: mixer is its config fields, scopes, leaves and function, and a row here.
+_BLOCK_KINDS = {
+    "mamba": mamba.KIND,
+    "experts": BlockKind(
+        length=1, leaves=_ffn_leaves, validate=_needs_experts,
+        apply=lambda p, x, positions, cfg, kind: _ffn_block(p, x, cfg)),
+    "attention": BlockKind(
+        length=3, leaves=_attention_leaves,
+        apply=lambda p, x, positions, cfg, kind: (
+            _attention_block(p, x, positions, cfg, kind[1:]), None)),
+}
+
+#: a kind (window, rope) that starts with no word: the attention row and the
+#: FFN row in one block, their leaves in one stack
+_TWO_SUBLAYERS = BlockKind(
+    length=2, apply=_attention_then_ffn,
+    leaves=lambda cfg: (*_BLOCK_KINDS["attention"].leaves(cfg),
+                        *_BLOCK_KINDS["experts"].leaves(cfg)))
 
 
-def _stage_fn_factory(cfg: TransformerConfig, positions):
-    """Returns stage_fn(stage_params, act) running L/pp blocks via scan.
+def _stack_of(kind) -> Optional[str]:
+    """The stack of ``layers`` a block of ``kind`` is in: its word's, or the
+    one unnamed stack (None) of two-sublayer blocks."""
+    return kind[0] if isinstance(kind[0], str) else None
 
-    The weighted sum of the MoE's auxiliary losses rides as one extra
-    feature column of the activation so the pipeline carry stays a single
-    array (pipeline_spmd requirement); it accumulates across stages and is
-    read back after the pipeline.
-    """
-    def block_of(kind):
-        def one_block(lp, x):
-            def fn(xx):
-                return _block(lp, xx, positions, cfg, kind)
-            if _remat(cfg, True):
-                fn = jax.checkpoint(fn)
-            return fn(x)
-        return one_block
 
-    def stage_fn(stage_params, act_with_aux):
-        act = act_with_aux[..., :-1]
-        aux_in = act_with_aux[..., -1:]
-        # a stage is whole periods (param_shardings), so its first layer
-        # is of the pattern's first kind
-        y, auxs = _scan_periods(block_of, act.astype(cfg.dtype),
-                                stage_params, cfg.layer_pattern)
-        aux_out = aux_in + jnp.sum(auxs["aux_loss"]) / max(cfg.n_layers, 1)
-        return jnp.concatenate([y.astype(jnp.float32), aux_out], axis=-1)
+def _row(kind) -> BlockKind:
+    return _BLOCK_KINDS.get(_stack_of(kind), _TWO_SUBLAYERS)
 
-    return stage_fn
+
+def _block(p, x, positions, cfg: TransformerConfig, kind=_PLAIN_LAYER):
+    """One block of ``kind`` on ``x``; returns (the new residual, the
+    block's auxiliary terms, None where it has none to stack)."""
+    return _row(kind).apply(p, x, positions, cfg, kind)
+
+
+# ---------------------------------------------------------------------------
+# Parameter init (host-side, then device_put with shardings)
+# ---------------------------------------------------------------------------
+
+def _model_leaves(cfg: TransformerConfig):
+    """The leaves beside the stack of blocks, drawn after it."""
+    M, V = cfg.d_model, cfg.vocab_size
+    yield Leaf("embed", (V, M), normal(0.02), ("tp",))
+    yield Leaf("ln_f", (M,), ones)
+    if not cfg.tie_embeddings:
+        yield Leaf("lm_head", (M, V), normal(), (None, "tp"))
+    if cfg.n_loops > 1:
+        # Linear(M -> 1), read on every loop step's state
+        yield Leaf("exit_gate", (M, 1), normal())
+        yield Leaf("exit_gate_bias", (1,), zeros)
+
+
+def _stacks(cfg: TransformerConfig, layers: int = 0) -> Dict:
+    """The stacks of blocks the tree's ``layers`` holds, in the order the
+    pattern first names them: {:func:`_stack_of` its kinds: (their row, how
+    many of ``layers`` consecutive layers are blocks of it)}."""
+    if not cfg.one_sublayer:
+        return {None: (_TWO_SUBLAYERS, layers)}
+    of = [_stack_of(kind) for kind in cfg.layer_pattern]
+    return {word: (_BLOCK_KINDS[word], layers // len(of) * of.count(word))
+            for word in dict.fromkeys(of)}
+
+
+def init_params(rng: np.random.RandomState, cfg: TransformerConfig,
+                n_stages: int = 1) -> Dict:
+    """Initialize parameters in the stacked-stage layout ``[pp, L/pp, ...]``;
+    a pattern of one-sublayer blocks has one such stack a word under
+    ``layers`` (``layers["mamba"]["ssm_in"]`` ``[pp, blocks / pp, ...]``)."""
+    L = cfg.n_layers
+    assert L % n_stages == 0, (L, n_stages)
+    stacks = {
+        key: {leaf.name: leaf.draw(rng, (n_stages, blocks) + leaf.shape)
+              for leaf in row.leaves(cfg)}
+        for key, (row, blocks) in _stacks(cfg, L // n_stages).items()}
+    params = {leaf.name: leaf.draw(rng, leaf.shape)
+              for leaf in _model_leaves(cfg)}
+    params["layers"] = stacks if cfg.one_sublayer else stacks[None]
+    return params
+
+
+def param_shardings(cfg: TransformerConfig, mesh: Mesh) -> Dict:
+    """NamedSharding tree matching :func:`init_params` layout."""
+    live = {a: a if mesh.shape.get(a, 1) > 1 else None
+            for a in ("sp", "pp", "tp", "ep")}
+    if live["tp"] and cfg.kv_heads % mesh.shape["tp"]:
+        raise ValueError(
+            f"tp={mesh.shape['tp']} does not divide n_kv_heads="
+            f"{cfg.kv_heads}: a tp shard holds whole k/v heads")
+    if live["ep"] and cfg.expert_share != (0, 1):
+        raise ValueError(
+            f"expert_share={cfg.expert_share} on a mesh with a live ep "
+            "axis: a device holds its experts by its place on the axis or "
+            "by being told, not both")
+    if live["pp"] and (cfg.n_layers // mesh.shape["pp"]
+                       ) % len(cfg.layer_pattern):
+        raise ValueError(
+            f"layer_pattern of {len(cfg.layer_pattern)} kinds on pp="
+            f"{mesh.shape['pp']}: a stage of "
+            f"{cfg.n_layers // mesh.shape['pp']} layers is not whole periods")
+
+    def sharding(leaf: Leaf, lead=()):
+        spec = tuple(axis and live[axis] for axis in leaf.spec)
+        return NamedSharding(mesh, P(*lead, None, *spec) if lead and spec
+                             else P(*lead, *spec))
+    stacks = {}
+    for key, (row, _blocks) in _stacks(cfg).items():
+        refused = [axis for axis in row.refuses if live[axis]]
+        if refused:
+            raise NotImplementedError(
+                f"a (\"{key}\",) block of layer_pattern on a live "
+                f"{' / '.join(refused)} axis: {row.refusal}")
+        # [stage, block of the stage, ...], the stages over pp where the
+        # kind runs on one
+        lead = () if "pp" in row.refuses else (live["pp"],)
+        stacks[key] = {leaf.name: sharding(leaf, lead)
+                       for leaf in row.leaves(cfg)}
+    shardings = {leaf.name: sharding(leaf) for leaf in _model_leaves(cfg)}
+    shardings["layers"] = stacks if cfg.one_sublayer else stacks[None]
+    return shardings
+
+
+def shard_params(params: Dict, cfg: TransformerConfig, mesh: Mesh) -> Dict:
+    sh = param_shardings(cfg, mesh)
+    return jax.tree_util.tree_map(
+        lambda x, s: jax.device_put(jnp.asarray(x), s), params, sh)
 
 
 # ---------------------------------------------------------------------------
@@ -1064,7 +777,7 @@ def forward_loss_spmd(params, tokens, targets, cfg: TransformerConfig):
             loss, exits = _looped_loss(_exit_gate(params, x), nll)
             aux_total = {**aux_total, **exits}
         else:
-            x = _rmsnorm(x, params["ln_f"], cfg.norm_eps)
+            x = rmsnorm(x, params["ln_f"], cfg.norm_eps)
             nll = _head_xent(x, head, targets)                  # [B,S]
             loss = jnp.mean(nll)
         # average over data-like axes so every shard reports the global
@@ -1129,7 +842,7 @@ def _loop_layers(lp, ln_f, x, positions, cfg: TransformerConfig):
 
     def loop_step(h, _):
         y, auxs = _scan_layers(lp, h, positions, cfg)
-        y = _rmsnorm(y, ln_f, cfg.norm_eps)
+        y = rmsnorm(y, ln_f, cfg.norm_eps)
         return y, (y, _over_layers(auxs))
     with jax.named_scope(scopes.LOOP):
         _, (states, auxs) = lax.scan(loop_step, x, None, length=cfg.n_loops)
@@ -1144,7 +857,20 @@ def _run_layers(lp, x, positions, cfg: TransformerConfig):
     if _axis_live("pp"):
         from horovod_tpu.parallel.pipeline import (pipeline_spmd,
                                                    psum_cotangent)
-        stage_fn = _stage_fn_factory(cfg, positions)
+
+        def stage_fn(stage_params, act_with_aux):
+            # the weighted sum of the MoE's auxiliary losses rides as one
+            # extra feature column of the activation, so that the carry
+            # stays a single array (pipeline_spmd requirement); it
+            # accumulates across stages and is read back after the pipeline
+            act, aux_in = act_with_aux[..., :-1], act_with_aux[..., -1:]
+            # a stage is whole periods (param_shardings), so its first
+            # layer is of the pattern's first kind
+            y, auxs = _scan_periods(act.astype(cfg.dtype), stage_params,
+                                    positions, cfg, lambda kind: True)
+            aux_out = aux_in + (jnp.sum(auxs["aux_loss"])
+                                / max(cfg.n_layers, 1))
+            return jnp.concatenate([y.astype(jnp.float32), aux_out], axis=-1)
         aux_col = jnp.zeros(x.shape[:-1] + (1,), jnp.float32)
         xa = jnp.concatenate([x.astype(jnp.float32), aux_col], -1)
         # the embedding is computed replicated over pp, but only stage 0
@@ -1170,63 +896,57 @@ def _scan_layers(lp, x, positions, cfg: TransformerConfig):
     """One scan over the blocks of ``lp`` (``[stage, layer, ...]`` leaves).
     Returns (activations, every layer's auxiliary terms stacked ``[L]``).
     A looped stack checkpoints each block (its passes' activations would
-    not fit beside the weights), the single pass only its Mamba blocks (a
-    block's float32 chunk states, decays and gate keep 1.2 GB at 8192
-    positions: PERF.md section 6, PR 39), unless ``cfg.remat`` says
+    not fit beside the weights), the single pass only the kinds whose row
+    says so (``BlockKind.checkpointed``), unless ``cfg.remat`` says
     otherwise."""
+    flat = jax.tree_util.tree_map(
+        lambda a: a.reshape((-1,) + a.shape[2:]), lp)
+    return _scan_periods(
+        x, flat, positions, cfg,
+        lambda kind: cfg.n_loops > 1 or _row(kind).checkpointed)
+
+
+def _scan_periods(x, layers, positions, cfg: TransformerConfig, needed):
+    """``x`` through every layer of ``layers`` (leaves ``[L, ...]``), layer
+    ``l`` a block of kind ``pattern[l % len(pattern)]``, checkpointed where
+    ``cfg.remat`` says or, where it says nothing, ``needed(kind)``: a scan
+    over periods with a period's layers unrolled inside, each with its
+    static kind; a period of one is a scan over layers. A pattern of
+    one-sublayer blocks has a stack a word (``layers[word]``, leaves
+    ``[blocks of that word, ...]``), and a period's i-th block of a word
+    takes the i-th of the period's layers in that stack. Returns
+    (activations, the auxiliary terms of every layer that has any,
+    stacked)."""
+    pattern = cfg.layer_pattern
+
     def block_of(kind):
         def block(layer_p, x):
             return _block(layer_p, x, positions, cfg, kind)
-        if _remat(cfg, cfg.n_loops > 1 or kind[0] == "mamba"):
-            block = jax.checkpoint(block)
-        return block
-    flat = jax.tree_util.tree_map(
-        lambda a: a.reshape((-1,) + a.shape[2:]), lp)
-    return _scan_periods(block_of, x, flat, cfg.layer_pattern)
-
-
-def _scan_periods(block_of, x, layers, pattern):
-    """``x`` through ``block_of(kind)(layer_p, x)`` for every layer of
-    ``layers`` (leaves ``[L, ...]``), layer ``l`` of kind ``pattern[l %
-    len(pattern)]``: a scan over periods with a period's layers unrolled
-    inside, each with its static kind; a period of one is a scan over
-    layers. A pattern of one-sublayer blocks has a stack a word
-    (``layers[word]``, leaves ``[blocks of that word, ...]``), and a
-    period's i-th block of a word takes the i-th of the period's layers
-    in that stack. Returns (activations, the auxiliary terms of every
-    layer that has any, stacked)."""
+        return jax.checkpoint(block) if remat(cfg, needed(kind)) else block
     blocks = {kind: block_of(kind) for kind in pattern}
-    by_word = _one_sublayer(pattern)
-    if len(pattern) == 1 and not by_word:
+    if len(pattern) == 1 and not cfg.one_sublayer:
+        # the program of every config without a pattern, to the letter
         def scan_body(carry, layer_p):
             y, aux = blocks[pattern[0]](layer_p, carry)
             return y, aux
         return lax.scan(scan_body, x, layers)
-    n = len(pattern)
-    if by_word:
-        words = [kind[0] for kind in pattern]
-        # (the stack, the place in a period's part of it) of each block
-        places = [(w, words[:i].count(w)) for i, w in enumerate(words)]
-        periods = {
-            w: jax.tree_util.tree_map(
-                lambda a, per=words.count(w): a.reshape(
-                    (a.shape[0] // per, per) + a.shape[1:]), layers[w])
-            for w in set(words)}
-
-        def layer_of(period_p, i):
-            word, place = places[i]
-            return jax.tree_util.tree_map(lambda a: a[place], period_p[word])
-    else:
-        periods = jax.tree_util.tree_map(
-            lambda a: a.reshape((a.shape[0] // n, n) + a.shape[1:]), layers)
-
-        def layer_of(period_p, i):
-            return jax.tree_util.tree_map(lambda a: a[i], period_p)
+    stacks = layers if cfg.one_sublayer else {None: layers}
+    # a stack as [periods, the period's blocks in it, ...], and each block
+    # of a period as (its stack, its place among those)
+    periods = {
+        key: jax.tree_util.tree_map(
+            lambda a, per=per: a.reshape(
+                (a.shape[0] // per, per) + a.shape[1:]), stacks[key])
+        for key, (_kind, per) in _stacks(cfg, len(pattern)).items()}
+    of = [_stack_of(kind) for kind in pattern]
+    places = [(key, of[:i].count(key)) for i, key in enumerate(of)]
 
     def period_body(carry, period_p):
         auxs = []
-        for i, kind in enumerate(pattern):
-            carry, aux = blocks[kind](layer_of(period_p, i), carry)
+        for kind, (key, place) in zip(pattern, places):
+            layer_p = jax.tree_util.tree_map(lambda a: a[place],
+                                             period_p[key])
+            carry, aux = blocks[kind](layer_p, carry)
             auxs.append(aux)
         auxs = [aux for aux in auxs if aux is not None] or [_no_aux()]
         return carry, jax.tree_util.tree_map(lambda *a: jnp.stack(a), *auxs)
@@ -1378,237 +1098,3 @@ def shard_batch(tokens, targets, mesh: Mesh):
     spec = data_sharding_spec(mesh)
     sh = NamedSharding(mesh, spec)
     return jax.device_put(tokens, sh), jax.device_put(targets, sh)
-
-
-# ---------------------------------------------------------------------------
-# Generative decode: KV-cache forward over the serving engine's paged pool
-# ---------------------------------------------------------------------------
-# The serving-side decode path (horovod_tpu/serving/generate/) runs the
-# SAME weights the training step produced, but at token granularity: one
-# fixed-shape decode step over a static slot array, with K/V history in
-# block-granular pages.  Everything below is single-device math in fp32
-# (serving replicas are world_size=1; bitwise-stable greedy decode is
-# the parity contract tests/test_generate.py enforces).  Layout:
-#
-#   k_pages / v_pages  [L, total_pages + 1, page_tokens, H*Dh]
-#       (+1 = the scratch page inactive/padded lanes write into, so
-#       membership churn never changes the compiled shape)
-#   page_table         [slots, pages_per_slot] int32 — a slot's j-th
-#       page holds its token positions [j*page_tokens, (j+1)*page_tokens);
-#       gathered back, position p of a slot lands at flat index p.
-
-def _dense_decode_only(cfg: TransformerConfig) -> None:
-    """The decode paths below compute multi-head attention over the whole
-    causal history with rope on every layer: refuse, by name, what they
-    would silently compute otherwise."""
-    off = [name for name, plain in (
-        ("layer_pattern", cfg.layer_pattern == (_PLAIN_LAYER,)),
-        ("n_kv_heads", cfg.kv_heads == cfg.n_heads),
-        ("moe_router_input", cfg.moe_router_input == "tokens"),
-        ("expert_share", cfg.expert_share == (0, 1)),
-        ("moe_router_scores", cfg.moe_router_scores == "softmax"),
-        ("moe_shared_width", cfg.moe_shared_width == 0),
-        ("ssm_heads (a Mamba block's recurrent state is no page of keys)",
-         cfg.ssm_heads == 0)) if not plain]
-    if off:
-        raise NotImplementedError(
-            f"paged decode does not implement {', '.join(off)}: its cache "
-            "holds n_heads k/v heads of every position, and its layers "
-            "attend to all of them with rope")
-
-
-def kv_cache_spec(cfg: TransformerConfig) -> Tuple[int, int, Any]:
-    """(n_layers, per-token K width, cache dtype) — the model
-    fingerprint the page planner sizes pages from."""
-    _dense_decode_only(cfg)
-    return cfg.n_layers, cfg.n_heads * cfg.head_dim, jnp.float32
-
-
-def flatten_decode_params(params: Dict) -> Dict:
-    """Collapse the stacked-stage layout ``[pp, L/pp, ...]`` to
-    ``[L, ...]`` — decode scans all layers on one device; the pipeline
-    split is a training-time concern."""
-    layers = params["layers"]
-    if "w1" not in layers or "q_norm" in layers or "lm_head" in params \
-            or "w3" in layers or "ln1_post" in layers \
-            or "exit_gate" in params:
-        raise NotImplementedError(
-            "paged decode supports the dense GPT block: n_experts=0, no "
-            "qk_norm, tied embeddings, no post_norm, no ffn_gated, no "
-            "looped stack (n_loops > 1)")
-    flat = {k: jnp.asarray(v).reshape((-1,) + tuple(np.shape(v)[2:]))
-            for k, v in layers.items()}
-    return {"embed": jnp.asarray(params["embed"]),
-            "ln_f": jnp.asarray(params["ln_f"]),
-            "layers": flat}
-
-
-def _rope_rows(x, pos, theta=10000.0):
-    """Rotary embedding for per-row positions: x [N, H, D], pos [N] —
-    the decode-time counterpart of :func:`_rope` (one token per row,
-    each at its own absolute position)."""
-    half = x.shape[-1] // 2
-    freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
-    ang = pos[:, None].astype(jnp.float32) * freqs[None, :]   # [N, half]
-    cos = jnp.cos(ang)[:, None, :].astype(x.dtype)
-    sin = jnp.sin(ang)[:, None, :].astype(x.dtype)
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
-
-
-def _paged_layer(lp, x, q_pos, kv_pages, dest_page, offs, gather_rows,
-                 key_mask, cfg: TransformerConfig):
-    """One transformer block over paged KV: write this call's K/V into
-    the pool, gather the full history back, attend, FFN.
-
-    x [N, M] (N = slots for decode, chunk for prefill); ``dest_page``/
-    ``offs`` [N] address each row's write; ``gather_rows`` indexes the
-    pages to read back ([N, P] per-row for decode, [P] shared for
-    prefill); ``key_mask`` [N, T] marks the attended positions."""
-    kp, vp = kv_pages
-    H, Dh = cfg.n_heads, cfg.head_dim
-    N = x.shape[0]
-    h = _rmsnorm(x, lp["ln1"].astype(jnp.float32), cfg.norm_eps)
-    q = _rope_rows((h @ lp["wq"].astype(jnp.float32)).reshape(N, H, Dh),
-                   q_pos, cfg.rope_theta)
-    k = _rope_rows((h @ lp["wk"].astype(jnp.float32)).reshape(N, H, Dh),
-                   q_pos, cfg.rope_theta)
-    v = (h @ lp["wv"].astype(jnp.float32))
-    kp = kp.at[dest_page, offs].set(k.reshape(N, H * Dh))
-    vp = vp.at[dest_page, offs].set(v)
-    k_all = kp[gather_rows].reshape(gather_rows.shape[:-1] + (-1, H, Dh))
-    v_all = vp[gather_rows].reshape(gather_rows.shape[:-1] + (-1, H, Dh))
-    if k_all.ndim == 3:           # shared gather (prefill): [T, H, Dh]
-        scores = jnp.einsum("nhd,thd->nht", q, k_all)
-    else:                         # per-row gather (decode): [N, T, H, Dh]
-        scores = jnp.einsum("nhd,nthd->nht", q, k_all)
-    scores = scores / np.sqrt(Dh).astype(np.float32)
-    scores = jnp.where(key_mask[:, None, :], scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1)
-    if k_all.ndim == 3:
-        o = jnp.einsum("nht,thd->nhd", probs, v_all)
-    else:
-        o = jnp.einsum("nht,nthd->nhd", probs, v_all)
-    x = x + o.reshape(N, H * Dh) @ lp["wo"].astype(jnp.float32)
-    h2 = _rmsnorm(x, lp["ln2"].astype(jnp.float32), cfg.norm_eps)
-    f = jax.nn.gelu(h2 @ lp["w1"].astype(jnp.float32))
-    return x + f @ lp["w2"].astype(jnp.float32), (kp, vp)
-
-
-def decode_step_paged(params: Dict, k_pages, v_pages, page_table,
-                      lengths, last_token, active,
-                      cfg: TransformerConfig):
-    """ONE decode step for every slot at once — the function the engine
-    jits exactly once, whatever joins or leaves between calls.
-
-    Shapes (all static): page_table [S, P] int32, lengths/last_token
-    [S] int32, active [S] bool.  Each active slot embeds its last
-    token, appends its K/V at position ``lengths[s]``, attends over its
-    own gathered history, and emits the greedy next token.  Inactive
-    slots compute masked garbage into the scratch page — their lanes
-    exist only to keep the shape constant.  Returns
-    ``(next_token [S] int32, k_pages, v_pages)``."""
-    _dense_decode_only(cfg)
-    S = last_token.shape[0]
-    pt = k_pages.shape[2]
-    scratch = k_pages.shape[1] - 1
-    emb = params["embed"].astype(jnp.float32)
-    x = emb[last_token]                                    # [S, M]
-    page_idx = jnp.clip(lengths // pt, 0, page_table.shape[1] - 1)
-    dest = jnp.take_along_axis(page_table, page_idx[:, None], axis=1)[:, 0]
-    dest = jnp.where(active, dest, scratch)
-    offs = lengths % pt
-    T = page_table.shape[1] * pt
-    key_mask = jnp.arange(T)[None, :] <= lengths[:, None]  # incl. new token
-
-    def body(x, layer):
-        lp, kp, vp = layer
-        x, pages = _paged_layer(lp, x, lengths, (kp, vp), dest, offs,
-                                page_table, key_mask, cfg)
-        return x, pages
-
-    x, (k_pages, v_pages) = lax.scan(
-        body, x, (params["layers"], k_pages, v_pages))
-    x = _rmsnorm(x, params["ln_f"].astype(jnp.float32), cfg.norm_eps)
-    logits = x @ emb.T                                     # [S, V]
-    return jnp.argmax(logits, axis=-1).astype(jnp.int32), k_pages, v_pages
-
-
-def prefill_chunk_paged(params: Dict, k_pages, v_pages, page_row,
-                        tokens, pos0, valid, cfg: TransformerConfig):
-    """Prefill ONE ``chunk``-token slice of ONE slot's prompt (fixed
-    chunk shape — the last chunk arrives padded with ``valid`` marking
-    the real tokens).  Writes the chunk's K/V into the slot's pages and
-    returns the greedy next token after the last VALID position — the
-    first generated token once the final chunk lands.  Returns
-    ``(next_token scalar int32, k_pages, v_pages)``."""
-    _dense_decode_only(cfg)
-    C = tokens.shape[0]
-    pt = k_pages.shape[2]
-    scratch = k_pages.shape[1] - 1
-    emb = params["embed"].astype(jnp.float32)
-    x = emb[tokens]                                        # [C, M]
-    pos = pos0 + jnp.arange(C, dtype=jnp.int32)
-    live = jnp.arange(C) < valid
-    dest = jnp.where(live,
-                     page_row[jnp.clip(pos // pt, 0,
-                                       page_row.shape[0] - 1)],
-                     scratch)
-    offs = pos % pt
-    T = page_row.shape[0] * pt
-    # causal within the chunk AND over every earlier chunk's positions
-    key_mask = jnp.arange(T)[None, :] <= pos[:, None]
-
-    def body(x, layer):
-        lp, kp, vp = layer
-        x, pages = _paged_layer(lp, x, pos, (kp, vp), dest, offs,
-                                page_row, key_mask, cfg)
-        return x, pages
-
-    x, (k_pages, v_pages) = lax.scan(
-        body, x, (params["layers"], k_pages, v_pages))
-    x = _rmsnorm(x, params["ln_f"].astype(jnp.float32), cfg.norm_eps)
-    x_last = x[jnp.clip(valid - 1, 0, C - 1)]
-    logits = x_last @ emb.T                                # [V]
-    return jnp.argmax(logits).astype(jnp.int32), k_pages, v_pages
-
-
-def reference_greedy_decode(params: Dict, cfg: TransformerConfig,
-                            prompt, max_new: int) -> list:
-    """Sequential non-paged oracle: recompute full-history attention
-    for every emitted token (no cache, no paging, no batching).  Slow
-    on purpose — this is the ground truth the paged continuous engine
-    must match token-for-token (tests/test_generate.py)."""
-    _dense_decode_only(cfg)
-    flat = flatten_decode_params(params)
-    H, Dh, L = cfg.n_heads, cfg.head_dim, cfg.n_layers
-    toks = [int(t) for t in np.asarray(prompt).reshape(-1)]
-    out = []
-    for _ in range(int(max_new)):
-        ids = jnp.asarray(toks, dtype=jnp.int32)
-        Tn = ids.shape[0]
-        emb = flat["embed"].astype(jnp.float32)
-        x = emb[ids]
-        pos = jnp.arange(Tn, dtype=jnp.int32)
-        for li in range(L):
-            lp = {k: v[li] for k, v in flat["layers"].items()}
-            h = _rmsnorm(x, lp["ln1"].astype(jnp.float32), cfg.norm_eps)
-            q = _rope_rows((h @ lp["wq"].astype(jnp.float32))
-                           .reshape(Tn, H, Dh), pos, cfg.rope_theta)
-            k = _rope_rows((h @ lp["wk"].astype(jnp.float32))
-                           .reshape(Tn, H, Dh), pos, cfg.rope_theta)
-            v = (h @ lp["wv"].astype(jnp.float32)).reshape(Tn, H, Dh)
-            scores = jnp.einsum("qhd,khd->hqk", q, k) / np.sqrt(Dh)
-            mask = pos[None, :] <= pos[:, None]
-            scores = jnp.where(mask[None, :, :], scores, -1e30)
-            probs = jax.nn.softmax(scores, axis=-1)
-            o = jnp.einsum("hqk,khd->qhd", probs, v).reshape(Tn, H * Dh)
-            x = x + o @ lp["wo"].astype(jnp.float32)
-            h2 = _rmsnorm(x, lp["ln2"].astype(jnp.float32), cfg.norm_eps)
-            f = jax.nn.gelu(h2 @ lp["w1"].astype(jnp.float32))
-            x = x + f @ lp["w2"].astype(jnp.float32)
-        x = _rmsnorm(x, flat["ln_f"].astype(jnp.float32), cfg.norm_eps)
-        nxt = int(jnp.argmax(x[-1] @ emb.T))
-        out.append(nxt)
-        toks.append(nxt)
-    return out

@@ -1,7 +1,7 @@
 """The Mamba-2 chunked scan as Pallas TPU kernels: what is inside a chunk
 stays on the chip.
 
-``models/transformer.py:ssm_chunked`` states the algorithm (arXiv:2405.21060,
+``models/mamba.py:ssm_chunked`` states the algorithm (arXiv:2405.21060,
 section 6). Per head, with ``s_i`` the running sum of ``dt_t a`` inside a
 chunk of ``Q`` positions and ``H`` the state the chunk starts from:
 

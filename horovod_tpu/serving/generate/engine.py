@@ -7,7 +7,7 @@ finish at different times, and a request-level batch strands chip time
 on every early finisher.  This engine decodes at TOKEN granularity:
 
 * a static array of ``HVD_TPU_GEN_SLOTS`` decode slots; the compiled
-  step (:func:`~horovod_tpu.models.transformer.decode_step_paged`)
+  step (:func:`~horovod_tpu.models.decode.decode_step_paged`)
   always runs over all of them, with an active mask — membership churn
   is host bookkeeping between steps and NEVER changes a compiled shape
   (the compile-stability guard in tests/test_generate.py asserts
@@ -55,8 +55,8 @@ def _jit_step_fns(cfg) -> Tuple[Callable, Callable]:
     one-compile guarantee is asserted against ``gen_decode_step``."""
     import jax
 
-    from horovod_tpu.models.transformer import (decode_step_paged,
-                                                prefill_chunk_paged)
+    from horovod_tpu.models.decode import (decode_step_paged,
+                                           prefill_chunk_paged)
 
     def gen_decode_step(params, k_pages, v_pages, page_table, lengths,
                         last_token, active):
@@ -89,8 +89,8 @@ class GenerateEngine:
                  batcher: Optional[DynamicBatcher] = None) -> None:
         import jax.numpy as jnp
 
-        from horovod_tpu.models.transformer import (flatten_decode_params,
-                                                    kv_cache_spec)
+        from horovod_tpu.models.decode import (flatten_decode_params,
+                                               kv_cache_spec)
         self.cfg = cfg
         self.n_slots = int(n_slots or env_int("GEN_SLOTS", 4))
         self.prefill_chunk = int(prefill_chunk

@@ -262,7 +262,7 @@ def test_token_parity_continuous_vs_sequential_vs_oracle():
     engine, and both match the dense full-recompute oracle — paging,
     slot churn, prefill chunking and co-batching must be numerically
     invisible."""
-    from horovod_tpu.models.transformer import reference_greedy_decode
+    from horovod_tpu.models.decode import reference_greedy_decode
     params, cfg = _demo()
     reqset = _reqset(np.random.RandomState(7), 5)
 

@@ -25,6 +25,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from horovod_tpu.models import decode, mamba
 from horovod_tpu.models import transformer as t
 from horovod_tpu.models import shard_batch, shard_params
 from horovod_tpu.parallel import build_mesh, moe
@@ -300,16 +301,16 @@ def test_the_chunked_form_is_the_step_by_step_form(chunk):
     ops = _scan_operands()
     weight = jnp.asarray(np.random.RandomState(1).randn(2, 64, 4, 8),
                          jnp.float32)
-    np.testing.assert_allclose(t.ssm_chunked(*ops, chunk), _stepwise(*ops),
+    np.testing.assert_allclose(mamba.ssm_chunked(*ops, chunk), _stepwise(*ops),
                                rtol=2e-5, atol=2e-5)
-    got = jax.grad(lambda *v: jnp.sum(t.ssm_chunked(*v, chunk) * weight),
+    got = jax.grad(lambda *v: jnp.sum(mamba.ssm_chunked(*v, chunk) * weight),
                    (0, 1, 2, 3, 4))(*ops)
     want = jax.grad(lambda *v: jnp.sum(_stepwise(*v) * weight),
                     (0, 1, 2, 3, 4))(*ops)
     for name, g, w in zip(("x", "dt", "a", "b", "c"), got, want):
         assert _rel(g, w) < TOL, name
     with pytest.raises(ValueError, match="ssm_chunk=24"):
-        t.ssm_chunked(*ops, 24)
+        mamba.ssm_chunked(*ops, 24)
 
 
 def test_the_scan_s_decays_in_bfloat16_fail(monkeypatch):
@@ -320,23 +321,23 @@ def test_the_scan_s_decays_in_bfloat16_fail(monkeypatch):
     reads 4e-4 to 5e-4 on the block's gradients)."""
     ops = _scan_operands()
     want = _stepwise(*ops)
-    assert _rel(t.ssm_chunked(*ops, 16), want) < TOL
-    monkeypatch.setattr(t, "_ssm_decay", _bf16_decay)
-    assert _rel(t.ssm_chunked(*ops, 16), want) > 5 * TOL
+    assert _rel(mamba.ssm_chunked(*ops, 16), want) < TOL
+    monkeypatch.setattr(mamba, "_ssm_decay", _bf16_decay)
+    assert _rel(mamba.ssm_chunked(*ops, 16), want) > 5 * TOL
 
 
 def test_one_chunk_is_the_whole_sequence_and_the_state_crosses_chunks():
     ops = _scan_operands(seed=2)
-    whole = t.ssm_chunked(*ops, 64)
-    np.testing.assert_allclose(t.ssm_chunked(*ops, 16), whole, rtol=2e-5,
+    whole = mamba.ssm_chunked(*ops, 64)
+    np.testing.assert_allclose(mamba.ssm_chunked(*ops, 16), whole, rtol=2e-5,
                                atol=2e-5)
     # the first chunk needs no carried state, the later ones do
     x, dt, a, b, c = ops
-    alone = t.ssm_chunked(x[:, 16:32], dt[:, 16:32], a, b[:, 16:32],
+    alone = mamba.ssm_chunked(x[:, 16:32], dt[:, 16:32], a, b[:, 16:32],
                           c[:, 16:32], 16)
     assert _rel(alone, whole[:, 16:32]) > 1e-2
     np.testing.assert_allclose(
-        t.ssm_chunked(x[:, :16], dt[:, :16], a, b[:, :16], c[:, :16], 16),
+        mamba.ssm_chunked(x[:, :16], dt[:, :16], a, b[:, :16], c[:, :16], 16),
         whole[:, :16], rtol=2e-5, atol=2e-5)
 
 
@@ -345,7 +346,7 @@ def test_the_convolution_is_causal_with_the_last_tap_on_the_present():
     x = jnp.asarray(rng.randn(1, 12, 3), jnp.float32)
     taps = jnp.asarray(rng.randn(4, 3), jnp.float32)
     bias = jnp.asarray(rng.randn(3), jnp.float32)
-    y = np.asarray(t._causal_conv(x, taps, bias))
+    y = np.asarray(mamba._causal_conv(x, taps, bias))
     for pos in (0, 2, 7):
         want = np.asarray(bias) + sum(
             np.asarray(taps[j]) * np.asarray(x[0, pos - 3 + j])
@@ -355,7 +356,7 @@ def test_the_convolution_is_causal_with_the_last_tap_on_the_present():
                                atol=1e-6)
     later = x.at[0, 8].add(1.0)
     np.testing.assert_array_equal(
-        np.asarray(t._causal_conv(later, taps, bias))[0, :8], y[0, :8])
+        np.asarray(mamba._causal_conv(later, taps, bias))[0, :8], y[0, :8])
 
 
 # -- what TOL must not let through ---------------------------------------------
@@ -419,8 +420,8 @@ def on_the_kernels(monkeypatch):
     """The Mamba blocks' scan on ``ops/pallas_ssm.py``'s kernels, in
     interpret mode (what a TPU runs at the cell's shapes)."""
     import functools
-    monkeypatch.setattr(t, "ssm_chunked", functools.partial(
-        t.ssm_chunked, interpret=True))
+    monkeypatch.setattr(mamba, "ssm_chunked", functools.partial(
+        mamba.ssm_chunked, interpret=True))
 
 
 def test_the_sound_small_stack_matches_the_reference_on_the_kernels(
@@ -433,7 +434,7 @@ def test_the_sound_small_stack_matches_the_reference_on_the_kernels(
 
 @pytest.mark.parametrize("what, change", [
     ("a chunk boundary that drops the carried state",
-     {"patch": (t, "_carried_states", _no_carried_state)}),
+     {"patch": (mamba, "_carried_states", _no_carried_state)}),
     ("silu for relu, squared", {"patch": (jax.nn, "relu", jax.nn.silu)}),
     ("gelu, the other ungated expert, for relu squared",
      {"cfg": {"moe_activation": "silu"}}),
@@ -446,7 +447,7 @@ def test_the_sound_small_stack_matches_the_reference_on_the_kernels(
     ("rope on the attention block", {"cfg": {"layer_pattern": (
         ("mamba",), ("experts",), ("attention", None, True))}}),
     ("the gate after the norm",
-     {"patch": (t, "_gated_norm", _gate_after_the_norm)}),
+     {"patch": (mamba, "_gated_norm", _gate_after_the_norm)}),
 ])
 def test_a_wrong_term_fails(monkeypatch, small_reference, what, change):
     """Each moves the loss or a named gradient of a stack of one Mamba, one
@@ -610,15 +611,15 @@ def test_the_decode_paths_refuse_the_new_fields_by_name():
             ("moe_shared_width", t.TransformerConfig(
                 n_experts=8, moe_shared_width=64))]:
         with pytest.raises(NotImplementedError, match=field):
-            t.kv_cache_spec(cfg)
+            decode.kv_cache_spec(cfg)
         with pytest.raises(NotImplementedError, match=field):
-            t.decode_step_paged(params, None, None, None, None, None, None,
-                                cfg)
+            decode.decode_step_paged(params, None, None, None, None, None,
+                                     None, cfg)
         with pytest.raises(NotImplementedError, match=field):
-            t.prefill_chunk_paged(params, None, None, None, None, None,
-                                  None, cfg)
+            decode.prefill_chunk_paged(params, None, None, None, None, None,
+                                       None, cfg)
         with pytest.raises(NotImplementedError, match=field):
-            t.reference_greedy_decode(params, cfg, [1, 2], 1)
+            decode.reference_greedy_decode(params, cfg, [1, 2], 1)
 
 
 def test_a_pattern_or_a_word_the_program_does_not_know_is_refused():

@@ -24,6 +24,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from horovod_tpu.models import _kinds
 from horovod_tpu.models import transformer as t
 from horovod_tpu.models import shard_batch, shard_params
 from horovod_tpu.parallel import build_mesh, moe
@@ -93,7 +94,7 @@ def _program_logits(cfg, params, tokens):
     x = params["embed"].astype(cfg.dtype)[tokens]
     x, _aux = t._run_layers(params["layers"], x,
                             jnp.arange(tokens.shape[1]), cfg)
-    return t._rmsnorm(x, params["ln_f"], cfg.norm_eps) @ params["lm_head"]
+    return _kinds.rmsnorm(x, params["ln_f"], cfg.norm_eps) @ params["lm_head"]
 
 
 def _reference(params, batch, sizes=SIZES, leaves=LEAVES):

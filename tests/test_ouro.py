@@ -25,6 +25,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from horovod_tpu.models import _kinds, decode
 from horovod_tpu.models import transformer as t
 from horovod_tpu.models import shard_batch, shard_params
 from horovod_tpu.parallel import build_mesh
@@ -182,7 +183,7 @@ def _unnormed_state_fed_forward(monkeypatch):
         states, auxs = [], None
         for _ in range(cfg.n_loops):
             x, auxs = t._scan_layers(lp, x, positions, cfg)
-            states.append(t._rmsnorm(x, ln_f, cfg.norm_eps))
+            states.append(_kinds.rmsnorm(x, ln_f, cfg.norm_eps))
         return jnp.stack(states), t._over_layers(auxs)
     monkeypatch.setattr(t, "_loop_layers", loop_layers)
 
@@ -251,7 +252,7 @@ def _parents_loss(params, tokens, targets, cfg):
 
     def block(x, p):
         x = t._attention_block(p, x, positions, cfg)
-        h = t._rmsnorm(x, p["ln2"], cfg.norm_eps)
+        h = _kinds.rmsnorm(x, p["ln2"], cfg.norm_eps)
         o = jax.nn.gelu(h @ p["w1"].astype(h.dtype)) @ p["w2"].astype(
             h.dtype)
         return x + o.astype(x.dtype), None
@@ -259,7 +260,7 @@ def _parents_loss(params, tokens, targets, cfg):
     flat = jax.tree_util.tree_map(
         lambda a: a.reshape((-1,) + a.shape[2:]), params["layers"])
     x, _ = jax.lax.scan(block, x, flat)
-    x = t._rmsnorm(x, params["ln_f"], cfg.norm_eps)
+    x = _kinds.rmsnorm(x, params["ln_f"], cfg.norm_eps)
     return jnp.mean(t._head_xent(
         x, params["embed"].astype(cfg.dtype).T, targets))
 
@@ -340,7 +341,7 @@ def test_tensor_parallel_gives_one_device_s_result():
     head's vocabulary (the psum algebra in place of the kernel); the
     post-norms, the gate and the loop see whole activations. Loss, exit
     distribution and the sharded leaves' gradients (which the flagship
-    leaves scaled by the shards, ROADMAP A16, as a replicated leaf's miss
+    leaves scaled by the shards, ROADMAP A17, as a replicated leaf's miss
     the tp sum)."""
     params, batch = _params(), _batch(n_seqs=4)
     loss1, aux1, grads1 = _program(CFG, params, batch)
@@ -404,7 +405,7 @@ def test_the_decode_paths_refuse_the_new_trees_by_name(field):
                                         else True})
     params = t.init_params(np.random.RandomState(0), cfg, 1)
     with pytest.raises(NotImplementedError, match=field):
-        t.flatten_decode_params(params)
+        decode.flatten_decode_params(params)
 
 
 def test_the_reference_imports_nothing_of_the_program():

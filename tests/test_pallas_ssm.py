@@ -1,6 +1,6 @@
 """The Mamba-2 scan's Pallas kernels (``ops/pallas_ssm.py``: ``hvd_ssm_scan``,
 ``hvd_ssm_scan_bwd``) in interpret mode on the CPU, in float32, against the
-``jax.numpy`` form of ``models/transformer.py:ssm_chunked`` and against the
+``jax.numpy`` form of ``models/mamba.py:ssm_chunked`` and against the
 recurrence one position at a time (the benchmark's plain reference), at
 shapes that cross three chunk boundaries and have two groups; one of them
 has two lane tiles of two heads a group.
@@ -19,7 +19,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from horovod_tpu.models import transformer as t
+from horovod_tpu.models import mamba, transformer as t
 from horovod_tpu.ops import pallas_ssm as ps
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -58,7 +58,7 @@ def _weight(shape, seed=1):
 
 
 def _kernels(x, dt, a, b, c, chunk):
-    return t.ssm_chunked(x, dt, a, b, c, chunk, interpret=True)
+    return mamba.ssm_chunked(x, dt, a, b, c, chunk, interpret=True)
 
 
 def _stepwise(x, dt, a, b, c):
@@ -73,7 +73,7 @@ def _rel(got, want):
 
 
 def _sums(dt, a, chunk):
-    return t._chunk_sums(dt * a, chunk)
+    return mamba._chunk_sums(dt * a, chunk)
 
 
 @pytest.mark.parametrize("shape", SHAPES.values(), ids=SHAPES.keys())
@@ -83,7 +83,7 @@ def test_the_forward_kernel_is_the_numpy_form_and_the_recurrence(shape):
     assert tiles.tiles * tiles.heads_per_tile == shape[2] // shape[4]
     got = _kernels(*ops, chunk)
     assert got.dtype == jnp.float32 and got.shape == ops[0].shape
-    assert _rel(got, t.ssm_chunked(*ops, chunk)) < TOL
+    assert _rel(got, mamba.ssm_chunked(*ops, chunk)) < TOL
     assert _rel(got, _stepwise(*ops)) < TOL
 
 
@@ -93,7 +93,7 @@ def test_every_cotangent_is_autodiff_s_of_the_numpy_form(shape):
     ops, chunk, weight = _operands(shape), shape[-1], _weight(shape)
     got = jax.grad(lambda *v: jnp.sum(_kernels(*v, chunk) * weight),
                    (0, 1, 2, 3, 4))(*ops)
-    want = jax.grad(lambda *v: jnp.sum(t.ssm_chunked(*v, chunk) * weight),
+    want = jax.grad(lambda *v: jnp.sum(mamba.ssm_chunked(*v, chunk) * weight),
                     (0, 1, 2, 3, 4))(*ops)
     stepwise = jax.grad(lambda *v: jnp.sum(_stepwise(*v) * weight),
                         (0, 1, 2, 3, 4))(*ops)
@@ -113,7 +113,7 @@ def test_dt_s_and_the_sums_cotangents_apart():
     got = jax.grad(lambda dt, s: jnp.sum(
         ps.ssm_scan(x, dt, s, b, c, chunk, True) * weight), (0, 1))(dt, s)
     want = jax.grad(lambda dt, s: jnp.sum(
-        t._ssm_chunked_numpy(x, dt, s, b, c, chunk) * weight), (0, 1))(dt, s)
+        mamba._ssm_chunked_numpy(x, dt, s, b, c, chunk) * weight), (0, 1))(dt, s)
     for name, g, w in zip(("dt", "s"), got, want):
         assert _rel(g, w) < TOL, name
     # neither is the other's, nor small beside it
@@ -193,10 +193,10 @@ def test_bfloat16_operands_keep_float32_sums_decays_and_state():
     ops, weight = _operands(shape, dtype=jnp.bfloat16), _weight(shape)
     got = _kernels(*ops, 16)
     assert got.dtype == jnp.float32
-    assert _rel(got, t.ssm_chunked(*ops, 16)) < 1e-2
+    assert _rel(got, mamba.ssm_chunked(*ops, 16)) < 1e-2
     grads = jax.grad(lambda *v: jnp.sum(_kernels(*v, 16) * weight),
                      (0, 1, 2, 3, 4))(*ops)
-    want = jax.grad(lambda *v: jnp.sum(t.ssm_chunked(*v, 16) * weight),
+    want = jax.grad(lambda *v: jnp.sum(mamba.ssm_chunked(*v, 16) * weight),
                     (0, 1, 2, 3, 4))(*ops)
     for name, g, w, op in zip(NAMES, grads, want, ops):
         assert g.dtype == op.dtype, name
@@ -215,7 +215,7 @@ def _calls_a_kernel(shape):
     a = jax.ShapeDtypeStruct((H,), jnp.float32)
     b = jax.ShapeDtypeStruct((B, S, G, N), jnp.bfloat16)
     return "pallas_call" in str(jax.make_jaxpr(
-        lambda *v: t.ssm_chunked(*v, chunk))(x, dt, a, b, b))
+        lambda *v: mamba.ssm_chunked(*v, chunk))(x, dt, a, b, b))
 
 
 def test_the_kernels_run_on_a_tpu_where_the_tiles_fit(monkeypatch):
@@ -224,10 +224,10 @@ def test_the_kernels_run_on_a_tpu_where_the_tiles_fit(monkeypatch):
                               ssm_chunk=128)
     assert ps.ssm_eligible(*CELL)
     assert ps.ssm_tiles(64, 64, 8) == ps.SsmTiles(2, 64, 4)
-    assert "jax.numpy (backend cpu)" in t.ssm_path(cfg, 8192)
+    assert "jax.numpy (backend cpu)" in mamba.ssm_path(cfg, 8192)
     assert not _calls_a_kernel((1, 256, 64, 64, 8, 128, 128))
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    said = t.ssm_path(cfg, 8192)
+    said = mamba.ssm_path(cfg, 8192)
     assert ps.FWD_NAME in said and ps.BWD_NAME in said
     assert "64 chunks" in said and "4 lane tiles of 2 heads" in said
     assert "128x512" in said and "checkpointed" in said
@@ -250,7 +250,7 @@ def test_a_shape_the_tiles_do_not_fit_takes_the_numpy_form(monkeypatch, what,
     shape = (1, 256, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups,
              cfg.ssm_state, cfg.ssm_chunk)
     assert not ps.ssm_eligible(256, *shape[2:]), what
-    said = t.ssm_path(cfg, 256)
+    said = mamba.ssm_path(cfg, 256)
     assert said.startswith("jax.numpy (") and ps.FWD_NAME not in said, what
     assert not _calls_a_kernel(shape), what
     with pytest.raises(ValueError, match="ssm_chunk=48"):

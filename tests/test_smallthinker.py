@@ -22,6 +22,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from horovod_tpu.models import _kinds, decode
 from horovod_tpu.models import transformer as t
 from horovod_tpu.models import shard_batch, shard_params
 from horovod_tpu.ops import pallas_attention as pa
@@ -92,7 +93,7 @@ def _program_logits(cfg, params, tokens):
     x = params["embed"].astype(cfg.dtype)[tokens]
     x, _aux = t._run_layers(params["layers"], x,
                             jnp.arange(tokens.shape[1]), cfg)
-    return t._rmsnorm(x, params["ln_f"], cfg.norm_eps) @ params["lm_head"]
+    return _kinds.rmsnorm(x, params["ln_f"], cfg.norm_eps) @ params["lm_head"]
 
 
 def _reference_logits(params, tokens, sizes=SIZES):
@@ -511,16 +512,16 @@ def test_paths_that_do_not_implement_a_field_refuse_it_by_name():
             ("expert_share", t.TransformerConfig(n_experts=8,
                                                  expert_share=(1, 4)))]:
         with pytest.raises(NotImplementedError, match=field):
-            t.kv_cache_spec(cfg)
+            decode.kv_cache_spec(cfg)
         with pytest.raises(NotImplementedError, match=field):
-            t.decode_step_paged(params, None, None, None, None, None, None,
-                                cfg)
+            decode.decode_step_paged(params, None, None, None, None, None,
+                                     None, cfg)
         with pytest.raises(NotImplementedError, match=field):
-            t.prefill_chunk_paged(params, None, None, None, None, None,
-                                  None, cfg)
+            decode.prefill_chunk_paged(params, None, None, None, None, None,
+                                       None, cfg)
         with pytest.raises(NotImplementedError, match=field):
-            t.reference_greedy_decode(params, cfg, [1, 2], 1)
-    assert t.kv_cache_spec(t.TransformerConfig())[0] == 4
+            decode.reference_greedy_decode(params, cfg, [1, 2], 1)
+    assert decode.kv_cache_spec(t.TransformerConfig())[0] == 4
 
 
 def test_a_pipeline_of_whole_periods_runs_the_pattern():

@@ -25,7 +25,7 @@ from horovod_tpu.ops import pallas_attention as pa
 from horovod_tpu.ops import pallas_quantize as pq
 from horovod_tpu.ops import pallas_ssm
 from horovod_tpu.ops import pallas_xent as px
-from horovod_tpu.models import transformer
+from horovod_tpu.models import mamba, transformer
 from horovod_tpu.parallel import moe
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -199,10 +199,10 @@ CASES = {
     # a block spec the lowering refuses (dt and the sums a head's column
     # and a head's row) or a working set past VMEM fails here
     "ssm_scan_cell": (
-        lambda x, dt, a, b, c: transformer.ssm_chunked(x, dt, a, b, c, 128),
+        lambda x, dt, a, b, c: mamba.ssm_chunked(x, dt, a, b, c, 128),
         _SSM_CELL, pallas_ssm.FWD_NAME),
     "ssm_scan_grad_cell": (
-        jax.grad(lambda x, dt, a, b, c: _sum32(transformer.ssm_chunked(
+        jax.grad(lambda x, dt, a, b, c: _sum32(mamba.ssm_chunked(
             x, dt, a, b, c, 128)), (0, 1, 2, 3, 4)),
         _SSM_CELL, (pallas_ssm.FWD_NAME, pallas_ssm.BWD_NAME)),
     "quantize": (pq.block_quantize, [_BLOCKS], "hvd_block_quantize"),
@@ -656,7 +656,7 @@ def test_mamba_block_keeps_a_chunk_s_inside_on_the_chip(v5e, no_compile_cache,
         d_model=M, n_heads=32, n_layers=1, layer_pattern=(("mamba",),),
         ssm_heads=H, ssm_head_dim=P, ssm_state=N, ssm_groups=G, ssm_chunk=Q,
         dtype=jnp.bfloat16)
-    assert pallas_ssm.FWD_NAME in transformer.ssm_path(cfg, S)
+    assert pallas_ssm.FWD_NAME in mamba.ssm_path(cfg, S)
     leaves = jax.eval_shape(lambda: jax.tree_util.tree_map(
         lambda v: jnp.asarray(v[0, 0]), transformer.init_params(
             np.random.RandomState(0), cfg, 1)["layers"]["mamba"]))
@@ -667,7 +667,7 @@ def test_mamba_block_keeps_a_chunk_s_inside_on_the_chip(v5e, no_compile_cache,
 
     def loss(p, h):
         block = jax.checkpoint(
-            lambda p, h: transformer._mamba_block(p, h, cfg))
+            lambda p, h: mamba._mamba_block(p, h, cfg))
         return _sum32(jnp.square(block(p, h)))
     text = jax.jit(jax.grad(loss, (0, 1))).lower(params, h).compile(
         ).as_text()
