@@ -33,7 +33,10 @@ Phases (default, one chip):
            against its XLA reference at real widths (the block attention
            kernels at the shapes of both BERT cells, with padded keys and
            a row of nothing else; the flash kernel's gradients through
-           hvd_flash_bwd, and both again with a window and seven query
+           hvd_flash_bwd, both again at the latent cell's head width 256
+           (with the tiles both kernels take at that cell's shape and the
+           blocks its step checkpoints), and both again with a window and
+           seven query
            heads a key/value head, attention_path saying what the band
            leaves of a head's tiles and the group); then two steps of the flagship transformer at
            head_dim 128 with the three kernels asserted in the compiled
@@ -131,6 +134,7 @@ class Sizes:
     bert_seq: int
     bert4: tuple          # four-chip BERT (global batch, seq)
     attn: tuple           # flash check q/k/v [B, S, H, D]
+    latent: tuple         # the same at the latent cell's head width
     banded: tuple         # flash check with a band and grouped heads:
     #                       (B, S, H, k/v heads, D, window)
     block: tuple          # block attention checks, each [B, S, H, D]
@@ -151,6 +155,10 @@ REAL = Sizes(
     # 512 positions: the shape at which attend picks the block kernels
     bert4=(8, 512),
     attn=(8, 2048, 8, 128),
+    # glm-4.7-flash.s8192's length and head width at 4 of its 20 heads (the
+    # tiles are the length's and the width's; the float32 reference's scores
+    # are 1.1 GB at 4 heads)
+    latent=(1, 8192, 4, 256),
     # a window of two 1024-tiles under four, seven query heads a k/v head
     # as in smallthinker-21b-a3b.s8192 (half its length and heads: the
     # float32 reference's scores are 0.9 GB)
@@ -173,7 +181,8 @@ TINY = Sizes(
     bert=dict(vocab_size=512, hidden_size=64, num_layers=2, num_heads=4,
               intermediate_size=128, max_position=64),
     bert_batch=8, bert_seq=16, bert4=(8, 16),
-    attn=(1, 256, 2, 128), banded=(1, 512, 4, 2, 128, 256),
+    attn=(1, 256, 2, 128), latent=(1, 256, 2, 256),
+    banded=(1, 512, 4, 2, 128, 256),
     block=((2, 128, 2, 64),),
     xent=(256, 1000), blocks=(64, 128),
     gmm=((256, 128, 128, 4, 192), (256, 128, 192, 4, 192)),
@@ -443,13 +452,12 @@ def _kernel_line(smoke: Smoke, kernel: str, what: str, err: float,
     check(err <= tol, f"{kernel} {what}: err {err} > tol {tol}")
 
 
-def _check_flash(smoke: Smoke) -> None:
+def _check_flash(smoke: Smoke, shape: tuple, **more) -> None:
     import jax
     import jax.numpy as jnp
     from horovod_tpu.ops.pallas_attention import flash_attention_tpu
 
     interpret = smoke.rehearsal
-    shape = smoke.sizes.attn
     keys = jax.random.split(jax.random.PRNGKey(smoke.seed), 4)
     q, k, v = (jax.random.normal(kk, shape, jnp.bfloat16) for kk in keys[:3])
     w = jax.random.normal(keys[3], shape, jnp.float32)  # cotangent
@@ -461,7 +469,7 @@ def _check_flash(smoke: Smoke) -> None:
     want = jax.jit(_reference_attention)(q, k, v)
     _kernel_line(smoke, "flash_attention", "fwd", _rel_err(got, want),
                  FLASH_TOL, shape=shape, dtype="bfloat16",
-                 attention_path=_attention_path(shape))
+                 attention_path=_attention_path(shape), **more)
     got = _run_compiled(smoke, jax.grad(_weighted_sum(kernel, w), (0, 1, 2)),
                         (q, k, v), "hvd_flash_bwd")
     want = jax.jit(jax.grad(_weighted_sum(_reference_attention, w),
@@ -469,6 +477,33 @@ def _check_flash(smoke: Smoke) -> None:
     for name, g, r in zip(("dq", "dk", "dv"), got, want):
         _kernel_line(smoke, "flash_attention", f"grad {name}",
                      _rel_err(g, r), FLASH_TOL)
+
+
+def _latent_cell(smoke: Smoke) -> dict:
+    """What the cell ``glm-4.7-flash.s8192`` takes, from its configuration
+    as the benchmark's adapter reads it: the attention core's path and
+    tiles at its 20 heads of 256 over 8192 keys, and the kinds of block its
+    step checkpoints."""
+    chip = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        "benchmarks", "chip")
+    if chip not in sys.path:
+        sys.path.insert(0, chip)
+    import run as harness
+    from adapters import glm4_moe_lite
+    from horovod_tpu.models import transformer as t
+    _bench, _entry, config, job = harness.load_cell("glm-4.7-flash.s8192",
+                                                    tiny=False)
+    cfg = glm4_moe_lite._model_config(config, job)
+    path = _attention_path((job["batch_per_chip"], job["seq_len"],
+                            cfg.n_heads, cfg.head_dim))
+    if smoke.on_chip:
+        check(path.startswith("pallas hvd_flash_attention "), path)
+    kinds = dict.fromkeys(cfg.lead_pattern + cfg.layer_pattern)
+    return dict(
+        attention_path_at_the_latent_cell=path,
+        recomputation_at_the_latent_cell={
+            kind[0]: "checkpointed" if t.remat(cfg, t._checkpointed(cfg, kind))
+            else "kept" for kind in kinds})
 
 
 def _check_banded(smoke: Smoke) -> None:
@@ -633,8 +668,8 @@ def _check_gmm(smoke: Smoke) -> None:
     of the weights' gradient. The reference is ragged_dot in float32 on the
     groups' rows alone. Prints which path ``grouped_matmul`` takes, which
     way round it reads the weights and the tile of each of its three calls,
-    at each size, at the OLMoE cell's, at the share cell's and at the hybrid
-    cell's."""
+    at each size, at the OLMoE cell's, at the share cell's, at the hybrid
+    cell's and at the latent cell's."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -644,11 +679,14 @@ def _check_gmm(smoke: Smoke) -> None:
     olmoe = gmm_path(65536, 2048, 1024)
     share = gmm_path(49152, 2560, 768)
     hybrid = gmm_path(49152, 2688, 1856)
+    latent = gmm_path(4 * 8192, 2048, 1536)
     if smoke.on_chip:
         check(olmoe.startswith(f"pallas {GMM_NAME} ") and "row-major" in olmoe
               and share.startswith(f"pallas {GMM_NAME} ")
               and hybrid.startswith(f"pallas {GMM_NAME} ")
-              and "[E, 1856, 2688]" in hybrid, olmoe + share + hybrid)
+              and "[E, 1856, 2688]" in hybrid
+              and latent.startswith(f"pallas {GMM_NAME} "),
+              olmoe + share + hybrid + latent)
     narrow = smoke.sizes.gmm[0][:2] + (64,) + smoke.sizes.gmm[0][3:]
     cases = [(GMM_NAME, size) for size in smoke.sizes.gmm] + [(None, narrow)]
     for case, (kernel, (rows, d_in, width, groups, inside)) in enumerate(cases):
@@ -694,6 +732,7 @@ def _check_gmm(smoke: Smoke) -> None:
                      gmm_path=path, gmm_path_at_the_olmoe_cell=olmoe,
                      gmm_path_at_the_share_cell=share,
                      gmm_path_at_the_hybrid_cell=hybrid,
+                     gmm_path_at_the_latent_cell=latent,
                      rows_in_groups=inside, largest_group=int(sizes.max()),
                      empty_groups=int((sizes == 0).sum()),
                      beyond_the_groups=None if kernel else beyond(got),
@@ -1029,7 +1068,8 @@ def _check_flagship(smoke: Smoke, hvd) -> None:
 
 
 def phase_kernels(smoke: Smoke, hvd) -> None:
-    _check_flash(smoke)
+    _check_flash(smoke, smoke.sizes.attn)
+    _check_flash(smoke, smoke.sizes.latent, **_latent_cell(smoke))
     _check_banded(smoke)
     _check_block(smoke)
     _check_xent(smoke)

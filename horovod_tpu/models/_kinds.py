@@ -63,6 +63,20 @@ def remat(cfg, needed: bool) -> bool:
     return needed if cfg.remat is None else cfg.remat
 
 
+def rope(x, positions, theta=10000.0):
+    """Rotary embedding, halves layout; x [B, S, H, D], positions [S]
+    absolute."""
+    B, S, H, D = x.shape
+    half = D // 2
+    freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    ang = positions[:, None].astype(jnp.float32) * freqs[None, :]  # [S, half]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    x1, x2 = x[..., :half], x[..., half:]
+    cos = cos[None, :, None, :].astype(x.dtype)
+    sin = sin[None, :, None, :].astype(x.dtype)
+    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
+
+
 def rmsnorm(x, g, eps=1e-6):
     var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
     return (x.astype(jnp.float32) * jax.lax.rsqrt(var + eps)

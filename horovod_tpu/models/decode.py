@@ -26,9 +26,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from horovod_tpu.models._kinds import rmsnorm
+from horovod_tpu.models._kinds import rmsnorm, rope
 from horovod_tpu.models.transformer import (TransformerConfig,
-                                            _model_leaves, _rope, _row)
+                                            _model_leaves, _row)
 
 #: the fields of ``TransformerConfig`` that the decode paths need at their
 #: defaults: anything else is not the plain dense GPT block (multi-head
@@ -37,7 +37,8 @@ from horovod_tpu.models.transformer import (TransformerConfig,
 _PLAIN_FIELDS = (
     "n_experts", "qk_norm", "tie_embeddings", "post_norm", "ffn_gated",
     "n_loops", "layer_pattern", "moe_router_input", "expert_share",
-    "moe_router_scores", "moe_shared_width", "ssm_heads")
+    "moe_router_scores", "moe_shared_width", "ssm_heads", "kv_latent",
+    "lead_pattern", "mtp_depth")
 _PLAIN = TransformerConfig()
 
 
@@ -52,9 +53,10 @@ def _plain_gpt_only(cfg: TransformerConfig) -> None:
         raise NotImplementedError(
             f"paged decode does not implement {', '.join(off)}: its cache "
             "holds n_heads k/v heads of every position (a Mamba block's "
-            "recurrent state is no page of keys), its layers attend to all "
-            "of them with rope, and the block is the dense GPT one "
-            "(pre-norms, a gelu FFN, the tied head, one pass)")
+            "recurrent state is no page of keys, nor is a latent), its "
+            "layers attend to all of them with rope, and the block is the "
+            "dense GPT one (pre-norms, a gelu FFN, the tied head, one pass "
+            "and one token a step)")
 
 
 def kv_cache_spec(cfg: TransformerConfig) -> Tuple[int, int, Any]:
@@ -88,9 +90,9 @@ def flatten_decode_params(params: Dict) -> Dict:
 
 
 def _rope_rows(x, pos, theta):
-    """``transformer._rope`` for one token a row, each at its own absolute
+    """``_kinds.rope`` for one token a row, each at its own absolute
     position: x [N, H, D], pos [N]."""
-    return _rope(x[None], pos, theta)[0]
+    return rope(x[None], pos, theta)[0]
 
 
 def _paged_stack(params, x, q_pos, k_pages, v_pages, dest_page, offs,

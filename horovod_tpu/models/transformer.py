@@ -9,7 +9,8 @@ counterpart of that contract at modern scale: the training step compiles to
 a single XLA program whose collectives (psum over tp, ppermute rings over
 sp and pp, all_to_all over ep, psum over dp for gradients) all ride ICI.
 
-This file is the training model; the Mamba-2 mixer is ``models/mamba.py``, the
+This file is the training model; the Mamba-2 mixer is ``models/mamba.py``,
+latent attention ``models/latent.py``, the
 serving plane's paged decode model and its oracle ``models/decode.py``. A leaf
 is declared once, in its block's ``*_leaves`` function (``models/_kinds.py``:
 name, shape, draw, partition spec, under the one ``if`` that says when the
@@ -33,7 +34,16 @@ Layout conventions (local = per-device shapes):
                   ("mamba",), ("experts",), ("attention", window, rope):
                   ``x + mixer(norm(x))`` and no more (Nemotron-H); the tree's
                   ``layers`` then holds one stack a word, ``[pp, blocks of
-                  that word / pp, ...]`` (a Mamba block: ``models/mamba.py``)
+                  that word / pp, ...]`` (a Mamba block: ``models/mamba.py``;
+                  ("latent",) latent attention: ``models/latent.py``;
+                  ("dense",) the dense FFN at ``dense_ff``)
+  leading blocks  ``lead_pattern``: blocks before the periodic stack, each
+                  once (``lead``, a stack a word ``[blocks, ...]``), so a
+                  dense layer leads a scan over (attention, experts) periods
+  prediction      ``mtp_depth`` 1: a module on the stack's output and the
+                  next token's embedding, one more period of the pattern,
+                  through the main head for the token after the next; its
+                  loss times ``mtp_weight`` joins the first (``mtp``)
   expert share    ``expert_share=(i, of)``: this device holds that share of
                   every layer's experts with no ep axis live (one chip of an
                   expert-parallel group, run alone)
@@ -57,9 +67,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from horovod_tpu._compat import axis_size, shard_map
 
-from horovod_tpu.models import mamba
+from horovod_tpu.models import latent, mamba
 from horovod_tpu.models._kinds import (BlockKind, Leaf, normal, ones, remat,
-                                       rmsnorm, zeros)
+                                       rmsnorm, rope, zeros)
 from horovod_tpu.models.scan_util import multi_step
 from horovod_tpu.parallel.ring_attention import ring_attention_spmd
 from horovod_tpu.parallel.moe import grouped_matmul, moe_layer_spmd
@@ -133,9 +143,35 @@ class TransformerConfig:
     moe_routed_scale: float = 1.0   # the top-k weights times this, after
     #                             their renormalisation
     moe_shared_width: int = 0   # > 0: an expert of this width every token
-    #                             runs beside its chosen ones (``ws1``,
-    #                             ``ws2``; ``moe_activation``), whole on every
-    #                             device that holds a share of the others
+    #                             runs beside its chosen ones, of their form
+    #                             (``ws1``, ``ws2``, and ``ws3`` where they
+    #                             are gated; ``moe_activation``), whole on
+    #                             every device that holds a share of the others
+    # -- latent attention (``models/latent.py``), where the pattern has it --
+    q_latent: int = 0           # channels of the queries' latent
+    kv_latent: int = 0          # channels of the keys' and values' latent
+    rope_width: int = 0         # a head's last channels, which carry the
+    #                             positions; the key's are one head shared by
+    #                             all (``head_width`` is both parts together)
+    # -- blocks beside the periodic stack -----------------------------------
+    lead_pattern: Tuple[Tuple, ...] = ()    # one-sublayer kinds of the blocks
+    #                             before the periodic stack, each once with
+    #                             weights of its own and not counted in
+    #                             ``n_layers`` (``params["lead"]``, a stack a
+    #                             word): ("latent",), ("dense",) is a dense
+    #                             layer leading a stack of expert layers
+    dense_ff: Optional[int] = None  # width of a ("dense",) block's FFN
+    #                             (``ffn_gated``). None: d_ff, which is an
+    #                             expert's width where there are experts
+    mtp_depth: int = 0          # multi-token-prediction modules (DeepSeek-V3,
+    #                             arXiv:2412.19437 section 2.2; 0 or 1): one
+    #                             more period of ``layer_pattern`` with
+    #                             weights of its own (``params["mtp"]``) on
+    #                             the stack's output before ``ln_f`` and the
+    #                             next token's embedding, through the main
+    #                             head, for the token after the next
+    mtp_weight: float = 0.3     # the second prediction's loss times this,
+    #                             added to the first's
     # -- a Mamba-2 mixer (arXiv:2405.21060), where the pattern has one ------
     ssm_heads: int = 0          # heads of ``ssm_head_dim`` channels: the
     #                             inner width is their product, not a
@@ -160,9 +196,10 @@ class TransformerConfig:
     n_microbatches: int = 1     # pipeline microbatches (per pp>1)
     remat: Optional[bool] = None    # jax.checkpoint each block (HBM for
     #                             FLOPs). None: where the architecture needs
-    #                             it: the pipeline's stages, a looped stack
-    #                             and a Mamba block yes, the single scan over
-    #                             layers of any other kind no
+    #                             it: the pipeline's stages, a looped stack,
+    #                             a stack with a prediction module, a Mamba
+    #                             and a latent attention block yes, the single
+    #                             scan over layers of any other kind no
 
     @property
     def head_dim(self) -> int:
@@ -210,18 +247,35 @@ class TransformerConfig:
         if self.moe_router_scores not in ("softmax", "sigmoid"):
             raise ValueError(
                 f"moe_router_scores={self.moe_router_scores!r}")
-        words = dict.fromkeys(kind[0] for kind in self.layer_pattern
+        if self.lead_pattern and not self.one_sublayer:
+            raise ValueError(
+                f"lead_pattern={self.lead_pattern} before a layer_pattern "
+                "of two-sublayer blocks: the leading blocks are of one "
+                "sublayer, as is the stack they lead")
+        kinds = self.layer_pattern + self.lead_pattern
+        words = dict.fromkeys(kind[0] for kind in kinds
                               if isinstance(kind[0], str))
         if words and not all(
                 kind[0] in _BLOCK_KINDS
                 and len(kind) == _BLOCK_KINDS[kind[0]].length
-                for kind in self.layer_pattern):
+                for kind in kinds):
             raise ValueError(
-                f"layer_pattern={self.layer_pattern}: a block of one "
+                f"layer_pattern={self.layer_pattern}, lead_pattern="
+                f"{self.lead_pattern}: a block of one "
                 f"sublayer is one of {sorted(_BLOCK_KINDS)}, and a pattern "
                 "is of such blocks throughout or of none")
         for word in words:
             _BLOCK_KINDS[word].validate(self)
+        if self.mtp_depth not in (0, 1):
+            raise NotImplementedError(
+                f"mtp_depth={self.mtp_depth}: one multi-token-prediction "
+                "module is built, a chain of them is not")
+        if self.n_loops > 1 and (self.lead_pattern or self.mtp_depth):
+            raise NotImplementedError(
+                f"n_loops={self.n_loops} with lead_pattern or mtp_depth: a "
+                "looped stack hands every loop step's normed state to the "
+                "head; which of them leading blocks run before, and which a "
+                "prediction module reads, nobody has said")
 
 
 # ---------------------------------------------------------------------------
@@ -248,20 +302,6 @@ def _qk_norm(x, g, eps):
     width = x.shape[-1] * (axis_size("tp") if _axis_live("tp") else 1)
     return (xf * jax.lax.rsqrt(total / width + eps)
             ).astype(x.dtype) * g.astype(x.dtype)
-
-
-def _rope(x, positions, theta=10000.0):
-    """Rotary embedding, halves layout; x [B, S, H, D], positions [S]
-    absolute."""
-    B, S, H, D = x.shape
-    half = D // 2
-    freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
-    ang = positions[:, None].astype(jnp.float32) * freqs[None, :]  # [S, half]
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    x1, x2 = x[..., :half], x[..., half:]
-    cos = cos[None, :, None, :].astype(x.dtype)
-    sin = sin[None, :, None, :].astype(x.dtype)
-    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
 
 
 #: the most columns a row of the embedding gradient's float32 sums has when
@@ -398,7 +438,7 @@ def _attention_block(p, x, positions, cfg: TransformerConfig,
     """x: [B', S', M] local. Heads sharded over tp; sequence over sp.
     ``kind``: the layer's (window or None, rope or not)."""
     B, S, M = x.shape
-    window, rope = kind
+    window, roped = kind
     grouped = cfg.kv_heads != cfg.n_heads
     if _axis_live("sp") and (window is not None or grouped):
         raise NotImplementedError(
@@ -417,9 +457,9 @@ def _attention_block(p, x, positions, cfg: TransformerConfig,
         q = q.reshape(B, S, Hl, cfg.head_dim)
         k = k.reshape(B, S, k.shape[-1] // cfg.head_dim, cfg.head_dim)
         v = v.reshape(B, S, v.shape[-1] // cfg.head_dim, cfg.head_dim)
-        if rope:
-            q = _rope(q, positions, cfg.rope_theta)
-            k = _rope(k, positions, cfg.rope_theta)
+        if roped:
+            q = rope(q, positions, cfg.rope_theta)
+            k = rope(k, positions, cfg.rope_theta)
         # the core is the call a kernel replaces: its custom_vjp backward
         # is traced under the same scope
         with jax.named_scope(scopes.ATTENTION_CORE):
@@ -443,13 +483,22 @@ def _attention_block(p, x, positions, cfg: TransformerConfig,
         return x + o
 
 
-def _ffn_leaves(cfg: TransformerConfig):
-    M, F, E, held = cfg.d_model, cfg.d_ff, cfg.n_experts, cfg.held_experts
+def _routed(cfg: TransformerConfig, routed: Optional[bool]) -> bool:
+    """Whether an FFN block is the expert layer: what its kind says
+    (("experts",) yes, ("dense",) no), else whether the config has
+    experts."""
+    return cfg.n_experts > 0 if routed is None else routed
+
+
+def _ffn_leaves(cfg: TransformerConfig, routed: Optional[bool] = None):
+    M, E, held = cfg.d_model, cfg.n_experts, cfg.held_experts
+    routed = _routed(cfg, routed)
+    F = cfg.d_ff if routed or cfg.dense_ff is None else cfg.dense_ff
     shared = cfg.moe_shared_width
     yield Leaf("ln2", (M,), ones)
     if cfg.post_norm:
         yield Leaf("ln2_post", (M,), ones)
-    if E > 0:
+    if routed:
         # we1 is the gate of a gated expert, we3 its up projection, we2
         # the way back down (the Mixtral numbering); the experts held
         # here lead, the router scores them all
@@ -465,6 +514,8 @@ def _ffn_leaves(cfg: TransformerConfig):
         if shared:
             yield Leaf("ws1", (M, shared), normal(), (None, "tp"))
             yield Leaf("ws2", (shared, M), normal(), ("tp", None))
+            if cfg.moe_gated:
+                yield Leaf("ws3", (M, shared), normal(), (None, "tp"))
     else:
         # w1 is the gate of a gated FFN and w3 its up projection, as the
         # experts number theirs
@@ -541,7 +592,8 @@ def _moe_ffn(p, x, cfg: TransformerConfig, logits=None):
             scores=cfg.moe_router_scores, bias=p.get("router_bias"),
             scale=cfg.moe_routed_scale)
         if cfg.moe_shared_width:
-            y = y + _shared_expert(p, toks, ungated)
+            y = y + _shared_expert(p, toks, gate if cfg.moe_gated
+                                   else ungated)
         y = _psum_if(y, "tp")
     metrics = m._asdict()
     if cfg.expert_share == (0, 1):
@@ -553,11 +605,15 @@ def _moe_ffn(p, x, cfg: TransformerConfig, logits=None):
 
 
 def _shared_expert(p, toks, activation):
-    """The expert every token runs, ``down(activation(up(toks)))``, two
-    dense matmuls over all the tokens (inner width over tp, the caller's
-    psum); the same on every device that holds a share of the others."""
+    """The expert every token runs, of the routed experts' form:
+    ``down(activation(up(toks)))``, or with a ``ws3`` (gated experts)
+    ``down(activation(gate(toks)) * up(toks))``, ``ws1`` the gate; dense
+    matmuls over all the tokens (inner width over tp, the caller's psum);
+    the same on every device that holds a share of the others."""
     with jax.named_scope(scopes.MOE_SHARED):
         h = activation(toks @ p["ws1"].astype(toks.dtype))
+        if "ws3" in p:
+            h = h * (toks @ p["ws3"].astype(toks.dtype))
         return h @ p["ws2"].astype(toks.dtype)
 
 
@@ -575,12 +631,13 @@ def _over_layers(auxs):
             if k != "experts"}
 
 
-def _ffn_block(p, x, cfg: TransformerConfig, logits=None):
-    """``x + ffn(norm(x))``, the FFN dense or the experts; ``logits``: a
-    router's that read something else than the normed tokens."""
+def _ffn_block(p, x, cfg: TransformerConfig, logits=None, routed=None):
+    """``x + ffn(norm(x))``, the FFN dense or the experts (:func:`_routed`);
+    ``logits``: a router's that read something else than the normed
+    tokens."""
     with jax.named_scope(scopes.MLP):
         h = rmsnorm(x, p["ln2"], cfg.norm_eps)
-        if cfg.n_experts > 0:
+        if _routed(cfg, routed):
             o, aux = _moe_ffn(p, h, cfg, logits)
         else:
             o, aux = _dense_ffn(p, h, cfg), _no_aux()
@@ -613,9 +670,14 @@ def _needs_experts(cfg: TransformerConfig) -> None:
 #: mixer is its config fields, scopes, leaves and function, and a row here.
 _BLOCK_KINDS = {
     "mamba": mamba.KIND,
+    "latent": latent.KIND,
     "experts": BlockKind(
         length=1, leaves=_ffn_leaves, validate=_needs_experts,
         apply=lambda p, x, positions, cfg, kind: _ffn_block(p, x, cfg)),
+    "dense": BlockKind(
+        length=1, leaves=functools.partial(_ffn_leaves, routed=False),
+        apply=lambda p, x, positions, cfg, kind: (
+            _ffn_block(p, x, cfg, routed=False)[0], None)),
     "attention": BlockKind(
         length=3, leaves=_attention_leaves,
         apply=lambda p, x, positions, cfg, kind: (
@@ -663,32 +725,68 @@ def _model_leaves(cfg: TransformerConfig):
         yield Leaf("exit_gate_bias", (1,), zeros)
 
 
-def _stacks(cfg: TransformerConfig, layers: int = 0) -> Dict:
-    """The stacks of blocks the tree's ``layers`` holds, in the order the
+def _mtp_leaves(cfg: TransformerConfig):
+    """The prediction module's leaves beside its blocks (``params["mtp"]``):
+    the norms of its two inputs, the projection of their concatenation
+    ``[state ; next token's embedding]``, and the norm before the main
+    head."""
+    M = cfg.d_model
+    yield Leaf("norm_h", (M,), ones)
+    yield Leaf("norm_e", (M,), ones)
+    yield Leaf("proj", (2 * M, M), normal())
+    yield Leaf("ln_f", (M,), ones)
+
+
+def _stacks(cfg: TransformerConfig, layers: int = 0, pattern=None) -> Dict:
+    """The stacks of blocks a part of the tree holds (``layers``; ``lead``
+    and the prediction module's by their ``pattern``), in the order the
     pattern first names them: {:func:`_stack_of` its kinds: (their row, how
     many of ``layers`` consecutive layers are blocks of it)}."""
-    if not cfg.one_sublayer:
+    pattern = cfg.layer_pattern if pattern is None else pattern
+    if _stack_of(pattern[0]) is None:
         return {None: (_TWO_SUBLAYERS, layers)}
-    of = [_stack_of(kind) for kind in cfg.layer_pattern]
+    of = [_stack_of(kind) for kind in pattern]
     return {word: (_BLOCK_KINDS[word], layers // len(of) * of.count(word))
             for word in dict.fromkeys(of)}
+
+
+def _build_tree(cfg: TransformerConfig, n_stages: int, leaf_of) -> Dict:
+    """The parameter tree with ``leaf_of(leaf, stack)`` at every leaf, in
+    ``init_params``' order of draws. ``stack``: None for a leaf in no
+    stack, else (the stack's word, its row, its leading dimensions:
+    ``(stages, blocks a stage)`` in ``layers``, ``(blocks,)`` beside it)."""
+    def stacks_of(pattern, *lead):
+        stacks = {
+            key: {leaf.name: leaf_of(leaf, (key, row, lead[:-1] + (blocks,)))
+                  for leaf in row.leaves(cfg)}
+            for key, (row, blocks) in _stacks(cfg, lead[-1], pattern).items()}
+        # (two-sublayer blocks are one unnamed stack: its leaves, bare)
+        return stacks.get(None, stacks)
+    layers = stacks_of(cfg.layer_pattern, n_stages, cfg.n_layers // n_stages)
+    tree = {leaf.name: leaf_of(leaf, None) for leaf in _model_leaves(cfg)}
+    tree["layers"] = layers
+    if cfg.lead_pattern:
+        tree["lead"] = stacks_of(cfg.lead_pattern, len(cfg.lead_pattern))
+    if cfg.mtp_depth:
+        tree["mtp"] = {
+            **{leaf.name: leaf_of(leaf, None) for leaf in _mtp_leaves(cfg)},
+            "layers": stacks_of(cfg.layer_pattern, len(cfg.layer_pattern))}
+    return tree
 
 
 def init_params(rng: np.random.RandomState, cfg: TransformerConfig,
                 n_stages: int = 1) -> Dict:
     """Initialize parameters in the stacked-stage layout ``[pp, L/pp, ...]``;
     a pattern of one-sublayer blocks has one such stack a word under
-    ``layers`` (``layers["mamba"]["ssm_in"]`` ``[pp, blocks / pp, ...]``)."""
-    L = cfg.n_layers
-    assert L % n_stages == 0, (L, n_stages)
-    stacks = {
-        key: {leaf.name: leaf.draw(rng, (n_stages, blocks) + leaf.shape)
-              for leaf in row.leaves(cfg)}
-        for key, (row, blocks) in _stacks(cfg, L // n_stages).items()}
-    params = {leaf.name: leaf.draw(rng, leaf.shape)
-              for leaf in _model_leaves(cfg)}
-    params["layers"] = stacks if cfg.one_sublayer else stacks[None]
-    return params
+    ``layers`` (``layers["mamba"]["ssm_in"]`` ``[pp, blocks / pp, ...]``).
+    ``lead_pattern``'s blocks are ``lead``, a stack a word ``[blocks of that
+    word, ...]``; the prediction module is ``mtp``: its own leaves
+    (:func:`_mtp_leaves`) and ``mtp["layers"]``, one period of the pattern
+    in stacks ``[blocks, ...]``."""
+    assert cfg.n_layers % n_stages == 0, (cfg.n_layers, n_stages)
+    return _build_tree(
+        cfg, n_stages, lambda leaf, stack: leaf.draw(
+            rng, (stack[2] if stack else ()) + leaf.shape))
 
 
 def param_shardings(cfg: TransformerConfig, mesh: Mesh) -> Dict:
@@ -710,26 +808,35 @@ def param_shardings(cfg: TransformerConfig, mesh: Mesh) -> Dict:
             f"layer_pattern of {len(cfg.layer_pattern)} kinds on pp="
             f"{mesh.shape['pp']}: a stage of "
             f"{cfg.n_layers // mesh.shape['pp']} layers is not whole periods")
+    beside = [field for field in ("lead_pattern", "mtp_depth")
+              if getattr(cfg, field)]
+    split = [axis for axis in ("sp", "pp") if live[axis]]
+    if beside and split:
+        raise NotImplementedError(
+            f"{' and '.join(beside)} on a live {' / '.join(split)} axis: "
+            "the leading blocks and the prediction module are whole on "
+            "every device and belong to no stage of the pipeline's "
+            "schedule, and the module's targets are the sequence moved by "
+            "one more position, across the sp shards' edges")
 
-    def sharding(leaf: Leaf, lead=()):
+    def sharding(leaf: Leaf, stack):
         spec = tuple(axis and live[axis] for axis in leaf.spec)
-        return NamedSharding(mesh, P(*lead, None, *spec) if lead and spec
-                             else P(*lead, *spec))
-    stacks = {}
-    for key, (row, _blocks) in _stacks(cfg).items():
+        if stack is None:
+            return NamedSharding(mesh, P(*spec))
+        word, row, lead_shape = stack
         refused = [axis for axis in row.refuses if live[axis]]
         if refused:
             raise NotImplementedError(
-                f"a (\"{key}\",) block of layer_pattern on a live "
+                f"a (\"{word}\",) block of layer_pattern on a live "
                 f"{' / '.join(refused)} axis: {row.refusal}")
-        # [stage, block of the stage, ...], the stages over pp where the
-        # kind runs on one
-        lead = () if "pp" in row.refuses else (live["pp"],)
-        stacks[key] = {leaf.name: sharding(leaf, lead)
-                       for leaf in row.leaves(cfg)}
-    shardings = {leaf.name: sharding(leaf) for leaf in _model_leaves(cfg)}
-    shardings["layers"] = stacks if cfg.one_sublayer else stacks[None]
-    return shardings
+        # [stage, block of the stage, ...] in ``layers``, the stages over
+        # pp where the kind runs on one; [block, ...] beside it
+        staged = len(lead_shape) == 2 and "pp" not in row.refuses
+        lead = (live["pp"],) if staged else ()
+        pad = (None,) * (len(lead_shape) - len(lead))
+        return NamedSharding(mesh, P(*lead, *pad, *spec) if spec
+                             else P(*lead))
+    return _build_tree(cfg, 1, sharding)
 
 
 def shard_params(params: Dict, cfg: TransformerConfig, mesh: Mesh) -> Dict:
@@ -750,7 +857,9 @@ def forward_loss_spmd(params, tokens, targets, cfg: TransformerConfig):
     ``router_z_loss``, ``max_expert_load``, ``dropped`` (always 0). A
     looped model's loss is the exit-weighted objective of
     :func:`_looped_loss`, and ``aux`` gains ``step_losses`` ``[T]``,
-    ``exit_share`` ``[T]`` and ``gate_entropy``."""
+    ``exit_share`` ``[T]`` and ``gate_entropy``. With a prediction module
+    (``mtp_depth``) the loss is ``main_loss + mtp_weight * mtp_loss``, both
+    in ``aux``, and the counters are over its expert layer too."""
     S = tokens.shape[1]
     sp_idx = lax.axis_index("sp") if _axis_live("sp") else 0
     positions = sp_idx * S + jnp.arange(S)
@@ -758,10 +867,13 @@ def forward_loss_spmd(params, tokens, targets, cfg: TransformerConfig):
     with jax.named_scope(scopes.EMBED):
         x = _embed_lookup(params["embed"], tokens, cfg)         # [B,S,M]
 
+    beside = bool(cfg.lead_pattern or cfg.mtp_depth)
     with jax.named_scope(scopes.LAYERS):
         if cfg.n_loops > 1:
             x, aux_total = _loop_layers(params["layers"], params["ln_f"], x,
                                         positions, cfg)      # [T,B,S,M]
+        elif beside:
+            x, auxs = _lead_then_layers(params, x, positions, cfg)
         else:
             x, aux_total = _run_layers(params["layers"], x, positions, cfg)
 
@@ -777,9 +889,22 @@ def forward_loss_spmd(params, tokens, targets, cfg: TransformerConfig):
             loss, exits = _looped_loss(_exit_gate(params, x), nll)
             aux_total = {**aux_total, **exits}
         else:
+            state = x       # what a prediction module reads: before ln_f
             x = rmsnorm(x, params["ln_f"], cfg.norm_eps)
             nll = _head_xent(x, head, targets)                  # [B,S]
             loss = jnp.mean(nll)
+    if beside:
+        parts = {}
+        if cfg.mtp_depth:
+            with jax.named_scope(scopes.MTP):
+                mtp_loss, mtp_auxs = _mtp_loss(params, state, targets, head,
+                                               positions, cfg)
+            auxs = _join_aux(cfg, [(cfg.layer_pattern, auxs),
+                                   (cfg.layer_pattern, mtp_auxs)])
+            parts = {"main_loss": loss, "mtp_loss": mtp_loss}
+            loss = loss + cfg.mtp_weight * mtp_loss
+        aux_total = {**_over_layers(auxs), **parts}
+    with jax.named_scope(scopes.HEAD):
         # average over data-like axes so every shard reports the global
         # loss (ep subdivides the batch — see data_sharding_spec)
         for ax in ("dp", "ep", "sp"):
@@ -788,6 +913,44 @@ def forward_loss_spmd(params, tokens, targets, cfg: TransformerConfig):
                 aux_total = jax.tree_util.tree_map(
                     lambda a: lax.pmean(a, ax), aux_total)
     return loss, aux_total
+
+
+def _mtp_input(params, state, targets, cfg: TransformerConfig):
+    """What the prediction module's layer reads: ``[norm(h_i) ;
+    norm(Emb(t_{i+1}))] W``, ``h`` the main stack's output before ``ln_f``
+    (``state``), ``t_{i+1}`` the next token (``targets[i]``), two norms of
+    the module's own and the main model's table."""
+    mp = params["mtp"]
+    with jax.named_scope(scopes.MTP_PROJ):
+        nxt = _embed_lookup(params["embed"], targets, cfg)
+        return jnp.concatenate(
+            [rmsnorm(state, mp["norm_h"], cfg.norm_eps),
+             rmsnorm(nxt, mp["norm_e"], cfg.norm_eps)], -1
+        ) @ mp["proj"].astype(state.dtype)
+
+
+def _mtp_targets(targets):
+    """The token after the next: ``targets`` one position on. The last
+    position's lies beyond the sequence (:func:`_mtp_loss` leaves it out)."""
+    return jnp.roll(targets, -1, axis=1)
+
+
+def _mtp_loss(params, state, targets, head, positions, cfg):
+    """The multi-token-prediction module's loss (DeepSeek-V3,
+    arXiv:2412.19437 eq. 21-25, one module) and its blocks' auxiliary
+    terms, stacked: :func:`_mtp_input`, one more period of the pattern
+    with the module's own weights, a norm of its own and the MAIN head
+    (``head``), for :func:`_mtp_targets`; the mean over the positions whose
+    target lies in the sequence."""
+    mp = params["mtp"]
+    u = _mtp_input(params, state, targets, cfg)
+    with jax.named_scope(scopes.LAYERS):
+        z, auxs = _scan_periods(u, mp["layers"], positions, cfg,
+                                functools.partial(_checkpointed, cfg))
+    with jax.named_scope(scopes.HEAD):
+        z = rmsnorm(z, mp["ln_f"], cfg.norm_eps)
+        nll = _head_xent(z, head, _mtp_targets(targets))
+        return jnp.mean(nll[:, :-1]), auxs
 
 
 def _exit_gate(params, states):
@@ -895,18 +1058,55 @@ def _run_layers(lp, x, positions, cfg: TransformerConfig):
 def _scan_layers(lp, x, positions, cfg: TransformerConfig):
     """One scan over the blocks of ``lp`` (``[stage, layer, ...]`` leaves).
     Returns (activations, every layer's auxiliary terms stacked ``[L]``).
-    A looped stack checkpoints each block (its passes' activations would
-    not fit beside the weights), the single pass only the kinds whose row
-    says so (``BlockKind.checkpointed``), unless ``cfg.remat`` says
-    otherwise."""
+    Which blocks are checkpointed is :func:`_checkpointed`'s to say, unless
+    ``cfg.remat`` says otherwise."""
     flat = jax.tree_util.tree_map(
         lambda a: a.reshape((-1,) + a.shape[2:]), lp)
-    return _scan_periods(
-        x, flat, positions, cfg,
-        lambda kind: cfg.n_loops > 1 or _row(kind).checkpointed)
+    return _scan_periods(x, flat, positions, cfg,
+                         functools.partial(_checkpointed, cfg))
 
 
-def _scan_periods(x, layers, positions, cfg: TransformerConfig, needed):
+def _checkpointed(cfg: TransformerConfig, kind) -> bool:
+    """Whether the stack checkpoints a block of ``kind`` where ``cfg.remat``
+    says nothing. A looped stack does every block (its passes' activations
+    would not fit beside the weights), and so does a stack with a
+    prediction module, whose layer's activations and second head's logits
+    lie beside the stack's (with only its attention blocks checkpointed
+    GLM-4.7-Flash's step of 8192 tokens asks for 16.2 GB: PERF.md section
+    6, PR 43); else the kinds whose row says so."""
+    return bool(cfg.n_loops > 1 or cfg.mtp_depth or _row(kind).checkpointed)
+
+
+def _has_experts(cfg: TransformerConfig, pattern) -> bool:
+    return cfg.n_experts > 0 and any(
+        _stack_of(kind) in (None, "experts") for kind in pattern)
+
+
+def _join_aux(cfg: TransformerConfig, parts):
+    """Several runs' stacked auxiliary terms, ``[(pattern, terms)]``, as one
+    stack: those of the runs whose pattern has expert layers, where any
+    has (a run without them stacks a zero a period)."""
+    keep = ([aux for pattern, aux in parts if _has_experts(cfg, pattern)]
+            or [aux for _pattern, aux in parts])
+    return {k: jnp.concatenate([aux[k] for aux in keep]) for k in keep[0]}
+
+
+def _lead_then_layers(params, x, positions, cfg: TransformerConfig):
+    """``lead_pattern``'s blocks, each once, then the periodic stack, all
+    on one stage (no pp: ``param_shardings``). Returns (activations, the
+    auxiliary terms of every layer that has any, stacked)."""
+    parts = []
+    if cfg.lead_pattern:
+        x, auxs = _scan_periods(x, params["lead"], positions, cfg,
+                                functools.partial(_checkpointed, cfg),
+                                cfg.lead_pattern)
+        parts.append((cfg.lead_pattern, auxs))
+    x, auxs = _scan_layers(params["layers"], x, positions, cfg)
+    return x, _join_aux(cfg, parts + [(cfg.layer_pattern, auxs)])
+
+
+def _scan_periods(x, layers, positions, cfg: TransformerConfig, needed,
+                  pattern=None):
     """``x`` through every layer of ``layers`` (leaves ``[L, ...]``), layer
     ``l`` a block of kind ``pattern[l % len(pattern)]``, checkpointed where
     ``cfg.remat`` says or, where it says nothing, ``needed(kind)``: a scan
@@ -914,30 +1114,32 @@ def _scan_periods(x, layers, positions, cfg: TransformerConfig, needed):
     static kind; a period of one is a scan over layers. A pattern of
     one-sublayer blocks has a stack a word (``layers[word]``, leaves
     ``[blocks of that word, ...]``), and a period's i-th block of a word
-    takes the i-th of the period's layers in that stack. Returns
-    (activations, the auxiliary terms of every layer that has any,
+    takes the i-th of the period's layers in that stack. ``pattern``: the
+    stack's where it is not ``cfg.layer_pattern`` (``lead_pattern``).
+    Returns (activations, the auxiliary terms of every layer that has any,
     stacked)."""
-    pattern = cfg.layer_pattern
+    pattern = cfg.layer_pattern if pattern is None else pattern
+    one_sublayer = _stack_of(pattern[0]) is not None
 
     def block_of(kind):
         def block(layer_p, x):
             return _block(layer_p, x, positions, cfg, kind)
         return jax.checkpoint(block) if remat(cfg, needed(kind)) else block
     blocks = {kind: block_of(kind) for kind in pattern}
-    if len(pattern) == 1 and not cfg.one_sublayer:
+    if len(pattern) == 1 and not one_sublayer:
         # the program of every config without a pattern, to the letter
         def scan_body(carry, layer_p):
             y, aux = blocks[pattern[0]](layer_p, carry)
             return y, aux
         return lax.scan(scan_body, x, layers)
-    stacks = layers if cfg.one_sublayer else {None: layers}
+    stacks = layers if one_sublayer else {None: layers}
     # a stack as [periods, the period's blocks in it, ...], and each block
     # of a period as (its stack, its place among those)
     periods = {
         key: jax.tree_util.tree_map(
             lambda a, per=per: a.reshape(
                 (a.shape[0] // per, per) + a.shape[1:]), stacks[key])
-        for key, (_kind, per) in _stacks(cfg, len(pattern)).items()}
+        for key, (_kind, per) in _stacks(cfg, len(pattern), pattern).items()}
     of = [_stack_of(kind) for kind in pattern]
     places = [(key, of[:i].count(key)) for i, key in enumerate(of)]
 
@@ -961,8 +1163,7 @@ def router_choices(params, tokens, cfg: TransformerConfig):
     diagnostics, such as telling what differing choices explain of an error
     against a reference (benchmarks/chip/tools/olmoe_routing.py)."""
     x = _embed_lookup(params["embed"], tokens, cfg)
-    _x, auxs = _scan_layers(params["layers"], x,
-                            jnp.arange(tokens.shape[1]), cfg)
+    _x, auxs = _lead_then_layers(params, x, jnp.arange(tokens.shape[1]), cfg)
     return auxs["experts"]
 
 

@@ -48,6 +48,14 @@ ATTENTION_CORE = "hvd.attention.core"
 #: window, and of one that sees the whole causal history
 ATTENTION_CORE_WINDOW = "hvd.attention.core.window"
 ATTENTION_CORE_FULL = "hvd.attention.core.full"
+#: nested in ATTENTION, around everything between a latent-attention block's
+#: norm and its core (``models/latent.py``), and its two parts. Down: the
+#: projections onto the two latents and the shared rope key, and the
+#: latents' norms. Up: the heads' queries, keys and values from the latents,
+#: rope, the rope key's broadcast over the heads, the concatenations
+ATTENTION_LATENT = "hvd.attention.latent"
+ATTENTION_LATENT_DOWN = "hvd.attention.latent.down"
+ATTENTION_LATENT_UP = "hvd.attention.latent.up"
 MLP = "hvd.mlp"
 #: nested in MLP: the expert layer of an MoE block, and its four parts.
 #: Router: logits, softmax, top-k, the auxiliary losses and counters.
@@ -80,6 +88,12 @@ LOOP = "hvd.loop"
 LOOP_GATE = "hvd.loop.gate"
 #: final norm or transform, logits, loss
 HEAD = "hvd.head"
+#: the multi-token-prediction module (``mtp_depth``): its blocks and its head
+#: call carry it beside their own names. Proj: the module's two input
+#: norms, the second read of the embedding and the projection of their
+#: concatenation
+MTP = "hvd.mtp"
+MTP_PROJ = "hvd.mtp.proj"
 GRAD_SYNC = "hvd.grad_sync"
 OPTIMIZER = "hvd.optimizer"
 
@@ -95,8 +109,12 @@ LOOP_PHASES = (LOOP, LOOP_GATE)
 #: phases only a stack with several kinds of layer has, each forward and
 #: backward
 MIXED_PHASES = (ATTENTION_CORE_WINDOW, ATTENTION_CORE_FULL)
+#: phases only a model with latent attention and a multi-token-prediction
+#: module has (GLM-4.7-Flash), each forward and backward
+LATENT_PHASES = (ATTENTION_LATENT, ATTENTION_LATENT_DOWN, ATTENTION_LATENT_UP,
+                 MTP, MTP_PROJ)
 DEVICE_PHASES = (MODEL_PHASES + MOE_PHASES + LOOP_PHASES + MIXED_PHASES
-                 + HYBRID_PHASES + (GRAD_SYNC, OPTIMIZER))
+                 + HYBRID_PHASES + LATENT_PHASES + (GRAD_SYNC, OPTIMIZER))
 
 # -- host spans (profiling.annotate) ------------------------------------------
 #: the input iterator's ``next()``: the host makes the batch

@@ -429,3 +429,91 @@ def test_the_hybrid_step_gives_what_the_nemotron_h_adapter_reads():
     assert scopes.HYBRID_PHASES == (
         "hvd.moe.shared", "hvd.ssm", "hvd.ssm.proj", "hvd.ssm.conv",
         "hvd.ssm.scan", "hvd.ssm.norm")
+
+
+def test_the_latent_step_gives_what_the_glm4_moe_lite_adapter_reads():
+    """``adapters/glm4_moe_lite.py`` names leaves of ``lead``, ``layers``
+    and ``mtp`` (``_leaf_paths``, ``_init_function``), reads ``held_rows``,
+    ``dropped``, ``main_loss`` and ``mtp_loss`` from the step's fourth
+    output and ``router_choices``; the configuration's fields reach
+    ``TransformerConfig`` by keyword as ``q_latent``, ``kv_latent``,
+    ``rope_width``, ``lead_pattern``, ``dense_ff``, ``mtp_depth``,
+    ``mtp_weight``; the phase files look for the latent projections' and
+    the prediction module's scopes, the roofline functions for the
+    adapter's ``shapes()`` keys."""
+    import sys
+    import jax
+    import numpy as np
+    if CHIP not in sys.path:
+        sys.path.insert(0, CHIP)
+    from adapters import glm4_moe_lite
+    from trees import get_leaves
+    from horovod_tpu.models import transformer as t
+    from horovod_tpu.parallel import build_mesh
+    from horovod_tpu.profiling import scopes
+    for name in ("shapes", "tokens_per_step", "flops_per_token",
+                 "host_batch", "abstract_step", "Cell"):
+        assert callable(getattr(glm4_moe_lite, name)), name
+    assert {"program_choices", "program_loss_and_grads", "compiled_step",
+            "step"} <= set(dir(glm4_moe_lite.Cell))
+    with open(os.path.join(CHIP, "configs", "glm-4.7-flash.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(CHIP, "workloads",
+                           "train.s8192.b1.latent.json")) as f:
+        job = json.load(f)
+    full = glm4_moe_lite._model_config(config, job)
+    assert (full.layer_pattern, full.lead_pattern) == (
+        (("latent",), ("experts",)), (("latent",), ("dense",)))
+    assert (full.q_latent, full.kv_latent, full.rope_width, full.head_dim,
+            full.dense_ff, full.mtp_depth, full.mtp_weight,
+            full.moe_shared_width, full.moe_gated, full.expert_share,
+            full.held_experts) == (
+                768, 512, 64, 256, 10240, 1, 0.3, 1536, True, (0, 8), 8)
+    for function in ("latent_moe_gmm", "latent_flash_attention",
+                     "latent_flash_attention_backward", "latent_head_xent"):
+        need = getattr(importlib.import_module(f"roofline_{function}"),
+                       function)(glm4_moe_lite.shapes(config, job))
+        assert need["flops"] > 0 and need["bytes"] > 0, function
+    config, job = {**config, **config["tiny"]}, {**job, **job["tiny"]}
+    cfg = glm4_moe_lite._model_config(config, job)
+    params = t.init_params(np.random.RandomState(0), cfg, 1)
+    assert set(params) == {"embed", "ln_f", "lm_head", "layers", "lead",
+                           "mtp"}
+    assert set(params["layers"]) == set(params["mtp"]["layers"]) == {
+        "latent", "experts"}
+    assert set(params["lead"]) == {"latent", "dense"}
+    assert set(params["mtp"]) == {"norm_h", "norm_e", "proj", "ln_f",
+                                  "layers"}
+    assert set(params["lead"]["latent"]) == {
+        "ln1", "wqa", "q_latent_norm", "wqb", "wkva", "kv_latent_norm",
+        "wkvb", "wo"}
+    assert set(params["lead"]["dense"]) == {"ln2", "w1", "w2", "w3"}
+    assert set(params["layers"]["experts"]) == {
+        "ln2", "router", "router_bias", "we1", "we2", "we3", "ws1", "ws2",
+        "ws3"}
+    assert params["layers"]["latent"]["wkvb"].shape == (
+        1, 2, cfg.kv_latent,
+        cfg.n_heads * (2 * cfg.head_dim - cfg.rope_width))
+    assert params["lead"]["latent"]["wkva"].shape == (
+        1, cfg.d_model, cfg.kv_latent + cfg.rope_width)
+    ours = jax.eval_shape(glm4_moe_lite._init_function(cfg, config),
+                          jax.random.PRNGKey(0))
+    assert jax.tree_util.tree_map(lambda a: a.shape, ours) == \
+        jax.tree_util.tree_map(lambda a: a.shape, params)
+    assert set(get_leaves(params, glm4_moe_lite._leaf_paths(2))) == {
+        "lm_head", "first_query_down", "last_kv_down", "last_kv_up",
+        "dense_down", "last_router", "last_experts_down", "mtp_proj"}
+    mesh = build_mesh(devices=jax.devices()[:1], dp=-1)
+    batch = glm4_moe_lite.host_batch(config, job, 0, 0, 1)
+    _loss, aux, _grads = jax.eval_shape(
+        t.make_grad_fn(cfg, mesh), params, batch["tokens"], batch["targets"])
+    assert set(aux) == {"aux_loss", "load_balance_loss", "router_z_loss",
+                        "max_expert_load", "dropped", "held_rows",
+                        "main_loss", "mtp_loss"}
+    choices = jax.eval_shape(
+        lambda p, tok: t.router_choices(p, tok, cfg), params,
+        batch["tokens"])
+    assert choices.shape == (2, batch["tokens"].size, cfg.moe_top_k)
+    assert scopes.LATENT_PHASES == (
+        "hvd.attention.latent", "hvd.attention.latent.down",
+        "hvd.attention.latent.up", "hvd.mtp", "hvd.mtp.proj")

@@ -37,7 +37,8 @@ _CHIP = os.path.join(_REPO, "benchmarks", "chip")
 if _CHIP not in sys.path:
     sys.path.insert(0, _CHIP)
 
-from adapters import nemotron_h, olmoe, ouro, smallthinker   # noqa: E402
+from adapters import (glm4_moe_lite, nemotron_h, olmoe, ouro,   # noqa: E402
+                      smallthinker)
 
 
 def _tiny(adapter, config: str, workload: str) -> TransformerConfig:
@@ -71,6 +72,9 @@ CONFIGS = {
     "nemotron-h-me*": dataclasses.replace(
         _NEMOTRON, n_layers=3,
         layer_pattern=tuple(nemotron_h.KINDS[c] for c in "ME*")),
+    # tests/test_glm4_moe_lite.py's CFG: leading blocks and a prediction
+    # module beside the periodic stack
+    "glm": _tiny(glm4_moe_lite, "glm-4.7-flash", "train.s8192.b1.latent"),
 }
 
 
@@ -126,10 +130,12 @@ def test_a_seed_gives_the_tree_it_gave_before_the_declarations(name, stages):
 
 def _live_axes(cfg: TransformerConfig) -> dict:
     """What of tp=2 and ep=2 the configuration runs on: a tp shard holds
-    whole k/v heads, a Mamba block has no tp, and a device holds its experts
-    by its place on ep or by ``expert_share``, not both."""
+    whole k/v heads, a Mamba or a latent attention block has no tp, and a
+    device holds its experts by its place on ep or by ``expert_share``, not
+    both."""
     axes = {}
-    if cfg.kv_heads % 2 == 0 and ("mamba",) not in cfg.layer_pattern:
+    if cfg.kv_heads % 2 == 0 and not {("mamba",), ("latent",)} & set(
+            cfg.layer_pattern):
         axes["tp"] = 2
     if cfg.expert_share == (0, 1):
         axes["ep"] = 2
@@ -186,6 +192,10 @@ NOT_PLAIN = {
     "moe_router_scores": {"moe_router_scores": "sigmoid"},
     "moe_shared_width": {"moe_shared_width": 64},
     "ssm_heads": {"ssm_heads": 2},
+    "kv_latent": {"kv_latent": 16},
+    "lead_pattern": {"lead_pattern": (("dense",),),
+                     "layer_pattern": (("attention", None, True),)},
+    "mtp_depth": {"mtp_depth": 1},
 }
 
 

@@ -25,7 +25,7 @@ PACKAGE = os.path.join(os.path.dirname(os.path.dirname(
 
 def test_the_vocabulary_is_the_only_place_the_strings_are_written():
     names = scopes.DEVICE_PHASES + scopes.HOST_SPANS
-    assert len(set(names)) == len(names) == 27
+    assert len(set(names)) == len(names) == 32
     assert scopes.HOST_SPANS == ("hvd.input.source", "hvd.input.place",
                                  "hvd.host.gc", "hvd.host.compile")
     assert all(n.startswith("hvd.") for n in names)

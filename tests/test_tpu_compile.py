@@ -88,6 +88,11 @@ _QKV_S4096 = [((1, 4096, 16, 128), jnp.bfloat16)] * 3
 # heads, 8192 positions (a head's float32 dq of 8192 rows stays in VMEM)
 _QKV_GROUPED = [((1, 8192, 28, 128), jnp.bfloat16)] \
     + [((1, 8192, 4, 128), jnp.bfloat16)] * 2
+# the cell glm-4.7-flash.s8192: 20 / 20 heads of 256 (latent attention's
+# keys and values come up for every head), 8192 positions: the forward's
+# 1024 x 1024 tile reads exactly its VMEM budget, the backward's resident
+# form leaves room for 512-tiles
+_QKV_LATENT = [((1, 8192, 20, 256), jnp.bfloat16)] * 3
 _FLASH = ("hvd_flash_attention", "hvd_flash_bwd")
 # the attention core of the cells bert-large.s128 and bert-large.s512: q, k,
 # v and the [B, S] key mask. Sixteen heads of 64 are eight 128-lane columns
@@ -112,6 +117,12 @@ _GMM_HYBRID = [((49152, 2688), jnp.bfloat16), ((8, 2688, 1856), jnp.float32),
                ((8,), jnp.int32)]
 _GMM_HYBRID_DOWN = [((49152, 1856), jnp.bfloat16),
                     ((8, 1856, 2688), jnp.float32), ((8,), jnp.int32)]
+# a held share's expert layer in glm-4.7-flash.s8192: 8192 tokens x top-4
+# gathered rows, 8 held experts of 2048 <-> 1536
+_GMM_LATENT = [((32768, 2048), jnp.bfloat16), ((8, 2048, 1536), jnp.float32),
+               ((8,), jnp.int32)]
+_GMM_LATENT_DOWN = [((32768, 1536), jnp.bfloat16),
+                    ((8, 1536, 2048), jnp.float32), ((8,), jnp.int32)]
 _GMM_SHARE_DOWN = [((49152, 768), jnp.bfloat16),
                    ((16, 768, 2560), jnp.float32), ((16,), jnp.int32)]
 # the Mamba-2 scan of the cell nemotron-3-nano-30b-a3b.s8192: x, dt, a, b,
@@ -139,6 +150,7 @@ CASES = {
         jax.grad(lambda q, k, v: _sum32(pa.flash_attention_tpu(
             q, k, v, True, window=4096)), (0, 1, 2)), _QKV_GROUPED, _FLASH),
     "flash_fwd_grad_full_grouped": (_flash_grad, _QKV_GROUPED, _FLASH),
+    "flash_fwd_grad_latent": (_flash_grad, _QKV_LATENT, _FLASH),
     # a window that is no multiple of the tile: whole masked tiles
     "flash_fwd_grad_window_unaligned": (
         jax.grad(lambda q, k, v: _sum32(pa.flash_attention_tpu(
@@ -173,6 +185,13 @@ CASES = {
     "moe_gmm_share_down_grad": (
         jax.grad(lambda x, w, g: _sum32(moe.grouped_matmul(x, w, g)),
                  (0, 1)), _GMM_SHARE_DOWN, "transpose_jvp_" + moe.GMM_NAME),
+    "moe_gmm_latent_grad": (
+        jax.grad(lambda x, w, g: _sum32(moe.grouped_matmul(x, w, g)),
+                 (0, 1)), _GMM_LATENT, "transpose_jvp_" + moe.GMM_NAME),
+    "moe_gmm_latent_down_grad": (
+        jax.grad(lambda x, w, g: _sum32(moe.grouped_matmul(x, w, g)),
+                 (0, 1)), _GMM_LATENT_DOWN,
+        "transpose_jvp_" + moe.GMM_NAME),
     # an expert width no 128-multiple divides (1856 = 2^6 * 29): a block
     # spans it whole, as the contraction and as the output's columns
     "moe_gmm_hybrid_grad": (
