@@ -669,17 +669,25 @@ def _check_gmm(smoke: Smoke) -> None:
     groups' rows alone. Prints which path ``grouped_matmul`` takes, which
     way round it reads the weights and the tile of each of its three calls,
     at each size, at the OLMoE cell's, at the share cell's, at the hybrid
-    cell's and at the latent cell's."""
+    cell's and at the latent cell's, and of how many of their chunks of
+    sorted rows the expert layer's row-wise passes run there."""
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from horovod_tpu.parallel.moe import GMM_NAME, gmm_path, grouped_matmul
+    from horovod_tpu.parallel.moe import (GMM_NAME, gmm_path, grouped_matmul,
+                                          held_chunks_path)
 
     interpret = smoke.rehearsal
     olmoe = gmm_path(65536, 2048, 1024)
     share = gmm_path(49152, 2560, 768)
     hybrid = gmm_path(49152, 2688, 1856)
     latent = gmm_path(4 * 8192, 2048, 1536)
+    # the rows each share holds by arithmetic: 16 of 64, 8 of 128, 8 of 64
+    # experts (ISSUE 44: the chunks the row-wise passes run of those there are)
+    chunks = {"share": held_chunks_path(49152, 49152 * 16 // 64),
+              "hybrid": held_chunks_path(49152, 49152 * 8 // 128),
+              "latent": held_chunks_path(4 * 8192, 4 * 8192 * 8 // 64),
+              "olmoe": held_chunks_path(65536, None)}
     if smoke.on_chip:
         check(olmoe.startswith(f"pallas {GMM_NAME} ") and "row-major" in olmoe
               and share.startswith(f"pallas {GMM_NAME} ")
@@ -733,6 +741,7 @@ def _check_gmm(smoke: Smoke) -> None:
                      gmm_path_at_the_share_cell=share,
                      gmm_path_at_the_hybrid_cell=hybrid,
                      gmm_path_at_the_latent_cell=latent,
+                     row_wise_passes_at_the_cells=chunks,
                      rows_in_groups=inside, largest_group=int(sizes.max()),
                      empty_groups=int((sizes == 0).sum()),
                      beyond_the_groups=None if kernel else beyond(got),

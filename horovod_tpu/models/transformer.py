@@ -72,7 +72,7 @@ from horovod_tpu.models._kinds import (BlockKind, Leaf, normal, ones, remat,
                                        rmsnorm, rope, zeros)
 from horovod_tpu.models.scan_util import multi_step
 from horovod_tpu.parallel.ring_attention import ring_attention_spmd
-from horovod_tpu.parallel.moe import grouped_matmul, moe_layer_spmd
+from horovod_tpu.parallel.moe import expert_ffn, moe_layer_spmd, rows_held
 from horovod_tpu.profiling import scopes
 
 
@@ -571,12 +571,11 @@ def _moe_ffn(p, x, cfg: TransformerConfig, logits=None):
         return jax.nn.gelu(h)
 
     def expert_fn(ep, rows, group_sizes):
-        h = grouped_matmul(rows, ep["we1"], group_sizes)
-        if cfg.moe_gated:
-            h = gate(h) * grouped_matmul(rows, ep["we3"], group_sizes)
-        else:
-            h = ungated(h)
-        return grouped_matmul(h, ep["we2"], group_sizes)
+        # fewer groups than the router has columns (a share, a live ep
+        # axis): the rows behind them are not this device's to compute
+        return expert_ffn(rows, ep["we1"], ep.get("we3"), ep["we2"],
+                          group_sizes, rows_held(group_sizes, cfg.n_experts),
+                          gate if cfg.moe_gated else ungated)
 
     if logits is None and cfg.moe_router_scores == "sigmoid":
         logits = _router_logits(p, x)

@@ -27,7 +27,9 @@ Of the ``G * k`` sorted rows only the first ``held = sum(group_sizes)`` are
 an expert's here (all of them, unless the experts are sharded or the device
 holds a ``share``), and nothing reads a sorted row behind them: the grouped
 matmuls visit their groups' tiles only and leave the rest unwritten; the
-combine's backward pass runs over the row chunks that hold a held row
+row-wise passes (the experts' activation between the kernels, its backward
+pass and the sum of the rows' two cotangents, :func:`expert_ffn`; the
+combine's backward pass) run over the row chunks that hold a held row
 (:func:`_over_held_chunks`, a loop with a device trip count; one pass and
 no loop where every row is held); and what the token-major gathers
 (:func:`_by_choice`) fetch from there is replaced by zeros. Those gathers
@@ -284,35 +286,61 @@ def _gmm_fwd(rows, weights, group_sizes, tiles, interpret):
             (rows, weights, group_sizes))
 
 
-def _gmm_bwd(tiles, interpret, res, g):
-    rows, weights, group_sizes = res
+def _gmm_d_rows(tiles, interpret, rows, weights, group_sizes, g):
+    """The cotangent of :func:`_gmm`'s ``rows`` (of which only shape and
+    dtype are read) under its result's cotangent ``g``."""
     if tiles is None:
         # what XLA does with a row beyond the groups is its own: no such
-        # row's cotangent may reach a weight gradient
-        d_rows, d_weights = jax.vjp(
-            lambda r, w: _ragged_dot(r, w, group_sizes), rows, weights)[1](
+        # row's cotangent may reach a gradient
+        (d_rows,) = jax.linear_transpose(
+            lambda r: _ragged_dot(r, weights, group_sizes),
+            jax.ShapeDtypeStruct(rows.shape, rows.dtype))(
                 _zero_beyond(g, group_sizes))
-        d_rows = _zero_beyond(d_rows, group_sizes)
-    else:
-        gmm, tgmm = _megablox()
-        with jax.named_scope(GMM_NAME):
-            d_rows = gmm(g, _as_read(weights, tiles, rows.dtype), group_sizes,
-                         rows.dtype, tiles.input_grad,
-                         transpose_rhs=not tiles.transposed,
-                         interpret=interpret)
-            # [E, K, F] is rows^T g; read as stored it is [E, F, K], g^T rows
-            # swapped back: the array the update reads beside the weights
-            # and their moments, in their layout
-            lhs, rhs = (g, rows) if tiles.transposed else (rows, g)
-            d_weights = tgmm(lhs.swapaxes(0, 1), rhs, group_sizes,
-                             rows.dtype, tiles.weight_grad,
-                             interpret=interpret)
-            if tiles.transposed:
-                d_weights = jnp.swapaxes(d_weights, -1, -2)
-    return d_rows, d_weights.astype(weights.dtype), None
+        return _zero_beyond(d_rows, group_sizes)
+    gmm, _ = _megablox()
+    with jax.named_scope(GMM_NAME):
+        return gmm(g, _as_read(weights, tiles, rows.dtype), group_sizes,
+                   rows.dtype, tiles.input_grad,
+                   transpose_rhs=not tiles.transposed, interpret=interpret)
+
+
+def _gmm_d_weights(tiles, interpret, rows, weights, group_sizes, g):
+    """The cotangent of :func:`_gmm`'s ``weights`` under ``g``."""
+    if tiles is None:
+        (d_weights,) = jax.vjp(
+            lambda w: _ragged_dot(rows, w, group_sizes), weights)[1](
+                _zero_beyond(g, group_sizes))
+        return d_weights
+    _, tgmm = _megablox()
+    with jax.named_scope(GMM_NAME):
+        # [E, K, F] is rows^T g; read as stored it is [E, F, K], g^T rows
+        # swapped back: the array the update reads beside the weights
+        # and their moments, in their layout
+        lhs, rhs = (g, rows) if tiles.transposed else (rows, g)
+        d_weights = tgmm(lhs.swapaxes(0, 1), rhs, group_sizes, rows.dtype,
+                         tiles.weight_grad, interpret=interpret)
+        if tiles.transposed:
+            d_weights = jnp.swapaxes(d_weights, -1, -2)
+    return d_weights.astype(weights.dtype)
+
+
+def _gmm_bwd(tiles, interpret, res, g):
+    rows, weights, group_sizes = res
+    return (_gmm_d_rows(tiles, interpret, rows, weights, group_sizes, g),
+            _gmm_d_weights(tiles, interpret, rows, weights, group_sizes, g),
+            None)
 
 
 _gmm.defvjp(_gmm_fwd, _gmm_bwd)
+
+
+def _gmm_tiles(n_rows: int, weights, dtype, interpret: bool):
+    """:func:`_gmm`'s tiles for ``n_rows`` rows of ``dtype`` against
+    ``weights``: the kernels' on a TPU (and under ``interpret``) where they
+    apply, else None."""
+    on_kernels = interpret or jax.default_backend() == "tpu"
+    return _gmm_tile(n_rows, *weights.shape[1:],
+                     jnp.dtype(dtype).itemsize) if on_kernels else None
 
 
 def grouped_matmul(rows: jax.Array, weights: jax.Array,
@@ -338,10 +366,8 @@ def grouped_matmul(rows: jax.Array, weights: jax.Array,
     divides ``N`` or a width is no multiple of 128 lanes,
     ``jax.lax.ragged_dot``, the same function as plain XLA (the pattern of
     ``pallas_attention.attend``; :func:`gmm_path` says which and why)."""
-    on_kernels = interpret or jax.default_backend() == "tpu"
-    tiles = _gmm_tile(rows.shape[0], *weights.shape[1:],
-                      rows.dtype.itemsize) if on_kernels else None
-    return _gmm(rows, weights, group_sizes.astype(jnp.int32), tiles,
+    return _gmm(rows, weights, group_sizes.astype(jnp.int32),
+                _gmm_tiles(rows.shape[0], weights, rows.dtype, interpret),
                 interpret)
 
 
@@ -407,6 +433,150 @@ def _over_held_chunks(held, n_rows: int, body, init):
     chunk = _row_chunk(n_rows)
     return lax.fori_loop(0, (held + chunk - 1) // chunk,
                          lambda i, carry: body(i * chunk, chunk, carry), init)
+
+
+def held_chunks_path(n_rows: int, held: Optional[int]) -> str:
+    """What the expert layer's row-wise passes (:func:`expert_ffn`'s, the
+    combine's backward) run of ``n_rows`` sorted rows of which ``held`` are
+    an expert's here, None for all of them (``chip_smoke.py`` prints it
+    beside :func:`gmm_path`)."""
+    if held is None:
+        return f"one pass over all {n_rows} rows (every row held: no loop)"
+    chunk = _row_chunk(n_rows)
+    return (f"loops over {-(-held // chunk)} of {n_rows // chunk} chunks of "
+            f"{chunk} rows ({held} of {n_rows} rows held)")
+
+
+def rows_held(group_sizes, n_experts: int):
+    """How many of the sorted rows are an expert's here, for
+    :func:`_over_held_chunks`: the groups' sum, a device scalar, where
+    ``group_sizes`` are fewer than the layer's ``n_experts`` (an ``ep``
+    shard's, a ``share``'s); None where they are all of them, so that every
+    row is held."""
+    return None if group_sizes.shape[0] == n_experts else jnp.sum(group_sizes)
+
+
+def _in_held_rows(fn, held, *arrays):
+    """``arrays`` (each ``[N, ..]``) with, in the chunks of rows that hold a
+    row below ``held``, the first of them written over by ``fn`` of those
+    rows of all of them: ``fn(*chunks)`` returns a chunk for each array it
+    replaces, cast to that array's dtype. The arrays are the loop's carry
+    and a chunk is read out of the carry before it is written, so an array
+    nothing else reads is updated where it lies (:func:`_combine_bwd`'s
+    loop, by name)."""
+    def body(start, chunk, carry):
+        new = fn(*(lax.dynamic_slice_in_dim(a, start, chunk) for a in carry))
+        written = tuple(
+            lax.dynamic_update_slice_in_dim(a, piece.astype(a.dtype), start, 0)
+            for a, piece in zip(carry, new))
+        return written + carry[len(written):]
+    return _over_held_chunks(held, arrays[0].shape[0], body, arrays)
+
+
+def _hidden(activation, h1, h3=None):
+    """What an expert hands its way down: ``activation(h1)``, times ``h3``
+    where the expert is gated (``h1`` its gate's product, ``h3`` its up
+    projection's)."""
+    return activation(h1) if h3 is None else activation(h1) * h3
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+def _ffn_held(rows, we1, we3, we2, group_sizes, held, activation, tiles,
+              interpret):
+    """:func:`expert_ffn` where only the sorted rows below ``held`` are an
+    expert's: every row-wise pass between the kernels is a loop over those
+    rows' chunks, forward and backward. ``tiles``: the way up's and the
+    way down's."""
+    return _ffn_held_fwd(rows, we1, we3, we2, group_sizes, held, activation,
+                         tiles, interpret)[0]
+
+
+def _ffn_held_fwd(rows, we1, we3, we2, group_sizes, held, activation, tiles,
+                  interpret):
+    up, down = tiles
+    hs = tuple(_gmm(rows, w, group_sizes, up, interpret)
+               for w in (we1, we3) if w is not None)
+    # the rows from ``held`` on stay what the buffer held: the way down
+    # visits its groups' tiles only, as the kernels that wrote ``hs`` did
+    hidden, *_ = _in_held_rows(lambda _, *hs: (_hidden(activation, *hs),),
+                               held, lax.empty(hs[0].shape, hs[0].dtype), *hs)
+    out = _gmm(hidden, we2, group_sizes, down, interpret)
+    # Of the layer's ``[N, F]`` arrays the kernels' outputs alone are kept:
+    # the backward pass makes the hidden rows and the activation's
+    # derivative again from them, a chunk at a time. Kept behind a barrier:
+    # under ``jax.checkpoint`` (a share's ``gathered``) JAX rounds a kept
+    # value that the forward pass also reads to its own precision
+    # (``reduce_precision``), an identity that XLA:TPU fuses into a fusion
+    # that made the value and runs as a pass over all N rows behind a
+    # kernel that did (tests/test_tpu_compile.py)
+    return out, (rows, we1, we3, we2, group_sizes, held,
+                 lax.optimization_barrier(hs))
+
+
+def _ffn_held_bwd(activation, tiles, interpret, res, g):
+    rows, we1, we3, we2, group_sizes, held, hs = res
+    up, down = tiles
+    d_hidden = _gmm_d_rows(down, interpret, hs[0], we2, group_sizes, g)
+
+    def cotangents(*chunks):
+        # from a chunk of ``hs``: the hidden rows as the forward pass made
+        # them, and the activation's derivative in float32
+        *hs, d_hidden = chunks
+        d_hs = jax.vjp(functools.partial(_hidden, activation),
+                       *(h.astype(jnp.float32) for h in hs))[1](
+                           d_hidden.astype(jnp.float32))
+        return (*d_hs, _hidden(activation, *hs))
+    # each written over what it was made from, which nothing reads after:
+    # the cotangents over ``hs``, the hidden rows over their own cotangent
+    *d_hs, hidden = _in_held_rows(cotangents, held, *hs, d_hidden)
+    d_we2 = _gmm_d_weights(down, interpret, hidden, we2, group_sizes, g)
+    d_rows, d_we1, _ = _gmm_bwd(up, interpret, (rows, we1, group_sizes),
+                                d_hs[0])
+    d_we3 = None
+    if we3 is not None:
+        d_rows3, d_we3, _ = _gmm_bwd(up, interpret, (rows, we3, group_sizes),
+                                     d_hs[1])
+        # the rows' two cotangents summed where the first lies (autodiff's
+        # sum of them is a pass over all N rows)
+        d_rows, _ = _in_held_rows(lambda a, b: (a + b,), held, d_rows,
+                                  d_rows3)
+    return d_rows, d_we1, d_we3, d_we2, None, None
+
+
+_ffn_held.defvjp(_ffn_held_fwd, _ffn_held_bwd)
+
+
+def expert_ffn(rows: jax.Array, we1: jax.Array, we3: Optional[jax.Array],
+               we2: jax.Array, group_sizes: jax.Array, held,
+               activation: Callable, interpret: bool = False) -> jax.Array:
+    """The experts' feed-forward over the sorted ``rows`` ``[N, M]``, an
+    ``expert_fn``'s body: ``activation(rows @ we1[g]) @ we2[g]`` for each
+    group g, the hidden rows times ``rows @ we3[g]`` where the experts are
+    gated (``we3`` not None). The matmuls are :func:`grouped_matmul`'s,
+    ``activation`` is row-wise, ``held`` is :func:`rows_held` of the groups.
+
+    None (every row is an expert's here): the plain expression, one fused
+    pass between the kernels, differentiated by JAX. A device scalar (the
+    rows from ``held`` on are other devices' to compute: seven eighths of
+    them in the cell glm-4.7-flash.s8192): the activation, its backward
+    pass and the sum of the rows' two cotangents each run over the chunks
+    that hold a held row (:func:`_over_held_chunks`), in the same
+    arithmetic, the backward pass in float32 within a chunk, and of the
+    ``[N, F]`` arrays only the kernels' outputs are kept for it. What the
+    result's rows from ``held`` on hold is for no one to read, as
+    :func:`grouped_matmul`'s are."""
+    if held is None:
+        h = grouped_matmul(rows, we1, group_sizes, interpret)
+        if we3 is None:
+            h = activation(h)
+        else:
+            h = activation(h) * grouped_matmul(rows, we3, group_sizes,
+                                               interpret)
+        return grouped_matmul(h, we2, group_sizes, interpret)
+    tiles = tuple(_gmm_tiles(rows.shape[0], w, rows.dtype, interpret)
+                  for w in (we1, we2))
+    return _ffn_held(rows, we1, we3, we2, group_sizes.astype(jnp.int32), held,
+                     activation, tiles, interpret)
 
 
 def _all_but_gathers(prim, *_, **__) -> bool:
@@ -571,7 +741,9 @@ def moe_layer_spmd(x: jax.Array, router_w: jax.Array,
     [N, M]``: rows sorted by local expert, group g the next
     ``group_sizes[g]`` of them; the rows beyond the groups (with ``ep`` > 1,
     the other shards' to compute) are not for this device, and what comes
-    back in their place is not read. ``stat_axes``: the mesh axes the
+    back in their place is not read (:func:`expert_ffn` is such a
+    function's body, told how many rows are by :func:`rows_held` of the
+    groups it is handed). ``stat_axes``: the mesh axes the
     tokens are sharded over, so that the metrics are those of the global
     batch and the same on every layout. ``logits``: the router's float32
     logits ``[G, E]`` where the caller computed them from something other
@@ -627,7 +799,7 @@ def moe_layer_spmd(x: jax.Array, router_w: jax.Array,
             axis=0, dtype=jnp.int32)
         # the sorted rows that are an expert's here are the first ``held``;
         # None where that is every row, which needs no device scalar
-        held = jnp.sum(group_sizes) if n * of > 1 else None
+        held = rows_held(group_sizes, E)
 
     def gathered(x_all, expert_params):
         with jax.named_scope(scopes.MOE_DISPATCH):

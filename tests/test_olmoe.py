@@ -193,16 +193,15 @@ def _bf16_products(rows, by):
     return rows.astype(jnp.bfloat16) * by.astype(jnp.bfloat16)
 
 
-def _drop_one(rows, weights, group_sizes, interpret=False,
-              gmm=moe.grouped_matmul):
-    return gmm(rows, weights, group_sizes).at[0].set(0.0)
+def _drop_one(*args, ffn=moe.expert_ffn):
+    return ffn(*args).at[0].set(0.0)
 
 
 @pytest.mark.parametrize("what, where, wrong", [
     ("router softmax in bfloat16", (moe, "route"), _bf16_softmax),
     ("top-k weights in bfloat16", (moe, "route"), _bf16_weights),
     ("combine in bfloat16", (moe, "_products"), _bf16_products),
-    ("a dropped assignment", (t, "grouped_matmul"), _drop_one),
+    ("a dropped assignment", (t, "expert_ffn"), _drop_one),
 ])
 def test_a_wrong_term_fails(monkeypatch, what, where, wrong):
     """What TOL must not let through: each moves the router's gradient far
@@ -252,8 +251,8 @@ def test_expert_parallel_on_the_kernels_gives_one_device_s_result(
     cfg = dataclasses.replace(CFG, d_ff=d_ff)
     params, batch = _params(cfg), _batch(n_seqs=4)
     loss1, _aux1, grads1 = _program(cfg, params, batch)
-    monkeypatch.setattr(t, "grouped_matmul", functools.partial(
-        moe.grouped_matmul, interpret=True))
+    monkeypatch.setattr(t, "expert_ffn", functools.partial(
+        moe.expert_ffn, interpret=True))
     loss, _aux, grads = _program(cfg, params, batch, axes)
     np.testing.assert_allclose(float(loss), float(loss1), rtol=1e-5)
     shards = int(np.prod(list(axes.values())))

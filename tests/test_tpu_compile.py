@@ -563,7 +563,8 @@ def test_mixed_step_moves_only_the_rows_it_holds(mixed_step):
     array (the parent zeroes the kernels' outputs behind the groups under
     a ``pred[49152]`` mask, five selects a layer), and the combine's
     backward pass is a loop over the held rows' chunks that writes into the
-    rows in place, one a layer."""
+    rows in place, one a layer (the other loop a layer that writes into
+    ``[49152, 2560]`` gathers nothing: ISSUE 44's sum, below)."""
     compiled, shapes, _step_bytes = mixed_step
     text = compiled.as_text()
     layers, width = shapes["layers"], shapes["d_model"]
@@ -624,11 +625,125 @@ def test_mixed_step_moves_only_the_rows_it_holds(mixed_step):
             assert on_chip, "the prefix is gathered from where it lies"
             by_prefix += 1
     assert by_prefix == 2 * layers
+    # (and, since ISSUE 44, the loop that sums the rows' two cotangents)
     in_place = [name for name in loop_bodies
                 if writes_whole_rows(name, "dynamic-update-slice")]
-    assert len(in_place) == layers, in_place
-    for name in in_place:
-        assert [s for s, _ in gather_sources(name)] == [tokens], name
+    assert len(in_place) == 2 * layers, in_place
+    assert sorted([s for s, _ in gather_sources(name)] for name in in_place) \
+        == [[]] * layers + [[tokens]] * layers
+
+
+def _row_array_writers(text, n_rows):
+    """(where, what, the line) of every instruction of a compiled program,
+    outside its fused computations, whose result (a tuple's first element)
+    is a whole ``[n_rows, ..]`` array in memory and that writes it: where
+    is "loop" (a ``while``'s body), "branch" (a ``conditional``'s) or
+    "outside"; what is the opcode, for a custom call its target's name or,
+    of a Pallas call, the kernel's. Not counted: what hands an array on
+    (tuples and their elements, bitcasts, parameters, barriers, the
+    containers themselves) and XLA's own moves of a buffer between HBM and
+    on-chip memory (``copy-start`` / ``copy-done``: the parent's step has
+    them too)."""
+    computations = _computations(text)
+    fused = set(re.findall(r"fusion\(.*calls=%([\w.\-]+)", text))
+    loops = set(re.findall(r"body=%([\w.\-]+)", text))
+    branches = {name for found in re.findall(
+        r"branch_computations=\{([^}]*)\}", text)
+        for name in re.findall(r"%([\w.\-]+)", found)}
+    hands_on = {"get-tuple-element", "tuple", "bitcast", "parameter", "while",
+                "conditional", "opt-barrier", "copy-start", "copy-done"}
+    whole = re.compile(
+        r"\s*(?:ROOT )?%%[\w.\-]+ = (?:\(\w+\[%d,\d+\].*?\)|\w+\[%d,\d+\]\S*) "
+        r"([\w\-]+)\(" % (n_rows, n_rows))
+    found = []
+    for name, lines in computations.items():
+        if name in fused:
+            continue
+        where = ("loop" if name in loops else
+                 "branch" if name in branches else "outside")
+        for line in lines:
+            made = whole.match(line)
+            if not made or made.group(1) in hands_on:
+                continue
+            what = made.group(1)
+            if what == "custom-call":
+                what = re.search(r'custom_call_target="(\w+)"', line).group(1)
+                if what == "tpu_custom_call":
+                    what = moe.GMM_NAME if moe.GMM_NAME in line else line
+            elif what == "fusion":
+                body = "\n".join(computations[re.search(
+                    r"calls=%([\w.\-]+)", line).group(1)])
+                what = ("gather" if " gather(" in body else
+                        "dynamic-update-slice"
+                        if " dynamic-update-slice(" in body else line)
+            found.append((where, what, line))
+    return found
+
+
+def _loops_that_write_rows_in_place(text, n_rows):
+    """ISSUE 44: outside a loop's body and a conditional's branch nothing
+    writes a whole array of the sorted rows but the grouped-matmul kernels
+    and the dispatch's gather out of the tokens (the hidden rows' buffer is
+    allocated, not written); so no ``add`` of the rows' two cotangents, no
+    activation and no ``reduce-precision`` over all the rows is left. The
+    loops that write into such arrays in place (returned: how many) are the
+    activation, its backward pass and, for gated experts, the cotangents'
+    sum, a layer."""
+    writers = _row_array_writers(text, n_rows)
+    outside = {what for where, what, _line in writers if where == "outside"}
+    assert outside <= {moe.GMM_NAME, "gather", "AllocateBuffer"}, outside
+    in_loops = [what for where, what, _line in writers if where == "loop"]
+    assert set(in_loops) == {"dynamic-update-slice"}, set(in_loops)
+    return len(in_loops)
+
+
+def test_mixed_step_runs_the_experts_row_wise_passes_over_held_rows(
+        mixed_step):
+    """On the compiled step of smallthinker-21b-a3b.s8192 (16 of 64 gated
+    experts held): see :func:`_loops_that_write_rows_in_place`. A
+    layer's loops that write in place: the activation, its backward pass
+    (one fusion that writes both cotangents and the hidden rows), the sum
+    of the rows' cotangents, and the combine's backward pass (ISSUE 37)."""
+    compiled, shapes, _step_bytes = mixed_step
+    rows = shapes["seq"] * shapes["experts_per_token"]
+    assert _loops_that_write_rows_in_place(compiled.as_text(), rows) \
+        == 4 * shapes["layers"]
+
+
+def test_a_latent_share_s_expert_layer_runs_row_wise_passes_over_held_rows(
+        v5e, no_compile_cache, monkeypatch):
+    """One expert layer of glm-4.7-flash.s8192 between its dispatch and its
+    combine (``_GMM_LATENT``'s shapes: 32 768 sorted rows of 8192 tokens,
+    8 of 64 gated silu experts of 2048 <-> 1536 held), forward and backward
+    under the layer's checkpoint policy, as ``moe_layer_spmd`` runs
+    ``expert_fn``: the same. The parent's program has here a fusion with
+    five ``bf16[32768,1536]`` outputs, one with two and ``add_any
+    bf16[32768,2048]``."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    ((rows, width), _), (weights, _), _ = _GMM_LATENT
+    k, tokens = 4, rows // 4
+
+    def layer(x, we1, we3, we2, order, inverse, sizes, g):
+        held = moe.rows_held(sizes, 64)
+
+        def gathered(x, we1, we3, we2):
+            sorted_rows = moe._dispatch(x, order, inverse, held, k)
+            return moe.expert_ffn(sorted_rows, we1, we3, we2, sizes, held,
+                                  jax.nn.silu)
+        out, vjp = jax.vjp(jax.checkpoint(
+            gathered, policy=moe._all_but_gathers), x, we1, we3, we2)
+        return out, vjp(g)
+    up, down = weights, (weights[0], weights[2], weights[1])
+    args = [jax.ShapeDtypeStruct(s, d, sharding=v5e) for s, d in [
+        ((tokens, width), jnp.bfloat16), (up, jnp.float32),
+        (up, jnp.float32), (down, jnp.float32), ((rows,), jnp.int32),
+        ((rows,), jnp.int32), ((weights[0],), jnp.int32),
+        ((rows, width), jnp.bfloat16)]]
+    text = jax.jit(layer).lower(*args).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert sum(moe.GMM_NAME in c for c in calls) == 9
+    assert _loops_that_write_rows_in_place(text, rows) == 3
 
 
 @pytest.mark.parametrize("cell", ["gpt-1.3b-widths.s2048",
