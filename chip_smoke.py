@@ -48,7 +48,10 @@ Phases (default, one chip):
            at the hybrid cell's heads against the recurrence one position
            at a time and against the jax.numpy form; ssm_path says the
            kernels' tiles, the chunk count and what the backward pass
-           keeps.
+           keeps. The block's gate and grouped norm (a group's sum through
+           a 0/1 matrix at Precision.HIGHEST, no axis for the groups)
+           against the same with the groups on an axis of their own, result
+           and gradients in float32.
 
 ``--chips 4`` runs only the four-chip phase and what it is compared with:
 BERT-Large dp=4 against one device at 2 x 512 tokens a chip (the block
@@ -759,6 +762,45 @@ def _check_gmm(smoke: Smoke) -> None:
               f"{name}: d_rows beyond the groups not zero")
 
 
+#: float32 sums in another order; a product that ran at one bfloat16 pass
+#: on the MXU would read 4e-3
+NORM_TOL = 1e-5
+
+
+def _check_gated_norm(smoke: Smoke) -> None:
+    """models/mamba.py:_gated_norm on the device against the definition with
+    a group's channels on an axis of their own (what XLA:TPU pays copies and
+    broadcasts for), float32 operands: the result and the three gradients.
+    The group sums go through the MXU; the bound holds them to float32."""
+    import jax
+    import jax.numpy as jnp
+    from horovod_tpu.models import mamba
+    S, H, P, G = smoke.sizes.ssm[:4]
+    C, eps = H * P, 1e-5
+    keys = jax.random.split(jax.random.PRNGKey(smoke.seed + 6), 4)
+    y = 3.0 * jax.random.normal(keys[0], (1, S, C), jnp.float32)
+    z = 2.0 * jax.random.normal(keys[1], (1, S, C), jnp.float32)
+    w = 1.0 + 0.1 * jax.random.normal(keys[2], (C,), jnp.float32)
+    ct = jax.random.normal(keys[3], (1, S, C), jnp.float32)
+
+    def by_axis(y, z, w):
+        g = (y * jax.nn.silu(z)).reshape(1, S, G, C // G)
+        g = g * jax.lax.rsqrt(jnp.mean(jnp.square(g), -1, keepdims=True)
+                              + eps)
+        return g.reshape(1, S, C) * w
+
+    def both(norm):
+        out, pull = jax.vjp(norm, y, z, w)
+        return (out,) + pull(ct)
+    got = jax.jit(lambda: both(
+        lambda y, z, w: mamba._gated_norm(y, z, w, G, eps)))()
+    want = jax.jit(lambda: both(by_axis))()
+    for what, g, r in zip(("fwd", "grad d_y", "grad d_z", "grad d_ssm_norm"),
+                          got, want):
+        _kernel_line(smoke, "mamba-2 gate + norm", what, _rel_err(g, r),
+                     NORM_TOL, shape=(S, C, G), ran="xla")
+
+
 def _check_ssm(smoke: Smoke) -> None:
     """The Mamba-2 scan on its kernels (ops/pallas_ssm.py: hvd_ssm_scan,
     hvd_ssm_scan_bwd, through models/mamba.py:ssm_chunked as a Mamba
@@ -1084,6 +1126,7 @@ def phase_kernels(smoke: Smoke, hvd) -> None:
     _check_xent(smoke)
     _check_gmm(smoke)
     _check_ssm(smoke)
+    _check_gated_norm(smoke)
     _check_embed(smoke)
     _check_codec(smoke)
     _check_flagship(smoke, hvd)

@@ -38,6 +38,9 @@ class BlockKind(NamedTuple):
     refuses: Tuple[str, ...] = ()   # mesh axes it does not run on live (with
     #                             "pp": its stack is whole on every device)
     refusal: str = ""           # and why: ``param_shardings``' error
+    gradients_first: bool = False   # the train step finishes every gradient
+    #                             as an array of its own before the
+    #                             optimizer reads any (``make_train_step``)
 
 
 def ones(rng, shape):

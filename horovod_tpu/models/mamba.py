@@ -9,6 +9,14 @@ whole sequence on this device (no sp, pp or tp):
                   steps, every decay and the carried state ``[P, N]`` a head
                   in float32; on a TPU the chunks run in the kernels of
                   ``ops/pallas_ssm.py`` where the shapes fit their tiles
+  gate + norm     ``rmsnorm(y * silu(z)) * ssm_norm`` over each of G groups
+                  of channels, in float32, in ``jax.numpy`` on every backend
+                  (:func:`_gated_norm`): ``[B, S, inner]`` row-major as the
+                  scan writes y and the in-projection z, a group's sum and
+                  its factor's way back products with a 0/1 matrix, so no
+                  array has the groups on an axis of their own; the backward
+                  pass is autodiff's (a checkpointed block keeps its input
+                  and makes the tail again)
 """
 
 from __future__ import annotations
@@ -97,13 +105,25 @@ def _carried_states(whole, states):
 def _gated_norm(y, z, weight, groups: int, eps: float):
     """``rmsnorm(y * silu(z)) * weight`` in float32, the gate before the
     norm and the norm over each of ``groups`` groups of channels. y, z
-    ``[B, S, C]``."""
-    B, S, C = y.shape
-    y = (y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
-         ).reshape(B, S, groups, C // groups)
-    y = y * jax.lax.rsqrt(jnp.mean(jnp.square(y), axis=-1, keepdims=True)
-                          + eps)
-    return y.reshape(B, S, C) * weight.astype(jnp.float32)
+    ``[B, S, C]``.
+
+    A group's sum of squares and the way of its factor back to the group's
+    channels are products with the 0/1 matrix ``[C, groups]`` of which channel
+    is in which group, at ``Precision.HIGHEST`` (float32 to the last bit or
+    two: the matrix is exact in bfloat16), so that every array keeps the
+    shape ``[B, S, C]`` it comes in: a reshape to ``[.., groups, C /
+    groups]`` costs XLA:TPU a copy to a groups-major layout each way and a
+    ``[B, S, groups, C / groups]`` float32 broadcast of the factors in
+    memory (PERF.md section 6, PR 47)."""
+    C = y.shape[-1]
+    member = (jnp.arange(C)[:, None] // (C // groups)
+              == jnp.arange(groups)).astype(jnp.float32)
+    y = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    mean = jnp.einsum("bsc,cg->bsg", jnp.square(y), member,
+                      precision=lax.Precision.HIGHEST) * (groups / C)
+    factor = jnp.einsum("bsg,cg->bsc", lax.rsqrt(mean + eps), member,
+                        precision=lax.Precision.HIGHEST)
+    return y * factor * weight.astype(jnp.float32)
 
 
 def _within_chunks(x, b, c, s, dt):
@@ -206,9 +226,9 @@ def _ssm_chunked_numpy(x, dt, s, b, c, chunk: int):
 
 
 def ssm_path(cfg, seq_len: int) -> str:
-    """How a Mamba block's scan runs at ``seq_len`` positions and what the
-    backward pass keeps of the block (``chip_smoke.py`` prints it, as it
-    does ``attend``'s choice)."""
+    """How a Mamba block's scan and its gate + norm run at ``seq_len``
+    positions and what the backward pass keeps of the block
+    (``chip_smoke.py`` prints it, as it does ``attend``'s choice)."""
     kept = ("each Mamba block checkpointed: its input kept, the block run "
             "again in the backward pass"
             if remat(cfg, KIND.checkpointed) else
@@ -218,7 +238,10 @@ def ssm_path(cfg, seq_len: int) -> str:
         cfg.ssm_state, cfg.ssm_chunk)
     return (f"{how}; chunked scan, {seq_len // cfg.ssm_chunk} chunks of "
             f"{cfg.ssm_chunk}, float32 sums, decays and carried state "
-            f"[{cfg.ssm_heads}, {cfg.ssm_head_dim}, {cfg.ssm_state}]; {kept}")
+            f"[{cfg.ssm_heads}, {cfg.ssm_head_dim}, {cfg.ssm_state}]; gate "
+            f"+ norm in jax.numpy, {cfg.ssm_groups} groups of "
+            f"{cfg.ssm_inner // cfg.ssm_groups} channels summed through a "
+            f"0/1 matrix (no axis for the groups); {kept}")
 
 
 def _mamba_block(p, x, cfg):
@@ -227,7 +250,10 @@ def _mamba_block(p, x, cfg):
     through the causal convolution and silu; ``dt = softplus(dt +
     dt_bias)``, ``a = -exp(a_log)`` a head; the scan (:func:`ssm_chunked`)
     plus the skip ``d x``; ``rmsnorm(y * silu(z))`` over each of the
-    ``ssm_groups`` groups of channels; ``W_out``."""
+    ``ssm_groups`` groups of channels (:func:`_gated_norm`: every array
+    ``[B, S, inner]`` as the scan wrote y, z read where the in-projection
+    wrote it); ``W_out``. Nothing of the tail is kept for the backward pass
+    but what autodiff keeps inside a checkpointed block's second forward."""
     B, S, M = x.shape
     H, P, G, N = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state
     inner, wide = cfg.ssm_inner, cfg.ssm_conv_width
@@ -267,11 +293,15 @@ def _validate(cfg) -> None:
 
 #: the row of ``transformer._BLOCK_KINDS``. The single pass checkpoints the
 #: block: its float32 chunk states, decays and gate keep 1.2 GB at 8192
-#: positions (PERF.md section 6, PR 39)
+#: positions (PERF.md section 6, PR 39). The train step finishes its
+#: gradients before the optimizer reads them: with this block's gate + norm
+#: XLA:TPU's update fused with the weight gradients reads VMEM out of range
+#: on the chip (PERF.md section 6, PR 47)
 KIND = BlockKind(
     length=1, leaves=_leaves,
     apply=lambda p, x, positions, cfg, kind: (_mamba_block(p, x, cfg), None),
-    validate=_validate, checkpointed=True, refuses=("sp", "pp", "tp"),
+    validate=_validate, checkpointed=True, gradients_first=True,
+    refuses=("sp", "pp", "tp"),
     refusal="the convolution and the scan's carried state run over the "
             "whole sequence on one device (no hand-over between sp shards), "
             "its heads and groups are not split over tp, and no pipeline "

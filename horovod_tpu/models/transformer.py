@@ -1240,12 +1240,24 @@ def make_train_step(cfg: TransformerConfig, mesh: Mesh, optimizer,
 
     ``params``/``opt_state`` buffers are DONATED (in-place update on
     device): keep only the returned state — the inputs are invalidated
-    after the call on TPU."""
+    after the call on TPU.
+
+    Where a block kind's row says ``gradients_first`` (a stack with a Mamba
+    block) every gradient is finished as an array of its own before the
+    optimizer reads any; elsewhere XLA is free to fuse a weight's gradient
+    into its update."""
     import optax
     grad_fn = make_grad_fn(cfg, mesh)
 
+    gradients_first = any(_row(kind).gradients_first
+                          for kind in cfg.lead_pattern + cfg.layer_pattern)
+
     def one_step(params, opt_state, tokens, targets):
         loss, aux, grads = grad_fn(params, tokens, targets)
+        if gradients_first:
+            # XLA:TPU otherwise fuses weight gradients into the update;
+            # the kind's row says why this stack does without
+            grads = lax.optimization_barrier(grads)
         with jax.named_scope(scopes.OPTIMIZER):
             updates, opt_state = optimizer.update(grads, opt_state, params)
             params = optax.apply_updates(params, updates)

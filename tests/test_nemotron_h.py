@@ -369,6 +369,54 @@ def _no_carried_state(whole, states):
     return jnp.zeros_like(states)
 
 
+def _norm_by_reshape(y, z, weight, groups, eps):
+    """The gate and the grouped norm as the definition reads: a group's
+    channels on an axis of their own."""
+    B, S, C = y.shape
+    y = (y * jax.nn.silu(z)).reshape(B, S, groups, C // groups)
+    y = y * jax.lax.rsqrt(jnp.mean(jnp.square(y), -1, keepdims=True) + eps)
+    return y.reshape(B, S, C) * weight
+
+
+@pytest.mark.parametrize("what", ["the result", "y", "z", "the weight"])
+@pytest.mark.parametrize("shape", [
+    (1, 24, 4096, 8), (2, 16, 384, 3), (1, 8, 64, 1), (3, 5, 40, 40),
+], ids=lambda s: "x".join(map(str, s)))
+def test_the_grouped_norm_needs_no_axis_for_the_groups(shape, what):
+    """``_gated_norm`` keeps ``[B, S, C]`` and sums a group through a 0/1
+    matrix; result and gradients are the definition's in float32 (the cell's
+    8 groups of 512, a width no lane tile divides, one group, a channel a
+    group)."""
+    B, S, C, groups = shape
+    rng = np.random.RandomState(C + groups)
+    y, z, seen = (jnp.asarray(rng.randn(B, S, C), jnp.float32) * scale
+                  for scale in (3.0, 2.0, 1.0))
+    weight = jnp.asarray(1 + 0.1 * rng.randn(C), jnp.float32)
+
+    def run(norm):
+        out, pull = jax.vjp(
+            lambda y, z, w: norm(y, z, w, groups, 1e-5), y, z, weight)
+        return dict(zip(["the result", "y", "z", "the weight"],
+                        (out,) + pull(seen)))
+    got, want = run(mamba._gated_norm)[what], run(_norm_by_reshape)[what]
+    assert got.shape == want.shape and got.dtype == jnp.float32
+    assert float(jnp.max(jnp.abs(got - want))) \
+        <= 1e-5 * float(jnp.max(jnp.abs(want))), (shape, what)
+
+
+def test_the_grouped_norm_takes_the_compute_dtype_s_operands():
+    """bfloat16 y and z are raised before the gate: the same numbers as
+    float32 copies of them."""
+    rng = np.random.RandomState(0)
+    y, z = (jnp.asarray(rng.randn(1, 16, 256), jnp.bfloat16) for _ in "yz")
+    weight = jnp.ones((256,), jnp.bfloat16)
+    got = mamba._gated_norm(y, z, weight, 2, 1e-5)
+    want = mamba._gated_norm(y.astype(jnp.float32), z.astype(jnp.float32),
+                             weight.astype(jnp.float32), 2, 1e-5)
+    assert got.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 def _gate_after_the_norm(y, z, weight, groups, eps):
     B, S, C = y.shape
     y = y.reshape(B, S, groups, C // groups)
@@ -669,3 +717,32 @@ def test_a_mamba_block_is_checkpointed_where_nothing_says_otherwise():
     for (path, x), y in zip(jax.tree_util.tree_leaves_with_path(a),
                             jax.tree_util.tree_leaves(b)):
         assert _rel(x, y) < 1e-5, path
+
+
+@pytest.mark.parametrize("pattern, barriers", [("ME*", 1), ("E*", 0)])
+def test_a_stack_with_a_mamba_block_finishes_its_gradients_before_the_update(
+        pattern, barriers):
+    """``make_train_step`` puts one ``optimization_barrier`` over the whole
+    tree of gradients between the backward pass and the optimizer where a
+    block kind's row says ``gradients_first`` (the Mamba row: ISSUE 47), and
+    none where no block says so."""
+    import optax
+    cfg = dataclasses.replace(SMALL, n_layers=len(pattern), remat=False,
+                              layer_pattern=tuple(
+                                  adapter.KINDS[c] for c in pattern))
+    mesh = build_mesh(devices=jax.devices()[:1], dp=1)
+    params = shard_params(_params(cfg), cfg, mesh)
+    tx = optax.adamw(1e-3)
+    batch = _batch()
+    tok, tgt = shard_batch(batch["tokens"], batch["targets"], mesh)
+    step = jax.make_jaxpr(t.make_train_step(cfg, mesh, tx))(
+        params, t.init_opt_state(tx, params, mesh), tok, tgt)
+    leaves = len(jax.tree_util.tree_leaves(params))
+
+    def over_the_tree(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "optimization_barrier":
+                yield len(eqn.outvars) == leaves
+            for inner in jax.core.jaxprs_in_params(eqn.params):
+                yield from over_the_tree(inner)
+    assert sum(over_the_tree(step.jaxpr)) == barriers, pattern

@@ -231,6 +231,7 @@ def test_the_kernels_run_on_a_tpu_where_the_tiles_fit(monkeypatch):
     assert ps.FWD_NAME in said and ps.BWD_NAME in said
     assert "64 chunks" in said and "4 lane tiles of 2 heads" in said
     assert "128x512" in said and "checkpointed" in said
+    assert "gate + norm in jax.numpy, 8 groups of 512 channels" in said
     assert _calls_a_kernel((1, 256, 64, 64, 8, 128, 128))
 
 

@@ -771,40 +771,51 @@ def test_the_block_s_new_fields_leave_the_flagship_cells_alone(
 
 # -- the Mamba-2 scan on its kernels (ISSUE 40) -------------------------------
 
-def test_mamba_block_keeps_a_chunk_s_inside_on_the_chip(v5e, no_compile_cache,
-                                                        monkeypatch):
+_BLOCK = dict(S=8192, H=64, P=64, G=8, N=128, Q=128, M=2688)
+
+
+@pytest.fixture(scope="module")
+def mamba_block_text(v5e):
     """A checkpointed Mamba block of the cell nemotron-3-nano-30b-a3b.s8192,
-    forward and backward: the forward kernel twice (the block runs again in
-    the backward pass) and the backward kernel once, under ``hvd.ssm.scan``;
-    of what the ``jax.numpy`` form keeps in memory only the states the
-    chunks start from are left, an output of the forward kernel (under
-    differentiation it writes them both times; the first copy is read by
-    nothing) that the backward kernel reads with no copy between: no ``[..,
-    128, 128]`` float32 array (scores, decays, weights) and no other array
-    of 64 chunks' states."""
+    forward and backward, compiled once for a described v5e: its text."""
+    import numpy as np
+    S, H, P, G, N, Q, M = _BLOCK.values()
+    with _compile_cache_off(), pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        cfg = transformer.TransformerConfig(
+            d_model=M, n_heads=32, n_layers=1, layer_pattern=(("mamba",),),
+            ssm_heads=H, ssm_head_dim=P, ssm_state=N, ssm_groups=G,
+            ssm_chunk=Q, dtype=jnp.bfloat16)
+        assert pallas_ssm.FWD_NAME in mamba.ssm_path(cfg, S)
+        leaves = jax.eval_shape(lambda: jax.tree_util.tree_map(
+            lambda v: jnp.asarray(v[0, 0]), transformer.init_params(
+                np.random.RandomState(0), cfg, 1)["layers"]["mamba"]))
+        params = jax.tree_util.tree_map(
+            lambda v: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=v5e),
+            leaves)
+        h = jax.ShapeDtypeStruct((1, S, M), jnp.bfloat16, sharding=v5e)
+
+        def loss(p, h):
+            block = jax.checkpoint(
+                lambda p, h: mamba._mamba_block(p, h, cfg))
+            return _sum32(jnp.square(block(p, h)))
+        return jax.jit(jax.grad(loss, (0, 1))).lower(params, h).compile(
+            ).as_text()
+
+
+def test_mamba_block_keeps_a_chunk_s_inside_on_the_chip(mamba_block_text):
+    """The forward kernel twice (the block runs again in the backward pass)
+    and the backward kernel once, under ``hvd.ssm.scan``; of what the
+    ``jax.numpy`` form keeps in memory only the states the chunks start
+    from are left, an output of the forward kernel (under differentiation
+    it writes them both times; the first copy is read by nothing) that the
+    backward kernel reads with no copy between: no ``[.., 128, 128]``
+    float32 array (scores, decays, weights) and no other array of 64
+    chunks' states."""
     import numpy as np
     from horovod_tpu.profiling import scopes
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    S, H, P, G, N, Q, M = 8192, 64, 64, 8, 128, 128, 2688
-    cfg = transformer.TransformerConfig(
-        d_model=M, n_heads=32, n_layers=1, layer_pattern=(("mamba",),),
-        ssm_heads=H, ssm_head_dim=P, ssm_state=N, ssm_groups=G, ssm_chunk=Q,
-        dtype=jnp.bfloat16)
-    assert pallas_ssm.FWD_NAME in mamba.ssm_path(cfg, S)
-    leaves = jax.eval_shape(lambda: jax.tree_util.tree_map(
-        lambda v: jnp.asarray(v[0, 0]), transformer.init_params(
-            np.random.RandomState(0), cfg, 1)["layers"]["mamba"]))
-    params = jax.tree_util.tree_map(
-        lambda v: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=v5e),
-        leaves)
-    h = jax.ShapeDtypeStruct((1, S, M), jnp.bfloat16, sharding=v5e)
-
-    def loss(p, h):
-        block = jax.checkpoint(
-            lambda p, h: mamba._mamba_block(p, h, cfg))
-        return _sum32(jnp.square(block(p, h)))
-    text = jax.jit(jax.grad(loss, (0, 1))).lower(params, h).compile(
-        ).as_text()
+    S, H, P, G, N, Q, _M = _BLOCK.values()
+    text = mamba_block_text
     calls = [line for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     backward = [c for c in calls if pallas_ssm.BWD_NAME + "/" in c]
@@ -824,6 +835,25 @@ def test_mamba_block_keeps_a_chunk_s_inside_on_the_chip(v5e, no_compile_cache,
             assert dims[-2:] != [Q, Q], line
             assert not (np.prod(dims) >= S // Q * H * P * N
                         and N in dims[-2:] and S not in dims), line
+
+
+def test_mamba_block_s_norm_leaves_its_groups_where_they_lie(
+        mamba_block_text):
+    """The gate and the grouped norm (ISSUE 47) keep ``[8192, 4096]``
+    row-major as the scan's kernel writes it: no array in memory has the
+    groups on an axis of their own (the factors broadcast as ``f32[8192, 8,
+    512]``, 134 MB each, and the gated product copied to the groups-major
+    ``f32[1024, 8, 8, 512]`` were three each a block), and a group's eight
+    factors a row are made by products with a 0/1 matrix."""
+    S, H, P, G, _N, _Q, _M = _BLOCK.values()
+    in_memory = _arrays_in_memory(mamba_block_text)
+    for dims in (f"[{S},{G},{H * P // G}]", f"[1,{S},{G},{H * P // G}]",
+                 f"[{S // 8},8,{G},{H * P // G}]"):
+        assert "f32" + dims not in in_memory, dims
+    factors = [line for line in in_memory.splitlines()
+               if re.search(rf" = f32\[{S},{G}\]\S* fusion\(", line)]
+    assert factors and all("hvd.ssm.norm/" in line for line in factors), \
+        factors
 
 
 # -- the embedding's gradient (ISSUE 38) --------------------------------------
