@@ -52,6 +52,12 @@ Phases (default, one chip):
            a 0/1 matrix at Precision.HIGHEST, no axis for the groups)
            against the same with the groups on an axis of their own, result
            and gradients in float32.
+           The kernels at the dense hybrid cell's shapes
+           (granite-4.0-h-micro.s4096): the scan at ONE group of 64 heads
+           and chunk 256 in head tiles, the flash pair at 32 / 8 heads of 64
+           (heads first) with the scores times 1/64, each against its
+           jax.numpy form; ssm_scan_path and attention_path at the cell's
+           shapes must name the kernels.
 
 ``--chips 4`` runs only the four-chip phase and what it is compared with:
 BERT-Large dp=4 against one device at 2 x 512 tokens a chip (the block
@@ -148,6 +154,10 @@ class Sizes:
     embed: tuple          # embedding checks, each (vocab, width, tokens)
     ssm: tuple            # Mamba-2 scan check (S, heads, head width, groups,
     #                       state, chunk)
+    dense_ssm: tuple      # the same at ONE group in head tiles
+    narrow: tuple         # flash check at a head of 64 with grouped heads
+    #                       and a scale of its own: (B, S, H, k/v heads, D,
+    #                       window, scale)
     gpt: dict             # flagship TransformerConfig fields
     gpt_batch: int
     ring: tuple           # four-chip ring attention [B, S, H, D]
@@ -176,6 +186,12 @@ REAL = Sizes(
     embed=((37984, 2560, 8192), (50304, 2048, 8192)),
     # nemotron-3-nano-30b-a3b.s8192's heads, an eighth of its length
     ssm=(1024, 64, 64, 8, 128, 128),
+    # granite-4.0-h-micro.s4096's heads in ONE group at chunk 256, a quarter
+    # of its length; and its attention block whole: 32 / 8 heads of 64 over
+    # 4096 keys, scores times 1/64 (the float32 reference's scores are 2.1
+    # GB)
+    dense_ssm=(1024, 64, 64, 1, 128, 256),
+    narrow=(1, 4096, 32, 8, 64, None, 1 / 64),
     # depth cut to 4 layers: this phase checks kernels in place, not a model
     gpt=dict(vocab_size=32000, d_model=1024, n_heads=8, n_layers=4,
              d_ff=4096, max_seq=2048),
@@ -191,6 +207,7 @@ TINY = Sizes(
     gmm=((256, 128, 128, 4, 192), (256, 128, 192, 4, 192)),
     embed=((64, 2560, 48),),
     ssm=(64, 4, 8, 2, 16, 16),
+    dense_ssm=(64, 8, 8, 1, 16, 16), narrow=(1, 256, 4, 1, 64, None, 1 / 64),
     gpt=dict(vocab_size=1000, d_model=256, n_heads=2, n_layers=2,
              d_ff=256, max_seq=256),
     gpt_batch=2, ring=(1, 512, 2, 128))
@@ -509,17 +526,18 @@ def _latent_cell(smoke: Smoke) -> dict:
             else "kept" for kind in kinds})
 
 
-def _check_banded(smoke: Smoke) -> None:
+def _check_banded(smoke: Smoke, sizes=None, what: str = "banded") -> None:
     """The flash kernels with a window and grouped heads against the XLA
     form of the same function at "highest": the band's edge in both index
     maps and masks, a k/v head read by its group, dk and dv summed over
-    it."""
+    it. ``sizes``: another shape than ``Sizes.banded``, with the scores'
+    multiplier last (``Sizes.narrow``: a head of 64, heads first)."""
     import jax
     import jax.numpy as jnp
     from horovod_tpu.ops.pallas_attention import (_banded_attention,
                                                   flash_attention_tpu)
 
-    B, S, H, Hkv, D, window = smoke.sizes.banded
+    B, S, H, Hkv, D, window, scale = sizes or smoke.sizes.banded + (None,)
     keys = jax.random.split(jax.random.PRNGKey(smoke.seed + 7), 4)
     q = jax.random.normal(keys[0], (B, S, H, D), jnp.bfloat16)
     k, v = (jax.random.normal(kk, (B, S, Hkv, D), jnp.bfloat16)
@@ -527,15 +545,15 @@ def _check_banded(smoke: Smoke) -> None:
     w = jax.random.normal(keys[3], (B, S, H, D), jnp.float32)
 
     def kernel(q, k, v):
-        return flash_attention_tpu(q, k, v, True, interpret=smoke.rehearsal,
-                                   window=window)
+        return flash_attention_tpu(q, k, v, True, scale,
+                                   interpret=smoke.rehearsal, window=window)
 
     def reference(q, k, v):
         with jax.default_matmul_precision("highest"):
-            return _banded_attention(q, k, v, window)
+            return _banded_attention(q, k, v, window, scale)
 
     got = _run_compiled(smoke, kernel, (q, k, v), "hvd_flash_attention")
-    _kernel_line(smoke, "flash_attention", "banded fwd",
+    _kernel_line(smoke, "flash_attention", f"{what} fwd",
                  _rel_err(got, jax.jit(reference)(q, k, v)), FLASH_TOL,
                  shape=(B, S, H, D), dtype="bfloat16",
                  attention_path=_attention_path((B, S, H, D), kv_heads=Hkv,
@@ -544,7 +562,7 @@ def _check_banded(smoke: Smoke) -> None:
                         (q, k, v), "hvd_flash_bwd")
     want = jax.jit(jax.grad(_weighted_sum(reference, w), (0, 1, 2)))(q, k, v)
     for name, g, r in zip(("dq", "dk", "dv"), got, want):
-        _kernel_line(smoke, "flash_attention", f"banded grad {name}",
+        _kernel_line(smoke, "flash_attention", f"{what} grad {name}",
                      _rel_err(g, r), FLASH_TOL)
 
 
@@ -767,6 +785,30 @@ def _check_gmm(smoke: Smoke) -> None:
 NORM_TOL = 1e-5
 
 
+def _check_dense_hybrid(smoke: Smoke) -> None:
+    """The kernels at the cell ``granite-4.0-h-micro.s4096``'s shapes, each
+    alone against its ``jax.numpy`` form: the scan at ONE group of 64 heads
+    and chunk 256 in head tiles, the flash pair at 32 / 8 heads of 64 with
+    the scores times 1/64; ``ssm_path`` and ``attention_path`` say what
+    runs, and on the chip neither may say ``jax.numpy`` or ``xla``."""
+    import jax.numpy as jnp
+    from horovod_tpu.ops import pallas_ssm
+    z = smoke.sizes
+    _check_ssm(smoke, z.dense_ssm, "dense_hybrid", 4 * z.dense_ssm[0])
+    _check_banded(smoke, z.narrow, "a head of 64")
+    B, S, H, Hkv, D = z.narrow[:5]
+    path = _attention_path((B, S, H, D), kv_heads=Hkv)
+    scan = pallas_ssm.ssm_scan_path(4 * z.dense_ssm[0], *z.dense_ssm[1:])
+    if smoke.on_chip:
+        check(path.startswith("pallas hvd_flash_attention ")
+              and scan.startswith("kernels hvd_ssm_scan "), f"{path}; {scan}")
+    smoke.emit("kernels", cell="granite-4.0-h-micro.s4096",
+               attention_path_at_the_dense_hybrid_cell=path,
+               ssm_scan_path_at_the_dense_hybrid_cell=scan,
+               head_tile=pallas_ssm.ssm_head_tile(
+                   *z.dense_ssm[1:], jnp.dtype(jnp.bfloat16).itemsize))
+
+
 def _check_gated_norm(smoke: Smoke) -> None:
     """models/mamba.py:_gated_norm on the device against the definition with
     a group's channels on an axis of their own (what XLA:TPU pays copies and
@@ -801,7 +843,8 @@ def _check_gated_norm(smoke: Smoke) -> None:
                      NORM_TOL, shape=(S, C, G), ran="xla")
 
 
-def _check_ssm(smoke: Smoke) -> None:
+def _check_ssm(smoke: Smoke, sizes=None, cell: str = "hybrid",
+               length: int = 8192) -> None:
     """The Mamba-2 scan on its kernels (ops/pallas_ssm.py: hvd_ssm_scan,
     hvd_ssm_scan_bwd, through models/mamba.py:ssm_chunked as a Mamba
     block calls it), bfloat16 operands with float32 time steps, sums, decays
@@ -809,14 +852,16 @@ def _check_ssm(smoke: Smoke) -> None:
     float32, forward and every operand's gradient, at the hybrid cell's
     heads; and against the ``jax.numpy`` form on the same operands. Prints
     ``ssm_path`` at the cell's length: the kernels' tiles, the chunk count
-    and what the backward pass keeps of a block."""
+    and what the backward pass keeps of a block. ``sizes``, ``cell``,
+    ``length``: another cell's heads than ``Sizes.ssm`` (``Sizes.dense_ssm``:
+    ONE group, whose heads go in head tiles)."""
     import jax
     import jax.numpy as jnp
     from horovod_tpu.models import mamba
     from horovod_tpu.models.mamba import ssm_chunked, ssm_path
     from horovod_tpu.models.transformer import TransformerConfig
     from horovod_tpu.ops import pallas_ssm
-    S, H, P, G, N, chunk = smoke.sizes.ssm
+    S, H, P, G, N, chunk = sizes or smoke.sizes.ssm
     keys = jax.random.split(jax.random.PRNGKey(smoke.seed + 5), 6)
     x = jax.random.normal(keys[0], (1, S, H, P), jnp.bfloat16)
     b, c = (jax.random.normal(k, (1, S, G, N), jnp.bfloat16) / N ** 0.25
@@ -856,7 +901,7 @@ def _check_ssm(smoke: Smoke) -> None:
                  shape=(S, H, P, G, N),
                  against_the_numpy_form=_rel_err(
                      got, jax.jit(numpy_form)(*ops)),
-                 ssm_path_at_the_hybrid_cell=ssm_path(cfg, 8 * S))
+                 **{f"ssm_path_at_the_{cell}_cell": ssm_path(cfg, length)})
     leaves = (0, 1, 2, 3, 4)
     got = _run_compiled(smoke, jax.grad(loss(kernels), leaves), ops,
                         pallas_ssm.BWD_NAME)
@@ -1097,10 +1142,11 @@ def _check_flagship(smoke: Smoke, hvd) -> None:
         losses.append(float(loss))
     check(all(math.isfinite(x) for x in losses), f"non-finite: {losses}")
 
-    # the same d_model over twice the heads (1024 / 16 heads,
-    # head_dim 64): a causal shape the kernels leave to XLA
+    # the same d_model over four times the heads (1024 / 32 heads,
+    # head_dim 32): a causal shape the kernels leave to XLA (a head of 64
+    # they take: _check_banded at Sizes.narrow)
     shapes = ((B, S, cfg.n_heads, cfg.head_dim),
-              (B, S, 2 * cfg.n_heads, cfg.head_dim // 2))
+              (B, S, 4 * cfg.n_heads, cfg.head_dim // 4))
     paths = {f"{s[2]} heads x head_dim {s[3]}": _attention_path(s)
              for s in shapes}
     if smoke.on_chip:
@@ -1126,6 +1172,7 @@ def phase_kernels(smoke: Smoke, hvd) -> None:
     _check_xent(smoke)
     _check_gmm(smoke)
     _check_ssm(smoke)
+    _check_dense_hybrid(smoke)
     _check_gated_norm(smoke)
     _check_embed(smoke)
     _check_codec(smoke)

@@ -66,6 +66,17 @@ def remat(cfg, needed: bool) -> bool:
     return needed if cfg.remat is None else cfg.remat
 
 
+def scaled(x, factor: float):
+    """``x * factor`` rounded once to ``x.dtype``, the factor in float32
+    (0.22 as a bfloat16 is 0.2197: every sublayer 0.12 % short, which the
+    chip's check read as a loss 9e-5 off on every seed, PERF.md section 6,
+    PR 49); ``x`` itself where the factor is 1 (a config that leaves a
+    multiplier at its default traces no multiply)."""
+    if factor == 1.0:
+        return x
+    return (x.astype(jnp.float32) * factor).astype(x.dtype)
+
+
 def rope(x, positions, theta=10000.0):
     """Rotary embedding, halves layout; x [B, S, H, D], positions [S]
     absolute."""

@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from horovod_tpu.models._kinds import (BlockKind, Leaf, normal, ones,
-                                       rmsnorm, rope)
+                                       rmsnorm, rope, scaled)
 from horovod_tpu.profiling import scopes
 
 
@@ -114,8 +114,9 @@ def _latent_block(p, x, positions, cfg):
                 q, k, v = _up(p, c_q, c_kv, k_r, positions, cfg)
         with jax.named_scope(scopes.ATTENTION_CORE), \
                 jax.named_scope(scopes.ATTENTION_CORE_FULL):
-            o = attend(q, k, v, causal=True)
-        return x + o.reshape(B, S, -1) @ p["wo"].astype(x.dtype)
+            o = attend(q, k, v, causal=True, scale=cfg.attention_scale)
+        return x + scaled(o.reshape(B, S, -1) @ p["wo"].astype(x.dtype),
+                          cfg.residual_scale)
 
 
 KIND = BlockKind(

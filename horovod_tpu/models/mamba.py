@@ -28,7 +28,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from horovod_tpu.models._kinds import (BlockKind, Leaf, normal, ones, remat,
-                                       rmsnorm)
+                                       rmsnorm, scaled)
 from horovod_tpu.ops import pallas_ssm
 from horovod_tpu.profiling import scopes
 
@@ -114,8 +114,13 @@ def _gated_norm(y, z, weight, groups: int, eps: float):
     shape ``[B, S, C]`` it comes in: a reshape to ``[.., groups, C /
     groups]`` costs XLA:TPU a copy to a groups-major layout each way and a
     ``[B, S, groups, C / groups]`` float32 broadcast of the factors in
-    memory (PERF.md section 6, PR 47)."""
+    memory (PERF.md section 6, PR 47). One group needs no matrix."""
     C = y.shape[-1]
+    if groups == 1:
+        # one group: a row's mean is a sum along its own lanes
+        y = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+        mean = jnp.mean(jnp.square(y), axis=-1, keepdims=True)
+        return y * lax.rsqrt(mean + eps) * weight.astype(jnp.float32)
     member = (jnp.arange(C)[:, None] // (C // groups)
               == jnp.arange(groups)).astype(jnp.float32)
     y = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
@@ -236,12 +241,14 @@ def ssm_path(cfg, seq_len: int) -> str:
     how = pallas_ssm.ssm_scan_path(
         seq_len, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups,
         cfg.ssm_state, cfg.ssm_chunk)
+    norm = (f"{cfg.ssm_groups} groups of "
+            f"{cfg.ssm_inner // cfg.ssm_groups} channels summed through a "
+            "0/1 matrix (no axis for the groups)" if cfg.ssm_groups > 1 else
+            f"one group of {cfg.ssm_inner} channels, a row's own mean")
     return (f"{how}; chunked scan, {seq_len // cfg.ssm_chunk} chunks of "
             f"{cfg.ssm_chunk}, float32 sums, decays and carried state "
             f"[{cfg.ssm_heads}, {cfg.ssm_head_dim}, {cfg.ssm_state}]; gate "
-            f"+ norm in jax.numpy, {cfg.ssm_groups} groups of "
-            f"{cfg.ssm_inner // cfg.ssm_groups} channels summed through a "
-            f"0/1 matrix (no axis for the groups); {kept}")
+            f"+ norm in jax.numpy, {norm}; {kept}")
 
 
 def _mamba_block(p, x, cfg):
@@ -281,7 +288,7 @@ def _mamba_block(p, x, cfg):
                             cfg.norm_eps).astype(h.dtype)
         with jax.named_scope(scopes.SSM_PROJ):
             o = y @ p["ssm_out"].astype(h.dtype)
-        return x + o
+        return x + scaled(o, cfg.residual_scale)
 
 
 def _validate(cfg) -> None:

@@ -69,7 +69,7 @@ from horovod_tpu._compat import axis_size, shard_map
 
 from horovod_tpu.models import latent, mamba
 from horovod_tpu.models._kinds import (BlockKind, Leaf, normal, ones, remat,
-                                       rmsnorm, rope, zeros)
+                                       rmsnorm, rope, scaled, zeros)
 from horovod_tpu.models.scan_util import multi_step
 from horovod_tpu.parallel.ring_attention import ring_attention_spmd
 from horovod_tpu.parallel.moe import expert_ffn, moe_layer_spmd, rows_held
@@ -189,6 +189,18 @@ class TransformerConfig:
     #                             of we1 / we3 / we2, with no ep axis live;
     #                             the router keeps its n_experts columns and
     #                             the layer's output is those experts' part
+    # -- scalar multipliers (Granite's "power scheduler" parametrisation,
+    # arXiv:2408.13359; at their defaults no multiply is traced) -----------
+    embed_scale: float = 1.0    # the embedding's rows times this, as they
+    #                             enter the residual stream
+    residual_scale: float = 1.0     # every sublayer's output times this,
+    #                             before the residual add: x + c * f(norm(x))
+    attention_scale: Optional[float] = None     # the attention scores'
+    #                             multiplier where it is not 1 / sqrt(head
+    #                             width)
+    logits_scale: float = 1.0   # the logits times this (the final normed
+    #                             state is, before the head: one [tokens, M]
+    #                             multiply, no second array of logits)
     norm_eps: float = 1e-6
     rope_theta: float = 10000.0
     dtype: Any = jnp.bfloat16
@@ -365,9 +377,9 @@ def _embed_lookup(emb_local, tokens, cfg: TransformerConfig):
     anyway: the lookup shares that cast, and its gradient joins the head's
     in the cast's transpose."""
     def take(ids):
-        if cfg.tie_embeddings:
-            return emb_local.astype(cfg.dtype)[ids]
-        return _table_rows(emb_local, ids, cfg.dtype)
+        rows = (emb_local.astype(cfg.dtype)[ids] if cfg.tie_embeddings
+                else _table_rows(emb_local, ids, cfg.dtype))
+        return scaled(rows, cfg.embed_scale)
     Vl, M = emb_local.shape
     if _axis_live("tp"):
         off = lax.axis_index("tp") * Vl
@@ -401,13 +413,16 @@ def _sharded_softmax_xent(logits_local, targets):
     return jnp.log(se) + m - corr     # [B, S]
 
 
-def _head_xent(x, head, targets):
-    """Per-token loss of the head ``x @ head``. A tp-sharded vocabulary
+def _head_xent(x, head, targets, scale: float = 1.0):
+    """Per-token loss of the head ``scale * (x @ head)``, the scale on ``x``
+    (``logits_scale``; a power of two is exact there). A tp-sharded
+    vocabulary
     takes the psum algebra above; a full local one takes the fused Pallas
     kernel, which leaves the logits' gradient in the logits' buffer for
     the two backward matmuls (one HBM pass over ``[N, V]``; falls back
     off-TPU / untiled by ``pallas_xent.xent_path``, the self-gating
     pattern of ``pallas_attention.attend``)."""
+    x = scaled(x, scale)
     if _axis_live("tp"):
         return _sharded_softmax_xent(x @ head, targets)       # [B,S,V/tp]
     from horovod_tpu.ops.pallas_xent import head_softmax_xent
@@ -440,11 +455,13 @@ def _attention_block(p, x, positions, cfg: TransformerConfig,
     B, S, M = x.shape
     window, roped = kind
     grouped = cfg.kv_heads != cfg.n_heads
-    if _axis_live("sp") and (window is not None or grouped):
+    if _axis_live("sp") and (window is not None or grouped
+                             or cfg.attention_scale is not None):
         raise NotImplementedError(
-            "a window (layer_pattern) or grouped heads (n_kv_heads) on a "
-            "live sp axis: ring_attention_spmd passes whole k/v blocks of "
-            "n_heads heads round the ring and masks by the diagonal only")
+            "a window (layer_pattern), grouped heads (n_kv_heads) or "
+            "attention_scale on a live sp axis: ring_attention_spmd passes "
+            "whole k/v blocks of n_heads heads round the ring, masks by the "
+            "diagonal only and scales by 1 / sqrt(head width)")
     with jax.named_scope(scopes.ATTENTION):
         h = rmsnorm(x, p["ln1"], cfg.norm_eps)
         q = (h @ p["wq"].astype(h.dtype))
@@ -475,12 +492,13 @@ def _attention_block(p, x, positions, cfg: TransformerConfig,
                       else jax.named_scope(scopes.ATTENTION_CORE_FULL
                                            if window is None else
                                            scopes.ATTENTION_CORE_WINDOW)):
-                    o = attend(q, k, v, causal=True, window=window)
+                    o = attend(q, k, v, causal=True, window=window,
+                               scale=cfg.attention_scale)
         o = o.reshape(B, S, Hl * cfg.head_dim) @ p["wo"].astype(x.dtype)
         o = _psum_if(o, "tp")
         if cfg.post_norm:
             o = rmsnorm(o, p["ln1_post"], cfg.norm_eps)
-        return x + o
+        return x + scaled(o, cfg.residual_scale)
 
 
 def _routed(cfg: TransformerConfig, routed: Optional[bool]) -> bool:
@@ -643,7 +661,7 @@ def _ffn_block(p, x, cfg: TransformerConfig, logits=None, routed=None):
         o = o.astype(x.dtype)
         if cfg.post_norm:
             o = rmsnorm(o, p["ln2_post"], cfg.norm_eps)
-        return x + o, aux
+        return x + scaled(o, cfg.residual_scale), aux
 
 
 def _attention_then_ffn(p, x, positions, cfg: TransformerConfig, kind):
@@ -883,14 +901,15 @@ def forward_loss_spmd(params, tokens, targets, cfg: TransformerConfig):
             head = params["lm_head"].astype(cfg.dtype)
         if cfg.n_loops > 1:
             # every loop step's state is already through ln_f
-            nll = jnp.stack([_head_xent(x[t], head, targets)
+            nll = jnp.stack([_head_xent(x[t], head, targets,
+                                        cfg.logits_scale)
                              for t in range(cfg.n_loops)])      # [T,B,S]
             loss, exits = _looped_loss(_exit_gate(params, x), nll)
             aux_total = {**aux_total, **exits}
         else:
             state = x       # what a prediction module reads: before ln_f
             x = rmsnorm(x, params["ln_f"], cfg.norm_eps)
-            nll = _head_xent(x, head, targets)                  # [B,S]
+            nll = _head_xent(x, head, targets, cfg.logits_scale)    # [B,S]
             loss = jnp.mean(nll)
     if beside:
         parts = {}
@@ -948,7 +967,7 @@ def _mtp_loss(params, state, targets, head, positions, cfg):
                                 functools.partial(_checkpointed, cfg))
     with jax.named_scope(scopes.HEAD):
         z = rmsnorm(z, mp["ln_f"], cfg.norm_eps)
-        nll = _head_xent(z, head, _mtp_targets(targets))
+        nll = _head_xent(z, head, _mtp_targets(targets), cfg.logits_scale)
         return jnp.mean(nll[:, :-1]), auxs
 
 

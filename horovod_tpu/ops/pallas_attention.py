@@ -19,7 +19,8 @@ all overhead: at B2·S2048·H16·D128 causal a call takes 2.59 ms with
 128 × 128 tiles and 0.48 ms with 1024 × 1024 (v5e; PERF.md, PR 25).
 Callers pass no block; ``block_q`` / ``block_k`` are overrides for
 tests. The contract to callers is only ``MIN_BLOCK``: sequence lengths
-and head_dim are multiples of 128.
+and head_dim are multiples of 128, or the head is :data:`NARROW_HEAD` = 64
+wide (below).
 
 **Dtypes.** q·kᵀ and p·v multiply operands in the dtype the caller passed
 (bf16 in training: what the MXU multiplies; float32 inputs give float32
@@ -70,6 +71,16 @@ operands multiply as they come and accumulate in float32, ``pT`` and
 read as ``[B, S, H·D]``, a head whole 128-lane columns, and dq, dk, dv
 written so: no transpose around the call.
 
+**A head of 64** (half a lane tile) runs both kernels heads-first, ``[B·H,
+S, 64]``: a ``(1, tile, 64)`` block spans the array's whole last dimension,
+which Mosaic takes where it refuses 64 of ``H·64`` lanes; the backward's
+operands are transposed around its call as the forward's always are (at 32
+/ 8 heads over 4096 positions 16 MB a q-sized array). The MXU contracts
+over 64 of its 128 rows for the scores and fills 64 of its columns for the
+outputs: half empty either way, as two heads side by side on one lane tile
+with the other's lanes zeroed would leave it (``ops/pallas_ssm.py``), and
+the ``[bq, bk]`` vector work, which bounds a tile, is the same.
+
 Falls back to the pure-XLA implementation on CPU or when shapes don't meet
 TPU tiling constraints (last dim 128-multiple, 128-divisible sequence).
 
@@ -97,6 +108,8 @@ NEG_INF = -1e30
 #: what callers are held to: Sq, Sk and head_dim are multiples of this
 #: (the TPU's lane count; the smallest tile)
 MIN_BLOCK = 128
+#: the one head width below a lane tile that the flash kernels take
+NARROW_HEAD = 64
 TILES = (1024, 512, 256, MIN_BLOCK)
 #: bytes the forward's working set may take by ``flash_vmem_bytes``: the
 #: v5e's default scoped-VMEM limit (16 MiB of 128), so no limit is asked
@@ -592,19 +605,27 @@ def _flash_bwd_kernel(q_ref, do_ref, k_ref, v_ref, lse_ref, adj_ref,
 # branches, layers outside a scan) share one trace and one Mosaic lowering
 @functools.partial(jax.jit, static_argnames=("H", "causal", "scale",
                                              "blocks", "interpret",
-                                             "window"))
+                                             "window", "heads_first"))
 def _flash_bwd_local(q, k, v, do, lse, adj, *, H, causal, scale, blocks,
-                     interpret, window=None):
+                     interpret, window=None, heads_first=False):
     """(dq ``[B, Sq, H*D]``, dk and dv ``[ranges, B, Sk, H*D]``) of q, do
     ``[B, Sq, H*D]`` and k, v ``[B, Sk, Hkv*D]`` as the projections wrote
     them: a head is whole 128-lane columns (one at head_dim 128), so a
     ``(1, tile, D)`` block addresses it with no transpose around the call.
     lse and adj are ``[B*H, 1, Sq]`` float32, a q row along the lanes. dk
     and dv come a q head: with grouped heads a k/v head's are the sum over
-    its group, the caller's to take."""
-    B, Sq, M = q.shape
-    Sk, D = k.shape[1], M // H
-    group = _group(H, k.shape[2] // D)
+    its group, the caller's to take. ``heads_first`` (a head of 64): q, do
+    and dq ``[B*H, Sq, D]``, k and v ``[B*Hkv, Sk, D]``, dk and dv
+    ``[ranges, B*H, Sk, D]``."""
+    if heads_first:
+        (BH, Sq, D), Sk = q.shape, k.shape[1]
+        B = BH // H
+        Hkv = k.shape[0] // B
+        group = _group(H, Hkv)
+    else:
+        B, Sq, M = q.shape
+        Sk, D = k.shape[1], M // H
+        group = _group(H, k.shape[2] // D)
     bq, bk, rows = blocks
     grid = flash_bwd_grid(B, H, Sq, Sk, blocks)
     nq = grid[3]
@@ -626,27 +647,38 @@ def _flash_bwd_local(q, k, v, do, lse, adj, *, H, causal, scale, blocks,
     else:
         def q_tile(r, j, i):
             return r * nq + i
-    q_spec = pl.BlockSpec((1, bq, D),
-                          lambda b, r, j, i: (b // H, q_tile(r, j, i), b % H))
-    if group == 1:
-        k_spec = pl.BlockSpec((1, bk, D),
-                              lambda b, r, j, i: (b // H, j, b % H))
-    else:
-        k_spec = pl.BlockSpec(
-            (1, bk, D), lambda b, r, j, i: (b // H, j, b % H // group))
     row_spec = pl.BlockSpec((1, 1, bq),
                             lambda b, r, j, i: (b, 0, q_tile(r, j, i)))
-    part_spec = pl.BlockSpec((1, 1, bk, D),
-                             lambda b, r, j, i: (r, b // H, j, b % H))
-    part = jax.ShapeDtypeStruct((grid[1], B, Sk, M), k.dtype)
+    if heads_first:
+        q_spec = pl.BlockSpec((1, bq, D),
+                              lambda b, r, j, i: (b, q_tile(r, j, i), 0))
+        k_spec = pl.BlockSpec(
+            (1, bk, D),
+            lambda b, r, j, i: (b // H * Hkv + b % H // group, j, 0))
+        dq_spec = pl.BlockSpec((1, rows, D), lambda b, r, j, i: (b, r, 0))
+        part_spec = pl.BlockSpec((1, 1, bk, D),
+                                 lambda b, r, j, i: (r, b, j, 0))
+        part = jax.ShapeDtypeStruct((grid[1], B * H, Sk, D), k.dtype)
+    else:
+        q_spec = pl.BlockSpec(
+            (1, bq, D), lambda b, r, j, i: (b // H, q_tile(r, j, i), b % H))
+        if group == 1:
+            k_spec = pl.BlockSpec((1, bk, D),
+                                  lambda b, r, j, i: (b // H, j, b % H))
+        else:
+            k_spec = pl.BlockSpec(
+                (1, bk, D), lambda b, r, j, i: (b // H, j, b % H // group))
+        dq_spec = pl.BlockSpec((1, rows, D),
+                               lambda b, r, j, i: (b // H, r, b % H))
+        part_spec = pl.BlockSpec((1, 1, bk, D),
+                                 lambda b, r, j, i: (r, b // H, j, b % H))
+        part = jax.ShapeDtypeStruct((grid[1], B, Sk, M), k.dtype)
     return pl.pallas_call(
         functools.partial(_flash_bwd_kernel, scale=scale, causal=causal,
                           block_q=bq, block_k=bk, window=window),
         grid=grid,
         in_specs=[q_spec, q_spec, k_spec, k_spec, row_spec, row_spec],
-        out_specs=[
-            pl.BlockSpec((1, rows, D), lambda b, r, j, i: (b // H, r, b % H)),
-            part_spec, part_spec],
+        out_specs=[dq_spec, part_spec, part_spec],
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype), part, part],
         scratch_shapes=[
             pltpu.VMEM((rows, D), jnp.float32),      # dq of the range
@@ -686,6 +718,9 @@ def flash_backward(q, k, v, o, lse, do, dlse, causal: bool, scale: float,
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     adj = delta.transpose(0, 2, 1).reshape(B * H, Sq) \
         - dlse.astype(jnp.float32)
+    if D % MIN_BLOCK:
+        return _flash_backward_heads_first(q, k, v, do, lse, adj, causal,
+                                           scale, blocks, interpret, window)
     dq, dk, dv = _flash_bwd_local(
         q.reshape(B, Sq, H * D), k.reshape(B, Sk, Hkv * D),
         v.reshape(B, Sk, Hkv * D), do.reshape(B, Sq, H * D),
@@ -701,6 +736,30 @@ def flash_backward(q, k, v, o, lse, do, dlse, causal: bool, scale: float,
             parts = parts.astype(jnp.float32).sum(0).astype(parts.dtype)
         return parts.reshape(B, Sk, H, D)
     return dq.reshape(B, Sq, H, D), total(dk), total(dv)
+
+
+def _flash_backward_heads_first(q, k, v, do, lse, adj, causal, scale, blocks,
+                                interpret, window):
+    """:func:`flash_backward` for a head that is no whole lane column of
+    ``[B, S, H·D]`` (D = 64): the operands heads first, ``[B·H, S, D]``, as
+    the forward takes them; a k/v head's dk and dv are the float32 sum
+    over its group's q heads and the q ranges."""
+    B, Sq, H, D = q.shape
+    Sk, Hkv = k.shape[1], k.shape[2]
+
+    def first(x):       # [B, S, heads, D] -> [B * heads, S, D]
+        return x.transpose(0, 2, 1, 3).reshape(-1, x.shape[1], D)
+    dq, dk, dv = _flash_bwd_local(
+        first(q), first(k), first(v), first(do), lse.reshape(B * H, 1, Sq),
+        adj.reshape(B * H, 1, Sq), H=H, causal=causal, scale=scale,
+        blocks=blocks, interpret=interpret, window=window, heads_first=True)
+
+    def total(parts):   # [ranges, B * H, Sk, D] -> [B, Sk, Hkv, D]
+        parts = parts.reshape(-1, B, Hkv, H // Hkv, Sk, D)
+        summed = parts.astype(jnp.float32).sum((0, 3)).astype(parts.dtype)
+        return summed.transpose(0, 2, 1, 3)
+    return (dq.reshape(B, H, Sq, D).transpose(0, 2, 1, 3), total(dk),
+            total(dv))
 
 
 def _flash_bwd(causal, scale, block_q, block_k, interpret, window, res, cts):
@@ -1129,25 +1188,29 @@ def _key_masked_attention(q, k, v, key_mask, scale=None):
 
 def flash_eligible(Sq: int, Sk: int, D: int) -> bool:
     """The kernel's contract to callers: every length a multiple of
-    ``MIN_BLOCK`` (the tile is then :func:`flash_blocks`' to choose)."""
-    return D % MIN_BLOCK == 0 and Sq % MIN_BLOCK == 0 and Sk % MIN_BLOCK == 0
+    ``MIN_BLOCK`` (the tile is then :func:`flash_blocks`' to choose), a
+    head of whole lane tiles or of ``NARROW_HEAD``."""
+    return ((D % MIN_BLOCK == 0 or D == NARROW_HEAD)
+            and Sq % MIN_BLOCK == 0 and Sk % MIN_BLOCK == 0)
 
 
 def attention_path(Sq: int, Sk: int, H: int, D: int, causal: bool,
                    masked: bool) -> str:
     """Which implementation :func:`attend` takes, from the shape alone:
     ``"flash"`` wherever the flash kernel's contract holds and no key
-    mask is given, ``"block"`` where a head's whole score tile fits VMEM
+    mask is given (but at a head of 64 where the block kernels serve the
+    call), ``"block"`` where a head's whole score tile fits VMEM
     and is large enough to be worth a kernel (non-causal; with or without
     a key mask), else ``"xla"``; off the TPU always ``"xla"``."""
     if jax.default_backend() != "tpu":
         return "xla"
-    if not masked and flash_eligible(Sq, Sk, D):
+    block = (not causal and block_eligible(Sq, Sk, H, D)
+             and Sq * Sk >= BLOCK_MIN_SCORES)
+    # (a short non-causal call at a head of 64 stays the block kernels')
+    if (not masked and flash_eligible(Sq, Sk, D)
+            and not (block and D % MIN_BLOCK)):
         return "flash"
-    if (not causal and block_eligible(Sq, Sk, H, D)
-            and Sq * Sk >= BLOCK_MIN_SCORES):
-        return "block"
-    return "xla"
+    return "block" if block else "xla"
 
 
 def _banded_attention(q, k, v, window, scale=None):

@@ -13,9 +13,12 @@ In ``jax.numpy`` every factor of that is an array in HBM (at 8192 positions,
 64 heads of 64 and state 128: the chunks' states and their cotangents are
 134 MB each, a dozen of them written, relaid and read again). Here:
 
-  grid = (batch, groups, chunks)            — the chunk axis sequential
-  forward  ``hvd_ssm_scan``:     a step reads a chunk's x ``[Q, R·P]`` (the
-      ``R = H / G`` heads of a group side by side), b and c ``[Q, N]``, dt
+  grid = (batch, groups · head tiles, chunks) — the chunk axis sequential
+  forward  ``hvd_ssm_scan``:     a step reads a chunk's x ``[Q, R·P]`` (``R``
+      heads of a group side by side: all ``H / G`` of them where the
+      group's blocks fit :data:`VMEM_BUDGET`, else a *head tile* of them,
+      :func:`ssm_head_tile`), b and c ``[Q, N]`` (read again by every head
+      tile of the group), dt
       and s (twice: positions along the sublanes ``[Q, R]`` and along the
       lanes ``[R, Q]``) and writes y ``[Q, R·P]`` float32; the group's
       carried state ``[N, R·P]`` float32 lives in a VMEM scratch across
@@ -27,7 +30,9 @@ In ``jax.numpy`` every factor of that is an array in HBM (at 8192 positions,
       each chunk started from, which the forward writes under
       differentiation (``[B, n, G, N, R·P]`` float32: the one array of the
       chunks' size that reaches HBM) and gives dx, db and dc (summed over
-      the group's heads in the step), d dt and d s.
+      the step's heads in the step, and over a group's head tiles outside
+      the kernel in float32, as ``hvd_flash_bwd``'s dk and dv are over a
+      query group), d dt and d s.
 
 Nothing ``[Q, Q]``-sized, no chunk's own state and no cotangent of a state
 is written to HBM in either direction.
@@ -53,7 +58,7 @@ return their cotangents.
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -97,6 +102,47 @@ def ssm_tiles(H: int, P: int, G: int) -> SsmTiles:
     return SsmTiles(per_tile, P, R // per_tile)
 
 
+#: bytes one grid step of the backward (the larger of the two) may take by
+#: :func:`ssm_vmem_bytes`: the v5e's default scoped-VMEM limit, which no call
+#: asks to raise
+VMEM_BUDGET = 16 * 1024 * 1024
+
+
+def ssm_vmem_bytes(chunk: int, width: int, N: int, itemsize: int,
+                   head_dim: int = 64) -> int:
+    """Working set of one grid step of the backward at a block of ``width``
+    channels: x, the float32 dy and the state the chunk started from in, dx
+    out, each double-buffered by the pipeline; b and c in, db and dc out;
+    dt and s in both forms and their cotangents (a ``[Q, R]`` float32 block
+    takes whole 128-lane tiles); the state's cotangent in its scratch; and
+    three ``[Q, Q]`` float32 arrays a head of the block (the loop over a
+    step's heads is unrolled and Mosaic gives each head's decays, weights
+    and their cotangents room of their own). Held to two compiles for a
+    v5e at ONE group, chunk 256: 8 heads of 64 (11.0 MiB here) fit the
+    default limit, 16 (19.8 MiB) do not (PERF.md section 6, PR 49)."""
+    wide = chunk * width * (2 * itemsize + 4) + N * width * 4
+    narrow = 2 * chunk * N * (itemsize + 4)
+    steps = 6 * chunk * LANES * 4
+    return (2 * (wide + narrow + steps) + N * width * 4
+            + 3 * (width // head_dim) * chunk * chunk * 4)
+
+
+def ssm_head_tile(H: int, P: int, G: int, N: int, chunk: int,
+                  itemsize: int = 2) -> int:
+    """Heads of a group one grid step works on: all ``H / G`` where their
+    blocks fit :data:`VMEM_BUDGET` (8 groups of 8 heads of 64 at chunk 128:
+    one block of 512 channels, 4.4 MiB), else the most whole lane tiles of
+    heads that do and divide the group (ONE group of 64 heads of 64 at
+    chunk 256: ``[256, 4096]`` blocks with 64 heads' scores are 72 MiB; 8
+    heads, 11.0 MiB)."""
+    tiles = ssm_tiles(H, P, G)
+    for n in range(tiles.tiles, 0, -1):
+        if tiles.tiles % n == 0 and ssm_vmem_bytes(
+                chunk, n * tiles.width, N, itemsize, P) <= VMEM_BUDGET:
+            return n * tiles.heads_per_tile
+    return tiles.heads_per_tile
+
+
 def ssm_eligible(S: int, H: int, P: int, G: int, N: int, chunk: int) -> bool:
     """The kernels' contract to callers: whole chunks, and the chunk, the
     state and a tile of heads multiples of the lane tile."""
@@ -113,11 +159,22 @@ def ssm_scan_path(S: int, H: int, P: int, G: int, N: int, chunk: int) -> str:
         return ("jax.numpy (the chunk, the state or a tile of heads is no "
                 f"multiple of {LANES} lanes)")
     tiles = ssm_tiles(H, P, G)
+    R = ssm_head_tile(H, P, G, N, chunk)
+    if R == H // G:
+        return (f"kernels {FWD_NAME} / {BWD_NAME}: grid "
+                f"({G} groups, {S // chunk} chunks), x and y blocks {chunk}x{H // G * P} in "
+                f"{tiles.tiles} lane tiles of {tiles.heads_per_tile} heads, "
+                f"b and c {chunk}x{N}, carried state {N}x{H // G * P} float32 "
+                "in VMEM")
     return (f"kernels {FWD_NAME} / {BWD_NAME}: grid "
-            f"({G} groups, {S // chunk} chunks), x and y blocks {chunk}x{H // G * P} in "
-            f"{tiles.tiles} lane tiles of {tiles.heads_per_tile} heads, "
-            f"b and c {chunk}x{N}, carried state {N}x{H // G * P} float32 "
-            "in VMEM")
+            f"({G} groups, {H // G // R} head tiles of {R} heads, "
+            f"{S // chunk} chunks), x and y blocks {chunk}x{R * P} in "
+            f"{R // tiles.heads_per_tile} lane tiles of "
+            f"{tiles.heads_per_tile} heads, b and c {chunk}x{N} read by "
+            f"every head tile, db and dc summed over them, carried state "
+            f"{N}x{R * P} float32 in VMEM "
+            f"({ssm_vmem_bytes(chunk, R * P, N, 2, P) / 2**20:.1f} MiB a step "
+            f"of {VMEM_BUDGET / 2**20:.0f})")
 
 
 # -- the pieces a test swaps for a wrong one ----------------------------------
@@ -238,31 +295,48 @@ class _Layout(NamedTuple):
     G: int
     N: int
     chunk: int
+    R: int      # heads a grid step works on: a group's, or a head tile of them
 
     @property
     def n(self) -> int:
         return self.S // self.chunk
 
     @property
-    def R(self) -> int:
-        return self.H // self.G
+    def steps(self) -> int:
+        """(group, head tile) pairs: the grid's second axis."""
+        return self.H // self.R
+
+    @property
+    def head_tiles(self) -> int:
+        """Head tiles a group."""
+        return self.H // self.G // self.R
+
+    @property
+    def tiles(self) -> SsmTiles:
+        """How a step's heads lie on the lanes."""
+        per_tile = ssm_tiles(self.H, self.P, self.G).heads_per_tile
+        return SsmTiles(per_tile, self.P, self.R // per_tile)
 
 
-def _layout(x, b, chunk: int) -> _Layout:
+def _layout(x, b, chunk: int, head_tile: Optional[int] = None) -> _Layout:
     B, S, H, P = x.shape
     G, N = b.shape[2:]
     if S % chunk or H % G:
         raise ValueError(f"chunk {chunk} does not divide {S} positions, or "
                          f"{G} groups {H} heads")
-    return _Layout(B, S, H, P, G, N, chunk)
+    R = head_tile or ssm_head_tile(H, P, G, N, chunk, x.dtype.itemsize)
+    if (H // G) % R or R % ssm_tiles(H, P, G).heads_per_tile:
+        raise ValueError(f"a head tile of {R} heads is not whole lane tiles "
+                         f"of a group's {H // G} heads")
+    return _Layout(B, S, H, P, G, N, chunk, R)
 
 
 def _operands(x, dt, s, b, c, lay: _Layout):
     """What both kernels read, in their block specs' order: x ``[B, S,
     H·P]``, b and c ``[B, S, G·N]``, dt and s with the positions along the
-    sublanes ``[B, G, S, R]``, then s and dt with them along the lanes
-    ``[B, G, R, S]``."""
-    dt_col, s_col = (v.reshape(lay.B, lay.S, lay.G, lay.R
+    sublanes ``[B, H / R, S, R]`` (a grid step's heads last), then s and dt
+    with them along the lanes ``[B, H / R, R, S]``."""
+    dt_col, s_col = (v.reshape(lay.B, lay.S, lay.steps, lay.R
                                ).transpose(0, 2, 1, 3) for v in (dt, s))
     return (x.reshape(lay.B, lay.S, lay.H * lay.P),
             b.reshape(lay.B, lay.S, lay.G * lay.N),
@@ -271,11 +345,21 @@ def _operands(x, dt, s, b, c, lay: _Layout):
 
 
 def _specs(lay: _Layout, chunk_of):
-    """Block specs of a chunk's (x-like, b-like, column-form, row-form,
-    states) arrays; ``chunk_of(k)`` the chunk a grid step works on."""
+    """Block specs of a chunk's (x-like, b-like, db-like, column-form,
+    row-form, states) arrays; ``chunk_of(k)`` the chunk a grid step works
+    on. The grid's second axis ``g`` walks the (group, head tile) pairs:
+    b and c are the pair's group's, db and dc a block a pair."""
     Q, RP = lay.chunk, lay.R * lay.P
+    if lay.head_tiles == 1:
+        def group_of(g):
+            return g
+    else:
+        def group_of(g):
+            return g // lay.head_tiles
     return (
         pl.BlockSpec((1, Q, RP), lambda i, g, k: (i, chunk_of(k), g)),
+        pl.BlockSpec((1, Q, lay.N),
+                     lambda i, g, k: (i, chunk_of(k), group_of(g))),
         pl.BlockSpec((1, Q, lay.N), lambda i, g, k: (i, chunk_of(k), g)),
         pl.BlockSpec((1, 1, Q, lay.R),
                      lambda i, g, k: (i, g, chunk_of(k), 0)),
@@ -288,19 +372,19 @@ def _specs(lay: _Layout, chunk_of):
 _SEMANTICS = ("parallel", "parallel", "arbitrary")
 
 
-def _forward(x, dt, s, b, c, chunk, interpret, save: bool):
+def _forward(x, dt, s, b, c, chunk, interpret, head_tile, save: bool):
     """(y ``[B, S, H, P]`` float32, the states the chunks start from ``[B,
-    n, G, N, R·P]`` float32 if ``save`` else None)."""
-    lay = _layout(x, b, chunk)
-    wide, narrow, col, row, at_start = _specs(lay, lambda k: k)
+    n, H / R, N, R·P]`` float32 if ``save`` else None)."""
+    lay = _layout(x, b, chunk, head_tile)
+    wide, narrow, _, col, row, at_start = _specs(lay, lambda k: k)
     shapes = [jax.ShapeDtypeStruct((lay.B, lay.S, lay.H * lay.P),
                                    jnp.float32),
               jax.ShapeDtypeStruct(
-                  (lay.B, lay.n, lay.G, lay.N, lay.R * lay.P), jnp.float32)]
+                  (lay.B, lay.n, lay.steps, lay.N, lay.R * lay.P),
+                  jnp.float32)]
     out = pl.pallas_call(
-        functools.partial(_fwd_kernel,
-                          tiles=ssm_tiles(lay.H, lay.P, lay.G)),
-        grid=(lay.B, lay.G, lay.n),
+        functools.partial(_fwd_kernel, tiles=lay.tiles),
+        grid=(lay.B, lay.steps, lay.n),
         in_specs=[wide, narrow, narrow, col, col, row, row],
         out_specs=[wide, at_start] if save else [wide],
         out_shape=shapes if save else shapes[:1],
@@ -391,24 +475,29 @@ def _bwd_kernel(x_ref, b_ref, c_ref, dtc_ref, sc_ref, sr_ref, dy_ref, h_ref,
     dsc_ref[0, 0] = _columns(ds, R)
 
 
-def _backward(x, dt, s, b, c, states, dy, chunk, interpret):
-    lay = _layout(x, b, chunk)
-    wide, narrow, col, row, at_start = _specs(lay, lambda k: lay.n - 1 - k)
+def _backward(x, dt, s, b, c, states, dy, chunk, interpret, head_tile):
+    lay = _layout(x, b, chunk, head_tile)
+    wide, narrow, part, col, row, at_start = _specs(
+        lay, lambda k: lay.n - 1 - k)
     flat = (lay.B, lay.S, lay.H * lay.P)
-    groups = (lay.B, lay.S, lay.G * lay.N)
-    col_shape = jax.ShapeDtypeStruct((lay.B, lay.G, lay.S, lay.R),
+    # db and dc a (group, head tile) pair: a group's own where it is one
+    # block, else its head tiles' parts in float32, summed below
+    parts = (lay.B, lay.S, lay.steps * lay.N)
+    whole = lay.head_tiles == 1
+    col_shape = jax.ShapeDtypeStruct((lay.B, lay.steps, lay.S, lay.R),
                                      jnp.float32)
-    row_shape = jax.ShapeDtypeStruct((lay.B, lay.G, lay.R, lay.S),
+    row_shape = jax.ShapeDtypeStruct((lay.B, lay.steps, lay.R, lay.S),
                                      jnp.float32)
     dx, db, dc, ddt_col, ds_col, ds_row = pl.pallas_call(
-        functools.partial(_bwd_kernel,
-                          tiles=ssm_tiles(lay.H, lay.P, lay.G)),
-        grid=(lay.B, lay.G, lay.n),
+        functools.partial(_bwd_kernel, tiles=lay.tiles),
+        grid=(lay.B, lay.steps, lay.n),
         in_specs=[wide, narrow, narrow, col, col, row, wide, at_start],
-        out_specs=[wide, narrow, narrow, col, col, row],
+        out_specs=[wide, part, part, col, col, row],
         out_shape=[jax.ShapeDtypeStruct(flat, x.dtype),
-                   jax.ShapeDtypeStruct(groups, b.dtype),
-                   jax.ShapeDtypeStruct(groups, c.dtype),
+                   jax.ShapeDtypeStruct(parts,
+                                        b.dtype if whole else jnp.float32),
+                   jax.ShapeDtypeStruct(parts,
+                                        c.dtype if whole else jnp.float32),
                    col_shape, col_shape, row_shape],
         scratch_shapes=[pltpu.VMEM((lay.N, lay.R * lay.P), jnp.float32)],
         compiler_params=pltpu.CompilerParams(dimension_semantics=_SEMANTICS),
@@ -418,30 +507,40 @@ def _backward(x, dt, s, b, c, states, dy, chunk, interpret):
 
     def heads_last(col_form):
         return col_form.transpose(0, 2, 1, 3).reshape(lay.B, lay.S, lay.H)
+
+    def of_group(part, like):
+        if not whole:
+            part = part.reshape(lay.B, lay.S, lay.G, lay.head_tiles, lay.N
+                                ).sum(3).astype(like.dtype)
+        return part.reshape(like.shape)
     ds = heads_last(ds_col + ds_row.transpose(0, 1, 3, 2))
     return (dx.reshape(x.shape), heads_last(ddt_col).astype(dt.dtype),
-            ds.astype(s.dtype), db.reshape(b.shape), dc.reshape(c.shape))
+            ds.astype(s.dtype), of_group(db, b), of_group(dc, c))
 
 
 # -- the differentiable call --------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def ssm_scan(x, dt, s, b, c, chunk: int, interpret: bool = False):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def ssm_scan(x, dt, s, b, c, chunk: int, interpret: bool = False,
+             head_tile: Optional[int] = None):
     """The scan of ``ssm_chunked`` on the kernels. x ``[B, S, H, P]``; dt
     ``[B, S, H]`` float32 after its softplus; s ``[B, S, H]`` float32, the
     running sums of ``dt a`` inside each chunk of ``chunk`` positions; b, c
     ``[B, S, G, N]``. Returns y ``[B, S, H, P]`` float32 (without the skip).
-    Differentiable in all five."""
-    return _forward(x, dt, s, b, c, chunk, interpret, save=False)[0]
+    Differentiable in all five. ``head_tile`` overrides
+    :func:`ssm_head_tile` (tests, sweeps)."""
+    return _forward(x, dt, s, b, c, chunk, interpret, head_tile,
+                    save=False)[0]
 
 
-def _scan_fwd(x, dt, s, b, c, chunk, interpret):
-    y, states = _forward(x, dt, s, b, c, chunk, interpret, save=True)
+def _scan_fwd(x, dt, s, b, c, chunk, interpret, head_tile):
+    y, states = _forward(x, dt, s, b, c, chunk, interpret, head_tile,
+                         save=True)
     return y, (x, dt, s, b, c, states)
 
 
-def _scan_bwd(chunk, interpret, res, dy):
-    return _backward(*res, dy, chunk, interpret)
+def _scan_bwd(chunk, interpret, head_tile, res, dy):
+    return _backward(*res, dy, chunk, interpret, head_tile)
 
 
 ssm_scan.defvjp(_scan_fwd, _scan_bwd)

@@ -130,8 +130,12 @@ _PATHS = {
     (4096, 4096, 16, 128, True, False): "flash",   # olmoe-1b-7b.s4096
     (128, 128, 16, 64, False, True): "xla",        # bert-large.s128: too few
     (128, 256, 16, 64, False, True): "xla",        # scores to win (PR 27)
-    (2048, 2048, 16, 64, True, False): "xla",      # causal at head_dim 64
-    (512, 512, 16, 64, True, False): "xla",        # causal is not the block's
+    (2048, 2048, 16, 64, True, False): "flash",    # causal at head_dim 64:
+    (512, 512, 16, 64, True, False): "flash",      # heads first since PR 49
+    (4096, 4096, 32, 64, True, False): "flash",    # granite-4.0-h-micro.s4096
+    (2048, 2048, 16, 64, False, False): "flash",   # too long for a block
+    (2048, 2048, 16, 64, False, True): "xla",      # and a mask is not flash's
+    (512, 512, 16, 32, True, False): "xla",        # no head under 64
     (1024, 1024, 16, 64, False, True): "xla",      # one score tile too many
     (300, 300, 16, 64, False, True): "xla",
     (256, 260, 16, 64, False, True): "xla",

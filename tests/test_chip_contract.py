@@ -517,3 +517,158 @@ def test_the_latent_step_gives_what_the_glm4_moe_lite_adapter_reads():
     assert scopes.LATENT_PHASES == (
         "hvd.attention.latent", "hvd.attention.latent.down",
         "hvd.attention.latent.up", "hvd.mtp", "hvd.mtp.proj")
+
+
+#: sha256 of the GPT cell's tiny train step's jaxpr (``_gpt_tiny_jaxpr``),
+#: taken at 4c983fc, before ``TransformerConfig`` had ``embed_scale``,
+#: ``residual_scale``, ``attention_scale`` and ``logits_scale``: a
+#: default-valued config traces no multiply for them. Take a new digest only
+#: where the GPT block's program is *meant* to change
+GPT_TINY_STEP_SHA256 = (
+    "50e6d4e177ae45f171398f411328471683d5af60ea28c310a71f26126c47fc39")
+
+
+def _gpt_tiny_jaxpr(**fields) -> str:
+    """The jitted train step of ``gpt-1.3b-widths.s2048`` at its ``tiny``
+    sizes as text, a function's address taken out and a ``frozenset``'s
+    members in order (its print order is the process's hash seed's)."""
+    import dataclasses
+    import sys
+    import jax
+    if CHIP not in sys.path:
+        sys.path.insert(0, CHIP)
+    import run as harness
+    from adapters import flagship
+    import horovod_tpu as hvd
+    from horovod_tpu.models import transformer as t
+    _b, _entry, config, job = harness.load_cell("gpt-1.3b-widths.s2048",
+                                                tiny=True)
+    mesh = hvd.build_mesh(devices=jax.devices()[:1], **job["mesh"])
+    tx = harness.make_optimizer(job)
+    step, shapes = flagship.abstract_step(config, job, mesh, tx)
+    if fields:
+        cfg = dataclasses.replace(flagship._model_config(config, job),
+                                  **fields)
+        step = t.make_train_step(cfg, mesh, tx)
+    text = re.sub(r" at 0x[0-9a-f]+", "", str(jax.make_jaxpr(step)(*shapes)))
+    return re.sub(
+        r"frozenset\(\{([^}]*)\}\)",
+        lambda m: "frozenset({%s})" % ", ".join(sorted(
+            m.group(1).split(", "))), text)
+
+
+def test_a_default_valued_config_traces_the_step_of_before_the_multipliers():
+    import hashlib
+    text = _gpt_tiny_jaxpr()
+    assert hashlib.sha256(text.encode()).hexdigest() == GPT_TINY_STEP_SHA256
+    # and each field, set, is in the program
+    for field, value in (("embed_scale", 12.0), ("residual_scale", 0.22),
+                         ("attention_scale", 1 / 64),
+                         ("logits_scale", 1 / 8)):
+        assert _gpt_tiny_jaxpr(**{field: value}) != text, field
+
+
+def test_the_dense_hybrid_step_gives_what_the_granite_hybrid_adapter_reads():
+    """``adapters/granite_hybrid.py`` hands the configuration's four
+    multipliers to ``TransformerConfig`` by keyword (``embed_scale``,
+    ``residual_scale``, ``attention_scale``, ``logits_scale``), builds a
+    period of twenty one-sublayer kinds of ``("mamba",)``, ``("dense",)``
+    and ``("attention", None, False)``, names leaves of the three stacks
+    (``_leaf_paths``, ``_init_function``); the configuration, the traffic
+    file and the metric files agree with ``BENCHMARK.json``; the roofline
+    functions read the adapter's ``shapes()`` keys."""
+    import sys
+    import jax
+    import numpy as np
+    if CHIP not in sys.path:
+        sys.path.insert(0, CHIP)
+    from adapters import granite_hybrid
+    from trees import get_leaves
+    from horovod_tpu.models import transformer as t
+    from horovod_tpu.parallel import build_mesh
+    for name in ("shapes", "tokens_per_step", "flops_per_token",
+                 "host_batch", "abstract_step", "Cell"):
+        assert callable(getattr(granite_hybrid, name)), name
+    fields = {f.name: f.default for f in
+              __import__("dataclasses").fields(t.TransformerConfig)}
+    assert (fields["embed_scale"], fields["residual_scale"],
+            fields["attention_scale"], fields["logits_scale"]) == (
+                1.0, 1.0, None, 1.0)
+    cell, name = "granite-4.0-h-micro.s4096", "granite-4.0-h-micro"
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    entry = next(w for w in bench["workloads"] if w["name"] == cell)
+    listed = next(c for c in bench["configs"] if c["name"] == name)
+    assert (entry["config"], entry["traffic"], entry["chips"]) == (
+        name, "train.s4096.b1.ssm", 1)
+    assert listed["file"] == f"benchmarks/chip/configs/{name}.json"
+    with open(os.path.join(REPO, listed["file"])) as f:
+        config = json.load(f)
+    with open(os.path.join(CHIP, "workloads",
+                           entry["traffic"] + ".json")) as f:
+        job = json.load(f)
+    assert config["source"] == listed["source"]
+    assert config["reduced"] == listed["reduced"] == [
+        "num_hidden_layers", "layer_types", "vocab_size"]
+    assert (job["seq_len"], job["batch_per_chip"], job["prefetch"],
+            job["max_ahead"], job["warmup_steps"], job["trace_steps"],
+            job["mesh"]) == (4096, 1, 2, 2, 10, 10, {"dp": -1})
+    mine = sorted(m["name"] for m in bench["per_layer"]
+                  if m.get("workloads") == [cell])
+    assert mine == sorted(f"dense_ssm.{m}" for m in (
+        "ssm_ms", "ssm_proj_ms", "ssm_conv_ms", "ssm_scan_ms", "ssm_norm_ms",
+        "mlp_ms", "ssm_scan_kernels_ms", "ssm_scan_roofline",
+        "attention_fwd_ms", "flash_attention_roofline", "attention_bwd_ms",
+        "flash_attention_bwd_roofline", "head_xent_ms",
+        "head_xent_roofline"))
+    # no list of an accepted metric was edited to take the cell in
+    assert not [m["name"] for m in bench["per_layer"]
+                if cell in m.get("workloads", ()) and m["name"] not in mine]
+    full = granite_hybrid._model_config(config, job)
+    assert len(full.layer_pattern) == full.n_layers == 20
+    assert [k[0] for k in full.layer_pattern].count("mamba") == 9
+    assert full.layer_pattern[10] == ("attention", None, False)
+    assert full.layer_pattern[1::2] == (("dense",),) * 10
+    assert (full.ssm_groups, full.ssm_chunk, full.head_dim, full.kv_heads,
+            full.embed_scale, full.residual_scale, full.attention_scale,
+            full.logits_scale) == (1, 256, 64, 8, 12.0, 0.22, 1 / 64, 1 / 8)
+    for function in ("dense_ssm_scan", "dense_ssm_flash_attention",
+                     "dense_ssm_flash_attention_backward",
+                     "dense_ssm_head_xent"):
+        need = getattr(importlib.import_module(f"roofline_{function}"),
+                       function)(granite_hybrid.shapes(config, job))
+        assert need["flops"] > 0 and need["bytes"] > 0, function
+    config, job = {**config, **config["tiny"]}, {**job, **job["tiny"]}
+    cfg = granite_hybrid._model_config(config, job)
+    params = t.init_params(np.random.RandomState(0), cfg, 1)
+    assert set(params) == {"embed", "ln_f", "layers"}
+    assert list(params["layers"]) == ["mamba", "dense", "attention"]
+    assert set(params["layers"]["mamba"]) == {
+        "ssm_dt_bias", "ln1", "ssm_in", "ssm_conv_w", "ssm_conv_b",
+        "ssm_a_log", "ssm_d", "ssm_norm", "ssm_out"}
+    assert set(params["layers"]["dense"]) == {"ln2", "w1", "w2", "w3"}
+    assert set(params["layers"]["attention"]) == {"ln1", "wq", "wk", "wv",
+                                                  "wo"}
+    assert params["layers"]["mamba"]["ssm_in"].shape == (
+        1, 9, cfg.d_model, 2 * cfg.ssm_inner + 2 * cfg.ssm_state
+        + cfg.ssm_heads)
+    assert params["layers"]["dense"]["w1"].shape == (1, 10, cfg.d_model,
+                                                     cfg.dense_ff)
+    assert params["layers"]["attention"]["wk"].shape == (
+        1, 1, cfg.d_model, cfg.kv_heads * cfg.head_dim)
+    ours = jax.eval_shape(granite_hybrid._init_function(cfg, config),
+                          jax.random.PRNGKey(0))
+    assert jax.tree_util.tree_map(lambda a: a.shape, ours) == \
+        jax.tree_util.tree_map(lambda a: a.shape, params)
+    assert set(get_leaves(params, granite_hybrid._leaf_paths(
+        config["layer_types"]))) == {
+            "table", "first_ssm_in", "first_ssm_norm", "last_ssm_a_log",
+            "last_ssm_dt_bias", "attention_query", "attention_key",
+            "last_ffn_gate"}
+    mesh = build_mesh(devices=jax.devices()[:1], dp=-1)
+    batch = granite_hybrid.host_batch(config, job, 0, 0, 1)
+    _loss, aux, grads = jax.eval_shape(
+        t.make_grad_fn(cfg, mesh), params, batch["tokens"], batch["targets"])
+    assert set(aux) == {"aux_loss"}
+    assert jax.tree_util.tree_structure(grads) == \
+        jax.tree_util.tree_structure(params)

@@ -391,7 +391,8 @@ def _the_rope_key_normed(p, h, cfg):
     return c_q, c_kv, rmsnorm(k_r, jnp.ones(k_r.shape[-1]), cfg.norm_eps)
 
 
-def _scale_of_the_position_free_part(q, k, v, causal=True, **kw):
+def _scale_of_the_position_free_part(q, k, v, causal=True, scale=None,
+                                     **kw):
     nope = SMALL.head_dim - SMALL.rope_width
     return _sound_attend(q, k, v, causal=causal, scale=nope ** -0.5, **kw)
 

@@ -293,7 +293,12 @@ def test_flash_blocks_refuses_what_128_does_not_divide(Sq, Sk):
 def test_flash_eligible_is_the_contract_of_128():
     assert pa.flash_eligible(128, 256, 128)
     assert pa.flash_eligible(384, 384, 256)
-    assert not pa.flash_eligible(256, 256, 64)
+    # a head of 64 runs heads first (PR 49); no other width under a tile
+    assert pa.flash_eligible(256, 256, 64) and pa.NARROW_HEAD == 64
+    assert not pa.flash_eligible(256, 256, 32)
+    assert not pa.flash_eligible(256, 256, 96)
+    assert not pa.flash_eligible(256, 256, 192)
+    assert not pa.flash_eligible(200, 256, 64)
 
 
 # -- the backward's tile rule -------------------------------------------------
@@ -392,3 +397,126 @@ def test_flash_grads_rect_causal():
                   lambda o: jnp.sum(o ** 2))
     _assert_grads(*_qkv(B=1, S=128, Sk=384, seed=14), True,
                   lambda o: jnp.sum(o ** 2))
+
+
+# -- a head of 64: heads first, grouped, a scale of its own (PR 49) -----------
+
+def _narrow(B=1, S=256, H=8, Hkv=2, D=64, seed=11, dtype=jnp.float32):
+    rng = np.random.RandomState(seed)
+
+    def mk(heads):
+        return (jnp.asarray(rng.randn(B, S, heads, D), jnp.float32)
+                * 0.8).astype(dtype)
+    return mk(H), mk(Hkv), mk(Hkv)
+
+
+#: (S, H, Hkv, block_q, block_k, rows of a q range): 4 query heads a k/v
+#: head as granite-4.0-h-micro's 32 / 8, tiles that meet the diagonal corner
+#: to corner and that do not, dq resident and in two q ranges
+_NARROW_TILES = [(256, 8, 2, 128, 128, 256), (512, 4, 1, 256, 256, 512),
+                 (512, 8, 2, 128, 256, 512), (512, 4, 4, 256, 128, 256),
+                 (256, 2, 2, 256, 256, 256)]
+SCALE = 1 / 64
+
+
+@pytest.mark.parametrize("tile", _NARROW_TILES)
+def test_flash_pair_at_a_head_of_64_is_the_banded_form(tile):
+    """Forward, dq, dk and dv at heads of 64, grouped, causal, without
+    positions, scores times 1/64 (not 1/sqrt(64)) against
+    ``_banded_attention``: the kernels heads first, a k/v head's gradient
+    the sum over its group's query heads and the q ranges."""
+    S, H, Hkv, bq, bk, rows = tile
+    q, k, v = _narrow(S=S, H=H, Hkv=Hkv)
+    o, lse = pa.flash_attention_with_lse(q, k, v, True, SCALE, bq, bk,
+                                         interpret=True)
+    want = pa._banded_attention(q, k, v, None, SCALE)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(want), rtol=2e-5,
+                               atol=2e-5)
+    assert lse.shape == (H, S) and lse.dtype == jnp.float32
+    w = jnp.cos(jnp.arange(o.size, dtype=jnp.float32).reshape(o.shape))
+    got = pa.flash_backward(q, k, v, o, lse, w, jnp.zeros_like(lse), True,
+                            SCALE, pa.BwdBlocks(bq, bk, rows),
+                            interpret=True)
+    ref = jax.grad(lambda q, k, v: jnp.sum(
+        pa._banded_attention(q, k, v, None, SCALE) * w), (0, 1, 2))(q, k, v)
+    for g, r, name in zip(got, ref, ("dq", "dk", "dv")):
+        assert g.shape == r.shape and g.dtype == r.dtype, name
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), rtol=2e-4,
+                                   atol=2e-4, err_msg=name)
+    # 1/sqrt(D) is another function
+    other = pa.flash_attention_tpu(q, k, v, True, None, bq, bk,
+                                   interpret=True)
+    assert float(jnp.max(jnp.abs(other - want))) > 1e-2
+
+
+def test_flash_at_a_head_of_64_is_differentiable_through_the_custom_vjp():
+    """``attend``'s way in: ``flash_attention_tpu`` with the rule's tiles,
+    bfloat16 operands, against the float32 banded form."""
+    q, k, v = _narrow(S=512, dtype=jnp.bfloat16)
+    w = jnp.sin(jnp.arange(q.size, dtype=jnp.float32).reshape(q.shape))
+
+    def loss(f):
+        return lambda q, k, v: jnp.sum(f(q, k, v).astype(jnp.float32) * w)
+    got = jax.grad(loss(lambda q, k, v: flash_attention_tpu(
+        q, k, v, True, SCALE, interpret=True)), (0, 1, 2))(q, k, v)
+    want = jax.grad(loss(lambda q, k, v: pa._banded_attention(
+        q, k, v, None, SCALE)), (0, 1, 2))(
+            *(x.astype(jnp.float32) for x in (q, k, v)))
+    for g, r, name in zip(got, want, ("dq", "dk", "dv")):
+        assert g.dtype == jnp.bfloat16, name
+        err = float(jnp.max(jnp.abs(g.astype(jnp.float32) - r))
+                    / jnp.max(jnp.abs(r)))
+        assert err < 2e-2, (name, err)
+
+
+def test_a_window_at_a_head_of_64():
+    q, k, v = _narrow(S=512, H=4, Hkv=2)
+    got = flash_attention_tpu(q, k, v, True, SCALE, 128, 128, interpret=True,
+                              window=256)
+    want = pa._banded_attention(q, k, v, 256, SCALE)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5,
+                               atol=2e-5)
+
+
+def test_attend_takes_the_kernels_at_a_head_of_64_on_a_tpu(monkeypatch):
+    assert pa.attention_path(4096, 4096, 32, 64, True, False) == "xla"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert pa.attention_path(4096, 4096, 32, 64, True, False) == "flash"
+    # BERT's core (a key mask, non-causal) stays on the block kernels
+    assert pa.attention_path(512, 512, 16, 64, False, True) == "block"
+    assert pa.attention_path(128, 128, 16, 64, False, True) == "xla"
+    q = jax.ShapeDtypeStruct((1, 256, 8, 64), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 256, 2, 64), jnp.bfloat16)
+    text = str(jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(pa.attend(
+        q, k, v, causal=True, scale=SCALE).astype(jnp.float32)), (0, 1, 2))
+    )(q, kv, kv))
+    assert "hvd_flash_attention" in text and "hvd_flash_bwd" in text
+    # heads first: the kernels' operands are [B * heads, S, 64]
+    assert "bf16[8,256,64]" in text and "bf16[2,256,64]" in text
+
+
+#: every cell's causal core: (S, head_dim) -> the forward's tile and the
+#: backward's (block_q, block_k, rows), bfloat16. A change to a rule that
+#: moves one of these moves a cell's kernel
+_CELL_TILES = {
+    "gpt-1.3b-widths.s2048": ((2048, 128), (1024, 1024), (1024, 1024, 2048)),
+    "olmoe-1b-7b.s4096": ((4096, 128), (1024, 1024), (1024, 1024, 4096)),
+    "ouro-2.6b.s4096": ((4096, 128), (1024, 1024), (1024, 1024, 4096)),
+    "smallthinker-21b-a3b.s8192": ((8192, 128), (1024, 1024),
+                                   (1024, 1024, 8192)),
+    "nemotron-3-nano-30b-a3b.s8192": ((8192, 128), (1024, 1024),
+                                      (1024, 1024, 8192)),
+    "glm-4.7-flash.s8192": ((8192, 256), (1024, 1024), (512, 512, 8192)),
+    "granite-4.0-h-micro.s4096": ((4096, 64), (1024, 1024),
+                                  (1024, 1024, 4096)),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_CELL_TILES))
+def test_flash_tiles_at_every_cell_s_shape(cell):
+    (S, D), fwd, bwd = _CELL_TILES[cell]
+    assert pa.flash_eligible(S, S, D)
+    assert flash_blocks(S, S, D, jnp.bfloat16) == fwd
+    blocks = pa.flash_bwd_blocks(S, S, D, jnp.bfloat16)
+    assert tuple(blocks) == bwd and blocks.rows == S     # dq resident
+    assert pa.flash_bwd_vmem_bytes(*blocks, D, 2) <= pa.BWD_VMEM_BUDGET

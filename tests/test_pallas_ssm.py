@@ -256,3 +256,142 @@ def test_a_shape_the_tiles_do_not_fit_takes_the_numpy_form(monkeypatch, what,
     assert not _calls_a_kernel(shape), what
     with pytest.raises(ValueError, match="ssm_chunk=48"):
         _calls_a_kernel((1, 256, 64, 64, 8, 128, 48))
+
+
+# -- a group's heads in head tiles (a grid axis; PR 49) ------------------------
+
+#: (shape, heads a grid step): ONE group at chunk 256 in four head tiles of a
+#: lane tile each and in two of two, two groups of two head tiles each, and
+#: heads that are a lane tile
+HEAD_TILES = {
+    "one group, chunk 256, four tiles of two heads":
+        ((1, 512, 8, 64, 1, 128, 256), 2),
+    "one group, chunk 256, two tiles of four heads":
+        ((1, 512, 8, 64, 1, 128, 256), 4),
+    "two groups of two tiles of two heads": ((2, 64, 8, 64, 2, 16, 16), 2),
+    "one group, four tiles of a head of 128": ((1, 64, 4, 128, 1, 8, 16), 1),
+}
+
+
+def _tiled(shape, head_tile, dtype=jnp.float32, seed=3):
+    x, dt, a, b, c = _operands(shape, seed, dtype)
+    return (x, dt, _sums(dt, a, shape[-1]), b, c), shape[-1]
+
+
+@pytest.mark.parametrize("shape, head_tile", HEAD_TILES.values(),
+                         ids=HEAD_TILES.keys())
+def test_head_tiles_give_the_numpy_form_s_output(shape, head_tile):
+    ops, chunk = _tiled(shape, head_tile)
+    lay = ps._layout(ops[0], ops[3], chunk, head_tile)
+    assert lay.head_tiles >= 2 and lay.steps == shape[2] // head_tile
+    got = ps.ssm_scan(*ops, chunk, True, head_tile)
+    assert got.dtype == jnp.float32
+    assert _rel(got, mamba._ssm_chunked_numpy(*ops, chunk)) < TOL
+    # and what the group's heads in one block give
+    assert _rel(got, ps.ssm_scan(*ops, chunk, True, shape[2] // shape[4])
+                ) < TOL
+
+
+@pytest.mark.parametrize("shape, head_tile", HEAD_TILES.values(),
+                         ids=HEAD_TILES.keys())
+def test_head_tiles_give_every_cotangent(shape, head_tile):
+    """dx, d dt, d s, and db and dc summed over the group's head tiles,
+    against autodiff of the ``jax.numpy`` form from the same operands."""
+    ops, chunk = _tiled(shape, head_tile)
+    weight = _weight(shape)
+    got = jax.grad(lambda *v: jnp.sum(
+        ps.ssm_scan(*v, chunk, True, head_tile) * weight),
+        (0, 1, 2, 3, 4))(*ops)
+    want = jax.grad(lambda *v: jnp.sum(
+        mamba._ssm_chunked_numpy(*v, chunk) * weight), (0, 1, 2, 3, 4))(*ops)
+    for name, g, w in zip(("x", "dt", "s", "b", "c"), got, want):
+        assert g.dtype == w.dtype and g.shape == w.shape, name
+        assert _rel(g, w) < TOL, name
+
+
+def test_a_head_tile_s_parts_of_db_are_summed_in_float32():
+    """bfloat16 operands: the parts leave the kernel in float32 and the
+    group's sum is rounded once, so four head tiles and one block differ
+    by a rounding of the result and not of every part."""
+    shape, head_tile = (1, 512, 8, 64, 1, 128, 256), 2
+    ops, chunk = _tiled(shape, head_tile, jnp.bfloat16)
+    weight = _weight(shape)
+
+    def grads(tile):
+        return jax.grad(lambda *v: jnp.sum(
+            ps.ssm_scan(*v, chunk, True, tile) * weight), (3, 4))(*ops)
+    for name, g, w in zip(("b", "c"), grads(head_tile), grads(8)):
+        assert g.dtype == jnp.bfloat16, name
+        assert _rel(g.astype(jnp.float32), w.astype(jnp.float32)) < 4e-3, name
+
+
+def test_a_head_tile_that_is_no_whole_lane_tiles_of_the_group_is_refused():
+    ops, chunk = _tiled((1, 64, 8, 64, 2, 16, 16), 2)
+    for bad in (1, 3, 8):
+        with pytest.raises(ValueError, match="head tile"):
+            ps.ssm_scan(*ops, chunk, True, bad)
+
+
+#: (S, H, P, G, N, chunk) of the cells that run a scan -> heads a grid step
+CELL_HEAD_TILES = {
+    "nemotron-3-nano-30b-a3b.s8192": ((8192, 64, 64, 8, 128, 128), 8),
+    "granite-4.0-h-micro.s4096": ((4096, 64, 64, 1, 128, 256), 8),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_HEAD_TILES))
+def test_the_head_tile_at_the_cells_shapes(cell, monkeypatch):
+    (S, H, P, G, N, chunk), heads = CELL_HEAD_TILES[cell]
+    assert ps.ssm_eligible(S, H, P, G, N, chunk)
+    assert ps.ssm_head_tile(H, P, G, N, chunk) == heads
+    assert ps.ssm_vmem_bytes(chunk, heads * P, N, 2, P) <= ps.VMEM_BUDGET
+    assert ps.VMEM_BUDGET == 16 * 2 ** 20   # the default limit: none asked
+    # a whole group of the hybrid cell is one block; ONE group of 64 heads
+    # at chunk 256 is not, and the estimate lies on the right side of the
+    # two compiles for a v5e it is held to (8 heads fit, 16 do not)
+    assert (heads == H // G) == (G == 8)
+    assert ps.ssm_vmem_bytes(256, 8 * 64, 128, 2) < 12 * 2 ** 20
+    assert ps.ssm_vmem_bytes(256, 16 * 64, 128, 2) > 19 * 2 ** 20
+    assert _calls_a_kernel((1, 256, H, P, G, N, chunk)) is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert _calls_a_kernel((1, 2 * chunk, H, P, G, N, chunk))
+
+
+def test_the_hybrid_cell_s_scan_path_line_to_the_letter(monkeypatch):
+    """What ``chip_smoke.py`` printed for the hybrid cell before the kernels
+    had head tiles: its blocks are what they were."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ps.ssm_scan_path(*CELL) == (
+        "kernels hvd_ssm_scan / hvd_ssm_scan_bwd: grid (8 groups, 64 "
+        "chunks), x and y blocks 128x512 in 4 lane tiles of 2 heads, b and "
+        "c 128x128, carried state 128x512 float32 in VMEM")
+
+
+def test_the_dense_hybrid_cell_s_scan_path(monkeypatch):
+    cfg = t.TransformerConfig(
+        layer_pattern=(("mamba",), ("dense",)), n_layers=2, ssm_heads=64,
+        ssm_head_dim=64, ssm_state=128, ssm_groups=1, ssm_chunk=256)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    said = mamba.ssm_path(cfg, 4096)
+    heads = CELL_HEAD_TILES["granite-4.0-h-micro.s4096"][1]
+    assert ps.FWD_NAME in said and ps.BWD_NAME in said
+    assert f"{64 // heads} head tiles of {heads} heads, 16 chunks" in said
+    assert f"x and y blocks 256x{heads * 64}" in said
+    assert "db and dc summed over them" in said
+    assert "one group of 4096 channels, a row's own mean" in said
+
+
+def test_one_group_s_gate_and_norm_is_the_matrix_form_s():
+    """``_gated_norm`` at one group takes a row's own mean; the 0/1-matrix
+    form at one group is the same function."""
+    rng = np.random.RandomState(5)
+    y, z = (jnp.asarray(rng.randn(2, 16, 64), jnp.float32) for _ in range(2))
+    w = jnp.asarray(rng.rand(64) + 0.5, jnp.float32)
+    got = mamba._gated_norm(y, z, w, 1, 1e-5)
+    g = y * jax.nn.silu(z)
+    want = g * jax.lax.rsqrt(jnp.mean(g * g, -1, keepdims=True) + 1e-5) * w
+    assert _rel(got, want) < 1e-6
+    two = mamba._gated_norm(y, z, w, 2, 1e-5)
+    assert _rel(two, want) > 1e-2
+    assert "dot_general" not in str(jax.make_jaxpr(
+        lambda y, z: mamba._gated_norm(y, z, w, 1, 1e-5))(y, z))

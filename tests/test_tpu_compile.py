@@ -129,6 +129,13 @@ _GMM_SHARE_DOWN = [((49152, 768), jnp.bfloat16),
 # c at 8192 positions, 64 heads of 64 in 8 groups, state 128, chunk 128
 _SSM_CELL = [((1, 8192, 64, 64), jnp.bfloat16), ((1, 8192, 64), jnp.float32),
              ((64,), jnp.float32)] + [((1, 8192, 8, 128), jnp.bfloat16)] * 2
+# the scan of the cell granite-4.0-h-micro.s4096: 4096 positions, 64 heads
+# of 64 in ONE group, state 128, chunk 256 (the group's heads in head tiles)
+_SSM_DENSE = [((1, 4096, 64, 64), jnp.bfloat16), ((1, 4096, 64), jnp.float32),
+              ((64,), jnp.float32)] + [((1, 4096, 1, 128), jnp.bfloat16)] * 2
+# and its attention block: 32 query heads of 64 on 8 key/value heads
+_QKV_NARROW = [((1, 4096, 32, 64), jnp.bfloat16)] \
+    + [((1, 4096, 8, 64), jnp.bfloat16)] * 2
 _BLOCKS = ((8192, 256), jnp.float32)
 _CODES = [((8192, 256), jnp.int8), ((8192, 1), jnp.float32)]
 
@@ -224,6 +231,16 @@ CASES = {
         jax.grad(lambda x, dt, a, b, c: _sum32(mamba.ssm_chunked(
             x, dt, a, b, c, 128)), (0, 1, 2, 3, 4)),
         _SSM_CELL, (pallas_ssm.FWD_NAME, pallas_ssm.BWD_NAME)),
+    # ONE group of 64 heads at chunk 256: [256, 4096] blocks do not fit the
+    # scoped VMEM, a head tile of them does (pallas_ssm.ssm_head_tile)
+    "ssm_scan_grad_one_group": (
+        jax.grad(lambda x, dt, a, b, c: _sum32(mamba.ssm_chunked(
+            x, dt, a, b, c, 256)), (0, 1, 2, 3, 4)),
+        _SSM_DENSE, (pallas_ssm.FWD_NAME, pallas_ssm.BWD_NAME)),
+    # a head of 64, heads first: a (1, tile, 64) block of [B*H, S, 64]
+    "flash_fwd_grad_head_of_64_grouped": (
+        jax.grad(lambda q, k, v: _sum32(pa.flash_attention_tpu(
+            q, k, v, True, 1 / 64)), (0, 1, 2)), _QKV_NARROW, _FLASH),
     "quantize": (pq.block_quantize, [_BLOCKS], "hvd_block_quantize"),
     "quantize_ef": (pq.block_quantize_ef, [_BLOCKS],
                     "hvd_block_quantize_ef"),
@@ -472,6 +489,40 @@ def test_looped_step_compiles_for_v5e_with_both_kernels(
                       if "op_name=" in line)
     assert scopes.LOOP + "/" in names and scopes.LOOP_GATE + "/" in names
     assert step_bytes(compiled.memory_analysis())["total"] < 15.0e9
+
+
+def test_dense_hybrid_step_compiles_for_v5e_on_the_kernels(
+        topo, no_compile_cache, monkeypatch):
+    """The cell granite-4.0-h-micro.s4096's step (nine Mamba-2 blocks of ONE
+    group at chunk 256, one attention block at 32 / 8 heads of 64, ten
+    SwiGLU FFNs, the tied sliced head): the scan's kernels, both flash
+    kernels and the head's kernel are in the program, no array of attention
+    scores (``[heads.., 4096, 4096]``) or of a head tile's whole states is
+    in memory, and the step fits with the room ISSUE 49 asks for (the
+    scan's float32 output ``y`` IS ``[1, 4096, 4096]``: 4096 positions of
+    64 x 64 channels)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    step, args, shapes, step_bytes = _cell_step("granite-4.0-h-micro.s4096",
+                                                topo)
+    b, s, h, d = (shapes[k] for k in ("batch", "seq", "heads", "head_dim"))
+    assert (s, h, shapes["kv_heads"], d) == (4096, 32, 8, 64)
+    assert pa.attention_path(s, s, h, d, True, False) == "flash"
+    assert px.xent_path(b * s, shapes["vocab"], jnp.bfloat16)[0] == "kernel"
+    assert pallas_ssm.ssm_eligible(s, 64, 64, 1, 128, 256)
+    compiled = step.lower(*args).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    for kernel in (pallas_ssm.FWD_NAME, pallas_ssm.BWD_NAME,
+                   "hvd_flash_attention", "hvd_flash_bwd", "hvd_fused_xent"):
+        assert any(kernel in c for c in calls), kernel
+    # the attention block is not checkpointed: one forward call, one backward
+    assert sum("hvd_flash_bwd" in c for c in calls) == 1
+    assert sum("hvd_fused_xent" in c for c in calls) == shapes["head_calls"]
+    scores = re.findall(r"(?:f32|bf16)\[(?:\d+,)*(?:8,4|32),4096,4096\]", text)
+    assert not scores, sorted(set(scores))
+    total = step_bytes(compiled.memory_analysis())["total"]
+    assert 13.6e9 < total < 14.9e9, total
 
 
 def _arrays_in_memory(text):
