@@ -33,12 +33,14 @@ Phases (default, one chip):
            against its XLA reference at real widths (the block attention
            kernels at the shapes of both BERT cells, with padded keys and
            a row of nothing else; the flash kernel's gradients through
-           hvd_flash_bwd, both again at the latent cell's head width 256
-           (with the tiles both kernels take at that cell's shape and the
-           blocks its step checkpoints), and both again with a window and
-           seven query
-           heads a key/value head, attention_path saying what the band
-           leaves of a head's tiles and the group); then two steps of the flagship transformer at
+           hvd_flash_bwd; o, lse and the gradients again at the four causal
+           cells' own shapes, the latent cell's 20 heads of 256 and the share
+           cell's window and seven query heads a key/value head among them,
+           attention_path saying the forward's form (operands in place or
+           heads first), the blocks a tile on the diagonal or on a band's
+           edge runs, what the band leaves of a head's tiles and the group,
+           with the blocks the latent cell's step checkpoints); then two
+           steps of the flagship transformer at
            head_dim 128 with the three kernels asserted in the compiled
            program. xent_path says how the LM loss ran (the
            kernel's rows and chunk and its grid steps, or why XLA).
@@ -143,8 +145,7 @@ class Sizes:
     bert_seq: int
     bert4: tuple          # four-chip BERT (global batch, seq)
     attn: tuple           # flash check q/k/v [B, S, H, D]
-    latent: tuple         # the same at the latent cell's head width
-    banded: tuple         # flash check with a band and grouped heads:
+    cells: tuple          # flash checks at the causal cells' shapes, each
     #                       (B, S, H, k/v heads, D, window)
     block: tuple          # block attention checks, each [B, S, H, D]
     xent: tuple           # fused xent check [rows, vocab]
@@ -168,14 +169,14 @@ REAL = Sizes(
     # 512 positions: the shape at which attend picks the block kernels
     bert4=(8, 512),
     attn=(8, 2048, 8, 128),
-    # glm-4.7-flash.s8192's length and head width at 4 of its 20 heads (the
-    # tiles are the length's and the width's; the float32 reference's scores
-    # are 1.1 GB at 4 heads)
-    latent=(1, 8192, 4, 256),
-    # a window of two 1024-tiles under four, seven query heads a k/v head
-    # as in smallthinker-21b-a3b.s8192 (half its length and heads: the
-    # float32 reference's scores are 0.9 GB)
-    banded=(1, 4096, 14, 2, 128, 2048),
+    # the attention core of glm-4.7-flash.s8192, ouro-2.6b.s4096,
+    # smallthinker-21b-a3b.s8192 (a full layer and a window layer: seven
+    # query heads a k/v head, a window of four 1024-tiles under eight) and
+    # gpt-1.3b-widths.s2048, whole: the float32 reference goes a query head
+    # at a time (a head's scores at 8192 keys are 268 MB)
+    cells=((1, 8192, 20, 20, 256, None), (1, 4096, 16, 16, 128, None),
+           (1, 8192, 28, 4, 128, None), (1, 8192, 28, 4, 128, 4096),
+           (2, 2048, 16, 16, 128, None)),
     # the attention core of bert-large.s128 and bert-large.s512
     block=((64, 128, 16, 64), (8, 512, 16, 64)),
     xent=(16384, 32000), blocks=(8192, 256),
@@ -200,8 +201,9 @@ TINY = Sizes(
     bert=dict(vocab_size=512, hidden_size=64, num_layers=2, num_heads=4,
               intermediate_size=128, max_position=64),
     bert_batch=8, bert_seq=16, bert4=(8, 16),
-    attn=(1, 256, 2, 128), latent=(1, 256, 2, 256),
-    banded=(1, 512, 4, 2, 128, 256),
+    attn=(1, 256, 2, 128),
+    cells=((1, 256, 2, 2, 256, None), (1, 512, 4, 2, 128, None),
+           (1, 512, 4, 2, 128, 256), (2, 256, 2, 2, 128, None)),
     block=((2, 128, 2, 64),),
     xent=(256, 1000), blocks=(64, 128),
     gmm=((256, 128, 128, 4, 192), (256, 128, 192, 4, 192)),
@@ -472,7 +474,7 @@ def _kernel_line(smoke: Smoke, kernel: str, what: str, err: float,
     check(err <= tol, f"{kernel} {what}: err {err} > tol {tol}")
 
 
-def _check_flash(smoke: Smoke, shape: tuple, **more) -> None:
+def _check_flash(smoke: Smoke, shape: tuple) -> None:
     import jax
     import jax.numpy as jnp
     from horovod_tpu.ops.pallas_attention import flash_attention_tpu
@@ -489,7 +491,7 @@ def _check_flash(smoke: Smoke, shape: tuple, **more) -> None:
     want = jax.jit(_reference_attention)(q, k, v)
     _kernel_line(smoke, "flash_attention", "fwd", _rel_err(got, want),
                  FLASH_TOL, shape=shape, dtype="bfloat16",
-                 attention_path=_attention_path(shape), **more)
+                 attention_path=_attention_path(shape))
     got = _run_compiled(smoke, jax.grad(_weighted_sum(kernel, w), (0, 1, 2)),
                         (q, k, v), "hvd_flash_bwd")
     want = jax.jit(jax.grad(_weighted_sum(_reference_attention, w),
@@ -497,6 +499,87 @@ def _check_flash(smoke: Smoke, shape: tuple, **more) -> None:
     for name, g, r in zip(("dq", "dk", "dv"), got, want):
         _kernel_line(smoke, "flash_attention", f"grad {name}",
                      _rel_err(g, r), FLASH_TOL)
+
+
+def _check_flash_cells(smoke: Smoke) -> None:
+    """o, lse and the three gradients of the flash kernels at the causal
+    cells' own shapes (``Sizes.cells``) against plain float32 attention at
+    "highest", a query head at a time: the forward's operands where the
+    projections write them, the bands of its tiles on the diagonal and on
+    a window's edge, a k/v head read by its group and dk, dv summed over
+    it. The cotangents weigh o and lse both."""
+    import jax
+    import jax.numpy as jnp
+    from horovod_tpu.ops.pallas_attention import flash_attention_with_lse
+
+    for n, (B, S, H, Hkv, D, window) in enumerate(smoke.sizes.cells):
+        keys = jax.random.split(jax.random.PRNGKey(smoke.seed + 50 + n), 5)
+        q = jax.random.normal(keys[0], (B, S, H, D), jnp.bfloat16)
+        k, v = (jax.random.normal(kk, (B, S, Hkv, D), jnp.bfloat16)
+                for kk in keys[1:3])
+        w = jax.random.normal(keys[3], (B, S, H, D), jnp.float32)
+        u = jax.random.normal(keys[4], (B, H, S), jnp.float32)
+
+        def kernel(q, k, v):
+            o, lse = flash_attention_with_lse(
+                q, k, v, True, interpret=smoke.rehearsal, window=window)
+            return o, lse.reshape(B, H, S)
+
+        def reference(q, k, v):     # one head each: [B, S, D]
+            q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+            with jax.default_matmul_precision("highest"):
+                s = jnp.einsum("bqd,bkd->bqk", q, k) / D ** 0.5
+                t, j = jnp.arange(S)[:, None], jnp.arange(S)[None, :]
+                live = j <= t
+                if window is not None:
+                    live = jnp.logical_and(live, j > t - window)
+                lse = jax.nn.logsumexp(jnp.where(live, s, -jnp.inf), -1)
+                o = jnp.einsum("bqk,bkd->bqd",
+                               jnp.where(live, jnp.exp(s - lse[..., None]),
+                                         0.0), v)
+            return o, lse
+
+        def weighed(f, w, u):
+            def total(q, k, v):
+                o, lse = f(q, k, v)
+                return jnp.sum(o.astype(jnp.float32) * w) + jnp.sum(lse * u)
+            return total
+
+        @jax.jit
+        def head(q, k, v, w, u):
+            return reference(q, k, v), jax.grad(
+                weighed(reference, w, u), (0, 1, 2))(q, k, v)
+
+        o, lse = _run_compiled(smoke, kernel, (q, k, v),
+                               "hvd_flash_attention")
+        dq, dk, dv = _run_compiled(
+            smoke, jax.grad(weighed(kernel, w, u), (0, 1, 2)), (q, k, v),
+            "hvd_flash_bwd")
+        errs = dict.fromkeys(
+            ("fwd o", "fwd lse", "grad dq", "grad dk", "grad dv"), 0.0)
+        group = H // Hkv
+        for g in range(Hkv):
+            dk_ref = dv_ref = 0.0
+            for h in range(g * group, (g + 1) * group):
+                (o_h, lse_h), (dq_h, dk_h, dv_h) = head(
+                    q[:, :, h], k[:, :, g], v[:, :, g], w[:, :, h], u[:, h])
+                dk_ref, dv_ref = dk_ref + dk_h, dv_ref + dv_h
+                for name, got, want in (("fwd o", o[:, :, h], o_h),
+                                        ("fwd lse", lse[:, h], lse_h),
+                                        ("grad dq", dq[:, :, h], dq_h)):
+                    errs[name] = max(errs[name], _rel_err(got, want))
+            for name, got, want in (("grad dk", dk[:, :, g], dk_ref),
+                                    ("grad dv", dv[:, :, g], dv_ref)):
+                errs[name] = max(errs[name], _rel_err(got, want))
+        more = dict(shape=(B, S, H, D), dtype="bfloat16",
+                    attention_path=_attention_path((B, S, H, D), kv_heads=Hkv,
+                                                   window=window))
+        if n == 0:      # glm-4.7-flash.s8192's
+            more.update(_latent_cell(smoke))
+        for what, err in errs.items():
+            _kernel_line(smoke, "flash_attention", what, err, FLASH_TOL,
+                         **more)
+            more = {}
 
 
 def _latent_cell(smoke: Smoke) -> dict:
@@ -526,18 +609,17 @@ def _latent_cell(smoke: Smoke) -> dict:
             else "kept" for kind in kinds})
 
 
-def _check_banded(smoke: Smoke, sizes=None, what: str = "banded") -> None:
-    """The flash kernels with a window and grouped heads against the XLA
-    form of the same function at "highest": the band's edge in both index
-    maps and masks, a k/v head read by its group, dk and dv summed over
-    it. ``sizes``: another shape than ``Sizes.banded``, with the scores'
-    multiplier last (``Sizes.narrow``: a head of 64, heads first)."""
+def _check_banded(smoke: Smoke, sizes: tuple, what: str) -> None:
+    """The flash kernels with grouped heads and a scale of their own
+    against the XLA form of the same function at "highest": a k/v head read
+    by its group, dk and dv summed over it. ``sizes``: ``Sizes.narrow`` (a
+    head of 64, heads first), the scores' multiplier last."""
     import jax
     import jax.numpy as jnp
     from horovod_tpu.ops.pallas_attention import (_banded_attention,
                                                   flash_attention_tpu)
 
-    B, S, H, Hkv, D, window, scale = sizes or smoke.sizes.banded + (None,)
+    B, S, H, Hkv, D, window, scale = sizes
     keys = jax.random.split(jax.random.PRNGKey(smoke.seed + 7), 4)
     q = jax.random.normal(keys[0], (B, S, H, D), jnp.bfloat16)
     k, v = (jax.random.normal(kk, (B, S, Hkv, D), jnp.bfloat16)
@@ -1051,9 +1133,12 @@ def _attention_path(shape, causal=True, masked=False, kv_heads=None,
     ``kv_heads`` heads if given, a key mask if ``masked``, a causal
     ``window``) on the default backend, read from the lowered program; for
     a kernel, what its rule picks for one call: the flash kernel's tile
-    and grid steps, forward and backward, and whether the backward keeps a
-    head's dq in VMEM or goes over the q rows in ranges, the tiles a band
-    leaves of a head's causal tiles and the query heads a k/v head serves;
+    and grid steps, forward and backward, the form of the forward's
+    operands (where they lie, or heads first) and the blocks it runs of a
+    tile on the diagonal and of one on a band's edge, whether the backward
+    keeps a head's dq in VMEM or goes over the q rows in ranges, the tiles a
+    band leaves of a head's causal tiles and the query heads a k/v head
+    serves;
     the block kernels' batch rows and heads a grid step and their VMEM
     estimate."""
     import math
@@ -1085,6 +1170,18 @@ def _attention_path(shape, causal=True, masked=False, kv_heads=None,
     ranges = S // bwd.rows
     form = ("dq resident" if ranges == 1
             else f"dq in {ranges} q ranges of {bwd.rows} rows")
+    def pieces(edge):
+        ran, of = pa.tile_piece_blocks(bq, bk, edge)
+        return (f"{ran} of {of} blocks in "
+                f"{len(pa.tile_pieces(bq, bk, edge))} pieces")
+    fwd = ("operands in place " + str([B, S, H * D]) if D % pa.MIN_BLOCK == 0
+           else "operands heads first " + str([B * H, S, D]))
+    if not pa.banded_tiles(bq, bk, window):
+        fwd += ", a tile the diagonal or the band's edge crosses whole"
+    else:
+        fwd += f", a tile on the diagonal {pieces(False)}"
+        if window is not None:
+            fwd += f", on the band's edge {pieces(True)}"
     band = ""
     if window is not None:
         def tiles(bq, bk):
@@ -1094,7 +1191,7 @@ def _attention_path(shape, causal=True, masked=False, kv_heads=None,
                  f"{tiles(bwd.block_q, bwd.block_k)}")
     if kv_heads not in (None, H):
         band += f"; kv heads {kv_heads}, group {H // kv_heads}"
-    return (f"pallas hvd_flash_attention {bq}x{bk}, {steps} steps; "
+    return (f"pallas hvd_flash_attention {bq}x{bk}, {steps} steps, {fwd}; "
             f"hvd_flash_bwd {bwd.block_q}x{bwd.block_k}, {form}, "
             f"{math.prod(pa.flash_bwd_grid(B, H, S, S, bwd))} steps, VMEM "
             f"estimate {pa.flash_bwd_vmem_bytes(*bwd, D, 2) / 2 ** 20:.1f} "
@@ -1166,8 +1263,7 @@ def _check_flagship(smoke: Smoke, hvd) -> None:
 
 def phase_kernels(smoke: Smoke, hvd) -> None:
     _check_flash(smoke, smoke.sizes.attn)
-    _check_flash(smoke, smoke.sizes.latent, **_latent_cell(smoke))
-    _check_banded(smoke)
+    _check_flash_cells(smoke)
     _check_block(smoke)
     _check_xent(smoke)
     _check_gmm(smoke)

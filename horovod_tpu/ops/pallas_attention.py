@@ -11,6 +11,19 @@ instead of O(S²):
   grid = (batch·heads, Sq/block_q, Sk/block_k)   — K tile innermost
   per (q tile): for each k tile: s = q @ kᵀ; online-softmax update
 
+**Operands where they lie.** A head of whole lane tiles (``D % 128 == 0``)
+is read and written in place: q, k, v and o are ``[B, S, heads·D]``, the
+array a projection writes and ``[B, S, heads, D]`` by a bitcast, and a grid
+step's block is ``(1, tile, D)`` lane columns of it at ``(b // H, tile, b %
+H)`` (k and v at the q head's k/v head). Nothing is transposed around
+either kernel's call (PR 31 the backward, PR 50 the forward: in
+glm-4.7-flash.s8192 the heads-first copies and the fusions that wrote q and
+k heads first were 15.6 ms of a 362.6 ms step). What XLA still puts in
+front of a call is its own choice of layout for what feeds it: at a batch of
+one sequence it keeps activations with the tokens on the lanes and copies
+q and k to row-major where an elementwise op (rope) stands between the
+projection and the call (PERF.md §7).
+
 **Tiles come from the shape** (:func:`flash_blocks`): the largest of
 1024/512/256/128 that divide ``Sq`` / ``Sk``, the q tile halved until
 the working set fits ``VMEM_BUDGET``. A grid step costs about 0.35 µs
@@ -31,13 +44,20 @@ the accumulator and the log-sum-exp are float32 for every input dtype.
 **Causal.** A tile strictly above the diagonal runs no work and fetches
 nothing: the K/V index maps clamp to the q tile's last live tile, and
 Pallas issues no DMA for a block index that repeats. Only the tiles the
-diagonal crosses build the iota mask; those below it skip it.
+diagonal crosses build a mask; those below it skip it. A square tile meets
+the diagonal corner to corner and runs in bands of ``FWD_DIAG_ROWS`` q rows
+(:func:`tile_pieces`): a band leaves out the k columns past its last row,
+which the mask kills for all of it (of a 1024 x 1024 tile 16 of its 64
+blocks of 128 x 128). A masked score added ``exp(-1e30 - m) = 0`` to ``l``
+and to ``p·v``: leaving it out changes no term.
 
 **Window.** ``window=W`` (causal only) keeps of a query at ``t`` the keys
 ``t - W < j <= t``: a band under the diagonal. A tile wholly below the
 band is skipped as one above the diagonal is (the index maps clamp to the
 first live tile too), and a tile the band's lower edge crosses is masked as
-a diagonal tile is. At 8192 x 8192 with 1024 x 1024 tiles and a window of
+a diagonal tile is; where the window is a multiple of the (square) tile the
+edge crosses its tiles corner to corner too, and they run in the mirror
+image of the diagonal's bands. At 8192 x 8192 with 1024 x 1024 tiles and a window of
 4096 a head runs 30 of its 36 causal tiles, four of them edge tiles.
 
 **Grouped heads.** k and v may have fewer heads than q (``H % Hkv == 0``):
@@ -73,9 +93,10 @@ written so: no transpose around the call.
 
 **A head of 64** (half a lane tile) runs both kernels heads-first, ``[B·H,
 S, 64]``: a ``(1, tile, 64)`` block spans the array's whole last dimension,
-which Mosaic takes where it refuses 64 of ``H·64`` lanes; the backward's
-operands are transposed around its call as the forward's always are (at 32
-/ 8 heads over 4096 positions 16 MB a q-sized array). The MXU contracts
+which Mosaic takes where it refuses 64 of ``H·64`` lanes; both kernels'
+operands are transposed around their calls (at 32 / 8 heads over 4096
+positions 16 MB a q-sized array). The form follows ``D % 128``, which the
+code sees in its input: no setting, one kernel each. The MXU contracts
 over 64 of its 128 rows for the scores and fills 64 of its columns for the
 outputs: half empty either way, as two heads side by side on one lane tile
 with the other's lanes zeroed would leave it (``ops/pallas_ssm.py``), and
@@ -227,6 +248,51 @@ def _last_band_q_tile(kj, block_q: int, block_k: int, window: int):
     return (kj * block_k + block_k - 1 + window - 1) // block_q
 
 
+#: q rows of a piece of a square tile on the diagonal or on a window's edge
+#: in the forward (:func:`tile_pieces`). v5e, a 1024 x 1024 tile, the
+#: kernel's ms a call, the tile whole / pieces of 128 / 256 / 512: [1, 8192,
+#: 20, 256] 5.327 / 5.207 / 5.040 / 5.083, [1, 4096, 16, 128] 0.745 / 0.769 /
+#: 0.720 / 0.695, [1, 8192, 28 on 4, 128] 4.397 / 4.485 / 4.312 / 4.224 and
+#: under a window of 4096 3.827 / 3.914 / 3.710 / 3.594, [2, 2048, 16, 128]
+#: 0.493 / 0.518 / 0.468 / 0.443 (PERF.md, PR 50). A piece of 128 rows
+#: leaves out 28 of a tile's 64 blocks and still costs more than the tile
+#: whole: a short piece streams few rows past each k block the MXU loads,
+#: where the backward's pieces (``DIAG_ROWS``) are k rows against many q rows
+FWD_DIAG_ROWS = 512
+
+
+def banded_tiles(block_q: int, block_k: int, window: Optional[int]) -> bool:
+    """Whether the forward runs the tiles the diagonal (and a window's lower
+    edge) crosses in :func:`tile_pieces`: square tiles, which the diagonal
+    crosses corner to corner, and a window of whole tiles, so that its edge
+    does too. Else such a tile runs whole, under its mask."""
+    return block_q == block_k and (window is None or window % block_k == 0)
+
+
+def tile_pieces(block_q: int, block_k: int, edge: bool = False):
+    """The static pieces the forward runs of a square tile that the diagonal
+    (or, ``edge``, a window's lower edge) crosses corner to corner, as
+    ``(r0, rows, c0, cols)``: q rows ``[r0, r0 + rows)`` against k columns
+    ``[c0, c0 + cols)`` of the tile, in bands of ``FWD_DIAG_ROWS`` q rows
+    that leave out the columns the mask kills for the whole band, the ones
+    past the band's last row on the diagonal and the ones before its first
+    row on the edge: a 1024 x 1024 tile runs 48 of its 64 blocks of 128 x
+    128 in two pieces."""
+    rows = min(FWD_DIAG_ROWS, block_q)
+    return [(r0, rows, r0, block_k - r0) if edge else (r0, rows, 0, r0 + rows)
+            for r0 in range(0, block_q, rows)]
+
+
+def tile_piece_blocks(block_q: int, block_k: int,
+                      edge: bool = False) -> Tuple[int, int]:
+    """(the ``MIN_BLOCK`` x ``MIN_BLOCK`` blocks :func:`tile_pieces` runs
+    of such a tile, the blocks the tile has): (48, 64) at 1024 x 1024. For
+    ``chip_smoke.py``'s ``attention_path`` and the tests."""
+    ran = sum(rows * cols for _, rows, _, cols
+              in tile_pieces(block_q, block_k, edge))
+    return ran // MIN_BLOCK ** 2, block_q * block_k // MIN_BLOCK ** 2
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
                   l_ref, *, scale: float, causal: bool, block_q: int,
                   block_k: int, window: Optional[int] = None):
@@ -240,56 +306,76 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    def body(masked: bool):
-        q = q_ref[0]                               # [bq, D]
-        k = k_ref[0]                               # [bk, D]
-        v = v_ref[0]                               # [bk, D]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+    def update(masked: bool, piece=(0, block_q, 0, block_k)):
+        """The online-softmax update of the tile's q rows ``[r0, r0 +
+        rows)`` over its k columns ``[c0, c0 + cols)`` (static)."""
+        r0, rows, c0, cols = piece
+        rs, cs = pl.ds(r0, rows), pl.ds(c0, cols)
+        q = q_ref[0, rs]                           # [rows, D]
+        k = k_ref[0, cs]                           # [cols, D]
+        v = v_ref[0, cs]                           # [cols, D]
+        s = _dot(q, k, _NT) * scale
         if masked:
-            qpos = q_idx * block_q + jax.lax.broadcasted_iota(
+            qpos = q_idx * block_q + r0 + lax.broadcasted_iota(
                 jnp.int32, s.shape, 0)
-            kpos = kv_idx * block_k + jax.lax.broadcasted_iota(
+            kpos = kv_idx * block_k + c0 + lax.broadcasted_iota(
                 jnp.int32, s.shape, 1)
             live = qpos >= kpos
             if window is not None:
                 live = jnp.logical_and(live, kpos > qpos - window)
             s = jnp.where(live, s, NEG_INF)
-        m_prev = m_ref[:]
-        l_prev = l_ref[:]
-        m_cur = jnp.max(s, axis=-1)[:, None]       # [bq, 1]
+        m_prev = m_ref[rs]
+        l_prev = l_ref[rs]
+        m_cur = jnp.max(s, axis=-1)[:, None]       # [rows, 1]
         m_next = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_next)                    # [bq, bk]
+        p = jnp.exp(s - m_next)                    # [rows, cols]
         alpha = jnp.exp(m_prev - m_next)
         l_next = l_prev * alpha + jnp.sum(p, -1)[:, None]
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[:] = m_next
-        l_ref[:] = l_next
+        acc_ref[rs] = acc_ref[rs] * alpha + _dot(p.astype(v.dtype), v, _NN)
+        m_ref[rs] = m_next
+        l_ref[rs] = l_next
 
+    def whole(masked: bool):
+        return functools.partial(update, masked)
+
+    def pieces(edge: bool):
+        """A tile the diagonal (or the band's edge) crosses corner to
+        corner, without the blocks the mask kills (:func:`tile_pieces`)."""
+        def run():
+            for piece in tile_pieces(block_q, block_k, edge):
+                update(True, piece)
+        return run
+
+    banded = banded_tiles(block_q, block_k, window)
     if causal:
         first_row = q_idx * block_q
         first_col = kv_idx * block_k
         last_col = first_col + block_k - 1
-        # the diagonal crosses the tile: some of it is masked, not all
+        # the diagonal crosses the tile: some of it is masked, not all.
+        # Square tiles meet it corner to corner (q_idx == kv_idx)
         crossed = jnp.logical_and(first_col <= first_row + block_q - 1,
                                   last_col > first_row)
         if window is None:
-            pl.when(crossed)(functools.partial(body, True))
+            pl.when(crossed)(pieces(False) if banded else whole(True))
             # wholly at or below the diagonal: no mask to build. Tiles
-            # strictly above it run nothing (and fetch nothing: kv_index)
-            pl.when(last_col <= first_row)(functools.partial(body, False))
+            # strictly above it run nothing (and fetch nothing: k_tile)
+            pl.when(last_col <= first_row)(whole(False))
         else:
-            crossed, whole = _band_tiles(first_row, first_col, block_q,
+            crossed, clean = _band_tiles(first_row, first_col, block_q,
                                          block_k, window)
-            pl.when(crossed)(functools.partial(body, True))
-            # a row the band's edge masks for all of its first tile holds
-            # garbage under m = NEG_INF until its next tile's alpha = 0
-            # wipes it: every row's diagonal tile comes later
-            pl.when(whole)(functools.partial(body, False))
+            # a row the band's edge masks for all of a piece (of its first
+            # tile) holds garbage under m = NEG_INF until its next piece's
+            # alpha = 0 wipes it: every row's diagonal piece comes later
+            if banded:
+                # the diagonal and the edge cross different tiles, each
+                # corner to corner
+                pl.when(q_idx == kv_idx)(pieces(False))
+                pl.when(first_col == first_row - window)(pieces(True))
+            else:
+                pl.when(crossed)(whole(True))
+            pl.when(clean)(whole(False))
     else:
-        body(False)
+        whole(False)()
 
     @pl.when(kv_idx == pl.num_programs(2) - 1)
     def _finalize():
@@ -302,59 +388,75 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
         lse_ref[0, 0] = (m_ref[:] + jnp.log(l_safe))[:, 0]
 
 
+def _heads_first(x):
+    """``[B, S, heads, D]`` -> ``[B * heads, S, D]``: the operands' form at
+    a head of ``NARROW_HEAD``, which is no whole lane column of ``[B, S,
+    heads * D]``."""
+    return x.transpose(0, 2, 1, 3).reshape(-1, x.shape[1], x.shape[3])
+
+
 def _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret,
                     window=None):
     """Run the kernel; q [B, S, H, D], k/v [B, S, Hkv, D] → (o [B, S, H,
-    D], lse [BH, Sq])."""
+    D], lse [BH, Sq]). A head of whole lane tiles (``D % MIN_BLOCK == 0``)
+    is read and written where it lies, as 128-lane columns of ``[B, S,
+    heads * D]`` (a bitcast of the operand); a head of 64 goes heads first,
+    transposed around the call."""
     B, Sq, H, D = q.shape
     Sk, Hkv = k.shape[1], k.shape[2]
     group = _group(H, Hkv)
     _check_window(window, causal)
-    # layout: fold batch & heads; tiles over sequence
-    qf = q.transpose(0, 2, 1, 3).reshape(B * H, Sq, D)
-    kf = k.transpose(0, 2, 1, 3).reshape(B * Hkv, Sk, D)
-    vf = v.transpose(0, 2, 1, 3).reshape(B * Hkv, Sk, D)
-
-    if group == 1:
-        def kv_head(b):
-            return b
-    else:
-        def kv_head(b):      # q head h of a batch row reads kv head h // group
-            return (b // H) * Hkv + (b % H) // group
+    in_place = D % MIN_BLOCK == 0
 
     if window is not None:
         # below the band as above the diagonal: the index stays on a live
         # tile, from both sides
-        def kv_index(b, i, j):
-            return (kv_head(b), jnp.clip(
-                j, _first_band_k_tile(i, block_q, block_k, window),
-                _last_live_k_tile(i, block_q, block_k)), 0)
+        def k_tile(i, j):
+            return jnp.clip(j, _first_band_k_tile(i, block_q, block_k, window),
+                            _last_live_k_tile(i, block_q, block_k))
     elif causal:
         # above the diagonal the block index repeats the q tile's last
         # live tile, so the pipeline issues no DMA for a skipped step
-        def kv_index(b, i, j):
-            return (kv_head(b),
-                    jnp.minimum(j, (i * block_q + block_q - 1) // block_k),
-                    0)
+        def k_tile(i, j):
+            return jnp.minimum(j, _last_live_k_tile(i, block_q, block_k))
     else:
-        def kv_index(b, i, j):
-            return (kv_head(b), j, 0)
+        def k_tile(i, j):
+            return j
+
+    # grid axis 0 is (batch row, q head); q head h reads kv head h // group
+    if in_place:
+        operands = (q.reshape(B, Sq, H * D), k.reshape(B, Sk, Hkv * D),
+                    v.reshape(B, Sk, Hkv * D))
+
+        def q_at(b, i, j):
+            return (b // H, i, b % H)
+
+        def k_at(b, i, j):
+            return (b // H, k_tile(i, j), b % H // group)
+    else:
+        operands = (_heads_first(q), _heads_first(k), _heads_first(v))
+
+        def q_at(b, i, j):
+            return (b, i, 0)
+
+        def k_at(b, i, j):
+            return (b // H * Hkv + b % H // group, k_tile(i, j), 0)
 
     out, lse = pl.pallas_call(
         functools.partial(_flash_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, window=window),
         grid=flash_grid(B, H, Sq, Sk, block_q, block_k),
         in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, D), kv_index),
-            pl.BlockSpec((1, block_k, D), kv_index),
+            pl.BlockSpec((1, block_q, D), q_at),
+            pl.BlockSpec((1, block_k, D), k_at),
+            pl.BlockSpec((1, block_k, D), k_at),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, D), q_at),
             pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B * H, Sq, D), q.dtype),
+            jax.ShapeDtypeStruct(operands[0].shape, q.dtype),
             jax.ShapeDtypeStruct((B * H, 1, Sq), jnp.float32),
         ],
         scratch_shapes=[
@@ -366,9 +468,10 @@ def _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret,
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="hvd_flash_attention",
-    )(qf, kf, vf)
-    return (out.reshape(B, H, Sq, D).transpose(0, 2, 1, 3),
-            lse.reshape(B * H, Sq))
+    )(*operands)
+    if not in_place:
+        out = out.reshape(B, H, Sq, D).transpose(0, 2, 1, 3)
+    return out.reshape(B, Sq, H, D), lse.reshape(B * H, Sq)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
@@ -715,6 +818,11 @@ def flash_backward(q, k, v, o, lse, do, dlse, causal: bool, scale: float,
     Sk, Hkv = k.shape[1], k.shape[2]
     _check_window(window, causal)
     blocks = blocks or flash_bwd_blocks(Sq, Sk, D, q.dtype)
+    # o's float32 copy is made when do arrives, not before: alone, the
+    # conversion depends on the forward pass only, and XLA:TPU has started it
+    # there and kept 4 bytes an element of o alive into the backward pass in
+    # place of 2 (smallthinker-21b-a3b.s8192: +0.23 GB; PERF.md, PR 50)
+    do, o = lax.optimization_barrier((do, o))
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     adj = delta.transpose(0, 2, 1).reshape(B * H, Sq) \
         - dlse.astype(jnp.float32)
@@ -747,12 +855,11 @@ def _flash_backward_heads_first(q, k, v, do, lse, adj, causal, scale, blocks,
     B, Sq, H, D = q.shape
     Sk, Hkv = k.shape[1], k.shape[2]
 
-    def first(x):       # [B, S, heads, D] -> [B * heads, S, D]
-        return x.transpose(0, 2, 1, 3).reshape(-1, x.shape[1], D)
     dq, dk, dv = _flash_bwd_local(
-        first(q), first(k), first(v), first(do), lse.reshape(B * H, 1, Sq),
-        adj.reshape(B * H, 1, Sq), H=H, causal=causal, scale=scale,
-        blocks=blocks, interpret=interpret, window=window, heads_first=True)
+        *(_heads_first(x) for x in (q, k, v, do)),
+        lse.reshape(B * H, 1, Sq), adj.reshape(B * H, 1, Sq), H=H,
+        causal=causal, scale=scale, blocks=blocks, interpret=interpret,
+        window=window, heads_first=True)
 
     def total(parts):   # [ranges, B * H, Sk, D] -> [B, Sk, Hkv, D]
         parts = parts.reshape(-1, B, Hkv, H // Hkv, Sk, D)

@@ -520,3 +520,182 @@ def test_flash_tiles_at_every_cell_s_shape(cell):
     blocks = pa.flash_bwd_blocks(S, S, D, jnp.bfloat16)
     assert tuple(blocks) == bwd and blocks.rows == S     # dq resident
     assert pa.flash_bwd_vmem_bytes(*blocks, D, 2) <= pa.BWD_VMEM_BUDGET
+
+
+# -- the forward in the backward's form (PR 50): operands in place as
+# [B, S, heads * D], a diagonal or edge tile in bands of DIAG_ROWS q rows ----
+
+def _heads(B, S, H, Hkv, D, seed=50):
+    rng = np.random.RandomState(seed)
+
+    def mk(heads):
+        return jnp.asarray(rng.randn(B, S, heads, D) * 0.5, jnp.float32)
+    return mk(H), mk(Hkv), mk(Hkv)
+
+
+def _banded_lse(q, k, window, scale):
+    """A row's log-partition over its live keys, ``[B * H, S]`` float32."""
+    B, S, H, D = q.shape
+    Hkv = k.shape[2]
+    s = jnp.einsum("bqhgd,bkhd->bhgqk",
+                   q.reshape(B, S, Hkv, H // Hkv, D), k) * scale
+    t, j = jnp.arange(S)[:, None], jnp.arange(S)[None, :]
+    live = j <= t
+    if window is not None:
+        live = jnp.logical_and(live, j > t - window)
+    return jax.nn.logsumexp(jnp.where(live, s, -jnp.inf), axis=-1).reshape(
+        B * H, S)
+
+
+#: name -> (B, S, H, Hkv, D, block_q, block_k, window): square tiles on the
+#: diagonal, on a window's edge (the window a multiple of the tile) and
+#: whole where the band's edge cuts a tile anywhere else or the tile is not
+#: square; grouped heads as the share cell's 28 / 4; a head of two lane
+#: tiles; a head of 64 (heads first). Bands of 128 rows, so that a tile of
+#: 256 has two and one of 512 four; the last two at ``FWD_DIAG_ROWS`` as it
+#: is: two bands a 1024 x 1024 tile
+_BANDED = {
+    "causal": (1, 512, 2, 2, 128, 256, 256, None),
+    "causal, four bands a tile": (1, 512, 1, 1, 128, 512, 512, None),
+    "two batch rows of three heads": (2, 256, 3, 3, 128, 256, 256, None),
+    "window a multiple of the tile": (1, 768, 2, 2, 128, 256, 256, 256),
+    "window of two tiles": (1, 1024, 1, 1, 128, 256, 256, 512),
+    "window no multiple of the tile": (1, 768, 2, 2, 128, 256, 256, 320),
+    "grouped heads 28 / 4": (1, 256, 28, 4, 128, 256, 256, None),
+    "grouped heads under a window": (1, 512, 4, 2, 128, 256, 256, 256),
+    "a head of 256": (1, 512, 2, 2, 256, 256, 256, None),
+    "a head of 64, heads first": (1, 512, 8, 2, 64, 256, 256, None),
+    "a head of 64 under a window": (1, 512, 4, 2, 64, 256, 256, 256),
+    "q tile wider than k tile": (1, 512, 2, 2, 128, 256, 128, None),
+    "k tile wider than q tile": (1, 512, 2, 1, 128, 128, 256, None),
+    "k tile wider, a window": (1, 512, 2, 2, 128, 128, 256, 256),
+    "the cells' tile, causal": (1, 2048, 1, 1, 128, 1024, 1024, None),
+    "the cells' tile, a window": (1, 3072, 2, 1, 128, 1024, 1024, 1024),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_BANDED))
+def test_flash_forward_in_place_and_banded_is_the_banded_form(case,
+                                                              monkeypatch):
+    """o, lse and the three gradients (of a loss that reads o and lse) of
+    the kernels against ``_plain_attention`` / ``_banded_attention`` in
+    float32, autodiff through it for the gradients."""
+    B, S, H, Hkv, D, bq, bk, window = _BANDED[case]
+    if bq < 1024:
+        monkeypatch.setattr(pa, "FWD_DIAG_ROWS", 128)
+    assert (len(pa.tile_pieces(bq, bq)) > 1) == (bq >= 256)
+    q, k, v = _heads(B, S, H, Hkv, D)
+    scale = 1.0 / D ** 0.5
+    w = jnp.cos(jnp.arange(q.size, dtype=jnp.float32).reshape(q.shape))
+    u = jnp.sin(jnp.arange(B * H * S, dtype=jnp.float32).reshape(B * H, S))
+
+    def flash(q, k, v):
+        return pa.flash_attention_with_lse(q, k, v, True, None, bq, bk,
+                                           interpret=True, window=window)
+
+    def reference(q, k, v):
+        o = (_plain_attention(q, k, v, True) if window is None and H == Hkv
+             else pa._banded_attention(q, k, v, window))
+        return o, _banded_lse(q, k, window, scale)
+
+    def loss(f):
+        def total(q, k, v):
+            o, lse = f(q, k, v)
+            return jnp.sum(o * w) + jnp.sum(lse * u)
+        return total
+
+    (o, lse), (o_ref, lse_ref) = flash(q, k, v), reference(q, k, v)
+    assert o.shape == q.shape and lse.shape == (B * H, S)
+    assert lse.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref), rtol=2e-5,
+                               atol=2e-5)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(lse_ref),
+                               rtol=2e-5, atol=2e-5)
+    got = jax.grad(loss(flash), (0, 1, 2))(q, k, v)
+    want = jax.grad(loss(reference), (0, 1, 2))(q, k, v)
+    for g, r, name in zip(got, want, ("dq", "dk", "dv")):
+        assert g.shape == r.shape, name
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), rtol=2e-4,
+                                   atol=2e-4, err_msg=name)
+
+
+@pytest.mark.parametrize("shape", [(1, 512, 4, 4, 128), (2, 256, 4, 2, 128),
+                                   (1, 256, 2, 2, 256)])
+def test_flash_forward_transposes_nothing_at_a_head_of_whole_lane_tiles(
+        shape):
+    """``_flash_fwd_impl`` at ``D % 128 == 0``: q, k, v go into the call
+    and o comes out of it by reshapes alone, ``[B, S, heads * D]``."""
+    B, S, H, Hkv, D = shape
+    q = jax.ShapeDtypeStruct((B, S, H, D), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((B, S, Hkv, D), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(lambda q, k, v: pa._flash_fwd_impl(
+        q, k, v, True, 0.1, 256, 256, False))(q, kv, kv)
+    outer = [str(e.primitive) for e in jaxpr.eqns]
+    assert "transpose" not in outer and "pallas_call" in outer, outer
+    call = next(e for e in jaxpr.eqns if str(e.primitive) == "pallas_call")
+    assert [tuple(x.aval.shape) for x in call.invars] == [
+        (B, S, H * D), (B, S, Hkv * D), (B, S, Hkv * D)]
+    assert [tuple(x.aval.shape) for x in call.outvars] == [
+        (B, S, H * D), (B * H, 1, S)]
+
+
+def test_flash_forward_goes_heads_first_at_a_head_of_64():
+    q = jax.ShapeDtypeStruct((1, 256, 8, 64), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 256, 2, 64), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(lambda q, k, v: pa._flash_fwd_impl(
+        q, k, v, True, 0.1, 256, 256, False))(q, kv, kv)
+    call = next(e for e in jaxpr.eqns if str(e.primitive) == "pallas_call")
+    assert [tuple(x.aval.shape) for x in call.invars] == [
+        (8, 256, 64), (2, 256, 64), (2, 256, 64)]
+    assert sum(str(e.primitive) == "transpose" for e in jaxpr.eqns) == 4
+
+
+def _live(r, c, edge):
+    """Whether score (q row r, k column c) of a square tile whose corners
+    lie on the diagonal (or, ``edge``, on a window's lower edge) is live."""
+    return c > r if edge else c <= r
+
+
+@pytest.mark.parametrize("edge", [False, True])
+@pytest.mark.parametrize("tile", [128, 256, 512, 1024])
+def test_tile_pieces_cover_every_live_score_once_and_no_dead_block(tile,
+                                                                   edge):
+    """The pieces of a diagonal tile are 48 of a 1024 x 1024 tile's 64
+    blocks of 128 x 128 (36 of them hold a live score; bands of 128 rows
+    would run those alone and cost more, ``FWD_DIAG_ROWS``) and of an edge
+    tile the mirror image; every live score lies in exactly one piece, and
+    every ``FWD_DIAG_ROWS`` x ``FWD_DIAG_ROWS`` block a piece holds has a
+    live score."""
+    pieces = pa.tile_pieces(tile, tile, edge)
+    rows = min(pa.FWD_DIAG_ROWS, tile)
+    bands, unit = tile // rows, (rows // pa.MIN_BLOCK) ** 2
+    assert pa.tile_piece_blocks(tile, tile, edge) == (
+        unit * bands * (bands + 1) // 2, (tile // pa.MIN_BLOCK) ** 2)
+    if tile == 1024:
+        assert pa.tile_piece_blocks(tile, tile, edge) == (48, 64)
+        assert len(pieces) == 2
+    covered = np.zeros((tile, tile), int)
+    for r0, n, c0, cols in pieces:
+        assert n == rows and cols % rows == 0
+        covered[r0:r0 + n, c0:c0 + cols] += 1
+    r, c = np.mgrid[:tile, :tile]
+    live = _live(r, c, edge)
+    assert covered.max() == 1 and (covered[live] == 1).all()
+    blocks = covered.reshape(bands, rows, bands, rows).max((1, 3)) > 0
+    assert (blocks == live.reshape(bands, rows, bands, rows).any(
+        (1, 3))).all()
+    # the edge's pieces are the diagonal's, mirrored in both axes
+    mirror = sorted((tile - r0 - n, n, tile - c0 - cols, cols)
+                    for r0, n, c0, cols in pa.tile_pieces(tile, tile,
+                                                          not edge))
+    assert sorted(pieces) == mirror
+
+
+@pytest.mark.parametrize("tiles", [
+    (1024, 1024, None, True), (1024, 1024, 4096, True),
+    (256, 256, 256, True), (1024, 1024, 1536, False),
+    (512, 1024, None, False), (1024, 512, 2048, False)])
+def test_tiles_run_in_bands_where_the_mask_s_lines_cross_them_corner_to_corner(
+        tiles):
+    block_q, block_k, window, banded = tiles
+    assert pa.banded_tiles(block_q, block_k, window) is banded

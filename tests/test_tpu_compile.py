@@ -438,6 +438,77 @@ def test_head_touches_the_logits_once(cell, v5e, no_compile_cache,
 
 # -- whole train steps of the benchmark's flagship cells ---------------------
 
+# -- the forward flash kernel where its operands lie (ISSUE 50) ---------------
+
+#: a checkpointed attention block, forward and backward, at a cell's real
+#: widths: (TransformerConfig fields, the block's stack, positions).
+#: glm-4.7-flash.s8192's latent block (20 heads of 256: ``flash_vmem_bytes``
+#: of its 1024 x 1024 tile is ``VMEM_BUDGET`` to the byte) and
+#: ouro-2.6b.s4096's plain one (16 heads of 128)
+_ATTENTION_BLOCKS = {
+    "latent block, 20 heads of 256": (dict(
+        d_model=2048, n_heads=20, head_width=256, q_latent=768,
+        kv_latent=512, rope_width=64, layer_pattern=(("latent",),)),
+        "latent", 8192),
+    "plain block, 16 heads of 128": (dict(d_model=2048, n_heads=16),
+                                     None, 4096),
+}
+
+
+@pytest.mark.parametrize("block", sorted(_ATTENTION_BLOCKS))
+def test_attention_block_hands_the_forward_kernel_its_operands_in_place(
+        block, v5e, no_compile_cache, monkeypatch):
+    """``hvd_flash_attention`` reads q, k, v and writes o as ``[1, S, H *
+    D]`` in both of a checkpointed block's calls, under the default scoped
+    VMEM (no limit is asked for), and the program holds no heads-first
+    copy of any of them: no ``[H, S, D]`` array (the parent's ``copy`` and
+    ``transpose`` between ``[1, 8192, 20, 256]`` and ``[20, 8192, 256]``),
+    so whatever lies beside the call moves ``[1, S, ..]`` arrays only."""
+    import numpy as np
+    fields, stack, S = _ATTENTION_BLOCKS[block]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = transformer.TransformerConfig(
+        n_layers=1, dtype=jnp.bfloat16, max_seq=S, vocab_size=1024, **fields)
+    H, D = cfg.n_heads, cfg.head_dim
+    layers = jax.eval_shape(lambda: transformer.init_params(
+        np.random.RandomState(0), cfg, 1))["layers"]
+    params = jax.tree_util.tree_map(
+        lambda v: jax.ShapeDtypeStruct(v.shape[2:], v.dtype, sharding=v5e),
+        layers[stack] if stack else layers)
+    h = jax.ShapeDtypeStruct((1, S, cfg.d_model), jnp.bfloat16, sharding=v5e)
+    positions = jax.ShapeDtypeStruct((S,), jnp.int32, sharding=v5e)
+    apply = (transformer._BLOCK_KINDS[stack].apply if stack else
+             lambda p, x, pos, cfg, kind: (
+                 transformer._attention_block(p, x, pos, cfg), None))
+    kind = (stack,) if stack else transformer._PLAIN_LAYER
+
+    def loss(p, h, positions):
+        run = jax.checkpoint(lambda p, h: apply(p, h, positions, cfg,
+                                                kind)[0])
+        return _sum32(jnp.square(run(p, h)))
+    assert pa.flash_vmem_bytes(*pa.flash_blocks(S, S, D, jnp.bfloat16), D,
+                               2) <= pa.VMEM_BUDGET
+    text = jax.jit(jax.grad(loss, (0, 1))).lower(
+        params, h, positions).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line
+             and "hvd_flash_attention" in line.split(" = ")[0]]
+    assert len(calls) == 2, calls          # the block's, and its recomputation
+    where = f"bf16[1,{S},{H * D}]"
+    for call in calls:
+        result, operands = call.split(" custom-call(")
+        operands = operands.split("), custom_call_target")[0]
+        assert result.count(where) == 1 and f"f32[{H},1,{S}]" in result, call
+        assert operands.count(",") == 2, call
+    defined = dict(re.findall(r"^\s*(?:ROOT )?(%\S+) = (\S+) ", text, re.M))
+    for call in calls:
+        for operand in re.findall(r"%[\w.\-]+", call.split(
+                " custom-call(")[1].split(")")[0]):
+            assert defined[operand].startswith(where), (operand, call)
+    heads_first = re.findall(rf"\w+\[(?:1,)?{H},{S},{D}\]", text)
+    assert not heads_first, sorted(set(heads_first))
+
+
 _CHIP = os.path.join(_REPO, "benchmarks", "chip")
 
 
