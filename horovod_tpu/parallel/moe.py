@@ -519,13 +519,13 @@ def _ffn_held_bwd(activation, tiles, interpret, res, g):
     d_hidden = _gmm_d_rows(down, interpret, hs[0], we2, group_sizes, g)
 
     def cotangents(*chunks):
-        # from a chunk of ``hs``: the hidden rows as the forward pass made
-        # them, and the activation's derivative in float32
+        # of a chunk: the activation's derivative in float32, the rows again
         *hs, d_hidden = chunks
         d_hs = jax.vjp(functools.partial(_hidden, activation),
                        *(h.astype(jnp.float32) for h in hs))[1](
                            d_hidden.astype(jnp.float32))
-        return (*d_hs, _hidden(activation, *hs))
+        with jax.named_scope(scopes.RECOMPUTE):     # as the forward made them
+            return (*d_hs, _hidden(activation, *hs))
     # each written over what it was made from, which nothing reads after:
     # the cotangents over ``hs``, the hidden rows over their own cotangent
     *d_hs, hidden = _in_held_rows(cotangents, held, *hs, d_hidden)

@@ -21,14 +21,46 @@ Host spans go through :func:`horovod_tpu.profiling.annotate`, which also
 keeps them in the host log (``profiling/host_log.py``) outside a profiler
 session.
 
+**Owner and reason.** A reader that gives every executed device
+instruction to one part of the step
+(``benchmarks/chip/readers/step_owners.py``) takes two things from an
+instruction's path. Its *owner* is the path's phases in their order
+(``hvd.layers/hvd.ssm/hvd.ssm.conv``): what part of the model the work is
+for. Its *reason* is why the work ran, and two reasons are names in the
+path: :data:`RECOMPUTED`, the component JAX itself writes around the
+second run of a ``jax.checkpoint``ed function when its transposition runs
+it again (``.../checkpoint/rematted_computation/hvd.mlp/..``: every
+checkpointed block of ``models/transformer.py`` and the held share's
+``gathered`` of ``parallel/moe.py``), and :data:`RECOMPUTE`, the scope the
+program puts where a hand-written ``custom_vjp`` backward runs forward work
+again *outside* a kernel. One place does today, ``parallel/moe.py``'s
+``_ffn_held_bwd`` (the hidden rows, made again from the kept products for
+the way down's weight gradient). Looked through and found to run none: the
+other backwards of ``parallel/moe.py`` (``_gmm_bwd``, ``_dispatch_bwd``,
+``_combine_bwd``), ``models/transformer.py:_table_rows_bwd``,
+``ops/pallas_xent.py`` (both keep the softmax's derivative),
+``ops/pallas_attention.py`` and ``ops/pallas_ssm.py`` (their backwards lay
+the kept operands out for the kernel again, heads first or column and row
+form: transposes, which a reader files under relayout whatever their name).
+Work made again *inside* a kernel (``hvd_flash_bwd``'s scores,
+``hvd_ssm_scan_bwd``'s decays) is the kernel's and has no name of its own.
+
 A model that needs another phase adds it here, and
 nowhere else: ``tests/test_scopes.py`` holds the strings to this file.
-The Pallas kernels' names (``hvd_flash_attention``, ``hvd_flash_bwd``,
-``hvd_fused_xent``) are instruction names, not scopes, and stay where the
-kernels are. A metric finds a kernel by searching its pattern in the
-instruction's name, so no kernel's name holds another's: attention's
-backward is not ``hvd_flash_attention_bwd``, which every metric of the
-forward kernel would sum in.
+The Pallas kernels' names are instruction names, not scopes, and stay where
+the kernels are: of the train steps ``hvd_flash_attention`` and
+``hvd_flash_bwd``, ``hvd_block_attention`` and ``hvd_block_attention_bwd``
+(``ops/pallas_attention.py``), ``hvd_fused_xent`` (``ops/pallas_xent.py``),
+``hvd_ssm_scan`` and ``hvd_ssm_scan_bwd`` (``ops/pallas_ssm.py``),
+``hvd_moe_gmm`` (``parallel/moe.py``, a scope around the grouped-matmul
+kernels, which keep megablox's own names under it); of the compressed
+exchange ``hvd_block_quantize``, ``hvd_block_quantize_ef``,
+``hvd_block_dequantize``, ``hvd_fused_sgd_apply`` and
+``hvd_fused_adam_apply`` (``ops/pallas_quantize.py``). A metric finds a
+kernel by searching its pattern in the instruction's name, so no kernel's
+name holds another's: attention's backward is not
+``hvd_flash_attention_bwd``, which every metric of the forward kernel would
+sum in.
 """
 
 from __future__ import annotations
@@ -97,6 +129,15 @@ MTP_PROJ = "hvd.mtp.proj"
 GRAD_SYNC = "hvd.grad_sync"
 OPTIMIZER = "hvd.optimizer"
 
+# -- reasons (the module docstring's "Owner and reason") ----------------------
+#: JAX's own path component (``ad_checkpoint.py``) around a
+#: ``jax.checkpoint``ed function's second run; ``tests/test_scopes.py`` tells
+#: the JAX upgrade that renames it
+RECOMPUTED = "rematted_computation"
+#: where a hand-written backward runs forward work again outside a kernel; a
+#: reason, not a part of the model: a reader leaves it out of an owner
+RECOMPUTE = "hvd.recompute"
+
 #: phases of the model proper: each appears forward and backward
 MODEL_PHASES = (EMBED, LAYERS, ATTENTION, ATTENTION_CORE, MLP, HEAD)
 #: phases only an MoE model has, each forward and backward
@@ -114,7 +155,8 @@ MIXED_PHASES = (ATTENTION_CORE_WINDOW, ATTENTION_CORE_FULL)
 LATENT_PHASES = (ATTENTION_LATENT, ATTENTION_LATENT_DOWN, ATTENTION_LATENT_UP,
                  MTP, MTP_PROJ)
 DEVICE_PHASES = (MODEL_PHASES + MOE_PHASES + LOOP_PHASES + MIXED_PHASES
-                 + HYBRID_PHASES + LATENT_PHASES + (GRAD_SYNC, OPTIMIZER))
+                 + HYBRID_PHASES + LATENT_PHASES
+                 + (GRAD_SYNC, OPTIMIZER, RECOMPUTE))
 
 # -- host spans (profiling.annotate) ------------------------------------------
 #: the input iterator's ``next()``: the host makes the batch

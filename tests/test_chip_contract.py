@@ -21,7 +21,14 @@ cannot go stale:
   nothing read: None, never a raise), a ``reader`` names a file under
   ``readers/`` whose imports from the product resolve like ``run.py``'s.
   The cases are the benchmark's own (``benchmarks/chip/tests/
-  test_layer_metrics.py``), imported here so that tier-1 runs them.
+  test_layer_metrics.py``), imported here so that tier-1 runs them: 106
+  entries and files since PR 51 (96 before it), two cases each, and two
+  readers (``host_pauses``, ``step_owners``);
+* the reader that gives every device instruction one owner and one reason
+  (``readers/step_owners.py``), by its own cases
+  (``benchmarks/chip/tests/test_step_owners.py``, imported the same way):
+  it reads the program's path components by name (``rematted_computation``,
+  ``hvd.recompute``, every ``hvd.*`` phase a metric's ``read`` asks for).
 
 * what an adapter reads by a string the walk cannot see: the ``ouro``
   adapter's leaves of ``init_params``' tree and the keys of the looped
@@ -144,9 +151,11 @@ def _names_looked_for():
         ops = read.get("trace_ops")
         if isinstance(ops, str) and re.fullmatch(r"hvd_\w+", ops):
             found.setdefault(("kernel", ops), rel)
-        phase = (read.get("trace_scope") or {}).get("phase")
-        if phase:
-            found.setdefault(("phase", phase), rel)
+        # a cover's phase, and the phase of an owner (readers/step_owners.py)
+        for phase in ((read.get("trace_scope") or {}).get("phase"),
+                      read.get("phase") if "reader" in read else None):
+            if phase and phase.startswith("hvd."):
+                found.setdefault(("phase", phase), rel)
         if read.get("host_span"):
             found.setdefault(("span", read["host_span"]), rel)
     for node in ast.walk(_parse(os.path.join(CHIP, "scope_reduce.py"))):
@@ -157,7 +166,8 @@ def _names_looked_for():
         for node in ast.walk(_parse(path)):
             if isinstance(node, ast.Constant) and isinstance(node.value, str) \
                     and re.fullmatch(r"hvd(\.[a-z_]+)+", node.value):
-                found.setdefault(("span", node.value),
+                # a reader looks for host spans or for device phases
+                found.setdefault(("name", node.value),
                                  os.path.relpath(path, CHIP))
     for name in BLOCK_KERNELS:
         found.setdefault(("kernel", name), "PERF.md §3")
@@ -194,7 +204,8 @@ def test_what_the_benchmark_looks_for_is_what_the_program_says(
         kind, name, where):
     from horovod_tpu.profiling import scopes
     said = {"kernel": KERNEL_NAMES, "phase": scopes.DEVICE_PHASES,
-            "span": scopes.HOST_SPANS}[kind]
+            "span": scopes.HOST_SPANS,
+            "name": scopes.DEVICE_PHASES + scopes.HOST_SPANS}[kind]
     assert name in said, (
         f"benchmarks/chip/{where} looks for the {kind} {name!r}; the "
         f"product has {sorted(said)}")
@@ -226,6 +237,28 @@ test_every_read_is_a_kind_the_harness_dispatches = \
     _DOOR.test_every_read_is_a_kind_the_harness_dispatches
 test_an_unknown_kind_without_a_reader_raises = \
     _DOOR.test_an_unknown_kind_without_a_reader_raises
+
+
+# The owner-and-reason reader's own cases, taken the same way (its module
+# fixture with them).
+_OWNERS = _benchmarks_own("test_step_owners")
+recorded = _OWNERS.recorded
+for _name in dir(_OWNERS):
+    if _name.startswith("test_"):
+        globals()[_name.replace("test_", "test_step_owners_", 1)] = \
+            getattr(_OWNERS, _name)
+
+
+def test_the_reader_s_words_are_the_program_s():
+    """``rematted_computation`` and ``hvd.recompute`` as
+    ``profiling/scopes.py`` writes them, not the reader's fallback for a
+    program from before them."""
+    from horovod_tpu.profiling import scopes
+    assert (_OWNERS.so.RECOMPUTE, _OWNERS.so.RECOMPUTED) == (
+        scopes.RECOMPUTE, scopes.RECOMPUTED)
+    with open(os.path.join(CHIP, "readers", "step_owners.py")) as f:
+        text = f.read()
+    assert f'"{scopes.RECOMPUTE}", "{scopes.RECOMPUTED}"' in text
 
 
 @pytest.mark.parametrize("path", sorted(
@@ -620,7 +653,9 @@ def test_the_dense_hybrid_step_gives_what_the_granite_hybrid_adapter_reads():
         "mlp_ms", "ssm_scan_kernels_ms", "ssm_scan_roofline",
         "attention_fwd_ms", "flash_attention_roofline", "attention_bwd_ms",
         "flash_attention_bwd_roofline", "head_xent_ms",
-        "head_xent_roofline"))
+        "head_xent_roofline",
+        # PR 51's two, by owner and reason (readers/step_owners.py)
+        "ssm_recompute_ms", "ssm_conv_self_ms"))
     # no list of an accepted metric was edited to take the cell in
     assert not [m["name"] for m in bench["per_layer"]
                 if cell in m.get("workloads", ()) and m["name"] not in mine]

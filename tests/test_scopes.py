@@ -1,8 +1,11 @@
 """The phase vocabulary (horovod_tpu/profiling/scopes.py): the names are
 written in one place, the two train steps the chip benchmark runs carry
 every phase forward and backward in their compiled text, a scope is
-metadata only, the input path's host spans open once per batch in order,
-and the compile watcher splits a program's way to the device."""
+metadata only, JAX names a checkpointed block's second forward as the
+vocabulary says it does and the one hand-written backward that runs
+forward work again says so, the input path's host spans open once per
+batch in order, and the compile watcher splits a program's way to the
+device."""
 
 import contextlib
 import os
@@ -25,10 +28,14 @@ PACKAGE = os.path.join(os.path.dirname(os.path.dirname(
 
 def test_the_vocabulary_is_the_only_place_the_strings_are_written():
     names = scopes.DEVICE_PHASES + scopes.HOST_SPANS
-    assert len(set(names)) == len(names) == 32
+    assert len(set(names)) == len(names) == 33
+    assert scopes.RECOMPUTE in scopes.DEVICE_PHASES
+    # JAX's own word is no phase, and is written here alone all the same
+    names += (scopes.RECOMPUTED,)
+    assert not scopes.RECOMPUTED.startswith("hvd.")
     assert scopes.HOST_SPANS == ("hvd.input.source", "hvd.input.place",
                                  "hvd.host.gc", "hvd.host.compile")
-    assert all(n.startswith("hvd.") for n in names)
+    assert all(n.startswith("hvd.") for n in names[:-1])
     home = os.path.join(PACKAGE, "profiling", "scopes.py")
     elsewhere = []
     for folder, _dirs, files in os.walk(PACKAGE):
@@ -328,6 +335,88 @@ def test_a_phase_is_not_found_by_substring():
            + scopes.ATTENTION_CORE + ')/add"}'
     assert _directions(text, scopes.ATTENTION_CORE) == {"fwd"}
     assert _directions(text, scopes.ATTENTION) == set()
+
+
+# -- the reasons: work done again --------------------------------------------
+
+def _parts(path: str) -> list:
+    return [re.sub(r"^(?:\w+\()+|\)+$", "", c) for c in path.split("/")]
+
+
+def test_jax_names_the_second_forward_of_a_checkpointed_block():
+    """A scan of ``jax.checkpoint``ed blocks under ``hvd.layers``, as
+    ``_scan_periods`` builds one, differentiated and compiled: the second
+    forward, and nothing else, carries ``scopes.RECOMPUTED`` in its path.
+    A JAX that renames the component fails here, before a reader on the
+    chip finds nothing recomputed."""
+    def block(x, w):
+        with jax.named_scope(scopes.MLP):
+            return jnp.tanh(x @ w)
+
+    def loss(ws, x):
+        with jax.named_scope(scopes.LAYERS):
+            x, _ = jax.lax.scan(
+                lambda x, w: (jax.checkpoint(block)(x, w), None), x, ws)
+        return jnp.sum(x)
+    text = jax.jit(jax.grad(loss)).lower(
+        jnp.ones((3, 8, 8)), jnp.ones((4, 8))).compile().as_text()
+    paths = [p for p in re.findall(r'op_name="([^"]*)"', text)
+             if scopes.MLP in _parts(p)]
+    assert all(scopes.LAYERS in _parts(p) for p in paths)
+    first = [p for p in paths if "transpose(" not in p]
+    again = [p for p in paths if scopes.RECOMPUTED in _parts(p)]
+    backward = [p for p in paths if "transpose(" in p and p not in again]
+    assert first and again and backward
+    assert not any(scopes.RECOMPUTED in p for p in first + backward)
+    assert all("transpose(" in p for p in again)
+    # what is made again is the forward's tanh and matmul; the backward
+    # proper is the matmul's two transposes
+    assert any(p.endswith("/tanh") for p in again)
+    assert any(p.endswith("/dot_general") for p in again)
+    assert any(p.endswith("/dot_general") for p in backward)
+    assert not any(p.endswith("/tanh") for p in backward)
+
+
+def test_the_hybrid_step_s_checkpointed_blocks_are_named_so():
+    """The program's own: the Mamba blocks of the hybrid stack are
+    checkpointed, and their second forward is every phase of the mixer
+    under ``scopes.RECOMPUTED``; the update is not."""
+    # (a reduction's own little computation carries the path's tail only)
+    paths = re.findall(r'op_name="(jit\([^"]*)"', _compiled_text("hybrid"))
+    again = [_parts(p) for p in paths if scopes.RECOMPUTED in _parts(p)]
+    for phase in (scopes.SSM_PROJ, scopes.SSM_CONV, scopes.SSM_SCAN,
+                  scopes.SSM_NORM):
+        assert any(phase in p for p in again), phase
+    assert all(scopes.LAYERS in p for p in again)
+    assert not any(scopes.OPTIMIZER in p for p in again)
+
+
+def test_the_held_experts_backward_names_the_hidden_rows_made_again():
+    """``_ffn_held_bwd`` makes the hidden rows again from the kept
+    products: under ``scopes.RECOMPUTE`` in the lowered text, inside the
+    experts' phase, in the backward pass only; the plain expression of a
+    layer whose every row is held runs nothing again."""
+    from horovod_tpu.parallel import moe
+    rng = np.random.RandomState(0)
+    rows = jnp.asarray(rng.randn(16, 8), jnp.float32)
+    we1, we3 = (jnp.asarray(rng.randn(2, 8, 4), jnp.float32)
+                for _ in range(2))
+    we2 = jnp.asarray(rng.randn(2, 4, 8), jnp.float32)
+    sizes = jnp.asarray([3, 5], jnp.int32)
+
+    def lowered(held):
+        def loss(rows, we1, we3, we2):
+            with jax.named_scope(scopes.MOE_EXPERTS):
+                return jnp.sum(moe.expert_ffn(rows, we1, we3, we2, sizes,
+                                              held, jax.nn.silu))
+        return jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
+            rows, we1, we3, we2).as_text(debug_info=True)
+    again = [p for p in re.findall(r'"(jit\([^"]*)"', lowered(jnp.int32(8)))
+             if scopes.RECOMPUTE in _parts(p)]
+    assert again
+    assert all(scopes.MOE_EXPERTS in _parts(p) and "transpose(" in p
+               for p in again)
+    assert scopes.RECOMPUTE not in lowered(None)
 
 
 def test_a_scope_is_metadata_only(monkeypatch):
