@@ -27,7 +27,13 @@ Layout conventions (local = per-device shapes):
   layers          stacked [pp, L/pp, ...]; GPipe schedule over pp
   loop            n_loops > 1: a scan over loop steps around the scan over
                   layers, the same weights each step, ln_f after each; every
-                  step's state goes to the head and the exit gate (no pp)
+                  step's state goes to the head and the exit gate (no pp).
+                  Checkpointed (remat None or True), the backward pass is
+                  the stack's own (``_looped_stack``): one accumulator of
+                  the stacked parameters in the loops' carry, each pass's
+                  weight gradient added into its layer's slice in place,
+                  where the scans' transpose holds two stacks and adds one
+                  to the other whole, once a loop step
   layer kinds     ``layer_pattern``: one period of (window, rope) kinds; the
                   scan goes over periods, a period's layers unrolled inside
   one sublayer    a kind that starts with a word of ``_BLOCK_KINDS``,
@@ -1009,10 +1015,23 @@ def _looped_loss(z, nll):
 
 def _loop_layers(lp, ln_f, x, positions, cfg: TransformerConfig):
     """``n_loops`` passes through the same stack of blocks, ``ln_f`` after
-    each: a scan over loop steps around the scan over layers, the stacked
-    parameters closed over, so a weight's gradient is the sum over its
-    uses. Returns (every step's normed state ``[T, B, S, M]``, the
-    auxiliary terms over all passes)."""
+    each. Returns (every step's normed state ``[T, B, S, M]``, the
+    auxiliary terms over all passes).
+
+    Where the stack checkpoints its blocks (every looped config that leaves
+    ``remat`` at None) the backward pass is :func:`_looped_stack`'s own, not
+    the scans' transpose. Left to autodiff, a scan over loop steps around the
+    scan over layers closes over the stacked parameters, and JAX transposes
+    that as it must: the inner backward scan stacks a pass's weight gradients
+    into a fresh ``[L, ...]`` set, the outer one carries a second set and
+    adds the first into it whole, once a loop step (two float32 stacks of
+    every layer parameter and ``n_loops`` adds of three stacks' traffic:
+    42.6 of the Ouro cell's 431 ms a step, PERF.md section 6, PR 52). A
+    weight's gradient is the sum over its uses, so the hand-written backward
+    carries ONE such set and every pass adds its gradient into its layer's
+    slice in place. An explicit ``remat=False`` keeps the scans and their
+    transpose (every pass's activations stored): the path the other is
+    tested against."""
     if _axis_live("pp"):
         raise NotImplementedError(
             "a looped stack (n_loops > 1) on a live pp axis: the pipeline "
@@ -1026,8 +1045,140 @@ def _loop_layers(lp, ln_f, x, positions, cfg: TransformerConfig):
         y = rmsnorm(y, ln_f, cfg.norm_eps)
         return y, (y, _over_layers(auxs))
     with jax.named_scope(scopes.LOOP):
-        _, (states, auxs) = lax.scan(loop_step, x, None, length=cfg.n_loops)
+        if all(remat(cfg, _checkpointed(cfg, kind))
+               for kind in cfg.layer_pattern):
+            flat = jax.tree_util.tree_map(
+                lambda a: a.reshape((-1,) + a.shape[2:]), lp)
+            # a stack a word, or the one unnamed stack (:func:`_stack_of`)
+            stacks = flat if _stack_of(cfg.layer_pattern[0]) else {None: flat}
+            states, auxs = _looped_stack(cfg, stacks, ln_f, x, positions)
+            auxs = jax.vmap(_over_layers)(auxs)
+        else:
+            _, (states, auxs) = lax.scan(loop_step, x, None,
+                                         length=cfg.n_loops)
     return states, _over_layers(auxs)
+
+
+def _period_blocks(cfg: TransformerConfig):
+    """The blocks of one period of ``cfg.layer_pattern``: [(kind, its
+    stack's key in ``stacks``, how many blocks a period that stack holds,
+    this block's place among them)]."""
+    of = [_stack_of(kind) for kind in cfg.layer_pattern]
+    return [(kind, key, of.count(key), of[:i].count(key))
+            for i, (kind, key) in enumerate(zip(cfg.layer_pattern, of))]
+
+
+def _period_params(cfg: TransformerConfig, stacks, i):
+    """The parameters of period ``i``'s blocks, one tree a block, out of
+    ``stacks`` ({stack's key: leaves ``[blocks of that stack, ...]``})."""
+    return [jax.tree_util.tree_map(
+        lambda a: lax.dynamic_index_in_dim(a, i * per + place,
+                                           keepdims=False), stacks[key])
+        for _kind, key, per, place in _period_blocks(cfg)]
+
+
+def _period(cfg: TransformerConfig, positions, layer_ps, x):
+    """``x`` through one period's blocks; returns (activations, the
+    auxiliary terms of the blocks that have any, stacked)."""
+    auxs = []
+    for (kind, *_), layer_p in zip(_period_blocks(cfg), layer_ps):
+        x, aux = _block(layer_p, x, positions, cfg, kind)
+        auxs.append(aux)
+    auxs = [aux for aux in auxs if aux is not None] or [_no_aux()]
+    return x, jax.tree_util.tree_map(lambda *a: jnp.stack(a), *auxs)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _looped_stack(cfg: TransformerConfig, stacks, ln_f, x, positions):
+    """``cfg.n_loops`` passes of ``x`` through the blocks of ``stacks``
+    ({stack's key: leaves ``[blocks, ...]``}), ``ln_f`` after each, every
+    block checkpointed: a scan over loop steps around a scan over periods.
+    Returns (every step's normed state ``[T, B, S, M]``, every period's
+    auxiliary terms ``[T, periods, blocks with any, ...]``).
+
+    Stored for the backward pass: each period's input of each loop step
+    ``[T, periods, B, S, M]`` (what ``jax.checkpoint`` of a block stores;
+    of a period of several kinds the first block's alone) and each step's
+    state before ``ln_f``. The backward pass walks the loop steps and the
+    periods from the last, with ONE accumulator of ``stacks``' shapes and
+    dtypes in its carry: a period's cotangent is pulled back through
+    ``jax.checkpoint`` of the same :func:`_block`s (every kernel, norm and
+    rope is the code the forward ran, and the second run carries JAX's own
+    name, ``scopes.RECOMPUTED``, as a checkpointed block's does in a scan's
+    transpose; the first run ``jax.vjp`` traces beside it has no reader and
+    XLA drops it), and the period writes
+    ``acc[l] + dw`` at ``acc[l]``, a dynamic-update-slice on the carry that
+    XLA keeps in place. The additions are the transpose's, in its order (the last pass
+    first, a layer's passes one at a time in the parameters' dtype), and
+    ``ln_f``'s gradient is summed over the loop steps the same way."""
+    return _looped_stack_fwd(cfg, stacks, ln_f, x, positions)[0]
+
+
+def _looped_stack_fwd(cfg: TransformerConfig, stacks, ln_f, x, positions):
+    periods = cfg.n_layers // len(cfg.layer_pattern)
+
+    def loop_step(h, _):
+        def period(h, i):
+            y, auxs = _period(cfg, positions,
+                              _period_params(cfg, stacks, i), h)
+            return y, (h, auxs)
+        y, (inputs, auxs) = lax.scan(period, h, jnp.arange(periods))
+        state = rmsnorm(y, ln_f, cfg.norm_eps)
+        return state, (state, auxs, inputs, y)
+    _, (states, auxs, inputs, ys) = lax.scan(loop_step, x, None,
+                                             length=cfg.n_loops)
+    return (states, auxs), (stacks, ln_f, positions, inputs, ys)
+
+
+def _looped_stack_bwd(cfg: TransformerConfig, res, cotangents):
+    stacks, ln_f, positions, inputs, ys = res
+    d_states, d_auxs = cotangents
+    # a count or a choice (an integer term) has no cotangent to scan over
+    d_auxs = {k: ct for k, ct in d_auxs.items()
+              if ct.dtype != jax.dtypes.float0}
+
+    def add_at(at, acc, g):
+        return lax.dynamic_update_index_in_dim(
+            acc, lax.dynamic_index_in_dim(acc, at, keepdims=False) + g, at, 0)
+
+    def loop_step(carry, step):
+        d_next, acc, d_ln_f = carry
+        inputs_t, y, d_state, d_auxs_t = step
+        _, pull_norm = jax.vjp(jax.checkpoint(
+            lambda y, g: rmsnorm(y, g, cfg.norm_eps)), y, ln_f)
+        # the state went to the head and into the next loop step
+        d_y, d_g = pull_norm(d_next + d_state)
+
+        def period(carry, step):
+            d_y, acc = carry
+            i, h, d_aux = step
+            (_, aux), pull = jax.vjp(
+                jax.checkpoint(functools.partial(_period, cfg, positions)),
+                _period_params(cfg, stacks, i), h)
+            d_ps, d_h = pull((d_y, {
+                k: d_aux[k] if k in d_aux
+                else np.zeros(v.shape, jax.dtypes.float0)
+                for k, v in aux.items()}))
+            acc = dict(acc)
+            for (_kind, key, per, place), d_p in zip(_period_blocks(cfg),
+                                                     d_ps):
+                acc[key] = jax.tree_util.tree_map(
+                    functools.partial(add_at, i * per + place),
+                    acc[key], d_p)
+            return (d_h, acc), None
+        (d_h, acc), _ = lax.scan(
+            period, (d_y, acc),
+            (jnp.arange(inputs_t.shape[0]), inputs_t, d_auxs_t),
+            reverse=True)
+        return (d_h, acc, d_ln_f + d_g), None
+    zeros = (jnp.zeros(ys.shape[1:], ys.dtype),
+             *jax.tree_util.tree_map(jnp.zeros_like, (stacks, ln_f)))
+    (d_x, d_stacks, d_ln_f), _ = lax.scan(
+        loop_step, zeros, (inputs, ys, d_states, d_auxs), reverse=True)
+    return d_stacks, d_ln_f, d_x, None
+
+
+_looped_stack.defvjp(_looped_stack_fwd, _looped_stack_bwd)
 
 
 def _run_layers(lp, x, positions, cfg: TransformerConfig):

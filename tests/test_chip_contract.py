@@ -21,9 +21,9 @@ cannot go stale:
   nothing read: None, never a raise), a ``reader`` names a file under
   ``readers/`` whose imports from the product resolve like ``run.py``'s.
   The cases are the benchmark's own (``benchmarks/chip/tests/
-  test_layer_metrics.py``), imported here so that tier-1 runs them: 106
-  entries and files since PR 51 (96 before it), two cases each, and two
-  readers (``host_pauses``, ``step_owners``);
+  test_layer_metrics.py``), imported here so that tier-1 runs them: 107
+  entries and files since PR 52 (106 since PR 51, 96 before it), two cases
+  each, and two readers (``host_pauses``, ``step_owners``);
 * the reader that gives every device instruction one owner and one reason
   (``readers/step_owners.py``), by its own cases
   (``benchmarks/chip/tests/test_step_owners.py``, imported the same way):

@@ -18,6 +18,7 @@ step dropped, ``p_T`` taken from ``lambda_T``, the post-norms left out.
 import dataclasses
 import json
 import os
+import re
 import sys
 
 import numpy as np
@@ -302,9 +303,11 @@ def test_remat_changes_what_is_stored_not_what_is_computed(path, cfg):
 
 def test_remat_none_checkpoints_the_looped_stack_only():
     """What ``None`` resolves to, read from the traced step: a looped
-    stack's blocks run under ``checkpoint``, the single scan's do not (the
-    GPT and OLMoE cells' programs stay as they were), and an explicit
-    value is honoured on both."""
+    stack's blocks run under ``checkpoint`` (since ISSUE 52 in the stack's
+    own backward pass, which pulls each period back through
+    ``jax.checkpoint``), the single scan's do not (the GPT and OLMoE cells'
+    programs stay as they were), and an explicit value is honoured on
+    both."""
     def checkpointed(cfg):
         mesh = build_mesh(devices=jax.devices()[:1], dp=1)
         params, batch = _params(cfg), _batch()
@@ -314,6 +317,124 @@ def test_remat_none_checkpoints_the_looped_stack_only():
     assert checkpointed(CFG) and not checkpointed(DENSE)
     assert not checkpointed(dataclasses.replace(CFG, remat=False))
     assert checkpointed(dataclasses.replace(DENSE, remat=True))
+
+
+# -- the looped stack's own backward pass (ISSUE 52) ----------------------------
+
+def _lowered(cfg, params=None, batch=None):
+    """The gradient function of ``cfg`` on one device, lowered."""
+    mesh = build_mesh(devices=jax.devices()[:1], dp=1)
+    params, batch = params or _params(cfg), batch or _batch()
+    return jax.jit(t.make_grad_fn(cfg, mesh)).lower(
+        params, batch["tokens"], batch["targets"])
+
+
+@pytest.mark.parametrize("loops", [2, 3])
+@pytest.mark.parametrize("axes", [
+    None, {"dp": 2}, {"sp": 2}, {"dp": 2, "sp": 2}, {"tp": 2}],
+    ids=lambda axes: "x".join(f"{k}{v}" for k, v in (axes or {"one": ""}
+                                                      ).items()))
+def test_the_accumulating_backward_is_autodiff_s(axes, loops):
+    """The checkpointed looped stack's hand-written backward pass (one
+    accumulator, every pass adding its slice in place) against the scans'
+    transpose (``remat=False``: two stacks, a whole add a loop step): the
+    same float32 additions in the same order, so every leaf's gradient to
+    the bit. Where the sequence is sharded the ring's forward is run again
+    by the one path and stored by the other, which XLA:CPU compiles to sums
+    of another order: 8e-7 of a matrix's norm and one or two units in the
+    last place of a scalar leaf (1.6e-6), the very numbers the parent's
+    checkpointed path reads against ``remat=False``, so 1e-5 there."""
+    cfg = dataclasses.replace(CFG, n_loops=loops)
+    params, batch = _params(cfg, seed=loops), _batch(n_seqs=4, seed=loops)
+    loss, aux, grads = _program(cfg, params, batch, axes)
+    loss0, aux0, grads0 = _program(dataclasses.replace(cfg, remat=False),
+                                   params, batch, axes)
+    np.testing.assert_allclose(float(loss), float(loss0), rtol=1e-6)
+    for k in aux0:
+        np.testing.assert_allclose(np.asarray(aux[k]), np.asarray(aux0[k]),
+                                   rtol=1e-6, atol=1e-7, err_msg=k)
+    for (path, g), g0 in zip(jax.tree_util.tree_leaves_with_path(grads),
+                             jax.tree_util.tree_leaves(grads0)):
+        if "sp" in (axes or {}):
+            assert _rel(g, g0) < 1e-5, path
+        else:
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(g0),
+                                          err_msg=str(path))
+
+
+@pytest.mark.parametrize("fields", [
+    dict(layer_pattern=((None, False), (8, True))),
+    dict(layer_pattern=(("attention", None, True), ("dense",)), n_layers=4,
+         dense_ff=64),
+    dict(n_experts=4, moe_top_k=2, moe_gated=True, moe_balance_weight=0.5,
+         moe_z_weight=0.1)],
+    ids=["two_kinds_a_period", "a_stack_a_word", "experts"])
+def test_the_accumulating_backward_takes_every_looped_stack(fields):
+    """What a looped stack may be beside Ouro's: a period of several kinds
+    (a period's input is stored, its blocks run again together), one-sublayer
+    blocks in a stack a word (an accumulator a stack), expert layers (the
+    auxiliary loss's gradient, the router's alone, goes through the
+    hand-written backward; the integer counters beside it have no
+    cotangent). Loss, terms and every gradient to the bit."""
+    cfg = dataclasses.replace(CFG, **fields)
+    params, batch = _params(cfg), _batch()
+    loss, aux, grads = _program(cfg, params, batch)
+    loss0, aux0, grads0 = _program(dataclasses.replace(cfg, remat=False),
+                                   params, batch)
+    assert (float(aux["aux_loss"]) > 0) == ("n_experts" in fields)
+    assert float(loss) == float(loss0)
+    for k in aux0:
+        np.testing.assert_array_equal(np.asarray(aux[k]), np.asarray(aux0[k]),
+                                      err_msg=k)
+    for (path, g), g0 in zip(jax.tree_util.tree_leaves_with_path(grads),
+                             jax.tree_util.tree_leaves(grads0)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(g0),
+                                      err_msg=str(path))
+
+
+def _backward_loops(text):
+    """{outer | inner: the shapes of the float32 arrays the loop carries,
+    leading 1s dropped} of the looped stack's two backward ``while``s in a
+    compiled step's text."""
+    loops = {}
+    for line in text.splitlines():
+        if " while(" not in line or "transpose(jvp(hvd.layers))" not in line:
+            continue
+        carried, rest = line.split(" while(", 1)
+        nested = "/while/body/" in rest.split('op_name="', 1)[1]
+        loops["inner" if nested else "outer"] = [
+            tuple(int(n) for n in dims.split(",") if n)
+            for dims in re.findall(r"f32\[([\d,]*)\]", carried)]
+    return {k: [tuple(int(n) for n in np.trim_zeros(np.array(s) - 1, "f") + 1)
+                for s in v] for k, v in loops.items()}
+
+
+@pytest.mark.parametrize("remat, whole_adds", [(None, False), (False, True)],
+                         ids=["accumulating", "autodiff"])
+def test_the_backward_loops_carry_one_accumulator(remat, whole_adds):
+    """Read from the compiled tiny step: each of the backward's two loops
+    carries the stacked float32 weights and ONE more float32 array of each
+    stacked leaf's shape, the accumulator the inner loop hands back to the
+    outer, and nowhere in the program is a whole stacked leaf added to
+    another. The scans' transpose (``remat=False``, and the parent's
+    checkpointed path) stacks a pass's gradients in the inner loop and adds
+    that stack to the outer loop's own, leaf by leaf, once a loop step:
+    the second case shows that the search finds those adds."""
+    cfg = dataclasses.replace(CFG, remat=remat)
+    params = _params(cfg)
+    text = _lowered(cfg, params).compile().as_text()
+    stacked = [leaf.shape[1:] for leaf in
+               jax.tree_util.tree_leaves(params["layers"])]
+    loops = _backward_loops(text)
+    assert set(loops) == {"outer", "inner"}
+    for shape in set(stacked) if remat is None else ():
+        # the weights themselves, and the gradients' one set
+        assert loops["inner"].count(shape) == 2 * stacked.count(shape), shape
+        assert loops["outer"].count(shape) == 2 * stacked.count(shape), shape
+    adds = [line for line in text.splitlines() if re.search(
+        r"= f32\[(?:1,)?(%s)\]\S* add\(" % "|".join(
+            ",".join(map(str, shape)) for shape in set(stacked)), line)]
+    assert bool(adds) == whole_adds, adds[:3]
 
 
 # -- layouts --------------------------------------------------------------------
