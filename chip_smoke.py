@@ -173,10 +173,15 @@ REAL = Sizes(
     # smallthinker-21b-a3b.s8192 (a full layer and a window layer: seven
     # query heads a k/v head, a window of four 1024-tiles under eight) and
     # gpt-1.3b-widths.s2048, whole: the float32 reference goes a query head
-    # at a time (a head's scores at 8192 keys are 268 MB)
+    # at a time (a head's scores at 8192 keys are 268 MB); then
+    # laguna-xs.2.s8192's two shapes on the same eight k/v heads: a window
+    # layer (64 query heads, groups of 8, a window of 512 under 1024 x 1024
+    # tiles: every live tile whole under its mask) and a full layer (48:
+    # groups of 6, no power of two)
     cells=((1, 8192, 20, 20, 256, None), (1, 4096, 16, 16, 128, None),
            (1, 8192, 28, 4, 128, None), (1, 8192, 28, 4, 128, 4096),
-           (2, 2048, 16, 16, 128, None)),
+           (2, 2048, 16, 16, 128, None),
+           (1, 8192, 64, 8, 128, 512), (1, 8192, 48, 8, 128, None)),
     # the attention core of bert-large.s128 and bert-large.s512
     block=((64, 128, 16, 64), (8, 512, 16, 64)),
     xent=(16384, 32000), blocks=(8192, 256),
@@ -203,7 +208,8 @@ TINY = Sizes(
     bert_batch=8, bert_seq=16, bert4=(8, 16),
     attn=(1, 256, 2, 128),
     cells=((1, 256, 2, 2, 256, None), (1, 512, 4, 2, 128, None),
-           (1, 512, 4, 2, 128, 256), (2, 256, 2, 2, 128, None)),
+           (1, 512, 4, 2, 128, 256), (2, 256, 2, 2, 128, None),
+           (1, 512, 6, 1, 128, 128)),
     block=((2, 128, 2, 64),),
     xent=(256, 1000), blocks=(64, 128),
     gmm=((256, 128, 128, 4, 192), (256, 128, 192, 4, 192)),
@@ -772,8 +778,10 @@ def _check_gmm(smoke: Smoke) -> None:
     groups' rows alone. Prints which path ``grouped_matmul`` takes, which
     way round it reads the weights and the tile of each of its three calls,
     at each size, at the OLMoE cell's, at the share cell's, at the hybrid
-    cell's and at the latent cell's, and of how many of their chunks of
-    sorted rows the expert layer's row-wise passes run there."""
+    cell's, at the latent cell's and at the banded cell's (Laguna: 32 held
+    experts of 2048 x 512 under 65 536 assignments), and of how many of
+    their chunks of sorted rows the expert layer's row-wise passes run
+    there."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -785,19 +793,23 @@ def _check_gmm(smoke: Smoke) -> None:
     share = gmm_path(49152, 2560, 768)
     hybrid = gmm_path(49152, 2688, 1856)
     latent = gmm_path(4 * 8192, 2048, 1536)
-    # the rows each share holds by arithmetic: 16 of 64, 8 of 128, 8 of 64
-    # experts (ISSUE 44: the chunks the row-wise passes run of those there are)
+    banded = gmm_path(8 * 8192, 2048, 512)
+    # the rows each share holds by arithmetic: 16 of 64, 8 of 128, 8 of 64,
+    # 32 of 256 experts (ISSUE 44: the chunks the row-wise passes run of
+    # those there are)
     chunks = {"share": held_chunks_path(49152, 49152 * 16 // 64),
               "hybrid": held_chunks_path(49152, 49152 * 8 // 128),
               "latent": held_chunks_path(4 * 8192, 4 * 8192 * 8 // 64),
+              "banded": held_chunks_path(8 * 8192, 8 * 8192 * 32 // 256),
               "olmoe": held_chunks_path(65536, None)}
     if smoke.on_chip:
         check(olmoe.startswith(f"pallas {GMM_NAME} ") and "row-major" in olmoe
               and share.startswith(f"pallas {GMM_NAME} ")
               and hybrid.startswith(f"pallas {GMM_NAME} ")
               and "[E, 1856, 2688]" in hybrid
-              and latent.startswith(f"pallas {GMM_NAME} "),
-              olmoe + share + hybrid + latent)
+              and latent.startswith(f"pallas {GMM_NAME} ")
+              and banded.startswith(f"pallas {GMM_NAME} "),
+              olmoe + share + hybrid + latent + banded)
     narrow = smoke.sizes.gmm[0][:2] + (64,) + smoke.sizes.gmm[0][3:]
     cases = [(GMM_NAME, size) for size in smoke.sizes.gmm] + [(None, narrow)]
     for case, (kernel, (rows, d_in, width, groups, inside)) in enumerate(cases):
@@ -844,6 +856,7 @@ def _check_gmm(smoke: Smoke) -> None:
                      gmm_path_at_the_share_cell=share,
                      gmm_path_at_the_hybrid_cell=hybrid,
                      gmm_path_at_the_latent_cell=latent,
+                     gmm_path_at_the_banded_cell=banded,
                      row_wise_passes_at_the_cells=chunks,
                      rows_in_groups=inside, largest_group=int(sizes.max()),
                      empty_groups=int((sizes == 0).sum()),

@@ -6,6 +6,7 @@ specs and the stack's scan from. It imports neither that file nor the mixers
 
 from __future__ import annotations
 
+import math
 from typing import Any, Callable, Iterable, NamedTuple, Optional, Tuple
 
 import numpy as np
@@ -41,6 +42,10 @@ class BlockKind(NamedTuple):
     gradients_first: bool = False   # the train step finishes every gradient
     #                             as an array of its own before the
     #                             optimizer reads any (``make_train_step``)
+    optional: int = 0           # further fields a kind of it may give, each
+    #                             of which changes a leaf's shape: such kinds
+    #                             are stacks of their own
+    #                             (``transformer._stack_of``)
 
 
 def ones(rng, shape):
@@ -77,10 +82,78 @@ def scaled(x, factor: float):
     return (x.astype(jnp.float32) * factor).astype(x.dtype)
 
 
+class Yarn(NamedTuple):
+    """YaRN's change to a rotary table (arXiv:2309.00071, as ``transformers``
+    computes it): the slow frequencies divided by ``factor``, the fast ones
+    kept, a linear ramp between the channels that turn ``beta_fast`` and
+    ``beta_slow`` times over ``original`` positions; cos and sin times
+    ``attention_factor``."""
+    factor: float
+    original: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: Optional[float] = None    # None: 0.1 ln(factor) + 1
+
+
+class Rope(NamedTuple):
+    """A rotary table of a layer kind's own, where the kind's rope is not
+    just on (``True``: the whole head at ``cfg.rope_theta``) or off."""
+    theta: float
+    width: Optional[int] = None     # the head's first channels that rotate,
+    #                             the others pass through. None: the whole head
+    yarn: Optional[Yarn] = None
+
+
+def yarn_ramp(table: Rope, width: int):
+    """(low, high) of YaRN's ramp over the ``width // 2`` frequencies of
+    ``table``: channel ``i`` keeps its frequency for ``i <= low`` and has it
+    divided by ``factor`` for ``i >= high``."""
+    y = table.yarn
+
+    def turns(n):   # the channel that turns n times over ``original``
+        return (width * math.log(y.original / (n * 2 * math.pi))
+                / (2 * math.log(table.theta)))
+    return (max(math.floor(turns(y.beta_fast)), 0),
+            min(math.ceil(turns(y.beta_slow)), width - 1))
+
+
+def rope_table(table: Rope, head_dim: int):
+    """(the ``width // 2`` float32 frequencies of ``table`` for a head of
+    ``head_dim``, the factor on cos and sin), computed on the host in
+    float64."""
+    width = head_dim if table.width is None else table.width
+    if width % 2 or not 0 < width <= head_dim:
+        raise ValueError(f"{table}: {width} rotated channels of a head of "
+                         f"{head_dim}")
+    i = np.arange(width // 2, dtype=np.float64)
+    freqs = table.theta ** (-2.0 * i / width)
+    if table.yarn is None:
+        return freqs.astype(np.float32), 1.0
+    y = table.yarn
+    low, high = yarn_ramp(table, width)
+    ramp = np.clip((i - low) / max(high - low, 1e-3), 0.0, 1.0)
+    freqs = freqs / y.factor * ramp + freqs * (1.0 - ramp)
+    factor = (0.1 * math.log(y.factor) + 1.0 if y.attention_factor is None
+              else y.attention_factor)
+    return freqs.astype(np.float32), float(factor)
+
+
 def rope(x, positions, theta=10000.0):
     """Rotary embedding, halves layout; x [B, S, H, D], positions [S]
-    absolute."""
+    absolute. ``theta``: the base of the default table over the whole head,
+    or a :class:`Rope` (the halves layout within its rotated channels)."""
     B, S, H, D = x.shape
+    if isinstance(theta, Rope):
+        # (a branch of its own: the plain table below is traced as it was,
+        # to the jaxpr's letter, for every config that names no table)
+        freqs, factor = rope_table(theta, D)
+        width = 2 * len(freqs)
+        ang = positions[:, None].astype(jnp.float32) * freqs[None, :]
+        cos = (jnp.cos(ang) * factor)[None, :, None, :].astype(x.dtype)
+        sin = (jnp.sin(ang) * factor)[None, :, None, :].astype(x.dtype)
+        x1, x2 = x[..., :width // 2], x[..., width // 2:width]
+        return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos,
+                                x[..., width:]], -1)
     half = D // 2
     freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
     ang = positions[:, None].astype(jnp.float32) * freqs[None, :]  # [S, half]

@@ -43,6 +43,14 @@ Layout conventions (local = per-device shapes):
                   that word / pp, ...]`` (a Mamba block: ``models/mamba.py``;
                   ("latent",) latent attention: ``models/latent.py``;
                   ("dense",) the dense FFN at ``dense_ff``)
+  attention kinds ("attention", window, rope, heads, gated): ``rope`` may be
+                  a table of the kind's own (``_kinds.Rope``: a theta, the
+                  rotated part of the head, YaRN); ``heads`` query heads
+                  where they are not ``n_heads`` and ``gated``, a sigmoid
+                  gate a head on the core's output (``wg``), change the
+                  block's leaves, so such kinds are stacks of their own
+                  (``layers["attention_64_gated"]``) and two attention
+                  shapes share one scan over periods (Laguna)
   leading blocks  ``lead_pattern``: blocks before the periodic stack, each
                   once (``lead``, a stack a word ``[blocks, ...]``), so a
                   dense layer leads a scan over (attention, experts) periods
@@ -74,8 +82,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from horovod_tpu._compat import axis_size, shard_map
 
 from horovod_tpu.models import latent, mamba
-from horovod_tpu.models._kinds import (BlockKind, Leaf, normal, ones, remat,
-                                       rmsnorm, rope, scaled, zeros)
+from horovod_tpu.models._kinds import (BlockKind, Leaf, Rope, normal, ones,
+                                       remat, rmsnorm, rope, scaled, zeros)
 from horovod_tpu.models.scan_util import multi_step
 from horovod_tpu.parallel.ring_attention import ring_attention_spmd
 from horovod_tpu.parallel.moe import expert_ffn, moe_layer_spmd, rows_held
@@ -132,7 +140,12 @@ class TransformerConfig:
     #                             ("mamba",) a Mamba-2 mixer (``ssm_*``),
     #                             ("experts",) the expert layer, ("attention",
     #                             window, rope) attention; a pattern is of
-    #                             such kinds throughout or of none
+    #                             such kinds throughout or of none. ``rope``
+    #                             may be a ``_kinds.Rope`` table; an attention
+    #                             kind may go on (.., heads, gated): its own
+    #                             number of query heads (None: ``n_heads``)
+    #                             and a sigmoid gate a head on the core's
+    #                             output, read from the block's normed input
     moe_router_input: str = "tokens"    # what the router's logits are
     #                             computed from: the normed tokens the experts
     #                             get ("tokens"), or the block's input, before
@@ -275,7 +288,8 @@ class TransformerConfig:
                               if isinstance(kind[0], str))
         if words and not all(
                 kind[0] in _BLOCK_KINDS
-                and len(kind) == _BLOCK_KINDS[kind[0]].length
+                and 0 <= len(kind) - _BLOCK_KINDS[kind[0]].length
+                <= _BLOCK_KINDS[kind[0]].optional
                 for kind in kinds):
             raise ValueError(
                 f"layer_pattern={self.layer_pattern}, lead_pattern="
@@ -284,6 +298,11 @@ class TransformerConfig:
                 "is of such blocks throughout or of none")
         for word in words:
             _BLOCK_KINDS[word].validate(self)
+        for kind in kinds:
+            heads = _kind_heads(self, kind)
+            if heads % self.kv_heads:
+                raise ValueError(f"n_kv_heads={self.kv_heads} does not "
+                                 f"divide the {heads} heads of {kind}")
         if self.mtp_depth not in (0, 1):
             raise NotImplementedError(
                 f"mtp_depth={self.mtp_depth}: one multi-token-prediction "
@@ -435,14 +454,33 @@ def _head_xent(x, head, targets, scale: float = 1.0):
     return head_softmax_xent(x, head, targets)
 
 
-def _attention_leaves(cfg: TransformerConfig):
+def _kind_fields(kind) -> Tuple[Optional[int], bool]:
+    """What an attention kind gives beyond (window, rope): (its query heads
+    or None, whether its core's output is gated); (None, False) for a kind
+    that names neither and for any other kind."""
+    if kind[0] != "attention":
+        return None, False
+    heads, gated = (tuple(kind[3:]) + (None, False))[:2]
+    return heads, bool(gated)
+
+
+def _kind_heads(cfg: TransformerConfig, kind) -> int:
+    """Query heads of a block of ``kind``: its own, or ``cfg.n_heads``."""
+    return _kind_fields(kind)[0] or cfg.n_heads
+
+
+def _attention_leaves(cfg: TransformerConfig, kind=("attention",)):
     M = cfg.d_model
-    q, kv = cfg.n_heads * cfg.head_dim, cfg.kv_heads * cfg.head_dim
+    heads = _kind_heads(cfg, kind)
+    q, kv = heads * cfg.head_dim, cfg.kv_heads * cfg.head_dim
     yield Leaf("ln1", (M,), ones)
     yield Leaf("wq", (M, q), normal(), (None, "tp"))
     yield Leaf("wk", (M, kv), normal(), (None, "tp"))
     yield Leaf("wv", (M, kv), normal(), (None, "tp"))
     yield Leaf("wo", (q, M), normal(), ("tp", None))
+    if _kind_fields(kind)[1]:
+        # one logit a head and position: the core's output times its sigmoid
+        yield Leaf("wg", (M, heads), normal(), (None, "tp"))
     if cfg.qk_norm:
         yield Leaf("q_norm", (q,), ones, ("tp",))
         yield Leaf("k_norm", (kv,), ones, ("tp",))
@@ -457,9 +495,11 @@ _PLAIN_LAYER = (None, True)
 def _attention_block(p, x, positions, cfg: TransformerConfig,
                      kind=_PLAIN_LAYER):
     """x: [B', S', M] local. Heads sharded over tp; sequence over sp.
-    ``kind``: the layer's (window or None, rope or not)."""
+    ``kind``: the layer's (window or None, rope or not or a
+    :class:`Rope` table of the kind's own); the block's query heads are its
+    ``wq``'s, and with a ``wg`` its core's output is gated a head."""
     B, S, M = x.shape
-    window, roped = kind
+    window, roped = kind[:2]
     grouped = cfg.kv_heads != cfg.n_heads
     if _axis_live("sp") and (window is not None or grouped
                              or cfg.attention_scale is not None):
@@ -481,8 +521,9 @@ def _attention_block(p, x, positions, cfg: TransformerConfig,
         k = k.reshape(B, S, k.shape[-1] // cfg.head_dim, cfg.head_dim)
         v = v.reshape(B, S, v.shape[-1] // cfg.head_dim, cfg.head_dim)
         if roped:
-            q = rope(q, positions, cfg.rope_theta)
-            k = rope(k, positions, cfg.rope_theta)
+            table = roped if isinstance(roped, Rope) else cfg.rope_theta
+            q = rope(q, positions, table)
+            k = rope(k, positions, table)
         # the core is the call a kernel replaces: its custom_vjp backward
         # is traced under the same scope
         with jax.named_scope(scopes.ATTENTION_CORE):
@@ -500,6 +541,11 @@ def _attention_block(p, x, positions, cfg: TransformerConfig,
                                            scopes.ATTENTION_CORE_WINDOW)):
                     o = attend(q, k, v, causal=True, window=window,
                                scale=cfg.attention_scale)
+        if "wg" in p:
+            with jax.named_scope(scopes.ATTENTION_GATE):
+                gate = jax.nn.sigmoid(
+                    (h @ p["wg"].astype(h.dtype)).astype(jnp.float32))
+                o = o * gate[..., None].astype(o.dtype)
         o = o.reshape(B, S, Hl * cfg.head_dim) @ p["wo"].astype(x.dtype)
         o = _psum_if(o, "tp")
         if cfg.post_norm:
@@ -702,7 +748,7 @@ _BLOCK_KINDS = {
         apply=lambda p, x, positions, cfg, kind: (
             _ffn_block(p, x, cfg, routed=False)[0], None)),
     "attention": BlockKind(
-        length=3, leaves=_attention_leaves,
+        length=3, optional=2, leaves=_attention_leaves,
         apply=lambda p, x, positions, cfg, kind: (
             _attention_block(p, x, positions, cfg, kind[1:]), None)),
 }
@@ -717,12 +763,25 @@ _TWO_SUBLAYERS = BlockKind(
 
 def _stack_of(kind) -> Optional[str]:
     """The stack of ``layers`` a block of ``kind`` is in: its word's, or the
-    one unnamed stack (None) of two-sublayer blocks."""
-    return kind[0] if isinstance(kind[0], str) else None
+    one unnamed stack (None) of two-sublayer blocks; a kind that gives
+    fields which change a leaf's shape (:func:`_kind_fields`) is in a stack
+    of the word and those (``attention_64_gated``)."""
+    if not isinstance(kind[0], str):
+        return None
+    heads, gated = _kind_fields(kind)
+    return "_".join([kind[0]] + [str(heads)] * (heads is not None)
+                    + ["gated"] * gated)
 
 
 def _row(kind) -> BlockKind:
-    return _BLOCK_KINDS.get(_stack_of(kind), _TWO_SUBLAYERS)
+    """The row of ``_BLOCK_KINDS`` a block of ``kind`` is of, its leaves
+    those of the kind where the kind changes them."""
+    if not isinstance(kind[0], str):
+        return _TWO_SUBLAYERS
+    row = _BLOCK_KINDS[kind[0]]
+    if _kind_fields(kind) != (None, False):
+        row = row._replace(leaves=functools.partial(row.leaves, kind=kind))
+    return row
 
 
 def _block(p, x, positions, cfg: TransformerConfig, kind=_PLAIN_LAYER):
@@ -769,8 +828,11 @@ def _stacks(cfg: TransformerConfig, layers: int = 0, pattern=None) -> Dict:
     if _stack_of(pattern[0]) is None:
         return {None: (_TWO_SUBLAYERS, layers)}
     of = [_stack_of(kind) for kind in pattern]
-    return {word: (_BLOCK_KINDS[word], layers // len(of) * of.count(word))
-            for word in dict.fromkeys(of)}
+    first = {}
+    for key, kind in zip(of, pattern):
+        first.setdefault(key, kind)
+    return {key: (_row(kind), layers // len(of) * of.count(key))
+            for key, kind in first.items()}
 
 
 def _build_tree(cfg: TransformerConfig, n_stages: int, leaf_of) -> Dict:

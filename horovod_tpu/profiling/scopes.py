@@ -80,6 +80,10 @@ ATTENTION_CORE = "hvd.attention.core"
 #: window, and of one that sees the whole causal history
 ATTENTION_CORE_WINDOW = "hvd.attention.core.window"
 ATTENTION_CORE_FULL = "hvd.attention.core.full"
+#: nested in ATTENTION, in a block of a gated kind (``("attention", window,
+#: rope, heads, True)``): the gate's projection of the block's normed input,
+#: its sigmoid and the multiply of the core's output, a scalar a head
+ATTENTION_GATE = "hvd.attention.gate"
 #: nested in ATTENTION, around everything between a latent-attention block's
 #: norm and its core (``models/latent.py``), and its two parts. Down: the
 #: projections onto the two latents and the shared rope key, and the
@@ -150,12 +154,15 @@ LOOP_PHASES = (LOOP, LOOP_GATE)
 #: phases only a stack with several kinds of layer has, each forward and
 #: backward
 MIXED_PHASES = (ATTENTION_CORE_WINDOW, ATTENTION_CORE_FULL)
+#: the phase only a stack with gated attention blocks has (Laguna), forward
+#: and backward
+GATED_PHASES = (ATTENTION_GATE,)
 #: phases only a model with latent attention and a multi-token-prediction
 #: module has (GLM-4.7-Flash), each forward and backward
 LATENT_PHASES = (ATTENTION_LATENT, ATTENTION_LATENT_DOWN, ATTENTION_LATENT_UP,
                  MTP, MTP_PROJ)
 DEVICE_PHASES = (MODEL_PHASES + MOE_PHASES + LOOP_PHASES + MIXED_PHASES
-                 + HYBRID_PHASES + LATENT_PHASES
+                 + HYBRID_PHASES + LATENT_PHASES + GATED_PHASES
                  + (GRAD_SYNC, OPTIMIZER, RECOMPUTE))
 
 # -- host spans (profiling.annotate) ------------------------------------------

@@ -21,8 +21,8 @@ cannot go stale:
   nothing read: None, never a raise), a ``reader`` names a file under
   ``readers/`` whose imports from the product resolve like ``run.py``'s.
   The cases are the benchmark's own (``benchmarks/chip/tests/
-  test_layer_metrics.py``), imported here so that tier-1 runs them: 107
-  entries and files since PR 52 (106 since PR 51, 96 before it), two cases
+  test_layer_metrics.py``), imported here so that tier-1 runs them: 123
+  entries and files since PR 53 (107 since PR 52, 106 since PR 51), two cases
   each, and two readers (``host_pauses``, ``step_owners``);
 * the reader that gives every device instruction one owner and one reason
   (``readers/step_owners.py``), by its own cases
@@ -550,6 +550,100 @@ def test_the_latent_step_gives_what_the_glm4_moe_lite_adapter_reads():
     assert scopes.LATENT_PHASES == (
         "hvd.attention.latent", "hvd.attention.latent.down",
         "hvd.attention.latent.up", "hvd.mtp", "hvd.mtp.proj")
+
+
+def test_the_banded_step_gives_what_the_laguna_adapter_reads():
+    """``adapters/laguna.py`` names the stacks of ``lead`` and ``layers`` by
+    their attention shapes (``_stack``, ``_places``, ``_leaf_paths``,
+    ``_init_function``), reads ``held_rows``, ``max_expert_load`` and
+    ``dropped`` from the step's fourth output and ``router_choices``; the
+    configuration's fields reach ``TransformerConfig`` as kinds
+    ``("attention", window, Rope, heads, gated)`` of ``layer_pattern`` and
+    ``lead_pattern``; the phase files look for the gate's scope, the roofline
+    functions for the adapter's ``shapes()`` keys."""
+    import sys
+    import jax
+    import numpy as np
+    if CHIP not in sys.path:
+        sys.path.insert(0, CHIP)
+    from adapters import laguna
+    from trees import get_leaves
+    from horovod_tpu.models import transformer as t
+    from horovod_tpu.models._kinds import Rope, Yarn
+    from horovod_tpu.parallel import build_mesh
+    from horovod_tpu.profiling import scopes
+    for name in ("shapes", "tokens_per_step", "flops_per_token",
+                 "host_batch", "abstract_step", "Cell"):
+        assert callable(getattr(laguna, name)), name
+    assert {"program_choices", "program_loss_and_grads", "compiled_step",
+            "step"} <= set(dir(laguna.Cell))
+    with open(os.path.join(CHIP, "configs", "laguna-xs.2.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(CHIP, "workloads",
+                           "train.s8192.b1.banded.json")) as f:
+        job = json.load(f)
+    full = laguna._model_config(config, job)
+    window_kind = ("attention", 512, Rope(10000.0), 64, True)
+    full_kind = ("attention", None, Rope(500000.0, 64, Yarn(
+        64.0, 4096, 64.0, 1.0, 1.4158883083359672)), 48, True)
+    assert full.layer_pattern == (window_kind, ("experts",)) * 3 + (
+        full_kind, ("experts",))
+    assert full.lead_pattern == (full_kind, ("dense",))
+    assert [t._stack_of(k) for k in (window_kind, full_kind)] == [
+        "attention_64_gated", "attention_48_gated"] == [
+            laguna._stack({"heads": h}, True) for h in (64, 48)]
+    assert (full.head_dim, full.kv_heads, full.dense_ff, full.d_ff,
+            full.moe_shared_width, full.moe_gated, full.expert_share,
+            full.held_experts, full.moe_routed_scale, full.remat) == (
+                128, 8, 8192, 512, 512, True, (0, 8), 32, 2.5,
+                config["assumed"]["checkpoint_every_block"] or None)
+    sizes = laguna.shapes(config, job)
+    for function in ("banded_flash_attention",
+                     "banded_flash_attention_backward", "latent_moe_gmm",
+                     "latent_head_xent"):
+        need = getattr(importlib.import_module(f"roofline_{function}"),
+                       function)(sizes)
+        assert need["flops"] > 0 and need["bytes"] > 0, function
+    assert {"layer_heads", "layer_windows", "kv_heads", "held_experts",
+            "first_expert", "d_expert", "dense_ff"} <= set(sizes)
+    config, job = {**config, **config["tiny"]}, {**job, **job["tiny"]}
+    cfg = laguna._model_config(config, job)
+    params = t.init_params(np.random.RandomState(0), cfg, 1)
+    assert set(params) == {"embed", "ln_f", "lm_head", "layers", "lead"}
+    assert set(params["layers"]) == {"attention_8_gated",
+                                     "attention_6_gated", "experts"}
+    assert set(params["lead"]) == {"attention_6_gated", "dense"}
+    assert set(params["lead"]["attention_6_gated"]) == {
+        "ln1", "wq", "wk", "wv", "wo", "wg"}
+    assert set(params["layers"]["experts"]) == {
+        "ln2", "router", "router_bias", "we1", "we2", "we3", "ws1", "ws2",
+        "ws3"}
+    ours = jax.eval_shape(laguna._init_function(cfg, config),
+                          jax.random.PRNGKey(0))
+    assert jax.tree_util.tree_map(lambda a: a.shape, ours) == \
+        jax.tree_util.tree_map(lambda a: a.shape, params)
+    leaves = get_leaves(params, laguna._leaf_paths(config))
+    assert set(leaves) == {
+        "lm_head", "first_query", "window_key", "window_gate",
+        "last_full_query", "dense_down", "last_router",
+        "last_experts_down"}
+    assert leaves["window_gate"].shape == (cfg.d_model, 8)
+    assert leaves["last_full_query"].shape == (cfg.d_model, 6 * 16)
+    # every layer's two blocks are where the adapter says the reference
+    # finds them
+    for (path, index), _ffn in laguna._places(config):
+        assert params[path[0]][path[1]]["wq"][index].ndim == 2
+    mesh = build_mesh(devices=jax.devices()[:1], dp=-1)
+    batch = laguna.host_batch(config, job, 0, 0, 1)
+    _loss, aux, _grads = jax.eval_shape(
+        t.make_grad_fn(cfg, mesh), params, batch["tokens"], batch["targets"])
+    assert set(aux) == {"aux_loss", "load_balance_loss", "router_z_loss",
+                        "max_expert_load", "dropped", "held_rows"}
+    choices = jax.eval_shape(
+        lambda p, tok: t.router_choices(p, tok, cfg), params,
+        batch["tokens"])
+    assert choices.shape == (8, batch["tokens"].size, cfg.moe_top_k)
+    assert scopes.GATED_PHASES == ("hvd.attention.gate",)
 
 
 #: sha256 of the GPT cell's tiny train step's jaxpr (``_gpt_tiny_jaxpr``),

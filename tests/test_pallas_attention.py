@@ -509,6 +509,7 @@ _CELL_TILES = {
     "glm-4.7-flash.s8192": ((8192, 256), (1024, 1024), (512, 512, 8192)),
     "granite-4.0-h-micro.s4096": ((4096, 64), (1024, 1024),
                                   (1024, 1024, 4096)),
+    "laguna-xs.2.s8192": ((8192, 128), (1024, 1024), (1024, 1024, 8192)),
 }
 
 
@@ -571,6 +572,17 @@ _BANDED = {
     "k tile wider, a window": (1, 512, 2, 2, 128, 128, 256, 256),
     "the cells' tile, causal": (1, 2048, 1, 1, 128, 1024, 1024, None),
     "the cells' tile, a window": (1, 3072, 2, 1, 128, 1024, 1024, 1024),
+    # Laguna's window layers and full layers: a window of half the tile, so
+    # every tile the band touches runs whole under its mask, and groups of 8
+    # and of 6 (no power of two) through the index maps
+    "window 512 under the cells' tile, a group of 8":
+        (1, 2048, 8, 1, 128, 1024, 1024, 512),
+    "window 512 under the cells' tile, a group of 6":
+        (1, 2048, 6, 1, 128, 1024, 1024, 512),
+    "a group of 6, causal, two k/v heads": (1, 512, 12, 2, 128, 256, 256,
+                                            None),
+    "window 512 in tiles of 512, a group of 8":
+        (1, 1536, 8, 1, 128, 512, 512, 512),
 }
 
 
@@ -699,3 +711,61 @@ def test_tiles_run_in_bands_where_the_mask_s_lines_cross_them_corner_to_corner(
         tiles):
     block_q, block_k, window, banded = tiles
     assert pa.banded_tiles(block_q, block_k, window) is banded
+
+
+# -- a window narrower than the tile (Laguna: 512 under 1024 x 1024) ----------
+
+def test_a_window_of_half_a_tile_at_the_cell_s_shape():
+    """8192 x 8192 in 1024 x 1024 tiles under a window of 512: 15 of the 36
+    causal tiles are live, eight on the diagonal and seven that the band's
+    lower edge crosses; none is wholly inside the band, 512 divides no tile,
+    so every one runs whole under its mask (2 x 1024^2 scores a q tile for
+    the 1024 x 512 + a triangle that are live); the index maps stay inside
+    them. In 512 x 512 tiles the band is corner to corner again."""
+    bq = bk = 1024
+    n, window = 8, 512
+    assert not pa.banded_tiles(bq, bk, window)
+    assert pa.banded_tiles(512, 512, window)
+    live = whole = 0
+    for qi in range(n):
+        lo = int(pa._first_band_k_tile(qi, bq, bk, window))
+        hi = int(pa._last_live_k_tile(qi, bq, bk))
+        assert hi == qi and lo == max(qi - 1, 0)
+        for kj in range(n):
+            crossed, clean = (bool(x) for x in pa._band_tiles(
+                qi * bq, kj * bk, bq, bk, window))
+            assert (crossed or clean) == (lo <= kj <= hi), (qi, kj)
+            live += crossed
+            whole += clean
+            if crossed:
+                assert int(pa._first_live_q_tile(kj, bq, bk)) <= qi \
+                    <= int(pa._last_band_q_tile(kj, bq, bk, window))
+    assert (live, whole) == (15, 0)
+    # (the band's edge crosses the diagonal tiles too: all fifteen)
+    assert pa.band_tile_counts(8192, bq, bk, window) == (36, 15, 15)
+    assert pa.band_tile_counts(8192, 512, 512, window) == (136, 31, 15)
+    # scores computed against scores live, a head: 15.7 M for 4.1 M
+    computed = 15 * bq * bk
+    alive = window * (window + 1) // 2 + (8192 - window) * window
+    assert round(computed / alive, 2) == 3.87
+
+
+@pytest.mark.parametrize("group", [6, 8])
+def test_the_backward_under_a_window_of_half_a_tile(group):
+    """``flash_backward`` at the cell's tile, a window of 512 and Laguna's
+    groups, on the forward's own (o, lse): dk and dv are the group's sum."""
+    B, S, D, window = 1, 2048, 128, 512
+    q, k, v = _heads(B, S, group, 1, D, seed=53)
+    w = jnp.cos(jnp.arange(q.size, dtype=jnp.float32).reshape(q.shape))
+    with jax.default_matmul_precision("highest"):
+        want = jax.grad(lambda *a: jnp.sum(
+            pa._banded_attention(*a, window) * w), (0, 1, 2))(q, k, v)
+        o, lse = pa.flash_attention_with_lse(q, k, v, True, None, 1024, 1024,
+                                             True, window)
+        got = pa.flash_backward(q, k, v, o, lse, w, jnp.zeros_like(lse),
+                                True, D ** -0.5,
+                                pa.BwdBlocks(1024, 1024, S), True, window)
+    for name, g, r in zip(("dq", "dk", "dv"), got, want):
+        assert g.shape == r.shape, name
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), rtol=2e-4,
+                                   atol=2e-4, err_msg=name)

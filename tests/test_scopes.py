@@ -28,7 +28,7 @@ PACKAGE = os.path.join(os.path.dirname(os.path.dirname(
 
 def test_the_vocabulary_is_the_only_place_the_strings_are_written():
     names = scopes.DEVICE_PHASES + scopes.HOST_SPANS
-    assert len(set(names)) == len(names) == 33
+    assert len(set(names)) == len(names) == 34
     assert scopes.RECOMPUTE in scopes.DEVICE_PHASES
     # JAX's own word is no phase, and is written here alone all the same
     names += (scopes.RECOMPUTED,)
@@ -183,6 +183,39 @@ def _hybrid_step():
             (params, init_opt_state(tx, params, mesh, cfg), t, y))
 
 
+def _gated_step():
+    """Two attention shapes in one period, each a stack of its own: window
+    layers of 8 query heads and full layers of 6 on 2 key/value heads, a
+    rotary table a kind, a gate a head on the core's output; a dense layer
+    leading sigmoid-routed experts of which a share is held."""
+    from horovod_tpu.models import (TransformerConfig, init_opt_state,
+                                    init_params, make_train_step,
+                                    shard_batch, shard_params)
+    from horovod_tpu.models._kinds import Rope, Yarn
+    full = ("attention", None, Rope(5e5, 8, Yarn(64.0, 8)), 6, True)
+    window = ("attention", 8, Rope(1e4), 8, True)
+    cfg = TransformerConfig(vocab_size=64, d_model=32, n_heads=6,
+                            n_layers=4, d_ff=16, dense_ff=32, max_seq=32,
+                            n_experts=4, moe_top_k=2, moe_gated=True,
+                            moe_renormalize=True, moe_balance_weight=0.0,
+                            moe_router_scores="sigmoid",
+                            moe_routed_scale=2.5, moe_shared_width=16,
+                            ffn_gated=True, tie_embeddings=False,
+                            dtype=jnp.float32, head_width=16, n_kv_heads=2,
+                            layer_pattern=(window, ("experts",),
+                                           full, ("experts",)),
+                            lead_pattern=(full, ("dense",)),
+                            expert_share=(0, 2))
+    mesh = build_mesh(devices=jax.devices()[:1], dp=-1)
+    params = shard_params(init_params(np.random.RandomState(0), cfg, 1),
+                          cfg, mesh)
+    tx = optax.adamw(1e-3)
+    tokens = np.zeros((2, 16), np.int32)
+    t, y = shard_batch(tokens, tokens, mesh)
+    return (make_train_step(cfg, mesh, tx),
+            (params, init_opt_state(tx, params, mesh, cfg), t, y))
+
+
 _TEXTS = {}
 
 
@@ -192,7 +225,8 @@ def _compiled_text(model: str) -> str:
                       "flagship.dp2": lambda: _flagship_step(2),
                       "moe": _moe_step, "looped": _looped_step,
                       "mixed": _mixed_step,
-                      "hybrid": _hybrid_step}[model]()
+                      "hybrid": _hybrid_step,
+                      "gated": _gated_step}[model]()
         _TEXTS[model] = step.lower(*args).compile().as_text()
     return _TEXTS[model]
 
@@ -239,6 +273,30 @@ def test_the_mixed_step_carries_every_phase_in_both_directions(phase):
                          + (scopes.ATTENTION_CORE_FULL,))
 def test_the_hybrid_step_carries_every_phase_in_both_directions(phase):
     assert _directions(_compiled_text("hybrid"), phase) == {"fwd", "bwd"}
+
+
+@pytest.mark.parametrize("phase", scopes.MODEL_PHASES + scopes.MOE_PHASES
+                         + scopes.MIXED_PHASES + scopes.GATED_PHASES
+                         + (scopes.MOE_SHARED,))
+def test_the_gated_step_carries_every_phase_in_both_directions(phase):
+    assert _directions(_compiled_text("gated"), phase) == {"fwd", "bwd"}
+
+
+def test_the_gate_nests_in_attention_beside_the_core():
+    """hvd.attention.gate inside hvd.attention and outside
+    hvd.attention.core (``step.attention_core_ms`` is the kernels' cover,
+    not the gate's); a step without a gated kind has no such phase."""
+    assert scopes.GATED_PHASES == ("hvd.attention.gate",)
+    paths = re.findall(r'op_name="([^"]*)"', _compiled_text("gated"))
+
+    def parts(path):
+        return [re.sub(r"^(?:\w+\()+|\)+$", "", c) for c in path.split("/")]
+    gate = [parts(p) for p in paths if scopes.ATTENTION_GATE in parts(p)]
+    assert gate and all(scopes.ATTENTION in p
+                        and scopes.ATTENTION_CORE not in p for p in gate)
+    assert not any(scopes.ATTENTION_GATE in _compiled_text(model)
+                   for model in ("flagship", "moe", "looped", "mixed",
+                                 "hybrid"))
 
 
 def test_the_mixer_s_parts_nest_in_ssm_and_the_shared_expert_in_moe():

@@ -93,6 +93,12 @@ _QKV_GROUPED = [((1, 8192, 28, 128), jnp.bfloat16)] \
 # 1024 x 1024 tile reads exactly its VMEM budget, the backward's resident
 # form leaves room for 512-tiles
 _QKV_LATENT = [((1, 8192, 20, 256), jnp.bfloat16)] * 3
+# the cell laguna-xs.2.s8192: window layers of 64 query heads and full
+# layers of 48 on the same 8 key/value heads of 128 (groups of 8 and of 6)
+_QKV_BANDED_WINDOW = [((1, 8192, 64, 128), jnp.bfloat16)] \
+    + [((1, 8192, 8, 128), jnp.bfloat16)] * 2
+_QKV_BANDED_FULL = [((1, 8192, 48, 128), jnp.bfloat16)] \
+    + [((1, 8192, 8, 128), jnp.bfloat16)] * 2
 _FLASH = ("hvd_flash_attention", "hvd_flash_bwd")
 # the attention core of the cells bert-large.s128 and bert-large.s512: q, k,
 # v and the [B, S] key mask. Sixteen heads of 64 are eight 128-lane columns
@@ -125,6 +131,12 @@ _GMM_LATENT_DOWN = [((32768, 1536), jnp.bfloat16),
                     ((8, 1536, 2048), jnp.float32), ((8,), jnp.int32)]
 _GMM_SHARE_DOWN = [((49152, 768), jnp.bfloat16),
                    ((16, 768, 2560), jnp.float32), ((16,), jnp.int32)]
+# a held share's expert layer in laguna-xs.2.s8192: 8192 tokens x top-8
+# gathered rows, 32 held experts of 2048 <-> 512, ~256 rows a group
+_GMM_BANDED = [((65536, 2048), jnp.bfloat16), ((32, 2048, 512), jnp.float32),
+               ((32,), jnp.int32)]
+_GMM_BANDED_DOWN = [((65536, 512), jnp.bfloat16),
+                    ((32, 512, 2048), jnp.float32), ((32,), jnp.int32)]
 # the Mamba-2 scan of the cell nemotron-3-nano-30b-a3b.s8192: x, dt, a, b,
 # c at 8192 positions, 64 heads of 64 in 8 groups, state 128, chunk 128
 _SSM_CELL = [((1, 8192, 64, 64), jnp.bfloat16), ((1, 8192, 64), jnp.float32),
@@ -158,6 +170,14 @@ CASES = {
             q, k, v, True, window=4096)), (0, 1, 2)), _QKV_GROUPED, _FLASH),
     "flash_fwd_grad_full_grouped": (_flash_grad, _QKV_GROUPED, _FLASH),
     "flash_fwd_grad_latent": (_flash_grad, _QKV_LATENT, _FLASH),
+    # a window of half the tile at a group of 8 (every live tile whole under
+    # its mask, both index maps clamped to two tiles a row of tiles), and a
+    # group of 6 through the k/v block index
+    "flash_fwd_grad_window_narrower_than_the_tile": (
+        jax.grad(lambda q, k, v: _sum32(pa.flash_attention_tpu(
+            q, k, v, True, window=512)), (0, 1, 2)), _QKV_BANDED_WINDOW,
+        _FLASH),
+    "flash_fwd_grad_group_of_six": (_flash_grad, _QKV_BANDED_FULL, _FLASH),
     # a window that is no multiple of the tile: whole masked tiles
     "flash_fwd_grad_window_unaligned": (
         jax.grad(lambda q, k, v: _sum32(pa.flash_attention_tpu(
@@ -198,6 +218,13 @@ CASES = {
     "moe_gmm_latent_down_grad": (
         jax.grad(lambda x, w, g: _sum32(moe.grouped_matmul(x, w, g)),
                  (0, 1)), _GMM_LATENT_DOWN,
+        "transpose_jvp_" + moe.GMM_NAME),
+    "moe_gmm_banded_grad": (
+        jax.grad(lambda x, w, g: _sum32(moe.grouped_matmul(x, w, g)),
+                 (0, 1)), _GMM_BANDED, "transpose_jvp_" + moe.GMM_NAME),
+    "moe_gmm_banded_down_grad": (
+        jax.grad(lambda x, w, g: _sum32(moe.grouped_matmul(x, w, g)),
+                 (0, 1)), _GMM_BANDED_DOWN,
         "transpose_jvp_" + moe.GMM_NAME),
     # an expert width no 128-multiple divides (1856 = 2^6 * 29): a block
     # spans it whole, as the contraction and as the output's columns
@@ -658,6 +685,52 @@ def test_mixed_step_compiles_for_v5e_on_the_kernels(mixed_step):
     for name in scopes.MIXED_PHASES:
         assert name + "/" in names, name
     assert 4.0e9 < step_bytes(compiled.memory_analysis())["total"] < 15.0e9
+
+
+def test_banded_step_compiles_for_v5e_on_the_kernels(topo):
+    """The cell laguna-xs.2.s8192's step: a leading full layer and one
+    period of three window-512 layers at 64 query heads and a full layer at
+    48, on 8 key/value heads, 32 of 256 experts held, no block checkpointed.
+    Every layer's attention is the two flash kernels at its own head count
+    (a call site a layer; no score-shaped array in the program), the experts
+    are ``hvd_moe_gmm``, the head ``hvd_fused_xent``; the gate's scope and
+    both layer kinds' are in the program; the bytes are what
+    ``assumed.recomputation`` says, under the compiler's 15.75 GB."""
+    with _compile_cache_off(), pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        step, args, shapes, step_bytes = _cell_step("laguna-xs.2.s8192",
+                                                    topo)
+        compiled = step.lower(*args).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    layers, routed = shapes["layers"], shapes["routed_layers"]
+    assert (layers, routed) == (5, 4)
+    assert shapes["attention_forward_calls"] == layers   # nothing run twice
+    assert sum("hvd_flash_attention" in c for c in calls) == layers
+    assert sum("hvd_flash_bwd" in c for c in calls) == layers
+    assert sum(moe.GMM_NAME in c for c in calls) == 9 * routed
+    assert sum("hvd_fused_xent" in c for c in calls) == 1
+    # the two attention shapes, each at its own query heads
+    for heads, n in ((64, 3), (48, 2)):
+        assert sum(f"bf16[1,8192,{heads * 128}]" in c for c in calls
+                   if "hvd_flash_attention" in c) == n, heads
+    s = shapes["seq"]
+    # (q of 64 heads of 128 is itself [1, 8192, 8192]: a score array has a
+    # dimension of heads in front of its two of positions)
+    assert not re.search(r"\[(?:\d+,)*(?:[2-9]|\d\d+),%d,%d\]" % (s, s),
+                         text), "a score-shaped array"
+    k, m = shapes["experts_per_token"], shapes["d_model"]
+    assert f"f32[{s * k},{m}]" not in _arrays_in_memory(text), \
+        "the rows in float32"
+    from horovod_tpu.profiling import scopes
+    names = "\n".join(line for line in text.splitlines()
+                      if "op_name=" in line)
+    for name in scopes.MIXED_PHASES + scopes.GATED_PHASES + (
+            scopes.MOE_SHARED,):
+        assert name + "/" in names, name
+    total = step_bytes(compiled.memory_analysis())["total"]
+    assert 15.4e9 < total < 15.75e9, total      # PERF.md section 6, PR 53
 
 
 def _computations(text):
