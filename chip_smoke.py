@@ -1177,9 +1177,22 @@ def _attention_path(shape, causal=True, masked=False, kv_heads=None,
                 f"{pa.LANES // D} heads a step, "
                 f"{math.prod(pa.block_grid(B, H, D, rows))} steps, "
                 f"VMEM estimate {mib:.1f} MiB")
-    bq, bk = pa.flash_blocks(S, S, D, jnp.bfloat16)
-    steps = math.prod(pa.flash_grid(B, H, S, S, bq, bk))
-    bwd = pa.flash_bwd_blocks(S, S, D, jnp.bfloat16)
+    return _flash_call((B, S, H, D), kv_heads, window)
+
+
+def _flash_call(shape, kv_heads=None, window=None) -> str:
+    """What the flash kernels' rules pick for one causal call at q of
+    ``shape`` (``_attention_path``), from the functions the kernels call:
+    tile and grid steps forward and backward, and under a window the grid
+    steps and the tiles run a head."""
+    import math
+    import jax.numpy as jnp
+    from horovod_tpu.ops import pallas_attention as pa
+    B, S, H, D = shape
+    bq, bk = pa.flash_blocks(S, S, D, jnp.bfloat16, window)
+    grid = pa.flash_grid(B, H, S, S, bq, bk, window)
+    bwd = pa.flash_bwd_blocks(S, S, D, jnp.bfloat16, window)
+    bwd_grid = pa.flash_bwd_grid(B, H, S, S, bwd, window)
     ranges = S // bwd.rows
     form = ("dq resident" if ranges == 1
             else f"dq in {ranges} q ranges of {bwd.rows} rows")
@@ -1197,16 +1210,17 @@ def _attention_path(shape, causal=True, masked=False, kv_heads=None,
             fwd += f", on the band's edge {pieces(True)}"
     band = ""
     if window is not None:
-        def tiles(bq, bk):
+        def tiles(grid, bq, bk):
             every, live, edge = pa.band_tile_counts(S, bq, bk, window)
-            return f"{live} of {every} causal tiles a head, {edge} on the edge"
-        band += (f"; window {window}: forward {tiles(bq, bk)}, backward "
-                 f"{tiles(bwd.block_q, bwd.block_k)}")
+            return (f"{math.prod(grid[1:])} steps and {live} of {every} "
+                    f"causal tiles a head, {edge} on the edge")
+        band += (f"; window {window}: forward {tiles(grid, bq, bk)}, "
+                 f"backward {tiles(bwd_grid, bwd.block_q, bwd.block_k)}")
     if kv_heads not in (None, H):
         band += f"; kv heads {kv_heads}, group {H // kv_heads}"
-    return (f"pallas hvd_flash_attention {bq}x{bk}, {steps} steps, {fwd}; "
-            f"hvd_flash_bwd {bwd.block_q}x{bwd.block_k}, {form}, "
-            f"{math.prod(pa.flash_bwd_grid(B, H, S, S, bwd))} steps, VMEM "
+    return (f"pallas hvd_flash_attention {bq}x{bk}, {math.prod(grid)} steps, "
+            f"{fwd}; hvd_flash_bwd {bwd.block_q}x{bwd.block_k}, {form}, "
+            f"{math.prod(bwd_grid)} steps, VMEM "
             f"estimate {pa.flash_bwd_vmem_bytes(*bwd, D, 2) / 2 ** 20:.1f} "
             f"MiB{band}")
 

@@ -10,6 +10,7 @@ instead of O(S²):
 
   grid = (batch·heads, Sq/block_q, Sk/block_k)   — K tile innermost
   per (q tile): for each k tile: s = q @ kᵀ; online-softmax update
+  (under a window the k axis spans a q tile's band alone: **Window**)
 
 **Operands where they lie.** A head of whole lane tiles (``D % 128 == 0``)
 is read and written in place: q, k, v and o are ``[B, S, heads·D]``, the
@@ -24,11 +25,12 @@ one sequence it keeps activations with the tokens on the lanes and copies
 q and k to row-major where an elementwise op (rope) stands between the
 projection and the call (PERF.md §7).
 
-**Tiles come from the shape** (:func:`flash_blocks`): the largest of
-1024/512/256/128 that divide ``Sq`` / ``Sk``, the q tile halved until
-the working set fits ``VMEM_BUDGET``. A grid step costs about 0.35 µs
-whatever it does, so a 128 × 128 tile (0.04 µs of MXU work at bf16) is
-all overhead: at B2·S2048·H16·D128 causal a call takes 2.59 ms with
+**Tiles come from the shape and the window** (:func:`flash_blocks`): the
+largest of 1024/512/256/128 that divide ``Sq`` / ``Sk`` and are no wider
+than a causal window (or than 128), the q tile halved until the working set
+fits ``VMEM_BUDGET``. A grid step that runs a tile costs about 0.35 µs
+beside the tile's work, so a 128 × 128 tile (0.04 µs of MXU work at bf16)
+is all overhead: at B2·S2048·H16·D128 causal a call takes 2.59 ms with
 128 × 128 tiles and 0.48 ms with 1024 × 1024 (v5e; PERF.md, PR 25).
 Callers pass no block; ``block_q`` / ``block_k`` are overrides for
 tests. The contract to callers is only ``MIN_BLOCK``: sequence lengths
@@ -45,20 +47,35 @@ the accumulator and the log-sum-exp are float32 for every input dtype.
 nothing: the K/V index maps clamp to the q tile's last live tile, and
 Pallas issues no DMA for a block index that repeats. Only the tiles the
 diagonal crosses build a mask; those below it skip it. A square tile meets
-the diagonal corner to corner and runs in bands of ``FWD_DIAG_ROWS`` q rows
-(:func:`tile_pieces`): a band leaves out the k columns past its last row,
-which the mask kills for all of it (of a 1024 x 1024 tile 16 of its 64
-blocks of 128 x 128). A masked score added ``exp(-1e30 - m) = 0`` to ``l``
+the diagonal corner to corner and runs in bands of q rows
+(:func:`tile_pieces`; 512 rows of a 1024 x 1024 tile, 128 of a smaller
+one): a band leaves out the k columns past its last row, which the mask
+kills for all of it (of a 1024 x 1024 tile 16 of its 64 blocks of 128 x
+128). A masked score added ``exp(-1e30 - m) = 0`` to ``l``
 and to ``p·v``: leaving it out changes no term.
 
 **Window.** ``window=W`` (causal only) keeps of a query at ``t`` the keys
-``t - W < j <= t``: a band under the diagonal. A tile wholly below the
-band is skipped as one above the diagonal is (the index maps clamp to the
-first live tile too), and a tile the band's lower edge crosses is masked as
-a diagonal tile is; where the window is a multiple of the (square) tile the
+``t - W < j <= t``: a band under the diagonal, and a windowed call walks
+its band alone. Its tile is no wider than the window (a window of 512 under
+1024 x 1024 tiles would leave every live tile crossed by the diagonal or
+the band's lower edge, 3.87 scores computed for one live), and its grid's
+innermost axis has as many steps as a row of tiles' band has tiles
+(:func:`flash_grid`: ``block_q / block_k + ceil((W - 1) / block_k)``, held
+to the sequence's), the block index the band's first tile plus the step
+(:func:`_band_k_tile`); the backward's q axis likewise
+(:func:`flash_bwd_grid`, :func:`_band_q_tile`). A step past the band's last
+tile (the first q tiles, whose band the sequence's start cuts; the last k
+tiles in the backward) runs nothing and fetches nothing: the index stays on
+the last live tile. A tile the band's lower edge crosses is masked as a
+diagonal tile is; where the window is a multiple of the (square) tile the
 edge crosses its tiles corner to corner too, and they run in the mirror
-image of the diagonal's bands. At 8192 x 8192 with 1024 x 1024 tiles and a window of
-4096 a head runs 30 of its 36 causal tiles, four of them edge tiles.
+image of the diagonal's bands. At 8192 x 8192 under a window of 4096 the
+tile stays 1024 x 1024 and a head takes 8 x 5 = 40 steps for its 30 live
+tiles (of 36 causal, four of them edge tiles); under a window of 512 the
+tile is 512 x 512 and a head takes 16 x 2 = 32 steps for 31 live tiles,
+every one on the diagonal or the edge (v5e, a call at 64 query heads on 8:
+5.21 ms forward and 7.26 backward where 1024 x 1024 tiles on the sequence's
+grid took 6.19 and 11.14; PERF.md, PR 54).
 
 **Grouped heads.** k and v may have fewer heads than q (``H % Hkv == 0``):
 q head ``h`` reads k/v head ``h // (H // Hkv)`` through the block index,
@@ -73,6 +90,7 @@ backward (Dao et al.) — so training through the kernel never writes a
 score-shaped array to HBM:
 
   grid = (batch·heads, q ranges, Sk/block_k, rows/block_q) — q tile innermost
+  (under a window: the q tiles of a k tile's band)
   per (k tile): for each q tile: pT = exp(k @ qᵀ·scale − lse);
       dv += pT @ do; dsT = pT ⊙ (v @ doᵀ − adj); dk += dsT @ q; dq += dsTᵀ @ k
 
@@ -82,7 +100,8 @@ its k tiles, so every tile is computed once: five matmuls and one pass of
 the vector work where two kernels do seven and two. Where that does not
 fit (tens of thousands of positions on one device) the q rows go in ranges
 (:func:`flash_bwd_blocks`). Its tile is a rule of its own
-(``flash_bwd_blocks``): 1024 × 1024 at the benchmark's shapes, where the
+(``flash_bwd_blocks``; no wider than a window either): 1024 × 1024 at the
+benchmark's shapes without a window narrower than that, where the
 MXU is at 86 % of its peak on the tiles it runs whole (v5e; PERF.md, PR
 31); a square tile on the diagonal runs in ``DIAG_ROWS``-row pieces that
 leave out the q rows masked for the whole piece. Same precision as the forward:
@@ -172,20 +191,32 @@ def flash_vmem_bytes(block_q: int, block_k: int, D: int, itemsize: int) -> int:
     return io + tiles + scratch
 
 
-def flash_blocks(Sq: int, Sk: int, D: int, dtype) -> Tuple[int, int]:
+def _tiles_under(window: Optional[int]) -> Tuple[int, ...]:
+    """The tiles a call under a causal ``window`` may take: none wider than
+    the window (or than ``MIN_BLOCK``), so that the diagonal and the band's
+    lower edge cross different tiles; all of ``TILES`` without a window."""
+    if window is None:
+        return TILES
+    return tuple(t for t in TILES if t <= max(window, MIN_BLOCK))
+
+
+def flash_blocks(Sq: int, Sk: int, D: int, dtype,
+                 window: Optional[int] = None) -> Tuple[int, int]:
     """``(block_q, block_k)`` of the forward kernel for q ``[.., Sq, D]``
     and k/v ``[.., Sk, D]``: the largest of ``TILES`` that divide the
-    sequence lengths, halved until the working set fits ``VMEM_BUDGET``,
-    the q tile first. The v5e sweep (PERF.md, PR 25) put a kernel call at
-    0.35 µs a grid step + 4.3 µs a million ``s`` elements + a cost per q
-    row and step that a wide k tile spreads thin, so ``block_k`` is the
-    one to keep."""
+    sequence lengths and, under a causal ``window``, are no wider than it
+    (:func:`_tiles_under`), halved until the working set fits
+    ``VMEM_BUDGET``, the q tile first. The v5e sweep (PERF.md, PR 25) put a
+    kernel call at 0.35 µs a grid step + 4.3 µs a million ``s`` elements +
+    a cost per q row and step that a wide k tile spreads thin, so
+    ``block_k`` is the one to keep."""
     if Sq % MIN_BLOCK or Sk % MIN_BLOCK:
         raise ValueError(f"Sq={Sq} and Sk={Sk} must be multiples of "
                          f"{MIN_BLOCK}")
     itemsize = jnp.dtype(dtype).itemsize
-    bq = next(t for t in TILES if Sq % t == 0)
-    bk = next(t for t in TILES if Sk % t == 0)
+    tiles = _tiles_under(window)
+    bq = next(t for t in tiles if Sq % t == 0)
+    bk = next(t for t in tiles if Sk % t == 0)
     while (flash_vmem_bytes(bq, bk, D, itemsize) > VMEM_BUDGET
            and max(bq, bk) > MIN_BLOCK):
         if bq > MIN_BLOCK:
@@ -196,9 +227,18 @@ def flash_blocks(Sq: int, Sk: int, D: int, dtype) -> Tuple[int, int]:
 
 
 def flash_grid(B: int, H: int, Sq: int, Sk: int, block_q: int,
-               block_k: int) -> Tuple[int, int, int]:
-    """The forward kernel's grid; its product is the grid steps of a call."""
-    return (B * H, Sq // block_q, Sk // block_k)
+               block_k: int,
+               window: Optional[int] = None) -> Tuple[int, int, int]:
+    """The forward kernel's grid; its product is the grid steps of a call.
+    Under a causal ``window`` the k axis spans a q tile's band, not the
+    sequence: as many steps as the widest band has k tiles
+    (:func:`_band_k_tile` says which tile a step is)."""
+    nq, nk = Sq // block_q, Sk // block_k
+    if window is not None:
+        bands = (_band_k_tile(qi, 0, block_q, block_k, window)
+                 for qi in range(nq))
+        nk = min(nk, max(last - first + 1 for first, last in bands))
+    return (B * H, nq, nk)
 
 
 def _band_tiles(first_row, first_col, block_q: int, block_k: int,
@@ -236,16 +276,54 @@ def band_tile_counts(S: int, block_q: int, block_k: int,
     return causal, live, edge
 
 
+def _larger(a, b):
+    """``max`` of two tile indices: Python ints (a grid's arithmetic, the
+    tests) or a kernel's or an index map's traced values."""
+    ints = isinstance(a, int) and isinstance(b, int)
+    return max(a, b) if ints else jnp.maximum(a, b)
+
+
+def _smaller(a, b):
+    """``min``, as :func:`_larger`."""
+    ints = isinstance(a, int) and isinstance(b, int)
+    return min(a, b) if ints else jnp.minimum(a, b)
+
+
 def _first_band_k_tile(qi, block_q: int, block_k: int, window: int):
     """Window: the first k tile with a column inside q tile ``qi``'s
     band; the k tiles before it are wholly below the band."""
-    return jnp.maximum(qi * block_q - window + 1, 0) // block_k
+    return _larger(qi * block_q - window + 1, 0) // block_k
+
+
+def _band_k_tile(qi, step, block_q: int, block_k: int, window: int):
+    """Window, the forward: (the k tile that grid step ``step`` of q tile
+    ``qi`` stands for, the q tile's last live k tile). The k axis starts at
+    the band's first tile; a step past the last live tile (the first q
+    tiles, whose band the sequence's start cuts) runs nothing, and its
+    block index stays on the last live tile so that nothing is fetched."""
+    return (_first_band_k_tile(qi, block_q, block_k, window) + step,
+            _last_live_k_tile(qi, block_q, block_k))
 
 
 def _last_band_q_tile(kj, block_q: int, block_k: int, window: int):
     """Window: the last q tile with a row whose band reaches k tile
     ``kj``'s last column (not yet held to the sequence's end)."""
     return (kj * block_k + block_k - 1 + window - 1) // block_q
+
+
+def _band_q_tile(kj, step, first_tile, tiles: int, block_q: int,
+                 block_k: int, window: int):
+    """Window, the backward: (the q tile that grid step ``step`` of k tile
+    ``kj`` stands for, the last q tile of the k tile's band) among the q
+    tiles ``[first_tile, first_tile + tiles)`` of a q range (the whole
+    sequence where dq is resident). The q axis starts at the first q tile
+    at or below the diagonal; a step past the last (the last k tiles, whose
+    band the sequence's end cuts, or a range the band leaves) runs
+    nothing."""
+    first = _larger(_first_live_q_tile(kj, block_q, block_k), first_tile)
+    last = _smaller(_last_band_q_tile(kj, block_q, block_k, window),
+                    first_tile + tiles - 1)
+    return first + step, last
 
 
 #: q rows of a piece of a square tile on the diagonal or on a window's edge
@@ -257,8 +335,19 @@ def _last_band_q_tile(kj, block_q: int, block_k: int, window: int):
 #: 0.493 / 0.518 / 0.468 / 0.443 (PERF.md, PR 50). A piece of 128 rows
 #: leaves out 28 of a tile's 64 blocks and still costs more than the tile
 #: whole: a short piece streams few rows past each k block the MXU loads,
-#: where the backward's pieces (``DIAG_ROWS``) are k rows against many q rows
+#: where the backward's pieces (``DIAG_ROWS``) are k rows against many q rows.
+#: A tile no taller than this runs in bands of ``MIN_BLOCK`` rows
+#: (:func:`fwd_band_rows`): under a window of 512 every live 512 x 512 tile is
+#: on the diagonal or on the edge, and at [1, 8192, 64 on 8, 128] a call takes
+#: 5.54 / 5.59 ms with the tiles whole, 5.65 / 5.66 in bands of 256 and 5.21 /
+#: 5.21 in bands of 128, 10 of a tile's 16 blocks (PERF.md, PR 54)
 FWD_DIAG_ROWS = 512
+
+
+def fwd_band_rows(block_q: int) -> int:
+    """q rows of a band of :func:`tile_pieces`, from the tile."""
+    return FWD_DIAG_ROWS if block_q > FWD_DIAG_ROWS else min(block_q,
+                                                             MIN_BLOCK)
 
 
 def banded_tiles(block_q: int, block_k: int, window: Optional[int]) -> bool:
@@ -273,12 +362,12 @@ def tile_pieces(block_q: int, block_k: int, edge: bool = False):
     """The static pieces the forward runs of a square tile that the diagonal
     (or, ``edge``, a window's lower edge) crosses corner to corner, as
     ``(r0, rows, c0, cols)``: q rows ``[r0, r0 + rows)`` against k columns
-    ``[c0, c0 + cols)`` of the tile, in bands of ``FWD_DIAG_ROWS`` q rows
+    ``[c0, c0 + cols)`` of the tile, in bands of :func:`fwd_band_rows` q rows
     that leave out the columns the mask kills for the whole band, the ones
     past the band's last row on the diagonal and the ones before its first
     row on the edge: a 1024 x 1024 tile runs 48 of its 64 blocks of 128 x
     128 in two pieces."""
-    rows = min(FWD_DIAG_ROWS, block_q)
+    rows = fwd_band_rows(block_q)
     return [(r0, rows, r0, block_k - r0) if edge else (r0, rows, 0, r0 + rows)
             for r0 in range(0, block_q, rows)]
 
@@ -296,11 +385,15 @@ def tile_piece_blocks(block_q: int, block_k: int,
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
                   l_ref, *, scale: float, causal: bool, block_q: int,
                   block_k: int, window: Optional[int] = None):
-    """One (q-tile, k-tile) step; grid (BH, nq, nk) with k innermost."""
-    kv_idx = pl.program_id(2)
+    """One (q-tile, k-tile) step; grid (BH, nq, nk) with k innermost. Under
+    a window the k axis spans the q tile's band (:func:`flash_grid`) and
+    ``kv_idx`` is the k tile the step stands for."""
+    kv_step = pl.program_id(2)
     q_idx = pl.program_id(1)
+    kv_idx = kv_step if window is None else _band_k_tile(
+        q_idx, kv_step, block_q, block_k, window)[0]
 
-    @pl.when(kv_idx == 0)
+    @pl.when(kv_step == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
@@ -377,7 +470,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
     else:
         whole(False)()
 
-    @pl.when(kv_idx == pl.num_programs(2) - 1)
+    @pl.when(kv_step == pl.num_programs(2) - 1)
     def _finalize():
         l_safe = jnp.maximum(l_ref[:], 1e-30)
         o_ref[0] = (acc_ref[:] / l_safe).astype(o_ref.dtype)
@@ -409,11 +502,10 @@ def _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret,
     in_place = D % MIN_BLOCK == 0
 
     if window is not None:
-        # below the band as above the diagonal: the index stays on a live
-        # tile, from both sides
+        # the k axis walks the q tile's band alone; past the diagonal the
+        # index stays on the last live tile
         def k_tile(i, j):
-            return jnp.clip(j, _first_band_k_tile(i, block_q, block_k, window),
-                            _last_live_k_tile(i, block_q, block_k))
+            return jnp.minimum(*_band_k_tile(i, j, block_q, block_k, window))
     elif causal:
         # above the diagonal the block index repeats the q tile's last
         # live tile, so the pipeline issues no DMA for a skipped step
@@ -445,7 +537,7 @@ def _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret,
     out, lse = pl.pallas_call(
         functools.partial(_flash_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, window=window),
-        grid=flash_grid(B, H, Sq, Sk, block_q, block_k),
+        grid=flash_grid(B, H, Sq, Sk, block_q, block_k, window),
         in_specs=[
             pl.BlockSpec((1, block_q, D), q_at),
             pl.BlockSpec((1, block_k, D), k_at),
@@ -528,9 +620,11 @@ def flash_bwd_vmem_bytes(block_q: int, block_k: int, rows: int, D: int,
     return io + stats + out + scratch + tiles
 
 
-def flash_bwd_blocks(Sq: int, Sk: int, D: int, dtype) -> BwdBlocks:
+def flash_bwd_blocks(Sq: int, Sk: int, D: int, dtype,
+                     window: Optional[int] = None) -> BwdBlocks:
     """The backward kernel's ``(block_q, block_k, rows)`` for q/do ``[..,
-    Sq, D]`` against k/v ``[.., Sk, D]``. ``rows == Sq`` is the resident
+    Sq, D]`` against k/v ``[.., Sk, D]``, a tile no wider than a causal
+    ``window`` (:func:`_tiles_under`). ``rows == Sq`` is the resident
     form: a head's whole float32 dq stays in VMEM across its k tiles and
     every tile is computed once (five matmuls, one pass of the vector
     work). Where that does not fit ``BWD_VMEM_BUDGET`` (a sequence of tens
@@ -549,8 +643,9 @@ def flash_bwd_blocks(Sq: int, Sk: int, D: int, dtype) -> BwdBlocks:
     units = Sq // MIN_BLOCK
     rows = Sq // next(n for n in range(1, units + 1) if units % n == 0
                       and not over(MIN_BLOCK, MIN_BLOCK, Sq // n))
-    bq = next(t for t in TILES if rows % t == 0)
-    bk = next(t for t in TILES if Sk % t == 0)
+    tiles = _tiles_under(window)
+    bq = next(t for t in tiles if rows % t == 0)
+    bk = next(t for t in tiles if Sk % t == 0)
     while over(bq, bk, rows) and max(bq, bk) > MIN_BLOCK:
         if bq >= bk:
             bq //= 2
@@ -559,12 +654,19 @@ def flash_bwd_blocks(Sq: int, Sk: int, D: int, dtype) -> BwdBlocks:
     return BwdBlocks(bq, bk, rows)
 
 
-def flash_bwd_grid(B: int, H: int, Sq: int, Sk: int,
-                   blocks: BwdBlocks) -> Tuple[int, int, int, int]:
+def flash_bwd_grid(B: int, H: int, Sq: int, Sk: int, blocks: BwdBlocks,
+                   window: Optional[int] = None) -> Tuple[int, int, int, int]:
     """The backward kernel's grid: (heads, q ranges, k tiles, q tiles of a
-    range), the last innermost."""
-    return (B * H, Sq // blocks.rows, Sk // blocks.block_k,
-            blocks.rows // blocks.block_q)
+    range), the last innermost. Under a causal ``window`` the q axis spans
+    a k tile's band, not the range: as many steps as the widest band has q
+    tiles (:func:`_band_q_tile` says which tile a step is)."""
+    bq, bk, rows = blocks
+    nq = rows // bq
+    if window is not None:
+        bands = (_band_q_tile(kj, 0, 0, Sq // bq, bq, bk, window)
+                 for kj in range(Sk // bk))
+        nq = min(nq, max(last - first + 1 for first, last in bands))
+    return (B * H, Sq // rows, Sk // bk, nq)
 
 
 def _first_live_q_tile(kj, block_q: int, block_k: int):
@@ -585,12 +687,23 @@ def _flash_bwd_kernel(q_ref, do_ref, k_ref, v_ref, lse_ref, adj_ref,
                       block_k: int, window: Optional[int] = None):
     """One (k tile, q tile) step; grid (BH, q ranges, nk, nq) with q
     innermost: dk and dv of the k tile accumulate over the q tiles, dq of
-    the range's rows over the k tiles."""
-    kj, i = pl.program_id(2), pl.program_id(3)
+    the range's rows over the k tiles. Under a window the q axis spans the
+    k tile's band (:func:`flash_bwd_grid`): ``qi`` is the q tile the step
+    stands for and ``i`` its place in the range, both past the band's last
+    tile where the step runs nothing (``live``)."""
+    kj, step = pl.program_id(2), pl.program_id(3)
     nk, nq = pl.num_programs(2), pl.num_programs(3)
-    qi = pl.program_id(1) * nq + i            # the q tile in the sequence
+    if window is None:
+        i = step
+        qi = pl.program_id(1) * nq + i        # the q tile in the sequence
+    else:
+        tiles = dq_acc.shape[0] // block_q    # the q tiles of a range
+        first_tile = pl.program_id(1) * tiles
+        qi, last_qi = _band_q_tile(kj, step, first_tile, tiles, block_q,
+                                   block_k, window)
+        i, live = qi - first_tile, qi <= last_qi
 
-    @pl.when(i == 0)
+    @pl.when(step == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
@@ -654,11 +767,16 @@ def _flash_bwd_kernel(q_ref, do_ref, k_ref, v_ref, lse_ref, adj_ref,
         for k0 in range(0, block_k, piece):
             part(k0, piece, 0, True, k0 + piece)
 
+    def when(cond):
+        """``pl.when`` of a step inside the k tile's band."""
+        return pl.when(cond if window is None
+                       else jnp.logical_and(live, cond))
+
     if window is not None:
         first_row, first_col = qi * block_q, kj * block_k
         rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
 
-        @pl.when(kj == _first_band_k_tile(qi, block_q, block_k, window))
+        @when(kj == _first_band_k_tile(qi, block_q, block_k, window))
         def _zero_dq():
             dq_acc[rows] = jnp.zeros((block_q, dq_acc.shape[1]),
                                      jnp.float32)
@@ -667,11 +785,11 @@ def _flash_bwd_kernel(q_ref, do_ref, k_ref, v_ref, lse_ref, adj_ref,
         if block_q == block_k and window % block_k == 0:
             # the diagonal and the edge cross different tiles, each corner
             # to corner
-            pl.when(qi == kj)(diagonal)
-            pl.when(first_col == first_row - window)(edge)
+            when(qi == kj)(diagonal)
+            when(first_col == first_row - window)(edge)
         else:
-            pl.when(crossed)(functools.partial(whole, True))
-        pl.when(clean)(functools.partial(whole, False))
+            when(crossed)(functools.partial(whole, True))
+        when(clean)(functools.partial(whole, False))
         last_kj = jnp.minimum(_last_live_k_tile(qi, block_q, block_k),
                               nk - 1)
     elif causal:
@@ -694,11 +812,11 @@ def _flash_bwd_kernel(q_ref, do_ref, k_ref, v_ref, lse_ref, adj_ref,
 
     rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
 
-    @pl.when(kj == last_kj)
+    @when(kj == last_kj)
     def _write_dq():
         dq_ref[0, rows] = (dq_acc[rows] * scale).astype(dq_ref.dtype)
 
-    @pl.when(i == nq - 1)
+    @pl.when(step == nq - 1)
     def _write_dkv():
         dk_ref[0, 0] = (dk_acc[:] * scale).astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
@@ -730,17 +848,16 @@ def _flash_bwd_local(q, k, v, do, lse, adj, *, H, causal, scale, blocks,
         Sk, D = k.shape[1], M // H
         group = _group(H, k.shape[2] // D)
     bq, bk, rows = blocks
-    grid = flash_bwd_grid(B, H, Sq, Sk, blocks)
-    nq = grid[3]
+    grid = flash_bwd_grid(B, H, Sq, Sk, blocks, window)
+    nq = rows // bq
 
     if window is not None:
-        # beyond the band as above the diagonal: the index stays on a
-        # live q tile, from both sides
+        # the q axis walks the k tile's band alone; past its last tile the
+        # index stays there (and inside the range, if the band leaves it)
         def q_tile(r, j, i):
-            first = _first_live_q_tile(j, bq, bk)
-            last = jnp.maximum(_last_band_q_tile(j, bq, bk, window), first)
-            return jnp.clip(jnp.clip(r * nq + i, first, last), r * nq,
-                            r * nq + nq - 1)
+            return jnp.clip(
+                jnp.minimum(*_band_q_tile(j, i, r * nq, nq, bq, bk, window)),
+                r * nq, r * nq + nq - 1)
     elif causal:
         # above the diagonal the block index repeats the k tile's first
         # live q tile, so the pipeline issues no DMA for a skipped step
@@ -817,7 +934,7 @@ def flash_backward(q, k, v, o, lse, do, dlse, causal: bool, scale: float,
     B, Sq, H, D = q.shape
     Sk, Hkv = k.shape[1], k.shape[2]
     _check_window(window, causal)
-    blocks = blocks or flash_bwd_blocks(Sq, Sk, D, q.dtype)
+    blocks = blocks or flash_bwd_blocks(Sq, Sk, D, q.dtype, window)
     # o's float32 copy is made when do arrives, not before: alone, the
     # conversion depends on the forward pass only, and XLA:TPU has started it
     # there and kept 4 bytes an element of o alive into the backward pass in
@@ -885,7 +1002,7 @@ def _call(q, k, v, causal, scale, block_q, block_k, interpret, window=None):
     D = q.shape[-1]
     scale = float(scale) if scale is not None else float(1.0 / (D ** 0.5))
     if block_q is None or block_k is None:
-        bq, bk = flash_blocks(q.shape[1], k.shape[1], D, q.dtype)
+        bq, bk = flash_blocks(q.shape[1], k.shape[1], D, q.dtype, window)
         block_q, block_k = block_q or bq, block_k or bk
     return _flash_lse(q, k, v, causal, scale, block_q, block_k, interpret,
                       window)

@@ -679,7 +679,8 @@ def test_tile_pieces_cover_every_live_score_once_and_no_dead_block(tile,
     every ``FWD_DIAG_ROWS`` x ``FWD_DIAG_ROWS`` block a piece holds has a
     live score."""
     pieces = pa.tile_pieces(tile, tile, edge)
-    rows = min(pa.FWD_DIAG_ROWS, tile)
+    rows = pa.fwd_band_rows(tile)
+    assert rows == {128: 128, 256: 128, 512: 128, 1024: 512}[tile]
     bands, unit = tile // rows, (rows // pa.MIN_BLOCK) ** 2
     assert pa.tile_piece_blocks(tile, tile, edge) == (
         unit * bands * (bands + 1) // 2, (tile // pa.MIN_BLOCK) ** 2)
@@ -769,3 +770,264 @@ def test_the_backward_under_a_window_of_half_a_tile(group):
         assert g.shape == r.shape, name
         np.testing.assert_allclose(np.asarray(g), np.asarray(r), rtol=2e-4,
                                    atol=2e-4, err_msg=name)
+
+
+# -- a windowed call takes its tile and its grid from the window (PR 54) ------
+
+#: window -> (forward tile, backward (block_q, block_k, rows)) at 8192
+#: positions of heads of 128, bfloat16: no tile wider than the window (or
+#: than MIN_BLOCK), SmallThinker's 4096 and no window as they were
+_WINDOW_TILES = {
+    None: ((1024, 1024), (1024, 1024, 8192)),
+    8192: ((1024, 1024), (1024, 1024, 8192)),
+    4096: ((1024, 1024), (1024, 1024, 8192)),
+    1024: ((1024, 1024), (1024, 1024, 8192)),
+    1000: ((512, 512), (512, 512, 8192)),
+    512: ((512, 512), (512, 512, 8192)),
+    320: ((256, 256), (256, 256, 8192)),
+    128: ((128, 128), (128, 128, 8192)),
+    100: ((128, 128), (128, 128, 8192)),
+    1: ((128, 128), (128, 128, 8192)),
+}
+
+
+@pytest.mark.parametrize("window", sorted(_WINDOW_TILES, key=str))
+def test_the_tile_rules_see_the_window(window):
+    fwd, bwd = _WINDOW_TILES[window]
+    assert flash_blocks(8192, 8192, 128, jnp.bfloat16, window) == fwd
+    assert tuple(pa.flash_bwd_blocks(8192, 8192, 128, jnp.bfloat16,
+                                     window)) == bwd
+    # a trailing argument: a call without one means what it meant
+    assert flash_blocks(8192, 8192, 128, jnp.bfloat16) == (1024, 1024)
+    assert pa.banded_tiles(*fwd, window) == (
+        window is None or window % fwd[0] == 0)
+
+
+def test_the_tile_rule_under_a_window_at_lengths_its_tile_must_divide():
+    # 512 does not divide 1280: the largest that does and is no wider
+    assert flash_blocks(1280, 1280, 128, jnp.bfloat16, 640) == (256, 256)
+    assert flash_blocks(1536, 1536, 128, jnp.bfloat16, 4096) == (512, 512)
+    # the budgets still hold: a head of 256 in float32 under a window
+    bq, bk = flash_blocks(4096, 4096, 256, jnp.float32, 1024)
+    assert pa.flash_vmem_bytes(bq, bk, 256, 4) <= pa.VMEM_BUDGET
+    assert max(bq, bk) <= 1024
+
+
+#: the two cells that pass a window: (S, window, group) -> the tile both
+#: kernels take, (grid steps, tiles run) a head forward and backward
+_WINDOW_CELLS = {
+    "laguna-xs.2.s8192": ((8192, 512), 512, (32, 31)),
+    "smallthinker-21b-a3b.s8192": ((8192, 4096), 1024, (40, 30)),
+}
+
+
+def _live_tiles(S, bq, bk, window):
+    """{(q tile, k tile)} with a live score, by the mask's definition."""
+    return {(r0 // bq, c0 // bk)
+            for r0 in range(0, S, bq) for c0 in range(0, S, bk)
+            if c0 <= r0 + bq - 1 and c0 + bk - 1 > r0 - window}
+
+
+@pytest.mark.parametrize("cell", sorted(_WINDOW_CELLS))
+def test_a_windowed_cell_s_grids_walk_the_band_alone(cell):
+    (S, window), tile, (steps, live) = _WINDOW_CELLS[cell]
+    H, D = 4, 128
+    assert flash_blocks(S, S, D, jnp.bfloat16, window) == (tile, tile)
+    blocks = pa.flash_bwd_blocks(S, S, D, jnp.bfloat16, window)
+    assert tuple(blocks) == (tile, tile, S)              # dq resident
+    fwd = pa.flash_grid(1, H, S, S, tile, tile, window)
+    bwd = pa.flash_bwd_grid(1, H, S, S, blocks, window)
+    assert fwd == (H, S // tile, steps // (S // tile))
+    assert bwd == (H, 1, S // tile, steps // (S // tile))
+    assert pa.band_tile_counts(S, tile, tile, window)[1] == live
+    # without a window the grids are the sequence's, as they were
+    assert pa.flash_grid(1, H, S, S, tile, tile) == (H, S // tile,
+                                                     S // tile)
+    assert pa.flash_bwd_grid(1, H, S, S, blocks) == (H, 1, S // tile,
+                                                     S // tile)
+
+
+#: (S, block_q, block_k, rows of a q range, window): the two cells', tiles
+#: that are not square, a window no multiple of the tile, one wider than
+#: the sequence, one of a single key, q rows in ranges
+_BAND_WALKS = [(8192, 512, 512, 8192, 512), (8192, 1024, 1024, 8192, 4096),
+               (8192, 1024, 1024, 8192, 512), (2048, 256, 256, 2048, 320),
+               (2048, 512, 256, 2048, 512), (2048, 256, 512, 2048, 384),
+               (1024, 256, 256, 1024, 4096), (1024, 128, 128, 1024, 1),
+               (2048, 256, 256, 1024, 512), (2048, 128, 256, 512, 700),
+               (1280, 256, 256, 1280, 640)]
+
+
+@pytest.mark.parametrize("walk", _BAND_WALKS)
+def test_the_band_s_index_maps_visit_every_live_tile_exactly_once(walk):
+    """The forward's k axis and the backward's q axis under a window, step
+    by step in plain integers: the steps that stand for a tile inside the
+    band are the tiles with a live score, each once; a step past the band
+    stays on the band's last tile (nothing to fetch)."""
+    S, bq, bk, rows, window = walk
+    want = _live_tiles(S, bq, bk, window)
+    assert len(want) == pa.band_tile_counts(S, bq, bk, window)[1]
+    _, nq, steps = pa.flash_grid(1, 1, S, S, bq, bk, window)
+    assert steps <= S // bk
+    seen = []
+    for qi in range(nq):
+        for step in range(steps):
+            kj, last = pa._band_k_tile(qi, step, bq, bk, window)
+            assert isinstance(kj, int) and last < S // bk
+            if kj <= last:
+                seen.append((qi, kj))
+    assert sorted(seen) == sorted(want)
+    _, ranges, nk, steps = pa.flash_bwd_grid(
+        1, 1, S, S, pa.BwdBlocks(bq, bk, rows), window)
+    tiles = rows // bq
+    assert steps <= tiles and ranges == S // rows
+    seen = []
+    for r in range(ranges):
+        for kj in range(nk):
+            for step in range(steps):
+                qi, last = pa._band_q_tile(kj, step, r * tiles, tiles, bq,
+                                           bk, window)
+                assert last < (r + 1) * tiles
+                if qi <= last:
+                    assert qi >= r * tiles
+                    seen.append((qi, kj))
+    assert sorted(seen) == sorted(want)
+
+
+#: name -> (B, S, H, Hkv, D, window, dtype, the rule's forward tile, the
+#: forward's k steps a q tile, the backward's q steps a k tile): o, lse, dq,
+#: dk and dv through the rule's own tile and grid (no override)
+_RULE_BANDED = {
+    "window 512, a group of 8":
+        (1, 2048, 8, 1, 128, 512, jnp.float32, (512, 512), 2, 2),
+    "window 512, a group of 6":
+        (1, 1536, 6, 1, 128, 512, jnp.float32, (512, 512), 2, 2),
+    "window 256": (1, 1024, 2, 1, 128, 256, jnp.float32, (256, 256), 2, 2),
+    "window 320, no multiple of 128":
+        (1, 768, 2, 2, 128, 320, jnp.float32, (256, 256), 3, 3),
+    "a window wider than the sequence":
+        (1, 512, 2, 1, 128, 1024, jnp.float32, (512, 512), 1, 1),
+    # SmallThinker's group and tile: 2 x 1024 < S, so the last q tile's band
+    # starts past the first k tile (float32 operands halve the q tile)
+    "a window of one tile, a group of 7":
+        (1, 3072, 7, 1, 128, 1024, jnp.bfloat16, (1024, 1024), 2, 2),
+    "a window of one k tile under a q tile of half":
+        (1, 3072, 2, 1, 128, 1024, jnp.float32, (512, 1024), 2, 4),
+    # three of five q tiles have a band the sequence's start cuts
+    "two tiles and a half, the first q tiles cut":
+        (1, 1280, 2, 2, 128, 640, jnp.float32, (256, 256), 4, 4),
+    "a window under the smallest tile, two batch rows":
+        (2, 512, 2, 2, 128, 100, jnp.float32, (128, 128), 2, 2),
+    "a head of 64 under a window of 256":
+        (1, 1024, 4, 2, 64, 256, jnp.float32, (256, 256), 2, 2),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_RULE_BANDED))
+def test_a_windowed_call_by_the_rule_s_tile_and_grid_is_the_banded_form(
+        case):
+    """o, lse and the three gradients (of a loss that reads o and lse) of a
+    windowed call as ``attend`` makes it, tile and grid by the rules,
+    against ``_banded_attention`` / ``_banded_lse`` in float32 and autodiff
+    through them."""
+    B, S, H, Hkv, D, window, dtype, tile, k_steps, q_steps = \
+        _RULE_BANDED[case]
+    assert flash_blocks(S, S, D, dtype, window) == tile
+    assert pa.flash_grid(B, H, S, S, *tile, window) == (
+        B * H, S // tile[0], k_steps)
+    blocks = pa.flash_bwd_blocks(S, S, D, dtype, window)
+    assert blocks.rows == S
+    assert pa.flash_bwd_grid(B, H, S, S, blocks, window) == (
+        B * H, 1, S // blocks.block_k, q_steps)
+    q, k, v = (x.astype(dtype) for x in _heads(B, S, H, Hkv, D, seed=54))
+    scale = 1.0 / D ** 0.5
+    w = jnp.cos(jnp.arange(q.size, dtype=jnp.float32).reshape(q.shape))
+    u = jnp.sin(jnp.arange(B * H * S, dtype=jnp.float32).reshape(B * H, S))
+
+    def flash(q, k, v):
+        o, lse = pa.flash_attention_with_lse(q, k, v, True, None,
+                                             interpret=True, window=window)
+        return o.astype(jnp.float32), lse
+
+    def reference(q, k, v):
+        return (pa._banded_attention(q, k, v, window),
+                _banded_lse(q, k, window, scale))
+
+    def loss(f):
+        def total(q, k, v):
+            o, lse = f(q, k, v)
+            return jnp.sum(o * w) + jnp.sum(lse * u)
+        return total
+
+    exact = dtype == jnp.float32
+    q32, k32, v32 = (x.astype(jnp.float32) for x in (q, k, v))
+    (o, lse), (o_ref, lse_ref) = flash(q, k, v), reference(q32, k32, v32)
+    assert o.shape == q.shape and lse.shape == (B * H, S)
+    assert lse.dtype == jnp.float32
+    # bfloat16 operands: p, ds and the cotangent are rounded to 8 bits for
+    # their matmuls, the reference multiplies the same values in float32
+    tol = dict(rtol=2e-5, atol=2e-5) if exact else dict(rtol=2e-2, atol=2e-2)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref), **tol)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(lse_ref), **tol)
+    got = jax.grad(loss(flash), (0, 1, 2))(q, k, v)
+    want = jax.grad(loss(reference), (0, 1, 2))(q32, k32, v32)
+    for g, r, name in zip(got, want, ("dq", "dk", "dv")):
+        assert g.shape == r.shape and g.dtype == dtype, name
+        if exact:
+            np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                       rtol=2e-4, atol=2e-4, err_msg=name)
+        else:
+            err = float(jnp.max(jnp.abs(g.astype(jnp.float32) - r))
+                        / jnp.max(jnp.abs(r)))
+            assert err < 4e-2, (name, err)
+
+
+@pytest.mark.parametrize("ranges", [(1024, 256, 256, 512, 256),
+                                    (1024, 128, 256, 256, 320),
+                                    (1024, 256, 128, 512, 2000)])
+def test_the_backward_s_band_walk_with_the_q_rows_in_ranges(ranges):
+    """A window where dq is not resident: a range's steps start at the
+    band's first q tile inside the range and a range the band leaves runs
+    nothing; dk and dv are the ranges' sum."""
+    S, bq, bk, rows, window = ranges
+    D = 128
+    q, k, v = _heads(1, S, 2, 1, D, seed=55)
+    w = jnp.cos(jnp.arange(q.size, dtype=jnp.float32).reshape(q.shape))
+    want = jax.grad(lambda *a: jnp.sum(
+        pa._banded_attention(*a, window) * w), (0, 1, 2))(q, k, v)
+    o, lse = pa.flash_attention_with_lse(q, k, v, True, None, interpret=True,
+                                         window=window)
+    blocks = pa.BwdBlocks(bq, bk, rows)
+    assert pa.flash_bwd_grid(1, 2, S, S, blocks, window)[1] == S // rows > 1
+    got = pa.flash_backward(q, k, v, o, lse, w, jnp.zeros_like(lse), True,
+                            D ** -0.5, blocks, True, window)
+    for name, g, r in zip(("dq", "dk", "dv"), got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), rtol=2e-4,
+                                   atol=2e-4, err_msg=name)
+
+
+def test_chip_smoke_s_attention_path_prints_the_band_s_grid_and_tiles():
+    """``chip_smoke.py``'s line for a windowed call, from the functions the
+    kernels call: the rule's tile, the grid steps a call takes and, a head,
+    the steps taken and the tiles run."""
+    import chip_smoke
+    laguna = chip_smoke._flash_call((1, 8192, 64, 128), 8, 512)
+    assert laguna.startswith(
+        "pallas hvd_flash_attention 512x512, 2048 steps, operands in place "
+        "[1, 8192, 8192], a tile on the diagonal 10 of 16 blocks in 4 "
+        "pieces, on the band's edge 10 of 16 blocks in 4 pieces; "
+        "hvd_flash_bwd 512x512, dq resident, 2048 steps, "), laguna
+    assert laguna.endswith(
+        "; window 512: forward 32 steps and 31 of 136 causal tiles a head, "
+        "15 on the edge, backward 32 steps and 31 of 136 causal tiles a "
+        "head, 15 on the edge; kv heads 8, group 8"), laguna
+    share = chip_smoke._flash_call((1, 8192, 28, 128), 4, 4096)
+    assert "hvd_flash_attention 1024x1024, 1120 steps" in share
+    assert "hvd_flash_bwd 1024x1024, dq resident, 1120 steps" in share
+    assert share.endswith(
+        "; window 4096: forward 40 steps and 30 of 36 causal tiles a head, "
+        "4 on the edge, backward 40 steps and 30 of 36 causal tiles a head, "
+        "4 on the edge; kv heads 4, group 7"), share
+    full = chip_smoke._flash_call((1, 8192, 48, 128), 8)
+    assert "hvd_flash_attention 1024x1024, 3072 steps" in full
+    assert "window" not in full and full.endswith("kv heads 8, group 6")
