@@ -159,6 +159,8 @@ class Sizes:
     narrow: tuple         # flash check at a head of 64 with grouped heads
     #                       and a scale of its own: (B, S, H, k/v heads, D,
     #                       window, scale)
+    short_conv: tuple     # gated short-convolution mixer check (B, S, M,
+    #                       taps)
     gpt: dict             # flagship TransformerConfig fields
     gpt_batch: int
     ring: tuple           # four-chip ring attention [B, S, H, D]
@@ -198,6 +200,8 @@ REAL = Sizes(
     # GB)
     dense_ssm=(1024, 64, 64, 1, 128, 256),
     narrow=(1, 4096, 32, 8, 64, None, 1 / 64),
+    # lfm2-24b-a2b.s8192's mixer at the cell's batch, whole
+    short_conv=(2, 8192, 2048, 3),
     # depth cut to 4 layers: this phase checks kernels in place, not a model
     gpt=dict(vocab_size=32000, d_model=1024, n_heads=8, n_layers=4,
              d_ff=4096, max_seq=2048),
@@ -216,6 +220,7 @@ TINY = Sizes(
     embed=((64, 2560, 48),),
     ssm=(64, 4, 8, 2, 16, 16),
     dense_ssm=(64, 8, 8, 1, 16, 16), narrow=(1, 256, 4, 1, 64, None, 1 / 64),
+    short_conv=(2, 64, 32, 3),
     gpt=dict(vocab_size=1000, d_model=256, n_heads=2, n_layers=2,
              d_ff=256, max_seq=256),
     gpt_batch=2, ring=(1, 512, 2, 128))
@@ -1008,6 +1013,71 @@ def _check_ssm(smoke: Smoke, sizes=None, cell: str = "hybrid",
                      SSM_TOL, against_the_numpy_form=_rel_err(g, n))
 
 
+#: bf16 operands and a bf16 round before the out-projection against float32
+#: at "highest", as FLASH_TOL
+SHORT_CONV_TOL = 2e-2
+
+
+def _check_short_conv(smoke: Smoke) -> None:
+    """The gated short-convolution mixer alone (models/short_conv.py: a
+    ``("conv",)`` block, norm to residual add, as the cell
+    ``lfm2-24b-a2b.s8192`` runs it: bf16 matmuls round a float32 chain ``C *
+    conv(B * u)``) against the same equations in ``jax.numpy``, float32,
+    matmuls at "highest": the output and the gradients of the input and of
+    every leaf. No kernel: XLA runs both. On the chip prints the
+    milliseconds of forward + backward (host clock over calls back to back:
+    a smoke print, a gate and no metric), the pair a later kernel for the
+    chain has to time."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from horovod_tpu.models import short_conv
+    from horovod_tpu.models.transformer import TransformerConfig
+    B, S, M, K = smoke.sizes.short_conv
+    cfg = TransformerConfig(d_model=M, n_heads=max(M // 64, 1), conv_taps=K,
+                            layer_pattern=(("conv",), ("dense",)),
+                            n_layers=2, norm_eps=1e-5, dtype=jnp.bfloat16)
+    rng = np.random.RandomState(smoke.seed + 7)
+    p = {leaf.name: jnp.asarray(leaf.draw(rng, leaf.shape))
+         for leaf in short_conv.KIND.leaves(cfg)}
+    keys = jax.random.split(jax.random.PRNGKey(smoke.seed + 7))
+    x = jax.random.normal(keys[0], (B, S, M), jnp.float32)
+    ct = jax.random.normal(keys[1], (B, S, M), jnp.float32)
+
+    def plain(p, x):
+        h = x * jax.lax.rsqrt(jnp.mean(jnp.square(x), -1, keepdims=True)
+                              + cfg.norm_eps) * p["ln1"]
+        b, c, u = jnp.split(h @ p["conv_in"], 3, axis=-1)
+        z = jnp.pad(b * u, ((0, 0), (K - 1, 0), (0, 0)))
+        taps = sum(p["conv_w"][j] * z[:, j:j + S] for j in range(K))
+        return x + (c * taps) @ p["conv_out"]
+
+    def both(block):
+        def run(p, x):
+            out, pull = jax.vjp(block, p, x)
+            return (out,) + pull(ct.astype(out.dtype))
+        return run
+    program = jax.jit(both(lambda p, x: short_conv._conv_block(
+        p, x.astype(cfg.dtype), cfg)))
+    got = jax.block_until_ready(program(p, x))
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(both(plain))(p, x)
+    more = {}
+    if smoke.on_chip:
+        t0 = time.perf_counter()
+        for _ in range(10):
+            last = program(p, x)
+        jax.block_until_ready(last)
+        more["fwd_and_bwd_host_ms"] = round(
+            (time.perf_counter() - t0) / 10 * 1e3, 3)
+    names = ["fwd", "grad d_x"] + [f"grad d_{k}" for k in sorted(p)]
+    flat = lambda out: [out[0], out[2]] + [out[1][k] for k in sorted(p)]
+    for what, g, r in zip(names, flat(got), flat(want)):
+        _kernel_line(smoke, "short conv mixer", what, _rel_err(g, r),
+                     SHORT_CONV_TOL, shape=(B, S, M, K), dtype="bfloat16",
+                     ran="xla", **(more if what == "fwd" else {}))
+
+
 def _check_embed(smoke: Smoke) -> None:
     """The lookup of a model whose head has a table of its own, and its
     hand-written gradient (models/transformer.py:_table_rows), at the
@@ -1297,6 +1367,7 @@ def phase_kernels(smoke: Smoke, hvd) -> None:
     _check_ssm(smoke)
     _check_dense_hybrid(smoke)
     _check_gated_norm(smoke)
+    _check_short_conv(smoke)
     _check_embed(smoke)
     _check_codec(smoke)
     _check_flagship(smoke, hvd)

@@ -71,13 +71,15 @@ def _leaves(cfg):
 def _causal_conv(x, taps, bias):
     """Depthwise causal convolution over the sequence: ``y[t] = bias +
     sum_j taps[j] * x[t - (K - 1) + j]`` with zeros before the start. x
-    ``[B, S, C]``, taps ``[K, C]``; K shifted multiply-adds in float32."""
+    ``[B, S, C]``, taps ``[K, C]``; K shifted multiply-adds in float32.
+    ``bias`` None: none is added (``models/short_conv.py``)."""
     K, S = taps.shape[0], x.shape[1]
     padded = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
-    y = bias.astype(jnp.float32)
+    y = None if bias is None else bias.astype(jnp.float32)
     for j in range(K):
-        y = y + (taps[j].astype(jnp.float32)
-                 * padded[:, j:j + S].astype(jnp.float32))
+        term = (taps[j].astype(jnp.float32)
+                * padded[:, j:j + S].astype(jnp.float32))
+        y = term if y is None else y + term
     return y
 
 

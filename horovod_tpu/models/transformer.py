@@ -10,7 +10,8 @@ a single XLA program whose collectives (psum over tp, ppermute rings over
 sp and pp, all_to_all over ep, psum over dp for gradients) all ride ICI.
 
 This file is the training model; the Mamba-2 mixer is ``models/mamba.py``,
-latent attention ``models/latent.py``, the
+the gated short-convolution mixer ``models/short_conv.py``, latent attention
+``models/latent.py``, the
 serving plane's paged decode model and its oracle ``models/decode.py``. A leaf
 is declared once, in its block's ``*_leaves`` function (``models/_kinds.py``:
 name, shape, draw, partition spec, under the one ``if`` that says when the
@@ -42,7 +43,8 @@ Layout conventions (local = per-device shapes):
                   ``layers`` then holds one stack a word, ``[pp, blocks of
                   that word / pp, ...]`` (a Mamba block: ``models/mamba.py``;
                   ("latent",) latent attention: ``models/latent.py``;
-                  ("dense",) the dense FFN at ``dense_ff``)
+                  ("dense",) the dense FFN at ``dense_ff``; ("conv",) a
+                  gated short convolution: ``models/short_conv.py``)
   attention kinds ("attention", window, rope, heads, gated): ``rope`` may be
                   a table of the kind's own (``_kinds.Rope``: a theta, the
                   rotated part of the head, YaRN); ``heads`` query heads
@@ -81,7 +83,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from horovod_tpu._compat import axis_size, shard_map
 
-from horovod_tpu.models import latent, mamba
+from horovod_tpu.models import latent, mamba, short_conv
 from horovod_tpu.models._kinds import (BlockKind, Leaf, Rope, normal, ones,
                                        remat, rmsnorm, rope, scaled, zeros)
 from horovod_tpu.models.scan_util import multi_step
@@ -107,8 +109,12 @@ class TransformerConfig:
     moe_renormalize: bool = False   # top-k weights divided by their sum
     moe_balance_weight: float = 0.01    # load-balancing loss, over all k
     moe_z_weight: float = 0.0   # router z-loss mean(logsumexp(logits)^2)
-    qk_norm: bool = False       # RMSNorm over the whole q and k projections,
-    #                             before the head split and rope
+    qk_norm: Any = False        # True: RMSNorm over the whole q and k
+    #                             projections, before the head split and rope
+    #                             (OLMoE). "head": over each head's channels,
+    #                             after the split and before rope, one weight
+    #                             ``[head_dim]`` for all q heads and one for
+    #                             all k heads (LFM2)
     tie_embeddings: bool = True     # logits from the embedding table; else
     #                                 an ``lm_head`` [M, V] of its own
     post_norm: bool = False     # an RMSNorm after each sublayer too, before
@@ -202,6 +208,10 @@ class TransformerConfig:
     ssm_conv: int = 4           # taps of the causal depthwise convolution
     ssm_chunk: int = 128        # positions a chunk of the scan; the sequence
     #                             is whole chunks
+    # -- a gated short convolution (``models/short_conv.py``), where the
+    # pattern has a ("conv",) block --------------------------------------
+    conv_taps: int = 0          # taps of its causal depthwise convolution,
+    #                             the last on the current position
     expert_share: Tuple[int, int] = (0, 1)  # (index, of): this device holds
     #                             experts [index * E / of, (index + 1) * E /
     #                             of) of every layer, as the leading dimension
@@ -271,6 +281,8 @@ class TransformerConfig:
             raise ValueError(
                 f"layer_pattern of {len(self.layer_pattern)} kinds does not "
                 f"divide n_layers={self.n_layers}")
+        if self.qk_norm not in (False, True, "head"):
+            raise ValueError(f"qk_norm={self.qk_norm!r}")
         if self.moe_router_input not in ("tokens", "block_input"):
             raise ValueError(f"moe_router_input={self.moe_router_input!r}")
         if self.moe_activation not in ("silu", "relu", "relu2"):
@@ -481,7 +493,11 @@ def _attention_leaves(cfg: TransformerConfig, kind=("attention",)):
     if _kind_fields(kind)[1]:
         # one logit a head and position: the core's output times its sigmoid
         yield Leaf("wg", (M, heads), normal(), (None, "tp"))
-    if cfg.qk_norm:
+    if cfg.qk_norm == "head":
+        # one weight for all the heads: whole on every tp shard
+        yield Leaf("q_norm", (cfg.head_dim,), ones)
+        yield Leaf("k_norm", (cfg.head_dim,), ones)
+    elif cfg.qk_norm:
         yield Leaf("q_norm", (q,), ones, ("tp",))
         yield Leaf("k_norm", (kv,), ones, ("tp",))
     if cfg.post_norm:
@@ -513,13 +529,16 @@ def _attention_block(p, x, positions, cfg: TransformerConfig,
         q = (h @ p["wq"].astype(h.dtype))
         k = (h @ p["wk"].astype(h.dtype))
         v = (h @ p["wv"].astype(h.dtype))
-        if cfg.qk_norm:
+        if cfg.qk_norm is True:
             q = _qk_norm(q, p["q_norm"], cfg.norm_eps)
             k = _qk_norm(k, p["k_norm"], cfg.norm_eps)
         Hl = q.shape[-1] // cfg.head_dim
         q = q.reshape(B, S, Hl, cfg.head_dim)
         k = k.reshape(B, S, k.shape[-1] // cfg.head_dim, cfg.head_dim)
         v = v.reshape(B, S, v.shape[-1] // cfg.head_dim, cfg.head_dim)
+        if cfg.qk_norm == "head":
+            q = rmsnorm(q, p["q_norm"], cfg.norm_eps)
+            k = rmsnorm(k, p["k_norm"], cfg.norm_eps)
         if roped:
             table = roped if isinstance(roped, Rope) else cfg.rope_theta
             q = rope(q, positions, table)
@@ -740,6 +759,7 @@ def _needs_experts(cfg: TransformerConfig) -> None:
 _BLOCK_KINDS = {
     "mamba": mamba.KIND,
     "latent": latent.KIND,
+    "conv": short_conv.KIND,
     "experts": BlockKind(
         length=1, leaves=_ffn_leaves, validate=_needs_experts,
         apply=lambda p, x, positions, cfg, kind: _ffn_block(p, x, cfg)),

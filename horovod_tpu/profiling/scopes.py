@@ -116,6 +116,13 @@ SSM_PROJ = "hvd.ssm.proj"
 SSM_CONV = "hvd.ssm.conv"
 SSM_SCAN = "hvd.ssm.scan"
 SSM_NORM = "hvd.ssm.norm"
+#: a gated short-convolution block (``models/short_conv.py``) with its norm
+#: and residual, and its two parts. Proj: both projections, in and out.
+#: Gate: ``B * u``, the causal depthwise taps, ``C *``, the rounding to the
+#: compute dtype
+SHORT_CONV = "hvd.short_conv"
+SHORT_CONV_PROJ = "hvd.short_conv.proj"
+SHORT_CONV_GATE = "hvd.short_conv.gate"
 #: nested in LAYERS: a looped model's passes through its stack, with the
 #: final norm that closes each loop step
 LOOP = "hvd.loop"
@@ -161,9 +168,12 @@ GATED_PHASES = (ATTENTION_GATE,)
 #: module has (GLM-4.7-Flash), each forward and backward
 LATENT_PHASES = (ATTENTION_LATENT, ATTENTION_LATENT_DOWN, ATTENTION_LATENT_UP,
                  MTP, MTP_PROJ)
+#: phases only a stack with gated short-convolution blocks has (LFM2), each
+#: forward and backward
+SHORT_CONV_PHASES = (SHORT_CONV, SHORT_CONV_PROJ, SHORT_CONV_GATE)
 DEVICE_PHASES = (MODEL_PHASES + MOE_PHASES + LOOP_PHASES + MIXED_PHASES
                  + HYBRID_PHASES + LATENT_PHASES + GATED_PHASES
-                 + (GRAD_SYNC, OPTIMIZER, RECOMPUTE))
+                 + SHORT_CONV_PHASES + (GRAD_SYNC, OPTIMIZER, RECOMPUTE))
 
 # -- host spans (profiling.annotate) ------------------------------------------
 #: the input iterator's ``next()``: the host makes the batch

@@ -21,9 +21,10 @@ cannot go stale:
   nothing read: None, never a raise), a ``reader`` names a file under
   ``readers/`` whose imports from the product resolve like ``run.py``'s.
   The cases are the benchmark's own (``benchmarks/chip/tests/
-  test_layer_metrics.py``), imported here so that tier-1 runs them: 123
-  entries and files since PR 53 (107 since PR 52, 106 since PR 51), two cases
-  each, and two readers (``host_pauses``, ``step_owners``);
+  test_layer_metrics.py``), imported here so that tier-1 runs them: 128
+  entries and files since PR 55, the most the contract admits (123 since PR
+  53, 107 since PR 52), two cases each, and two readers (``host_pauses``,
+  ``step_owners``);
 * the reader that gives every device instruction one owner and one reason
   (``readers/step_owners.py``), by its own cases
   (``benchmarks/chip/tests/test_step_owners.py``, imported the same way):
@@ -644,6 +645,141 @@ def test_the_banded_step_gives_what_the_laguna_adapter_reads():
         batch["tokens"])
     assert choices.shape == (8, batch["tokens"].size, cfg.moe_top_k)
     assert scopes.GATED_PHASES == ("hvd.attention.gate",)
+
+
+def test_the_short_conv_step_gives_what_the_lfm2_moe_adapter_reads():
+    """``adapters/lfm2_moe.py`` names the stacks of ``lead`` and ``layers``
+    by their words (``_places``, ``_leaf_paths``, ``_init_function``), reads
+    ``held_rows``, ``max_expert_load`` and ``dropped`` from the step's
+    fourth output and ``router_choices``; the configuration's fields reach
+    ``TransformerConfig`` as the kind ``("conv",)``, ``conv_taps`` and
+    ``qk_norm="head"``; the cell's five metric files (the places
+    ``per_layer`` had left: 128) look for the mixer's three scopes, the
+    expert layer's and ``hvd_moe_gmm``, the roofline function for the
+    adapter's ``shapes()`` keys."""
+    import sys
+    import jax
+    import numpy as np
+    if CHIP not in sys.path:
+        sys.path.insert(0, CHIP)
+    from adapters import lfm2_moe
+    from trees import get_leaves
+    from horovod_tpu.models import transformer as t
+    from horovod_tpu.parallel import build_mesh
+    from horovod_tpu.profiling import scopes
+    for name in ("shapes", "tokens_per_step", "flops_per_token",
+                 "host_batch", "abstract_step", "Cell"):
+        assert callable(getattr(lfm2_moe, name)), name
+    assert {"program_choices", "program_loss_and_grads", "compiled_step",
+            "step"} <= set(dir(lfm2_moe.Cell))
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    name, cell = "lfm2-24b-a2b", "lfm2-24b-a2b.s8192"
+    listed = bench["configs"][-1]
+    entry = bench["workloads"][-1]
+    assert (listed["name"], entry["name"], entry["config"],
+            entry["traffic"], entry["chips"]) == (
+                name, cell, name, "train.s8192.b2", 1)
+    # (ISSUE 55 counts thirteen cells; the benchmark had eleven before it)
+    assert len(bench["workloads"]) == 12 and len(bench["configs"]) == 10
+    assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
+    assert listed["file"] == f"benchmarks/chip/configs/{name}.json"
+    with open(os.path.join(REPO, listed["file"])) as f:
+        config = json.load(f)
+    with open(os.path.join(CHIP, "workloads",
+                           entry["traffic"] + ".json")) as f:
+        job = json.load(f)
+    assert config["source"] == listed["source"]
+    assert config["reduced"] == listed["reduced"] == [
+        "num_hidden_layers", "num_dense_layers", "layer_types",
+        "num_experts", "vocab_size"]
+    assert (job["seq_len"], job["batch_per_chip"], job["prefetch"],
+            job["max_ahead"], job["warmup_steps"], job["trace_steps"],
+            job["mesh"], job["optimizer"]) == (
+                8192, 2, 2, 2, 10, 10, {"dp": -1},
+                {"name": "adamw", "learning_rate": 0.0001})
+    mine = {m["name"]: m for m in bench["per_layer"]
+            if m.get("workloads") == [cell]}
+    assert sorted(mine) == sorted(f"short_conv.{m}" for m in (
+        "mixer_ms", "mixer_proj_ms", "mixer_gate_ms", "moe_ms",
+        "moe_gmm_roofline"))
+    assert [m["name"] for m in bench["per_layer"][-5:]] == list(mine)
+    assert len(bench["per_layer"]) == 128       # the contract's most
+    # no list of an accepted metric was edited to take the cell in
+    assert not [m["name"] for m in bench["per_layer"]
+                if cell in m.get("workloads", ()) and m["name"] not in mine]
+    read = {}
+    for metric in mine:
+        with open(os.path.join(CHIP, "layer_metrics",
+                               metric + ".json")) as f:
+            read[metric] = json.load(f)["read"]
+    assert {k: v["trace_scope"]["phase"] for k, v in read.items()
+            if "trace_scope" in v} == {
+        "short_conv.mixer_ms": scopes.SHORT_CONV,
+        "short_conv.mixer_proj_ms": scopes.SHORT_CONV_PROJ,
+        "short_conv.mixer_gate_ms": scopes.SHORT_CONV_GATE,
+        "short_conv.moe_ms": scopes.MOE}
+    assert read["short_conv.moe_gmm_roofline"] == {
+        "trace_ops": "hvd_moe_gmm", "roofline": "latent_moe_gmm"}
+    full = lfm2_moe._model_config(config, job)
+    assert full.layer_pattern == (("attention", None, True), ("experts",)) \
+        + (("conv",), ("experts",)) * 3
+    assert full.lead_pattern == (("conv",), ("dense",))
+    assert (full.head_dim, full.kv_heads, full.dense_ff, full.d_ff,
+            full.conv_taps, full.qk_norm, full.moe_shared_width,
+            full.expert_share, full.held_experts, full.tie_embeddings,
+            full.remat) == (
+                64, 8, 11776, 1536, 3, "head", 0, (0, 8), 8, True,
+                config["assumed"]["checkpoint_every_block"] or None)
+    sizes = lfm2_moe.shapes(config, job)
+    need = importlib.import_module(
+        "roofline_latent_moe_gmm").latent_moe_gmm(sizes)
+    assert need["flops"] > 0 and need["bytes"] > 0
+    assert {"layer_types", "layer_dense", "layer_windows", "kv_heads",
+            "held_experts", "first_expert", "d_expert", "dense_ff",
+            "routed_layers", "head_calls", "conv_taps"} <= set(sizes)
+    config, job = {**config, **config["tiny"]}, {**job, **job["tiny"]}
+    cfg = lfm2_moe._model_config(config, job)
+    params = t.init_params(np.random.RandomState(0), cfg, 1)
+    assert set(params) == {"embed", "ln_f", "layers", "lead"}
+    assert set(params["layers"]) == {"attention", "conv", "experts"}
+    assert set(params["lead"]) == {"conv", "dense"}
+    assert set(params["lead"]["conv"]) == {"ln1", "conv_in", "conv_w",
+                                           "conv_out"}
+    assert set(params["layers"]["attention"]) == {
+        "ln1", "wq", "wk", "wv", "wo", "q_norm", "k_norm"}
+    assert set(params["layers"]["experts"]) == {
+        "ln2", "router", "router_bias", "we1", "we2", "we3"}
+    ours = jax.eval_shape(lfm2_moe._init_function(cfg, config),
+                          jax.random.PRNGKey(0))
+    assert jax.tree_util.tree_map(lambda a: a.shape, ours) == \
+        jax.tree_util.tree_map(lambda a: a.shape, params)
+    leaves = get_leaves(params, lfm2_moe._leaf_paths(config))
+    assert set(leaves) == {
+        "embed", "first_conv_in", "dense_down", "attention_key",
+        "attention_q_norm", "last_conv_taps", "last_conv_out",
+        "last_router", "last_experts_down"}
+    assert leaves["attention_q_norm"].shape == (cfg.head_dim,)
+    assert leaves["last_conv_taps"].shape == (3, cfg.d_model)
+    assert leaves["first_conv_in"].shape == (cfg.d_model, 3 * cfg.d_model)
+    # every layer's two blocks are where the adapter says the reference
+    # finds them
+    for (path, index), (ffn, at) in lfm2_moe._places(config):
+        assert params[path[0]][path[1]]["ln1"][index].ndim == 1
+        assert params[ffn[0]][ffn[1]]["ln2"][at].ndim == 1
+    mesh = build_mesh(devices=jax.devices()[:1], dp=-1)
+    batch = lfm2_moe.host_batch(config, job, 0, 0, 2)
+    _loss, aux, _grads = jax.eval_shape(
+        t.make_grad_fn(cfg, mesh), params, batch["tokens"], batch["targets"])
+    assert set(aux) == {"aux_loss", "load_balance_loss", "router_z_loss",
+                        "max_expert_load", "dropped", "held_rows"}
+    choices = jax.eval_shape(
+        lambda p, tok: t.router_choices(p, tok, cfg), params,
+        batch["tokens"])
+    assert choices.shape == (8, batch["tokens"].size, cfg.moe_top_k)
+    assert scopes.SHORT_CONV_PHASES == (
+        "hvd.short_conv", "hvd.short_conv.proj", "hvd.short_conv.gate")
+    assert set(scopes.SHORT_CONV_PHASES) <= set(scopes.DEVICE_PHASES)
 
 
 #: sha256 of the GPT cell's tiny train step's jaxpr (``_gpt_tiny_jaxpr``),

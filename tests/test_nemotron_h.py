@@ -672,7 +672,7 @@ def test_the_decode_paths_refuse_the_new_fields_by_name():
 
 def test_a_pattern_or_a_word_the_program_does_not_know_is_refused():
     with pytest.raises(ValueError, match="one of"):
-        dataclasses.replace(CFG, layer_pattern=(("mamba",), ("conv",)),
+        dataclasses.replace(CFG, layer_pattern=(("mamba",), ("retention",)),
                             n_layers=2)
     with pytest.raises(ValueError, match="throughout or of none"):
         dataclasses.replace(CFG, layer_pattern=(("mamba",), (None, True)),

@@ -28,7 +28,7 @@ PACKAGE = os.path.join(os.path.dirname(os.path.dirname(
 
 def test_the_vocabulary_is_the_only_place_the_strings_are_written():
     names = scopes.DEVICE_PHASES + scopes.HOST_SPANS
-    assert len(set(names)) == len(names) == 34
+    assert len(set(names)) == len(names) == 37
     assert scopes.RECOMPUTE in scopes.DEVICE_PHASES
     # JAX's own word is no phase, and is written here alone all the same
     names += (scopes.RECOMPUTED,)
@@ -216,6 +216,35 @@ def _gated_step():
             (params, init_opt_state(tx, params, mesh, cfg), t, y))
 
 
+def _short_conv_step():
+    """Gated short-convolution blocks beside an attention block with a norm
+    a head, a conv + dense layer leading sigmoid-routed experts of which a
+    share is held, a tied head."""
+    from horovod_tpu.models import (TransformerConfig, init_opt_state,
+                                    init_params, make_train_step,
+                                    shard_batch, shard_params)
+    cfg = TransformerConfig(vocab_size=64, d_model=32, n_heads=4,
+                            n_layers=4, d_ff=16, dense_ff=32, max_seq=32,
+                            n_experts=4, moe_top_k=2, moe_gated=True,
+                            moe_renormalize=True, moe_balance_weight=0.0,
+                            moe_router_scores="sigmoid", ffn_gated=True,
+                            dtype=jnp.float32, head_width=16, n_kv_heads=2,
+                            qk_norm="head", conv_taps=3,
+                            layer_pattern=(("attention", None, True),
+                                           ("experts",), ("conv",),
+                                           ("experts",)),
+                            lead_pattern=(("conv",), ("dense",)),
+                            expert_share=(0, 2))
+    mesh = build_mesh(devices=jax.devices()[:1], dp=-1)
+    params = shard_params(init_params(np.random.RandomState(0), cfg, 1),
+                          cfg, mesh)
+    tx = optax.adamw(1e-3)
+    tokens = np.zeros((2, 16), np.int32)
+    t, y = shard_batch(tokens, tokens, mesh)
+    return (make_train_step(cfg, mesh, tx),
+            (params, init_opt_state(tx, params, mesh, cfg), t, y))
+
+
 _TEXTS = {}
 
 
@@ -226,7 +255,8 @@ def _compiled_text(model: str) -> str:
                       "moe": _moe_step, "looped": _looped_step,
                       "mixed": _mixed_step,
                       "hybrid": _hybrid_step,
-                      "gated": _gated_step}[model]()
+                      "gated": _gated_step,
+                      "short_conv": _short_conv_step}[model]()
         _TEXTS[model] = step.lower(*args).compile().as_text()
     return _TEXTS[model]
 
@@ -297,6 +327,46 @@ def test_the_gate_nests_in_attention_beside_the_core():
     assert not any(scopes.ATTENTION_GATE in _compiled_text(model)
                    for model in ("flagship", "moe", "looped", "mixed",
                                  "hybrid"))
+
+
+@pytest.mark.parametrize("phase", scopes.MODEL_PHASES + scopes.MOE_PHASES
+                         + scopes.SHORT_CONV_PHASES)
+def test_the_short_conv_step_carries_every_phase_in_both_directions(phase):
+    assert _directions(_compiled_text("short_conv"), phase) == {"fwd", "bwd"}
+
+
+def test_the_short_conv_s_parts_nest_in_it_and_the_heads_norm_in_attention():
+    """hvd.short_conv.proj and hvd.short_conv.gate inside hvd.short_conv
+    (the mixer, norm to out-projection); the QK-norm a
+    head inside hvd.attention and outside hvd.attention.core
+    (``step.attention_core_ms`` is the kernels' cover); a step without the
+    kind has no such phase."""
+    assert scopes.SHORT_CONV_PHASES == (
+        "hvd.short_conv", "hvd.short_conv.proj", "hvd.short_conv.gate")
+    paths = re.findall(r'op_name="([^"]*)"', _compiled_text("short_conv"))
+
+    def parts(path):
+        return [re.sub(r"^(?:\w+\()+|\)+$", "", c) for c in path.split("/")]
+    for part in (scopes.SHORT_CONV_PROJ, scopes.SHORT_CONV_GATE):
+        inside = [parts(p) for p in paths if part in parts(p)]
+        assert inside and all(
+            scopes.SHORT_CONV in p
+            and p.index(scopes.SHORT_CONV) < p.index(part)
+            and scopes.ATTENTION not in p and scopes.MLP not in p
+            for p in inside), part
+        assert any(scopes.LAYERS in p for p in inside), part
+    # the matmuls are the projections', the taps' pads the gate's
+    assert any(p[-1].startswith("dot_general") for p in map(parts, paths)
+               if scopes.SHORT_CONV_PROJ in p)
+    assert not any(p[-1].startswith("dot_general") for p in map(parts, paths)
+                   if scopes.SHORT_CONV_GATE in p)
+    # three norms an attention block (ln1, q's, k's): none in the core
+    norms = [parts(p) for p in paths if "rsqrt" in parts(p)[-1]
+             and scopes.ATTENTION in parts(p)]
+    assert norms and not any(scopes.ATTENTION_CORE in p for p in norms)
+    assert not any(scopes.SHORT_CONV in _compiled_text(model)
+                   for model in ("flagship", "moe", "looped", "mixed",
+                                 "hybrid", "gated"))
 
 
 def test_the_mixer_s_parts_nest_in_ssm_and_the_shared_expert_in_moe():

@@ -99,6 +99,10 @@ _QKV_BANDED_WINDOW = [((1, 8192, 64, 128), jnp.bfloat16)] \
     + [((1, 8192, 8, 128), jnp.bfloat16)] * 2
 _QKV_BANDED_FULL = [((1, 8192, 48, 128), jnp.bfloat16)] \
     + [((1, 8192, 8, 128), jnp.bfloat16)] * 2
+# the cell lfm2-24b-a2b.s8192's attention block: 32 query heads on 8
+# key/value heads of 64 over 8192 keys, two sequences
+_QKV_SHORT_CONV = [((2, 8192, 32, 64), jnp.bfloat16)] \
+    + [((2, 8192, 8, 64), jnp.bfloat16)] * 2
 _FLASH = ("hvd_flash_attention", "hvd_flash_bwd")
 # the attention core of the cells bert-large.s128 and bert-large.s512: q, k,
 # v and the [B, S] key mask. Sixteen heads of 64 are eight 128-lane columns
@@ -137,6 +141,12 @@ _GMM_BANDED = [((65536, 2048), jnp.bfloat16), ((32, 2048, 512), jnp.float32),
                ((32,), jnp.int32)]
 _GMM_BANDED_DOWN = [((65536, 512), jnp.bfloat16),
                     ((32, 512, 2048), jnp.float32), ((32,), jnp.int32)]
+# a held share's expert layer in lfm2-24b-a2b.s8192: 16 384 tokens x top-4
+# gathered rows, 8 held experts of 2048 <-> 1536, ~1024 rows a group
+_GMM_SHORT_CONV = [((65536, 2048), jnp.bfloat16),
+                   ((8, 2048, 1536), jnp.float32), ((8,), jnp.int32)]
+_GMM_SHORT_CONV_DOWN = [((65536, 1536), jnp.bfloat16),
+                        ((8, 1536, 2048), jnp.float32), ((8,), jnp.int32)]
 # the Mamba-2 scan of the cell nemotron-3-nano-30b-a3b.s8192: x, dt, a, b,
 # c at 8192 positions, 64 heads of 64 in 8 groups, state 128, chunk 128
 _SSM_CELL = [((1, 8192, 64, 64), jnp.bfloat16), ((1, 8192, 64), jnp.float32),
@@ -178,6 +188,8 @@ CASES = {
             q, k, v, True, window=512)), (0, 1, 2)), _QKV_BANDED_WINDOW,
         _FLASH),
     "flash_fwd_grad_group_of_six": (_flash_grad, _QKV_BANDED_FULL, _FLASH),
+    # a head of 64 in groups of 4 at the default scale, two sequences
+    "flash_fwd_grad_short_conv_cell": (_flash_grad, _QKV_SHORT_CONV, _FLASH),
     # a window that is no multiple of the tile: whole masked tiles
     "flash_fwd_grad_window_unaligned": (
         jax.grad(lambda q, k, v: _sum32(pa.flash_attention_tpu(
@@ -225,6 +237,13 @@ CASES = {
     "moe_gmm_banded_down_grad": (
         jax.grad(lambda x, w, g: _sum32(moe.grouped_matmul(x, w, g)),
                  (0, 1)), _GMM_BANDED_DOWN,
+        "transpose_jvp_" + moe.GMM_NAME),
+    "moe_gmm_short_conv_grad": (
+        jax.grad(lambda x, w, g: _sum32(moe.grouped_matmul(x, w, g)),
+                 (0, 1)), _GMM_SHORT_CONV, "transpose_jvp_" + moe.GMM_NAME),
+    "moe_gmm_short_conv_down_grad": (
+        jax.grad(lambda x, w, g: _sum32(moe.grouped_matmul(x, w, g)),
+                 (0, 1)), _GMM_SHORT_CONV_DOWN,
         "transpose_jvp_" + moe.GMM_NAME),
     # an expert width no 128-multiple divides (1856 = 2^6 * 29): a block
     # spans it whole, as the contraction and as the output's columns
@@ -731,6 +750,57 @@ def test_banded_step_compiles_for_v5e_on_the_kernels(topo):
         assert name + "/" in names, name
     total = step_bytes(compiled.memory_analysis())["total"]
     assert 15.4e9 < total < 15.75e9, total      # PERF.md section 6, PR 53
+
+
+def test_short_conv_step_compiles_for_v5e_on_the_kernels(topo):
+    """The cell lfm2-24b-a2b.s8192's step: a leading conv + dense layer and
+    one period of an attention block (32 / 8 heads of 64, a norm a head) and
+    three conv blocks, each with 8 of 64 experts held, two sequences of
+    8192, no block checkpointed. The attention block is the two flash
+    kernels at 32 / 8 x 64 (no score-shaped array in the program), the
+    experts are ``hvd_moe_gmm`` at 2048 <-> 1536 on the tiles ``gmm_path``
+    picks, the head ``hvd_fused_xent`` on the tied table; the mixer's three
+    scopes are in the program; the bytes are what ``assumed.recomputation``
+    says, under the compiler's 15.75 GB."""
+    with _compile_cache_off(), pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        step, args, shapes, step_bytes = _cell_step("lfm2-24b-a2b.s8192",
+                                                    topo)
+        compiled = step.lower(*args).compile()
+        # the latent cell's widths at twice its rows a group: its tiles
+        assert moe._gmm_tile(65536, 2048, 1536, 2) == moe.GmmTiles(
+            (256, 2048, 768), (256, 1536, 1024), (128, 1024, 1536))
+        assert moe._gmm_tile(65536, 1536, 2048, 2) == moe.GmmTiles(
+            (256, 1536, 1024), (256, 2048, 768), (128, 1536, 1024))
+        assert moe.gmm_path(65536, 2048, 1536).startswith(
+            f"pallas {moe.GMM_NAME} weights read as stored, [E, 2048, 1536] "
+            "row-major")
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    routed, blocks = shapes["routed_layers"], len(shapes["layer_windows"])
+    assert (shapes["layers"], routed, blocks, shapes["conv_layers"]) == (
+        5, 4, 1, 4)
+    assert sum("hvd_flash_attention" in c for c in calls) == blocks
+    assert sum("hvd_flash_bwd" in c for c in calls) == blocks
+    assert sum(moe.GMM_NAME in c for c in calls) == 9 * routed
+    assert sum("hvd_fused_xent" in c for c in calls) == 1
+    # a head of 64 goes heads first: two sequences' 32 query heads on 8
+    flash = next(c for c in calls if "hvd_flash_attention" in c)
+    assert "bf16[64,8192,64]" in flash and "bf16[16,8192,64]" in flash
+    s = shapes["seq"]
+    assert not re.search(r"\[(?:\d+,)*(?:[2-9]|\d\d+),%d,%d\]" % (s, s),
+                         text), "a score-shaped array"
+    k, m = shapes["experts_per_token"], shapes["d_model"]
+    assert f"f32[{2 * s * k},{m}]" not in _arrays_in_memory(text), \
+        "the rows in float32"
+    from horovod_tpu.profiling import scopes
+    names = "\n".join(line for line in text.splitlines()
+                      if "op_name=" in line)
+    for name in scopes.SHORT_CONV_PHASES:
+        assert name + "/" in names, name
+    total = step_bytes(compiled.memory_analysis())["total"]
+    assert 13.8e9 < total < 14.1e9, total      # PERF.md section 6, PR 55
 
 
 def _computations(text):
