@@ -1266,18 +1266,20 @@ def _flash_call(shape, kv_heads=None, window=None) -> str:
     ranges = S // bwd.rows
     form = ("dq resident" if ranges == 1
             else f"dq in {ranges} q ranges of {bwd.rows} rows")
-    def pieces(edge):
-        ran, of = pa.tile_piece_blocks(bq, bk, edge)
-        return (f"{ran} of {of} blocks in "
-                f"{len(pa.tile_pieces(bq, bk, edge))} pieces")
+    def blocks(crossed):
+        ran, of = pa.tile_piece_blocks(bq, bk, crossed)
+        return f"{ran} of {of} blocks"
     fwd = ("operands in place " + str([B, S, H * D]) if D % pa.MIN_BLOCK == 0
            else "operands heads first " + str([B * H, S, D]))
+    fwd += (f", scores [k, q] with m, l [1, {bq}] and acc [{D}, {bq}] along "
+            f"the lanes, a tile in {len(pa.tile_pieces(bq, bk))} pieces of "
+            f"{pa.fwd_piece_rows(bk)} k rows")
     if not pa.banded_tiles(bq, bk, window):
-        fwd += ", a tile the diagonal or the band's edge crosses whole"
+        fwd += ", all q rows of a tile the diagonal or the band's edge crosses"
     else:
-        fwd += f", a tile on the diagonal {pieces(False)}"
+        fwd += f", on the diagonal {blocks('diagonal')}"
         if window is not None:
-            fwd += f", on the band's edge {pieces(True)}"
+            fwd += f", on the band's edge {blocks('edge')}"
     band = ""
     if window is not None:
         def tiles(grid, bq, bk):

@@ -9,8 +9,28 @@ running-softmax (m, l, acc) in VMEM scratch so HBM traffic is O(S·D)
 instead of O(S²):
 
   grid = (batch·heads, Sq/block_q, Sk/block_k)   — K tile innermost
-  per (q tile): for each k tile: s = q @ kᵀ; online-softmax update
+  per (q tile): for each k tile, in pieces of 128 k rows:
+      sT = k @ qᵀ·scale; m, l, alpha as [1, q rows]; accT += vᵀ @ pT
   (under a window the k axis spans a q tile's band alone: **Window**)
+
+**The softmax state lies along the lanes** (PR 56). The scores are computed
+transposed, ``[k rows, q rows]``, as the backward computes them: a q row's
+running max ``m``, running sum ``l`` and rescale ``alpha`` are ``[1, q
+rows]`` float32 rows (128 rows a vreg, every lane used), the max and the sum
+run down the sublanes (elementwise across vregs, one reduce in a vreg at the
+end), every broadcast is along the sublanes, the accumulator is ``[D, q
+rows]`` and is transposed once a q tile on its way out, and ``lse`` is
+written as the row it is. With ``m`` and ``l`` as ``[q rows, 1]`` columns
+(a vreg for 8 rows, one lane used) every q row paid ~4.2 ns a k step for two
+reductions across the lanes and three broadcasts back. Since a step's row
+work is now small, a tile runs as **pieces of ``FWD_PIECE_ROWS`` k rows**,
+each an online-softmax update of its own, and a piece's score matmul is
+written before the reduction of the piece before it: Mosaic's scheduler keeps
+the order it is given, so written score tile, reduction, p·v the MXU waits
+for the vector unit and back; written one ahead it runs a piece's scores
+while the vector unit reduces the last (v5e, 48 query heads on 8 over 8192
+keys: 7.39 ms a call with the column state, 7.95 transposed in the order
+written, 6.14 one ahead; PERF.md, PR 56).
 
 **Operands where they lie.** A head of whole lane tiles (``D % 128 == 0``)
 is read and written in place: q, k, v and o are ``[B, S, heads·D]``, the
@@ -32,10 +52,12 @@ fits ``VMEM_BUDGET``. A grid step that runs a tile costs about 0.35 µs
 beside the tile's work, so a 128 × 128 tile (0.04 µs of MXU work at bf16)
 is all overhead: at B2·S2048·H16·D128 causal a call takes 2.59 ms with
 128 × 128 tiles and 0.48 ms with 1024 × 1024 (v5e; PERF.md, PR 25).
-Callers pass no block; ``block_q`` / ``block_k`` are overrides for
-tests. The contract to callers is only ``MIN_BLOCK``: sequence lengths
-and head_dim are multiples of 128, or the head is :data:`NARROW_HEAD` = 64
-wide (below).
+The score tile is in VMEM a piece at a time, so what fills the budget is
+the operands' blocks (``flash_vmem_bytes``: 5.6 MiB at 1024 × 1024 and a
+bf16 head of 128, 8.6 at 256). Callers pass no block; ``block_q`` /
+``block_k`` are overrides for tests. The contract to callers is only
+``MIN_BLOCK``: sequence lengths and head_dim are multiples of 128, or the
+head is :data:`NARROW_HEAD` = 64 wide (below).
 
 **Dtypes.** q·kᵀ and p·v multiply operands in the dtype the caller passed
 (bf16 in training: what the MXU multiplies; float32 inputs give float32
@@ -47,12 +69,11 @@ the accumulator and the log-sum-exp are float32 for every input dtype.
 nothing: the K/V index maps clamp to the q tile's last live tile, and
 Pallas issues no DMA for a block index that repeats. Only the tiles the
 diagonal crosses build a mask; those below it skip it. A square tile meets
-the diagonal corner to corner and runs in bands of q rows
-(:func:`tile_pieces`; 512 rows of a 1024 x 1024 tile, 128 of a smaller
-one): a band leaves out the k columns past its last row, which the mask
-kills for all of it (of a 1024 x 1024 tile 16 of its 64 blocks of 128 x
-128). A masked score added ``exp(-1e30 - m) = 0`` to ``l``
-and to ``p·v``: leaving it out changes no term.
+the diagonal corner to corner, and its pieces (:func:`tile_pieces`) leave
+out the q rows before a piece's first k row, which the mask kills for all
+of it (of a 1024 x 1024 tile 28 of its 64 blocks of 128 x 128), as the
+backward's do. A masked score added ``exp(-1e30 - m) = 0`` to ``l`` and to
+``p·v``: leaving it out changes no term.
 
 **Window.** ``window=W`` (causal only) keeps of a query at ``t`` the keys
 ``t - W < j <= t``: a band under the diagonal, and a windowed call walks
@@ -69,13 +90,13 @@ tiles in the backward) runs nothing and fetches nothing: the index stays on
 the last live tile. A tile the band's lower edge crosses is masked as a
 diagonal tile is; where the window is a multiple of the (square) tile the
 edge crosses its tiles corner to corner too, and they run in the mirror
-image of the diagonal's bands. At 8192 x 8192 under a window of 4096 the
+image of the diagonal's pieces. At 8192 x 8192 under a window of 4096 the
 tile stays 1024 x 1024 and a head takes 8 x 5 = 40 steps for its 30 live
 tiles (of 36 causal, four of them edge tiles); under a window of 512 the
 tile is 512 x 512 and a head takes 16 x 2 = 32 steps for 31 live tiles,
 every one on the diagonal or the edge (v5e, a call at 64 query heads on 8:
 5.21 ms forward and 7.26 backward where 1024 x 1024 tiles on the sequence's
-grid took 6.19 and 11.14; PERF.md, PR 54).
+grid took 6.19 and 11.14; PERF.md, PR 54; the forward 2.43 since PR 56).
 
 **Grouped heads.** k and v may have fewer heads than q (``H % Hkv == 0``):
 q head ``h`` reads k/v head ``h // (H // Hkv)`` through the block index,
@@ -153,8 +174,7 @@ NARROW_HEAD = 64
 TILES = (1024, 512, 256, MIN_BLOCK)
 #: bytes the forward's working set may take by ``flash_vmem_bytes``: the
 #: v5e's default scoped-VMEM limit (16 MiB of 128), so no limit is asked
-#: for. The estimate counts ``s`` and ``p`` apart where the compiler shares
-#: their room: tiles it puts at 22 MiB still compile under that limit
+#: for
 VMEM_BUDGET = 16 * 1024 * 1024
 
 _NT = (((1,), (1,)), ((), ()))      # a · bᵀ
@@ -181,14 +201,16 @@ def _check_window(window, causal) -> None:
 
 
 def flash_vmem_bytes(block_q: int, block_k: int, D: int, itemsize: int) -> int:
-    """Working set of one grid step: q, k, v and o tiles double-buffered
-    by the pipeline, the float32 ``s`` and ``p`` tiles and ``p`` cast for
-    p·v, the float32 accumulator, and ``m`` / ``l`` (a ``[bq, 1]`` float32
-    array takes whole 128-lane tiles)."""
-    io = 2 * (2 * block_q + 2 * block_k) * D * itemsize
-    tiles = block_q * block_k * (4 + 4 + itemsize)
-    scratch = block_q * D * 4 + 2 * block_q * 128 * 4
-    return io + tiles + scratch
+    """Working set of one grid step: q, k, v and o tiles and the ``[1,
+    bq]`` lse row (8 sublanes) double-buffered by the pipeline, two pieces
+    of the score tile in flight (one's ``sT`` while the other is reduced),
+    each ``[piece rows, bq]`` in float32 as ``sT`` and ``pT`` and ``pT``
+    cast for p·v, the float32 accumulator ``[D, bq]`` and its transpose on
+    the way out, and the ``m`` / ``l`` rows."""
+    io = 2 * (2 * block_q + 2 * block_k) * D * itemsize + 2 * 8 * block_q * 4
+    pieces = 2 * fwd_piece_rows(block_k) * block_q * (4 + 4 + itemsize)
+    scratch = 2 * block_q * D * 4 + 2 * 8 * block_q * 4
+    return io + pieces + scratch
 
 
 def _tiles_under(window: Optional[int]) -> Tuple[int, ...]:
@@ -326,59 +348,62 @@ def _band_q_tile(kj, step, first_tile, tiles: int, block_q: int,
     return first + step, last
 
 
-#: q rows of a piece of a square tile on the diagonal or on a window's edge
-#: in the forward (:func:`tile_pieces`). v5e, a 1024 x 1024 tile, the
-#: kernel's ms a call, the tile whole / pieces of 128 / 256 / 512: [1, 8192,
-#: 20, 256] 5.327 / 5.207 / 5.040 / 5.083, [1, 4096, 16, 128] 0.745 / 0.769 /
-#: 0.720 / 0.695, [1, 8192, 28 on 4, 128] 4.397 / 4.485 / 4.312 / 4.224 and
-#: under a window of 4096 3.827 / 3.914 / 3.710 / 3.594, [2, 2048, 16, 128]
-#: 0.493 / 0.518 / 0.468 / 0.443 (PERF.md, PR 50). A piece of 128 rows
-#: leaves out 28 of a tile's 64 blocks and still costs more than the tile
-#: whole: a short piece streams few rows past each k block the MXU loads,
-#: where the backward's pieces (``DIAG_ROWS``) are k rows against many q rows.
-#: A tile no taller than this runs in bands of ``MIN_BLOCK`` rows
-#: (:func:`fwd_band_rows`): under a window of 512 every live 512 x 512 tile is
-#: on the diagonal or on the edge, and at [1, 8192, 64 on 8, 128] a call takes
-#: 5.54 / 5.59 ms with the tiles whole, 5.65 / 5.66 in bands of 256 and 5.21 /
-#: 5.21 in bands of 128, 10 of a tile's 16 blocks (PERF.md, PR 54)
-FWD_DIAG_ROWS = 512
+#: k rows of a piece of the forward (:func:`tile_pieces`): every tile runs as
+#: pieces of this many k rows against its q rows, a piece's score matmul
+#: issued before the piece before it is reduced (:func:`_flash_kernel`). v5e,
+#: the kernel's ms a call alone, the parent (q-major scores, ``[rows, 1]``
+#: state, the tile whole) / pieces of 512 / 256 / 128 k rows issued one ahead:
+#: [1, 8192, 48 on 8, 128] 7.387 / 7.241 / 7.044 / 6.143, [2, 2048, 16, 128]
+#: 0.457 / 0.324 / 0.319 / 0.298, [1, 8192, 20, 256] 5.096 / 5.119 / 5.089 /
+#: 4.682, heads first [1, 4096, 32 on 8, 64] 1.336 / 0.959 / 0.938 / 0.818;
+#: under a window of 512 (512 x 512 tiles, [1, 8192, 64 on 8, 128]) 4.264 /
+#: - / 2.448 / 2.434. Issued in the order written (a piece's scores, its
+#: reduction, its p . v) the same pieces take what the tile whole takes
+#: (7.949, 0.389, 5.489, 1.117): Mosaic's scheduler keeps the order it is
+#: given, so the MXU waits for the vector unit and back (PERF.md, PR 56)
+FWD_PIECE_ROWS = 128
 
 
-def fwd_band_rows(block_q: int) -> int:
-    """q rows of a band of :func:`tile_pieces`, from the tile."""
-    return FWD_DIAG_ROWS if block_q > FWD_DIAG_ROWS else min(block_q,
-                                                             MIN_BLOCK)
+def fwd_piece_rows(block_k: int) -> int:
+    """k rows of a piece of :func:`tile_pieces`, from the tile."""
+    return min(block_k, FWD_PIECE_ROWS)
 
 
 def banded_tiles(block_q: int, block_k: int, window: Optional[int]) -> bool:
-    """Whether the forward runs the tiles the diagonal (and a window's lower
-    edge) crosses in :func:`tile_pieces`: square tiles, which the diagonal
-    crosses corner to corner, and a window of whole tiles, so that its edge
-    does too. Else such a tile runs whole, under its mask."""
+    """Whether the forward's pieces of a tile the diagonal (or a window's
+    lower edge) crosses leave out the q rows masked for a whole piece:
+    square tiles, which the diagonal crosses corner to corner, and a window
+    of whole tiles, so that its edge does too. Else such a tile's pieces
+    span all its q rows, under the mask."""
     return block_q == block_k and (window is None or window % block_k == 0)
 
 
-def tile_pieces(block_q: int, block_k: int, edge: bool = False):
-    """The static pieces the forward runs of a square tile that the diagonal
-    (or, ``edge``, a window's lower edge) crosses corner to corner, as
-    ``(r0, rows, c0, cols)``: q rows ``[r0, r0 + rows)`` against k columns
-    ``[c0, c0 + cols)`` of the tile, in bands of :func:`fwd_band_rows` q rows
-    that leave out the columns the mask kills for the whole band, the ones
-    past the band's last row on the diagonal and the ones before its first
-    row on the edge: a 1024 x 1024 tile runs 48 of its 64 blocks of 128 x
-    128 in two pieces."""
-    rows = fwd_band_rows(block_q)
-    return [(r0, rows, r0, block_k - r0) if edge else (r0, rows, 0, r0 + rows)
-            for r0 in range(0, block_q, rows)]
+def tile_pieces(block_q: int, block_k: int, crossed: Optional[str] = None):
+    """The static pieces the forward runs of a tile, as ``(k0, rows, q0,
+    q1)``: k rows ``[k0, k0 + rows)`` against q rows ``[q0, q1)`` of the
+    tile (the backward's ``diagonal()`` / ``edge()`` geometry), in
+    :func:`fwd_piece_rows` k rows. ``crossed`` is None for all q rows
+    (a tile inside the band, or one a mask's line cuts anywhere), else the
+    line that crosses a square tile corner to corner: ``"diagonal"`` leaves
+    out the q rows before a piece's first k row and ``"edge"`` (a window's
+    lower edge) the ones from its last k row on, which the mask kills for
+    the whole piece: a 1024 x 1024 tile runs 36 of its 64 blocks of 128 x
+    128 in eight pieces."""
+    rows = fwd_piece_rows(block_k)
+    span = {None: lambda k0: (0, block_q),
+            "diagonal": lambda k0: (k0, block_q),
+            "edge": lambda k0: (0, k0 + rows)}[crossed]
+    return [(k0, rows, *span(k0)) for k0 in range(0, block_k, rows)]
 
 
 def tile_piece_blocks(block_q: int, block_k: int,
-                      edge: bool = False) -> Tuple[int, int]:
+                      crossed: Optional[str] = None) -> Tuple[int, int]:
     """(the ``MIN_BLOCK`` x ``MIN_BLOCK`` blocks :func:`tile_pieces` runs
-    of such a tile, the blocks the tile has): (48, 64) at 1024 x 1024. For
-    ``chip_smoke.py``'s ``attention_path`` and the tests."""
-    ran = sum(rows * cols for _, rows, _, cols
-              in tile_pieces(block_q, block_k, edge))
+    of such a tile, the blocks the tile has): (36, 64) on the diagonal of
+    1024 x 1024. For ``chip_smoke.py``'s ``attention_path`` and the
+    tests."""
+    ran = sum(rows * (q1 - q0) for _, rows, q0, q1
+              in tile_pieces(block_q, block_k, crossed))
     return ran // MIN_BLOCK ** 2, block_q * block_k // MIN_BLOCK ** 2
 
 
@@ -387,7 +412,12 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
                   block_k: int, window: Optional[int] = None):
     """One (q-tile, k-tile) step; grid (BH, nq, nk) with k innermost. Under
     a window the k axis spans the q tile's band (:func:`flash_grid`) and
-    ``kv_idx`` is the k tile the step stands for."""
+    ``kv_idx`` is the k tile the step stands for. The scores are computed
+    transposed, ``sT = k @ qT`` as ``[k rows, q rows]``, so a q row's
+    running max ``m``, sum ``l`` and rescale ``alpha`` are ``[1, q rows]``
+    rows along the lanes (``m_ref``, ``l_ref``; the accumulator ``[D, q
+    rows]`` beside them): the max and the sum run down the sublanes and
+    every broadcast is along them."""
     kv_step = pl.program_id(2)
     q_idx = pl.program_id(1)
     kv_idx = kv_step if window is None else _band_k_tile(
@@ -399,45 +429,51 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    def update(masked: bool, piece=(0, block_q, 0, block_k)):
-        """The online-softmax update of the tile's q rows ``[r0, r0 +
-        rows)`` over its k columns ``[c0, c0 + cols)`` (static)."""
-        r0, rows, c0, cols = piece
-        rs, cs = pl.ds(r0, rows), pl.ds(c0, cols)
-        q = q_ref[0, rs]                           # [rows, D]
-        k = k_ref[0, cs]                           # [cols, D]
-        v = v_ref[0, cs]                           # [cols, D]
-        s = _dot(q, k, _NT) * scale
+    def scores(masked: bool, piece):
+        """float32 ``sT`` of a piece (static), masked where asked."""
+        k0, rows, q0, q1 = piece
+        st = _dot(k_ref[0, pl.ds(k0, rows)], q_ref[0, pl.ds(q0, q1 - q0)],
+                  _NT) * scale                          # [rows, q1 - q0]
         if masked:
-            qpos = q_idx * block_q + r0 + lax.broadcasted_iota(
-                jnp.int32, s.shape, 0)
-            kpos = kv_idx * block_k + c0 + lax.broadcasted_iota(
-                jnp.int32, s.shape, 1)
+            kpos = kv_idx * block_k + k0 + lax.broadcasted_iota(
+                jnp.int32, st.shape, 0)
+            qpos = q_idx * block_q + q0 + lax.broadcasted_iota(
+                jnp.int32, st.shape, 1)
             live = qpos >= kpos
             if window is not None:
                 live = jnp.logical_and(live, kpos > qpos - window)
-            s = jnp.where(live, s, NEG_INF)
-        m_prev = m_ref[rs]
-        l_prev = l_ref[rs]
-        m_cur = jnp.max(s, axis=-1)[:, None]       # [rows, 1]
-        m_next = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_next)                    # [rows, cols]
+            st = jnp.where(live, st, NEG_INF)
+        return st
+
+    def update(piece, st):
+        """The online-softmax update of the piece's q rows over its k
+        rows."""
+        k0, rows, q0, q1 = piece
+        qs = pl.ds(q0, q1 - q0)
+        v = v_ref[0, pl.ds(k0, rows)]                   # [rows, D]
+        m_prev = m_ref[:, qs]                           # [1, q1 - q0]
+        m_next = jnp.maximum(m_prev, jnp.max(st, axis=0, keepdims=True))
+        pt = jnp.exp(st - m_next)
         alpha = jnp.exp(m_prev - m_next)
-        l_next = l_prev * alpha + jnp.sum(p, -1)[:, None]
-        acc_ref[rs] = acc_ref[rs] * alpha + _dot(p.astype(v.dtype), v, _NN)
-        m_ref[rs] = m_next
-        l_ref[rs] = l_next
+        l_ref[:, qs] = l_ref[:, qs] * alpha + jnp.sum(pt, axis=0,
+                                                      keepdims=True)
+        m_ref[:, qs] = m_next
+        acc_ref[:, qs] = acc_ref[:, qs] * alpha + _dot(
+            v, pt.astype(v.dtype), _TN)                 # [D, q1 - q0]
 
-    def whole(masked: bool):
-        return functools.partial(update, masked)
-
-    def pieces(edge: bool):
-        """A tile the diagonal (or the band's edge) crosses corner to
-        corner, without the blocks the mask kills (:func:`tile_pieces`)."""
-        def run():
-            for piece in tile_pieces(block_q, block_k, edge):
-                update(True, piece)
-        return run
+    def run(masked: bool, line: Optional[str] = None):
+        """A tile in :func:`tile_pieces`, all in one basic block, a piece's
+        score matmul written before the piece before it is reduced: the
+        scheduler keeps that order, and the MXU then runs a piece's scores
+        while the vector unit reduces the last (``FWD_PIECE_ROWS``)."""
+        def tile():
+            pieces = tile_pieces(block_q, block_k, line)
+            st = scores(masked, pieces[0])
+            for piece, ahead in zip(pieces, pieces[1:] + [None]):
+                st_ahead = None if ahead is None else scores(masked, ahead)
+                update(piece, st)
+                st = st_ahead
+        return tile
 
     banded = banded_tiles(block_q, block_k, window)
     if causal:
@@ -449,36 +485,38 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
         crossed = jnp.logical_and(first_col <= first_row + block_q - 1,
                                   last_col > first_row)
         if window is None:
-            pl.when(crossed)(pieces(False) if banded else whole(True))
+            pl.when(crossed)(run(True, "diagonal" if banded else None))
             # wholly at or below the diagonal: no mask to build. Tiles
             # strictly above it run nothing (and fetch nothing: k_tile)
-            pl.when(last_col <= first_row)(whole(False))
+            pl.when(last_col <= first_row)(run(False))
         else:
             crossed, clean = _band_tiles(first_row, first_col, block_q,
                                          block_k, window)
-            # a row the band's edge masks for all of a piece (of its first
-            # tile) holds garbage under m = NEG_INF until its next piece's
-            # alpha = 0 wipes it: every row's diagonal piece comes later
+            # a q row the band's edge masks for all of a piece (of its
+            # first tile) holds garbage under m = NEG_INF until its next
+            # piece's alpha = 0 wipes it: every row's diagonal piece comes
+            # later
             if banded:
                 # the diagonal and the edge cross different tiles, each
                 # corner to corner
-                pl.when(q_idx == kv_idx)(pieces(False))
-                pl.when(first_col == first_row - window)(pieces(True))
+                pl.when(q_idx == kv_idx)(run(True, "diagonal"))
+                pl.when(first_col == first_row - window)(run(True, "edge"))
             else:
-                pl.when(crossed)(whole(True))
-            pl.when(clean)(whole(False))
+                pl.when(crossed)(run(True))
+            pl.when(clean)(run(False))
     else:
-        whole(False)()
+        run(False)()
 
     @pl.when(kv_step == pl.num_programs(2) - 1)
     def _finalize():
         l_safe = jnp.maximum(l_ref[:], 1e-30)
-        o_ref[0] = (acc_ref[:] / l_safe).astype(o_ref.dtype)
-        # log-sum-exp residual for the backward pass: lse = m + log(l),
-        # written lane-dense as a [1, bq] row of the [BH, 1, Sq] output
-        # (a (1, bq) block of a 2-D [BH, Sq] array is not tile-aligned
-        # and the TPU lowering refuses it)
-        lse_ref[0, 0] = (m_ref[:] + jnp.log(l_safe))[:, 0]
+        # the accumulator is transposed once a q tile, on its way out
+        o_ref[0] = jnp.transpose(acc_ref[:] / l_safe).astype(o_ref.dtype)
+        # log-sum-exp residual for the backward pass: lse = m + log(l), the
+        # [1, bq] row it already is, of the [BH, 1, Sq] output (a (1, bq)
+        # block of a 2-D [BH, Sq] array is not tile-aligned and the TPU
+        # lowering refuses it)
+        lse_ref[0] = m_ref[:] + jnp.log(l_safe)
 
 
 def _heads_first(x):
@@ -552,9 +590,9 @@ def _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret,
             jax.ShapeDtypeStruct((B * H, 1, Sq), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, D), jnp.float32),   # acc
-            pltpu.VMEM((block_q, 1), jnp.float32),   # m (running max)
-            pltpu.VMEM((block_q, 1), jnp.float32),   # l (running sum)
+            pltpu.VMEM((D, block_q), jnp.float32),   # acc, transposed
+            pltpu.VMEM((1, block_q), jnp.float32),   # m (running max)
+            pltpu.VMEM((1, block_q), jnp.float32),   # l (running sum)
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
@@ -582,10 +620,13 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret, window):
 # The backward: one kernel, k tile outer, q tile inner, dq resident
 # ---------------------------------------------------------------------------
 #
-# The scores are computed transposed, ``sT = k @ qT`` as ``[bk, bq]``: a q
-# row's log-sum-exp and its ``adj`` then lie along the lanes, as the ``[1,
-# bq]`` rows the forward wrote, and of the five matmuls only dq's needs a
-# transposed operand (q-major scores need it for dv and for dk).
+# Both kernels compute the scores transposed, ``sT = k @ qT`` as ``[k rows, q
+# rows]``: what belongs to a q row then lies along the lanes. In the forward
+# that is the online-softmax state (``m``, ``l``, ``alpha`` as ``[1, bq]``
+# rows, the accumulator ``[D, bq]``); here it is the row's log-sum-exp and
+# its ``adj``, read as the ``[1, bq]`` rows the forward wrote, and of the
+# five matmuls only dq's needs a transposed operand (q-major scores need it
+# for dv and for dk).
 
 #: k rows of a piece of a square tile on the diagonal
 #: (:func:`_flash_bwd_kernel`). v5e, a 1024 x 1024 tile, ms a call at the

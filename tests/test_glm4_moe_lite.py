@@ -224,8 +224,9 @@ def test_the_flash_tiles_at_the_cell_s_head_width():
     with this PR)."""
     assert pallas_attention.flash_blocks(8192, 8192, 256, jnp.bfloat16) == (
         1024, 1024)
+    # (the score tile in pieces of 128 k rows since PR 56: 8.6 MiB of the 16)
     assert pallas_attention.flash_vmem_bytes(1024, 1024, 256, 2) == \
-        pallas_attention.VMEM_BUDGET
+        9043968 <= pallas_attention.VMEM_BUDGET
     assert pallas_attention.flash_bwd_blocks(
         8192, 8192, 256, jnp.bfloat16) == (512, 512, 8192)
     assert pallas_attention.flash_blocks(8192, 8192, 128, jnp.bfloat16) == (

@@ -52,12 +52,12 @@ def test_flash_kernel_matches_oracle(causal):
     _assert_forward(*_qkv(), causal)
 
 
-# (Sq, Sk, H) -> the tile the rule picks at float32, head_dim 128: one tile,
-# several tiles of one size, a q and a k tile of different sizes, and the
-# narrow tile on one axis only
+# (Sq, Sk, H) -> the tile the rule picks at float32, head_dim 128: one tile
+# (of two pieces, of eight), several tiles of one size, a q and a k tile of
+# different sizes, and the narrow tile on one axis only
 _RULE_SHAPES = {
     (256, 256, 2): (256, 256),
-    (1024, 1024, 1): (512, 1024),
+    (1024, 1024, 1): (1024, 1024),
     (384, 384, 1): (128, 128),
     (128, 256, 2): (128, 256),
     (512, 1536, 1): (512, 512),
@@ -275,12 +275,32 @@ def test_flash_blocks_short_and_rectangular_shapes_keep_their_tiles():
 
 
 def test_flash_blocks_shrink_the_q_tile_first_under_the_vmem_budget():
-    # float32 tiles of 1024 x 1024 do not fit; the k tile stays wide (the
-    # sweep: a wide k tile is worth more than a tall q tile)
-    assert pa.flash_vmem_bytes(1024, 1024, 128, 4) > pa.VMEM_BUDGET
-    assert flash_blocks(2048, 2048, 128, jnp.float32) == (512, 1024)
-    assert flash_blocks(4096, 4096, 512, jnp.float32) == (256, 1024)
-    assert pa.flash_vmem_bytes(256, 1024, 512, 4) <= pa.VMEM_BUDGET
+    # the score tile is in pieces of FWD_PIECE_ROWS k rows, so what fills
+    # the budget is the operands' blocks: float32 tiles of 1024 x 1024 fit
+    # at a head of 128 and of 256, not of 512; the k tile stays wide
+    assert pa.flash_vmem_bytes(1024, 1024, 128, 4) <= pa.VMEM_BUDGET
+    assert flash_blocks(2048, 2048, 128, jnp.float32) == (1024, 1024)
+    assert pa.flash_vmem_bytes(1024, 1024, 512, 4) > pa.VMEM_BUDGET
+    assert flash_blocks(2048, 2048, 512, jnp.float32) == (512, 1024)
+    assert pa.flash_vmem_bytes(512, 1024, 512, 4) <= pa.VMEM_BUDGET
+    # the q tile first, down to MIN_BLOCK, then the k tile
+    assert flash_blocks(4096, 4096, 1024, jnp.bfloat16) == (256, 1024)
+    assert flash_blocks(4096, 4096, 1024, jnp.float32) == (128, 512)
+
+
+def test_flash_vmem_bytes_counts_the_pieces_not_the_score_tile():
+    """Two pieces of ``FWD_PIECE_ROWS`` k rows are in flight, whatever the k
+    tile: doubling it adds its k and v blocks alone; the state is rows."""
+    def count(bq, bk, D=128, itemsize=2):
+        return pa.flash_vmem_bytes(bq, bk, D, itemsize)
+    assert pa.FWD_PIECE_ROWS == pa.MIN_BLOCK == pa.fwd_piece_rows(1024)
+    assert count(1024, 1024) - count(1024, 512) == 2 * 2 * 512 * 128 * 2
+    io = 2 * 4 * 1024 * 128 * 2 + 2 * 8 * 1024 * 4
+    pieces = 2 * 128 * 1024 * (4 + 4 + 2)
+    scratch = 2 * 1024 * 128 * 4 + 2 * 8 * 1024 * 4
+    assert count(1024, 1024) == io + pieces + scratch == 5898240
+    # glm-4.7-flash.s8192's head of 256 at the cells' tile
+    assert count(1024, 1024, 256) == 9043968 <= pa.VMEM_BUDGET
 
 
 @pytest.mark.parametrize("Sq,Sk", [(100, 128), (128, 192), (64, 64)])
@@ -523,8 +543,9 @@ def test_flash_tiles_at_every_cell_s_shape(cell):
     assert pa.flash_bwd_vmem_bytes(*blocks, D, 2) <= pa.BWD_VMEM_BUDGET
 
 
-# -- the forward in the backward's form (PR 50): operands in place as
-# [B, S, heads * D], a diagonal or edge tile in bands of DIAG_ROWS q rows ----
+# -- the forward in the backward's form (PR 50, PR 56): operands in place as
+# [B, S, heads * D], scores transposed and the softmax state along the lanes,
+# every tile in pieces of FWD_PIECE_ROWS k rows ------------------------------
 
 def _heads(B, S, H, Hkv, D, seed=50):
     rng = np.random.RandomState(seed)
@@ -552,9 +573,8 @@ def _banded_lse(q, k, window, scale):
 #: diagonal, on a window's edge (the window a multiple of the tile) and
 #: whole where the band's edge cuts a tile anywhere else or the tile is not
 #: square; grouped heads as the share cell's 28 / 4; a head of two lane
-#: tiles; a head of 64 (heads first). Bands of 128 rows, so that a tile of
-#: 256 has two and one of 512 four; the last two at ``FWD_DIAG_ROWS`` as it
-#: is: two bands a 1024 x 1024 tile
+#: tiles; a head of 64 (heads first). Pieces of 128 k rows: a tile of 256
+#: has two, one of 512 four and the cells' 1024 x 1024 eight
 _BANDED = {
     "causal": (1, 512, 2, 2, 128, 256, 256, None),
     "causal, four bands a tile": (1, 512, 1, 1, 128, 512, 512, None),
@@ -583,19 +603,23 @@ _BANDED = {
                                             None),
     "window 512 in tiles of 512, a group of 8":
         (1, 1536, 8, 1, 128, 512, 512, 512),
+    # SmallThinker's window: an edge tile and four whole ones a row of tiles
+    # under it, in eight pieces each; and with a q tile of half the k tile,
+    # where every crossed tile's pieces span all its q rows
+    "window 4096 at the cells' tile, a group of 2":
+        (1, 5120, 2, 1, 128, 1024, 1024, 4096),
+    "window 4096, a q tile of half the k tile":
+        (1, 5120, 1, 1, 128, 512, 1024, 4096),
 }
 
 
 @pytest.mark.parametrize("case", sorted(_BANDED))
-def test_flash_forward_in_place_and_banded_is_the_banded_form(case,
-                                                              monkeypatch):
+def test_flash_forward_in_place_and_banded_is_the_banded_form(case):
     """o, lse and the three gradients (of a loss that reads o and lse) of
     the kernels against ``_plain_attention`` / ``_banded_attention`` in
     float32, autodiff through it for the gradients."""
     B, S, H, Hkv, D, bq, bk, window = _BANDED[case]
-    if bq < 1024:
-        monkeypatch.setattr(pa, "FWD_DIAG_ROWS", 128)
-    assert (len(pa.tile_pieces(bq, bq)) > 1) == (bq >= 256)
+    assert len(pa.tile_pieces(bq, bk)) == bk // pa.FWD_PIECE_ROWS
     q, k, v = _heads(B, S, H, Hkv, D)
     scale = 1.0 / D ** 0.5
     w = jnp.cos(jnp.arange(q.size, dtype=jnp.float32).reshape(q.shape))
@@ -631,6 +655,57 @@ def test_flash_forward_in_place_and_banded_is_the_banded_form(case,
                                    atol=2e-4, err_msg=name)
 
 
+@pytest.mark.parametrize("D", [64, 128, 256])
+def test_flash_without_a_mask_at_every_head_width(D):
+    """Non-causal (a ring step's off-diagonal call): every tile runs all its
+    pieces unmasked; o, lse and, through the ``custom_vjp``, dq, dk, dv of a
+    loss that reads both, at groups of 2, two q tiles of 256 against a k
+    tile of four pieces."""
+    B, S, H, Hkv = 1, 512, 4, 2
+    q, k, v = _heads(B, S, H, Hkv, D, seed=56)
+    scale = 1.0 / D ** 0.5
+    w = jnp.cos(jnp.arange(q.size, dtype=jnp.float32).reshape(q.shape))
+    u = jnp.sin(jnp.arange(B * H * S, dtype=jnp.float32).reshape(B * H, S))
+
+    def flash(q, k, v):
+        return pa.flash_attention_with_lse(q, k, v, False, None, 256, 512,
+                                           interpret=True)
+
+    def reference(q, k, v):
+        s = jnp.einsum("bqhgd,bkhd->bhgqk",
+                       q.reshape(B, S, Hkv, H // Hkv, D), k) * scale
+        o = jnp.einsum("bhgqk,bkhd->bqhgd", jax.nn.softmax(s, -1), v)
+        return o.reshape(q.shape), jax.nn.logsumexp(s, -1).reshape(B * H, S)
+
+    def loss(f):
+        def total(q, k, v):
+            o, lse = f(q, k, v)
+            return jnp.sum(o * w) + jnp.sum(lse * u)
+        return total
+
+    for got, want in zip(flash(q, k, v), reference(q, k, v)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-5, atol=2e-5)
+    got = jax.grad(loss(flash), (0, 1, 2))(q, k, v)
+    want = jax.grad(loss(reference), (0, 1, 2))(q, k, v)
+    for g, r, name in zip(got, want, ("dq", "dk", "dv")):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), rtol=2e-4,
+                                   atol=2e-4, err_msg=name)
+
+
+def test_the_forward_s_state_lies_along_the_lanes():
+    """The kernel's scratch: the accumulator ``[D, block_q]`` and ``m``,
+    ``l`` as ``[1, block_q]`` rows; no ``[block_q, 1]`` column is left."""
+    q = jax.ShapeDtypeStruct((1, 512, 2, 128), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(lambda q, k, v: pa._flash_fwd_impl(
+        q, k, v, True, 0.1, 256, 256, False))(q, q, q)
+    call = next(e for e in jaxpr.eqns if str(e.primitive) == "pallas_call")
+    kernel = call.params["jaxpr"]
+    scratch = [tuple(x.aval.shape) for x in kernel.invars[-3:]]
+    assert scratch == [(128, 256), (1, 256), (1, 256)], scratch
+    assert "hvd_flash_attention" in str(jaxpr)
+
+
 @pytest.mark.parametrize("shape", [(1, 512, 4, 4, 128), (2, 256, 4, 2, 128),
                                    (1, 256, 2, 2, 256)])
 def test_flash_forward_transposes_nothing_at_a_head_of_whole_lane_tiles(
@@ -662,46 +737,77 @@ def test_flash_forward_goes_heads_first_at_a_head_of_64():
     assert sum(str(e.primitive) == "transpose" for e in jaxpr.eqns) == 4
 
 
-def _live(r, c, edge):
-    """Whether score (q row r, k column c) of a square tile whose corners
-    lie on the diagonal (or, ``edge``, on a window's lower edge) is live."""
-    return c > r if edge else c <= r
+def _live(r, c, crossed):
+    """Whether score (q row r, k row c) of a square tile whose corners lie
+    on the diagonal (or on a window's lower ``"edge"``) is live."""
+    return c > r if crossed == "edge" else c <= r
 
 
-@pytest.mark.parametrize("edge", [False, True])
+@pytest.mark.parametrize("crossed", ["diagonal", "edge"])
 @pytest.mark.parametrize("tile", [128, 256, 512, 1024])
 def test_tile_pieces_cover_every_live_score_once_and_no_dead_block(tile,
-                                                                   edge):
-    """The pieces of a diagonal tile are 48 of a 1024 x 1024 tile's 64
-    blocks of 128 x 128 (36 of them hold a live score; bands of 128 rows
-    would run those alone and cost more, ``FWD_DIAG_ROWS``) and of an edge
-    tile the mirror image; every live score lies in exactly one piece, and
-    every ``FWD_DIAG_ROWS`` x ``FWD_DIAG_ROWS`` block a piece holds has a
-    live score."""
-    pieces = pa.tile_pieces(tile, tile, edge)
-    rows = pa.fwd_band_rows(tile)
-    assert rows == {128: 128, 256: 128, 512: 128, 1024: 512}[tile]
-    bands, unit = tile // rows, (rows // pa.MIN_BLOCK) ** 2
-    assert pa.tile_piece_blocks(tile, tile, edge) == (
-        unit * bands * (bands + 1) // 2, (tile // pa.MIN_BLOCK) ** 2)
+                                                                   crossed):
+    """The pieces of a diagonal tile are ``FWD_PIECE_ROWS`` k rows against
+    the q rows from the piece's first k row on: 36 of a 1024 x 1024 tile's
+    64 blocks of 128 x 128, the ones that hold a live score, in eight
+    pieces (the parent's two bands of 512 q rows ran 48); of an edge tile
+    the mirror image. Every live score lies in exactly one piece, and
+    every block a piece holds has a live score."""
+    pieces = pa.tile_pieces(tile, tile, crossed)
+    rows = pa.fwd_piece_rows(tile)
+    assert rows == 128 == pa.FWD_PIECE_ROWS
+    n = tile // rows
+    assert len(pieces) == n
+    assert pa.tile_piece_blocks(tile, tile, crossed) == (
+        n * (n + 1) // 2, n * n)
     if tile == 1024:
-        assert pa.tile_piece_blocks(tile, tile, edge) == (48, 64)
-        assert len(pieces) == 2
-    covered = np.zeros((tile, tile), int)
-    for r0, n, c0, cols in pieces:
-        assert n == rows and cols % rows == 0
-        covered[r0:r0 + n, c0:c0 + cols] += 1
+        assert pa.tile_piece_blocks(tile, tile, crossed) == (36, 64)
+    covered = np.zeros((tile, tile), int)       # [q row, k row]
+    for k0, n_k, q0, q1 in pieces:
+        assert n_k == rows and k0 % rows == q0 % rows == q1 % rows == 0
+        covered[q0:q1, k0:k0 + n_k] += 1
     r, c = np.mgrid[:tile, :tile]
-    live = _live(r, c, edge)
+    live = _live(r, c, crossed)
     assert covered.max() == 1 and (covered[live] == 1).all()
-    blocks = covered.reshape(bands, rows, bands, rows).max((1, 3)) > 0
-    assert (blocks == live.reshape(bands, rows, bands, rows).any(
-        (1, 3))).all()
+    blocks = covered.reshape(n, rows, n, rows).max((1, 3)) > 0
+    assert (blocks == live.reshape(n, rows, n, rows).any((1, 3))).all()
     # the edge's pieces are the diagonal's, mirrored in both axes
-    mirror = sorted((tile - r0 - n, n, tile - c0 - cols, cols)
-                    for r0, n, c0, cols in pa.tile_pieces(tile, tile,
-                                                          not edge))
+    other = "edge" if crossed == "diagonal" else "diagonal"
+    mirror = sorted((tile - k0 - n_k, n_k, tile - q1, tile - q0)
+                    for k0, n_k, q0, q1 in pa.tile_pieces(tile, tile, other))
     assert sorted(pieces) == mirror
+
+
+@pytest.mark.parametrize("tile", [(128, 128), (512, 512), (1024, 1024),
+                                  (512, 1024), (1024, 256)])
+def test_tile_pieces_of_a_tile_no_line_crosses_corner_to_corner(tile):
+    """A tile inside the band, or one the mask cuts anywhere else (a tile
+    that is not square, a window that is no multiple of it): every piece
+    spans all the q rows, the k rows once each."""
+    bq, bk = tile
+    pieces = pa.tile_pieces(bq, bk)
+    assert pieces == [(k0, 128, 0, bq) for k0 in range(0, bk, 128)]
+    assert pa.tile_piece_blocks(bq, bk) == (bq * bk // 128 ** 2,) * 2
+
+
+@pytest.mark.parametrize("tile", [256, 512, 1024])
+def test_an_edge_piece_ends_on_a_q_row_with_no_live_key(tile):
+    """The ``NEG_INF`` / ``alpha = 0`` case: the last q row of an edge
+    tile's piece sees none of the piece's k rows (``c > r`` fails for all
+    of them), every other row does; on the diagonal every row of a piece
+    sees a key. The row's next piece, or its next tile's first, holds a live
+    key for it, so the ``exp(0)`` it gathered is wiped (the parity cases
+    under a window of whole tiles run it)."""
+    r, c = np.mgrid[:tile, :tile]
+    for crossed, dead in (("edge", 1), ("diagonal", 0)):
+        live = _live(r, c, crossed)
+        for k0, n_k, q0, q1 in pa.tile_pieces(tile, tile, crossed):
+            seen = live[q0:q1, k0:k0 + n_k].any(1)
+            assert (~seen).sum() == dead
+            if dead:
+                assert not seen[-1] and q1 == k0 + n_k
+                later = live[q1 - 1, k0 + n_k:]
+                assert later.all() and (later.size or k0 + n_k == tile)
 
 
 @pytest.mark.parametrize("tiles", [
@@ -908,11 +1014,12 @@ _RULE_BANDED = {
     "a window wider than the sequence":
         (1, 512, 2, 1, 128, 1024, jnp.float32, (512, 512), 1, 1),
     # SmallThinker's group and tile: 2 x 1024 < S, so the last q tile's band
-    # starts past the first k tile (float32 operands halve the q tile)
+    # starts past the first k tile
     "a window of one tile, a group of 7":
         (1, 3072, 7, 1, 128, 1024, jnp.bfloat16, (1024, 1024), 2, 2),
+    # (float32 at a head of 512 halves the q tile)
     "a window of one k tile under a q tile of half":
-        (1, 3072, 2, 1, 128, 1024, jnp.float32, (512, 1024), 2, 4),
+        (1, 3072, 2, 1, 512, 1024, jnp.float32, (512, 1024), 2, 5),
     # three of five q tiles have a band the sequence's start cuts
     "two tiles and a half, the first q tiles cut":
         (1, 1280, 2, 2, 128, 640, jnp.float32, (256, 256), 4, 4),
@@ -1014,8 +1121,9 @@ def test_chip_smoke_s_attention_path_prints_the_band_s_grid_and_tiles():
     laguna = chip_smoke._flash_call((1, 8192, 64, 128), 8, 512)
     assert laguna.startswith(
         "pallas hvd_flash_attention 512x512, 2048 steps, operands in place "
-        "[1, 8192, 8192], a tile on the diagonal 10 of 16 blocks in 4 "
-        "pieces, on the band's edge 10 of 16 blocks in 4 pieces; "
+        "[1, 8192, 8192], scores [k, q] with m, l [1, 512] and acc [128, "
+        "512] along the lanes, a tile in 4 pieces of 128 k rows, on the "
+        "diagonal 10 of 16 blocks, on the band's edge 10 of 16 blocks; "
         "hvd_flash_bwd 512x512, dq resident, 2048 steps, "), laguna
     assert laguna.endswith(
         "; window 512: forward 32 steps and 31 of 136 causal tiles a head, "
@@ -1030,4 +1138,9 @@ def test_chip_smoke_s_attention_path_prints_the_band_s_grid_and_tiles():
         "4 on the edge; kv heads 4, group 7"), share
     full = chip_smoke._flash_call((1, 8192, 48, 128), 8)
     assert "hvd_flash_attention 1024x1024, 3072 steps" in full
+    assert ("acc [128, 1024] along the lanes, a tile in 8 pieces of 128 k "
+            "rows, on the diagonal 36 of 64 blocks; hvd_flash_bwd") in full
+    narrow = chip_smoke._flash_call((1, 4096, 32, 64), 8)
+    assert "operands heads first [32, 4096, 64], scores [k, q] with m, l " \
+        "[1, 1024] and acc [64, 1024] along the lanes" in narrow
     assert "window" not in full and full.endswith("kv heads 8, group 6")

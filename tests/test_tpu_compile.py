@@ -489,7 +489,7 @@ def test_head_touches_the_logits_once(cell, v5e, no_compile_cache,
 #: a checkpointed attention block, forward and backward, at a cell's real
 #: widths: (TransformerConfig fields, the block's stack, positions).
 #: glm-4.7-flash.s8192's latent block (20 heads of 256: ``flash_vmem_bytes``
-#: of its 1024 x 1024 tile is ``VMEM_BUDGET`` to the byte) and
+#: of its 1024 x 1024 tile is 8.6 MiB of ``VMEM_BUDGET``'s 16) and
 #: ouro-2.6b.s4096's plain one (16 heads of 128)
 _ATTENTION_BLOCKS = {
     "latent block, 20 heads of 256": (dict(
