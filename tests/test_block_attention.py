@@ -62,8 +62,8 @@ def test_block_kernels_match_the_xla_core(S, D, dtype, padded):
     out = kernel(q, k, v)
     assert out.dtype == dtype and out.shape == q.shape
     _close(out, core(*f32), tol, "o")
-    got = jax.grad(_loss(kernel, w), (0, 1, 2))(q, k, v)
-    want = jax.grad(_loss(core, w), (0, 1, 2))(*f32)
+    got = jax.jit(jax.grad(_loss(kernel, w), (0, 1, 2)))(q, k, v)
+    want = jax.jit(jax.grad(_loss(core, w), (0, 1, 2)))(*f32)
     for g, r, name in zip(got, want, ("dq", "dk", "dv")):
         assert g.dtype == dtype
         _close(g, r, tol * max(1.0, float(jnp.max(jnp.abs(r)))), name)
@@ -83,8 +83,8 @@ def test_block_kernels_without_a_mask_are_plain_attention():
     def plain(q, k, v):
         return _plain_attention(q, k, v, causal=False)
     _close(kernel(q, k, v), plain(q, k, v), 2e-5, "o")
-    for g, r in zip(jax.grad(_loss(kernel, w), (0, 1, 2))(q, k, v),
-                    jax.grad(_loss(plain, w), (0, 1, 2))(q, k, v)):
+    for g, r in zip(jax.jit(jax.grad(_loss(kernel, w), (0, 1, 2)))(q, k, v),
+                    jax.jit(jax.grad(_loss(plain, w), (0, 1, 2)))(q, k, v)):
         _close(g, r, 2e-5, "grad")
 
 
@@ -111,8 +111,8 @@ def test_rectangular_scores():
     def core(q, k, v):
         return pa._key_masked_attention(q, k, v, mask)
     _close(kernel(q, k, v), core(q, k, v), 2e-5, "o")
-    for g, r in zip(jax.grad(_loss(kernel, w), (0, 1, 2))(q, k, v),
-                    jax.grad(_loss(core, w), (0, 1, 2))(q, k, v)):
+    for g, r in zip(jax.jit(jax.grad(_loss(kernel, w), (0, 1, 2)))(q, k, v),
+                    jax.jit(jax.grad(_loss(core, w), (0, 1, 2)))(q, k, v)):
         _close(g, r, 2e-4, "grad")
 
 

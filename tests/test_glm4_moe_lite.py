@@ -13,128 +13,24 @@ terms must do (``test_a_wrong_term_fails``).
 """
 
 import dataclasses
-import json
-import os
-import sys
 
 import numpy as np
 import pytest
 import jax
 import jax.numpy as jnp
 
-from horovod_tpu.models import decode, latent
+import arch
+from arch import TOL, get_leaves, rel as _rel
+from horovod_tpu.models import latent
 from horovod_tpu.models import transformer as t
-from horovod_tpu.models import shard_batch, shard_params
 from horovod_tpu.models._kinds import rmsnorm, rope
 from horovod_tpu.ops import pallas_attention
 from horovod_tpu.parallel import build_mesh
 
-_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-_CHIP = os.path.join(_REPO, "benchmarks", "chip")
-if _CHIP not in sys.path:
-    sys.path.insert(0, _CHIP)
-
-from adapters import glm4_moe_lite as adapter            # noqa: E402
-from reference import glm4_moe_lite as reference         # noqa: E402
-from trees import get_leaves                              # noqa: E402
-
-TOL = 1e-4
-
-
-def _cell(tiny: bool):
-    with open(os.path.join(_CHIP, "configs", "glm-4.7-flash.json")) as f:
-        config = json.load(f)
-    with open(os.path.join(_CHIP, "workloads",
-                           "train.s8192.b1.latent.json")) as f:
-        job = json.load(f)
-    if tiny:
-        config, job = {**config, **config["tiny"]}, {**job, **job["tiny"]}
-    return config, job
-
-
-CONFIG, JOB = _cell(tiny=True)
-SIZES = adapter.shapes(CONFIG, JOB)
-CFG = adapter._model_config(CONFIG, JOB)
-LEAVES = {
-    **adapter._leaf_paths(SIZES["expert_layers"]),
-    "embed": (("embed",), None),
-    "final_norm": (("ln_f",), None),
-    "query_norm": (("layers", "latent", "q_latent_norm"), (0, 0)),
-    "kv_norm": (("layers", "latent", "kv_latent_norm"), (0, 1)),
-    "query_up": (("layers", "latent", "wqb"), (0, 0)),
-    "attention_out": (("lead", "latent", "wo"), (0,)),
-    "dense_gate": (("lead", "dense", "w1"), (0,)),
-    "dense_up": (("lead", "dense", "w3"), (0,)),
-    "first_router": (("layers", "experts", "router"), (0, 0)),
-    "expert_gate": (("layers", "experts", "we1"), (0, 1, 1)),
-    "expert_up": (("layers", "experts", "we3"), (0, 0, 0)),
-    "shared_gate": (("layers", "experts", "ws1"), (0, 1)),
-    "shared_up": (("layers", "experts", "ws3"), (0, 0)),
-    "mtp_state_norm": (("mtp", "norm_h"), None),
-    "mtp_token_norm": (("mtp", "norm_e"), None),
-    "mtp_final_norm": (("mtp", "ln_f"), None),
-    "mtp_kv_down": (("mtp", "layers", "latent", "wkva"), (0,)),
-    "mtp_router": (("mtp", "layers", "experts", "router"), (0,)),
-    "mtp_experts_down": (("mtp", "layers", "experts", "we2"), (0,)),
-}
-
-
-def _params(cfg=CFG, seed=0):
-    """``init_params``' tree with every norm's weight moved off 1 (a norm
-    after a norm is no change while both weights are 1: what the prediction
-    module reads would not show)."""
-    rng = np.random.RandomState(seed + 100)
-    return jax.tree_util.tree_map(
-        lambda a: jnp.asarray(
-            1 + 0.3 * rng.randn(*a.shape).astype(np.float32)
-            if np.all(a == 1) else a),
-        t.init_params(np.random.RandomState(seed), cfg, 1))
-
-
-def _batch(n_seqs=2, seed=0):
-    return jax.tree_util.tree_map(
-        jnp.asarray, adapter.host_batch(CONFIG, JOB, seed, 0, n_seqs))
-
-
-def _rel(got, want):
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    return np.linalg.norm(got - want) / (np.linalg.norm(want) or 1.0)
-
-
-def _program(cfg, params, batch):
-    """(loss, aux, gradients) on a mesh of one device, through
-    ``make_grad_fn`` as the benchmark's adapter calls it."""
-    mesh = build_mesh(devices=jax.devices()[:1], dp=1)
-    p = shard_params(params, cfg, mesh)
-    tok, tgt = shard_batch(batch["tokens"], batch["targets"], mesh)
-    loss, aux, grads = jax.jit(t.make_grad_fn(cfg, mesh))(p, tok, tgt)
-    return loss + aux["aux_loss"], aux, grads
-
-
-def _plain_grads(cfg, params, batch):
-    """Loss and gradients with no mesh (a tree that holds a leaf ``cfg``
-    does not read is no error here)."""
-    def loss_fn(p):
-        loss, aux = t.forward_loss_spmd(p, batch["tokens"],
-                                        batch["targets"], cfg)
-        return loss + aux["aux_loss"]
-    return jax.jit(jax.value_and_grad(loss_fn))(params)
-
-
-def test_the_tiny_preset_is_the_one_the_issue_asks_for():
-    assert CFG.dtype == jnp.float32 and CFG.one_sublayer
-    assert CFG.layer_pattern == (("latent",), ("experts",))
-    assert CFG.lead_pattern == (("latent",), ("dense",))
-    assert (CFG.n_layers, CFG.mtp_depth, CFG.mtp_weight) == (4, 1, 0.3)
-    assert (CFG.n_heads, CFG.kv_heads, CFG.head_dim, CFG.rope_width,
-            CFG.q_latent, CFG.kv_latent) == (4, 4, 16, 4, 24, 16)
-    assert (CFG.d_ff, CFG.dense_ff, CFG.moe_shared_width) == (32, 96, 32)
-    assert (CFG.n_experts, CFG.moe_top_k, CFG.held_experts,
-            CFG.expert_share) == (16, 4, 2, (0, 8))
-    assert (CFG.moe_router_scores, CFG.moe_activation, CFG.moe_gated,
-            CFG.ffn_gated, CFG.moe_routed_scale, CFG.moe_renormalize,
-            CFG.moe_balance_weight, CFG.tie_embeddings) == (
-                "sigmoid", "silu", True, True, 1.8, True, 0.0, False)
+ARCH = arch.get("glm4_moe_lite")
+adapter, reference = ARCH.adapter, ARCH.reference
+SIZES, CFG, LEAVES = ARCH.SIZES, ARCH.CFG, ARCH.LEAVES
+_cell, _params, _batch = ARCH.cell, ARCH.params, ARCH.batch
 
 
 def test_the_cell_keeps_every_published_width():
@@ -157,18 +53,13 @@ def test_the_cell_keeps_every_published_width():
     assert config["reduced_from"] == {
         "num_hidden_layers": 47, "n_routed_experts": 64,
         "vocab_size": 154880}
-    n = sum(int(np.prod(a.shape)) for a in jax.tree_util.tree_leaves(
-        jax.eval_shape(adapter._init_function(cfg, config),
-                       jax.random.PRNGKey(0))))
+    n = arch.count(arch.drawn_shapes(adapter, cfg, config))
     assert 706e6 < n < 707e6, n      # the deployment's 706.5 M parameters
     # the adapter's tree is init_params' tree
-    want = jax.eval_shape(lambda: t.init_params(
-        np.random.RandomState(0), dataclasses.replace(cfg, vocab_size=8)))
-    got = jax.eval_shape(adapter._init_function(
-        dataclasses.replace(cfg, vocab_size=8), config),
-        jax.random.PRNGKey(0))
-    assert jax.tree_util.tree_map(lambda a: a.shape, got) == \
-        jax.tree_util.tree_map(lambda a: a.shape, want)
+    small = dataclasses.replace(cfg, vocab_size=8, d_model=16, dense_ff=8,
+                                d_ff=8, moe_shared_width=8, head_width=16,
+                                rope_width=4, q_latent=8, kv_latent=8)
+    arch.assert_the_adapter_s_tree_is_init_params(adapter, small, config)
 
 
 def test_the_step_s_required_flops_by_hand():
@@ -238,31 +129,15 @@ def test_the_flash_tiles_at_the_cell_s_head_width():
 
 # -- the program against the reference ----------------------------------------
 
-@pytest.fixture(scope="module")
-def both_sides():
-    params, batch = _params(), _batch()
-    loss, aux, grads = _program(CFG, params, batch)
-    got = {"loss": loss, "main_loss": aux["main_loss"],
-           "mtp_loss": aux["mtp_loss"],
-           **{f"grad:{k}": v for k, v in get_leaves(grads, LEAVES).items()}}
-    want_loss, want_grads = reference.loss_and_grads(params, LEAVES, batch,
-                                                     SIZES)
-    with jax.default_matmul_precision("highest"):
-        parts = reference.losses(params, batch, SIZES)
-    want = {"loss": want_loss, "main_loss": parts[1], "mtp_loss": parts[5],
-            **{f"grad:{k}": v for k, v in want_grads.items()}}
-    return got, want, aux, grads
-
-
 @pytest.mark.parametrize("what", ["loss", "main_loss", "mtp_loss"]
                          + [f"grad:{k}" for k in LEAVES])
-def test_program_matches_the_reference(both_sides, what):
-    got, want, _aux, _grads = both_sides
+def test_program_matches_the_reference(what):
+    got, want, _aux, _grads = ARCH.sides
     assert _rel(got[what], want[what]) < TOL, what
 
 
-def test_the_loss_is_its_two_parts_and_the_step_reports_its_rows(both_sides):
-    got, _want, aux, grads = both_sides
+def test_the_loss_is_its_two_parts_and_the_step_reports_its_rows():
+    got, _want, aux, grads = ARCH.sides
     assert set(aux) == {"aux_loss", "load_balance_loss", "router_z_loss",
                         "max_expert_load", "dropped", "held_rows",
                         "main_loss", "mtp_loss"}
@@ -313,10 +188,7 @@ def test_the_rope_key_is_one_head_that_every_query_head_reads():
 # -- what TOL must not let through --------------------------------------------
 
 #: layer 0 dense, one expert layer, the prediction module
-SMALL_CONFIG = {**CONFIG, "num_hidden_layers": 2}
-SMALL = adapter._model_config(SMALL_CONFIG, JOB)
-SMALL_SIZES = adapter.shapes(SMALL_CONFIG, JOB)
-SMALL_LEAVES = {
+SMALL = ARCH.cut({"num_hidden_layers": 2}, {
     "lm_head": (("lm_head",), None),
     "embed": (("embed",), None),
     "first_query_down": (("lead", "latent", "wqa"), (0,)),
@@ -326,7 +198,7 @@ SMALL_LEAVES = {
     "router": (("layers", "experts", "router"), (0, 0)),
     "shared_down": (("layers", "experts", "ws2"), (0, 0)),
     "mtp_proj": (("mtp", "proj"), None),
-}
+})
 
 
 def _merged(a, b):
@@ -337,28 +209,17 @@ def _merged(a, b):
                     for k, v in a.items()}}
 
 
-@pytest.fixture(scope="module")
-def small_reference():
-    params, batch = _params(SMALL), _batch()
-    want_loss, want = reference.loss_and_grads(params, SMALL_LEAVES, batch,
-                                               SMALL_SIZES)
-    return params, batch, want_loss, want
-
-
-def _small_error(cfg, small_reference):
-    params, batch, want_loss, want = small_reference
-    if cfg.lead_pattern != SMALL.lead_pattern \
-            or cfg.layer_pattern != SMALL.layer_pattern:
+def _small_error(what, cfg):
+    tree = None
+    if cfg.lead_pattern != SMALL.CFG.lead_pattern \
+            or cfg.layer_pattern != SMALL.CFG.layer_pattern:
         # the blocks the sound tree lacks, drawn for this stack
-        params = _merged(params, _params(cfg, seed=1))
-    loss, grads = _plain_grads(cfg, params, batch)
-    return max([_rel(loss, want_loss)] + [
-        _rel(v, want[k])
-        for k, v in get_leaves(grads, SMALL_LEAVES).items()])
+        tree = _merged(SMALL.kept()[0], SMALL.params(cfg, seed=1))
+    return SMALL.error(what, cfg, tree)
 
 
-def test_the_sound_small_stack_matches_the_reference(small_reference):
-    assert _small_error(SMALL, small_reference) < TOL
+def test_the_sound_small_stack_matches_the_reference():
+    assert SMALL.sound < TOL
 
 
 _sound_rotate, _sound_down = latent._rotate, latent._down
@@ -394,7 +255,7 @@ def _the_rope_key_normed(p, h, cfg):
 
 def _scale_of_the_position_free_part(q, k, v, causal=True, scale=None,
                                      **kw):
-    nope = SMALL.head_dim - SMALL.rope_width
+    nope = SMALL.CFG.head_dim - SMALL.CFG.rope_width
     return _sound_attend(q, k, v, causal=causal, scale=nope ** -0.5, **kw)
 
 
@@ -465,14 +326,14 @@ def _a_head_of_its_own(params, state, targets, head, positions, cfg):
     ("a head of its own in the prediction module",
      {"patch": (t, "_mtp_loss", _a_head_of_its_own)}),
 ])
-def test_a_wrong_term_fails(monkeypatch, small_reference, what, change):
+def test_a_wrong_term_fails(monkeypatch, what, change):
     """Each moves the loss or a named gradient of a stack of the dense
     layer, one expert layer and the prediction module far beyond TOL."""
     for key in ("patch", "also"):
         if key in change:
             monkeypatch.setattr(*change[key])
-    cfg = dataclasses.replace(SMALL, **change.get("cfg", {}))
-    err = _small_error(cfg, small_reference)
+    cfg = dataclasses.replace(SMALL.CFG, **change.get("cfg", {}))
+    err = _small_error(what, cfg)
     assert err > 5 * TOL, (what, err)
 
 
@@ -492,65 +353,22 @@ def _second_head_cut(params, state, targets, head, positions, cfg):
 @pytest.mark.parametrize("leaf, patch", [
     ("embed", ("_mtp_input", _second_lookup_cut)),
     ("lm_head", ("_mtp_loss", _second_head_cut))])
-def test_a_gradient_is_the_sum_of_its_two_uses(monkeypatch, small_reference,
-                                               leaf, patch):
+def test_a_gradient_is_the_sum_of_its_two_uses(monkeypatch, leaf, patch):
     """The embedding is read for the tokens and again for the next tokens,
     the head by both predictions: the program's gradient of each is the
     reference's (``jax.grad`` through both uses), and with the second use's
     cotangent cut it is far from it, while every other named gradient is
     what it was."""
-    params, batch, _want_loss, want = small_reference
-    _loss, grads = _plain_grads(SMALL, params, batch)
-    assert _rel(grads[leaf], want[leaf]) < TOL
+    params, batch, want = SMALL.kept()
+    _loss, grads = SMALL.sound_grads
+    assert _rel(grads[leaf], want[f"grad:{leaf}"]) < TOL
     monkeypatch.setattr(t, *patch)
-    _loss, cut = _plain_grads(SMALL, params, batch)
-    assert _rel(cut[leaf], want[leaf]) > 50 * TOL
-    others = {name: spec for name, spec in SMALL_LEAVES.items()
+    _loss, cut = SMALL.plain(SMALL.CFG, params, batch)
+    assert _rel(cut[leaf], want[f"grad:{leaf}"]) > 50 * TOL
+    others = {name: spec for name, spec in SMALL.LEAVES.items()
               if name not in ("embed", "lm_head")}
     for name, got in get_leaves(cut, others).items():
-        assert _rel(got, want[name]) < TOL, name
-
-
-# -- the share cut: one expert layer ------------------------------------------
-
-def test_the_eight_shares_add_up_to_the_uncut_layer():
-    """model-configs guide, section 4: the routed parts that the eight
-    shares compute and the shared expert counted ONCE are what the uncut
-    reference gives for the whole layer; between them the shares hold
-    every assignment once."""
-    cfg = dataclasses.replace(CFG, expert_share=(0, 1))
-    rng = np.random.RandomState(0)
-    m, f, fs, e = cfg.d_model, cfg.d_ff, cfg.moe_shared_width, cfg.n_experts
-    h = jnp.asarray(rng.randn(1, 96, m), jnp.float32)
-
-    def w(*shape, scale=1 / 8):
-        return jnp.asarray(rng.randn(*shape) * scale, jnp.float32)
-    p = {"router": w(m, e, scale=0.3), "router_bias": w(e, scale=0.1),
-         "we1": w(e, m, f), "we3": w(e, m, f), "we2": w(e, f, m),
-         "ws1": w(m, fs), "ws3": w(m, fs), "ws2": w(fs, m)}
-    sizes = {**SIZES, "experts": e, "first_expert": 0, "held_experts": e}
-    with jax.default_matmul_precision("highest"):
-        want, _choice = reference.expert_layer(p, h[0], sizes)
-        shared = want - reference.expert_layer(p, h[0], sizes,
-                                               shared=False)[0]
-    parts, held_rows = [], []
-    for i in range(8):
-        share = dataclasses.replace(cfg, expert_share=(i, 8))
-        held = {k: v[2 * i:2 * i + 2] if k in ("we1", "we2", "we3") else v
-                for k, v in p.items()}
-        y, aux = t._moe_ffn(held, h, share)
-        assert float(aux["dropped"]) == 0.0
-        parts.append(y[0])
-        held_rows.append(float(aux["held_rows"]))
-    routed = [part - shared for part in parts]
-    assert _rel(sum(routed) + shared, want) < TOL
-    assert sum(held_rows) == 96 * cfg.moe_top_k
-    # the shares' outputs summed count the shared expert eight times
-    assert _rel(sum(parts), want) > 1.0
-    # no share is the whole, and the layer that holds every expert is
-    assert _rel(routed[0] + shared, want) > 0.3
-    y, aux = t._moe_ffn(p, h, cfg)
-    assert _rel(y[0], want) < TOL and "held_rows" not in aux
+        assert _rel(got, want[f"grad:{name}"]) < TOL, name
 
 
 # -- what is refused, by name -------------------------------------------------
@@ -592,20 +410,3 @@ def test_a_looped_stack_with_the_new_fields_is_refused_by_name():
         dataclasses.replace(CFG, qk_norm=True)
 
 
-def test_the_decode_paths_refuse_the_new_fields_by_name():
-    params = _params()
-    for field, cfg in [
-            ("kv_latent", CFG), ("lead_pattern", CFG), ("mtp_depth", CFG),
-            ("mtp_depth", t.TransformerConfig(mtp_depth=1))]:
-        with pytest.raises(NotImplementedError, match=field):
-            decode.kv_cache_spec(cfg)
-        with pytest.raises(NotImplementedError, match=field):
-            decode.decode_step_paged(params, None, None, None, None, None,
-                                     None, cfg)
-        with pytest.raises(NotImplementedError, match=field):
-            decode.prefill_chunk_paged(params, None, None, None, None, None,
-                                       None, cfg)
-        with pytest.raises(NotImplementedError, match=field):
-            decode.reference_greedy_decode(params, cfg, [1, 2], 1)
-    with pytest.raises(NotImplementedError, match="dense GPT block"):
-        decode.flatten_decode_params(params)

@@ -13,134 +13,27 @@ read 2e-7 to 7e-7): 1e-4 is far below what the least of the wrong terms does
 """
 
 import dataclasses
-import json
 import math
-import os
-import sys
 
 import numpy as np
 import pytest
 import jax
 import jax.numpy as jnp
 
-from horovod_tpu.models import _kinds, decode
+import arch
+from arch import TOL, rel as _rel
+from horovod_tpu.models import _kinds
 from horovod_tpu.models import transformer as t
 from horovod_tpu.models import shard_batch, shard_params
 from horovod_tpu.models._kinds import Rope, Yarn
 from horovod_tpu.parallel import build_mesh
 from horovod_tpu.profiling import scopes
 
-_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-_CHIP = os.path.join(_REPO, "benchmarks", "chip")
-if _CHIP not in sys.path:
-    sys.path.insert(0, _CHIP)
-
-from adapters import laguna as adapter                   # noqa: E402
-from reference import laguna as reference                # noqa: E402
-from trees import get_leaves                              # noqa: E402
-
-TOL = 1e-4
-
-
-def _cell(tiny: bool):
-    with open(os.path.join(_CHIP, "configs", "laguna-xs.2.json")) as f:
-        config = json.load(f)
-    with open(os.path.join(_CHIP, "workloads",
-                           "train.s8192.b1.banded.json")) as f:
-        job = json.load(f)
-    if tiny:
-        config, job = {**config, **config["tiny"]}, {**job, **job["tiny"]}
-    return config, job
-
-
-CONFIG, JOB = _cell(tiny=True)
-SIZES = adapter.shapes(CONFIG, JOB)
-CFG = adapter._model_config(CONFIG, JOB)
+ARCH = arch.get("laguna")
+adapter, reference = ARCH.adapter, ARCH.reference
+CONFIG, CFG, LEAVES = ARCH.CONFIG, ARCH.CFG, ARCH.LEAVES
+_cell, _params, _batch = ARCH.cell, ARCH.params, ARCH.batch
 WINDOW, FULL = "attention_8_gated", "attention_6_gated"
-LEAVES = {
-    **adapter._leaf_paths(CONFIG),
-    "embed": (("embed",), None),
-    "final_norm": (("ln_f",), None),
-    "lead_gate": (("lead", FULL, "wg"), (0,)),
-    "lead_key": (("lead", FULL, "wk"), (0,)),
-    "lead_norm": (("lead", FULL, "ln1"), (0,)),
-    "dense_gate": (("lead", "dense", "w1"), (0,)),
-    "dense_up": (("lead", "dense", "w3"), (0,)),
-    "window_query": (("layers", WINDOW, "wq"), (0, 4)),
-    "window_value": (("layers", WINDOW, "wv"), (0, 5)),
-    "window_out": (("layers", WINDOW, "wo"), (0, 2)),
-    "second_window_gate": (("layers", WINDOW, "wg"), (0, 3)),
-    "full_key": (("layers", FULL, "wk"), (0, 0)),
-    "full_gate": (("layers", FULL, "wg"), (0, 1)),
-    "full_out": (("layers", FULL, "wo"), (0, 0)),
-    "first_router": (("layers", "experts", "router"), (0, 0)),
-    "expert_gate": (("layers", "experts", "we1"), (0, 3, 1)),
-    "expert_up": (("layers", "experts", "we3"), (0, 3, 0)),
-    "shared_gate": (("layers", "experts", "ws1"), (0, 1)),
-    "shared_up": (("layers", "experts", "ws3"), (0, 6)),
-    "shared_down": (("layers", "experts", "ws2"), (0, 7)),
-    "experts_norm": (("layers", "experts", "ln2"), (0, 2)),
-}
-
-
-def _params(cfg=CFG, seed=0):
-    """``init_params``' tree with every norm's weight moved off 1 and the
-    routers' correction bias off 0 (so that the choice is of score + bias)."""
-    rng = np.random.RandomState(seed + 100)
-
-    def moved(path, a):
-        if np.all(a == 1):
-            a = 1 + 0.3 * rng.randn(*a.shape).astype(np.float32)
-        if path[-1].key == "router_bias":
-            a = 0.1 * rng.randn(*a.shape).astype(np.float32)
-        return jnp.asarray(a)
-    return jax.tree_util.tree_map_with_path(
-        moved, t.init_params(np.random.RandomState(seed), cfg, 1))
-
-
-def _batch(n_seqs=2, seed=0):
-    return jax.tree_util.tree_map(
-        jnp.asarray, adapter.host_batch(CONFIG, JOB, seed, 0, n_seqs))
-
-
-def _rel(got, want):
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    return np.linalg.norm(got - want) / (np.linalg.norm(want) or 1.0)
-
-
-def _program(cfg, params, batch):
-    """(loss, aux, gradients) on a mesh of one device, through
-    ``make_grad_fn`` as the benchmark's adapter calls it."""
-    mesh = build_mesh(devices=jax.devices()[:1], dp=1)
-    p = shard_params(params, cfg, mesh)
-    tok, tgt = shard_batch(batch["tokens"], batch["targets"], mesh)
-    loss, aux, grads = jax.jit(t.make_grad_fn(cfg, mesh))(p, tok, tgt)
-    return loss + aux["aux_loss"], aux, grads
-
-
-def test_the_tiny_preset_is_the_one_the_issue_asks_for():
-    assert CFG.dtype == jnp.float32 and CFG.one_sublayer
-    window, experts, *_rest, full, _ = CFG.layer_pattern
-    assert CFG.layer_pattern == (window, experts) * 3 + (full, experts)
-    assert CFG.lead_pattern == (full, ("dense",))
-    assert experts == ("experts",) and CFG.n_layers == 16    # two periods
-    assert window[0] == full[0] == "attention"
-    assert (window[1], window[3:], full[1], full[3:]) == (
-        8, (8, True), None, (6, True))
-    assert JOB["seq_len"] == 64 > window[1]
-    assert window[2] == Rope(10000.0) and full[2] == Rope(
-        500000.0, 8, Yarn(64.0, 16, 64.0, 1.0, 1.4158883083359672))
-    # groups of 4 and 3 on the same two key/value heads
-    assert (CFG.n_heads, CFG.kv_heads, CFG.head_dim) == (6, 2, 16)
-    assert (CFG.d_ff, CFG.dense_ff, CFG.moe_shared_width) == (16, 64, 16)
-    assert (CFG.n_experts, CFG.moe_top_k, CFG.held_experts,
-            CFG.expert_share) == (16, 4, 2, (0, 8))
-    assert (CFG.moe_router_scores, CFG.moe_activation, CFG.moe_gated,
-            CFG.ffn_gated, CFG.moe_routed_scale, CFG.moe_renormalize,
-            CFG.moe_balance_weight, CFG.tie_embeddings, CFG.qk_norm) == (
-                "sigmoid", "silu", True, True, 2.5, True, 0.0, False, False)
-    assert SIZES["layer_heads"] == [6, 8, 8, 8, 6, 8, 8, 8, 6]
-    assert SIZES["layer_windows"] == [None, 8, 8, 8, None, 8, 8, 8, None]
 
 
 def test_the_cell_keeps_every_published_width():
@@ -175,9 +68,8 @@ def test_the_cell_keeps_every_published_width():
             sizes["d_expert"], sizes["dense_ff"]) == (8, 32, 0, 512, 8192)
     for reading in ("gate", "router", "blocks", "yarn", "rope_layout"):
         assert "no network here" in config["assumed"][reading], reading
-    shapes = jax.eval_shape(adapter._init_function(cfg, config),
-                            jax.random.PRNGKey(0))
-    n = sum(int(np.prod(a.shape)) for a in jax.tree_util.tree_leaves(shapes))
+    shapes = arch.drawn_shapes(adapter, cfg, config)
+    n = arch.count(shapes)
     assert 691.5e6 < n < 691.7e6, n     # the deployment's 691.6 M parameters
     full_block = sum(int(np.prod(a.shape[1:])) for a in
                      shapes["lead"]["attention_48_gated"].values())
@@ -188,12 +80,7 @@ def test_the_cell_keeps_every_published_width():
     # the adapter's tree is init_params' tree
     small = dataclasses.replace(cfg, vocab_size=8, d_model=16, dense_ff=8,
                                 d_ff=8, moe_shared_width=8, head_width=8)
-    want = jax.eval_shape(
-        lambda: t.init_params(np.random.RandomState(0), small))
-    got = jax.eval_shape(adapter._init_function(small, config),
-                         jax.random.PRNGKey(0))
-    assert jax.tree_util.tree_map(lambda a: a.shape, got) == \
-        jax.tree_util.tree_map(lambda a: a.shape, want)
+    arch.assert_the_adapter_s_tree_is_init_params(adapter, small, config)
 
 
 def test_the_step_s_required_flops_by_hand():
@@ -322,31 +209,17 @@ def test_a_table_of_the_kind_s_own_rotates_its_channels_only():
 
 # -- the program against the reference ----------------------------------------
 
-@pytest.fixture(scope="module")
-def both_sides():
-    params, batch = _params(), _batch()
-    loss, aux, grads = _program(CFG, params, batch)
-    got = {"loss": loss,
-           **{f"grad:{k}": v for k, v in get_leaves(grads, LEAVES).items()}}
-    want_loss, want_grads = reference.loss_and_grads(params, LEAVES, batch,
-                                                     SIZES)
-    want = {"loss": want_loss,
-            **{f"grad:{k}": v for k, v in want_grads.items()}}
-    return got, want, aux, grads
-
-
 @pytest.mark.parametrize("what", ["loss"] + [f"grad:{k}" for k in LEAVES])
-def test_program_matches_the_reference(both_sides, what):
-    got, want, _aux, _grads = both_sides
+def test_program_matches_the_reference(what):
+    got, want, _aux, _grads = ARCH.sides
     assert np.linalg.norm(np.asarray(want[what])) > 0
     assert _rel(got[what], want[what]) < TOL, what
 
-
-def test_every_gate_s_leaf_reaches_its_gradient(both_sides):
+def test_every_gate_s_leaf_reaches_its_gradient():
     """``wg`` of all nine attention blocks, both shapes: no block's gate is
     a constant, and no leaf of the tree is left without a gradient but the
     routers' correction bias, a buffer."""
-    _got, _want, aux, grads = both_sides
+    _got, _want, aux, grads = ARCH.sides
     for part, stack, heads, blocks in (("lead", FULL, 6, 1),
                                        ("layers", FULL, 6, 2),
                                        ("layers", WINDOW, 8, 6)):
@@ -359,16 +232,6 @@ def test_every_gate_s_leaf_reaches_its_gradient(both_sides):
     assert float(aux["dropped"]) == 0.0
     assert 0 < float(aux["held_rows"]) < 8 * 2 * 64 * 4
     assert float(aux["max_expert_load"]) >= 1.0
-
-
-def test_the_routers_choices_are_the_reference_s():
-    params, batch = _params(), _batch()
-    got = jax.jit(lambda p, tok: t.router_choices(p, tok, CFG))(
-        params, batch["tokens"])
-    with jax.default_matmul_precision("highest"):
-        want = reference.losses(params, batch, SIZES)[4]
-    assert got.shape == want.shape == (8, 2 * 64, 4)
-    np.testing.assert_array_equal(np.sort(got, -1), np.sort(want, -1))
 
 
 # -- each wrong reading of the equations fails --------------------------------
@@ -446,11 +309,30 @@ WRONG = [
 ]
 
 
-@pytest.fixture(scope="module")
-def wanted():
-    params, batch = _params(), _batch(n_seqs=1)
-    return (params, batch,
-            *reference.loss_and_grads(params, LEAVES, batch, SIZES))
+#: the leading full layer with its dense FFN, and one window layer with its
+#: experts: each wrong term below is in one of these four blocks (the tree
+#: from seed 3: the one router sends rows to both experts held here)
+_CUT = {"num_hidden_layers": 2, "num_attention_heads_per_layer": [6, 8],
+        "layer_types": ["full_attention", "sliding_attention"],
+        "mlp_layer_types": ["dense", "sparse"]}
+SMALL = ARCH.cut(_CUT, {
+    **{name: leaf for name, leaf in adapter._leaf_paths(
+        {**CONFIG, **_CUT}).items() if name != "first_query"},
+    "embed": (("embed",), None),
+    "final_norm": (("ln_f",), None),
+    "lead_gate": (("lead", FULL, "wg"), (0,)),
+    "lead_key": (("lead", FULL, "wk"), (0,)),
+    "lead_out": (("lead", FULL, "wo"), (0,)),
+    "dense_gate": (("lead", "dense", "w1"), (0,)),
+    "dense_up": (("lead", "dense", "w3"), (0,)),
+    "window_query": (("layers", WINDOW, "wq"), (0, 0)),
+    "window_value": (("layers", WINDOW, "wv"), (0, 0)),
+    "expert_gate": (("layers", "experts", "we1"), (0, 0, 1)),
+    "expert_up": (("layers", "experts", "we3"), (0, 0, 0)),
+    "shared_gate": (("layers", "experts", "ws1"), (0, 0)),
+    "shared_down": (("layers", "experts", "ws2"), (0, 0)),
+    "experts_norm": (("layers", "experts", "ln2"), (0, 0)),
+}, seed=3)
 
 
 def _sound_name(stack: str) -> str:
@@ -460,12 +342,11 @@ def _sound_name(stack: str) -> str:
         else stack + "_gated"
 
 
-def _error(cfg, wanted):
-    """The largest relative distance of the loss and the named gradients of
-    ``cfg``'s program from the sound reference's, on the sound tree under
+def _error(what, cfg):
+    """``SMALL.error`` of ``cfg``'s program on the sound tree under
     ``cfg``'s names for its stacks (with no mesh a leaf ``cfg`` does not
     read is no error; a block is gated where it has a ``wg``)."""
-    params, batch, want_loss, want = wanted
+    params, batch, _want = SMALL.kept(n_seqs=1)
 
     def stacks(pattern, sound):
         return {key: {name: leaf for name, leaf in
@@ -474,100 +355,33 @@ def _error(cfg, wanted):
                 for key in dict.fromkeys(t._stack_of(k) for k in pattern)}
     tree = {**params, "lead": stacks(cfg.lead_pattern, params["lead"]),
             "layers": stacks(cfg.layer_pattern, params["layers"])}
-
-    def loss_fn(p):
-        loss, aux = t.forward_loss_spmd(p, batch["tokens"],
-                                        batch["targets"], cfg)
-        return loss + aux["aux_loss"]
-    loss, grads = jax.jit(jax.value_and_grad(loss_fn))(tree)
+    loss, grads = SMALL.plain(cfg, tree, batch)
     for part in ("lead", "layers"):
         grads[part] = {_sound_name(key): stack
                        for key, stack in grads[part].items()}
-    errs = [_rel(loss, want_loss)]
-    for name, (path, index) in LEAVES.items():
+    got = {"loss": loss}
+    for name, (path, index) in SMALL.LEAVES.items():
         if path[-1] == "wg" and "wg" not in grads[path[0]][path[1]]:
             continue
-        errs.append(_rel(get_leaves(grads, {name: (path, index)})[name],
-                         want[name]))
-    return max(errs)
+        got[f"grad:{name}"] = arch.get_leaves(grads, {name: (path, index)})[
+            name]
+    return SMALL.error(what, got=got, n_seqs=1)
 
 
-def test_the_sound_program_is_inside_the_tolerance(wanted):
-    assert _error(CFG, wanted) < TOL
+def test_the_sound_program_is_inside_the_tolerance():
+    assert _error("the sound program", SMALL.CFG) < TOL
+    assert all(np.linalg.norm(np.asarray(v)) > 0
+               for v in SMALL.kept(n_seqs=1)[2].values())
 
 
 @pytest.mark.parametrize("what, change", WRONG, ids=[w for w, _ in WRONG])
-def test_a_wrong_term_fails(wanted, what, change):
+def test_a_wrong_term_fails(what, change):
     """Each moves the loss or a named gradient far beyond TOL."""
-    err = _error(change(CFG), wanted)
+    err = _error(what, change(SMALL.CFG))
     assert err > 5 * TOL, (what, err)
 
 
-# -- the share cut: one expert layer ------------------------------------------
-
-def test_the_eight_shares_add_up_to_the_uncut_layer():
-    """model-configs guide, section 4: the routed parts that the eight
-    shares compute and the shared expert counted ONCE are what the uncut
-    reference gives for the whole layer; between them the shares hold
-    every assignment once."""
-    cfg = dataclasses.replace(CFG, expert_share=(0, 1))
-    rng = np.random.RandomState(0)
-    m, f, fs, e = cfg.d_model, cfg.d_ff, cfg.moe_shared_width, cfg.n_experts
-    h = jnp.asarray(rng.randn(1, 96, m), jnp.float32)
-
-    def w(*shape, scale=1 / 8):
-        return jnp.asarray(rng.randn(*shape) * scale, jnp.float32)
-    p = {"router": w(m, e, scale=0.3), "router_bias": w(e, scale=0.1),
-         "we1": w(e, m, f), "we3": w(e, m, f), "we2": w(e, f, m),
-         "ws1": w(m, fs), "ws3": w(m, fs), "ws2": w(fs, m)}
-    sizes = {**SIZES, "experts": e, "first_expert": 0, "held_experts": e}
-    with jax.default_matmul_precision("highest"):
-        want, _choice = reference.expert_layer(p, h[0], sizes)
-        shared = want - reference.expert_layer(p, h[0], sizes,
-                                               shared=False)[0]
-    parts, held_rows = [], []
-    for i in range(8):
-        share = dataclasses.replace(cfg, expert_share=(i, 8))
-        held = {k: v[2 * i:2 * i + 2] if k in ("we1", "we2", "we3") else v
-                for k, v in p.items()}
-        y, aux = t._moe_ffn(held, h, share)
-        assert float(aux["dropped"]) == 0.0
-        parts.append(y[0])
-        held_rows.append(float(aux["held_rows"]))
-    routed = [part - shared for part in parts]
-    assert _rel(sum(routed) + shared, want) < TOL
-    assert sum(held_rows) == 96 * cfg.moe_top_k
-    # the shares' outputs summed count the shared expert eight times
-    assert _rel(sum(parts), want) > 0.5
-    # no share is the whole, and the layer that holds every expert is
-    assert _rel(routed[0] + shared, want) > 0.3
-    y, aux = t._moe_ffn(p, h, cfg)
-    assert _rel(y[0], want) < TOL and "held_rows" not in aux
-
-
 # -- one mechanism, and the configurations that name none of it ---------------
-
-def _configs():
-    """Every benchmark configuration's tiny model config, by its adapter."""
-    import importlib
-    with open(os.path.join(_REPO, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    files = {c["name"]: c["file"] for c in bench["configs"]}
-    seen = {}
-    for cell in bench["workloads"]:
-        if cell["config"] in seen:
-            continue
-        with open(os.path.join(_REPO, files[cell["config"]])) as f:
-            config = json.load(f)
-        with open(os.path.join(_CHIP, "workloads",
-                               cell["traffic"] + ".json")) as f:
-            job = json.load(f)
-        config, job = {**config, **config["tiny"]}, {**job, **job["tiny"]}
-        model = getattr(importlib.import_module(
-            f"adapters.{config['adapter']}"), "_model_config", None)
-        if model is not None:       # (BERT is no TransformerConfig)
-            seen[cell["config"]] = (model, config, job)
-    return seen
 
 
 #: the stacks of each accepted configuration's tree, as the parent commit
@@ -584,7 +398,7 @@ PARENT_STACKS = {
 
 @pytest.mark.parametrize("name", sorted(PARENT_STACKS))
 def test_a_configuration_that_names_neither_keeps_its_tree(name):
-    model, config, job = _configs()[name]
+    model, config, job = arch.configs()[name]
     cfg = model(config, job)
     for kind in cfg.layer_pattern + cfg.lead_pattern:
         assert t._kind_fields(kind) == (None, False)
@@ -604,16 +418,6 @@ def test_a_configuration_that_names_neither_keeps_its_tree(name):
         assert all("wg" not in stack for stack in layers.values())
 
 
-def _grad_jaxpr(cfg) -> str:
-    shapes = jax.eval_shape(
-        lambda: t.init_params(np.random.RandomState(0), cfg))
-    tok = jax.ShapeDtypeStruct((2, 32), jnp.int32)
-
-    def loss_fn(p, tokens, targets):
-        return t.forward_loss_spmd(p, tokens, targets, cfg)[0]
-    return str(jax.make_jaxpr(jax.grad(loss_fn))(shapes, tok, tok))
-
-
 def test_a_kind_s_fields_at_their_defaults_trace_the_short_kind_s_program():
     """``("attention", window, rope)`` and the same kind with ``heads``
     None and ``gated`` False: one tree, one jaxpr, to the letter."""
@@ -626,11 +430,11 @@ def test_a_kind_s_fields_at_their_defaults_trace_the_short_kind_s_program():
         k + (None, False) if k[0] == "attention" else k
         for k in short.layer_pattern))
     assert spelled != short
-    assert _grad_jaxpr(spelled) == _grad_jaxpr(short)
+    assert arch.grad_jaxpr(spelled) == arch.grad_jaxpr(short)
     gated = dataclasses.replace(short, layer_pattern=tuple(
         k + (None, True) if k[0] == "attention" else k
         for k in short.layer_pattern))
-    assert _grad_jaxpr(gated) != _grad_jaxpr(short)
+    assert arch.grad_jaxpr(gated) != arch.grad_jaxpr(short)
 
 
 def test_two_attention_shapes_are_two_stacks_under_one_scan():
@@ -647,7 +451,7 @@ def test_two_attention_shapes_are_two_stacks_under_one_scan():
     assert window["wk"].shape == full["wk"].shape[:1] + (6, 32, 2 * 16)
     assert params["lead"][FULL]["wq"].shape == (1, 32, 6 * 16)
     # one scan over the two periods, both shapes inside its body
-    text = _grad_jaxpr(CFG)
+    text = arch.grad_jaxpr(CFG)
     assert text.count("scan[") >= 2     # forward and its transpose
     mesh = build_mesh(devices=jax.devices()[:1], dp=1)
     sh = t.param_shardings(CFG, mesh)
@@ -688,41 +492,3 @@ def test_what_the_kinds_do_not_admit_is_refused_by_name():
         t.param_shardings(CFG, mesh)
 
 
-def test_the_decode_paths_refuse_the_new_kinds_by_name():
-    params = _params()
-    gated = t.TransformerConfig(
-        layer_pattern=(("attention", None, True, None, True), ("dense",)))
-    for cfg in (CFG, gated):
-        with pytest.raises(NotImplementedError, match="layer_pattern"):
-            decode.kv_cache_spec(cfg)
-        with pytest.raises(NotImplementedError, match="layer_pattern"):
-            decode.reference_greedy_decode(params, cfg, [1, 2], 1)
-    with pytest.raises(NotImplementedError, match="dense GPT block"):
-        decode.flatten_decode_params(params)
-
-
-def test_the_adapter_draws_init_params_tree_on_the_device():
-    host = t.init_params(np.random.RandomState(0), CFG, 1)
-    ours = jax.device_get(jax.jit(adapter._init_function(CFG, CONFIG))(
-        jax.random.PRNGKey(0)))
-    assert jax.tree_util.tree_structure(host) == \
-        jax.tree_util.tree_structure(ours)
-    for (path, h), o in zip(jax.tree_util.tree_leaves_with_path(host),
-                            jax.tree_util.tree_leaves(ours)):
-        assert h.shape == o.shape and h.dtype == o.dtype, path
-        if float(h.std()) > 0 and path[0].key != "embed":
-            assert abs(float(o.std()) / float(h.std()) - 1) < 0.25, path
-    assert float(ours["embed"].std()) == pytest.approx(
-        CONFIG["assumed"]["embedding_std"], rel=0.05)
-
-
-def test_the_reference_imports_nothing_of_the_program():
-    with open(os.path.join(_CHIP, "reference", "laguna.py")) as f:
-        text = f.read()
-    assert "horovod_tpu" not in text.split('"""', 2)[2]
-    assert '"highest"' in text
-    imports = [line for line in text.splitlines()
-               if line.startswith(("import ", "from "))]
-    assert all(line.split()[1].split(".")[0] in
-               ("__future__", "math", "numpy", "jax", "trees", "reference")
-               for line in imports), imports

@@ -62,3 +62,32 @@ def test_histogram_subseries_not_stale():
     docs = lint.doc_metrics()
     assert any(d.startswith("hvd_step_time_seconds_bucket")
                for d in docs)
+
+
+def test_suite_time_reads_a_junit_file_s_cases():
+    """ci/suite_time.py (ISSUE 57 satellite; ROADMAP C11's table): a file's
+    case-seconds and cases, the longest case first, the sum, and the floor
+    ``--dist loadfile`` puts on the wall clock: the larger of sum / n and
+    the largest file."""
+    sys.path.insert(0, os.path.join(REPO, "ci"))
+    try:
+        import suite_time
+    finally:
+        sys.path.pop(0)
+    junit = ('<testsuites><testsuite tests="3">'
+             '<testcase classname="tests.test_a" name="one" time="7.5" />'
+             '<testcase classname="tests.test_a" name="two[x-1]" time="2.5" />'
+             '<testcase classname="tests.test_b" name="three" time="4" />'
+             '</testsuite></testsuites>')
+    assert suite_time.read(junit) == [
+        ("test_a.py", "one", 7.5), ("test_a.py", "two[x-1]", 2.5),
+        ("test_b.py", "three", 4.0)]
+    lines = suite_time.report(junit, 2).splitlines()
+    assert lines[1].split() == ["10.0", "2", "test_a.py"]
+    assert lines[2].split() == ["4.0", "1", "test_b.py"]
+    assert lines[5].split() == ["7.5", "test_a.py::one"]
+    assert lines[-1] == ("sum 14.0 case-seconds over 3 cases; floor at 2 "
+                         "loadfile workers 10.0 s (sum / n 7.0, largest file "
+                         "10.0)")
+    assert suite_time.report(junit, 1).endswith(
+        "14.0 s (sum / n 14.0, largest file 10.0)")

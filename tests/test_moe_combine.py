@@ -12,13 +12,15 @@ On the CPU only values are checked; which arrays the TPU's compiler then
 makes is ``tests/test_tpu_compile.py``'s (the share cell's real step).
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.extend import core as jex_core
 
 from horovod_tpu.parallel import moe
+from moe_cases import all_jaxprs
 
 T, M = 48, 40
 
@@ -104,24 +106,6 @@ def test_dispatch_backward_is_the_sum_it_replaces(k, dtype):
     _close(got, scattered, 1e-5 if dtype == jnp.float32 else 2 ** -7)
 
 
-def _sub_jaxprs(value):
-    if isinstance(value, jex_core.ClosedJaxpr):
-        yield value.jaxpr
-    elif isinstance(value, jex_core.Jaxpr):
-        yield value
-    elif isinstance(value, (tuple, list)):
-        for item in value:
-            yield from _sub_jaxprs(item)
-
-
-def _all_jaxprs(jaxpr):
-    yield jaxpr
-    for eqn in jaxpr.eqns:
-        for value in eqn.params.values():
-            for sub in _sub_jaxprs(value):
-                yield from _all_jaxprs(sub)
-
-
 #: what one loop fusion holds: casts, products, a smaller operand broadcast
 #: into them, and the sum that ends them
 _MAKES = {"convert_element_type", "mul", "broadcast_in_dim"}
@@ -161,7 +145,7 @@ def test_the_layer_s_gradient_has_no_top_k_axis_beside_the_width(k):
         aval = var.aval
         return (aval.dtype == jnp.float32 and getattr(aval, "size", 0) >= big)
 
-    for jaxpr in _all_jaxprs(top):
+    for jaxpr in all_jaxprs(top):
         for eqn in jaxpr.eqns:
             name = eqn.primitive.name
             for var in eqn.outvars:
@@ -215,33 +199,12 @@ def _dense_share(x, logits, params, dtype):
     return y.astype(dtype)
 
 
-@pytest.mark.parametrize("path", ["xla", "kernels"])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("held", [0, 1, _CHUNK - 1, _CHUNK, _CHUNK + 1,
-                                  _PREFIX, _PREFIX + 1, _ROWS])
-def test_the_layer_over_the_rows_it_holds_is_the_dense_weighted_sum(
-        held, dtype, path, monkeypatch):
-    """``moe_layer_spmd`` of a share against the dense sum over its
-    experts: forward, and the gradients of the tokens, of the router's
-    logits (through the k weights) and of the expert parameters; routing
-    forced so that no assignment, one, a chunk of them less one, a chunk,
-    a chunk and one, as many as the token-major gathers' fast source holds,
-    one more, and all of them are held; nothing dropped. ``kernels`` runs
-    the megablox kernels in interpret mode, where a row that no kernel
-    wrote reads NaN: none reaches a result."""
-    monkeypatch.setattr(moe, "ROW_CHUNK", _CHUNK)
-    monkeypatch.setattr(moe, "GATHER_SOURCE_BYTES",
-                        _PREFIX * _WIDTH * jnp.dtype(dtype).itemsize)
-    assert moe._row_chunk(_ROWS) == _CHUNK
-    rng = np.random.RandomState(held)
-    logits = _forced_logits(held, rng)
-    x = jnp.asarray(rng.randn(_TOKENS, _WIDTH), dtype)
-    params = {
-        "w1": jnp.asarray(rng.randn(_EXPERTS // 2, _WIDTH, _WIDTH)
-                          / np.sqrt(_WIDTH), jnp.float32),
-        "w2": jnp.asarray(rng.randn(_EXPERTS // 2, _WIDTH, _WIDTH)
-                          / np.sqrt(_WIDTH), jnp.float32)}
-    ct = jnp.asarray(rng.randn(_TOKENS, _WIDTH), jnp.float32)
+@functools.lru_cache(maxsize=None)
+def _both(dtype, path):
+    """(the layer's value and gradients, the dense sum's) as ``(x, logits,
+    params, ct) ->``, each traced and compiled once a ``(dtype, path)`` under
+    this section's ``ROW_CHUNK`` and ``GATHER_SOURCE_BYTES``: ``held``
+    changes the forced logits' values, no shape."""
     router = jnp.zeros((_WIDTH, _EXPERTS), jnp.float32)    # logits are given
     interpret = path == "kernels"
 
@@ -251,25 +214,63 @@ def test_the_layer_over_the_rows_it_holds_is_the_dense_weighted_sum(
         return moe.grouped_matmul(h, p["w2"], group_sizes,
                                   interpret=interpret)
 
-    def layer(x, logits, params):
+    def layer(x, logits, params, ct):
         y, metrics = moe.moe_layer_spmd(
             x, router, expert_fn, params, axis_name=None, k=_K,
             logits=logits, share=(0, 2))
         return jnp.sum(y.astype(jnp.float32) * ct), (y, metrics)
 
-    def dense(x, logits, params):
-        return jnp.sum(_dense_share(x, logits, params, dtype
-                                    ).astype(jnp.float32) * ct)
+    def dense(x, logits, params, ct):
+        y = _dense_share(x, logits, params, dtype)
+        return jnp.sum(y.astype(jnp.float32) * ct), y
 
-    (_, (y, metrics)), got = jax.value_and_grad(
-        layer, (0, 1, 2), has_aux=True)(x, logits, params)
+    f32 = jnp.float32
+    weights = jax.ShapeDtypeStruct((_EXPERTS // 2, _WIDTH, _WIDTH), f32)
+    shapes = (jax.ShapeDtypeStruct((_TOKENS, _WIDTH), dtype),
+              jax.ShapeDtypeStruct((_TOKENS, _EXPERTS), f32),
+              {"w1": weights, "w2": weights},
+              jax.ShapeDtypeStruct((_TOKENS, _WIDTH), f32))
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(moe, "ROW_CHUNK", _CHUNK)
+        patch.setattr(moe, "GATHER_SOURCE_BYTES",
+                      _PREFIX * _WIDTH * jnp.dtype(dtype).itemsize)
+        assert moe._row_chunk(_ROWS) == _CHUNK
+        return tuple(jax.jit(jax.value_and_grad(f, (0, 1, 2), has_aux=True)
+                             ).lower(*shapes).compile()
+                     for f in (layer, dense))
+
+
+@pytest.mark.parametrize("path", ["xla", "kernels"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("held", [0, 1, _CHUNK - 1, _CHUNK, _CHUNK + 1,
+                                  _PREFIX, _PREFIX + 1, _ROWS])
+def test_the_layer_over_the_rows_it_holds_is_the_dense_weighted_sum(
+        held, dtype, path):
+    """``moe_layer_spmd`` of a share against the dense sum over its
+    experts: forward, and the gradients of the tokens, of the router's
+    logits (through the k weights) and of the expert parameters; routing
+    forced so that no assignment, one, a chunk of them less one, a chunk,
+    a chunk and one, as many as the token-major gathers' fast source holds,
+    one more, and all of them are held; nothing dropped. ``kernels`` runs
+    the megablox kernels in interpret mode, where a row that no kernel
+    wrote reads NaN: none reaches a result."""
+    rng = np.random.RandomState(held)
+    logits = _forced_logits(held, rng)
+    x = jnp.asarray(rng.randn(_TOKENS, _WIDTH), dtype)
+    params = {
+        "w1": jnp.asarray(rng.randn(_EXPERTS // 2, _WIDTH, _WIDTH)
+                          / np.sqrt(_WIDTH), jnp.float32),
+        "w2": jnp.asarray(rng.randn(_EXPERTS // 2, _WIDTH, _WIDTH)
+                          / np.sqrt(_WIDTH), jnp.float32)}
+    ct = jnp.asarray(rng.randn(_TOKENS, _WIDTH), jnp.float32)
+    layer, dense = _both(dtype, path)
+    (_, (y, metrics)), got = layer(x, logits, params, ct)
     assert float(metrics.held_rows) == held and float(metrics.dropped) == 0
-    want = jax.grad(dense, (0, 1, 2))(x, logits, params)
+    (_, want_y), want = dense(x, logits, params, ct)
     tol = 2e-5 if dtype == jnp.float32 else 2 ** -6
     names = ["y", "d_x", "d_logits", "d_w1", "d_w2"]
     pairs = zip(names, [y, got[0], got[1], got[2]["w1"], got[2]["w2"]],
-                [_dense_share(x, logits, params, dtype), want[0], want[1],
-                 want[2]["w1"], want[2]["w2"]])
+                [want_y, want[0], want[1], want[2]["w1"], want[2]["w2"]])
     for name, g, w in pairs:
         g, w = (np.asarray(a, np.float64) for a in (g, w))
         assert np.all(np.isfinite(g)), name

@@ -16,115 +16,25 @@ what bfloat16 decays do to the scan
 """
 
 import dataclasses
-import json
-import os
-import sys
 
 import numpy as np
 import pytest
 import jax
 import jax.numpy as jnp
 
-from horovod_tpu.models import decode, mamba
+import arch
+from arch import TOL, rel as _rel
+from horovod_tpu.models import mamba
 from horovod_tpu.models import transformer as t
 from horovod_tpu.models import shard_batch, shard_params
 from horovod_tpu.parallel import build_mesh, moe
 
-_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-_CHIP = os.path.join(_REPO, "benchmarks", "chip")
-if _CHIP not in sys.path:
-    sys.path.insert(0, _CHIP)
-
-from adapters import nemotron_h as adapter            # noqa: E402
-from reference import nemotron_h as reference         # noqa: E402
-from trees import get_leaves                           # noqa: E402
-
-TOL = 1e-4
-
-
-def _cell(tiny: bool):
-    with open(os.path.join(_CHIP, "configs",
-                           "nemotron-3-nano-30b-a3b.json")) as f:
-        config = json.load(f)
-    with open(os.path.join(_CHIP, "workloads",
-                           "train.s8192.b1.hybrid.json")) as f:
-        job = json.load(f)
-    if tiny:
-        config, job = {**config, **config["tiny"]}, {**job, **job["tiny"]}
-    return config, job
-
-
-CONFIG, JOB = _cell(tiny=True)
-SIZES = adapter.shapes(CONFIG, JOB)
-CFG = adapter._model_config(CONFIG, JOB)
+ARCH = arch.get("nemotron_h")
+adapter, reference = ARCH.adapter, ARCH.reference
+CONFIG, SIZES, CFG, LEAVES = ARCH.CONFIG, ARCH.SIZES, ARCH.CFG, ARCH.LEAVES
+_cell, _params, _batch = ARCH.cell, ARCH.params, ARCH.batch
+_plain_grads = ARCH.plain
 PATTERN = CONFIG["hybrid_override_pattern"]
-LEAVES = {
-    **adapter._leaf_paths(PATTERN),
-    "embed": (("embed",), None),
-    "conv_taps": (("layers", "mamba", "ssm_conv_w"), (0, 1)),
-    "conv_bias": (("layers", "mamba", "ssm_conv_b"), (0, 2)),
-    "dt_bias": (("layers", "mamba", "ssm_dt_bias"), (0, 4)),
-    "skip": (("layers", "mamba", "ssm_d"), (0, 5)),
-    "gate_norm": (("layers", "mamba", "ssm_norm"), (0, 6)),
-    "ssm_out": (("layers", "mamba", "ssm_out"), (0, 7)),
-    "mamba_norm": (("layers", "mamba", "ln1"), (0, 3)),
-    "query": (("layers", "attention", "wq"), (0, 1)),
-    "first_router": (("layers", "experts", "router"), (0, 0)),
-    "expert_up": (("layers", "experts", "we1"), (0, 5, 1)),
-    "shared_up": (("layers", "experts", "ws1"), (0, 2)),
-}
-
-
-def _params(cfg=CFG, seed=0):
-    return jax.tree_util.tree_map(
-        jnp.asarray, t.init_params(np.random.RandomState(seed), cfg, 1))
-
-
-def _batch(n_seqs=2, seed=0):
-    return jax.tree_util.tree_map(
-        jnp.asarray, adapter.host_batch(CONFIG, JOB, seed, 0, n_seqs))
-
-
-def _rel(got, want):
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    return np.linalg.norm(got - want) / (np.linalg.norm(want) or 1.0)
-
-
-def _program(cfg, params, batch):
-    """(loss, aux, gradients) on a mesh of one device, through
-    ``make_grad_fn`` as the benchmark's adapter calls it."""
-    mesh = build_mesh(devices=jax.devices()[:1], dp=1)
-    p = shard_params(params, cfg, mesh)
-    tok, tgt = shard_batch(batch["tokens"], batch["targets"], mesh)
-    loss, aux, grads = jax.jit(t.make_grad_fn(cfg, mesh))(p, tok, tgt)
-    return loss + aux["aux_loss"], aux, grads
-
-
-def _plain_grads(cfg, params, batch):
-    """Loss and gradients with no mesh (a tree that holds a leaf ``cfg``
-    does not read is no error here)."""
-    def loss_fn(p):
-        loss, aux = t.forward_loss_spmd(p, batch["tokens"],
-                                        batch["targets"], cfg)
-        return loss + aux["aux_loss"]
-    return jax.jit(jax.value_and_grad(loss_fn))(params)
-
-
-def test_the_tiny_preset_is_the_one_the_issue_asks_for():
-    assert CFG.dtype == jnp.float32 and CFG.n_layers == 18
-    assert CFG.layer_pattern == tuple(adapter.KINDS[c] for c in "MEMEMEM*E")
-    assert CFG.one_sublayer and PATTERN == "MEMEMEM*E" * 2
-    assert (CFG.ssm_heads, CFG.ssm_head_dim, CFG.ssm_state, CFG.ssm_groups,
-            CFG.ssm_conv, CFG.ssm_chunk) == (4, 8, 16, 2, 4, 16)
-    assert CFG.ssm_inner == 32 != CONFIG["expand"] * CFG.d_model
-    assert JOB["seq_len"] == 4 * CFG.ssm_chunk
-    assert (CFG.n_heads, CFG.kv_heads, CFG.head_dim) == (4, 2, 16)
-    assert (CFG.n_experts, CFG.moe_top_k, CFG.held_experts,
-            CFG.expert_share) == (16, 4, 2, (0, 8))
-    assert (CFG.moe_router_scores, CFG.moe_activation, CFG.moe_gated,
-            CFG.moe_routed_scale, CFG.moe_shared_width,
-            CFG.moe_renormalize, CFG.moe_balance_weight) == (
-                "sigmoid", "relu2", False, 2.5, 64, True, 0.0)
 
 
 def test_the_cell_keeps_every_published_width():
@@ -144,9 +54,7 @@ def test_the_cell_keeps_every_published_width():
     assert set(config["reduced"]) == set(config["reduced_from"]) == {
         "num_hidden_layers", "hybrid_override_pattern", "n_routed_experts",
         "vocab_size"}
-    n = sum(int(np.prod(a.shape)) for a in jax.tree_util.tree_leaves(
-        jax.eval_shape(adapter._init_function(cfg, config),
-                       jax.random.PRNGKey(0))))
+    n = arch.count(arch.drawn_shapes(adapter, cfg, config))
     assert 666e6 < n < 668e6, n      # the deployment's 667 M parameters
 
 
@@ -212,28 +120,14 @@ def test_the_scan_kernels_least_work_by_hand():
 
 # -- the program against the reference ---------------------------------------
 
-@pytest.fixture(scope="module")
-def both_sides():
-    params, batch = _params(), _batch()
-    loss, aux, grads = _program(CFG, params, batch)
-    got = {"loss": loss,
-           **{f"grad:{k}": v for k, v in get_leaves(grads, LEAVES).items()}}
-    want_loss, want_grads = reference.loss_and_grads(params, LEAVES, batch,
-                                                     SIZES)
-    want = {"loss": want_loss,
-            **{f"grad:{k}": v for k, v in want_grads.items()}}
-    return got, want, aux, grads
-
-
 @pytest.mark.parametrize("what", ["loss"] + [f"grad:{k}" for k in LEAVES])
-def test_program_matches_the_reference(both_sides, what):
-    got, want, _aux, _grads = both_sides
+def test_program_matches_the_reference(what):
+    got, want, _aux, _grads = ARCH.sides
     assert _rel(got[what], want[what]) < TOL, what
 
 
-def test_the_step_reports_its_rows_and_no_gradient_reaches_the_bias(
-        both_sides):
-    _got, _want, aux, grads = both_sides
+def test_the_step_reports_its_rows_and_no_gradient_reaches_the_bias():
+    _got, _want, aux, grads = ARCH.sides
     assert set(aux) == {"aux_loss", "load_balance_loss", "router_z_loss",
                         "max_expert_load", "dropped", "held_rows"}
     assert float(aux["dropped"]) == 0.0 and float(aux["aux_loss"]) == 0.0
@@ -244,9 +138,11 @@ def test_the_step_reports_its_rows_and_no_gradient_reaches_the_bias(
     assert not np.any(np.asarray(grads["layers"]["experts"]["router_bias"]))
     # the choices are the reference's, block by block
     params, batch = _params(), _batch()
-    ours = t.router_choices(params, batch["tokens"], CFG)
+    ours = jax.jit(lambda p, tok: t.router_choices(p, tok, CFG))(
+        params, batch["tokens"])
     with jax.default_matmul_precision("highest"):
-        theirs = reference.forward(params, batch["tokens"], SIZES)[1]
+        theirs = jax.jit(lambda p, tok: reference.forward(p, tok, SIZES)[1])(
+            params, batch["tokens"])
     assert ours.shape == (PATTERN.count("E"), 128, CFG.moe_top_k)
     np.testing.assert_array_equal(np.sort(ours, -1), np.sort(theirs, -1))
 
@@ -301,12 +197,13 @@ def test_the_chunked_form_is_the_step_by_step_form(chunk):
     ops = _scan_operands()
     weight = jnp.asarray(np.random.RandomState(1).randn(2, 64, 4, 8),
                          jnp.float32)
-    np.testing.assert_allclose(mamba.ssm_chunked(*ops, chunk), _stepwise(*ops),
-                               rtol=2e-5, atol=2e-5)
-    got = jax.grad(lambda *v: jnp.sum(mamba.ssm_chunked(*v, chunk) * weight),
-                   (0, 1, 2, 3, 4))(*ops)
-    want = jax.grad(lambda *v: jnp.sum(_stepwise(*v) * weight),
-                    (0, 1, 2, 3, 4))(*ops)
+    np.testing.assert_allclose(mamba.ssm_chunked(*ops, chunk),
+                               jax.jit(_stepwise)(*ops), rtol=2e-5, atol=2e-5)
+    got = jax.jit(jax.grad(
+        lambda *v: jnp.sum(mamba.ssm_chunked(*v, chunk) * weight),
+        (0, 1, 2, 3, 4)))(*ops)
+    want = jax.jit(jax.grad(lambda *v: jnp.sum(_stepwise(*v) * weight),
+                            (0, 1, 2, 3, 4)))(*ops)
     for name, g, w in zip(("x", "dt", "a", "b", "c"), got, want):
         assert _rel(g, w) < TOL, name
     with pytest.raises(ValueError, match="ssm_chunk=24"):
@@ -432,35 +329,18 @@ def _shared_once_a_held_expert(p, toks, activation):
 
 
 #: one block of each kind: what a wrong term is shown on
-SMALL = dataclasses.replace(CFG, n_layers=3, layer_pattern=tuple(
-    adapter.KINDS[c] for c in "ME*"))
-SMALL_SIZES = {**SIZES, "pattern": "ME*", "layers": 3}
-SMALL_LEAVES = {
+SMALL = ARCH.cut({"num_hidden_layers": 3, "hybrid_override_pattern": "ME*"}, {
     "lm_head": (("lm_head",), None),
     "ssm_in": (("layers", "mamba", "ssm_in"), (0, 0)),
     "ssm_a_log": (("layers", "mamba", "ssm_a_log"), (0, 0)),
     "router": (("layers", "experts", "router"), (0, 0)),
-}
+})
 
 
-@pytest.fixture(scope="module")
-def small_reference():
-    params, batch = _params(SMALL), _batch()
-    want_loss, want = reference.loss_and_grads(params, SMALL_LEAVES, batch,
-                                               SMALL_SIZES)
-    return params, batch, want_loss, want
-
-
-def _small_error(cfg, small_reference):
-    params, batch, want_loss, want = small_reference
-    loss, grads = _plain_grads(cfg, params, batch)
-    return max([_rel(loss, want_loss)] + [
-        _rel(v, want[k])
-        for k, v in get_leaves(grads, SMALL_LEAVES).items()])
-
-
-def test_the_sound_small_stack_matches_the_reference(small_reference):
-    assert _small_error(SMALL, small_reference) < TOL
+def test_the_sound_small_stack_matches_the_reference():
+    assert SMALL.CFG == dataclasses.replace(CFG, n_layers=3, layer_pattern=(
+        ("mamba",), ("experts",), ("attention", None, False)))
+    assert SMALL.sound < TOL
 
 
 @pytest.fixture
@@ -473,11 +353,11 @@ def on_the_kernels(monkeypatch):
 
 
 def test_the_sound_small_stack_matches_the_reference_on_the_kernels(
-        small_reference, on_the_kernels):
+        on_the_kernels):
     calls = str(jax.make_jaxpr(lambda p, b: t.forward_loss_spmd(
-        p, b["tokens"], b["targets"], SMALL)[0])(*small_reference[:2]))
+        p, b["tokens"], b["targets"], SMALL.CFG)[0])(*SMALL.kept()[:2]))
     assert "pallas_call" in calls
-    assert _small_error(SMALL, small_reference) < TOL
+    assert SMALL.error("the sound program on the kernels") < TOL
 
 
 @pytest.mark.parametrize("what, change", [
@@ -497,13 +377,13 @@ def test_the_sound_small_stack_matches_the_reference_on_the_kernels(
     ("the gate after the norm",
      {"patch": (mamba, "_gated_norm", _gate_after_the_norm)}),
 ])
-def test_a_wrong_term_fails(monkeypatch, small_reference, what, change):
+def test_a_wrong_term_fails(monkeypatch, what, change):
     """Each moves the loss or a named gradient of a stack of one Mamba, one
     expert and one attention block far beyond TOL."""
     if "patch" in change:
         monkeypatch.setattr(*change["patch"])
-    cfg = dataclasses.replace(SMALL, **change.get("cfg", {}))
-    err = _small_error(cfg, small_reference)
+    cfg = dataclasses.replace(SMALL.CFG, **change.get("cfg", {}))
+    err = SMALL.error(what, cfg)
     assert err > 5 * TOL, (what, err)
 
 
@@ -516,122 +396,16 @@ def _kernel_state_not_carried(state, whole, own):
      ("_carry", _kernel_state_not_carried)),
     ("the decays made in bfloat16", ("_decay", _bf16_decay)),
 ])
-def test_a_wrong_scan_fails_on_the_kernels(monkeypatch, small_reference,
-                                           on_the_kernels, what, patch):
+def test_a_wrong_scan_fails_on_the_kernels(monkeypatch, on_the_kernels, what,
+                                           patch):
     """The same stack with the scan on the kernels: a dropped state moves
     the loss far beyond TOL, bfloat16 decays a block's gradients by four
     times TOL and more (``test_the_scan_s_decays_in_bfloat16_fail`` says
     why a whole block reads less than the scan alone)."""
     from horovod_tpu.ops import pallas_ssm
     monkeypatch.setattr(pallas_ssm, *patch)
-    err = _small_error(SMALL, small_reference)
+    err = SMALL.error(what + ", on the kernels")
     assert err > 4 * TOL, (what, err)
-
-
-# -- the share cut: one expert layer ---------------------------------------------
-
-def test_the_sixteen_shares_add_up_to_the_uncut_layer():
-    """model-configs guide, section 4: the routed parts that the sixteen
-    shares compute and the shared expert counted ONCE are what the uncut
-    reference gives for the whole layer; between them the shares hold
-    every assignment once."""
-    cfg = dataclasses.replace(CFG, n_experts=32, expert_share=(0, 1))
-    rng = np.random.RandomState(0)
-    m, f, fs, e = cfg.d_model, cfg.d_ff, cfg.moe_shared_width, cfg.n_experts
-    h = jnp.asarray(rng.randn(1, 96, m), jnp.float32)
-    p = {"router": jnp.asarray(rng.randn(m, e) * 0.3, jnp.float32),
-         "router_bias": jnp.asarray(rng.randn(e) * 0.1, jnp.float32),
-         "we1": jnp.asarray(rng.randn(e, m, f) / 8, jnp.float32),
-         "we2": jnp.asarray(rng.randn(e, f, m) / 8, jnp.float32),
-         "ws1": jnp.asarray(rng.randn(m, fs) / 8, jnp.float32),
-         "ws2": jnp.asarray(rng.randn(fs, m) / 8, jnp.float32)}
-    sizes = {**SIZES, "experts": e, "first_expert": 0, "held_experts": e}
-    with jax.default_matmul_precision("highest"):
-        want, _choice = reference.layer(p, h[0], sizes)
-        shared = reference.layer(p, h[0], sizes)[0] \
-            - reference.layer(p, h[0], sizes, shared=False)[0]
-    parts, held_rows = [], []
-    for i in range(16):
-        share = dataclasses.replace(cfg, expert_share=(i, 16))
-        held = {k: v[2 * i:2 * i + 2] if k in ("we1", "we2") else v
-                for k, v in p.items()}
-        y, aux = t._moe_ffn(held, h, share)
-        assert float(aux["dropped"]) == 0.0
-        parts.append(y[0])
-        held_rows.append(float(aux["held_rows"]))
-    routed = [part - shared for part in parts]
-    assert _rel(sum(routed) + shared, want) < TOL
-    assert sum(held_rows) == 96 * cfg.moe_top_k
-    # the shares' outputs summed count the shared expert sixteen times
-    assert _rel(sum(parts), want) > 1.0
-    # no share is the whole, and the layer that holds every expert is
-    assert _rel(routed[0] + shared, want) > 0.3
-    y, aux = t._moe_ffn(p, h, cfg)
-    assert _rel(y[0], want) < TOL and "held_rows" not in aux
-
-
-# -- the grouped matmul at a width no 128-multiple divides ------------------------
-
-def test_gmm_takes_a_width_no_lane_tile_divides_as_one_block(monkeypatch):
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    path = moe.gmm_path(49152, 2688, 1856)
-    assert path.startswith(
-        "pallas hvd_moe_gmm weights read as stored, [E, 1856, 2688] (1856 "
-        "columns are no multiple of 128 lanes and 2688 rows are, so the "
-        "chip keeps the rows minor: transpose_rhs forward, the weight "
-        "gradient written [E, 1856, 2688]): forward 256x896x1856; input "
-        "gradient 256x1856x896, a group's weights resident; weight gradient "
-        "256x1856x384"), path
-    down = moe.gmm_path(49152, 1856, 2688)
-    assert down.startswith(
-        "pallas hvd_moe_gmm weights read as stored, [E, 1856, 2688] "
-        "row-major (transpose_rhs in the input gradient): forward "
-        "256x1856x896, a group's weights resident; input gradient "
-        "256x896x1856; weight gradient 256x1856x384"), down
-    assert moe._lane_tiles(1856) == [1856] and moe._lane_tiles(64) == []
-    assert moe._lane_tiles(2688) == [2688, 896, 384, 128]
-    # the way up's calls are the way down's, each other's: one stored shape
-    assert moe._gmm_tile(49152, 2688, 1856, 2) == moe.GmmTiles(
-        (256, 896, 1856), (256, 1856, 896), (256, 1856, 384), True)
-    assert moe._gmm_tile(49152, 1856, 2688, 2) == moe.GmmTiles(
-        (256, 1856, 896), (256, 896, 1856), (256, 1856, 384), False)
-    # the OLMoE and SmallThinker shapes keep the tiles PR 33 gave them
-    assert moe._gmm_tile(65536, 2048, 1024, 2) == moe.GmmTiles(
-        (256, 2048, 1024), (256, 1024, 2048), (256, 1024, 1024))
-    assert moe._gmm_tile(49152, 2560, 768, 2) == moe.GmmTiles(
-        (256, 2560, 768), (256, 768, 2560), (256, 1280, 768))
-    assert moe._gmm_tile(49152, 768, 2560, 2) == moe.GmmTiles(
-        (256, 768, 2560), (256, 2560, 768), (256, 768, 1280))
-
-
-@pytest.mark.parametrize("k, f, transposed", [
-    (128, 192, True), (256, 192, True), (128, 256, False),
-    (192, 128, False), (192, 192, False)])
-def test_the_kernels_at_such_a_width_are_the_ragged_dot(k, f, transposed):
-    """Interpret mode: 192 columns (1.5 lane tiles) as one block, forward
-    and both gradients, groups that start inside a row tile and end before
-    the rows do. Where the columns are no multiple of 128 lanes and the
-    rows are, the calls read the weights ``[E, f, k]`` and return their
-    gradient swapped back (ISSUE 41); elsewhere in the order they had."""
-    rng = np.random.RandomState(0)
-    rows = jnp.asarray(rng.randn(256, k), jnp.float32)
-    w = jnp.asarray(rng.randn(3, k, f) / 8, jnp.float32)
-    sizes = jnp.asarray([100, 0, 92], jnp.int32)
-    assert moe._gmm_tile(256, k, f, 4).transposed == transposed
-    weight = jnp.asarray(rng.randn(256, f), jnp.float32)
-    inside = (jnp.arange(256) < 192)[:, None]
-
-    def run(interpret):
-        def loss(r, w):
-            y = moe.grouped_matmul(r, w, sizes, interpret=interpret)
-            return jnp.sum(jnp.where(inside, y, 0) * weight)
-        return jax.value_and_grad(loss, (0, 1))(rows, w)
-    with jax.default_matmul_precision("highest"):
-        (got, (d_rows, d_w)), (want, (r_rows, r_w)) = run(True), run(False)
-    assert d_w.shape == w.shape and d_w.dtype == w.dtype
-    assert _rel(got, want) < 1e-5
-    assert _rel(jnp.where(inside, d_rows, 0), r_rows) < 1e-5
-    assert _rel(d_w, r_w) < 1e-5
 
 
 # -- what is refused, by name -------------------------------------------------------
@@ -648,26 +422,6 @@ def test_a_mamba_block_on_a_live_axis_is_refused_by_name(axis):
         ("experts",), ("attention", None, False)))
     if axis != "sp":
         t.param_shardings(rest, mesh)
-
-
-def test_the_decode_paths_refuse_the_new_fields_by_name():
-    params = _params()
-    for field, cfg in [
-            ("ssm_heads", CFG),
-            ("moe_router_scores", t.TransformerConfig(
-                n_experts=8, moe_router_scores="sigmoid")),
-            ("moe_shared_width", t.TransformerConfig(
-                n_experts=8, moe_shared_width=64))]:
-        with pytest.raises(NotImplementedError, match=field):
-            decode.kv_cache_spec(cfg)
-        with pytest.raises(NotImplementedError, match=field):
-            decode.decode_step_paged(params, None, None, None, None, None,
-                                     None, cfg)
-        with pytest.raises(NotImplementedError, match=field):
-            decode.prefill_chunk_paged(params, None, None, None, None, None,
-                                       None, cfg)
-        with pytest.raises(NotImplementedError, match=field):
-            decode.reference_greedy_decode(params, cfg, [1, 2], 1)
 
 
 def test_a_pattern_or_a_word_the_program_does_not_know_is_refused():
@@ -704,15 +458,16 @@ def test_a_mamba_block_is_checkpointed_where_nothing_says_otherwise():
     """``remat=None``: the Mamba blocks under ``jax.checkpoint``, the
     expert and attention blocks not; ``remat=False`` keeps everything, and
     the gradients are the same."""
-    params, batch = _params(SMALL), _batch()
+    small = SMALL.CFG
+    params, batch = _params(small), _batch()
 
     def checkpoints(cfg):
         return str(jax.make_jaxpr(jax.grad(lambda p: t.forward_loss_spmd(
             p, batch["tokens"], batch["targets"], cfg)[0]))(params)
             ).count("remat")
-    kept = dataclasses.replace(SMALL, remat=False)
-    assert checkpoints(SMALL) > checkpoints(kept)
-    _loss, a = _plain_grads(SMALL, params, batch)
+    kept = dataclasses.replace(small, remat=False)
+    assert checkpoints(small) > checkpoints(kept)
+    _loss, a = _plain_grads(small, params, batch)
     _loss, b = _plain_grads(kept, params, batch)
     for (path, x), y in zip(jax.tree_util.tree_leaves_with_path(a),
                             jax.tree_util.tree_leaves(b)):
@@ -727,7 +482,7 @@ def test_a_stack_with_a_mamba_block_finishes_its_gradients_before_the_update(
     block kind's row says ``gradients_first`` (the Mamba row: ISSUE 47), and
     none where no block says so."""
     import optax
-    cfg = dataclasses.replace(SMALL, n_layers=len(pattern), remat=False,
+    cfg = dataclasses.replace(SMALL.CFG, n_layers=len(pattern), remat=False,
                               layer_pattern=tuple(
                                   adapter.KINDS[c] for c in pattern))
     mesh = build_mesh(devices=jax.devices()[:1], dp=1)

@@ -15,122 +15,37 @@ fails``), so none of them can hide in it.
 
 import dataclasses
 import functools
-import json
-import os
-import sys
 
 import numpy as np
 import pytest
 import jax
 import jax.numpy as jnp
 
-from horovod_tpu.models import _kinds
+import arch
+from arch import TOL, rel as _rel
 from horovod_tpu.models import transformer as t
 from horovod_tpu.models import shard_batch, shard_params
 from horovod_tpu.parallel import build_mesh, moe
 
-_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-_CHIP = os.path.join(_REPO, "benchmarks", "chip")
-if _CHIP not in sys.path:
-    sys.path.insert(0, _CHIP)
-
-from adapters import olmoe as adapter          # noqa: E402
-from reference import olmoe as reference       # noqa: E402
-
-TOL = 1e-4
-
-
-def _tiny():
-    with open(os.path.join(_CHIP, "configs", "olmoe-1b-7b.json")) as f:
-        config = json.load(f)
-    with open(os.path.join(_CHIP, "workloads", "train.s4096.b2.json")) as f:
-        job = json.load(f)
-    return {**config, **config["tiny"]}, {**job, **job["tiny"]}
-
-
-CONFIG, JOB = _tiny()
-SIZES = adapter.shapes(CONFIG, JOB)
-CFG = dataclasses.replace(adapter._model_config(CONFIG, JOB),
-                          dtype=jnp.float32)
-LEAVES = {
-    "router": (("layers", "router"), (0, 1)),
-    "expert_gate": (("layers", "we1"), (0, 1, 3)),
-    "expert_up": (("layers", "we3"), (0, 1, 3)),
-    "expert_down": (("layers", "we2"), (0, 1, 3)),
-    "wq": (("layers", "wq"), (0, 0)),
-    "lm_head": (("lm_head",), None),
-}
-
-
-def _params(cfg=CFG, seed=0):
-    return jax.tree_util.tree_map(
-        jnp.asarray, t.init_params(np.random.RandomState(seed), cfg, 1))
-
-
-def _batch(n_seqs=2, seed=0):
-    return jax.tree_util.tree_map(
-        jnp.asarray, adapter.host_batch(CONFIG, JOB, seed, 0, n_seqs))
-
-
-def _rel(got, want):
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    return np.linalg.norm(got - want) / np.linalg.norm(want)
-
-
-def _program(cfg, params, batch, mesh_axes=None):
-    """(loss + weighted auxiliary losses, aux, gradients) by the program's
-    make_grad_fn on a mesh (one device by default)."""
-    axes = mesh_axes or {"dp": 1}
-    n = int(np.prod(list(axes.values())))
-    mesh = build_mesh(devices=jax.devices()[:n], **axes)
-    p = shard_params(params, cfg, mesh)
-    tok, tgt = shard_batch(batch["tokens"], batch["targets"], mesh)
-    loss, aux, grads = jax.jit(t.make_grad_fn(cfg, mesh))(p, tok, tgt)
-    return loss + aux["aux_loss"], aux, grads
-
-
-def _program_logits(cfg, params, tokens):
-    """The program's blocks and head on one device, up to the logits."""
-    x = params["embed"].astype(cfg.dtype)[tokens]
-    x, _aux = t._run_layers(params["layers"], x,
-                            jnp.arange(tokens.shape[1]), cfg)
-    return _kinds.rmsnorm(x, params["ln_f"], cfg.norm_eps) @ params["lm_head"]
-
-
-def _reference(params, batch, sizes=SIZES, leaves=LEAVES):
-    with jax.default_matmul_precision("highest"):
-        total, _xent, balance, z, _choice = reference.losses(
-            params, batch, sizes)
-        logits = reference.forward(params, batch["tokens"], sizes)[0]
-    _loss, grads = reference.loss_and_grads(params, leaves, batch, sizes)
-    return {"loss": total, "load_balance_loss": balance, "router_z_loss": z,
-            "logits": logits, **{f"grad:{k}": v for k, v in grads.items()}}
-
-
-@pytest.fixture(scope="module")
-def both_sides():
-    from trees import get_leaves
-    params, batch = _params(), _batch()
-    loss, aux, grads = _program(CFG, params, batch)
-    got = {"loss": loss, "load_balance_loss": aux["load_balance_loss"],
-           "router_z_loss": aux["router_z_loss"],
-           "logits": _program_logits(CFG, params, batch["tokens"]),
-           **{f"grad:{k}": v
-              for k, v in get_leaves(grads, LEAVES).items()}}
-    return got, _reference(params, batch), aux
+ARCH = arch.get("olmoe")
+reference = ARCH.reference
+CONFIG, SIZES, CFG, LEAVES = ARCH.CONFIG, ARCH.SIZES, ARCH.CFG, ARCH.LEAVES
+_params, _batch, _program = ARCH.params, ARCH.batch, ARCH.program
+assert CFG.dtype == jnp.float32
 
 
 @pytest.mark.parametrize("what", [
     "logits", "loss", "load_balance_loss", "router_z_loss", "grad:router",
     "grad:expert_gate", "grad:expert_up", "grad:expert_down", "grad:wq",
     "grad:lm_head"])
-def test_program_matches_the_reference(both_sides, what):
-    got, want, _aux = both_sides
+def test_program_matches_the_reference(what):
+    got, want, _aux, _grads = ARCH.sides
+    assert np.linalg.norm(np.asarray(want[what])) > 0
     assert _rel(got[what], want[what]) < TOL, what
 
 
-def test_the_step_reports_its_load_and_drops_nothing(both_sides):
-    _got, _want, aux = both_sides
+def test_the_step_reports_its_load_and_drops_nothing():
+    _got, _want, aux, _grads = ARCH.sides
     assert set(aux) == {"aux_loss", "load_balance_loss", "router_z_loss",
                         "max_expert_load", "dropped"}
     assert float(aux["dropped"]) == 0.0
@@ -151,7 +66,7 @@ def test_dropless_when_every_token_takes_the_same_experts():
     loss, aux, grads = _program(CFG, params, batch)
     assert float(aux["dropped"]) == 0.0
     assert float(aux["max_expert_load"]) == CFG.n_experts / CFG.moe_top_k
-    want = _reference(params, batch)
+    want = ARCH.want(params, batch)
     assert _rel(loss, want["loss"]) < TOL
     assert _rel(grads["layers"]["we2"][0, 1, 0],
                 reference.loss_and_grads(
@@ -206,12 +121,9 @@ def _drop_one(*args, ffn=moe.expert_ffn):
 def test_a_wrong_term_fails(monkeypatch, what, where, wrong):
     """What TOL must not let through: each moves the router's gradient far
     beyond it (the loss of 128 random tokens hardly notices)."""
-    params, batch = _params(), _batch()
-    _want_loss, want = reference.loss_and_grads(
-        params, {"router": LEAVES["router"]}, batch, SIZES)
+    assert ARCH.sound < TOL
     monkeypatch.setattr(*where, wrong)
-    _loss, _aux, grads = _program(CFG, params, batch)
-    err = _rel(grads["layers"]["router"][0, 1], want["router"])
+    err = ARCH.error(what, only=("grad:router",))
     assert err > 20 * TOL, (what, err)
 
 
@@ -268,7 +180,8 @@ def test_router_choices_are_the_step_s():
     params, batch = _params(), _batch()
     ours = jax.jit(functools.partial(t.router_choices, cfg=CFG))(
         params, batch["tokens"])
-    theirs = reference.losses(params, batch, SIZES)[4]
+    theirs = jax.jit(lambda p, b: reference.losses(p, b, SIZES)[4])(
+        params, batch)
     assert ours.shape == theirs.shape == (
         CFG.n_layers, batch["tokens"].size, CFG.moe_top_k)
     np.testing.assert_array_equal(np.sort(ours, -1), np.sort(theirs, -1))
@@ -320,26 +233,6 @@ def test_init_params_and_shardings_hold_the_new_leaves():
     assert set(dense) == {"embed", "ln_f", "layers"}
     assert set(dense["layers"]) == {"ln1", "ln2", "wq", "wk", "wv", "wo",
                                     "w1", "w2"}
-
-
-def test_the_adapter_draws_init_params_tree_on_the_device():
-    host = t.init_params(np.random.RandomState(0), CFG, 1)
-    ours = jax.device_get(jax.jit(adapter._init_function(CFG))(
-        jax.random.PRNGKey(0)))
-    assert jax.tree_util.tree_structure(host) == \
-        jax.tree_util.tree_structure(ours)
-    for (path, h), o in zip(jax.tree_util.tree_leaves_with_path(host),
-                            jax.tree_util.tree_leaves(ours)):
-        assert h.shape == o.shape and h.dtype == o.dtype, path
-        if float(h.std()) > 0:
-            assert abs(float(o.std()) / float(h.std()) - 1) < 0.1, path
-
-
-def test_the_reference_imports_nothing_of_the_program():
-    with open(os.path.join(_CHIP, "reference", "olmoe.py")) as f:
-        text = f.read()
-    assert "horovod_tpu" not in text.split('"""', 2)[2]
-    assert '"highest"' in text
 
 
 # -- the reference against the public implementation -------------------------

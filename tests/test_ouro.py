@@ -16,121 +16,36 @@ step dropped, ``p_T`` taken from ``lambda_T``, the post-norms left out.
 """
 
 import dataclasses
-import json
-import os
 import re
-import sys
 
 import numpy as np
 import pytest
 import jax
 import jax.numpy as jnp
 
-from horovod_tpu.models import _kinds, decode
+import arch
+from arch import TOL, get_leaves, rel as _rel
+from horovod_tpu.models import _kinds
 from horovod_tpu.models import transformer as t
-from horovod_tpu.models import shard_batch, shard_params
 from horovod_tpu.parallel import build_mesh
 
-_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-_CHIP = os.path.join(_REPO, "benchmarks", "chip")
-if _CHIP not in sys.path:
-    sys.path.insert(0, _CHIP)
-
-from adapters import ouro as adapter          # noqa: E402
-from reference import ouro as reference       # noqa: E402
-from trees import get_leaves                  # noqa: E402
-
-TOL = 1e-4
-
-
-def _tiny():
-    with open(os.path.join(_CHIP, "configs", "ouro-2.6b.json")) as f:
-        config = json.load(f)
-    with open(os.path.join(_CHIP, "workloads", "train.s4096.b1.json")) as f:
-        job = json.load(f)
-    return {**config, **config["tiny"]}, {**job, **job["tiny"]}
-
-
-CONFIG, JOB = _tiny()
-SIZES = adapter.shapes(CONFIG, JOB)
-CFG = adapter._model_config(CONFIG, JOB)
-LEAVES = {
-    "embed": (("embed",), None),
-    "ln_f": (("ln_f",), None),
-    "lm_head": (("lm_head",), None),
-    "exit_gate": (("exit_gate",), None),
-    "exit_gate_bias": (("exit_gate_bias",), None),
-    **{name: (("layers", name), (0, layer))
-       for layer, names in enumerate((
-           ("ln1", "wq", "wk", "wo", "w1", "ln2_post"),
-           ("ln1_post", "wv", "ln2", "w3", "w2")))
-       for name in names},
-}
-
-
-def _params(cfg=CFG, seed=0, n_stages=1):
-    params = jax.tree_util.tree_map(
-        jnp.asarray, t.init_params(np.random.RandomState(seed), cfg,
-                                   n_stages))
-    if "exit_gate_bias" in params:    # a bias of 0 hides a wrong gradient
-        params["exit_gate_bias"] = jnp.full((1,), 0.3, jnp.float32)
-    return params
-
-
-def _batch(n_seqs=2, seed=0):
-    return jax.tree_util.tree_map(
-        jnp.asarray, adapter.host_batch(CONFIG, JOB, seed, 0, n_seqs))
-
-
-def _rel(got, want):
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    return np.linalg.norm(got - want) / np.linalg.norm(want)
-
-
-def _program(cfg, params, batch, mesh_axes=None):
-    """(loss, aux, gradients) by the program's make_grad_fn on a mesh (one
-    device by default)."""
-    axes = mesh_axes or {"dp": 1}
-    n = int(np.prod(list(axes.values())))
-    mesh = build_mesh(devices=jax.devices()[:n], **axes)
-    p = shard_params(params, cfg, mesh)
-    tok, tgt = shard_batch(batch["tokens"], batch["targets"], mesh)
-    return jax.jit(t.make_grad_fn(cfg, mesh))(p, tok, tgt)
-
-
-def _reference(params, batch, sizes=SIZES, leaves=LEAVES):
-    """The plain objective, no checkpoint anywhere, and its gradients."""
-    def objective(p):
-        total, *reported = reference.objective(p, batch, sizes)
-        return total, reported
-    with jax.default_matmul_precision("highest"):
-        (total, (steps, share, entropy)), grads = jax.value_and_grad(
-            objective, has_aux=True)(params)
-    return {"loss": total, "step_losses": steps, "exit_share": share,
-            "gate_entropy": entropy,
-            **{f"grad:{k}": v for k, v in get_leaves(grads, leaves).items()}}
-
-
-@pytest.fixture(scope="module")
-def both_sides():
-    params, batch = _params(), _batch()
-    loss, aux, grads = _program(CFG, params, batch)
-    got = {"loss": loss, **{k: aux[k] for k in (
-        "step_losses", "exit_share", "gate_entropy")},
-        **{f"grad:{k}": v for k, v in get_leaves(grads, LEAVES).items()}}
-    return got, _reference(params, batch), aux
+ARCH = arch.get("ouro")
+reference = ARCH.reference
+SIZES, CFG, LEAVES = ARCH.SIZES, ARCH.CFG, ARCH.LEAVES
+_params, _batch, _program = ARCH.params, ARCH.batch, ARCH.program
 
 
 @pytest.mark.parametrize("what", [
     "loss", "step_losses", "exit_share", "gate_entropy",
     *(f"grad:{k}" for k in LEAVES)])
-def test_program_matches_the_reference(both_sides, what):
-    got, want, _aux = both_sides
+def test_program_matches_the_reference(what):
+    got, want, _aux, _grads = ARCH.sides
+    assert np.linalg.norm(np.asarray(want[what])) > 0
     assert _rel(got[what], want[what]) < TOL, what
 
 
-def test_the_step_reports_its_exit_distribution(both_sides):
-    _got, _want, aux = both_sides
+def test_the_step_reports_its_exit_distribution():
+    _got, _want, aux, _grads = ARCH.sides
     assert set(aux) == {"aux_loss", "step_losses", "exit_share",
                         "gate_entropy"}
     assert aux["step_losses"].shape == aux["exit_share"].shape == (
@@ -148,7 +63,7 @@ def test_three_loop_steps_match_the_reference():
     sizes = {**SIZES, "loops": 3}
     params, batch = _params(cfg, seed=1), _batch(seed=1)
     loss, aux, grads = _program(cfg, params, batch)
-    want = _reference(params, batch, sizes)
+    want = ARCH.want(params, batch, sizes)
     assert _rel(loss, want["loss"]) < TOL
     assert _rel(aux["exit_share"], want["exit_share"]) < TOL
     np.testing.assert_allclose(float(aux["exit_share"].sum()), 1.0,
@@ -226,22 +141,18 @@ def _post_norms_left_out(monkeypatch):
     _gate_in_bf16, _entropy_in_bf16, _unnormed_state_fed_forward,
     _a_loop_step_dropped, _last_share_from_its_own_gate,
     _post_norms_left_out], ids=lambda f: f.__name__.strip("_"))
-def test_a_wrong_term_fails(monkeypatch, both_sides, fault):
+def test_a_wrong_term_fails(monkeypatch, fault):
     """Each moves the loss or the gate's gradient far beyond TOL."""
-    _got, want, _aux = both_sides
+    assert ARCH.sound < TOL
     fault(monkeypatch)
-    loss, _aux, grads = _program(CFG, _params(), _batch())
-    errors = [_rel(loss, want["loss"]),
-              _rel(grads["exit_gate"], want["grad:exit_gate"]),
-              _rel(grads["layers"]["wq"][0, 0], want["grad:wq"])]
-    assert max(errors) > 20 * TOL, (fault.__name__, errors)
+    err = ARCH.error(fault.__name__.strip("_"),
+                     only=("loss", "grad:exit_gate", "grad:wq"))
+    assert err > 20 * TOL, (fault.__name__, err)
 
 
 # -- defaults reproduce the parent's model; remat means something -------------
 
-DENSE = t.TransformerConfig(vocab_size=512, d_model=128, n_heads=4,
-                            n_layers=2, d_ff=256, max_seq=64,
-                            dtype=jnp.float32)
+DENSE = arch.DENSE
 
 
 def _parents_loss(params, tokens, targets, cfg):
@@ -507,34 +418,10 @@ def test_init_params_and_shardings_hold_the_new_leaves():
     assert sh["layers"]["w3"].spec == sh["layers"]["w1"].spec
 
 
-def test_the_adapter_draws_init_params_tree_on_the_device():
-    host = t.init_params(np.random.RandomState(0), CFG, 1)
-    ours = jax.device_get(jax.jit(adapter._init_function(CFG))(
-        jax.random.PRNGKey(0)))
-    assert jax.tree_util.tree_structure(host) == \
-        jax.tree_util.tree_structure(ours)
-    for (path, h), o in zip(jax.tree_util.tree_leaves_with_path(host),
-                            jax.tree_util.tree_leaves(ours)):
-        assert h.shape == o.shape and h.dtype == o.dtype, path
-        if float(h.std()) > 0:
-            assert abs(float(o.std()) / float(h.std()) - 1) < 0.15, path
-
-
-@pytest.mark.parametrize("field", ["post_norm", "ffn_gated", "n_loops"])
-def test_the_decode_paths_refuse_the_new_trees_by_name(field):
-    cfg = dataclasses.replace(DENSE, **{field: 2 if field == "n_loops"
-                                        else True})
-    params = t.init_params(np.random.RandomState(0), cfg, 1)
-    with pytest.raises(NotImplementedError, match=field):
-        decode.flatten_decode_params(params)
-
-
-def test_the_reference_imports_nothing_of_the_program():
-    with open(os.path.join(_CHIP, "reference", "ouro.py")) as f:
+def test_the_plain_objective_is_python_loops_alone():
+    """The objective tier-1 differentiates: the scan and the checkpoints
+    are ``loss_and_grads``' (the chip's check)."""
+    with open(reference.__file__) as f:
         text = f.read()
-    assert "horovod_tpu" not in text.split('"""', 2)[2]
-    assert '"highest"' in text
-    # the objective tier-1 differentiates is Python loops alone: the scan
-    # and the checkpoints are loss_and_grads' (the chip's check)
     plain = text.split("def stored_less", 1)[0].split('"""', 2)[2]
     assert "scan" not in plain and "checkpoint" not in plain

@@ -1,0 +1,281 @@
+"""The flash-attention backward kernel ``hvd_flash_bwd`` against autodiff
+through the XLA oracle (interpret mode), the rule that picks its tile and q
+ranges, and the pair of kernels at a head of 64 (heads first, grouped)."""
+
+import numpy as np
+import pytest
+import jax
+import jax.numpy as jnp
+
+from horovod_tpu.ops import pallas_attention as pa
+from horovod_tpu.ops.pallas_attention import flash_attention_tpu
+from pallas_attention_cases import (LENGTHS, assert_backward, assert_grads,
+                                    backward, cos_cotangent, qkv)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_flash_kernel_grads_match_oracle(causal):
+    assert_grads(*qkv(B=1, S=256, H=2, D=128), causal, cos_cotangent)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("shape", [(1024, 1024, 1), (384, 384, 1),
+                                   (128, 256, 2)])
+def test_flash_kernel_grads_match_oracle_at_the_rules_tiles(shape, causal):
+    """The forward's tile (512 x 1024, 128 x 128, 128 x 256) and the
+    backward's (``flash_bwd_blocks``: 1024 x 1024 in four pieces on the
+    diagonal, 128 x 128, 128 x 256) are two rules."""
+    Sq, Sk, H = shape
+    assert_grads(*qkv(B=1, S=Sq, Sk=Sk, H=H, seed=7), causal,
+                  cos_cotangent)
+
+
+# (Sq, Sk, block_q, block_k, rows): q tiles wider and narrower than k
+# tiles, square tiles of several diagonal pieces, dq resident and in q
+# ranges of two tiles and of one, Sq != Sk both ways
+_BWD_TILES = [(512, 512, 128, 128, 512), (512, 512, 256, 128, 512),
+              (512, 512, 128, 256, 512), (512, 512, 512, 512, 512),
+              (1024, 1024, 512, 512, 1024), (512, 512, 128, 256, 256),
+              (512, 512, 128, 128, 128), (768, 512, 256, 256, 768),
+              (256, 512, 128, 128, 256)]
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("tile", _BWD_TILES)
+def test_flash_backward_tile_overrides(tile, causal):
+    """Every tile and every q range gives the oracle's gradients: the
+    diagonal crosses tiles in every way, tiles above it are skipped, a
+    square tile on it runs in pieces, partial dk / dv of ranges add up."""
+    Sq, Sk, bq, bk, rows = tile
+    got, want = backward(*qkv(B=1, S=Sq, Sk=Sk, H=2, seed=9), causal,
+                          cos_cotangent, pa.BwdBlocks(bq, bk, rows))
+    assert_backward(got, want)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_flash_backward_takes_the_lse_cotangent(causal):
+    q, k, v = qkv(B=1, S=256, H=2, seed=10)
+    weight = jnp.asarray(np.random.RandomState(11).randn(2, 256),
+                         jnp.float32)
+    got, want = backward(q, k, v, causal, cos_cotangent,
+                          lse_weight=weight)
+    assert_backward(got, want)
+    # and it matters: without it dq differs
+    plain, _ = backward(q, k, v, causal, cos_cotangent)
+    assert float(jnp.max(jnp.abs(plain[0] - got[0]))) > 1e-3
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("S", [256, 1024])
+def test_flash_backward_bf16_inputs_match_float32_oracle(S, causal):
+    """bf16 operands multiply as bf16 with float32 accumulation, p and ds
+    are cast for their matmuls: against the float32 oracle on the same
+    values the gradients hold chip_smoke.py's tolerance."""
+    q, k, v = qkv(B=1, S=S, H=1, seed=12, dtype=jnp.bfloat16)
+    got, want = backward(q, k, v, causal, lambda o: jnp.sum(o ** 2))
+    assert got[0].dtype == jnp.bfloat16
+    for g, w, name in zip(got, want, ("dq", "dk", "dv")):
+        err = jnp.max(jnp.abs(g.astype(jnp.float32) - w)) / jnp.max(
+            jnp.abs(w))
+        assert float(err) <= 2e-2, (name, float(err))
+
+
+def test_flash_grads_rect():
+    """Sq != Sk backward (cross-attention shape)."""
+    assert_grads(*qkv(B=1, S=128, Sk=256, seed=2), False,
+                  lambda o: jnp.sum(o ** 2))
+
+
+# -- the backward's tile rule -------------------------------------------------
+
+# what the three causal cells and a ring step of chip_smoke.py call it
+# with (head_dim 128, bf16): (Sq, Sk) -> (block_q, block_k, rows)
+_BWD_RULE = {
+    (2048, 2048): (1024, 1024, 2048),     # gpt-1.3b-widths.s2048
+    (4096, 4096): (1024, 1024, 4096),     # olmoe-1b-7b.s4096, ouro-2.6b.s4096
+    (512, 512): (512, 512, 512),          # ring attention, sp=4 of 2048
+    (128, 256): (128, 256, 128),
+    (384, 640): (128, 128, 384),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(_BWD_RULE))
+def test_flash_bwd_blocks_at_the_shapes_that_run(shape):
+    blocks = pa.flash_bwd_blocks(*shape, 128, jnp.bfloat16)
+    assert blocks == _BWD_RULE[shape]
+    assert blocks.rows == shape[0]        # dq resident: one range
+    assert pa.flash_bwd_vmem_bytes(*blocks, 128, 2) <= pa.BWD_VMEM_BUDGET
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("D", [128, 256, 512])
+def test_flash_bwd_blocks_divide_and_fit(D, dtype):
+    itemsize = jnp.dtype(dtype).itemsize
+    for Sq in LENGTHS + [16384, 65536]:
+        for Sk in LENGTHS:
+            bq, bk, rows = pa.flash_bwd_blocks(Sq, Sk, D, dtype)
+            assert bq in pa.TILES and bk in pa.TILES
+            assert Sq % rows == 0 and rows % bq == 0 and Sk % bk == 0
+            assert pa.flash_bwd_vmem_bytes(bq, bk, rows, D, itemsize) \
+                <= pa.BWD_VMEM_BUDGET
+
+
+def test_flash_bwd_blocks_keep_dq_resident_while_it_fits():
+    """A head's float32 dq and its output block are 8 bytes a row and
+    lane at bf16: resident to 16 384 rows at head_dim 128 beside the
+    smallest tiles; beyond that the q rows go in ranges."""
+    for S in (4096, 8192, 16384):
+        assert pa.flash_bwd_blocks(S, S, 128, jnp.bfloat16).rows == S
+    long = pa.flash_bwd_blocks(65536, 65536, 128, jnp.bfloat16)
+    assert long.rows < 65536 and 65536 % long.rows == 0
+    assert pa.flash_bwd_grid(1, 2, 65536, 65536, long)[1] \
+        == 65536 // long.rows
+    # a length whose only divisors are 1 and itself goes tile by tile
+    prime = pa.flash_bwd_blocks(128 * 251, 128 * 251, 128, jnp.bfloat16)
+    assert prime == (128, 128, 128)
+    # float32 and a wider head hold fewer rows
+    assert pa.flash_bwd_blocks(16384, 16384, 256, jnp.float32).rows < 16384
+    assert pa.flash_bwd_grid(2, 16, 2048, 2048, pa.BwdBlocks(
+        1024, 1024, 2048)) == (32, 1, 2, 2)
+
+
+@pytest.mark.parametrize("tile", [(2048, 2048, 1024, 1024, 3, 4),
+                                  (2048, 2048, 512, 512, 10, 16),
+                                  (4096, 4096, 1024, 1024, 10, 16),
+                                  (512, 1024, 256, 128, 6, 16),
+                                  (1024, 512, 128, 256, 14, 16)])
+def test_flash_bwd_causal_tiles_above_the_diagonal_are_not_visited(tile):
+    """The q tile a grid step fetches is clamped to the k tile's first
+    live one, and a q tile's dq is written at its last live k tile: by
+    that arithmetic the live tiles are those the mask leaves anything
+    of (S 2048: 3 of 4 at 1024 x 1024, 10 of 16 at 512 x 512)."""
+    Sq, Sk, bq, bk, live, steps = tile
+    nq, nk = Sq // bq, Sk // bk
+    assert nq * nk == steps
+    seen = 0
+    for kj in range(nk):
+        first = min(pa._first_live_q_tile(kj, bq, bk), nq)
+        for qi in range(nq):
+            any_live = qi * bq + bq - 1 >= kj * bk
+            assert any_live == (qi >= first)
+            if any_live:
+                assert kj <= min(pa._last_live_k_tile(qi, bq, bk), nk - 1)
+            seen += any_live
+    assert seen == live
+    for qi in range(nq):     # the write comes at a live tile, the last
+        last = min(pa._last_live_k_tile(qi, bq, bk), nk - 1)
+        assert qi >= pa._first_live_q_tile(last, bq, bk)
+        assert last == nk - 1 or qi < pa._first_live_q_tile(last + 1, bq,
+                                                            bk)
+
+
+@pytest.mark.parametrize("Sq,Sk", [(100, 128), (128, 192)])
+def test_flash_bwd_blocks_refuses_what_128_does_not_divide(Sq, Sk):
+    with pytest.raises(ValueError):
+        pa.flash_bwd_blocks(Sq, Sk, 128, jnp.float32)
+
+
+def test_flash_grads_rect_causal():
+    """Sq != Sk under the causal mask, both ways (top-left alignment: a
+    k tile beyond the last q row gets zeros)."""
+    assert_grads(*qkv(B=1, S=256, Sk=128, seed=13), True,
+                  lambda o: jnp.sum(o ** 2))
+    assert_grads(*qkv(B=1, S=128, Sk=384, seed=14), True,
+                  lambda o: jnp.sum(o ** 2))
+
+
+# -- a head of 64: heads first, grouped, a scale of its own (PR 49) -----------
+
+def _narrow(B=1, S=256, H=8, Hkv=2, D=64, seed=11, dtype=jnp.float32):
+    rng = np.random.RandomState(seed)
+
+    def mk(heads):
+        return (jnp.asarray(rng.randn(B, S, heads, D), jnp.float32)
+                * 0.8).astype(dtype)
+    return mk(H), mk(Hkv), mk(Hkv)
+
+
+#: (S, H, Hkv, block_q, block_k, rows of a q range): 4 query heads a k/v
+#: head as granite-4.0-h-micro's 32 / 8, tiles that meet the diagonal corner
+#: to corner and that do not, dq resident and in two q ranges
+_NARROW_TILES = [(256, 8, 2, 128, 128, 256), (512, 4, 1, 256, 256, 512),
+                 (512, 8, 2, 128, 256, 512), (512, 4, 4, 256, 128, 256),
+                 (256, 2, 2, 256, 256, 256)]
+SCALE = 1 / 64
+
+
+@pytest.mark.parametrize("tile", _NARROW_TILES)
+def test_flash_pair_at_a_head_of_64_is_the_banded_form(tile):
+    """Forward, dq, dk and dv at heads of 64, grouped, causal, without
+    positions, scores times 1/64 (not 1/sqrt(64)) against
+    ``_banded_attention``: the kernels heads first, a k/v head's gradient
+    the sum over its group's query heads and the q ranges."""
+    S, H, Hkv, bq, bk, rows = tile
+    q, k, v = _narrow(S=S, H=H, Hkv=Hkv)
+    o, lse = pa.flash_attention_with_lse(q, k, v, True, SCALE, bq, bk,
+                                         interpret=True)
+    want = pa._banded_attention(q, k, v, None, SCALE)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(want), rtol=2e-5,
+                               atol=2e-5)
+    assert lse.shape == (H, S) and lse.dtype == jnp.float32
+    w = jnp.cos(jnp.arange(o.size, dtype=jnp.float32).reshape(o.shape))
+    got = pa.flash_backward(q, k, v, o, lse, w, jnp.zeros_like(lse), True,
+                            SCALE, pa.BwdBlocks(bq, bk, rows),
+                            interpret=True)
+    ref = jax.jit(jax.grad(lambda q, k, v: jnp.sum(
+        pa._banded_attention(q, k, v, None, SCALE) * w), (0, 1, 2)))(q, k, v)
+    for g, r, name in zip(got, ref, ("dq", "dk", "dv")):
+        assert g.shape == r.shape and g.dtype == r.dtype, name
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), rtol=2e-4,
+                                   atol=2e-4, err_msg=name)
+    # 1/sqrt(D) is another function
+    other = pa.flash_attention_tpu(q, k, v, True, None, bq, bk,
+                                   interpret=True)
+    assert float(jnp.max(jnp.abs(other - want))) > 1e-2
+
+
+def test_flash_at_a_head_of_64_is_differentiable_through_the_custom_vjp():
+    """``attend``'s way in: ``flash_attention_tpu`` with the rule's tiles,
+    bfloat16 operands, against the float32 banded form."""
+    q, k, v = _narrow(S=512, dtype=jnp.bfloat16)
+    w = jnp.sin(jnp.arange(q.size, dtype=jnp.float32).reshape(q.shape))
+
+    def loss(f):
+        return lambda q, k, v: jnp.sum(f(q, k, v).astype(jnp.float32) * w)
+    got = jax.jit(jax.grad(loss(lambda q, k, v: flash_attention_tpu(
+        q, k, v, True, SCALE, interpret=True)), (0, 1, 2)))(q, k, v)
+    want = jax.jit(jax.grad(loss(lambda q, k, v: pa._banded_attention(
+        q, k, v, None, SCALE)), (0, 1, 2)))(
+            *(x.astype(jnp.float32) for x in (q, k, v)))
+    for g, r, name in zip(got, want, ("dq", "dk", "dv")):
+        assert g.dtype == jnp.bfloat16, name
+        err = float(jnp.max(jnp.abs(g.astype(jnp.float32) - r))
+                    / jnp.max(jnp.abs(r)))
+        assert err < 2e-2, (name, err)
+
+
+def test_a_window_at_a_head_of_64():
+    q, k, v = _narrow(S=512, H=4, Hkv=2)
+    got = flash_attention_tpu(q, k, v, True, SCALE, 128, 128, interpret=True,
+                              window=256)
+    want = pa._banded_attention(q, k, v, 256, SCALE)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5,
+                               atol=2e-5)
+
+
+def test_attend_takes_the_kernels_at_a_head_of_64_on_a_tpu(monkeypatch):
+    assert pa.attention_path(4096, 4096, 32, 64, True, False) == "xla"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert pa.attention_path(4096, 4096, 32, 64, True, False) == "flash"
+    # BERT's core (a key mask, non-causal) stays on the block kernels
+    assert pa.attention_path(512, 512, 16, 64, False, True) == "block"
+    assert pa.attention_path(128, 128, 16, 64, False, True) == "xla"
+    q = jax.ShapeDtypeStruct((1, 256, 8, 64), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 256, 2, 64), jnp.bfloat16)
+    text = str(jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(pa.attend(
+        q, k, v, causal=True, scale=SCALE).astype(jnp.float32)), (0, 1, 2))
+    )(q, kv, kv))
+    assert "hvd_flash_attention" in text and "hvd_flash_bwd" in text
+    # heads first: the kernels' operands are [B * heads, S, 64]
+    assert "bf16[8,256,64]" in text and "bf16[2,256,64]" in text
+

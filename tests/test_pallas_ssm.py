@@ -87,16 +87,19 @@ def test_the_forward_kernel_is_the_numpy_form_and_the_recurrence(shape):
     assert _rel(got, _stepwise(*ops)) < TOL
 
 
+def _cotangents(f, weight, ops, of=(0, 1, 2, 3, 4)):
+    """The cotangents ``of`` the operands under ``sum(f(*ops) weight)``,
+    as one traced and compiled program."""
+    return jax.jit(jax.grad(lambda *v: jnp.sum(f(*v) * weight), of))(*ops)
+
+
 @pytest.mark.parametrize("shape", SHAPES.values(), ids=SHAPES.keys())
 def test_every_cotangent_is_autodiff_s_of_the_numpy_form(shape):
     """x, dt (through the sums and directly), a, b and c."""
     ops, chunk, weight = _operands(shape), shape[-1], _weight(shape)
-    got = jax.grad(lambda *v: jnp.sum(_kernels(*v, chunk) * weight),
-                   (0, 1, 2, 3, 4))(*ops)
-    want = jax.grad(lambda *v: jnp.sum(mamba.ssm_chunked(*v, chunk) * weight),
-                    (0, 1, 2, 3, 4))(*ops)
-    stepwise = jax.grad(lambda *v: jnp.sum(_stepwise(*v) * weight),
-                        (0, 1, 2, 3, 4))(*ops)
+    got = _cotangents(lambda *v: _kernels(*v, chunk), weight, ops)
+    want = _cotangents(lambda *v: mamba.ssm_chunked(*v, chunk), weight, ops)
+    stepwise = _cotangents(_stepwise, weight, ops)
     for name, g, w, r in zip(NAMES, got, want, stepwise):
         assert g.dtype == w.dtype and g.shape == w.shape, name
         assert _rel(g, w) < TOL, name
@@ -110,10 +113,10 @@ def test_dt_s_and_the_sums_cotangents_apart():
     shape = SHAPES["two heads a tile"]
     (x, dt, a, b, c), chunk, weight = _operands(shape), 16, _weight(shape)
     s = _sums(dt, a, chunk)
-    got = jax.grad(lambda dt, s: jnp.sum(
-        ps.ssm_scan(x, dt, s, b, c, chunk, True) * weight), (0, 1))(dt, s)
-    want = jax.grad(lambda dt, s: jnp.sum(
-        mamba._ssm_chunked_numpy(x, dt, s, b, c, chunk) * weight), (0, 1))(dt, s)
+    got = _cotangents(lambda dt, s: ps.ssm_scan(x, dt, s, b, c, chunk, True),
+                      weight, (dt, s), (0, 1))
+    want = _cotangents(lambda dt, s: mamba._ssm_chunked_numpy(
+        x, dt, s, b, c, chunk), weight, (dt, s), (0, 1))
     for name, g, w in zip(("dt", "s"), got, want):
         assert _rel(g, w) < TOL, name
     # neither is the other's, nor small beside it
@@ -132,8 +135,8 @@ def test_one_chunk_is_the_whole_sequence_and_the_state_crosses_chunks():
     assert _rel(_kernels(x[:, :16], dt[:, :16], a, b[:, :16], c[:, :16], 16),
                 whole[:, :16]) < TOL
     weight = _weight(shape)
-    one, four = (jax.grad(lambda *v: jnp.sum(_kernels(*v, chunk) * weight),
-                          (0, 1, 2, 3, 4))(*ops) for chunk in (64, 16))
+    one, four = (_cotangents(lambda *v: _kernels(*v, chunk), weight, ops)
+                 for chunk in (64, 16))
     for name, g, w in zip(NAMES, four, one):
         assert _rel(g, w) < TOL, name
 
@@ -172,10 +175,8 @@ def test_a_wrong_piece_fails_on_the_kernels(monkeypatch, what, patches):
 
     def errors():
         forward = _rel(_kernels(*ops, 16), _stepwise(*ops))
-        got = jax.grad(lambda *v: jnp.sum(_kernels(*v, 16) * weight),
-                       (0, 1, 2, 3, 4))(*ops)
-        want = jax.grad(lambda *v: jnp.sum(_stepwise(*v) * weight),
-                        (0, 1, 2, 3, 4))(*ops)
+        got = _cotangents(lambda *v: _kernels(*v, 16), weight, ops)
+        want = _cotangents(_stepwise, weight, ops)
         return forward, max(_rel(g, w) for g, w in zip(got, want))
     assert max(errors()) < TOL
     for name, wrong in patches.items():
@@ -194,10 +195,8 @@ def test_bfloat16_operands_keep_float32_sums_decays_and_state():
     got = _kernels(*ops, 16)
     assert got.dtype == jnp.float32
     assert _rel(got, mamba.ssm_chunked(*ops, 16)) < 1e-2
-    grads = jax.grad(lambda *v: jnp.sum(_kernels(*v, 16) * weight),
-                     (0, 1, 2, 3, 4))(*ops)
-    want = jax.grad(lambda *v: jnp.sum(mamba.ssm_chunked(*v, 16) * weight),
-                    (0, 1, 2, 3, 4))(*ops)
+    grads = _cotangents(lambda *v: _kernels(*v, 16), weight, ops)
+    want = _cotangents(lambda *v: mamba.ssm_chunked(*v, 16), weight, ops)
     for name, g, w, op in zip(NAMES, grads, want, ops):
         assert g.dtype == op.dtype, name
         assert _rel(g, w) < 2e-2, name
@@ -299,11 +298,10 @@ def test_head_tiles_give_every_cotangent(shape, head_tile):
     against autodiff of the ``jax.numpy`` form from the same operands."""
     ops, chunk = _tiled(shape, head_tile)
     weight = _weight(shape)
-    got = jax.grad(lambda *v: jnp.sum(
-        ps.ssm_scan(*v, chunk, True, head_tile) * weight),
-        (0, 1, 2, 3, 4))(*ops)
-    want = jax.grad(lambda *v: jnp.sum(
-        mamba._ssm_chunked_numpy(*v, chunk) * weight), (0, 1, 2, 3, 4))(*ops)
+    got = _cotangents(lambda *v: ps.ssm_scan(*v, chunk, True, head_tile),
+                      weight, ops)
+    want = _cotangents(lambda *v: mamba._ssm_chunked_numpy(*v, chunk),
+                       weight, ops)
     for name, g, w in zip(("x", "dt", "s", "b", "c"), got, want):
         assert g.dtype == w.dtype and g.shape == w.shape, name
         assert _rel(g, w) < TOL, name
@@ -318,8 +316,8 @@ def test_a_head_tile_s_parts_of_db_are_summed_in_float32():
     weight = _weight(shape)
 
     def grads(tile):
-        return jax.grad(lambda *v: jnp.sum(
-            ps.ssm_scan(*v, chunk, True, tile) * weight), (3, 4))(*ops)
+        return _cotangents(lambda *v: ps.ssm_scan(*v, chunk, True, tile),
+                           weight, ops, (3, 4))
     for name, g, w in zip(("b", "c"), grads(head_tile), grads(8)):
         assert g.dtype == jnp.bfloat16, name
         assert _rel(g.astype(jnp.float32), w.astype(jnp.float32)) < 4e-3, name

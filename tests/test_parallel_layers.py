@@ -68,8 +68,8 @@ def test_ring_attention_flash_path_grads():
     def loss_ref(q, k, v):
         return jnp.sum(_plain_attention(q, k, v, causal=True) ** 2)
 
-    gr = jax.grad(loss_ring, argnums=(0, 1, 2))(q, k, v)
-    gf = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    gr = jax.jit(jax.grad(loss_ring, argnums=(0, 1, 2)))(q, k, v)
+    gf = jax.jit(jax.grad(loss_ref, argnums=(0, 1, 2)))(q, k, v)
     for a, b, name in zip(gr, gf, "qkv"):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=5e-4, atol=5e-4, err_msg=name)
