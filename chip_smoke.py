@@ -1266,20 +1266,24 @@ def _flash_call(shape, kv_heads=None, window=None) -> str:
     ranges = S // bwd.rows
     form = ("dq resident" if ranges == 1
             else f"dq in {ranges} q ranges of {bwd.rows} rows")
-    def blocks(crossed):
-        ran, of = pa.tile_piece_blocks(bq, bk, crossed)
-        return f"{ran} of {of} blocks"
+    def pieces(of, bq, bk):
+        """A kernel's tile in pieces, and what of a crossed tile runs."""
+        def blocks(crossed):
+            ran, every = pa.tile_piece_blocks(bq, bk, crossed)
+            return f"{ran} of {every} blocks"
+        text = (f"{of} in {len(pa.tile_pieces(bq, bk))} pieces of "
+                f"{pa.piece_rows(bk)} k rows")
+        if not pa.banded_tiles(bq, bk, window):
+            return text + (", all q rows of a tile the diagonal or the "
+                           "band's edge crosses")
+        text += f", on the diagonal {blocks('diagonal')}"
+        if window is not None:
+            text += f", on the band's edge {blocks('edge')}"
+        return text
     fwd = ("operands in place " + str([B, S, H * D]) if D % pa.MIN_BLOCK == 0
            else "operands heads first " + str([B * H, S, D]))
     fwd += (f", scores [k, q] with m, l [1, {bq}] and acc [{D}, {bq}] along "
-            f"the lanes, a tile in {len(pa.tile_pieces(bq, bk))} pieces of "
-            f"{pa.fwd_piece_rows(bk)} k rows")
-    if not pa.banded_tiles(bq, bk, window):
-        fwd += ", all q rows of a tile the diagonal or the band's edge crosses"
-    else:
-        fwd += f", on the diagonal {blocks('diagonal')}"
-        if window is not None:
-            fwd += f", on the band's edge {blocks('edge')}"
+            f"the lanes, {pieces('a tile', bq, bk)}")
     band = ""
     if window is not None:
         def tiles(grid, bq, bk):
@@ -1290,9 +1294,13 @@ def _flash_call(shape, kv_heads=None, window=None) -> str:
                  f"backward {tiles(bwd_grid, bwd.block_q, bwd.block_k)}")
     if kv_heads not in (None, H):
         band += f"; kv heads {kv_heads}, group {H // kv_heads}"
+    crossed = pieces("a crossed tile", *bwd[:2])
     return (f"pallas hvd_flash_attention {bq}x{bk}, {math.prod(grid)} steps, "
             f"{fwd}; hvd_flash_bwd {bwd.block_q}x{bwd.block_k}, {form}, "
-            f"{math.prod(bwd_grid)} steps, VMEM "
+            f"{math.prod(bwd_grid)} steps, "
+            f"{crossed}, sT and dpT written {pa.BWD_AHEAD} ahead, a clean "
+            f"tile in {len(pa.bwd_tile_pieces(*bwd[:2], False))}, dqT "
+            f"[{D}, {bwd.block_q}] a q tile, VMEM "
             f"estimate {pa.flash_bwd_vmem_bytes(*bwd, D, 2) / 2 ** 20:.1f} "
             f"MiB{band}")
 

@@ -23,7 +23,7 @@ rows]`` and is transposed once a q tile on its way out, and ``lse`` is
 written as the row it is. With ``m`` and ``l`` as ``[q rows, 1]`` columns
 (a vreg for 8 rows, one lane used) every q row paid ~4.2 ns a k step for two
 reductions across the lanes and three broadcasts back. Since a step's row
-work is now small, a tile runs as **pieces of ``FWD_PIECE_ROWS`` k rows**,
+work is now small, a tile runs as **pieces of ``PIECE_ROWS`` k rows**,
 each an online-softmax update of its own, and a piece's score matmul is
 written before the reduction of the piece before it: Mosaic's scheduler keeps
 the order it is given, so written score tile, reduction, p·v the MXU waits
@@ -113,7 +113,7 @@ score-shaped array to HBM:
   grid = (batch·heads, q ranges, Sk/block_k, rows/block_q) — q tile innermost
   (under a window: the q tiles of a k tile's band)
   per (k tile): for each q tile: pT = exp(k @ qᵀ·scale − lse);
-      dv += pT @ do; dsT = pT ⊙ (v @ doᵀ − adj); dk += dsT @ q; dq += dsTᵀ @ k
+      dv += pT @ do; dsT = pT ⊙ (v @ doᵀ − adj); dk += dsT @ q; dqT += kᵀ @ dsT
 
 One kernel, not the usual dk/dv + dq pair: a head's whole float32 dq
 (``Sq·D·4`` bytes, 2 MiB at 4096 positions) stays in a VMEM scratch across
@@ -124,8 +124,15 @@ fit (tens of thousands of positions on one device) the q rows go in ranges
 (``flash_bwd_blocks``; no wider than a window either): 1024 × 1024 at the
 benchmark's shapes without a window narrower than that, where the
 MXU is at 86 % of its peak on the tiles it runs whole (v5e; PERF.md, PR
-31); a square tile on the diagonal runs in ``DIAG_ROWS``-row pieces that
-leave out the q rows masked for the whole piece. Same precision as the forward:
+31). A tile that a mask's line crosses runs in the forward's pieces
+(:func:`bwd_tile_pieces`; on the diagonal and a window's edge they leave
+out the q rows masked for the whole piece), and a piece is written in two
+parts: the two matmuls that need operands alone (``sT``, ``dpT``)
+``BWD_AHEAD`` pieces before the ``exp``, ``dsT`` and the three matmuls that
+need the piece's scores, so the MXU runs a piece's first two while the
+vector unit is at the piece before; a clean tile, which its five matmuls
+bound, runs whole; dq accumulates transposed, ``dqT += kT @ dsT`` (PR 58).
+Same precision as the forward:
 operands multiply as they come and accumulate in float32, ``pT`` and
 ``dsT`` are float32 in VMEM and cast for their matmuls. q, k, v and do are
 read as ``[B, S, H·D]``, a head whole 128-lane columns, and dq, dk, dv
@@ -208,7 +215,7 @@ def flash_vmem_bytes(block_q: int, block_k: int, D: int, itemsize: int) -> int:
     cast for p·v, the float32 accumulator ``[D, bq]`` and its transpose on
     the way out, and the ``m`` / ``l`` rows."""
     io = 2 * (2 * block_q + 2 * block_k) * D * itemsize + 2 * 8 * block_q * 4
-    pieces = 2 * fwd_piece_rows(block_k) * block_q * (4 + 4 + itemsize)
+    pieces = 2 * piece_rows(block_k) * block_q * (4 + 4 + itemsize)
     scratch = 2 * block_q * D * 4 + 2 * 8 * block_q * 4
     return io + pieces + scratch
 
@@ -361,12 +368,12 @@ def _band_q_tile(kj, step, first_tile, tiles: int, block_q: int,
 #: reduction, its p . v) the same pieces take what the tile whole takes
 #: (7.949, 0.389, 5.489, 1.117): Mosaic's scheduler keeps the order it is
 #: given, so the MXU waits for the vector unit and back (PERF.md, PR 56)
-FWD_PIECE_ROWS = 128
+PIECE_ROWS = 128
 
 
-def fwd_piece_rows(block_k: int) -> int:
+def piece_rows(block_k: int) -> int:
     """k rows of a piece of :func:`tile_pieces`, from the tile."""
-    return min(block_k, FWD_PIECE_ROWS)
+    return min(block_k, PIECE_ROWS)
 
 
 def banded_tiles(block_q: int, block_k: int, window: Optional[int]) -> bool:
@@ -382,14 +389,14 @@ def tile_pieces(block_q: int, block_k: int, crossed: Optional[str] = None):
     """The static pieces the forward runs of a tile, as ``(k0, rows, q0,
     q1)``: k rows ``[k0, k0 + rows)`` against q rows ``[q0, q1)`` of the
     tile (the backward's ``diagonal()`` / ``edge()`` geometry), in
-    :func:`fwd_piece_rows` k rows. ``crossed`` is None for all q rows
+    :func:`piece_rows` k rows. ``crossed`` is None for all q rows
     (a tile inside the band, or one a mask's line cuts anywhere), else the
     line that crosses a square tile corner to corner: ``"diagonal"`` leaves
     out the q rows before a piece's first k row and ``"edge"`` (a window's
     lower edge) the ones from its last k row on, which the mask kills for
     the whole piece: a 1024 x 1024 tile runs 36 of its 64 blocks of 128 x
     128 in eight pieces."""
-    rows = fwd_piece_rows(block_k)
+    rows = piece_rows(block_k)
     span = {None: lambda k0: (0, block_q),
             "diagonal": lambda k0: (k0, block_q),
             "edge": lambda k0: (0, k0 + rows)}[crossed]
@@ -465,7 +472,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
         """A tile in :func:`tile_pieces`, all in one basic block, a piece's
         score matmul written before the piece before it is reduced: the
         scheduler keeps that order, and the MXU then runs a piece's scores
-        while the vector unit reduces the last (``FWD_PIECE_ROWS``)."""
+        while the vector unit reduces the last (``PIECE_ROWS``)."""
         def tile():
             pieces = tile_pieces(block_q, block_k, line)
             st = scores(masked, pieces[0])
@@ -626,13 +633,41 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret, window):
 # rows, the accumulator ``[D, bq]``); here it is the row's log-sum-exp and
 # its ``adj``, read as the ``[1, bq]`` rows the forward wrote, and of the
 # five matmuls only dq's needs a transposed operand (q-major scores need it
-# for dv and for dk).
+# for dv and for dk): computed as ``dqT = kT @ dsT`` into a ``[D, bq]``
+# accumulator a q tile, it is the k rows that are transposed, ``[rows, D]``,
+# and not the score-shaped ``dsT`` (the forward's ``accT``; PR 58).
 
-#: k rows of a piece of a square tile on the diagonal
-#: (:func:`_flash_bwd_kernel`). v5e, a 1024 x 1024 tile, ms a call at the
-#: looped cell's shape: whole 1.311, pieces of 512 1.229, 256 1.232, 128
-#: 1.195 (PERF.md, PR 31)
-DIAG_ROWS = 128
+#: pieces of a crossed tile whose two operand-only matmuls (``sT``, ``dpT``)
+#: the backward writes ahead of the piece whose ``exp`` and ``dsT`` it is at
+#: (:func:`_flash_bwd_kernel`, :func:`bwd_tile_pieces`). v5e, the kernel's
+#: ms a call alone under a window of 512 ([1, 8192, 64 on 8, 128], 512 x 512
+#: tiles, every live tile crossed): the parent (a piece's five matmuls in
+#: the order ``sT``, dv, ``dpT``, dk, dq) 4.292, ``sT`` and ``dpT`` first
+#: and none ahead 3.881, one ahead 3.542, two 3.433 (with ``dqT`` 3.581 /
+#: 3.538), pieces of 256 k rows one ahead 3.789. On the full calls the
+#: diagonal tiles are a seventh of the work: none / one / two ahead 12.581 /
+#: 12.543 / 12.533 at [1, 8192, 48 on 8, 128] (PERF.md, PR 58)
+BWD_AHEAD = 1
+
+
+def bwd_tile_pieces(block_q: int, block_k: int, masked: bool,
+                    crossed: Optional[str] = None):
+    """The static pieces the backward runs of a tile, as
+    :func:`tile_pieces` gives them: the forward's where a mask's line
+    crosses the tile (``masked``; ``crossed`` as there), the tile whole
+    where none does. A clean tile is bound by its five matmuls and gains
+    nothing from any order, and every piece adds a pass over the q rows'
+    float32 dq: v5e, ms a call alone, the tile whole / in two pieces of 512
+    k rows / in eight of 128, each one ahead: [1, 8192, 48 on 8, 128]
+    12.308 / 12.451 / 12.499 (the parent whole 12.549; with ``dsT``
+    transposed for dq, as the parent has it, 12.543 / 12.602 / 13.541),
+    [1, 4096, 16, 128] 1.079 / 1.090 / 1.093, [2, 8192, 32 on 8, 64]
+    heads first 14.380 / 14.565 / 14.647 (PERF.md, PR 58)."""
+    if masked:
+        return tile_pieces(block_q, block_k, crossed)
+    return [(0, block_k, 0, block_q)]
+
+
 #: bytes the backward's working set may take by ``flash_bwd_vmem_bytes``,
 #: asked for as the call's scoped-VMEM limit (the v5e has 128 MiB)
 BWD_VMEM_BUDGET = 32 * 1024 * 1024
@@ -650,15 +685,21 @@ def flash_bwd_vmem_bytes(block_q: int, block_k: int, rows: int, D: int,
     """Working set of one grid step of the backward: q, do, k, v tiles and
     the dk, dv and ``[rows, D]`` dq output blocks double-buffered by the
     pipeline, the lse and adj rows (a ``[1, bq]`` float32 block takes 8
-    sublanes), the float32 accumulators of dk, dv and dq, and the score
-    tile five times: ``sT`` / ``pT``, ``dpT`` and ``dsT`` in float32, dsT
-    transposed for dq's matmul, and the two casts for the matmuls."""
+    sublanes), the float32 accumulators of dk, dv and dqT, and the score
+    tile of a clean tile, which runs whole (:func:`bwd_tile_pieces`; a
+    crossed tile's pieces in flight are less): a float32 copy and a cast
+    and a half. That is the compiler's own count rounded up: the least
+    scoped-VMEM limit that compiles for a described v5e leaves, above the
+    blocks and accumulators, 5.3 to 6.8 bytes a score at bfloat16 (512 x
+    512 and 1024 x 1024 at heads of 128; 1024 x 1024 at a head of 256:
+    30.87 MiB in all) and 8.3 at float32 (PERF.md, PR 58; the parent
+    counted the tile five times, 18 bytes)."""
     io = 2 * (2 * block_q + 2 * block_k) * D * itemsize
     stats = 2 * 2 * 8 * block_q * 4
     out = 2 * (2 * block_k + rows) * D * itemsize
     scratch = (2 * block_k + rows) * D * 4
-    tiles = block_q * block_k * (3 * 4 + 3 * itemsize)
-    return io + stats + out + scratch + tiles
+    tile = block_q * block_k * (4 + 3 * itemsize // 2)
+    return io + stats + out + scratch + tile
 
 
 def flash_bwd_blocks(Sq: int, Sk: int, D: int, dtype,
@@ -728,7 +769,8 @@ def _flash_bwd_kernel(q_ref, do_ref, k_ref, v_ref, lse_ref, adj_ref,
                       block_k: int, window: Optional[int] = None):
     """One (k tile, q tile) step; grid (BH, q ranges, nk, nq) with q
     innermost: dk and dv of the k tile accumulate over the q tiles, dq of
-    the range's rows over the k tiles. Under a window the q axis spans the
+    the range's rows over the k tiles, transposed (``dq_acc`` is ``[q tiles
+    of the range, D, block_q]``). Under a window the q axis spans the
     k tile's band (:func:`flash_bwd_grid`): ``qi`` is the q tile the step
     stands for and ``i`` its place in the range, both past the band's last
     tile where the step runs nothing (``live``)."""
@@ -738,7 +780,7 @@ def _flash_bwd_kernel(q_ref, do_ref, k_ref, v_ref, lse_ref, adj_ref,
         i = step
         qi = pl.program_id(1) * nq + i        # the q tile in the sequence
     else:
-        tiles = dq_acc.shape[0] // block_q    # the q tiles of a range
+        tiles = dq_acc.shape[0]               # the q tiles of a range
         first_tile = pl.program_id(1) * tiles
         qi, last_qi = _band_q_tile(kj, step, first_tile, tiles, block_q,
                                    block_k, window)
@@ -749,13 +791,13 @@ def _flash_bwd_kernel(q_ref, do_ref, k_ref, v_ref, lse_ref, adj_ref,
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    def part(k0: int, n_k: int, q0: int, masked: bool,
-             q1: int = block_q):
-        """The tile's k rows ``[k0, k0 + n_k)`` against its q rows ``[q0,
-        q1)`` (static): dk and dv of those k rows, dq of those q rows."""
+    def ahead(masked: bool, piece):
+        """What of a piece (static) needs its operands alone: float32
+        ``sT``, masked where asked, and ``dpT = v @ doT``, both ``[rows, q1
+        - q0]``."""
+        k0, n_k, q0, q1 = piece
         ks, qs = pl.ds(k0, n_k), pl.ds(q0, q1 - q0)
-        q, do, k, v = q_ref[0, qs], do_ref[0, qs], k_ref[0, ks], v_ref[0, ks]
-        st = _dot(k, q, _NT) * scale                    # [n_k, q1 - q0]
+        st = _dot(k_ref[0, ks], q_ref[0, qs], _NT) * scale
         if masked:
             kpos = kj * block_k + k0 + lax.broadcasted_iota(
                 jnp.int32, st.shape, 0)
@@ -765,72 +807,74 @@ def _flash_bwd_kernel(q_ref, do_ref, k_ref, v_ref, lse_ref, adj_ref,
             if window is not None:
                 live = jnp.logical_and(live, kpos > qpos - window)
             st = jnp.where(live, st, NEG_INF)
-        pt = jnp.exp(st - lse_ref[0, :, qs])            # lse: [1, bq - q0]
+        return st, _dot(v_ref[0, ks], do_ref[0, qs], _NT)
+
+    def finish(piece, st, dpt):
+        """What needs the piece's scores: dk and dv of its k rows, dq of
+        its q rows."""
+        k0, n_k, q0, q1 = piece
+        ks, qs = pl.ds(k0, n_k), pl.ds(q0, q1 - q0)
+        q, do = q_ref[0, qs], do_ref[0, qs]
+        pt = jnp.exp(st - lse_ref[0, :, qs])            # lse: [1, q1 - q0]
         dv_acc[ks] += _dot(pt.astype(do.dtype), do, _NN)
         # d loss / d s = p * (dp - adj); s = scale * q kT, and the scale
         # goes on dq and dk as they are written, not on the score tile
-        dst = (pt * (_dot(v, do, _NT) - adj_ref[0, :, qs])).astype(q.dtype)
+        dst = (pt * (dpt - adj_ref[0, :, qs])).astype(q.dtype)
         dk_acc[ks] += _dot(dst, q, _NN)
-        dq = _dot(dst, k, _TN)                          # [bq - q0, D]
-        rows = pl.ds(pl.multiple_of(i * block_q + q0, MIN_BLOCK),
-                     q1 - q0)
+        # dqT = kT @ dsT: the operand to transpose is the k rows, not the
+        # score-shaped dsT
+        dq = _dot(k_ref[0, ks], dst, _TN)               # [D, q1 - q0]
+        at = (i, slice(None), qs)
         if window is not None:  # zeroed at the rows' first live tile
-            dq_acc[rows] += dq
+            dq_acc[at] += dq
         elif k0 == 0:   # the rows' first k rows, if this is the first tile
             @pl.when(kj == 0)       # every q tile meets the first k tile
             def _first():
-                dq_acc[rows] = dq
+                dq_acc[at] = dq
 
             @pl.when(kj > 0)
             def _later():
-                dq_acc[rows] += dq
+                dq_acc[at] += dq
         else:
-            dq_acc[rows] += dq
+            dq_acc[at] += dq
 
-    def whole(masked: bool):
-        part(0, block_k, 0, masked)
-
-    def diagonal():
-        """A square tile on the diagonal, ``DIAG_ROWS`` k rows at a time:
-        the q rows before a piece's first column are masked for all of it
-        and are left out, so a 1024 x 1024 tile runs 36 of its 64 blocks
-        of 128 x 128 in eight pieces."""
-        piece = min(DIAG_ROWS, block_k)
-        for k0 in range(0, block_k, piece):
-            part(k0, piece, k0, True)
-
-    def edge():
-        """A square tile whose corners lie on the band's lower edge (the
-        window a multiple of the tile): k row ``c`` is live for the q rows
-        ``r < c``, so a piece leaves out the q rows from its last k row
-        on."""
-        piece = min(DIAG_ROWS, block_k)
-        for k0 in range(0, block_k, piece):
-            part(k0, piece, 0, True, k0 + piece)
+    def run(masked: bool, line: Optional[str] = None):
+        """A tile in :func:`bwd_tile_pieces`, all in one basic block, the
+        two operand-only matmuls of the next ``BWD_AHEAD`` pieces written
+        before a piece's vector work: the scheduler keeps that order, and
+        the MXU then runs them while the vector unit takes the mask, the
+        ``exp`` and ``dsT`` of the piece before."""
+        def tile():
+            pieces = bwd_tile_pieces(block_q, block_k, masked, line)
+            issued = []
+            for n, piece in enumerate(pieces):
+                for nxt in pieces[len(issued):n + 1 + BWD_AHEAD]:
+                    issued.append(ahead(masked, nxt))
+                finish(piece, *issued[n])
+        return tile
 
     def when(cond):
         """``pl.when`` of a step inside the k tile's band."""
         return pl.when(cond if window is None
                        else jnp.logical_and(live, cond))
 
+    banded = banded_tiles(block_q, block_k, window)
     if window is not None:
         first_row, first_col = qi * block_q, kj * block_k
-        rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
 
         @when(kj == _first_band_k_tile(qi, block_q, block_k, window))
         def _zero_dq():
-            dq_acc[rows] = jnp.zeros((block_q, dq_acc.shape[1]),
-                                     jnp.float32)
+            dq_acc[i] = jnp.zeros(dq_acc.shape[1:], jnp.float32)
         crossed, clean = _band_tiles(first_row, first_col, block_q, block_k,
                                      window)
-        if block_q == block_k and window % block_k == 0:
+        if banded:
             # the diagonal and the edge cross different tiles, each corner
             # to corner
-            when(qi == kj)(diagonal)
-            when(first_col == first_row - window)(edge)
+            when(qi == kj)(run(True, "diagonal"))
+            when(first_col == first_row - window)(run(True, "edge"))
         else:
-            when(crossed)(functools.partial(whole, True))
-        when(clean)(functools.partial(whole, False))
+            when(crossed)(run(True))
+        when(clean)(run(False))
         last_kj = jnp.minimum(_last_live_k_tile(qi, block_q, block_k),
                               nk - 1)
     elif causal:
@@ -840,22 +884,23 @@ def _flash_bwd_kernel(q_ref, do_ref, k_ref, v_ref, lse_ref, adj_ref,
         # Square tiles meet the diagonal corner to corner (qi == kj)
         crossed = jnp.logical_and(first_col <= last_row,
                                   last_col > first_row)
-        pl.when(crossed)(diagonal if block_q == block_k
-                         else functools.partial(whole, True))
+        pl.when(crossed)(run(True, "diagonal" if banded else None))
         # wholly at or below the diagonal: no mask to build. Tiles
         # strictly above it run nothing (and fetch nothing: q_tile)
-        pl.when(last_col <= first_row)(functools.partial(whole, False))
+        pl.when(last_col <= first_row)(run(False))
         last_kj = jnp.minimum(_last_live_k_tile(qi, block_q, block_k),
                               nk - 1)
     else:
-        whole(False)
+        run(False)()
         last_kj = nk - 1
 
     rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
 
     @when(kj == last_kj)
     def _write_dq():
-        dq_ref[0, rows] = (dq_acc[rows] * scale).astype(dq_ref.dtype)
+        # the accumulator is transposed once a q tile, on its way out
+        dq_ref[0, rows] = jnp.transpose(dq_acc[i] * scale).astype(
+            dq_ref.dtype)
 
     @pl.when(step == nq - 1)
     def _write_dkv():
@@ -942,7 +987,7 @@ def _flash_bwd_local(q, k, v, do, lse, adj, *, H, causal, scale, blocks,
         out_specs=[dq_spec, part_spec, part_spec],
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype), part, part],
         scratch_shapes=[
-            pltpu.VMEM((rows, D), jnp.float32),      # dq of the range
+            pltpu.VMEM((nq, D, bq), jnp.float32),    # dqT, a q tile each
             pltpu.VMEM((bk, D), jnp.float32),        # dk of the k tile
             pltpu.VMEM((bk, D), jnp.float32),        # dv
         ],

@@ -2,7 +2,7 @@
 (interpret mode on the CPU mesh; the chip's compile of it is
 tests/test_tpu_compile_kernels.py's), the rule that picks its tile, and the
 form it runs a tile in: the state along the lanes, pieces of
-``FWD_PIECE_ROWS`` k rows. The backward kernel: test_pallas_attention_backward
+``PIECE_ROWS`` k rows. The backward kernel: test_pallas_attention_backward
 .py; both under a mask's band: test_pallas_attention_banded.py and
 test_pallas_attention_windows.py."""
 
@@ -133,7 +133,7 @@ def test_flash_blocks_short_and_rectangular_shapes_keep_their_tiles():
 
 
 def test_flash_blocks_shrink_the_q_tile_first_under_the_vmem_budget():
-    # the score tile is in pieces of FWD_PIECE_ROWS k rows, so what fills
+    # the score tile is in pieces of PIECE_ROWS k rows, so what fills
     # the budget is the operands' blocks: float32 tiles of 1024 x 1024 fit
     # at a head of 128 and of 256, not of 512; the k tile stays wide
     assert pa.flash_vmem_bytes(1024, 1024, 128, 4) <= pa.VMEM_BUDGET
@@ -147,11 +147,11 @@ def test_flash_blocks_shrink_the_q_tile_first_under_the_vmem_budget():
 
 
 def test_flash_vmem_bytes_counts_the_pieces_not_the_score_tile():
-    """Two pieces of ``FWD_PIECE_ROWS`` k rows are in flight, whatever the k
+    """Two pieces of ``PIECE_ROWS`` k rows are in flight, whatever the k
     tile: doubling it adds its k and v blocks alone; the state is rows."""
     def count(bq, bk, D=128, itemsize=2):
         return pa.flash_vmem_bytes(bq, bk, D, itemsize)
-    assert pa.FWD_PIECE_ROWS == pa.MIN_BLOCK == pa.fwd_piece_rows(1024)
+    assert pa.PIECE_ROWS == pa.MIN_BLOCK == pa.piece_rows(1024)
     assert count(1024, 1024) - count(1024, 512) == 2 * 2 * 512 * 128 * 2
     io = 2 * 4 * 1024 * 128 * 2 + 2 * 8 * 1024 * 4
     pieces = 2 * 128 * 1024 * (4 + 4 + 2)
@@ -190,7 +190,7 @@ _CELL_TILES = {
                                    (1024, 1024, 8192)),
     "nemotron-3-nano-30b-a3b.s8192": ((8192, 128), (1024, 1024),
                                       (1024, 1024, 8192)),
-    "glm-4.7-flash.s8192": ((8192, 256), (1024, 1024), (512, 512, 8192)),
+    "glm-4.7-flash.s8192": ((8192, 256), (1024, 1024), (1024, 1024, 8192)),
     "granite-4.0-h-micro.s4096": ((4096, 64), (1024, 1024),
                                   (1024, 1024, 4096)),
     "laguna-xs.2.s8192": ((8192, 128), (1024, 1024), (1024, 1024, 8192)),
@@ -261,15 +261,17 @@ def _live(r, c, crossed):
 @pytest.mark.parametrize("tile", [128, 256, 512, 1024])
 def test_tile_pieces_cover_every_live_score_once_and_no_dead_block(tile,
                                                                    crossed):
-    """The pieces of a diagonal tile are ``FWD_PIECE_ROWS`` k rows against
+    """The pieces both kernels run of a diagonal tile (the backward's are
+    the forward's: ``bwd_tile_pieces``) are ``PIECE_ROWS`` k rows against
     the q rows from the piece's first k row on: 36 of a 1024 x 1024 tile's
     64 blocks of 128 x 128, the ones that hold a live score, in eight
     pieces (the parent's two bands of 512 q rows ran 48); of an edge tile
     the mirror image. Every live score lies in exactly one piece, and
     every block a piece holds has a live score."""
     pieces = pa.tile_pieces(tile, tile, crossed)
-    rows = pa.fwd_piece_rows(tile)
-    assert rows == 128 == pa.FWD_PIECE_ROWS
+    assert pa.bwd_tile_pieces(tile, tile, True, crossed) == pieces
+    rows = pa.piece_rows(tile)
+    assert rows == 128 == pa.PIECE_ROWS
     n = tile // rows
     assert len(pieces) == n
     assert pa.tile_piece_blocks(tile, tile, crossed) == (
@@ -297,9 +299,12 @@ def test_tile_pieces_cover_every_live_score_once_and_no_dead_block(tile,
 def test_tile_pieces_of_a_tile_no_line_crosses_corner_to_corner(tile):
     """A tile inside the band, or one the mask cuts anywhere else (a tile
     that is not square, a window that is no multiple of it): every piece
-    spans all the q rows, the k rows once each."""
+    spans all the q rows, the k rows once each. The backward runs those
+    pieces of a tile the mask cuts and a tile inside the band whole."""
     bq, bk = tile
     pieces = pa.tile_pieces(bq, bk)
+    assert pa.bwd_tile_pieces(bq, bk, True) == pieces
+    assert pa.bwd_tile_pieces(bq, bk, False) == [(0, bk, 0, bq)]
     assert pieces == [(k0, 128, 0, bq) for k0 in range(0, bk, 128)]
     assert pa.tile_piece_blocks(bq, bk) == (bq * bk // 128 ** 2,) * 2
 

@@ -1,6 +1,10 @@
 """The flash-attention backward kernel ``hvd_flash_bwd`` against autodiff
-through the XLA oracle (interpret mode), the rule that picks its tile and q
-ranges, and the pair of kernels at a head of 64 (heads first, grouped)."""
+through the XLA oracle (interpret mode): a tile that a mask's line crosses
+in pieces of ``PIECE_ROWS`` k rows whose two operand-only matmuls are
+written ``BWD_AHEAD`` pieces ahead, a clean tile whole (PR 58; the written
+order itself is held in the kernel's jaxpr). The rule that picks its tile
+and q ranges, and the pair of kernels at a head of 64 (heads first,
+grouped)."""
 
 import numpy as np
 import pytest
@@ -31,13 +35,16 @@ def test_flash_kernel_grads_match_oracle_at_the_rules_tiles(shape, causal):
 
 
 # (Sq, Sk, block_q, block_k, rows): q tiles wider and narrower than k
-# tiles, square tiles of several diagonal pieces, dq resident and in q
-# ranges of two tiles and of one, Sq != Sk both ways
+# tiles (a crossed tile's pieces span all its q rows under the mask), square
+# tiles of several diagonal pieces beside clean tiles, dq resident and in q
+# ranges of two tiles and of one, Sq != Sk both ways; a k tile of four
+# pieces against q tiles of one (every piece adds to a q tile's whole dqT)
 _BWD_TILES = [(512, 512, 128, 128, 512), (512, 512, 256, 128, 512),
               (512, 512, 128, 256, 512), (512, 512, 512, 512, 512),
               (1024, 1024, 512, 512, 1024), (512, 512, 128, 256, 256),
               (512, 512, 128, 128, 128), (768, 512, 256, 256, 768),
-              (256, 512, 128, 128, 256)]
+              (256, 512, 128, 128, 256), (512, 512, 128, 512, 256),
+              (768, 1024, 256, 512, 768)]
 
 
 @pytest.mark.parametrize("causal", [True, False])
@@ -47,9 +54,95 @@ def test_flash_backward_tile_overrides(tile, causal):
     diagonal crosses tiles in every way, tiles above it are skipped, a
     square tile on it runs in pieces, partial dk / dv of ranges add up."""
     Sq, Sk, bq, bk, rows = tile
+    assert len(pa.bwd_tile_pieces(bq, bk, True)) == bk // pa.PIECE_ROWS
     got, want = backward(*qkv(B=1, S=Sq, Sk=Sk, H=2, seed=9), causal,
                           cos_cotangent, pa.BwdBlocks(bq, bk, rows))
     assert_backward(got, want)
+
+
+@pytest.mark.parametrize("D", [64, 256])
+def test_flash_backward_in_pieces_at_a_head_of(D):
+    """A head of half a lane tile (heads first) and of two, grouped 4 on
+    2, causal in two 256 x 256 tiles a side: a diagonal tile in two pieces
+    and a clean one, the transposed dq ``[D, 256]`` a q tile."""
+    rng = np.random.RandomState(15)
+
+    def mk(heads):
+        return jnp.asarray(rng.randn(1, 512, heads, D) * 0.5, jnp.float32)
+    q, k, v, w = mk(4), mk(2), mk(2), mk(4)
+    o, lse = pa.flash_attention_with_lse(q, k, v, True, None, 256, 256,
+                                         interpret=True)
+    got = pa.flash_backward(q, k, v, o, lse, w, jnp.zeros_like(lse), True,
+                            D ** -0.5, pa.BwdBlocks(256, 256, 512),
+                            interpret=True)
+    want = jax.jit(jax.grad(lambda q, k, v: jnp.sum(
+        pa._banded_attention(q, k, v, None) * w), (0, 1, 2)))(q, k, v)
+    assert_backward(got, want)
+
+
+# -- the order a tile is written in (PR 58) -----------------------------------
+
+def _tile_bodies(jaxpr):
+    """The jaxprs, this one or a ``cond`` branch inside it, that hold an
+    ``exp``: a tile's body, each of its pieces one ``exp``."""
+    found = []
+    if any(str(e.primitive) == "exp" for e in jaxpr.eqns):
+        found.append(jaxpr)
+    for e in jaxpr.eqns:
+        for branch in e.params.get("branches", ()):
+            found += _tile_bodies(branch.jaxpr)
+    return found
+
+
+#: name -> (Sq, Sk, block_q, block_k, causal, window) and the pieces of each
+#: tile body the kernel holds, in the order written: the crossed tiles'
+#: (diagonal, then the band's edge), then the clean tile's one
+_WRITTEN = {
+    "causal": (1024, 1024, 512, 512, True, None, [4, 1]),
+    "a window of whole tiles": (1024, 1024, 256, 256, True, 256, [2, 2, 1]),
+    "no mask": (256, 512, 256, 512, False, None, [1]),
+    "a crossed tile that is not square": (512, 512, 128, 256, True, None,
+                                          [2, 1]),
+    "the cells' tile": (2048, 2048, 1024, 1024, True, None, [8, 1]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_WRITTEN))
+def test_a_piece_s_operand_only_matmuls_are_written_before_the_last_exp(case):
+    """What the gain rests on (PERF.md, PR 56, PR 58): Mosaic's scheduler
+    keeps the order a kernel is written in, so in the body of a crossed
+    tile in the kernel's jaxpr the two ``dot_general``s that need operands
+    alone (``sT``, ``dpT``) of the ``BWD_AHEAD`` pieces after piece ``n``
+    come before piece ``n``'s ``exp``, beside its own two and the three a
+    piece (dv, dk, dq) of the pieces before it. Written in order the count
+    before the ``exp`` would be ``2 (n + 1) + 3 n``. A clean tile is one
+    piece (:func:`bwd_tile_pieces`)."""
+    Sq, Sk, bq, bk, causal, window, pieces = _WRITTEN[case]
+    D, H = 128, 2
+    x = jax.ShapeDtypeStruct((1, Sq, H * D), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, Sk, H * D), jnp.bfloat16)
+    row = jax.ShapeDtypeStruct((H, 1, Sq), jnp.float32)
+    jaxpr = jax.make_jaxpr(lambda *a: pa._flash_bwd_local.__wrapped__(
+        *a, H=H, causal=causal, scale=0.1, blocks=pa.BwdBlocks(bq, bk, Sq),
+        interpret=False, window=window))(x, kv, kv, x, row, row)
+    call = next(e for e in jaxpr.eqns if "pallas_call" in str(e.primitive))
+    found = _tile_bodies(call.params["jaxpr"])
+    assert pa.BWD_AHEAD >= 1
+    assert len(pa.bwd_tile_pieces(bq, bk, False)) == 1
+    assert len(pa.bwd_tile_pieces(bq, bk, True)) == bk // pa.PIECE_ROWS
+    assert pa.bwd_tile_pieces(bq, bk, True, "edge") == pa.tile_pieces(
+        bq, bk, "edge")
+    written = []
+    for body in found:
+        names = [str(e.primitive) for e in body.eqns]
+        exps = [n for n, name in enumerate(names) if name == "exp"]
+        written.append(len(exps))
+        assert names.count("dot_general") == 5 * len(exps)
+        for n, at in enumerate(exps):
+            ahead = min(n + 1 + pa.BWD_AHEAD, len(exps))
+            assert names[:at].count("dot_general") == 2 * ahead + 3 * n, (
+                case, n)
+    assert written == pieces
 
 
 @pytest.mark.parametrize("causal", [True, False])
@@ -88,23 +181,53 @@ def test_flash_grads_rect():
 
 # -- the backward's tile rule -------------------------------------------------
 
-# what the three causal cells and a ring step of chip_smoke.py call it
-# with (head_dim 128, bf16): (Sq, Sk) -> (block_q, block_k, rows)
+# what the nine causal cells and a ring step of chip_smoke.py call it with
+# (bf16): (Sq, Sk, head_dim, window) -> (block_q, block_k, rows)
 _BWD_RULE = {
-    (2048, 2048): (1024, 1024, 2048),     # gpt-1.3b-widths.s2048
-    (4096, 4096): (1024, 1024, 4096),     # olmoe-1b-7b.s4096, ouro-2.6b.s4096
-    (512, 512): (512, 512, 512),          # ring attention, sp=4 of 2048
-    (128, 256): (128, 256, 128),
-    (384, 640): (128, 128, 384),
+    (2048, 2048, 128, None): (1024, 1024, 2048),  # gpt-1.3b-widths.s2048
+    (4096, 4096, 128, None): (1024, 1024, 4096),  # olmoe-1b-7b, ouro-2.6b
+    (4096, 4096, 64, None): (1024, 1024, 4096),   # granite-4.0-h-micro
+    # nemotron-3-nano-30b-a3b, and the full layers of smallthinker-21b-a3b
+    # and laguna-xs.2
+    (8192, 8192, 128, None): (1024, 1024, 8192),
+    (8192, 8192, 128, 4096): (1024, 1024, 8192),  # smallthinker's windows
+    (8192, 8192, 128, 512): (512, 512, 8192),     # laguna-xs.2's windows
+    # glm-4.7-flash: 512 x 512 while the score tile was counted five times
+    # (PR 58: 11.07 ms a call alone at 512 x 512, 9.64 at 1024 x 1024)
+    (8192, 8192, 256, None): (1024, 1024, 8192),
+    (8192, 8192, 64, None): (1024, 1024, 8192),   # lfm2-24b-a2b
+    (512, 512, 128, None): (512, 512, 512),       # ring attention, sp=4
+    (128, 256, 128, None): (128, 256, 128),
+    (384, 640, 128, None): (128, 128, 384),
 }
 
 
-@pytest.mark.parametrize("shape", sorted(_BWD_RULE))
+@pytest.mark.parametrize("shape", sorted(_BWD_RULE, key=str))
 def test_flash_bwd_blocks_at_the_shapes_that_run(shape):
-    blocks = pa.flash_bwd_blocks(*shape, 128, jnp.bfloat16)
+    Sq, Sk, D, window = shape
+    blocks = pa.flash_bwd_blocks(Sq, Sk, D, jnp.bfloat16, window)
     assert blocks == _BWD_RULE[shape]
-    assert blocks.rows == shape[0]        # dq resident: one range
-    assert pa.flash_bwd_vmem_bytes(*blocks, 128, 2) <= pa.BWD_VMEM_BUDGET
+    assert blocks.rows == Sq              # dq resident: one range
+    assert pa.flash_bwd_vmem_bytes(*blocks, D, 2) <= pa.BWD_VMEM_BUDGET
+
+
+def test_flash_bwd_vmem_bytes_counts_a_clean_tile_s_scores_once():
+    """The blocks the pipeline double-buffers, the float32 accumulators
+    (dqT a q tile of the range) and one clean tile's scores at 7 bytes each
+    in bfloat16, 10 in float32: what the compiler takes, rounded up (the
+    parent counted 18 and 24). The latent cell's 1024 x 1024 at a head of
+    256 fits by that count and not by the parent's."""
+    def count(bq, bk, rows, D=128, itemsize=2):
+        return pa.flash_bwd_vmem_bytes(bq, bk, rows, D, itemsize)
+    io = 2 * 4 * 1024 * 128 * 2 + 2 * 2 * 8 * 1024 * 4
+    out = 2 * (2 * 1024 + 8192) * 128 * 2
+    scratch = (2 * 1024 + 8192) * 128 * 4
+    assert count(1024, 1024, 8192) == io + out + scratch + 7 * 1024 * 1024
+    assert count(512, 512, 512, itemsize=4) - count(256, 512, 512,
+                                                    itemsize=4) \
+        == 2 * 2 * 256 * 128 * 4 + 2 * 2 * 8 * 256 * 4 + 10 * 256 * 512
+    assert count(1024, 1024, 8192, D=256) <= pa.BWD_VMEM_BUDGET \
+        < count(1024, 1024, 8192, D=256) + 11 * 1024 * 1024
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
@@ -131,7 +254,7 @@ def test_flash_bwd_blocks_keep_dq_resident_while_it_fits():
     assert pa.flash_bwd_grid(1, 2, 65536, 65536, long)[1] \
         == 65536 // long.rows
     # a length whose only divisors are 1 and itself goes tile by tile
-    prime = pa.flash_bwd_blocks(128 * 251, 128 * 251, 128, jnp.bfloat16)
+    prime = pa.flash_bwd_blocks(128 * 263, 128 * 263, 128, jnp.bfloat16)
     assert prime == (128, 128, 128)
     # float32 and a wider head hold fewer rows
     assert pa.flash_bwd_blocks(16384, 16384, 256, jnp.float32).rows < 16384

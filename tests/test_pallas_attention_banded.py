@@ -1,7 +1,7 @@
 """Both flash kernels in the backward's form (PR 50, PR 56) under the mask's
 band, tile by override: operands in place as ``[B, S, heads * D]``, scores
 transposed and the softmax state along the lanes, every tile in pieces of
-``FWD_PIECE_ROWS`` k rows; against ``_banded_attention`` in float32
+``PIECE_ROWS`` k rows; against ``_banded_attention`` in float32
 (interpret mode). A windowed call by the rule's own tile and grid:
 test_pallas_attention_windows.py.
 
@@ -77,7 +77,7 @@ def test_flash_forward_in_place_and_banded_is_the_banded_form(case):
     the kernels against ``_plain_attention`` / ``_banded_attention`` in
     float32, autodiff through it for the gradients."""
     B, S, H, Hkv, D, bq, bk, window = _BANDED[case]
-    assert len(pa.tile_pieces(bq, bk)) == bk // pa.FWD_PIECE_ROWS
+    assert len(pa.tile_pieces(bq, bk)) == bk // pa.PIECE_ROWS
     q, k, v = heads(B, S, H, Hkv, D)
     scale = 1.0 / D ** 0.5
     w, u = weights(q, B * H)

@@ -317,9 +317,10 @@ _RULE_BANDED = {
     # maps: test_pallas_attention_banded.py's "grouped heads 28 / 4")
     "a window of one tile, bfloat16":
         (1, 3072, 2, 1, 128, 1024, jnp.bfloat16, (1024, 1024), 2, 2),
-    # (float32 at a head of 512 halves the q tile)
+    # (float32 at a head of 512 halves the q tile; the backward's is 256 x
+    # 512, six q tiles a k tile's band)
     "a window of one k tile under a q tile of half":
-        (1, 3072, 2, 1, 512, 1024, jnp.float32, (512, 1024), 2, 5),
+        (1, 3072, 2, 1, 512, 1024, jnp.float32, (512, 1024), 2, 6),
     # three of five q tiles have a band the sequence's start cuts
     "two tiles and a half, the first q tiles cut":
         (1, 1280, 2, 2, 128, 640, jnp.float32, (256, 256), 4, 4),
@@ -407,7 +408,8 @@ def test_the_backward_s_band_walk_with_the_q_rows_in_ranges(ranges):
 
 def test_chip_smoke_s_attention_path_prints_the_band_s_grid_and_tiles():
     """``chip_smoke.py``'s line for a windowed call, from the functions the
-    kernels call: the rule's tile, the grid steps a call takes and, a head,
+    kernels call: the rule's tile, the grid steps a call takes, both
+    kernels' pieces (and how many the backward writes ahead) and, a head,
     the steps taken and the tiles run."""
     import chip_smoke
     laguna = chip_smoke._flash_call((1, 8192, 64, 128), 8, 512)
@@ -416,14 +418,20 @@ def test_chip_smoke_s_attention_path_prints_the_band_s_grid_and_tiles():
         "[1, 8192, 8192], scores [k, q] with m, l [1, 512] and acc [128, "
         "512] along the lanes, a tile in 4 pieces of 128 k rows, on the "
         "diagonal 10 of 16 blocks, on the band's edge 10 of 16 blocks; "
-        "hvd_flash_bwd 512x512, dq resident, 2048 steps, "), laguna
+        "hvd_flash_bwd 512x512, dq resident, 2048 steps, a crossed tile in 4 "
+        "pieces of 128 k rows, on the diagonal 10 of 16 blocks, on the "
+        "band's edge 10 of 16 blocks, sT and dpT written "
+        f"{pa.BWD_AHEAD} ahead, a clean tile in 1, dqT [128, 512] a q tile, "
+        "VMEM estimate "), laguna
     assert laguna.endswith(
         "; window 512: forward 32 steps and 31 of 136 causal tiles a head, "
         "15 on the edge, backward 32 steps and 31 of 136 causal tiles a "
         "head, 15 on the edge; kv heads 8, group 8"), laguna
     share = chip_smoke._flash_call((1, 8192, 28, 128), 4, 4096)
     assert "hvd_flash_attention 1024x1024, 1120 steps" in share
-    assert "hvd_flash_bwd 1024x1024, dq resident, 1120 steps" in share
+    assert ("hvd_flash_bwd 1024x1024, dq resident, 1120 steps, a crossed tile "
+            "in 8 pieces of 128 k rows, on the diagonal 36 of 64 blocks, on "
+            "the band's edge 36 of 64 blocks, sT and dpT written") in share
     assert share.endswith(
         "; window 4096: forward 40 steps and 30 of 36 causal tiles a head, "
         "4 on the edge, backward 40 steps and 30 of 36 causal tiles a head, "

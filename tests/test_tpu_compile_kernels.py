@@ -63,8 +63,9 @@ _QKV_GROUPED = [((1, 8192, 28, 128), jnp.bfloat16)] \
     + [((1, 8192, 4, 128), jnp.bfloat16)] * 2
 # the cell glm-4.7-flash.s8192: 20 / 20 heads of 256 (latent attention's
 # keys and values come up for every head), 8192 positions: the forward's
-# 1024 x 1024 tile reads exactly its VMEM budget, the backward's resident
-# form leaves room for 512-tiles
+# 1024 x 1024 tile reads exactly its VMEM budget, and so does the backward's
+# beside a head's resident dq (30.9 of BWD_VMEM_BUDGET's 32 MiB by the least
+# limit that compiles; 512-tiles until PR 58 counted what the compiler does)
 _QKV_LATENT = [((1, 8192, 20, 256), jnp.bfloat16)] * 3
 # the cell laguna-xs.2.s8192: window layers of 64 query heads and full
 # layers of 48 on the same 8 key/value heads of 128 (groups of 8 and of 6)
