@@ -66,7 +66,8 @@ def test_the_count_at_the_cells_shapes(cell):
 def test_the_metric_files_and_their_entries(name):
     spec = _read(CHIP, "layer_metrics", name + ".json")
     assert spec["read"]["trace_ops"] == "hvd_flash_bwd"
-    assert spec["workloads"] == sorted(CELLS)
+    # the three cells counted here first; a cell listed since stands after
+    assert spec["workloads"][:3] == sorted(CELLS)
     entry = {m["name"]: m for m in _read(ROOT, "BENCHMARK.json")[
         "per_layer"]}[name]
     for key in ("layer", "unit", "better", "source", "moves", "workloads"):

@@ -62,7 +62,7 @@ def test_a_cell_a_config_and_a_metric_are_added_by_files_alone(tmp_path):
            chip, "layer_metrics", "setup.throwaway_hits.json")
     # ... and over a reader the harness lacks, a file found by its name,
     # which sees every duration of the window and not only the median
-    os.makedirs(os.path.join(chip, "readers"))
+    os.makedirs(os.path.join(chip, "readers"), exist_ok=True)
     with open(os.path.join(chip, "readers", "throwaway_max.py"), "w") as f:
         f.write("def read(read, ctx):\n"
                 "    assert len(ctx['step_done_at_s']) > 0\n"

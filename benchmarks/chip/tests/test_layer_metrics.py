@@ -68,8 +68,10 @@ def test_every_cell_reports_setup_a_rate_and_a_layer():
                 "step.unscoped_ms", "input.place_ms"} <= layers, cell
     moe = {m["name"]: m["workloads"] for m in BENCH["per_layer"]
            if m["name"].startswith("step.moe_")}
+    # (PR 59 listed the other cells with an expert layer under its parts
+    # where they had no name of their own for that read)
     assert len(moe) == 5 and all(
-        w == ["olmoe-1b-7b.s4096", "smallthinker-21b-a3b.s8192"]
+        w[:2] == ["olmoe-1b-7b.s4096", "smallthinker-21b-a3b.s8192"]
         for w in moe.values())
 
 
