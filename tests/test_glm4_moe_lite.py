@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 
 import arch
+import chip_door
 from arch import TOL, get_leaves, rel as _rel
 from horovod_tpu.models import latent
 from horovod_tpu.models import transformer as t
@@ -86,26 +87,25 @@ def test_the_step_s_required_flops_by_hand():
 
 
 def test_the_kernels_least_work_by_hand():
-    import roofline_latent_flash_attention as fwd
-    import roofline_latent_flash_attention_backward as bwd
-    import roofline_latent_head_xent as xent
-    import roofline_latent_moe_gmm as gmm
+    gmm, fwd, bwd, xent = (
+        chip_door.roofline("glm-4.7-flash.s8192", kernel) for kernel in (
+            "hvd_moe_gmm", "hvd_flash_attention", "hvd_flash_bwd",
+            "hvd_fused_xent"))
     config, job = _cell(tiny=False)
     sizes = adapter.shapes(config, job)
     rows = 8192 * 4 * 8 / 64
     assert rows == 4096
-    need = gmm.latent_moe_gmm(sizes)
+    need = gmm(sizes)
     assert need["flops"] == 5 * 9 * 2 * rows * 2048 * 1536
     assert need["bytes"] == 5 * 9 * 2 * (rows * (2048 + 1536)
                                          + 8 * 2048 * 1536)
     one = 2 * 2 * 20 * 256 * 8192 * 8193 / 2
-    need = fwd.latent_flash_attention(sizes)
+    need = fwd(sizes)
     # six blocks, each one's forward run again by its checkpointed backward
     assert need["flops"] == 12 * one
     assert need["bytes"] == 12 * (4 * 8192 * 20 * 256 * 2 + 20 * 8192 * 4)
-    assert bwd.latent_flash_attention_backward(sizes)["flops"] == \
-        6 * 2.5 * one
-    need = xent.latent_head_xent(sizes)
+    assert bwd(sizes)["flops"] == 6 * 2.5 * one
+    need = xent(sizes)
     assert need["bytes"] == 2 * (2 * 8192 * 19360 * 2 + 12 * 8192)
 
 

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 import arch
+import chip_door
 from arch import TOL, rel as _rel
 from horovod_tpu.models import mamba
 from horovod_tpu.models import transformer as t
@@ -129,25 +130,25 @@ def test_the_step_s_required_flops_by_hand():
 
 
 def test_the_kernels_least_work_by_hand():
-    import roofline_dense_ssm_flash_attention as fwd
-    import roofline_dense_ssm_flash_attention_backward as bwd
-    import roofline_dense_ssm_head_xent as xent
-    import roofline_dense_ssm_scan as scan
+    fwd, bwd, xent, scan = (
+        chip_door.roofline("granite-4.0-h-micro.s4096", kernel)
+        for kernel in ("hvd_flash_attention", "hvd_flash_bwd",
+                       "hvd_fused_xent", "hvd_ssm_scan"))
     config, job = _cell(tiny=False)
     sizes = adapter.shapes(config, job)
     once = 2 * 2 * 32 * 64 * 4096 * 4097 / 2
-    need = fwd.dense_ssm_flash_attention(sizes)
+    need = fwd(sizes)
     assert need["flops"] == once
     assert need["bytes"] == 2 * 4096 * (32 + 8) * 64 * 2 + 32 * 4096 * 4
-    need = bwd.dense_ssm_flash_attention_backward(sizes)
+    need = bwd(sizes)
     assert need["flops"] == 2.5 * once
     assert need["bytes"] == 4 * 4096 * (32 + 8) * 64 * 2 + 2 * 32 * 4096 * 4
-    need = xent.dense_ssm_head_xent(sizes)
+    need = xent(sizes)
     assert need["bytes"] == 2 * 4096 * 12544 * 2 + 12 * 4096
     assert need["bytes"] / 819e9 > need["flops"] / 197e12
     # nine Mamba blocks, two forward calls and one backward call each, ONE
     # group: the scores and b and c once, whatever the head tiles
-    need = scan.dense_ssm_scan(sizes)
+    need = scan(sizes)
     scores, weighted, state = (2 * 128 * 128.5, 2 * 4096 * 128.5,
                                2 * 4096 * 128)
     forward = scores + weighted + 2 * state

@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 import arch
+import chip_door
 from arch import TOL, rel as _rel
 from horovod_tpu.models import _kinds
 from horovod_tpu.models import transformer as t
@@ -110,17 +111,17 @@ def test_the_step_s_required_flops_by_hand():
 
 
 def test_the_kernels_least_work_by_hand():
-    import roofline_banded_flash_attention as fwd
-    import roofline_banded_flash_attention_backward as bwd
-    import roofline_latent_head_xent as xent
-    import roofline_latent_moe_gmm as gmm
+    gmm, fwd, bwd, xent = (
+        chip_door.roofline("laguna-xs.2.s8192", kernel) for kernel in (
+            "hvd_moe_gmm", "hvd_flash_attention", "hvd_flash_bwd",
+            "hvd_fused_xent"))
     config, job = _cell(tiny=False)
     sizes = adapter.shapes(config, job)
     live = {None: 8192 * 8193 / 2, 512: 512 * 513 / 2 + 7680 * 512}
     one = {w: 2 * 2 * 128 * s for w, s in live.items()}
     calls = sizes["attention_forward_calls"] // 5
     assert calls == 1 + bool(config["assumed"]["checkpoint_every_block"])
-    need = fwd.banded_flash_attention(sizes)
+    need = fwd(sizes)
     assert need["flops"] == calls * (2 * 48 * one[None] + 3 * 64 * one[512])
     assert need["bytes"] == calls * (
         2 * (2 * 8192 * 56 * 128 * 2 + 48 * 8192 * 4)
@@ -128,19 +129,18 @@ def test_the_kernels_least_work_by_hand():
     # the window cores' least work is a fifth of the attention cores'
     assert 3 * 64 * one[512] / (need["flops"] / calls) == pytest.approx(
         0.19, abs=0.01)
-    need = bwd.banded_flash_attention_backward(sizes)
+    need = bwd(sizes)
     assert need["flops"] == 2.5 * (2 * 48 * one[None] + 3 * 64 * one[512])
     assert need["bytes"] == (
         2 * (4 * 8192 * 56 * 128 * 2 + 2 * 48 * 8192 * 4)
         + 3 * (4 * 8192 * 72 * 128 * 2 + 2 * 64 * 8192 * 4))
     rows = 8192 * 8 * 32 / 256
     assert rows == 8192
-    need = gmm.latent_moe_gmm(sizes)
+    need = gmm(sizes)
     assert need["flops"] == 4 * 9 * 2 * rows * 2048 * 512
     assert need["bytes"] == 4 * 9 * 2 * (rows * (2048 + 512)
                                          + 32 * 2048 * 512)
-    assert xent.latent_head_xent(sizes)["bytes"] == \
-        2 * 8192 * 12544 * 2 + 12 * 8192
+    assert xent(sizes)["bytes"] == 2 * 8192 * 12544 * 2 + 12 * 8192
 
 
 # -- the rotary tables --------------------------------------------------------

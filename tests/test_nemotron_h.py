@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 import arch
+import chip_door
 from arch import TOL, rel as _rel
 from horovod_tpu.models import mamba
 from horovod_tpu.models import transformer as t
@@ -35,6 +36,7 @@ CONFIG, SIZES, CFG, LEAVES = ARCH.CONFIG, ARCH.SIZES, ARCH.CFG, ARCH.LEAVES
 _cell, _params, _batch = ARCH.cell, ARCH.params, ARCH.batch
 _plain_grads = ARCH.plain
 PATTERN = CONFIG["hybrid_override_pattern"]
+CELL = "nemotron-3-nano-30b-a3b.s8192"
 
 
 def test_the_cell_keeps_every_published_width():
@@ -80,30 +82,28 @@ def test_the_step_s_required_flops_by_hand():
 
 
 def test_the_kernels_least_work_by_hand():
-    import roofline_hybrid_flash_attention as fwd
-    import roofline_hybrid_flash_attention_backward as bwd
-    import roofline_hybrid_moe_gmm as gmm
+    gmm, fwd, bwd = (chip_door.roofline(CELL, kernel) for kernel in (
+        "hvd_moe_gmm", "hvd_flash_attention", "hvd_flash_bwd"))
     config, job = _cell(tiny=False)
     sizes = adapter.shapes(config, job)
     rows = 8192 * 6 * 8 / 128
     assert rows == 3072
-    need = gmm.hybrid_moe_gmm(sizes)
+    need = gmm(sizes)
     assert need["flops"] == 4 * 6 * 2 * rows * 2688 * 1856
     assert need["bytes"] == 4 * 6 * 2 * (rows * (2688 + 1856)
                                          + 8 * 2688 * 1856)
-    need = fwd.hybrid_flash_attention(sizes)
+    need = fwd(sizes)
     assert need["flops"] == 2 * 2 * 32 * 128 * 8192 * 8193 / 2
     assert need["bytes"] == 2 * 8192 * (32 + 2) * 128 * 2 + 32 * 8192 * 4
-    assert bwd.hybrid_flash_attention_backward(sizes)["flops"] == \
-        2.5 * need["flops"]
+    assert bwd(sizes)["flops"] == 2.5 * need["flops"]
 
 
 def test_the_scan_kernels_least_work_by_hand():
     """Four Mamba blocks, two forward calls and one backward call each, at
     8192 positions of 64 heads of 64, 8 groups, state 128, chunk 128."""
-    import roofline_hybrid_ssm_scan as scan
     config, job = _cell(tiny=False)
-    need = scan.hybrid_ssm_scan(adapter.shapes(config, job))
+    need = chip_door.roofline(CELL, "hvd_ssm_scan")(
+        adapter.shapes(config, job))
     scores, weighted = 2 * 8 * 128 * 64.5, 2 * 4096 * 64.5
     state = 2 * 4096 * 128
     forward = scores + weighted + 2 * state
