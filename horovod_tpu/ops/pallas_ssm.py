@@ -32,7 +32,12 @@ In ``jax.numpy`` every factor of that is an array in HBM (at 8192 positions,
       chunks' size that reaches HBM) and gives dx, db and dc (summed over
       the step's heads in the step, and over a group's head tiles outside
       the kernel in float32, as ``hvd_flash_bwd``'s dk and dv are over a
-      query group), d dt and d s.
+      query group), d dt and d s. The loop over a step's heads keeps what
+      needs a head's ``[Q, Q]`` arrays and leaves four raw reductions a
+      head; d dt and d s are then one expression over the step's ``[Q, R]``
+      arrays (:func:`_ddt_ds`): in a Mosaic kernel a ``[Q, 1]`` float32
+      value takes a vector register a sublane tile with one lane in use, so
+      a head's column arithmetic cost what a ``[Q, 128]`` pass costs.
 
 Nothing ``[Q, Q]``-sized, no chunk's own state and no cotangent of a state
 is written to HBM in either direction.
@@ -104,7 +109,8 @@ def ssm_tiles(H: int, P: int, G: int) -> SsmTiles:
 
 #: bytes one grid step of the backward (the larger of the two) may take by
 #: :func:`ssm_vmem_bytes`: the v5e's default scoped-VMEM limit, which no call
-#: asks to raise
+#: asks to raise (the largest tile under it is the fastest a whole scan call
+#: has been measured at: PERF.md section 6, PR 61)
 VMEM_BUDGET = 16 * 1024 * 1024
 
 
@@ -114,27 +120,35 @@ def ssm_vmem_bytes(chunk: int, width: int, N: int, itemsize: int,
     channels: x, the float32 dy and the state the chunk started from in, dx
     out, each double-buffered by the pipeline; b and c in, db and dc out;
     dt and s in both forms and their cotangents (a ``[Q, R]`` float32 block
-    takes whole 128-lane tiles); the state's cotangent in its scratch; and
-    three ``[Q, Q]`` float32 arrays a head of the block (the loop over a
-    step's heads is unrolled and Mosaic gives each head's decays, weights
-    and their cotangents room of their own). Held to two compiles for a
-    v5e at ONE group, chunk 256: 8 heads of 64 (11.0 MiB here) fit the
-    default limit, 16 (19.8 MiB) do not (PERF.md section 6, PR 49)."""
+    takes whole 128-lane tiles); the state's cotangent in its scratch; five
+    ``[Q, Q]`` float32 arrays a step (the scores, their cotangent, and a
+    head's decays, weights and their cotangent, whose room the next head of
+    the unrolled loop takes over); and three ``[Q, 1]`` columns a head, the
+    raw reductions kept for :func:`_ddt_ds`, a vector register a sublane
+    tile each. Held from above, within 3 %, to what the compiler takes of
+    the scoped VMEM for the call compiled for a v5e (its
+    ``used_scoped_memory_configs``, the most over sequences of 2048 to
+    8192) at ONE group of heads of 64, state 128, bfloat16 (MiB here /
+    taken): chunk 128 at 8, 16, 32 heads 4.69 / 4.60, 7.94 / 7.89, 14.44 /
+    14.27; chunk 256 at 8, 16 heads 9.25 / 9.05, 15.0 / 14.76 (and 26.5 at
+    32, which the default limit refuses). The forward takes under half
+    (2.3, 3.7, 6.7; 4.4, 6.5). PERF.md section 6, PR 61."""
     wide = chunk * width * (2 * itemsize + 4) + N * width * 4
     narrow = 2 * chunk * N * (itemsize + 4)
     steps = 6 * chunk * LANES * 4
     return (2 * (wide + narrow + steps) + N * width * 4
-            + 3 * (width // head_dim) * chunk * chunk * 4)
+            + 5 * chunk * chunk * 4
+            + 3 * (width // head_dim) * chunk * LANES * 4)
 
 
 def ssm_head_tile(H: int, P: int, G: int, N: int, chunk: int,
                   itemsize: int = 2) -> int:
     """Heads of a group one grid step works on: all ``H / G`` where their
     blocks fit :data:`VMEM_BUDGET` (8 groups of 8 heads of 64 at chunk 128:
-    one block of 512 channels, 4.4 MiB), else the most whole lane tiles of
+    one block of 512 channels, 4.7 MiB), else the most whole lane tiles of
     heads that do and divide the group (ONE group of 64 heads of 64 at
-    chunk 256: ``[256, 4096]`` blocks with 64 heads' scores are 72 MiB; 8
-    heads, 11.0 MiB)."""
+    chunk 256: ``[256, 4096]`` blocks are 49.5 MiB, 32 heads 26.5; 16
+    heads, 15.0 MiB)."""
     tiles = ssm_tiles(H, P, G)
     for n in range(tiles.tiles, 0, -1):
         if tiles.tiles % n == 0 and ssm_vmem_bytes(
@@ -243,6 +257,23 @@ def _columns(cols, width: int):
     for r in reversed(range(width - 1)):
         out = jnp.where(lane <= r, cols[r], out)
     return out
+
+
+def _ddt_ds(d_dt_inside, d_since_start, d_to_end, d_whole, dt, since_start,
+            until_end, to_end, whole):
+    """The cotangents of dt and of the sums (their column form) for all of
+    a step's heads at once, from the heads' raw reductions: every operand
+    ``[Q, R]`` with a head a lane (``d_whole`` and ``whole`` ``[1, R]``),
+    so the arithmetic is done once a step and not once a head on ``[Q, 1]``
+    columns that take a whole vector register a sublane tile each."""
+    Q = dt.shape[0]
+    last = lax.broadcasted_iota(jnp.int32, (Q, 1), 0) == Q - 1
+    ddt = d_dt_inside + d_to_end * until_end
+    at_end = (jnp.sum(d_to_end * to_end, axis=0, keepdims=True)
+              + d_whole * whole)
+    ds = (d_since_start * since_start - dt * d_dt_inside - d_to_end * to_end
+          + jnp.where(last, at_end, 0.0))
+    return ddt, ds
 
 
 # -- forward ------------------------------------------------------------------
@@ -421,11 +452,11 @@ def _bwd_kernel(x_ref, b_ref, c_ref, dtc_ref, sc_ref, sr_ref, dy_ref, h_ref,
     until_end = _decay(s_last - s_col)
     to_end = until_end * dt_col
     whole = _decay(s_last)                        # [1, R]
-    last = lax.broadcasted_iota(jnp.int32, (Q, 1), 0) == Q - 1
     dscores = jnp.zeros((Q, Q), jnp.float32)
     db = jnp.zeros(b.shape, jnp.float32)
     dc = jnp.zeros(c.shape, jnp.float32)
-    ddt, ds = [None] * R, [None] * R
+    # a head's four raw reductions, a [Q, 1] (d_whole: [1, 1]) column each
+    d_dt_inside, d_since_start, d_to_end, d_whole = [], [], [], []
     for t in range(tiles.tiles):
         cols = slice(t * T, (t + 1) * T)
         x_t, dy_t = x_ref[0, :, cols], dy_ref[0, :, cols]
@@ -437,14 +468,14 @@ def _bwd_kernel(x_ref, b_ref, c_ref, dtc_ref, sc_ref, sr_ref, dy_ref, h_ref,
         from_state = _dot(c, h_low, _NN)
         dfrom = (dy_t * start_t).astype(dtype)
         dc = dc + _dot(dfrom, h_low, _NT)
-        d_since_start = _per_head(dy_t * from_state, tiles)
+        d_since_start += _per_head(dy_t * from_state, tiles)
         # the state the chunk hands on: exp(s_Q) H + b^T (to_end x)
         dleaves = _dot(b, dh_low, _NN)
         db = db + _dot((x_f32 * end_t).astype(dtype), dh_low, _NT)
         dx_t = dleaves * end_t
-        d_to_end = _per_head(dleaves * x_f32, tiles)
-        d_whole = [jnp.sum(v, axis=0, keepdims=True)
-                   for v in _per_head(dh_t * h_t, tiles)]
+        d_to_end += _per_head(dleaves * x_f32, tiles)
+        d_whole += _per_head(jnp.sum(dh_t * h_t, axis=0, keepdims=True),
+                             tiles)
         dstate[:, cols] = _carry(dh_t, _spread(whole, t, tiles),
                                  _dot(c, dfrom, _TN))
         for j in range(tiles.heads_per_tile):
@@ -455,24 +486,20 @@ def _bwd_kernel(x_ref, b_ref, c_ref, dtc_ref, sc_ref, sr_ref, dy_ref, h_ref,
             dweights = _dot(x_t, dy_j, _NT) * decay
             dscores_r = dweights * dt_r
             dscores = dscores + dscores_r
-            d_dt_inside = jnp.sum(dweights * scores, axis=1, keepdims=True)
+            d_dt_inside.append(jnp.sum(dweights * scores, axis=1,
+                                       keepdims=True))
             dsr_ref[0, 0, r:r + 1] = jnp.sum(dscores_r * scores, axis=0,
                                              keepdims=True)
             weights = (scores * decay * dt_r).astype(dtype)
             dx_t = dx_t + _dot(weights, dy_j, _NN)
-            to_end_r = to_end[:, r:r + 1]
-            ddt[r] = d_dt_inside + d_to_end[j] * until_end[:, r:r + 1]
-            at_end = (jnp.sum(d_to_end[j] * to_end_r, axis=0, keepdims=True)
-                      + d_whole[j] * whole[:, r:r + 1])
-            ds[r] = (d_since_start[j] * since_start[:, r:r + 1]
-                     - dt_r * d_dt_inside - d_to_end[j] * to_end_r
-                     + jnp.where(last, at_end, 0.0))
         dx_ref[0, :, cols] = dx_t.astype(dx_ref.dtype)
     dscores = dscores.astype(dtype)
     db_ref[0] = (db + _dot(dscores, c, _NN)).astype(db_ref.dtype)
     dc_ref[0] = (dc + _dot(dscores, b, _TN)).astype(dc_ref.dtype)
-    ddt_ref[0, 0] = _columns(ddt, R)
-    dsc_ref[0, 0] = _columns(ds, R)
+    ddt_ref[0, 0], dsc_ref[0, 0] = _ddt_ds(
+        *(_columns(v, R) for v in (d_dt_inside, d_since_start, d_to_end,
+                                   d_whole)),
+        dt_col, since_start, until_end, to_end, whole)
 
 
 def _backward(x, dt, s, b, c, states, dy, chunk, interpret, head_tile):

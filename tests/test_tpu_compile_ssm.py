@@ -35,16 +35,29 @@ def no_compile_cache():
         yield
 
 
+def _results_in_memory(text):
+    """(line, result type) of every array in memory that is no kernel
+    call's own output."""
+    for line in arrays_in_memory(text).splitlines():
+        if "custom-call" in line or "get-tuple-element" in line:
+            continue
+        yield line, (line.split(" = ")[1].split("(")[0]
+                     if " = " in line else "")
+
+
 def test_dense_hybrid_step_compiles_for_v5e_on_the_kernels(
         topo, no_compile_cache, monkeypatch):
     """The cell granite-4.0-h-micro.s4096's step (nine Mamba-2 blocks of ONE
     group at chunk 256, one attention block at 32 / 8 heads of 64, ten
-    SwiGLU FFNs, the tied sliced head): the scan's kernels, both flash
+    SwiGLU FFNs, the tied sliced head): the scan's kernels at 16 heads a
+    grid step (what ``ssm_head_tile`` picks under the default scoped VMEM,
+    which the compiler accepts with nothing asked of it: PR 61), both flash
     kernels and the head's kernel are in the program, no array of attention
-    scores (``[heads.., 4096, 4096]``) or of a head tile's whole states is
-    in memory, and the step fits with the room ISSUE 49 asks for (the
-    scan's float32 output ``y`` IS ``[1, 4096, 4096]``: 4096 positions of
-    64 x 64 channels)."""
+    scores (``[heads.., 4096, 4096]``) is in memory and none of the chunks'
+    states but the forward kernel's own output, which the backward kernel
+    reads with no copy between, and the step fits with the room ISSUE 49
+    asks for (the scan's float32 output ``y`` IS ``[1, 4096, 4096]``: 4096
+    positions of 64 x 64 channels)."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     step, args, shapes, step_bytes = cell_step("granite-4.0-h-micro.s4096",
                                                 topo)
@@ -53,6 +66,8 @@ def test_dense_hybrid_step_compiles_for_v5e_on_the_kernels(
     assert pa.attention_path(s, s, h, d, True, False) == "flash"
     assert px.xent_path(b * s, shapes["vocab"], jnp.bfloat16)[0] == "kernel"
     assert pallas_ssm.ssm_eligible(s, 64, 64, 1, 128, 256)
+    tile = pallas_ssm.ssm_head_tile(64, 64, 1, 128, 256)
+    assert tile == 16
     compiled = step.lower(*args).compile()
     text = compiled.as_text()
     calls = [line for line in text.splitlines()
@@ -65,6 +80,23 @@ def test_dense_hybrid_step_compiles_for_v5e_on_the_kernels(
     assert sum("hvd_fused_xent" in c for c in calls) == shapes["head_calls"]
     scores = re.findall(r"(?:f32|bf16)\[(?:\d+,)*(?:8,4|32),4096,4096\]", text)
     assert not scores, sorted(set(scores))
+    # the states 16 chunks start from, a head tile's 1024 channels a block
+    states = f"f32[{b},{s // 256},{64 // tile},128,{tile * 64}]"
+    forward = [c for c in calls if pallas_ssm.FWD_NAME + "/" in c]
+    backward = [c for c in calls if pallas_ssm.BWD_NAME + "/" in c]
+    assert forward and backward
+    # what the compiler took of the scoped VMEM for each call, against the
+    # estimate the tile was chosen by and the default limit
+    used = [int(n) for c in forward + backward for n in re.findall(
+        r'"used_scoped_memory_configs":\[\{[^}]*"size":"(\d+)"', c)]
+    estimate = pallas_ssm.ssm_vmem_bytes(256, tile * 64, 128, 2)
+    assert len(used) == len(forward + backward)
+    assert max(used) <= estimate <= pallas_ssm.VMEM_BUDGET == 16 * 2 ** 20, (
+        used, estimate)
+    assert any(states in c.split(" custom-call(")[0] for c in forward)
+    assert all(states in c.split(" custom-call(")[1] for c in backward)
+    for line, result in _results_in_memory(text):
+        assert states not in result, line
     total = step_bytes(compiled.memory_analysis())["total"]
     assert 13.6e9 < total < 14.9e9, total
 
@@ -125,10 +157,7 @@ def test_mamba_block_keeps_a_chunk_s_inside_on_the_chip(mamba_block_text):
     states = f"f32[1,{S // Q},{G},{N},{H // G * P}]"
     assert all(states in c.split(" custom-call(")[0] for c in forward)
     assert states in backward[0].split(" custom-call(")[1]
-    for line in arrays_in_memory(text).splitlines():
-        result = line.split(" = ")[1].split("(")[0] if " = " in line else ""
-        if "custom-call" in line or "get-tuple-element" in line:
-            continue
+    for line, result in _results_in_memory(text):
         assert states not in result, line
         for dims in re.findall(r"f32\[([\d,]+)\]", result):
             dims = [int(d) for d in dims.split(",")]
