@@ -33,15 +33,18 @@ Phases (default, one chip):
            against its XLA reference at real widths (the block attention
            kernels at the shapes of both BERT cells, with padded keys and
            a row of nothing else; the flash kernel's gradients through
-           hvd_flash_bwd; o, lse and the gradients again at the four causal
+           hvd_flash_bwd and, where a head is whole lane tiles,
+           hvd_flash_adj, which makes its adj rows; o, lse and the gradients
+           again at the four causal
            cells' own shapes, the latent cell's 20 heads of 256 and the share
            cell's window and seven query heads a key/value head among them,
            attention_path saying the forward's form (operands in place or
            heads first), the blocks a tile on the diagonal or on a band's
-           edge runs, what the band leaves of a head's tiles and the group,
+           edge runs, what the band leaves of a head's tiles, the group and
+           who made adj,
            with the blocks the latent cell's step checkpoints); then two
            steps of the flagship transformer at
-           head_dim 128 with the three kernels asserted in the compiled
+           head_dim 128 with the four kernels asserted in the compiled
            program. xent_path says how the LM loss ran (the
            kernel's rows and chunk and its grid steps, or why XLA).
            The untied embedding's lookup and hand-written gradient at two
@@ -435,6 +438,15 @@ def phase_train(smoke: Smoke, hvd) -> None:
 # kernels: compiled Pallas against the XLA reference of the same file
 # ---------------------------------------------------------------------------
 
+def _flash_bwd_kernels(head_dim: int) -> tuple:
+    """The flash backward's calls at heads of ``head_dim``: the kernel and,
+    where a head is whole lane tiles, the one that makes the ``adj`` rows
+    it reads from do and o (a head of 64 keeps the ``jax.numpy`` sums)."""
+    from horovod_tpu.ops import pallas_attention as pa
+    return ("hvd_flash_bwd",) + (
+        () if head_dim % pa.MIN_BLOCK else (pa.ADJ_NAME,))
+
+
 def _has_kernel(compiled, kernel: str) -> bool:
     """Whether the compiled program holds a tpu_custom_call instruction
     named after ``kernel`` (the pallas_call's ``name``)."""
@@ -456,15 +468,16 @@ def _weighted_sum(attn, w):
     return lambda q, k, v: jnp.sum(attn(q, k, v).astype(jnp.float32) * w)
 
 
-def _run_compiled(smoke: Smoke, fn, args, kernel: str):
-    """Compile ``fn`` for the attached device, require the named kernel (if
-    one is named) as a tpu_custom_call in the program (on the chip), and run
-    that program."""
+def _run_compiled(smoke: Smoke, fn, args, kernel):
+    """Compile ``fn`` for the attached device, require the named kernel
+    (or kernels; none if none is named) as a tpu_custom_call in the program
+    (on the chip), and run that program."""
     import jax
     compiled = jax.jit(fn).lower(*args).compile()
-    if smoke.on_chip and kernel:
-        check(_has_kernel(compiled, kernel),
-              f"{kernel}: no such tpu_custom_call in the compiled program")
+    names = (kernel,) if isinstance(kernel, str) else tuple(kernel or ())
+    for name in names if smoke.on_chip else ():
+        check(_has_kernel(compiled, name),
+              f"{name}: no such tpu_custom_call in the compiled program")
     return compiled(*args)
 
 
@@ -504,7 +517,7 @@ def _check_flash(smoke: Smoke, shape: tuple) -> None:
                  FLASH_TOL, shape=shape, dtype="bfloat16",
                  attention_path=_attention_path(shape))
     got = _run_compiled(smoke, jax.grad(_weighted_sum(kernel, w), (0, 1, 2)),
-                        (q, k, v), "hvd_flash_bwd")
+                        (q, k, v), _flash_bwd_kernels(shape[-1]))
     want = jax.jit(jax.grad(_weighted_sum(_reference_attention, w),
                             (0, 1, 2)))(q, k, v)
     for name, g, r in zip(("dq", "dk", "dv"), got, want):
@@ -565,7 +578,7 @@ def _check_flash_cells(smoke: Smoke) -> None:
                                "hvd_flash_attention")
         dq, dk, dv = _run_compiled(
             smoke, jax.grad(weighed(kernel, w, u), (0, 1, 2)), (q, k, v),
-            "hvd_flash_bwd")
+            _flash_bwd_kernels(D))
         errs = dict.fromkeys(
             ("fwd o", "fwd lse", "grad dq", "grad dk", "grad dv"), 0.0)
         group = H // Hkv
@@ -652,7 +665,7 @@ def _check_banded(smoke: Smoke, sizes: tuple, what: str) -> None:
                  attention_path=_attention_path((B, S, H, D), kv_heads=Hkv,
                                                 window=window))
     got = _run_compiled(smoke, jax.grad(_weighted_sum(kernel, w), (0, 1, 2)),
-                        (q, k, v), "hvd_flash_bwd")
+                        (q, k, v), _flash_bwd_kernels(D))
     want = jax.jit(jax.grad(_weighted_sum(reference, w), (0, 1, 2)))(q, k, v)
     for name, g, r in zip(("dq", "dk", "dv"), got, want):
         _kernel_line(smoke, "flash_attention", f"{what} grad {name}",
@@ -1232,9 +1245,9 @@ def _attention_path(shape, causal=True, masked=False, kv_heads=None,
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
     kv = jax.ShapeDtypeStruct((B, S, kv_heads or H, D), jnp.bfloat16)
     mask = jax.ShapeDtypeStruct((B, S), bool) if masked else None
-    text = jax.jit(lambda q, k, v, m: pa.attend(
-        q, k, v, causal=causal, key_mask=m, window=window)).lower(
-            x, kv, kv, mask).as_text()
+    text = jax.jit(jax.grad(lambda q, k, v, m: jnp.sum(pa.attend(
+        q, k, v, causal=causal, key_mask=m, window=window).astype(
+            jnp.float32)), (0, 1, 2))).lower(x, kv, kv, mask).as_text()
     if "tpu_custom_call" not in text:
         if window is not None or kv_heads not in (None, H):
             return "xla _banded_attention"
@@ -1247,7 +1260,14 @@ def _attention_path(shape, causal=True, masked=False, kv_heads=None,
                 f"{pa.LANES // D} heads a step, "
                 f"{math.prod(pa.block_grid(B, H, D, rows))} steps, "
                 f"VMEM estimate {mib:.1f} MiB")
-    return _flash_call((B, S, H, D), kv_heads, window)
+    adj = "jax.numpy sums, no kernel"
+    if pa.ADJ_NAME in text:
+        rows, heads = pa.flash_adj_blocks(S, H, D, jnp.bfloat16)
+        adj = (f"{pa.ADJ_NAME} from do and o {[B, S, H * D]} where they "
+               f"lie, {rows} rows x {heads} heads and "
+               f"{B * S // rows * H // heads} steps")
+    return (_flash_call((B, S, H, D), kv_heads, window)
+            + f"; adj = sum do * o - dlse by {adj}")
 
 
 def _flash_call(shape, kv_heads=None, window=None) -> str:
@@ -1334,7 +1354,8 @@ def _check_flagship(smoke: Smoke, hvd) -> None:
     compiled = step.lower(params, opt_state, tokens, targets).compile()
     compile_s = time.perf_counter() - t0
     in_program = {k: _has_kernel(compiled, k)
-                  for k in ("hvd_flash_attention", "hvd_flash_bwd",
+                  for k in ("hvd_flash_attention",
+                            *_flash_bwd_kernels(cfg.head_dim),
                             "hvd_fused_xent")}
     if smoke.on_chip:
         check(all(in_program.values()),
@@ -1548,9 +1569,9 @@ def _four_ring(smoke: Smoke, hvd) -> None:
     compiled = jax.jit(jax.grad(_weighted_sum(ring, w), (0, 1, 2))).lower(
         q, k, v).compile()
     if smoke.on_chip:
-        check(_has_kernel(compiled, "hvd_flash_attention")
-              and _has_kernel(compiled, "hvd_flash_bwd"),
-              "the flash kernels are not both in the ring attention "
+        check(all(_has_kernel(compiled, k) for k in (
+            "hvd_flash_attention", *_flash_bwd_kernels(shape[-1]))),
+              "the flash kernels are not all in the ring attention "
               "program")
         check("collective-permute" in compiled.as_text(),
               "no ring permute in the program")

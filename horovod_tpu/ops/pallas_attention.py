@@ -136,7 +136,7 @@ Same precision as the forward:
 operands multiply as they come and accumulate in float32, ``pT`` and
 ``dsT`` are float32 in VMEM and cast for their matmuls. q, k, v and do are
 read as ``[B, S, H·D]``, a head whole 128-lane columns, and dq, dk, dv
-written so: no transpose around the call.
+written so: no transpose around the call (``adj``: ``hvd_flash_adj``, below).
 
 **A head of 64** (half a lane tile) runs both kernels heads-first, ``[B·H,
 S, 64]``: a ``(1, tile, 64)`` block spans the array's whole last dimension,
@@ -1000,6 +1000,91 @@ def _flash_bwd_local(q, k, v, do, lse, adj, *, H, causal, scale, blocks,
     )(q, do, k, v, lse, adj)
 
 
+# ``adj = sum_d do · o - dlse``, the ``[B·H, 1, Sq]`` float32 rows the
+# backward reads beside ``lse``, is a third kernel's at heads of whole lane
+# tiles, ``hvd_flash_adj`` (PR 62): it reads do and o once, as the ``[B, S,
+# H·D]`` arrays the o-projection's backward and the forward kernel wrote,
+# whole rows a grid step, and is bound by those bytes (v5e, ``[1, 8192,
+# 20·256]`` in glm-4.7-flash.s8192's step: 0.22 ms a call, 753 GB/s). As
+# ``jax.numpy`` the sums were XLA's to lay out: the arithmetic was 0.11 ms a
+# call there, but XLA fed it do and o relaid with the tokens on the lanes,
+# 0.83 ms a call of copies and 0.26 of waits. Alone, on row-major operands,
+# XLA's sums are as fast as this kernel: the gain is the step's, not the
+# call's. Inside ``hvd_flash_bwd`` (o a fourth q-shaped operand, the rows
+# made at a q tile's first live k tile) the sums read within 0.12 ms a call
+# of this form and took the backward's last 0.27 MiB of VMEM at 20 heads of
+# 256 (PERF.md, PR 62).
+ADJ_NAME = "hvd_flash_adj"
+#: bytes of do (and of o) a grid step of ``hvd_flash_adj`` may read: two
+#: operands double-buffered are four such blocks, under the v5e's default
+#: scoped-VMEM limit
+ADJ_BLOCK_BYTES = 2 * 1024 * 1024
+
+
+def flash_adj_blocks(Sq: int, H: int, D: int, dtype) -> Tuple[int, int]:
+    """``(rows, heads)`` of a grid step of ``hvd_flash_adj``: a block is
+    ``rows`` positions of ``heads`` heads' lane tiles of ``[B, Sq, H·D]``.
+    The kernel is bound by the bytes it reads, so a block is as many whole
+    heads as ``ADJ_BLOCK_BYTES`` hold at ``MIN_BLOCK`` rows (all of them in
+    every cell: whole rows of the array, one contiguous read), then as many
+    rows of ``TILES`` as still fit."""
+    unit = MIN_BLOCK * D * jnp.dtype(dtype).itemsize
+    heads = max(h for h in range(1, H + 1) if H % h == 0
+                and (h == 1 or h * unit <= ADJ_BLOCK_BYTES))
+    rows = next((t for t in TILES if Sq % t == 0
+                 and t // MIN_BLOCK * heads * unit <= ADJ_BLOCK_BYTES),
+                MIN_BLOCK)
+    return rows, heads
+
+
+def _flash_adj_kernel(do_ref, o_ref, dlse_ref, adj_ref, *, D: int):
+    """One (batch row, q rows, heads) step: ``adj = sum_d do · o - dlse`` of
+    the block's heads, each a ``[1, rows]`` row of ``adj_ref [heads, 1,
+    rows]``, from ``[1, rows, heads · D]`` blocks of do and o. Of a head's
+    ``[MIN_BLOCK positions, D]`` float32 products the lane tiles are added,
+    the one that is left is transposed and the sum runs down its sublanes,
+    so that a position's sum lies on its lane: no MXU pass, nothing rounded
+    below float32."""
+    rows, lanes = do_ref.shape[1:]
+    for r0 in range(0, rows, MIN_BLOCK):
+        at = pl.ds(r0, MIN_BLOCK)
+        for h in range(lanes // D):
+            tiles = [pl.ds(l0, MIN_BLOCK)
+                     for l0 in range(h * D, (h + 1) * D, MIN_BLOCK)]
+            folded = functools.reduce(jnp.add, (
+                do_ref[0, at, t].astype(jnp.float32)
+                * o_ref[0, at, t].astype(jnp.float32) for t in tiles))
+            adj_ref[h, :, at] = jnp.sum(jnp.transpose(folded), axis=0,
+                                        keepdims=True) - dlse_ref[h, :, at]
+
+
+@functools.partial(jax.jit, static_argnames=("H", "interpret"))
+def _flash_adj_local(do, o, dlse, *, H, interpret):
+    """``adj [B·H, 1, Sq]`` float32, the rows ``hvd_flash_bwd`` reads, of do
+    and o ``[B, Sq, H·D]`` as they arrive (the forward's output and its
+    cotangent, in the dtype they have; heads of whole lane tiles) and
+    ``dlse [B·H, 1, Sq]`` float32: do and o are read once, by this kernel,
+    and nothing float32 of their size is written."""
+    B, Sq, M = do.shape
+    D = M // H
+    rows, heads = flash_adj_blocks(Sq, H, D, do.dtype)
+    groups = H // heads
+    wide = pl.BlockSpec((1, rows, heads * D), lambda b, i, g: (b, i, g))
+    row = pl.BlockSpec((heads, 1, rows),
+                       lambda b, i, g: (b * groups + g, 0, i))
+    return pl.pallas_call(
+        functools.partial(_flash_adj_kernel, D=D),
+        grid=(B, Sq // rows, groups),
+        in_specs=[wide, wide, row],
+        out_specs=row,
+        out_shape=jax.ShapeDtypeStruct((B * H, 1, Sq), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel")),
+        interpret=interpret,
+        name=ADJ_NAME,
+    )(do, o, dlse)
+
+
 def flash_backward(q, k, v, o, lse, do, dlse, causal: bool, scale: float,
                    blocks: Optional[BwdBlocks] = None,
                    interpret: bool = False, window: Optional[int] = None):
@@ -1007,10 +1092,10 @@ def flash_backward(q, k, v, o, lse, do, dlse, causal: bool, scale: float,
     ``[B, S, H, D]``, lse ``[B*H, Sq]``) and the cotangents of ``o`` and
     ``lse``: p = exp(s - lse) is recomputed tile by tile in VMEM (Dao et
     al.), dv = pT do, ds = p * (do vT - adj), dq = ds k, dk = dsT q, all
-    times ``scale``. ``adj = sum_d do * o - dlse`` is computed here, once,
-    in float32: the lse cotangent enters through d lse / d s_j = p_j (lse
-    is the row log-partition), which is what makes the (o, lse) pair
-    usable as a mergeable partial result (ring attention). Operands
+    times ``scale``. ``adj = sum_d do * o - dlse`` is ``hvd_flash_adj``'s,
+    once a call, in float32: the lse cotangent enters through d lse / d
+    s_j = p_j (lse is the row log-partition), which is what makes the (o,
+    lse) pair usable as a mergeable partial result (ring attention). Operands
     multiply in the dtype they come in and accumulate in float32; p and ds
     are float32 in VMEM and cast for their matmuls. ``blocks`` overrides
     :func:`flash_bwd_blocks` (tests, sweeps). k and v may have fewer heads
@@ -1021,23 +1106,18 @@ def flash_backward(q, k, v, o, lse, do, dlse, causal: bool, scale: float,
     Sk, Hkv = k.shape[1], k.shape[2]
     _check_window(window, causal)
     blocks = blocks or flash_bwd_blocks(Sq, Sk, D, q.dtype, window)
-    # o's float32 copy is made when do arrives, not before: alone, the
-    # conversion depends on the forward pass only, and XLA:TPU has started it
-    # there and kept 4 bytes an element of o alive into the backward pass in
-    # place of 2 (smallthinker-21b-a3b.s8192: +0.23 GB; PERF.md, PR 50)
-    do, o = lax.optimization_barrier((do, o))
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-    adj = delta.transpose(0, 2, 1).reshape(B * H, Sq) \
-        - dlse.astype(jnp.float32)
     if D % MIN_BLOCK:
-        return _flash_backward_heads_first(q, k, v, do, lse, adj, causal,
+        return _flash_backward_heads_first(q, k, v, o, lse, do, dlse, causal,
                                            scale, blocks, interpret, window)
+    adj = _flash_adj_local(
+        do.reshape(B, Sq, H * D), o.reshape(B, Sq, H * D),
+        dlse.astype(jnp.float32).reshape(B * H, 1, Sq), H=H,
+        interpret=interpret)
     dq, dk, dv = _flash_bwd_local(
         q.reshape(B, Sq, H * D), k.reshape(B, Sk, Hkv * D),
         v.reshape(B, Sk, Hkv * D), do.reshape(B, Sq, H * D),
-        lse.reshape(B * H, 1, Sq), adj.reshape(B * H, 1, Sq), H=H,
-        causal=causal, scale=scale, blocks=blocks, interpret=interpret,
-        window=window)
+        lse.reshape(B * H, 1, Sq), adj, H=H, causal=causal, scale=scale,
+        blocks=blocks, interpret=interpret, window=window)
 
     def total(parts):
         if Hkv != H:    # a k/v head's gradient: its group's, every range's
@@ -1049,14 +1129,26 @@ def flash_backward(q, k, v, o, lse, do, dlse, causal: bool, scale: float,
     return dq.reshape(B, Sq, H, D), total(dk), total(dv)
 
 
-def _flash_backward_heads_first(q, k, v, do, lse, adj, causal, scale, blocks,
-                                interpret, window):
+def _flash_backward_heads_first(q, k, v, o, lse, do, dlse, causal, scale,
+                                blocks, interpret, window):
     """:func:`flash_backward` for a head that is no whole lane column of
     ``[B, S, H·D]`` (D = 64): the operands heads first, ``[B·H, S, D]``, as
     the forward takes them; a k/v head's dk and dv are the float32 sum
-    over its group's q heads and the q ranges."""
+    over its group's q heads and the q ranges. ``adj``'s row sums stay
+    ``jax.numpy``: XLA makes them in the pass that moves do heads first,
+    where ``hvd_flash_adj`` would read do a second time
+    (lfm2-24b-a2b.s8192: -0.18 % with the kernel reading do and o in place,
+    two heads a lane tile; PERF.md, PR 62)."""
     B, Sq, H, D = q.shape
     Sk, Hkv = k.shape[1], k.shape[2]
+    # o's float32 copy is made when do arrives, not before: alone, the
+    # conversion depends on the forward pass only, and XLA:TPU has started it
+    # there and kept 4 bytes an element of o alive into the backward pass in
+    # place of 2 (PERF.md, PR 50)
+    do, o = lax.optimization_barrier((do, o))
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    adj = delta.transpose(0, 2, 1).reshape(B * H, Sq) \
+        - dlse.astype(jnp.float32)
 
     dq, dk, dv = _flash_bwd_local(
         *(_heads_first(x) for x in (q, k, v, do)),

@@ -402,3 +402,131 @@ def test_attend_takes_the_kernels_at_a_head_of_64_on_a_tpu(monkeypatch):
     # heads first: the kernels' operands are [B * heads, S, 64]
     assert "bf16[8,256,64]" in text and "bf16[2,256,64]" in text
 
+
+
+# -- adj = sum_d do * o - dlse is a kernel's, ``hvd_flash_adj``, where a head
+# is whole lane tiles (ISSUE 62) --------------------------------------------
+
+def _parent_adj(do, o, dlse, *, H, interpret):
+    """The parent's ``jax.numpy`` form in ``_flash_adj_local``'s place:
+    float32 copies of do and o, their product summed over a head, the
+    heads moved in front of the positions."""
+    B, Sq, M = do.shape
+    prod = do.astype(jnp.float32) * o.astype(jnp.float32)
+    delta = jnp.sum(prod.reshape(B, Sq, H, M // H), axis=-1)
+    return delta.transpose(0, 2, 1).reshape(B * H, 1, Sq) - dlse
+
+
+#: name -> (S, H, Hkv, D, window, the backward's blocks (None: the rule's,
+#: and ``jax.grad`` through the custom VJP too), dtype, a live dlse)
+_ADJ_CASES = {
+    "a head of 64 in groups of 4, heads first": (256, 8, 2, 64, None, None,
+                                                 jnp.bfloat16, True),
+    "three heads of 64, heads first": (128, 3, 3, 64, None, None,
+                                       jnp.float32, False),
+    "a head of 128, a group of 1": (256, 2, 2, 128, None, None, jnp.float32,
+                                    True),
+    "a head of 256": (256, 2, 2, 256, None, None, jnp.bfloat16, False),
+    "a group of 7": (128, 7, 1, 128, None, None, jnp.float32, True),
+    "a group of 16": (128, 16, 1, 128, None, None, jnp.bfloat16, False),
+    "a window narrower than a tile": (512, 2, 1, 128, 192,
+                                      pa.BwdBlocks(256, 256, 512),
+                                      jnp.float32, True),
+    "a window wider than a tile": (512, 2, 2, 128, 320,
+                                   pa.BwdBlocks(128, 128, 512), jnp.float32,
+                                   False),
+    "two q ranges": (512, 2, 2, 128, None, pa.BwdBlocks(128, 128, 256),
+                     jnp.float32, True),
+    "two q ranges under a window, heads of 64": (
+        512, 4, 2, 64, 256, pa.BwdBlocks(128, 128, 256), jnp.float32, True),
+    "more lanes than a block holds: a head a step": (
+        128, 17, 17, 256, None, None, jnp.float32, True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_ADJ_CASES))
+def test_adj_is_the_kernel_s_and_the_gradients_are_the_parent_s(case,
+                                                                 monkeypatch):
+    """``hvd_flash_adj``'s rows against ``sum(do32 * o32, -1) - dlse`` to
+    1e-6 of the largest, and dq, dk, dv of ``flash_backward`` (and of
+    ``jax.grad`` through ``flash_attention_with_lse`` / ``_tpu`` where the
+    tile is the rule's) against the same call with the parent's
+    ``jax.numpy`` sums in the kernel's place, to the backward cases'
+    tolerance. A head of 64 goes heads first and keeps those sums: its
+    call holds no ``hvd_flash_adj``."""
+    S, H, Hkv, D, window, blocks, dtype, live_dlse = _ADJ_CASES[case]
+    rng = np.random.RandomState(62)
+
+    def mk(heads, scale=0.5):
+        return (jnp.asarray(rng.randn(1, S, heads, D), jnp.float32)
+                * scale).astype(dtype)
+    q, k, v, do = mk(H), mk(Hkv), mk(Hkv), mk(H, 1.0)
+    o, lse = pa.flash_attention_with_lse(q, k, v, True, interpret=True,
+                                         window=window)
+    dlse = jnp.asarray(rng.randn(H, S) * live_dlse, jnp.float32)
+
+    def backward():
+        return pa.flash_backward(q, k, v, o, lse, do, dlse, True, D ** -0.5,
+                                 blocks, interpret=True, window=window)
+    in_place = D % pa.MIN_BLOCK == 0
+    assert (pa.ADJ_NAME in str(jax.make_jaxpr(backward)())) == in_place
+    if in_place:
+        rows = pa._flash_adj_local(
+            do.reshape(1, S, H * D), o.reshape(1, S, H * D),
+            dlse.reshape(H, 1, S), H=H, interpret=True)
+        want = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                       -1).transpose(0, 2, 1).reshape(H, 1, S) \
+            - dlse.reshape(H, 1, S)
+        assert rows.shape == want.shape and rows.dtype == jnp.float32
+        assert float(jnp.max(jnp.abs(rows - want))) \
+            <= 1e-6 * float(jnp.max(jnp.abs(want)))
+
+    def grads():
+        if blocks is not None:
+            return ()
+        if live_dlse:
+            def loss(q, k, v):
+                o, lse = pa.flash_attention_with_lse(
+                    q, k, v, True, interpret=True, window=window)
+                return jnp.sum(o.astype(jnp.float32) * do) \
+                    + jnp.sum(lse * dlse)
+        else:
+            def loss(q, k, v):
+                return jnp.sum(flash_attention_tpu(
+                    q, k, v, True, interpret=True,
+                    window=window).astype(jnp.float32) * do)
+        return jax.grad(loss, (0, 1, 2))(q, k, v)
+    got = backward() + grads()
+    monkeypatch.setattr(pa, "_flash_adj_local", _parent_adj)
+    reference = backward() + grads()
+    assert len(got) == (3 if blocks else 6)
+    assert_backward(got[:3], reference[:3])
+    assert_backward(got[3:], reference[3:])
+    # and the two ways in agree: the custom VJP calls ``flash_backward``
+    for g, r in zip(got[3:], got[:3]):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(r))
+
+
+#: q of the seven cells that run ``hvd_flash_adj`` (``flash_backward`` at
+#: heads of whole lane tiles) -> (rows, heads) a step
+_ADJ_BLOCKS = {
+    (8192, 20, 256): (128, 20), (8192, 28, 128): (256, 28),
+    (8192, 64, 128): (128, 64), (8192, 48, 128): (128, 48),
+    (4096, 16, 128): (512, 16), (2048, 16, 128): (512, 16),
+    (8192, 32, 128): (256, 32),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(_ADJ_BLOCKS))
+def test_flash_adj_blocks_are_whole_rows_of_the_cells_arrays(shape):
+    """A step of ``hvd_flash_adj`` reads whole rows of ``[B, S, H * D]``
+    (every head: one contiguous read) at the cells' shapes, as many as
+    ``ADJ_BLOCK_BYTES`` hold; wider rows go a divisor of the heads a
+    step."""
+    S, H, D = shape
+    rows, heads = pa.flash_adj_blocks(S, H, D, jnp.bfloat16)
+    assert (rows, heads) == _ADJ_BLOCKS[shape]
+    assert rows * heads * D * 2 <= pa.ADJ_BLOCK_BYTES
+    rows32, heads32 = pa.flash_adj_blocks(S, H, D, jnp.float32)
+    assert H % heads32 == 0
+    assert rows32 * heads32 * D * 4 <= pa.ADJ_BLOCK_BYTES

@@ -5,6 +5,7 @@ and the embedding's gradient around their kernels. Also here: the one
 compile-cache rule (``utils/compile_cache``) and the contract that
 ``chip_smoke.py`` fails without a chip."""
 
+import math
 import os
 import re
 import subprocess
@@ -150,21 +151,18 @@ _ATTENTION_BLOCKS = {
 }
 
 
-@pytest.mark.parametrize("block", sorted(_ATTENTION_BLOCKS))
-def test_attention_block_hands_the_forward_kernel_its_operands_in_place(
-        block, v5e, no_compile_cache, monkeypatch):
-    """``hvd_flash_attention`` reads q, k, v and writes o as ``[1, S, H *
-    D]`` in both of a checkpointed block's calls, under the default scoped
-    VMEM (no limit is asked for), and the program holds no heads-first
-    copy of any of them: no ``[H, S, D]`` array (the parent's ``copy`` and
-    ``transpose`` between ``[1, 8192, 20, 256]`` and ``[20, 8192, 256]``),
-    so whatever lies beside the call moves ``[1, S, ..]`` arrays only."""
+_block_texts = {}
+
+
+def _attention_block_text(block, v5e):
+    """(the compiled text of the checkpointed block's gradient, S, heads,
+    head width); compiled once a block and module."""
     import numpy as np
     fields, stack, S = _ATTENTION_BLOCKS[block]
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg = transformer.TransformerConfig(
         n_layers=1, dtype=jnp.bfloat16, max_seq=S, vocab_size=1024, **fields)
-    H, D = cfg.n_heads, cfg.head_dim
+    if block in _block_texts:
+        return _block_texts[block], S, cfg.n_heads, cfg.head_dim
     layers = jax.eval_shape(lambda: transformer.init_params(
         np.random.RandomState(0), cfg, 1))["layers"]
     params = jax.tree_util.tree_map(
@@ -181,10 +179,24 @@ def test_attention_block_hands_the_forward_kernel_its_operands_in_place(
         run = jax.checkpoint(lambda p, h: apply(p, h, positions, cfg,
                                                 kind)[0])
         return sum32(jnp.square(run(p, h)))
+    _block_texts[block] = jax.jit(jax.grad(loss, (0, 1))).lower(
+        params, h, positions).compile().as_text()
+    return _block_texts[block], S, cfg.n_heads, cfg.head_dim
+
+
+@pytest.mark.parametrize("block", sorted(_ATTENTION_BLOCKS))
+def test_attention_block_hands_the_forward_kernel_its_operands_in_place(
+        block, v5e, no_compile_cache, monkeypatch):
+    """``hvd_flash_attention`` reads q, k, v and writes o as ``[1, S, H *
+    D]`` in both of a checkpointed block's calls, under the default scoped
+    VMEM (no limit is asked for), and the program holds no heads-first
+    copy of any of them: no ``[H, S, D]`` array (the parent's ``copy`` and
+    ``transpose`` between ``[1, 8192, 20, 256]`` and ``[20, 8192, 256]``),
+    so whatever lies beside the call moves ``[1, S, ..]`` arrays only."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text, S, H, D = _attention_block_text(block, v5e)
     assert pa.flash_vmem_bytes(*pa.flash_blocks(S, S, D, jnp.bfloat16), D,
                                2) <= pa.VMEM_BUDGET
-    text = jax.jit(jax.grad(loss, (0, 1))).lower(
-        params, h, positions).compile().as_text()
     calls = [line for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line
              and "hvd_flash_attention" in line.split(" = ")[0]]
@@ -202,6 +214,50 @@ def test_attention_block_hands_the_forward_kernel_its_operands_in_place(
             assert defined[operand].startswith(where), (operand, call)
     heads_first = re.findall(rf"\w+\[(?:1,)?{H},{S},{D}\]", text)
     assert not heads_first, sorted(set(heads_first))
+
+
+
+@pytest.mark.parametrize("block", sorted(_ATTENTION_BLOCKS))
+def test_the_flash_backward_s_row_sums_are_a_kernel_s(
+        block, v5e, no_compile_cache, monkeypatch):
+    """ISSUE 62: ``adj = sum_d do * o - dlse`` is ``hvd_flash_adj``'s. The
+    kernel takes do as the o-projection's backward wrote it and o as the
+    (recomputed) forward kernel did, both ``bf16[1, S, H * D]``, and hands
+    ``hvd_flash_bwd`` its last operand; beside the three Pallas calls
+    nothing of the phase ``hvd.attention.core`` makes a float32 value of
+    o's size, in memory or inside a fusion (the parent's ``jax.numpy`` sums
+    were a fusion of two ``convert`` and a ``multiply`` of ``f32[1, 8192,
+    20, 256]`` in front of each backward call: 0.97 ms a call in
+    glm-4.7-flash.s8192 for 0.2 ms of bytes)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text, S, H, D = _attention_block_text(block, v5e)
+    defined = dict(re.findall(r"^\s*(?:ROOT )?(%\S+) = (.*)$", text, re.M))
+
+    def call(kernel):
+        (found,) = [(name, line) for name, line in defined.items()
+                    if 'custom_call_target="tpu_custom_call"' in line
+                    and name.startswith("%" + kernel + ".")]
+        name, line = found
+        return name, re.findall(r"%[\w.\-]+", line.split(
+            " custom-call(")[1].split(")")[0])
+    adj, (do, o, _dlse) = call(pa.ADJ_NAME)
+    _bwd, operands = call("hvd_flash_bwd")
+    assert operands[-1] == adj and operands[1] == do, operands
+    where = f"bf16[1,{S},{H * D}]"
+    assert defined[do].startswith(where) and defined[o].startswith(where)
+    # o is the forward kernel's own output (XLA's own move of a buffer to
+    # on-chip memory and back apart)
+    while (moved := re.search(r" copy-(?:done|start)\((%[\w.\-]+)\)",
+                              defined[o])):
+        o = moved.group(1)
+    made_o = re.search(r"get-tuple-element\((%[\w.\-]+)\), index=0",
+                       defined[o])
+    assert made_o and "hvd_flash_attention" in made_o.group(1), defined[o]
+    for line in text.splitlines():
+        if "hvd.attention.core" not in line:
+            continue
+        for dims in re.findall(r" = f32\[([\d,]+)\]", line):
+            assert math.prod(map(int, dims.split(","))) < S * H * D, line
 
 
 @pytest.mark.parametrize("cell", ["gpt-1.3b-widths.s2048",
