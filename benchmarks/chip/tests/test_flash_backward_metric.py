@@ -66,15 +66,20 @@ def test_the_count_at_the_cells_shapes(cell):
 def test_the_metric_files_and_their_entries(name):
     spec = _read(CHIP, "layer_metrics", name + ".json")
     assert spec["read"]["trace_ops"] == "hvd_flash_bwd"
-    # the three cells counted here first; a cell listed since stands after
-    assert spec["workloads"][:3] == sorted(CELLS)
     entry = {m["name"]: m for m in _read(ROOT, "BENCHMARK.json")[
         "per_layer"]}[name]
-    for key in ("layer", "unit", "better", "source", "moves", "workloads"):
+    # the three cells counted here first; a cell listed since stands after
+    assert entry["workloads"][:3] == sorted(CELLS)
+    for key in ("layer", "unit", "better", "source", "moves"):
         assert entry[key] == spec[key], key
     # the forward kernel's metrics must not catch the backward's name
-    for other in ("flash_attention_roofline", "loop.attention_fwd_ms",
-                  "loop.flash_attention_roofline"):
+    for other in ("flash_attention_roofline", "flash_attention_fwd_ms",
+                  "flash_attention_calls_roofline",
+                  "flash_attention_adj_ms"):
         pattern = _read(CHIP, "layer_metrics", other + ".json")[
             "read"]["trace_ops"]
         assert not re.search(pattern, "hvd_flash_bwd.3 custom-call")
+    # nor the backward's the row sums' kernel's, nor the forward's
+    for instruction in ("hvd_flash_adj.3 custom-call",
+                        "hvd_flash_attention.3 custom-call"):
+        assert not re.search(spec["read"]["trace_ops"], instruction)

@@ -41,12 +41,14 @@ def test_file_and_entry_agree(name):
                           "moves", "workloads"}
     for key in ("unit", "better", "source", "layer", "moves"):
         assert spec[key] == entry[key], key
-    assert spec.get("workloads") == entry.get("workloads")
+    # (the list of cells stands in the entry alone: a cell joins it with
+    # no edit to the file)
+    assert "workloads" not in spec
     assert spec["source"] in SOURCES and spec["better"] in ("lower", "higher")
     assert spec["moves"] in {m["name"] for m in BENCH["end_to_end"]}
     cells = {w["name"] for w in BENCH["workloads"]}
-    assert set(spec.get("workloads", ())) <= cells
-    assert spec.get("workloads") is None or spec["workloads"]
+    assert set(entry.get("workloads", ())) <= cells
+    assert "workloads" not in entry or entry["workloads"]
     if name.endswith("_roofline"):
         assert spec["unit"] == "%" and "roofline" in spec["read"]
 
@@ -66,28 +68,38 @@ def test_every_cell_reports_setup_a_rate_and_a_layer():
                 "setup.reference_s", "setup.first_step_s", "setup.warmup_s",
                 "step.fwd_ms", "step.bwd_ms", "step.opt_ms", "step.mixed_ms",
                 "step.unscoped_ms", "input.place_ms"} <= layers, cell
-    moe = {m["name"]: m["workloads"] for m in BENCH["per_layer"]
-           if m["name"].startswith("step.moe_")}
-    # (PR 59 listed the other cells with an expert layer under its parts
-    # where they had no name of their own for that read)
-    assert len(moe) == 5 and all(
-        w[:2] == ["olmoe-1b-7b.s4096", "smallthinker-21b-a3b.s8192"]
-        for w in moe.values())
+    # (since PR 63 a cell that reports its expert layer reports the
+    # layer's four parts too; the shared expert is some of those cells')
+    lists = {m["name"]: m.get("workloads") for m in BENCH["per_layer"]}
+    for part in ("router", "dispatch", "experts", "combine"):
+        assert lists[f"step.moe_{part}_ms"] == lists["step.moe_ms"], part
+    assert set(lists["step.moe_shared_ms"]) < set(lists["step.moe_ms"])
 
 
 def test_the_accepted_entries_stand_first_and_as_they_were():
-    """PR 34 appended twenty-six; the twenty-four before them are the
-    parent's, in the parent's order."""
+    """The standing list since PR 63's merge: the names it kept in the
+    order they had (nine of PR 34's twenty-four retired into others, so
+    fifteen stand before its twenty-six), the twelve it made appended in
+    the order of PERF.md section 3's table, then ``hvd_flash_adj``'s. A
+    later PR's entries stand after them."""
     names = [m["name"] for m in BENCH["per_layer"]]
     assert names[:5] == ["host.input_ms", "host.dispatch_ms",
                          "setup.compile_s", "setup.compiles_in_window",
                          "step.device_ms"]
-    assert names[23] == "share.moe_gmm_roofline"
-    assert names[24:36] == [
+    assert names[14] == "share.moe_gmm_roofline"
+    assert names[15:27] == [
         "step.fwd_ms", "step.bwd_ms", "step.opt_ms", "step.mixed_ms",
         "step.unscoped_ms", "step.attention_core_ms", "step.head_ms",
         "input.source_ms", "input.place_ms", "setup.trace_lower_s",
         "setup.cache_read_s", "setup.cache_misses"]
+    assert names[71:84] == [
+        "step.moe_shared_ms", "flash_attention_fwd_ms", "head_xent_ms",
+        "ssm_scan_kernels_ms", "step.attention_recompute_ms",
+        "step.ssm_recompute_ms", "moe_gmm_held_roofline",
+        "head_xent_roofline", "flash_attention_calls_roofline",
+        "flash_attention_kinds_roofline",
+        "flash_attention_kinds_bwd_roofline", "ssm_scan_roofline",
+        "flash_attention_adj_ms"]
     assert len(names) == len(set(names))
 
 

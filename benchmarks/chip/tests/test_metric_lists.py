@@ -1,8 +1,13 @@
 """A metric lists a cell only where the cell's program gives its ``read``
-something to read, and a cell reports one ``read`` under one name.
+something to read, and one ``read`` has one name.
 
-One case a (metric, cell) pair of ``BENCHMARK.json``'s ``workloads`` lists,
-as far as a CPU can tell:
+A metric's list of cells stands in ``BENCHMARK.json`` alone (its file in
+``layer_metrics/`` holds the ``read`` and the words, and no copy of the
+list), so a cell joins an accepted metric by its name in that entry's
+``workloads``, in the PR that brings the cell, with no edit to a file.
+
+One case a (metric, cell) pair of ``BENCHMARK.json``, as far as a CPU can
+tell:
 
 * a ``trace_scope`` phase, or a ``step_owners`` phase: the phase is in the
   lowered text of the cell's ``tiny`` step (lowered once a cell, never
@@ -10,25 +15,28 @@ as far as a CPU can tell:
 * a roofline share: its function takes ``adapter.shapes(config, job)`` at
   the published sizes and returns positive ``flops`` and ``bytes``;
 * a kernel (a sum over it, or a share of its roofline): the program's own
-  gate for that kernel (``flash_eligible``, ``gmm_path``, ``xent_path``,
-  ``ssm_scan_path``) takes the kernel at the published shapes, the backend
-  answered as ``rehearse.py`` answers it;
+  gate for that kernel (``flash_eligible``, ``flash_backward``'s head of
+  whole lane tiles, ``gmm_path``, ``xent_path``, ``ssm_scan_path``) takes
+  the kernel at the published shapes, the backend answered as
+  ``rehearse.py`` answers it;
 * a collective: the cell has more than one chip.
 
-Beside them the rule of README.md, "Adding a per-layer metric", in the two
-forms a CPU can hold: no cell is listed under two names of one ``read`` (a
-roofline function that only calls another's counts as that one), which
-holds today and is what a listing must keep; and no two files share a
-``read`` at all, which the per-cell copies break until a ``benchmark`` PR
-merges them (PERF.md section 7): that case is expected to fail and says so
-the day it stops.
+Beside them the rule of README.md, "One reading, one metric", as a CPU can
+hold it, whatever the metrics and cells are: one case a file that no other
+file has its ``read``; no roofline function only calls another's (a copy of
+a count under a second name); one case a metric that lists several cells
+that the list is in the benchmark's order and that what differs in each
+cell is said, in the file's ``what`` or in the cell's own configuration
+(``"reads": {"<metric>": "<words>"}``: where a cell that joins later says
+it); and no name that a merge retired (``retired_*.json``: old name, and
+the name that took its ``read``) stands again, since the ledger's history
+of it has ended.
 """
 
 import ast
 import functools
 import glob
 import importlib
-import json
 import os
 import re
 
@@ -45,6 +53,9 @@ SPECS = {m["name"]: harness.read_json(CHIP, "layer_metrics",
 PAIRS = [(m["name"], cell) for m in BENCH["per_layer"]
          for cell in m.get("workloads", ())]
 COLLECTIVE = "all-reduce"
+CELLS = [w["name"] for w in BENCH["workloads"]]
+SHARED = {m["name"]: m["workloads"] for m in BENCH["per_layer"]
+          if len(m.get("workloads", ())) > 1}
 
 
 @functools.lru_cache(maxsize=None)
@@ -78,6 +89,12 @@ def _gate(kernel: str, sizes: dict, monkeypatch) -> bool:
     if kernel in ("hvd_flash_attention", "hvd_flash_bwd"):
         return pallas_attention.flash_eligible(
             sizes["seq"], sizes["seq"], sizes["head_dim"])
+    if kernel == pallas_attention.ADJ_NAME:
+        # (``flash_backward``: a head of 64 goes heads first and keeps the
+        # row sums ``jax.numpy``)
+        return pallas_attention.flash_eligible(
+            sizes["seq"], sizes["seq"], sizes["head_dim"]) \
+            and sizes["head_dim"] % pallas_attention.MIN_BLOCK == 0
     if kernel == "hvd_fused_xent":
         return pallas_xent.xent_path(rows, sizes["vocab"],
                                      jnp.bfloat16)[0] == "kernel"
@@ -143,33 +160,35 @@ def _only_calls() -> dict:
     return bare
 
 
-def _names_by_read(calls: dict) -> dict:
-    names = {}
-    for name, spec in SPECS.items():
-        read = dict(spec["read"])
-        if "roofline" in read:
-            read["roofline"] = calls.get(read["roofline"], read["roofline"])
-        names.setdefault(json.dumps(read, sort_keys=True), []).append(name)
-    return names
+@pytest.mark.parametrize("name", sorted(SPECS))
+def test_one_read_has_one_name(name):
+    """A second file with this file's ``read`` is a copy of a number: the
+    cell joins this metric's list in ``BENCHMARK.json`` instead."""
+    read = SPECS[name]["read"]
+    assert [n for n, spec in SPECS.items() if spec["read"] == read] == [name]
 
 
-def test_a_cell_reports_a_read_under_one_name():
-    """Listing a cell under a metric whose ``read`` the cell already
-    reports under another name is a copy of a number: refused."""
-    cells = [w["name"] for w in BENCH["workloads"]]
-    twice = []
-    for names in _names_by_read(_only_calls()).values():
-        listed = [c for n in names
-                  for c in SPECS[n].get("workloads", cells)]
-        twice += [(sorted(names), c) for c in set(listed)
-                  if listed.count(c) > 1]
-    assert not twice
-
-
-@pytest.mark.xfail(strict=True, reason="the per-cell copies of a read and "
-                   "the functions that only call another's stand until a "
-                   "benchmark PR merges them (PERF.md section 7)")
-def test_one_read_has_one_name():
+def test_no_roofline_function_only_calls_another():
     assert not _only_calls()
-    assert not [names for names in _names_by_read({}).values()
-                if len(names) > 1]
+
+
+@pytest.mark.parametrize("name", sorted(SHARED))
+def test_a_shared_metric_says_what_each_cell_reads(name):
+    listed = SHARED[name]
+    assert listed == sorted(listed, key=CELLS.index)
+    configs = {c["name"]: c["file"] for c in BENCH["configs"]}
+    unsaid = [w["name"] for w in BENCH["workloads"]
+              if w["name"] in listed
+              and w["name"] not in SPECS[name]["what"]
+              and name not in harness.read_json(
+                  ROOT, configs[w["config"]]).get("reads", {})]
+    assert not unsaid, (
+        f"neither {name}'s what nor the configuration's reads says what "
+        f"{unsaid} read")
+
+
+def test_no_retired_name_stands_again():
+    for path in sorted(glob.glob(os.path.join(CHIP, "tests",
+                                              "retired_*.json"))):
+        retired = harness.read_json(path)
+        assert not set(retired) & set(SPECS), path

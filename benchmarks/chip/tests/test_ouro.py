@@ -148,20 +148,18 @@ def test_the_new_cell_and_metric_files_are_well_formed():
             cells["ouro-2.6b.s4096"]["chips"]) == (
                 "ouro-2.6b", "train.s4096.b1", 1)
     declared = {m["name"]: m for m in bench["per_layer"]}
-    kernels = {"loop.attention_fwd_ms": "hvd_flash_attention",
-               "loop.flash_attention_roofline": "hvd_flash_attention",
-               "loop.head_xent_ms": "hvd_fused_xent",
-               "loop.head_xent_roofline": "hvd_fused_xent"}
-    # appended in this order; every later PR's entries stand after them
-    names = [m["name"] for m in bench["per_layer"]]
-    assert [n for n in names if n in kernels] == list(kernels)
+    # (the names the looped cell's four kernel readings have since PR 63's
+    # merge, each with the other cells that report the same read)
+    kernels = {"flash_attention_fwd_ms": "hvd_flash_attention",
+               "flash_attention_calls_roofline": "hvd_flash_attention",
+               "head_xent_ms": "hvd_fused_xent",
+               "head_xent_roofline": "hvd_fused_xent"}
     for name, kernel in kernels.items():
         spec = _read(CHIP, "layer_metrics", name + ".json")
         entry = declared[name]
-        for key in ("layer", "unit", "better", "source", "moves",
-                    "workloads"):
+        for key in ("layer", "unit", "better", "source", "moves"):
             assert spec[key] == entry[key], (name, key)
-        assert entry["workloads"] == ["ouro-2.6b.s4096"]
+        assert "ouro-2.6b.s4096" in entry["workloads"]
         assert spec["read"]["trace_ops"] == kernel
         if name.endswith("_roofline"):
             import run as harness
@@ -171,7 +169,7 @@ def test_the_new_cell_and_metric_files_are_well_formed():
                         ("step.loop_gate_ms", "hvd.loop.gate")):
         spec = _read(CHIP, "layer_metrics", name + ".json")
         assert spec["read"]["trace_scope"]["phase"] == phase
-        assert spec["workloads"] == declared[name]["workloads"] == [
+        assert declared[name]["workloads"] == [
             "ouro-2.6b.s4096"]       # the driver's since PR 34
 
 
@@ -201,8 +199,8 @@ def test_a_loop_metric_with_nothing_to_read_is_left_out():
     """On a program without the kernels (the CPU, or a parent commit
     without the loop) the readers return nothing and do not raise."""
     import run as harness
-    for name in ("loop.attention_fwd_ms", "loop.flash_attention_roofline",
-                 "loop.head_xent_ms", "loop.head_xent_roofline"):
+    for name in ("flash_attention_fwd_ms", "flash_attention_calls_roofline",
+                 "head_xent_ms", "head_xent_roofline"):
         spec = _read(CHIP, "layer_metrics", name + ".json")
         assert harness.read_layer_metric(spec["read"], {"trace": None}) \
             is None
