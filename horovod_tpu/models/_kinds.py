@@ -168,3 +168,13 @@ def rmsnorm(x, g, eps=1e-6):
     var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
     return (x.astype(jnp.float32) * jax.lax.rsqrt(var + eps)
             ).astype(x.dtype) * g.astype(x.dtype)
+
+
+def layernorm(x, g, b, eps=1e-6):
+    """LayerNorm with weight and bias over the last dimension, its
+    statistics in float32."""
+    xf = x.astype(jnp.float32)
+    xf = xf - jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+    return ((xf * jax.lax.rsqrt(var + eps)).astype(x.dtype)
+            * g.astype(x.dtype) + b.astype(x.dtype))

@@ -39,7 +39,7 @@ _PLAIN_FIELDS = (
     "n_loops", "layer_pattern", "moe_router_input", "expert_share",
     "moe_router_scores", "moe_shared_width", "ssm_heads", "kv_latent",
     "conv_taps", "lead_pattern", "mtp_depth", "embed_scale", "residual_scale",
-    "attention_scale", "logits_scale")
+    "attention_scale", "logits_scale", "index_topk")
 _PLAIN = TransformerConfig()
 
 

@@ -84,8 +84,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from horovod_tpu._compat import axis_size, shard_map
 
 from horovod_tpu.models import latent, mamba, short_conv
-from horovod_tpu.models._kinds import (BlockKind, Leaf, Rope, normal, ones,
-                                       remat, rmsnorm, rope, scaled, zeros)
+from horovod_tpu.models._kinds import (BlockKind, Leaf, Rope, layernorm,
+                                       normal, ones, remat, rmsnorm, rope,
+                                       scaled, zeros)
 from horovod_tpu.models.scan_util import multi_step
 from horovod_tpu.parallel.ring_attention import ring_attention_spmd
 from horovod_tpu.parallel.moe import expert_ffn, moe_layer_spmd, rows_held
@@ -212,6 +213,21 @@ class TransformerConfig:
     # pattern has a ("conv",) block --------------------------------------
     conv_taps: int = 0          # taps of its causal depthwise convolution,
     #                             the last on the current position
+    # -- a learned index over the keys (DeepSeek-V3.2, arXiv:2512.02556
+    # section 2.1; ``ops/sparse_attention.py``), beside grouped-query
+    # attention in every block of two sublayers ------------------------------
+    index_topk: int = 0         # > 0: a query attends to the keys of its
+    #                             ``index_topk`` largest index scores among
+    #                             the causal ones (all of them while it has
+    #                             no more), one set for all its heads, exact;
+    #                             the indexer (``wq_idx``, ``wk_idx``,
+    #                             ``k_idx_norm`` + bias, ``w_idx``) reads the
+    #                             block's normed input behind a
+    #                             ``stop_gradient`` and learns from its own
+    #                             loss alone (``index_loss``, the step's loss
+    #                             plus its sum over the layers). 0: no indexer
+    index_heads: int = 0        # the indexer's query heads
+    index_head_dim: int = 0     # their width, and its one key head's
     expert_share: Tuple[int, int] = (0, 1)  # (index, of): this device holds
     #                             experts [index * E / of, (index + 1) * E /
     #                             of) of every layer, as the leading dimension
@@ -315,6 +331,28 @@ class TransformerConfig:
             if heads % self.kv_heads:
                 raise ValueError(f"n_kv_heads={self.kv_heads} does not "
                                  f"divide the {heads} heads of {kind}")
+        if self.index_topk:
+            if self.index_heads <= 0 or self.index_head_dim <= 0 \
+                    or self.index_head_dim % 2:
+                raise ValueError(
+                    f"index_topk={self.index_topk} with index_heads="
+                    f"{self.index_heads} of index_head_dim="
+                    f"{self.index_head_dim}: the indexer has heads of an "
+                    "even width (rope)")
+            windowed = any(kind[0] is not None for kind in kinds
+                           if not isinstance(kind[0], str))
+            if windowed or self.one_sublayer or self.n_loops > 1:
+                raise NotImplementedError(
+                    f"index_topk={self.index_topk} with layer_pattern="
+                    f"{self.layer_pattern}, n_loops={self.n_loops}: the "
+                    "index selects among ALL causal keys of a block of two "
+                    "sublayers, whose terms join the expert layer's in one "
+                    "stack. A window beside the selection, a latent "
+                    "attention block (whose keys are one shared latent: the "
+                    "published form of the index, which nobody has asked "
+                    "for here), a stack of one-sublayer blocks (an attention "
+                    "block's terms would not stack with an expert block's) "
+                    "and a looped stack are not built")
         if self.mtp_depth not in (0, 1):
             raise NotImplementedError(
                 f"mtp_depth={self.mtp_depth}: one multi-token-prediction "
@@ -500,6 +538,15 @@ def _attention_leaves(cfg: TransformerConfig, kind=("attention",)):
     elif cfg.qk_norm:
         yield Leaf("q_norm", (q,), ones, ("tp",))
         yield Leaf("k_norm", (kv,), ones, ("tp",))
+    if cfg.index_topk:
+        # the indexer: its heads' queries, its ONE key head with a LayerNorm
+        # of its own, a weight a head; whole on every device
+        Hi, Di = cfg.index_heads, cfg.index_head_dim
+        yield Leaf("wq_idx", (M, Hi * Di), normal())
+        yield Leaf("wk_idx", (M, Di), normal())
+        yield Leaf("k_idx_norm", (Di,), ones)
+        yield Leaf("k_idx_norm_bias", (Di,), zeros)
+        yield Leaf("w_idx", (M, Hi), normal())
     if cfg.post_norm:
         yield Leaf("ln1_post", (M,), ones)
 
@@ -508,13 +555,52 @@ def _attention_leaves(cfg: TransformerConfig, kind=("attention",)):
 _PLAIN_LAYER = (None, True)
 
 
+def _index_inputs(p, h, positions, cfg: TransformerConfig):
+    """The indexer's queries ``[B, S, Hi, Di]``, its one key head ``[B, S,
+    Di]`` and its heads' weights ``[B, S, Hi]`` (float32, times ``Hi ** -0.5
+    * Di ** -0.5``) of the block's normed input ``h``, which it reads behind
+    a ``stop_gradient``: the key through a LayerNorm with bias, rope over
+    the whole index head on both."""
+    B, S, _ = h.shape
+    Hi, Di = cfg.index_heads, cfg.index_head_dim
+    with jax.named_scope(scopes.ATTENTION_INDEX):
+        h = lax.stop_gradient(h)
+        qi = (h @ p["wq_idx"].astype(h.dtype)).reshape(B, S, Hi, Di)
+        ki = layernorm(h @ p["wk_idx"].astype(h.dtype), p["k_idx_norm"],
+                       p["k_idx_norm_bias"], cfg.norm_eps)
+        qi = rope(qi, positions, cfg.rope_theta)
+        ki = rope(ki[:, :, None], positions, cfg.rope_theta)[:, :, 0]
+        w = (h @ p["w_idx"].astype(h.dtype)).astype(jnp.float32) * (
+            Hi ** -0.5 * Di ** -0.5)
+        return qi, ki, w
+
+
 def _attention_block(p, x, positions, cfg: TransformerConfig,
                      kind=_PLAIN_LAYER):
+    """The attention sublayer's new residual (:func:`_attention_sublayer`
+    without its terms)."""
+    return _attention_sublayer(p, x, positions, cfg, kind)[0]
+
+
+def _attention_sublayer(p, x, positions, cfg: TransformerConfig,
+                        kind=_PLAIN_LAYER):
     """x: [B', S', M] local. Heads sharded over tp; sequence over sp.
     ``kind``: the layer's (window or None, rope or not or a
     :class:`Rope` table of the kind's own); the block's query heads are its
-    ``wq``'s, and with a ``wg`` its core's output is gated a head."""
+    ``wq``'s, and with a ``wg`` its core's output is gated a head. Returns
+    (the new residual, the sublayer's auxiliary terms: None, or with a
+    learned index (``cfg.index_topk``) its ``index_loss``, the
+    ``selected_keys`` a query attended on average and the ``selection``'s
+    bits)."""
     B, S, M = x.shape
+    terms = None
+    if cfg.index_topk and (_axis_live("sp") or _axis_live("tp")):
+        raise NotImplementedError(
+            "a learned index over the keys (index_topk) on a live sp or tp "
+            "axis: a query's selection is over every causal key, which an "
+            "sp shard does not hold, and one set for all the heads, which a "
+            "tp shard's heads would have to agree on (the heads' mean "
+            "attention, the indexer's target, is a sum over tp)")
     window, roped = kind[:2]
     grouped = cfg.kv_heads != cfg.n_heads
     if _axis_live("sp") and (window is not None or grouped
@@ -546,7 +632,9 @@ def _attention_block(p, x, positions, cfg: TransformerConfig,
         # the core is the call a kernel replaces: its custom_vjp backward
         # is traced under the same scope
         with jax.named_scope(scopes.ATTENTION_CORE):
-            if _axis_live("sp"):
+            if cfg.index_topk:
+                pass    # below: the index's scopes are the core's siblings
+            elif _axis_live("sp"):
                 o = ring_attention_spmd(q, k, v, "sp", causal=True)
             else:
                 # pallas flash kernel on TPU when tiling permits, XLA
@@ -560,6 +648,13 @@ def _attention_block(p, x, positions, cfg: TransformerConfig,
                                            scopes.ATTENTION_CORE_WINDOW)):
                     o = attend(q, k, v, causal=True, window=window,
                                scale=cfg.attention_scale)
+        if cfg.index_topk:
+            from horovod_tpu.ops.sparse_attention import indexed_attention
+            o, index_loss, selected, bits = indexed_attention(
+                q, k, v, *_index_inputs(p, h, positions, cfg),
+                cfg.index_topk, cfg.attention_scale or cfg.head_dim ** -0.5)
+            terms = {"index_loss": index_loss, "selected_keys": selected,
+                     "selection": bits}
         if "wg" in p:
             with jax.named_scope(scopes.ATTENTION_GATE):
                 gate = jax.nn.sigmoid(
@@ -569,7 +664,7 @@ def _attention_block(p, x, positions, cfg: TransformerConfig,
         o = _psum_if(o, "tp")
         if cfg.post_norm:
             o = rmsnorm(o, p["ln1_post"], cfg.norm_eps)
-        return x + scaled(o, cfg.residual_scale)
+        return x + scaled(o, cfg.residual_scale), terms
 
 
 def _routed(cfg: TransformerConfig, routed: Optional[bool]) -> bool:
@@ -714,9 +809,9 @@ def _over_layers(auxs):
     losses averaged, the largest load, the dropped assignments summed
     (the tokens' choices are :func:`router_choices`' to return)."""
     how = {"max_expert_load": jnp.max, "dropped": jnp.sum,
-           "held_rows": jnp.sum}
+           "held_rows": jnp.sum, "index_loss": jnp.sum}
     return {k: how.get(k, jnp.mean)(v) for k, v in auxs.items()
-            if k != "experts"}
+            if k not in ("experts", "selection")}
 
 
 def _ffn_block(p, x, cfg: TransformerConfig, logits=None, routed=None):
@@ -743,8 +838,23 @@ def _attention_then_ffn(p, x, positions, cfg: TransformerConfig, kind):
         # before attention, from the residual as it comes in: nothing of
         # this block stands between the choice and its experts' weights
         logits = _router_logits(p, x)
-    x = _attention_block(p, x, positions, cfg, kind)
-    return _ffn_block(p, x, cfg, logits)
+    x, terms = _attention_sublayer(p, x, positions, cfg, kind)
+    x, aux = _ffn_block(p, x, cfg, logits)
+    return x, {**aux, **(terms or {})}
+
+
+def _kept_across(cfg: TransformerConfig) -> Dict:
+    """``jax.checkpoint``'s arguments for a block of ``cfg``: nothing (every
+    config's checkpoint as it was), or with a learned index the policy that
+    keeps the sparse core's output and its selection across the block's
+    checkpoint (``ops/sparse_attention.py:KEPT``: 134 + 33.5 MB a layer at
+    16 384 tokens), so that the block's second run selects nothing and
+    attends to nothing again: the backward pass has the selection, and each
+    block of rows makes its own scores and softmax again as it must."""
+    if not cfg.index_topk:
+        return {}
+    from horovod_tpu.ops.sparse_attention import KEPT
+    return {"policy": jax.checkpoint_policies.save_only_these_names(*KEPT)}
 
 
 def _needs_experts(cfg: TransformerConfig) -> None:
@@ -913,6 +1023,14 @@ def param_shardings(cfg: TransformerConfig, mesh: Mesh) -> Dict:
             f"layer_pattern of {len(cfg.layer_pattern)} kinds on pp="
             f"{mesh.shape['pp']}: a stage of "
             f"{cfg.n_layers // mesh.shape['pp']} layers is not whole periods")
+    cut = [axis for axis in ("sp", "tp", "pp") if live[axis]]
+    if cfg.index_topk and cut:
+        raise NotImplementedError(
+            f"index_topk={cfg.index_topk} on a live {' / '.join(cut)} axis: "
+            "a query's selection is over every causal key (an sp shard "
+            "holds a part), one set for all the heads (a tp shard holds "
+            "some), and the pipeline's schedule carries one auxiliary "
+            "column, the experts', not the indexers' loss")
     beside = [field for field in ("lead_pattern", "mtp_depth")
               if getattr(cfg, field)]
     split = [axis for axis in ("sp", "pp") if live[axis]]
@@ -1375,7 +1493,9 @@ def _scan_periods(x, layers, positions, cfg: TransformerConfig, needed,
     def block_of(kind):
         def block(layer_p, x):
             return _block(layer_p, x, positions, cfg, kind)
-        return jax.checkpoint(block) if remat(cfg, needed(kind)) else block
+        if not remat(cfg, needed(kind)):
+            return block
+        return jax.checkpoint(block, **_kept_across(cfg))
     blocks = {kind: block_of(kind) for kind in pattern}
     if len(pattern) == 1 and not one_sublayer:
         # the program of every config without a pattern, to the letter
@@ -1418,6 +1538,16 @@ def router_choices(params, tokens, cfg: TransformerConfig):
     return auxs["experts"]
 
 
+def index_selections(params, tokens, cfg: TransformerConfig):
+    """The keys each layer's index selects for each query, as bits ``[L, B,
+    S, S // 8]`` (``jnp.packbits`` along the keys): the model's own blocks,
+    on one device (no mesh), as :func:`router_choices` gives the experts
+    (benchmarks/chip/tools/keye_vl2_precision.py)."""
+    x = _embed_lookup(params["embed"], tokens, cfg)
+    _x, auxs = _lead_then_layers(params, x, jnp.arange(tokens.shape[1]), cfg)
+    return auxs["selection"]
+
+
 # ---------------------------------------------------------------------------
 # Jitted train/eval step factories
 # ---------------------------------------------------------------------------
@@ -1454,6 +1584,15 @@ def _grad_sync(grads, pspec):
         return jax.tree_util.tree_map(one, grads, pspec)
 
 
+def _objective(loss, aux):
+    """What training descends: the loss, the weighted auxiliary losses and,
+    of a stack with a learned index, the indexers' own (``index_loss``, the
+    sum over the layers at weight 1: its gradient reaches the indexers'
+    leaves alone, so the weight only scales their rate)."""
+    total = loss + aux["aux_loss"]
+    return total + aux["index_loss"] if "index_loss" in aux else total
+
+
 def make_grad_fn(cfg: TransformerConfig, mesh: Mesh):
     """SPMD (loss, aux, grads) function over the mesh; grads come back with
     param shardings, ready for any optax optimizer applied under jit."""
@@ -1469,7 +1608,7 @@ def make_grad_fn(cfg: TransformerConfig, mesh: Mesh):
     def grad_fn(params, tokens, targets):
         def loss_fn(p):
             loss, aux = forward_loss_spmd(p, tokens, targets, cfg)
-            return loss + aux["aux_loss"], (loss, aux)
+            return _objective(loss, aux), (loss, aux)
         grads, (loss, aux) = jax.grad(loss_fn, has_aux=True)(params)
         grads = _grad_sync(grads, pspec)
         return loss, aux, grads
@@ -1535,7 +1674,7 @@ def make_forward(cfg: TransformerConfig, mesh: Mesh):
                        out_specs=P(), check_vma=False)
     def fwd(params, tokens, targets):
         loss, aux = forward_loss_spmd(params, tokens, targets, cfg)
-        return loss + aux["aux_loss"]
+        return _objective(loss, aux)
 
     return jax.jit(fwd)
 

@@ -89,6 +89,21 @@ ATTENTION_GATE = "hvd.attention.gate"
 #: projections onto the two latents and the shared rope key, and the
 #: latents' norms. Up: the heads' queries, keys and values from the latents,
 #: rope, the rope key's broadcast over the heads, the concatenations
+#: nested in ATTENTION, in a block with a learned index over its keys
+#: (``TransformerConfig.index_topk``; ``ops/sparse_attention.py``), and its
+#: three parts. Index: the indexer's three projections of the block's normed
+#: input, its key's LayerNorm and rope. Scores: every causal key's index score
+#: for every query, a block of query rows at a time. Select: the exact top-k
+#: a query row (the searches by value and by index, the mask and its bits).
+#: Loss: the KL of the heads' mean attention against the index's softmax over
+#: the selected keys
+ATTENTION_INDEX = "hvd.attention.index"
+ATTENTION_INDEX_SCORES = "hvd.attention.index.scores"
+ATTENTION_INDEX_SELECT = "hvd.attention.index.select"
+ATTENTION_INDEX_LOSS = "hvd.attention.index.loss"
+#: nested in ATTENTION_CORE, in such a block: scores, softmax and weighted sum
+#: under the selection's mask, forward and (autodiff's) backward
+ATTENTION_CORE_SPARSE = "hvd.attention.core.sparse"
 ATTENTION_LATENT = "hvd.attention.latent"
 ATTENTION_LATENT_DOWN = "hvd.attention.latent.down"
 ATTENTION_LATENT_UP = "hvd.attention.latent.up"
@@ -171,9 +186,14 @@ LATENT_PHASES = (ATTENTION_LATENT, ATTENTION_LATENT_DOWN, ATTENTION_LATENT_UP,
 #: phases only a stack with gated short-convolution blocks has (LFM2), each
 #: forward and backward
 SHORT_CONV_PHASES = (SHORT_CONV, SHORT_CONV_PROJ, SHORT_CONV_GATE)
+#: phases only a stack whose attention selects keys by a learned index has,
+#: each forward and backward but the selection, which no gradient passes
+INDEX_PHASES = (ATTENTION_INDEX, ATTENTION_INDEX_SCORES,
+                ATTENTION_INDEX_SELECT, ATTENTION_INDEX_LOSS,
+                ATTENTION_CORE_SPARSE)
 DEVICE_PHASES = (MODEL_PHASES + MOE_PHASES + LOOP_PHASES + MIXED_PHASES
                  + HYBRID_PHASES + LATENT_PHASES + GATED_PHASES
-                 + SHORT_CONV_PHASES + (GRAD_SYNC, OPTIMIZER, RECOMPUTE))
+                 + SHORT_CONV_PHASES + INDEX_PHASES + (GRAD_SYNC, OPTIMIZER, RECOMPUTE))
 
 # -- host spans (profiling.annotate) ------------------------------------------
 #: the input iterator's ``next()``: the host makes the batch

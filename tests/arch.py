@@ -79,6 +79,17 @@ def _norms_off_one(bias: bool):
     return moved
 
 
+def _keye_moved(arch, tree, rng, seed):
+    """Every norm's weight off 1 and the index key's bias off 0 (a bias of
+    0 hides a wrong gradient, and a dropped one)."""
+    tree = _norms_off_one(bias=False)(arch, tree, rng, seed)
+    bias = tree["layers"]["k_idx_norm_bias"]
+    tree["layers"]["k_idx_norm_bias"] = (
+        0.3 * np.random.RandomState(seed + 200).randn(*bias.shape)
+    ).astype(np.float32)
+    return tree
+
+
 def _granite_moved(arch, tree, rng, seed):
     """The table at the configuration's scale (at 0.02 the logits say
     nothing) and the norm weights and the skip off their ones, so that a
@@ -143,6 +154,21 @@ def _glm_want(arch, params, batch, sizes):
         parts = jax.jit(lambda p, b: arch.reference.losses(p, b, sizes))(
             params, batch)
     return {"main_loss": parts[1], "mtp_loss": parts[5]}
+
+
+def _keye_got(arch, params, batch, aux):
+    return {"logits": logits(arch, params, batch["tokens"]),
+            "index_loss": aux["index_loss"]}
+
+
+def _keye_want(arch, params, batch, sizes):
+    """``reference.losses``' (objective, xent, the indexers' summed loss,
+    selections) and ``reference.forward``'s logits."""
+    with jax.default_matmul_precision("highest"):
+        losses, logits = jax.jit(lambda p, b: (
+            arch.reference.losses(p, b, sizes)[:3],
+            arch.reference.forward(p, b["tokens"], sizes)[0]))(params, batch)
+    return {"logits": logits, "index_loss": losses[2]}
 
 
 _OURO_REPORTED = ("step_losses", "exit_share", "gate_entropy")
@@ -525,6 +551,28 @@ ROWS = {
              _plain(layer_pattern=(_CONV, _DENSE), conv_taps=3), "spec"),
             ("the tree", "dense GPT block", None, "flatten")),
         shares=(8, 16, None), choices=(8, 2 * 64, 4)),
+    "keye_vl2": Row(
+        config="keye-vl-2.0-30b-a3b", workload="train.s16384.b1.sparse",
+        leaves=_every_leaf, moved=_keye_moved,
+        init_args=lambda arch: (arch.CONFIG["assumed"]["embedding_std"],),
+        got_more=_keye_got, want_more=_keye_want,
+        tiny={"cfg": {
+            "dtype": jnp.float32, "n_layers": 2, "d_model": 64,
+            "n_heads": 8, "kv_heads": 2, "head_dim": 16, "qk_norm": "head",
+            "index_topk": 16, "index_heads": 2, "index_head_dim": 8,
+            "n_experts": 16, "moe_top_k": 2, "held_experts": 2,
+            "expert_share": (0, 8), "moe_gated": True,
+            "moe_renormalize": True, "moe_balance_weight": 0.0,
+            "tie_embeddings": False, "rope_theta": 1e7, "remat": True},
+            "job": {"seq_len": 64}},            # four times topk
+        reference_imports=("__future__", "math", "jax", "trees"),
+        drawn=(0.25, True),
+        refused=(
+            ("the cell", "index_topk", None, _PAGED),
+            ("the index alone", "index_topk", _plain(
+                index_topk=16, index_heads=2, index_head_dim=8), _PAGED),
+            ("the tree", "dense GPT block", None, "flatten")),
+        shares=(8, 16, None)),
 }
 
 
@@ -599,7 +647,7 @@ class Arch:
         p = shard_params(params, cfg, mesh)
         tok, tgt = shard_batch(batch["tokens"], batch["targets"], mesh)
         loss, aux, grads = jax.jit(t.make_grad_fn(cfg, mesh))(p, tok, tgt)
-        return loss + aux["aux_loss"], aux, grads
+        return t._objective(loss, aux), aux, grads
 
     def plain(self, cfg, params, batch):
         """(loss + weighted auxiliary losses, gradients) with no mesh (a
@@ -607,7 +655,7 @@ class Arch:
         def loss_fn(p):
             loss, aux = t.forward_loss_spmd(p, batch["tokens"],
                                             batch["targets"], cfg)
-            return loss + aux["aux_loss"]
+            return t._objective(loss, aux)
         return jax.jit(jax.value_and_grad(loss_fn))(params)
 
     def want(self, params, batch, sizes=None, leaves=None) -> dict:

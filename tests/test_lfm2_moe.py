@@ -552,7 +552,9 @@ def _digest(text: str) -> str:
 
 
 def test_the_new_configuration_is_the_only_one_without_a_parent():
-    assert sorted(_configs()) == sorted([*PARENT_PROGRAMS, "lfm2-24b-a2b"])
+    """(Of the configurations there were at PR 55: a later one's file holds
+    this file's configuration to its own parent, tests/test_keye_vl2.py.)"""
+    assert set(PARENT_PROGRAMS) | {"lfm2-24b-a2b"} <= set(_configs())
 
 
 @pytest.mark.parametrize("name", sorted(PARENT_PROGRAMS))

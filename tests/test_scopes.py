@@ -28,7 +28,7 @@ PACKAGE = os.path.join(os.path.dirname(os.path.dirname(
 
 def test_the_vocabulary_is_the_only_place_the_strings_are_written():
     names = scopes.DEVICE_PHASES + scopes.HOST_SPANS
-    assert len(set(names)) == len(names) == 37
+    assert len(set(names)) == len(names) == 42
     assert scopes.RECOMPUTE in scopes.DEVICE_PHASES
     # JAX's own word is no phase, and is written here alone all the same
     names += (scopes.RECOMPUTED,)
