@@ -42,7 +42,12 @@ Phases (default, one chip):
            heads first), the blocks a tile on the diagonal or on a band's
            edge runs, what the band leaves of a head's tiles, the group and
            who made adj,
-           with the blocks the latent cell's step checkpoints); then two
+           with the blocks the latent cell's step checkpoints); the sparse
+           core's three kernels (hvd_sparse_fwd, hvd_sparse_mean,
+           hvd_sparse_bwd) at keye-vl-2.0-30b-a3b.s16384's shape whole, a
+           seeded selection of 2048 keys a query, against the jax.numpy
+           sums a block of 128 queries at a time (sparse_path names the
+           form the program takes there); then two
            steps of the flagship transformer at
            head_dim 128 with the four kernels asserted in the compiled
            program. xent_path says how the LM loss ran (the
@@ -76,7 +81,10 @@ The printed seconds and bytes are smoke prints, not benchmark metrics.
 Tolerances (all stated here, none tuned per run):
   flash attention   max|got-ref| / max|ref| <= 2e-2 (bf16 outputs and
                     grads; the reference runs at "highest" precision);
-                    the block attention kernels the same
+                    the block attention kernels and the sparse core's
+                    three (o, lse, the heads' mean attention, dq, dk, dv)
+                    the same: at the cell's shape they read 5e-8 (the
+                    mean), 2e-5 (lse) and 3e-3 to 5e-3 (PERF.md, PR 65)
   fused xent        loss (f32) abs <= 2e-3 on values ~ log(vocab);
                     dlogits (bf16), and dx / dw through the head form,
                     normalized <= 1e-2
@@ -159,6 +167,7 @@ class Sizes:
     ssm: tuple            # Mamba-2 scan check (S, heads, head width, groups,
     #                       state, chunk)
     dense_ssm: tuple      # the same at ONE group in head tiles
+    sparse: tuple         # sparse core check (S, H, k/v heads, D, topk, k tile)
     narrow: tuple         # flash check at a head of 64 with grouped heads
     #                       and a scale of its own: (B, S, H, k/v heads, D,
     #                       window, scale)
@@ -203,6 +212,9 @@ REAL = Sizes(
     # GB)
     dense_ssm=(1024, 64, 64, 1, 128, 256),
     narrow=(1, 4096, 32, 8, 64, None, 1 / 64),
+    # keye-vl-2.0-30b-a3b.s16384's sparse core whole: one sequence, 32 / 4
+    # heads of 128, 2048 of a query's causal keys, k tiles of 1024
+    sparse=(16384, 32, 4, 128, 2048, 1024),
     # lfm2-24b-a2b.s8192's mixer at the cell's batch, whole
     short_conv=(2, 8192, 2048, 3),
     # depth cut to 4 layers: this phase checks kernels in place, not a model
@@ -223,6 +235,7 @@ TINY = Sizes(
     embed=((64, 2560, 48),),
     ssm=(64, 4, 8, 2, 16, 16),
     dense_ssm=(64, 8, 8, 1, 16, 16), narrow=(1, 256, 4, 1, 64, None, 1 / 64),
+    sparse=(512, 4, 2, 128, 48, 256),
     short_conv=(2, 64, 32, 3),
     gpt=dict(vocab_size=1000, d_model=256, n_heads=2, n_layers=2,
              d_ff=256, max_seq=256),
@@ -670,6 +683,101 @@ def _check_banded(smoke: Smoke, sizes: tuple, what: str) -> None:
     for name, g, r in zip(("dq", "dk", "dv"), got, want):
         _kernel_line(smoke, "flash_attention", f"{what} grad {name}",
                      _rel_err(g, r), FLASH_TOL)
+
+
+def _check_sparse(smoke: Smoke) -> None:
+    """The sparse core's three kernels (``ops/pallas_sparse_attention.py``)
+    at ``Sizes.sparse`` under a seeded selection (``topk`` of a query's
+    causal keys, every causal key of the first ``topk`` queries: what the
+    selection leaves the core), against the ``jax.numpy`` form of the same
+    sums at "highest", a block of 128 queries at a time: o, the rows'
+    log-sum-exp, the heads' mean attention, and dq, dk, dv under a seeded
+    cotangent of o. bf16 operands on both sides; the kernels round the
+    weights to bf16 in front of their matmuls, the reference nothing."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+    from horovod_tpu.ops import pallas_sparse_attention as ps
+    from horovod_tpu.ops import sparse_attention as sa
+
+    S, H, Hkv, D, topk, block_k = smoke.sizes.sparse
+    kern = ps.Kernels(block_k, interpret=smoke.rehearsal)
+    scale, n = D ** -0.5, S // ps.ROWS
+    keys = jax.random.split(jax.random.PRNGKey(smoke.seed + 90), 5)
+    q, w = (jax.random.normal(kk, (S, H, D), jnp.bfloat16)
+            for kk in keys[:2])
+    k, v = (jax.random.normal(kk, (S, Hkv, D), jnp.bfloat16)
+            for kk in keys[2:4])
+    t0 = ps.ROWS * jnp.arange(n, dtype=jnp.int32)
+    blocked = (q.reshape(n, ps.ROWS, H, D), w.reshape(n, ps.ROWS, H, D), t0)
+
+    def selection(t0):
+        scores = jax.random.uniform(jax.random.fold_in(keys[4], t0),
+                                    (ps.ROWS, S))
+        t = t0 + jnp.arange(ps.ROWS, dtype=jnp.int32)
+        return ps.pack_selection(sa.select(scores, t, topk), block_k)
+    mask = jax.jit(lambda: lax.map(selection, t0))()
+
+    def kernels(q, k, v, mask):
+        flat = (k.reshape(S, Hkv * D), v.reshape(S, Hkv * D))
+
+        def block(x):
+            (qb, _, t0), mb = x
+            qb = qb.reshape(ps.ROWS, H * D)
+            o, lse = ps.sparse_forward(qb, *flat, mb, t0, scale=scale, head_dim=D,
+                                       kern=kern)
+            return o, lse, ps.heads_mean(
+                qb, flat[0], lse, mb, t0, scale=scale, head_dim=D, kern=kern)
+        o, lse, p = lax.map(block, (blocked, mask))
+        o = o.reshape(S, H, D)
+        lse = lse.transpose(1, 2, 0, 3).reshape(H, 1, S)
+        return (o, lse, p) + ps.sparse_backward(q, k, v, o, lse, mask,
+                                                w, scale, kern)
+
+    def block_reference(qb, k, v, chosen):
+        f32 = jnp.float32
+        s = jnp.einsum("rhgd,khd->hgrk",
+                       qb.astype(f32).reshape(ps.ROWS, Hkv, -1, D),
+                       k.astype(f32)) * scale
+        s = jnp.where(chosen, s, -jnp.inf)
+        lse = jax.nn.logsumexp(s, axis=-1)
+        e = jnp.exp(s - lse[..., None])
+        o = jnp.einsum("hgrk,khd->rhgd", e, v.astype(f32))
+        return (o.reshape(ps.ROWS, H, D), lse.reshape(H, ps.ROWS),
+                jnp.sum(e, axis=(0, 1)) / H)
+
+    def reference(q, k, v, mask):
+        def block(carry, x):
+            (qb, wb, _), mb = x
+            chosen = ps.unpack_selection(mb, block_k)
+            with jax.default_matmul_precision("highest"):
+                out, back = jax.vjp(
+                    lambda qb, k, v: block_reference(qb, k, v, chosen),
+                    qb, k, v)
+                dq, dk, dv = back((wb.astype(jnp.float32),
+                                   jnp.zeros_like(out[1]),
+                                   jnp.zeros_like(out[2])))
+            return (carry[0] + dk, carry[1] + dv), out + (dq,)
+        zero = jnp.zeros((S, Hkv, D), jnp.float32)
+        (dk, dv), (o, lse, p, dq) = lax.scan(block, (zero, zero),
+                                             (blocked, mask))
+        return (o.reshape(S, H, D), lse.transpose(1, 0, 2).reshape(H, 1, S),
+                p, dq.reshape(S, H, D), dk, dv)
+
+    got = _run_compiled(smoke, kernels, (q, k, v, mask),
+                        (ps.FWD_NAME, ps.MEAN_NAME, ps.BWD_NAME))
+    want = jax.jit(reference)(q, k, v, mask)
+    more = dict(shape=(S, H, Hkv, D), topk=topk, block_k=block_k,
+                dtype="bfloat16", sparse_path=sa.sparse_path(S, H, Hkv, D),
+                selected_keys=float(jnp.mean(jnp.sum(
+                    want[2] > 0, axis=-1, dtype=jnp.float32))))
+    if smoke.on_chip:
+        check(more["sparse_path"] == "pallas", str(more))
+    for what, g, r in zip(("fwd o", "fwd lse", "mean p", "grad dq",
+                           "grad dk", "grad dv"), got, want):
+        _kernel_line(smoke, "sparse_attention", what, _rel_err(g, r),
+                     FLASH_TOL, **more)
+        more = {}
 
 
 def _check_block(smoke: Smoke) -> None:
@@ -1392,6 +1500,7 @@ def _check_flagship(smoke: Smoke, hvd) -> None:
 def phase_kernels(smoke: Smoke, hvd) -> None:
     _check_flash(smoke, smoke.sizes.attn)
     _check_flash_cells(smoke)
+    _check_sparse(smoke)
     _check_block(smoke)
     _check_xent(smoke)
     _check_gmm(smoke)

@@ -12,15 +12,30 @@ see, and the indexer is trained to predict where those heads put their weight.
     p[t, s] = stop_gradient(mean_h softmax_{S_t}(..)[s])
     L_I     = mean_t KL(p[t, .] || softmax_{s in S_t} I[t, s])            (eq. 4)
 
-**The form.** Plain XLA, no kernel: a block of ``ROWS`` query rows at a time
-(``lax.map``; the batch's sequences one at a time around it), against the
-keys of the block's *band*: the sequence's rows are cut into ``BANDS`` equal
-bands and a band's blocks read the keys up to the band's end, so that no
-block walks the whole upper triangle (8 bands: 9/16 of the square). A block
-computes its rows' index scores ``[rows, keys]``, selects, and attends under
-the selection's mask: it walks every causal tile and masks, it does not
-gather. No ``[S, S]`` array exists: the widest is one block's scores, ``[H,
-rows, keys]`` float32.
+**The form.** A block of ``ROWS`` query rows at a time (``lax.map``; the
+batch's sequences one at a time around it), against the keys of the block's
+*band*: the sequence's rows are cut into ``BANDS`` equal bands and a band's
+blocks read the keys up to the band's end, so that no block walks the whole
+upper triangle (8 bands: 9/16 of the square). A block computes its rows' index
+scores ``[rows, keys]``, selects, and attends under the selection's mask: it
+walks every causal tile and masks, it does not gather. No ``[S, S]`` float
+array exists. Two forms of the core, chosen from the backend and the shape
+(:func:`sparse_path`; no setting):
+
+* ``"pallas"`` (a TPU, whole blocks of 128 positions, heads of whole lane
+  tiles): the three kernels of ``ops/pallas_sparse_attention.py``. The block
+  packs its selection as the kernels' mask (a bit a (query, key), the name
+  :data:`SELECTION`) and calls ``hvd_sparse_fwd`` (its outputs and the rows'
+  log-sum-exp, :data:`STATS`) and ``hvd_sparse_mean`` (the heads' mean
+  attention ``p``, ``[rows, keys]`` float32, for the loss) there, on q, k, v
+  behind ``stop_gradient``; the scores never leave VMEM. The gradient of the
+  outputs reaches q, k, v through ONE ``custom_vjp`` a sequence
+  (:func:`_attached`), whose backward is one ``hvd_sparse_bwd`` call beside
+  ``hvd_flash_adj``: dk and dv of a k tile are summed in VMEM over the q
+  blocks and the group's heads and written once a range of 2048 positions.
+* ``"xla"`` (the CPU, odd shapes; what the tests hold the kernels to): the
+  block's masked scores ``[H, rows, keys]`` float32 and their softmax are
+  ``jax.numpy``, and the backward is autodiff's, a block at a time.
 
 **The selection is exact.** A row's ``topk``-th largest score is found by
 value, bit by bit: float32 scores map to unsigned integers in the same order,
@@ -30,29 +45,32 @@ has ``topk`` keys at or above it. Keys above the threshold are in; of those
 the index, which runs only where a block has a tie to break). No sort, no
 approximate top-k, no block-level stand-in.
 
-**The backward pass** is autodiff's, a block at a time: each block is a
-``jax.checkpoint`` that keeps its selection (bits, a byte for eight keys:
-the name :data:`SELECTION`) and nothing else, so the transposed map runs a
-block's scores and softmax again, as a flash kernel's backward does, and
-selects nothing twice. A checkpoint AROUND the call (a block of the model)
-that keeps :data:`KEPT` runs neither the selection nor the core a second
-time: 33.5 MB of bits and 134 MB of outputs a layer at 16 384 tokens. The
-keys, values and index keys enter the map in float32, so their gradients
-add up over the blocks in float32. Two
-``stop_gradient``s keep the graphs apart: the target ``p`` and the scores the
-selection reads; the caller stops the gradient into the indexer's input.
+**The backward pass.** Each block is a ``jax.checkpoint`` that keeps its
+selection (and, in the kernels' form, its rows' log-sum-exp) and nothing
+else: the transposed map computes a block's index scores again and, for the
+KL's gradient ``softmax(I) - p``, the target (``hvd_sparse_mean`` once more;
+in the XLA form the block's scores and softmax, which there also carry the
+gradient to q, k, v, the keys, values and index keys in float32 so that their
+gradients add up over the blocks in float32), and selects nothing twice. A
+checkpoint AROUND the call (a block of the model) that keeps :data:`KEPT` runs
+neither the selection nor the core a second time: at 16 384 tokens 33.5 MB of
+mask, 134 MB of outputs and 2 MB of rows a layer, and in the kernels' form
+the backward pass calls ``hvd_sparse_fwd`` never. Two ``stop_gradient``s keep
+the graphs apart: the target ``p`` and the scores the selection reads; the
+caller stops the gradient into the indexer's input.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
+from horovod_tpu.ops import pallas_sparse_attention as ps
 from horovod_tpu.profiling import scopes
 
 #: query rows of a block; its float32 scores are ``[H, ROWS, keys]``
@@ -66,7 +84,10 @@ SELECTION = "hvd_sparse_selection"
 #: them, and with SELECTION what a checkpoint AROUND the call keeps so that
 #: its second run makes neither again
 OUTPUT = "hvd_sparse_output"
-KEPT = (SELECTION, OUTPUT)
+#: the name of the rows' log-sum-exp (the kernels' form): with the selection,
+#: all the backward pass needs of the forward kernel's run
+STATS = "hvd_sparse_lse"
+KEPT = (SELECTION, OUTPUT, STATS)
 
 
 def blocks(seq: int) -> Tuple[int, int]:
@@ -137,10 +158,20 @@ def select(scores, t, topk: int):
     return jnp.where(many[:, None], chosen, causal)
 
 
+def _index_loss(target, scores, chosen):
+    """A block's summed ``KL(target || softmax_chosen(scores))``: ``target``
+    the heads' mean attention ``[rows, keys]`` (0 outside the selection, as
+    the softmax of the masked scores is: exp(-inf) on both sides)."""
+    log_index = jax.nn.log_softmax(
+        jnp.where(chosen, scores, -jnp.inf), axis=-1)
+    log_target = jnp.log(jnp.where(target > 0, target, 1.0))
+    return jnp.sum(target * (log_target - jnp.where(chosen, log_index, 0.0)))
+
+
 def _block(topk: int, scale: float, total: int, x, consts):
-    """One block of rows of one sequence: (its heads' outputs ``[rows, H,
-    D]``, its rows' summed KL, its selected keys counted, its selection as
-    bits ``[rows, total // 8]``)."""
+    """One block of rows of one sequence, the XLA form: (its heads' outputs
+    ``[rows, H, D]``, its rows' summed KL, its selected keys counted, its
+    selection as bits ``[rows, total // 8]``)."""
     q, qi, w, t0 = x
     k, v, ki = consts                               # float32, the band's
     rows, heads, d = q.shape
@@ -168,43 +199,158 @@ def _block(topk: int, scale: float, total: int, x, consts):
                        preferred_element_type=jnp.float32
                        ) * share.transpose(2, 0, 1)[..., None]
     with jax.named_scope(scopes.ATTENTION_INDEX_LOSS):
-        # both are 0 outside the selection: exp(-inf) on both sides
         target = lax.stop_gradient(
             jnp.sum(e * share[..., None], axis=(0, 1)) / heads)
-        log_index = jax.nn.log_softmax(
-            jnp.where(chosen, scores, -jnp.inf), axis=-1)
-        log_target = jnp.log(jnp.where(target > 0, target, 1.0))
-        kl = jnp.sum(target * (log_target
-                               - jnp.where(chosen, log_index, 0.0)))
+        kl = _index_loss(target, scores, chosen)
     return (o.reshape(rows, heads, d).astype(dtype), kl,
             jnp.sum(chosen, dtype=jnp.float32), bits)
 
 
-def _sequence(topk: int, scale: float, args):
-    """One sequence's blocks, band by band."""
-    q, k, v, qi, ki, w = args                       # [S, ..]
-    seq = q.shape[0]
+def _over_bands(block, seq: int, xs, consts):
+    """``block(x, consts(hi))`` over the sequence's blocks of rows, band by
+    band: ``xs`` are ``[S, ..]`` arrays cut into blocks of rows, to which a
+    block's first position is added; ``consts(hi)`` is what the blocks of the
+    band that ends at ``hi`` share. The blocks' results, concatenated."""
     rows, bands = blocks(seq)
     per_band = seq // bands
+    outs = []
+    for band in range(bands):
+        lo, hi = band * per_band, (band + 1) * per_band
+        shared = consts(hi)
+        cut = tuple(a[lo:hi].reshape((per_band // rows, rows) + a.shape[1:])
+                    for a in xs)
+        t0 = lo + rows * jnp.arange(per_band // rows, dtype=jnp.int32)
+        outs.append(lax.map(lambda x, shared=shared: block(x, shared),
+                            cut + (t0,)))
+    return tuple(jnp.concatenate(parts) for parts in zip(*outs))
+
+
+def _sequence(topk: int, scale: float, args):
+    """One sequence's blocks, band by band, the XLA form."""
+    q, k, v, qi, ki, w = args                       # [S, ..]
+    seq = q.shape[0]
     k, v, ki = (a.astype(jnp.float32) for a in (k, v, ki))
     block = jax.checkpoint(
         functools.partial(_block, topk, scale, seq),
         policy=jax.checkpoint_policies.save_only_these_names(SELECTION))
-    outs = []
-    for band in range(bands):
-        lo, hi = band * per_band, (band + 1) * per_band
-        consts = (k[:hi], v[:hi], ki[:hi])
-        xs = tuple(a[lo:hi].reshape((per_band // rows, rows) + a.shape[1:])
-                   for a in (q, qi, w))
-        t0 = lo + rows * jnp.arange(per_band // rows, dtype=jnp.int32)
-        outs.append(lax.map(lambda x, consts=consts: block(x, consts),
-                            xs + (t0,)))
-    o, kl, count, bits = (jnp.concatenate(parts) for parts in zip(*outs))
+    o, kl, count, bits = _over_bands(
+        block, seq, (q, qi, w), lambda hi: (k[:hi], v[:hi], ki[:hi]))
     return (o.reshape((seq,) + q.shape[1:]), jnp.sum(kl), jnp.sum(count),
             bits.reshape(seq, seq // 8))
 
 
-def indexed_attention(q, k, v, qi, ki, w, topk: int, scale: float):
+# -- the kernels' form ---------------------------------------------------------
+
+def _call_tiles(keys: int, seq: int, block_k: int) -> int:
+    """The k tiles that the kernels' calls of a band of ``keys`` keys cover:
+    the sequence's, or the first half of them where the band ends there. Two
+    shapes of each kernel in a program and not one a band: a kernel is traced
+    and lowered for Mosaic once a shape in every run's set-up, and a grid
+    step past a block's diagonal costs ~0.35 us (at 16 384 positions 448 of
+    them a k/v head and layer more than a grid a band would run, of 1088
+    live)."""
+    tiles = seq // block_k
+    half = tiles // 2
+    return half if tiles % 2 == 0 and keys <= half * block_k else tiles
+
+
+def _kernel_block(topk: int, scale: float, seq: int, head_dim: int,
+                  kern: ps.Kernels, x, consts):
+    """One block of ``ps.ROWS`` positions of one sequence, the kernels' form:
+    :func:`_block`'s results with, between the outputs and the KL, the rows'
+    log-sum-exp ``[H, 1, rows]``, and last the selection as the kernels read
+    it, ``[seq / block_k, 128, rows]`` (``ps.pack_selection``). q, k and v
+    come flat (heads side by side) and behind ``stop_gradient``: their
+    gradient is :func:`_attached`'s."""
+    q, qi, w, t0 = x        # q [rows, H * D]
+    k, v, ki = consts       # k, v the sequence's [S, Hkv * D]; ki the band's
+    rows = q.shape[0]
+    keys = ki.shape[0]
+    t = t0 + jnp.arange(rows, dtype=jnp.int32)
+    with jax.named_scope(scopes.ATTENTION_INDEX_SCORES):
+        scores = index_scores(qi, w, ki)
+    with jax.named_scope(scopes.ATTENTION_INDEX_SELECT):
+        picked = select(lax.stop_gradient(scores), t, topk)
+        # kept as the kernels' mask (a byte for eight keys at a tile of
+        # 1024), read back for the loss
+        mask = checkpoint_name(ps.pack_selection(picked, kern.block_k),
+                               SELECTION)
+        chosen = ps.unpack_selection(mask, kern.block_k)[:, :keys]
+        tiles = _call_tiles(keys, seq, kern.block_k)
+        called = jnp.pad(mask, ((0, tiles - mask.shape[0]), (0, 0), (0, 0)))
+        bits = jnp.packbits(picked, axis=1)
+        bits = jnp.pad(bits, ((0, 0), (0, seq // 8 - bits.shape[1])))
+    with jax.named_scope(scopes.ATTENTION_CORE), \
+            jax.named_scope(scopes.ATTENTION_CORE_SPARSE):
+        o, lse = ps.sparse_forward(q, k, v, called, t0, scale=scale,
+                                   head_dim=head_dim, kern=kern)
+        lse = checkpoint_name(lse, STATS)
+    with jax.named_scope(scopes.ATTENTION_INDEX_LOSS):
+        target = ps.heads_mean(q, k, lse, called, t0, scale=scale,
+                               head_dim=head_dim, kern=kern)[:, :keys]
+        kl = _index_loss(target, scores, chosen)
+    mask = jnp.pad(called, ((0, seq // kern.block_k - tiles), (0, 0), (0, 0)))
+    return o, lse, kl, jnp.sum(chosen, dtype=jnp.float32), bits, mask
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _attached(q, k, v, o, lse, mask, scale, kern):
+    """``o``, the sequence's outputs as the blocks' ``hvd_sparse_fwd`` calls
+    made them, with its gradient to q, k and v: one ``hvd_sparse_bwd`` call
+    on the blocks' rows (``lse``) and masks."""
+    return o
+
+
+def _attached_fwd(q, k, v, o, lse, mask, scale, kern):
+    return o, (q, k, v, o, lse, mask)
+
+
+def _attached_bwd(scale, kern, res, do):
+    q, k, v, o, lse, mask = res
+    with jax.named_scope(scopes.ATTENTION_CORE), \
+            jax.named_scope(scopes.ATTENTION_CORE_SPARSE):
+        dq, dk, dv = ps.sparse_backward(q, k, v, o, lse, mask, do, scale,
+                                        kern)
+    return dq, dk, dv, None, None, None
+
+
+_attached.defvjp(_attached_fwd, _attached_bwd)
+
+
+def _kernel_sequence(topk: int, scale: float, kern: ps.Kernels, args):
+    """One sequence's blocks, band by band, the kernels' form; then the
+    outputs' one custom_vjp."""
+    q, k, v, qi, ki, w = args                       # [S, ..]
+    seq, heads, head_dim = q.shape
+    ki = ki.astype(jnp.float32)
+    block = jax.checkpoint(
+        functools.partial(_kernel_block, topk, scale, seq, head_dim, kern),
+        policy=jax.checkpoint_policies.save_only_these_names(SELECTION,
+                                                             STATS))
+    # flat once, here: a reshape inside the map is a copy of k and v a block
+    flat_q, flat_k, flat_v = (lax.stop_gradient(a.reshape(seq, -1))
+                              for a in (q, k, v))
+    o, lse, kl, count, bits, mask = _over_bands(
+        block, seq, (flat_q, qi, w), lambda hi: (flat_k, flat_v, ki[:hi]))
+    o = checkpoint_name(o.reshape(q.shape), OUTPUT)
+    lse = lse.transpose(1, 2, 0, 3).reshape(heads, 1, seq)
+    return (_attached(q, k, v, o, lse, mask, scale, kern), jnp.sum(kl),
+            jnp.sum(count), bits.reshape(seq, seq // 8))
+
+
+def sparse_path(seq: int, heads: int, kv_heads: int, head_dim: int) -> str:
+    """Which form :func:`indexed_attention` takes, from the backend and the
+    shape alone: ``"pallas"`` (``ops/pallas_sparse_attention.py``) on a TPU
+    at whole blocks of 128 positions, heads of whole lane tiles and whole
+    groups, else ``"xla"``."""
+    if (jax.default_backend() == "tpu" and seq % ps.ROWS == 0
+            and head_dim % ps.MIN_BLOCK == 0 and heads % kv_heads == 0):
+        return "pallas"
+    return "xla"
+
+
+def indexed_attention(q, k, v, qi, ki, w, topk: int, scale: float,
+                      kernels: Optional[ps.Kernels] = None):
     """Causal grouped-query attention over the ``topk`` keys a query's index
     scores select, and the indexer's loss (the module docstring's equations).
 
@@ -218,12 +364,23 @@ def indexed_attention(q, k, v, qi, ki, w, topk: int, scale: float):
     selection; nothing else reads it and XLA drops it).
 
     The gradient of ``o`` reaches ``q``, ``k``, ``v`` and that of ``L_I``
-    reaches ``qi``, ``ki``, ``w``; neither reaches the other three."""
+    reaches ``qi``, ``ki``, ``w``; neither reaches the other three.
+    :func:`sparse_path` says which form runs; ``kernels`` overrides it
+    (tests: the kernels in interpret mode, a smaller k tile)."""
     if q.shape[1] % 8:
         raise ValueError(f"a sequence of {q.shape[1]} positions: the "
                          "selection's bits are whole bytes a row")
-    o, kl, count, bits = lax.map(
-        functools.partial(_sequence, topk, scale), (q, k, v, qi, ki, w))
+    if kernels is None and sparse_path(q.shape[1], q.shape[2], k.shape[2],
+                                       q.shape[3]) == "pallas":
+        kernels = ps.Kernels(ps.key_tile(q.shape[1]))
+    sequence = (functools.partial(_sequence, topk, scale) if kernels is None
+                else functools.partial(_kernel_sequence, topk, float(scale),
+                                       kernels))
+    o, kl, count, bits = lax.map(sequence, (q, k, v, qi, ki, w))
     tokens = q.shape[0] * q.shape[1]
-    o, kl = checkpoint_name((o, jnp.sum(kl) / tokens), OUTPUT)
+    kl = jnp.sum(kl) / tokens
+    if kernels is None:
+        o, kl = checkpoint_name((o, kl), OUTPUT)
+    else:       # (o is named where the custom_vjp keeps it)
+        kl = checkpoint_name(kl, OUTPUT)
     return o, kl, jnp.sum(count) / tokens, bits

@@ -57,7 +57,8 @@ CLIENT_FILES = [os.path.join(CHIP, "run.py")] + sorted(
     glob.glob(os.path.join(CHIP, "adapters", "*.py"))
     + glob.glob(os.path.join(CHIP, "readers", "*.py")))
 KERNEL_FILES = [os.path.join(REPO, "horovod_tpu", *p) for p in (
-    ("ops", "pallas_attention.py"), ("ops", "pallas_xent.py"),
+    ("ops", "pallas_attention.py"), ("ops", "pallas_sparse_attention.py"),
+    ("ops", "pallas_xent.py"),
     ("ops", "pallas_ssm.py"), ("parallel", "moe.py"))]
 #: read by name in a run's ``breakdown`` (PERF.md §3) until a metric file
 #: names them

@@ -5,8 +5,31 @@ cell's program gives its ``read`` something to read
 or a case that a ``benchmark`` PR adds there is tier-1's without an edit
 here. A file of its own: a cell's ``tiny`` step is lowered once a process,
 and under ``--dist loadfile`` that is one worker's load and no part of
-another's (CPU only, nothing is compiled)."""
+another's (CPU only, nothing is compiled).
+
+The benchmark's ``_gate`` knows the kernels its own PRs met. The sparse
+core's three (PR 65, a ``perf_opt`` PR: it adds metric files and edits no
+file of the benchmark's) are answered here by the program's own gate,
+``sparse_path``, until a ``benchmark`` PR gives ``_gate`` that branch."""
 
 import chip_door
 
 chip_door.take("test_metric_lists", globals())
+
+_own = chip_door.benchmarks_own("test_metric_lists")
+_benchmarks_gate = _own._gate
+
+
+def _gate(kernel: str, sizes: dict, monkeypatch) -> bool:
+    import jax
+    from horovod_tpu.ops import pallas_sparse_attention as ps
+    from horovod_tpu.ops import sparse_attention
+    if kernel in (ps.FWD_NAME, ps.MEAN_NAME, ps.BWD_NAME):
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        return sizes.get("index_topk", 0) > 0 and sparse_attention.sparse_path(
+            sizes["seq"], sizes["heads"], sizes["kv_heads"],
+            sizes["head_dim"]) == "pallas"
+    return _benchmarks_gate(kernel, sizes, monkeypatch)
+
+
+_own._gate = _gate
