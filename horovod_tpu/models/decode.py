@@ -39,7 +39,8 @@ _PLAIN_FIELDS = (
     "n_loops", "layer_pattern", "moe_router_input", "expert_share",
     "moe_router_scores", "moe_shared_width", "ssm_heads", "kv_latent",
     "conv_taps", "lead_pattern", "mtp_depth", "embed_scale", "residual_scale",
-    "attention_scale", "logits_scale", "index_topk")
+    "attention_scale", "logits_scale", "index_topk", "delta_heads",
+    "latent_rope", "value_width")
 _PLAIN = TransformerConfig()
 
 
@@ -53,9 +54,9 @@ def _plain_gpt_only(cfg: TransformerConfig) -> None:
     if off:
         raise NotImplementedError(
             f"paged decode does not implement {', '.join(off)}: its cache "
-            "holds n_heads k/v heads of every position (a Mamba block's "
-            "recurrent state is no page of keys, nor is a latent or a short "
-            "convolution's last positions), its "
+            "holds n_heads k/v heads of every position (a Mamba or delta-rule "
+            "block's recurrent state is no page of keys, nor is a latent, "
+            "rotated or not, or a short convolution's last positions), its "
             "layers attend to all of them with rope, and the block is the "
             "dense GPT one (pre-norms, a gelu FFN, the tied head, no scalar "
             "multipliers, one pass and one token a step)")

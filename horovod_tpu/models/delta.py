@@ -1,0 +1,379 @@
+"""A gated delta-rule mixer with a decay a channel (Kimi Delta Attention,
+arXiv:2510.26692 section 3; the public implementation is
+``fla/layers/kda.py`` of ``fla-org/flash-linear-attention``) as
+``models/transformer.py``'s ``("delta",)`` blocks, :data:`KIND` in its table
+of block kinds: the leaves, the block, and the recurrence in its chunked
+form. Per head, keys and values ``delta_head_dim`` wide, the state ``S``
+``[key, value]`` zero at the start of a sequence:
+
+    S' = Diag(alpha_t) S_{t-1}              alpha_t = exp(g_t) in (0, 1)^D
+    S_t = S' + beta_t k_t (v_t - S'^T k_t)^T
+    o_t = S_t^T q_t
+
+where a Mamba-2 head (``models/mamba.py``) has one scalar decay and *adds*
+an outer product, this rule first takes away what the decayed state already
+predicts for the key, and decays every key channel at its own rate. Local
+shapes, the whole sequence on this device (no sp, pp or tp), ``H`` heads of
+``D`` channels, ``M`` the model's width:
+  wq, wk, wv      [M, H D] each, then a causal depthwise convolution of
+                  ``delta_taps`` taps (``conv_q``, ``conv_k``, ``conv_v``
+                  ``[tap, H D]``, no bias: ``mamba._causal_conv``) and silu;
+                  q and k L2-normed over a head's channels, q times
+                  ``D^-1/2``
+  decay           ``g = -exp(a_log) softplus((h wf_down) wf_up + dt_bias)``
+                  float32 ``[B, S, H, D]``: ``wf_down`` ``[M, D]``, ``wf_up``
+                  ``[D, H D]``, ``a_log`` ``[H]``, ``dt_bias`` ``[H D]``
+  beta            ``sigmoid(h w_beta)``, ``w_beta`` ``[M, H]``: a scalar a head
+  output          ``rmsnorm(o; norm [D]) * sigmoid((h wg_down) wg_up)`` a
+                  head, then ``wo`` ``[H D, M]``
+The scan is :func:`delta_chunked`, ``jax.numpy`` over chunks differentiated
+by JAX but for the triangular inverse, whose backward is by hand.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from horovod_tpu.models._kinds import (BlockKind, Leaf, normal, ones,
+                                       rmsnorm, scaled)
+from horovod_tpu.models.mamba import (_causal_conv, draw_a_log,
+                                      draw_dt_bias, draw_taps)
+from horovod_tpu.profiling import scopes
+
+#: rows of a chunk's sub-block: pairs inside one are decayed pairwise, pairs
+#: of two sub-blocks relative to the later one's first row (the public
+#: kernel's secondary chunking), so that no exponent is ever positive
+SUB = 16
+#: added to a head's sum of squares before the L2 norm's ``rsqrt``
+L2_EPS = 1e-6
+_HIGHEST = lax.Precision.HIGHEST
+
+
+def _leaves(cfg):
+    """A delta block's leaves, in the order they are drawn (the decay's
+    bias and rate first, as a Mamba block's time step is)."""
+    M, H, D, K = cfg.d_model, cfg.delta_heads, cfg.delta_head_dim, \
+        cfg.delta_taps
+    dt_bias, a_log, taps = draw_dt_bias, draw_a_log, draw_taps(K)
+    yield Leaf("dt_bias", (H * D,), dt_bias)
+    yield Leaf("a_log", (H,), a_log)
+    yield Leaf("ln1", (M,), ones)
+    for name in ("wq", "wk", "wv"):
+        yield Leaf(name, (M, H * D), normal())
+    for name in ("conv_q", "conv_k", "conv_v"):
+        yield Leaf(name, (K, H * D), taps)
+    yield Leaf("wf_down", (M, D), normal())
+    yield Leaf("wf_up", (D, H * D), normal())
+    yield Leaf("w_beta", (M, H), normal())
+    yield Leaf("wg_down", (M, D), normal())
+    yield Leaf("wg_up", (D, H * D), normal())
+    yield Leaf("norm", (D,), ones)
+    yield Leaf("wo", (H * D, M), normal())
+
+
+# ---------------------------------------------------------------------------
+# the inverse of a unit lower triangular matrix
+# ---------------------------------------------------------------------------
+
+def _blocks_on_diagonal(blocks):
+    """``[.., m, s, s]`` as the block diagonal of ``[.., m s, m s]``."""
+    m, s = blocks.shape[-3:-1]
+    eye = jnp.eye(m, dtype=blocks.dtype)[:, None, :, None]
+    full = eye * blocks[..., :, :, None, :]
+    return full.reshape(blocks.shape[:-3] + (m * s, m * s))
+
+
+def _inverse(a, sub: int):
+    """``(I + a)^-1`` of a strictly lower triangular ``a`` ``[.., C, C]``,
+    float32: forward substitution a row at a time inside the ``C / sub``
+    diagonal blocks (all at once), then the blocks merged: with ``d`` the
+    block diagonal's inverse and ``n`` what lies under it, ``e = d n`` is
+    nilpotent of order ``C / sub`` and ``(I + e)^-1 = (I - e)(I + e^2)(I +
+    e^4)..``, a few powers (no power of ``a`` itself, whose entries grow
+    like binomials). Matmuls at ``HIGHEST``."""
+    C = a.shape[-1]
+    m = C // sub
+    lead = a.shape[:-2]
+    blocks = a.reshape(lead + (m, sub, m, sub))
+    eye_m = jnp.eye(m, dtype=a.dtype)
+    diag = jnp.einsum("...isjt,ij->...ist", blocks, eye_m)
+    eye_s = jnp.eye(sub, dtype=a.dtype)
+    x = jnp.broadcast_to(eye_s, diag.shape)
+    for r in range(1, sub):
+        # rows >= r of x are still the identity's and diag[r, t >= r] = 0
+        row = eye_s[r] - jnp.sum(diag[..., r, :, None] * x, axis=-2)
+        x = x.at[..., r, :].set(row)
+    d = _blocks_on_diagonal(x)
+    if m == 1:
+        return d
+    under = (blocks * (1 - eye_m)[:, None, :, None]).reshape(a.shape)
+    eye = jnp.eye(C, dtype=a.dtype)
+    e = jnp.matmul(d, under, precision=_HIGHEST)
+    inv, power, e_p = eye - e, 2, e
+    while power < m:
+        e_p = jnp.matmul(e_p, e_p, precision=_HIGHEST)
+        inv = jnp.matmul(inv, eye + e_p, precision=_HIGHEST)
+        power *= 2
+    return jnp.matmul(inv, d, precision=_HIGHEST)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def unit_lower_inverse(a, sub: int):
+    """:func:`_inverse` with the backward pass of an inverse: for ``t = (I
+    + a)^-1``, ``da = -(t^T dt t^T)`` under the diagonal. Only ``t`` is
+    kept."""
+    return _inverse(a, sub)
+
+
+def _inverse_fwd(a, sub):
+    t = _inverse(a, sub)
+    return t, t
+
+
+def _inverse_bwd(sub, t, dt):
+    tt = jnp.swapaxes(t, -1, -2)
+    da = -jnp.matmul(jnp.matmul(tt, dt, precision=_HIGHEST), tt,
+                     precision=_HIGHEST)
+    return (jnp.tril(da, -1),)
+
+
+unit_lower_inverse.defvjp(_inverse_fwd, _inverse_bwd)
+
+
+# ---------------------------------------------------------------------------
+# the chunked scan
+# ---------------------------------------------------------------------------
+
+def _decay(log_decay):
+    """``exp`` of a difference of the sums ``Gamma`` (never positive where
+    it is read), in float32 as it comes: the one place the scan's decay
+    factors are made (a test swaps it for the nearest precision below)."""
+    return jnp.exp(log_decay)
+
+
+def _carry(x):
+    """The carried state as the scan keeps it from chunk to chunk: float32
+    (a test swaps it for the nearest precision below)."""
+    return x
+
+
+@jax.checkpoint
+def _pairs(qb, kb, gamma_b, gamma, k):
+    """The decayed products of a chunk's rows, ``[.., C, C]`` float32, lower
+    triangular with the diagonal: ``sum_c x_i[c] k_j[c] exp(Gamma_i[c] -
+    Gamma_j[c])`` for ``x`` = q and for ``x`` = k, every exponent <= 0.
+
+    Rows ``i`` of sub-block ``I`` against rows ``j`` of an earlier
+    sub-block: both sides relative to ``I``'s first row ``r``, ``(x_i
+    exp(Gamma_i - Gamma_r)) . (k_j exp(Gamma_r - Gamma_j))``, matmuls of
+    operands in the compute dtype. Rows of one sub-block: the difference
+    itself, ``[sub, sub, D]`` a sub-block, products and sum in float32.
+    Checkpointed: the backward pass makes the decay factors again from q, k
+    and Gamma (kept, they are two float32 ``[chunks, H, C, sub, D]`` arrays
+    a block, 4.2 GB at 8192 positions, 32 heads of 128 and sub-blocks of
+    16). qb, kb, gamma_b ``[.., m, sub, D]``, the sub-blocks of gamma, k
+    ``[.., C, D]`` (cut by the caller: with the reshapes inside the
+    checkpoint the cell's step compiles 0.68 GB larger, 14.90 for 14.22 GB,
+    PERF.md section 6, PR 66)."""
+    m, sub, D = kb.shape[-3:]
+    C, dtype = m * sub, k.dtype
+    first = gamma_b[..., :, 0, :]                           # [.., m, D]
+    left = _decay(gamma_b - first[..., None, :])
+    earlier = (jnp.arange(C)[None, :] < sub * jnp.arange(m)[:, None])
+    right = _decay(jnp.where(earlier[..., None],
+                             first[..., :, None, :] - gamma[..., None, :, :],
+                             -jnp.inf))                     # [.., m, C, D]
+    k_right = (k.astype(jnp.float32)[..., None, :, :] * right).astype(dtype)
+
+    def across(xb):
+        x_left = (xb.astype(jnp.float32) * left).astype(dtype)
+        return jnp.einsum("...msd,...mcd->...msc", x_left, k_right,
+                          preferred_element_type=jnp.float32)
+
+    lower = jnp.tril(jnp.ones((sub, sub), bool))
+    within = _decay(jnp.where(
+        lower[..., None],
+        gamma_b[..., :, None, :] - gamma_b[..., None, :, :], -jnp.inf))
+    k_within = kb.astype(jnp.float32)[..., None, :, :] * within
+
+    def inside(xb):
+        return jnp.sum(xb.astype(jnp.float32)[..., :, None, :] * k_within,
+                       axis=-1)                             # [.., m, s, s]
+
+    def whole(xb):
+        pairs = across(xb).reshape(xb.shape[:-3] + (C, C))
+        return pairs + _blocks_on_diagonal(inside(xb))
+    return whole(qb), whole(kb)
+
+
+def delta_chunked(q, k, v, g, beta, chunk: int, sub: int = SUB):
+    """The gated delta rule of the module's docstring in its chunked form.
+    With ``Gamma_i = sum_{j <= i} g_j`` inside a chunk of ``C`` positions
+    (a channel, float32) and ``S`` the state the chunk starts from:
+
+        A[i, j] = beta_i sum_c k_i[c] k_j[c] exp(Gamma_i[c] - Gamma_j[c])   j < i
+        T = (I + A)^-1 Diag(beta)       W = T (K * exp Gamma)      U = T V
+        R = U - W S
+        O = (Q * exp Gamma) S + tril(P) R       P as A with q_i for beta_i k_i, j <= i
+        S_next = Diag(exp Gamma_C) S + (K * exp(Gamma_C - Gamma))^T R
+
+    ``A``, ``P``, ``T``, ``W`` and ``U`` of every chunk at once; one
+    ``lax.scan`` over the chunks carries ``S`` (two matmuls a step) and
+    gives every chunk's ``S`` and ``R``; ``O`` of every chunk at once. No
+    factor ``exp(-Gamma_j)`` is ever formed alone (:func:`_pairs`). ``g``,
+    ``Gamma``, every decay factor, the inverse and the carried state are
+    float32; the matmuls take operands of ``q.dtype`` and accumulate in
+    float32.
+
+    q, k ``[B, S, H, D]``, v ``[B, S, H, Dv]``; g ``[B, S, H, D]`` float32,
+    never positive; beta ``[B, S, H]`` float32. Returns (o ``[B, S, H, Dv]``
+    float32, the most negative ``Gamma_C`` of any chunk, head and
+    channel)."""
+    B, S, H, D = q.shape
+    if S % chunk:
+        raise ValueError(f"delta_chunk={chunk} does not divide the sequence "
+                         f"of {S} positions")
+    sub = min(sub, chunk)
+    if chunk % sub:
+        raise ValueError(f"delta_chunk={chunk} is not whole sub-blocks of "
+                         f"{sub} rows")
+    n, m, dtype = S // chunk, chunk // sub, q.dtype
+
+    def chunks(x):      # [B, S, H, ..] -> [n, B, H, C, ..]
+        x = x.reshape((B, n, chunk) + x.shape[2:])
+        return jnp.swapaxes(jnp.moveaxis(x, 1, 0), 2, 3)
+
+    def blocks(x):      # [.., C, D] -> [.., m, sub, D]
+        return x.reshape(x.shape[:-2] + (m, sub, x.shape[-1]))
+    q, k, v, g = chunks(q), chunks(k), chunks(v), chunks(g)
+    beta = chunks(beta)                                     # [n, B, H, C]
+    gamma = jnp.cumsum(g, axis=3)
+    p, a = _pairs(blocks(q), blocks(k), blocks(gamma), gamma, k)
+    t = unit_lower_inverse(jnp.tril(a, -1) * beta[..., None], sub)
+    t = (t * beta[..., None, :]).astype(dtype)
+    since_start = _decay(gamma)                             # exp(Gamma_i)
+    k_start = (k.astype(jnp.float32) * since_start).astype(dtype)
+    w = jnp.einsum("nbhij,nbhjd->nbhid", t, k_start,
+                   preferred_element_type=jnp.float32).astype(dtype)
+    u = jnp.einsum("nbhij,nbhjd->nbhid", t, v,
+                   preferred_element_type=jnp.float32)
+    k_end = (k.astype(jnp.float32) * _decay(gamma[..., -1:, :] - gamma)
+             ).astype(dtype)
+    whole = since_start[..., -1, :]                         # exp(Gamma_C)
+
+    def carry(state, chunk_):
+        w_c, u_c, k_end_c, whole_c = chunk_
+        r = u_c - jnp.einsum("bhcd,bhdv->bhcv", w_c, state.astype(dtype),
+                             preferred_element_type=jnp.float32)
+        after = whole_c[..., None] * state + jnp.einsum(
+            "bhcd,bhcv->bhdv", k_end_c, r.astype(dtype),
+            preferred_element_type=jnp.float32)
+        return _carry(after), (state, r)
+    zero = _carry(jnp.zeros((B, H, D, v.shape[-1]), jnp.float32))
+    _, (before, r) = lax.scan(carry, zero, (w, u, k_end, whole))
+    q_start = (q.astype(jnp.float32) * since_start).astype(dtype)
+    o = (jnp.einsum("nbhcd,nbhdv->nbhcv", q_start, before.astype(dtype),
+                    preferred_element_type=jnp.float32)
+         + jnp.einsum("nbhij,nbhjv->nbhiv", p.astype(dtype), r.astype(dtype),
+                      preferred_element_type=jnp.float32))
+    o = jnp.moveaxis(jnp.swapaxes(o, 2, 3), 0, 1)           # [B, n, C, H, Dv]
+    return (o.reshape(B, S, H, -1),
+            lax.stop_gradient(jnp.min(gamma[..., -1, :])))
+
+
+# ---------------------------------------------------------------------------
+# the block
+# ---------------------------------------------------------------------------
+
+def _short_conv(x, taps):
+    """``silu`` of the causal depthwise convolution of x ``[B, S, C]`` with
+    taps ``[K, C]`` (no bias), float32."""
+    return jax.nn.silu(_causal_conv(x, taps, None))
+
+
+def _l2norm(x):
+    """``x / |x|`` over the last dimension, float32."""
+    x = x.astype(jnp.float32)
+    return x * lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True)
+                         + L2_EPS)
+
+
+def _delta_block(p, x, cfg):
+    """``x + delta(norm(x))``, x ``[B', S', M]`` with the whole sequence
+    here (no sp); and the step's most negative ``Gamma_C``."""
+    B, S, _ = x.shape
+    H, D = cfg.delta_heads, cfg.delta_head_dim
+    with jax.named_scope(scopes.DELTA):
+        h = rmsnorm(x, p["ln1"], cfg.norm_eps)
+        with jax.named_scope(scopes.DELTA_PROJ):
+            q, k, v = (h @ p[name].astype(h.dtype)
+                       for name in ("wq", "wk", "wv"))
+        with jax.named_scope(scopes.DELTA_CONV):
+            q, k, v = (_short_conv(y, p[name]).reshape(B, S, H, D)
+                       for y, name in ((q, "conv_q"), (k, "conv_k"),
+                                       (v, "conv_v")))
+            q = (_l2norm(q) * D ** -0.5).astype(h.dtype)
+            k = _l2norm(k).astype(h.dtype)
+            v = v.astype(h.dtype)
+        with jax.named_scope(scopes.DELTA_GATES):
+            rate = jnp.matmul(
+                h @ p["wf_down"].astype(h.dtype),
+                p["wf_up"].astype(h.dtype),
+                preferred_element_type=jnp.float32)
+            g = -(jnp.exp(p["a_log"].astype(jnp.float32))[:, None]
+                  * jax.nn.softplus(
+                      (rate + p["dt_bias"].astype(jnp.float32)
+                       ).reshape(B, S, H, D)))
+            beta = jax.nn.sigmoid(jnp.matmul(
+                h, p["w_beta"].astype(h.dtype),
+                preferred_element_type=jnp.float32))
+            gate = (h @ p["wg_down"].astype(h.dtype)
+                    ) @ p["wg_up"].astype(h.dtype)
+        with jax.named_scope(scopes.DELTA_SCAN):
+            o, min_log_decay = delta_chunked(q, k, v, g, beta,
+                                             cfg.delta_chunk)
+        with jax.named_scope(scopes.DELTA_NORM):
+            var = jnp.mean(jnp.square(o), axis=-1, keepdims=True)
+            y = (o * lax.rsqrt(var + cfg.norm_eps)
+                 * p["norm"].astype(jnp.float32)
+                 * jax.nn.sigmoid(gate.astype(jnp.float32)
+                                  ).reshape(B, S, H, D))
+            y = y.reshape(B, S, H * D).astype(h.dtype)
+        with jax.named_scope(scopes.DELTA_PROJ):
+            out = y @ p["wo"].astype(h.dtype)
+        return (x + scaled(out, cfg.residual_scale),
+                {"delta_min_log_decay": min_log_decay})
+
+
+def _validate(cfg) -> None:
+    if cfg.delta_heads < 1 or cfg.delta_taps < 1 or cfg.delta_chunk < 1:
+        raise ValueError(
+            f"layer_pattern has (\"delta\",) blocks and delta_heads="
+            f"{cfg.delta_heads}, delta_taps={cfg.delta_taps}, delta_chunk="
+            f"{cfg.delta_chunk}: the mixer has at least one head, one tap "
+            "and one position a chunk")
+    if cfg.n_loops > 1:
+        raise NotImplementedError(
+            f"a (\"delta\",) block with n_loops={cfg.n_loops}: a looped "
+            "stack stacks one kind of auxiliary terms a pass, the experts', "
+            "not a mixer's own, and nobody has said whether the state "
+            "starts from zero at every loop step")
+
+
+#: the row of ``transformer._BLOCK_KINDS``. The single pass checkpoints the
+#: block: every chunk's float32 pairs, inverse, states and decays would
+#: otherwise be kept for the backward pass
+KIND = BlockKind(
+    length=1, leaves=_leaves, validate=_validate,
+    apply=lambda p, x, positions, cfg, kind: _delta_block(p, x, cfg),
+    checkpointed=True, refuses=("sp", "pp", "tp"),
+    refusal="the convolutions and the scan's carried state run over the "
+            "whole sequence on one device (no hand-over of the last taps "
+            "and of the state between sp shards), its heads are not split "
+            "over tp, and no pipeline schedule has run it (its stages "
+            "carry one auxiliary column, the experts', not a mixer's own "
+            "terms)")

@@ -39,24 +39,32 @@ from horovod_tpu.profiling import scopes
 SSM_DT_RANGE, SSM_DT_FLOOR, SSM_A_RANGE = (1e-3, 1e-1), 1e-4, (1.0, 16.0)
 
 
+def draw_dt_bias(rng, shape):
+    """A time step's bias: ``softplus(dt_bias) = dt``, ``dt`` log-uniform
+    over ``SSM_DT_RANGE`` and no less than ``SSM_DT_FLOOR``."""
+    dt = np.exp(rng.uniform(*np.log(SSM_DT_RANGE), size=shape))
+    return np.log(np.expm1(np.maximum(dt, SSM_DT_FLOOR))).astype(np.float32)
+
+
+def draw_a_log(rng, shape):
+    """``log(-A)``, ``-A`` uniform over ``SSM_A_RANGE``."""
+    return np.log(rng.uniform(*SSM_A_RANGE, size=shape)).astype(np.float32)
+
+
+def draw_taps(K: int):
+    """A causal depthwise convolution's ``K`` taps, uniform in ``(-1, 1) /
+    sqrt(K)``."""
+    def taps(rng, shape):
+        return (rng.uniform(-1, 1, shape) / np.sqrt(K)).astype(np.float32)
+    return taps
+
+
 def _leaves(cfg):
     """A Mamba block's leaves, in the order they are drawn (a head's time
     step before the matrices)."""
     M, H, inner, K = cfg.d_model, cfg.ssm_heads, cfg.ssm_inner, cfg.ssm_conv
     wide = cfg.ssm_conv_width
-
-    def dt_bias(rng, shape):
-        # softplus(dt_bias) = dt
-        dt = np.exp(rng.uniform(*np.log(SSM_DT_RANGE), size=shape))
-        return np.log(np.expm1(np.maximum(dt, SSM_DT_FLOOR))
-                      ).astype(np.float32)
-
-    def taps(rng, shape):
-        return (rng.uniform(-1, 1, shape) / np.sqrt(K)).astype(np.float32)
-
-    def a_log(rng, shape):
-        return np.log(rng.uniform(*SSM_A_RANGE, size=shape)
-                      ).astype(np.float32)
+    dt_bias, a_log, taps = draw_dt_bias, draw_a_log, draw_taps(K)
     yield Leaf("ssm_dt_bias", (H,), dt_bias)
     yield Leaf("ln1", (M,), ones)
     yield Leaf("ssm_in", (M, inner + wide + H), normal())

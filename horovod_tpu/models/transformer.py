@@ -10,8 +10,9 @@ a single XLA program whose collectives (psum over tp, ppermute rings over
 sp and pp, all_to_all over ep, psum over dp for gradients) all ride ICI.
 
 This file is the training model; the Mamba-2 mixer is ``models/mamba.py``,
-the gated short-convolution mixer ``models/short_conv.py``, latent attention
-``models/latent.py``, the
+the gated short-convolution mixer ``models/short_conv.py``, the gated
+delta-rule mixer ``models/delta.py``, latent attention ``models/latent.py``,
+the
 serving plane's paged decode model and its oracle ``models/decode.py``. A leaf
 is declared once, in its block's ``*_leaves`` function (``models/_kinds.py``:
 name, shape, draw, partition spec, under the one ``if`` that says when the
@@ -44,7 +45,8 @@ Layout conventions (local = per-device shapes):
                   that word / pp, ...]`` (a Mamba block: ``models/mamba.py``;
                   ("latent",) latent attention: ``models/latent.py``;
                   ("dense",) the dense FFN at ``dense_ff``; ("conv",) a
-                  gated short convolution: ``models/short_conv.py``)
+                  gated short convolution: ``models/short_conv.py``;
+                  ("delta",) a gated delta rule: ``models/delta.py``)
   attention kinds ("attention", window, rope, heads, gated): ``rope`` may be
                   a table of the kind's own (``_kinds.Rope``: a theta, the
                   rotated part of the head, YaRN); ``heads`` query heads
@@ -83,7 +85,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from horovod_tpu._compat import axis_size, shard_map
 
-from horovod_tpu.models import latent, mamba, short_conv
+from horovod_tpu.models import delta, latent, mamba, short_conv
 from horovod_tpu.models._kinds import (BlockKind, Leaf, Rope, layernorm,
                                        normal, ones, remat, rmsnorm, rope,
                                        scaled, zeros)
@@ -174,11 +176,16 @@ class TransformerConfig:
     #                             are gated; ``moe_activation``), whole on
     #                             every device that holds a share of the others
     # -- latent attention (``models/latent.py``), where the pattern has it --
-    q_latent: int = 0           # channels of the queries' latent
+    q_latent: int = 0           # channels of the queries' latent; 0: none,
+    #                             the queries come from the block's input
     kv_latent: int = 0          # channels of the keys' and values' latent
     rope_width: int = 0         # a head's last channels, which carry the
     #                             positions; the key's are one head shared by
     #                             all (``head_width`` is both parts together)
+    latent_rope: bool = True    # False: those channels are not rotated (a
+    #                             latent block without positions, NoPE)
+    value_width: Optional[int] = None   # a latent block's value channels a
+    #                             head where they are not ``head_width``
     # -- blocks beside the periodic stack -----------------------------------
     lead_pattern: Tuple[Tuple, ...] = ()    # one-sublayer kinds of the blocks
     #                             before the periodic stack, each once with
@@ -213,6 +220,15 @@ class TransformerConfig:
     # pattern has a ("conv",) block --------------------------------------
     conv_taps: int = 0          # taps of its causal depthwise convolution,
     #                             the last on the current position
+    # -- a gated delta rule (``models/delta.py``), where the pattern has a
+    # ("delta",) block ----------------------------------------------------
+    delta_heads: int = 0        # heads of ``delta_head_dim`` key and value
+    #                             channels; 0: none
+    delta_head_dim: int = 128
+    delta_taps: int = 4         # taps of the causal depthwise convolutions
+    #                             on q, k and v
+    delta_chunk: int = 64       # positions a chunk of the scan; the sequence
+    #                             is whole chunks
     # -- a learned index over the keys (DeepSeek-V3.2, arXiv:2512.02556
     # section 2.1; ``ops/sparse_attention.py``), beside grouped-query
     # attention in every block of two sublayers ------------------------------
@@ -809,7 +825,8 @@ def _over_layers(auxs):
     losses averaged, the largest load, the dropped assignments summed
     (the tokens' choices are :func:`router_choices`' to return)."""
     how = {"max_expert_load": jnp.max, "dropped": jnp.sum,
-           "held_rows": jnp.sum, "index_loss": jnp.sum}
+           "held_rows": jnp.sum, "index_loss": jnp.sum,
+           "delta_min_log_decay": jnp.min}
     return {k: how.get(k, jnp.mean)(v) for k, v in auxs.items()
             if k not in ("experts", "selection")}
 
@@ -870,6 +887,7 @@ _BLOCK_KINDS = {
     "mamba": mamba.KIND,
     "latent": latent.KIND,
     "conv": short_conv.KIND,
+    "delta": delta.KIND,
     "experts": BlockKind(
         length=1, leaves=_ffn_leaves, validate=_needs_experts,
         apply=lambda p, x, positions, cfg, kind: _ffn_block(p, x, cfg)),
@@ -1453,11 +1471,19 @@ def _has_experts(cfg: TransformerConfig, pattern) -> bool:
 
 def _join_aux(cfg: TransformerConfig, parts):
     """Several runs' stacked auxiliary terms, ``[(pattern, terms)]``, as one
-    stack: those of the runs whose pattern has expert layers, where any
-    has (a run without them stacks a zero a period)."""
-    keep = ([aux for pattern, aux in parts if _has_experts(cfg, pattern)]
-            or [aux for _pattern, aux in parts])
-    return {k: jnp.concatenate([aux[k] for aux in keep]) for k in keep[0]}
+    stack a term: of the runs whose pattern has expert layers, where any of
+    them has the term (a run without experts stacks a zero a period for the
+    auxiliary loss); else, a mixer's own term, of every run that has it."""
+    every = [aux for _pattern, aux in parts]
+    routed = [aux for pattern, aux in parts if _has_experts(cfg, pattern)]
+    terms = dict.fromkeys(k for aux in (routed or every)[:1] + every
+                          for k in aux)
+
+    def stacked(k):
+        among = ([aux for aux in routed if k in aux]
+                 or [aux for aux in every if k in aux])
+        return jnp.concatenate([aux[k] for aux in among])
+    return {k: stacked(k) for k in terms}
 
 
 def _lead_then_layers(params, x, positions, cfg: TransformerConfig):
@@ -1521,8 +1547,16 @@ def _scan_periods(x, layers, positions, cfg: TransformerConfig, needed,
                                              period_p[key])
             carry, aux = blocks[kind](layer_p, carry)
             auxs.append(aux)
-        auxs = [aux for aux in auxs if aux is not None] or [_no_aux()]
-        return carry, jax.tree_util.tree_map(lambda *a: jnp.stack(a), *auxs)
+        auxs = [aux for aux in auxs if aux is not None]
+        if not any("aux_loss" in aux for aux in auxs):
+            auxs.append(_no_aux())
+        # one stack a set of terms: the expert blocks', a mixer's own
+        by_terms = {}
+        for aux in auxs:
+            by_terms.setdefault(tuple(aux), []).append(aux)
+        return carry, {k: v for same in by_terms.values()
+                       for k, v in jax.tree_util.tree_map(
+                           lambda *a: jnp.stack(a), *same).items()}
     y, auxs = lax.scan(period_body, x, periods)
     return y, jax.tree_util.tree_map(
         lambda a: a.reshape((-1,) + a.shape[2:]), auxs)

@@ -138,6 +138,19 @@ SSM_NORM = "hvd.ssm.norm"
 SHORT_CONV = "hvd.short_conv"
 SHORT_CONV_PROJ = "hvd.short_conv.proj"
 SHORT_CONV_GATE = "hvd.short_conv.gate"
+#: a gated delta-rule block (``models/delta.py``) with its norm and residual,
+#: and its five parts. Proj: the three projections of the normed input and
+#: the out-projection. Conv: the three causal depthwise convolutions, their
+#: silu, the L2 norms of q and k. Gates: the decay's two matmuls, softplus
+#: and rate, beta, the output gate's two matmuls. Scan: the chunks' sums,
+#: decayed pairs, triangular inverse, the carried state, the outputs. Norm:
+#: the head's RMSNorm and the sigmoid gate
+DELTA = "hvd.delta"
+DELTA_PROJ = "hvd.delta.proj"
+DELTA_CONV = "hvd.delta.conv"
+DELTA_GATES = "hvd.delta.gates"
+DELTA_SCAN = "hvd.delta.scan"
+DELTA_NORM = "hvd.delta.norm"
 #: nested in LAYERS: a looped model's passes through its stack, with the
 #: final norm that closes each loop step
 LOOP = "hvd.loop"
@@ -191,9 +204,14 @@ SHORT_CONV_PHASES = (SHORT_CONV, SHORT_CONV_PROJ, SHORT_CONV_GATE)
 INDEX_PHASES = (ATTENTION_INDEX, ATTENTION_INDEX_SCORES,
                 ATTENTION_INDEX_SELECT, ATTENTION_INDEX_LOSS,
                 ATTENTION_CORE_SPARSE)
+#: phases only a stack with gated delta-rule blocks has, each forward and
+#: backward
+DELTA_PHASES = (DELTA, DELTA_PROJ, DELTA_CONV, DELTA_GATES, DELTA_SCAN,
+                DELTA_NORM)
 DEVICE_PHASES = (MODEL_PHASES + MOE_PHASES + LOOP_PHASES + MIXED_PHASES
                  + HYBRID_PHASES + LATENT_PHASES + GATED_PHASES
-                 + SHORT_CONV_PHASES + INDEX_PHASES + (GRAD_SYNC, OPTIMIZER, RECOMPUTE))
+                 + SHORT_CONV_PHASES + INDEX_PHASES + DELTA_PHASES
+                 + (GRAD_SYNC, OPTIMIZER, RECOMPUTE))
 
 # -- host spans (profiling.annotate) ------------------------------------------
 #: the input iterator's ``next()``: the host makes the batch

@@ -90,6 +90,21 @@ def _keye_moved(arch, tree, rng, seed):
     return tree
 
 
+def _kimi_moved(arch, tree, rng, seed):
+    """Every norm's weight off 1, the table at a scale at which the logits
+    say something, and the routers' bias off 0 under routers wide enough
+    that the token and not the bias decides the choice (at init_params'
+    0.02 every token of a layer picks the bias's two experts, and a cut
+    stack's held experts see none)."""
+    tree = _norms_off_one(bias=False)(arch, tree, rng, seed)
+    tree["embed"] = tree["embed"] * (1.0 / 0.02)
+    experts = tree["layers"]["experts"]
+    experts["router"] = experts["router"] * 10.0
+    experts["router_bias"] = (0.05 * np.random.RandomState(seed + 300).randn(
+        *experts["router_bias"].shape)).astype(np.float32)
+    return tree
+
+
 def _granite_moved(arch, tree, rng, seed):
     """The table at the configuration's scale (at 0.02 the logits say
     nothing) and the norm weights and the skip off their ones, so that a
@@ -253,6 +268,7 @@ class Row:
 
 _EXPERTS, _DENSE, _CONV, _MAMBA = ("experts",), ("dense",), ("conv",), (
     "mamba",)
+_DELTA = ("delta",)
 _NOPE = ("attention", None, False)
 _LAGUNA_WINDOW = ("attention", 8, Rope(10000.0), 8, True)
 _LAGUNA_FULL = ("attention", None, Rope(
@@ -573,6 +589,47 @@ ROWS = {
                 index_topk=16, index_heads=2, index_head_dim=8), _PAGED),
             ("the tree", "dense GPT block", None, "flatten")),
         shares=(8, 16, None)),
+    "kimi_linear": Row(
+        config="kimi-linear-48b-a3b", workload="train.s8192.b1.delta",
+        leaves=_every_leaf, moved=_kimi_moved,
+        tiny={"cfg": {
+            "dtype": jnp.float32, "one_sublayer": True,
+            "layer_pattern": (_DELTA, _EXPERTS) * 2 + (("latent",), _EXPERTS)
+            + (_DELTA, _EXPERTS),
+            "lead_pattern": (_DELTA, _DENSE),
+            "n_layers": 8,                      # layers 2-5: one period
+            "d_model": 64, "delta_heads": 2, "delta_head_dim": 16,
+            "delta_taps": 4, "delta_chunk": 8,
+            "n_heads": 4, "kv_heads": 4, "head_dim": 16, "rope_width": 4,
+            "q_latent": 0, "kv_latent": 16, "latent_rope": False,
+            "value_width": 8,
+            "d_ff": 32, "dense_ff": 96, "moe_shared_width": 32,
+            **{**_SIGMOID_SHARE, "moe_top_k": 2},
+            "moe_activation": "silu", "moe_gated": True, "ffn_gated": True,
+            "moe_routed_scale": 2.446, "tie_embeddings": False,
+            "norm_eps": 1e-5},
+            "job": {"seq_len": 8 * 8},          # eight chunks carry a state
+            "sizes": {
+                "layer_mixers": ["delta"] * 3 + ["latent", "delta"],
+                "head_dim": 128, "qk_head_dim": 16, "value_head_dim": 8}},
+        reference_imports=("__future__", "math", "jax", "trees",
+                           "reference"),
+        # (no ``drawn``: a rate a head is two numbers at the tiny preset, no
+        # sample to take a spread of; tests/test_kimi_linear.py holds the
+        # adapter's draw leaf by leaf)
+        refused=(
+            ("the cell", "delta_heads", None, _PAGED),
+            ("the mixer alone", "layer_pattern.*delta_heads", _plain(
+                layer_pattern=(_DELTA, _DENSE), delta_heads=2,
+                delta_head_dim=16), _PAGED),
+            ("a latent block without rotation", "latent_rope", _plain(
+                layer_pattern=(("latent",), _DENSE), kv_latent=16,
+                rope_width=4, latent_rope=False), _PAGED),
+            ("values narrower than keys", "value_width", _plain(
+                layer_pattern=(("latent",), _DENSE), kv_latent=16,
+                rope_width=4, value_width=8), _PAGED),
+            ("the tree", "dense GPT block", None, "flatten")),
+        shares=(8, 16, 1.0), choices=(4, 2 * 64, 2)),
 }
 
 

@@ -406,8 +406,10 @@ def test_a_looped_stack_with_the_new_fields_is_refused_by_name():
         dataclasses.replace(CFG, mtp_depth=2)
     with pytest.raises(ValueError, match="lead_pattern.*two-sublayer"):
         t.TransformerConfig(lead_pattern=(("dense",),))
-    with pytest.raises(ValueError, match="q_latent=0"):
-        dataclasses.replace(CFG, q_latent=0)
+    with pytest.raises(ValueError, match="kv_latent=0"):
+        dataclasses.replace(CFG, kv_latent=0)
+    # (no query latent is a form of its own since PR 66: another tree)
+    assert dataclasses.replace(CFG, q_latent=0).q_latent == 0
     with pytest.raises(ValueError, match="n_kv_heads, qk_norm or post_norm"):
         dataclasses.replace(CFG, qk_norm=True)
 

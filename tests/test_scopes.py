@@ -28,7 +28,7 @@ PACKAGE = os.path.join(os.path.dirname(os.path.dirname(
 
 def test_the_vocabulary_is_the_only_place_the_strings_are_written():
     names = scopes.DEVICE_PHASES + scopes.HOST_SPANS
-    assert len(set(names)) == len(names) == 42
+    assert len(set(names)) == len(names) == 48
     assert scopes.RECOMPUTE in scopes.DEVICE_PHASES
     # JAX's own word is no phase, and is written here alone all the same
     names += (scopes.RECOMPUTED,)
@@ -245,6 +245,36 @@ def _short_conv_step():
             (params, init_opt_state(tx, params, mesh, cfg), t, y))
 
 
+def _delta_step():
+    """Gated delta-rule blocks beside a latent block without rotation and
+    with values narrower than keys, a delta + dense layer leading
+    sigmoid-routed experts of which a share is held, an untied head."""
+    from horovod_tpu.models import (TransformerConfig, init_opt_state,
+                                    init_params, make_train_step,
+                                    shard_batch, shard_params)
+    cfg = TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
+                            n_layers=4, d_ff=16, dense_ff=32, max_seq=32,
+                            n_experts=4, moe_top_k=2, moe_gated=True,
+                            moe_renormalize=True, moe_balance_weight=0.0,
+                            moe_router_scores="sigmoid", ffn_gated=True,
+                            dtype=jnp.float32, head_width=16, kv_latent=8,
+                            rope_width=4, latent_rope=False, value_width=8,
+                            delta_heads=2, delta_head_dim=8, delta_chunk=8,
+                            tie_embeddings=False,
+                            layer_pattern=(("delta",), ("experts",),
+                                           ("latent",), ("experts",)),
+                            lead_pattern=(("delta",), ("dense",)),
+                            expert_share=(0, 2))
+    mesh = build_mesh(devices=jax.devices()[:1], dp=-1)
+    params = shard_params(init_params(np.random.RandomState(0), cfg, 1),
+                          cfg, mesh)
+    tx = optax.adamw(1e-3)
+    tokens = np.zeros((2, 16), np.int32)
+    t, y = shard_batch(tokens, tokens, mesh)
+    return (make_train_step(cfg, mesh, tx),
+            (params, init_opt_state(tx, params, mesh, cfg), t, y))
+
+
 _TEXTS = {}
 
 
@@ -256,7 +286,8 @@ def _compiled_text(model: str) -> str:
                       "mixed": _mixed_step,
                       "hybrid": _hybrid_step,
                       "gated": _gated_step,
-                      "short_conv": _short_conv_step}[model]()
+                      "short_conv": _short_conv_step,
+                      "delta": _delta_step}[model]()
         _TEXTS[model] = step.lower(*args).compile().as_text()
     return _TEXTS[model]
 
@@ -367,6 +398,42 @@ def test_the_short_conv_s_parts_nest_in_it_and_the_heads_norm_in_attention():
     assert not any(scopes.SHORT_CONV in _compiled_text(model)
                    for model in ("flagship", "moe", "looped", "mixed",
                                  "hybrid", "gated"))
+
+
+@pytest.mark.parametrize("phase", scopes.MODEL_PHASES + scopes.MOE_PHASES
+                         + scopes.DELTA_PHASES
+                         + (scopes.ATTENTION_LATENT,
+                            scopes.ATTENTION_LATENT_DOWN,
+                            scopes.ATTENTION_LATENT_UP))
+def test_the_delta_step_carries_every_phase_in_both_directions(phase):
+    assert _directions(_compiled_text("delta"), phase) == {"fwd", "bwd"}
+
+
+def test_the_delta_mixer_s_parts_nest_in_it():
+    """hvd.delta.proj, .conv, .gates, .scan and .norm inside hvd.delta (the
+    mixer, norm to out-projection) and in no attention or MLP phase; the
+    scan holds the chunks' loop; a step without the kind has no such
+    phase."""
+    assert scopes.DELTA_PHASES == (
+        "hvd.delta", "hvd.delta.proj", "hvd.delta.conv", "hvd.delta.gates",
+        "hvd.delta.scan", "hvd.delta.norm")
+    paths = re.findall(r'op_name="([^"]*)"', _compiled_text("delta"))
+
+    def parts(path):
+        return [re.sub(r"^(?:\w+\()+|\)+$", "", c) for c in path.split("/")]
+    for part in scopes.DELTA_PHASES[1:]:
+        inside = [parts(p) for p in paths if part in parts(p)]
+        assert inside and all(
+            scopes.DELTA in p and p.index(scopes.DELTA) < p.index(part)
+            and scopes.ATTENTION not in p and scopes.MLP not in p
+            for p in inside), part
+        assert any(scopes.LAYERS in p for p in inside), part
+    assert any("while" in p[-1] for p in map(parts, paths)
+               if scopes.DELTA_SCAN in p)
+    assert not any(p[-1].startswith("dot_general") for p in map(parts, paths)
+                   if scopes.DELTA_CONV in p or scopes.DELTA_NORM in p)
+    assert not any(scopes.DELTA in _compiled_text(model)
+                   for model in ("flagship", "moe", "hybrid", "short_conv"))
 
 
 def test_the_mixer_s_parts_nest_in_ssm_and_the_shared_expert_in_moe():
