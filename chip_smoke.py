@@ -168,6 +168,8 @@ class Sizes:
     #                       state, chunk)
     dense_ssm: tuple      # the same at ONE group in head tiles
     sparse: tuple         # sparse core check (S, H, k/v heads, D, topk, k tile)
+    delta: tuple          # delta-rule scan check (S, heads, head width,
+    #                       value width, chunk)
     narrow: tuple         # flash check at a head of 64 with grouped heads
     #                       and a scale of its own: (B, S, H, k/v heads, D,
     #                       window, scale)
@@ -215,6 +217,9 @@ REAL = Sizes(
     # keye-vl-2.0-30b-a3b.s16384's sparse core whole: one sequence, 32 / 4
     # heads of 128, 2048 of a query's causal keys, k tiles of 1024
     sparse=(16384, 32, 4, 128, 2048, 1024),
+    # kimi-linear-48b-a3b.s8192's scan whole: one sequence, 32 heads of 128,
+    # chunks of 64
+    delta=(8192, 32, 128, 128, 64),
     # lfm2-24b-a2b.s8192's mixer at the cell's batch, whole
     short_conv=(2, 8192, 2048, 3),
     # depth cut to 4 layers: this phase checks kernels in place, not a model
@@ -236,6 +241,7 @@ TINY = Sizes(
     ssm=(64, 4, 8, 2, 16, 16),
     dense_ssm=(64, 8, 8, 1, 16, 16), narrow=(1, 256, 4, 1, 64, None, 1 / 64),
     sparse=(512, 4, 2, 128, 48, 256),
+    delta=(64, 2, 128, 128, 32),
     short_conv=(2, 64, 32, 3),
     gpt=dict(vocab_size=1000, d_model=256, n_heads=2, n_layers=2,
              d_ff=256, max_seq=256),
@@ -1134,6 +1140,62 @@ def _check_ssm(smoke: Smoke, sizes=None, cell: str = "hybrid",
                      SSM_TOL, against_the_numpy_form=_rel_err(g, n))
 
 
+def _check_delta(smoke: Smoke) -> None:
+    """The gated delta rule's scan on its kernels (ops/pallas_delta.py:
+    hvd_delta_scan, hvd_delta_scan_bwd, through models/delta.py:
+    delta_chunked as a delta block calls it) at ``Sizes.delta``, the cell
+    kimi-linear-48b-a3b.s8192's shape whole: bfloat16 q, k, v with float32
+    log decays, sums, decay factors, inverse and carried state, against the
+    ``jax.numpy`` form on the same operands with its matmuls at "highest",
+    o and every operand's gradient; decays as fast as the cell's (the most
+    negative chunk sum is printed). Prints ``delta_scan_path``. Bound as
+    ``_check_ssm``'s."""
+    import jax
+    import jax.numpy as jnp
+    from horovod_tpu.models import delta
+    from horovod_tpu.ops import pallas_delta
+    S, H, D, Dv, chunk = smoke.sizes.delta
+    keys = jax.random.split(jax.random.PRNGKey(smoke.seed + 8), 7)
+    q = (delta._l2norm(jax.random.normal(keys[0], (1, S, H, D)))
+         * D ** -0.5).astype(jnp.bfloat16)
+    k = delta._l2norm(jax.random.normal(keys[1], (1, S, H, D))
+                      ).astype(jnp.bfloat16)
+    v = jax.nn.silu(jax.random.normal(keys[2], (1, S, H, Dv))
+                    ).astype(jnp.bfloat16)
+    rate = jnp.exp(jax.random.uniform(keys[3], (H, 1), minval=-4.0,
+                                      maxval=2.0))
+    g = -rate * jax.nn.softplus(jax.random.normal(keys[4], (1, S, H, D)))
+    beta = jax.nn.sigmoid(jax.random.normal(keys[5], (1, S, H)))
+    ct = jax.random.normal(keys[6], (1, S, H, Dv), jnp.float32)
+    ops = (q, k, v, g, beta)
+
+    def kernels(*ops):
+        return delta.delta_chunked(*ops, chunk, interpret=smoke.rehearsal)
+
+    def numpy_form(*ops):
+        with jax.default_matmul_precision("highest"):
+            return delta._delta_chunked_numpy(*ops, chunk)
+
+    def loss(f):
+        return lambda *ops: jnp.sum(f(*ops)[0] * ct)
+    path = pallas_delta.describe(S, H, D, Dv, chunk)
+    if smoke.on_chip:
+        check(pallas_delta.delta_scan_path(S, H, D, Dv, chunk) == "kernels",
+              path)
+    got, low = _run_compiled(smoke, kernels, ops, pallas_delta.FWD_NAME)
+    want, want_low = jax.jit(numpy_form)(*ops)
+    _kernel_line(smoke, "delta scan", "fwd", _rel_err(got, want), SSM_TOL,
+                 shape=(S, H, D, Dv, chunk), delta_scan_path=path,
+                 min_log_decay=[float(low), float(want_low)])
+    leaves = (0, 1, 2, 3, 4)
+    got = _run_compiled(smoke, jax.grad(loss(kernels), leaves), ops,
+                        (pallas_delta.FWD_NAME, pallas_delta.BWD_NAME))
+    want = jax.jit(jax.grad(loss(numpy_form), leaves))(*ops)
+    for leaf, g_, w in zip(("d_q", "d_k", "d_v", "d_g", "d_beta"), got, want):
+        _kernel_line(smoke, "delta scan", f"grad {leaf}", _rel_err(g_, w),
+                     SSM_TOL)
+
+
 #: bf16 operands and a bf16 round before the out-projection against float32
 #: at "highest", as FLASH_TOL
 SHORT_CONV_TOL = 2e-2
@@ -1505,6 +1567,7 @@ def phase_kernels(smoke: Smoke, hvd) -> None:
     _check_xent(smoke)
     _check_gmm(smoke)
     _check_ssm(smoke)
+    _check_delta(smoke)
     _check_dense_hybrid(smoke)
     _check_gated_norm(smoke)
     _check_short_conv(smoke)

@@ -26,8 +26,13 @@ shapes, the whole sequence on this device (no sp, pp or tp), ``H`` heads of
   beta            ``sigmoid(h w_beta)``, ``w_beta`` ``[M, H]``: a scalar a head
   output          ``rmsnorm(o; norm [D]) * sigmoid((h wg_down) wg_up)`` a
                   head, then ``wo`` ``[H D, M]``
-The scan is :func:`delta_chunked`, ``jax.numpy`` over chunks differentiated
-by JAX but for the triangular inverse, whose backward is by hand.
+The scan is :func:`delta_chunked`: on a TPU at whole chunks and heads of
+whole lane tiles two Pallas kernels (``ops/pallas_delta.py``:
+``hvd_delta_scan``, ``hvd_delta_scan_bwd``, a chunk's pairs, inverse, ``W``,
+``U`` and the carried state in VMEM), else :func:`_delta_chunked_numpy`,
+``jax.numpy`` over chunks differentiated by JAX but for the triangular
+inverse, whose backward is by hand. ``pallas_delta.delta_scan_path`` names
+the form from the backend and the shapes alone.
 """
 
 from __future__ import annotations
@@ -42,6 +47,7 @@ from horovod_tpu.models._kinds import (BlockKind, Leaf, normal, ones,
                                        rmsnorm, scaled)
 from horovod_tpu.models.mamba import (_causal_conv, draw_a_log,
                                       draw_dt_bias, draw_taps)
+from horovod_tpu.ops import pallas_delta
 from horovod_tpu.profiling import scopes
 
 #: rows of a chunk's sub-block: pairs inside one are decayed pairwise, pairs
@@ -210,8 +216,32 @@ def _pairs(qb, kb, gamma_b, gamma, k):
     return whole(qb), whole(kb)
 
 
-def delta_chunked(q, k, v, g, beta, chunk: int, sub: int = SUB):
-    """The gated delta rule of the module's docstring in its chunked form.
+def delta_chunked(q, k, v, g, beta, chunk: int, sub: int = SUB,
+                  interpret: bool = False):
+    """The gated delta rule of the module's docstring in its chunked form
+    (:func:`_delta_chunked_numpy` states it), on the Pallas kernels of
+    ``ops/pallas_delta.py`` where :func:`pallas_delta.delta_scan_path` says
+    so from the backend and the shapes alone (a TPU, whole chunks, heads of
+    whole lane tiles), else in ``jax.numpy``. ``interpret`` runs the kernels
+    off the chip (tests). Same arguments, same results."""
+    _, S, H, D = q.shape
+    if interpret or pallas_delta.delta_scan_path(
+            S, H, D, v.shape[-1], chunk, q.dtype, sub) == "kernels":
+        o, last = _scan_kernels(q, k, v, g, beta, chunk, sub, interpret)
+        return o, lax.stop_gradient(jnp.min(last))
+    return _delta_chunked_numpy(q, k, v, g, beta, chunk, sub)
+
+
+@functools.partial(jax.jit, static_argnums=(5, 6, 7))
+def _scan_kernels(q, k, v, g, beta, chunk, sub, interpret):
+    """One jit around the kernels' call, so that a stack's delta blocks
+    share one tracing and one Mosaic lowering of each kernel."""
+    return pallas_delta.delta_scan(q, k, v, g, beta, chunk, sub, interpret)
+
+
+def _delta_chunked_numpy(q, k, v, g, beta, chunk: int, sub: int = SUB):
+    """The chunked form in ``jax.numpy``: the CPU's path, the fall-back for
+    shapes the kernels refuse, and what the tests hold the kernels to.
     With ``Gamma_i = sum_{j <= i} g_j`` inside a chunk of ``C`` positions
     (a channel, float32) and ``S`` the state the chunk starts from:
 

@@ -59,7 +59,8 @@ CLIENT_FILES = [os.path.join(CHIP, "run.py")] + sorted(
 KERNEL_FILES = [os.path.join(REPO, "horovod_tpu", *p) for p in (
     ("ops", "pallas_attention.py"), ("ops", "pallas_sparse_attention.py"),
     ("ops", "pallas_xent.py"),
-    ("ops", "pallas_ssm.py"), ("parallel", "moe.py"))]
+    ("ops", "pallas_ssm.py"), ("ops", "pallas_delta.py"),
+    ("parallel", "moe.py"))]
 #: read by name in a run's ``breakdown`` (PERF.md §3) until a metric file
 #: names them
 BLOCK_KERNELS = ("hvd_block_attention", "hvd_block_attention_bwd")
@@ -154,6 +155,9 @@ def _names_looked_for():
             read = json.load(f)["read"]
         rel = os.path.relpath(path, CHIP)
         ops = read.get("trace_ops")
+        # (a name that another kernel's starts with ends in a lookahead
+        # that leaves the other out: ``hvd_delta_scan(?!_bwd)``)
+        ops = re.sub(r"\(\?!\w+\)$", "", ops) if isinstance(ops, str) else ops
         if isinstance(ops, str) and re.fullmatch(r"hvd_\w+", ops):
             found.setdefault(("kernel", ops), rel)
         # a cover's phase, and the phase of an owner (readers/step_owners.py)

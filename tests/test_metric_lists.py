@@ -10,7 +10,11 @@ another's (CPU only, nothing is compiled).
 The benchmark's ``_gate`` knows the kernels its own PRs met. The sparse
 core's three (PR 65, a ``perf_opt`` PR: it adds metric files and edits no
 file of the benchmark's) are answered here by the program's own gate,
-``sparse_path``, until a ``benchmark`` PR gives ``_gate`` that branch."""
+``sparse_path``, until a ``benchmark`` PR gives ``_gate`` that branch; so
+are the delta rule's two (PR 67, the same kind of PR), by
+``delta_scan_path`` at the cell's ``shapes()``: the forward's metric reads
+``hvd_delta_scan(?!_bwd)``, a pattern, since the backward's name starts
+with the forward's."""
 
 import chip_door
 
@@ -23,7 +27,14 @@ _benchmarks_gate = _own._gate
 def _gate(kernel: str, sizes: dict, monkeypatch) -> bool:
     import jax
     from horovod_tpu.ops import pallas_sparse_attention as ps
-    from horovod_tpu.ops import sparse_attention
+    from horovod_tpu.ops import pallas_delta, sparse_attention
+    if kernel in (pallas_delta.FWD_NAME + "(?!_bwd)", pallas_delta.BWD_NAME):
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        width = sizes.get("delta_head_dim", 0)
+        return sizes.get("delta_layers", 0) > 0 \
+            and pallas_delta.delta_scan_path(
+                sizes["seq"], sizes["delta_heads"], width, width,
+                sizes["delta_chunk"]) == "kernels"
     if kernel in (ps.FWD_NAME, ps.MEAN_NAME, ps.BWD_NAME):
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         return sizes.get("index_topk", 0) > 0 and sparse_attention.sparse_path(

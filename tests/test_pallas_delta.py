@@ -1,0 +1,271 @@
+"""The gated delta rule's Pallas kernels (``ops/pallas_delta.py``:
+``hvd_delta_scan``, ``hvd_delta_scan_bwd``) in interpret mode on the CPU, in
+float32, against the ``jax.numpy`` form ``models/delta.py:
+_delta_chunked_numpy`` and against the recurrence one position at a time
+(the benchmark's plain reference, ``reference/kimi_linear.py:delta_rule``),
+o and all five cotangents, at heads of one lane tile (the kernels' least)
+and shapes that cross a chunk boundary, have two sub-blocks or four a chunk
+(one or two levels of the inverse's merge), two heads a grid step, a batch of
+two and values wider than keys.
+
+TOL is ``tests/test_pallas_ssm.py``'s: both sides are float32 and differ in
+the order of their sums (1e-7 to 1e-5 here); what TOL must not let through
+(a decay in bfloat16, a state that is not carried, the decay applied after
+the correction, a missing causal mask) reads 4 times it and more, forward
+and backward.
+"""
+
+import functools
+import os
+import sys
+
+import numpy as np
+import pytest
+import jax
+import jax.numpy as jnp
+
+from horovod_tpu.models import delta
+from horovod_tpu.ops import pallas_delta as pd
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+_CHIP = os.path.join(_REPO, "benchmarks", "chip")
+if _CHIP not in sys.path:
+    sys.path.insert(0, _CHIP)
+
+from reference import kimi_linear as reference        # noqa: E402
+
+TOL = 1e-4
+#: (B, S, H, D, Dv, chunk, heads a grid step)
+SHAPES = {
+    "two chunks of four sub-blocks": (1, 128, 1, 128, 128, 64, 1),
+    "batch 2, two heads a step, two sub-blocks": (2, 64, 2, 128, 128, 32, 2),
+    "values wider than keys, one sub-block": (1, 32, 1, 128, 256, 16, 1),
+}
+NAMES = ("q", "k", "v", "g", "beta")
+
+
+def _operands(shape, seed=0, dtype=jnp.float32, rate=0.3):
+    B, S, H, D, Dv = shape[:5]
+    rng = np.random.RandomState(seed)
+
+    def unit(x):
+        return x / np.linalg.norm(x, axis=-1, keepdims=True)
+    q = unit(rng.randn(B, S, H, D)) * D ** -0.5
+    k = unit(rng.randn(B, S, H, D))
+    v = rng.randn(B, S, H, Dv)
+    g = -rate * np.exp(rng.randn(B, S, H, D))
+    beta = 1 / (1 + np.exp(-rng.randn(B, S, H)))
+    return (jnp.asarray(q, dtype), jnp.asarray(k, dtype),
+            jnp.asarray(v, dtype), jnp.asarray(g, jnp.float32),
+            jnp.asarray(beta, jnp.float32))
+
+
+def _weight(shape, seed=1):
+    B, S, H, _, Dv = shape[:5]
+    return jnp.asarray(np.random.RandomState(seed).randn(B, S, H, Dv),
+                       jnp.float32)
+
+
+def _kernels(shape):
+    chunk, head_tile = shape[5:]
+    return lambda *v: pd.delta_scan(*v, chunk, delta.SUB, True, head_tile)[0]
+
+
+def _numpy_form(shape):
+    return lambda *v: delta._delta_chunked_numpy(*v, shape[5])[0]
+
+
+def _stepwise(*ops):
+    return reference.delta_rule(*(x.astype(jnp.float32) for x in ops))
+
+
+def _rel(got, want):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    return np.linalg.norm(got - want) / (np.linalg.norm(want) or 1.0)
+
+
+def _both(f, weight, ops):
+    """o and the five cotangents under ``sum(f(*ops) weight)``, as one
+    traced and compiled program."""
+    def run(*v):
+        o, pull = jax.vjp(f, *v)
+        return (o,) + pull(weight)
+    return jax.jit(run)(*ops)
+
+
+@pytest.mark.parametrize("shape", SHAPES.values(), ids=SHAPES.keys())
+def test_the_kernels_are_the_numpy_form_and_the_recurrence(shape):
+    """o, and the cotangents of q, k, v, g and beta."""
+    ops, weight = _operands(shape), _weight(shape)
+    got = _both(_kernels(shape), weight, ops)
+    want = _both(_numpy_form(shape), weight, ops)
+    stepwise = _both(_stepwise, weight, ops)
+    assert got[0].dtype == jnp.float32 and got[0].shape == weight.shape
+    for name, g, w, r in zip(("o",) + NAMES, got, want, stepwise):
+        assert g.dtype == w.dtype and g.shape == w.shape, name
+        assert _rel(g, w) < TOL, name
+        assert _rel(g, r) < TOL, name
+
+
+def test_one_chunk_is_the_whole_sequence_and_the_state_crosses_chunks():
+    shape = (1, 64, 1, 128, 128, 64, 1)
+    ops, weight = _operands(shape, seed=2), _weight(shape)
+    whole = _both(_kernels(shape), weight, ops)
+    halves = _both(_kernels(shape[:5] + (32, 1)), weight, ops)
+    for name, g, w in zip(("o",) + NAMES, halves, whole):
+        assert _rel(g, w) < TOL, name
+    alone = _kernels(shape[:5] + (32, 1))(*(x[:, 32:] for x in ops))
+    assert _rel(alone, whole[0][:, 32:]) > 1e-2
+    first = _kernels(shape[:5] + (32, 1))(*(x[:, :32] for x in ops))
+    assert _rel(first, whole[0][:, :32]) < TOL
+
+
+def test_the_chunks_last_sums_are_the_numpy_form_s():
+    """``delta_min_log_decay``: the most negative ``Gamma_C`` of any chunk,
+    head and channel, from the kernel's small extra output."""
+    shape = SHAPES["batch 2, two heads a step, two sub-blocks"]
+    ops = _operands(shape, rate=1.5)
+    _, got = delta.delta_chunked(*ops, shape[5], interpret=True)
+    _, want = delta._delta_chunked_numpy(*ops, shape[5])
+    assert float(got) == pytest.approx(float(want), rel=1e-6)
+    assert float(got) < -10
+
+
+def test_a_fast_decay_stays_finite_and_equal():
+    """``g = -3.5`` a position: ``Gamma_C`` is -224 a chunk of 64 (the cell's
+    ``delta_min_log_decay`` is -223 to -250) and ``exp(-Gamma_j)`` alone
+    would overflow float32; in the kernels as in ``_pairs`` every exponent
+    is <= 0."""
+    shape = SHAPES["two chunks of four sub-blocks"]
+    q, k, v, g, beta = _operands(shape)
+    ops, weight = (q, k, v, jnp.full_like(g, -3.5), beta), _weight(shape)
+    got = _both(_kernels(shape), weight, ops)
+    want = _both(_stepwise, weight, ops)
+    assert all(bool(jnp.all(jnp.isfinite(x))) for x in got)
+    for name, g_, w in zip(("o",) + NAMES, got, want):
+        assert _rel(g_, w) < TOL, name
+    _, low = delta.delta_chunked(*ops, 64, interpret=True)
+    assert float(low) == -224.0
+
+
+# -- what TOL must not let through -------------------------------------------
+
+def _bf16_decay(log_decay):
+    return jnp.exp(log_decay.astype(jnp.bfloat16)).astype(jnp.float32)
+
+
+def _not_carried(state, whole, own):
+    return jnp.zeros_like(own)
+
+
+def _decay_after_the_correction(state, whole, own):
+    return (state + own) * whole
+
+
+def _no_mask(later, earlier):
+    return jnp.ones(jnp.broadcast_shapes(jnp.shape(later),
+                                         jnp.shape(earlier)), bool)
+
+
+def _decays_capped(log_decay):
+    """Without the mask a decay's exponent is positive above the diagonal;
+    capped so that the wrong sum stays finite."""
+    return jnp.exp(jnp.minimum(log_decay, 3.0))
+
+
+@pytest.mark.parametrize("what, patches", [
+    ("the decays made in bfloat16", {"_decay": _bf16_decay}),
+    ("a state that is not carried", {"_carry": _not_carried}),
+    ("the decay applied after the correction",
+     {"_carry": _decay_after_the_correction}),
+    ("no causal mask inside a sub-block", {"_reaches": _no_mask,
+                                           "_decay": _decays_capped}),
+])
+def test_a_wrong_piece_fails_on_the_kernels(monkeypatch, what, patches):
+    """The forward and, beside it, the gradients (the backward kernel makes
+    its decays, mask and carried cotangent from the same three pieces). The
+    sound kernels on the same operands: ``_sound_errors``, run once."""
+    assert max(_sound_errors()) < TOL
+    for name, wrong in patches.items():
+        monkeypatch.setattr(pd, name, wrong)
+    forward, backward = _errors()
+    assert forward > 4 * TOL, (what, forward)
+    assert backward > 4 * TOL, (what, backward)
+
+
+_WRONG_AT = (1, 64, 1, 128, 128, 32, 1)
+
+
+@functools.lru_cache(maxsize=None)
+def _recurrence_at_the_wrong_pieces_shape():
+    return _both(_stepwise, _weight(_WRONG_AT), _operands(_WRONG_AT, rate=1.0))
+
+
+def _errors():
+    """(o's, the worst cotangent's) distance from the recurrence's."""
+    want = _recurrence_at_the_wrong_pieces_shape()
+    got = _both(_kernels(_WRONG_AT), _weight(_WRONG_AT),
+                _operands(_WRONG_AT, rate=1.0))
+    return (_rel(got[0], want[0]),
+            max(_rel(g, w) for g, w in zip(got[1:], want[1:])))
+
+
+_sound_errors = functools.lru_cache(maxsize=None)(_errors)
+
+
+def test_bfloat16_operands_keep_float32_sums_decays_and_state():
+    """The cell's dtypes: o float32, every cotangent in its operand's dtype,
+    and both within bfloat16's rounding of the ``jax.numpy`` form at the
+    same dtypes (which rounds the same operands in another order)."""
+    shape = SHAPES["batch 2, two heads a step, two sub-blocks"]
+    ops, weight = _operands(shape, dtype=jnp.bfloat16), _weight(shape)
+    got = _both(_kernels(shape), weight, ops)
+    want = _both(_numpy_form(shape), weight, ops)
+    assert got[0].dtype == jnp.float32
+    assert _rel(got[0], want[0]) < 1e-2
+    for name, g, w, op in zip(NAMES, got[1:], want[1:], ops):
+        assert g.dtype == op.dtype, name
+        assert _rel(g, w) < 2e-2, name
+
+
+# -- which form runs -----------------------------------------------------------
+
+CELL = (8192, 32, 128, 128, 64)           # S, H, D, Dv, chunk
+
+
+def _calls_a_kernel(S, H, D, Dv, chunk):
+    q = jax.ShapeDtypeStruct((1, S, H, D), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((1, S, H, Dv), jnp.bfloat16)
+    g = jax.ShapeDtypeStruct((1, S, H, D), jnp.float32)
+    beta = jax.ShapeDtypeStruct((1, S, H), jnp.float32)
+    return "pallas_call" in str(jax.make_jaxpr(
+        lambda *x: delta.delta_chunked(*x, chunk))(q, q, v, g, beta))
+
+
+def test_the_kernels_run_on_a_tpu_at_the_cell_s_shape(monkeypatch):
+    assert pd.delta_scan_path(*CELL) == "xla"       # the CPU
+    assert not _calls_a_kernel(256, 2, 128, 128, 64)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert pd.delta_scan_path(*CELL) == "kernels"
+    said = pd.describe(*CELL)
+    assert pd.FWD_NAME in said and pd.BWD_NAME in said
+    assert "128 chunks" in said and "sub-blocks of 16 rows" in said
+    assert pd.delta_vmem_bytes(64, 128, 128, pd.delta_head_tile(
+        32, 128, 128, 64), 2) <= pd.VMEM_BUDGET
+    assert _calls_a_kernel(256, 2, 128, 128, 64)
+
+
+@pytest.mark.parametrize("what, shape", [
+    ("the tiny sizes: heads of 16, chunks of 8", (64, 2, 16, 16, 8)),
+    ("heads of 64", (8192, 32, 64, 64, 64)),
+    ("values of 96", (8192, 32, 128, 96, 64)),
+    ("a chunk of 8 rows", (8192, 32, 128, 128, 8)),
+    ("a chunk of one and a half sub-blocks", (8192, 32, 128, 128, 24)),
+    ("a sequence of half a chunk more", (8192 + 32, 32, 128, 128, 64)),
+])
+def test_the_numpy_form_runs_where_the_kernels_do_not_fit(monkeypatch, what,
+                                                          shape):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert pd.delta_scan_path(*shape) == "xla", what
+    if shape[0] % shape[4] == 0:
+        assert not _calls_a_kernel(*shape), what

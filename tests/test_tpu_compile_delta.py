@@ -6,6 +6,7 @@ import re
 import jax
 import pytest
 
+from horovod_tpu.ops import pallas_delta
 from horovod_tpu.parallel import moe
 from tpu_compile_cases import cell_step, compile_cache_off, described_v5e
 
@@ -24,9 +25,15 @@ def test_delta_step_compiles_for_v5e_with_no_array_of_two_sequence_lengths(
     latent block is the flash kernels at 32 heads of the padded 256 (two
     forward calls: the block runs again in the backward pass), the experts
     are ``hvd_moe_gmm`` at 2304 <-> 1024, the head ``hvd_fused_xent``; the
-    delta rule's scan is XLA code under its six scopes, a ``while`` over the
-    128 chunks in it, and no array of the program has two dimensions a
-    sequence long; the bytes are under the compiler's 15.75 GB."""
+    delta rule's scan is its two kernels under ``hvd.delta.scan`` (PR 67):
+    8 calls of ``hvd_delta_scan`` (four blocks, each forward run twice) and
+    4 of ``hvd_delta_scan_bwd``, no ``while`` over the 128 chunks there and
+    no float32 array of a chunk's and head's pairs, inverse, ``W``, ``U`` or
+    decays (``[128, 32, 64, 128]``, ``[.., 64, 64]``) but the kernels' own:
+    the states the chunks start from, ``[1, 128, 32, 128, 128]`` a
+    differentiated forward call; the six ``hvd.delta*`` scopes are still
+    there, no array of the program has two dimensions a sequence long, and
+    the bytes are under the compiler's 15.75 GB."""
     with compile_cache_off(), pytest.MonkeyPatch.context() as patch:
         patch.setattr(jax, "default_backend", lambda: "tpu")
         step, args, shapes, step_bytes = cell_step(
@@ -39,21 +46,34 @@ def test_delta_step_compiles_for_v5e_with_no_array_of_two_sequence_lengths(
     assert (shapes["layers"], shapes["delta_layers"],
             shapes["attention_layers"], shapes["routed_layers"]) == (
                 5, 4, 1, 4)
+    s = shapes["seq"]
+    from horovod_tpu.profiling import scopes
     assert sum("hvd_flash_attention" in c for c in calls) == 2
     assert sum("hvd_flash_bwd" in c for c in calls) == 1
     assert sum("hvd_flash_adj" in c for c in calls) >= 1
     assert sum(moe.GMM_NAME in c for c in calls) == 9 * 4
     assert sum("hvd_fused_xent" in c for c in calls) == 1
-    s = shapes["seq"]
+    scans = [c for c in calls if scopes.DELTA_SCAN + "/" in c]
+    backward = [c for c in scans if pallas_delta.BWD_NAME in c]
+    assert len(backward) == 4 and len(scans) == 8 + 4
+    assert all(pallas_delta.FWD_NAME in c for c in scans)
+    n, h = s // shapes["delta_chunk"], shapes["delta_heads"]
+    d, c = shapes["delta_head_dim"], shapes["delta_chunk"]
+    assert (n, h, d, c) == (128, 32, 128, 64)
+    assert not re.search(r"f32\[(?:\d+,)*%d,%d,%d,(?:%d|%d)\]" % (n, h, c, d, c),
+                         text), "a chunk's and head's float32 intermediate"
+    assert not re.search(r"f32\[(?:\d+,)*%d,%d\][^ ]* " % (c, c), text), \
+        "a chunk's float32 pairs or inverse"
+    states = re.findall(r"f32\[1,%d,%d,%d,%d\]" % (n, h, d, d), text)
+    assert states, "the states the chunks start from"
     assert not re.search(r"\[(?:\d+,)*(?:[2-9]|\d\d+),%d,%d\]" % (s, s),
                          text), "a score-shaped array"
     assert not re.search(r"\[(?:\d+,)*%d,(?:\d+,)+%d[\],]" % (s, s), text), \
         "an array with two sequence-long dimensions"
-    from horovod_tpu.profiling import scopes
     names = "\n".join(line for line in text.splitlines()
                       if "op_name=" in line)
     for name in scopes.DELTA_PHASES:
         assert name + "/" in names, name
-    assert re.search(scopes.DELTA_SCAN + r"/[^\"]*while", names)
+    assert not re.search(scopes.DELTA_SCAN + r"/[^\"]*while", names)
     total = step_bytes(compiled.memory_analysis())["total"]
-    assert 13.9e9 < total < 14.6e9, total      # PERF.md section 6, PR 66
+    assert 12.78e9 < total < 13.48e9, total     # PERF.md section 6, PR 67

@@ -21,10 +21,11 @@ import jax.numpy as jnp
 import pytest
 
 from horovod_tpu.ops import pallas_attention as pa
+from horovod_tpu.ops import pallas_delta
 from horovod_tpu.ops import pallas_quantize as pq
 from horovod_tpu.ops import pallas_ssm
 from horovod_tpu.ops import pallas_xent as px
-from horovod_tpu.models import mamba
+from horovod_tpu.models import delta, mamba
 from horovod_tpu.parallel import moe
 from tpu_compile_cases import (compile_cache_off, described_v5e,
                                loops_that_write_rows_in_place, sum32)
@@ -78,6 +79,9 @@ _QKV_BANDED_FULL = [((1, 8192, 48, 128), jnp.bfloat16)] \
 _QKV_SHORT_CONV = [((2, 8192, 32, 64), jnp.bfloat16)] \
     + [((2, 8192, 8, 64), jnp.bfloat16)] * 2
 _FLASH = ("hvd_flash_attention", "hvd_flash_bwd")
+# the delta rule's scan: q, k, v bf16, g float32 [B, S, H, D], beta [B, S, H]
+_DELTA_CELL = [((1, 512, 32, 128), jnp.bfloat16)] * 3 \
+    + [((1, 512, 32, 128), jnp.float32), ((1, 512, 32), jnp.float32)]
 # the attention core of the cells bert-large.s128 and bert-large.s512: q, k,
 # v and the [B, S] key mask. Sixteen heads of 64 are eight 128-lane columns
 _BERT_S128 = [((64, 128, 16, 64), jnp.bfloat16)] * 3 + [((64, 128), jnp.bool_)]
@@ -257,6 +261,16 @@ CASES = {
         jax.grad(lambda x, dt, a, b, c: sum32(mamba.ssm_chunked(
             x, dt, a, b, c, 256)), (0, 1, 2, 3, 4)),
         _SSM_DENSE, (pallas_ssm.FWD_NAME, pallas_ssm.BWD_NAME)),
+    # the cell kimi-linear-48b-a3b.s8192's scan at a sixteenth of its
+    # length: blocks of 64 rows, [64, 64] float32 matmuls at HIGHEST, a
+    # column of beta a head, the states' and the last sums' block specs
+    "delta_scan_cell": (
+        lambda *v: delta.delta_chunked(*v, 64), _DELTA_CELL,
+        pallas_delta.FWD_NAME),
+    "delta_scan_grad_cell": (
+        jax.grad(lambda *v: sum32(delta.delta_chunked(*v, 64)[0]),
+                 (0, 1, 2, 3, 4)),
+        _DELTA_CELL, (pallas_delta.FWD_NAME, pallas_delta.BWD_NAME)),
     # a head of 64, heads first: a (1, tile, 64) block of [B*H, S, 64]
     "flash_fwd_grad_head_of_64_grouped": (
         jax.grad(lambda q, k, v: sum32(pa.flash_attention_tpu(
