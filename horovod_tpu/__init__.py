@@ -18,6 +18,9 @@ TPU-idiomatic surface::
     tx = hvd.DistributedOptimizer(optax.adamw(1e-3))   # optax transform
 """
 
+import time as _time
+_T_IMPORT = _time.perf_counter()    # scopes.HOST_IMPORT, this file's last lines
+
 from horovod_tpu.version import __version__  # noqa: F401
 
 # Lifecycle / identity (reference: horovod/common/basics.py)
@@ -206,3 +209,11 @@ from horovod_tpu import elastic  # noqa: F401
 # batcher, hedging router, hot weight swap (reference analog: the
 # elastic driver's Spark/Ray serving integrations)
 from horovod_tpu import serving  # noqa: F401
+
+# What a start pays for this package's own import, before any line of a job
+# runs (``scopes.HOST_IMPORT``: one record of the host log, from the first
+# line above to here; profiling/host_log.py)
+from horovod_tpu.profiling import host_log as _host_log
+from horovod_tpu.profiling import scopes as _scopes
+_host_log.record(_scopes.HOST_IMPORT, _T_IMPORT,
+                 _time.perf_counter() - _T_IMPORT)

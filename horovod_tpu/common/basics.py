@@ -101,7 +101,19 @@ def init(ranks: Optional[Sequence[int]] = None,
       process_sets: optional list of :class:`~horovod_tpu.ProcessSet` to
         register at init time (reference: dynamic/static process sets,
         ``operations.cc:1194-1260``).
+
+    One ``scopes.HOST_INIT`` span of the host log, the backend's creation
+    a span inside it: what a start, a re-mesh or a restart pays here.
     """
+    if _state.initialized:      # (checked again under the lock)
+        return
+    from horovod_tpu.profiling import annotate, scopes
+    with annotate(scopes.HOST_INIT):
+        _init(ranks, process_sets)
+
+
+def _init(ranks: Optional[Sequence[int]], process_sets: Optional[list]
+          ) -> None:
     with _state.lock:
         if _state.initialized:
             return
@@ -157,7 +169,9 @@ def init(ranks: Optional[Sequence[int]] = None,
         import time as _time
 
         from horovod_tpu.elastic import remesh as _remesh
-        with _remesh.phase("rendezvous"):
+        from horovod_tpu.profiling import annotate, scopes
+        with _remesh.phase("rendezvous"), \
+                annotate(scopes.HOST_INIT + "/backend"):
             _state.backend = _create_backend(_state)
         _t_rebuild = _time.perf_counter()
 

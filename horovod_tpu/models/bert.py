@@ -103,7 +103,7 @@ class SelfAttention(nn.Module):
                 kernel_init=nn.with_partitioning(
                     nn.initializers.normal(0.02), (None, "tp", None)))(x)
                 for name in ("query", "key", "value"))
-        with jax.named_scope(scopes.ATTENTION_CORE):
+        with scopes.scope(scopes.ATTENTION_CORE):
             o = attend(q, k, v, causal=False, key_mask=mask)
         if flat:
             return HeadsDense(heads + (c.hidden_size,), ("tp", None, None),
@@ -121,10 +121,10 @@ class BertLayer(nn.Module):
     @nn.compact
     def __call__(self, x, mask):
         c = self.cfg
-        with jax.named_scope(scopes.ATTENTION):
+        with scopes.scope(scopes.ATTENTION):
             a = SelfAttention(c, name="attention")(x, mask)
             x = nn.LayerNorm(dtype=jnp.float32, name="ln_att")(x + a)
-        with jax.named_scope(scopes.MLP):
+        with scopes.scope(scopes.MLP):
             h = nn.Dense(c.intermediate_size, dtype=c.dtype, name="ffn_in",
                          kernel_init=nn.with_partitioning(
                              nn.initializers.normal(0.02), (None, "tp")))(x)
@@ -146,7 +146,7 @@ class Bert(nn.Module):
                        name="word_embeddings",
                        embedding_init=nn.with_partitioning(
                            nn.initializers.normal(0.02), ("tp", None)))
-        with jax.named_scope(scopes.EMBED):
+        with scopes.scope(scopes.EMBED):
             x = emb(input_ids)
             pos = jnp.arange(input_ids.shape[1])[None]
             x = x + nn.Embed(c.max_position, c.hidden_size, dtype=c.dtype,
@@ -155,11 +155,11 @@ class Bert(nn.Module):
                              dtype=c.dtype,
                              name="token_type_embeddings")(token_type_ids)
             x = nn.LayerNorm(dtype=jnp.float32, name="ln_emb")(x)
-        with jax.named_scope(scopes.LAYERS):
+        with scopes.scope(scopes.LAYERS):
             for i in range(c.num_layers):
                 x = BertLayer(c, name=f"layer_{i}")(x, attention_mask)
         # MLM head (tied to word embeddings) + NSP head on [CLS]
-        with jax.named_scope(scopes.HEAD):
+        with scopes.scope(scopes.HEAD):
             h = nn.Dense(c.hidden_size, dtype=c.dtype,
                          name="mlm_transform")(x)
             h = nn.LayerNorm(dtype=jnp.float32, name="mlm_ln")(nn.gelu(h))
@@ -171,7 +171,7 @@ class Bert(nn.Module):
 
 def pretrain_loss(mlm_logits, nsp_logits, mlm_labels, mlm_mask, nsp_labels):
     """Masked-LM + next-sentence loss (standard BERT pretraining)."""
-    with jax.named_scope(scopes.HEAD):
+    with scopes.scope(scopes.HEAD):
         v = mlm_logits.shape[-1]
         mlm = optax.softmax_cross_entropy(
             mlm_logits, jax.nn.one_hot(mlm_labels, v))
@@ -207,7 +207,7 @@ def make_bert_train_step(model: Bert, optimizer, mesh: Mesh,
                                  batch["mlm_labels"], batch["mlm_mask"],
                                  batch["nsp_labels"])
         loss, grads = jax.value_and_grad(loss_fn)(params)
-        with jax.named_scope(scopes.OPTIMIZER):
+        with scopes.scope(scopes.OPTIMIZER):
             updates, opt_state = optimizer.update(grads, opt_state, params)
             params = optax.apply_updates(params, updates)
         return params, opt_state, loss

@@ -337,19 +337,19 @@ def _delta_block(p, x, cfg):
     here (no sp); and the step's most negative ``Gamma_C``."""
     B, S, _ = x.shape
     H, D = cfg.delta_heads, cfg.delta_head_dim
-    with jax.named_scope(scopes.DELTA):
+    with scopes.scope(scopes.DELTA):
         h = rmsnorm(x, p["ln1"], cfg.norm_eps)
-        with jax.named_scope(scopes.DELTA_PROJ):
+        with scopes.scope(scopes.DELTA_PROJ):
             q, k, v = (h @ p[name].astype(h.dtype)
                        for name in ("wq", "wk", "wv"))
-        with jax.named_scope(scopes.DELTA_CONV):
+        with scopes.scope(scopes.DELTA_CONV):
             q, k, v = (_short_conv(y, p[name]).reshape(B, S, H, D)
                        for y, name in ((q, "conv_q"), (k, "conv_k"),
                                        (v, "conv_v")))
             q = (_l2norm(q) * D ** -0.5).astype(h.dtype)
             k = _l2norm(k).astype(h.dtype)
             v = v.astype(h.dtype)
-        with jax.named_scope(scopes.DELTA_GATES):
+        with scopes.scope(scopes.DELTA_GATES):
             rate = jnp.matmul(
                 h @ p["wf_down"].astype(h.dtype),
                 p["wf_up"].astype(h.dtype),
@@ -363,17 +363,17 @@ def _delta_block(p, x, cfg):
                 preferred_element_type=jnp.float32))
             gate = (h @ p["wg_down"].astype(h.dtype)
                     ) @ p["wg_up"].astype(h.dtype)
-        with jax.named_scope(scopes.DELTA_SCAN):
+        with scopes.scope(scopes.DELTA_SCAN):
             o, min_log_decay = delta_chunked(q, k, v, g, beta,
                                              cfg.delta_chunk)
-        with jax.named_scope(scopes.DELTA_NORM):
+        with scopes.scope(scopes.DELTA_NORM):
             var = jnp.mean(jnp.square(o), axis=-1, keepdims=True)
             y = (o * lax.rsqrt(var + cfg.norm_eps)
                  * p["norm"].astype(jnp.float32)
                  * jax.nn.sigmoid(gate.astype(jnp.float32)
                                   ).reshape(B, S, H, D))
             y = y.reshape(B, S, H * D).astype(h.dtype)
-        with jax.named_scope(scopes.DELTA_PROJ):
+        with scopes.scope(scopes.DELTA_PROJ):
             out = y @ p["wo"].astype(h.dtype)
         return (x + scaled(out, cfg.residual_scale),
                 {"delta_min_log_decay": min_log_decay})

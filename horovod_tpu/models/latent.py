@@ -152,15 +152,15 @@ def _latent_block(p, x, positions, cfg):
     and position-free part together."""
     from horovod_tpu.ops.pallas_attention import attend
     B, S, _ = x.shape
-    with jax.named_scope(scopes.ATTENTION):
+    with scopes.scope(scopes.ATTENTION):
         h = rmsnorm(x, p["ln1"], cfg.norm_eps)
-        with jax.named_scope(scopes.ATTENTION_LATENT):
-            with jax.named_scope(scopes.ATTENTION_LATENT_DOWN):
+        with scopes.scope(scopes.ATTENTION_LATENT):
+            with scopes.scope(scopes.ATTENTION_LATENT_DOWN):
                 c_q, c_kv, k_r = _down(p, h, cfg)
-            with jax.named_scope(scopes.ATTENTION_LATENT_UP):
+            with scopes.scope(scopes.ATTENTION_LATENT_UP):
                 q, k, v = _up(p, c_q, c_kv, k_r, positions, cfg)
-        with jax.named_scope(scopes.ATTENTION_CORE), \
-                jax.named_scope(scopes.ATTENTION_CORE_FULL):
+        with scopes.scope(scopes.ATTENTION_CORE), \
+                scopes.scope(scopes.ATTENTION_CORE_FULL):
             if v.shape[-1] == q.shape[-1]:
                 o = attend(q, k, v, causal=True, scale=cfg.attention_scale)
             else:

@@ -274,29 +274,29 @@ def _mamba_block(p, x, cfg):
     B, S, M = x.shape
     H, P, G, N = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state
     inner, wide = cfg.ssm_inner, cfg.ssm_conv_width
-    with jax.named_scope(scopes.SSM):
+    with scopes.scope(scopes.SSM):
         h = rmsnorm(x, p["ln1"], cfg.norm_eps)
-        with jax.named_scope(scopes.SSM_PROJ):
+        with scopes.scope(scopes.SSM_PROJ):
             zxbcdt = h @ p["ssm_in"].astype(h.dtype)
         z, xbc, dt = (zxbcdt[..., :inner], zxbcdt[..., inner:inner + wide],
                       zxbcdt[..., inner + wide:])
-        with jax.named_scope(scopes.SSM_CONV):
+        with scopes.scope(scopes.SSM_CONV):
             xbc = jax.nn.silu(_causal_conv(
                 xbc, p["ssm_conv_w"], p["ssm_conv_b"]).astype(h.dtype))
         xs = xbc[..., :inner].reshape(B, S, H, P)
         b = xbc[..., inner:inner + G * N].reshape(B, S, G, N)
         c = xbc[..., inner + G * N:].reshape(B, S, G, N)
-        with jax.named_scope(scopes.SSM_SCAN):
+        with scopes.scope(scopes.SSM_SCAN):
             dt = jax.nn.softplus(dt.astype(jnp.float32)
                                  + p["ssm_dt_bias"].astype(jnp.float32))
             a = -jnp.exp(p["ssm_a_log"].astype(jnp.float32))
             y = ssm_chunked(xs, dt, a, b, c, cfg.ssm_chunk)
             y = y + (p["ssm_d"].astype(jnp.float32)[:, None]
                      * xs.astype(jnp.float32))
-        with jax.named_scope(scopes.SSM_NORM):
+        with scopes.scope(scopes.SSM_NORM):
             y = _gated_norm(y.reshape(B, S, inner), z, p["ssm_norm"], G,
                             cfg.norm_eps).astype(h.dtype)
-        with jax.named_scope(scopes.SSM_PROJ):
+        with scopes.scope(scopes.SSM_PROJ):
             o = y @ p["ssm_out"].astype(h.dtype)
         return x + scaled(o, cfg.residual_scale)
 

@@ -52,13 +52,13 @@ def gate_chain(bcu, taps):
 def _conv_block(p, x, cfg):
     """``x + short_conv(norm(x))``, x ``[B', S', M]`` with the whole
     sequence here (no sp)."""
-    with jax.named_scope(scopes.SHORT_CONV):
+    with scopes.scope(scopes.SHORT_CONV):
         h = rmsnorm(x, p["ln1"], cfg.norm_eps)
-        with jax.named_scope(scopes.SHORT_CONV_PROJ):
+        with scopes.scope(scopes.SHORT_CONV_PROJ):
             bcu = h @ p["conv_in"].astype(h.dtype)
-        with jax.named_scope(scopes.SHORT_CONV_GATE):
+        with scopes.scope(scopes.SHORT_CONV_GATE):
             y = gate_chain(bcu, p["conv_w"]).astype(h.dtype)
-        with jax.named_scope(scopes.SHORT_CONV_PROJ):
+        with scopes.scope(scopes.SHORT_CONV_PROJ):
             o = y @ p["conv_out"].astype(h.dtype)
         return x + scaled(o, cfg.residual_scale)
 

@@ -579,7 +579,7 @@ def _index_inputs(p, h, positions, cfg: TransformerConfig):
     the whole index head on both."""
     B, S, _ = h.shape
     Hi, Di = cfg.index_heads, cfg.index_head_dim
-    with jax.named_scope(scopes.ATTENTION_INDEX):
+    with scopes.scope(scopes.ATTENTION_INDEX):
         h = lax.stop_gradient(h)
         qi = (h @ p["wq_idx"].astype(h.dtype)).reshape(B, S, Hi, Di)
         ki = layernorm(h @ p["wk_idx"].astype(h.dtype), p["k_idx_norm"],
@@ -626,7 +626,7 @@ def _attention_sublayer(p, x, positions, cfg: TransformerConfig,
             "attention_scale on a live sp axis: ring_attention_spmd passes "
             "whole k/v blocks of n_heads heads round the ring, masks by the "
             "diagonal only and scales by 1 / sqrt(head width)")
-    with jax.named_scope(scopes.ATTENTION):
+    with scopes.scope(scopes.ATTENTION):
         h = rmsnorm(x, p["ln1"], cfg.norm_eps)
         q = (h @ p["wq"].astype(h.dtype))
         k = (h @ p["wk"].astype(h.dtype))
@@ -647,7 +647,7 @@ def _attention_sublayer(p, x, positions, cfg: TransformerConfig,
             k = rope(k, positions, table)
         # the core is the call a kernel replaces: its custom_vjp backward
         # is traced under the same scope
-        with jax.named_scope(scopes.ATTENTION_CORE):
+        with scopes.scope(scopes.ATTENTION_CORE):
             if cfg.index_topk:
                 pass    # below: the index's scopes are the core's siblings
             elif _axis_live("sp"):
@@ -659,9 +659,9 @@ def _attention_sublayer(p, x, positions, cfg: TransformerConfig,
                 from horovod_tpu.ops.pallas_attention import attend
                 with (contextlib.nullcontext()
                       if cfg.layer_pattern == (_PLAIN_LAYER,)
-                      else jax.named_scope(scopes.ATTENTION_CORE_FULL
-                                           if window is None else
-                                           scopes.ATTENTION_CORE_WINDOW)):
+                      else scopes.scope(scopes.ATTENTION_CORE_FULL
+                                        if window is None else
+                                        scopes.ATTENTION_CORE_WINDOW)):
                     o = attend(q, k, v, causal=True, window=window,
                                scale=cfg.attention_scale)
         if cfg.index_topk:
@@ -672,7 +672,7 @@ def _attention_sublayer(p, x, positions, cfg: TransformerConfig,
             terms = {"index_loss": index_loss, "selected_keys": selected,
                      "selection": bits}
         if "wg" in p:
-            with jax.named_scope(scopes.ATTENTION_GATE):
+            with scopes.scope(scopes.ATTENTION_GATE):
                 gate = jax.nn.sigmoid(
                     (h @ p["wg"].astype(h.dtype)).astype(jnp.float32))
                 o = o * gate[..., None].astype(o.dtype)
@@ -745,7 +745,7 @@ def _router_logits(p, x):
     pass over the router's weights moves a logit by 0.002: the product is
     float32 in fact ("highest": a TPU multiplies float32 operands as
     bfloat16 otherwise), on a matmul of ``E`` columns."""
-    with jax.named_scope(scopes.MOE), jax.named_scope(scopes.MOE_ROUTER):
+    with scopes.scope(scopes.MOE), scopes.scope(scopes.MOE_ROUTER):
         return jnp.matmul(x.reshape(-1, x.shape[-1]).astype(jnp.float32),
                           p["router"].astype(jnp.float32),
                           precision=lax.Precision.HIGHEST)
@@ -779,7 +779,7 @@ def _moe_ffn(p, x, cfg: TransformerConfig, logits=None):
 
     if logits is None and cfg.moe_router_scores == "sigmoid":
         logits = _router_logits(p, x)
-    with jax.named_scope(scopes.MOE):
+    with scopes.scope(scopes.MOE):
         y, m = moe_layer_spmd(
             toks, p["router"], expert_fn,
             {leaf.name: p[leaf.name] for leaf in _ffn_leaves(cfg)
@@ -809,7 +809,7 @@ def _shared_expert(p, toks, activation):
     ``down(activation(gate(toks)) * up(toks))``, ``ws1`` the gate; dense
     matmuls over all the tokens (inner width over tp, the caller's psum);
     the same on every device that holds a share of the others."""
-    with jax.named_scope(scopes.MOE_SHARED):
+    with scopes.scope(scopes.MOE_SHARED):
         h = activation(toks @ p["ws1"].astype(toks.dtype))
         if "ws3" in p:
             h = h * (toks @ p["ws3"].astype(toks.dtype))
@@ -835,7 +835,7 @@ def _ffn_block(p, x, cfg: TransformerConfig, logits=None, routed=None):
     """``x + ffn(norm(x))``, the FFN dense or the experts (:func:`_routed`);
     ``logits``: a router's that read something else than the normed
     tokens."""
-    with jax.named_scope(scopes.MLP):
+    with scopes.scope(scopes.MLP):
         h = rmsnorm(x, p["ln2"], cfg.norm_eps)
         if _routed(cfg, routed):
             o, aux = _moe_ffn(p, h, cfg, logits)
@@ -1105,11 +1105,11 @@ def forward_loss_spmd(params, tokens, targets, cfg: TransformerConfig):
     sp_idx = lax.axis_index("sp") if _axis_live("sp") else 0
     positions = sp_idx * S + jnp.arange(S)
 
-    with jax.named_scope(scopes.EMBED):
+    with scopes.scope(scopes.EMBED):
         x = _embed_lookup(params["embed"], tokens, cfg)         # [B,S,M]
 
     beside = bool(cfg.lead_pattern or cfg.mtp_depth)
-    with jax.named_scope(scopes.LAYERS):
+    with scopes.scope(scopes.LAYERS):
         if cfg.n_loops > 1:
             x, aux_total = _loop_layers(params["layers"], params["ln_f"], x,
                                         positions, cfg)      # [T,B,S,M]
@@ -1118,7 +1118,7 @@ def forward_loss_spmd(params, tokens, targets, cfg: TransformerConfig):
         else:
             x, aux_total = _run_layers(params["layers"], x, positions, cfg)
 
-    with jax.named_scope(scopes.HEAD):
+    with scopes.scope(scopes.HEAD):
         if cfg.tie_embeddings:
             head = params["embed"].astype(cfg.dtype).T
         else:
@@ -1138,7 +1138,7 @@ def forward_loss_spmd(params, tokens, targets, cfg: TransformerConfig):
     if beside:
         parts = {}
         if cfg.mtp_depth:
-            with jax.named_scope(scopes.MTP):
+            with scopes.scope(scopes.MTP):
                 mtp_loss, mtp_auxs = _mtp_loss(params, state, targets, head,
                                                positions, cfg)
             auxs = _join_aux(cfg, [(cfg.layer_pattern, auxs),
@@ -1146,7 +1146,7 @@ def forward_loss_spmd(params, tokens, targets, cfg: TransformerConfig):
             parts = {"main_loss": loss, "mtp_loss": mtp_loss}
             loss = loss + cfg.mtp_weight * mtp_loss
         aux_total = {**_over_layers(auxs), **parts}
-    with jax.named_scope(scopes.HEAD):
+    with scopes.scope(scopes.HEAD):
         # average over data-like axes so every shard reports the global
         # loss (ep subdivides the batch — see data_sharding_spec)
         for ax in ("dp", "ep", "sp"):
@@ -1163,7 +1163,7 @@ def _mtp_input(params, state, targets, cfg: TransformerConfig):
     (``state``), ``t_{i+1}`` the next token (``targets[i]``), two norms of
     the module's own and the main model's table."""
     mp = params["mtp"]
-    with jax.named_scope(scopes.MTP_PROJ):
+    with scopes.scope(scopes.MTP_PROJ):
         nxt = _embed_lookup(params["embed"], targets, cfg)
         return jnp.concatenate(
             [rmsnorm(state, mp["norm_h"], cfg.norm_eps),
@@ -1186,10 +1186,10 @@ def _mtp_loss(params, state, targets, head, positions, cfg):
     target lies in the sequence."""
     mp = params["mtp"]
     u = _mtp_input(params, state, targets, cfg)
-    with jax.named_scope(scopes.LAYERS):
+    with scopes.scope(scopes.LAYERS):
         z, auxs = _scan_periods(u, mp["layers"], positions, cfg,
                                 functools.partial(_checkpointed, cfg))
-    with jax.named_scope(scopes.HEAD):
+    with scopes.scope(scopes.HEAD):
         z = rmsnorm(z, mp["ln_f"], cfg.norm_eps)
         nll = _head_xent(z, head, _mtp_targets(targets), cfg.logits_scale)
         return jnp.mean(nll[:, :-1]), auxs
@@ -1199,7 +1199,7 @@ def _exit_gate(params, states):
     """The exit gate's logits ``z_t = h_t w + b`` ``[T, B, S]`` of the loop
     steps' normed states ``[T, B, S, M]``, in float32: a multiply and a
     sum, not a matmul the MXU would take in bfloat16."""
-    with jax.named_scope(scopes.LOOP_GATE):
+    with scopes.scope(scopes.LOOP_GATE):
         return (jnp.sum(states.astype(jnp.float32)
                         * params["exit_gate"][:, 0].astype(jnp.float32), -1)
                 + params["exit_gate_bias"].astype(jnp.float32))
@@ -1217,7 +1217,7 @@ def _looped_loss(z, nll):
     what is left (``lambda_T`` is not read), and
     ``mean(sum_t p_t nll_t - beta H(p))``, in log space and the dtype of
     ``z`` (float32). Returns (loss, what the step reports of it)."""
-    with jax.named_scope(scopes.LOOP_GATE):
+    with scopes.scope(scopes.LOOP_GATE):
         # log p_t = log lambda_t + sum_{j<t} log(1 - lambda_j)
         stay = jnp.cumsum(jax.nn.log_sigmoid(-z[:-1]), axis=0)
         stay = jnp.concatenate([jnp.zeros_like(z[:1]), stay])
@@ -1262,7 +1262,7 @@ def _loop_layers(lp, ln_f, x, positions, cfg: TransformerConfig):
         y, auxs = _scan_layers(lp, h, positions, cfg)
         y = rmsnorm(y, ln_f, cfg.norm_eps)
         return y, (y, _over_layers(auxs))
-    with jax.named_scope(scopes.LOOP):
+    with scopes.scope(scopes.LOOP):
         if all(remat(cfg, _checkpointed(cfg, kind))
                for kind in cfg.layer_pattern):
             flat = jax.tree_util.tree_map(
@@ -1614,7 +1614,7 @@ def _grad_sync(grads, pspec):
             if ax not in used:
                 g = _psum_if(g, ax)
         return g
-    with jax.named_scope(scopes.GRAD_SYNC):
+    with scopes.scope(scopes.GRAD_SYNC):
         return jax.tree_util.tree_map(one, grads, pspec)
 
 
@@ -1683,7 +1683,7 @@ def make_train_step(cfg: TransformerConfig, mesh: Mesh, optimizer,
             # XLA:TPU otherwise fuses weight gradients into the update;
             # the kind's row says why this stack does without
             grads = lax.optimization_barrier(grads)
-        with jax.named_scope(scopes.OPTIMIZER):
+        with scopes.scope(scopes.OPTIMIZER):
             updates, opt_state = optimizer.update(grads, opt_state, params)
             params = optax.apply_updates(params, updates)
         return params, opt_state, loss, aux

@@ -171,6 +171,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
+from horovod_tpu.profiling.compile_watch import kernel_call
+
 NEG_INF = -1e30
 
 #: what callers are held to: Sq, Sk and head_dim are multiples of this
@@ -579,7 +581,7 @@ def _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret,
         def k_at(b, i, j):
             return (b // H * Hkv + b % H // group, k_tile(i, j), 0)
 
-    out, lse = pl.pallas_call(
+    out, lse = kernel_call(pl.pallas_call,
         functools.partial(_flash_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, window=window),
         grid=flash_grid(B, H, Sq, Sk, block_q, block_k, window),
@@ -979,7 +981,7 @@ def _flash_bwd_local(q, k, v, do, lse, adj, *, H, causal, scale, blocks,
         part_spec = pl.BlockSpec((1, 1, bk, D),
                                  lambda b, r, j, i: (r, b // H, j, b % H))
         part = jax.ShapeDtypeStruct((grid[1], B, Sk, M), k.dtype)
-    return pl.pallas_call(
+    return kernel_call(pl.pallas_call,
         functools.partial(_flash_bwd_kernel, scale=scale, causal=causal,
                           block_q=bq, block_k=bk, window=window),
         grid=grid,
@@ -1072,7 +1074,7 @@ def _flash_adj_local(do, o, dlse, *, H, interpret):
     wide = pl.BlockSpec((1, rows, heads * D), lambda b, i, g: (b, i, g))
     row = pl.BlockSpec((heads, 1, rows),
                        lambda b, i, g: (b * groups + g, 0, i))
-    return pl.pallas_call(
+    return kernel_call(pl.pallas_call,
         functools.partial(_flash_adj_kernel, D=D),
         grid=(B, Sq // rows, groups),
         in_specs=[wide, wide, row],
@@ -1460,7 +1462,7 @@ def _block_call(kernel, name, io, q, k, statics: _Statics):
              "lse": jax.ShapeDtypeStruct((B, M // LANES, heads, Sq),
                                          jnp.float32)}
     ins, outs = io
-    return pl.pallas_call(
+    return kernel_call(pl.pallas_call,
         functools.partial(kernel, scale=statics.scale, D=statics.D,
                           masked=statics.masked),
         grid=(B // rows, M // LANES),

@@ -81,6 +81,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from horovod_tpu.profiling.compile_watch import kernel_call
+
 LANES = 128
 FWD_NAME = "hvd_delta_scan"
 BWD_NAME = "hvd_delta_scan_bwd"
@@ -501,7 +503,7 @@ def _forward(q, k, v, g, beta, chunk, sub, interpret, head_tile, save: bool):
     out_specs = [vwide, last, at_start]
     if not save:
         shapes, out_specs = shapes[:2], out_specs[:2]
-    out = pl.pallas_call(
+    out = kernel_call(pl.pallas_call,
         functools.partial(_fwd_kernel, R=lay.R, D=lay.D, Dv=lay.Dv,
                           sub=lay.sub),
         grid=(lay.B, lay.steps, lay.n),
@@ -668,7 +670,7 @@ def _backward(q, k, v, g, beta, states, do, chunk, sub, interpret, head_tile):
     lay = _layout(q, v, chunk, sub, head_tile)
     wide, vwide, col, at_start, _ = _specs(lay, lambda j: lay.n - 1 - j)
     C, D = lay.chunk, lay.D
-    dq, dk, dv, dg, dbeta = pl.pallas_call(
+    dq, dk, dv, dg, dbeta = kernel_call(pl.pallas_call,
         functools.partial(_bwd_kernel, R=lay.R, D=D, Dv=lay.Dv, sub=lay.sub),
         grid=(lay.B, lay.steps, lay.n),
         in_specs=[wide, wide, vwide, wide, col, vwide, at_start],

@@ -69,6 +69,7 @@ from jax.experimental.pallas import tpu as pltpu
 from horovod_tpu.ops.pallas_attention import (
     BWD_AHEAD, BWD_VMEM_BUDGET, MIN_BLOCK, NEG_INF, TILES, _NN, _NT, _TN,
     _dot, _flash_adj_local, _group)
+from horovod_tpu.profiling.compile_watch import kernel_call
 
 FWD_NAME = "hvd_sparse_fwd"
 MEAN_NAME = "hvd_sparse_mean"
@@ -246,7 +247,7 @@ def sparse_forward(q, k, v, mask, t0, *, scale: float, head_dim: int,
     def k_at(h, j, t0_ref):
         return (jnp.minimum(j, _last_tile(t0_ref[0], bk)), h)
     wide = pl.BlockSpec((ROWS, group * D), lambda h, j, t0_ref: (0, h))
-    return pl.pallas_call(
+    return kernel_call(pl.pallas_call,
         functools.partial(_sparse_fwd_kernel, scale=scale, block_k=bk,
                           group=group),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -336,7 +337,7 @@ def heads_mean(q, k, lse, mask, t0, *, scale: float, head_dim: int,
 
     def tile(j, t0_ref):
         return jnp.minimum(j, _last_tile(t0_ref[0], bk))
-    return pl.pallas_call(
+    return kernel_call(pl.pallas_call,
         functools.partial(_sparse_mean_kernel, scale=scale, block_k=bk,
                           group=group, heads=H),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -491,7 +492,7 @@ def _sparse_bwd_local(q, k, v, do, lse, adj, mask, *, H, scale, kern):
                        lambda h, r, j, i: (h, 0, q_block(r, j, i)))
     part_spec = pl.BlockSpec((1, bk, D), lambda h, r, j, i: (r, j, h))
     part = jax.ShapeDtypeStruct((S // rows,) + k.shape, k.dtype)
-    return pl.pallas_call(
+    return kernel_call(pl.pallas_call,
         functools.partial(_sparse_bwd_kernel, scale=scale, block_k=bk,
                           group=group),
         grid=(Hkv, S // rows, S // bk, nq),

@@ -71,6 +71,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from horovod_tpu.profiling.compile_watch import kernel_call
+
 LANES = 128
 FWD_NAME = "hvd_ssm_scan"
 BWD_NAME = "hvd_ssm_scan_bwd"
@@ -413,7 +415,7 @@ def _forward(x, dt, s, b, c, chunk, interpret, head_tile, save: bool):
               jax.ShapeDtypeStruct(
                   (lay.B, lay.n, lay.steps, lay.N, lay.R * lay.P),
                   jnp.float32)]
-    out = pl.pallas_call(
+    out = kernel_call(pl.pallas_call,
         functools.partial(_fwd_kernel, tiles=lay.tiles),
         grid=(lay.B, lay.steps, lay.n),
         in_specs=[wide, narrow, narrow, col, col, row, row],
@@ -515,7 +517,7 @@ def _backward(x, dt, s, b, c, states, dy, chunk, interpret, head_tile):
                                      jnp.float32)
     row_shape = jax.ShapeDtypeStruct((lay.B, lay.steps, lay.R, lay.S),
                                      jnp.float32)
-    dx, db, dc, ddt_col, ds_col, ds_row = pl.pallas_call(
+    dx, db, dc, ddt_col, ds_col, ds_row = kernel_call(pl.pallas_call,
         functools.partial(_bwd_kernel, tiles=lay.tiles),
         grid=(lay.B, lay.steps, lay.n),
         in_specs=[wide, narrow, narrow, col, col, row, wide, at_start],

@@ -48,6 +48,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from horovod_tpu.profiling.compile_watch import kernel_call
+
 NEG_INF = -1e30
 
 LANES = 128
@@ -203,7 +205,7 @@ def _xent_call(logits, labels, blocks: Tuple[int, int], with_grad: bool,
     need = xent_vmem_bytes(bn, v, logits.dtype.itemsize)
     rows = pl.BlockSpec((bn, 1), lambda i: (i, 0))
     whole = pl.BlockSpec((bn, v), lambda i: (i, 0))
-    out = pl.pallas_call(
+    out = kernel_call(pl.pallas_call,
         functools.partial(_xent_kernel, chunk=chunk, piece=piece),
         grid=(n // bn,),
         in_specs=[rows, whole],

@@ -178,16 +178,16 @@ def _block(topk: int, scale: float, total: int, x, consts):
     keys, kv_heads, _ = k.shape
     dtype = q.dtype
     t = t0 + jnp.arange(rows, dtype=jnp.int32)
-    with jax.named_scope(scopes.ATTENTION_INDEX_SCORES):
+    with scopes.scope(scopes.ATTENTION_INDEX_SCORES):
         scores = index_scores(qi, w, ki)
-    with jax.named_scope(scopes.ATTENTION_INDEX_SELECT):
+    with scopes.scope(scopes.ATTENTION_INDEX_SELECT):
         # kept as bits (a byte for eight keys), read back as the mask
         bits = checkpoint_name(jnp.packbits(
             select(lax.stop_gradient(scores), t, topk), axis=1), SELECTION)
         chosen = jnp.unpackbits(bits, axis=1).astype(bool)
         bits = jnp.pad(bits, ((0, 0), (0, total // 8 - bits.shape[1])))
-    with jax.named_scope(scopes.ATTENTION_CORE), \
-            jax.named_scope(scopes.ATTENTION_CORE_SPARSE):
+    with scopes.scope(scopes.ATTENTION_CORE), \
+            scopes.scope(scopes.ATTENTION_CORE_SPARSE):
         s = jnp.einsum(
             "rhgd,khd->hgrk", q.reshape(rows, kv_heads, -1, d),
             k.astype(dtype), preferred_element_type=jnp.float32) * scale
@@ -198,7 +198,7 @@ def _block(topk: int, scale: float, total: int, x, consts):
         o = jnp.einsum("hgrk,khd->rhgd", e.astype(dtype), v.astype(dtype),
                        preferred_element_type=jnp.float32
                        ) * share.transpose(2, 0, 1)[..., None]
-    with jax.named_scope(scopes.ATTENTION_INDEX_LOSS):
+    with scopes.scope(scopes.ATTENTION_INDEX_LOSS):
         target = lax.stop_gradient(
             jnp.sum(e * share[..., None], axis=(0, 1)) / heads)
         kl = _index_loss(target, scores, chosen)
@@ -267,9 +267,9 @@ def _kernel_block(topk: int, scale: float, seq: int, head_dim: int,
     rows = q.shape[0]
     keys = ki.shape[0]
     t = t0 + jnp.arange(rows, dtype=jnp.int32)
-    with jax.named_scope(scopes.ATTENTION_INDEX_SCORES):
+    with scopes.scope(scopes.ATTENTION_INDEX_SCORES):
         scores = index_scores(qi, w, ki)
-    with jax.named_scope(scopes.ATTENTION_INDEX_SELECT):
+    with scopes.scope(scopes.ATTENTION_INDEX_SELECT):
         picked = select(lax.stop_gradient(scores), t, topk)
         # kept as the kernels' mask (a byte for eight keys at a tile of
         # 1024), read back for the loss
@@ -280,12 +280,12 @@ def _kernel_block(topk: int, scale: float, seq: int, head_dim: int,
         called = jnp.pad(mask, ((0, tiles - mask.shape[0]), (0, 0), (0, 0)))
         bits = jnp.packbits(picked, axis=1)
         bits = jnp.pad(bits, ((0, 0), (0, seq // 8 - bits.shape[1])))
-    with jax.named_scope(scopes.ATTENTION_CORE), \
-            jax.named_scope(scopes.ATTENTION_CORE_SPARSE):
+    with scopes.scope(scopes.ATTENTION_CORE), \
+            scopes.scope(scopes.ATTENTION_CORE_SPARSE):
         o, lse = ps.sparse_forward(q, k, v, called, t0, scale=scale,
                                    head_dim=head_dim, kern=kern)
         lse = checkpoint_name(lse, STATS)
-    with jax.named_scope(scopes.ATTENTION_INDEX_LOSS):
+    with scopes.scope(scopes.ATTENTION_INDEX_LOSS):
         target = ps.heads_mean(q, k, lse, called, t0, scale=scale,
                                head_dim=head_dim, kern=kern)[:, :keys]
         kl = _index_loss(target, scores, chosen)
@@ -307,8 +307,8 @@ def _attached_fwd(q, k, v, o, lse, mask, scale, kern):
 
 def _attached_bwd(scale, kern, res, do):
     q, k, v, o, lse, mask = res
-    with jax.named_scope(scopes.ATTENTION_CORE), \
-            jax.named_scope(scopes.ATTENTION_CORE_SPARSE):
+    with scopes.scope(scopes.ATTENTION_CORE), \
+            scopes.scope(scopes.ATTENTION_CORE_SPARSE):
         dq, dk, dv = ps.sparse_backward(q, k, v, o, lse, mask, do, scale,
                                         kern)
     return dq, dk, dv, None, None, None

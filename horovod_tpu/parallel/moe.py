@@ -63,6 +63,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from horovod_tpu._compat import axis_size, shard_map
 from horovod_tpu.profiling import scopes
+from horovod_tpu.profiling.compile_watch import kernel_trace
 
 
 class MoEMetrics(NamedTuple):
@@ -275,7 +276,7 @@ def _gmm(rows, weights, group_sizes, tiles, interpret):
         return _zero_beyond(_ragged_dot(rows, weights, group_sizes),
                             group_sizes)
     gmm, _tgmm = _megablox()
-    with jax.named_scope(GMM_NAME):
+    with jax.named_scope(GMM_NAME), kernel_trace(GMM_NAME):
         return gmm(rows, _as_read(weights, tiles, rows.dtype), group_sizes,
                    rows.dtype, tiles.forward, transpose_rhs=tiles.transposed,
                    interpret=interpret)
@@ -298,7 +299,7 @@ def _gmm_d_rows(tiles, interpret, rows, weights, group_sizes, g):
                 _zero_beyond(g, group_sizes))
         return _zero_beyond(d_rows, group_sizes)
     gmm, _ = _megablox()
-    with jax.named_scope(GMM_NAME):
+    with jax.named_scope(GMM_NAME), kernel_trace(GMM_NAME):
         return gmm(g, _as_read(weights, tiles, rows.dtype), group_sizes,
                    rows.dtype, tiles.input_grad,
                    transpose_rhs=not tiles.transposed, interpret=interpret)
@@ -312,7 +313,7 @@ def _gmm_d_weights(tiles, interpret, rows, weights, group_sizes, g):
                 _zero_beyond(g, group_sizes))
         return d_weights
     _, tgmm = _megablox()
-    with jax.named_scope(GMM_NAME):
+    with jax.named_scope(GMM_NAME), kernel_trace(GMM_NAME):
         # [E, K, F] is rows^T g; read as stored it is [E, F, K], g^T rows
         # swapped back: the array the update reads beside the weights
         # and their moments, in their layout
@@ -524,7 +525,7 @@ def _ffn_held_bwd(activation, tiles, interpret, res, g):
         d_hs = jax.vjp(functools.partial(_hidden, activation),
                        *(h.astype(jnp.float32) for h in hs))[1](
                            d_hidden.astype(jnp.float32))
-        with jax.named_scope(scopes.RECOMPUTE):     # as the forward made them
+        with scopes.scope(scopes.RECOMPUTE):     # as the forward made them
             return (*d_hs, _hidden(activation, *hs))
     # each written over what it was made from, which nothing reads after:
     # the cotangents over ``hs``, the hidden rows over their own cotangent
@@ -771,7 +772,7 @@ def moe_layer_spmd(x: jax.Array, router_w: jax.Array,
         raise ValueError(f"expert_params hold {sorted(held)} experts, the "
                          f"layout {e_local} of {E}")
 
-    with jax.named_scope(scopes.MOE_ROUTER):
+    with scopes.scope(scopes.MOE_ROUTER):
         if logits is None:
             logits = x.astype(jnp.float32) @ router_w.astype(jnp.float32)
         # (the plain router by its three arguments, as every caller of
@@ -781,7 +782,7 @@ def moe_layer_spmd(x: jax.Array, router_w: jax.Array,
                                   "scale": scale}
         probs, weights, experts = route(logits, k, renormalize, **other)
 
-    with jax.named_scope(scopes.MOE_DISPATCH):
+    with scopes.scope(scopes.MOE_DISPATCH):
         if n > 1:
             # the ep group's tokens, and which experts each chose
             x_all = lax.all_gather(x, axis_name, axis=0, tiled=True)
@@ -802,9 +803,9 @@ def moe_layer_spmd(x: jax.Array, router_w: jax.Array,
         held = rows_held(group_sizes, E)
 
     def gathered(x_all, expert_params):
-        with jax.named_scope(scopes.MOE_DISPATCH):
+        with scopes.scope(scopes.MOE_DISPATCH):
             rows = _dispatch(x_all, order, inverse, held, k)
-        with jax.named_scope(scopes.MOE_EXPERTS):
+        with scopes.scope(scopes.MOE_EXPERTS):
             return expert_fn(expert_params, rows, group_sizes)
     if of > 1:
         # of a share's G * k gathered rows all but 1 / of lie behind the
@@ -814,7 +815,7 @@ def moe_layer_spmd(x: jax.Array, router_w: jax.Array,
         gathered = jax.checkpoint(gathered, policy=_all_but_gathers)
     rows = gathered(x_all, expert_params)
 
-    with jax.named_scope(scopes.MOE_COMBINE):
+    with scopes.scope(scopes.MOE_COMBINE):
         if n > 1:
             weights_all = lax.all_gather(weights, axis_name, axis=0,
                                          tiled=True)
@@ -824,7 +825,7 @@ def moe_layer_spmd(x: jax.Array, router_w: jax.Array,
         y = _combine(rows, weights_all, order, inverse, held,
                      axis_name if n > 1 else None, x.dtype)
 
-    with jax.named_scope(scopes.MOE_ROUTER):
+    with scopes.scope(scopes.MOE_ROUTER):
         def total(v):
             return lax.psum(v, tuple(stat_axes)) if stat_axes else v
         tokens = total(jnp.float32(G))

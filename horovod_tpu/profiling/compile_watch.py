@@ -39,9 +39,28 @@ Sources:
   compile too quick or too small to be kept is neither). Each timed
   event is also a ``scopes.HOST_COMPILE`` record of the host log
   (:mod:`.host_log`): *when* it happened, not only for how long;
+* the same events' ``fun_name`` (JAX passes it to the listener for a trace,
+  ``step``, and for a lowering and a backend compile, ``jit(step)``): one
+  name, ``step``, in every record and in :func:`by_function`, which keeps
+  each function's count and seconds of all four kinds. A cache read
+  carries no name and is given to the backend compile it lies inside;
+* **nesting.** JAX times every jitted function traced *inside* another (an
+  inner ``jax.jit``, every ``jax.numpy`` function), so ``trace_seconds``
+  counts an inner trace again in each trace around it. A trace that ends
+  while another is open (``jax._src.core.trace_state_clean()`` is false)
+  is *nested*: seconds of its function, apart from its top-level ones, and
+  no record of the host log (``host_log.py`` says when it is one all the
+  same). ``trace_lower_cover_seconds`` is the union of the top-level
+  traces' and the lowerings' intervals on their thread, kept as the events
+  end: one that lies inside a later one (a trace that a lowering made)
+  turns nested after the fact, which is also all that is left should JAX
+  move that private name;
+* :func:`kernel_trace`, around every Pallas call site: the kernel's body is
+  traced where the call is bound, a ``scopes.HOST_TRACE`` span and
+  ``kernel_traces`` / ``kernel_trace_seconds`` of :func:`totals`;
 * the ``jax_log_compiles`` log line ("Compiling jit(<name>) with global
-  shapes...") names the function being compiled — jax's monitoring
-  events carry no name, so the log record is the attribution channel.
+  shapes...") counts a function's tracing-cache misses for the storm
+  detector, and names a compile where an event came without a name.
   When this module enabled the flag itself it also stops those records
   propagating to the root logger (they become metrics, not stderr
   noise); a user who pre-enabled the flag keeps their output.
@@ -53,6 +72,8 @@ only the per-function attribution goes to ``unknown``.
 
 from __future__ import annotations
 
+import collections
+import contextlib
 import logging
 import re
 import threading
@@ -120,8 +141,31 @@ _label_set: set = set()
 _ZERO_TOTALS = {"compiles": 0, "cache_misses": 0, "seconds_total": 0.0,
                 "trace_seconds": 0.0, "lower_seconds": 0.0,
                 "cache_read_seconds": 0.0, "persistent_cache_hits": 0,
-                "persistent_cache_misses": 0}
+                "persistent_cache_misses": 0,
+                "trace_lower_cover_seconds": 0.0, "kernel_traces": 0,
+                "kernel_trace_seconds": 0.0}
 _totals = dict(_ZERO_TOTALS)
+
+# by_function(): name -> counts and seconds a kind, at most
+# MAX_FUNCTION_LABELS names and ``OTHER``
+OTHER = "other"
+_KINDS = ("trace", "nested_trace", "lower", "backend_compile", "cache_read")
+_ZERO_FUNCTION = {k: v for kind in _KINDS
+                  for k, v in ((kind + "s", 0), (kind + "_seconds", 0.0))}
+_functions: Dict[str, dict] = {}
+# thread -> its top-level traces and lowerings that a later event may yet
+# turn out to lie around: (start, end, function, kind, its seconds, those of
+# them that the cover counts)
+_open: Dict[int, collections.deque] = {}
+OPEN_INTERVALS = 1024
+SLACK_S = 50e-6     # what two clocks read a few lines apart may differ by
+_WRAPPED_RE = re.compile(r"^\w+\((.+)\)$")     # jit(step), pmap(step)
+
+try:
+    from jax._src.core import trace_state_clean as _no_trace_open
+except ImportError:        # every trace then counts as top-level at its end
+    def _no_trace_open() -> bool:
+        return True
 
 
 def _envi(name: str, default: int) -> int:
@@ -176,6 +220,7 @@ def _check_storm(name: str) -> None:
         n = _compiles.get(name, 0) + 1
         if len(_compiles) < 4096 or name in _compiles:
             _compiles[name] = n
+        _function(name)        # by_function() says it, seconds or none yet
         recompiles = n - warmup
         last = _flagged_at.get(name, 0)
         if recompiles <= 0 or recompiles - last < storm:
@@ -191,8 +236,66 @@ def _check_storm(name: str) -> None:
         pass
 
 
-def _on_backend_compile(seconds: float) -> None:
-    name = getattr(_TLS, "last_name", None) or "unknown"
+def _function_name(fun_name) -> Optional[str]:
+    """JAX's ``fun_name`` without the API's wrapper: ``jit(step)`` and
+    ``step`` are one function."""
+    if not fun_name:
+        return None
+    wrapped = _WRAPPED_RE.match(fun_name)
+    return wrapped.group(1) if wrapped else fun_name
+
+
+def _function(name: Optional[str]) -> dict:
+    """``name``'s entry of :func:`by_function` (hold ``_LOCK``). A full table
+    folds the entry with the fewest seconds into ``OTHER``: set-up runs
+    dozens of one-line ``jax.numpy`` programs before the step that matters,
+    and first come, first kept would keep those."""
+    name = name or "unknown"
+    entry = _functions.get(name)
+    if entry is None:
+        if len(_functions) >= MAX_FUNCTION_LABELS + (OTHER in _functions):
+            least = min((k for k in _functions if k != OTHER),
+                        key=lambda k: sum(v for f, v in _functions[k].items()
+                                          if f.endswith("_seconds")))
+            other = _functions.setdefault(OTHER, dict(_ZERO_FUNCTION))
+            for field, value in _functions.pop(least).items():
+                other[field] += value
+        entry = _functions[name] = dict(_ZERO_FUNCTION)
+    return entry
+
+
+def _count(name: Optional[str], kind: str, seconds: float,
+           events: int = 1) -> None:
+    entry = _function(name)
+    entry[kind + "s"] += events
+    entry[kind + "_seconds"] += seconds
+
+
+def _top_level(kind: str, name: Optional[str], start: float,
+               seconds: float) -> None:
+    """A trace or a lowering that ended with no trace open (hold ``_LOCK``):
+    seconds of its function and, less what this thread's cover already
+    holds of it, of the cover. Earlier ones that lie inside it were nested
+    after all."""
+    intervals = _open.setdefault(
+        threading.get_ident(), collections.deque(maxlen=OPEN_INTERVALS))
+    while intervals and intervals[-1][0] >= start - SLACK_S:
+        _s, _e, inner, inner_kind, inside, covered = intervals.pop()
+        if (inner or "unknown") not in _functions:
+            inner = OTHER                   # folded away since
+        _count(inner, inner_kind, -inside, -1)
+        _count(inner, "nested_trace", inside)
+        _totals["trace_lower_cover_seconds"] -= covered
+    end = start + seconds
+    covered = min(seconds, max(0.0, end - intervals[-1][1])) \
+        if intervals else seconds
+    intervals.append((start, end, name, kind, seconds, covered))
+    _count(name, kind, seconds)
+    _totals["trace_lower_cover_seconds"] += covered
+
+
+def _on_backend_compile(seconds: float, name: Optional[str]) -> None:
+    name = name or getattr(_TLS, "last_name", None) or "unknown"
     with _LOCK:
         _totals["compiles"] += 1
         _totals["seconds_total"] += float(seconds)
@@ -241,15 +344,38 @@ def ensure_installed(registry=None) -> bool:
             if not _installed or event not in _DURATION_EVENTS:
                 return
             kind, key = _DURATION_EVENTS[event]
-            host_log.record(
-                scopes.HOST_COMPILE, time.perf_counter() - duration,
-                float(duration),
-                {"event": kind, "function": kw.get("fun_name")})
-            if event == _BACKEND_COMPILE_EVENT:
-                _on_backend_compile(duration)
-            else:
+            duration = float(duration)
+            end = time.perf_counter()
+            start = end - duration
+            name = _function_name(kw.get("fun_name"))
+            meta = {"event": kind, "function": name}
+            if kind == "trace" and not _no_trace_open():
                 with _LOCK:
-                    _totals[key] += float(duration)
+                    _totals[key] += duration
+                    _count(name, "nested_trace", duration)
+                if not host_log.ended_since(scopes.HOST_TRACE, start):
+                    return
+                meta["nested"] = True
+            elif kind in ("trace", "lower"):
+                with _LOCK:
+                    _totals[key] += duration
+                    _top_level(kind, name, start, duration)
+            elif kind == "cache_read":
+                # inside its function's backend compile, which ends later
+                # and says whose it was
+                _TLS.cache_read = (start, duration, meta)
+                with _LOCK:
+                    _totals[key] += duration
+            else:
+                read = getattr(_TLS, "cache_read", None)
+                _TLS.cache_read = None
+                _on_backend_compile(duration, name)
+                with _LOCK:
+                    _count(name, kind, duration)
+                    if read is not None and read[0] >= start - SLACK_S:
+                        read[2]["function"] = name
+                        _count(name, "cache_read", read[1])
+            host_log.record(scopes.HOST_COMPILE, start, duration, meta)
 
         def _event_listener(event: str, **_kw) -> None:
             if _installed and event in _EVENT_TOTALS:
@@ -321,14 +447,61 @@ def totals() -> dict:
     persistent-cache hit hold the read in the compile's place;
     ``cache_misses`` is jit's *tracing*-cache misses. ``trace_seconds``, ``lower_seconds``,
     ``cache_read_seconds``, ``persistent_cache_hits`` and
-    ``persistent_cache_misses`` are the module docstring's split."""
+    ``persistent_cache_misses`` are the module docstring's split;
+    ``trace_seconds`` counts a nested trace once more in every trace around
+    it, ``trace_lower_cover_seconds`` (the top-level traces and the
+    lowerings, as a union) counts every second once. ``kernel_traces`` and
+    ``kernel_trace_seconds``: :func:`kernel_trace`."""
     with _LOCK:
         return dict(_totals)
 
 
-def per_function_compiles() -> Dict[str, int]:
+def by_function() -> Dict[str, dict]:
+    """``{function: {...}}``, at most ``MAX_FUNCTION_LABELS`` names (those
+    with the most seconds) and ``OTHER``: ``compiles`` (the function's
+    tracing-cache misses, what the storm detector counts) and, for each of
+    ``trace``, ``nested_trace``, ``lower``, ``backend_compile`` and
+    ``cache_read``, ``<kind>s`` and ``<kind>_seconds``. A function's
+    ``trace_seconds`` and ``lower_seconds`` are its top-level ones: over all
+    functions they add up to ``trace_lower_cover_seconds`` (to the clocks'
+    slack); ``nested_trace_seconds`` are its traces inside another
+    function's, which that one's seconds hold already."""
     with _LOCK:
-        return dict(_compiles)
+        table = {name: {"compiles": _compiles.get(name, 0), **entry}
+                 for name, entry in _functions.items()}
+        if OTHER in table:
+            table[OTHER]["compiles"] = sum(_compiles.values()) - sum(
+                e["compiles"] for n, e in table.items() if n != OTHER)
+        return table
+
+
+@contextlib.contextmanager
+def kernel_trace(name: str):
+    """Around a Pallas call site, ``name`` the kernel's instruction name: the
+    kernel's body is traced when the call is bound, so this is what one
+    kernel shape costs a program's trace. One span of the host log,
+    ``scopes.HOST_TRACE`` + ``/kernel/<name>``, and one more of
+    ``kernel_traces``, its seconds onto ``kernel_trace_seconds``."""
+    t0 = time.perf_counter()
+    try:
+        with host_log.Span(f"{scopes.HOST_TRACE}/kernel/{name}"):
+            yield
+    finally:
+        if _installed:
+            with _LOCK:
+                _totals["kernel_traces"] += 1
+                _totals["kernel_trace_seconds"] += time.perf_counter() - t0
+
+
+def kernel_call(pallas_call, *args, name: str, **kwargs):
+    """``pallas_call(*args, name=name, **kwargs)`` whose call on its operands
+    runs inside :func:`kernel_trace`: a call site changes one word."""
+    call = pallas_call(*args, name=name, **kwargs)
+
+    def bound(*operands):
+        with kernel_trace(name):
+            return call(*operands)
+    return bound
 
 
 def reset_counts() -> None:
@@ -344,4 +517,6 @@ def reset_counts() -> None:
         _compiles.clear()
         _flagged_at.clear()
         _label_set.clear()
+        _functions.clear()
+        _open.clear()
         _totals.update(_ZERO_TOTALS)

@@ -2,24 +2,34 @@
 
 One bounded ring of ``(name, start, duration, meta)`` records on
 ``time.perf_counter()``, always on (as :mod:`.compile_watch` is), with
-three writers and no other:
+four writers and no other:
 
 * :class:`Span`, what :func:`horovod_tpu.profiling.annotate` returns: a
   ``jax.profiler.TraceAnnotation`` (so the span lies on the device
   planes' clock while a profiler session is open) that also leaves a
   record here, session or none. ``data_loader.put_next`` opens
   ``scopes.INPUT_SOURCE`` and ``scopes.INPUT_PLACE`` once a batch, so those
-  two are the program's own step clock in an untraced run;
+  two are the program's own step clock in an untraced run; ``hvd.init()``
+  is one ``scopes.HOST_INIT``; ``scopes.scope`` (a device phase) and
+  ``compile_watch.kernel_trace`` (a Pallas call site) open
+  ``scopes.HOST_TRACE`` spans, which a function leaves while it is traced
+  and never once it is an executable;
 * the ``gc.callbacks`` entry of :func:`install_gc_callback` (installed by
   ``hvd.init()``, removed by ``hvd.shutdown()``): every garbage
   collection is a ``scopes.HOST_GC`` record, ``meta`` its ``generation``
   and ``collected``; generations 1 and 2 are ``TraceAnnotation`` s too,
   on the trace's clock;
-* :mod:`.compile_watch`'s duration listener: every trace, lowering,
-  backend compile and persistent-cache read JAX times is a
+* :mod:`.compile_watch`'s duration listener: every lowering, backend
+  compile, persistent-cache read and top-level trace JAX times is a
   ``scopes.HOST_COMPILE`` record (``meta``: ``event``, ``function``), written
   at its end with ``start = now - duration``; a record only, because a
-  ``TraceAnnotation`` cannot be written after the fact.
+  ``TraceAnnotation`` cannot be written after the fact. A trace *inside*
+  another (``jax.numpy``'s own jitted functions, thousands a step) is
+  seconds of its function in ``compile_watch.by_function()`` and no record,
+  unless a ``scopes.HOST_TRACE`` span ended inside it (:func:`ended_since`:
+  a jitted call site of this program's; ``meta["nested"]`` is then true);
+* ``horovod_tpu/__init__.py``: one ``scopes.HOST_IMPORT`` record, the
+  package's import from its first line to its last.
 
 :func:`records` reads the ring back. Names come from :mod:`.scopes`.
 """
@@ -55,6 +65,22 @@ def record(name: str, start: float, duration: float,
 def records() -> List[Record]:
     """The ring's records in the order they ended."""
     return list(_RING)
+
+
+def ended_since(prefix: str, start: float) -> bool:
+    """Whether a record named ``prefix...`` ended at or after ``start``.
+    Looks back from the newest record only as far as records that ended
+    after ``start`` (records lie in the order they ended)."""
+    for back in range(1, len(_RING) + 1):
+        try:
+            name, t0, duration, _meta = _RING[-back]
+        except IndexError:      # another thread's append moved the ring
+            return False
+        if t0 + duration < start:
+            return False
+        if name.startswith(prefix):
+            return True
+    return False
 
 
 def clear() -> None:

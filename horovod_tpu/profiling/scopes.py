@@ -1,9 +1,10 @@
 """The one vocabulary of phase names: what the train step's parts are
 called inside the compiled program and on the profiler's host plane.
 
-Device phases are put with ``jax.named_scope`` where the work happens
-(``models/bert.py``, ``models/transformer.py``). A scope is metadata:
-it adds a path component to the ``op_name`` of every HLO instruction
+Device phases are put with :func:`scope` (``jax.named_scope`` and, for
+what the phase's Python costs while a step is traced, a host span) where
+the work happens (``models/bert.py``, ``models/transformer.py``). A scope is
+metadata: it adds a path component to the ``op_name`` of every HLO instruction
 traced under it and changes no jaxpr and no compiled code. (Nor the
 persistent cache's key, which leaves metadata out, except where the
 program holds a Pallas kernel, whose serialised body carries the name
@@ -19,7 +20,8 @@ direction from them.
 
 Host spans go through :func:`horovod_tpu.profiling.annotate`, which also
 keeps them in the host log (``profiling/host_log.py``) outside a profiler
-session.
+session; :func:`scope` and ``compile_watch.kernel_trace`` open the same
+kind of span.
 
 **Owner and reason.** A reader that gives every executed device
 instruction to one part of the step
@@ -226,5 +228,38 @@ HOST_GC = "hvd.host.gc"
 #: timed (``profiling/compile_watch.py``'s listener); a record of the host
 #: log only, written when it ends
 HOST_COMPILE = "hvd.host.compile"
+#: the Python of one part of a function while JAX traces it, the part after a
+#: slash: a device phase (:func:`scope`: ``hvd.host.trace/hvd.moe.experts``)
+#: or a Pallas call site with its kernel's body
+#: (``compile_watch.kernel_trace``: ``hvd.host.trace/kernel/hvd_flash_bwd``)
+HOST_TRACE = "hvd.host.trace"
+#: the import of this package, from ``horovod_tpu/__init__.py``'s first line
+#: to its last; a record only, written once
+HOST_IMPORT = "hvd.host.import"
+#: one ``hvd.init()``; its backend's creation is ``hvd.host.init/backend``
+HOST_INIT = "hvd.host.init"
 
-HOST_SPANS = (INPUT_SOURCE, INPUT_PLACE, HOST_GC, HOST_COMPILE)
+HOST_SPANS = (INPUT_SOURCE, INPUT_PLACE, HOST_GC, HOST_COMPILE, HOST_TRACE,
+              HOST_IMPORT, HOST_INIT)
+
+
+# -- the one door for a device phase ------------------------------------------
+import contextlib                                       # noqa: E402
+
+import jax                                              # noqa: E402
+
+# host_log reads this module's names when a span ends, never at import
+from horovod_tpu.profiling import host_log              # noqa: E402
+
+
+@contextlib.contextmanager
+def scope(name: str):
+    """``with scopes.scope(scopes.MLP):`` is ``jax.named_scope`` (the jaxpr
+    and every ``op_name`` as before) inside one :class:`host_log.Span` named
+    :data:`HOST_TRACE` ``/`` the phase: what the block's Python cost. That
+    Python runs while a function is traced and not when its executable runs,
+    so a steady step leaves none; a retrace inside a profiler session lies on
+    the device planes' clock. (Called op by op, outside any ``jit``, the span
+    is the block's eager run.)"""
+    with host_log.Span(f"{HOST_TRACE}/{name}"), jax.named_scope(name):
+        yield

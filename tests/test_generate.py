@@ -306,7 +306,8 @@ def test_decode_step_compiles_exactly_once_under_churn():
                        max_new=1 + i % 6)
             for i in range(12)]
     _run_to_done(eng, reqs)
-    counts = compile_watch.per_function_compiles()
+    counts = {name: entry["compiles"]
+              for name, entry in compile_watch.by_function().items()}
     assert counts.get("gen_decode_step", 0) == 1, counts
     assert counts.get("gen_prefill_chunk", 0) == 1, counts
 

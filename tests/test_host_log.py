@@ -3,8 +3,12 @@ record with no profiler session open, the ring is bounded, a garbage
 collection is an ``hvd.host.gc`` span with its generation,
 ``hvd.init()`` / ``hvd.shutdown()`` install and remove one callback, a
 compile's events are ``hvd.host.compile`` records that add up to
-``compile_watch.totals()``, the input path leaves one pair of records a
-batch, and nothing a callback meets can break the ring or the collector."""
+``compile_watch.totals()``, a trace inside another counts once in the cover
+and is its function's in ``by_function()``, a scope and a kernel call site
+leave ``hvd.host.trace`` spans while a function is traced and none after,
+the package's import and ``hvd.init()`` leave theirs, the input path leaves
+one pair of records a batch, and nothing a callback meets can break the ring
+or the collector."""
 
 import gc
 import glob
@@ -234,8 +238,15 @@ def test_a_first_call_leaves_compile_records_that_add_up_to_the_totals():
     assert {r[3]["event"] for r in got} >= {"trace", "lower",
                                             "backend_compile"}
     for kind, key in KIND_OF_TOTAL.items():
+        if kind == "trace":     # jnp.tanh's own trace, inside logged_once's,
+            continue            # is seconds of the total and no record
         assert sum(r[2] for r in got if r[3]["event"] == kind) == \
             pytest.approx(after[key] - before[key], abs=1e-9), kind
+    traced = sum(r[2] for r in got if r[3]["event"] == "trace")
+    assert 0 < traced < after["trace_seconds"] - before["trace_seconds"]
+    assert traced + sum(r[2] for r in got if r[3]["event"] == "lower") == \
+        pytest.approx(after["trace_lower_cover_seconds"]
+                      - before["trace_lower_cover_seconds"], abs=1e-4)
     # each lies where it happened: it began after the call did, it ended
     # before the call returned
     now = time.perf_counter()
@@ -272,6 +283,170 @@ def test_each_of_jaxs_timed_compile_events_is_a_record_and_no_other():
     compile_watch.reset_counts()
 
 
+# -- set-up's seconds: once, by function, inside a trace ------------------------
+
+def _slow_pair(seconds=0.05):
+    """An outer jitted function over an inner one, Python time in both."""
+    @jax.jit
+    def inner_of_the_pair(x):
+        time.sleep(seconds)
+        return jnp.sin(x)
+
+    @jax.jit
+    def outer_of_the_pair(x):
+        time.sleep(seconds)
+        return inner_of_the_pair(x) + 1.0
+    return outer_of_the_pair
+
+
+def test_a_nested_trace_counts_once_in_the_cover_and_twice_in_the_sum():
+    compile_watch.ensure_installed()
+    outer = _slow_pair()
+    x = jnp.ones(3)                    # (made before: its own little programs)
+    compile_watch.reset_counts()
+    since = time.perf_counter()
+    outer.lower(x)
+    wall = time.perf_counter() - since
+    totals, table = compile_watch.totals(), compile_watch.by_function()
+    # the inner trace's 0.05 s: in its own event and in the outer's
+    assert totals["trace_seconds"] >= 0.15
+    assert 0.1 <= totals["trace_lower_cover_seconds"] <= wall
+    assert totals["trace_lower_cover_seconds"] < \
+        totals["trace_seconds"] + totals["lower_seconds"] - 0.04
+    inner, outer_ = table["inner_of_the_pair"], table["outer_of_the_pair"]
+    assert (inner["traces"], inner["nested_traces"]) == (0, 1)
+    assert inner["nested_trace_seconds"] >= 0.05 > inner["trace_seconds"]
+    assert (outer_["traces"], outer_["nested_traces"]) == (1, 0)
+    assert outer_["trace_seconds"] >= 0.1
+    # every function's top-level seconds add up to the cover
+    assert sum(e["trace_seconds"] + e["lower_seconds"]
+               for e in table.values()) == pytest.approx(
+        totals["trace_lower_cover_seconds"], abs=1e-3)
+    # and the nested ones (jnp.sin's, the inner function's) are no records
+    got = _named(scopes.HOST_COMPILE, since)
+    assert [(r[3]["event"], r[3]["function"]) for r in got] == [
+        ("trace", "outer_of_the_pair"), ("lower", "outer_of_the_pair")]
+    assert not any(r[3].get("nested") for r in got)
+
+
+def test_by_function_joins_a_functions_trace_lowering_compile_and_read():
+    import jax.monitoring
+    compile_watch.ensure_installed()
+    compile_watch.reset_counts()
+    for event, seconds, name in (
+            ("/jax/core/compile/jaxpr_trace_duration", 0.5, "f"),
+            ("/jax/core/compile/jaxpr_to_mlir_module_duration", 0.25,
+             "jit(f)"),
+            ("/jax/compilation_cache/cache_retrieval_time_sec", 1.5, None),
+            ("/jax/core/compile/backend_compile_duration", 2.0, "jit(f)")):
+        kw = {} if name is None else {"fun_name": name}
+        jax.monitoring.record_event_duration_secs(event, seconds, **kw)
+    compile_watch._note_compiling("f")
+    assert compile_watch.by_function() == {"f": {
+        "compiles": 1, "traces": 1, "trace_seconds": 0.5,
+        "nested_traces": 0, "nested_trace_seconds": 0.0,
+        "lowers": 1, "lower_seconds": 0.25,
+        "backend_compiles": 1, "backend_compile_seconds": 2.0,
+        "cache_reads": 1, "cache_read_seconds": 1.5}}
+    # one name in every record, the read given to the compile around it
+    assert [(r[3]["event"], r[3]["function"])
+            for r in _named(scopes.HOST_COMPILE)] == [
+        ("trace", "f"), ("lower", "f"), ("cache_read", "f"),
+        ("backend_compile", "f")]
+    compile_watch.reset_counts()
+    assert compile_watch.by_function() == {}
+
+
+def test_by_function_keeps_the_functions_with_the_most_seconds():
+    import jax.monitoring
+    compile_watch.ensure_installed()
+    compile_watch.reset_counts()
+
+    def traced(name, seconds):
+        jax.monitoring.record_event_duration_secs(
+            "/jax/core/compile/jaxpr_trace_duration", seconds, fun_name=name)
+        time.sleep(seconds * 1.5)      # (apart: no interval in another)
+    traced("the_step", 0.004)
+    for i in range(compile_watch.MAX_FUNCTION_LABELS + 3):
+        traced(f"little_{i}", 0.0005)
+    table = compile_watch.by_function()
+    assert len(table) == compile_watch.MAX_FUNCTION_LABELS + 1
+    assert table["the_step"]["trace_seconds"] == 0.004
+    assert table[compile_watch.OTHER]["traces"] == 3 + 1   # of 32 + 3 + 1
+    assert sum(e["trace_seconds"] for e in table.values()) == \
+        pytest.approx(compile_watch.totals()["trace_lower_cover_seconds"])
+    compile_watch.reset_counts()
+
+
+def test_a_scope_leaves_a_span_when_its_function_is_traced_and_only_then():
+    def spans():
+        return [r for r in host_log.records()
+                if r[0].startswith(scopes.HOST_TRACE)]
+
+    @jax.jit
+    def scoped_once(x):
+        with scopes.scope(scopes.MLP):
+            time.sleep(0.01)
+            return x * 2.0
+    text = scoped_once.lower(jnp.ones(4)).as_text(debug_info=True)
+    assert scopes.MLP in text          # the named scope, as ever
+    (name, _t0, duration, meta), = spans()
+    assert name == scopes.HOST_TRACE + "/" + scopes.MLP and meta is None
+    assert duration >= 0.01
+    scoped_once(jnp.ones(4)).block_until_ready()    # compiles: no new trace
+    scoped_once(jnp.ones(4)).block_until_ready()    # the cached executable
+    assert len(spans()) == 1
+
+
+def test_a_kernel_call_site_is_a_span_and_a_count_and_names_its_caller():
+    from horovod_tpu.ops.pallas_xent import fused_softmax_xent
+    compile_watch.ensure_installed()
+    labels = jnp.zeros((128,), jnp.int32)
+    logits = jnp.ones((128, 256), jnp.float32)
+
+    @jax.jit
+    def a_jitted_call_site(lg):
+        return fused_softmax_xent(lg, labels, interpret=True)
+
+    compile_watch.reset_counts()
+    host_log.clear()
+    jax.jit(lambda lg: a_jitted_call_site(lg) * 2.0).trace(logits)
+    kernel = scopes.HOST_TRACE + "/kernel/hvd_fused_xent"
+    (name, t0, duration, _m), = [r for r in host_log.records()
+                                 if r[0].startswith(scopes.HOST_TRACE)]
+    assert name == kernel
+    totals = compile_watch.totals()
+    assert totals["kernel_traces"] == 1
+    assert totals["kernel_trace_seconds"] >= duration > 0
+    # the nested trace a span of the program's ended in is a record; the
+    # jax.numpy traces around the kernel are not
+    nested = [r for r in _named(scopes.HOST_COMPILE) if r[3].get("nested")]
+    assert [r[3]["function"] for r in nested] == ["a_jitted_call_site"]
+    assert nested[0][1] <= t0 and t0 + duration <= nested[0][1] + nested[0][2]
+    assert compile_watch.by_function()["a_jitted_call_site"][
+        "nested_traces"] == 1
+
+
+def test_the_packages_import_is_one_record_and_an_init_one_span():
+    import importlib
+    before = time.perf_counter()
+    importlib.reload(hvd)              # the file again, first line to last
+    (name, start, duration, meta), = _named(scopes.HOST_IMPORT)
+    assert before <= start <= start + duration <= time.perf_counter()
+    assert duration > 0 and meta is None
+    hvd.shutdown()
+    host_log.clear()
+    try:
+        hvd.init()
+        (_n, t0, seconds, _m), = _named(scopes.HOST_INIT)
+        (_n, b0, backend_s, _m), = _named(scopes.HOST_INIT + "/backend")
+        assert t0 <= b0 and b0 + backend_s <= t0 + seconds
+        hvd.init()                     # initialised already: nothing to time
+        assert len(_named(scopes.HOST_INIT)) == 1
+    finally:
+        hvd.shutdown()
+
+
 # -- the input path: the program's own step clock ------------------------------
 
 def test_device_prefetch_leaves_a_pair_a_batch_and_its_buffers():
@@ -292,9 +467,10 @@ def test_device_prefetch_leaves_a_pair_a_batch_and_its_buffers():
     assert len(spans()) == 2 * (6 + 2)
 
 
-def test_one_ring_and_three_writers():
-    """No second recorder: the ring is appended to in host_log.py alone,
-    and host_log.record has one caller, compile_watch's listener."""
+def test_one_ring_and_four_writers():
+    """No second recorder: the ring is appended to in host_log.py alone
+    (spans, collections), and host_log.record has two callers,
+    compile_watch's listener and the package's import."""
     package = os.path.dirname(os.path.abspath(profiling.__file__))
     root = os.path.dirname(package)
     deques, record_calls = [], []
@@ -311,4 +487,5 @@ def test_one_ring_and_three_writers():
             if "host_log.record(" in text:
                 record_calls.append(rel)
     assert deques == [os.path.join("profiling", "host_log.py")]
-    assert record_calls == [os.path.join("profiling", "compile_watch.py")]
+    assert sorted(record_calls) == [
+        "__init__.py", os.path.join("profiling", "compile_watch.py")]

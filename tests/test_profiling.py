@@ -353,10 +353,10 @@ def test_init_resets_storm_counts_per_generation(hvd):
     compile_watch.reset_counts()
     for _ in range(4):
         compile_watch._note_compiling("train_step")
-    assert compile_watch.per_function_compiles()["train_step"] == 4
+    assert compile_watch.by_function()["train_step"]["compiles"] == 4
     hvd.shutdown()
     hvd.init()
-    assert compile_watch.per_function_compiles().get("train_step") is None
+    assert compile_watch.by_function().get("train_step") is None
 
 
 def test_label_budget_resets_with_counts():
