@@ -47,7 +47,10 @@ Phases (default, one chip):
            hvd_sparse_bwd) at keye-vl-2.0-30b-a3b.s16384's shape whole, a
            seeded selection of 2048 keys a query, against the jax.numpy
            sums a block of 128 queries at a time (sparse_path names the
-           form the program takes there); then two
+           form the program takes there), and the index score pass's
+           backward (hvd_index_bwd) there, 16 index heads of 64 under the
+           selection of their own scores and a seeded target, against
+           autodiff of the jax.numpy expression at "highest"; then two
            steps of the flagship transformer at
            head_dim 128 with the four kernels asserted in the compiled
            program. xent_path says how the LM loss ran (the
@@ -167,7 +170,8 @@ class Sizes:
     ssm: tuple            # Mamba-2 scan check (S, heads, head width, groups,
     #                       state, chunk)
     dense_ssm: tuple      # the same at ONE group in head tiles
-    sparse: tuple         # sparse core check (S, H, k/v heads, D, topk, k tile)
+    sparse: tuple         # sparse core check (S, H, k/v heads, D, topk, k
+    #                       tile, index heads, their width)
     delta: tuple          # delta-rule scan check (S, heads, head width,
     #                       value width, chunk)
     narrow: tuple         # flash check at a head of 64 with grouped heads
@@ -215,8 +219,9 @@ REAL = Sizes(
     dense_ssm=(1024, 64, 64, 1, 128, 256),
     narrow=(1, 4096, 32, 8, 64, None, 1 / 64),
     # keye-vl-2.0-30b-a3b.s16384's sparse core whole: one sequence, 32 / 4
-    # heads of 128, 2048 of a query's causal keys, k tiles of 1024
-    sparse=(16384, 32, 4, 128, 2048, 1024),
+    # heads of 128, 2048 of a query's causal keys, k tiles of 1024, 16 index
+    # heads of 64
+    sparse=(16384, 32, 4, 128, 2048, 1024, 16, 64),
     # kimi-linear-48b-a3b.s8192's scan whole: one sequence, 32 heads of 128,
     # chunks of 64
     delta=(8192, 32, 128, 128, 64),
@@ -240,7 +245,7 @@ TINY = Sizes(
     embed=((64, 2560, 48),),
     ssm=(64, 4, 8, 2, 16, 16),
     dense_ssm=(64, 8, 8, 1, 16, 16), narrow=(1, 256, 4, 1, 64, None, 1 / 64),
-    sparse=(512, 4, 2, 128, 48, 256),
+    sparse=(512, 4, 2, 128, 48, 256, 4, 64),
     delta=(64, 2, 128, 128, 32),
     short_conv=(2, 64, 32, 3),
     gpt=dict(vocab_size=1000, d_model=256, n_heads=2, n_layers=2,
@@ -706,7 +711,7 @@ def _check_sparse(smoke: Smoke) -> None:
     from horovod_tpu.ops import pallas_sparse_attention as ps
     from horovod_tpu.ops import sparse_attention as sa
 
-    S, H, Hkv, D, topk, block_k = smoke.sizes.sparse
+    S, H, Hkv, D, topk, block_k = smoke.sizes.sparse[:6]
     kern = ps.Kernels(block_k, interpret=smoke.rehearsal)
     scale, n = D ** -0.5, S // ps.ROWS
     keys = jax.random.split(jax.random.PRNGKey(smoke.seed + 90), 5)
@@ -783,6 +788,74 @@ def _check_sparse(smoke: Smoke) -> None:
                            "grad dk", "grad dv"), got, want):
         _kernel_line(smoke, "sparse_attention", what, _rel_err(g, r),
                      FLASH_TOL, **more)
+        more = {}
+    _check_index_backward(smoke, kern)
+
+
+def _check_index_backward(smoke: Smoke, kern) -> None:
+    """The index score pass's backward kernel (``hvd_index_bwd``) at
+    ``Sizes.sparse``: for every block of 128 queries the gradient of ``ct *
+    KL(target || softmax_chosen(I))`` to the index queries, weights and keys
+    (the keys' summed over the blocks in float32, as the step sums them),
+    under the exact selection of the block's own scores and a seeded target
+    on it, against autodiff of the ``jax.numpy`` expression at "highest" on
+    the same bf16 operands."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+    from horovod_tpu.ops import pallas_sparse_attention as ps
+    from horovod_tpu.ops import sparse_attention as sa
+
+    S, topk, Hi, Di = (smoke.sizes.sparse[n] for n in (0, 4, 6, 7))
+    check(ps.index_kernel_shapes(ps.ROWS, Hi, Di), f"index heads {Hi, Di}")
+    n, f32 = S // ps.ROWS, jnp.float32
+    keys = jax.random.split(jax.random.PRNGKey(smoke.seed + 91), 5)
+    qi = jax.random.normal(keys[0], (n, ps.ROWS, Hi, Di), jnp.bfloat16)
+    w = jax.random.normal(keys[1], (n, ps.ROWS, Hi), f32) * (Hi * Di) ** -0.5
+    ki = jax.random.normal(keys[2], (S, Di), jnp.bfloat16)
+    blocked = (qi, w, ps.ROWS * jnp.arange(n, dtype=jnp.int32))
+
+    def given(q, ww, t0):
+        t = t0 + jnp.arange(ps.ROWS, dtype=jnp.int32)
+        scores = sa.index_scores(q, ww, ki)
+        chosen = sa.select(scores, t, topk)
+        seeded = jax.random.normal(jax.random.fold_in(keys[3], t0),
+                                   chosen.shape, f32)
+        target = jax.nn.softmax(jnp.where(chosen, seeded, -jnp.inf), axis=-1)
+        return scores, chosen, target, 1.0 + jax.random.uniform(
+            jax.random.fold_in(keys[4], t0))
+
+    def kernels(blocked, ki):
+        placed = ps.place_keys(ki, Di, S)
+
+        def block(x):
+            scores, chosen, target, ct = given(*x)
+            return ps.index_backward(
+                x[0], x[1], placed, target,
+                ps.pack_selection(chosen, kern.block_k),
+                ps.index_rows(target, scores, chosen), ct, x[2], S, kern)
+        dq, dw, dk = lax.map(block, blocked)
+        return dq, dw, jnp.sum(dk, axis=0)
+
+    def reference(blocked, ki):
+        def block(dk, x):
+            _, chosen, target, ct = given(*x)
+            with jax.default_matmul_precision("highest"):
+                dq, dw, more = jax.grad(
+                    lambda q, ww, k: ct * sa._index_loss(
+                        target, sa.index_scores(q, ww, k), chosen),
+                    (0, 1, 2))(x[0].astype(f32), x[1], ki.astype(f32))
+            return dk + more, (dq, dw)
+        dk, (dq, dw) = lax.scan(block, jnp.zeros((S, Di), f32), blocked)
+        return dq, dw, dk
+
+    got = _run_compiled(smoke, kernels, (blocked, ki), ps.INDEX_BWD_NAME)
+    want = jax.jit(reference)(blocked, ki)
+    more = dict(shape=(S, Hi, Di), topk=topk, block_k=kern.block_k,
+                dtype="bfloat16")
+    for what, g, r in zip(("grad dqi", "grad dw", "grad dki"), got, want):
+        _kernel_line(smoke, "index_scores", what, _rel_err(g, r), FLASH_TOL,
+                     **more)
         more = {}
 
 

@@ -1,6 +1,6 @@
 #!/usr/bin/env python
-"""The static schedule of a Mamba-2 scan kernel compiled for a described TPU
-v5e, off the chip: the bundles of one grid step (the kernel's body is one
+"""The static schedule of a Mamba-2 scan kernel (or of the index score pass's
+backward kernel) compiled for a described TPU v5e, off the chip: the bundles of one grid step (the kernel's body is one
 straight line a step, so bundles are about its cycles, the pipeline's waits
 apart) and how many slots of each unit they fill, spills and fills apart.
 From libtpu's own dump of its last passes; nothing runs and no time is
@@ -11,9 +11,15 @@ and 1.249), so a form that does not lower the count is not worth a chip call.
 
     python ci/kernel_schedule.py <checkout> fwd|bwd <heads a step> <chunk> \\
         <groups> [<positions>=4 chunks]
+    python ci/kernel_schedule.py <checkout> index <index heads> <their width> \\
+        <k tiles of the call> [<k tile>=1024]
 
 e.g. ``. bwd 8 128 8`` (the cell nemotron-3-nano-30b-a3b.s8192's step) and
-``. bwd 16 256 1`` (granite-4.0-h-micro.s4096's). The dump's flags are read
+``. bwd 16 256 1`` (granite-4.0-h-micro.s4096's); ``. index 16 64 16`` is
+``hvd_index_bwd`` (``ops/pallas_sparse_attention.py``) at the cell
+keye-vl-2.0-30b-a3b.s16384's shapes, a live k tile a grid step (PR 69: 12 897
+bundles, the MXU's slots 90 % filled, for 39.9 ms a step of 4352 live tiles
+on the chip: 1.07 bundles' time a tile). The dump's flags are read
 when libtpu starts, so the compile runs in a child process; beside a
 described-topology test or another of these set
 ``ALLOW_MULTIPLE_LIBTPU_LOAD=1``. libtpu aborts after the dump (a report
@@ -62,19 +68,51 @@ else:
 """
 
 
+_INDEX_CHILD = r"""
+import os, sys
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+root, _, heads, dim, tiles, bk = sys.argv[1:7]
+sys.path.insert(0, root)
+import jax, jax.numpy as jnp
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+from horovod_tpu.ops import pallas_sparse_attention as ps
+heads, dim, tiles, bk = int(heads), int(dim), int(tiles), int(bk)
+jax.config.update("jax_enable_compilation_cache", False)
+topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+chip = SingleDeviceSharding(topo.devices[0])
+def arg(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+span, f32 = tiles * bk, jnp.float32
+jax.jit(lambda *v: ps.index_backward(*v, span, ps.Kernels(bk))).lower(
+    arg((ps.ROWS, heads, dim), jnp.bfloat16), arg((ps.ROWS, heads), f32),
+    arg((ps.MIN_BLOCK // dim, span, ps.MIN_BLOCK), jnp.bfloat16),
+    arg((ps.ROWS, span), f32), arg((tiles, ps.PIECE, ps.ROWS), jnp.int8),
+    arg((2, ps.ROWS), f32), arg((), f32), arg((), jnp.int32)).compile()
+"""
+
+
 def schedule(root: str, which: str, heads: int, chunk: int, groups: int,
              positions: int | None = None) -> dict:
     """{"bundles": .., "slots": {unit: filled slots}, "capacity": {unit: a
-    bundle's}} of the kernel's final schedule, or {"error": ..}."""
-    from horovod_tpu.ops import pallas_ssm
-    name = pallas_ssm.BWD_NAME if which == "bwd" else pallas_ssm.FWD_NAME
+    bundle's}} of the kernel's final schedule, or {"error": ..}. ``which``
+    ``"index"``: (index heads, their width, k tiles, the k tile) in the
+    arguments' places."""
+    if which == "index":
+        from horovod_tpu.ops import pallas_sparse_attention
+        name, child = pallas_sparse_attention.INDEX_BWD_NAME, _INDEX_CHILD
+        positions = positions or 1024
+    else:
+        from horovod_tpu.ops import pallas_ssm
+        name = pallas_ssm.BWD_NAME if which == "bwd" else pallas_ssm.FWD_NAME
+        child = _CHILD
     with tempfile.TemporaryDirectory(prefix="kernel_schedule_") as out:
         env = dict(os.environ, JAX_PLATFORMS="cpu", LIBTPU_INIT_ARGS=(
             os.environ.get("LIBTPU_INIT_ARGS", "")
             + f" --xla_jf_dump_to={out} --xla_jf_dump_llo_text=true"
             " --xla_jf_dump_llo_pass_label_regex=final").strip())
         done = subprocess.run(
-            [sys.executable, "-c", _CHILD, os.path.abspath(root), which,
+            [sys.executable, "-c", child, os.path.abspath(root), which,
              str(heads), str(chunk), str(groups),
              str(positions or 4 * chunk)],
             env=env, capture_output=True, text=True)

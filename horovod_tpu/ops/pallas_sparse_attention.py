@@ -2,11 +2,11 @@
 attention under a per-query selection of keys (``ops/sparse_attention.py``
 makes the selection; this module reads it).
 
-Three kernels in ``ops/pallas_attention.py``'s form (scores transposed, ``sT =
-k @ qT`` as ``[k rows, q rows]``, a q row's softmax state along the lanes,
-a key tile in pieces of 128 k rows, a piece's score matmul written before the
-piece before it is reduced), with two differences that make them bodies of
-their own:
+Three kernels of the core in ``ops/pallas_attention.py``'s form (scores
+transposed, ``sT = k @ qT`` as ``[k rows, q rows]``, a q row's softmax state
+along the lanes, a key tile in pieces of 128 k rows, a piece's score matmul
+written before the piece before it is reduced), with two differences that
+make them bodies of their own:
 
 **The group is on the rows.** A grid step takes ``ROWS`` = 128 query positions
 of ALL the ``G`` query heads of one k/v head: the q operand is ``[G * 128,
@@ -47,6 +47,18 @@ unrolled form runs all eight there: 5 % of the pieces) 26.20 / 17.84 / 48.62:
 the scheduler sees one iteration at a time and the MXU waits for the vector
 unit again (PERF.md, PR 65).
 
+``hvd_index_bwd`` is the indexer's: the backward of its score pass and of the
+loss on it, a block of 128 query positions a call inside the transposed map
+(:func:`index_backward`; grid (k tiles), the same scalar-prefetch skip of
+the tiles past the diagonal). Of the score pass ``I[t, s] = sum_j w[t, j]
+relu(qI[t, j] . kI[s])`` the ``[16 heads, 128 rows, keys]`` float32 products
+are 134 MB a block at 16 384 keys: XLA fuses them away in the forward pass
+and autodiff writes them in the backward. The kernel makes them again a
+piece of 128 keys at a time in the sparse kernels' form (keys on the
+sublanes, the stacked heads' positions on the lanes), the scores ``I`` and
+the KL's gradient ``dI`` from them, and writes the three gradients; neither
+the products nor ``dI`` reach HBM.
+
 **A row with no selected key in its first tiles** (causal flash has none:
 every row's first tile holds key 0). The running max starts at ``M_FLOOR`` =
 -1e20, far above a masked score's ``NEG_INF`` = -1e30 and far below any real
@@ -74,6 +86,7 @@ from horovod_tpu.profiling.compile_watch import kernel_call
 FWD_NAME = "hvd_sparse_fwd"
 MEAN_NAME = "hvd_sparse_mean"
 BWD_NAME = "hvd_sparse_bwd"
+INDEX_BWD_NAME = "hvd_index_bwd"
 
 #: query positions of a block: the lanes of a mask tile
 ROWS = MIN_BLOCK
@@ -543,3 +556,212 @@ def sparse_backward(q, k, v, o, lse, mask, do, scale: float, kern: Kernels
             parts = parts.astype(jnp.float32).sum(0).astype(parts.dtype)
         return parts.reshape(S, Hkv, D)
     return dq.reshape(S, H, D), total(dk), total(dv)
+
+
+# ---------------------------------------------------------------------------
+# The indexer's score pass, backward: one call a block of positions
+# ---------------------------------------------------------------------------
+
+def index_kernel_shapes(rows: int, heads: int, dim: int) -> bool:
+    """Whether :func:`index_backward` takes a block's index queries ``[rows,
+    heads, dim]``: a block of ``ROWS`` positions, an index head a whole
+    sublane tile wide and a whole share of a lane tile, the heads whole lane
+    tiles of ``[rows, heads * dim]`` (16 heads of 64: two a tile)."""
+    return (rows == ROWS and dim % 8 == 0 and dim <= MIN_BLOCK
+            and MIN_BLOCK % dim == 0 and heads % (MIN_BLOCK // dim) == 0)
+
+
+def _index_bwd_kernel(t0_ref, q_ref, w_ref, k_ref, p_ref, mask_ref, rows_ref,
+                      dq_ref, dw_ref, dk_ref, qc_ref, wl_ref, dq_acc, dw_acc,
+                      *, block_k: int, dim: int):
+    """One k tile of a block of ``ROWS`` positions. The index queries come
+    transposed, ``[heads * dim, ROWS]`` (a head's channels on the sublanes,
+    the positions on the lanes); ``pack`` = 128 / ``dim`` heads are a sublane
+    tile ``[128, ROWS]`` of it and ``qc_ref`` holds the block's ``T`` tiles
+    side by side, ``[128, T * ROWS]``. Head ``t * pack + p``'s products are a
+    matmul of the whole tile against the keys laid in lanes ``[p * dim, (p +
+    1) * dim)`` and zeros elsewhere (``k_ref[p]``: the other heads' channels
+    meet zeros, and a contraction of ``dim`` fills no more of the MXU than
+    one of 128), so a piece of 128 keys is ``pack`` units ``s[p]`` of ``[128
+    keys, T * ROWS]`` float32, keys on the sublanes, a head's positions a
+    lane tile, and nothing crosses lanes: ``w`` is a lane row (``wl_ref[p]``),
+    and what is ``[128 keys, ROWS]`` (the piece's mask bits, ``p`` transposed
+    here once a piece, the rows' three statistics) repeats over the ``T``
+    tiles. A piece's scores are written one piece ahead of its vector work.
+    From them the index scores ``I = sum_heads w relu(s)``, ``dI = ct (sum_p
+    exp(I - lse) - p)`` at the selected keys, and a unit's two more matmuls:
+    ``dk = g @ qc^T`` (``[128 keys, 128]``, of which lanes ``p``'s are the
+    keys' gradient; written transposed, the keys on the lanes as the index
+    keys lie in HBM) and ``dq += k_ref[p]^T @ g`` (``[128, T * ROWS]``: rows
+    ``p``'s, the others add zeros), which is the q operand's own form."""
+    j = pl.program_id(0)
+    pack = MIN_BLOCK // dim
+    tiles = qc_ref.shape[1] // ROWS
+    pieces = block_k // PIECE
+
+    @pl.when(j == 0)
+    def _init():
+        for t in range(tiles):
+            at = slice(t * ROWS, (t + 1) * ROWS)
+            qc_ref[:, at] = q_ref[t * MIN_BLOCK:(t + 1) * MIN_BLOCK]
+            for p in range(pack):
+                wl_ref[p, :, at] = w_ref[t * pack + p]
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+        dw_acc[...] = jnp.zeros_like(dw_acc)
+
+    @pl.when(j <= _last_tile(t0_ref[0], block_k))
+    def _tile():
+        lane = lax.broadcasted_iota(jnp.int32, (PIECE, MIN_BLOCK), 1)
+        words = mask_ref[0].astype(jnp.int32)
+        lse, mass, ct = rows_ref[0:1], rows_ref[1:2], rows_ref[2:3]
+
+        def scores(n):
+            return [_dot(k_ref[p, pl.ds(n * PIECE, PIECE)], qc_ref[...], _NN)
+                    for p in range(pack)]
+
+        def over_tiles(x):
+            return x if tiles == 1 else jnp.concatenate([x] * tiles, axis=1)
+        s = scores(0)
+        for n in range(pieces):
+            ahead = scores(n + 1) if n + 1 < pieces else None
+            ks = pl.ds(n * PIECE, PIECE)
+            weighted = [jnp.maximum(s[p], 0.0) * wl_ref[p]
+                        for p in range(pack)]
+            index = functools.reduce(jnp.add, (
+                x[:, t * ROWS:(t + 1) * ROWS]
+                for x in weighted for t in range(tiles)))   # [keys, ROWS]
+            e = jnp.where((words & (1 << n)) != 0, jnp.exp(index - lse), 0.0)
+            di = over_tiles(mass * e - ct * jnp.transpose(p_ref[:, ks]))
+            for p in range(pack):
+                live = jnp.where(s[p] > 0, di, 0.0)         # relu'(0) = 0
+                dw_acc[p] += jnp.sum(s[p] * live, axis=0, keepdims=True)
+                g = (live * wl_ref[p]).astype(qc_ref.dtype)
+                part = _dot(g, qc_ref[...], _NT)            # [keys, 128]
+                dk = part if p == 0 else jnp.where(lane >= p * dim, part, dk)
+                dq_acc[...] += _dot(k_ref[p, ks], g, _TN)
+            dk_ref[:, ks] = jnp.transpose(dk)
+            s = ahead
+
+    @pl.when(j == pl.num_programs(0) - 1)
+    def _write():
+        for t in range(tiles):
+            at = slice(t * ROWS, (t + 1) * ROWS)
+            dq_ref[t * MIN_BLOCK:(t + 1) * MIN_BLOCK] = dq_acc[:, at].astype(
+                dq_ref.dtype)
+            for p in range(pack):
+                dw_ref[t * pack + p] = dw_acc[p, :, at]
+
+
+# jitted on the call's own operands (every one already in the call's shape):
+# a program's call sites (a band's map each) share one trace and one Mosaic
+# lowering a shape
+@functools.partial(jax.jit, static_argnames=("dim", "kern"))
+def _index_bwd_local(t0, q, w, k, p, mask, rows, *, dim: int, kern: Kernels):
+    """(dq ``[heads * dim, ROWS]`` in q's dtype, dw ``[heads, 1, ROWS]`` and
+    dk ``[128, span]`` float32: the keys' gradient of heads ``p``'s in rows
+    ``[p * dim, (p + 1) * dim)``) of q ``[heads * dim, ROWS]``, w ``[heads, 1,
+    ROWS]`` float32, k ``[pack, span, 128]`` (:func:`place_keys`), p ``[ROWS,
+    span]`` float32, ``mask`` ``[span / block_k, PIECE, ROWS]`` and ``rows``
+    ``[3, ROWS]``. dk's columns past the block's last live tile are not
+    written."""
+    bk = kern.block_k
+    pack, span, _ = k.shape
+    wide = q.shape[0] // MIN_BLOCK * ROWS
+
+    def tile(j, t0_ref):
+        return jnp.minimum(j, _last_tile(t0_ref[0], bk))
+
+    def whole(shape):
+        return pl.BlockSpec(shape, lambda j, t0_ref: (0,) * len(shape))
+    return kernel_call(pl.pallas_call,
+        functools.partial(_index_bwd_kernel, block_k=bk, dim=dim),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(span // bk,),
+            in_specs=[
+                whole(q.shape),
+                whole(w.shape),
+                pl.BlockSpec((pack, bk, MIN_BLOCK),
+                             lambda j, t0_ref: (0, tile(j, t0_ref), 0)),
+                pl.BlockSpec((ROWS, bk),
+                             lambda j, t0_ref: (0, tile(j, t0_ref))),
+                pl.BlockSpec((1, PIECE, ROWS),
+                             lambda j, t0_ref: (tile(j, t0_ref), 0, 0)),
+                whole(rows.shape),
+            ],
+            out_specs=[
+                whole(q.shape),
+                whole(w.shape),
+                pl.BlockSpec((MIN_BLOCK, bk),
+                             lambda j, t0_ref: (0, tile(j, t0_ref))),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((MIN_BLOCK, wide), q.dtype),         # q's tiles
+                pltpu.VMEM((pack, 1, wide), jnp.float32),       # w's rows
+                pltpu.VMEM((MIN_BLOCK, wide), jnp.float32),     # dq
+                pltpu.VMEM((pack, 1, wide), jnp.float32),       # dw
+            ]),
+        out_shape=[
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct(w.shape, jnp.float32),
+            jax.ShapeDtypeStruct((MIN_BLOCK, span), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=kern.interpret,
+        name=INDEX_BWD_NAME,
+    )(jnp.reshape(t0, (1,)).astype(jnp.int32), q, w, k, p, mask, rows)
+
+
+def place_keys(ki, dim: int, span: int):
+    """The index keys ``[keys, dim]`` as :func:`index_backward` reads them,
+    ``[pack, span, 128]``: padded with zero keys to ``span`` and laid in
+    lanes ``[p * dim, (p + 1) * dim)`` of a tile of zeros, ``p = 0 .. pack -
+    1``. Made once a band, outside the map over its blocks."""
+    keys = ki.shape[0]
+    return jnp.stack([
+        jnp.pad(ki, ((0, span - keys), (p * dim, MIN_BLOCK - (p + 1) * dim)))
+        for p in range(MIN_BLOCK // dim)])
+
+
+def index_rows(target, scores, chosen):
+    """What :func:`index_backward` keeps of a block's forward pass beside
+    its operands, ``[2, ROWS]`` float32: the rows' log-sum-exp of the index
+    scores ``[ROWS, keys]`` over the selection ``chosen``, and the rows'
+    sums of ``target`` (the heads' mean attention: 1 but for rounding)."""
+    lse = jax.nn.logsumexp(jnp.where(chosen, scores, -jnp.inf), axis=-1)
+    return jnp.stack([lse, jnp.sum(target, axis=-1)])
+
+
+def index_backward(qi, w, placed, target, mask, kept, ct, t0, keys: int,
+                   kern: Kernels):
+    """The gradient of a block's ``ct * KL(target || softmax_chosen(I))``, ``I
+    = sparse_attention.index_scores(qi, w, ki)``, to (qi, w, ki): one
+    ``hvd_index_bwd`` call over the k tiles of ``placed`` (:func:`place_keys`
+    of the band's ``ki`` in ``qi``'s dtype), ``target`` ``[ROWS, span]``
+    (:func:`heads_mean`'s, as it writes it) and ``mask`` (the selection over
+    the same tiles), with ``kept`` :func:`index_rows` of the forward pass and
+    ``t0`` the block's first position. For a tile up to the block's diagonal
+    the products ``s = kI . qI^T`` are made again in VMEM, the scores ``I``
+    from them, ``dI = ct (sum(target) softmax_chosen(I) - target)`` at the
+    selected keys (what autodiff makes of the KL), ``g = (s > 0) * w * dI``
+    rounded to ``qi``'s dtype as the transposed matmuls' operand, and ``dqi +=
+    g^T . kI``, ``dki = g . qI``, ``dw += sum_keys relu(s) * dI`` in float32:
+    what autodiff makes of the expression at default precision, with neither
+    the ``[heads, rows, keys]`` products nor ``dI`` ever in HBM. (Where a
+    row's weighted sum cancels to exactly 0 under live heads autodiff's
+    ``where`` stops the gradient; the kernel passes it: the expression is the
+    identity there.) ``dki`` is float32 ``[keys, dim]``."""
+    rows, heads, dim = qi.shape
+    pack, span, _ = placed.shape
+    stats = jnp.stack([kept[0], ct * kept[1], jnp.full_like(kept[0], ct)])
+    # the operands as XLA lays them for the forward pass's contraction: a
+    # head's channels by positions, the keys' channels by keys
+    dq, dw, dk = _index_bwd_local(
+        t0, qi.transpose(1, 2, 0).reshape(heads * dim, rows),
+        w.T.reshape(heads, 1, rows), placed, target, mask, stats, dim=dim,
+        kern=kern)
+    dk = dk.reshape(pack, dim, span).sum(0)[:, :keys]
+    live = jnp.arange(keys) < (_last_tile(t0, kern.block_k) + 1) * kern.block_k
+    return (dq.reshape(heads, dim, rows).transpose(2, 0, 1),
+            dw.reshape(heads, rows).T, jnp.where(live, dk, 0.0).T)

@@ -23,7 +23,7 @@ array exists. Two forms of the core, chosen from the backend and the shape
 (:func:`sparse_path`; no setting):
 
 * ``"pallas"`` (a TPU, whole blocks of 128 positions, heads of whole lane
-  tiles): the three kernels of ``ops/pallas_sparse_attention.py``. The block
+  tiles): the kernels of ``ops/pallas_sparse_attention.py``. The block
   packs its selection as the kernels' mask (a bit a (query, key), the name
   :data:`SELECTION`) and calls ``hvd_sparse_fwd`` (its outputs and the rows'
   log-sum-exp, :data:`STATS`) and ``hvd_sparse_mean`` (the heads' mean
@@ -33,6 +33,15 @@ array exists. Two forms of the core, chosen from the backend and the shape
   (:func:`_attached`), whose backward is one ``hvd_sparse_bwd`` call beside
   ``hvd_flash_adj``: dk and dv of a k tile are summed in VMEM over the q
   blocks and the group's heads and written once a range of 2048 positions.
+  The gradient of the indexer's loss reaches the index's queries, weights
+  and keys through one ``custom_vjp`` a block (:func:`_index_attached`),
+  whose backward is one ``hvd_index_bwd`` call, where the index heads are
+  whole shares of a lane tile (``ps.index_kernel_shapes``; else autodiff of
+  :func:`index_scores`): the ``[rows, Hi, keys]`` products of the score
+  pass are made again a tile at a time in VMEM, and from them the scores,
+  the KL's gradient ``softmax(I) - p`` and the three gradients; the
+  forward pass's score expression is the XLA form's, so both forms select
+  from the same bits.
 * ``"xla"`` (the CPU, odd shapes; what the tests hold the kernels to): the
   block's masked scores ``[H, rows, keys]`` float32 and their softmax are
   ``jax.numpy``, and the backward is autodiff's, a block at a time.
@@ -46,16 +55,21 @@ the index, which runs only where a block has a tie to break). No sort, no
 approximate top-k, no block-level stand-in.
 
 **The backward pass.** Each block is a ``jax.checkpoint`` that keeps its
-selection (and, in the kernels' form, its rows' log-sum-exp) and nothing
-else: the transposed map computes a block's index scores again and, for the
-KL's gradient ``softmax(I) - p``, the target (``hvd_sparse_mean`` once more;
-in the XLA form the block's scores and softmax, which there also carry the
-gradient to q, k, v, the keys, values and index keys in float32 so that their
-gradients add up over the blocks in float32), and selects nothing twice. A
+selection (and, in the kernels' form, its rows' log-sum-exp and two more
+rows, :data:`INDEX_ROWS`) and nothing else: the transposed map computes, for
+the KL's gradient ``softmax(I) - p``, the target again (``hvd_sparse_mean``
+once more) and the block's index scores (inside ``hvd_index_bwd``, which
+needs of the forward pass's scores only their log-sum-exp over the
+selection, a kept row; in the XLA form, and at an index shape the kernel does
+not take, the block's scores and softmax by XLA, which in the XLA form also
+carry the gradient to q, k, v, the keys, values and index keys in float32 so
+that their gradients add up over the blocks in float32), and selects nothing
+twice. A
 checkpoint AROUND the call (a block of the model) that keeps :data:`KEPT` runs
 neither the selection nor the core a second time: at 16 384 tokens 33.5 MB of
 mask, 134 MB of outputs and 2 MB of rows a layer, and in the kernels' form
-the backward pass calls ``hvd_sparse_fwd`` never. Two ``stop_gradient``s keep
+the backward pass calls ``hvd_sparse_fwd`` never and runs no score pass
+outside ``hvd_index_bwd``. Two ``stop_gradient``s keep
 the graphs apart: the target ``p`` and the scores the selection reads; the
 caller stops the gradient into the indexer's input.
 """
@@ -87,7 +101,11 @@ OUTPUT = "hvd_sparse_output"
 #: the name of the rows' log-sum-exp (the kernels' form): with the selection,
 #: all the backward pass needs of the forward kernel's run
 STATS = "hvd_sparse_lse"
-KEPT = (SELECTION, OUTPUT, STATS)
+#: the name of the index scores' log-sum-exp over the selection and the
+#: target's sums, a block's rows (the kernels' form): all ``hvd_index_bwd``
+#: needs of the forward pass's scores
+INDEX_ROWS = "hvd_sparse_index_rows"
+KEPT = (SELECTION, OUTPUT, STATS, INDEX_ROWS)
 
 
 def blocks(seq: int) -> Tuple[int, int]:
@@ -254,6 +272,31 @@ def _call_tiles(keys: int, seq: int, block_k: int) -> int:
     return half if tiles % 2 == 0 and keys <= half * block_k else tiles
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(9,))
+def _index_attached(qi, w, ki, kl, placed, target, mask, rows, t0, kern):
+    """``kl``, a block's summed KL as :func:`_index_loss` made it, with its
+    gradient to the index's qi, w and ki: one ``hvd_index_bwd`` call
+    (``ps.index_backward``) on the block's target, mask and kept rows, so
+    that the backward pass makes the ``[rows, Hi, keys]`` products again in
+    VMEM only and runs no score pass and no softmax of its own."""
+    return kl
+
+
+def _index_attached_fwd(qi, w, ki, kl, placed, target, mask, rows, t0, kern):
+    return kl, (qi, w, ki, placed, target, mask, rows, t0)
+
+
+def _index_attached_bwd(kern, res, ct):
+    qi, w, ki, placed, target, mask, rows, t0 = res
+    with scopes.scope(scopes.ATTENTION_INDEX_SCORES):
+        dqi, dw, dki = ps.index_backward(qi, w, placed, target, mask, rows,
+                                         ct, t0, ki.shape[0], kern)
+    return (dqi, dw, dki) + (None,) * 6
+
+
+_index_attached.defvjp(_index_attached_fwd, _index_attached_bwd)
+
+
 def _kernel_block(topk: int, scale: float, seq: int, head_dim: int,
                   kern: ps.Kernels, x, consts):
     """One block of ``ps.ROWS`` positions of one sequence, the kernels' form:
@@ -263,12 +306,14 @@ def _kernel_block(topk: int, scale: float, seq: int, head_dim: int,
     come flat (heads side by side) and behind ``stop_gradient``: their
     gradient is :func:`_attached`'s."""
     q, qi, w, t0 = x        # q [rows, H * D]
-    k, v, ki = consts       # k, v the sequence's [S, Hkv * D]; ki the band's
-    rows = q.shape[0]
+    k, v, ki, placed = consts   # k, v the sequence's [S, Hkv * D]; ki the
+    rows = q.shape[0]           # band's, placed its ps.place_keys or None
     keys = ki.shape[0]
     t = t0 + jnp.arange(rows, dtype=jnp.int32)
     with scopes.scope(scopes.ATTENTION_INDEX_SCORES):
         scores = index_scores(qi, w, ki)
+        if placed is not None:      # the gradient is _index_attached's
+            scores = lax.stop_gradient(scores)
     with scopes.scope(scopes.ATTENTION_INDEX_SELECT):
         picked = select(lax.stop_gradient(scores), t, topk)
         # kept as the kernels' mask (a byte for eight keys at a tile of
@@ -287,8 +332,14 @@ def _kernel_block(topk: int, scale: float, seq: int, head_dim: int,
         lse = checkpoint_name(lse, STATS)
     with scopes.scope(scopes.ATTENTION_INDEX_LOSS):
         target = ps.heads_mean(q, k, lse, called, t0, scale=scale,
-                               head_dim=head_dim, kern=kern)[:, :keys]
-        kl = _index_loss(target, scores, chosen)
+                               head_dim=head_dim, kern=kern)
+        kl = _index_loss(target[:, :keys], scores, chosen)
+        if placed is not None:
+            kept = checkpoint_name(
+                ps.index_rows(target, scores, chosen), INDEX_ROWS)
+    if placed is not None:      # (its backward names its own scope)
+        kl = _index_attached(qi, w, ki, kl, placed, target, called, kept, t0,
+                             kern)
     mask = jnp.pad(called, ((0, seq // kern.block_k - tiles), (0, 0), (0, 0)))
     return o, lse, kl, jnp.sum(chosen, dtype=jnp.float32), bits, mask
 
@@ -325,13 +376,21 @@ def _kernel_sequence(topk: int, scale: float, kern: ps.Kernels, args):
     ki = ki.astype(jnp.float32)
     block = jax.checkpoint(
         functools.partial(_kernel_block, topk, scale, seq, head_dim, kern),
-        policy=jax.checkpoint_policies.save_only_these_names(SELECTION,
-                                                             STATS))
+        policy=jax.checkpoint_policies.save_only_these_names(
+            SELECTION, STATS, INDEX_ROWS))
     # flat once, here: a reshape inside the map is a copy of k and v a block
     flat_q, flat_k, flat_v = (lax.stop_gradient(a.reshape(seq, -1))
                               for a in (q, k, v))
+    index_kernel = ps.index_kernel_shapes(ps.ROWS, *qi.shape[1:])
+
+    def consts(hi):     # the band's index keys, and as hvd_index_bwd reads them
+        placed = lax.stop_gradient(ps.place_keys(
+            ki[:hi].astype(qi.dtype), qi.shape[2],
+            _call_tiles(hi, seq, kern.block_k) * kern.block_k)
+        ) if index_kernel else None
+        return flat_k, flat_v, ki[:hi], placed
     o, lse, kl, count, bits, mask = _over_bands(
-        block, seq, (flat_q, qi, w), lambda hi: (flat_k, flat_v, ki[:hi]))
+        block, seq, (flat_q, qi, w), consts)
     o = checkpoint_name(o.reshape(q.shape), OUTPUT)
     lse = lse.transpose(1, 2, 0, 3).reshape(heads, 1, seq)
     return (_attached(q, k, v, o, lse, mask, scale, kern), jnp.sum(kl),

@@ -14,7 +14,9 @@ file of the benchmark's) are answered here by the program's own gate,
 are the delta rule's two (PR 67, the same kind of PR), by
 ``delta_scan_path`` at the cell's ``shapes()``: the forward's metric reads
 ``hvd_delta_scan(?!_bwd)``, a pattern, since the backward's name starts
-with the forward's."""
+with the forward's. The index score pass's backward (PR 69, again a
+``perf_opt`` PR) is answered by ``sparse_path`` and the index's own shape
+rule, ``index_kernel_shapes``."""
 
 import chip_door
 
@@ -35,11 +37,13 @@ def _gate(kernel: str, sizes: dict, monkeypatch) -> bool:
             and pallas_delta.delta_scan_path(
                 sizes["seq"], sizes["delta_heads"], width, width,
                 sizes["delta_chunk"]) == "kernels"
-    if kernel in (ps.FWD_NAME, ps.MEAN_NAME, ps.BWD_NAME):
+    if kernel in (ps.FWD_NAME, ps.MEAN_NAME, ps.BWD_NAME, ps.INDEX_BWD_NAME):
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         return sizes.get("index_topk", 0) > 0 and sparse_attention.sparse_path(
             sizes["seq"], sizes["heads"], sizes["kv_heads"],
-            sizes["head_dim"]) == "pallas"
+            sizes["head_dim"]) == "pallas" and (
+                kernel != ps.INDEX_BWD_NAME or ps.index_kernel_shapes(
+                    ps.ROWS, sizes["index_heads"], sizes["index_head_dim"]))
     return _benchmarks_gate(kernel, sizes, monkeypatch)
 
 

@@ -1,11 +1,13 @@
-"""The sparse core's three Pallas kernels (``ops/pallas_sparse_attention.py``:
-``hvd_sparse_fwd``, ``hvd_sparse_mean``, ``hvd_sparse_bwd``) against the XLA
-form of ``ops/sparse_attention.py``, interpret mode on the CPU: the outputs,
-the indexer's loss, the counted and the packed selection, and the six
-gradients through ``indexed_attention``; the rows' log-sum-exp and the heads'
-mean attention at the kernels' own door. Each side of a case is one jitted
-program, run once a process; the shapes are the least that cross what the
-case names (a block is 128 positions, a piece 128 keys)."""
+"""The sparse core's Pallas kernels (``ops/pallas_sparse_attention.py``:
+``hvd_sparse_fwd``, ``hvd_sparse_mean``, ``hvd_sparse_bwd`` and the index
+score pass's backward ``hvd_index_bwd``) against the XLA form of
+``ops/sparse_attention.py``, interpret mode on the CPU: the outputs, the
+indexer's loss, the counted and the packed selection, and the six gradients
+through ``indexed_attention``; the rows' log-sum-exp, the heads' mean
+attention and the index's three gradients at the kernels' own door. Each side
+of a case is one jitted program, run once a process; the shapes are the least
+that cross what the case names (a block is 128 positions, a piece 128
+keys)."""
 
 import functools
 
@@ -44,13 +46,22 @@ CASES = {
         (1, 384, 2, 1, 16, 128, jnp.float32, _recent),
     "a row selects one key": (1, 256, 2, 1, 1, 128, jnp.float32, None),
     "ties, groups of 1": (1, 256, 2, 2, 24, 256, jnp.float32, _tied),
+    # index heads two a lane tile: the index's gradient is hvd_index_bwd's
+    "16 index heads of 64, bands shorter than their calls' three tiles":
+        (1, 384, 2, 1, 48, 128, jnp.float32, None),
+}
+#: (index heads, their width) of a case: two of 8 (an odd shape, autodiff of
+#: the XLA expression) unless named
+INDEX_HEADS = {
+    "16 index heads of 64, bands shorter than their calls' three tiles":
+        (16, 64),
 }
 RESULTS = ("o", "index_loss", "selected_keys", "selection")
 GRADIENTS = ("dq", "dk", "dv", "d index query", "d index key",
              "d index weight")
 
 
-def _inputs(B, S, H, Hkv, dtype, index, D=128, Hi=2, Di=8):
+def _inputs(B, S, H, Hkv, dtype, index, Hi=2, Di=8, D=128):
     keys = jax.random.split(jax.random.PRNGKey(65), 6)
     q = jax.random.normal(keys[0], (B, S, H, D), dtype)
     k, v = (jax.random.normal(kk, (B, S, Hkv, D), dtype) for kk in keys[1:3])
@@ -67,7 +78,7 @@ def _both(case):
     """{form: (results, gradients)} of ``indexed_attention`` under a seeded
     cotangent of o plus three times the indexer's loss."""
     B, S, H, Hkv, topk, block_k, dtype, index = CASES[case]
-    args = _inputs(B, S, H, Hkv, dtype, index)
+    args = _inputs(B, S, H, Hkv, dtype, index, *INDEX_HEADS.get(case, (2, 8)))
     u = jax.random.normal(jax.random.PRNGKey(66), args[0].shape, jnp.float32)
 
     def side(kernels):
@@ -178,6 +189,102 @@ def test_rows_with_no_selected_key_in_their_first_tiles(group, what):
     if what == "p":         # row 0 puts all its weight on key 5
         assert got[n][0, 5] == pytest.approx(1.0, abs=1e-6)
         assert got[n][0].sum() == pytest.approx(1.0, abs=1e-6)
+
+
+# (index heads, keys of the band, keys of the call, k tile, the block's first
+# position, dtype, whether row 5's products are all <= 0)
+INDEX_DOOR = {
+    "16 heads, a band of 256 keys in a call of 512, a row of score +0.0":
+        (16, 256, 512, 128, 128, jnp.float32, True),
+    "4 heads, the diagonal tile the band's last":
+        (4, 384, 384, 128, 256, jnp.float32, False),
+    "16 heads, the diagonal in the first of two tiles of two pieces, "
+    "bfloat16": (16, 512, 512, 256, 128, jnp.bfloat16, False),
+}
+INDEX_GRADIENTS = ("d index query", "d index weight", "d index key")
+
+
+@functools.lru_cache(maxsize=None)
+def _index_door(case):
+    """(``hvd_index_bwd``'s (dqi, dw, dki), autodiff's of the XLA expression)
+    of ``ct * KL(target || softmax_chosen(index_scores))`` for one block
+    under a seeded selection (half its causal keys and its own) and a seeded
+    target on it."""
+    Hi, keys, span, bk, t0, dtype, dead = INDEX_DOOR[case]
+    Di, ct = 64, 1.7
+    rng = jax.random.split(jax.random.PRNGKey(69), 5)
+    qi = jax.random.normal(rng[0], (ps.ROWS, Hi, Di), dtype)
+    w = jax.random.normal(rng[1], (ps.ROWS, Hi), jnp.float32)
+    ki = jax.random.normal(rng[2], (keys, Di), jnp.float32)
+    if dead:
+        qi, ki = qi.at[5].set(-jnp.abs(qi[5])), jnp.abs(ki)
+    t = t0 + np.arange(ps.ROWS)
+    chosen = np.array(jax.random.bernoulli(rng[3], 0.5, (ps.ROWS, keys)))
+    chosen &= np.arange(keys)[None] <= t[:, None]
+    chosen[np.arange(ps.ROWS), t] = True
+    chosen = jnp.asarray(chosen)
+    target = jax.nn.softmax(jnp.where(
+        chosen, jax.random.normal(rng[4], chosen.shape), -jnp.inf), axis=-1)
+    if dead:
+        assert float(sa.index_scores(qi, w, ki)[5].max()) == 0.0
+
+    def loss(qi, w, ki):
+        return ct * sa._index_loss(target, sa.index_scores(qi, w, ki), chosen)
+
+    @jax.jit
+    def kernel(qi, w, ki):
+        mask = ps.pack_selection(chosen, bk)
+        mask = jnp.pad(mask, ((0, span // bk - mask.shape[0]), (0, 0), (0, 0)))
+        return ps.index_backward(
+            qi, w, ps.place_keys(ki.astype(dtype), Di, span),
+            jnp.pad(target, ((0, 0), (0, span - keys))), mask,
+            ps.index_rows(target, sa.index_scores(qi, w, ki), chosen),
+            jnp.float32(ct), jnp.int32(t0), keys, ps.Kernels(bk, True))
+    return ([np.asarray(x, np.float64) for x in kernel(qi, w, ki)],
+            [np.asarray(x, np.float64)
+             for x in jax.jit(jax.grad(loss, (0, 1, 2)))(qi, w, ki)])
+
+
+@pytest.mark.parametrize("what", INDEX_GRADIENTS)
+@pytest.mark.parametrize("case", INDEX_DOOR)
+def test_the_index_kernel_gives_autodiff_s_gradients(case, what):
+    """The products made again a tile at a time, ``dI`` formed from them,
+    the keys past the band and the tiles past the diagonal untouched (their
+    dki exactly 0), ``relu'(0) = 0`` on a row whose products are all <= 0."""
+    got, want = _index_door(case)
+    n = INDEX_GRADIENTS.index(what)
+    assert got[n].shape == want[n].shape and np.abs(want[n]).max() > 0
+    _close(got[n], want[n], INDEX_DOOR[case][5])
+    if what == "d index key":
+        _, _, _, bk, t0, _, _ = INDEX_DOOR[case]
+        past = ((t0 + ps.ROWS - 1) // bk + 1) * bk
+        assert not got[n][past:].any() and not want[n][past:].any()
+    elif INDEX_DOOR[case][6]:       # row 5: no head is live
+        assert not got[n][5].any() and not want[n][5].any()
+
+
+def test_the_index_kernel_runs_in_the_backward_pass_alone():
+    """The kernels' form makes the index scores with ``index_scores`` itself
+    (the selection reads the parent's bits) and calls ``hvd_index_bwd`` once
+    a band in the gradient, never in the forward pass; an index shape the
+    kernel does not take (two heads of 8) keeps autodiff."""
+    kern = ps.Kernels(128, True)
+
+    def text(Hi, Di, grad):
+        args = _inputs(1, 256, 2, 1, jnp.float32, None, Hi, Di)
+
+        def loss(*args):
+            return sa.indexed_attention(*args, 32, SCALE, kernels=kern)[1]
+        return str(jax.make_jaxpr(jax.grad(loss, (3, 4, 5)) if grad else loss)(
+            *args))
+    assert sa.blocks(256) == (128, 2)
+    assert text(16, 64, True).count("name=" + ps.INDEX_BWD_NAME) == 2
+    assert ps.INDEX_BWD_NAME not in text(16, 64, False)
+    assert ps.INDEX_BWD_NAME not in text(2, 8, True)
+    assert ps.index_kernel_shapes(128, 16, 64)
+    for odd in ((64, 16, 64), (128, 2, 8), (128, 3, 64), (128, 16, 48),
+                (128, 4, 256)):
+        assert not ps.index_kernel_shapes(*odd), odd
 
 
 def test_the_mask_is_a_bit_a_key_at_a_tile_of_1024():

@@ -15,6 +15,24 @@ def topo():
     return described_v5e()
 
 
+def _instructions(text):
+    """(the computation's name, the line) of every instruction of a compiled
+    module's text."""
+    where = None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?(%[\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            where = head.group(1)
+        elif where and " = " in line:
+            yield where, line
+
+
+def _result(line):
+    """The result's shape of an instruction's line."""
+    found = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (.*?) [\w\-]+\(", line)
+    return found.group(1) if found else ""
+
+
 def test_sparse_step_compiles_for_v5e_under_the_chip_s_memory(topo):
     """The cell's step: four blocks of grouped-query attention (32 / 4 heads
     of 128) under a learned top-2048 index over 16 384 keys and 16 of 128
@@ -25,7 +43,12 @@ def test_sparse_step_compiles_for_v5e_under_the_chip_s_memory(topo):
     backward pass runs it never: the layer's checkpoint keeps its outputs)
     and ``hvd_sparse_mean`` twice (forward, and in each block's checkpoint
     for the KL's gradient), ``hvd_sparse_bwd`` once a layer beside
-    ``hvd_flash_adj``, and no other flash kernel; no float array with two
+    ``hvd_flash_adj``, and no other flash kernel; the index score pass's
+    backward is ``hvd_index_bwd`` once a band's backward body in two shapes
+    (half the sequence's k tiles or all), and no instruction outside a
+    fusion has a block's ``[128, 16, keys]`` products for its result, in
+    float32 or bfloat16 (the parent wrote them a band's backward body, 134
+    MB a block at 16 384 keys); no float array with two
     sequence-long dimensions, the selection's own bytes alone; no block's dk
     / dv added into a band's float32 keys; the experts are ``hvd_moe_gmm``
     (twelve calls a layer: the checkpointed forward's three run twice) and
@@ -56,6 +79,18 @@ def test_sparse_step_compiles_for_v5e_under_the_chip_s_memory(topo):
     assert count(ps.MEAN_NAME) == 2 * bands
     assert count(ps.BWD_NAME) == 1 and count(pa.ADJ_NAME) == 1
     assert count("hvd_flash") == 1                     # (the adj's)
+    index_calls = [c for c in calls if re.match(
+        r"\s*(?:ROOT )?%%%s[\w.]* = " % ps.INDEX_BWD_NAME, c)]
+    assert len(index_calls) == bands
+    assert len({re.search(r"operand_layout_constraints=\{[^}]*\}", c).group()
+                for c in index_calls}) <= 2
+    products = re.compile(r"\b(?:f32|bf16)\[%d,%d,\d{4,}\]" % (
+        ps.ROWS, shapes["index_heads"]))
+    written = [line.split(" = ")[0].strip() + " in " + where
+               for where, line in _instructions(text)
+               if "fused_computation" not in where
+               and products.search(_result(line))]
+    assert not written, written
     # the layers are one scan: its forward body's three calls, and in the
     # backward body the checkpointed block's three again and the six behind
     assert count(moe.GMM_NAME) == 12
