@@ -1213,7 +1213,7 @@ def _check_ssm(smoke: Smoke, sizes=None, cell: str = "hybrid",
                      SSM_TOL, against_the_numpy_form=_rel_err(g, n))
 
 
-def _check_delta(smoke: Smoke) -> None:
+def _check_delta(smoke: Smoke, key_heads: int = 0) -> None:
     """The gated delta rule's scan on its kernels (ops/pallas_delta.py:
     hvd_delta_scan, hvd_delta_scan_bwd, through models/delta.py:
     delta_chunked as a delta block calls it) at ``Sizes.delta``, the cell
@@ -1222,22 +1222,29 @@ def _check_delta(smoke: Smoke) -> None:
     ``jax.numpy`` form on the same operands with its matmuls at "highest",
     o and every operand's gradient; decays as fast as the cell's (the most
     negative chunk sum is printed). Prints ``delta_scan_path``. Bound as
-    ``_check_ssm``'s."""
+    ``_check_ssm``'s. With ``key_heads`` the form with a decay a head, the
+    cell qwen3-next-80b-a3b.s8192's: a float32 log decay ``[S, H]`` and q
+    and k at ``key_heads`` heads, each read by ``H / key_heads`` value
+    heads (the kernels' other bodies)."""
     import jax
     import jax.numpy as jnp
     from horovod_tpu.models import delta
     from horovod_tpu.ops import pallas_delta
     S, H, D, Dv, chunk = smoke.sizes.delta
+    Hk, what = key_heads or H, "delta scan, a decay a head" if key_heads \
+        else "delta scan"
     keys = jax.random.split(jax.random.PRNGKey(smoke.seed + 8), 7)
-    q = (delta._l2norm(jax.random.normal(keys[0], (1, S, H, D)))
+    q = (delta._l2norm(jax.random.normal(keys[0], (1, S, Hk, D)))
          * D ** -0.5).astype(jnp.bfloat16)
-    k = delta._l2norm(jax.random.normal(keys[1], (1, S, H, D))
+    k = delta._l2norm(jax.random.normal(keys[1], (1, S, Hk, D))
                       ).astype(jnp.bfloat16)
     v = jax.nn.silu(jax.random.normal(keys[2], (1, S, H, Dv))
                     ).astype(jnp.bfloat16)
     rate = jnp.exp(jax.random.uniform(keys[3], (H, 1), minval=-4.0,
                                       maxval=2.0))
     g = -rate * jax.nn.softplus(jax.random.normal(keys[4], (1, S, H, D)))
+    if key_heads:
+        g = g[..., 0]
     beta = jax.nn.sigmoid(jax.random.normal(keys[5], (1, S, H)))
     ct = jax.random.normal(keys[6], (1, S, H, Dv), jnp.float32)
     ops = (q, k, v, g, beta)
@@ -1251,22 +1258,22 @@ def _check_delta(smoke: Smoke) -> None:
 
     def loss(f):
         return lambda *ops: jnp.sum(f(*ops)[0] * ct)
-    path = pallas_delta.describe(S, H, D, Dv, chunk)
+    path = pallas_delta.describe(S, H, D, Dv, chunk, a_head=bool(key_heads))
     if smoke.on_chip:
         check(pallas_delta.delta_scan_path(S, H, D, Dv, chunk) == "kernels",
               path)
     got, low = _run_compiled(smoke, kernels, ops, pallas_delta.FWD_NAME)
     want, want_low = jax.jit(numpy_form)(*ops)
-    _kernel_line(smoke, "delta scan", "fwd", _rel_err(got, want), SSM_TOL,
-                 shape=(S, H, D, Dv, chunk), delta_scan_path=path,
+    _kernel_line(smoke, what, "fwd", _rel_err(got, want), SSM_TOL,
+                 shape=(S, H, D, Dv, chunk), key_heads=Hk,
+                 delta_scan_path=path,
                  min_log_decay=[float(low), float(want_low)])
     leaves = (0, 1, 2, 3, 4)
     got = _run_compiled(smoke, jax.grad(loss(kernels), leaves), ops,
                         (pallas_delta.FWD_NAME, pallas_delta.BWD_NAME))
     want = jax.jit(jax.grad(loss(numpy_form), leaves))(*ops)
     for leaf, g_, w in zip(("d_q", "d_k", "d_v", "d_g", "d_beta"), got, want):
-        _kernel_line(smoke, "delta scan", f"grad {leaf}", _rel_err(g_, w),
-                     SSM_TOL)
+        _kernel_line(smoke, what, f"grad {leaf}", _rel_err(g_, w), SSM_TOL)
 
 
 #: bf16 operands and a bf16 round before the out-projection against float32
@@ -1641,6 +1648,7 @@ def phase_kernels(smoke: Smoke, hvd) -> None:
     _check_gmm(smoke)
     _check_ssm(smoke)
     _check_delta(smoke)
+    _check_delta(smoke, key_heads=smoke.sizes.delta[1] // 2)
     _check_dense_hybrid(smoke)
     _check_gated_norm(smoke)
     _check_short_conv(smoke)

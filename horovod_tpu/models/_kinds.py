@@ -164,10 +164,15 @@ def rope(x, positions, theta=10000.0):
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
 
 
-def rmsnorm(x, g, eps=1e-6):
+def rmsnorm(x, g, eps=1e-6, zero_centred: bool = False):
+    """``x * rsqrt(mean(x^2) + eps) * g``, the statistics in float32. A
+    ``zero_centred`` weight's scale is ``1 + g``, formed and applied in
+    float32 (``TransformerConfig.zero_centred_norms``)."""
     var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
-    return (x.astype(jnp.float32) * jax.lax.rsqrt(var + eps)
-            ).astype(x.dtype) * g.astype(x.dtype)
+    normed = x.astype(jnp.float32) * jax.lax.rsqrt(var + eps)
+    if zero_centred:
+        return (normed * (1.0 + g.astype(jnp.float32))).astype(x.dtype)
+    return normed.astype(x.dtype) * g.astype(x.dtype)
 
 
 def layernorm(x, g, b, eps=1e-6):

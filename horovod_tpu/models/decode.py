@@ -40,7 +40,8 @@ _PLAIN_FIELDS = (
     "moe_router_scores", "moe_shared_width", "ssm_heads", "kv_latent",
     "conv_taps", "lead_pattern", "mtp_depth", "embed_scale", "residual_scale",
     "attention_scale", "logits_scale", "index_topk", "delta_heads",
-    "latent_rope", "value_width")
+    "latent_rope", "value_width", "delta_decay", "delta_key_heads",
+    "moe_shared_gate", "zero_centred_norms")
 _PLAIN = TransformerConfig()
 
 

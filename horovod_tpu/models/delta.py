@@ -1,6 +1,8 @@
-"""A gated delta-rule mixer with a decay a channel (Kimi Delta Attention,
-arXiv:2510.26692 section 3; the public implementation is
-``fla/layers/kda.py`` of ``fla-org/flash-linear-attention``) as
+"""A gated delta-rule mixer in its two published forms, chosen by
+``delta_decay``: a decay a channel (Kimi Delta Attention, arXiv:2510.26692
+section 3; the public implementation is ``fla/layers/kda.py`` of
+``fla-org/flash-linear-attention``) and a decay a head (Gated DeltaNet,
+arXiv:2412.06464, as ``transformers``' ``qwen3_next`` model builds it), as
 ``models/transformer.py``'s ``("delta",)`` blocks, :data:`KIND` in its table
 of block kinds: the leaves, the block, and the recurrence in its chunked
 form. Per head, keys and values ``delta_head_dim`` wide, the state ``S``
@@ -26,6 +28,19 @@ shapes, the whole sequence on this device (no sp, pp or tp), ``H`` heads of
   beta            ``sigmoid(h w_beta)``, ``w_beta`` ``[M, H]``: a scalar a head
   output          ``rmsnorm(o; norm [D]) * sigmoid((h wg_down) wg_up)`` a
                   head, then ``wo`` ``[H D, M]``
+**A decay a head** (``delta_decay="head"``): ``alpha_t`` is one scalar a
+value head, ``Diag(alpha_t)`` a multiple of the identity; ``delta_key_heads``
+key heads ``Hk`` are read by ``H`` value heads (value head ``h`` reads key
+head ``h // (H / Hk)``), and the block is Gated DeltaNet's:
+  w_in            [M, 2 Hk D + 2 H D]: q | k | v | z in one matrix; one causal
+                  depthwise convolution (``conv`` ``[tap, 2 Hk D + H D]``, no
+                  bias) over q | k | v, then silu; q and k L2-normed a key
+                  head, q times ``D^-1/2``
+  w_ba            [M, 2 H]: ``beta = sigmoid(b)``, ``g = -exp(a_log)
+                  softplus(a + dt_bias)`` float32 ``[B, S, H]``, ``a_log``
+                  and ``dt_bias`` ``[H]``
+  output          ``rmsnorm(o; norm [D]) * silu(z)`` a value head (``norm``
+                  is never zero-centred), then ``wo`` ``[H D, M]``
 The scan is :func:`delta_chunked`: on a TPU at whole chunks and heads of
 whole lane tiles two Pallas kernels (``ops/pallas_delta.py``:
 ``hvd_delta_scan``, ``hvd_delta_scan_bwd``, a chunk's pairs, inverse, ``W``,
@@ -44,7 +59,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from horovod_tpu.models._kinds import (BlockKind, Leaf, normal, ones,
-                                       rmsnorm, scaled)
+                                       rmsnorm, scaled, zeros)
 from horovod_tpu.models.mamba import (_causal_conv, draw_a_log,
                                       draw_dt_bias, draw_taps)
 from horovod_tpu.ops import pallas_delta
@@ -59,15 +74,30 @@ L2_EPS = 1e-6
 _HIGHEST = lax.Precision.HIGHEST
 
 
+def _key_heads(cfg) -> int:
+    return cfg.delta_key_heads or cfg.delta_heads
+
+
 def _leaves(cfg):
     """A delta block's leaves, in the order they are drawn (the decay's
     bias and rate first, as a Mamba block's time step is)."""
     M, H, D, K = cfg.d_model, cfg.delta_heads, cfg.delta_head_dim, \
         cfg.delta_taps
     dt_bias, a_log, taps = draw_dt_bias, draw_a_log, draw_taps(K)
+    if cfg.delta_decay == "head":
+        keys = _key_heads(cfg) * D
+        yield Leaf("dt_bias", (H,), dt_bias)
+        yield Leaf("a_log", (H,), a_log)
+        yield Leaf("ln1", (M,), zeros if cfg.zero_centred_norms else ones)
+        yield Leaf("w_in", (M, 2 * keys + 2 * H * D), normal())
+        yield Leaf("conv", (K, 2 * keys + H * D), taps)
+        yield Leaf("w_ba", (M, 2 * H), normal())
+        yield Leaf("norm", (D,), ones)
+        yield Leaf("wo", (H * D, M), normal())
+        return
     yield Leaf("dt_bias", (H * D,), dt_bias)
     yield Leaf("a_log", (H,), a_log)
-    yield Leaf("ln1", (M,), ones)
+    yield Leaf("ln1", (M,), zeros if cfg.zero_centred_norms else ones)
     for name in ("wq", "wk", "wv"):
         yield Leaf(name, (M, H * D), normal())
     for name in ("conv_q", "conv_k", "conv_v"):
@@ -216,6 +246,23 @@ def _pairs(qb, kb, gamma_b, gamma, k):
     return whole(qb), whole(kb)
 
 
+def _pairs_a_head(q, k, gamma):
+    """:func:`_pairs` for a decay a head: the scalar factors out of the
+    products, ``(x_i . k_j) exp(Gamma_i - Gamma_j)``, one matmul of operands
+    in the compute dtype and a ``[C, C]`` float32 factor with no positive
+    exponent (nothing under the diagonal is read above it). q, k ``[.., C,
+    D]``, gamma ``[.., C]``."""
+    C = gamma.shape[-1]
+    lower = jnp.tril(jnp.ones((C, C), bool))
+    factor = _decay(jnp.where(lower, gamma[..., :, None] - gamma[..., None, :],
+                              -jnp.inf))
+
+    def whole(x):
+        return factor * jnp.einsum("...id,...jd->...ij", x, k,
+                                   preferred_element_type=jnp.float32)
+    return whole(q), whole(k)
+
+
 def delta_chunked(q, k, v, g, beta, chunk: int, sub: int = SUB,
                   interpret: bool = False):
     """The gated delta rule of the module's docstring in its chunked form
@@ -223,8 +270,12 @@ def delta_chunked(q, k, v, g, beta, chunk: int, sub: int = SUB,
     ``ops/pallas_delta.py`` where :func:`pallas_delta.delta_scan_path` says
     so from the backend and the shapes alone (a TPU, whole chunks, heads of
     whole lane tiles), else in ``jax.numpy``. ``interpret`` runs the kernels
-    off the chip (tests). Same arguments, same results."""
-    _, S, H, D = q.shape
+    off the chip (tests). Same arguments, same results. A ``g`` ``[B, S,
+    H]`` is a decay a head, and q and k may then have fewer heads than v
+    (value head ``h`` reads key head ``h // (H / Hk)``): the kernels read a
+    key head's block for its value heads and ``g`` as it comes."""
+    S, D = q.shape[1], q.shape[3]
+    H = v.shape[2]
     if interpret or pallas_delta.delta_scan_path(
             S, H, D, v.shape[-1], chunk, q.dtype, sub) == "kernels":
         o, last = _scan_kernels(q, k, v, g, beta, chunk, sub, interpret)
@@ -262,8 +313,20 @@ def _delta_chunked_numpy(q, k, v, g, beta, chunk: int, sub: int = SUB):
     q, k ``[B, S, H, D]``, v ``[B, S, H, Dv]``; g ``[B, S, H, D]`` float32,
     never positive; beta ``[B, S, H]`` float32. Returns (o ``[B, S, H, Dv]``
     float32, the most negative ``Gamma_C`` of any chunk, head and
-    channel)."""
-    B, S, H, D = q.shape
+    channel).
+
+    A decay a head, g ``[B, S, H]``: ``Gamma`` is a scalar a row, every
+    ``exp`` above a number a row (or a pair of rows: :func:`_pairs_a_head`),
+    and q, k ``[B, S, Hk, D]`` are repeated to the value heads that read
+    them."""
+    B, S, H = beta.shape
+    D = q.shape[-1]
+    a_head = g.ndim == 3
+    if q.shape[2] != H:
+        if not a_head or H % q.shape[2]:
+            raise ValueError(f"{q.shape[2]} key heads for {H} value heads "
+                             "with a decay a channel")
+        q, k = (jnp.repeat(x, H // x.shape[2], axis=2) for x in (q, k))
     if S % chunk:
         raise ValueError(f"delta_chunk={chunk} does not divide the sequence "
                          f"of {S} positions")
@@ -282,7 +345,11 @@ def _delta_chunked_numpy(q, k, v, g, beta, chunk: int, sub: int = SUB):
     q, k, v, g = chunks(q), chunks(k), chunks(v), chunks(g)
     beta = chunks(beta)                                     # [n, B, H, C]
     gamma = jnp.cumsum(g, axis=3)
-    p, a = _pairs(blocks(q), blocks(k), blocks(gamma), gamma, k)
+    if a_head:
+        p, a = _pairs_a_head(q, k, gamma)
+        gamma = gamma[..., None]        # a row's one factor over its channels
+    else:
+        p, a = _pairs(blocks(q), blocks(k), blocks(gamma), gamma, k)
     t = unit_lower_inverse(jnp.tril(a, -1) * beta[..., None], sub)
     t = (t * beta[..., None, :]).astype(dtype)
     since_start = _decay(gamma)                             # exp(Gamma_i)
@@ -332,13 +399,35 @@ def _l2norm(x):
                          + L2_EPS)
 
 
+def _scan_to_residual(p, x, q, k, v, g, beta, gate, activation, cfg):
+    """What both forms share, called under the mixer's scope: the scan, the
+    head's RMSNorm on its output times ``activation(gate)`` (gate ``[B, S,
+    H D]``), the out-projection and the residual add; and the step's most
+    negative ``Gamma_C``."""
+    B, S, H, D = v.shape
+    with scopes.scope(scopes.DELTA_SCAN):
+        o, min_log_decay = delta_chunked(q, k, v, g, beta, cfg.delta_chunk)
+    with scopes.scope(scopes.DELTA_NORM):
+        var = jnp.mean(jnp.square(o), axis=-1, keepdims=True)
+        y = (o * lax.rsqrt(var + cfg.norm_eps)
+             * p["norm"].astype(jnp.float32)
+             * activation(gate.astype(jnp.float32)).reshape(B, S, H, D))
+        y = y.reshape(B, S, H * D).astype(x.dtype)
+    with scopes.scope(scopes.DELTA_PROJ):
+        out = y @ p["wo"].astype(x.dtype)
+    return (x + scaled(out, cfg.residual_scale),
+            {"delta_min_log_decay": min_log_decay})
+
+
 def _delta_block(p, x, cfg):
     """``x + delta(norm(x))``, x ``[B', S', M]`` with the whole sequence
     here (no sp); and the step's most negative ``Gamma_C``."""
+    if cfg.delta_decay == "head":
+        return _delta_block_a_head(p, x, cfg)
     B, S, _ = x.shape
     H, D = cfg.delta_heads, cfg.delta_head_dim
     with scopes.scope(scopes.DELTA):
-        h = rmsnorm(x, p["ln1"], cfg.norm_eps)
+        h = rmsnorm(x, p["ln1"], cfg.norm_eps, cfg.zero_centred_norms)
         with scopes.scope(scopes.DELTA_PROJ):
             q, k, v = (h @ p[name].astype(h.dtype)
                        for name in ("wq", "wk", "wv"))
@@ -363,20 +452,37 @@ def _delta_block(p, x, cfg):
                 preferred_element_type=jnp.float32))
             gate = (h @ p["wg_down"].astype(h.dtype)
                     ) @ p["wg_up"].astype(h.dtype)
-        with scopes.scope(scopes.DELTA_SCAN):
-            o, min_log_decay = delta_chunked(q, k, v, g, beta,
-                                             cfg.delta_chunk)
-        with scopes.scope(scopes.DELTA_NORM):
-            var = jnp.mean(jnp.square(o), axis=-1, keepdims=True)
-            y = (o * lax.rsqrt(var + cfg.norm_eps)
-                 * p["norm"].astype(jnp.float32)
-                 * jax.nn.sigmoid(gate.astype(jnp.float32)
-                                  ).reshape(B, S, H, D))
-            y = y.reshape(B, S, H * D).astype(h.dtype)
+        return _scan_to_residual(p, x, q, k, v, g, beta, gate,
+                                 jax.nn.sigmoid, cfg)
+
+
+def _delta_block_a_head(p, x, cfg):
+    """:func:`_delta_block` with a decay a head (the module's docstring):
+    one in-projection, one convolution over q | k | v, ``b`` and ``a`` from
+    one projection, the output norm times ``silu(z)``."""
+    B, S, _ = x.shape
+    H, Hk, D = cfg.delta_heads, _key_heads(cfg), cfg.delta_head_dim
+    keys = Hk * D
+    with scopes.scope(scopes.DELTA):
+        h = rmsnorm(x, p["ln1"], cfg.norm_eps, cfg.zero_centred_norms)
         with scopes.scope(scopes.DELTA_PROJ):
-            out = y @ p["wo"].astype(h.dtype)
-        return (x + scaled(out, cfg.residual_scale),
-                {"delta_min_log_decay": min_log_decay})
+            qkvz = h @ p["w_in"].astype(h.dtype)
+            qkv, z = qkvz[..., :2 * keys + H * D], qkvz[..., 2 * keys + H * D:]
+        with scopes.scope(scopes.DELTA_CONV):
+            qkv = _short_conv(qkv, p["conv"])
+            q = qkv[..., :keys].reshape(B, S, Hk, D)
+            k = qkv[..., keys:2 * keys].reshape(B, S, Hk, D)
+            q = (_l2norm(q) * D ** -0.5).astype(h.dtype)
+            k = _l2norm(k).astype(h.dtype)
+            v = qkv[..., 2 * keys:].reshape(B, S, H, D).astype(h.dtype)
+        with scopes.scope(scopes.DELTA_GATES):
+            ba = jnp.matmul(h, p["w_ba"].astype(h.dtype),
+                            preferred_element_type=jnp.float32)
+            beta = jax.nn.sigmoid(ba[..., :H])
+            g = -(jnp.exp(p["a_log"].astype(jnp.float32))
+                  * jax.nn.softplus(ba[..., H:]
+                                    + p["dt_bias"].astype(jnp.float32)))
+        return _scan_to_residual(p, x, q, k, v, g, beta, z, jax.nn.silu, cfg)
 
 
 def _validate(cfg) -> None:
@@ -386,6 +492,16 @@ def _validate(cfg) -> None:
             f"{cfg.delta_heads}, delta_taps={cfg.delta_taps}, delta_chunk="
             f"{cfg.delta_chunk}: the mixer has at least one head, one tap "
             "and one position a chunk")
+    if cfg.delta_decay not in ("channel", "head"):
+        raise ValueError(f"delta_decay={cfg.delta_decay!r}: a decay a "
+                         "\"channel\" or a \"head\"")
+    if cfg.delta_heads % _key_heads(cfg) or (
+            cfg.delta_key_heads and cfg.delta_decay != "head"):
+        raise ValueError(
+            f"delta_key_heads={cfg.delta_key_heads} with delta_heads="
+            f"{cfg.delta_heads}, delta_decay={cfg.delta_decay!r}: key heads "
+            "divide the value heads, and the form with a decay a channel "
+            "has a key head a value head")
     if cfg.n_loops > 1:
         raise NotImplementedError(
             f"a (\"delta\",) block with n_loops={cfg.n_loops}: a looped "
@@ -403,7 +519,8 @@ KIND = BlockKind(
     checkpointed=True, refuses=("sp", "pp", "tp"),
     refusal="the convolutions and the scan's carried state run over the "
             "whole sequence on one device (no hand-over of the last taps "
-            "and of the state between sp shards), its heads are not split "
-            "over tp, and no pipeline schedule has run it (its stages "
+            "and of the state between sp shards), its heads (with a decay a "
+            "head: the value heads and the key heads they share) are not "
+            "split over tp, and no pipeline schedule has run it (its stages "
             "carry one auxiliary column, the experts', not a mixer's own "
             "terms)")

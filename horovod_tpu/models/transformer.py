@@ -51,7 +51,9 @@ Layout conventions (local = per-device shapes):
                   a table of the kind's own (``_kinds.Rope``: a theta, the
                   rotated part of the head, YaRN); ``heads`` query heads
                   where they are not ``n_heads`` and ``gated``, a sigmoid
-                  gate a head on the core's output (``wg``), change the
+                  gate on the core's output, a head (True: ``wg``) or a
+                  channel ("channel": the second half of a ``wq`` twice as
+                  wide, split a head), change the
                   block's leaves, so such kinds are stacks of their own
                   (``layers["attention_64_gated"]``) and two attention
                   shapes share one scan over periods (Laguna)
@@ -153,8 +155,11 @@ class TransformerConfig:
     #                             may be a ``_kinds.Rope`` table; an attention
     #                             kind may go on (.., heads, gated): its own
     #                             number of query heads (None: ``n_heads``)
-    #                             and a sigmoid gate a head on the core's
-    #                             output, read from the block's normed input
+    #                             and a sigmoid gate on the core's output,
+    #                             read from the block's normed input: True a
+    #                             logit a head, "channel" a logit a channel,
+    #                             the second half of each head's columns of a
+    #                             query projection twice as wide
     moe_router_input: str = "tokens"    # what the router's logits are
     #                             computed from: the normed tokens the experts
     #                             get ("tokens"), or the block's input, before
@@ -175,6 +180,8 @@ class TransformerConfig:
     #                             (``ws1``, ``ws2``, and ``ws3`` where they
     #                             are gated; ``moe_activation``), whole on
     #                             every device that holds a share of the others
+    moe_shared_gate: bool = False   # the shared expert's output times the
+    #                             sigmoid of one logit a token (``ws_gate``)
     # -- latent attention (``models/latent.py``), where the pattern has it --
     q_latent: int = 0           # channels of the queries' latent; 0: none,
     #                             the queries come from the block's input
@@ -229,6 +236,16 @@ class TransformerConfig:
     #                             on q, k and v
     delta_chunk: int = 64       # positions a chunk of the scan; the sequence
     #                             is whole chunks
+    delta_decay: str = "channel"    # the state's decay a position: a vector
+    #                             over a head's key channels, or one scalar a
+    #                             "head": Gated DeltaNet's block (one fused
+    #                             in-projection and convolution, ``b`` and
+    #                             ``a`` from one direct projection, the output
+    #                             norm times silu of a full-rank ``z``)
+    delta_key_heads: Optional[int] = None   # key heads where they are fewer
+    #                             than ``delta_heads``, which then counts the
+    #                             value heads: value head h reads key head
+    #                             h // (delta_heads // delta_key_heads)
     # -- a learned index over the keys (DeepSeek-V3.2, arXiv:2512.02556
     # section 2.1; ``ops/sparse_attention.py``), beside grouped-query
     # attention in every block of two sublayers ------------------------------
@@ -263,6 +280,11 @@ class TransformerConfig:
     #                             state is, before the head: one [tokens, M]
     #                             multiply, no second array of logits)
     norm_eps: float = 1e-6
+    zero_centred_norms: bool = False    # the norms' scale is ``1 + w`` in
+    #                             float32 and ``w`` drawn as zeros (weight
+    #                             decay pulls the scale to 1): the blocks' and
+    #                             the final norm and a head's q and k norm,
+    #                             not a mixer's output norm
     rope_theta: float = 10000.0
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
@@ -343,10 +365,31 @@ class TransformerConfig:
         for word in words:
             _BLOCK_KINDS[word].validate(self)
         for kind in kinds:
-            heads = _kind_heads(self, kind)
-            if heads % self.kv_heads:
+            heads, gated = _kind_fields(kind)
+            if (heads or self.n_heads) % self.kv_heads:
                 raise ValueError(f"n_kv_heads={self.kv_heads} does not "
-                                 f"divide the {heads} heads of {kind}")
+                                 f"divide the {heads or self.n_heads} heads "
+                                 f"of {kind}")
+            if gated not in (False, True, "channel") or (
+                    gated == "channel" and self.qk_norm is True):
+                raise ValueError(
+                    f"{kind}: an attention block's gate is a head's (True) "
+                    "or a \"channel\"'s, and a gate a channel shares the "
+                    "query projection, which qk_norm=True would norm whole")
+        unsupported = [
+            name for name, on in (
+                ("post_norm", self.post_norm),
+                ("qk_norm=True", self.qk_norm is True),
+                ("n_loops", self.n_loops > 1), ("mtp_depth", self.mtp_depth),
+                ("index_topk", self.index_topk),
+                *((f'("{word}",) blocks', word in words)
+                  for word in ("mamba", "latent", "conv"))) if on]
+        if self.zero_centred_norms and unsupported:
+            raise NotImplementedError(
+                f"zero_centred_norms with {', '.join(unsupported)}: "
+                "zero-centred weights are built "
+                "for the attention, delta and FFN blocks' norms, a head's q "
+                "and k norm and the final norm")
         if self.index_topk:
             if self.index_heads <= 0 or self.index_head_dim <= 0 \
                     or self.index_head_dim % 2:
@@ -520,14 +563,14 @@ def _head_xent(x, head, targets, scale: float = 1.0):
     return head_softmax_xent(x, head, targets)
 
 
-def _kind_fields(kind) -> Tuple[Optional[int], bool]:
+def _kind_fields(kind) -> Tuple[Optional[int], Any]:
     """What an attention kind gives beyond (window, rope): (its query heads
-    or None, whether its core's output is gated); (None, False) for a kind
-    that names neither and for any other kind."""
+    or None, how its core's output is gated: False, True a head, "channel");
+    (None, False) for a kind that names neither and for any other kind."""
     if kind[0] != "attention":
         return None, False
     heads, gated = (tuple(kind[3:]) + (None, False))[:2]
-    return heads, bool(gated)
+    return heads, gated or False
 
 
 def _kind_heads(cfg: TransformerConfig, kind) -> int:
@@ -537,20 +580,23 @@ def _kind_heads(cfg: TransformerConfig, kind) -> int:
 
 def _attention_leaves(cfg: TransformerConfig, kind=("attention",)):
     M = cfg.d_model
-    heads = _kind_heads(cfg, kind)
+    heads, gated = _kind_heads(cfg, kind), _kind_fields(kind)[1]
     q, kv = heads * cfg.head_dim, cfg.kv_heads * cfg.head_dim
-    yield Leaf("ln1", (M,), ones)
-    yield Leaf("wq", (M, q), normal(), (None, "tp"))
+    norm = zeros if cfg.zero_centred_norms else ones
+    yield Leaf("ln1", (M,), norm)
+    # with a gate a channel a head's columns are its query then its gate
+    yield Leaf("wq", (M, 2 * q if gated == "channel" else q), normal(),
+               (None, "tp"))
     yield Leaf("wk", (M, kv), normal(), (None, "tp"))
     yield Leaf("wv", (M, kv), normal(), (None, "tp"))
     yield Leaf("wo", (q, M), normal(), ("tp", None))
-    if _kind_fields(kind)[1]:
+    if gated is True:
         # one logit a head and position: the core's output times its sigmoid
         yield Leaf("wg", (M, heads), normal(), (None, "tp"))
     if cfg.qk_norm == "head":
         # one weight for all the heads: whole on every tp shard
-        yield Leaf("q_norm", (cfg.head_dim,), ones)
-        yield Leaf("k_norm", (cfg.head_dim,), ones)
+        yield Leaf("q_norm", (cfg.head_dim,), norm)
+        yield Leaf("k_norm", (cfg.head_dim,), norm)
     elif cfg.qk_norm:
         yield Leaf("q_norm", (q,), ones, ("tp",))
         yield Leaf("k_norm", (kv,), ones, ("tp",))
@@ -603,7 +649,8 @@ def _attention_sublayer(p, x, positions, cfg: TransformerConfig,
     """x: [B', S', M] local. Heads sharded over tp; sequence over sp.
     ``kind``: the layer's (window or None, rope or not or a
     :class:`Rope` table of the kind's own); the block's query heads are its
-    ``wq``'s, and with a ``wg`` its core's output is gated a head. Returns
+    ``wq``'s, and with a ``wg`` its core's output is gated a head, with a
+    ``wq`` twice as wide as ``wo`` is tall a channel. Returns
     (the new residual, the sublayer's auxiliary terms: None, or with a
     learned index (``cfg.index_topk``) its ``index_loss``, the
     ``selected_keys`` a query attended on average and the ``selection``'s
@@ -627,8 +674,13 @@ def _attention_sublayer(p, x, positions, cfg: TransformerConfig,
             "whole k/v blocks of n_heads heads round the ring, masks by the "
             "diagonal only and scales by 1 / sqrt(head width)")
     with scopes.scope(scopes.ATTENTION):
-        h = rmsnorm(x, p["ln1"], cfg.norm_eps)
+        h = rmsnorm(x, p["ln1"], cfg.norm_eps, cfg.zero_centred_norms)
         q = (h @ p["wq"].astype(h.dtype))
+        channel_gate = None
+        if p["wq"].shape[-1] == 2 * p["wo"].shape[-2]:
+            q = q.reshape(B, S, -1, 2 * cfg.head_dim)
+            q, channel_gate = (q[..., :cfg.head_dim].reshape(B, S, -1),
+                               q[..., cfg.head_dim:])
         k = (h @ p["wk"].astype(h.dtype))
         v = (h @ p["wv"].astype(h.dtype))
         if cfg.qk_norm is True:
@@ -639,8 +691,8 @@ def _attention_sublayer(p, x, positions, cfg: TransformerConfig,
         k = k.reshape(B, S, k.shape[-1] // cfg.head_dim, cfg.head_dim)
         v = v.reshape(B, S, v.shape[-1] // cfg.head_dim, cfg.head_dim)
         if cfg.qk_norm == "head":
-            q = rmsnorm(q, p["q_norm"], cfg.norm_eps)
-            k = rmsnorm(k, p["k_norm"], cfg.norm_eps)
+            q = rmsnorm(q, p["q_norm"], cfg.norm_eps, cfg.zero_centred_norms)
+            k = rmsnorm(k, p["k_norm"], cfg.norm_eps, cfg.zero_centred_norms)
         if roped:
             table = roped if isinstance(roped, Rope) else cfg.rope_theta
             q = rope(q, positions, table)
@@ -676,6 +728,10 @@ def _attention_sublayer(p, x, positions, cfg: TransformerConfig,
                 gate = jax.nn.sigmoid(
                     (h @ p["wg"].astype(h.dtype)).astype(jnp.float32))
                 o = o * gate[..., None].astype(o.dtype)
+        if channel_gate is not None:
+            with scopes.scope(scopes.ATTENTION_GATE):
+                o = o * jax.nn.sigmoid(channel_gate.astype(jnp.float32)
+                                       ).astype(o.dtype)
         o = o.reshape(B, S, Hl * cfg.head_dim) @ p["wo"].astype(x.dtype)
         o = _psum_if(o, "tp")
         if cfg.post_norm:
@@ -695,7 +751,7 @@ def _ffn_leaves(cfg: TransformerConfig, routed: Optional[bool] = None):
     routed = _routed(cfg, routed)
     F = cfg.d_ff if routed or cfg.dense_ff is None else cfg.dense_ff
     shared = cfg.moe_shared_width
-    yield Leaf("ln2", (M,), ones)
+    yield Leaf("ln2", (M,), zeros if cfg.zero_centred_norms else ones)
     if cfg.post_norm:
         yield Leaf("ln2_post", (M,), ones)
     if routed:
@@ -716,6 +772,8 @@ def _ffn_leaves(cfg: TransformerConfig, routed: Optional[bool] = None):
             yield Leaf("ws2", (shared, M), normal(), ("tp", None))
             if cfg.moe_gated:
                 yield Leaf("ws3", (M, shared), normal(), (None, "tp"))
+            if cfg.moe_shared_gate:
+                yield Leaf("ws_gate", (M, 1), normal())
     else:
         # w1 is the gate of a gated FFN and w3 its up projection, as the
         # experts number theirs
@@ -808,12 +866,19 @@ def _shared_expert(p, toks, activation):
     ``down(activation(up(toks)))``, or with a ``ws3`` (gated experts)
     ``down(activation(gate(toks)) * up(toks))``, ``ws1`` the gate; dense
     matmuls over all the tokens (inner width over tp, the caller's psum);
-    the same on every device that holds a share of the others."""
+    the same on every device that holds a share of the others. With a
+    ``ws_gate`` (``moe_shared_gate``) its output times the sigmoid of one
+    logit a token, ``toks . ws_gate``."""
     with scopes.scope(scopes.MOE_SHARED):
         h = activation(toks @ p["ws1"].astype(toks.dtype))
         if "ws3" in p:
             h = h * (toks @ p["ws3"].astype(toks.dtype))
-        return h @ p["ws2"].astype(toks.dtype)
+        out = h @ p["ws2"].astype(toks.dtype)
+        if "ws_gate" in p:
+            logit = jnp.matmul(toks, p["ws_gate"].astype(toks.dtype),
+                               preferred_element_type=jnp.float32)
+            out = out * jax.nn.sigmoid(logit).astype(out.dtype)
+        return out
 
 
 def _no_aux():
@@ -836,7 +901,7 @@ def _ffn_block(p, x, cfg: TransformerConfig, logits=None, routed=None):
     ``logits``: a router's that read something else than the normed
     tokens."""
     with scopes.scope(scopes.MLP):
-        h = rmsnorm(x, p["ln2"], cfg.norm_eps)
+        h = rmsnorm(x, p["ln2"], cfg.norm_eps, cfg.zero_centred_norms)
         if _routed(cfg, routed):
             o, aux = _moe_ffn(p, h, cfg, logits)
         else:
@@ -918,7 +983,8 @@ def _stack_of(kind) -> Optional[str]:
         return None
     heads, gated = _kind_fields(kind)
     return "_".join([kind[0]] + [str(heads)] * (heads is not None)
-                    + ["gated"] * gated)
+                    + ["gated"] * bool(gated)
+                    + ["channel"] * (gated == "channel"))
 
 
 def _row(kind) -> BlockKind:
@@ -946,7 +1012,7 @@ def _model_leaves(cfg: TransformerConfig):
     """The leaves beside the stack of blocks, drawn after it."""
     M, V = cfg.d_model, cfg.vocab_size
     yield Leaf("embed", (V, M), normal(0.02), ("tp",))
-    yield Leaf("ln_f", (M,), ones)
+    yield Leaf("ln_f", (M,), zeros if cfg.zero_centred_norms else ones)
     if not cfg.tie_embeddings:
         yield Leaf("lm_head", (M, V), normal(), (None, "tp"))
     if cfg.n_loops > 1:
@@ -1132,7 +1198,7 @@ def forward_loss_spmd(params, tokens, targets, cfg: TransformerConfig):
             aux_total = {**aux_total, **exits}
         else:
             state = x       # what a prediction module reads: before ln_f
-            x = rmsnorm(x, params["ln_f"], cfg.norm_eps)
+            x = rmsnorm(x, params["ln_f"], cfg.norm_eps, cfg.zero_centred_norms)
             nll = _head_xent(x, head, targets, cfg.logits_scale)    # [B,S]
             loss = jnp.mean(nll)
     if beside:

@@ -93,6 +93,13 @@ VMEM_BUDGET = 16 * 1024 * 1024
 #: kernel's text and compile time grow with it) and their chains of
 #: dependent ``HIGHEST`` matmuls interleave
 HEAD_TILE = 2
+#: and with a decay a head, whose bodies are shorter and keep no scratch
+#: beside the state: at the cell qwen3-next-80b-a3b.s8192's shape a forward
+#: call and a forward + backward pair take 8.07 / 19.62 ms at one value head a
+#: step, 5.54 / 12.96 at two and 4.46 / 10.60 at four (host clock over ten
+#: calls back to back, my chip run, PR 70: the chains of two key heads' four
+#: value heads interleave), for 0.6 s more of lowering a shape
+HEAD_TILE_A_HEAD = 4
 
 _HIGHEST = lax.Precision.HIGHEST
 _NT = (((1,), (1,)), ((), ()))      # a · bᵀ
@@ -138,10 +145,11 @@ def delta_vmem_bytes(chunk: int, D: int, Dv: int, R: int, itemsize: int,
 
 
 def delta_head_tile(H: int, D: int, Dv: int, chunk: int,
-                    itemsize: int = 2) -> int:
-    """Heads one grid step works on: the most up to :data:`HEAD_TILE` that
-    divide ``H`` and fit :data:`VMEM_BUDGET`; 0 where one head does not."""
-    for R in range(min(HEAD_TILE, H), 0, -1):
+                    itemsize: int = 2, most: int = HEAD_TILE) -> int:
+    """Heads one grid step works on: the most up to ``most`` (:data:`HEAD_TILE`;
+    :data:`HEAD_TILE_A_HEAD` for a decay a head) that divide ``H`` and fit
+    :data:`VMEM_BUDGET`; 0 where one head does not."""
+    for R in range(min(most, H), 0, -1):
         if H % R == 0 and delta_vmem_bytes(chunk, D, Dv, R,
                                            itemsize) <= VMEM_BUDGET:
             return R
@@ -170,15 +178,16 @@ def delta_scan_path(S: int, H: int, D: int, Dv: int, chunk: int,
 
 
 def describe(S: int, H: int, D: int, Dv: int, chunk: int,
-             dtype=jnp.bfloat16, sub: int = 16) -> str:
+             dtype=jnp.bfloat16, sub: int = 16, a_head: bool = False) -> str:
     """:func:`delta_scan_path` with the kernels' grid and blocks (what
-    ``chip_smoke.py`` prints)."""
+    ``chip_smoke.py`` prints); ``a_head``: the form with a decay a head."""
     path = delta_scan_path(S, H, D, Dv, chunk, dtype, sub)
     if path != "kernels":
         return f"xla (backend {jax.default_backend()}, heads of {D} / {Dv}, " \
                f"chunks of {chunk})"
     itemsize = jnp.dtype(dtype).itemsize
-    R = delta_head_tile(H, D, Dv, chunk, itemsize)
+    R = delta_head_tile(H, D, Dv, chunk, itemsize,
+                        HEAD_TILE_A_HEAD if a_head else HEAD_TILE)
     return (f"kernels {FWD_NAME} / {BWD_NAME}: grid ({H // R} head tiles of "
             f"{R}, {S // chunk} chunks), q, k, g blocks {chunk}x{R * D}, "
             f"sub-blocks of {min(sub, chunk)} rows, carried state "
@@ -438,29 +447,51 @@ class _Layout(NamedTuple):
     chunk: int
     sub: int
     R: int      # heads a grid step works on
+    Hk: int     # key heads: H, or fewer with a decay a head
 
     @property
     def n(self) -> int:
         return self.S // self.chunk
 
     @property
+    def group(self) -> int:
+        """Value heads that read one key head."""
+        return self.H // self.Hk
+
+    @property
+    def key_tile(self) -> int:
+        """Key heads a grid step's value heads read."""
+        return max(1, self.R // self.group)
+
+    @property
     def steps(self) -> int:
         return self.H // self.R
 
 
-def _layout(q, v, chunk: int, sub: int,
-            head_tile: Optional[int] = None) -> _Layout:
-    B, S, H, D = q.shape
-    Dv = v.shape[-1]
+def _layout(q, v, chunk: int, sub: int, head_tile: Optional[int] = None,
+            a_head: bool = False) -> _Layout:
+    B, S, Hk, D = q.shape
+    H, Dv = v.shape[2:]
     sub = min(sub, chunk)
     if S % chunk or chunk % sub:
         raise ValueError(f"chunk {chunk} does not divide {S} positions, or "
                          f"sub-blocks of {sub} rows the chunk")
-    R = head_tile or delta_head_tile(H, D, Dv, chunk, q.dtype.itemsize)
-    if not R or H % R:
+    if H % Hk:
+        raise ValueError(f"{Hk} key heads do not divide {H} value heads")
+    group = H // Hk
+
+    def splits(R):  # a step's value heads are whole groups or within one
+        return R % group and group % R
+    R = head_tile or delta_head_tile(
+        H, D, Dv, chunk, q.dtype.itemsize,
+        HEAD_TILE_A_HEAD if a_head else HEAD_TILE)
+    if R and not head_tile and splits(R):
+        R = 1
+    if not R or H % R or splits(R):
         raise ValueError(f"a head tile of {R} heads does not divide {H} "
-                         f"heads of {D} / {Dv} at chunks of {chunk}")
-    return _Layout(B, S, H, D, Dv, chunk, sub, R)
+                         f"heads of {D} / {Dv} on {Hk} key heads at chunks "
+                         f"of {chunk}")
+    return _Layout(B, S, H, D, Dv, chunk, sub, R, Hk)
 
 
 def _flat(x, lay: _Layout):
@@ -494,7 +525,12 @@ def _forward(q, k, v, g, beta, chunk, sub, interpret, head_tile, save: bool):
     """(o ``[B, S, H, Dv]`` float32, every chunk's last ``Gamma`` ``[B, n,
     1, H D]`` float32, the states the chunks start from ``[B, n, H, Dv, D]``
     float32 if ``save`` else None)."""
-    lay = _layout(q, v, chunk, sub, head_tile)
+    lay = _layout(q, v, chunk, sub, head_tile, g.ndim == 3)
+    if g.ndim == 3:
+        return _forward_a_head(q, k, v, g, beta, lay, interpret, save)
+    if lay.group != 1:
+        raise ValueError(f"{lay.Hk} key heads for {lay.H} value heads with "
+                         "a decay a channel")
     wide, vwide, col, at_start, last = _specs(lay, lambda j: j)
     shapes = [
         jax.ShapeDtypeStruct((lay.B, lay.S, lay.H * lay.Dv), _F32),
@@ -667,7 +703,9 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, do_ref, start_ref,
 
 
 def _backward(q, k, v, g, beta, states, do, chunk, sub, interpret, head_tile):
-    lay = _layout(q, v, chunk, sub, head_tile)
+    lay = _layout(q, v, chunk, sub, head_tile, g.ndim == 3)
+    if g.ndim == 3:
+        return _backward_a_head(q, k, v, g, beta, states, do, lay, interpret)
     wide, vwide, col, at_start, _ = _specs(lay, lambda j: lay.n - 1 - j)
     C, D = lay.chunk, lay.D
     dq, dk, dv, dg, dbeta = kernel_call(pl.pallas_call,
@@ -695,6 +733,315 @@ def _backward(q, k, v, g, beta, states, do, chunk, sub, interpret, head_tile):
             dg.reshape(g.shape).astype(g.dtype), dbeta.astype(beta.dtype))
 
 
+# -- a decay a head -----------------------------------------------------------
+#
+# ``g`` ``[B, S, H]``: ``Gamma`` is one number a row, so the decay factors
+# out of a chunk's pairs, ``A[i, j] = exp(Gamma_i - Gamma_j) (k_i . k_j)``:
+# one ``[2 C, D] x [D, C]`` matmul a KEY head (q over k against k), shared
+# by the value heads that read it, and a ``[C, C]`` float32 factor a value
+# head with no positive exponent under the diagonal. No pairwise pass over
+# sub-blocks, no lane reductions over the channels; forward substitution
+# reads its columns out of ``A`` as it lies. ``H`` value heads read ``Hk``
+# key heads (value head ``h`` key head ``h // (H / Hk)``): a grid step's
+# block of q and k is its key heads', taken out of ``[B, S, Hk D]`` by the
+# index map, and their cotangents are summed over a key head's value heads
+# in VMEM.
+
+def _running_sum(column):
+    """``[C, 1]`` float32: row ``i`` the sum of rows ``<= i``, exact
+    float32 sums on the vector unit."""
+    C = column.shape[0]
+    return jnp.sum(jnp.where(_iota((C, C), 0) >= _iota((C, C), 1),
+                             _row_of(column), 0.0), axis=1, keepdims=True)
+
+
+def _sum_of_later(column):
+    """Row ``i`` the sum of rows ``>= i``: ``_running_sum`` transposed."""
+    C = column.shape[0]
+    return jnp.sum(jnp.where(_iota((C, C), 1) >= _iota((C, C), 0),
+                             _row_of(column), 0.0), axis=1, keepdims=True)
+
+
+def _chunk_forward_a_head(products, q, k, v, g, beta, state, sub: int):
+    """:func:`_chunk_forward` for a decay a head, a generator that returns
+    a :class:`_Chunk` whose ``gamma`` and decays are ``[C, 1]``, whose
+    ``lefts`` is the pairs' factor ``exp(Gamma_i - Gamma_j)`` (lower with the
+    diagonal) and whose ``k_pairs`` is all of ``A`` under the diagonal.
+    ``products`` ``[2 C, C]`` float32: the key head's ``q k^T`` over its ``k
+    k^T``; g, beta ``[C, 1]``."""
+    C, D = k.shape
+    m, dtype = C // sub, k.dtype
+    rows, cols = _iota((C, C), 0), _iota((C, C), 1)
+    gamma = _running_sum(g)
+    factor = _decay(jnp.where(_reaches(rows, cols), gamma - _row_of(gamma),
+                              -jnp.inf))
+    last = gamma[C - 1:C]
+    since_start = _decay(gamma)
+    until_end = _decay(last - gamma)
+    whole = _decay(last)
+    pairs = products[:C] * factor
+    k_pairs = jnp.where(rows > cols, products[C:] * factor, 0.0)
+    lower = k_pairs * beta
+    yield
+
+    # the sub-blocks on the diagonal by forward substitution, side by side
+    # on the lanes of one ``[sub, C]`` array: step t takes column t of every
+    # block (nothing on or above the diagonal) times row t from the rows
+    lane = _iota((sub, C), 1)
+    block_of_lane = lane // sub
+    x = (lane % sub == _iota((sub, C), 0)).astype(_F32)
+    diag = jnp.zeros((sub, C), _F32)
+    for blk in range(m):
+        diag = jnp.where(block_of_lane == blk,
+                         lower[blk * sub:(blk + 1) * sub], diag)
+    for t in range(sub - 1):
+        column = jnp.zeros((sub, C), _F32)
+        for blk in range(m):
+            at = blk * sub + t
+            column = jnp.where(block_of_lane == blk, diag[:, at:at + 1],
+                               column)
+        x = x - column * x[t:t + 1]
+        if t % 4 == 3:
+            yield
+    d = jnp.concatenate(
+        [jnp.where(block_of_lane == blk, x, 0.0) for blk in range(m)],
+        axis=0)
+    t0 = d
+    if m > 1:       # the blocks merged, as in :func:`_chunk_forward`
+        under = jnp.where(rows // sub > cols // sub, lower, 0.0)
+        e_p = _dot(d, under, _NN, _HIGHEST)
+        yield
+        t0, power = d - _dot(e_p, d, _NN, _HIGHEST), 2
+        while power < m:
+            e_p = _dot(e_p, e_p, _NN, _HIGHEST)
+            yield
+            t0 = t0 + _dot(e_p, t0, _NN, _HIGHEST)
+            power *= 2
+        yield
+    qf, kf = q.astype(_F32), k.astype(_F32)
+    beta_row = _row_of(beta)
+    t = (t0 * beta_row).astype(dtype)
+    k_start = (kf * since_start).astype(dtype)
+    k_end = (kf * until_end).astype(dtype)
+    q_start = (qf * since_start).astype(dtype)
+    w = _dot(t, k_start, _NN).astype(dtype)
+    u = _dot(t, v, _NN)
+    yield
+    state_low = state.astype(dtype)
+    from_state = _dot(jnp.concatenate([w, q_start], axis=0), state_low, _NT)
+    return _Chunk(gamma, since_start, until_end, whole, factor, pairs,
+                  k_pairs, None, t0, beta_row, t, k_start, k_end, q_start,
+                  w, u - from_state[:C], from_state[C:], state_low)
+
+
+def _key_products(q_ref, k_ref, lay):
+    """A grid step's key heads: (q, k, ``[q k^T ; k k^T]`` float32) each."""
+    keys = []
+    for head in range(lay.key_tile):
+        cols = slice(head * lay.D, (head + 1) * lay.D)
+        q, k = q_ref[0, :, cols], k_ref[0, :, cols]
+        keys.append((q, k, _dot(jnp.concatenate([q, k], axis=0), k, _NT)))
+    return keys
+
+
+def _fwd_kernel_a_head(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *rest,
+                       lay):
+    """One chunk of ``R`` value heads. ``rest``: the output of the states
+    the chunks start from (under differentiation only), then the scratch."""
+    state = rest[-1]
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        state[...] = jnp.zeros_like(state)
+
+    R, Dv, dtype = lay.R, lay.Dv, q_ref.dtype
+    starts = [state[h] for h in range(R)]
+    if len(rest) == 2:
+        for h in range(R):
+            rest[0][0, 0, h] = starts[h]
+    keys = _key_products(q_ref, k_ref, lay)
+    chunks = _interleaved(*(
+        _chunk_forward_a_head(
+            keys[h // lay.group][2], *keys[h // lay.group][:2],
+            v_ref[0, :, h * Dv:(h + 1) * Dv], g_ref[0, 0, :, h:h + 1],
+            beta_ref[0, 0, :, h:h + 1], starts[h], lay.sub)
+        for h in range(R)))
+    for h, c in enumerate(chunks):
+        o_ref[0, :, h * Dv:(h + 1) * Dv] = _chunk_output(c, dtype)
+        state[h] = _chunk_next_state(c, starts[h], dtype)
+
+
+def _chunk_backward_a_head(q, k, v, beta, state, c: _Chunk, do, dstate):
+    """:func:`_chunk_backward` for a decay a head: (dq, dk ``[C, D]``
+    float32 without the pairs' part, dv ``[C, Dv]``, dg, dbeta ``[C, 1]``,
+    the cotangent of the state the chunk started from ``[Dv, D]``, the
+    cotangent of the key head's products ``[2 C, C]`` float32)."""
+    C, D = k.shape
+    dtype = k.dtype
+    rows, cols = _iota((C, C), 0), _iota((C, C), 1)
+    qf, kf = q.astype(_F32), k.astype(_F32)
+    do_low, dnext_low = do.astype(dtype), dstate.astype(dtype)
+    r_low = c.r.astype(dtype)
+    # o = q_start S + P R
+    dq_start = _dot(do_low, c.state_low, _NN)
+    dstart = _dot(do_low, c.q_start, _TN)
+    dpairs = _dot(do_low, r_low, _NT)                       # [i, j]
+    dr = _dot(c.pairs.astype(dtype), do_low, _TN)
+    yield
+    # S_next = whole S + k_end^T R
+    dwhole = jnp.sum(jnp.sum(dstate * state, axis=0, keepdims=True),
+                     axis=1, keepdims=True)                 # [1, 1]
+    dstart = _carry(dstate, c.whole, dstart)
+    dk_end = _dot(r_low, dnext_low, _NN)
+    dr = dr + _dot(c.k_end, dnext_low, _NT)
+    # R = U - W S;  W = T k_start, U = T v
+    dr_low = dr.astype(dtype)
+    dw_low = (-_dot(dr_low, c.state_low, _NN)).astype(dtype)
+    dstart = dstart - _dot(dr_low, c.w, _TN)
+    dt = _dot(dw_low, c.k_start, _NT) + _dot(dr_low, v, _NT)
+    dk_start = _dot(c.t, dw_low, _TN)
+    dv = _dot(c.t, dr_low, _TN)
+    yield
+    # T = t0 Diag(beta), t0 = (I + L)^-1, L = Diag(beta) tril(A, -1)
+    dbeta_row = jnp.sum(dt * c.t0, axis=0, keepdims=True)
+    dt0 = dt * c.beta_row
+    dl = jnp.where(rows > cols, -_dot(
+        _dot(c.t0, dt0, _TN, _HIGHEST), c.t0, _NT, _HIGHEST), 0.0)
+    dbeta = jnp.sum(dl * c.k_pairs, axis=1, keepdims=True)
+    dk_pairs = dl * beta
+    yield
+    # the decays from the chunk's start and to its end: a number a row
+    end_part = jnp.sum(dk_end * kf, axis=1, keepdims=True) * c.until_end
+    at_last = jnp.sum(end_part, axis=0, keepdims=True) + dwhole * c.whole
+    dq = dq_start * c.since_start
+    dk = dk_start * c.since_start + dk_end * c.until_end
+    # the pairs' factor: exp(Gamma_i - Gamma_j) gives row i and takes from
+    # row j what the pair's cotangent times the pair is
+    through = dpairs * c.pairs + dk_pairs * c.k_pairs
+    dgamma = (jnp.sum(dq_start * qf + dk_start * kf, axis=1, keepdims=True)
+              * c.since_start - end_part
+              + jnp.where(_iota((C, 1), 0) == C - 1, at_last, 0.0)
+              + jnp.sum(through, axis=1, keepdims=True)
+              - _column_of(jnp.sum(through, axis=0, keepdims=True)))
+    dproducts = jnp.concatenate([dpairs * c.lefts, dk_pairs * c.lefts],
+                                axis=0)
+    return (dq, dk, dv, _sum_of_later(dgamma),
+            dbeta + _column_of(dbeta_row), dstart, dproducts)
+
+
+def _bwd_kernel_a_head(q_ref, k_ref, v_ref, g_ref, beta_ref, do_ref,
+                       start_ref, dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref,
+                       dstate, *, lay):
+    """One chunk of ``R`` value heads, the chunks walked from the last to
+    the first; ``dstate`` the cotangent of the state a chunk hands on."""
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        dstate[...] = jnp.zeros_like(dstate)
+
+    R, D, Dv, C = lay.R, lay.D, lay.Dv, lay.chunk
+    dtype = q_ref.dtype
+    keys = _key_products(q_ref, k_ref, lay)
+    operands = [(*keys[h // lay.group][:2], v_ref[0, :, h * Dv:(h + 1) * Dv],
+                 beta_ref[0, 0, :, h:h + 1], start_ref[0, 0, h])
+                for h in range(R)]
+    chunks = _interleaved(*(
+        _chunk_forward_a_head(keys[h // lay.group][2], q, k, v,
+                              g_ref[0, 0, :, h:h + 1], beta, start, lay.sub)
+        for h, (q, k, v, beta, start) in enumerate(operands)))
+    cotangents = _interleaved(*(
+        _chunk_backward_a_head(*operands[h], chunks[h],
+                               do_ref[0, :, h * Dv:(h + 1) * Dv], dstate[h])
+        for h in range(R)))
+    for h, (_dq, _dk, dv, dg, dbeta, dstart, _) in enumerate(cotangents):
+        dv_ref[0, :, h * Dv:(h + 1) * Dv] = dv.astype(dv_ref.dtype)
+        dg_ref[0, 0, :, h:h + 1] = dg
+        dbeta_ref[0, 0, :, h:h + 1] = dbeta
+        dstate[h] = dstart
+    for head, (q, k, _) in enumerate(keys):
+        mine = [cot for h, cot in enumerate(cotangents)
+                if h // lay.group == head]
+        dq, dk, dproducts = (sum(cot[i] for cot in mine) for i in (0, 1, 6))
+        dproducts = dproducts.astype(dtype)
+        # products = [q ; k] k^T
+        dq = dq + _dot(dproducts[:C], k, _NN)
+        dk = (dk + _dot(dproducts[:C], q, _TN) + _dot(dproducts[C:], k, _NN)
+              + _dot(dproducts[C:], k, _TN))
+        cols = slice(head * D, (head + 1) * D)
+        dq_ref[0, :, cols] = dq.astype(dq_ref.dtype)
+        dk_ref[0, :, cols] = dk.astype(dk_ref.dtype)
+
+
+def _specs_a_head(lay: _Layout, chunk_of):
+    """Block specs of a chunk's (q-like, v-like, beta-like, states)
+    arrays: the q-like block is the step's key heads'."""
+    C, R = lay.chunk, lay.R
+    per_key_block = lay.group * lay.key_tile    # value heads a key block
+    return (
+        pl.BlockSpec((1, C, lay.key_tile * lay.D),
+                     lambda i, h, j: (i, chunk_of(j), h * R // per_key_block)),
+        pl.BlockSpec((1, C, R * lay.Dv), lambda i, h, j: (i, chunk_of(j), h)),
+        pl.BlockSpec((1, 1, C, R), lambda i, h, j: (i, h, chunk_of(j), 0)),
+        pl.BlockSpec((1, 1, R, lay.Dv, lay.D),
+                     lambda i, h, j: (i, chunk_of(j), h, 0, 0)))
+
+
+def _forward_a_head(q, k, v, g, beta, lay: _Layout, interpret, save: bool):
+    key, vwide, col, at_start = _specs_a_head(lay, lambda j: j)
+    shapes = [
+        jax.ShapeDtypeStruct((lay.B, lay.S, lay.H * lay.Dv), _F32),
+        jax.ShapeDtypeStruct((lay.B, lay.n, lay.H, lay.Dv, lay.D), _F32)]
+    out_specs = [vwide, at_start]
+    if not save:
+        shapes, out_specs = shapes[:1], out_specs[:1]
+    out = kernel_call(pl.pallas_call,
+        functools.partial(_fwd_kernel_a_head, lay=lay),
+        grid=(lay.B, lay.steps, lay.n),
+        in_specs=[key, key, vwide, col, col],
+        out_specs=out_specs, out_shape=shapes,
+        scratch_shapes=[pltpu.VMEM((lay.R, lay.Dv, lay.D), _F32)],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=_SEMANTICS),
+        interpret=interpret, name=FWD_NAME,
+    )(_flat(q, lay), _flat(k, lay), _flat(v, lay), _columns(g, lay),
+      _columns(beta, lay))
+    o = out[0].reshape(lay.B, lay.S, lay.H, lay.Dv)
+    # every chunk's last Gamma: a sum of g's 1 MB, XLA's
+    last = jnp.sum(g.reshape(lay.B, lay.n, lay.chunk, lay.H), axis=2)
+    return o, last[:, :, None, :], (out[1] if save else None)
+
+
+def _backward_a_head(q, k, v, g, beta, states, do, lay: _Layout, interpret):
+    key, vwide, col, at_start = _specs_a_head(lay, lambda j: lay.n - 1 - j)
+    keys_out = lay.steps * lay.key_tile     # key heads written, a step its own
+    small = jax.ShapeDtypeStruct((lay.B, lay.steps, lay.S, lay.R), _F32)
+    key_out = pl.BlockSpec((1, lay.chunk, lay.key_tile * lay.D),
+                           lambda i, h, j: (i, lay.n - 1 - j, h))
+    dq, dk, dv, dg, dbeta = kernel_call(pl.pallas_call,
+        functools.partial(_bwd_kernel_a_head, lay=lay),
+        grid=(lay.B, lay.steps, lay.n),
+        in_specs=[key, key, vwide, col, col, vwide, at_start],
+        out_specs=[key_out, key_out, vwide, col, col],
+        out_shape=[
+            jax.ShapeDtypeStruct((lay.B, lay.S, keys_out * lay.D), q.dtype),
+            jax.ShapeDtypeStruct((lay.B, lay.S, keys_out * lay.D), k.dtype),
+            jax.ShapeDtypeStruct((lay.B, lay.S, lay.H * lay.Dv), v.dtype),
+            small, small],
+        scratch_shapes=[pltpu.VMEM((lay.R, lay.Dv, lay.D), _F32)],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=_SEMANTICS),
+        interpret=interpret, name=BWD_NAME,
+    )(_flat(q, lay), _flat(k, lay), _flat(v, lay), _columns(g, lay),
+      _columns(beta, lay), _flat(do.astype(_F32), lay), states)
+
+    def heads(x):       # [B, H / R, S, R] -> [B, S, H]
+        return x.transpose(0, 2, 1, 3).reshape(beta.shape)
+
+    def key_heads(x):   # a key head's steps' parts summed
+        x = x.reshape(lay.B, lay.S, lay.Hk, keys_out // lay.Hk, lay.D)
+        return (x[:, :, :, 0] if keys_out == lay.Hk
+                else jnp.sum(x.astype(_F32), axis=3).astype(x.dtype))
+    return (key_heads(dq), key_heads(dk), dv.reshape(v.shape),
+            heads(dg).astype(g.dtype), heads(dbeta).astype(beta.dtype))
+
+
 # -- the differentiable call --------------------------------------------------
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
@@ -705,7 +1052,9 @@ def delta_scan(q, k, v, g, beta, chunk: int, sub: int = 16,
     ``[B, S, H]`` float32. Returns (o ``[B, S, H, Dv]`` float32, every
     chunk's last ``Gamma`` ``[B, n, 1, H D]`` float32, which carries no
     gradient). Differentiable in all five. ``head_tile`` overrides
-    :func:`delta_head_tile` (tests, sweeps)."""
+    :func:`delta_head_tile` (tests, sweeps). A decay a head: g ``[B, S,
+    H]``, q and k ``[B, S, Hk, D]`` with ``Hk`` dividing ``H``, the last
+    ``Gamma`` ``[B, n, 1, H]``; the kernels' bodies are the form's own."""
     return _forward(q, k, v, g, beta, chunk, sub, interpret, head_tile,
                     save=False)[:2]
 

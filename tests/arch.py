@@ -105,6 +105,24 @@ def _kimi_moved(arch, tree, rng, seed):
     return tree
 
 
+def _qwen_moved(arch, tree, rng, seed):
+    """The zero-centred norm weights off 0 and the delta blocks' output norm
+    off 1 (a weight of 0 or 1 hides whether the scale is ``1 + w`` or
+    ``w``), the table at a scale at which the logits say something, and the
+    routers wide enough that a cut stack's held experts see tokens."""
+    rng = np.random.RandomState(seed + 100)
+
+    def leaf(path, a):
+        if np.all(a == 0) or np.all(a == 1):
+            a = a + 0.3 * rng.randn(*a.shape).astype(np.float32)
+        return a
+    tree = jax.tree_util.tree_map_with_path(leaf, tree)
+    tree["embed"] = tree["embed"] * (1.0 / 0.02)
+    experts = tree["layers"]["experts"]
+    experts["router"] = experts["router"] * 10.0
+    return tree
+
+
 def _granite_moved(arch, tree, rng, seed):
     """The table at the configuration's scale (at 0.02 the logits say
     nothing) and the norm weights and the skip off their ones, so that a
@@ -273,6 +291,7 @@ _NOPE = ("attention", None, False)
 _LAGUNA_WINDOW = ("attention", 8, Rope(10000.0), 8, True)
 _LAGUNA_FULL = ("attention", None, Rope(
     500000.0, 8, Yarn(64.0, 16, 64.0, 1.0, 1.4158883083359672)), 6, True)
+_QWEN_ATTENTION = ("attention", None, Rope(1e7, 4), None, "channel")
 _SIGMOID_SHARE = {"n_experts": 16, "moe_top_k": 4, "held_experts": 2,
                   "expert_share": (0, 8), "moe_router_scores": "sigmoid",
                   "moe_renormalize": True, "moe_balance_weight": 0.0}
@@ -630,6 +649,51 @@ ROWS = {
                 rope_width=4, value_width=8), _PAGED),
             ("the tree", "dense GPT block", None, "flatten")),
         shares=(8, 16, 1.0), choices=(4, 2 * 64, 2)),
+    "qwen3_next": Row(
+        config="qwen3-next-80b-a3b", workload="train.s8192.b1.gdn",
+        leaves=_every_leaf, moved=_qwen_moved,
+        tiny={"cfg": {
+            "dtype": jnp.float32, "one_sublayer": True,
+            "layer_pattern": (_DELTA, _EXPERTS) * 3 + (_QWEN_ATTENTION,
+                                                      _EXPERTS),
+            "lead_pattern": (), "n_layers": 8,  # layers 0-3: one period
+            "d_model": 64, "delta_heads": 4, "delta_key_heads": 2,
+            "delta_head_dim": 16, "delta_taps": 4, "delta_chunk": 8,
+            "delta_decay": "head",
+            "n_heads": 4, "kv_heads": 2, "head_dim": 16, "qk_norm": "head",
+            "zero_centred_norms": True, "d_ff": 32, "moe_shared_width": 32,
+            "moe_shared_gate": True, "n_experts": 16, "moe_top_k": 2,
+            "held_experts": 2, "expert_share": (0, 8),
+            "moe_router_scores": "softmax", "moe_renormalize": True,
+            "moe_balance_weight": 0.0, "moe_activation": "silu",
+            "moe_gated": True, "tie_embeddings": False, "norm_eps": 1e-6},
+            "job": {"seq_len": 8 * 8},          # eight chunks carry a state
+            "sizes": {
+                "layer_mixers": ["delta"] * 3 + ["attention"],
+                "rope_width": 4, "delta_decay_width": 1}},
+        reference_imports=("__future__", "jax", "trees", "reference"),
+        # (no ``drawn``: a rate a head is a dozen numbers at the tiny preset;
+        # tests/test_qwen3_next.py holds the adapter's draw leaf by leaf)
+        refused=(
+            ("the cell", "delta_heads.*delta_decay.*delta_key_heads"
+             ".*moe_shared_gate.*zero_centred_norms", None, _PAGED),
+            ("a decay a head alone", "delta_decay", _plain(
+                layer_pattern=(_DELTA, _DENSE), delta_heads=2,
+                delta_head_dim=16, delta_decay="head"), _PAGED),
+            ("shared keys alone", "delta_key_heads", _plain(
+                layer_pattern=(_DELTA, _DENSE), delta_heads=4,
+                delta_key_heads=2, delta_head_dim=16, delta_decay="head"),
+             _PAGED),
+            ("a gate a channel alone", "layer_pattern", _plain(
+                layer_pattern=(("attention", None, True, None, "channel"),
+                               _DENSE)), "spec greedy"),
+            ("the shared expert's gate alone", "moe_shared_gate", _plain(
+                n_experts=8, moe_shared_width=64, moe_shared_gate=True),
+             _PAGED),
+            ("zero-centred norms alone", "zero_centred_norms",
+             _plain(zero_centred_norms=True), _PAGED),
+            ("the tree", "dense GPT block", None, "flatten")),
+        shares=(16, 32, 0.5), choices=(4, 2 * 64, 2)),
 }
 
 

@@ -128,7 +128,8 @@ def test_the_shares_add_up_to_the_uncut_layer(name):
          **{k: w(e, *((f, m) if k == "we2" else (m, f)))
             for k in routed_names},
          **{k.replace("e", "s"): w(*((fs, m) if k == "we2" else (m, fs)))
-            for k in routed_names if fs}}
+            for k in routed_names if fs},
+         **({"ws_gate": w(m, 1)} if cfg.moe_shared_gate else {})}
     layer_fn = getattr(a.reference, "expert_layer", None) or a.reference.layer
 
     @functools.partial(jax.jit, static_argnums=(2, 3, 4))

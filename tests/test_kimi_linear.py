@@ -414,8 +414,10 @@ def _digest(text: str) -> str:
 
 
 def test_the_new_configuration_is_the_only_one_without_a_parent():
-    assert sorted(arch.configs()) == sorted(
-        [*PARENT_PROGRAMS, "kimi-linear-48b-a3b"])
+    """(Of the configurations there were at PR 66: a later one's file holds
+    this file's configuration to its own parent, tests/test_qwen3_next.py.)"""
+    assert set(PARENT_PROGRAMS) | {"kimi-linear-48b-a3b"} <= set(
+        arch.configs())
 
 
 @pytest.mark.parametrize("name", sorted(PARENT_PROGRAMS))

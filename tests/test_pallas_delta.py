@@ -33,6 +33,7 @@ if _CHIP not in sys.path:
     sys.path.insert(0, _CHIP)
 
 from reference import kimi_linear as reference        # noqa: E402
+from reference import qwen3_next as head_reference    # noqa: E402
 
 TOL = 1e-4
 #: (B, S, H, D, Dv, chunk, heads a grid step)
@@ -226,6 +227,98 @@ def test_bfloat16_operands_keep_float32_sums_decays_and_state():
     for name, g, w, op in zip(NAMES, got[1:], want[1:], ops):
         assert g.dtype == op.dtype, name
         assert _rel(g, w) < 2e-2, name
+
+
+# -- a decay a head, keys shared by the value heads (PR 70) -------------------
+
+#: (B, S, H, key heads, D, Dv, chunk, value heads a grid step)
+HEAD_SHAPES = {
+    "two value heads on one key head a step, four sub-blocks":
+        (1, 128, 2, 1, 128, 128, 64, 2),
+    "batch 2, a key head a value head, two sub-blocks":
+        (2, 64, 2, 2, 128, 128, 32, 2),
+    "four value heads on one key head, two a step":
+        (1, 64, 4, 1, 128, 128, 32, 2),
+    "values wider than keys, one value head a step":
+        (1, 32, 2, 1, 128, 256, 16, 1),
+    "two key heads' four value heads a step (the cell's tile)":
+        (1, 64, 4, 2, 128, 128, 32, None),
+}
+
+
+def _head_operands(shape, seed=0, dtype=jnp.float32, rate=0.3):
+    B, S, H, Hk, D, Dv = shape[:6]
+    q, k, v, g, beta = _operands((B, S, H, D, Dv), seed, dtype, rate)
+    return q[:, :, :Hk], k[:, :, :Hk], v, g[..., 0], beta
+
+
+def _head_kernels(shape):
+    chunk, head_tile = shape[6:]
+    return lambda *v: pd.delta_scan(*v, chunk, delta.SUB, True, head_tile)[0]
+
+
+def _head_stepwise(*ops):
+    return head_reference.delta_rule(*(x.astype(jnp.float32) for x in ops))
+
+
+@pytest.mark.parametrize("shape", HEAD_SHAPES.values(),
+                         ids=HEAD_SHAPES.keys())
+def test_the_kernels_for_a_decay_a_head_are_the_numpy_form_and_the_recurrence(
+        shape):
+    """o, and the cotangents of q, k (at the key heads: a key head's value
+    heads' parts summed in the kernel, or outside it where a step holds a
+    part of a group), v, g ``[B, S, H]`` and beta."""
+    ops = _head_operands(shape)
+    weight = _weight(shape[:3] + shape[4:6])
+    got = _both(_head_kernels(shape), weight, ops)
+    want = _both(lambda *v: delta._delta_chunked_numpy(*v, shape[6])[0],
+                 weight, ops)
+    stepwise = _both(_head_stepwise, weight, ops)
+    for name, g, w, r, op in zip(("o",) + NAMES, got, want, stepwise,
+                                 (weight,) + ops):
+        assert g.dtype == w.dtype and g.shape == w.shape == op.shape, name
+        assert _rel(g, w) < TOL, name
+        assert _rel(g, r) < TOL, name
+    _, last = pd.delta_scan(*ops, shape[6], delta.SUB, True, shape[7])
+    assert last.shape == (shape[0], shape[1] // shape[6], 1, shape[2])
+    assert float(jnp.min(last)) == pytest.approx(float(
+        delta._delta_chunked_numpy(*ops, shape[6])[1]), rel=1e-6)
+
+
+@pytest.mark.parametrize("what, patches", [
+    ("the decays made in bfloat16", {"_decay": _bf16_decay}),
+    ("the decay applied after the correction",
+     {"_carry": _decay_after_the_correction}),
+    ("no causal mask on the pairs' factor", {"_reaches": _no_mask,
+                                             "_decay": _decays_capped}),
+])
+def test_a_wrong_piece_fails_on_the_kernels_for_a_decay_a_head(
+        monkeypatch, what, patches):
+    """The three pieces both forms' bodies are made of, here under the
+    bodies for a decay a head, at a fast decay; the sound kernels on the
+    same operands are inside TOL."""
+    shape = (1, 64, 2, 1, 128, 128, 32, 2)
+    ops = _head_operands(shape, rate=1.0)
+    weight = _weight(shape[:3] + shape[4:6])
+    want = _both(_head_stepwise, weight, ops)
+
+    def errors():
+        got = _both(_head_kernels(shape), weight, ops)
+        return (_rel(got[0], want[0]),
+                max(_rel(g, w) for g, w in zip(got[1:], want[1:])))
+    assert max(errors()) < TOL
+    for name, wrong in patches.items():
+        monkeypatch.setattr(pd, name, wrong)
+    forward, backward = errors()
+    assert forward > 4 * TOL, (what, forward)
+    assert backward > 4 * TOL, (what, backward)
+
+
+def test_a_decay_a_channel_refuses_shared_keys():
+    q, k, v, g, beta = _operands((1, 32, 2, 128, 128))
+    with pytest.raises(ValueError, match="key heads"):
+        pd.delta_scan(q[:, :, :1], k[:, :, :1], v, g, beta, 16,
+                      delta.SUB, True, 1)
 
 
 # -- which form runs -----------------------------------------------------------

@@ -277,6 +277,39 @@ def _delta_step():
             (params, init_opt_state(tx, params, mesh, cfg), t, y))
 
 
+def _gated_delta_step():
+    """Delta blocks with a decay a head on shared keys beside an attention
+    block gated a channel, zero-centred norms, softmax-routed experts with
+    a gated shared expert of which a share is held (Qwen3-Next's blocks)."""
+    from horovod_tpu.models import (TransformerConfig, init_opt_state,
+                                    init_params, make_train_step,
+                                    shard_batch, shard_params)
+    from horovod_tpu.models._kinds import Rope
+    cfg = TransformerConfig(vocab_size=64, d_model=32, n_heads=4,
+                            n_kv_heads=2, head_width=8, qk_norm="head",
+                            zero_centred_norms=True, n_layers=4, d_ff=16,
+                            max_seq=32,
+                            n_experts=4, moe_top_k=2, moe_gated=True,
+                            moe_renormalize=True, moe_balance_weight=0.0,
+                            moe_shared_width=16, moe_shared_gate=True,
+                            dtype=jnp.float32, delta_heads=4,
+                            delta_key_heads=2, delta_head_dim=8,
+                            delta_chunk=8, delta_decay="head",
+                            tie_embeddings=False,
+                            layer_pattern=(("delta",), ("experts",), (
+                                "attention", None, Rope(1e7, 2), None,
+                                "channel"), ("experts",)),
+                            expert_share=(0, 2))
+    mesh = build_mesh(devices=jax.devices()[:1], dp=-1)
+    params = shard_params(init_params(np.random.RandomState(0), cfg, 1),
+                          cfg, mesh)
+    tx = optax.adamw(1e-3)
+    tokens = np.zeros((2, 16), np.int32)
+    t, y = shard_batch(tokens, tokens, mesh)
+    return (make_train_step(cfg, mesh, tx),
+            (params, init_opt_state(tx, params, mesh, cfg), t, y))
+
+
 _TEXTS = {}
 
 
@@ -289,7 +322,8 @@ def _compiled_text(model: str) -> str:
                       "hybrid": _hybrid_step,
                       "gated": _gated_step,
                       "short_conv": _short_conv_step,
-                      "delta": _delta_step}[model]()
+                      "delta": _delta_step,
+                      "gated_delta": _gated_delta_step}[model]()
         _TEXTS[model] = step.lower(*args).compile().as_text()
     return _TEXTS[model]
 
@@ -409,6 +443,41 @@ def test_the_short_conv_s_parts_nest_in_it_and_the_heads_norm_in_attention():
                             scopes.ATTENTION_LATENT_UP))
 def test_the_delta_step_carries_every_phase_in_both_directions(phase):
     assert _directions(_compiled_text("delta"), phase) == {"fwd", "bwd"}
+
+
+@pytest.mark.parametrize("phase", scopes.MODEL_PHASES + scopes.MOE_PHASES
+                         + scopes.DELTA_PHASES
+                         + (scopes.ATTENTION_GATE, scopes.MOE_SHARED,
+                            scopes.ATTENTION_CORE_FULL))
+def test_the_gated_delta_step_carries_every_phase_in_both_directions(phase):
+    """The form with a decay a head keeps the mixer's six phases; the
+    attention's gate a channel is under ``hvd.attention.gate``, the shared
+    expert with its gate under ``hvd.moe.shared``."""
+    assert _directions(_compiled_text("gated_delta"), phase) == {"fwd",
+                                                                 "bwd"}
+
+
+def test_the_gates_of_the_gated_delta_step_are_where_their_phases_say():
+    paths = re.findall(r'op_name="([^"]*)"', _compiled_text("gated_delta"))
+
+    def parts(path):
+        return [re.sub(r"^(?:\w+\()+|\)+$", "", c) for c in path.split("/")]
+    # the sigmoids: the attention's gate a channel, the shared expert's a
+    # token, beta's in the mixer's gates; silu(z) in the mixer's norm
+    # (a sigmoid is ``logistic``, or where the backend expands it its ``exp``)
+    gates = [parts(p) for p in paths
+             if parts(p)[-1].startswith(("logistic", "exp"))]
+    assert any(scopes.ATTENTION_GATE in p for p in gates)
+    assert any(scopes.MOE_SHARED in p for p in gates)
+    assert any(scopes.DELTA_GATES in p for p in gates)
+    assert any(scopes.DELTA_NORM in p for p in gates)
+    # b | a are one projection under the gates, q | k | v | z one under proj
+    dots = [parts(p) for p in paths if parts(p)[-1].startswith("dot_general")
+            and scopes.DELTA in parts(p)]
+    assert {next(c for c in reversed(p) if c.startswith("hvd.")) for p in
+            dots} >= {scopes.DELTA_PROJ, scopes.DELTA_GATES}
+    assert not any(scopes.DELTA_CONV in p or scopes.DELTA_NORM in p
+                   for p in dots)
 
 
 def test_the_delta_mixer_s_parts_nest_in_it():

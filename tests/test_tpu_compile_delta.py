@@ -77,3 +77,44 @@ def test_delta_step_compiles_for_v5e_with_no_array_of_two_sequence_lengths(
     assert not re.search(scopes.DELTA_SCAN + r"/[^\"]*while", names)
     total = step_bytes(compiled.memory_analysis())["total"]
     assert 12.78e9 < total < 13.48e9, total     # PERF.md section 6, PR 67
+
+
+def test_the_kernels_for_a_decay_a_head_lower_for_v5e(topo):
+    """``hvd_delta_scan`` / ``hvd_delta_scan_bwd`` in their bodies for a
+    decay a head at the cell qwen3-next-80b-a3b.s8192's shape (one sequence
+    of 8192, 32 value heads on 16 key heads of 128, chunks of 64), compiled
+    by Mosaic for a described v5e: q and k stay ``[1, 8192, 16 x 128]`` (no
+    array of them at the 32 value heads), the log decay and its cotangent
+    ``[1, 8, 8192, 4]`` float32 in the kernels' layout (four value heads a step) (no ``[8192, 32 x
+    128]`` float32 broadcast), dq and dk written once at the key heads."""
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    rep = NamedSharding(Mesh(np.array(topo.devices[:1]), ("dp",)), P())
+    S, H, Hk, D, C = 8192, 32, 16, 128, 64
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=rep)
+    args = (shape((1, S, Hk, D), jnp.bfloat16),
+            shape((1, S, Hk, D), jnp.bfloat16),
+            shape((1, S, H, D), jnp.bfloat16),
+            shape((1, S, H), jnp.float32), shape((1, S, H), jnp.float32))
+
+    def gradients(*ops):
+        return jax.grad(lambda *x: jnp.sum(
+            pallas_delta.delta_scan(*x, C)[0]), argnums=(0, 1, 2, 3, 4))(*ops)
+    with compile_cache_off():
+        compiled = jax.jit(gradients).lower(*args).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 2
+    forward = next(c for c in calls if pallas_delta.BWD_NAME not in c)
+    backward = next(c for c in calls if pallas_delta.BWD_NAME in c)
+    assert pallas_delta.FWD_NAME in forward
+    assert f"f32[1,{S // C},{H},{D},{D}]" in forward        # the states
+    assert backward.count(f"bf16[1,{S},{Hk * D}]") >= 2     # dq, dk
+    tile = pallas_delta.HEAD_TILE_A_HEAD
+    assert f"f32[1,{H // tile},{S},{tile}]" in backward     # dg, dbeta
+    assert not re.search(r"bf16\[1,%d,%d,%d\]" % (S, H, D) + r"[^ ]* (?:"
+                         r"broadcast|concatenate)", text), "q or k repeated"
