@@ -1143,6 +1143,48 @@ def _check_gated_norm(smoke: Smoke) -> None:
                      NORM_TOL, shape=(S, C, G), ran="xla")
 
 
+def _check_delta_heads_on_lanes(smoke: Smoke) -> None:
+    """models/delta.py's L2 norm and output norm on the device at the delta
+    cells' heads, ``[1, S, H D]`` with a head's sums products with a 0/1
+    matrix, against the same with a head's channels on an axis of their own
+    (what XLA:TPU pays a copy each way for), float32 operands: the results
+    and the gradients. The sums go through the MXU; the bound holds them to
+    float32 (a single bfloat16 pass reads ~3e-3)."""
+    import jax
+    import jax.numpy as jnp
+    from horovod_tpu.models import delta
+    S, H, D = smoke.sizes.delta[:3]
+    C, eps = H * D, 1e-5
+    keys = jax.random.split(jax.random.PRNGKey(smoke.seed + 9), 3)
+    x = 3.0 * jax.random.normal(keys[0], (1, S, C), jnp.float32)
+    w = 1.0 + 0.1 * jax.random.normal(keys[1], (D,), jnp.float32)
+    ct = jax.random.normal(keys[2], (1, S, C), jnp.float32)
+
+    def l2_by_axis(x, w):
+        return delta._l2norm(x.reshape(1, S, H, D)).reshape(1, S, C)
+
+    def norm_by_axis(x, w):
+        y = x.reshape(1, S, H, D)
+        y = y * jax.lax.rsqrt(jnp.mean(jnp.square(y), -1, keepdims=True)
+                              + eps) * w
+        return y.reshape(1, S, C)
+
+    def both(f):
+        out, pull = jax.vjp(f, x, w)
+        return (out,) + pull(ct)
+    # zip stops at the leaves named: the L2 norm has no weight to differentiate
+    for name, leaves, flat, by_axis in (
+            ("L2 norm", ("fwd", "grad d_x"),
+             lambda x, w: delta._l2norm(x, H), l2_by_axis),
+            ("output norm", ("fwd", "grad d_x", "grad d_norm"),
+             lambda x, w: delta._head_norm(x, w, H, eps), norm_by_axis)):
+        got, want = jax.jit(lambda: both(flat))(), \
+            jax.jit(lambda: both(by_axis))()
+        for what, g, r in zip(leaves, got, want):
+            _kernel_line(smoke, f"delta {name}, heads on the lanes", what,
+                         _rel_err(g, r), NORM_TOL, shape=(S, H, D), ran="xla")
+
+
 def _check_ssm(smoke: Smoke, sizes=None, cell: str = "hybrid",
                length: int = 8192) -> None:
     """The Mamba-2 scan on its kernels (ops/pallas_ssm.py: hvd_ssm_scan,
@@ -1649,6 +1691,7 @@ def phase_kernels(smoke: Smoke, hvd) -> None:
     _check_ssm(smoke)
     _check_delta(smoke)
     _check_delta(smoke, key_heads=smoke.sizes.delta[1] // 2)
+    _check_delta_heads_on_lanes(smoke)
     _check_dense_hybrid(smoke)
     _check_gated_norm(smoke)
     _check_short_conv(smoke)

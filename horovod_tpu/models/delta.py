@@ -23,8 +23,9 @@ shapes, the whole sequence on this device (no sp, pp or tp), ``H`` heads of
                   q and k L2-normed over a head's channels, q times
                   ``D^-1/2``
   decay           ``g = -exp(a_log) softplus((h wf_down) wf_up + dt_bias)``
-                  float32 ``[B, S, H, D]``: ``wf_down`` ``[M, D]``, ``wf_up``
-                  ``[D, H D]``, ``a_log`` ``[H]``, ``dt_bias`` ``[H D]``
+                  float32 ``[B, S, H D]``: ``wf_down`` ``[M, D]``, ``wf_up``
+                  ``[D, H D]``, ``a_log`` ``[H]`` (a head's rate repeated
+                  over its channels), ``dt_bias`` ``[H D]``
   beta            ``sigmoid(h w_beta)``, ``w_beta`` ``[M, H]``: a scalar a head
   output          ``rmsnorm(o; norm [D]) * sigmoid((h wg_down) wg_up)`` a
                   head, then ``wo`` ``[H D, M]``
@@ -41,6 +42,21 @@ head ``h // (H / Hk)``), and the block is Gated DeltaNet's:
                   and ``dt_bias`` ``[H]``
   output          ``rmsnorm(o; norm [D]) * silu(z)`` a value head (``norm``
                   is never zero-centred), then ``wo`` ``[H D, M]``
+**One layout** (PR 71): between the in-projection's output and the
+out-projection's input every array of the block is ``[B, S, heads D]`` as
+the projections write it, a head a run of ``D`` lanes and never an axis: a
+head's sum of squares (the two L2 norms, the output norm's mean) is a product
+of the squared float32 array with the 0/1 matrix ``[heads D, heads]`` and the
+head's factor goes back to its channels by the transpose, both at ``HIGHEST``
+(:func:`_head_sums`, :func:`_to_channels`, as ``mamba._gated_norm``); a
+constant a head (``exp(a_log)``, ``norm``) reaches the channels as a ``[H D]``
+vector. On a TPU a float32 ``[B, S, H, D]`` view lies in tiles of 8 heads x
+128 channels, a copy of the whole array away from the projections' tiles of 8
+positions x 128 channels, each way. What crosses into :func:`delta_chunked`
+is a ``reshape`` view ``[B, S, heads, D]`` on which nothing is computed: the
+kernels read and write ``[B, S, heads D]`` and XLA folds the two reshapes
+(``tests/test_tpu_compile_delta.py`` reads the compiled step for it).
+
 The scan is :func:`delta_chunked`: on a TPU at whole chunks and heads of
 whole lane tiles two Pallas kernels (``ops/pallas_delta.py``:
 ``hvd_delta_scan``, ``hvd_delta_scan_bwd``, a chunk's pairs, inverse, ``W``,
@@ -392,27 +408,84 @@ def _short_conv(x, taps):
     return jax.nn.silu(_causal_conv(x, taps, None))
 
 
-def _l2norm(x):
-    """``x / |x|`` over the last dimension, float32."""
+def _members(channels: int, heads: int):
+    """The 0/1 matrix ``[channels, heads]`` of which channel is in which
+    head: head ``h`` is the ``h``-th run of ``channels / heads`` channels."""
+    return (jnp.arange(channels)[:, None] // (channels // heads)
+            == jnp.arange(heads)).astype(jnp.float32)
+
+
+def _head_sums(x, member):
+    """Every head's sum over its channels, ``[.., H D]`` float32 to ``[..,
+    H]``: a product with :func:`_members` at ``HIGHEST`` (float32 to the
+    last bit or two: the matrix is exact in bfloat16), so that no array has
+    the heads on an axis (``mamba._gated_norm``'s way: a ``[.., H, D]``
+    float32 view costs XLA:TPU a copy to a heads-major layout each way,
+    PERF.md section 6, PR 71)."""
+    return jnp.einsum("...c,ch->...h", x, member, precision=_HIGHEST)
+
+
+def _to_channels(x, member):
+    """A number a head at each of the head's channels, ``[.., H]`` float32
+    to ``[.., H D]``: :func:`_head_sums` the other way."""
+    return jnp.einsum("...h,ch->...c", x, member, precision=_HIGHEST)
+
+
+def _l2norm(x, heads: int = 1):
+    """``x / |x|`` over each of the ``heads`` runs of channels of the last
+    dimension, float32; one head is the last dimension whole."""
     x = x.astype(jnp.float32)
-    return x * lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True)
-                         + L2_EPS)
+    if heads == 1:
+        return x * lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True)
+                             + L2_EPS)
+    member = _members(x.shape[-1], heads)
+    return x * _to_channels(
+        lax.rsqrt(_head_sums(jnp.square(x), member) + L2_EPS), member)
+
+
+def _head_norm(o, weight, heads: int, eps: float):
+    """``rmsnorm`` of every head of o ``[B, S, H D]`` float32 over its ``D``
+    channels, times ``weight`` ``[D]`` (the same for every head)."""
+    member = _members(o.shape[-1], heads)
+    mean = _head_sums(jnp.square(o), member) * (heads / o.shape[-1])
+    return (o * _to_channels(lax.rsqrt(mean + eps), member)
+            * jnp.tile(weight.astype(jnp.float32), heads))
+
+
+def _log_decay(rate, a_log, dt_bias):
+    """``g = -exp(a_log) softplus(rate + dt_bias)`` float32, ``a_log``
+    ``[H]`` a head: with a rate a channel (``rate``, ``dt_bias`` ``[.., H
+    D]``) a head's factor is repeated over its channels, a vector of a few
+    KB, and nothing leaves ``[B, S, H D]``."""
+    speed = jnp.repeat(jnp.exp(a_log.astype(jnp.float32)),
+                       rate.shape[-1] // a_log.shape[0])
+    return -speed * jax.nn.softplus(rate + dt_bias.astype(jnp.float32))
 
 
 def _scan_to_residual(p, x, q, k, v, g, beta, gate, activation, cfg):
     """What both forms share, called under the mixer's scope: the scan, the
-    head's RMSNorm on its output times ``activation(gate)`` (gate ``[B, S,
-    H D]``), the out-projection and the residual add; and the step's most
-    negative ``Gamma_C``."""
-    B, S, H, D = v.shape
+    head's RMSNorm on its output times ``activation(gate)``, the
+    out-projection and the residual add; and the step's most negative
+    ``Gamma_C``. q, k, v, gate and a decay a channel ``[B, S, heads D]`` as
+    the projections wrote them (q and k of the form with a decay a head come
+    from their L2 norm as ``[B, S, Hk, D]``): the scan is handed ``[B, S,
+    heads, D]`` views on which nothing is computed (``pallas_delta`` reads
+    them flat again, and XLA folds a reshape of a reshape), and its o is flat
+    from there on."""
+    B, S, _ = v.shape
+    H, D = cfg.delta_heads, cfg.delta_head_dim
+
+    def heads(y):
+        return y.reshape(B, S, -1, D)
     with scopes.scope(scopes.DELTA_SCAN):
-        o, min_log_decay = delta_chunked(q, k, v, g, beta, cfg.delta_chunk)
+        o, min_log_decay = delta_chunked(
+            heads(q), heads(k), heads(v),
+            g if cfg.delta_decay == "head" else heads(g), beta,
+            cfg.delta_chunk)
+        o = o.reshape(B, S, H * D)
     with scopes.scope(scopes.DELTA_NORM):
-        var = jnp.mean(jnp.square(o), axis=-1, keepdims=True)
-        y = (o * lax.rsqrt(var + cfg.norm_eps)
-             * p["norm"].astype(jnp.float32)
-             * activation(gate.astype(jnp.float32)).reshape(B, S, H, D))
-        y = y.reshape(B, S, H * D).astype(x.dtype)
+        y = (_head_norm(o, p["norm"], H, cfg.norm_eps)
+             * activation(gate.astype(jnp.float32))).astype(x.dtype)
     with scopes.scope(scopes.DELTA_PROJ):
         out = y @ p["wo"].astype(x.dtype)
     return (x + scaled(out, cfg.residual_scale),
@@ -421,10 +494,11 @@ def _scan_to_residual(p, x, q, k, v, g, beta, gate, activation, cfg):
 
 def _delta_block(p, x, cfg):
     """``x + delta(norm(x))``, x ``[B', S', M]`` with the whole sequence
-    here (no sp); and the step's most negative ``Gamma_C``."""
+    here (no sp); and the step's most negative ``Gamma_C``. Between the
+    in-projections and the out-projection a head is a run of ``D`` lanes of
+    ``[B, S, H D]`` and never an axis."""
     if cfg.delta_decay == "head":
         return _delta_block_a_head(p, x, cfg)
-    B, S, _ = x.shape
     H, D = cfg.delta_heads, cfg.delta_head_dim
     with scopes.scope(scopes.DELTA):
         h = rmsnorm(x, p["ln1"], cfg.norm_eps, cfg.zero_centred_norms)
@@ -432,21 +506,18 @@ def _delta_block(p, x, cfg):
             q, k, v = (h @ p[name].astype(h.dtype)
                        for name in ("wq", "wk", "wv"))
         with scopes.scope(scopes.DELTA_CONV):
-            q, k, v = (_short_conv(y, p[name]).reshape(B, S, H, D)
+            q, k, v = (_short_conv(y, p[name])
                        for y, name in ((q, "conv_q"), (k, "conv_k"),
                                        (v, "conv_v")))
-            q = (_l2norm(q) * D ** -0.5).astype(h.dtype)
-            k = _l2norm(k).astype(h.dtype)
+            q = (_l2norm(q, H) * D ** -0.5).astype(h.dtype)
+            k = _l2norm(k, H).astype(h.dtype)
             v = v.astype(h.dtype)
         with scopes.scope(scopes.DELTA_GATES):
             rate = jnp.matmul(
                 h @ p["wf_down"].astype(h.dtype),
                 p["wf_up"].astype(h.dtype),
                 preferred_element_type=jnp.float32)
-            g = -(jnp.exp(p["a_log"].astype(jnp.float32))[:, None]
-                  * jax.nn.softplus(
-                      (rate + p["dt_bias"].astype(jnp.float32)
-                       ).reshape(B, S, H, D)))
+            g = _log_decay(rate, p["a_log"], p["dt_bias"])
             beta = jax.nn.sigmoid(jnp.matmul(
                 h, p["w_beta"].astype(h.dtype),
                 preferred_element_type=jnp.float32))
@@ -459,7 +530,16 @@ def _delta_block(p, x, cfg):
 def _delta_block_a_head(p, x, cfg):
     """:func:`_delta_block` with a decay a head (the module's docstring):
     one in-projection, one convolution over q | k | v, ``b`` and ``a`` from
-    one projection, the output norm times ``silu(z)``."""
+    one projection, the output norm times ``silu(z)``. The convolution
+    runs on the column ranges of q, k and v apart (a depthwise convolution is
+    its ranges'), so that none of them waits on a float32 q | k | v. **The
+    one head axis left in a delta block:** q and k are L2-normed as ``[B, S,
+    Hk, D]``. Flat, in any of the forms tried (the products, their bfloat16
+    pieces, a ``repeat`` for the way back, q | k at once), the cell
+    qwen3-next-80b-a3b.s8192's step is scheduled as Kimi's is, the head's
+    weight update after the last layer's experts, its logits' cotangent
+    (312 MB) alive until then, and compiles to 12.574 GB for the parent's
+    12.412 (PERF.md section 6 and 7, PR 71)."""
     B, S, _ = x.shape
     H, Hk, D = cfg.delta_heads, _key_heads(cfg), cfg.delta_head_dim
     keys = Hk * D
@@ -469,19 +549,18 @@ def _delta_block_a_head(p, x, cfg):
             qkvz = h @ p["w_in"].astype(h.dtype)
             qkv, z = qkvz[..., :2 * keys + H * D], qkvz[..., 2 * keys + H * D:]
         with scopes.scope(scopes.DELTA_CONV):
-            qkv = _short_conv(qkv, p["conv"])
-            q = qkv[..., :keys].reshape(B, S, Hk, D)
-            k = qkv[..., keys:2 * keys].reshape(B, S, Hk, D)
-            q = (_l2norm(q) * D ** -0.5).astype(h.dtype)
-            k = _l2norm(k).astype(h.dtype)
-            v = qkv[..., 2 * keys:].reshape(B, S, H, D).astype(h.dtype)
+            q, k, v = (_short_conv(qkv[..., cols], p["conv"][:, cols])
+                       for cols in (slice(keys), slice(keys, 2 * keys),
+                                    slice(2 * keys, None)))
+            q, k = (_l2norm(y.reshape(B, S, Hk, D)) for y in (q, k))
+            q = (q * D ** -0.5).astype(h.dtype)
+            k = k.astype(h.dtype)
+            v = v.astype(h.dtype)
         with scopes.scope(scopes.DELTA_GATES):
             ba = jnp.matmul(h, p["w_ba"].astype(h.dtype),
                             preferred_element_type=jnp.float32)
             beta = jax.nn.sigmoid(ba[..., :H])
-            g = -(jnp.exp(p["a_log"].astype(jnp.float32))
-                  * jax.nn.softplus(ba[..., H:]
-                                    + p["dt_bias"].astype(jnp.float32)))
+            g = _log_decay(ba[..., H:], p["a_log"], p["dt_bias"])
         return _scan_to_residual(p, x, q, k, v, g, beta, z, jax.nn.silu, cfg)
 
 

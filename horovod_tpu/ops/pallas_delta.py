@@ -37,6 +37,19 @@ relayouts between ``[S, H D]`` and per-head chunks). Here:
       gives dq, dk, dv, dg (the cotangent of ``Gamma`` summed back over a
       chunk's later rows) and dbeta.
 
+**What crosses the boundary.** :func:`delta_scan` takes q, k ``[B, S, Hk,
+D]``, v ``[B, S, H, Dv]`` and a decay a channel ``[B, S, H, D]`` and gives o
+``[B, S, H, Dv]``, but a delta block computes nothing on those shapes: they
+are ``reshape`` views of its ``[B, S, heads D]`` arrays, :func:`_flat` views
+them flat again for the kernels, o and the cotangents leave flat and are
+viewed with heads for the caller, who flattens them; XLA folds each pair of
+reshapes, so the compiled step has no array with a head axis and no relayout
+between the projections' tiles (8 positions x 128 channels) and a head-axis
+float32 array's (8 heads x 128 channels) (:data:`LAYOUT`; PERF.md section 6,
+PR 71; the block with a decay a head L2-norms its q and k on a head axis
+first, ``models/delta.py`` says why). beta, and g with a decay a head, are ``[B, S, H]`` and go in as ``[B,
+H / R, S, R]`` (:func:`_columns`, 1 MB).
+
 **The pairs.** As ``delta._pairs``: rows of two sub-blocks of ``sub`` rows
 are decayed relative to the later one's first row and multiplied on the MXU
 (operands in the compute dtype); rows of one sub-block pairwise in float32
@@ -177,14 +190,25 @@ def delta_scan_path(S: int, H: int, D: int, Dv: int, chunk: int,
         S, H, D, Dv, chunk, sub, jnp.dtype(dtype).itemsize) else "xla"
 
 
+#: how a delta block (``models/delta.py``) keeps what it hands the scan and
+#: takes from it, on either path: what the kernels read and write; and the
+#: one exception, in the block with a decay a head
+LAYOUT = ("heads on the lanes: q, k, v, a decay a channel and o are [B, S, "
+          "heads x D] from the in-projection to the out-projection, a head's "
+          "sums products with a 0/1 matrix (no axis for the heads)")
+LAYOUT_A_HEAD = LAYOUT + "; q and k L2-normed as [B, S, key heads, D]"
+
+
 def describe(S: int, H: int, D: int, Dv: int, chunk: int,
              dtype=jnp.bfloat16, sub: int = 16, a_head: bool = False) -> str:
-    """:func:`delta_scan_path` with the kernels' grid and blocks (what
-    ``chip_smoke.py`` prints); ``a_head``: the form with a decay a head."""
+    """:func:`delta_scan_path` with the kernels' grid and blocks and the
+    block's layout round them (what ``chip_smoke.py`` prints); ``a_head``:
+    the form with a decay a head."""
     path = delta_scan_path(S, H, D, Dv, chunk, dtype, sub)
+    layout = LAYOUT_A_HEAD if a_head else LAYOUT
     if path != "kernels":
         return f"xla (backend {jax.default_backend()}, heads of {D} / {Dv}, " \
-               f"chunks of {chunk})"
+               f"chunks of {chunk}); {layout}"
     itemsize = jnp.dtype(dtype).itemsize
     R = delta_head_tile(H, D, Dv, chunk, itemsize,
                         HEAD_TILE_A_HEAD if a_head else HEAD_TILE)
@@ -193,7 +217,7 @@ def describe(S: int, H: int, D: int, Dv: int, chunk: int,
             f"sub-blocks of {min(sub, chunk)} rows, carried state "
             f"{R}x{Dv}x{D} float32 in VMEM "
             f"({delta_vmem_bytes(chunk, D, Dv, R, itemsize) / 2**20:.1f} MiB "
-            f"a step of {VMEM_BUDGET / 2**20:.0f})")
+            f"a step of {VMEM_BUDGET / 2**20:.0f}); {layout}")
 
 
 # -- the pieces a test swaps for a wrong one ----------------------------------

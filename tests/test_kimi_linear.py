@@ -161,6 +161,88 @@ def test_a_chunk_that_does_not_divide_the_sequence_is_refused():
         delta.delta_chunked(*_scan_inputs(s=24), 16)
 
 
+# -- a head is a run of lanes ---------------------------------------------------
+
+def _flat_pieces(form):
+    """(the block's flat pieces, the same with a head's channels on an axis
+    of their own, float32 operands) of a block of 4 value heads of 16, in
+    the form with a decay a head on 2 key heads: each a function of arrays
+    ``[B, S, heads 16]`` as the projections write them."""
+    B, S, H, D = 2, 24, 4, 16
+    Hk = 2 if form == "head" else H
+    keys, eps = Hk * D, 1e-5
+    activation = jax.nn.silu if form == "head" else jax.nn.sigmoid
+    rng = np.random.RandomState(5)
+
+    def draw(*shape):
+        return jnp.asarray(rng.randn(*shape), jnp.float32)
+
+    def axis_l2norm(x, heads):
+        y = x.reshape(B, S, heads, D)
+        y = y * jax.lax.rsqrt(jnp.sum(jnp.square(y), -1, keepdims=True)
+                              + delta.L2_EPS)
+        return y.reshape(x.shape)
+
+    def cut(qkv):       # q | k of one array, as the form with a decay a head
+        return qkv[..., :keys], qkv[..., keys:2 * keys]
+
+    def axis_norm_gate(o, gate, weight):
+        o = o.reshape(B, S, H, D)
+        y = (o * jax.lax.rsqrt(jnp.mean(jnp.square(o), -1, keepdims=True)
+                               + eps)
+             * weight * activation(gate).reshape(B, S, H, D))
+        return y.reshape(B, S, H * D)
+
+    def axis_decay(rate, a_log, dt_bias):
+        if form == "head":
+            return -(jnp.exp(a_log) * jax.nn.softplus(rate + dt_bias))
+        return -(jnp.exp(a_log)[:, None] * jax.nn.softplus(
+            (rate + dt_bias).reshape(B, S, H, D))).reshape(rate.shape)
+    per = 1 if form == "head" else D
+    return {
+        "l2norm": (
+            lambda qkv: tuple(delta._l2norm(x, Hk) for x in cut(qkv)),
+            lambda qkv: tuple(axis_l2norm(x, Hk) for x in cut(qkv)),
+            (3.0 * draw(B, S, 2 * keys + H * D),)),
+        "norm_gate": (
+            lambda o, gate, weight: delta._head_norm(o, weight, H, eps)
+            * activation(gate),
+            axis_norm_gate,
+            (3.0 * draw(B, S, H * D), draw(B, S, H * D),
+             1.0 + 0.1 * draw(D))),
+        "decay": (
+            delta._log_decay, axis_decay,
+            (draw(B, S, H * per), draw(H), draw(H * per))),
+    }
+
+
+@pytest.mark.parametrize("piece", ["l2norm", "norm_gate", "decay"])
+@pytest.mark.parametrize("form", ["channel", "head"])
+def test_a_head_on_the_lanes_is_the_head_on_an_axis(form, piece):
+    """The two L2 norms, the output norm times its gate and the log decay,
+    every array ``[B, S, heads D]`` with a head's sums products with a 0/1
+    matrix, against the head-axis form: values and every operand's gradient,
+    float32 (heads of 16: the products are right at any width, the cells'
+    128 is only where the lanes' tiles fall; the block with a decay a head
+    takes its q and k through the head-axis L2 norm, models/delta.py says
+    why, so the flat one on fewer key heads is held here and by Kimi's
+    block)."""
+    flat, by_axis, operands = _flat_pieces(form)[piece]
+
+    def both(f):
+        out, pull = jax.vjp(f, *operands)
+        rng = np.random.RandomState(6)
+        ct = jax.tree_util.tree_map(
+            lambda o: jnp.asarray(rng.randn(*o.shape), jnp.float32), out)
+        return jax.tree_util.tree_leaves((out, pull(ct)))
+    got, want = both(flat), both(by_axis)
+    assert len(got) == len(want) > len(operands)
+    for g, w in zip(got, want):
+        assert g.shape == w.shape and g.dtype == jnp.float32
+        assert np.linalg.norm(np.asarray(w)) > 0
+        assert _rel(g, w) < 1e-6
+
+
 # -- the padded core ------------------------------------------------------------
 
 def test_the_padded_core_is_the_unpadded_one():
@@ -235,7 +317,7 @@ FAULTS = {
             beta=jnp.ones_like))},
     "the L2 norm dropped":
         {"patch": lambda: (delta, "_l2norm",
-                           lambda x: x.astype(jnp.float32))},
+                           lambda x, heads: x.astype(jnp.float32))},
     "silu off the convolution":
         {"patch": lambda: (delta, "_short_conv", lambda x, taps:
                            delta._causal_conv(x, taps, None))},

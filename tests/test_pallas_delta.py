@@ -343,6 +343,7 @@ def test_the_kernels_run_on_a_tpu_at_the_cell_s_shape(monkeypatch):
     said = pd.describe(*CELL)
     assert pd.FWD_NAME in said and pd.BWD_NAME in said
     assert "128 chunks" in said and "sub-blocks of 16 rows" in said
+    assert "heads on the lanes" in said
     assert pd.delta_vmem_bytes(64, 128, 128, pd.delta_head_tile(
         32, 128, 128, 64), 2) <= pd.VMEM_BUDGET
     assert _calls_a_kernel(256, 2, 128, 128, 64)

@@ -246,7 +246,9 @@ PARENT_PROGRAMS = {
     "gpt-1.3b-widths": ("8d09444310935e8c", "f756b4a151f15d25"),
     "granite-4.0-h-micro": ("e450fe130a391ab5", "a91a56ea9b269743"),
     "keye-vl-2.0-30b-a3b": ("4c76e28950e8d448", "2d71ac9c8cb44b7e"),
-    "kimi-linear-48b-a3b": ("caee61d05be8b7da", "7be7a7599ccf84b6"),
+    # the one program PR 71 meant to change (the delta block keeps its heads
+    # on the lanes; "caee61d05be8b7da" before), the tree as it was
+    "kimi-linear-48b-a3b": ("5c52dba4f9460937", "7be7a7599ccf84b6"),
     "laguna-xs.2": ("bbf4b9c942caf34e", "17eefd1c9df9f2a1"),
     "lfm2-24b-a2b": ("5c2c60f6948d2ef1", "bb85add417c43d56"),
     "nemotron-3-nano-30b-a3b": ("6b9d91906fbdd3c6", "b5efb155c4415a28"),

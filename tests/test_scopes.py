@@ -457,6 +457,10 @@ def test_the_gated_delta_step_carries_every_phase_in_both_directions(phase):
                                                                  "bwd"}
 
 
+#: ``models/delta.py:_head_sums`` and ``_to_channels`` as a jaxpr names them
+_HEAD_SUMS = ("...c,ch->...h", "...h,ch->...c")
+
+
 def test_the_gates_of_the_gated_delta_step_are_where_their_phases_say():
     paths = re.findall(r'op_name="([^"]*)"', _compiled_text("gated_delta"))
 
@@ -476,8 +480,8 @@ def test_the_gates_of_the_gated_delta_step_are_where_their_phases_say():
             and scopes.DELTA in parts(p)]
     assert {next(c for c in reversed(p) if c.startswith("hvd.")) for p in
             dots} >= {scopes.DELTA_PROJ, scopes.DELTA_GATES}
-    assert not any(scopes.DELTA_CONV in p or scopes.DELTA_NORM in p
-                   for p in dots)
+    assert all(p[-2] in _HEAD_SUMS for p in dots
+               if scopes.DELTA_CONV in p or scopes.DELTA_NORM in p)
 
 
 def test_the_delta_mixer_s_parts_nest_in_it():
@@ -501,8 +505,11 @@ def test_the_delta_mixer_s_parts_nest_in_it():
         assert any(scopes.LAYERS in p for p in inside), part
     assert any("while" in p[-1] for p in map(parts, paths)
                if scopes.DELTA_SCAN in p)
-    assert not any(p[-1].startswith("dot_general") for p in map(parts, paths)
-                   if scopes.DELTA_CONV in p or scopes.DELTA_NORM in p)
+    # no projection under the convolutions or the norm: the products there
+    # are a head's sums with the 0/1 matrix and their way back
+    assert all(p[-2] in _HEAD_SUMS for p in map(parts, paths)
+               if p[-1].startswith("dot_general")
+               and (scopes.DELTA_CONV in p or scopes.DELTA_NORM in p))
     assert not any(scopes.DELTA in _compiled_text(model)
                    for model in ("flagship", "moe", "hybrid", "short_conv"))
 
