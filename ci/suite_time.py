@@ -3,7 +3,13 @@
 ROADMAP C11's table): case-seconds and cases a file, the twenty longest
 cases, the sum, and the floor ``--dist loadfile`` puts on the wall clock
 at ``n`` workers — a file is one worker's indivisible load, so the run
-takes at least the larger of sum / n and the largest file.
+takes at least the larger of sum / n and the largest file. That floor is of
+this run's seconds, not of the suite: the driver's six workers keep the
+machine's eight cores 95 to 100 % busy from the first case to the last
+files (PR 72, ``/proc/stat`` every 5 s over four whole runs), so a case's
+seconds stretch with what runs beside it, the sum moves with the order and
+the hour (7995 to 9624 case-seconds for one tree in one evening), and what
+bounds the wall clock is the work in CPU-seconds over the cores.
 
     python ci/suite_time.py /tmp/_t1.xml [n_workers=6]
 """

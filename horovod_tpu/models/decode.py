@@ -7,7 +7,9 @@ token granularity: one fixed-shape decode step over a static slot array, with
 K/V history in block-granular pages. A model of its own, single-device math
 in fp32 (serving replicas are world_size=1; bitwise-stable greedy decode is
 the parity contract ``tests/test_generate.py`` enforces); what it does not
-implement of ``TransformerConfig`` every entry point refuses by name.
+implement of ``TransformerConfig`` every entry point refuses by name, and the
+refusal is closed: a field outside ``_ALLOWED_FIELDS`` must be at its
+default, a harmless one too (``moe_z_weight`` with no experts is refused).
   k_pages / v_pages  [L, total_pages + 1, page_tokens, H*Dh]
       (+1 = the scratch page inactive/padded lanes write into, so
       membership churn never changes the compiled shape)
@@ -18,6 +20,7 @@ implement of ``TransformerConfig`` every entry point refuses by name.
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Any, Dict, Tuple
 
 import numpy as np
@@ -30,26 +33,27 @@ from horovod_tpu.models._kinds import rmsnorm, rope
 from horovod_tpu.models.transformer import (TransformerConfig,
                                             _model_leaves, _row)
 
-#: the fields of ``TransformerConfig`` that the decode paths need at their
-#: defaults: anything else is not the plain dense GPT block (multi-head
-#: attention over the whole causal history with rope on every layer, a gelu
-#: FFN, pre-norms, the tied head). The first six change the tree
-_PLAIN_FIELDS = (
-    "n_experts", "qk_norm", "tie_embeddings", "post_norm", "ffn_gated",
-    "n_loops", "layer_pattern", "moe_router_input", "expert_share",
-    "moe_router_scores", "moe_shared_width", "ssm_heads", "kv_latent",
-    "conv_taps", "lead_pattern", "mtp_depth", "embed_scale", "residual_scale",
-    "attention_scale", "logits_scale", "index_topk", "delta_heads",
-    "latent_rope", "value_width", "delta_decay", "delta_key_heads",
-    "moe_shared_gate", "zero_centred_norms")
+#: the fields of ``TransformerConfig`` a served config may set: the sizes and
+#: constants the decode paths read (from the config or the tree's shapes),
+#: and three they never read, which say how training stores and schedules the
+#: same function (param_dtype, n_microbatches, remat). Every other field
+#: is refused unless it is at its default, the plain dense GPT block
+#: (multi-head attention over the whole causal history with rope on every
+#: layer, a gelu FFN, pre-norms, the tied head): a field this module has
+#: never heard of is refused without an edit here
+_ALLOWED_FIELDS = (
+    "vocab_size", "d_model", "n_heads", "n_layers", "d_ff", "max_seq",
+    "head_width", "norm_eps", "rope_theta", "dtype", "param_dtype",
+    "n_microbatches", "remat")
 _PLAIN = TransformerConfig()
 
 
 def _plain_gpt_only(cfg: TransformerConfig) -> None:
     """Refuse, by name, what the decode paths would silently compute
     otherwise: every entry point below calls it before anything else."""
-    off = [field for field in _PLAIN_FIELDS
-           if getattr(cfg, field) != getattr(_PLAIN, field)]
+    off = [field.name for field in dataclasses.fields(_PLAIN)
+           if field.name not in _ALLOWED_FIELDS and field.name != "n_kv_heads"
+           and getattr(cfg, field.name) != getattr(_PLAIN, field.name)]
     if cfg.kv_heads != cfg.n_heads:
         off.append("n_kv_heads")
     if off:
@@ -85,7 +89,8 @@ def flatten_decode_params(params: Dict) -> Dict:
         raise NotImplementedError(
             f"paged decode supports the dense GPT block's tree, and this "
             f"one differs in {sorted(other)}: the tree of a config that "
-            f"sets one of {', '.join(_PLAIN_FIELDS[:6])}")
+            "sets one of n_experts, qk_norm, tie_embeddings, post_norm, "
+            "ffn_gated, n_loops or another field ``kv_cache_spec`` refuses")
     flat = {k: jnp.asarray(v).reshape((-1,) + tuple(np.shape(v)[2:]))
             for k, v in layers.items()}
     return {"embed": jnp.asarray(params["embed"]),

@@ -675,8 +675,8 @@ ROWS = {
         # (no ``drawn``: a rate a head is a dozen numbers at the tiny preset;
         # tests/test_qwen3_next.py holds the adapter's draw leaf by leaf)
         refused=(
-            ("the cell", "delta_heads.*delta_decay.*delta_key_heads"
-             ".*moe_shared_gate.*zero_centred_norms", None, _PAGED),
+            ("the cell", "moe_shared_gate.*delta_heads.*delta_decay"
+             ".*delta_key_heads.*zero_centred_norms", None, _PAGED),
             ("a decay a head alone", "delta_decay", _plain(
                 layer_pattern=(_DELTA, _DENSE), delta_heads=2,
                 delta_head_dim=16, delta_decay="head"), _PAGED),

@@ -134,6 +134,9 @@ def test_the_flash_tiles_at_the_cell_s_head_width():
 @pytest.mark.parametrize("what", ["loss", "main_loss", "mtp_loss"]
                          + [f"grad:{k}" for k in LEAVES])
 def test_program_matches_the_reference(what):
+    """The first case to run pays for ``ARCH.sides``: the one trace and
+    compile of the tiny preset's step and of the reference, which every case
+    after it reads."""
     got, want, _aux, _grads = ARCH.sides
     assert _rel(got[what], want[what]) < TOL, what
 

@@ -51,9 +51,12 @@ def test_driver_call_path(capsys, monkeypatch):
 
 @pytest.mark.parametrize("n", [2, 4, 16])
 def test_dryrun_device_counts(n, monkeypatch):
-    # the function self-bootstraps; call it directly at every
-    # driver-plausible device count (scaling is the driver-artifact
-    # phase, covered by test_driver_call_path — skip it here)
+    """A forced-CPU child of ``n`` devices runs every layout's step to its
+    loss: a mesh of 2, 4 or 16 devices is built, not described, and the
+    child is the driver's own call. The function self-bootstraps; call it
+    directly at every driver-plausible device count (scaling is the
+    driver-artifact phase, covered by test_driver_call_path — skip it
+    here)."""
     monkeypatch.setenv("HVD_DRYRUN_SCALING", "0")
     sys.path.insert(0, REPO)
     try:

@@ -178,6 +178,9 @@ def test_the_paths_at_the_cell_s_shapes_name_the_kernels(monkeypatch):
 @pytest.mark.parametrize("what", ["loss"] + [f"grad:{k}"
                                              for k in sorted(LEAVES)])
 def test_program_matches_the_reference(what):
+    """The first case to run pays for ``ARCH.sides``: the one trace and
+    compile of the tiny preset's step and of the reference, which every case
+    after it reads."""
     got, want, aux, _grads = ARCH.sides
     assert set(aux) == {"aux_loss"} and float(aux["aux_loss"]) == 0.0
     assert got[what].shape == want[what].shape

@@ -40,29 +40,28 @@ def test_inception_v3_trains(hvd):
 
 def test_inception_v3_channel_geometry():
     """Stage output channels match the canonical architecture:
-    35x35 stages end at 288, 17x17 at 768, 8x8 at 2048."""
+    35x35 stages end at 288, 17x17 at 768, 8x8 at 2048. Shapes only, so
+    each stage is described (``jax.eval_shape`` over ``init`` and
+    ``apply``), not run."""
     from horovod_tpu.models.inception import (InceptionA, ReductionA,
                                               InceptionB, ReductionB,
                                               InceptionC)
-    x = jnp.zeros((1, 35, 35, 192), jnp.float32)
+
+    def through(m, x):
+        def run(x):
+            v = m.init(jax.random.PRNGKey(0), x, train=False)
+            return m.apply(v, x, train=False)
+        return jax.eval_shape(run, x)
+
+    x = jax.ShapeDtypeStruct((1, 35, 35, 192), jnp.float32)
     for pf, want in ((32, 256), (64, 288), (64, 288)):
-        m = InceptionA(pf, jnp.float32)
-        v = m.init(jax.random.PRNGKey(0), x, train=False)
-        x = m.apply(v, x, train=False)
+        x = through(InceptionA(pf, jnp.float32), x)
         assert x.shape[-1] == want
-    m = ReductionA(jnp.float32)
-    v = m.init(jax.random.PRNGKey(0), x, train=False)
-    x = m.apply(v, x, train=False)
+    x = through(ReductionA(jnp.float32), x)
     assert x.shape == (1, 17, 17, 768)
-    m = InceptionB(128, jnp.float32)
-    v = m.init(jax.random.PRNGKey(0), x, train=False)
-    x = m.apply(v, x, train=False)
+    x = through(InceptionB(128, jnp.float32), x)
     assert x.shape[-1] == 768
-    m = ReductionB(jnp.float32)
-    v = m.init(jax.random.PRNGKey(0), x, train=False)
-    x = m.apply(v, x, train=False)
+    x = through(ReductionB(jnp.float32), x)
     assert x.shape == (1, 8, 8, 1280)
-    m = InceptionC(jnp.float32)
-    v = m.init(jax.random.PRNGKey(0), x, train=False)
-    x = m.apply(v, x, train=False)
+    x = through(InceptionC(jnp.float32), x)
     assert x.shape[-1] == 2048

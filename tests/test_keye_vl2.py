@@ -52,6 +52,9 @@ def _program_logits(cfg, params, tokens):
 @pytest.mark.parametrize("what", ["logits", "loss", "index_loss"]
                          + [f"grad:{k}" for k in LEAVES])
 def test_program_matches_the_reference(what):
+    """The first case to run pays for ``ARCH.sides``: the one trace and
+    compile of the tiny preset's step and of the reference, which every case
+    after it reads."""
     got, want, _aux, _grads = ARCH.sides
     assert np.linalg.norm(np.asarray(want[what])) > 0, what
     assert _rel(got[what], want[what]) < TOL, what

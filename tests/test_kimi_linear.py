@@ -37,6 +37,9 @@ SIZES, CFG, LEAVES = ARCH.SIZES, ARCH.CFG, ARCH.LEAVES
 
 @pytest.mark.parametrize("what", ["loss"] + [f"grad:{k}" for k in LEAVES])
 def test_program_matches_the_reference(what):
+    """The first case to run pays for ``ARCH.sides``: the one trace and
+    compile of the tiny preset's step and of the reference, which every case
+    after it reads."""
     got, want, _aux, _grads = ARCH.sides
     if what != "grad:layers.experts.router_bias":   # a buffer: no gradient
         assert np.linalg.norm(np.asarray(want[what])) > 0, what

@@ -211,6 +211,9 @@ def test_a_table_of_the_kind_s_own_rotates_its_channels_only():
 
 @pytest.mark.parametrize("what", ["loss"] + [f"grad:{k}" for k in LEAVES])
 def test_program_matches_the_reference(what):
+    """The first case to run pays for ``ARCH.sides``: the one trace and
+    compile of the tiny preset's step and of the reference, which every case
+    after it reads."""
     got, want, _aux, _grads = ARCH.sides
     assert np.linalg.norm(np.asarray(want[what])) > 0
     assert _rel(got[what], want[what]) < TOL, what

@@ -125,12 +125,9 @@ def test_the_step_s_required_flops_by_hand():
 
 def test_the_kernels_least_work_by_hand():
     """The grouped matmuls' count is the one a metric of this cell reads.
-    No metric lists the cell for the other three kernels yet (the ○ of
-    PERF.md section 3's table: ``per_layer`` has no place left until the
-    per-cell copies merge, section 7): their counts are the ones the dense
-    hybrid cell's metrics read, which take generic ``shapes()`` keys only
-    and count this cell's calls right too, so the day the cell is listed
-    under them its shares are held already."""
+    The other three kernels' counts are taken through the dense hybrid
+    cell's metrics, which list this cell too since PR 63: they take generic
+    ``shapes()`` keys only and count this cell's calls right."""
     gmm = chip_door.roofline("lfm2-24b-a2b.s8192", "hvd_moe_gmm")
     fwd, bwd, xent = (
         chip_door.roofline("granite-4.0-h-micro.s4096", kernel)
@@ -159,6 +156,9 @@ def test_the_kernels_least_work_by_hand():
 
 @pytest.mark.parametrize("what", ["loss"] + [f"grad:{k}" for k in LEAVES])
 def test_program_matches_the_reference(what):
+    """The first case to run pays for ``ARCH.sides``: the one trace and
+    compile of the tiny preset's step and of the reference, which every case
+    after it reads."""
     got, want, _aux, _grads = ARCH.sides
     assert np.linalg.norm(np.asarray(want[what])) > 0
     assert _rel(got[what], want[what]) < TOL, what

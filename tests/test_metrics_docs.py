@@ -91,3 +91,15 @@ def test_suite_time_reads_a_junit_file_s_cases():
                          "10.0)")
     assert suite_time.report(junit, 1).endswith(
         "14.0 s (sum / n 14.0, largest file 10.0)")
+
+
+def test_perf_md_can_be_opened_whole():
+    """``PERF.md`` stays under the 256 000 bytes at which a session's file
+    reader refuses a file whole, no line over 8 000 (ROADMAP C8 e: it had
+    grown to 559 175 bytes with lines of 21 KB, and a record nobody can
+    open is a record nobody checks against the ledger). When Findings
+    outgrows its room, fold the oldest entries; ``CHANGES.md`` keeps them."""
+    with open(os.path.join(REPO, "PERF.md"), "rb") as f:
+        text = f.read()
+    assert len(text) < 256_000
+    assert max(len(line) for line in text.split(b"\n")) <= 8_000

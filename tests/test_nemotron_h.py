@@ -122,6 +122,9 @@ def test_the_scan_kernels_least_work_by_hand():
 
 @pytest.mark.parametrize("what", ["loss"] + [f"grad:{k}" for k in LEAVES])
 def test_program_matches_the_reference(what):
+    """The first case to run pays for ``ARCH.sides``: the one trace and
+    compile of the tiny preset's step and of the reference, which every case
+    after it reads."""
     got, want, _aux, _grads = ARCH.sides
     assert _rel(got[what], want[what]) < TOL, what
 

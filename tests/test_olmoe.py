@@ -39,6 +39,9 @@ assert CFG.dtype == jnp.float32
     "grad:expert_gate", "grad:expert_up", "grad:expert_down", "grad:wq",
     "grad:lm_head"])
 def test_program_matches_the_reference(what):
+    """The first case to run pays for ``ARCH.sides``: the one trace and
+    compile of the tiny preset's step and of the reference, which every case
+    after it reads."""
     got, want, _aux, _grads = ARCH.sides
     assert np.linalg.norm(np.asarray(want[what])) > 0
     assert _rel(got[what], want[what]) < TOL, what

@@ -39,6 +39,9 @@ _params, _batch, _program = ARCH.params, ARCH.batch, ARCH.program
     "loss", "step_losses", "exit_share", "gate_entropy",
     *(f"grad:{k}" for k in LEAVES)])
 def test_program_matches_the_reference(what):
+    """The first case to run pays for ``ARCH.sides``: the one trace and
+    compile of the tiny preset's step and of the reference, which every case
+    after it reads."""
     got, want, _aux, _grads = ARCH.sides
     assert np.linalg.norm(np.asarray(want[what])) > 0
     assert _rel(got[what], want[what]) < TOL, what

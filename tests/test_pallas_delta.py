@@ -67,17 +67,22 @@ def _weight(shape, seed=1):
                        jnp.float32)
 
 
+# A form: shape -> (operands -> (o, what the form gives beside o)).
+
 def _kernels(shape):
-    chunk, head_tile = shape[5:]
-    return lambda *v: pd.delta_scan(*v, chunk, delta.SUB, True, head_tile)[0]
+    """Beside o: every chunk's last ``Gamma``, the kernels' small output."""
+    chunk, head_tile = shape[-2:]
+    return lambda *v: pd.delta_scan(*v, chunk, delta.SUB, True, head_tile)
 
 
 def _numpy_form(shape):
-    return lambda *v: delta._delta_chunked_numpy(*v, shape[5])[0]
+    """Beside o: the least of the chunks' last ``Gamma``."""
+    return lambda *v: delta._delta_chunked_numpy(*v, shape[-2])
 
 
-def _stepwise(*ops):
-    return reference.delta_rule(*(x.astype(jnp.float32) for x in ops))
+def _recurrence(shape):
+    return lambda *v: (reference.delta_rule(
+        *(x.astype(jnp.float32) for x in v)), None)
 
 
 def _rel(got, want):
@@ -85,22 +90,40 @@ def _rel(got, want):
     return np.linalg.norm(got - want) / (np.linalg.norm(want) or 1.0)
 
 
-def _both(f, weight, ops):
-    """o and the five cotangents under ``sum(f(*ops) weight)``, as one
-    traced and compiled program."""
-    def run(*v):
-        o, pull = jax.vjp(f, *v)
-        return (o,) + pull(weight)
-    return jax.jit(run)(*ops)
+def _program(form, shape):
+    """``(weight, *ops)`` -> o, the five cotangents under
+    ``sum(form(shape)(*ops) weight)`` and what the form gives beside o, as
+    one jitted program."""
+    f = form(shape)
+
+    def run(weight, *v):
+        o, pull, beside = jax.vjp(f, *v, has_aux=True)
+        return (o,) + pull(weight) + (beside,)
+    return jax.jit(run)
+
+
+#: a form at a shape is traced and compiled once a file (``jax.jit`` keeps a
+#: program an operand dtype); operands and weights differ a case
+_sound_program = functools.lru_cache(maxsize=None)(_program)
+
+
+def _both(form, shape, weight, ops):
+    return _sound_program(form, shape)(weight, *ops)
+
+
+def _both_under_a_patch(form, shape, weight, ops):
+    """Traced now, from the pieces as they are patched now, and kept by
+    nobody: no sound program serves a patched case, nor the reverse."""
+    return _program(form, shape)(weight, *ops)
 
 
 @pytest.mark.parametrize("shape", SHAPES.values(), ids=SHAPES.keys())
 def test_the_kernels_are_the_numpy_form_and_the_recurrence(shape):
     """o, and the cotangents of q, k, v, g and beta."""
     ops, weight = _operands(shape), _weight(shape)
-    got = _both(_kernels(shape), weight, ops)
-    want = _both(_numpy_form(shape), weight, ops)
-    stepwise = _both(_stepwise, weight, ops)
+    got = _both(_kernels, shape, weight, ops)
+    want = _both(_numpy_form, shape, weight, ops)
+    stepwise = _both(_recurrence, shape, weight, ops)
     assert got[0].dtype == jnp.float32 and got[0].shape == weight.shape
     for name, g, w, r in zip(("o",) + NAMES, got, want, stepwise):
         assert g.dtype == w.dtype and g.shape == w.shape, name
@@ -111,13 +134,14 @@ def test_the_kernels_are_the_numpy_form_and_the_recurrence(shape):
 def test_one_chunk_is_the_whole_sequence_and_the_state_crosses_chunks():
     shape = (1, 64, 1, 128, 128, 64, 1)
     ops, weight = _operands(shape, seed=2), _weight(shape)
-    whole = _both(_kernels(shape), weight, ops)
-    halves = _both(_kernels(shape[:5] + (32, 1)), weight, ops)
+    whole = _both(_kernels, shape, weight, ops)
+    halves = _both(_kernels, shape[:5] + (32, 1), weight, ops)
     for name, g, w in zip(("o",) + NAMES, halves, whole):
         assert _rel(g, w) < TOL, name
-    alone = _kernels(shape[:5] + (32, 1))(*(x[:, 32:] for x in ops))
+    a_half = jax.jit(_kernels(shape[:5] + (32, 1)))     # traced once for both
+    alone, _ = a_half(*(x[:, 32:] for x in ops))
     assert _rel(alone, whole[0][:, 32:]) > 1e-2
-    first = _kernels(shape[:5] + (32, 1))(*(x[:, :32] for x in ops))
+    first, _ = a_half(*(x[:, :32] for x in ops))
     assert _rel(first, whole[0][:, :32]) < TOL
 
 
@@ -127,7 +151,7 @@ def test_the_chunks_last_sums_are_the_numpy_form_s():
     shape = SHAPES["batch 2, two heads a step, two sub-blocks"]
     ops = _operands(shape, rate=1.5)
     _, got = delta.delta_chunked(*ops, shape[5], interpret=True)
-    _, want = delta._delta_chunked_numpy(*ops, shape[5])
+    want = _both(_numpy_form, shape, _weight(shape), ops)[-1]
     assert float(got) == pytest.approx(float(want), rel=1e-6)
     assert float(got) < -10
 
@@ -140,9 +164,9 @@ def test_a_fast_decay_stays_finite_and_equal():
     shape = SHAPES["two chunks of four sub-blocks"]
     q, k, v, g, beta = _operands(shape)
     ops, weight = (q, k, v, jnp.full_like(g, -3.5), beta), _weight(shape)
-    got = _both(_kernels(shape), weight, ops)
-    want = _both(_stepwise, weight, ops)
-    assert all(bool(jnp.all(jnp.isfinite(x))) for x in got)
+    got = _both(_kernels, shape, weight, ops)
+    want = _both(_recurrence, shape, weight, ops)
+    assert all(bool(jnp.all(jnp.isfinite(x))) for x in got[:6])
     for name, g_, w in zip(("o",) + NAMES, got, want):
         assert _rel(g_, w) < TOL, name
     _, low = delta.delta_chunked(*ops, 64, interpret=True)
@@ -185,11 +209,11 @@ def _decays_capped(log_decay):
 def test_a_wrong_piece_fails_on_the_kernels(monkeypatch, what, patches):
     """The forward and, beside it, the gradients (the backward kernel makes
     its decays, mask and carried cotangent from the same three pieces). The
-    sound kernels on the same operands: ``_sound_errors``, run once."""
-    assert max(_sound_errors()) < TOL
+    sound kernels on the same operands are inside TOL."""
+    assert max(_errors(_both)) < TOL
     for name, wrong in patches.items():
         monkeypatch.setattr(pd, name, wrong)
-    forward, backward = _errors()
+    forward, backward = _errors(_both_under_a_patch)
     assert forward > 4 * TOL, (what, forward)
     assert backward > 4 * TOL, (what, backward)
 
@@ -197,21 +221,13 @@ def test_a_wrong_piece_fails_on_the_kernels(monkeypatch, what, patches):
 _WRONG_AT = (1, 64, 1, 128, 128, 32, 1)
 
 
-@functools.lru_cache(maxsize=None)
-def _recurrence_at_the_wrong_pieces_shape():
-    return _both(_stepwise, _weight(_WRONG_AT), _operands(_WRONG_AT, rate=1.0))
-
-
-def _errors():
+def _errors(both):
     """(o's, the worst cotangent's) distance from the recurrence's."""
-    want = _recurrence_at_the_wrong_pieces_shape()
-    got = _both(_kernels(_WRONG_AT), _weight(_WRONG_AT),
-                _operands(_WRONG_AT, rate=1.0))
+    weight, ops = _weight(_WRONG_AT), _operands(_WRONG_AT, rate=1.0)
+    want = _both(_recurrence, _WRONG_AT, weight, ops)
+    got = both(_kernels, _WRONG_AT, weight, ops)
     return (_rel(got[0], want[0]),
-            max(_rel(g, w) for g, w in zip(got[1:], want[1:])))
-
-
-_sound_errors = functools.lru_cache(maxsize=None)(_errors)
+            max(_rel(g, w) for g, w in zip(got[1:6], want[1:6])))
 
 
 def test_bfloat16_operands_keep_float32_sums_decays_and_state():
@@ -220,8 +236,8 @@ def test_bfloat16_operands_keep_float32_sums_decays_and_state():
     same dtypes (which rounds the same operands in another order)."""
     shape = SHAPES["batch 2, two heads a step, two sub-blocks"]
     ops, weight = _operands(shape, dtype=jnp.bfloat16), _weight(shape)
-    got = _both(_kernels(shape), weight, ops)
-    want = _both(_numpy_form(shape), weight, ops)
+    got = _both(_kernels, shape, weight, ops)
+    want = _both(_numpy_form, shape, weight, ops)
     assert got[0].dtype == jnp.float32
     assert _rel(got[0], want[0]) < 1e-2
     for name, g, w, op in zip(NAMES, got[1:], want[1:], ops):
@@ -252,13 +268,9 @@ def _head_operands(shape, seed=0, dtype=jnp.float32, rate=0.3):
     return q[:, :, :Hk], k[:, :, :Hk], v, g[..., 0], beta
 
 
-def _head_kernels(shape):
-    chunk, head_tile = shape[6:]
-    return lambda *v: pd.delta_scan(*v, chunk, delta.SUB, True, head_tile)[0]
-
-
-def _head_stepwise(*ops):
-    return head_reference.delta_rule(*(x.astype(jnp.float32) for x in ops))
+def _head_recurrence(shape):
+    return lambda *v: (head_reference.delta_rule(
+        *(x.astype(jnp.float32) for x in v)), None)
 
 
 @pytest.mark.parametrize("shape", HEAD_SHAPES.values(),
@@ -270,19 +282,17 @@ def test_the_kernels_for_a_decay_a_head_are_the_numpy_form_and_the_recurrence(
     part of a group), v, g ``[B, S, H]`` and beta."""
     ops = _head_operands(shape)
     weight = _weight(shape[:3] + shape[4:6])
-    got = _both(_head_kernels(shape), weight, ops)
-    want = _both(lambda *v: delta._delta_chunked_numpy(*v, shape[6])[0],
-                 weight, ops)
-    stepwise = _both(_head_stepwise, weight, ops)
+    got = _both(_kernels, shape, weight, ops)
+    want = _both(_numpy_form, shape, weight, ops)
+    stepwise = _both(_head_recurrence, shape, weight, ops)
     for name, g, w, r, op in zip(("o",) + NAMES, got, want, stepwise,
                                  (weight,) + ops):
         assert g.dtype == w.dtype and g.shape == w.shape == op.shape, name
         assert _rel(g, w) < TOL, name
         assert _rel(g, r) < TOL, name
-    _, last = pd.delta_scan(*ops, shape[6], delta.SUB, True, shape[7])
+    last = got[-1]
     assert last.shape == (shape[0], shape[1] // shape[6], 1, shape[2])
-    assert float(jnp.min(last)) == pytest.approx(float(
-        delta._delta_chunked_numpy(*ops, shape[6])[1]), rel=1e-6)
+    assert float(jnp.min(last)) == pytest.approx(float(want[-1]), rel=1e-6)
 
 
 @pytest.mark.parametrize("what, patches", [
@@ -300,16 +310,16 @@ def test_a_wrong_piece_fails_on_the_kernels_for_a_decay_a_head(
     shape = (1, 64, 2, 1, 128, 128, 32, 2)
     ops = _head_operands(shape, rate=1.0)
     weight = _weight(shape[:3] + shape[4:6])
-    want = _both(_head_stepwise, weight, ops)
+    want = _both(_head_recurrence, shape, weight, ops)
 
-    def errors():
-        got = _both(_head_kernels(shape), weight, ops)
+    def errors(both):
+        got = both(_kernels, shape, weight, ops)
         return (_rel(got[0], want[0]),
-                max(_rel(g, w) for g, w in zip(got[1:], want[1:])))
-    assert max(errors()) < TOL
+                max(_rel(g, w) for g, w in zip(got[1:6], want[1:6])))
+    assert max(errors(_both)) < TOL
     for name, wrong in patches.items():
         monkeypatch.setattr(pd, name, wrong)
-    forward, backward = errors()
+    forward, backward = errors(_both_under_a_patch)
     assert forward > 4 * TOL, (what, forward)
     assert backward > 4 * TOL, (what, backward)
 

@@ -49,6 +49,9 @@ def _reference_logits(params, tokens, sizes=SIZES):
 @pytest.mark.parametrize("what", ["logits", "loss", "load_balance_loss"]
                          + [f"grad:{k}" for k in LEAVES])
 def test_program_matches_the_reference(what):
+    """The first case to run pays for ``ARCH.sides``: the one trace and
+    compile of the tiny preset's step and of the reference, which every case
+    after it reads."""
     got, want, _aux, _grads = ARCH.sides
     assert _rel(got[what], want[what]) < TOL, what
 
