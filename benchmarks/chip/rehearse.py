@@ -4,7 +4,9 @@
 
 compiles the cell's real train step, at its real sizes, for a *described*
 ``v5e:2x2`` (no chip attached), and prints ``memory_analysis()`` per
-device, the collectives and the Pallas kernels found in the program. A
+device, how that stands to the fit ``run.py`` holds on the chip (the limit,
+the margin, the headroom), the collectives and the Pallas kernels found in
+the program. A
 compile is not a chip run: it says that the TPU compiler accepts the step
 and what it needs, nothing about results or times.
 
@@ -54,6 +56,11 @@ def compile_cell(name: str) -> dict:
         jax.default_backend = real_backend
     text = compiled.as_text()
     nbytes = harness.step_bytes(compiled.memory_analysis())
+    try:        # the fit run.py holds every run on the chip to
+        fit = harness.hold_fit(
+            nbytes, harness.read_json(HERE, "peaks.json")["TPU v5 lite"])
+    except harness.BenchFailure as e:
+        fit = {"refused": str(e)}
     collectives = {}
     for op in re.findall(r"= \S+ (all-reduce|reduce-scatter|all-gather|"
                          r"collective-permute|all-to-all)[-a-z]*\(", text):
@@ -65,6 +72,7 @@ def compile_cell(name: str) -> dict:
         "cell": name, "compiled_for": "described v5e:2x2, no chip",
         "devices": int(mesh.size), "compile_seconds": time.perf_counter() - t0,
         "per_device_gb": {k: v / 1e9 for k, v in nbytes.items()},
+        "fit": fit,
         "collectives_in_program": collectives,
         "pallas_kernels_in_program": kernels,
     }

@@ -93,6 +93,27 @@ def step_bytes(mem) -> dict:
     return doc
 
 
+def hold_fit(compiled: dict, peaks: dict) -> dict:
+    """The compiled step (:func:`step_bytes`) against the chip: the most
+    the compiler admits a program on this device (``peaks.json``) less what
+    the step takes, which must leave the margin ``peaks.json`` states, else
+    the run gives no result. Memory is a fit and no rate: no parent is
+    asked, and what a change spends inside the fit is a per-layer reading
+    (``hbm.compiled_gb``). The margin is for the next change: the heap's
+    packing alone moves a step's bytes (README.md, "The fit")."""
+    limit = peaks["hbm_compile_limit_bytes"]
+    margin = peaks["hbm_fit_margin_bytes"]
+    headroom = limit - compiled["total"]
+    if headroom < margin:
+        raise BenchFailure(
+            f"the compiled step takes {compiled['total'] / GB:.3f} GB a "
+            f"device and the compiler admits {limit / GB:.3f}: "
+            f"{headroom / GB:.3f} GB of headroom, under the margin of "
+            f"{margin / GB:.3f} GB the benchmark holds every cell to")
+    return {"limit_bytes": limit, "margin_bytes": margin,
+            "headroom_bytes": headroom}
+
+
 def record_path(out: str, cell: str, seed: int, trace: int) -> str:
     return os.path.join(out, f"{cell}.seed{seed}.trace{trace}.json")
 
@@ -305,7 +326,10 @@ def read_layer_metric(read: dict, ctx: dict):
             raise BenchFailure(f"unknown span reduce {read['reduce']!r}")
         return ctx.get("spans_median_ms", {}).get(read["span"])
     if "counter" in read:
-        return ctx.get("counters", {}).get(read["counter"])
+        value = ctx.get("counters", {}).get(read["counter"])
+        if value is not None and "per" in read:     # bytes as GB, ..
+            value /= read["per"]
+        return value
     if "trace_ops" in read:
         trace = ctx.get("trace")
         if trace is None:
@@ -513,8 +537,10 @@ def measure(args, bench, entry, config, job) -> int:
     # lowering again reuses the jitted step's executable: no second compile
     compiled_step = cell.compiled_step(next(batches))
     compiled = step_bytes(compiled_step.memory_analysis())
-    live_peak = max(((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
-                     for d in devices), default=0)
+    # (a rehearsal has no chip, so no row of peaks.json and no limit to hold)
+    fit = hold_fit(compiled, peaks) if peaks is not None else None
+    stats = [d.memory_stats() or {} for d in devices]
+    live_peak = max((s.get("peak_bytes_in_use", 0) for s in stats), default=0)
     # memory_stats() counts live arrays and misses a program's temporaries
     # on this chip, so the fullest chip held at least the compiled step
     device["memory_peak_bytes"] = int(max(live_peak, compiled["total"]))
@@ -537,8 +563,9 @@ def measure(args, bench, entry, config, job) -> int:
             "trace": trace, "hlo_text": hlo_text, "scopes": scopes,
             "peaks": peaks, "trace_steps": job["trace_steps"],
             "shapes": adapter.shapes(config, job),
-            "counters": setup_counters(compile_totals, split,
-                                       compiles_in_window)}
+            "counters": {
+                **setup_counters(compile_totals, split, compiles_in_window),
+                **{f"step_bytes_{k}": v for k, v in compiled.items()}}}
         values = layer_values(bench, args.workload, ctx)
         laps.append(time.perf_counter())
         phases = {"hlo_text_bytes": len(hlo_text),
@@ -552,8 +579,7 @@ def measure(args, bench, entry, config, job) -> int:
                           (b - a for a, b in zip(laps, laps[1:]))))
     else:
         values = {"tokens_per_s_per_chip": tokens_per_s_per_chip,
-                  "setup_s": setup_s,
-                  "hbm_compiled_gb": compiled["total"] / GB}
+                  "setup_s": setup_s}
         if peaks is not None:
             values["mfu_pct"] = (
                 100.0 * tokens_per_s_per_chip
@@ -607,8 +633,10 @@ def measure(args, bench, entry, config, job) -> int:
         "step_done_at_s": done_at, "losses": loss_values,
         "warmup_steps": len(warm),
         "host_spans_median_ms": spans.median_ms(),
-        "compiled_step_bytes": compiled,
+        "compiled_step_bytes": compiled, "hbm_fit": fit,
         "memory_stats_peak_bytes": live_peak,
+        # (what peaks.json's hbm_compile_limit_bytes was read from)
+        "memory_stats_bytes_limit": [s.get("bytes_limit") for s in stats],
         "after_window_s": time.perf_counter() - t_after,
     }
     if phases is not None:
@@ -622,7 +650,7 @@ def measure(args, bench, entry, config, job) -> int:
         last_loss=window_losses[-1], compiles_in_window=compiles_in_window,
         replicas_agree=replicas_agree, record=os.path.relpath(path, ROOT),
         host_spans_median_ms=record["host_spans_median_ms"],
-        compiled_step_bytes=record["compiled_step_bytes"],
+        compiled_step_bytes=compiled, hbm_fit=fit,
         memory_stats_peak_bytes=live_peak)
     print(json.dumps(result), flush=True)
     for name, (value, limit) in result["compared"].items():

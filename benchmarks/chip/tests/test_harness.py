@@ -136,6 +136,15 @@ def test_a_cell_a_config_and_a_metric_are_added_by_files_alone(tmp_path):
             record = _read(root, "out", "throwaway.s8.seed3.trace1.json")
             assert record["phases"]["program_has_scopes"] is True
             assert record["phases"]["hlo_text_bytes"] > 0
+            # the compiled step's memory: every cell's, a later one's too,
+            # the record's bytes to the digit; no limit is held off the chip
+            nbytes = record["compiled_step_bytes"]
+            assert got["hbm.compiled_gb"] == nbytes["total"] / 1e9
+            assert got["hbm.temporaries_gb"] == nbytes["temp"] / 1e9 > 0
+            assert record["hbm_fit"] is None
+        else:
+            assert set(result["metrics"]) == {
+                "rehearsal.tokens_per_s_per_chip", "rehearsal.setup_s"}
         # nothing of a rehearsal stands under a metric's own name
         assert all(k.startswith("rehearsal.") for k in result["metrics"])
         # every earlier line names the device it ran on
@@ -150,6 +159,76 @@ def test_a_cell_a_config_and_a_metric_are_added_by_files_alone(tmp_path):
         env=env, capture_output=True, text=True, timeout=300)
     assert run.returncode != 0
     assert run.stdout.strip() == ""
+
+
+class _Memory:
+    """A ``memory_analysis()`` written by hand: arguments donated in place
+    but for a scalar's worth of outputs, as a train step's are."""
+
+    def __init__(self, argument: int, temp: int):
+        self.argument_size_in_bytes = argument
+        self.output_size_in_bytes = argument
+        self.alias_size_in_bytes = argument - 512
+        self.temp_size_in_bytes = temp
+
+
+def test_the_fit_is_held_to_the_limit_less_the_margin():
+    """Memory is a fit: a compiled step inside the margin under the chip's
+    limit gives no result and the failure names both numbers; one byte
+    outside the margin passes, whatever a parent took."""
+    import pytest
+    import run as harness
+    peaks = _read(CHIP, "peaks.json")["TPU v5 lite"]
+    limit, margin = (peaks["hbm_compile_limit_bytes"],
+                     peaks["hbm_fit_margin_bytes"])
+    argument = 8_000_000_000
+
+    def step(total):
+        return harness.step_bytes(_Memory(argument, total - argument - 512))
+
+    edge = limit - margin
+    assert step(edge)["total"] == edge
+    assert harness.hold_fit(step(edge), peaks) == {
+        "limit_bytes": limit, "margin_bytes": margin,
+        "headroom_bytes": margin}
+    for total in (edge + 1, limit, limit + margin):
+        with pytest.raises(harness.BenchFailure) as failure:
+            harness.hold_fit(step(total), peaks)
+        said = str(failure.value)
+        assert f"{total / 1e9:.3f} GB" in said, said
+        assert f"{limit / 1e9:.3f}" in said and f"{margin / 1e9:.3f} GB" in said
+    # the margin's own terms (PERF.md section 2): at least twice the largest
+    # move the heap's packing alone has made (0.16 GB), and no cell the
+    # benchmark has stands inside it (the fullest: 15.059 GB)
+    assert margin >= 2 * 0.16e9 and 15_058_583_552 + margin < limit
+    # the limit is the compiler's "15.75G" as the device states it: GiB (16
+    # less the runtime's 258 MiB), not 15.75 GB
+    assert limit == 16 * 2**30 - 258 * 2**20 - 512
+
+
+def test_memory_is_no_end_to_end_metric_and_every_cell_reads_it():
+    """``end_to_end`` holds no reading of memory (a bound against a parent
+    refused every trade of memory for time that fits the chip); the two
+    ``hbm.*`` readings are every cell's: no ``workloads`` list, so a cell a
+    later PR adds reports them too."""
+    import run as harness
+    bench = _read(ROOT, "BENCHMARK.json")
+    assert [m["name"] for m in bench["end_to_end"]] == [
+        "tokens_per_s_per_chip", "mfu_pct", "setup_s"]
+    entries = {m["name"]: m for m in bench["per_layer"]}
+    counters = {"step_bytes_total": 10_892_703_744,
+                "step_bytes_temp": 6_869_846_016}
+    for name, want in (("hbm.compiled_gb", 10.892703744),
+                       ("hbm.temporaries_gb", 6.869846016)):
+        assert "workloads" not in entries[name]
+        assert entries[name]["source"] == "program_counter"
+        assert entries[name]["moves"] == "tokens_per_s_per_chip"
+        spec = _read(CHIP, "layer_metrics", name + ".json")
+        assert harness.read_layer_metric(
+            spec["read"], {"counters": counters}) == want
+        for cell in (w["name"] for w in bench["workloads"]):
+            assert entries[name] in harness.metrics_of(
+                bench, "per_layer", cell)
 
 
 def _tiny_cell(config_name, traffic, adapter_name):

@@ -80,7 +80,9 @@ def test_the_accepted_entries_stand_first_and_as_they_were():
     """The standing list since PR 74's merge: the names PR 63 and the PRs
     after it kept, in the order they had (the nineteen that
     ``retired_pr74.json`` names taken out, nothing moved), then the six
-    shares PR 74 made. A later PR's entries stand after them."""
+    shares PR 74 made, then the compiled step's two readings of memory
+    (PR 75, when ``hbm_compiled_gb`` left ``end_to_end``). A later PR's
+    entries stand after them."""
     names = [m["name"] for m in BENCH["per_layer"]]
     assert names[:5] == ["host.input_ms", "host.dispatch_ms",
                          "setup.compile_s", "setup.compiles_in_window",
@@ -103,6 +105,7 @@ def test_the_accepted_entries_stand_first_and_as_they_were():
         "sparse_fwd_roofline", "sparse_mean_roofline", "sparse_bwd_roofline",
         "index_bwd_roofline", "delta_conv_roofline",
         "delta_conv_bwd_roofline"]
+    assert names[108:110] == ["hbm.compiled_gb", "hbm.temporaries_gb"]
     assert len(names) == len(set(names))
     retired = harness.read_json(HERE, "retired_pr74.json")
     assert len(retired) == 19 and not set(retired) & set(names)
@@ -166,6 +169,12 @@ def test_the_counters_a_traced_run_offers():
                  "setup.compiles_in_window"):
         assert harness.read_layer_metric(
             _spec(name)["read"], {"counters": got}) is not None, name
+    # a count stays the whole number it was; only a read that says "per"
+    # divides (the compiled step's bytes as GB)
+    assert isinstance(harness.read_layer_metric(
+        {"counter": "setup_compiles"}, {"counters": got}), int)
+    assert harness.read_layer_metric(
+        {"counter": "setup_compiles", "per": 2}, {"counters": got}) == 3.5
     # a program without the split leaves those metrics out
     old = harness.setup_counters({"compiles": 1, "seconds_total": 1.0},
                                  split, 0)
